@@ -6,6 +6,11 @@ survive the scrollback.  ``--trace PATH.trace.jsonl`` replays a recorded
 serving trace (see deepspeed_tpu.autotuning) through a gateway built
 from the ambient DS_* / DS_AUTOTUNE_CONFIG environment instead of
 running a training sweep — the serving-side twin of the MFU lanes.
+
+Like ``bench.py`` it measures the chip only: no TPU is an error before
+any work, the peak comes from ``bench.PEAK_FLOPS`` by ``device_kind``,
+and an experiment that raised is recorded and makes the exit code
+non-zero.
 """
 
 import json
@@ -18,7 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-PEAK = 197e12  # v5e bf16
+from bench import _peak_flops, _require_tpu
 
 RESULTS_PATH = os.environ.get("BENCH_SWEEP_RESULTS_PATH",
                               "bench_sweep_results.json")
@@ -26,13 +31,17 @@ RESULTS = []  # every run()/run_trace() appends one record
 
 
 def _flush_results():
-    """Write this invocation's records alongside the printed lines."""
+    """Write this invocation's records alongside the printed lines;
+    exit non-zero if any experiment raised."""
     if not RESULTS:
         return
     payload = {"argv": sys.argv[1:], "results": RESULTS}
     with open(RESULTS_PATH, "w") as f:
         json.dump(payload, f, indent=1)
     print(f"results -> {RESULTS_PATH}")
+    failed = [r["name"] for r in RESULTS if "error" in r]
+    if failed:
+        sys.exit(f"bench_sweep: {len(failed)} experiment(s) raised: {failed}")
 
 
 def run(name, *, hidden=1536, inter=4096, layers=16, heads=16, B=4, S=2048,
@@ -71,8 +80,10 @@ def run(name, *, hidden=1536, inter=4096, layers=16, heads=16, B=4, S=2048,
             engine.train_batch(batch=(ids, ids))
             jax.block_until_ready(engine.params)
             times.append(time.perf_counter() - t0)
-        dt = min(times)  # min filters chip contention spikes
+        dt = min(times)
     except Exception as e:
+        # recorded so the rest of the sweep still runs; the invocation
+        # exits non-zero (see _flush_results)
         print(f"{name}: FAILED {type(e).__name__}: {str(e)[:160]}")
         RESULTS.append({"name": name,
                         "error": f"{type(e).__name__}: {str(e)[:160]}"})
@@ -81,9 +92,10 @@ def run(name, *, hidden=1536, inter=4096, layers=16, heads=16, B=4, S=2048,
     tokens = B * gas * S
     dense = 6.0 * n_params * tokens
     attn = 12.0 * layers * tokens * S * hidden
-    mfu = (dense + attn) / dt / PEAK
+    peak = _peak_flops(jax.devices()[0])
+    mfu = (dense + attn) / dt / peak
     print(f"{name}: params={n_params/1e6:.0f}M step={dt*1e3:.1f}ms "
-          f"tok/s={tokens/dt:,.0f} MFU={mfu:.3f} (dense-only {dense/dt/PEAK:.3f})")
+          f"tok/s={tokens/dt:,.0f} MFU={mfu:.3f} (dense-only {dense/dt/peak:.3f})")
     RESULTS.append({"name": name, "params": n_params,
                     "step_ms": round(dt * 1e3, 2),
                     "tok_s": round(tokens / dt, 1), "mfu": round(mfu, 4)})
@@ -113,18 +125,13 @@ def run_trace(path):
     # token_strings) recompiled against THIS config's vocab
     constrained = any(getattr(r, "schema", None) is not None for r in trace)
     groups.destroy_mesh()
-    on_tpu = jax.default_backend() == "tpu"
     need_ctx = int(s["mean_prompt_len"] + s["mean_max_new"]) * 4
-    if on_tpu:
-        model = build_llama("7b", hidden_size=3072, intermediate_size=8192,
-                            num_hidden_layers=22, num_attention_heads=24,
-                            num_key_value_heads=8,
-                            max_position_embeddings=2048,
-                            vocab_size=32000, remat=False)
-        block, n_seqs, batch, vocab = 32, 16, 512, 32000
-    else:
-        model = build_llama("debug")
-        block, n_seqs, batch, vocab = 8, 8, 96, 256
+    model = build_llama("7b", hidden_size=3072, intermediate_size=8192,
+                        num_hidden_layers=22, num_attention_heads=24,
+                        num_key_value_heads=8,
+                        max_position_embeddings=2048,
+                        vocab_size=32000, remat=False)
+    block, n_seqs, batch, vocab = 32, 16, 512, 32000
     max_ctx = max(block * 4, -(-need_ctx // block) * block)
     engine = InferenceEngineV2(
         model=model,
@@ -162,6 +169,7 @@ def run_trace(path):
 
 
 if __name__ == "__main__":
+    _require_tpu("bench_sweep.py")
     if "--trace" in sys.argv:
         i = sys.argv.index("--trace")
         if i + 1 >= len(sys.argv):

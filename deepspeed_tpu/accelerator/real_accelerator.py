@@ -33,13 +33,10 @@ def get_accelerator():
         _validate_accelerator(accelerator_name)
 
     if accelerator_name is None:
-        accelerator_name = "cpu"
-        try:
-            import jax
-            if any(d.platform not in ("cpu", "host") for d in jax.devices()):
-                accelerator_name = "tpu"
-        except Exception:
-            pass
+        # a backend that fails to initialize raises here: a chip that is
+        # there but unusable must not be mistaken for "this is a CPU host"
+        import jax
+        accelerator_name = "cpu" if all(d.platform == "cpu" for d in jax.devices()) else "tpu"
 
     set_accelerator_name(accelerator_name)
     return ds_accelerator

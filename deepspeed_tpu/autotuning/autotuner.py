@@ -71,10 +71,11 @@ class Autotuner:
         # autotuner.py:663 profiles model info to prune the space;
         # mem_model.py estimates from eval_shape + jaxpr walk instead)
         self.memory_budget_bytes = memory_budget_bytes
-        if world_size is None:
-            import jax as _jax
-            world_size = len(_jax.devices())
-        self.world_size = int(world_size)
+        # Devices each experiment runs on, for the memory model. None is
+        # resolved where the experiments run: tune() asks JAX in this
+        # process; tune_distributed() takes its slots_per_exp, because a
+        # parent that touches JAX holds the chip its workers need.
+        self.world_size = None if world_size is None else int(world_size)
         # JSON-able specs for the distributed mode's out-of-process
         # workers (exp_runner.py schema)
         self.model_spec = model_spec
@@ -133,6 +134,9 @@ class Autotuner:
         from deepspeed_tpu.autotuning.mem_model import estimate_experiment_memory
         if not hasattr(self, "_mem_trace_cache"):
             self._mem_trace_cache = {}
+        if self.world_size is None:
+            import jax
+            self.world_size = len(jax.devices())
         return estimate_experiment_memory(
             self.model_fn, self.batch_fn,
             self._experiment_config(stage, mbs, gas, offload), mbs,
@@ -306,6 +310,8 @@ class Autotuner:
                              "exp_runner model description)")
         if hosts is None:
             hosts = parse_hostfile(hostfile) if hostfile else {"localhost": 1}
+        if self.world_size is None:
+            self.world_size = int(slots_per_exp)
         results_dir = self.results_dir or "autotuning_exps"
         self.results = []
         grid = []  # (stage, mbs, gas, offload, name, exp_dir)

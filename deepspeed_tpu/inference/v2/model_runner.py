@@ -96,7 +96,9 @@ def _paged_attend(q, k, v, kc, vc, batch, Dh, alibi=None, mesh=None, impl=None):
     block-tabled context. The attention implementation comes from the
     ``modules/heuristics`` registry (Pallas decode kernel single-device
     or per-TP-shard, XLA gather fallback / ALiBi path), optionally
-    pinned by the engine config's ``implementation_overrides``."""
+    pinned by the engine config's ``implementation_overrides``; ``impl``
+    is the engine's :class:`AttentionChoice`, which carries the pin in
+    and the selected implementation's name out."""
     bs = kc.shape[1]
     blk = batch["block_tables"][batch["token_seq"], batch["token_pos"] // bs]  # [T]
     off = batch["token_pos"] % bs
@@ -106,8 +108,11 @@ def _paged_attend(q, k, v, kc, vc, batch, Dh, alibi=None, mesh=None, impl=None):
     from deepspeed_tpu.inference.v2.modules.heuristics import instantiate_attn
     tab = batch["block_tables"][batch["token_seq"]]  # [T, MB]
     pos = batch["token_pos"]
-    _, attn_fn = instantiate_attn(mesh, Dh, bs, q.shape, kc.shape, alibi,
-                                  override=impl)
+    name, attn_fn = instantiate_attn(mesh, Dh, bs, q.shape, kc.shape, alibi,
+                                     max_blocks=tab.shape[1],
+                                     override=impl.override if impl else None)
+    if impl is not None:
+        impl.selected[q.shape[0]] = name
     out = attn_fn(q, kc, vc, tab, pos)
     return _c(out, (None, "tensor", None), mesh), kc, vc
 
@@ -288,7 +293,10 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     ``a[site] [L, S, in, r]`` / ``b[site] [L, S, r, out]``, per-slot
     ``scales [S]``, the batch's per-sequence adapter slots
     ``seq_adapters [max_seqs + 1]`` (pad row = slot 0 = base), and the
-    static kernel impl selector. Llama-family layers only."""
+    static kernel impl selector. Llama-family layers only.
+
+    ``attn_impl``: the engine's ``heuristics.AttentionChoice`` (None =
+    unpinned and unreported)."""
     is_gpt = hasattr(cfg, "position_embedding")
     embed = params["model"]["embed_tokens"]
     h = _c(embed[batch["token_ids"]].astype(dtype), (None, None), mesh)  # [T, D]

@@ -14,7 +14,8 @@ Implementations registered for ``attention`` (the ragged decode op):
   (``ops/pallas/paged_attention``); needs ``head_dim % 128 == 0`` and
   ``block_size % 8 == 0`` (Mosaic lane alignment — 64-dim-head models
   such as Bloom-560M take the XLA path; lane-packing two 64-dim heads
-  is possible but unimplemented).
+  is possible but unimplemented) and a ``[tokens, max_blocks]`` block
+  table that fits the kernel's SMEM budget.
 - ``pallas_paged_sharded``  — the same kernel per tensor-parallel shard
   under ``shard_map`` (query/KV heads divide over 'tensor').
 - ``xla_gather``            — gather-based XLA reference; always
@@ -24,6 +25,18 @@ Implementations registered for ``attention`` (the ragged decode op):
 from jax.sharding import PartitionSpec as P
 
 REGISTRY = {"attention": []}
+
+
+class AttentionChoice:
+    """The engine's side of the selection: the implementation its config
+    pinned (``override``, None = choose), and ``selected`` — what each
+    traced program actually got, keyed by the program's token count, so
+    the engine can say which implementation serves and nothing has to be
+    inferred from the backend."""
+
+    def __init__(self, override=None):
+        self.override = override
+        self.selected = {}
 
 
 def register_implementation(op, name):
@@ -43,12 +56,13 @@ def implementations(op):
 class _PallasPaged:
 
     @staticmethod
-    def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi):
+    def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
         from deepspeed_tpu.ops.pallas import use_pallas
-        from deepspeed_tpu.ops.pallas.paged_attention import kernel_supported
+        from deepspeed_tpu.ops.pallas.paged_attention import kernel_supported, smem_table_fits
         return (alibi is None and (mesh is None or mesh.size == 1)
                 and use_pallas()
-                and kernel_supported(head_dim, block_size, kc_shape[2]))
+                and kernel_supported(head_dim, block_size, kc_shape[2])
+                and smem_table_fits(q_shape[0], max_blocks))
 
     @staticmethod
     def instantiate(mesh, head_dim, block_size, q_shape, kc_shape, alibi):
@@ -63,15 +77,17 @@ class _PallasPagedSharded:
     KV_SPEC = P(None, None, "tensor", None)
 
     @staticmethod
-    def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi):
+    def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
         from deepspeed_tpu.ops.pallas import kernel_dispatch, spec_divides
-        from deepspeed_tpu.ops.pallas.paged_attention import kernel_supported
+        from deepspeed_tpu.ops.pallas.paged_attention import kernel_supported, smem_table_fits
         if alibi is not None or mesh is None or mesh.size == 1:
             return False
         tp = dict(mesh.shape).get("tensor", 1)
         return (kernel_dispatch(mesh) == "shard_map"
                 and kernel_supported(head_dim, block_size,
                                      max(kc_shape[2] // tp, 1))
+                # tokens and tables are replicated: every shard holds them whole
+                and smem_table_fits(q_shape[0], max_blocks)
                 and spec_divides(mesh, _PallasPagedSharded.Q_SPEC, q_shape)
                 and spec_divides(mesh, _PallasPagedSharded.KV_SPEC, kc_shape)
                 # per-shard GQA grouping needs whole KV-head groups
@@ -92,7 +108,7 @@ class _PallasPagedSharded:
 class _XlaGather:
 
     @staticmethod
-    def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi):
+    def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
         return True
 
     @staticmethod
@@ -104,22 +120,26 @@ class _XlaGather:
 
 
 def instantiate_attn(mesh, head_dim, block_size, q_shape, kc_shape, alibi,
-                     override=None):
+                     max_blocks, override=None):
     """→ ``(impl_name, fn(q, kc, vc, tab, pos))`` — the first supported
     implementation in registration (priority) order, or the named one
     when the config pins ``override`` (reference
-    heuristics.instantiate_attn + config_bundle semantics)."""
+    heuristics.instantiate_attn + config_bundle semantics). A pin that
+    does not support the config raises; it never degrades.
+    ``max_blocks``: the block table's width (blocks per sequence)."""
     for name, impl in REGISTRY["attention"]:
         if override is not None and name != override:
             continue
-        if impl.supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi):
+        if impl.supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
             return name, impl.instantiate(mesh, head_dim, block_size,
                                           q_shape, kc_shape, alibi)
         if override is not None:
+            import jax
             raise ValueError(
                 f"implementation_overrides pinned attention={override!r}, but it "
                 f"does not support this config (head_dim={head_dim}, "
-                f"block_size={block_size}, mesh={mesh and mesh.shape}, "
-                f"alibi={alibi is not None})")
+                f"block_size={block_size}, tokens={q_shape[0]}, max_blocks={max_blocks}, "
+                f"mesh={mesh and dict(mesh.shape)}, alibi={alibi is not None}, "
+                f"backend={jax.default_backend()!r})")
     raise ValueError(f"no attention implementation named {override!r}; "
                      f"available: {implementations('attention')}")

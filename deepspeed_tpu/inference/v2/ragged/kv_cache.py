@@ -28,7 +28,10 @@ class KVCacheHandleError(ValueError):
 class BlockedKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, n_kv_heads, head_dim,
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, sharding=None):
+        """``sharding``: where the pool lives (a serving mesh shards it
+        over KV heads); it is allocated there directly, never whole on
+        the default device first."""
         assert num_blocks >= 2, "need at least one real block beyond the null block"
         self.num_layers = num_layers
         self.num_blocks = num_blocks
@@ -37,8 +40,8 @@ class BlockedKVCache:
         self.head_dim = head_dim
         self.dtype = dtype
         shape = (num_layers, num_blocks, block_size, n_kv_heads, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        self.k = jnp.zeros(shape, dtype, device=sharding)
+        self.v = jnp.zeros(shape, dtype, device=sharding)
         self._allocator = BlockedAllocator(num_blocks)
         self._allocator.allocate(1)  # pin the null block forever
 

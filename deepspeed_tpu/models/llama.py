@@ -261,6 +261,9 @@ def einsum_attention(q, k, v, causal=True, bias=None, mask=None):
 
 
 def _local_attention(q, k, v, impl: str, causal=True):
+    """``impl``: "einsum", "flash" (a pin: the Pallas kernels run or this
+    raises — never the XLA reference under the kernel's name), or "auto"
+    (the kernels where they can run and pay off, else einsum)."""
     from deepspeed_tpu.ops.pallas import kernel_dispatch, shard_map_kernel
     from deepspeed_tpu.parallel import groups
     mesh = groups.get_mesh(required=False)
@@ -271,15 +274,21 @@ def _local_attention(q, k, v, impl: str, causal=True):
         # The Pallas kernel wins once the [S, S] score matrix dominates;
         # tiny test shapes stay on the fused-by-XLA einsum path.
         impl = "flash" if mode != "xla" and q.shape[1] >= 256 else "einsum"
+    elif impl == "flash" and mode == "xla":
+        raise ValueError(
+            f"attention_impl='flash' is pinned but the Pallas kernel cannot run here: "
+            f"backend={jax.default_backend()!r}, mesh={mesh and dict(mesh.shape)}, "
+            f"q{tuple(q.shape)} (kernels need a TPU backend or DS_PALLAS=1, no enclosing "
+            f"manual shard_map, and batch/heads that divide the mesh)")
     if impl == "flash":
         from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+        attend = lambda a, b, c: flash_attention(a, b, c, causal=causal, force_pallas=True)
         if mode == "shard_map":
             # Run the kernel per-shard on the post-Ulysses layout (full
             # sequence, head-sharded) — causal masking is shard-local.
             spec = heads_spec(mesh)
-            return shard_map_kernel(lambda a, b, c: flash_attention(a, b, c, causal=causal),
-                                    mesh, (spec, spec, spec), spec)(q, k, v)
-        return flash_attention(q, k, v, causal=causal)
+            return shard_map_kernel(attend, mesh, (spec, spec, spec), spec)(q, k, v)
+        return attend(q, k, v)
     return einsum_attention(q, k, v, causal=causal)
 
 

@@ -31,8 +31,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
-
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def fused_gmm_enabled():
@@ -200,10 +199,7 @@ def _use_pallas_gmm(num_rows, d_model, d_ff, quantized=False):
     scale instead of falling back)."""
     if FORCE_INTERPRET:
         return True
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-    except Exception:
+    if jax.devices()[0].platform != "tpu":
         return False
     if d_model % 128 or d_ff % 128:
         return False

@@ -11,8 +11,12 @@ defeat the compiler.
 
 Dispatch policy: each op has a reference XLA implementation and a
 Pallas kernel; ``use_pallas()`` selects the kernel on TPU backends
-(override with ``DS_PALLAS=0/1``). Tests exercise the kernels in
-interpreter mode on CPU against the XLA references.
+(override with ``DS_PALLAS=0/1``). Kernels run COMPILED: interpreter
+mode is what a caller asks for (``interpret=True``, ``FORCE_INTERPRET``,
+or ``DS_PALLAS=1`` on a backend with no Mosaic, as the CPU tests do) —
+see :func:`default_interpret`. A call site that pinned a kernel raises
+when the kernel cannot run; only unpinned ("auto") sites fall back to
+the XLA reference.
 """
 
 import contextlib
@@ -52,6 +56,18 @@ def _pallas_enabled() -> bool:
     if forced is not None:
         return forced
     return jax.default_backend() == "tpu"
+
+
+def default_interpret() -> bool:
+    """The ``interpret=None`` default of every kernel entry point.
+
+    False — kernels compile through Mosaic — with one exception:
+    ``DS_PALLAS=1`` forcing the kernel path on a backend that has no
+    Mosaic (the virtual CPU mesh of the test suite), where interpreted
+    is the only way the forced kernel can run. On a TPU it is never
+    True, so nothing the chip runs is silently interpreted."""
+    from deepspeed_tpu.utils.env_registry import env_opt_bool
+    return env_opt_bool("DS_PALLAS") is True and jax.default_backend() != "tpu"
 
 
 def use_pallas() -> bool:
@@ -119,9 +135,8 @@ def shard_map_kernel(fn, mesh, in_specs, out_specs):
         finally:
             _local_kernel_ctx.reset(tok)
 
-    from deepspeed_tpu.utils.jax_compat import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention  # noqa: E402,F401

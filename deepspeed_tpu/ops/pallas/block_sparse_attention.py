@@ -314,7 +314,8 @@ def block_sparse_attention(q, k, v, layout, block, interpret=None):
     """
     B, S, Hq, D = q.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from deepspeed_tpu.ops.pallas import default_interpret
+        interpret = default_interpret()
     layout = np.asarray(layout, bool)
     if layout.shape[0] == 1 and Hq > 1:
         layout = np.broadcast_to(layout, (Hq,) + layout.shape[1:])

@@ -12,8 +12,10 @@ model stack; internally blocks run per (batch*head) over [S, D] tiles.
 Causal masking is applied by global block indices; sequence lengths
 that do not divide the block size are zero-padded and masked.
 
-On non-TPU backends the public entry point falls back to a fused-by-XLA
-reference implementation (identical math, fp32 softmax).
+``flash_attention(force_pallas=None)`` is the unpinned entry: it takes
+the kernels where ``use_pallas()`` says so and the fused-by-XLA
+reference (identical math, fp32 softmax) elsewhere. A caller that pinned
+the kernel passes ``force_pallas=True`` and never gets the reference.
 """
 
 import functools
@@ -230,6 +232,7 @@ def _fwd_impl(q, k, v, seg, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q_p, k_p, v_p, seg_p, seg_p)
     # Drop the lane replication before saving lse as a VJP residual
     # (128x HBM otherwise); the backward re-broadcasts it.
@@ -277,6 +280,7 @@ def _bwd_impl(q, k, v, seg, o, lse, do, causal, sm_scale, block_q, block_k, inte
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q_p, k_p, v_p, do_p, lse_p, delta, seg_p, seg_p)
     dk, dv = dkv
 
@@ -298,6 +302,7 @@ def _bwd_impl(q, k, v, seg, o, lse, do, causal, sm_scale, block_q, block_k, inte
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(q_p, k_p, v_p, do_p, lse_p, delta, seg_p, seg_p)
 
     return dq[:, :seq_len], dk[:, :seq_len], dv[:, :seq_len]
@@ -347,9 +352,9 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=1024, block_k=1
                     segment_ids=None, bias=None, interpret=None, force_pallas=None):
     """Blocked flash attention on [B, S, H, D] tensors.
 
-    On TPU runs the Pallas kernels; elsewhere defaults to the XLA
-    reference (set ``force_pallas=True``/``interpret=True`` to exercise
-    the kernels off-TPU, as the unit tests do).
+    ``force_pallas``: True runs the Pallas kernels or raises, False the
+    XLA reference, None picks by ``use_pallas()``. The kernels compile
+    unless ``interpret=True`` asks otherwise (the unit tests off-TPU).
 
     ``segment_ids``: [B, S] int32 — packed sequences attend only within
     equal ids (composes with ``causal``); supported by the kernels.
@@ -361,12 +366,14 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=1024, block_k=1
     b, s, h, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(d)
-    on_tpu = jax.default_backend() == "tpu"
+    from deepspeed_tpu.ops.pallas import default_interpret, use_pallas
+    if force_pallas and bias is not None:
+        raise ValueError("flash_attention(force_pallas=True) with an additive bias: "
+                         "the bias path has no kernel, only the XLA reference")
     if force_pallas is None:
-        from deepspeed_tpu.ops.pallas import use_pallas
         force_pallas = use_pallas()
     if interpret is None:
-        interpret = not on_tpu
+        interpret = default_interpret()
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, d)

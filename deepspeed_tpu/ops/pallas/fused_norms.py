@@ -33,7 +33,7 @@ def _ln_fwd_kernel(x_ref, scale_ref, bias_ref, o_ref, *, eps):
                 + bias_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _row_call(kernel, x2d, others, out_dtype, block_rows, interpret):
+def _row_call(kernel, name, x2d, others, out_dtype, block_rows, interpret):
     rows, d = x2d.shape
     block_rows = min(block_rows, rows)
     pad = (-rows) % block_rows
@@ -47,6 +47,7 @@ def _row_call(kernel, x2d, others, out_dtype, block_rows, interpret):
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x_p.shape, out_dtype),
         interpret=interpret,
+        name=name,
     )(x_p, *others)
     return out[:rows] if pad else out
 
@@ -59,17 +60,17 @@ def fused_rms_norm(x, scale, eps=1e-5, interpret=None):
 
 
 def _rms_fwd(x, scale, eps, interpret):
-    from deepspeed_tpu.ops.pallas import use_pallas
+    from deepspeed_tpu.ops.pallas import default_interpret, use_pallas
     # interpret=True forces the kernel (tests); interpret=False or None
     # off-TPU takes the XLA fallback.
     use_kernel = use_pallas() or interpret is True
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     shape = x.shape
     if use_kernel:
         x2d = x.reshape(-1, shape[-1])
-        out = _row_call(functools.partial(_rms_fwd_kernel, eps=eps), x2d, (scale,),
-                        x.dtype, 256, interpret).reshape(shape)
+        out = _row_call(functools.partial(_rms_fwd_kernel, eps=eps), "fused_rms_norm", x2d,
+                        (scale,), x.dtype, 256, interpret).reshape(shape)
     else:
         x32 = x.astype(jnp.float32)
         rstd = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
@@ -102,17 +103,17 @@ def fused_layer_norm(x, scale, bias, eps=1e-5, interpret=None):
 
 
 def _ln_fwd(x, scale, bias, eps, interpret):
-    from deepspeed_tpu.ops.pallas import use_pallas
+    from deepspeed_tpu.ops.pallas import default_interpret, use_pallas
     # interpret=True forces the kernel (tests); interpret=False or None
     # off-TPU takes the XLA fallback.
     use_kernel = use_pallas() or interpret is True
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     shape = x.shape
     if use_kernel:
         x2d = x.reshape(-1, shape[-1])
-        out = _row_call(functools.partial(_ln_fwd_kernel, eps=eps), x2d, (scale, bias),
-                        x.dtype, 256, interpret).reshape(shape)
+        out = _row_call(functools.partial(_ln_fwd_kernel, eps=eps), "fused_layer_norm", x2d,
+                        (scale, bias), x.dtype, 256, interpret).reshape(shape)
     else:
         x32 = x.astype(jnp.float32)
         mean = jnp.mean(x32, axis=-1, keepdims=True)

@@ -197,17 +197,22 @@ def _qmm_pallas(x2, values, scales, scheme, dequant_dtype, out_dtype, interpret)
 
 def _qmm_impl(x, values, scales, scheme, dequant_dtype, out_dtype, interpret,
               force_pallas):
-    from deepspeed_tpu.ops.pallas import use_pallas
+    from deepspeed_tpu.ops.pallas import default_interpret, use_pallas
     use_kernel = (force_pallas is True or interpret is True
                   or (force_pallas is not False and use_pallas()))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     lead, k_dim = x.shape[:-1], x.shape[-1]
     if use_kernel and values.ndim == 2 and scales.ndim == 2:
         out = _qmm_pallas(x.reshape(-1, k_dim), values, scales, scheme,
                           dequant_dtype, out_dtype, interpret)
         if out is not None:
             return out.reshape(lead + (out.shape[-1],))
+    if force_pallas is True:
+        raise ValueError(
+            f"quant_matmul(force_pallas=True): the fused kernel admits no legal tiling for "
+            f"x{tuple(x.shape)}, values{tuple(values.shape)}, scales{tuple(scales.shape)} "
+            f"({scheme}); only the dequantize-then-matmul reference can take these shapes")
     w = dequantize_grouped(values, scales, scheme, dequant_dtype)
     return jnp.matmul(x, w).astype(out_dtype)
 

@@ -10,8 +10,11 @@ max / sum) with positions beyond the token's context masked. GQA is
 handled by viewing the query heads as [Hkv, G, Dh].
 
 The XLA reference path (``xla_paged_attention``) is the same math via
-gather; the v2 model runner dispatches the kernel on TPU through
-``use_pallas()`` and this fallback elsewhere.
+gather. Which of the two a program runs is decided in ONE place, the
+``inference/v2/modules/heuristics`` registry (``supports()`` there reads
+:func:`kernel_supported` and :func:`smem_table_fits`), where the choice
+has a name the engine reports; the kernel entry itself never hands a
+refused shape to the reference — it raises.
 """
 
 import functools
@@ -25,6 +28,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(np.finfo(np.float32).min)
 
+# The block tables + positions ride in SMEM via scalar prefetch and v5e
+# SMEM is ~1 MB: oversized state configs (e.g. the default
+# max_tokens=768 x max_context/bs tables) overflow it at COMPILE time
+# ("Ran out of memory in memory space smem").
+SMEM_TABLE_BYTES = 768 * 1024
+# The gather reference materializes a dense [T, MB*bs, Hkv, Dh] copy of
+# K and of V per layer; past this it is an opaque allocator OOM.
+GATHER_LIMIT_BYTES = 2 << 30
+
 
 def xla_paged_attention(q, kc, vc, block_tables, token_pos, alibi_slopes=None):
     """Reference math. q: [T, H, Dh]; kc/vc: [NB, bs, Hkv, Dh];
@@ -34,6 +46,12 @@ def xla_paged_attention(q, kc, vc, block_tables, token_pos, alibi_slopes=None):
     relative-position penalty slope_h * (k_pos - q_pos) to the scores."""
     T, H, Dh = q.shape
     _, bs, Hkv, _ = kc.shape
+    gather_bytes = 2 * T * block_tables.shape[1] * bs * Hkv * Dh * kc.dtype.itemsize
+    if gather_bytes > GATHER_LIMIT_BYTES:
+        raise ValueError(
+            f"the XLA gather attention would materialize {gather_bytes / 1e9:.0f} GB of KV "
+            f"for block table [{T}, {block_tables.shape[1]}] — shrink "
+            f"max_ragged_batch_size / max_context, or raise kv_block_size")
     ks = kc[block_tables].reshape(T, -1, Hkv, Dh).astype(q.dtype)
     vs = vc[block_tables].reshape(T, -1, Hkv, Dh).astype(q.dtype)
     if Hkv != H:
@@ -65,6 +83,12 @@ def kernel_supported(head_dim, block_size, n_kv_heads=None):
     models take the XLA gather path
     (see ``inference/v2/modules/heuristics.py``)."""
     return head_dim % 128 == 0 and block_size % 8 == 0
+
+
+def smem_table_fits(n_tokens, max_blocks):
+    """Do the ``[n_tokens, max_blocks]`` int32 block table and the
+    ``[n_tokens]`` positions fit the kernel's SMEM budget?"""
+    return (n_tokens * max_blocks + n_tokens) * 4 <= SMEM_TABLE_BYTES
 
 
 def _kernel(tab_ref, pos_ref, q_ref, kc_ref, vc_ref, o_ref,
@@ -134,30 +158,22 @@ def _kernel(tab_ref, pos_ref, q_ref, kc_ref, vc_ref, o_ref,
 def paged_decode_attention(q, kc, vc, block_tables, token_pos, interpret=None):
     """Pallas path of :func:`xla_paged_attention` (same contract)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from deepspeed_tpu.ops.pallas import default_interpret
+        interpret = default_interpret()
     T, H, Dh = q.shape
     NB, bs, Hkv, _ = kc.shape
     MB = block_tables.shape[1]
     groups = H // Hkv
-    if not interpret and not kernel_supported(Dh, bs, Hkv):
-        return xla_paged_attention(q, kc, vc, block_tables, token_pos)
-    # The block tables + positions ride in SMEM via scalar prefetch and
-    # v5e SMEM is ~1 MB: oversized state configs (e.g. the default
-    # max_tokens=768 x max_context/bs tables) overflow it at COMPILE
-    # time ("Ran out of memory in memory space smem"). Fall back to the
-    # XLA gather path when ITS dense [T, MB*bs, Hkv, Dh] KV copy is
-    # affordable; otherwise raise actionable guidance — the gather at
-    # these shapes can be 100s of GB and would surface as an opaque
-    # allocator OOM.
-    if not interpret and (T * MB + T) * 4 > 768 * 1024:
-        gather_bytes = 2 * T * MB * bs * Hkv * Dh * kc.dtype.itemsize
-        if gather_bytes <= 2 << 30:
-            return xla_paged_attention(q, kc, vc, block_tables, token_pos)
-        raise ValueError(
-            f"paged decode block table [{T}, {MB}] overflows the kernel's SMEM "
-            f"budget and the XLA gather fallback would materialize "
-            f"{gather_bytes/1e9:.0f} GB of KV — shrink max_ragged_batch_size / "
-            f"max_context, or raise kv_block_size")
+    if not interpret:
+        if not kernel_supported(Dh, bs, Hkv):
+            raise ValueError(
+                f"paged decode kernel needs head_dim % 128 == 0 and block_size % 8 == 0, "
+                f"got head_dim={Dh}, block_size={bs}")
+        if not smem_table_fits(T, MB):
+            raise ValueError(
+                f"paged decode block table [{T}, {MB}] overflows the kernel's "
+                f"{SMEM_TABLE_BYTES >> 10} KB SMEM budget — shrink max_ragged_batch_size / "
+                f"max_context, or raise kv_block_size")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # tables, positions
@@ -187,4 +203,5 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, interpret=None):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, H, Dh), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), token_pos.astype(jnp.int32), q, kc2, vc2)

@@ -63,10 +63,10 @@ def quantize_int8(x, group_size=2048, stochastic=False, seed=0, interpret=None):
     taken over the flattened tensor; pads to a group multiple. Pass a
     step-varying ``seed`` when ``stochastic`` so rounding averages out
     over steps."""
-    from deepspeed_tpu.ops.pallas import use_pallas
+    from deepspeed_tpu.ops.pallas import default_interpret, use_pallas
     use_kernel = use_pallas() or interpret is True
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     groups, _ = _group_view(x, group_size)
     g = groups.shape[0]
 
@@ -117,10 +117,10 @@ def dequantize_int8(values, scales, orig_shape, dtype=None, interpret=None):
     full precision matters (round-trip bounds, LoRA fuse math)."""
     if dtype is None:
         dtype = jnp.bfloat16
-    from deepspeed_tpu.ops.pallas import use_pallas
+    from deepspeed_tpu.ops.pallas import default_interpret, use_pallas
     use_kernel = use_pallas() or interpret is True
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     g, group_size = values.shape
     if use_kernel:
         block = min(256, g)

@@ -201,12 +201,7 @@ def make_mesh_topology(world_size=None,
     assert total == ndev, (f"mesh {dims} requires {total} devices but {ndev} are available")
 
     shape = tuple(dims[a] for a in MESH_AXES)
-    try:
-        # Auto axis types: classic pjit-style sharding propagation (the
-        # jax 0.9 default of Explicit would demand sharding-typed programs).
-        axis_types = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
-        return jax.make_mesh(shape, MESH_AXES, axis_types=axis_types, devices=devices)
-    except (TypeError, AttributeError):
-        # Older make_mesh signatures
-        dev_array = np.asarray(devices).reshape(shape)
-        return jax.sharding.Mesh(dev_array, MESH_AXES)
+    # Auto axis types: classic pjit-style sharding propagation (the
+    # jax 0.9 default of Explicit would demand sharding-typed programs).
+    axis_types = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
+    return jax.make_mesh(shape, MESH_AXES, axis_types=axis_types, devices=devices)

@@ -31,6 +31,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -55,7 +56,6 @@ from deepspeed_tpu.runtime.dataloader import DeepSpeedDataLoader, RepeatingLoade
 from deepspeed_tpu.runtime.fp16.loss_scaler import DynamicLossScaler, has_overflow, scaler_state, update_scale
 from deepspeed_tpu.runtime.zero.partitioning import ZeroShardingPolicy, batch_spec, path_tree_map
 from deepspeed_tpu.utils.env_registry import env_bool, env_int, env_raw
-from deepspeed_tpu.utils.jax_compat import shard_map
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (BACKWARD_GLOBAL_TIMER, BACKWARD_MICRO_TIMER, FORWARD_GLOBAL_TIMER,
                                        FORWARD_MICRO_TIMER, STEP_GLOBAL_TIMER, STEP_MICRO_TIMER, TRAIN_BATCH_TIMER,

@@ -35,6 +35,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -43,7 +44,6 @@ from deepspeed_tpu.runtime.engine import DeepSpeedEngine, _is_float
 from deepspeed_tpu.runtime.pipe.module import PipelineModule
 from deepspeed_tpu.runtime.pipe.schedule import InferenceSchedule, TrainSchedule
 from deepspeed_tpu.runtime.zero.partitioning import batch_spec, path_tree_map
-from deepspeed_tpu.utils.jax_compat import shard_map
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.timer import TRAIN_BATCH_TIMER
 

@@ -23,10 +23,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.parallel import groups
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 NEG_INF = -jnp.inf
 

@@ -21,11 +21,7 @@ def mesh():
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from jax import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 def test_all_reduce(mesh):

@@ -120,7 +120,8 @@ def test_ragged_rows_and_empty_row():
     assert np.all(out[:, 32:48] == 0.0)  # empty row → zeros
 
 
-def test_sparse_self_attention_dispatches_kernel():
+def test_sparse_self_attention_dispatches_kernel(monkeypatch):
+    monkeypatch.setenv("DS_PALLAS", "1")  # no Mosaic here: ask for the interpreted kernel
     B, S, H, D, block = 1, 64, 2, 16, 16
     cfg = FixedSparsityConfig(num_heads=H, block=block, num_local_blocks=2,
                               num_global_blocks=1)

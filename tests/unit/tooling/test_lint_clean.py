@@ -18,8 +18,9 @@ from tools.graft_lint.linter import (KNOB_DOCS, RULES, lint_paths,
                                      load_baseline)
 
 PKG = os.path.join(REPO_ROOT, "deepspeed_tpu")
-# the same default scope bin/ds_lint lints: the package plus bin/
-SCOPE = [PKG, os.path.join(REPO_ROOT, "bin")]
+# the default scope bin/ds_lint lints (the package plus bin/), plus the
+# chip entry point, which drives both engines
+SCOPE = [PKG, os.path.join(REPO_ROOT, "bin"), os.path.join(REPO_ROOT, "chip_smoke.py")]
 
 
 def _fmt(violations):
