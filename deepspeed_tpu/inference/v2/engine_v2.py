@@ -20,6 +20,7 @@ from deepspeed_tpu.inference.v2.model_runner import ragged_forward
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
+from deepspeed_tpu.utils import tracing
 from deepspeed_tpu.utils.env_registry import env_int, env_opt_bool
 from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.utils.sanitize import maybe_checkify_jit, sanitize_enabled
@@ -110,7 +111,7 @@ class AsyncBurstHandle:
     Pump-thread only (it is part of the engine step surface)."""
 
     def __init__(self, engine, uids, descs, k, out, st=None,
-                 entry_np=None, prev=None):
+                 entry_np=None, prev=None, record=None):
         self.uids = list(uids)
         self.k = int(k)
         self.out = out            # device [k, max_seqs] int32
@@ -120,6 +121,7 @@ class AsyncBurstHandle:
         self._entry_np = entry_np  # host entry tokens, or None when chained
         self._prev = prev          # previous handle in the device chain
         self._toks = None
+        self._record = record      # step record, open until fetch()
 
     @property
     def entry_next(self):
@@ -144,7 +146,16 @@ class AsyncBurstHandle:
         unbounded memory."""
         if self._toks is None:
             self._engine.count_host_sync()
-            self._toks = np.asarray(self.out)[:, :len(self.uids)]  # ds-lint: disable=host-sync -- THE one intended sync per pipelined burst, paid at fence time
+            rec = self._record
+            if rec is not None:
+                tracing.resume(rec)
+            try:
+                with tracing.phase("engine.fetch"):
+                    self._toks = np.asarray(self.out)[:, :len(self.uids)]  # ds-lint: disable=host-sync -- THE one intended sync per pipelined burst, paid at fence time
+            finally:
+                if rec is not None:
+                    tracing.end(rec)
+                    self._engine.last_step = rec
             self.out = None
             if self._prev is not None:
                 if self._entry_np is None:
@@ -477,6 +488,10 @@ class InferenceEngineV2:
         # number the pipelined pump exists to drive toward 1/k.
         self.host_syncs = 0
         self.tokens_emitted = 0
+        # step records (utils/tracing.py): this engine's number in them, and
+        # the record of the program run last — the scheduler reads its seq
+        self.trace_id = tracing.engine_id()
+        self.last_step = None
         self._suspended = {}  # uid -> {"handle": host KV, "seen_tokens": int}
         # Counter-PRNG root for sampling: every sampled token's key folds
         # (request seed, absolute position) into this DS_SEED-derived
@@ -615,110 +630,119 @@ class InferenceEngineV2:
         ``do_checks`` exists for reference API parity but is ignored:
         validation is what keeps sequence state consistent with the KV
         pool, so it always runs."""
-        mode, specs = self._classify_sample(sample, len(batch_uids))
-        if self.structured is not None and \
-                any(self.structured.bound(u) for u in batch_uids):
-            if mode == "logits":
-                raise RuntimeError(
-                    "constrained sequences sample on device — call put "
-                    "with sample='greedy' or a sampling spec, not the "
-                    "raw-logits path")
-            mode = "packed"  # greedy rows still need the DFA mask rows
-            specs = specs if specs is not None else [None] * len(batch_uids)
-        # host-side list→array prep on caller-provided tokens, no device sync
-        self.count_host_sync()
-        batch_tokens = [np.atleast_1d(np.asarray(t, np.int32)) for t in batch_tokens]  # ds-lint: disable=host-sync -- input tokens are host lists, never device arrays
-        # Validate the WHOLE batch before touching any sequence state: a
-        # mid-loop failure after allocate/advance would leave earlier
-        # sequences claiming KV that was never written.
-        total = sum(len(t) for t in batch_tokens)
-        if total > self.max_tokens:
-            raise ValueError(f"batch has {total} tokens > "
-                             f"max_ragged_batch_size={self.max_tokens}")
-        if len(batch_uids) > self.max_seqs:
-            raise ValueError(f"{len(batch_uids)} sequences > "
-                             f"max_ragged_sequence_count={self.max_seqs}")
-        max_ctx = self.max_ctx_tokens
-        blocks_needed = 0
-        new_seqs = 0
-        for uid, tokens in zip(batch_uids, batch_tokens):
-            desc = self.state_manager.query(uid)
-            seen = desc.seen_tokens if desc is not None else 0
-            if desc is None:
-                new_seqs += 1
-            if seen + len(tokens) > max_ctx:
-                raise ValueError(f"sequence {uid}: {seen}+{len(tokens)} tokens exceed "
-                                 f"max_context={max_ctx}")
-            blocks_needed += (desc.blocks_needed(len(tokens)) if desc is not None
-                              else -(-len(tokens) // self.block_size))
-        if blocks_needed > self._reclaimable_blocks():
-            raise RuntimeError(f"KV pool exhausted: need {blocks_needed} blocks, "
-                               f"{self._reclaimable_blocks()} reclaimable — "
-                               f"flush() sequences first")
-        if new_seqs + self.state_manager.n_tracked_sequences > \
-                self.state_manager.max_tracked_sequences:
-            raise RuntimeError("max_tracked_sequences exceeded for this batch")
+        with tracing.step("put", engine=self.trace_id, uids=tuple(batch_uids)) as rec:
+            with tracing.phase("engine.pack"):
+                mode, specs = self._classify_sample(sample, len(batch_uids))
+                if self.structured is not None and \
+                        any(self.structured.bound(u) for u in batch_uids):
+                    if mode == "logits":
+                        raise RuntimeError(
+                            "constrained sequences sample on device — call put "
+                            "with sample='greedy' or a sampling spec, not the "
+                            "raw-logits path")
+                    mode = "packed"  # greedy rows still need the DFA mask rows
+                    specs = specs if specs is not None else [None] * len(batch_uids)
+                # host-side list→array prep on caller-provided tokens, no device sync
+                self.count_host_sync()
+                batch_tokens = [np.atleast_1d(np.asarray(t, np.int32)) for t in batch_tokens]  # ds-lint: disable=host-sync -- input tokens are host lists, never device arrays
+                # Validate the WHOLE batch before touching any sequence state: a
+                # mid-loop failure after allocate/advance would leave earlier
+                # sequences claiming KV that was never written.
+                total = sum(len(t) for t in batch_tokens)
+                if total > self.max_tokens:
+                    raise ValueError(f"batch has {total} tokens > "
+                                     f"max_ragged_batch_size={self.max_tokens}")
+                if len(batch_uids) > self.max_seqs:
+                    raise ValueError(f"{len(batch_uids)} sequences > "
+                                     f"max_ragged_sequence_count={self.max_seqs}")
+                max_ctx = self.max_ctx_tokens
+                blocks_needed = 0
+                new_seqs = 0
+                for uid, tokens in zip(batch_uids, batch_tokens):
+                    desc = self.state_manager.query(uid)
+                    seen = desc.seen_tokens if desc is not None else 0
+                    if desc is None:
+                        new_seqs += 1
+                    if seen + len(tokens) > max_ctx:
+                        raise ValueError(f"sequence {uid}: {seen}+{len(tokens)} tokens exceed "
+                                         f"max_context={max_ctx}")
+                    blocks_needed += (desc.blocks_needed(len(tokens)) if desc is not None
+                                      else -(-len(tokens) // self.block_size))
+                if blocks_needed > self._reclaimable_blocks():
+                    raise RuntimeError(f"KV pool exhausted: need {blocks_needed} blocks, "
+                                       f"{self._reclaimable_blocks()} reclaimable — "
+                                       f"flush() sequences first")
+                if new_seqs + self.state_manager.n_tracked_sequences > \
+                        self.state_manager.max_tracked_sequences:
+                    raise RuntimeError("max_tracked_sequences exceeded for this batch")
 
-        self._batch.clear()
-        slots = []
-        for i, (uid, tokens) in enumerate(zip(batch_uids, batch_tokens)):
-            desc = self.state_manager.get_or_create_sequence(uid)
-            desc.slot = i  # slots are per-batch rows in the device tables
-            if self.lora_store is not None:
-                # re-resolve per batch: a hot-swap/eviction between steps
-                # may have moved the adapter to a different slot
-                desc.adapter_slot = self.lora_store.slot_of(uid)
-            self.state_manager.allocate_for(desc, len(tokens))
-            self._batch.insert_sequence(desc, tokens)
-            desc.advance(len(tokens))
-            if self._log_tokens:
-                # content log: retire-time insertion into the prefix
-                # trie, and the n-gram drafter's lookup corpus. A host
-                # append must land AFTER any pending device segments
-                # from drained pipelined bursts, so fence first (a
-                # cached re-read once the scheduler has fetched them)
-                desc.tokens.fence()
-                desc.tokens.extend(int(t) for t in tokens)
-            slots.append(desc.slot)
-        # decode bucket: a batch of ≤ max_seqs tokens (pure decode round)
-        # runs the small compiled step; prefill chunks run the full-budget
-        # one. Two programs total — shapes stay static per bucket.
-        bucket = self.max_seqs if total <= self.max_seqs else self.max_tokens
-        arrays = self._batch.finalize_packed(bucket=bucket)
-        if mode == "packed":
-            # sampling specs ride the SAME flat metadata vector: resolve
-            # engine-stream seeds for specs submitted without one, then
-            # append the six int32 rows per sequence
-            for s in specs:
-                if s is not None and "seed" not in s:
-                    s["seed"] = self.draw_seed()
-            dfa = None
-            if self.structured is not None:
-                dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
-                       for u in batch_uids]
-            arrays = np.concatenate(
-                [arrays, pack_sample_meta(specs, self.max_seqs, dfa=dfa)])
-        if self.mesh is not None:
-            # batch metadata is replicated over the serving mesh (the flat
-            # token batch carries no sharding — only weights/KV do)
-            arrays = jax.device_put(arrays, self._replicated)
-        # hot adapter slabs ride as jit ARGUMENTS (not captured constants)
-        # so promotions/hot-swaps rebind buffers without any retrace
-        extra = (self.lora_store.slabs(),) if self.lora_store is not None else ()
-        if mode == "packed":
-            sargs = (self._base_key,)
-            if self.structured is not None:
-                sargs += (self.structured.slabs(),)  # rebind, never retrace
-            out, self.kv_cache.k, self.kv_cache.v = self._step_sampled(
-                self.params, self.kv_cache.k, self.kv_cache.v, arrays,
-                *sargs, *extra)
-        else:
-            fn = self._step_greedy if mode == "greedy" else self._step
-            out, self.kv_cache.k, self.kv_cache.v = fn(
-                self.params, self.kv_cache.k, self.kv_cache.v, arrays, *extra)
-        self.count_host_sync()
-        self.tokens_emitted += len(batch_uids)
-        return np.asarray(out)[np.asarray(slots)]  # ds-lint: disable=host-sync -- THE one intended sync per step: callers consume host tokens/logits
+                self._batch.clear()
+                slots = []
+                for i, (uid, tokens) in enumerate(zip(batch_uids, batch_tokens)):
+                    desc = self.state_manager.get_or_create_sequence(uid)
+                    desc.slot = i  # slots are per-batch rows in the device tables
+                    if self.lora_store is not None:
+                        # re-resolve per batch: a hot-swap/eviction between steps
+                        # may have moved the adapter to a different slot
+                        desc.adapter_slot = self.lora_store.slot_of(uid)
+                    self.state_manager.allocate_for(desc, len(tokens))
+                    self._batch.insert_sequence(desc, tokens)
+                    desc.advance(len(tokens))
+                    if self._log_tokens:
+                        # content log: retire-time insertion into the prefix
+                        # trie, and the n-gram drafter's lookup corpus. A host
+                        # append must land AFTER any pending device segments
+                        # from drained pipelined bursts, so fence first (a
+                        # cached re-read once the scheduler has fetched them)
+                        desc.tokens.fence()
+                        desc.tokens.extend(int(t) for t in tokens)
+                    slots.append(desc.slot)
+                # decode bucket: a batch of ≤ max_seqs tokens (pure decode round)
+                # runs the small compiled step; prefill chunks run the full-budget
+                # one. Two programs total — shapes stay static per bucket.
+                bucket = self.max_seqs if total <= self.max_seqs else self.max_tokens
+                arrays = self._batch.finalize_packed(bucket=bucket)
+                if mode == "packed":
+                    # sampling specs ride the SAME flat metadata vector: resolve
+                    # engine-stream seeds for specs submitted without one, then
+                    # append the six int32 rows per sequence
+                    for s in specs:
+                        if s is not None and "seed" not in s:
+                            s["seed"] = self.draw_seed()
+                    dfa = None
+                    if self.structured is not None:
+                        dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
+                               for u in batch_uids]
+                    arrays = np.concatenate(
+                        [arrays, pack_sample_meta(specs, self.max_seqs, dfa=dfa)])
+                if self.mesh is not None:
+                    # batch metadata is replicated over the serving mesh (the flat
+                    # token batch carries no sharding — only weights/KV do)
+                    arrays = jax.device_put(arrays, self._replicated)
+                rec.program, rec.n_seqs, rec.n_tokens = str(bucket), len(batch_uids), total
+                # without a scheduler to say which chunks are prompt: rows longer than one
+                rec.n_prompt_tokens = sum(len(t) for t in batch_tokens if len(t) > 1)
+            # hot adapter slabs ride as jit ARGUMENTS (not captured constants)
+            # so promotions/hot-swaps rebind buffers without any retrace
+            extra = (self.lora_store.slabs(),) if self.lora_store is not None else ()
+            with tracing.phase("engine.dispatch"):
+                if mode == "packed":
+                    sargs = (self._base_key,)
+                    if self.structured is not None:
+                        sargs += (self.structured.slabs(),)  # rebind, never retrace
+                    out, self.kv_cache.k, self.kv_cache.v = self._step_sampled(
+                        self.params, self.kv_cache.k, self.kv_cache.v, arrays,
+                        *sargs, *extra)
+                else:
+                    fn = self._step_greedy if mode == "greedy" else self._step
+                    out, self.kv_cache.k, self.kv_cache.v = fn(
+                        self.params, self.kv_cache.k, self.kv_cache.v, arrays, *extra)
+            self.count_host_sync()
+            self.tokens_emitted += len(batch_uids)
+            with tracing.phase("engine.fetch"):
+                host = np.asarray(out)[np.asarray(slots)]  # ds-lint: disable=host-sync -- THE one intended sync per step: callers consume host tokens/logits
+            self.last_step = rec
+            return host
 
     def _classify_sample(self, sample, n):
         """Normalize ``put``/burst ``sample`` arguments → ``(mode,
@@ -906,98 +930,106 @@ class InferenceEngineV2:
         k = int(k)
         if k < 1:
             raise ValueError("k must be >= 1")
-        mode, specs = self._classify_sample(sample, len(batch_uids))
-        if self.structured is not None and \
-                any(self.structured.bound(u) for u in batch_uids):
-            mode = "packed"  # constrained rows need their DFA meta rows
-            specs = specs if specs is not None else [None] * len(batch_uids)
-        sampled = mode == "packed"
-        if len(batch_uids) != len(batch_tokens):
-            raise ValueError(f"{len(batch_uids)} uids vs {len(batch_tokens)} tokens")
-        if len(batch_uids) > self.max_seqs:
-            raise ValueError(f"{len(batch_uids)} sequences > "
-                             f"max_ragged_sequence_count={self.max_seqs}")
-        from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
-        ms = self.max_seqs
-        descs, err = self._validate_burst(batch_uids, k)
-        if err is not None:
-            raise err
+        with tracing.step("burst", engine=self.trace_id, program=f"burst{k}", k=k,
+                          n_seqs=len(batch_uids), n_tokens=k * len(batch_uids),
+                          uids=tuple(batch_uids)) as rec:
+            with tracing.phase("engine.pack"):
+                mode, specs = self._classify_sample(sample, len(batch_uids))
+                if self.structured is not None and \
+                        any(self.structured.bound(u) for u in batch_uids):
+                    mode = "packed"  # constrained rows need their DFA meta rows
+                    specs = specs if specs is not None else [None] * len(batch_uids)
+                sampled = mode == "packed"
+                if len(batch_uids) != len(batch_tokens):
+                    raise ValueError(f"{len(batch_uids)} uids vs {len(batch_tokens)} tokens")
+                if len(batch_uids) > self.max_seqs:
+                    raise ValueError(f"{len(batch_uids)} sequences > "
+                                     f"max_ragged_sequence_count={self.max_seqs}")
+                from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
+                ms = self.max_seqs
+                descs, err = self._validate_burst(batch_uids, k)
+                if err is not None:
+                    raise err
 
-        lora_on = self.lora_store is not None
-        tokens0 = np.zeros(ms, np.int32)
-        token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
-        pos0 = np.zeros(ms, np.int32)
-        tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
-        adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
-        for i, (desc, tok) in enumerate(zip(descs, batch_tokens)):
-            desc.slot = i
-            if lora_on:
-                desc.adapter_slot = self.lora_store.slot_of(desc.uid)
-                adapters[i] = desc.adapter_slot
-            self.state_manager.allocate_for(desc, k)
+                lora_on = self.lora_store is not None
+                tokens0 = np.zeros(ms, np.int32)
+                token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
+                pos0 = np.zeros(ms, np.int32)
+                tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
+                adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
+                for i, (desc, tok) in enumerate(zip(descs, batch_tokens)):
+                    desc.slot = i
+                    if lora_on:
+                        desc.adapter_slot = self.lora_store.slot_of(desc.uid)
+                        adapters[i] = desc.adapter_slot
+                    self.state_manager.allocate_for(desc, k)
+                    self.count_host_sync()
+                    tokens0[i] = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- entry tokens come from the previous burst's host copy
+                    token_seq[i] = i
+                    pos0[i] = desc.seen_tokens
+                    tables[i, :len(desc.blocks)] = desc.blocks
+                    desc.advance(k)
+                parts = [tokens0, token_seq, pos0, tables.ravel()]
+                if lora_on:
+                    parts.append(adapters)
+                if sampled:
+                    for s in specs:
+                        if s is not None and "seed" not in s:
+                            s["seed"] = self.draw_seed()
+                    dfa = None
+                    if self.structured is not None:
+                        dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
+                               for u in batch_uids]
+                    parts.append(pack_sample_meta(specs, ms, dfa=dfa))
+                meta = np.concatenate(parts)
+                assert meta.shape[0] == sum(e - s for s, e in _burst_layout(
+                    ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled).values())
+                if self.mesh is not None:
+                    meta = jax.device_put(meta, self._replicated)
+                # Off-state keys are EXACTLY the pre-feature keys (DS_LORA=0 /
+                # greedy contract); sampled bursts run ONE program regardless of
+                # the specs (they are data), keyed "sampled" plus — when
+                # constrained decoding is live — the DFA slab shape signature,
+                # and the LoRA rank-bucket signature when serving adapters, so a
+                # reconfigured store can't replay a stale program.
+                skey = "sampled" if sampled else None
+                key = ("burst", k, skey)
+                if sampled and self.structured is not None:
+                    key = key + (("dfa",) + self.structured.signature(),)
+                if lora_on:
+                    key = key + (self.lora_store.signature(),)
+                fn = self._get_burst_fn(key, lambda: self._make_burst_fn(k, skey))
+                extra = (self.lora_store.slabs(),) if lora_on else ()
+            with tracing.phase("engine.dispatch"):
+                if skey is None:
+                    out, self.kv_cache.k, self.kv_cache.v = fn(
+                        self.params, self.kv_cache.k, self.kv_cache.v, meta, *extra)
+                else:
+                    sargs = (self._base_key,)
+                    if self.structured is not None:
+                        sargs += (self.structured.slabs(),)
+                    out, self.kv_cache.k, self.kv_cache.v = fn(
+                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
+                        *sargs, *extra)
             self.count_host_sync()
-            tokens0[i] = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- entry tokens come from the previous burst's host copy
-            token_seq[i] = i
-            pos0[i] = desc.seen_tokens
-            tables[i, :len(desc.blocks)] = desc.blocks
-            desc.advance(k)
-        parts = [tokens0, token_seq, pos0, tables.ravel()]
-        if lora_on:
-            parts.append(adapters)
-        if sampled:
-            for s in specs:
-                if s is not None and "seed" not in s:
-                    s["seed"] = self.draw_seed()
-            dfa = None
-            if self.structured is not None:
-                dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
-                       for u in batch_uids]
-            parts.append(pack_sample_meta(specs, ms, dfa=dfa))
-        meta = np.concatenate(parts)
-        assert meta.shape[0] == sum(e - s for s, e in _burst_layout(
-            ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled).values())
-        if self.mesh is not None:
-            meta = jax.device_put(meta, self._replicated)
-        # Off-state keys are EXACTLY the pre-feature keys (DS_LORA=0 /
-        # greedy contract); sampled bursts run ONE program regardless of
-        # the specs (they are data), keyed "sampled" plus — when
-        # constrained decoding is live — the DFA slab shape signature,
-        # and the LoRA rank-bucket signature when serving adapters, so a
-        # reconfigured store can't replay a stale program.
-        skey = "sampled" if sampled else None
-        key = ("burst", k, skey)
-        if sampled and self.structured is not None:
-            key = key + (("dfa",) + self.structured.signature(),)
-        if lora_on:
-            key = key + (self.lora_store.signature(),)
-        fn = self._get_burst_fn(key, lambda: self._make_burst_fn(k, skey))
-        extra = (self.lora_store.slabs(),) if lora_on else ()
-        if skey is None:
-            out, self.kv_cache.k, self.kv_cache.v = fn(
-                self.params, self.kv_cache.k, self.kv_cache.v, meta, *extra)
-        else:
-            sargs = (self._base_key,)
-            if self.structured is not None:
-                sargs += (self.structured.slabs(),)
-            out, self.kv_cache.k, self.kv_cache.v = fn(
-                self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                *sargs, *extra)
-        self.count_host_sync()
-        self.tokens_emitted += k * len(batch_uids)
-        toks = np.asarray(out)[:, :len(batch_uids)]  # ds-lint: disable=host-sync -- THE one intended sync per k-step burst
-        if self._log_tokens:
-            # log what the burst actually WROTE to the KV cache: step i
-            # writes its input token's KV, so positions [seen, seen+k)
-            # hold the entry token followed by the first k-1 outputs (the
-            # final sampled token is never written — it would be the next
-            # step's input). EOS truncation is a scheduler concern; the
-            # cache is content-addressed, so post-EOS tokens just hash to
-            # prefixes nobody asks for.
-            for i, desc in enumerate(descs):
-                desc.tokens.fence()  # order after drained pipelined segments
-                desc.tokens.append(int(tokens0[i]))
-                desc.tokens.extend(int(t) for t in toks[:-1, i])
-        return toks
+            self.tokens_emitted += k * len(batch_uids)
+            with tracing.phase("engine.fetch"):
+                toks = np.asarray(out)[:, :len(batch_uids)]  # ds-lint: disable=host-sync -- THE one intended sync per k-step burst
+            with tracing.phase("engine.log"):
+                if self._log_tokens:
+                    # log what the burst actually WROTE to the KV cache: step i
+                    # writes its input token's KV, so positions [seen, seen+k)
+                    # hold the entry token followed by the first k-1 outputs (the
+                    # final sampled token is never written — it would be the next
+                    # step's input). EOS truncation is a scheduler concern; the
+                    # cache is content-addressed, so post-EOS tokens just hash to
+                    # prefixes nobody asks for.
+                    for i, desc in enumerate(descs):
+                        desc.tokens.fence()  # order after drained pipelined segments
+                        desc.tokens.append(int(tokens0[i]))
+                        desc.tokens.extend(int(t) for t in toks[:-1, i])
+            self.last_step = rec
+            return toks
 
     def decode_burst_async(self, batch_uids, batch_tokens, k, sample=None,
                            prev=None):
@@ -1024,104 +1056,113 @@ class InferenceEngineV2:
             raise ValueError(
                 "chained async burst must keep its predecessor's uid "
                 "order — drain the pipeline when the live set changes")
-        mode, specs = self._classify_sample(sample, len(batch_uids))
-        if self.structured is not None and \
-                any(self.structured.bound(u) for u in batch_uids):
-            mode = "packed"
-            specs = specs if specs is not None else [None] * len(batch_uids)
-        sampled = mode == "packed"
-        if sampled and prev is not None and prev.st is None:
-            raise ValueError(
-                "sampled async burst chained onto a greedy handle — "
-                "drain the pipeline before changing decode mode")
-        if len(batch_uids) > self.max_seqs:
-            raise ValueError(f"{len(batch_uids)} sequences > "
-                             f"max_ragged_sequence_count={self.max_seqs}")
-        from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
-        ms = self.max_seqs
-        descs, err = self._validate_burst(batch_uids, k)
-        if err is not None:
-            raise err
+        rec = tracing.begin("burst_async", engine=self.trace_id, program=f"aburst{k}", k=k,
+                            n_seqs=len(batch_uids), n_tokens=k * len(batch_uids),
+                            uids=tuple(batch_uids))
+        try:
+            with tracing.phase("engine.pack"):
+                mode, specs = self._classify_sample(sample, len(batch_uids))
+                if self.structured is not None and \
+                        any(self.structured.bound(u) for u in batch_uids):
+                    mode = "packed"
+                    specs = specs if specs is not None else [None] * len(batch_uids)
+                sampled = mode == "packed"
+                if sampled and prev is not None and prev.st is None:
+                    raise ValueError(
+                        "sampled async burst chained onto a greedy handle — "
+                        "drain the pipeline before changing decode mode")
+                if len(batch_uids) > self.max_seqs:
+                    raise ValueError(f"{len(batch_uids)} sequences > "
+                                     f"max_ragged_sequence_count={self.max_seqs}")
+                from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
+                ms = self.max_seqs
+                descs, err = self._validate_burst(batch_uids, k)
+                if err is not None:
+                    raise err
 
-        lora_on = self.lora_store is not None
-        token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
-        pos0 = np.zeros(ms, np.int32)
-        tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
-        adapters = np.zeros(ms + 1, np.int32)
-        for i, desc in enumerate(descs):
-            desc.slot = i
-            if lora_on:
-                desc.adapter_slot = self.lora_store.slot_of(desc.uid)
-                adapters[i] = desc.adapter_slot
-            self.state_manager.allocate_for(desc, k)
-            token_seq[i] = i
-            pos0[i] = desc.seen_tokens
-            tables[i, :len(desc.blocks)] = desc.blocks
-            desc.advance(k)
-        parts = [token_seq, pos0, tables.ravel()]
-        if lora_on:
-            parts.append(adapters)
-        st0 = None
-        if sampled:
-            for s in specs:
-                if s is not None and "seed" not in s:
-                    s["seed"] = self.draw_seed()
-            dfa = None
-            if self.structured is not None:
-                dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
-                       for u in batch_uids]
-            parts.append(pack_sample_meta(specs, ms, dfa=dfa))
-            if prev is not None:
-                st0 = prev.st  # device chain — host DFA mirror lags one burst
-            else:
-                st_np = np.zeros(ms, np.int32)
-                if dfa is not None:
-                    for i, (_, state) in enumerate(dfa):
-                        st_np[i] = int(state)
-                st0 = jax.device_put(st_np, self._replicated) \
-                    if self.mesh is not None else jnp.asarray(st_np)
-        meta = np.concatenate(parts)
-        assert meta.shape[0] == sum(e - s for s, e in _burst_layout(
-            ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled,
-            async_entry=True).values())
-        if self.mesh is not None:
-            meta = jax.device_put(meta, self._replicated)
-        entry_np = None
-        if prev is not None:
-            entry = prev.entry_next  # device row, no sync
-        else:
-            entry_full = np.zeros(ms, np.int32)
-            for i, tok in enumerate(batch_tokens):
-                entry_full[i] = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- cold-start entries are host ints (put()'s already-fetched outputs), not device data
-            entry_np = entry_full[:len(batch_uids)].copy()
-            entry = jax.device_put(entry_full, self._replicated) \
-                if self.mesh is not None else jnp.asarray(entry_full)
-        # "aburst" keys are disjoint from the sync "burst" keys by
-        # construction, so DS_ASYNC_BURST=0 replays byte-identical keys
-        skey = "sampled" if sampled else None
-        key = ("aburst", k, skey)
-        if sampled and self.structured is not None:
-            key = key + (("dfa",) + self.structured.signature(),)
-        if lora_on:
-            key = key + (self.lora_store.signature(),)
-        fn = self._get_burst_fn(
-            key, lambda: self._make_burst_fn(k, skey, async_entry=True))
-        extra = (self.lora_store.slabs(),) if lora_on else ()
-        st = None
-        if skey is None:
-            out, self.kv_cache.k, self.kv_cache.v = fn(
-                self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                entry, *extra)
-        else:
-            sargs = (self._base_key,)
-            if self.structured is not None:
-                sargs += (self.structured.slabs(),)
-            out, st, self.kv_cache.k, self.kv_cache.v = fn(
-                self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                entry, st0, *sargs, *extra)
+                lora_on = self.lora_store is not None
+                token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
+                pos0 = np.zeros(ms, np.int32)
+                tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
+                adapters = np.zeros(ms + 1, np.int32)
+                for i, desc in enumerate(descs):
+                    desc.slot = i
+                    if lora_on:
+                        desc.adapter_slot = self.lora_store.slot_of(desc.uid)
+                        adapters[i] = desc.adapter_slot
+                    self.state_manager.allocate_for(desc, k)
+                    token_seq[i] = i
+                    pos0[i] = desc.seen_tokens
+                    tables[i, :len(desc.blocks)] = desc.blocks
+                    desc.advance(k)
+                parts = [token_seq, pos0, tables.ravel()]
+                if lora_on:
+                    parts.append(adapters)
+                st0 = None
+                if sampled:
+                    for s in specs:
+                        if s is not None and "seed" not in s:
+                            s["seed"] = self.draw_seed()
+                    dfa = None
+                    if self.structured is not None:
+                        dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
+                               for u in batch_uids]
+                    parts.append(pack_sample_meta(specs, ms, dfa=dfa))
+                    if prev is not None:
+                        st0 = prev.st  # device chain — host DFA mirror lags one burst
+                    else:
+                        st_np = np.zeros(ms, np.int32)
+                        if dfa is not None:
+                            for i, (_, state) in enumerate(dfa):
+                                st_np[i] = int(state)
+                        st0 = jax.device_put(st_np, self._replicated) \
+                            if self.mesh is not None else jnp.asarray(st_np)
+                meta = np.concatenate(parts)
+                assert meta.shape[0] == sum(e - s for s, e in _burst_layout(
+                    ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled,
+                    async_entry=True).values())
+                if self.mesh is not None:
+                    meta = jax.device_put(meta, self._replicated)
+                entry_np = None
+                if prev is not None:
+                    entry = prev.entry_next  # device row, no sync
+                else:
+                    entry_full = np.zeros(ms, np.int32)
+                    for i, tok in enumerate(batch_tokens):
+                        entry_full[i] = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- cold-start entries are host ints (put()'s already-fetched outputs), not device data
+                    entry_np = entry_full[:len(batch_uids)].copy()
+                    entry = jax.device_put(entry_full, self._replicated) \
+                        if self.mesh is not None else jnp.asarray(entry_full)
+                # "aburst" keys are disjoint from the sync "burst" keys by
+                # construction, so DS_ASYNC_BURST=0 replays byte-identical keys
+                skey = "sampled" if sampled else None
+                key = ("aburst", k, skey)
+                if sampled and self.structured is not None:
+                    key = key + (("dfa",) + self.structured.signature(),)
+                if lora_on:
+                    key = key + (self.lora_store.signature(),)
+                fn = self._get_burst_fn(
+                    key, lambda: self._make_burst_fn(k, skey, async_entry=True))
+                extra = (self.lora_store.slabs(),) if lora_on else ()
+                st = None
+            with tracing.phase("engine.dispatch"):
+                if skey is None:
+                    out, self.kv_cache.k, self.kv_cache.v = fn(
+                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
+                        entry, *extra)
+                else:
+                    sargs = (self._base_key,)
+                    if self.structured is not None:
+                        sargs += (self.structured.slabs(),)
+                    out, st, self.kv_cache.k, self.kv_cache.v = fn(
+                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
+                        entry, st0, *sargs, *extra)
+        finally:
+            # open until AsyncBurstHandle.fetch: the device runs meanwhile
+            tracing.suspend(rec)
         self.tokens_emitted += k * len(batch_uids)
         handle = AsyncBurstHandle(self, batch_uids, descs, k, out, st=st,
-                                  entry_np=entry_np, prev=prev)
+                                  entry_np=entry_np, prev=prev, record=rec)
         if self._log_tokens:
             # KV content over [seen, seen+k) = the entry token plus the
             # first k-1 outputs, exactly like the sync path — but it
@@ -1309,122 +1350,132 @@ class InferenceEngineV2:
         accepted count — the rejected tail is abandoned in place (the
         block tables make it unreachable; the next tokens overwrite it)
         and trailing whole blocks return to the pool."""
-        from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
-        if self.spec is None:
-            raise RuntimeError("speculative decoding is disabled "
-                               "(config.spec_decode / DS_SPEC_DECODE)")
-        mode, specs = self._classify_sample(sample, len(batch_uids))
-        if mode == "logits":
-            mode = "greedy"  # verify has no raw-logits mode
-        sampled = mode == "packed"
-        if self.structured is not None and \
-                any(self.structured.bound(u) for u in batch_uids):
-            raise RuntimeError(
-                "constrained sequences cannot enter verify bursts — the "
-                "drafter proposed tokens without the DFA mask; schedulers "
-                "route schema-bound sequences through plain bursts")
-        if not (len(batch_uids) == len(batch_tokens) == len(batch_drafts)):
-            raise ValueError(f"{len(batch_uids)} uids vs {len(batch_tokens)} "
-                             f"tokens vs {len(batch_drafts)} drafts")
-        if len(batch_uids) > self.max_seqs:
-            raise ValueError(f"{len(batch_uids)} sequences > "
-                             f"max_ragged_sequence_count={self.max_seqs}")
-        d = max((len(dr) for dr in batch_drafts), default=0)
-        if d < 1:
-            raise ValueError("verify_burst needs at least one draft token; "
-                             "use put()/decode_burst for draft-free decoding")
-        descs, err = self._validate_burst(batch_uids, d + 1)
-        if err is not None:
-            raise err
-        ms, mb = self.max_seqs, self.max_blocks_per_seq
-        lora_on = self.lora_store is not None
-        toks = np.zeros((ms, d + 1), np.int32)
-        dlen = np.zeros(ms, np.int32)
-        token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
-        pos0 = np.zeros(ms, np.int32)
-        tables = np.full((ms + 1, mb), NULL_BLOCK, np.int32)
-        adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
-        entries = []
-        for i, (desc, tok, drafts) in enumerate(
-                zip(descs, batch_tokens, batch_drafts)):
-            desc.slot = i
-            if lora_on:
-                desc.adapter_slot = self.lora_store.slot_of(desc.uid)
-                adapters[i] = desc.adapter_slot
-            self.state_manager.allocate_for(desc, d + 1)
-            self.count_host_sync()
-            entry = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- entry tokens come from the previous step's host copy
-            entries.append(entry)
-            row = [entry] + [int(t) for t in drafts]
-            toks[i, :len(row)] = row
-            toks[i, len(row):] = entry  # inert pad: dlen masks acceptance
-            dlen[i] = len(drafts)
-            token_seq[i] = i
-            pos0[i] = desc.seen_tokens
-            tables[i, :len(desc.blocks)] = desc.blocks
-        parts = [toks.ravel(), dlen, token_seq, pos0, tables.ravel()]
-        if lora_on:
-            parts.append(adapters)
-        if sampled:
-            for s in specs:
-                if s is not None and "seed" not in s:
-                    s["seed"] = self.draw_seed()
-            parts.append(pack_sample_meta(specs, ms))
-        meta = np.concatenate(parts)
-        assert meta.shape[0] == sum(
-            e - s for s, e in _verify_layout(ms, mb, d, lora=lora_on,
-                                             sampled=sampled).values())
-        if self.mesh is not None:
-            meta = jax.device_put(meta, self._replicated)
-        # the verify must see the SAME adapter deltas decode does, or
-        # acceptance silently diverges from stepwise decoding
-        key = ("verify", d) if not sampled else ("verify", d, "sampled")
-        if self.async_burst:
-            # one-fetch-per-burst: the program concatenates tokens and
-            # accept counts into ONE int32 vector, so the host pays a
-            # single device→host copy instead of two. A distinct key —
-            # the off state keeps the exact pre-pipeline keys/programs.
-            key = key + ("packed",)
-        if lora_on:
-            key = key + (self.lora_store.signature(),)
-        packed = self.async_burst
-        fn = self._get_burst_fn(
-            key, lambda: self._make_verify_fn(d, sampled, packed=packed))
-        extra = (self.lora_store.slabs(),) if lora_on else ()
-        sargs = (self._base_key,) if sampled else ()
-        if packed:
-            wire, self.kv_cache.k, self.kv_cache.v = fn(
-                self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                *sargs, *extra)
-            self.count_host_sync()
-            wire = np.asarray(wire)  # ds-lint: disable=host-sync -- THE one intended sync per verify burst (packed tokens + accept counts)
-            out = wire[:ms * (d + 1)].reshape(ms, d + 1)
-            acc = wire[ms * (d + 1):].astype(np.int64)
-        else:
-            out, acc, self.kv_cache.k, self.kv_cache.v = fn(
-                self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                *sargs, *extra)
-            self.count_host_sync(2)
-            out = np.asarray(out)  # ds-lint: disable=host-sync -- THE one intended sync per verify burst
-            acc = np.asarray(acc)  # ds-lint: disable=host-sync -- host copy of the device result above, already synced
-        n = len(batch_uids)
-        for i, desc in enumerate(descs):
-            a = int(acc[i])
-            self.tokens_emitted += a + 1
-            # KV positions [seen, seen+a] hold the entry token and the a
-            # accepted drafts; the bonus token out[i, a] is the NEXT
-            # step's entry and was never written (same convention as the
-            # plain burst). Advance by accepted only, then return whole
-            # unused trailing blocks.
-            desc.advance(a + 1)
-            if self._log_tokens:
-                desc.tokens.fence()  # order after drained pipelined segments
-                desc.tokens.append(entries[i])
-                desc.tokens.extend(int(t) for t in out[i, :a])
-            self.state_manager.release_unused_blocks(desc)
-            if int(dlen[i]):
-                self.spec.note(desc.uid, accepted=a, drafted=int(dlen[i]))
-        return out[:n], acc[:n]
+        with tracing.step("verify", engine=self.trace_id, uids=tuple(batch_uids)) as rec:
+            with tracing.phase("engine.pack"):
+                from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
+                if self.spec is None:
+                    raise RuntimeError("speculative decoding is disabled "
+                                       "(config.spec_decode / DS_SPEC_DECODE)")
+                mode, specs = self._classify_sample(sample, len(batch_uids))
+                if mode == "logits":
+                    mode = "greedy"  # verify has no raw-logits mode
+                sampled = mode == "packed"
+                if self.structured is not None and \
+                        any(self.structured.bound(u) for u in batch_uids):
+                    raise RuntimeError(
+                        "constrained sequences cannot enter verify bursts — the "
+                        "drafter proposed tokens without the DFA mask; schedulers "
+                        "route schema-bound sequences through plain bursts")
+                if not (len(batch_uids) == len(batch_tokens) == len(batch_drafts)):
+                    raise ValueError(f"{len(batch_uids)} uids vs {len(batch_tokens)} "
+                                     f"tokens vs {len(batch_drafts)} drafts")
+                if len(batch_uids) > self.max_seqs:
+                    raise ValueError(f"{len(batch_uids)} sequences > "
+                                     f"max_ragged_sequence_count={self.max_seqs}")
+                d = max((len(dr) for dr in batch_drafts), default=0)
+                if d < 1:
+                    raise ValueError("verify_burst needs at least one draft token; "
+                                     "use put()/decode_burst for draft-free decoding")
+                descs, err = self._validate_burst(batch_uids, d + 1)
+                if err is not None:
+                    raise err
+                rec.program, rec.n_seqs = f"verify{d}", len(batch_uids)
+                rec.n_tokens = len(batch_uids) * (d + 1)
+                ms, mb = self.max_seqs, self.max_blocks_per_seq
+                lora_on = self.lora_store is not None
+                toks = np.zeros((ms, d + 1), np.int32)
+                dlen = np.zeros(ms, np.int32)
+                token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
+                pos0 = np.zeros(ms, np.int32)
+                tables = np.full((ms + 1, mb), NULL_BLOCK, np.int32)
+                adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
+                entries = []
+                for i, (desc, tok, drafts) in enumerate(
+                        zip(descs, batch_tokens, batch_drafts)):
+                    desc.slot = i
+                    if lora_on:
+                        desc.adapter_slot = self.lora_store.slot_of(desc.uid)
+                        adapters[i] = desc.adapter_slot
+                    self.state_manager.allocate_for(desc, d + 1)
+                    self.count_host_sync()
+                    entry = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- entry tokens come from the previous step's host copy
+                    entries.append(entry)
+                    row = [entry] + [int(t) for t in drafts]
+                    toks[i, :len(row)] = row
+                    toks[i, len(row):] = entry  # inert pad: dlen masks acceptance
+                    dlen[i] = len(drafts)
+                    token_seq[i] = i
+                    pos0[i] = desc.seen_tokens
+                    tables[i, :len(desc.blocks)] = desc.blocks
+                parts = [toks.ravel(), dlen, token_seq, pos0, tables.ravel()]
+                if lora_on:
+                    parts.append(adapters)
+                if sampled:
+                    for s in specs:
+                        if s is not None and "seed" not in s:
+                            s["seed"] = self.draw_seed()
+                    parts.append(pack_sample_meta(specs, ms))
+                meta = np.concatenate(parts)
+                assert meta.shape[0] == sum(
+                    e - s for s, e in _verify_layout(ms, mb, d, lora=lora_on,
+                                                     sampled=sampled).values())
+                if self.mesh is not None:
+                    meta = jax.device_put(meta, self._replicated)
+                # the verify must see the SAME adapter deltas decode does, or
+                # acceptance silently diverges from stepwise decoding
+                key = ("verify", d) if not sampled else ("verify", d, "sampled")
+                if self.async_burst:
+                    # one-fetch-per-burst: the program concatenates tokens and
+                    # accept counts into ONE int32 vector, so the host pays a
+                    # single device→host copy instead of two. A distinct key —
+                    # the off state keeps the exact pre-pipeline keys/programs.
+                    key = key + ("packed",)
+                if lora_on:
+                    key = key + (self.lora_store.signature(),)
+                packed = self.async_burst
+                fn = self._get_burst_fn(
+                    key, lambda: self._make_verify_fn(d, sampled, packed=packed))
+                extra = (self.lora_store.slabs(),) if lora_on else ()
+                sargs = (self._base_key,) if sampled else ()
+            if packed:
+                with tracing.phase("engine.dispatch"):
+                    wire, self.kv_cache.k, self.kv_cache.v = fn(
+                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
+                        *sargs, *extra)
+                self.count_host_sync()
+                with tracing.phase("engine.fetch"):
+                    wire = np.asarray(wire)  # ds-lint: disable=host-sync -- THE one intended sync per verify burst (packed tokens + accept counts)
+                out = wire[:ms * (d + 1)].reshape(ms, d + 1)
+                acc = wire[ms * (d + 1):].astype(np.int64)
+            else:
+                with tracing.phase("engine.dispatch"):
+                    out, acc, self.kv_cache.k, self.kv_cache.v = fn(
+                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
+                        *sargs, *extra)
+                self.count_host_sync(2)
+                with tracing.phase("engine.fetch"):
+                    out = np.asarray(out)  # ds-lint: disable=host-sync -- THE one intended sync per verify burst
+                    acc = np.asarray(acc)  # ds-lint: disable=host-sync -- host copy of the device result above, already synced
+            n = len(batch_uids)
+            with tracing.phase("engine.log"):
+                for i, desc in enumerate(descs):
+                    a = int(acc[i])
+                    self.tokens_emitted += a + 1
+                    # KV positions [seen, seen+a] hold the entry token and the a
+                    # accepted drafts; the bonus token out[i, a] is the NEXT
+                    # step's entry and was never written (same convention as the
+                    # plain burst). Advance by accepted only, then return whole
+                    # unused trailing blocks.
+                    desc.advance(a + 1)
+                    if self._log_tokens:
+                        desc.tokens.fence()  # order after drained pipelined segments
+                        desc.tokens.append(entries[i])
+                        desc.tokens.extend(int(t) for t in out[i, :a])
+                    self.state_manager.release_unused_blocks(desc)
+                    if int(dlen[i]):
+                        self.spec.note(desc.uid, accepted=a, drafted=int(dlen[i]))
+            self.last_step = rec
+            return out[:n], acc[:n]
 
     def _make_verify_fn(self, d, sampled=False, packed=False):
         """One compiled verify program for draft length ``d``: a single

@@ -12,6 +12,8 @@ from collections import OrderedDict, deque
 
 import numpy as np
 
+from deepspeed_tpu.utils import tracing
+
 
 class Request:
 
@@ -39,6 +41,14 @@ class Request:
         # from the cache (prefill skips them — the cursor starts there)
         self.prefix_cached_tokens = 0
         self.prefix_checked = False
+        # life-cycle stamps for the request record (utils/tracing.py):
+        # when the request first got a place in a step, the seq of that
+        # step's record and of the one that gave its first token, and
+        # how many steps carried a chunk of its prompt
+        self.first_scheduled_ns = None
+        self.first_scheduled_seq = 0
+        self.first_token_seq = 0
+        self.prefill_steps = 0
         self.generated = []
         self.next_token = None  # decode token awaiting scheduling
         # pipelined (async) bursts: tokens dispatched to the device but
@@ -104,6 +114,12 @@ class DynamicSplitFuseScheduler:
             and self._device_greedy and self.max_burst >= 2
         self.async_depth = max(1, int(getattr(engine, "async_burst_depth", 2)))
         self._pipeline = deque()  # (AsyncBurstHandle, [Request]) oldest first
+        # seq of the step record the engine wrote last (0: it writes none)
+        self.last_step_seq = 0
+        # what the last _plan() knows and the engine cannot: prompt tokens
+        # in the step, and the requests that got their first place in it
+        self._planned_prompt_tokens = 0
+        self._planned_first = []
 
     def add_request(self, uid, prompt_tokens, max_new_tokens=16, priority=0,
                     spec=True, adapter_id=None, sample=None, schema=None):
@@ -234,6 +250,8 @@ class DynamicSplitFuseScheduler:
         budget = self.budget
         max_seqs = self.engine.max_seqs
         live = self._live()
+        self._planned_prompt_tokens = 0
+        self._planned_first = []
         # 1) decodes: one token each
         for r in live:
             if r.next_token is not None and budget > 0 and len(uids) < max_seqs:
@@ -252,6 +270,8 @@ class DynamicSplitFuseScheduler:
                     # starts at the first uncached token (batch positions
                     # follow the descriptor's seen_tokens automatically)
                     r.prefix_checked = True
+                    r.first_scheduled_ns = tracing.now_ns()
+                    self._planned_first.append(r)
                     match = getattr(self.engine, "prefix_match", None)
                     if match is not None and r.prefill_cursor == 0:
                         r.prefix_cached_tokens = int(match(r.uid, r.prompt))
@@ -259,53 +279,66 @@ class DynamicSplitFuseScheduler:
                 take = min(budget, len(r.prompt) - r.prefill_cursor)
                 chunk = r.prompt[r.prefill_cursor:r.prefill_cursor + take]
                 r.prefill_cursor += take
+                r.prefill_steps += 1
+                self._planned_prompt_tokens += take
                 uids.append(r.uid)
                 chunks.append(chunk)
                 budget -= take
         return uids, chunks
 
+    def _ran(self):
+        """The engine call just returned: note the seq of the step record
+        it wrote, so that what is accepted next can point at it."""
+        rec = getattr(self.engine, "last_step", None)
+        self.last_step_seq = rec.seq if rec is not None else 0
+        return rec
+
     def _try_burst(self):
         """All live requests decoding → run a k-step decode burst; None
         when the burst path doesn't apply this round."""
-        live = self._live()
-        if (self.max_burst < 2 or not live or len(live) > self.engine.max_seqs
-                or len(live) > self.budget  # burst must respect the per-step
-                # token budget too: one decode token per live request per
-                # burst step, same bound _plan enforces
-                or any(r.next_token is None for r in live)):
-            return None
-        k = min(self.max_burst,
-                min(r.max_new_tokens - len(r.generated) for r in live),
-                min(self.engine.max_ctx_tokens - self.engine.query(r.uid)[0]
-                    for r in live))
-        if k < 2:
-            return None
-        k = 1 << (k.bit_length() - 1)  # power-of-two bursts: each distinct
-        # k compiles its own scan program, so an arbitrary tail (15, 14,
-        # 13...) would compile once per value; rounding down bounds the
-        # set to log2(max_burst) programs
-        uids = [r.uid for r in live]
-        if not self.engine.can_burst(uids, k):
-            # KV pool too tight to reserve k tokens per sequence up
-            # front. The stepwise path needs at most one block per
-            # sequence per step and EOS flushes free blocks between
-            # steps, so fall back. (A pre-check, not try/except: a
-            # failure inside the compiled burst would land after state
-            # mutation + KV donation and is not recoverable.)
-            return None
-        toks = self.engine.decode_burst(uids, [r.next_token for r in live], k,
-                                        sample=self._sample_arg(live))
-        for r in live:
-            r.next_token = None
-        for step_i in range(k):
-            for j, r in enumerate(live):
-                if r.done:
-                    continue  # hit EOS mid-burst; later rows are discarded
-                # the burst advanced KV by all k tokens; if generation
-                # ends HERE, positions past entry + the first step_i
-                # outputs hold post-EOS garbage the rewind reclaims
-                self._accept_token(r, int(toks[step_i, j]),
-                                   unused_tokens=k - step_i - 1)
+        with tracing.phase("sched.plan"):
+            live = self._live()
+            if (self.max_burst < 2 or not live or len(live) > self.engine.max_seqs
+                    or len(live) > self.budget  # burst must respect the per-step
+                    # token budget too: one decode token per live request per
+                    # burst step, same bound _plan enforces
+                    or any(r.next_token is None for r in live)):
+                return None
+            k = min(self.max_burst,
+                    min(r.max_new_tokens - len(r.generated) for r in live),
+                    min(self.engine.max_ctx_tokens - self.engine.query(r.uid)[0]
+                        for r in live))
+            if k < 2:
+                return None
+            k = 1 << (k.bit_length() - 1)  # power-of-two bursts: each distinct
+            # k compiles its own scan program, so an arbitrary tail (15, 14,
+            # 13...) would compile once per value; rounding down bounds the
+            # set to log2(max_burst) programs
+            uids = [r.uid for r in live]
+            if not self.engine.can_burst(uids, k):
+                # KV pool too tight to reserve k tokens per sequence up
+                # front. The stepwise path needs at most one block per
+                # sequence per step and EOS flushes free blocks between
+                # steps, so fall back. (A pre-check, not try/except: a
+                # failure inside the compiled burst would land after state
+                # mutation + KV donation and is not recoverable.)
+                return None
+            entry = [r.next_token for r in live]
+            sample = self._sample_arg(live)
+        toks = self.engine.decode_burst(uids, entry, k, sample=sample)
+        self._ran()
+        with tracing.phase("sched.accept"):
+            for r in live:
+                r.next_token = None
+            for step_i in range(k):
+                for j, r in enumerate(live):
+                    if r.done:
+                        continue  # hit EOS mid-burst; later rows are discarded
+                    # the burst advanced KV by all k tokens; if generation
+                    # ends HERE, positions past entry + the first step_i
+                    # outputs hold post-EOS garbage the rewind reclaims
+                    self._accept_token(r, int(toks[step_i, j]),
+                                       unused_tokens=k - step_i - 1)
         return uids
 
     def _spec_of(self, r):
@@ -335,51 +368,55 @@ class DynamicSplitFuseScheduler:
         spec = getattr(engine, "spec", None)
         if spec is None or not self._device_greedy:
             return None
-        live = self._live()
-        if (not live or len(live) > engine.max_seqs
-                or any(r.next_token is None for r in live)
-                # constrained sequences never verify: their drafts were
-                # proposed without the DFA mask
-                or any(r.schema is not None for r in live)):
-            return None
-        n = len(live)
-        # each sequence enters the verify batch as a (d+1)-token chunk,
-        # so the shared d is bounded by the per-step token budget…
-        d_cap = self.budget // n - 1
-        # …and by context room for EVERY live sequence: all rows write
-        # d+1 KV positions regardless of their own draft count
-        for r in live:
-            d_cap = min(d_cap, engine.max_ctx_tokens
-                        - engine.query(r.uid)[0] - 1)
-        if d_cap < 1:
-            return None
-        max_lens = [min(d_cap, r.max_new_tokens - len(r.generated) - 1)
-                    if r.spec else 0 for r in live]
-        uids = [r.uid for r in live]
-        drafts = engine.propose_drafts(uids, [[r.next_token] for r in live],
-                                       max_lens)
-        d = max((len(dr) for dr in drafts), default=0)
-        if d < 1:
-            return None
-        # pad the shared draft length up to a power of two (within the
-        # caps): dlen masks the padding, so acceptance is unchanged, and
-        # the verify-program set stays log2-bounded instead of compiling
-        # once per distinct max-draft-length the drafter happens to find
-        d = min(1 << (d - 1).bit_length(), d_cap)
-        if not engine.can_burst(uids, d + 1):
-            return None  # pool too tight: fall back (see _try_burst)
-        toks, acc = engine.verify_burst(uids, [[r.next_token] for r in live],
-                                        drafts, sample=self._sample_arg(live))
-        for r in live:
-            r.next_token = None
-        for j, r in enumerate(live):
-            a = int(acc[j])
-            for e in range(a + 1):
-                if r.done:
-                    break  # EOS among the accepted run; rest discarded
-                # the verify advanced KV by a+1; ending at emitted index
-                # e leaves a-e post-EOS tokens for the rewind to reclaim
-                self._accept_token(r, int(toks[j, e]), unused_tokens=a - e)
+        with tracing.phase("sched.plan"):
+            live = self._live()
+            if (not live or len(live) > engine.max_seqs
+                    or any(r.next_token is None for r in live)
+                    # constrained sequences never verify: their drafts were
+                    # proposed without the DFA mask
+                    or any(r.schema is not None for r in live)):
+                return None
+            n = len(live)
+            # each sequence enters the verify batch as a (d+1)-token chunk,
+            # so the shared d is bounded by the per-step token budget…
+            d_cap = self.budget // n - 1
+            # …and by context room for EVERY live sequence: all rows write
+            # d+1 KV positions regardless of their own draft count
+            for r in live:
+                d_cap = min(d_cap, engine.max_ctx_tokens
+                            - engine.query(r.uid)[0] - 1)
+            if d_cap < 1:
+                return None
+            max_lens = [min(d_cap, r.max_new_tokens - len(r.generated) - 1)
+                        if r.spec else 0 for r in live]
+            uids = [r.uid for r in live]
+            drafts = engine.propose_drafts(uids, [[r.next_token] for r in live],
+                                           max_lens)
+            d = max((len(dr) for dr in drafts), default=0)
+            if d < 1:
+                return None
+            # pad the shared draft length up to a power of two (within the
+            # caps): dlen masks the padding, so acceptance is unchanged, and
+            # the verify-program set stays log2-bounded instead of compiling
+            # once per distinct max-draft-length the drafter happens to find
+            d = min(1 << (d - 1).bit_length(), d_cap)
+            if not engine.can_burst(uids, d + 1):
+                return None  # pool too tight: fall back (see _try_burst)
+            entry = [[r.next_token] for r in live]
+            sample = self._sample_arg(live)
+        toks, acc = engine.verify_burst(uids, entry, drafts, sample=sample)
+        self._ran()
+        with tracing.phase("sched.accept"):
+            for r in live:
+                r.next_token = None
+            for j, r in enumerate(live):
+                a = int(acc[j])
+                for e in range(a + 1):
+                    if r.done:
+                        break  # EOS among the accepted run; rest discarded
+                    # the verify advanced KV by a+1; ending at emitted index
+                    # e leaves a-e post-EOS tokens for the rewind to reclaim
+                    self._accept_token(r, int(toks[j, e]), unused_tokens=a - e)
         return uids
 
     def _accept_token(self, r, tok, unused_tokens=0):
@@ -391,6 +428,8 @@ class DynamicSplitFuseScheduler:
         retire frees them — and the prefix cache never content-addresses
         post-EOS garbage."""
         r.generated.append(tok)
+        if len(r.generated) == 1:
+            r.first_token_seq = self.last_step_seq
         if r.schema is not None:
             # the authoritative host DFA advances ONLY for accepted
             # tokens — burst tails discarded after EOS/max_new never
@@ -439,6 +478,8 @@ class DynamicSplitFuseScheduler:
         over this sequence's KV reservation."""
         r._inflight -= 1
         r.generated.append(tok)
+        if len(r.generated) == 1:
+            r.first_token_seq = self.last_step_seq
         if r.schema is not None:
             self.engine.advance_schema(r.uid, tok)
         if (self.eos_token_id is not None and tok == self.eos_token_id) \
@@ -456,11 +497,13 @@ class DynamicSplitFuseScheduler:
         their ``_inflight`` debt is rewound at drain time."""
         handle, rows = self._pipeline.popleft()
         toks = handle.fetch()
-        for step_i in range(handle.k):
-            for j, r in enumerate(rows):
-                if r.done:
-                    continue  # finished mid-pipeline; tail is debt
-                self._accept_async(r, int(toks[step_i, j]))
+        self._ran()
+        with tracing.phase("sched.accept"):
+            for step_i in range(handle.k):
+                for j, r in enumerate(rows):
+                    if r.done:
+                        continue  # finished mid-pipeline; tail is debt
+                    self._accept_async(r, int(toks[step_i, j]))
         return [r.uid for r in rows]
 
     def _drain_pipeline(self):
@@ -492,16 +535,18 @@ class DynamicSplitFuseScheduler:
         packs while the device runs), then fence one burst late. Any
         condition that breaks the chain — live set changed, tail too
         short, pool too tight, a fenced row finished — drains."""
-        rows = self._pipeline_rows()
-        live = self._live()
-        chainable = live == rows and not any(r.done for r in rows)
-        k = self._plan_async_k(rows) if chainable else None
-        uids = [r.uid for r in rows]
-        if k is None or not self.engine.can_burst(uids, k):
+        with tracing.phase("sched.plan"):
+            rows = self._pipeline_rows()
+            live = self._live()
+            chainable = live == rows and not any(r.done for r in rows)
+            k = self._plan_async_k(rows) if chainable else None
+            uids = [r.uid for r in rows]
+            chain = k is not None and self.engine.can_burst(uids, k)
+            sample = self._sample_arg(rows) if chain else None
+        if not chain:
             return self._drain_pipeline()
         handle = self.engine.decode_burst_async(
-            uids, None, k, sample=self._sample_arg(rows),
-            prev=self._pipeline[-1][0])
+            uids, None, k, sample=sample, prev=self._pipeline[-1][0])
         for r in rows:
             r._inflight += k
         self._pipeline.append((handle, rows))
@@ -515,20 +560,21 @@ class DynamicSplitFuseScheduler:
         """Pipeline cold start: same applicability test as
         :meth:`_try_burst`, but the burst is dispatched WITHOUT a fetch
         — the fence lands ``async_depth`` bursts later."""
-        live = self._live()
-        if (not live or len(live) > self.engine.max_seqs
-                or len(live) > self.budget
-                or any(r.next_token is None for r in live)):
-            return None
-        k = self._plan_async_k(live)
-        if k is None:
-            return None
-        uids = [r.uid for r in live]
-        if not self.engine.can_burst(uids, k):
-            return None  # tight pool: fall back, see _try_burst
-        handle = self.engine.decode_burst_async(
-            uids, [[r.next_token] for r in live], k,
-            sample=self._sample_arg(live))
+        with tracing.phase("sched.plan"):
+            live = self._live()
+            if (not live or len(live) > self.engine.max_seqs
+                    or len(live) > self.budget
+                    or any(r.next_token is None for r in live)):
+                return None
+            k = self._plan_async_k(live)
+            if k is None:
+                return None
+            uids = [r.uid for r in live]
+            if not self.engine.can_burst(uids, k):
+                return None  # tight pool: fall back, see _try_burst
+            entry = [[r.next_token] for r in live]
+            sample = self._sample_arg(live)
+        handle = self.engine.decode_burst_async(uids, entry, k, sample=sample)
         for r in live:
             r.next_token = None
             r._inflight += k
@@ -551,20 +597,29 @@ class DynamicSplitFuseScheduler:
         burst = self._try_burst()
         if burst is not None:
             return burst
-        uids, chunks = self._plan()
-        if not uids:
-            return []
+        with tracing.phase("sched.plan"):
+            uids, chunks = self._plan()
+            if not uids:
+                return []
+            if self._device_greedy:
+                sample = self._sample_arg([self.requests[u] for u in uids]) or "greedy"
         if self._device_greedy:
-            rows = [self.requests[u] for u in uids]
-            out = self.engine.put(uids, chunks,
-                                  sample=self._sample_arg(rows) or "greedy")
+            out = self.engine.put(uids, chunks, sample=sample)
         else:
             out = self.engine.put(uids, chunks)
-        for uid, row in zip(uids, out):
-            r = self.requests[uid]
-            if r.prefilling:
-                continue  # mid-prompt chunk: its last-token logits are unused
-            self._accept_token(r, int(row) if self._device_greedy else self.sample_fn(row))
+        rec = self._ran()
+        if rec is not None:
+            # _plan knows which chunks are prompt; the engine cannot tell a
+            # one-token chunk from a decode token
+            rec.n_prompt_tokens = self._planned_prompt_tokens
+        for r in self._planned_first:
+            r.first_scheduled_seq = self.last_step_seq
+        with tracing.phase("sched.accept"):
+            for uid, row in zip(uids, out):
+                r = self.requests[uid]
+                if r.prefilling:
+                    continue  # mid-prompt chunk: its last-token logits are unused
+                self._accept_token(r, int(row) if self._device_greedy else self.sample_fn(row))
         return uids
 
     def run_to_completion(self, max_steps=10000):

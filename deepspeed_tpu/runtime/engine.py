@@ -22,6 +22,7 @@ Analogue of the reference's ``deepspeed/runtime/engine.py``
   all wired as in the reference.
 """
 
+import math
 import os
 import re
 import time
@@ -55,6 +56,7 @@ from deepspeed_tpu.runtime.constants import (ADAGRAD_OPTIMIZER, ADAM_OPTIMIZER, 
 from deepspeed_tpu.runtime.dataloader import DeepSpeedDataLoader, RepeatingLoader
 from deepspeed_tpu.runtime.fp16.loss_scaler import DynamicLossScaler, has_overflow, scaler_state, update_scale
 from deepspeed_tpu.runtime.zero.partitioning import ZeroShardingPolicy, batch_spec, path_tree_map
+from deepspeed_tpu.utils import tracing
 from deepspeed_tpu.utils.env_registry import env_bool, env_int, env_raw
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (BACKWARD_GLOBAL_TIMER, BACKWARD_MICRO_TIMER, FORWARD_GLOBAL_TIMER,
@@ -114,6 +116,7 @@ class DeepSpeedEngine:
         self.collate_fn = collate_fn
         self.mpu = mpu
         self.loss_fn = loss_fn
+        self.trace_id = tracing.engine_id()  # this engine's number in the step records
         self.global_steps = 0
         self.global_samples = 0
         self.micro_steps = 0
@@ -1314,88 +1317,100 @@ class DeepSpeedEngine:
         single jitted program (reference PipelineEngine.train_batch:326
         surface, here for the data-parallel engine)."""
         gas = self.gradient_accumulation_steps()
-        if batch is None:
-            assert data_iter is not None, "provide data_iter or batch"
-            micro = [next(data_iter) for _ in range(gas)]
-            batch = jax.tree.map(lambda *xs: np.stack(xs), *micro)
-        else:
-            lead = jax.tree.leaves(batch)[0].shape[0]
-            if lead != gas:
-                assert lead == gas * self.train_micro_batch_size_per_gpu(), (
-                    f"batch leading dim {lead} != gas*micro")
-                batch = jax.tree.map(
-                    lambda x: x.reshape((gas, self.train_micro_batch_size_per_gpu()) + x.shape[1:]), batch)
-        if not (isinstance(batch, tuple) and len(batch) == 2 and isinstance(batch[1], dict)):
-            batch = ((batch,) if not isinstance(batch, (tuple, list)) else tuple(batch), {})
-        if self.curriculum_scheduler_legacy is not None:
-            seqlen = self.curriculum_scheduler_legacy.update_difficulty(self.global_steps + 1)
-            # truncate only integer [gas, mbs, S] token-id/label leaves;
-            # float features, attention masks [.., S, S], images pass
-            # through — models with such inputs consume the scheduler
-            # directly (engine.curriculum_scheduler_legacy)
-            trunc = lambda x: x[:, :, :seqlen] if (
-                getattr(x, "ndim", 0) == 3 and
-                jnp.issubdtype(jnp.asarray(x).dtype, jnp.integer)) else x
-            batch = (tuple(jax.tree.map(trunc, a) for a in batch[0]),
-                     jax.tree.map(trunc, batch[1]))
-        self._materialize_state(*jax.tree.map(lambda x: x[0], batch[0]),
-                                **jax.tree.map(lambda x: x[0], batch[1]))
-        self._ensure_params_resident()
-        batch = self._shard_batch(batch, extra_leading=1)
-        self._maybe_flops_profile(jax.tree.map(lambda x: x[0], batch[0]),
-                                  jax.tree.map(lambda x: x[0], batch[1]))
-
-        self.tput_timer.start()
-        self.timers(TRAIN_BATCH_TIMER).start()
-        self._dropout_rng, sub = jax.random.split(self._dropout_rng)
-        if self._use_compressed_now():
-            # compressed stage threads error feedback through each micro
-            # step: run the unfused forward/backward loop + one step()
-            micro_losses = []
-            for g in range(gas):
-                micro = jax.tree.map(lambda x: x[g], batch)
-                loss = self.forward(*micro[0], **micro[1])
-                self.backward(loss)
-                micro_losses.append(loss)
-            self.step()
-            mean_loss = jnp.mean(jnp.stack([jnp.asarray(l) for l in micro_losses]))
-            self.losses = mean_loss
-            self.timers(TRAIN_BATCH_TIMER).stop()
-            self.tput_timer.stop(global_step=True)
-            self._write_monitor(loss=mean_loss)
+        with tracing.step("train", engine=self.trace_id, program="train_batch", k=gas,
+                          n_seqs=self.train_batch_size()) as rec:
+            with tracing.phase("train.prepare"):
+                if batch is None:
+                    assert data_iter is not None, "provide data_iter or batch"
+                    micro = [next(data_iter) for _ in range(gas)]
+                    batch = jax.tree.map(lambda *xs: np.stack(xs), *micro)
+                else:
+                    lead = jax.tree.leaves(batch)[0].shape[0]
+                    if lead != gas:
+                        assert lead == gas * self.train_micro_batch_size_per_gpu(), (
+                            f"batch leading dim {lead} != gas*micro")
+                        batch = jax.tree.map(
+                            lambda x: x.reshape((gas, self.train_micro_batch_size_per_gpu()) + x.shape[1:]), batch)
+                if not (isinstance(batch, tuple) and len(batch) == 2 and isinstance(batch[1], dict)):
+                    batch = ((batch,) if not isinstance(batch, (tuple, list)) else tuple(batch), {})
+                if self.curriculum_scheduler_legacy is not None:
+                    seqlen = self.curriculum_scheduler_legacy.update_difficulty(self.global_steps + 1)
+                    # truncate only integer [gas, mbs, S] token-id/label leaves;
+                    # float features, attention masks [.., S, S], images pass
+                    # through — models with such inputs consume the scheduler
+                    # directly (engine.curriculum_scheduler_legacy)
+                    trunc = lambda x: x[:, :, :seqlen] if (
+                        getattr(x, "ndim", 0) == 3 and
+                        jnp.issubdtype(jnp.asarray(x).dtype, jnp.integer)) else x
+                    batch = (tuple(jax.tree.map(trunc, a) for a in batch[0]),
+                             jax.tree.map(trunc, batch[1]))
+                self._materialize_state(*jax.tree.map(lambda x: x[0], batch[0]),
+                                        **jax.tree.map(lambda x: x[0], batch[1]))
+                self._ensure_params_resident()
+                batch = self._shard_batch(batch, extra_leading=1)
+                self._maybe_flops_profile(jax.tree.map(lambda x: x[0], batch[0]),
+                                          jax.tree.map(lambda x: x[0], batch[1]))
+                lead = jax.tree.leaves(batch)[0]   # [gas, rows, tokens, ...]
+                rec.n_tokens = math.prod(lead.shape[:3])
+            with tracing.phase("train.timer_sync"):
+                self.tput_timer.start()
+                self.timers(TRAIN_BATCH_TIMER).start()
+            self._dropout_rng, sub = jax.random.split(self._dropout_rng)
+            if self._use_compressed_now():
+                # compressed stage threads error feedback through each micro
+                # step: run the unfused forward/backward loop + one step()
+                with tracing.phase("train.dispatch"):
+                    micro_losses = []
+                    for g in range(gas):
+                        micro = jax.tree.map(lambda x: x[g], batch)
+                        loss = self.forward(*micro[0], **micro[1])
+                        self.backward(loss)
+                        micro_losses.append(loss)
+                    self.step()
+                    mean_loss = jnp.mean(jnp.stack([jnp.asarray(l) for l in micro_losses]))
+                self.losses = mean_loss
+                with tracing.phase("train.timer_sync"):
+                    self.timers(TRAIN_BATCH_TIMER).stop()
+                    self.tput_timer.stop(global_step=True)
+                with tracing.phase("train.post"):
+                    self._write_monitor(loss=mean_loss)
+                return mean_loss
+            with tracing.phase("train.dispatch"):
+                if self._host_offload is not None:
+                    grads32, mean_loss, gnorm, overflow = self._train_batch_grads_fn()(
+                        self.params, self.scaler_state, sub, batch)
+                    self._offload_apply(grads32, gnorm, overflow)
+                else:
+                    lr = jnp.asarray(self.get_lr()[0], jnp.float32)
+                    fn, tied = self._train_batch_fn()
+                    if tied:
+                        out = fn(self.params, self.opt_state, self.scaler_state, lr, sub, batch)
+                        self.params, self.opt_state, self.scaler_state, mean_loss, gnorm, overflow = out
+                        self.master_params = self.params
+                    else:
+                        out = fn(self.params, self.master_params, self.opt_state, self.scaler_state, lr, sub, batch)
+                        self.params, self.master_params, self.opt_state, self.scaler_state, mean_loss, gnorm, overflow = out
+                    self._enforce_param_memory_kinds()
+                self._nvme_offload_params()
+            self.global_steps += 1
+            self.micro_steps += gas
+            self.global_samples += self.train_batch_size()
+            with tracing.phase("train.sync"):
+                self.overflow = bool(overflow) if self.fp16_enabled() else False
+                self.global_grad_norm = float(gnorm)
+            if not self.overflow and self.lr_scheduler is not None:
+                self.lr_scheduler.step()
+            elif self.overflow:
+                self.skipped_steps += 1
+            with tracing.phase("train.timer_sync"):
+                self.timers(TRAIN_BATCH_TIMER).stop()
+                self.tput_timer.stop(global_step=True)
+            with tracing.phase("train.post"):
+                self.losses = mean_loss
+                self._write_monitor(loss=mean_loss)
+                self._heartbeat.beat(self.global_steps)
+                self._maybe_handle_preemption()
             return mean_loss
-        if self._host_offload is not None:
-            grads32, mean_loss, gnorm, overflow = self._train_batch_grads_fn()(
-                self.params, self.scaler_state, sub, batch)
-            self._offload_apply(grads32, gnorm, overflow)
-        else:
-            lr = jnp.asarray(self.get_lr()[0], jnp.float32)
-            fn, tied = self._train_batch_fn()
-            if tied:
-                out = fn(self.params, self.opt_state, self.scaler_state, lr, sub, batch)
-                self.params, self.opt_state, self.scaler_state, mean_loss, gnorm, overflow = out
-                self.master_params = self.params
-            else:
-                out = fn(self.params, self.master_params, self.opt_state, self.scaler_state, lr, sub, batch)
-                self.params, self.master_params, self.opt_state, self.scaler_state, mean_loss, gnorm, overflow = out
-            self._enforce_param_memory_kinds()
-        self._nvme_offload_params()
-        self.global_steps += 1
-        self.micro_steps += gas
-        self.global_samples += self.train_batch_size()
-        self.overflow = bool(overflow) if self.fp16_enabled() else False
-        self.global_grad_norm = float(gnorm)
-        if not self.overflow and self.lr_scheduler is not None:
-            self.lr_scheduler.step()
-        elif self.overflow:
-            self.skipped_steps += 1
-        self.timers(TRAIN_BATCH_TIMER).stop()
-        self.tput_timer.stop(global_step=True)
-        self.losses = mean_loss
-        self._write_monitor(loss=mean_loss)
-        self._heartbeat.beat(self.global_steps)
-        self._maybe_handle_preemption()
-        return mean_loss
 
     # ------------------------------------------------------------------
     # Preemption (checked between steps; never inside a signal handler)

@@ -115,7 +115,7 @@ class FleetRouter:
             self.replicas[rep.name] = rep
         self.config = config or FleetConfig()
         self.monitor = monitor
-        self._now = now_fn or time.monotonic
+        self._now = now_fn or time.perf_counter  # the clock of RequestHandle.deadline
         self._seed = seed
         self.health = {name: ReplicaHealth(self.config, now_fn=self._now,
                                            name=name)

@@ -41,6 +41,7 @@ from deepspeed_tpu.serving.admission import (AdmissionQueue, CapacityGate,
                                              RequestShedError)
 from deepspeed_tpu.serving.config import ServingConfig
 from deepspeed_tpu.serving.metrics import ServingMetrics
+from deepspeed_tpu.utils import tracing
 from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.utils.sanitize import tracked_lock
 
@@ -107,7 +108,15 @@ class RequestHandle:
         # schema; None/None = greedy unconstrained
         self.sample = sample
         self.schema = schema
-        self.submitted_at = time.monotonic()
+        # life-cycle stamps, all time.perf_counter_ns() (utils/tracing.py):
+        # the request record is written from them when the request ends
+        self.submitted_ns = tracing.now_ns()
+        self.admitted_ns = None
+        self.admitted_seq = 0       # seq of the pump pass that admitted it
+        self.first_token_ns = None
+        self.last_token_ns = None
+        # the same clock in seconds (time.perf_counter()), for deadlines
+        self.submitted_at = self.submitted_ns / 1e9
         self.deadline = (self.submitted_at + deadline_s
                          if deadline_s is not None else None)
         self.status = "queued"  # queued|running|completed|cancelled|shed|deadline|failed
@@ -116,8 +125,6 @@ class RequestHandle:
         self.queue_wait_s = None
         self._stream = _queue.Queue()
         self._collected = []
-        self._first_token_at = None
-        self._last_token_at = None
         self._done = threading.Event()
         self._cancel_cb = None  # wired by the gateway
 
@@ -186,6 +193,13 @@ class ServingGateway:
             sampling=cfg.sampling,
             on_token=self._on_token)
         self.metrics = ServingMetrics(window=cfg.metrics_window)
+        # gauges and subsystem stats are read when somebody asks
+        # (snapshot() / events()), not pushed on every pump pass
+        # step and request records (utils/tracing.py) carry the engine's number
+        self._engine_id = getattr(engine, "trace_id", 0)
+        self.metrics.attach_sources(self._gauges, self._external,
+                                    engine_id=self._engine_id)
+        self._pump_seq = 0   # seq of the pump pass in progress
         # disaggregated serving: a "prefill" gateway exports a KV
         # handoff record into a bounded outbox when a request finishes;
         # the fleet router claims it via take_handoff() and delivers it
@@ -375,8 +389,7 @@ class ServingGateway:
         self.metrics.gauge_peak("queue_depth_peak",
                                 getattr(handle, "_depth_at_enqueue", 1))
         if shed is not None:
-            self.metrics.count("shed")
-            shed._finish("shed", RequestShedError(
+            self._end(shed, "shed", RequestShedError(
                 f"request {shed.uid} (priority {shed.priority}) evicted from a "
                 f"full queue by request {handle.uid} (priority {prio})"))
         # KV-tier prefetch kick at ADMISSION, not at scheduling: the
@@ -462,6 +475,7 @@ class ServingGateway:
         if self._state != "failed":
             with self._state_lock:
                 self._state = "stopped"
+            self.metrics.detach_sources()  # last observed values stay readable
             self.engine.destroy()
 
     def shutdown(self):
@@ -476,6 +490,7 @@ class ServingGateway:
         self._fail_outstanding(GatewayClosedError("gateway shut down"))
         with self._state_lock:
             self._state = "stopped"
+        self.metrics.detach_sources()
         self.engine.destroy()
 
     def kill(self, error=None):
@@ -492,6 +507,7 @@ class ServingGateway:
         self.queue.close()
         self._stop_pump()
         self._fail_outstanding(error or GatewayFailedError("gateway killed"))
+        self.metrics.detach_sources()
         try:
             self.engine.destroy()
         except Exception:
@@ -506,8 +522,7 @@ class ServingGateway:
         yet, so nothing can double-emit). Returns the number shed."""
         n = 0
         for entry in self.queue.candidates():
-            if self.queue.remove(entry) and entry._finish("failed", error):
-                self.metrics.count("failed")
+            if self.queue.remove(entry) and self._end(entry, "failed", error):
                 n += 1
         return n
 
@@ -635,15 +650,14 @@ class ServingGateway:
             pending["done"].set()
         for entry in self.queue.candidates():
             self.queue.remove(entry)
-            if entry._finish("failed", error):
-                self.metrics.count("failed")
+            self._end(entry, "failed", error)
         for uid, handle in list(self._active.items()):
             try:
                 self.scheduler.cancel(uid)
             except Exception:
                 pass
-            if handle._finish("failed", error):
-                self.metrics.count("failed")
+            self._end(handle, "failed", error,
+                      request=self.scheduler.requests.get(uid))
         self._active.clear()
         self._paused = []
 
@@ -679,48 +693,80 @@ class ServingGateway:
 
     def _pump_once(self):
         """One pump iteration; True when any request made progress."""
-        did = False
-        did |= self._process_cancels()
-        did |= self._process_deadlines()
-        did |= self._maybe_refresh()
-        refreshing = self._pending_refresh is not None
-        if not refreshing:  # admission held while a weight swap is staged
-            did |= self._admit()
-        did |= self._resume_paused()
-        did |= self._step()
-        self.metrics.gauge(
-            queue_depth=len(self.queue),
-            running=len(self._active) - len(self._paused),
-            paused=len(self._paused),
-            kv_free_blocks=int(self.engine.free_blocks),
-            kv_occupancy=round(1.0 - self.engine.free_blocks /
-                               max(self.gate.usable_blocks, 1), 4))
-        prefix_cache = getattr(self.engine, "prefix_cache", None)
-        if prefix_cache is not None:
-            self.metrics.set_external("Serve/PrefixCache", prefix_cache.stats())
-        kv_tier = getattr(self.engine, "kv_tier", None)
-        if kv_tier is not None:
-            self.metrics.set_external("Serve/KVTier", kv_tier.stats())
-        spec = getattr(self.engine, "spec", None)
-        if spec is not None:
-            self.metrics.set_external("Serve/Spec", spec.stats())
-        lora_store = getattr(self.engine, "lora_store", None)
-        if lora_store is not None:
-            self.metrics.set_external("Serve/LoRA", lora_store.stats())
-        syncs = getattr(self.engine, "host_syncs", None)
-        if syncs is not None:
-            self.metrics.set_external("Serve/Engine", {
-                "host_syncs": int(syncs),
-                "tokens_emitted": int(self.engine.tokens_emitted),
-                "syncs_per_token": self.engine.syncs_per_generated_token,
-                "async_burst": int(getattr(self.engine, "async_burst", 0)),
-            })
+        # one step record a pass (kind "pump"); a pass that did nothing —
+        # the pump polls every idle_poll_s — leaves none
+        with tracing.step("pump", span="gateway.pump", engine=self._engine_id) as rec:
+            self._pump_seq = rec.seq
+            with tracing.phase("gateway.admit"):
+                did = self._process_cancels()
+                did |= self._process_deadlines()
+                did |= self._maybe_refresh()
+                refreshing = self._pending_refresh is not None
+                if not refreshing:  # admission held while a weight swap is staged
+                    did |= self._admit()
+                did |= self._resume_paused()
+            did |= self._step()
+            rec.keep = did
         interval = self.config.metrics_interval_steps
         if self.monitor is not None and interval and did:
-            steps = self.metrics.snapshot()["counters"]["engine_steps"]
+            steps = self.metrics.counter("engine_steps")
             if steps and steps % interval == 0:
                 self.metrics.write_events(self.monitor, step=steps)
         return did
+
+    def _gauges(self):
+        """Gauge source of :class:`ServingMetrics` (read on demand; races
+        the pump benignly, like :meth:`inflight`)."""
+        free = int(self.engine.free_blocks)
+        return {"queue_depth": len(self.queue),
+                "running": len(self._active) - len(self._paused),
+                "paused": len(self._paused),
+                "kv_free_blocks": free,
+                "kv_occupancy": round(1.0 - free / max(self.gate.usable_blocks, 1), 4)}
+
+    def _external(self):
+        """External-group source of :class:`ServingMetrics`: each engine
+        subsystem's ``stats()`` under its tag prefix."""
+        engine = self.engine
+        groups = {}
+        for prefix, attr in (("Serve/PrefixCache", "prefix_cache"),
+                             ("Serve/KVTier", "kv_tier"), ("Serve/Spec", "spec"),
+                             ("Serve/LoRA", "lora_store")):
+            subsystem = getattr(engine, attr, None)
+            if subsystem is not None:
+                groups[prefix] = subsystem.stats()
+        syncs = getattr(engine, "host_syncs", None)
+        if syncs is not None:
+            groups["Serve/Engine"] = {
+                "host_syncs": int(syncs),
+                "tokens_emitted": int(engine.tokens_emitted),
+                "syncs_per_token": engine.syncs_per_generated_token,
+                "async_burst": int(getattr(engine, "async_burst", 0)),
+            }
+        return groups
+
+    def _end(self, handle, status, error=None, counter=None, request=None):
+        """Every ending of a request the gateway accepted: finish the
+        handle, count it, and write its request record (utils/tracing.py)
+        from the stamps on the handle and on ``request``, its
+        ``scheduler.Request`` if it was ever admitted. False when it had
+        ended already."""
+        if not handle._finish(status, error):
+            return False
+        self.metrics.count(counter or status)
+        tracing.request(
+            uid=handle.uid, engine=self._engine_id, status=status,
+            prompt_len=len(handle.prompt), generated=len(handle._collected),
+            submitted_ns=handle.submitted_ns,
+            admitted_ns=handle.admitted_ns, admitted_seq=handle.admitted_seq,
+            first_scheduled_ns=request.first_scheduled_ns if request else None,
+            first_scheduled_seq=request.first_scheduled_seq if request else 0,
+            first_token_ns=handle.first_token_ns,
+            first_token_seq=request.first_token_seq if request else 0,
+            prefill_steps=request.prefill_steps if request else 0,
+            prefix_cached_tokens=request.prefix_cached_tokens if request else 0,
+            ended_ns=tracing.now_ns())
+        return True
 
     def _process_cancels(self):
         with self._cancel_lock:
@@ -735,7 +781,7 @@ class ServingGateway:
         return did
 
     def _process_deadlines(self):
-        now = time.monotonic()
+        now = time.perf_counter()  # the clock of submitted_at and deadline
         did = False
         for entry in self.queue.expired(now):
             did |= self._terminate(entry, "deadline", DeadlineExceededError(
@@ -752,16 +798,14 @@ class ServingGateway:
     def _terminate(self, handle, status, error, counter):
         """Stop a queued or active request with the given terminal state."""
         uid = handle.uid
+        request = None
         if uid in self._active:
             self.scheduler.cancel(uid)
-            self.scheduler.retire(uid)
+            request = self.scheduler.retire(uid)
             self._release(handle)
         elif not self.queue.remove(handle):
             return False  # already finished concurrently
-        if handle._finish(status, error):
-            self.metrics.count(counter)
-            return True
-        return False
+        return self._end(handle, status, error, counter, request)
 
     def _release(self, handle):
         self.gate.release(len(handle.prompt), handle.max_new_tokens)
@@ -808,13 +852,13 @@ class ServingGateway:
                 # request with the retryable error instead of killing
                 # the pump — the fleet router fails it over
                 self.gate.release(plen, max_new)
-                if entry._finish("failed", e):
-                    self.metrics.count("rejected_schema" if schema is not None
-                                       else "rejected_adapter")
+                self._end(entry, "failed", e, "rejected_schema" if schema is not None
+                          else "rejected_adapter")
                 did = True
                 continue
             entry.status = "running"
-            entry.queue_wait_s = time.monotonic() - entry.submitted_at
+            entry.admitted_ns, entry.admitted_seq = tracing.now_ns(), self._pump_seq
+            entry.queue_wait_s = (entry.admitted_ns - entry.submitted_ns) / 1e9
             self.metrics.observe_queue_wait(entry.queue_wait_s)
             self.metrics.count("admitted")
             self._active[entry.uid] = entry
@@ -871,19 +915,19 @@ class ServingGateway:
             # stall would spin the pump forever; fail fast instead
             raise RuntimeError(
                 f"scheduler stalled with {len(self._active)} active requests")
-        for uid in self._finished:
-            handle = self._active.get(uid)
-            if handle is None:
-                continue
-            self.scheduler.retire(uid)
-            self._release(handle)
-            if self.role == "prefill":
-                # retire first: the release path folds the request's
-                # full blocks into the trie, which is what export walks
-                self._export_handoff(handle)
-            if handle._finish("completed"):
-                self.metrics.count("completed")
-        self._finished = []
+        with tracing.phase("gateway.deliver"):
+            for uid in self._finished:
+                handle = self._active.get(uid)
+                if handle is None:
+                    continue
+                request = self.scheduler.retire(uid)
+                self._release(handle)
+                if self.role == "prefill":
+                    # retire first: the release path folds the request's
+                    # full blocks into the trie, which is what export walks
+                    self._export_handoff(handle)
+                self._end(handle, "completed", request=request)
+            self._finished = []
         return True
 
     def _export_handoff(self, handle):
@@ -935,14 +979,21 @@ class ServingGateway:
         handle = self._active.get(uid)
         if handle is None:
             return
-        now = time.monotonic()
-        if handle._first_token_at is None:
-            handle._first_token_at = now
-            handle.ttft_s = now - handle.submitted_at
-            self.metrics.observe_ttft(handle.ttft_s)
+        now = tracing.now_ns()
+        if handle.first_token_ns is None:
+            handle.first_token_ns = now
+            handle.ttft_s = (now - handle.submitted_ns) / 1e9
+            request = self.scheduler.requests.get(uid)
+            scheduled = request.first_scheduled_ns if request is not None else None
+            # ttft = queue_wait (submitted → admitted) + sched_wait + prefill_span
+            self.metrics.observe_first_token(
+                handle.ttft_s,
+                sched_wait_s=None if scheduled is None
+                else (scheduled - handle.admitted_ns) / 1e9,
+                prefill_span_s=None if scheduled is None else (now - scheduled) / 1e9)
         else:
-            self.metrics.observe_token_latency(now - handle._last_token_at)
-        handle._last_token_at = now
+            self.metrics.observe_token_latency((now - handle.last_token_ns) / 1e9)
+        handle.last_token_ns = now
         handle._emit(int(token))
         self.metrics.count("tokens_generated")
         if done:

@@ -9,13 +9,25 @@ through the existing ``deepspeed_tpu/monitor`` ``Monitor.write_events``
 interface, so serving metrics land in the same TensorBoard/WandB/CSV
 backends as training metrics.
 
+Gauges and subsystem stats (prefix cache, KV tier, …) are *pulled*: the
+gateway hands over two callables once (:meth:`attach_sources`) and they
+are read when ``snapshot()`` / ``events()`` is called, not pushed on every
+pump pass. The ``steps`` group is a summary of the process's newest step
+records (``deepspeed_tpu/utils/tracing.py``) for this gateway's engine —
+the same records, quantities and clock the benchmark's per-layer metrics
+read.
+
 Thread-safe: ``submit()`` runs on client threads while the pump thread
 records step/token events.
 """
 
 import bisect
+import itertools
 import threading
 from collections import deque
+from statistics import median
+
+from deepspeed_tpu.utils import tracing
 
 # log-ish bucket upper bounds in milliseconds; the last bucket is +inf
 LATENCY_BUCKETS_MS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
@@ -60,6 +72,30 @@ class _LatencyHistogram:
         }
 
 
+def summarize_steps(records):
+    """Step records (``tracing.StepRecord``) → the ``steps`` group:
+    counts by kind, mean ``k`` of the decode bursts, and the median
+    milliseconds from dispatch to the end of the fetch — per burst step,
+    and per ``put`` that carried prompt tokens."""
+    counts, burst_k, burst_ms, mixed_ms = {}, [], [], []
+    for rec in records:
+        counts[rec.kind] = counts.get(rec.kind, 0) + 1
+        enter = [t for name, t, _ in rec.phases if name == "ds.engine.dispatch"]
+        exit_ = [t for name, _, t in rec.phases if name == "ds.engine.fetch"]
+        if not enter or not exit_:
+            continue
+        ms = (exit_[-1] - enter[0]) / 1e6
+        if rec.kind in ("burst", "burst_async"):
+            burst_k.append(rec.k)
+            burst_ms.append(ms / rec.k)
+        elif rec.kind == "put" and rec.n_prompt_tokens > 0:
+            mixed_ms.append(ms)
+    return {"counts": counts,
+            "burst_k_mean": sum(burst_k) / len(burst_k) if burst_k else 0.0,
+            "decode_step_ms_p50": median(burst_ms) if burst_ms else 0.0,
+            "mixed_step_ms_p50": median(mixed_ms) if mixed_ms else 0.0}
+
+
 class ServingMetrics:
 
     COUNTERS = ("submitted", "admitted", "completed", "cancelled",
@@ -71,17 +107,25 @@ class ServingMetrics:
                 "rejected_adapter")
 
     def __init__(self, window=1024):
+        self._window = window
         self._lock = threading.Lock()
         self._counters = {name: 0 for name in self.COUNTERS}
         self.ttft = _LatencyHistogram(window)
         self.token_latency = _LatencyHistogram(window)  # inter-token gap
         self.queue_wait = _LatencyHistogram(window)     # submit -> admitted
+        self.sched_wait = _LatencyHistogram(window)     # admitted -> first scheduled
+        self.prefill_span = _LatencyHistogram(window)   # first scheduled -> first token
         # gauges (last observed; *_peak are high-water marks)
         self._gauges = {"queue_depth": 0, "queue_depth_peak": 0, "running": 0,
                         "paused": 0, "kv_free_blocks": 0, "kv_occupancy": 0.0}
         # external gauge groups published under their own tag prefix
         # (e.g. "Serve/PrefixCache" -> {"hit_rate": ..., ...})
         self._external = {}
+        # pulled sources (attach_sources): read under _pull_lock, so that
+        # detach_sources() returns only when no read is in flight
+        self._pull_lock = threading.Lock()
+        self._gauge_source = self._external_source = None
+        self._engine_id = None
 
     # ---------------------------------------------------------------- events
     def count(self, name, n=1):
@@ -91,6 +135,16 @@ class ServingMetrics:
     def observe_ttft(self, seconds):
         with self._lock:
             self.ttft.observe(seconds * 1e3)
+
+    def observe_first_token(self, ttft_s, sched_wait_s=None, prefill_span_s=None):
+        """A request's first token: TTFT and the two spans of it that
+        follow admission (None: the scheduler stamped none)."""
+        with self._lock:
+            self.ttft.observe(ttft_s * 1e3)
+            if sched_wait_s is not None:
+                self.sched_wait.observe(sched_wait_s * 1e3)
+            if prefill_span_s is not None:
+                self.prefill_span.observe(prefill_span_s * 1e3)
 
     def observe_token_latency(self, seconds):
         with self._lock:
@@ -116,9 +170,45 @@ class ServingMetrics:
         with self._lock:
             self._external[tag_prefix] = dict(values)
 
+    def counter(self, name):
+        with self._lock:
+            return self._counters[name]
+
+    # --------------------------------------------------------------- sources
+    def attach_sources(self, gauges=None, external=None, engine_id=None):
+        """``gauges()`` → a dict of gauge values; ``external()`` →
+        ``{tag_prefix: stats dict}``. Both are called on every
+        ``snapshot()`` / ``events()`` and never otherwise. ``engine_id``
+        selects this gateway's step records for the ``steps`` group."""
+        with self._pull_lock:
+            self._gauge_source, self._external_source = gauges, external
+            self._engine_id = engine_id
+
+    def detach_sources(self):
+        """Read the sources one last time and let go of them (the engine
+        behind them is about to be destroyed): the last observed values
+        stay in every later snapshot."""
+        self._pull()
+        with self._pull_lock:
+            self._gauge_source = self._external_source = None
+
+    def _pull(self):
+        with self._pull_lock:
+            gauges = self._gauge_source() if self._gauge_source is not None else {}
+            external = self._external_source() if self._external_source is not None else {}
+        with self._lock:
+            self._gauges.update(gauges)
+            for prefix, values in external.items():
+                self._external[prefix] = dict(values)
+
     # ---------------------------------------------------------------- export
     def snapshot(self):
         """Plain-dict view of everything (tests / CLI / debugging)."""
+        self._pull()
+        mine = self._engine_id
+        # the newest `window` step records, like the histograms' percentiles
+        recent = itertools.islice(reversed(tuple(tracing.RECORDER.steps)), self._window)
+        steps = summarize_steps(r for r in recent if mine is None or r.engine == mine)
         with self._lock:
             return {
                 "counters": dict(self._counters),
@@ -127,6 +217,9 @@ class ServingMetrics:
                 "ttft": self.ttft.to_dict(),
                 "token_latency": self.token_latency.to_dict(),
                 "queue_wait": self.queue_wait.to_dict(),
+                "sched_wait": self.sched_wait.to_dict(),
+                "prefill_span": self.prefill_span.to_dict(),
+                "steps": steps,
             }
 
     def events(self, step=None):
@@ -141,9 +234,13 @@ class ServingMetrics:
         for prefix, vals in snap["external"].items():
             for name, val in vals.items():
                 out.append((f"{prefix}/{name}", val, step))
-        for hist in ("ttft", "token_latency", "queue_wait"):
+        for hist in ("ttft", "token_latency", "queue_wait", "sched_wait", "prefill_span"):
             for stat in ("mean_ms", "p50_ms", "p95_ms", "p99_ms", "max_ms"):
                 out.append((f"serving/{hist}/{stat}", snap[hist][stat], step))
+        for kind, n in snap["steps"]["counts"].items():
+            out.append((f"serving/steps/count/{kind}", n, step))
+        for name in ("burst_k_mean", "decode_step_ms_p50", "mixed_step_ms_p50"):
+            out.append((f"serving/steps/{name}", snap["steps"][name], step))
         return out
 
     def write_events(self, monitor, step=None):

@@ -1,0 +1,280 @@
+"""Step records and request stamps as the engine, the scheduler, the
+gateway and the trainer write them (deepspeed_tpu/utils/tracing.py):
+one record per program run on every path that runs one, request stamps
+that are ordered and point at records that exist, and a gateway snapshot
+that keeps every key it had when gauges were pushed each pump pass."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, DynamicSplitFuseScheduler,
+                                        InferenceEngineV2, PrefixCacheConfig,
+                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
+from deepspeed_tpu.inference.v2.config_v2 import AsyncBurstConfig
+from deepspeed_tpu.models import build_llama
+from deepspeed_tpu.serving import ServingConfig, ServingGateway
+from deepspeed_tpu.utils import tracing
+
+PROMPT = (np.arange(1, 13) % 250).astype(np.int32)           # 12 tokens
+REPETITIVE = np.tile(np.array([7, 8, 9, 10], np.int32), 6)   # 24 tokens: the drafter finds drafts
+
+
+@pytest.fixture(scope="module")
+def model_and_params():
+    model = build_llama("debug")
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    return model, params
+
+
+def make_engine(model_and_params, async_on=False, spec=False, prefix=False, n_seqs=4,
+                batch=32, max_context=96):
+    model, params = model_and_params
+    cfg = RaggedInferenceEngineConfig(
+        kv_block_size=8, num_kv_blocks=0,
+        async_burst=AsyncBurstConfig(enabled=async_on, depth=2),
+        spec_decode=SpecDecodeConfig(enabled=spec),
+        prefix_cache=PrefixCacheConfig(enabled=prefix),
+        state_manager=DSStateManagerConfig(max_ragged_batch_size=batch,
+                                           max_ragged_sequence_count=n_seqs,
+                                           max_tracked_sequences=n_seqs,
+                                           max_context=max_context))
+    return InferenceEngineV2(model=model, config=cfg, params=params, dtype=jnp.float32)
+
+
+def records_of(engine_id, after=0):
+    return [r for r in tracing.snapshot()["steps"]
+            if r["engine"] == engine_id and r["seq"] > after]
+
+
+def last_seq():
+    steps = tracing.RECORDER.steps
+    return steps[-1].seq if steps else 0
+
+
+def names(record):
+    return [p[0] for p in record["phases"]]
+
+
+def ordered(record):
+    """Phases follow one another inside the record."""
+    stamps = [record["start_ns"]] + [t for p in record["phases"] for t in p[1:]] + [record["end_ns"]]
+    return stamps == sorted(stamps)
+
+
+# ------------------------------------------------- every path that runs a program
+def run_put(engine):
+    engine.put([1, 2], [PROMPT, PROMPT[:1]], sample="greedy")
+    return dict(kind="put", k=1, n_seqs=2, n_tokens=13, n_prompt_tokens=12, program="32",
+                phases=["ds.engine.pack", "ds.engine.dispatch", "ds.engine.fetch"])
+
+
+def run_burst(engine):
+    first = engine.put([1, 2], [PROMPT, PROMPT + 1], sample="greedy")
+    mark = last_seq()
+    engine.decode_burst([1, 2], list(first), 4)
+    return dict(kind="burst", k=4, n_seqs=2, n_tokens=8, n_prompt_tokens=0, program="burst4",
+                after=mark, phases=["ds.engine.pack", "ds.engine.dispatch", "ds.engine.fetch",
+                                    "ds.engine.log"])
+
+
+def run_async(engine):
+    first = engine.put([1, 2], [PROMPT, PROMPT + 1], sample="greedy")
+    mark = last_seq()
+    handle = engine.decode_burst_async([1, 2], [[t] for t in first], 2)
+    assert records_of(engine.trace_id, mark) == [], "an unfetched burst has written nothing yet"
+    engine.put([3], [PROMPT[:5]], sample="greedy")      # another program in between
+    assert [r["kind"] for r in records_of(engine.trace_id, mark)] == ["put"]
+    handle.fetch()
+    handle.fetch()                                      # idempotent: still one record
+    # the burst's seq is older than the put's (it was opened first); it ended later
+    assert [r["kind"] for r in records_of(engine.trace_id, mark)] == ["put", "burst_async"]
+    return dict(kind="burst_async", k=2, n_seqs=2, n_tokens=4, n_prompt_tokens=0,
+                program="aburst2", after=mark,
+                phases=["ds.engine.pack", "ds.engine.dispatch", "ds.engine.fetch"])
+
+
+def run_verify(engine):
+    first = engine.put([1], [REPETITIVE], sample="greedy")
+    mark = last_seq()
+    engine.verify_burst([1], [[int(first[0])]], [[8, 9, 10]])
+    return dict(kind="verify", k=1, n_seqs=1, n_tokens=4, n_prompt_tokens=0, program="verify3",
+                after=mark, phases=["ds.engine.pack", "ds.engine.dispatch", "ds.engine.fetch",
+                                    "ds.engine.log"])
+
+
+@pytest.mark.parametrize("path", ["put", "decode_burst", "async_burst_and_fetch",
+                                  "verify_burst", "train_batch"])
+def test_every_path_that_runs_a_program_writes_one_record(model_and_params, path):
+    if path == "train_batch":
+        import deepspeed_tpu
+        from deepspeed_tpu.utils import groups
+        from tests.unit.simple_model import SimpleModel
+        groups.destroy_mesh()
+        engine, *_ = deepspeed_tpu.initialize(
+            model=SimpleModel(hidden_dim=16), config={
+                "train_batch_size": 16, "gradient_accumulation_steps": 2,
+                "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                "mesh": {"data_parallel_size": 8}})
+        x = np.random.RandomState(0).randn(16, 16).astype(np.float32)
+        y = np.arange(16) % 16
+        engine.train_batch(batch=(x, y))
+        mark = last_seq()
+        engine.train_batch(batch=(x, y))
+        want = dict(kind="train", k=2, n_seqs=16, n_tokens=2 * 8 * 16, n_prompt_tokens=0,
+                    program="train_batch", after=mark,
+                    phases=["ds.train.prepare", "ds.train.timer_sync", "ds.train.dispatch",
+                            "ds.train.sync", "ds.train.timer_sync", "ds.train.post"])
+    else:
+        engine = make_engine(model_and_params, async_on=path == "async_burst_and_fetch",
+                             spec=path == "verify_burst")
+        want = {"put": run_put, "decode_burst": run_burst, "async_burst_and_fetch": run_async,
+                "verify_burst": run_verify}[path](engine)
+    wrote = [r for r in records_of(engine.trace_id, want.pop("after", 0))
+             if r["kind"] == want["kind"]]
+    assert len(wrote) == 1, [r["kind"] for r in wrote]
+    record, phases = wrote[0], want.pop("phases")
+    assert {key: record[key] for key in want} == want
+    assert names(record) == phases and ordered(record)
+    assert record["caused_by"] == 0                      # nobody's pump pass
+    if path != "train_batch":
+        assert engine.last_step.seq == record["seq"]
+    engine.destroy()
+
+
+def test_a_rejected_batch_writes_no_record(model_and_params):
+    engine = make_engine(model_and_params)
+    mark = last_seq()
+    with pytest.raises(ValueError):
+        engine.put([1], [np.zeros(500, np.int32)])       # over the token budget
+    with pytest.raises(ValueError):
+        engine.decode_burst_async([9], [[1]], 2)         # unknown sequence
+    assert records_of(engine.trace_id, mark) == [] and tracing.current() is None
+    engine.destroy()
+
+
+def test_the_scheduler_says_which_tokens_are_prompt(model_and_params):
+    """A one-token prompt chunk looks like a decode token to the engine;
+    ``_plan`` knows better and corrects the record."""
+    engine = make_engine(model_and_params, batch=32)
+    sched = DynamicSplitFuseScheduler(engine, token_budget=16, max_burst=1)
+    sched.add_request(1, np.arange(1, 18, dtype=np.int32), max_new_tokens=2)   # 16 + 1
+    mark = last_seq()
+    sched.run_to_completion()
+    puts = records_of(engine.trace_id, mark)
+    assert [r["n_prompt_tokens"] for r in puts] == [16, 1, 0]
+    assert [r["n_tokens"] for r in puts] == [16, 1, 1]
+    request = sched.requests[1]
+    assert request.prefill_steps == 2 and request.first_scheduled_seq == puts[0]["seq"]
+    assert request.first_token_seq == puts[1]["seq"]
+    engine.destroy()
+
+
+# ------------------------------------------------------------------ the gateway
+def serve(model_and_params, prompts, max_new=6, async_on=False, **config):
+    engine = make_engine(model_and_params, async_on=async_on, n_seqs=8, batch=16)
+    mark = last_seq()
+    gw = ServingGateway(engine, config=ServingConfig(token_budget=16, **config))
+    handles = [gw.submit(p, max_new_tokens=max_new) for p in prompts]
+    for h in handles:
+        h.result(timeout=120)
+    return engine, gw, handles, mark
+
+
+@pytest.mark.parametrize("async_on", [False, True])
+def test_request_stamps_are_ordered_and_point_at_records_that_exist(model_and_params, async_on):
+    prompts = [np.arange(1, 1 + n, dtype=np.int32) for n in (5, 40, 12, 3)]   # 40 = three chunks
+    engine, gw, handles, mark = serve(model_and_params, prompts, async_on=async_on)
+    gw.drain(timeout=60)
+    steps = records_of(engine.trace_id, mark)
+    by_seq = {r["seq"]: r for r in steps}
+    requests = {q["uid"]: q for q in tracing.snapshot()["requests"]
+                if q["engine"] == engine.trace_id}
+    assert sorted(requests) == sorted(h.uid for h in handles)
+    for h in handles:
+        q = requests[h.uid]
+        assert q["status"] == "completed" and q["generated"] == 6
+        assert q["prompt_len"] == len(h.prompt)
+        assert q["submitted_ns"] <= q["admitted_ns"] <= q["first_scheduled_ns"] \
+            < q["first_token_ns"] <= q["ended_ns"]
+        assert h.queue_wait_s == pytest.approx((q["admitted_ns"] - q["submitted_ns"]) / 1e9)
+        assert h.ttft_s == pytest.approx((q["first_token_ns"] - q["submitted_ns"]) / 1e9)
+        # the links: a pump pass admitted it, a put first held it, a step gave its first token
+        assert by_seq[q["admitted_seq"]]["kind"] == "pump"
+        first, token = by_seq[q["first_scheduled_seq"]], by_seq[q["first_token_seq"]]
+        assert first["kind"] == "put" and h.uid in first["uids"] and h.uid in token["uids"]
+        assert first["start_ns"] <= token["start_ns"] and token["end_ns"] <= q["first_token_ns"]
+        assert by_seq[first["caused_by"]]["kind"] == "pump"
+        held = [r for r in steps if r["kind"] == "put" and h.uid in r["uids"]
+                and r["seq"] <= q["first_token_seq"]]
+        assert q["prefill_steps"] == len(held) >= -(-len(h.prompt) // 16)
+        assert all(r["n_prompt_tokens"] > 0 for r in held)
+    # every engine record of the run was caused by a pump pass that is in the ring
+    pumps = {r["seq"] for r in steps if r["kind"] == "pump"}
+    engine_records = [r for r in steps if r["kind"] != "pump"]
+    assert engine_records and all(r["caused_by"] in pumps for r in engine_records)
+    assert {r["kind"] for r in engine_records} >= {"put", "burst_async" if async_on else "burst"}
+    # a pump pass holds admit, then the scheduler's phases around the engine call, then deliver
+    busy = [r for r in steps if r["kind"] == "pump" and "ds.sched.plan" in names(r)]
+    assert busy and all(names(r)[0] == "ds.gateway.admit" and names(r)[-1] == "ds.gateway.deliver"
+                        and ordered(r) for r in busy)
+
+
+def test_an_idle_gateway_writes_nothing_and_a_request_that_never_ran_has_no_step(model_and_params):
+    engine = make_engine(model_and_params, n_seqs=8, batch=16)
+    gw = ServingGateway(engine, config=ServingConfig(token_budget=16), auto_start=False)
+    mark = last_seq()
+    for _ in range(20):
+        assert gw._pump_once() is False
+    assert records_of(engine.trace_id, mark) == []
+    handle = gw.submit(PROMPT, max_new_tokens=4)
+    handle.cancel()
+    gw._pump_once()
+    (q,) = [q for q in tracing.snapshot()["requests"] if q["engine"] == engine.trace_id]
+    assert q["status"] == "cancelled" and q["admitted_ns"] is None
+    assert q["first_scheduled_ns"] is None and q["first_token_ns"] is None
+    assert q["submitted_ns"] <= q["ended_ns"] and q["prefill_steps"] == 0
+    gw.shutdown()
+
+
+GAUGES = {"queue_depth", "queue_depth_peak", "running", "paused", "kv_free_blocks",
+          "kv_occupancy"}
+TOP = {"counters", "gauges", "external", "ttft", "token_latency", "queue_wait", "state"}
+
+
+def test_the_snapshot_keeps_every_key_with_the_pulled_sources(model_and_params):
+    engine = make_engine(model_and_params, spec=True, prefix=True, n_seqs=8, batch=16)
+    mark = last_seq()
+    gw = ServingGateway(engine, config=ServingConfig(token_budget=16))
+    for p in (REPETITIVE, PROMPT, PROMPT[:4]):
+        gw.submit(p, max_new_tokens=6).result(timeout=120)
+    live = gw.snapshot()
+    assert set(live) >= TOP and set(live["gauges"]) == GAUGES
+    assert set(live["external"]) == {"Serve/PrefixCache", "Serve/Spec", "Serve/Engine"}
+    assert set(live["external"]["Serve/Engine"]) == {"host_syncs", "tokens_emitted",
+                                                     "syncs_per_token", "async_burst"}
+    assert live["external"]["Serve/PrefixCache"] == engine.prefix_cache.stats()
+    assert live["external"]["Serve/Engine"]["tokens_emitted"] == engine.tokens_emitted > 0
+    assert live["gauges"]["running"] == 0 and live["gauges"]["queue_depth"] == 0
+    assert set(live["counters"]) == set(gw.metrics.COUNTERS)
+    # the new keys: the two spans after admission, and the step records' summary
+    assert live["sched_wait"]["count"] == live["prefill_span"]["count"] == live["ttft"]["count"] == 3
+    assert live["ttft"]["mean_ms"] == pytest.approx(
+        live["queue_wait"]["mean_ms"] + live["sched_wait"]["mean_ms"]
+        + live["prefill_span"]["mean_ms"], rel=1e-6)
+    kinds = [r["kind"] for r in records_of(engine.trace_id, mark)]
+    assert live["steps"]["counts"] == {k: kinds.count(k) for k in set(kinds)}
+    assert live["steps"]["counts"]["put"] >= 3 and live["steps"]["mixed_step_ms_p50"] > 0
+    tags = {tag for tag, _, _ in gw.metrics.events()}
+    assert {"serving/gauge/kv_free_blocks", "Serve/Engine/host_syncs", "serving/ttft/p99_ms",
+            "serving/sched_wait/p50_ms", "serving/prefill_span/p95_ms",
+            "serving/steps/burst_k_mean", "serving/steps/count/put"} <= tags
+    # nothing is pushed from the pump any more, and the values outlive the engine
+    gw.drain(timeout=60)
+    assert engine.kv_cache is None
+    after = gw.snapshot()
+    assert after["gauges"] == live["gauges"] and after["state"] == "stopped"
+    assert after["external"]["Serve/Engine"] == live["external"]["Serve/Engine"]
+    assert set(after["external"]) == set(live["external"])
