@@ -231,13 +231,14 @@ def kernels_phase(cfg):
         ("fused_rms_norm",), 1e-2)
 
     # paged decode attention at the model's head geometry: 24 tokens at
-    # assorted positions over 8-block tables into a 64-block pool
-    T, NB, bs, MB = 24, 64, 16, 8
-    qd, kc, vc = normal(T, H, Dh), normal(NB, bs, Hkv, Dh), normal(NB, bs, Hkv, Dh)
+    # assorted positions over 8-block tables into layer 1 of a 3-layer,
+    # 64-block pool
+    T, L, NB, bs, MB = 24, 3, 64, 16, 8
+    qd, kc, vc = normal(T, H, Dh), normal(L, NB, bs, Hkv * Dh), normal(L, NB, bs, Hkv * Dh)
     tabs = jnp.asarray(rng.integers(1, NB, (T, MB)), jnp.int32)
     pos = jnp.asarray(rng.integers(0, MB * bs, (T,)), jnp.int32)
     run("paged_decode_attention", paged_decode_attention, xla_paged_attention,
-        (qd, kc, vc, tabs, pos), ("paged_decode_attention",), 1e-2)
+        (qd, kc, vc, tabs, pos, jnp.int32(1)), ("paged_decode_attention",), 1e-2)
     return facts
 
 

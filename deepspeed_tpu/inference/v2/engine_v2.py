@@ -236,7 +236,7 @@ class InferenceEngineV2:
             # Derived sizing (max_seqs x max_context worst case) can dwarf
             # HBM for wide-KV models — e.g. the default 512-seq manager at
             # 20 KV heads x Dh 128 derives a 43 GB pool. Cap the DEFAULT
-            # at 8 GB PER POOL SHARD (the pool shards its KV-head dim over
+            # at 8 GB PER POOL SHARD (the pool shards whole KV heads over
             # the 'tensor' axis when divisible) with a warning; an explicit
             # num_kv_blocks is honored as given.
             bytes_per_block = (2 * cfg.num_hidden_layers * self.block_size *

@@ -8,13 +8,17 @@ atom-based blocked attention). TPU redesign: one jitted function
 consumes the padded flat batch —
 
 - tokens are a flat ``[T]`` buffer with per-token (slot, position);
-- each layer scatters new K/V into the block pool at
-  ``(block_tables[slot, pos // bs], pos % bs)`` and attends by
-  gathering the sequence's block table (masked to ``pos``), which
-  handles mixed prefill chunks + decodes in ONE program — the
-  Dynamic SplitFuse execution model;
-- the layer stack is ``lax.scan`` over the model's stacked scan params,
-  so any ``LlamaForCausalLM`` (Llama/Mistral/Mixtral/Qwen2) or
+- the block pool ``[L, NB, bs, Hkv*Dh]`` is the CARRY of the layer scan:
+  layer ``l`` scatters its new K/V rows into the whole pool at
+  ``(l, block_tables[slot, pos // bs], pos % bs)`` and attends by
+  reading the sequence's block table out of ``pool[l]`` (masked to
+  ``pos``), which handles mixed prefill chunks + decodes in ONE program
+  — the Dynamic SplitFuse execution model. The pool is never sliced,
+  stacked or reshaped, so with the callers' donation the write is in
+  place from the program's argument to its result;
+- the layer stack is ``lax.scan`` over the model's stacked scan params
+  (the scan's ``xs``: what is read-only per layer), so any
+  ``LlamaForCausalLM`` (Llama/Mistral/Mixtral/Qwen2) or
   ``GPTForCausalLM`` (GPT-2/J/NeoX, OPT, Bloom, Falcon, Phi) checkpoint
   serves directly.
 """
@@ -91,19 +95,32 @@ def _rope_flat_interleaved(x, cos, sin, positions):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _paged_attend(q, k, v, kc, vc, batch, Dh, alibi=None, mesh=None, impl=None):
-    """Scatter new K/V into the paged pool and attend over each token's
-    block-tabled context. The attention implementation comes from the
-    ``modules/heuristics`` registry (Pallas decode kernel single-device
-    or per-TP-shard, XLA gather fallback / ALiBi path), optionally
-    pinned by the engine config's ``implementation_overrides``; ``impl``
-    is the engine's :class:`AttentionChoice`, which carries the pin in
-    and the selected implementation's name out."""
-    bs = kc.shape[1]
+def _c_pool(x, n_kv_heads, mesh):
+    """Pin the pool ``[L, NB, bs, Hkv*Dh]`` to the layout it is stored in
+    (whole KV heads over 'tensor', ``sharding.kv_pool_spec``)."""
+    if mesh is None:
+        return x
+    from deepspeed_tpu.inference.v2.sharding import kv_pool_spec
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, kv_pool_spec(mesh, n_kv_heads)))
+
+
+def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl=None):
+    """Scatter layer ``layer``'s new K/V rows into the paged pool (one
+    scatter into the whole pool, which comes back as the same buffers)
+    and attend over each token's block-tabled context. The attention
+    implementation comes from the ``modules/heuristics`` registry
+    (Pallas decode kernel single-device or per-TP-shard, XLA gather
+    fallback / ALiBi path), optionally pinned by the engine config's
+    ``implementation_overrides``; ``impl`` is the engine's
+    :class:`AttentionChoice`, which carries the pin in and the selected
+    implementation's name out."""
+    bs = kc.shape[2]
+    T, Hkv = k.shape[:2]
     blk = batch["block_tables"][batch["token_seq"], batch["token_pos"] // bs]  # [T]
     off = batch["token_pos"] % bs
-    kc = _c(kc.at[blk, off].set(k.astype(kc.dtype)), (None, None, "tensor", None), mesh)
-    vc = _c(vc.at[blk, off].set(v.astype(vc.dtype)), (None, None, "tensor", None), mesh)
+    kc = _c_pool(kc.at[layer, blk, off].set(k.reshape(T, -1).astype(kc.dtype)), Hkv, mesh)
+    vc = _c_pool(vc.at[layer, blk, off].set(v.reshape(T, -1).astype(vc.dtype)), Hkv, mesh)
 
     from deepspeed_tpu.inference.v2.modules.heuristics import instantiate_attn
     tab = batch["block_tables"][batch["token_seq"]]  # [T, MB]
@@ -113,13 +130,14 @@ def _paged_attend(q, k, v, kc, vc, batch, Dh, alibi=None, mesh=None, impl=None):
                                      override=impl.override if impl else None)
     if impl is not None:
         impl.selected[q.shape[0]] = name
-    out = attn_fn(q, kc, vc, tab, pos)
+    out = attn_fn(q, kc, vc, tab, pos, layer)
     return _c(out, (None, "tensor", None), mesh), kc, vc
 
 
-def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, h, xs):
+def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, carry, xs):
+    h, kc, vc = carry
     if lora_ctx is None:
-        lp, kc, vc = xs
+        layer, lp = xs
 
         def lproj(x, p, site):
             return _proj(x, p)
@@ -127,7 +145,7 @@ def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, h, xs):
         # Multi-tenant LoRA: the scan sliced this layer's stacked hot
         # slabs alongside the params; each targeted projection adds the
         # segmented per-token adapter delta (slot 0 = base = exact 0.0).
-        lp, kc, vc, la, lb = xs
+        layer, lp, la, lb = xs
         slots, scales, lora_impl = lora_ctx
         from deepspeed_tpu.ops.pallas.lora_matmul import apply_lora_delta
 
@@ -154,7 +172,7 @@ def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, h, xs):
     q = _rope_flat(q, cos, sin, batch["token_pos"])
     k = _rope_flat(k, cos, sin, batch["token_pos"])
 
-    out, kc, vc = _paged_attend(q, k, v, kc, vc, batch, Dh, mesh=mesh,
+    out, kc, vc = _paged_attend(q, k, v, kc, vc, layer, batch, Dh, mesh=mesh,
                                 impl=attn_impl)
     h = _c(h + lproj(out.reshape(T, H * Dh), attn["o_proj"], "o_proj"), (None, None), mesh)
 
@@ -170,7 +188,7 @@ def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, h, xs):
         else:
             inter = jax.nn.silu(gate) * up
         h = _c(h + _proj(inter, mlp["down_proj"]), (None, None), mesh)
-    return h, (kc, vc)
+    return (h, kc, vc), None
 
 
 def _moe_mlp(x, p, k, mesh=None):
@@ -216,11 +234,12 @@ def _moe_mlp(x, p, k, mesh=None):
     # transpose psum, which serving never runs)
 
 
-def _gpt_layer_step(cfg, cos, sin, alibi, batch, mesh, attn_impl, h, xs):
+def _gpt_layer_step(cfg, cos, sin, alibi, batch, mesh, attn_impl, carry, xs):
     """One GPT-family block over the flat ragged batch (sequential or
     parallel wiring, optional partial rotary / ALiBi, biased
     projections, LayerNorm or RMSNorm)."""
-    lp, kc, vc = xs
+    h, kc, vc = carry
+    layer, lp = xs
     # Quantized carriers stay boxed; _proj consumes them fused.
     T, D = h.shape
     H, Hkv, Dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -255,7 +274,7 @@ def _gpt_layer_step(cfg, cos, sin, alibi, batch, mesh, attn_impl, h, xs):
             k = jnp.concatenate(
                 [rope(k[..., :rd], cos, sin, batch["token_pos"]), k[..., rd:]], -1)
 
-    out, kc, vc = _paged_attend(q, k, v, kc, vc, batch, Dh, alibi=alibi,
+    out, kc, vc = _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=alibi,
                                 mesh=mesh, impl=attn_impl)
     attn_out = _proj(out.reshape(T, H * Dh), attn["o_proj"])
 
@@ -273,15 +292,16 @@ def _gpt_layer_step(cfg, cos, sin, alibi, batch, mesh, attn_impl, h, xs):
     else:
         h = _c(h + attn_out, (None, None), mesh)
         h = _c(h + mlp(norm(lp["post_attention_layernorm"], h)), (None, None), mesh)
-    return h, (kc, vc)
+    return (h, kc, vc), None
 
 
 def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=None,
                    attn_impl=None, lora=None):
-    """→ (last-token logits [max_seqs, vocab] fp32, new kcache, new vcache).
+    """→ (last-token logits [max_seqs, vocab] fp32, kcache, vcache).
 
-    ``kcache``/``vcache``: [L, NB, bs, Hkv, Dh]; ``batch``: the arrays
-    of ``RaggedBatchWrapper.finalize()``. ``cfg`` is a ``LlamaConfig``
+    ``kcache``/``vcache``: the pool [L, NB, bs, Hkv*Dh], carried through
+    the layer scan and written in place (donate them); ``batch``: the
+    arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is a ``LlamaConfig``
     or ``GPTConfig``; the layer wiring follows it. ``mesh``: an optional
     serving mesh — params/KV arrive sharded per
     ``inference/v2/sharding.py`` and the step pins the Megatron layout
@@ -304,6 +324,7 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     if mult != 1.0:  # Gemma: sqrt(hidden_size)
         h = h * jnp.asarray(mult, h.dtype)
 
+    layer_ids = jnp.arange(kcache.shape[0], dtype=jnp.int32)
     if lora is not None and is_gpt:
         raise NotImplementedError(
             "multi-tenant LoRA serving targets the Llama-family layer "
@@ -325,24 +346,24 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
             h = _layernorm(h, params["model"]["embed_layernorm"], cfg.layer_norm_eps)
         step = functools.partial(_gpt_layer_step, cfg, cos, sin, alibi, batch, mesh,
                                  attn_impl)
-        xs = (params["model"]["layers"], kcache, vcache)
+        xs = (layer_ids, params["model"]["layers"])
     else:
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta,
                                     scaling=rope_scaling_of(cfg))
         cos, sin = jnp.asarray(cos), jnp.asarray(sin)
         lora_ctx = None
-        xs = (params["model"]["layers"], kcache, vcache)
+        xs = (layer_ids, params["model"]["layers"])
         if lora is not None:
             la, lb, scales, seq_adapters, lora_impl = lora
             # per-token adapter slot: pad tokens hit the pad row, which
             # carries slot 0 (base) by construction
             slots = seq_adapters[batch["token_seq"]]
             lora_ctx = (slots, scales, lora_impl)
-            xs = (params["model"]["layers"], kcache, vcache, la, lb)
+            xs = (layer_ids, params["model"]["layers"], la, lb)
         step = functools.partial(_layer_step, cfg, cos, sin, batch, mesh, attn_impl,
                                  lora_ctx)
 
-    h, (kc, vc) = jax.lax.scan(step, h, xs)
+    (h, kc, vc), _ = jax.lax.scan(step, (h, kcache, vcache), xs)
 
     if is_gpt:
         if cfg.norm_type == "layernorm":

@@ -20,6 +20,10 @@ Implementations registered for ``attention`` (the ragged decode op):
   under ``shard_map`` (query/KV heads divide over 'tensor').
 - ``xla_gather``            — gather-based XLA reference; always
   supported, and the only path for ALiBi models.
+
+Every implementation takes the whole pool ``[L, NB, bs, Hkv*Dh]`` and
+the layer to read as an index (``kc_shape`` below is the pool's shape;
+the KV-head count is its last dim over ``head_dim``).
 """
 
 from jax.sharding import PartitionSpec as P
@@ -61,7 +65,7 @@ class _PallasPaged:
         from deepspeed_tpu.ops.pallas.paged_attention import kernel_supported, smem_table_fits
         return (alibi is None and (mesh is None or mesh.size == 1)
                 and use_pallas()
-                and kernel_supported(head_dim, block_size, kc_shape[2])
+                and kernel_supported(head_dim, block_size, kc_shape[3] // head_dim)
                 and smem_table_fits(q_shape[0], max_blocks))
 
     @staticmethod
@@ -74,7 +78,7 @@ class _PallasPaged:
 class _PallasPagedSharded:
 
     Q_SPEC = P(None, "tensor", None)
-    KV_SPEC = P(None, None, "tensor", None)
+    KV_SPEC = P(None, None, None, "tensor")
 
     @staticmethod
     def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
@@ -83,15 +87,16 @@ class _PallasPagedSharded:
         if alibi is not None or mesh is None or mesh.size == 1:
             return False
         tp = dict(mesh.shape).get("tensor", 1)
+        n_kv = kc_shape[3] // head_dim
         return (kernel_dispatch(mesh) == "shard_map"
-                and kernel_supported(head_dim, block_size,
-                                     max(kc_shape[2] // tp, 1))
+                and kernel_supported(head_dim, block_size, max(n_kv // tp, 1))
                 # tokens and tables are replicated: every shard holds them whole
                 and smem_table_fits(q_shape[0], max_blocks)
                 and spec_divides(mesh, _PallasPagedSharded.Q_SPEC, q_shape)
-                and spec_divides(mesh, _PallasPagedSharded.KV_SPEC, kc_shape)
+                # a shard of the flattened last dim is a contiguous group of whole heads
+                and n_kv % tp == 0
                 # per-shard GQA grouping needs whole KV-head groups
-                and (q_shape[1] // kc_shape[2]) * kc_shape[2] == q_shape[1])
+                and (q_shape[1] // n_kv) * n_kv == q_shape[1])
 
     @staticmethod
     def instantiate(mesh, head_dim, block_size, q_shape, kc_shape, alibi):
@@ -100,7 +105,7 @@ class _PallasPagedSharded:
         cls = _PallasPagedSharded
         return shard_map_kernel(
             paged_decode_attention, mesh,
-            in_specs=(cls.Q_SPEC, cls.KV_SPEC, cls.KV_SPEC, P(), P()),
+            in_specs=(cls.Q_SPEC, cls.KV_SPEC, cls.KV_SPEC, P(), P(), P()),
             out_specs=cls.Q_SPEC)
 
 
@@ -121,7 +126,7 @@ class _XlaGather:
 
 def instantiate_attn(mesh, head_dim, block_size, q_shape, kc_shape, alibi,
                      max_blocks, override=None):
-    """→ ``(impl_name, fn(q, kc, vc, tab, pos))`` — the first supported
+    """→ ``(impl_name, fn(q, kc, vc, tab, pos, layer))`` — the first supported
     implementation in registration (priority) order, or the named one
     when the config pins ``override`` (reference
     heuristics.instantiate_attn + config_bundle semantics). A pin that

@@ -4,11 +4,15 @@ Capability match for the reference's
 ``deepspeed/inference/v2/ragged/kv_cache.py`` (``BlockedKVCache`` at
 kv_cache.py:40): a pool of fixed-size KV blocks shared by all
 sequences, fronted by :class:`BlockedAllocator`. TPU design: the pool
-is two device arrays ``[num_layers, num_blocks, block_size, n_kv_heads,
-head_dim]`` updated functionally (the engine donates them through the
-jitted step, so XLA updates in place). Block 0 is reserved as the
-null block — padding tokens scatter there and no live sequence ever
-owns it."""
+is two device arrays ``[num_layers, num_blocks, block_size, n_kv_heads *
+head_dim]`` — the layout the paged kernel's block DMA reads, so no
+program reshapes it — updated functionally (the engine donates them
+through the jitted step, whose layer scan carries them, so XLA updates
+in place). What leaves or enters the pool a few blocks at a time
+(offload handles) keeps the 5-D wire shape ``[num_layers, n, block_size,
+n_kv_heads, head_dim]``: those blocks are reshaped, never the pool.
+Block 0 is reserved as the null block — padding tokens scatter there
+and no live sequence ever owns it."""
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +33,9 @@ class BlockedKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, n_kv_heads, head_dim,
                  dtype=jnp.bfloat16, sharding=None):
-        """``sharding``: where the pool lives (a serving mesh shards it
-        over KV heads); it is allocated there directly, never whole on
-        the default device first."""
+        """``sharding``: where the pool lives (a serving mesh shards its
+        last dim over whole KV heads); it is allocated there directly,
+        never whole on the default device first."""
         assert num_blocks >= 2, "need at least one real block beyond the null block"
         self.num_layers = num_layers
         self.num_blocks = num_blocks
@@ -39,7 +43,7 @@ class BlockedKVCache:
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim
         self.dtype = dtype
-        shape = (num_layers, num_blocks, block_size, n_kv_heads, head_dim)
+        shape = (num_layers, num_blocks, block_size, n_kv_heads * head_dim)
         self.k = jnp.zeros(shape, dtype, device=sharding)
         self.v = jnp.zeros(shape, dtype, device=sharding)
         self._allocator = BlockedAllocator(num_blocks)
@@ -79,15 +83,14 @@ class BlockedKVCache:
                 raise KVCacheHandleError(f"invalid block id {b} for a "
                                          f"{self.num_blocks}-block pool")
         n = len(blocks)
+        wire = (self.num_layers, n, self.block_size, self.n_kv_heads, self.head_dim)
         if n == 0:
-            shape = (self.num_layers, 0, self.block_size, self.n_kv_heads,
-                     self.head_dim)
-            empty = jax.device_get(jnp.zeros(shape, self.dtype))
+            empty = jax.device_get(jnp.zeros(wire, self.dtype))
             return {"k": empty, "v": empty.copy()}
         padded = 1 << (n - 1).bit_length()
         ids = jnp.asarray(blocks + [blocks[-1]] * (padded - n), jnp.int32)
         k_host, v_host = jax.device_get(_gather_blocks(self.k, self.v, ids))
-        return {"k": k_host[:, :n], "v": v_host[:, :n]}
+        return {"k": k_host[:, :n].reshape(wire), "v": v_host[:, :n].reshape(wire)}
 
     def offload(self, blocks, keep=()):
         """Move ``blocks``' KV to host memory and free them for reuse.
@@ -174,16 +177,18 @@ class BlockedKVCache:
             return []
         blocks = self.reserve(n)
         ids = jnp.asarray(blocks, jnp.int32)
+        # the handle's blocks take the pool's flattened layout; the pool is never reshaped
+        flat = (self.num_layers, n, self.block_size, self.n_kv_heads * self.head_dim)
         if handle.get("quantized"):
             self.k, self.v = _scatter_blocks_q(
                 self.k, self.v, ids,
-                jnp.asarray(handle["k"]), jnp.asarray(handle["v"]),
+                jnp.asarray(handle["k"]).reshape(flat), jnp.asarray(handle["v"]).reshape(flat),
                 jnp.asarray(handle["k_scales"], jnp.float32),
                 jnp.asarray(handle["v_scales"], jnp.float32))
         else:
             self.k, self.v = _scatter_blocks(self.k, self.v, ids,
-                                             jnp.asarray(handle["k"], self.dtype),
-                                             jnp.asarray(handle["v"], self.dtype))
+                                             jnp.asarray(handle["k"], self.dtype).reshape(flat),
+                                             jnp.asarray(handle["v"], self.dtype).reshape(flat))
         return blocks
 
 
@@ -199,12 +204,12 @@ _gather_blocks = jax.jit(
 
 
 def _dequant_blocks(vals, scales, dtype):
-    """Per-group int8 dequant in pool layout (traced inside the restore
-    scatter): group ``g`` of block ``b`` in layer ``l`` scales by
-    ``scales[l, b, g]``."""
-    L, n, bs, H, D = vals.shape
+    """Per-group int8 dequant of blocks in pool layout ``[L, n, bs,
+    Hkv*Dh]`` (traced inside the restore scatter): group ``g`` of block
+    ``b`` in layer ``l`` scales by ``scales[l, b, g]``."""
+    L, n, bs, HD = vals.shape
     groups = scales.shape[-1]
-    gs = (bs * H * D) // groups
+    gs = (bs * HD) // groups
     deq = vals.astype(jnp.float32).reshape(L, n, groups, gs) * scales[..., None]
     return deq.reshape(vals.shape).astype(dtype)
 

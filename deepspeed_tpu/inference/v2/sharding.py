@@ -7,7 +7,7 @@ sharding) and the TP wiring in ``engine_v2.py:30``. TPU redesign:
 instead of slicing torch tensors per rank, every decision is a
 ``PartitionSpec`` from the model family's ``tp_rule`` — parameters are
 ``device_put`` once with those shardings, the flat token batch stays
-replicated, the blocked KV pool is sharded over its KV-head dim, and
+replicated, the blocked KV pool is sharded over whole KV heads, and
 GSPMD inserts the Megatron all-reduces inside the jitted ragged step.
 """
 
@@ -161,9 +161,11 @@ def moe_expert_specs(mesh, w1, w3, w2):
 
 
 def kv_pool_spec(mesh, n_kv_heads) -> P:
-    """Blocked KV pool [L, NB, bs, Hkv, Dh]: shard the KV-head dim over
-    'tensor' (reference sharding/attn.py shards KV heads per rank; MQA
-    with Hkv < tp replicates, exactly as the reference replicates the
-    single KV head)."""
-    return P(*live_entries(mesh, P(None, None, None, "tensor", None),
-                           (1, 1, 1, n_kv_heads, 1)))
+    """Blocked KV pool [L, NB, bs, Hkv*Dh]: shard the flattened last dim
+    over 'tensor' in contiguous groups of whole KV heads (reference
+    sharding/attn.py shards KV heads per rank; MQA with Hkv < tp — or
+    any Hkv % tp != 0 — replicates, exactly as the reference replicates
+    the single KV head), so divisibility is the head count's, not the
+    flattened width's."""
+    return P(*live_entries(mesh, P(None, None, None, "tensor"),
+                           (1, 1, 1, n_kv_heads)))

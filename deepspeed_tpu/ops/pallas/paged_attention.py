@@ -9,6 +9,12 @@ indexed out of the pool, and scores accumulate flash-style (running
 max / sum) with positions beyond the token's context masked. GQA is
 handled by viewing the query heads as [Hkv, G, Dh].
 
+Both paths take the WHOLE pool ``[L, NB, bs, Hkv*Dh]`` — the layout
+``BlockedKVCache`` stores — and the layer as an index, so the layer
+scan never cuts a layer out of the pool and no program reshapes it:
+the kernel's block DMA reads ``pool[layer, blk]``, the reference
+gathers ``pool[layer, block_tables]``.
+
 The XLA reference path (``xla_paged_attention``) is the same math via
 gather. Which of the two a program runs is decided in ONE place, the
 ``inference/v2/modules/heuristics`` registry (``supports()`` there reads
@@ -38,22 +44,23 @@ SMEM_TABLE_BYTES = 768 * 1024
 GATHER_LIMIT_BYTES = 2 << 30
 
 
-def xla_paged_attention(q, kc, vc, block_tables, token_pos, alibi_slopes=None):
-    """Reference math. q: [T, H, Dh]; kc/vc: [NB, bs, Hkv, Dh];
+def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, alibi_slopes=None):
+    """Reference math. q: [T, H, Dh]; kc/vc: the pool [L, NB, bs, Hkv*Dh];
     block_tables: [T, MB] (per TOKEN, already indexed by its sequence);
-    token_pos: [T]. → [T, H, Dh]; attends to positions <= token_pos.
+    token_pos: [T]; layer: int32 scalar, the layer of the pool to read.
+    → [T, H, Dh]; attends to positions <= token_pos.
     ``alibi_slopes``: optional [H] — adds the Bloom-style linear
     relative-position penalty slope_h * (k_pos - q_pos) to the scores."""
     T, H, Dh = q.shape
-    _, bs, Hkv, _ = kc.shape
+    bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     gather_bytes = 2 * T * block_tables.shape[1] * bs * Hkv * Dh * kc.dtype.itemsize
     if gather_bytes > GATHER_LIMIT_BYTES:
         raise ValueError(
             f"the XLA gather attention would materialize {gather_bytes / 1e9:.0f} GB of KV "
             f"for block table [{T}, {block_tables.shape[1]}] — shrink "
             f"max_ragged_batch_size / max_context, or raise kv_block_size")
-    ks = kc[block_tables].reshape(T, -1, Hkv, Dh).astype(q.dtype)
-    vs = vc[block_tables].reshape(T, -1, Hkv, Dh).astype(q.dtype)
+    ks = kc[layer, block_tables].reshape(T, -1, Hkv, Dh).astype(q.dtype)
+    vs = vc[layer, block_tables].reshape(T, -1, Hkv, Dh).astype(q.dtype)
     if Hkv != H:
         from deepspeed_tpu.models.llama import repeat_kv
         ks, vs = repeat_kv(ks, vs, H // Hkv)
@@ -71,8 +78,8 @@ def xla_paged_attention(q, kc, vc, block_tables, token_pos, alibi_slopes=None):
 
 def kernel_supported(head_dim, block_size, n_kv_heads=None):
     """Mosaic constraint: the per-block DMA copies a 2-D
-    ``[block_size, Hkv*Dh]`` slice (the pool's KV-head and head dims are
-    flattened before the kernel), so the lane dim is ``Hkv * head_dim``
+    ``[block_size, Hkv*Dh]`` slice (the pool stores its KV-head and head
+    dims flattened), so the lane dim is ``Hkv * head_dim``
     — a multiple of 128 for any head count when head_dim % 128 == 0, and
     the sublane dim is ``block_size`` (multiple of 8). ANY KV-head count
     is supported this way (round 4's Hkv % 8 restriction came from
@@ -86,20 +93,22 @@ def kernel_supported(head_dim, block_size, n_kv_heads=None):
 
 
 def smem_table_fits(n_tokens, max_blocks):
-    """Do the ``[n_tokens, max_blocks]`` int32 block table and the
-    ``[n_tokens]`` positions fit the kernel's SMEM budget?"""
-    return (n_tokens * max_blocks + n_tokens) * 4 <= SMEM_TABLE_BYTES
+    """Do the ``[n_tokens, max_blocks]`` int32 block table, the
+    ``[n_tokens]`` positions and the layer index fit the kernel's SMEM
+    budget?"""
+    return (n_tokens * max_blocks + n_tokens + 1) * 4 <= SMEM_TABLE_BYTES
 
 
-def _kernel(tab_ref, pos_ref, q_ref, kc_ref, vc_ref, o_ref,
+def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
             k_buf, v_buf, k_sem, v_sem, *, bs, max_blocks, groups, n_kv_heads):
-    """One token: q_ref [1, H, Dh] (VMEM); kc/vc whole pool flattened to
-    [NB, bs, Hkv*Dh] stay in HBM (ANY) — each table block is DMA'd into
-    the VMEM scratch buffers as a 2-D [bs, Hkv*Dh] slice (lane dim a
-    128-multiple for ANY KV-head count); tab/pos in SMEM via scalar
-    prefetch. Per-head columns are 128-aligned lane slices of the
-    buffer."""
+    """One token: q_ref [1, H, Dh] (VMEM); kc/vc, the whole pool
+    [L, NB, bs, Hkv*Dh], stay in HBM (ANY) — each table block of the
+    layer is DMA'd into the VMEM scratch buffers as a 2-D [bs, Hkv*Dh]
+    slice (lane dim a 128-multiple for ANY KV-head count); tab/pos/layer
+    in SMEM via scalar prefetch. Per-head columns are 128-aligned lane
+    slices of the buffer."""
     t = pl.program_id(0)
+    layer = layer_ref[0]
     H, Dh = q_ref.shape[1], q_ref.shape[2]
     Hkv = n_kv_heads
     G = groups
@@ -111,8 +120,8 @@ def _kernel(tab_ref, pos_ref, q_ref, kc_ref, vc_ref, o_ref,
     def block_step(i, carry):
         m, l, acc = carry  # [H, 1], [H, 1], [H, Dh]
         blk = tab_ref[t, i]
-        ck = pltpu.make_async_copy(kc_ref.at[blk], k_buf, k_sem)
-        cv = pltpu.make_async_copy(vc_ref.at[blk], v_buf, v_sem)
+        ck = pltpu.make_async_copy(kc_ref.at[layer, blk], k_buf, k_sem)
+        cv = pltpu.make_async_copy(vc_ref.at[layer, blk], v_buf, v_sem)
         ck.start()
         cv.start()
         ck.wait()
@@ -155,13 +164,13 @@ def _kernel(tab_ref, pos_ref, q_ref, kc_ref, vc_ref, o_ref,
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, kc, vc, block_tables, token_pos, interpret=None):
+def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=None):
     """Pallas path of :func:`xla_paged_attention` (same contract)."""
     if interpret is None:
         from deepspeed_tpu.ops.pallas import default_interpret
         interpret = default_interpret()
     T, H, Dh = q.shape
-    NB, bs, Hkv, _ = kc.shape
+    bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     MB = block_tables.shape[1]
     groups = H // Hkv
     if not interpret:
@@ -176,14 +185,14 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, interpret=None):
                 f"max_context, or raise kv_block_size")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tables, positions
+        num_scalar_prefetch=3,  # tables, positions, layer
         grid=(T,),
         in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda t, tab, pos: (t, 0, 0)),
+            pl.BlockSpec((1, H, Dh), lambda t, tab, pos, layer: (t, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, Dh), lambda t, tab, pos: (t, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, Dh), lambda t, tab, pos, layer: (t, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((bs, Hkv * Dh), kc.dtype),
             pltpu.VMEM((bs, Hkv * Dh), vc.dtype),
@@ -193,15 +202,11 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, interpret=None):
     )
     kernel = functools.partial(_kernel, bs=bs, max_blocks=MB, groups=groups,
                                n_kv_heads=Hkv)
-    # flatten [NB, bs, Hkv, Dh] → [NB, bs, Hkv*Dh]: contiguous view, and
-    # the per-block DMA slice becomes 2-D with a 128-multiple lane dim
-    # for any KV-head count
-    kc2 = kc.reshape(NB, bs, Hkv * Dh)
-    vc2 = vc.reshape(NB, bs, Hkv * Dh)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, H, Dh), q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables.astype(jnp.int32), token_pos.astype(jnp.int32), q, kc2, vc2)
+    )(block_tables.astype(jnp.int32), token_pos.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, kc, vc)

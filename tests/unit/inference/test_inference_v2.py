@@ -5,6 +5,8 @@ bookkeeping, ragged batch assembly, and — the core contract — that
 ``put`` over mixed prefill/decode ragged batches produces the same
 logits as the dense ``model.apply`` path on the flagship model."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -304,6 +306,115 @@ class TestEngineV2Correctness:
         assert engine.free_blocks == free0
         assert engine.state_manager.query(2) is None
         assert engine.state_manager.query(3) is None
+
+
+# --------------------------------------------------------------- the pool
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<type>\w+)\[(?P<dims>[\d,]*)\]"
+                        r"(?:\{[^}]*\})? (?P<op>[\w\-]+)\(")
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice", "reshape", "transpose")
+
+
+def pool_findings(hlo, pool_shape, dtype):
+    """Read a compiled program's optimised HLO text → ``(pool parameters
+    of the entry computation, those of them aliased to a result,
+    offenders)``: an offender is a ``copy`` / ``dynamic-slice`` /
+    ``dynamic-update-slice`` / ``reshape`` / ``transpose`` instruction,
+    in any computation (fused ones included), whose result has as many
+    bytes as the pool or as one layer of it. A scatter may have."""
+    item = jnp.dtype(dtype).itemsize
+    pool_bytes = int(np.prod(pool_shape)) * item
+    sizes = {pool_bytes, pool_bytes // pool_shape[0]}
+    hlo_type = {"float32": "f32", "bfloat16": "bf16"}[jnp.dtype(dtype).name]
+    want = f"{hlo_type}[{','.join(str(d) for d in pool_shape)}]"
+    entry = hlo[hlo.index("\nENTRY "):]
+    pool_params = [int(n) for n in re.findall(
+        r"= " + re.escape(want) + r"(?:\{[^}]*\})? parameter\((\d+)\)", entry)]
+    header = hlo[:hlo.index("\n")]
+    block = re.search(r"input_output_alias=\{(.*?)\}, \w+=", header)
+    aliased = {int(n) for n in re.findall(r"\((\d+), \{\}", block[1])} if block else set()
+    offenders = []
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m or m["op"] not in _MOVES or not m["dims"]:
+            continue
+        n = int(np.prod([int(d) for d in m["dims"].split(",")]))
+        width = int(re.sub(r"\D", "", m["type"]) or 8) // 8  # f32 -> 4, bf16 -> 2, pred -> 1
+        if n * width in sizes:
+            offenders.append(f"{m['name']} {m['op']} {m['type']}[{m['dims']}]")
+    return pool_params, aliased, offenders
+
+
+def capture_programs(engine, uid=71):
+    """Serve one prompt, one greedy step and a 4-step burst through the
+    engine, and → ``{program: (jitted fn, its arguments as shapes)}`` of
+    the greedy step program and the k = 4 burst as the engine ran them."""
+    seen = {}
+
+    def spy(name, fn):
+        def call(*args):
+            seen[name] = (fn, jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), args))
+            return fn(*args)
+        return call
+
+    step, make = engine._step_greedy, engine._make_burst_fn
+    engine._step_greedy = spy("greedy_step", step)
+    engine._make_burst_fn = lambda k, *a, **kw: spy(f"burst{k}", make(k, *a, **kw))
+    try:
+        prompt = (np.arange(10, dtype=np.int32) * 7) % 250
+        first = int(engine.put([uid], [prompt], sample="greedy")[0])
+        engine.decode_burst([uid], [first], 4)
+    finally:
+        engine._step_greedy, engine._make_burst_fn = step, make
+        engine._burst_fns.clear()  # the spied program is not the engine's to keep
+        engine.flush(uid)
+    return seen
+
+
+class TestPoolStaysInPlace:
+    """No serving program copies, slices, stacks or relays out the KV
+    pool: the layer scan carries it, writes it with a scatter and the
+    attention reads it by layer. Fails if a copy comes back."""
+
+    @pytest.fixture(scope="class")
+    def programs(self):
+        model = build_llama("debug")
+        params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+        # 37 blocks: no weight of the debug model has a layer's or the pool's bytes
+        cfg = RaggedInferenceEngineConfig(
+            kv_block_size=8, num_kv_blocks=37,
+            state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
+                                               max_ragged_sequence_count=4,
+                                               max_tracked_sequences=4, max_context=64))
+        engine = InferenceEngineV2(model=model, config=cfg, params=params, dtype=jnp.float32)
+        return engine.kv_cache.k.shape, capture_programs(engine)
+
+    @pytest.mark.parametrize("program", ["greedy_step", "burst4"])
+    def test_no_pool_sized_copy_in_compiled_program(self, programs, program):
+        pool_shape, seen = programs
+        fn, shapes = seen[program]
+        hlo = fn.lower(*shapes).compile().as_text()
+        pool_params, aliased, offenders = pool_findings(hlo, pool_shape, jnp.float32)
+        assert len(pool_params) == 2, pool_params
+        assert set(pool_params) <= aliased, (pool_params, aliased)
+        assert offenders == []
+
+    def test_findings_see_a_copy(self):
+        """The reader itself: a program that stacks the pool out of a
+        scan (the form this replaced) is flagged, an in-place one is not."""
+        pool = jnp.zeros((2, 37, 8, 32))
+
+        def stacked(p):
+            return jax.lax.scan(lambda c, layer: (c, layer.at[0, 0].set(1.0)), 0, p)[1]
+
+        def in_place(p):
+            return p.at[jnp.array([0, 1]), jnp.array([3, 4]), 0].set(1.0)
+
+        for fn, clean in ((stacked, False), (in_place, True)):
+            hlo = jax.jit(fn, donate_argnums=0).lower(pool).compile().as_text()
+            params, aliased, offenders = pool_findings(hlo, pool.shape, jnp.float32)
+            assert params == [0] and aliased == {0}
+            assert (offenders == []) == clean, offenders
 
 
 class TestGPTFamilyServing:

@@ -21,24 +21,24 @@ def test_registry_lists_implementations():
 def test_auto_selection_without_pallas_falls_back_to_xla(monkeypatch):
     # kernels disabled (as on the CPU backend) → gather path wins
     monkeypatch.setenv("DS_PALLAS", "0")
-    name, fn = instantiate_attn(None, 128, 16, (4, 8, 128), (8, 16, 2, 128), None,
+    name, fn = instantiate_attn(None, 128, 16, (4, 8, 128), (2, 8, 16, 2 * 128), None,
                                 max_blocks=4)
     assert name == "xla_gather" and callable(fn)
 
 
 def test_alibi_always_xla():
     alibi = jnp.ones(4)
-    name, _ = instantiate_attn(None, 128, 16, (4, 4, 128), (8, 16, 4, 128), alibi,
+    name, _ = instantiate_attn(None, 128, 16, (4, 4, 128), (2, 8, 16, 4 * 128), alibi,
                                max_blocks=4)
     assert name == "xla_gather"
 
 
 def test_override_pins_implementation():
-    name, _ = instantiate_attn(None, 128, 16, (4, 8, 128), (8, 16, 2, 128), None,
+    name, _ = instantiate_attn(None, 128, 16, (4, 8, 128), (2, 8, 16, 2 * 128), None,
                                max_blocks=4, override="xla_gather")
     assert name == "xla_gather"
     with pytest.raises(ValueError, match="no attention implementation"):
-        instantiate_attn(None, 128, 16, (4, 8, 128), (8, 16, 2, 128), None,
+        instantiate_attn(None, 128, 16, (4, 8, 128), (2, 8, 16, 2 * 128), None,
                          max_blocks=4, override="nonexistent")
 
 
@@ -48,12 +48,12 @@ def test_pinned_kernel_raises_instead_of_degrading(monkeypatch):
     Mosaic on this backend, and (kernels forced on) a block table past
     the SMEM budget — which ``supports()`` sees, so an unpinned engine
     visibly selects ``xla_gather`` there."""
-    args = (None, 128, 16, (4, 8, 128), (8, 16, 2, 128), None)
+    args = (None, 128, 16, (4, 8, 128), (2, 8, 16, 2 * 128), None)
     with pytest.raises(ValueError, match="pinned attention='pallas_paged'.*backend='cpu'"):
         instantiate_attn(*args, max_blocks=4, override="pallas_paged")
     monkeypatch.setenv("DS_PALLAS", "1")
     assert instantiate_attn(*args, max_blocks=4)[0] == "pallas_paged"
-    wide = (None, 128, 16, (768, 8, 128), (8, 16, 2, 128), None)
+    wide = (None, 128, 16, (768, 8, 128), (2, 8, 16, 2 * 128), None)
     assert instantiate_attn(*wide, max_blocks=512)[0] == "xla_gather"
     with pytest.raises(ValueError, match="tokens=768, max_blocks=512"):
         instantiate_attn(*wide, max_blocks=512, override="pallas_paged")
@@ -64,10 +64,10 @@ def test_kernel_entry_refuses_rather_than_falls_back():
     take, the kernel entry raises — it used to return the reference."""
     from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
     q = jnp.zeros((4, 4, 64))
-    kc = jnp.zeros((8, 16, 2, 64))
+    kc = jnp.zeros((1, 8, 16, 2 * 64))
     tab = jnp.zeros((4, 2), jnp.int32)
     with pytest.raises(ValueError, match="head_dim % 128"):
-        paged_decode_attention(q, kc, kc, tab, jnp.zeros(4, jnp.int32), interpret=False)
+        paged_decode_attention(q, kc, kc, tab, jnp.zeros(4, jnp.int32), 0, interpret=False)
 
 
 def test_engine_config_override_serves_correctly():

@@ -72,10 +72,12 @@ def test_llama_tp_kv_pool_actually_sharded():
     model = build_llama("debug", remat=False)
     engine = InferenceEngineV2(model=model, config=_cfg(tensor_parallel_degree=2),
                                params=_params(model), dtype=jnp.float32)
-    # KV pool [L, NB, bs, Hkv=2, Dh] sharded over 'tensor' on the head dim
+    # KV pool [L, NB, bs, Hkv*Dh] sharded over 'tensor' on the flattened head dim
+    # (Hkv=2: one whole KV head a shard)
     assert len(engine.kv_cache.k.sharding.device_set) == 2
     spec = engine.kv_cache.k.sharding.spec
     assert spec[3] == "tensor"
+    assert engine.kv_cache.k.addressable_shards[0].data.shape[3] == engine.kv_cache.head_dim
     # q_proj kernel column-sharded, o_proj row-sharded
     qk = engine.params["model"]["layers"]["self_attn"]["q_proj"]["kernel"]
     ok = engine.params["model"]["layers"]["self_attn"]["o_proj"]["kernel"]

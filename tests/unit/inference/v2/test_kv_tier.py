@@ -96,8 +96,9 @@ def fill_blocks(cache, blocks):
     k = rng.standard_normal(shape).astype(np.float32)
     v = rng.standard_normal(shape).astype(np.float32)
     ids = jnp.asarray(blocks)
-    cache.k = cache.k.at[:, ids].set(jnp.asarray(k))
-    cache.v = cache.v.at[:, ids].set(jnp.asarray(v))
+    flat = shape[:3] + (-1,)  # the pool stores [L, NB, bs, Hkv*Dh]
+    cache.k = cache.k.at[:, ids].set(jnp.asarray(k).reshape(flat))
+    cache.v = cache.v.at[:, ids].set(jnp.asarray(v).reshape(flat))
     return {"k": k, "v": v}
 
 
@@ -252,6 +253,31 @@ class TestPoolOffloadRestore:
         np.testing.assert_array_equal(got["v"], np.asarray(host["v"]))
         bound = np.abs(orig["k"]).max() / 127.0 / 2.0 + 1e-5
         assert np.abs(got["k"] - orig["k"]).max() <= bound
+
+    @pytest.mark.parametrize("quantized", [False, True])
+    def test_handles_keep_the_wire_shape_from_the_flattened_pool(self, quantized):
+        """The pool is stored ``[L, NB, bs, Hkv*Dh]``; what leaves and
+        enters it stays ``[L, n, bs, Hkv, Dh]`` — plain and int8 alike —
+        and a round trip through either lands in the flattened pool."""
+        cache = small_pool()
+        assert cache.k.shape == (2, 10, 4, 2 * 4)
+        blocks = cache.reserve(3)
+        orig = fill_blocks(cache, blocks)
+        handle = cache.gather(blocks)
+        assert handle["k"].shape == handle["v"].shape == (2, 3, 4, 2, 4)
+        if quantized:
+            handle = quantize_handle(handle)
+            assert handle["k"].shape == (2, 3, 4, 2, 4) and handle["k"].dtype == np.int8
+            assert handle["k_scales"].shape == (2, 3, 1)
+        new = cache.restore(handle)
+        assert cache.k.shape == cache.v.shape == (2, 10, 4, 2 * 4)
+        got = cache.gather(new)
+        assert got["k"].shape == (2, 3, 4, 2, 4)
+        want = dequantize_handle(handle, jnp.float32) if quantized else orig
+        np.testing.assert_array_equal(got["k"], np.asarray(want["k"]))
+        np.testing.assert_array_equal(got["v"], np.asarray(want["v"]))
+        with pytest.raises(KVCacheHandleError, match=r"n_kv_heads=2, head_dim=4"):
+            cache.restore({"k": got["k"].reshape(2, 3, 4, 8), "v": got["v"].reshape(2, 3, 4, 8)})
 
     def test_validate_rejects_malformed_quantized_handles(self):
         cache = small_pool()

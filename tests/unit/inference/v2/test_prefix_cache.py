@@ -425,3 +425,21 @@ class TestAllocatorAndHandles:
         assert len(blocks) == 2
         with pytest.raises(KVCacheHandleError, match="invalid block id"):
             cache.offload([99])
+
+    def test_offload_restore_keep_wire_shape_from_flattened_pool(self):
+        """The prefix cache's suspend path copies blocks through
+        ``offload(keep=...)`` / ``restore``: the handle is 5-D
+        ``[L, n, bs, Hkv, Dh]`` as before although the pool is stored
+        ``[L, NB, bs, Hkv*Dh]``, and the kept block is not freed."""
+        cache = BlockedKVCache(2, 8, 4, 2, 4, dtype=jnp.float32)
+        assert cache.k.shape == (2, 8, 4, 8)
+        blocks = [int(b) for b in cache.reserve(3)]
+        rows = np.arange(2 * 3 * 4 * 8, dtype=np.float32).reshape(2, 3, 4, 8)
+        cache.k = cache.k.at[:, jnp.asarray(blocks)].set(rows)
+        handle = cache.offload(blocks, keep=blocks[:1])
+        assert handle["k"].shape == handle["v"].shape == (2, 3, 4, 2, 4)
+        np.testing.assert_array_equal(handle["k"].reshape(2, 3, 4, 8), rows)
+        assert cache.free_blocks == 8 - 1 - 1  # the null block and the kept one stay owned
+        back = cache.restore(handle)
+        assert cache.k.shape == (2, 8, 4, 8)
+        np.testing.assert_array_equal(cache.gather(back)["k"], handle["k"])
