@@ -72,6 +72,21 @@ def rel_err(got, want):
     return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
 
 
+def reference_sample(config, seed):
+    """The seeded sequences of the reference check → (the sequences, all
+    of them in one zero-padded batch for the reference: attention is
+    causal, so what follows a sequence's last token does not reach its
+    logits)."""
+    rng = np.random.default_rng(seed)
+    vocab = config["model"]["vocab_size"]
+    seqs = [rng.integers(0, vocab, n, dtype=np.int32)
+            for n in config["reference"]["sample_lengths"]]
+    padded = np.zeros((len(seqs), max(len(s) for s in seqs)), np.int32)
+    for i, s in enumerate(seqs):
+        padded[i, :len(s)] = s
+    return seqs, padded
+
+
 def reference_check(engine, config, seed):
     """Prefill, then one decode step through the cache, for a seeded
     sample of sequences in one ragged batch, against the float32
@@ -79,14 +94,7 @@ def reference_check(engine, config, seed):
     with random weights the largest logit changes on rounding."""
     import jax.numpy as jnp
     ref = config["reference"]
-    rng = np.random.default_rng(seed)
-    vocab = config["model"]["vocab_size"]
-    seqs = [rng.integers(0, vocab, n, dtype=np.int32) for n in ref["sample_lengths"]]
-    # one padded batch through the reference: attention is causal, so what
-    # follows a sequence's last token does not reach its logits
-    padded = np.zeros((len(seqs), max(len(s) for s in seqs)), np.int32)
-    for i, s in enumerate(seqs):
-        padded[i, :len(s)] = s
+    seqs, padded = reference_sample(config, seed)
     full = np.asarray(reference.logits(engine.params, jnp.asarray(padded), config["model"]))
     want = [full[i, :len(s)] for i, s in enumerate(seqs)]
     uids = [-(i + 1) for i in range(len(seqs))]
@@ -338,9 +346,10 @@ def run(ctx):
     ttft = [(f.first - f.due) * 1e3 for f in measured if f.first is not None] if is_open else []
     ended_inside = [f for f in client.done
                     if f.ended is not None and client.in_window(f.ended) and f.error is None]
-    tpot = [(f.last - f.first) * 1e3 / (f.tokens - 1) for f in ended_inside if f.tokens >= 2]
-    decode_s = sum(f.last - f.first for f in ended_inside if f.tokens >= 2)
-    decode_n = sum(f.tokens - 1 for f in ended_inside if f.tokens >= 2)
+    timed = [f for f in ended_inside if f.tokens >= 2]
+    tpot = [(f.last - f.first) * 1e3 / (f.tokens - 1) for f in timed]
+    decode_s = sum(f.last - f.first for f in timed)
+    decode_n = sum(f.tokens - 1 for f in timed)
     tpot_mean = decode_s * 1e3 / decode_n if decode_n else None
     late = [(f.sent - f.due) * 1e3 for f in measured] if is_open else []
     waits = [(counts.first_step_at[f.handle.uid] - f.due) * 1e3 for f in measured
@@ -369,11 +378,18 @@ def run(ctx):
         "ttft_by_due": [[round(f.due - t_open, 3),
                          None if f.first is None else round((f.first - f.due) * 1e3, 1),
                          f.prompt_len] for f in measured] if is_open else [],
+        # every request behind tpot_mean_ms and tpot_req_p90_ms (pre-roll arrivals that
+        # ended in the window too, so a due before 0): due, prompt length, tokens received,
+        # TPOT in ms as measured - tpot_req_p90_ms is percentile(column 3, 90), tpot_mean_ms
+        # is sum(column 3 x (column 2 - 1)) / sum(column 2 - 1)
+        "tpot_by_request": [[round(f.due - t_open, 3), f.prompt_len, f.tokens, t]
+                            for f, t in zip(timed, tpot)],
     }
     observed = {
         "setup_s": setup_s,
         "ttft_p90_ms": percentile(ttft, 90),
         "ttft_p50_ms": percentile(ttft, 50),
+        "tpot_mean_ms": tpot_mean,
         "tpot_p90_ms": percentile(tpot, 90),
         "serve_tok_s": window_tokens(client) / seconds,
         "gen_late_p99_ms": percentile(late, 99),

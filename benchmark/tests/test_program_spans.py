@@ -174,7 +174,7 @@ def test_readers_on_a_ring_written_by_the_program(recorded, monkeypatch):
 def test_a_traced_rehearsal_reports_the_new_metrics_that_need_no_device(tmp_path):
     """``--rehearse --trace 1`` of the chat cell on the CPU: the program's
     own records, joined to the CPU profile's ``bench.*`` host spans."""
-    out = run_cell(rehearsal_root(tmp_path), "mistral7b-chat", "--rehearse", "--trace", "1",
+    out = run_cell(rehearsal_root(tmp_path), "mistral7b-chat-r2", "--rehearse", "--trace", "1",
                    "--seconds", "8")
     assert out.returncode == 0, out.stderr[-2000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
