@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
-from deepspeed_tpu.inference.v2.model_runner import ragged_forward
+from deepspeed_tpu.inference.v2.model_runner import kind_of, ragged_forward
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
@@ -47,6 +47,12 @@ def async_burst_enabled(config) -> bool:
     if forced is not None:
         return forced
     return bool(getattr(config, "enabled", False))
+
+
+def _burst_ctx_tokens(seen, k):
+    """Context positions one row attends over a burst of ``k`` steps that
+    starts with ``seen`` tokens cached: step ``j`` attends ``seen + j + 1``."""
+    return k * seen + k * (k + 1) // 2
 
 
 def _burst_layout(ms, mb, lora=False, sampled=False, async_entry=False):
@@ -178,9 +184,12 @@ class InferenceEngineV2:
 
     def __init__(self, model=None, config: RaggedInferenceEngineConfig = None,
                  params=None, model_config=None, dtype=jnp.bfloat16, rng=None):
-        """``model``: a ``LlamaForCausalLM`` (its scan-stacked params are
+        """``model``: a ``LlamaForCausalLM``, ``GPTForCausalLM`` or
+        ``MoonlightForCausalLM`` (its scan-stacked params are
         initialized here when ``params`` is not given), or pass
-        ``params`` + ``model_config`` directly."""
+        ``params`` + ``model_config`` directly. The config's type picks
+        the model kind (``model_runner.kind_of``), which says what state
+        the paged pool holds and how a layer steps."""
         self._config = config or RaggedInferenceEngineConfig()
         sm = self._config.state_manager
         self.dtype = dtype
@@ -189,6 +198,7 @@ class InferenceEngineV2:
             model_config = model.config
         self.model_config = model_config
         cfg = self.model_config
+        kind = self.kind = kind_of(cfg)
         # Serving mesh (reference engine_v2.py:30 builds the model over its
         # TP group via model_implementations/sharding/): tensor- and, for
         # MoE, expert-parallel. Params/KV-pool are placed sharded so models
@@ -214,6 +224,8 @@ class InferenceEngineV2:
         qmode = getattr(self._config.quantization, "quantization_mode", "none")
         self._qmode = qmode
         self._quantized = bool(qmode and qmode != "none")
+        if kind.state_kind != "kv":
+            self._refuse_unsupported(kind, tp * ep)
         if params is not None:
             owns = all(not isinstance(leaf, jax.Array) for leaf in jax.tree.leaves(params))
             self.params = self._place_params(params, owns)
@@ -239,9 +251,8 @@ class InferenceEngineV2:
             # at 8 GB PER POOL SHARD (the pool shards whole KV heads over
             # the 'tensor' axis when divisible) with a warning; an explicit
             # num_kv_blocks is honored as given.
-            bytes_per_block = (2 * cfg.num_hidden_layers * self.block_size *
-                               cfg.num_key_value_heads * cfg.head_dim *
-                               jnp.dtype(dtype).itemsize)
+            bytes_per_block = (cfg.num_hidden_layers * self.block_size *
+                               sum(kind.state_rows(cfg)) * jnp.dtype(dtype).itemsize)
             pool_shards = 1
             if self.mesh is not None:
                 tp_size = dict(self.mesh.shape).get("tensor", 1)
@@ -261,8 +272,12 @@ class InferenceEngineV2:
             from deepspeed_tpu.inference.v2.sharding import kv_pool_spec
             pool = NamedSharding(self.mesh, kv_pool_spec(self.mesh, cfg.num_key_value_heads))
         self.kv_cache = BlockedKVCache(cfg.num_hidden_layers, num_blocks, self.block_size,
-                                       cfg.num_key_value_heads, cfg.head_dim, dtype=dtype,
-                                       sharding=pool)
+                                       cfg.num_key_value_heads, getattr(cfg, "head_dim", None),
+                                       dtype=dtype, sharding=pool, state_kind=kind.state_kind,
+                                       row_widths=kind.state_rows(cfg))
+        # what the pool holds, for whoever sizes or reads it (docs/OBSERVABILITY.md)
+        self.state_kind = kind.state_kind
+        self.state_bytes_per_token = self.kv_cache.bytes_per_token()
         self.state_manager = DSStateManager(self.kv_cache, int(sm.max_tracked_sequences))
         # Radix prefix cache (cross-request KV reuse): config-gated with
         # the DS_PREFIX_CACHE env kill switch. When live, retired
@@ -321,7 +336,7 @@ class InferenceEngineV2:
                                                 lora_serving_enabled)
         self.lora_store = None
         if lora_serving_enabled(self._config.lora):
-            if hasattr(cfg, "position_embedding"):
+            if not kind.lora:
                 logger.warning(
                     "lora serving enabled but the model is GPT-family — "
                     "the segmented adapter path targets the Llama layer "
@@ -516,6 +531,32 @@ class InferenceEngineV2:
                     f"kv_bytes={self.kv_cache.bytes()/1e6:.1f}MB")
 
     # ------------------------------------------------------------------
+    def _refuse_unsupported(self, kind, n_devices):
+        """A model kind whose state is not keys and values is served by
+        the core path only: every optional subsystem that reads, moves or
+        shards the two KV pools refuses it here, at construction and by
+        name, instead of failing inside a program."""
+        from deepspeed_tpu.inference.v2.kv_tier import kv_tier_enabled
+        from deepspeed_tpu.inference.v2.prefix_cache import prefix_cache_enabled
+        from deepspeed_tpu.inference.v2.spec import spec_decode_enabled
+        from deepspeed_tpu.serving.lora import lora_serving_enabled
+        c = self._config
+        asked = {
+            "prefix cache": prefix_cache_enabled(c.prefix_cache),
+            "KV tier (and the disaggregated handoff, which exports through it)":
+                kv_tier_enabled(c.kv_tier),
+            "speculative decoding": spec_decode_enabled(c.spec_decode),
+            "LoRA serving": lora_serving_enabled(c.lora),
+            "weight-only quantization": self._quantized,
+            "tensor/expert-parallel sharding": n_devices > 1,
+        }
+        for subsystem, on in asked.items():
+            if on:
+                raise NotImplementedError(
+                    f"{subsystem} does not support the {kind.state_kind!r} paged state of "
+                    f"model kind {kind.name!r} ({type(self.model_config).__name__}); "
+                    f"turn it off to serve this model")
+
     def _init_params(self, model, rng):
         """Random-initialize ``model`` straight into serving placement:
         one jitted program whose outputs are already cast to the serving
@@ -688,6 +729,7 @@ class InferenceEngineV2:
                     self.state_manager.allocate_for(desc, len(tokens))
                     self._batch.insert_sequence(desc, tokens)
                     desc.advance(len(tokens))
+                    rec.n_ctx_tokens += desc.seen_tokens
                     if self._log_tokens:
                         # content log: retire-time insertion into the prefix
                         # trie, and the n-gram drafter's lookup corpus. A host
@@ -969,6 +1011,7 @@ class InferenceEngineV2:
                     pos0[i] = desc.seen_tokens
                     tables[i, :len(desc.blocks)] = desc.blocks
                     desc.advance(k)
+                    rec.n_ctx_tokens += _burst_ctx_tokens(int(pos0[i]), k)
                 parts = [tokens0, token_seq, pos0, tables.ravel()]
                 if lora_on:
                     parts.append(adapters)
@@ -1095,6 +1138,7 @@ class InferenceEngineV2:
                     pos0[i] = desc.seen_tokens
                     tables[i, :len(desc.blocks)] = desc.blocks
                     desc.advance(k)
+                    rec.n_ctx_tokens += _burst_ctx_tokens(int(pos0[i]), k)
                 parts = [token_seq, pos0, tables.ravel()]
                 if lora_on:
                     parts.append(adapters)
@@ -1407,6 +1451,7 @@ class InferenceEngineV2:
                     token_seq[i] = i
                     pos0[i] = desc.seen_tokens
                     tables[i, :len(desc.blocks)] = desc.blocks
+                    rec.n_ctx_tokens += desc.seen_tokens + d + 1
                 parts = [toks.ravel(), dlen, token_seq, pos0, tables.ravel()]
                 if lora_on:
                     parts.append(adapters)
@@ -1738,6 +1783,10 @@ class InferenceEngineV2:
         BlockedKVCache declares but leaves NotImplementedError,
         kv_cache.py:166 — vLLM-style swapping). The sequence stops being
         tracked until :meth:`resume`."""
+        if self.state_kind != "kv":
+            raise NotImplementedError(
+                f"suspend/resume export does not support the {self.state_kind!r} paged state "
+                f"of model kind {self.kind.name!r}")
         desc = self.state_manager.query(uid)
         if desc is None:
             raise KeyError(f"unknown sequence {uid}")

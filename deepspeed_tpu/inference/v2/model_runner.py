@@ -20,7 +20,12 @@ consumes the padded flat batch —
   (the scan's ``xs``: what is read-only per layer), so any
   ``LlamaForCausalLM`` (Llama/Mistral/Mixtral/Qwen2) or
   ``GPTForCausalLM`` (GPT-2/J/NeoX, OPT, Bloom, Falcon, Phi) checkpoint
-  serves directly.
+  serves directly;
+- what differs between model families sits in one object each
+  (:class:`LlamaKind`, :class:`GPTKind`, :class:`MoonlightKind`;
+  :func:`kind_of` picks by the config's type): the state the pool holds,
+  the layer step, the layer pattern (leading layers, then the scan) and
+  the final norm. :func:`ragged_forward` is the same for all.
 """
 
 import functools
@@ -295,41 +300,61 @@ def _gpt_layer_step(cfg, cos, sin, alibi, batch, mesh, attn_impl, carry, xs):
     return (h, kc, vc), None
 
 
-def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=None,
-                   attn_impl=None, lora=None):
-    """→ (last-token logits [max_seqs, vocab] fp32, kcache, vcache).
+class LlamaKind:
+    """What the ragged engine asks of a model family: the per-layer state
+    it keeps in the paged pool (``state_kind``, :meth:`state_rows`), its
+    layer step and its layer pattern (:meth:`layers`: the leading layers,
+    run one by one, then the step and ``xs`` of the layer scan), and its
+    final norm. One object per family; :func:`kind_of` picks it from the
+    config's type. This one is the Llama family (Llama, Mistral, Mixtral,
+    Qwen2, InternLM, Gemma): keys and values, one scan over identical
+    blocks."""
+    name = "llama"
+    state_kind = "kv"       # two pools of expanded keys and values, [L, NB, bs, Hkv*Dh]
+    lora = True
 
-    ``kcache``/``vcache``: the pool [L, NB, bs, Hkv*Dh], carried through
-    the layer scan and written in place (donate them); ``batch``: the
-    arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is a ``LlamaConfig``
-    or ``GPTConfig``; the layer wiring follows it. ``mesh``: an optional
-    serving mesh — params/KV arrive sharded per
-    ``inference/v2/sharding.py`` and the step pins the Megatron layout
-    (replicated tokens, head/feature-sharded projections) so GSPMD
-    inserts the TP all-reduces.
+    @staticmethod
+    def state_rows(cfg):
+        """→ the row widths of the engine's two pools (values a token a layer)."""
+        width = cfg.num_key_value_heads * cfg.head_dim
+        return width, width
 
-    ``lora``: None (the exact pre-LoRA program) or
-    ``(a, b, scales, seq_adapters, impl)`` — per-site stacked hot slabs
-    ``a[site] [L, S, in, r]`` / ``b[site] [L, S, r, out]``, per-slot
-    ``scales [S]``, the batch's per-sequence adapter slots
-    ``seq_adapters [max_seqs + 1]`` (pad row = slot 0 = base), and the
-    static kernel impl selector. Llama-family layers only.
+    @staticmethod
+    def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
+        """→ (h, leading [(step, xs), ...], scan step, scan xs)."""
+        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta,
+                                    scaling=rope_scaling_of(cfg))
+        cos, sin = jnp.asarray(cos), jnp.asarray(sin)
+        lora_ctx = None
+        xs = (layer_ids, params["model"]["layers"])
+        if lora is not None:
+            la, lb, scales, seq_adapters, lora_impl = lora
+            # per-token adapter slot: pad tokens hit the pad row, which
+            # carries slot 0 (base) by construction
+            slots = seq_adapters[batch["token_seq"]]
+            lora_ctx = (slots, scales, lora_impl)
+            xs = (layer_ids, params["model"]["layers"], la, lb)
+        step = functools.partial(_layer_step, cfg, cos, sin, batch, mesh, attn_impl,
+                                 lora_ctx)
+        return h, (), step, xs
 
-    ``attn_impl``: the engine's ``heuristics.AttentionChoice`` (None =
-    unpinned and unreported)."""
-    is_gpt = hasattr(cfg, "position_embedding")
-    embed = params["model"]["embed_tokens"]
-    h = _c(embed[batch["token_ids"]].astype(dtype), (None, None), mesh)  # [T, D]
-    mult = getattr(cfg, "embedding_multiplier", 1.0)
-    if mult != 1.0:  # Gemma: sqrt(hidden_size)
-        h = h * jnp.asarray(mult, h.dtype)
+    @staticmethod
+    def final_norm(params, cfg, h):
+        return _rms(h, params["model"]["norm"]["scale"], cfg.rms_norm_eps)
 
-    layer_ids = jnp.arange(kcache.shape[0], dtype=jnp.int32)
-    if lora is not None and is_gpt:
-        raise NotImplementedError(
-            "multi-tenant LoRA serving targets the Llama-family layer "
-            "stack; GPT-family models serve base-only")
-    if is_gpt:
+
+class GPTKind(LlamaKind):
+    """The GPT family (GPT-2/J/NeoX, OPT, Bloom, Falcon, Phi): the same
+    state, its own block and position schemes."""
+    name = "gpt"
+    lora = False
+
+    @staticmethod
+    def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
+        if lora is not None:
+            raise NotImplementedError(
+                "multi-tenant LoRA serving targets the Llama-family layer "
+                "stack; GPT-family models serve base-only")
         cos = sin = None
         if cfg.position_embedding == "rope" and cfg.rotary_dim > 0:
             cos, sin = rope_frequencies(cfg.rotary_dim, cfg.max_position_embeddings,
@@ -346,32 +371,217 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
             h = _layernorm(h, params["model"]["embed_layernorm"], cfg.layer_norm_eps)
         step = functools.partial(_gpt_layer_step, cfg, cos, sin, alibi, batch, mesh,
                                  attn_impl)
-        xs = (layer_ids, params["model"]["layers"])
-    else:
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta,
-                                    scaling=rope_scaling_of(cfg))
-        cos, sin = jnp.asarray(cos), jnp.asarray(sin)
-        lora_ctx = None
-        xs = (layer_ids, params["model"]["layers"])
-        if lora is not None:
-            la, lb, scales, seq_adapters, lora_impl = lora
-            # per-token adapter slot: pad tokens hit the pad row, which
-            # carries slot 0 (base) by construction
-            slots = seq_adapters[batch["token_seq"]]
-            lora_ctx = (slots, scales, lora_impl)
-            xs = (layer_ids, params["model"]["layers"], la, lb)
-        step = functools.partial(_layer_step, cfg, cos, sin, batch, mesh, attn_impl,
-                                 lora_ctx)
+        return h, (), step, (layer_ids, params["model"]["layers"])
 
-    (h, kc, vc), _ = jax.lax.scan(step, (h, kcache, vcache), xs)
-
-    if is_gpt:
+    @staticmethod
+    def final_norm(params, cfg, h):
         if cfg.norm_type == "layernorm":
-            h = _layernorm(h, params["model"]["final_layernorm"], cfg.layer_norm_eps)
-        else:
-            h = _rms(h, params["model"]["final_norm"]["scale"], cfg.layer_norm_eps)
+            return _layernorm(h, params["model"]["final_layernorm"], cfg.layer_norm_eps)
+        return _rms(h, params["model"]["final_norm"]["scale"], cfg.layer_norm_eps)
+
+
+LATENT_ROPE_LANES = 128     # the rotated key's row, padded to one lane tile
+
+
+class MoonlightKind:
+    """Moonlight / DeepSeek-V3 (``models/moonlight.py``): a **latent**
+    state and ``dense x first_k_dense_replace, moe x (L - that)``.
+
+    Per token and layer the pool holds ``kv_a_layernorm(c_kv)``
+    (``kv_lora_rank`` values) in the engine's first pool and the
+    **rotated** ``k_rope`` (``qk_rope_head_dim`` values, zero-padded to
+    one 128-lane tile, which is what Mosaic's block DMA and the HBM
+    tiling would make of a 64-wide row anyway) in its second: 512 + 128
+    = 640 values = 1280 B a token a layer in bf16 for Moonlight, against
+    8192 B for its keys and values expanded. Never expanded keys or
+    values: ``kv_b_proj`` is absorbed, its key half into the query and
+    its value half after the attention."""
+    name = "moonlight"
+    state_kind = "latent"
+    lora = False
+
+    @staticmethod
+    def state_rows(cfg):
+        return cfg.kv_lora_rank, -(-cfg.qk_rope_head_dim // LATENT_ROPE_LANES) * LATENT_ROPE_LANES
+
+    @staticmethod
+    def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
+        if lora is not None or mesh is not None:
+            raise NotImplementedError("the Moonlight layer stack serves base-only on one device")
+        cos, sin = rope_frequencies(cfg.qk_rope_head_dim, cfg.max_position_embeddings,
+                                    cfg.rope_theta)
+        cos, sin = jnp.asarray(cos), jnp.asarray(sin)
+        n_dense = cfg.first_k_dense_replace
+        layers = params["model"]["layers"]
+        # The routed experts ride the step whole [layers, experts, in, out] and are no
+        # part of the scan's xs: _moonlight_moe says how a layer reaches its own.
+        experts = layers["mlp"]["experts"]
+        sliced = {**layers, "mlp": {k: v for k, v in layers["mlp"].items() if k != "experts"}}
+        step = functools.partial(_moonlight_layer_step, cfg, cos, sin, batch, attn_impl, experts)
+        dense = params["model"]["dense_layers"]
+        lead = [(step, (layer_ids[i], jax.tree.map(lambda x, i=i: x[i], dense)))
+                for i in range(n_dense)]
+        return h, lead, step, (layer_ids[n_dense:], sliced)
+
+    final_norm = LlamaKind.final_norm
+
+
+def kind_of(cfg):
+    """The model kind of a config, by its type."""
+    from deepspeed_tpu.models.moonlight import MoonlightConfig
+    if isinstance(cfg, MoonlightConfig):
+        return MoonlightKind
+    return GPTKind if hasattr(cfg, "position_embedding") else LlamaKind
+
+
+def _rope_deinterleaved(x, cos, sin, positions):
+    """``modeling_deepseek.apply_rotary_pos_emb``: the pairs (2i, 2i+1) of
+    the last dim go to (i, i + d/2), then rotate by halves."""
+    T, H, d = x.shape
+    x = x.reshape(T, H, d // 2, 2).swapaxes(-1, -2).reshape(T, H, d)
+    return _rope_flat(x, cos, sin, positions)
+
+
+def _latent_attend(q_lat, q_rope, c_kv, k_rope, kc, vc, layer, batch, scale, impl):
+    """Scatter layer ``layer``'s new latent rows into the two pools (in
+    place, as :func:`_paged_attend` does) and attend with absorbed
+    weights: per head ``softmax((q_lat . c + q_rope . k_rope) * scale) @
+    c`` over the token's block-tabled context, every head reading the
+    same rows. → (o_lat [T, H, rank], kc, vc)."""
+    bs, rank, lanes = kc.shape[2], kc.shape[3], vc.shape[3]
+    T, H = q_lat.shape[:2]
+    blk = batch["block_tables"][batch["token_seq"], batch["token_pos"] // bs]
+    off = batch["token_pos"] % bs
+    kc = kc.at[layer, blk, off].set(c_kv.astype(kc.dtype))
+    pad = jnp.zeros((T, lanes - k_rope.shape[-1]), vc.dtype)
+    vc = vc.at[layer, blk, off].set(jnp.concatenate([k_rope.astype(vc.dtype), pad], axis=-1))
+
+    from deepspeed_tpu.inference.v2.modules.heuristics import instantiate_attn
+    tab = batch["block_tables"][batch["token_seq"]]
+    # one query row per head over the whole pooled row; pre-scaled, so that no
+    # implementation has to know the 192 of the softmax scale
+    q = jnp.concatenate([q_lat, q_rope, jnp.zeros((T, H, lanes - q_rope.shape[-1]), q_lat.dtype)],
+                        axis=-1) * jnp.asarray(scale, q_lat.dtype)
+    name, attn_fn = instantiate_attn(None, rank, bs, q.shape, kc.shape, None,
+                                     max_blocks=tab.shape[1],
+                                     override=impl.override if impl else None,
+                                     state_kind="latent")
+    if impl is not None:
+        impl.selected[T] = name
+    return attn_fn(q, kc, vc, tab, batch["token_pos"], layer), kc, vc
+
+
+def _swiglu(x, p):
+    return _proj(jax.nn.silu(_proj(x, p["gate_proj"])) * _proj(x, p["up_proj"]), p["down_proj"])
+
+
+def _moonlight_moe(x, p, experts, layer, cfg):
+    """``noaux_tc`` routing in float32: sigmoid scores; the top k are
+    chosen on ``score + e_score_correction_bias`` and weighted by the
+    unbiased scores, normalised, times ``routed_scaling_factor``. Routed
+    part through the dropless grouped GEMM, shared experts on every token.
+    ``experts``: ``{gate,up,down}_proj [Lm, E, in, out]`` of all expert
+    layers, ``layer`` this one's index among them: the grouped GEMM takes
+    the stack as one table of ``Lm x E`` groups and this layer's first
+    group, and chooses its dispatch on ``E`` (``ragged_dot`` takes its
+    weights as one buffer, so a layer's experts cut out of the stack
+    would be copied first, every step: 1.1 GB a layer for Moonlight, as
+    long as the three matmuls themselves — PERF.md, PR 28; ROADMAP S2 for
+    Mixtral)."""
+    from deepspeed_tpu.ops.grouped_gemm import dropless_moe_ffn
+    with jax.named_scope("ds.moe_routed"):
+        scores = jax.nn.sigmoid(x.astype(jnp.float32) @ p["gate"]["weight"].astype(jnp.float32))
+        biased = scores + p["gate"]["e_score_correction_bias"].astype(jnp.float32)
+        _, topk_idx = jax.lax.top_k(biased, cfg.num_experts_per_tok)
+        topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1)
+        if cfg.norm_topk_prob:
+            topk_vals = topk_vals / (topk_vals.sum(-1, keepdims=True) + 1e-20)
+        topk_vals = topk_vals * cfg.routed_scaling_factor
+        table = jax.tree.map(lambda w: w.reshape((-1,) + w.shape[2:]), experts)
+        routed = dropless_moe_ffn(x, topk_idx, topk_vals, table["gate_proj"], table["up_proj"],
+                                  table["down_proj"], num_experts=cfg.n_routed_experts,
+                                  widen_boundary=False,
+                                  first_group=layer * cfg.n_routed_experts)
+    with jax.named_scope("ds.moe_shared"):
+        return routed + _swiglu(x, p["shared_experts"])
+
+
+def _moonlight_layer_step(cfg, cos, sin, batch, attn_impl, experts, carry, xs):
+    """One Moonlight layer over the flat ragged batch: latent attention
+    with ``kv_b_proj`` absorbed, then the dense SwiGLU (a leading layer)
+    or the experts (a scanned layer) — the layer's own params say which.
+    ``experts``: every expert layer's routed experts, stacked and whole
+    (:meth:`MoonlightKind.layers`)."""
+    h, kc, vc = carry
+    layer, lp = xs
+    T = h.shape[0]
+    H, r = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    attn = lp["self_attn"]
+
+    with jax.named_scope("ds.mla"):
+        hn = _rms(h, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
+        q = _proj(hn, attn["q_proj"]).reshape(T, H, dn + dr)
+        kv_a = _proj(hn, attn["kv_a_proj_with_mqa"])                       # [T, r + dr]
+        c_kv = _rms(kv_a[:, :r], attn["kv_a_layernorm"]["scale"], cfg.rms_norm_eps)
+        pos = batch["token_pos"]
+        q_rope = _rope_deinterleaved(q[..., dn:], cos, sin, pos)
+        k_rope = _rope_deinterleaved(kv_a[:, None, r:], cos, sin, pos)[:, 0]
+        w_kv = attn["kv_b_proj"]["kernel"].reshape(r, H, dn + dv)
+        q_lat = jnp.einsum("thd,rhd->thr", q[..., :dn], w_kv[..., :dn])    # W_UK absorbed
+        o_lat, kc, vc = _latent_attend(q_lat, q_rope, c_kv, k_rope, kc, vc, layer, batch,
+                                       1.0 / math.sqrt(dn + dr), attn_impl)
+        out = jnp.einsum("thr,rhv->thv", o_lat, w_kv[..., dn:])            # W_UV after attention
+        h = h + _proj(out.reshape(T, H * dv), attn["o_proj"])
+
+    hn2 = _rms(h, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
+    if "gate" in lp["mlp"]:
+        h = h + _moonlight_moe(hn2, lp["mlp"], experts, layer - cfg.first_k_dense_replace, cfg)
     else:
-        h = _rms(h, params["model"]["norm"]["scale"], cfg.rms_norm_eps)
+        h = h + _swiglu(hn2, lp["mlp"])
+    return (h, kc, vc), None
+
+
+def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=None,
+                   attn_impl=None, lora=None):
+    """→ (last-token logits [max_seqs, vocab] fp32, kcache, vcache).
+
+    ``kcache``/``vcache``: the two pools of the model kind's state
+    (:func:`kind_of`: keys and values ``[L, NB, bs, Hkv*Dh]``, or the
+    latent rows and rotated keys of ``MoonlightKind``), carried through
+    the layers and written in place (donate them); ``batch``: the
+    arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is a ``LlamaConfig``,
+    ``GPTConfig`` or ``MoonlightConfig``; the layer wiring follows its kind. ``mesh``: an optional
+    serving mesh — params/KV arrive sharded per
+    ``inference/v2/sharding.py`` and the step pins the Megatron layout
+    (replicated tokens, head/feature-sharded projections) so GSPMD
+    inserts the TP all-reduces.
+
+    ``lora``: None (the exact pre-LoRA program) or
+    ``(a, b, scales, seq_adapters, impl)`` — per-site stacked hot slabs
+    ``a[site] [L, S, in, r]`` / ``b[site] [L, S, r, out]``, per-slot
+    ``scales [S]``, the batch's per-sequence adapter slots
+    ``seq_adapters [max_seqs + 1]`` (pad row = slot 0 = base), and the
+    static kernel impl selector. Llama-family layers only.
+
+    ``attn_impl``: the engine's ``heuristics.AttentionChoice`` (None =
+    unpinned and unreported)."""
+    kind = kind_of(cfg)
+    embed = params["model"]["embed_tokens"]
+    h = _c(embed[batch["token_ids"]].astype(dtype), (None, None), mesh)  # [T, D]
+    mult = getattr(cfg, "embedding_multiplier", 1.0)
+    if mult != 1.0:  # Gemma: sqrt(hidden_size)
+        h = h * jnp.asarray(mult, h.dtype)
+
+    layer_ids = jnp.arange(kcache.shape[0], dtype=jnp.int32)
+    h, leading, step, xs = kind.layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora,
+                                       layer_ids)
+    carry = (h, kcache, vcache)
+    for lead_step, lead_xs in leading:
+        carry, _ = lead_step(carry, lead_xs)
+    (h, kc, vc), _ = jax.lax.scan(step, carry, xs)
+
+    h = kind.final_norm(params, cfg, h)
     if "lm_head" in params:
         logits = h @ params["lm_head"]["kernel"].astype(h.dtype)
     else:  # tied embeddings

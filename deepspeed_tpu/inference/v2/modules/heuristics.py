@@ -24,6 +24,23 @@ Implementations registered for ``attention`` (the ragged decode op):
 Every implementation takes the whole pool ``[L, NB, bs, Hkv*Dh]`` and
 the layer to read as an index (``kc_shape`` below is the pool's shape;
 the KV-head count is its last dim over ``head_dim``).
+
+Those three read the state kind ``kv`` (keys and values). A model kind
+whose state is ``latent`` (``model_runner.MoonlightKind``: one
+normalised compressed row and one rotated key a token, shared by all
+heads, queried with absorbed weights) has its own two, and an
+implementation is never offered a state kind other than its own
+(``STATE_KIND``; ``kv`` where a class names none):
+
+- ``pallas_paged_mla``      — single-device Pallas latent decode kernel
+  (``ops/pallas/paged_mla_attention``); needs ``kv_lora_rank % 128 ==
+  0``, the rotated key's row padded to whole 128-lane tiles,
+  ``block_size % 16 == 0``, no mesh, and a block table that fits SMEM.
+- ``xla_gather_mla``        — the same mathematics by gather; always
+  supported for the latent state (the CPU's path).
+
+For these ``head_dim`` is ``kv_lora_rank``, ``kc_shape`` the latent
+pool's shape and ``q_shape`` ``[tokens, heads, rank + rope lanes]``.
 """
 
 from jax.sharding import PartitionSpec as P
@@ -124,27 +141,67 @@ class _XlaGather:
         return functools.partial(xla_paged_attention, alibi_slopes=alibi)
 
 
+@register_implementation("attention", "pallas_paged_mla")
+class _PallasPagedMLA:
+    STATE_KIND = "latent"
+
+    @staticmethod
+    def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
+        from deepspeed_tpu.ops.pallas import use_pallas
+        from deepspeed_tpu.ops.pallas.paged_attention import smem_table_fits
+        from deepspeed_tpu.ops.pallas.paged_mla_attention import mla_kernel_supported
+        return (alibi is None and (mesh is None or mesh.size == 1) and use_pallas()
+                and mla_kernel_supported(head_dim, q_shape[2] - head_dim, block_size)
+                and smem_table_fits(q_shape[0], max_blocks))
+
+    @staticmethod
+    def instantiate(mesh, head_dim, block_size, q_shape, kc_shape, alibi):
+        from deepspeed_tpu.ops.pallas.paged_mla_attention import paged_mla_decode_attention
+        return paged_mla_decode_attention
+
+
+@register_implementation("attention", "xla_gather_mla")
+class _XlaGatherMLA:
+    STATE_KIND = "latent"
+
+    @staticmethod
+    def supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
+        return alibi is None
+
+    @staticmethod
+    def instantiate(mesh, head_dim, block_size, q_shape, kc_shape, alibi):
+        from deepspeed_tpu.ops.pallas.paged_mla_attention import xla_paged_mla_attention
+        return xla_paged_mla_attention
+
+
 def instantiate_attn(mesh, head_dim, block_size, q_shape, kc_shape, alibi,
-                     max_blocks, override=None):
+                     max_blocks, override=None, state_kind="kv"):
     """→ ``(impl_name, fn(q, kc, vc, tab, pos, layer))`` — the first supported
     implementation in registration (priority) order, or the named one
     when the config pins ``override`` (reference
     heuristics.instantiate_attn + config_bundle semantics). A pin that
     does not support the config raises; it never degrades.
-    ``max_blocks``: the block table's width (blocks per sequence)."""
+    ``max_blocks``: the block table's width (blocks per sequence).
+    ``state_kind``: what the pool holds (``kv`` | ``latent``); only the
+    implementations of that kind are candidates."""
     for name, impl in REGISTRY["attention"]:
         if override is not None and name != override:
             continue
-        if impl.supports(mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
+        if getattr(impl, "STATE_KIND", "kv") == state_kind and impl.supports(
+                mesh, head_dim, block_size, q_shape, kc_shape, alibi, max_blocks):
             return name, impl.instantiate(mesh, head_dim, block_size,
                                           q_shape, kc_shape, alibi)
         if override is not None:
             import jax
             raise ValueError(
                 f"implementation_overrides pinned attention={override!r}, but it "
-                f"does not support this config (head_dim={head_dim}, "
+                f"does not support this config (state kind {state_kind!r}, its own "
+                f"{getattr(impl, 'STATE_KIND', 'kv')!r}; head_dim={head_dim}, "
                 f"block_size={block_size}, tokens={q_shape[0]}, max_blocks={max_blocks}, "
                 f"mesh={mesh and dict(mesh.shape)}, alibi={alibi is not None}, "
                 f"backend={jax.default_backend()!r})")
+    if override is None:
+        raise ValueError(f"no attention implementation supports state kind {state_kind!r} here "
+                         f"(head_dim={head_dim}, block_size={block_size}, alibi={alibi is not None})")
     raise ValueError(f"no attention implementation named {override!r}; "
                      f"available: {implementations('attention')}")

@@ -12,7 +12,15 @@ in place). What leaves or enters the pool a few blocks at a time
 (offload handles) keeps the 5-D wire shape ``[num_layers, n, block_size,
 n_kv_heads, head_dim]``: those blocks are reshaped, never the pool.
 Block 0 is reserved as the null block — padding tokens scatter there
-and no live sequence ever owns it."""
+and no live sequence ever owns it.
+
+What a row of the two arrays holds is the model kind's to say
+(``model_runner.kind_of(cfg).state_rows``): keys and values, ``n_kv_heads *
+head_dim`` wide each (``state_kind`` ``kv``, the default), or another
+state under another name — the ``latent`` kind keeps its normalised
+compressed row in ``k`` and its rotated shared key in ``v``, of different
+widths. Blocks, allocation and in-place update are the same for every
+kind; the offload wire format is the ``kv`` kind's only."""
 
 import jax
 import jax.numpy as jnp
@@ -32,10 +40,12 @@ class KVCacheHandleError(ValueError):
 class BlockedKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, n_kv_heads, head_dim,
-                 dtype=jnp.bfloat16, sharding=None):
+                 dtype=jnp.bfloat16, sharding=None, state_kind="kv", row_widths=None):
         """``sharding``: where the pool lives (a serving mesh shards its
         last dim over whole KV heads); it is allocated there directly,
-        never whole on the default device first."""
+        never whole on the default device first. ``state_kind`` /
+        ``row_widths``: a state other than keys and values, and the widths
+        of its two rows (``n_kv_heads`` / ``head_dim`` are then unused)."""
         assert num_blocks >= 2, "need at least one real block beyond the null block"
         self.num_layers = num_layers
         self.num_blocks = num_blocks
@@ -43,9 +53,11 @@ class BlockedKVCache:
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim
         self.dtype = dtype
-        shape = (num_layers, num_blocks, block_size, n_kv_heads * head_dim)
-        self.k = jnp.zeros(shape, dtype, device=sharding)
-        self.v = jnp.zeros(shape, dtype, device=sharding)
+        self.state_kind = state_kind
+        self.row_widths = tuple(row_widths or (n_kv_heads * head_dim,) * 2)
+        shape = (num_layers, num_blocks, block_size)
+        self.k = jnp.zeros(shape + self.row_widths[:1], dtype, device=sharding)
+        self.v = jnp.zeros(shape + self.row_widths[1:], dtype, device=sharding)
         self._allocator = BlockedAllocator(num_blocks)
         self._allocator.allocate(1)  # pin the null block forever
 
@@ -62,7 +74,17 @@ class BlockedKVCache:
             self._allocator.free(blocks)
 
     def bytes(self) -> int:
-        return 2 * self.k.size * self.k.dtype.itemsize
+        return (self.k.size + self.v.size) * self.k.dtype.itemsize
+
+    def bytes_per_token(self) -> int:
+        """What one token holds in the pool, over all layers."""
+        return self.num_layers * sum(self.row_widths) * self.k.dtype.itemsize
+
+    def _kv_only(self, what):
+        if self.state_kind != "kv":
+            raise NotImplementedError(
+                f"KV offload ({what}) moves blocks in the [layers, n, block_size, n_kv_heads, "
+                f"head_dim] wire format of the 'kv' state; the {self.state_kind!r} state has none")
 
     # ------------------------------------------------------------------
     # Host offload / restore (the reference declares this surface but
@@ -77,6 +99,7 @@ class BlockedKVCache:
         id vector padded to a power of two (repeating the last id), so
         arbitrary batch sizes reuse log2-many compiled programs instead
         of retracing an eager ``jnp.take`` per distinct length."""
+        self._kv_only("gather")
         blocks = [int(b) for b in blocks]
         for b in blocks:
             if b < 0 or b >= self.num_blocks:
@@ -171,6 +194,7 @@ class BlockedKVCache:
         scales cross to device; the fp32 expansion never exists on
         host). An empty handle (``n == 0``) is a no-op returning ``[]``
         — no reservation, no zero-block scatter through jit."""
+        self._kv_only("restore")
         self._validate_handle(handle)
         n = handle["k"].shape[1]
         if n == 0:

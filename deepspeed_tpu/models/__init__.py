@@ -5,3 +5,19 @@ from deepspeed_tpu.models.gpt import (GPT_CONFIGS, GPTConfig, GPTForCausalLM, bu
 from deepspeed_tpu.models.bert import (BERT_CONFIGS, BertConfig, BertForMaskedLM,
                                        BertForSequenceClassification, bert_tp_rule,
                                        build_bert)  # noqa: F401
+from deepspeed_tpu.models.moonlight import (MOONLIGHT_CONFIGS, MoonlightConfig,
+                                            MoonlightForCausalLM, build_moonlight)  # noqa: F401
+
+# The causal-LM families a preset name can build, in the order names are looked up
+# (the v2 serving engine takes any of them: inference/v2/model_runner.kind_of).
+MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
+                  (MOONLIGHT_CONFIGS, build_moonlight))
+
+
+def build_model(preset, **overrides):
+    """A causal LM by its preset's name, whichever family holds it."""
+    for presets, build in MODEL_REGISTRY:
+        if preset in presets:
+            return build(preset, **overrides)
+    known = sorted(name for presets, _ in MODEL_REGISTRY for name in presets)
+    raise KeyError(f"no model preset {preset!r}; known: {known}")

@@ -250,7 +250,8 @@ def _gmm_dispatch(xp, w, te, tm, interp):
     return gmm(xp, w, te, tm, 512, 256, interp)
 
 
-def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation=jax.nn.silu):
+def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation=jax.nn.silu,
+                    first_group=None):
     """Dropless top-1 MoE FFN: x [T, D]; expert_idx [T]; weights
     [E, D, F] / [E, D, F] / [E, F, D] → [T, D]. Every token reaches its
     expert (no capacity drops — the grouped-GEMM advantage). Each
@@ -267,7 +268,17 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
     ``remat_policy="moe"`` training policy exactly these are saved,
     which is the full residual set the backward needs to skip re-running
     all three grouped GEMMs (``inter`` rebuilds elementwise from
-    gate/up; the down GEMM's forward is dead code in the rebuild)."""
+    gate/up; the down GEMM's forward is dead code in the rebuild).
+
+    ``first_group`` (a traced scalar; None = the stacks are this call's
+    ``num_experts``): the stacks are a table of ``G >= num_experts``
+    groups — every layer's experts, say — of which this call's are
+    ``first_group .. first_group + num_experts``. The dispatch is chosen
+    on ``num_experts`` as without a table. ``ragged_dot`` and the gathered
+    contraction index the table where it lies (the other groups stay
+    empty; a layer's experts cut out of a stack would be copied first,
+    every call), the Pallas grouped matmul, which pads every group to a
+    row tile, gets the call's groups cut out."""
     from jax.ad_checkpoint import checkpoint_name
     quantized = any(_is_quantized(w) for w in (w_gate, w_up, w_down))
     if quantized and not fused_gmm_enabled():
@@ -284,6 +295,14 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
             not _is_quantized(w)
             or gmm_quant_supported(w.values, w.scales, w.scheme)
             for w in (w_gate, w_up, w_down))
+    groups = num_experts
+    if first_group is not None and use_pallas:
+        w_gate, w_up, w_down = (
+            jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, first_group, num_experts), w)
+            for w in (w_gate, w_up, w_down))
+    elif first_group is not None:
+        expert_idx = expert_idx + first_group
+        groups = jax.tree.leaves(w_gate)[0].shape[0]
     if use_pallas:
         GMM_STATS.count("pallas_quant" if quantized else "pallas")
         if FORCE_INTERPRET:
@@ -330,7 +349,7 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
         return _gathered_moe_mlp(x, expert_idx, w_gate, w_up, w_down,
                                  activation)
     GMM_STATS.count("ragged_quant" if quantized else "ragged")
-    xs, sizes, unsort = sort_by_expert(x, expert_idx, num_experts)
+    xs, sizes, unsort = sort_by_expert(x, expert_idx, groups)
     xs = checkpoint_name(xs, "moe_xs")
     gate = checkpoint_name(grouped_gemm_any(xs, w_gate, sizes).astype(x.dtype), "moe_gate")
     up = checkpoint_name(grouped_gemm_any(xs, w_up, sizes).astype(x.dtype), "moe_up")
@@ -370,7 +389,7 @@ def _join_stacks(flat, tags):
 
 
 def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
-                     widen_boundary=True):
+                     widen_boundary=True, first_group=None):
     """Post-gate dropless MoE FFN over flat tokens — the one
     implementation behind BOTH v2 ragged serving and dropless training.
 
@@ -395,7 +414,11 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
     of an operand, and values/scales need different specs) with the
     shard plan from ``inference/v2/sharding.moe_expert_specs``: E over
     'expert' (E/ep carriers per replica), features over 'tensor' when
-    the carrier geometry allows, and the same psum combine either way."""
+    the carrier geometry allows, and the same psum combine either way.
+
+    ``first_group``: the stacks are a table of expert groups of which
+    this call's ``num_experts`` start there (:func:`moe_grouped_mlp`);
+    without expert/tensor axes only."""
     T, k = topk_idx.shape
     idx_rep = topk_idx.reshape(-1)  # [T*k]
     if not fused_gmm_enabled():
@@ -409,6 +432,9 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         ep = sizes.get("expert", 1)
         if ep > 1 or sizes.get("tensor", 1) > 1:
+            if first_group is not None:
+                raise NotImplementedError("a table of expert groups (first_group) is not "
+                                          "sharded over expert/tensor axes")
             E = num_experts
             from deepspeed_tpu.inference.v2.sharding import moe_expert_specs
             w_specs, psum_axes = moe_expert_specs(mesh, w1, w3, w2)
@@ -461,7 +487,7 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
     x_rep = jnp.repeat(x, k, axis=0)  # [T*k, D]
     out_rep = moe_grouped_mlp(x_rep, idx_rep, _cast_stack(w1, x.dtype),
                               _cast_stack(w3, x.dtype), _cast_stack(w2, x.dtype),
-                              num_experts=num_experts)
+                              num_experts=num_experts, first_group=first_group)
     out_k = out_rep.reshape(T, k, -1)
     return jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
 

@@ -88,7 +88,11 @@ def kernel_supported(head_dim, block_size, n_kv_heads=None):
     Mosaic; the flattened layout re-measured compiling and matching the
     XLA reference on a real v5e for all four counts, 2026-08-01). 64-dim-head models (e.g. Bloom-560M, GPT-2) and ALiBi
     models take the XLA gather path
-    (see ``inference/v2/modules/heuristics.py``)."""
+    (see ``inference/v2/modules/heuristics.py``). A model whose heads are
+    neither (Moonlight's 192-wide queries over a 576-value latent row) is
+    not this kernel's at all: its state kind is ``latent`` and its kernel
+    ``paged_mla_attention.paged_mla_decode_attention``, with
+    ``mla_kernel_supported`` as its own constraint."""
     return head_dim % 128 == 0 and block_size % 8 == 0
 
 

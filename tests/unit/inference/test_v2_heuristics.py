@@ -14,8 +14,9 @@ from deepspeed_tpu.models import build_llama
 
 
 def test_registry_lists_implementations():
+    # in priority order; the two of the latent state kind are only ever offered to it
     assert implementations("attention") == ["pallas_paged", "pallas_paged_sharded",
-                                            "xla_gather"]
+                                            "xla_gather", "pallas_paged_mla", "xla_gather_mla"]
 
 
 def test_auto_selection_without_pallas_falls_back_to_xla(monkeypatch):

@@ -1,0 +1,393 @@
+"""Moonlight-16B-A3B (``deepseek_v3``) through the v2 ragged engine at the
+debug preset: the served logits against the plain float32 reference.
+
+The served path absorbs ``kv_b_proj`` and attends over a latent paged
+cache; the reference (``models/moonlight.reference_logits``) expands it
+and has no cache. They share no line, so agreement is evidence.
+
+Tolerances. The engines here run in float32 on the CPU (the default
+matmul precision there is full float32), so program and reference differ
+by the order of float32 additions only: relative L2 errors of 2-4e-7
+were read when this was written. ``TOL`` = 2e-5 is fifty times that and
+still three orders under the smallest fault a mutation of the
+mathematics makes (the reference on bf16 weights reads 3e-3, the others 0.10-0.35;
+``test_each_mutation_of_the_reference_is_caught`` shows each). Logits,
+not tokens: with random weights the largest logit changes on rounding.
+"""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
+                                        KVTierConfig, PrefixCacheConfig,
+                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
+from deepspeed_tpu.inference.v2 import model_runner
+from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
+from deepspeed_tpu.inference.v2.modules import heuristics
+from deepspeed_tpu.models import (MOONLIGHT_CONFIGS, MoonlightConfig, build_llama, build_model)
+from deepspeed_tpu.models import moonlight
+from deepspeed_tpu.models.moonlight import param_shapes, reference_logits
+from deepspeed_tpu.ops.pallas.paged_mla_attention import (paged_mla_decode_attention,
+                                                          xla_paged_mla_attention)
+from deepspeed_tpu.utils import tracing
+
+TOL = 2e-5
+BLOCK = 16
+
+
+def rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def engine_config(**over):
+    return RaggedInferenceEngineConfig(
+        kv_block_size=BLOCK, num_kv_blocks=64,
+        state_manager=DSStateManagerConfig(max_ragged_batch_size=48,
+                                           max_ragged_sequence_count=8,
+                                           max_tracked_sequences=8, max_context=256), **over)
+
+
+@pytest.fixture(scope="module")
+def model():
+    return build_model("moonlight-debug")
+
+
+@pytest.fixture(scope="module")
+def engine(model):
+    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
+                             rng=jax.random.PRNGKey(7))
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(11).integers(0, 256, (4, 160), dtype=np.int32)
+
+
+def reference(engine, seq):
+    return np.asarray(reference_logits(engine.params, jnp.asarray(seq)[None],
+                                       engine.model_config))[0]
+
+
+# ------------------------------------------------------------------ the model
+def test_the_debug_preset_has_every_mechanism():
+    cfg = MOONLIGHT_CONFIGS["moonlight-debug"]
+    assert cfg.first_k_dense_replace == 1 and cfg.num_moe_layers >= 2
+    assert cfg.n_routed_experts >= 8 and cfg.num_experts_per_tok == 3
+    assert 1 <= cfg.n_shared_experts <= 2 and cfg.kv_lora_rank > 0
+    assert len({cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim}) == 3
+    full = MOONLIGHT_CONFIGS["moonlight-16b-a3b"]
+    n = sum(int(np.prod(s)) for s in jax.tree.leaves(
+        param_shapes(full), is_leaf=lambda x: isinstance(x, tuple)))
+    assert abs(n - 15.96e9) < 0.01e9, n     # the published parameter count
+
+
+def test_presets_build_by_name_through_one_registry(model):
+    assert isinstance(model.config, MoonlightConfig)
+    assert build_model("debug").config == build_llama("debug").config
+    assert type(build_model("gpt2-debug")).__name__ == "GPTForCausalLM"
+    with pytest.raises(KeyError, match="moonlight-debug"):
+        build_model("no-such-preset")
+    assert model_runner.kind_of(model.config) is model_runner.MoonlightKind
+    assert model_runner.kind_of(build_llama("debug").config) is model_runner.LlamaKind
+    assert model_runner.kind_of(build_model("gpt2-debug").config) is model_runner.GPTKind
+
+
+@pytest.mark.parametrize("field,value", [("q_lora_rank", 1536), ("n_group", 8), ("topk_group", 4),
+                                         ("scoring_func", "softmax"),
+                                         ("topk_method", "greedy"),
+                                         ("rope_scaling", {"type": "yarn"})])
+def test_what_the_source_switches_on_elsewhere_is_refused_by_name(field, value):
+    with pytest.raises(ValueError, match=field):
+        dataclasses.replace(MOONLIGHT_CONFIGS["moonlight-debug"], **{field: value})
+
+
+def test_the_parameter_tree_has_the_checkpoints_names_and_a_live_bias(engine):
+    layers = engine.params["model"]["layers"]
+    assert set(layers["self_attn"]) == {"q_proj", "kv_a_proj_with_mqa", "kv_a_layernorm",
+                                        "kv_b_proj", "o_proj"}
+    assert set(layers["mlp"]) == {"gate", "experts", "shared_experts"}
+    assert set(layers["mlp"]["gate"]) == {"weight", "e_score_correction_bias"}
+    assert set(engine.params["model"]["dense_layers"]["mlp"]) == {"gate_proj", "up_proj",
+                                                                  "down_proj"}
+    bias = np.asarray(layers["mlp"]["gate"]["e_score_correction_bias"])
+    assert np.abs(bias).min() > 0 and bias.std() > 0.03   # zeros would hide a biased weighting
+
+
+# ------------------------------------------------------ served against reference
+def test_the_state_is_one_latent_row_a_token_a_layer(engine):
+    cfg = engine.model_config
+    assert engine.state_kind == "latent"
+    assert engine.kv_cache.k.shape == (3, 64, BLOCK, cfg.kv_lora_rank)
+    assert engine.kv_cache.v.shape == (3, 64, BLOCK, 128)
+    assert engine.state_bytes_per_token == 3 * (cfg.kv_lora_rank + 128) * 4
+    full = MOONLIGHT_CONFIGS["moonlight-16b-a3b"]
+    assert sum(model_runner.MoonlightKind.state_rows(full)) == 640   # <= 640 values, 1280 B in bf16
+    assert model_runner.LlamaKind.state_rows(build_llama("debug").config) == (32, 32)
+
+
+def test_prefill_in_one_chunk(engine, tokens):
+    seq = tokens[0][:40]
+    got = engine.put([100], [seq])
+    engine.flush(100)
+    assert rel_err(got[0], reference(engine, seq)[-1]) < TOL
+
+
+def test_prefill_over_several_chunks_beside_decoding_sequences(engine, tokens):
+    """A 130-token prompt in chunks of 40 + 40 + 40 + 10 (context crosses
+    eight 16-token blocks), while two other sequences decode one token in
+    each of the same steps."""
+    long_, a, b = tokens[1][:130], tokens[2][:30], tokens[3][:21]
+    want = {1: reference(engine, long_), 2: reference(engine, a), 3: reference(engine, b)}
+    engine.put([2, 3], [a[:20], b[:11]])
+    errs, fed = [], 0
+    for step, n in enumerate((40, 40, 40, 10)):
+        out = engine.put([1, 2, 3], [long_[fed:fed + n], a[20 + step:21 + step],
+                                     b[11 + step:12 + step]])
+        fed += n
+        errs += [rel_err(out[0], want[1][fed - 1]), rel_err(out[1], want[2][20 + step]),
+                 rel_err(out[2], want[3][11 + step])]
+    for uid in (1, 2, 3):
+        engine.flush(uid)
+    assert len(errs) == 12 and max(errs) < TOL, errs
+
+
+def test_forty_decode_steps_through_the_cache_in_bursts(engine, tokens):
+    """Prompt of 23 (inside the second block), then decode bursts of 16 +
+    16 + 8 = 40 steps over block boundaries at 32 and 48. A burst returns
+    tokens, so the check is (1) the logits of one more step, which read
+    every row the bursts wrote, and (2) that each burst token is the
+    reference's argmax where its margin is clear of rounding."""
+    prompt = tokens[0][60:83]
+    first = int(np.argmax(engine.put([5], [prompt])[0]))
+    generated, last = [first], first
+    for k in (16, 16, 8):
+        out = engine.decode_burst([5], [last], k)
+        generated += [int(t) for t in out[:, 0]]
+        last = generated[-1]
+    after = engine.put([5], [np.asarray([last], np.int32)])
+    engine.flush(5)
+    seq = np.concatenate([prompt, np.asarray(generated, np.int32)])
+    want = reference(engine, seq)
+    assert len(generated) == 41
+    assert rel_err(after[0], want[-1]) < TOL
+    top2 = np.sort(want[len(prompt) - 1:-1], axis=-1)[:, -2:]
+    clear = (top2[:, 1] - top2[:, 0]) > 1e-4
+    assert clear.sum() >= 35
+    assert (np.argmax(want[len(prompt) - 1:-1], axis=-1)[clear] == np.asarray(generated)[clear]).all()
+
+
+@pytest.mark.parametrize("rows,path", [(2, "gathered"), (9, "ragged")])
+def test_a_layers_experts_are_read_where_they_lie_in_the_stack(engine, rows, path):
+    """The routed experts of all layers are one table of groups to the
+    grouped GEMM, which chooses its dispatch on the layer's own expert
+    count (8 here: 6 routed rows are gathered, 27 ride ``ragged_dot``):
+    the same output as on the layer's experts cut out."""
+    from deepspeed_tpu.ops.grouped_gemm import GMM_STATS
+    cfg = engine.model_config
+    layers = engine.params["model"]["layers"]
+    p = jax.tree.map(lambda w: w[1], {k: v for k, v in layers["mlp"].items() if k != "experts"})
+    x = jnp.asarray(np.random.default_rng(2).standard_normal((rows, cfg.hidden_size)), jnp.float32)
+    GMM_STATS.reset()
+    got = model_runner._moonlight_moe(x, p, layers["mlp"]["experts"], 1, cfg)
+    cut = jax.tree.map(lambda w: w[1:2], layers["mlp"]["experts"])
+    want = model_runner._moonlight_moe(x, p, cut, 0, cfg)
+    assert GMM_STATS.snapshot() == {path: 2}
+    assert rel_err(got, want) < 1e-6
+
+
+def _shared_experts_zeroed(params):
+    params = jax.tree.map(lambda x: x, params)
+    down = params["model"]["layers"]["mlp"]["shared_experts"]["down_proj"]
+    down["kernel"] = jnp.zeros_like(down["kernel"])
+    return params
+
+
+def _bias_zeroed(params):
+    params = jax.tree.map(lambda x: x, params)
+    gate = params["model"]["layers"]["mlp"]["gate"]
+    gate["e_score_correction_bias"] = jnp.zeros_like(gate["e_score_correction_bias"])
+    return params
+
+
+# One deliberate fault of the reference each, made from outside it: (params, cfg) → the
+# faulty reference's (params, cfg), or the name of what is patched in ``models/moonlight``.
+MUTATIONS = {
+    "bf16_weights": lambda p, c: (jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16).astype(x.dtype), p), c),
+    "no_routed_scale": lambda p, c: (p, dataclasses.replace(c, routed_scaling_factor=1.0)),
+    "no_shared_experts": lambda p, c: (_shared_experts_zeroed(p), c),
+    "unbiased_choice": lambda p, c: (_bias_zeroed(p), c),
+    "raw_c_kv": "_rms_norm",
+}
+
+
+@pytest.mark.parametrize("mutation", MUTATIONS)
+def test_each_mutation_of_the_reference_is_caught(engine, tokens, mutation, monkeypatch):
+    """The tolerance would catch each piece of the mathematics left out or
+    done in less: a reference on weights rounded to bfloat16, a missing
+    ``routed_scaling_factor``, a missing shared expert, experts chosen
+    without the bias, a cache of un-normalised ``c_kv``."""
+    seq = tokens[2][40:88]
+    got = engine.put([200], [seq[:-1]])
+    got = np.stack([got[0], engine.put([200], [seq[-1:]])[0]])
+    engine.flush(200)
+    assert rel_err(got, reference(engine, seq)[-2:]) < TOL
+    params, cfg = engine.params, engine.model_config
+    if mutation == "raw_c_kv":
+        rms_norm = moonlight._rms_norm
+        monkeypatch.setattr(moonlight, "_rms_norm", lambda x, scale, eps: (
+            x if x.shape[-1] == cfg.kv_lora_rank else rms_norm(x, scale, eps)))
+    else:
+        params, cfg = MUTATIONS[mutation](params, cfg)
+    faulty = np.asarray(reference_logits(params, jnp.asarray(seq)[None], cfg))[0]
+    assert rel_err(got, faulty[-2:]) > 50 * TOL
+
+
+# ------------------------------------------------------------------ the router
+def test_the_bias_chooses_and_the_unbiased_scores_weigh():
+    cfg = dataclasses.replace(MOONLIGHT_CONFIGS["moonlight-debug"], n_shared_experts=1)
+    rng = np.random.default_rng(3)
+    D, E, I, k = cfg.hidden_size, cfg.n_routed_experts, cfg.moe_intermediate_size, 3
+    x = np.abs(rng.standard_normal((5, D))).astype(np.float32)
+    gate_w = (rng.standard_normal((D, E)) * 0.2).astype(np.float32)
+    gate_w[:, 0] = -0.1      # with x >= 0: expert 0 scores near zero for every token
+    bias = np.zeros(E, np.float32)
+    scores = 1.0 / (1.0 + np.exp(-(x @ gate_w)))
+    unbiased_top = [set(np.argsort(-scores[t])[:k]) for t in range(len(x))]
+    lowest = [e for e in range(E) if not any(e in top for top in unbiased_top)][0]
+    bias[lowest] = 5.0       # an expert no token would choose is forced into every choice
+    w = {n: (rng.standard_normal(s) * 0.1).astype(np.float32)
+         for n, s in (("gate_proj", (E, D, I)), ("up_proj", (E, D, I)), ("down_proj", (E, I, D)))}
+    zeros = {"gate_proj": {"kernel": np.zeros((D, I), np.float32)},
+             "up_proj": {"kernel": np.zeros((D, I), np.float32)},
+             "down_proj": {"kernel": np.zeros((I, D), np.float32)}}
+    p = {"gate": {"weight": gate_w, "e_score_correction_bias": bias}, "shared_experts": zeros}
+    # the layer's experts are the second of two layers' (the other's would be seen at once)
+    stack = {n: jnp.stack([jnp.full(v.shape, 1e3, jnp.float32), jnp.asarray(v)])
+             for n, v in w.items()}
+    got = np.asarray(model_runner._moonlight_moe(jnp.asarray(x), jax.tree.map(jnp.asarray, p),
+                                                 stack, 1, cfg))
+
+    def silu(v):
+        return v / (1.0 + np.exp(-v))
+
+    want = np.zeros_like(x)
+    for t in range(len(x)):
+        chosen = np.argsort(-(scores[t] + bias))[:k]
+        assert lowest in chosen and set(chosen) != set(np.argsort(-scores[t])[:k])
+        weights = scores[t, chosen] / scores[t, chosen].sum() * cfg.routed_scaling_factor
+        for e, wt in zip(chosen, weights):
+            h = silu(x[t] @ w["gate_proj"][e]) * (x[t] @ w["up_proj"][e])
+            want[t] += wt * (h @ w["down_proj"][e])
+    assert rel_err(got, want) < 1e-5
+
+
+# ------------------------------------------------------------------ the kernel
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)])
+def test_the_latent_kernel_matches_its_xla_twin(dtype, tol):
+    """Interpret mode; contexts that end at the first row of a block, at
+    its last, and inside one; heads share each fetched row. bfloat16: the
+    kernel keeps a running max over blocks where the twin takes one
+    softmax, and both round probabilities to bf16 (2^-9 a value)."""
+    rng = np.random.default_rng(0)
+    L, NB, bs, rank, lanes, H, T, MB = 2, 14, 16, 128, 128, 4, 7, 6
+    c = jnp.asarray(rng.standard_normal((L, NB, bs, rank)), dtype)
+    r = jnp.asarray(rng.standard_normal((L, NB, bs, lanes)), dtype)
+    q = jnp.asarray(rng.standard_normal((T, H, rank + lanes)) * 0.1, dtype)
+    tab = jnp.asarray(np.stack([rng.permutation(NB - 1)[:MB] + 1 for _ in range(T)]), jnp.int32)
+    pos = jnp.asarray([0, 5, 15, 16, 37, 79, 95], jnp.int32)
+    got = paged_mla_decode_attention(q, c, r, tab, pos, 1, interpret=True)
+    want = xla_paged_mla_attention(q, c, r, tab, pos, jnp.int32(1))
+    assert got.shape == (T, H, rank) and got.dtype == dtype
+    assert rel_err(got, want) < tol
+    other = xla_paged_mla_attention(q, c, r, tab, pos, jnp.int32(0))
+    assert rel_err(other, want) > 0.5           # the layer index is read
+
+
+def test_the_registry_names_the_latent_implementations_and_a_wrong_pin_says_the_state_kind():
+    assert {"pallas_paged_mla", "xla_gather_mla"} <= set(heuristics.implementations("attention"))
+    q, pool = (8, 4, 160), (3, 64, 16, 32)
+    name, _ = heuristics.instantiate_attn(None, 32, 16, q, pool, None, max_blocks=16,
+                                          state_kind="latent")
+    assert name == "xla_gather_mla"             # rank 32 is no whole lane tile: never the kernel
+    name, _ = heuristics.instantiate_attn(None, 128, 16, (8, 4, 128), (2, 64, 16, 256), None,
+                                          max_blocks=16)
+    assert name in ("pallas_paged", "xla_gather")    # a kv state is never offered a latent one
+    for pin, kind in (("pallas_paged", "latent"), ("xla_gather", "latent"),
+                      ("xla_gather_mla", "kv"), ("pallas_paged_mla", "latent")):
+        with pytest.raises(ValueError, match=f"state kind '{kind}'"):
+            heuristics.instantiate_attn(None, 32, 16, q, pool, None, max_blocks=16,
+                                        override=pin, state_kind=kind)
+
+
+# ------------------------------------------------------------ what is refused
+@pytest.mark.parametrize("name,over", [
+    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
+    ("KV tier", {"kv_tier": KVTierConfig(enabled=True)}),
+    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
+    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
+    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
+    ("tensor/expert-parallel sharding", {"tensor_parallel_degree": 2}),
+])
+def test_each_subsystem_that_assumes_two_kv_pools_refuses_the_model_by_name(model, name, over):
+    with pytest.raises(NotImplementedError, match=name) as e:
+        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
+    assert "'latent'" in str(e.value) and "moonlight" in str(e.value)
+
+
+def test_suspend_refuses_the_latent_state(engine, tokens):
+    engine.put([300], [tokens[0][:5]])
+    with pytest.raises(NotImplementedError, match="suspend/resume export"):
+        engine.suspend(300)
+    engine.flush(300)
+
+
+# ------------------------------------------------------------------- tracing
+def test_step_records_carry_the_attended_context(engine, tokens):
+    engine.put([400, 401], [tokens[0][:10], tokens[1][:7]])
+    assert engine.last_step.n_ctx_tokens == 17
+    engine.put([400, 401], [tokens[0][10:11], tokens[1][7:9]])
+    assert engine.last_step.n_ctx_tokens == 11 + 9
+    engine.decode_burst([400, 401], [1, 2], 4)
+    # step j attends seen + j + 1 positions: 11 and 9 seen
+    assert engine.last_step.n_ctx_tokens == (12 + 13 + 14 + 15) + (10 + 11 + 12 + 13)
+    assert tracing.snapshot()["steps"][-1]["n_ctx_tokens"] == engine.last_step.n_ctx_tokens
+    for uid in (400, 401):
+        engine.flush(uid)
+
+
+# ------------------------------------------------------------------- gateway
+def test_the_gateway_serves_it_through_the_same_scheduler(model, engine, tokens):
+    """Behind ``ServingGateway`` (admission, SplitFuse scheduler, decode
+    bursts): the greedy stream of each request is the one the engine
+    gives alone, prompts longer than the token budget included."""
+    from deepspeed_tpu.serving import ServingConfig, ServingGateway
+    prompts = [tokens[0][:60], tokens[1][:9], tokens[2][:33]]
+    alone = []
+    for i, prompt in enumerate(prompts):
+        out, fed, stream = None, 0, []
+        while fed < len(prompt):
+            out = engine.put([500 + i], [prompt[fed:fed + 48]])
+            fed += 48
+        for _ in range(12):
+            stream.append(int(np.argmax(out[0])))
+            out = engine.put([500 + i], [np.asarray(stream[-1:], np.int32)])
+        engine.flush(500 + i)
+        alone.append(stream)
+    served = InferenceEngineV2(params=engine.params, model_config=model.config,
+                               config=engine_config(), dtype=jnp.float32)
+    gateway = ServingGateway(served, config=ServingConfig(default_max_new_tokens=12))
+    try:
+        handles = [gateway.submit(p, max_new_tokens=12) for p in prompts]
+        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
+    finally:
+        gateway.shutdown()
+    assert streams == alone
+    kinds = {r["kind"] for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id}
+    assert "burst" in kinds and "put" in kinds

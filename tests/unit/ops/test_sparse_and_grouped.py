@@ -152,6 +152,33 @@ class TestGroupedGemm:
         assert np.isfinite(np.asarray(g)).all()
         np.testing.assert_array_equal(np.asarray(g[3]), 0.0)
 
+    @pytest.mark.parametrize("rows,path", [(3, "gathered"), (96, "ragged"), (64, "pallas")])
+    def test_a_table_of_groups_is_this_calls_experts_cut_out(self, rows, path):
+        """``first_group``: the stacks are a table of more groups than the
+        call's experts (every layer's, say). Each dispatch - chosen on the
+        call's own expert count, as without a table - gives what it gives
+        on the call's experts cut out of the table."""
+        import deepspeed_tpu.ops.grouped_gemm as gg
+        rng = np.random.RandomState(7)
+        D, F, E, layers, layer = 64, 128, 4, 3, 1
+        x = jnp.asarray(rng.randn(rows, D).astype(np.float32))
+        idx = jnp.asarray(rng.randint(0, E, rows).astype(np.int32))
+        wg, wu, wd = (jnp.asarray(rng.randn(layers * E, *shape).astype(np.float32) * 0.05)
+                      for shape in ((D, F), (D, F), (F, D)))
+        cut = [w[layer * E:(layer + 1) * E] for w in (wg, wu, wd)]
+        gg.FORCE_INTERPRET = path == "pallas"
+        gg.GMM_STATS.reset()
+        try:
+            want = moe_grouped_mlp(x, idx, *cut, E)
+            got = jax.jit(lambda first: moe_grouped_mlp(x, idx, wg, wu, wd, E,
+                                                        first_group=first))(jnp.int32(layer * E))
+        finally:
+            gg.FORCE_INTERPRET = False
+        assert gg.GMM_STATS.snapshot() == {path: 2}
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense_reference_mlp(x, idx, *cut)),
+                                   rtol=1e-4, atol=1e-4)
+
     def test_grouped_under_jit_and_grad(self):
         rng = np.random.RandomState(2)
         T, D, F, E = 16, 8, 8, 2
