@@ -528,7 +528,8 @@ class InferenceEngineV2:
         logger.info(f"InferenceEngineV2: max_tokens={self.max_tokens} "
                     f"max_seqs={self.max_seqs} kv_blocks={num_blocks} "
                     f"block_size={self.block_size} tp={tp} ep={ep} "
-                    f"kv_bytes={self.kv_cache.bytes()/1e6:.1f}MB")
+                    f"kv_bytes={self.kv_cache.bytes()/1e6:.1f}MB "
+                    f"experts={kind.experts_form(self.params, self.mesh)}")
 
     # ------------------------------------------------------------------
     def _refuse_unsupported(self, kind, n_devices):

@@ -139,7 +139,10 @@ def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl
     return _c(out, (None, "tensor", None), mesh), kc, vc
 
 
-def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, carry, xs):
+def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, experts, carry, xs):
+    """One Llama-family block over the flat ragged batch. ``experts``:
+    None, or every layer's routed expert stacks, whole and outside the
+    scan's ``xs`` (:func:`_split_expert_stacks`)."""
     h, kc, vc = carry
     if lora_ctx is None:
         layer, lp = xs
@@ -183,7 +186,8 @@ def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, carry, xs):
 
     hn2 = _rms(h, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
     if "moe_mlp" in lp:
-        h = h + _moe_mlp(hn2, lp["moe_mlp"]["deepspeed_moe"], cfg.moe_top_k, mesh)
+        h = h + _moe_mlp(hn2, lp["moe_mlp"]["deepspeed_moe"], cfg.moe_top_k, mesh,
+                         experts, layer)
     else:
         mlp = lp["mlp"]
         gate = _c(_proj(hn2, mlp["gate_proj"]), (None, "tensor"), mesh)
@@ -196,7 +200,41 @@ def _layer_step(cfg, cos, sin, batch, mesh, attn_impl, lora_ctx, carry, xs):
     return (h, kc, vc), None
 
 
-def _moe_mlp(x, p, k, mesh=None):
+EXPERT_STACKS = ("experts_w1", "experts_w3", "experts_w2")
+
+
+def _split_expert_stacks(layers, mesh):
+    """→ (the scan's layer tree, the routed expert stacks it no longer
+    holds or None). The stacks ``[L, E, in, out]`` leave the scan's
+    ``xs`` and ride the step whole where the grouped GEMM can index them
+    as one table (:func:`_layer_groups`): plain arrays, and no
+    expert/tensor axis to shard them over. Quantized carriers (another
+    container, whose ``ragged_dot`` dequantizes the stack it is given)
+    and sharded experts (``E/ep`` a shard inside ``shard_map``) stay in
+    ``xs`` and are sliced by layer, as every other weight is."""
+    from deepspeed_tpu.inference.quantization import QuantizedWeight
+    from deepspeed_tpu.ops.grouped_gemm import shards_experts
+    moe = layers.get("moe_mlp", {}).get("deepspeed_moe")
+    if (moe is None or shards_experts(mesh)
+            or any(isinstance(moe[n], QuantizedWeight) for n in EXPERT_STACKS)):
+        return layers, None
+    rest = {n: w for n, w in moe.items() if n not in EXPERT_STACKS}
+    return ({**layers, "moe_mlp": {**layers["moe_mlp"], "deepspeed_moe": rest}},
+            {n: moe[n] for n in EXPERT_STACKS})
+
+
+def _layer_groups(stacks, layer):
+    """Every layer's routed experts ``[L, E, in, out]`` → (one table of
+    ``L x E`` groups ``[L*E, in, out]``: a bitcast, layer ``layer``'s
+    first group in it). ``ragged_dot`` takes its weights as one buffer,
+    so a layer's experts cut out of the stack would be copied first, in
+    every layer of every step: 2.8 GB for Mixtral's 8, 47 % of the
+    device's time; 1.1 GB for Moonlight's 64 (PERF.md, PRs 28 and 29)."""
+    table = jax.tree.map(lambda w: w.reshape((-1,) + w.shape[2:]), stacks)
+    return table, layer * jax.tree.leaves(stacks)[0].shape[1]
+
+
+def _moe_mlp(x, p, k, mesh=None, experts=None, layer=None):
     """Dropless top-k MoE over the flat [T, D] batch (Mixtral serving —
     reference inference/v2 cutlass MoE gather/scatter). At serving time
     capacity dropping is undesirable, so every token reaches its full
@@ -217,7 +255,12 @@ def _moe_mlp(x, p, k, mesh=None):
     kernel on TPU, gathered/ragged identical-math fallbacks elsewhere);
     only the [D, E] router sliver dequantizes here (its fp32 matmul
     needs the logits exactly as the unboxed path computed them).
-    ``DS_FUSED_GMM=0`` restores the old dequantize-at-entry subtree."""
+    ``DS_FUSED_GMM=0`` restores the old dequantize-at-entry subtree.
+
+    ``experts`` / ``layer``: every layer's expert stacks, kept out of the
+    scan (:func:`_split_expert_stacks`), and this layer's index: the
+    grouped GEMM reads the layer's groups where they lie in the table.
+    Without them the stacks are ``p``'s own, this layer's slice."""
     from deepspeed_tpu.inference.quantization import QuantizedWeight
     from deepspeed_tpu.ops.grouped_gemm import dropless_moe_ffn, fused_gmm_enabled
     if not fused_gmm_enabled():
@@ -231,9 +274,11 @@ def _moe_mlp(x, p, k, mesh=None):
     topk_vals, topk_idx = jax.lax.top_k(gates, k)  # [T, k]
     if k > 1:
         topk_vals = topk_vals / jnp.maximum(topk_vals.sum(-1, keepdims=True), 1e-9)
+    stacks, first_group = (p, None) if experts is None else _layer_groups(experts, layer)
     return dropless_moe_ffn(x, topk_idx, topk_vals,
-                            p["experts_w1"], p["experts_w3"], p["experts_w2"],
+                            *(stacks[n] for n in EXPERT_STACKS),
                             num_experts=gates.shape[-1], mesh=mesh,
+                            first_group=first_group,
                             widen_boundary=False)  # forward-only: keep the
     # bf16 expert-axis gather (the fp32 boundary exists for the backward
     # transpose psum, which serving never runs)
@@ -326,17 +371,28 @@ class LlamaKind:
                                     scaling=rope_scaling_of(cfg))
         cos, sin = jnp.asarray(cos), jnp.asarray(sin)
         lora_ctx = None
-        xs = (layer_ids, params["model"]["layers"])
+        layers, experts = _split_expert_stacks(params["model"]["layers"], mesh)
+        xs = (layer_ids, layers)
         if lora is not None:
             la, lb, scales, seq_adapters, lora_impl = lora
             # per-token adapter slot: pad tokens hit the pad row, which
             # carries slot 0 (base) by construction
             slots = seq_adapters[batch["token_seq"]]
             lora_ctx = (slots, scales, lora_impl)
-            xs = (layer_ids, params["model"]["layers"], la, lb)
+            xs = (layer_ids, layers, la, lb)
         step = functools.partial(_layer_step, cfg, cos, sin, batch, mesh, attn_impl,
-                                 lora_ctx)
+                                 lora_ctx, experts)
         return h, (), step, xs
+
+    @staticmethod
+    def experts_form(params, mesh):
+        """How a layer reaches its routed experts, for the engine's
+        start-up line: ``table`` (the stacks ride the step whole),
+        ``sliced`` (the scan cuts the layer's out) or None (no experts)."""
+        layers = params["model"]["layers"]
+        if "moe_mlp" not in layers:
+            return None
+        return "sliced" if _split_expert_stacks(layers, mesh)[1] is None else "table"
 
     @staticmethod
     def final_norm(params, cfg, h):
@@ -423,6 +479,10 @@ class MoonlightKind:
                 for i in range(n_dense)]
         return h, lead, step, (layer_ids[n_dense:], sliced)
 
+    @staticmethod
+    def experts_form(params, mesh):
+        return "table"
+
     final_norm = LlamaKind.final_norm
 
 
@@ -483,11 +543,8 @@ def _moonlight_moe(x, p, experts, layer, cfg):
     ``experts``: ``{gate,up,down}_proj [Lm, E, in, out]`` of all expert
     layers, ``layer`` this one's index among them: the grouped GEMM takes
     the stack as one table of ``Lm x E`` groups and this layer's first
-    group, and chooses its dispatch on ``E`` (``ragged_dot`` takes its
-    weights as one buffer, so a layer's experts cut out of the stack
-    would be copied first, every step: 1.1 GB a layer for Moonlight, as
-    long as the three matmuls themselves — PERF.md, PR 28; ROADMAP S2 for
-    Mixtral)."""
+    group (:func:`_layer_groups`, as Mixtral's :func:`_moe_mlp` does),
+    and chooses its dispatch on ``E``."""
     from deepspeed_tpu.ops.grouped_gemm import dropless_moe_ffn
     with jax.named_scope("ds.moe_routed"):
         scores = jax.nn.sigmoid(x.astype(jnp.float32) @ p["gate"]["weight"].astype(jnp.float32))
@@ -497,11 +554,10 @@ def _moonlight_moe(x, p, experts, layer, cfg):
         if cfg.norm_topk_prob:
             topk_vals = topk_vals / (topk_vals.sum(-1, keepdims=True) + 1e-20)
         topk_vals = topk_vals * cfg.routed_scaling_factor
-        table = jax.tree.map(lambda w: w.reshape((-1,) + w.shape[2:]), experts)
+        table, first_group = _layer_groups(experts, layer)
         routed = dropless_moe_ffn(x, topk_idx, topk_vals, table["gate_proj"], table["up_proj"],
                                   table["down_proj"], num_experts=cfg.n_routed_experts,
-                                  widen_boundary=False,
-                                  first_group=layer * cfg.n_routed_experts)
+                                  widen_boundary=False, first_group=first_group)
     with jax.named_scope("ds.moe_shared"):
         return routed + _swiglu(x, p["shared_experts"])
 

@@ -48,10 +48,11 @@ class GroupedGemmStats:
     """Trace-time dispatch telemetry for the grouped GEMM.
 
     Records which path each ``moe_grouped_mlp`` trace took
-    (pallas/gathered/ragged, quantized or dense) so bench lanes and the
-    parity suite can assert the path they think they measured is the
-    one that ran. Serving traces from gateway worker threads, so all
-    counter access takes the lock.
+    (pallas/gathered/ragged, quantized or dense, and ``_table`` where
+    the stacks were a table of groups indexed where it lies: see
+    ``first_group``) so bench lanes and the parity suite can assert the
+    path they think they measured is the one that ran. Serving traces
+    from gateway worker threads, so all counter access takes the lock.
     """
 
     def __init__(self):
@@ -277,8 +278,9 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
     on ``num_experts`` as without a table. ``ragged_dot`` and the gathered
     contraction index the table where it lies (the other groups stay
     empty; a layer's experts cut out of a stack would be copied first,
-    every call), the Pallas grouped matmul, which pads every group to a
-    row tile, gets the call's groups cut out."""
+    every call; ``GMM_STATS`` counts these as ``ragged_table`` /
+    ``gathered_table``), the Pallas grouped matmul, which pads every
+    group to a row tile, gets the call's groups cut out."""
     from jax.ad_checkpoint import checkpoint_name
     quantized = any(_is_quantized(w) for w in (w_gate, w_up, w_down))
     if quantized and not fused_gmm_enabled():
@@ -295,14 +297,14 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
             not _is_quantized(w)
             or gmm_quant_supported(w.values, w.scales, w.scheme)
             for w in (w_gate, w_up, w_down))
-    groups = num_experts
+    groups, table = num_experts, ""
     if first_group is not None and use_pallas:
         w_gate, w_up, w_down = (
             jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, first_group, num_experts), w)
             for w in (w_gate, w_up, w_down))
     elif first_group is not None:
         expert_idx = expert_idx + first_group
-        groups = jax.tree.leaves(w_gate)[0].shape[0]
+        groups, table = jax.tree.leaves(w_gate)[0].shape[0], "_table"
     if use_pallas:
         GMM_STATS.count("pallas_quant" if quantized else "pallas")
         if FORCE_INTERPRET:
@@ -345,10 +347,10 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
         return jnp.take(_gmm_dispatch(inter, w_down, te, tm, interp), pdst,
                         axis=0, unique_indices=True)
     if x.shape[0] < num_experts:
-        GMM_STATS.count("gathered_quant" if quantized else "gathered")
+        GMM_STATS.count(("gathered_quant" if quantized else "gathered") + table)
         return _gathered_moe_mlp(x, expert_idx, w_gate, w_up, w_down,
                                  activation)
-    GMM_STATS.count("ragged_quant" if quantized else "ragged")
+    GMM_STATS.count(("ragged_quant" if quantized else "ragged") + table)
     xs, sizes, unsort = sort_by_expert(x, expert_idx, groups)
     xs = checkpoint_name(xs, "moe_xs")
     gate = checkpoint_name(grouped_gemm_any(xs, w_gate, sizes).astype(x.dtype), "moe_gate")
@@ -386,6 +388,14 @@ def _join_stacks(flat, tags):
             out.append(flat[i])
             i += 1
     return out
+
+
+def shards_experts(mesh):
+    """Whether :func:`dropless_moe_ffn` runs its experts sharded under
+    ``mesh``: an ``expert`` or ``tensor`` axis larger than 1."""
+    if mesh is None or mesh.size <= 1:
+        return False
+    return mesh.shape.get("expert", 1) > 1 or mesh.shape.get("tensor", 1) > 1
 
 
 def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
@@ -427,62 +437,59 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
         # is exactly the pre-fused execution model.
         w1, w3, w2 = (_unbox_stack(w, x.dtype) for w in (w1, w3, w2))
 
-    if mesh is not None and mesh.size > 1:
+    if shards_experts(mesh):
+        if first_group is not None:
+            raise NotImplementedError("a table of expert groups (first_group) is not "
+                                      "sharded over expert/tensor axes")
         from jax.sharding import PartitionSpec as P
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        ep = sizes.get("expert", 1)
-        if ep > 1 or sizes.get("tensor", 1) > 1:
-            if first_group is not None:
-                raise NotImplementedError("a table of expert groups (first_group) is not "
-                                          "sharded over expert/tensor axes")
-            E = num_experts
-            from deepspeed_tpu.inference.v2.sharding import moe_expert_specs
-            w_specs, psum_axes = moe_expert_specs(mesh, w1, w3, w2)
-            if E % ep == 0:
-                dtype = x.dtype
-                parts, tags, flat_specs = [], [], []
-                for w, sp in zip((w1, w3, w2), w_specs):
-                    ps, tag = _split_stack(w)
-                    parts.extend(ps)
-                    tags.append(tag)
-                    flat_specs.extend(sp)
+        E, ep = num_experts, mesh.shape.get("expert", 1)
+        from deepspeed_tpu.inference.v2.sharding import moe_expert_specs
+        w_specs, psum_axes = moe_expert_specs(mesh, w1, w3, w2)
+        if E % ep == 0:
+            dtype = x.dtype
+            parts, tags, flat_specs = [], [], []
+            for w, sp in zip((w1, w3, w2), w_specs):
+                ps, tag = _split_stack(w)
+                parts.extend(ps)
+                tags.append(tag)
+                flat_specs.extend(sp)
 
-                def shard_body(x_full, idx, *wflat):
-                    w1s, w3s, w2s = _join_stacks(wflat, tags)
-                    e_local = E // ep
-                    off = jax.lax.axis_index("expert") * e_local
-                    local = (idx >= off) & (idx < off + e_local)
-                    lidx = jnp.where(local, idx - off, 0)
-                    x_rep = jnp.repeat(x_full.astype(dtype), k, axis=0)
-                    out = moe_grouped_mlp(x_rep, lidx, _cast_stack(w1s, dtype),
-                                          _cast_stack(w3s, dtype),
-                                          _cast_stack(w2s, dtype),
-                                          num_experts=e_local)
-                    out = jnp.where(local[:, None], out, 0)
-                    # combine partial expert/feature sums in fp32 (also
-                    # dodges an XLA:CPU CHECK-crash on bf16 all-reduce
-                    # inside shard_map)
-                    return jax.lax.psum(out.astype(jnp.float32),
-                                        psum_axes).astype(dtype)
+            def shard_body(x_full, idx, *wflat):
+                w1s, w3s, w2s = _join_stacks(wflat, tags)
+                e_local = E // ep
+                off = jax.lax.axis_index("expert") * e_local
+                local = (idx >= off) & (idx < off + e_local)
+                lidx = jnp.where(local, idx - off, 0)
+                x_rep = jnp.repeat(x_full.astype(dtype), k, axis=0)
+                out = moe_grouped_mlp(x_rep, lidx, _cast_stack(w1s, dtype),
+                                      _cast_stack(w3s, dtype),
+                                      _cast_stack(w2s, dtype),
+                                      num_experts=e_local)
+                out = jnp.where(local[:, None], out, 0)
+                # combine partial expert/feature sums in fp32 (also
+                # dodges an XLA:CPU CHECK-crash on bf16 all-reduce
+                # inside shard_map)
+                return jax.lax.psum(out.astype(jnp.float32),
+                                    psum_axes).astype(dtype)
 
-                # Training (widen_boundary=True): x crosses the region
-                # boundary in fp32 — the TRANSPOSE of the replicated
-                # in_spec is a psum of dx over 'expert', and a bf16 psum
-                # there hits the same XLA:CPU CHECK-crash ('Invalid
-                # binary instruction opcode copy') the forward psum above
-                # dodges; it goes live whenever the layer sits inside
-                # lax.scan (the carry keeps dx alive). Compute stays in
-                # the caller's dtype; only the boundary is widened.
-                # Forward-only serving passes widen_boundary=False and
-                # keeps the bf16 (half-traffic) expert-axis gather.
-                x_in = x.astype(jnp.float32) if widen_boundary else x
-                out_rep = shard_map(
-                    shard_body, mesh=mesh,
-                    in_specs=(P(), P(), *flat_specs),
-                    out_specs=P(), axis_names={"expert", "tensor"},
-                    check_vma=False)(x_in, idx_rep, *parts)
-                out_k = out_rep.reshape(T, k, -1)
-                return jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
+            # Training (widen_boundary=True): x crosses the region
+            # boundary in fp32 — the TRANSPOSE of the replicated
+            # in_spec is a psum of dx over 'expert', and a bf16 psum
+            # there hits the same XLA:CPU CHECK-crash ('Invalid
+            # binary instruction opcode copy') the forward psum above
+            # dodges; it goes live whenever the layer sits inside
+            # lax.scan (the carry keeps dx alive). Compute stays in
+            # the caller's dtype; only the boundary is widened.
+            # Forward-only serving passes widen_boundary=False and
+            # keeps the bf16 (half-traffic) expert-axis gather.
+            x_in = x.astype(jnp.float32) if widen_boundary else x
+            out_rep = shard_map(
+                shard_body, mesh=mesh,
+                in_specs=(P(), P(), *flat_specs),
+                out_specs=P(), axis_names={"expert", "tensor"},
+                check_vma=False)(x_in, idx_rep, *parts)
+            out_k = out_rep.reshape(T, k, -1)
+            return jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
 
     x_rep = jnp.repeat(x, k, axis=0)  # [T*k, D]
     out_rep = moe_grouped_mlp(x_rep, idx_rep, _cast_stack(w1, x.dtype),

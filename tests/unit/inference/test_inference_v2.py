@@ -314,13 +314,28 @@ _HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<type>\w+)\[(?
 _MOVES = ("copy", "dynamic-slice", "dynamic-update-slice", "reshape", "transpose")
 
 
+def moves_of(hlo):
+    """Optimised HLO text → ``[(instruction, result dims, result bytes)]``
+    of its ``copy`` / ``dynamic-slice`` / ``dynamic-update-slice`` /
+    ``reshape`` / ``transpose`` instructions, in any computation (fused
+    ones included)."""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m or m["op"] not in _MOVES or not m["dims"]:
+            continue
+        dims = tuple(int(d) for d in m["dims"].split(","))
+        width = int(re.sub(r"\D", "", m["type"]) or 8) // 8  # f32 -> 4, bf16 -> 2, pred -> 1
+        found.append((f"{m['name']} {m['op']} {m['type']}[{m['dims']}]", dims,
+                      int(np.prod(dims)) * width))
+    return found
+
+
 def pool_findings(hlo, pool_shape, dtype):
     """Read a compiled program's optimised HLO text → ``(pool parameters
     of the entry computation, those of them aliased to a result,
-    offenders)``: an offender is a ``copy`` / ``dynamic-slice`` /
-    ``dynamic-update-slice`` / ``reshape`` / ``transpose`` instruction,
-    in any computation (fused ones included), whose result has as many
-    bytes as the pool or as one layer of it. A scatter may have."""
+    offenders)``: an offender is one of :func:`moves_of` whose result has
+    as many bytes as the pool or as one layer of it. A scatter may have."""
     item = jnp.dtype(dtype).itemsize
     pool_bytes = int(np.prod(pool_shape)) * item
     sizes = {pool_bytes, pool_bytes // pool_shape[0]}
@@ -332,16 +347,7 @@ def pool_findings(hlo, pool_shape, dtype):
     header = hlo[:hlo.index("\n")]
     block = re.search(r"input_output_alias=\{(.*?)\}, \w+=", header)
     aliased = {int(n) for n in re.findall(r"\((\d+), \{\}", block[1])} if block else set()
-    offenders = []
-    for line in hlo.splitlines():
-        m = _HLO_INSTR.match(line)
-        if not m or m["op"] not in _MOVES or not m["dims"]:
-            continue
-        n = int(np.prod([int(d) for d in m["dims"].split(",")]))
-        width = int(re.sub(r"\D", "", m["type"]) or 8) // 8  # f32 -> 4, bf16 -> 2, pred -> 1
-        if n * width in sizes:
-            offenders.append(f"{m['name']} {m['op']} {m['type']}[{m['dims']}]")
-    return pool_params, aliased, offenders
+    return pool_params, aliased, [name for name, _, nbytes in moves_of(hlo) if nbytes in sizes]
 
 
 def capture_programs(engine, uid=71):
@@ -415,6 +421,216 @@ class TestPoolStaysInPlace:
             params, aliased, offenders = pool_findings(hlo, pool.shape, jnp.float32)
             assert params == [0] and aliased == {0}
             assert (offenders == []) == clean, offenders
+
+
+# ------------------------------------------------------------ the experts
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def expert_findings(jaxpr, stack_shapes):
+    """A traced program → ``(shapes of the scans' xs that are an expert
+    stack's [L, E, in, out], the number of groups of each ragged_dot's
+    weight operand)``. Read from the jaxpr, which no backend has touched
+    (the CPU's compiler expands ``ragged_dot``, the TPU's does not)."""
+    sliced, groups = [], []
+    for eqn in _equations(jaxpr):
+        if eqn.primitive.name == "scan":
+            first = eqn.params["num_consts"] + eqn.params["num_carry"]
+            sliced += [v.aval.shape for v in eqn.invars[first:] if v.aval.shape in stack_shapes]
+        elif eqn.primitive.name.startswith("ragged_dot"):
+            groups.append(eqn.invars[1].aval.shape[0])
+    return sliced, groups
+
+
+def stack_shapes_of(engine):
+    from deepspeed_tpu.inference.v2.model_runner import EXPERT_STACKS
+    moe = engine.params["model"]["layers"]["moe_mlp"]["deepspeed_moe"]
+    return {moe[name].shape for name in EXPERT_STACKS}
+
+
+def layer_stack_moves(hlo, stack_shapes):
+    """The moves of a compiled program whose result is one layer's expert
+    stack ``[E, in, out]``, in any order of its dimensions (by dimensions
+    and not by bytes: the debug model's activations have the same bytes)."""
+    layer = {tuple(sorted(shape[1:])) for shape in stack_shapes}
+    return [name for name, dims, _ in moves_of(hlo)
+            if tuple(sorted(d for d in dims if d != 1)) in layer]
+
+
+def mixtral_engine(experts=4, **overrides):
+    model = build_llama("mixtral-debug", remat=False, moe_num_experts=experts)
+    params = model.init(jax.random.PRNGKey(2), jnp.zeros((1, 8), jnp.int32))["params"]
+    config = RaggedInferenceEngineConfig(
+        kv_block_size=8, **overrides,
+        state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
+                                           max_ragged_sequence_count=4,
+                                           max_tracked_sequences=4, max_context=128))
+    return InferenceEngineV2(model=model, config=config, params=params, dtype=jnp.float32)
+
+
+def slice_per_layer(monkeypatch):
+    """The form this replaced: the expert stacks stay in the scan's xs and
+    the layer step gets its layer's slice (what sharded and quantized
+    experts still do)."""
+    from deepspeed_tpu.inference.v2 import model_runner
+    monkeypatch.setattr(model_runner, "_split_expert_stacks", lambda layers, mesh: (layers, None))
+
+
+class TestExpertsStayInPlace:
+    """No serving program of a mixture model cuts a layer's experts out of
+    the stack: the stacks ride the step whole, and the grouped matmul
+    reads the layer's groups out of one table of ``L x E``. Fails on the
+    per-layer slice (``ragged_dot`` takes a buffer, so the TPU's compiler
+    materialises the slice first: 47 % of Mixtral's device time, PR 29)."""
+
+    @pytest.fixture(scope="class")
+    def programs(self):
+        engine = mixtral_engine()
+        return engine, capture_programs(engine)
+
+    @pytest.mark.parametrize("program", ["greedy_step", "burst4"])
+    def test_no_expert_stack_in_the_scan_and_one_table_of_groups(self, programs, program):
+        engine, seen = programs
+        fn, shapes = seen[program]
+        cfg = engine.model_config
+        sliced, groups = expert_findings(jax.make_jaxpr(fn)(*shapes).jaxpr,
+                                         stack_shapes_of(engine))
+        assert sliced == []
+        assert groups == [cfg.num_hidden_layers * cfg.moe_num_experts] * 3
+
+    @pytest.mark.parametrize("program", ["greedy_step", "burst4"])
+    def test_no_expert_stack_sized_copy_in_compiled_program(self, programs, program):
+        engine, seen = programs
+        fn, shapes = seen[program]
+        hlo = fn.lower(*shapes).compile().as_text()
+        assert layer_stack_moves(hlo, stack_shapes_of(engine)) == []
+
+    def test_findings_see_a_slice(self, monkeypatch):
+        """The readers themselves: the sliced form (the engine's own, with
+        the stacks left in the scan) is flagged by both, the table form
+        (the tests above) by neither; and a bare scan over a stack by the
+        jaxpr reader, its table twin not."""
+        slice_per_layer(monkeypatch)
+        engine = mixtral_engine()
+        cfg, stacks = engine.model_config, stack_shapes_of(engine)
+        for name, (fn, shapes) in capture_programs(engine).items():
+            sliced, groups = expert_findings(jax.make_jaxpr(fn)(*shapes).jaxpr, stacks)
+            assert len(sliced) == 3 and set(sliced) == stacks, name
+            assert groups == [cfg.moe_num_experts] * 3, name
+            # the CPU's compiler expands ragged_dot, and still cuts the layer out
+            cut = layer_stack_moves(fn.lower(*shapes).compile().as_text(), stacks)
+            assert sum("dynamic-slice" in move for move in cut) == 3, (name, cut)
+
+        x, sizes = jnp.ones((8, 16)), jnp.array([3, 5], jnp.int32)
+        stack = jnp.ones((3, 2, 16, 32))
+
+        def per_layer(w):
+            return jax.lax.scan(lambda c, wl: (c + jax.lax.ragged_dot(x, wl, sizes), None),
+                                jnp.zeros((8, 32)), w)[0]
+
+        def table(w):
+            flat = w.reshape((-1,) + w.shape[2:])
+            return jax.lax.scan(lambda c, l: (c + jax.lax.ragged_dot(
+                x, flat, jnp.zeros(6, jnp.int32).at[2 * l + jnp.arange(2)].set(sizes)), None),
+                jnp.zeros((8, 32)), jnp.arange(3))[0]
+
+        assert expert_findings(jax.make_jaxpr(per_layer)(stack).jaxpr, {stack.shape}) == (
+            [stack.shape], [2])
+        assert expert_findings(jax.make_jaxpr(table)(stack).jaxpr, {stack.shape}) == ([], [6])
+
+
+def served(engine, lora_uid=None):
+    """Three prompts through one engine: two short ones that decode while a
+    70-token one is prefilled in three chunks beside them, 24 burst steps
+    of all three, one more step → (logits of every mixed step, burst
+    tokens, logits after the bursts, both pools)."""
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 250, n).astype(np.int32) for n in (9, 13, 70)]
+    if lora_uid is not None:
+        store = engine.lora_store
+        rs = np.random.RandomState(3)
+        engine.register_adapter(101, {
+            site: (rs.randn(store.num_layers, din, 4).astype(np.float32) * 0.05,
+                   rs.randn(store.num_layers, 4, dout).astype(np.float32) * 0.05)
+            for site, (din, dout) in store.dims.items()}, alpha=8.0)
+        engine.bind_adapter(lora_uid, 101)
+    logits = [engine.put([1, 2], prompts[:2])]
+    last = [int(np.argmax(row)) for row in logits[0]]
+    for chunk in (prompts[2][:30], prompts[2][30:60], prompts[2][60:]):
+        out = engine.put([1, 2, 3], [[last[0]], [last[1]], chunk])
+        logits.append(out)
+        last = [int(np.argmax(row)) for row in out]
+    tokens = []
+    for _ in range(3):
+        burst = np.asarray(engine.decode_burst([1, 2, 3], last, 8))
+        tokens.append(burst)
+        last = [int(t) for t in burst[-1]]
+    after = engine.put([1, 2, 3], [[t] for t in last])
+    pools = np.asarray(engine.kv_cache.k), np.asarray(engine.kv_cache.v)
+    return logits, np.concatenate(tokens), after, pools
+
+
+class TestExpertTableSameAnswers:
+    """The table of groups gives what the per-layer slice gave, to 1e-5 in
+    float32: a multi-chunk prefill beside decoding rows, 24 burst steps
+    and the pool they wrote, in both dispatches the cell's sizes take and
+    with LoRA's slabs beside the layers in the scan's xs."""
+
+    @pytest.mark.parametrize("experts,lora,paths", [
+        (4, False, {"ragged"}),                 # 8 decode rows >= 4 experts
+        (16, False, {"gathered", "ragged"}),    # 8 decode rows < 16 experts; 64 prefill rows
+        (4, True, {"ragged"}),
+    ])
+    def test_logits_tokens_and_pool_match_the_per_layer_slice(self, monkeypatch, experts, lora,
+                                                               paths):
+        from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig
+        from deepspeed_tpu.ops.grouped_gemm import GMM_STATS
+        extra = {"lora": LoRAServingConfig(enabled=True, hot_set=4, max_rank=4,
+                                           prefetch=False)} if lora else {}
+        results = {}
+        for form in ("sliced", "table"):
+            with monkeypatch.context() as patch:
+                if form == "sliced":
+                    slice_per_layer(patch)
+                engine = mixtral_engine(experts=experts, **extra)
+                GMM_STATS.reset()
+                results[form] = served(engine, lora_uid=2 if lora else None)
+                suffix = "_table" if form == "table" else ""
+                assert set(GMM_STATS.snapshot()) == {path + suffix for path in paths}
+        (want_logits, want_tokens, want_after, want_pools) = results["sliced"]
+        (logits, tokens, after, pools) = results["table"]
+        assert tokens.shape == (24, 3)
+        np.testing.assert_array_equal(tokens, want_tokens)
+        for got, want in zip(logits + [after], want_logits + [want_after]):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        for got, want in zip(pools, want_pools):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("overrides,form,paths", [
+        ({}, "table", {"ragged_table"}),
+        ({"expert_parallel_degree": 2}, "sliced", {"ragged"}),
+        ({"tensor_parallel_degree": 2}, "sliced", {"ragged"}),
+        ({"quantization": {"quantization_mode": "int8"}}, "sliced", {"ragged_quant"}),
+    ])
+    def test_who_takes_the_table(self, overrides, form, paths):
+        """Plain stacks on one device ride the step whole; sharded experts
+        (``E/ep`` a shard inside ``shard_map``) and quantized carriers keep
+        the scan's per-layer slice and the dispatch they had."""
+        from deepspeed_tpu.ops.grouped_gemm import GMM_STATS
+        engine = mixtral_engine(**overrides)
+        assert engine.kind.experts_form(engine.params, engine.mesh) == form
+        GMM_STATS.reset()
+        out = engine.put([1], [(np.arange(10, dtype=np.int32) * 13) % 250])
+        assert np.isfinite(out).all()
+        assert set(GMM_STATS.snapshot()) == paths
 
 
 class TestGPTFamilyServing:

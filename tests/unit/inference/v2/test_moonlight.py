@@ -197,7 +197,7 @@ def test_a_layers_experts_are_read_where_they_lie_in_the_stack(engine, rows, pat
     got = model_runner._moonlight_moe(x, p, layers["mlp"]["experts"], 1, cfg)
     cut = jax.tree.map(lambda w: w[1:2], layers["mlp"]["experts"])
     want = model_runner._moonlight_moe(x, p, cut, 0, cfg)
-    assert GMM_STATS.snapshot() == {path: 2}
+    assert GMM_STATS.snapshot() == {path + "_table": 2}
     assert rel_err(got, want) < 1e-6
 
 

@@ -116,10 +116,11 @@ def test_layer_index_reads_that_layer_bit_for_bit(path, Hkv, G):
 # ------------------------------------------------- ragged_forward, end to end
 def _stacked_layer_scan(real_scan, used):
     """The form the layer scan had before the pool became its carry, as a
-    drop-in for ``jax.lax.scan``: every layer gets its own slice of the
-    pool as a one-layer pool (a copy), scatters into that, and the
-    slices are stacked into a new pool. Same layer function, the other
-    data flow; any other scan goes to the real one."""
+    drop-in for ``jax.lax.scan``: every layer scatters into a copy of the
+    pool, its own slice is cut out of that, and the slices are stacked
+    into a new pool. Same layer function and the same layer index (the
+    step also finds its experts by it), the other data flow; any other
+    scan goes to the real one."""
     def scan(step, carry, xs, *args, **kwargs):
         is_layer_scan = (isinstance(carry, tuple) and len(carry) == 3
                          and carry[0].ndim == 2 and carry[1].ndim == 4)
@@ -129,11 +130,9 @@ def _stacked_layer_scan(real_scan, used):
         ks, vs = [], []
         used.append(kc.shape)
         for layer in range(kc.shape[0]):
-            x = jax.tree.map(lambda a: a[layer], xs)
-            (h, k1, v1), _ = step((h, kc[layer:layer + 1], vc[layer:layer + 1]),
-                                  (jnp.int32(0),) + tuple(x[1:]))
-            ks.append(k1)
-            vs.append(v1)
+            (h, k1, v1), _ = step((h, kc, vc), jax.tree.map(lambda a: a[layer], xs))
+            ks.append(k1[layer:layer + 1])
+            vs.append(v1[layer:layer + 1])
         return (h, jnp.concatenate(ks), jnp.concatenate(vs)), None
     return scan
 
