@@ -200,8 +200,7 @@ def _dequantize_grouped(values, scales, scheme, dtype):
 
 def fused_qmm_enabled():
     """Fused dequant-matmul toggle (env ``DS_FUSED_QMM``, default on).
-    Read at trace time — flip it and retrace to A/B the unbox path
-    (bench.py's fused-vs-unbox lanes do exactly that)."""
+    Read at trace time — flip it and retrace to A/B the unbox path."""
     return env_bool("DS_FUSED_QMM")
 
 

@@ -116,19 +116,16 @@ class StructuredConfig(DeepSpeedConfigModel):
 
 
 class AsyncBurstConfig(DeepSpeedConfigModel):
-    """Pipelined (double-buffered) decode bursts: the host plans, packs
-    and dispatches burst k+1 while burst k executes on device, and
-    consumes burst k's tokens only when it fences before dispatching
-    burst k+2 — EOS/finished state and the token log are discovered one
-    burst late, never by blocking the device. ``enabled`` is the config
-    gate; the ``DS_ASYNC_BURST`` env var overrides it in both
-    directions (kill switch), and the off state rebuilds the exact
-    pre-pipeline loop — byte-identical program keys, identical sync
-    structure. ``depth`` is the number of in-flight (dispatched,
-    unfenced) bursts the scheduler keeps; 2 is the classic double
-    buffer (fence burst k before dispatching burst k+2)."""
-    enabled: bool = False
-    depth: int = 2
+    """Pipelined decode bursts. ``depth`` is the number of in-flight
+    (dispatched, unfetched) bursts the scheduler keeps. 0, the default,
+    fetches every burst in the call that dispatched it. Above 0 the host
+    plans, packs and dispatches burst k+1 while burst k executes on
+    device and consumes burst k's tokens only when it fences — EOS,
+    finished state and the token log are discovered late, never by
+    blocking the device; 2 is the classic double buffer (fence burst k
+    before dispatching burst k+2). One burst program family serves every
+    depth, and the emitted streams are bit-identical at every depth."""
+    depth: int = 0
 
 
 class RaggedInferenceEngineConfig(DeepSpeedConfigModel):
@@ -150,14 +147,12 @@ class RaggedInferenceEngineConfig(DeepSpeedConfigModel):
     # compiled decode/verify programs kept per engine: each distinct
     # (burst length k, sampling key) and (verify, draft length) compiles
     # its own program; beyond the cap the least-recently-used is dropped.
-    # Sizing for the pipelined program set: sync and async burst variants
-    # are separate keys and burst k / k+1 hold DIFFERENT keys alive
-    # simultaneously when the pipeline tapers (k halves toward max_new),
-    # so a steady mixed workload can keep live
-    #   2 (sync/async) x 2 (greedy/sampled) x log2(max_burst)=4 burst
-    #   keys (= 16) + 2 (plain/packed) x 2 x log2(draft cap)=4 verify
-    #   keys (= 16)
-    # = 32 programs at once. 48 leaves headroom so the steady state
+    # Sizing: one burst family whatever the pipeline's depth, and burst
+    # k / k+1 hold DIFFERENT keys alive simultaneously when bursts taper
+    # (k halves toward max_new), so a steady mixed workload can keep live
+    #   2 (greedy/sampled) x log2(max_burst)=4 burst keys (= 8)
+    #   + 2 (plain/packed) x 2 x log2(draft cap)=4 verify keys (= 16)
+    # = 24 programs at once. 48 leaves headroom so the steady state
     # never thrashes (the eviction-regression test asserts zero
     # evictions over a pipelined trace).
     burst_fn_cache_cap: int = 48

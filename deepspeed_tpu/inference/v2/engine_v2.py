@@ -21,7 +21,7 @@ from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
 from deepspeed_tpu.utils import tracing
-from deepspeed_tpu.utils.env_registry import env_int, env_opt_bool
+from deepspeed_tpu.utils.env_registry import env_int
 from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.utils.sanitize import maybe_checkify_jit, sanitize_enabled
 
@@ -37,39 +37,17 @@ from deepspeed_tpu.inference.structured.sampling import (SAMPLE_META_ROWS,
                                                          unpack_sample_meta)
 
 
-def async_burst_enabled(config) -> bool:
-    """Config gate plus the ``DS_ASYNC_BURST`` kill switch: when the
-    env var is set it wins in BOTH directions (``0``/``false``/``off``
-    forces the pre-pipeline loop, anything else forces pipelining);
-    unset defers to ``config.enabled``. The off state rebuilds the
-    exact pre-pipeline decode loop — byte-identical program keys."""
-    forced = env_opt_bool("DS_ASYNC_BURST")
-    if forced is not None:
-        return forced
-    return bool(getattr(config, "enabled", False))
-
-
 def _burst_ctx_tokens(seen, k):
     """Context positions one row attends over a burst of ``k`` steps that
     starts with ``seen`` tokens cached: step ``j`` attends ``seen + j + 1``."""
     return k * seen + k * (k + 1) // 2
 
 
-def _burst_layout(ms, mb, lora=False, sampled=False, async_entry=False):
-    """Single source for the decode-burst metadata wire format: field →
-    (start, end) offsets into the flat int32 vector. Both the host pack
-    (``decode_burst``) and the traced unpack (``_make_burst_fn``) read
-    this, so the layout cannot silently diverge. ``lora`` appends the
-    per-sequence adapter-slot row and ``sampled`` the per-sequence
-    sampling-spec rows — each strictly opt-in, so the off-state wire
-    format is byte-identical to the pre-feature one."""
-    fields = [("tokens0", ms), ("token_seq", ms), ("pos0", ms),
-              ("tables", (ms + 1) * mb)]
-    if async_entry:
-        # pipelined bursts chain entry tokens on DEVICE (the previous
-        # burst's last output row rides in as a separate argument), so
-        # the packed vector drops the host tokens0 field entirely
-        fields = fields[1:]
+def _offsets(fields, lora, sampled, ms):
+    """field → (start, end) into a flat int32 metadata vector. ``lora``
+    appends the per-sequence adapter-slot row and ``sampled`` the
+    per-sequence sampling-spec rows — each strictly opt-in, so the
+    off-state wire format carries neither."""
     if lora:
         fields.append(("seq_adapters", ms + 1))
     if sampled:
@@ -79,6 +57,16 @@ def _burst_layout(ms, mb, lora=False, sampled=False, async_entry=False):
         lay[name] = (o, o + size)
         o += size
     return lay
+
+
+def _burst_layout(ms, mb, lora=False, sampled=False):
+    """Single source for the decode-burst metadata wire format. Both the
+    host pack (``_dispatch_burst``) and the traced unpack
+    (``_make_burst_fn``) read this, so the layout cannot silently
+    diverge. Entry tokens are not in it: they are a device argument of
+    their own, from the host or chained from the burst before."""
+    return _offsets([("token_seq", ms), ("pos0", ms), ("tables", (ms + 1) * mb)],
+                    lora, sampled, ms)
 
 
 def _verify_layout(ms, mb, d, lora=False, sampled=False):
@@ -88,18 +76,8 @@ def _verify_layout(ms, mb, d, lora=False, sampled=False):
     slot/position/block-table fields (plus the adapter-slot row when
     LoRA serving is on and the sampling-spec rows for the
     rejection-sampled verify)."""
-    fields = [("tokens", ms * (d + 1)), ("dlen", ms),
-              ("token_seq", ms), ("pos0", ms),
-              ("tables", (ms + 1) * mb)]
-    if lora:
-        fields.append(("seq_adapters", ms + 1))
-    if sampled:
-        fields.append(("sample_meta", SAMPLE_META_ROWS * ms))
-    o, lay = 0, {}
-    for name, size in fields:
-        lay[name] = (o, o + size)
-        o += size
-    return lay
+    return _offsets([("tokens", ms * (d + 1)), ("dlen", ms), ("token_seq", ms),
+                     ("pos0", ms), ("tables", (ms + 1) * mb)], lora, sampled, ms)
 
 
 class AsyncBurstHandle:
@@ -487,13 +465,10 @@ class InferenceEngineV2:
         self._burst_fns = OrderedDict()
         self._burst_fn_cap = max(1, int(self._config.burst_fn_cache_cap))
         self.burst_fn_evictions = 0
-        # Pipelined (double-buffered) decode bursts: schedulers consult
-        # this to run the async dispatch/fence pump instead of the
-        # fetch-every-burst loop. OFF state: every pre-pipeline code
-        # path below is untouched — byte-identical program keys.
-        self.async_burst = async_burst_enabled(self._config.async_burst)
-        self.async_burst_depth = max(1, int(getattr(
-            self._config.async_burst, "depth", 2)))
+        # How many decode bursts the scheduler keeps in flight, unfetched.
+        # 0: every burst is fetched in the call that dispatched it; 2 is
+        # the double buffer. One burst program family serves every depth.
+        self.async_burst_depth = max(0, int(self._config.async_burst.depth))
         # Host-sync accounting: host_syncs increments at every pragma'd
         # host-sync site EXECUTION (the graft-lint host-sync rule maps
         # the sites; the counter measures how often serving actually
@@ -954,6 +929,123 @@ class InferenceEngineV2:
             self.burst_fn_evictions += 1
         return fn
 
+    def _replicated_input(self, host):
+        """A host metadata row as a program's argument: replicated over
+        the serving mesh where there is one (the flat batch carries no
+        sharding — only weights/KV do); on one device ``jit`` moves it
+        with the call."""
+        if self.mesh is None:
+            return host
+        return jax.device_put(host, self._replicated)
+
+    def _dispatch_burst(self, rec, batch_uids, batch_tokens, k, sample, prev=None):
+        """Pack and dispatch one ``k``-step burst: the one packer behind
+        :meth:`decode_burst` and :meth:`decode_burst_async`, inside the
+        step record ``rec`` its caller opened. Entry tokens come from the
+        host (``batch_tokens``; ``prev=None``) or chain on the device
+        from ``prev``'s last output row, and with them a sampled burst's
+        DFA state row. → ``(descs, entry_np, out, st)``: ``entry_np`` the
+        host entry row (None when chained), ``out`` the device
+        ``[k, max_seqs]`` tokens, ``st`` the final DFA state row (None
+        for a greedy burst). Nothing is fetched here."""
+        with tracing.phase("engine.pack"):
+            if k < 1:
+                raise ValueError("k must be >= 1")
+            n, ms = len(batch_uids), self.max_seqs
+            mode, specs = self._classify_sample(sample, n)
+            if self.structured is not None and \
+                    any(self.structured.bound(u) for u in batch_uids):
+                mode = "packed"  # constrained rows need their DFA meta rows
+                specs = specs if specs is not None else [None] * n
+            sampled = mode == "packed"
+            if prev is None:
+                if n != len(batch_tokens):
+                    raise ValueError(f"{n} uids vs {len(batch_tokens)} tokens")
+            elif list(prev.uids) != list(batch_uids):
+                raise ValueError(
+                    "chained async burst must keep its predecessor's uid "
+                    "order — drain the pipeline when the live set changes")
+            elif sampled and prev.st is None:
+                raise ValueError(
+                    "sampled async burst chained onto a greedy handle — "
+                    "drain the pipeline before changing decode mode")
+            if n > ms:
+                raise ValueError(f"{n} sequences > max_ragged_sequence_count={ms}")
+            from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
+            descs, err = self._validate_burst(batch_uids, k)
+            if err is not None:
+                raise err
+            if prev is not None:
+                entry_np, entry = None, prev.entry_next  # device row, no sync
+            else:
+                entry_np = np.zeros(ms, np.int32)
+                entry_np[:n] = [int(np.asarray(tok).reshape(-1)[-1]) for tok in batch_tokens]  # ds-lint: disable=host-sync -- host entry tokens are ints the caller already fetched (put()'s outputs, the burst before), not device data
+                entry = self._replicated_input(entry_np)
+
+            lora_on = self.lora_store is not None
+            token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
+            pos0 = np.zeros(ms, np.int32)
+            tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
+            adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
+            for i, desc in enumerate(descs):
+                desc.slot = i
+                if lora_on:
+                    desc.adapter_slot = self.lora_store.slot_of(desc.uid)
+                    adapters[i] = desc.adapter_slot
+                self.state_manager.allocate_for(desc, k)
+                token_seq[i] = i
+                pos0[i] = desc.seen_tokens
+                tables[i, :len(desc.blocks)] = desc.blocks
+                desc.advance(k)
+                rec.n_ctx_tokens += _burst_ctx_tokens(int(pos0[i]), k)
+            parts = [token_seq, pos0, tables.ravel()]
+            # the optional inputs of the program: keys present or absent, and
+            # jit specialises on which. Slabs ride as ARGUMENTS (not captured
+            # constants), so promotions / hot-swaps rebind, never retrace
+            opt = {}
+            if lora_on:
+                parts.append(adapters)
+                opt["lora"] = self.lora_store.slabs()
+            if sampled:
+                for s in specs:
+                    if s is not None and "seed" not in s:
+                        s["seed"] = self.draw_seed()
+                dfa = None
+                if self.structured is not None:
+                    dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
+                           for u in batch_uids]
+                    opt["dfa"] = self.structured.slabs()
+                parts.append(pack_sample_meta(specs, ms, dfa=dfa))
+                opt["base"] = self._base_key
+                if prev is not None:
+                    opt["state"] = prev.st  # device chain — the host DFA mirror lags
+                else:
+                    state = np.zeros(ms, np.int32)
+                    if dfa is not None:
+                        state[:n] = [int(st) for _, st in dfa]
+                    opt["state"] = self._replicated_input(state)
+            meta = np.concatenate(parts)
+            assert meta.shape[0] == sum(e - s for s, e in _burst_layout(
+                ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled).values())
+            meta = self._replicated_input(meta)
+            # Sampled bursts run ONE program regardless of the specs (they are
+            # data), keyed "sampled" plus — when constrained decoding is live —
+            # the DFA slab shape signature, and the LoRA rank-bucket signature
+            # when serving adapters, so a reconfigured store can't replay a
+            # stale program.
+            skey = "sampled" if sampled else None
+            key = ("burst", k, skey)
+            if "dfa" in opt:
+                key = key + (("dfa",) + self.structured.signature(),)
+            if lora_on:
+                key = key + (self.lora_store.signature(),)
+            fn = self._get_burst_fn(key, lambda: self._make_burst_fn(k, skey))
+        with tracing.phase("engine.dispatch"):
+            out, st, self.kv_cache.k, self.kv_cache.v = fn(
+                self.params, self.kv_cache.k, self.kv_cache.v, meta, entry, opt)
+        self.tokens_emitted += k * n
+        return descs, entry_np, out, st
+
     def decode_burst(self, batch_uids, batch_tokens, k, sample=None):
         """Run ``k`` decode steps for one current token per uid in ONE
         compiled program: on-device-sampled tokens feed the next step
@@ -969,96 +1061,19 @@ class InferenceEngineV2:
         tokens ``[k, len(uids)]``.
 
         KV blocks for all ``k`` tokens are reserved up front, so the
-        block tables are static across the burst."""
-        k = int(k)
-        if k < 1:
-            raise ValueError("k must be >= 1")
+        block tables are static across the burst. This is the pipeline
+        with nothing in flight: the burst :meth:`decode_burst_async`
+        dispatches, fetched and logged before the call returns."""
+        k, n = int(k), len(batch_uids)
         with tracing.step("burst", engine=self.trace_id, program=f"burst{k}", k=k,
-                          n_seqs=len(batch_uids), n_tokens=k * len(batch_uids),
-                          uids=tuple(batch_uids)) as rec:
-            with tracing.phase("engine.pack"):
-                mode, specs = self._classify_sample(sample, len(batch_uids))
-                if self.structured is not None and \
-                        any(self.structured.bound(u) for u in batch_uids):
-                    mode = "packed"  # constrained rows need their DFA meta rows
-                    specs = specs if specs is not None else [None] * len(batch_uids)
-                sampled = mode == "packed"
-                if len(batch_uids) != len(batch_tokens):
-                    raise ValueError(f"{len(batch_uids)} uids vs {len(batch_tokens)} tokens")
-                if len(batch_uids) > self.max_seqs:
-                    raise ValueError(f"{len(batch_uids)} sequences > "
-                                     f"max_ragged_sequence_count={self.max_seqs}")
-                from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
-                ms = self.max_seqs
-                descs, err = self._validate_burst(batch_uids, k)
-                if err is not None:
-                    raise err
-
-                lora_on = self.lora_store is not None
-                tokens0 = np.zeros(ms, np.int32)
-                token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
-                pos0 = np.zeros(ms, np.int32)
-                tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
-                adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
-                for i, (desc, tok) in enumerate(zip(descs, batch_tokens)):
-                    desc.slot = i
-                    if lora_on:
-                        desc.adapter_slot = self.lora_store.slot_of(desc.uid)
-                        adapters[i] = desc.adapter_slot
-                    self.state_manager.allocate_for(desc, k)
-                    self.count_host_sync()
-                    tokens0[i] = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- entry tokens come from the previous burst's host copy
-                    token_seq[i] = i
-                    pos0[i] = desc.seen_tokens
-                    tables[i, :len(desc.blocks)] = desc.blocks
-                    desc.advance(k)
-                    rec.n_ctx_tokens += _burst_ctx_tokens(int(pos0[i]), k)
-                parts = [tokens0, token_seq, pos0, tables.ravel()]
-                if lora_on:
-                    parts.append(adapters)
-                if sampled:
-                    for s in specs:
-                        if s is not None and "seed" not in s:
-                            s["seed"] = self.draw_seed()
-                    dfa = None
-                    if self.structured is not None:
-                        dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
-                               for u in batch_uids]
-                    parts.append(pack_sample_meta(specs, ms, dfa=dfa))
-                meta = np.concatenate(parts)
-                assert meta.shape[0] == sum(e - s for s, e in _burst_layout(
-                    ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled).values())
-                if self.mesh is not None:
-                    meta = jax.device_put(meta, self._replicated)
-                # Off-state keys are EXACTLY the pre-feature keys (DS_LORA=0 /
-                # greedy contract); sampled bursts run ONE program regardless of
-                # the specs (they are data), keyed "sampled" plus — when
-                # constrained decoding is live — the DFA slab shape signature,
-                # and the LoRA rank-bucket signature when serving adapters, so a
-                # reconfigured store can't replay a stale program.
-                skey = "sampled" if sampled else None
-                key = ("burst", k, skey)
-                if sampled and self.structured is not None:
-                    key = key + (("dfa",) + self.structured.signature(),)
-                if lora_on:
-                    key = key + (self.lora_store.signature(),)
-                fn = self._get_burst_fn(key, lambda: self._make_burst_fn(k, skey))
-                extra = (self.lora_store.slabs(),) if lora_on else ()
-            with tracing.phase("engine.dispatch"):
-                if skey is None:
-                    out, self.kv_cache.k, self.kv_cache.v = fn(
-                        self.params, self.kv_cache.k, self.kv_cache.v, meta, *extra)
-                else:
-                    sargs = (self._base_key,)
-                    if self.structured is not None:
-                        sargs += (self.structured.slabs(),)
-                    out, self.kv_cache.k, self.kv_cache.v = fn(
-                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                        *sargs, *extra)
-            self.count_host_sync()
-            self.tokens_emitted += k * len(batch_uids)
+                          n_seqs=n, n_tokens=k * n, uids=tuple(batch_uids)) as rec:
+            descs, entry_np, out, _ = self._dispatch_burst(
+                rec, batch_uids, batch_tokens, k, sample)
+            # the fetched form reads its entry row from the host every
+            # burst (one site a row) and pays the fetch
+            self.count_host_sync(n + 1)
             with tracing.phase("engine.fetch"):
-                toks = np.asarray(out)[:, :len(batch_uids)]  # ds-lint: disable=host-sync -- THE one intended sync per k-step burst
+                toks = np.asarray(out)[:, :n]  # ds-lint: disable=host-sync -- THE one intended sync per k-step burst
             with tracing.phase("engine.log"):
                 if self._log_tokens:
                     # log what the burst actually WROTE to the KV cache: step i
@@ -1070,7 +1085,7 @@ class InferenceEngineV2:
                     # prefixes nobody asks for.
                     for i, desc in enumerate(descs):
                         desc.tokens.fence()  # order after drained pipelined segments
-                        desc.tokens.append(int(tokens0[i]))
+                        desc.tokens.append(int(entry_np[i]))
                         desc.tokens.extend(int(t) for t in toks[:-1, i])
             self.last_step = rec
             return toks
@@ -1090,127 +1105,23 @@ class InferenceEngineV2:
         exactly (the scheduler drains the pipeline whenever the live set
         changes). Sampled chains also carry the DFA state row from
         ``prev.st``, so constrained streams stay bit-identical to the
-        sync path. Token-log segments are appended as pending DEVICE
+        fetched form. Token-log segments are appended as pending DEVICE
         segments (:meth:`TokenLog.append_device`); prefix-cache retire,
         suspend and handoff export fence them lazily."""
-        k = int(k)
-        if k < 1:
-            raise ValueError("k must be >= 1")
-        if prev is not None and list(prev.uids) != list(batch_uids):
-            raise ValueError(
-                "chained async burst must keep its predecessor's uid "
-                "order — drain the pipeline when the live set changes")
-        rec = tracing.begin("burst_async", engine=self.trace_id, program=f"aburst{k}", k=k,
-                            n_seqs=len(batch_uids), n_tokens=k * len(batch_uids),
-                            uids=tuple(batch_uids))
+        k, n = int(k), len(batch_uids)
+        rec = tracing.begin("burst_async", engine=self.trace_id, program=f"burst{k}", k=k,
+                            n_seqs=n, n_tokens=k * n, uids=tuple(batch_uids))
         try:
-            with tracing.phase("engine.pack"):
-                mode, specs = self._classify_sample(sample, len(batch_uids))
-                if self.structured is not None and \
-                        any(self.structured.bound(u) for u in batch_uids):
-                    mode = "packed"
-                    specs = specs if specs is not None else [None] * len(batch_uids)
-                sampled = mode == "packed"
-                if sampled and prev is not None and prev.st is None:
-                    raise ValueError(
-                        "sampled async burst chained onto a greedy handle — "
-                        "drain the pipeline before changing decode mode")
-                if len(batch_uids) > self.max_seqs:
-                    raise ValueError(f"{len(batch_uids)} sequences > "
-                                     f"max_ragged_sequence_count={self.max_seqs}")
-                from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
-                ms = self.max_seqs
-                descs, err = self._validate_burst(batch_uids, k)
-                if err is not None:
-                    raise err
-
-                lora_on = self.lora_store is not None
-                token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
-                pos0 = np.zeros(ms, np.int32)
-                tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
-                adapters = np.zeros(ms + 1, np.int32)
-                for i, desc in enumerate(descs):
-                    desc.slot = i
-                    if lora_on:
-                        desc.adapter_slot = self.lora_store.slot_of(desc.uid)
-                        adapters[i] = desc.adapter_slot
-                    self.state_manager.allocate_for(desc, k)
-                    token_seq[i] = i
-                    pos0[i] = desc.seen_tokens
-                    tables[i, :len(desc.blocks)] = desc.blocks
-                    desc.advance(k)
-                    rec.n_ctx_tokens += _burst_ctx_tokens(int(pos0[i]), k)
-                parts = [token_seq, pos0, tables.ravel()]
-                if lora_on:
-                    parts.append(adapters)
-                st0 = None
-                if sampled:
-                    for s in specs:
-                        if s is not None and "seed" not in s:
-                            s["seed"] = self.draw_seed()
-                    dfa = None
-                    if self.structured is not None:
-                        dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
-                               for u in batch_uids]
-                    parts.append(pack_sample_meta(specs, ms, dfa=dfa))
-                    if prev is not None:
-                        st0 = prev.st  # device chain — host DFA mirror lags one burst
-                    else:
-                        st_np = np.zeros(ms, np.int32)
-                        if dfa is not None:
-                            for i, (_, state) in enumerate(dfa):
-                                st_np[i] = int(state)
-                        st0 = jax.device_put(st_np, self._replicated) \
-                            if self.mesh is not None else jnp.asarray(st_np)
-                meta = np.concatenate(parts)
-                assert meta.shape[0] == sum(e - s for s, e in _burst_layout(
-                    ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled,
-                    async_entry=True).values())
-                if self.mesh is not None:
-                    meta = jax.device_put(meta, self._replicated)
-                entry_np = None
-                if prev is not None:
-                    entry = prev.entry_next  # device row, no sync
-                else:
-                    entry_full = np.zeros(ms, np.int32)
-                    for i, tok in enumerate(batch_tokens):
-                        entry_full[i] = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- cold-start entries are host ints (put()'s already-fetched outputs), not device data
-                    entry_np = entry_full[:len(batch_uids)].copy()
-                    entry = jax.device_put(entry_full, self._replicated) \
-                        if self.mesh is not None else jnp.asarray(entry_full)
-                # "aburst" keys are disjoint from the sync "burst" keys by
-                # construction, so DS_ASYNC_BURST=0 replays byte-identical keys
-                skey = "sampled" if sampled else None
-                key = ("aburst", k, skey)
-                if sampled and self.structured is not None:
-                    key = key + (("dfa",) + self.structured.signature(),)
-                if lora_on:
-                    key = key + (self.lora_store.signature(),)
-                fn = self._get_burst_fn(
-                    key, lambda: self._make_burst_fn(k, skey, async_entry=True))
-                extra = (self.lora_store.slabs(),) if lora_on else ()
-                st = None
-            with tracing.phase("engine.dispatch"):
-                if skey is None:
-                    out, self.kv_cache.k, self.kv_cache.v = fn(
-                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                        entry, *extra)
-                else:
-                    sargs = (self._base_key,)
-                    if self.structured is not None:
-                        sargs += (self.structured.slabs(),)
-                    out, st, self.kv_cache.k, self.kv_cache.v = fn(
-                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                        entry, st0, *sargs, *extra)
+            descs, entry_np, out, st = self._dispatch_burst(
+                rec, batch_uids, batch_tokens, k, sample, prev)
         finally:
             # open until AsyncBurstHandle.fetch: the device runs meanwhile
             tracing.suspend(rec)
-        self.tokens_emitted += k * len(batch_uids)
         handle = AsyncBurstHandle(self, batch_uids, descs, k, out, st=st,
                                   entry_np=entry_np, prev=prev, record=rec)
         if self._log_tokens:
             # KV content over [seen, seen+k) = the entry token plus the
-            # first k-1 outputs, exactly like the sync path — but it
+            # first k-1 outputs, exactly like the fetched form — but it
             # stays a pending DEVICE segment until something fences
             for i, desc in enumerate(descs):
                 desc.tokens.append_device(
@@ -1219,14 +1130,16 @@ class InferenceEngineV2:
                         + [int(t) for t in h.fetch()[:-1, i]])
         return handle
 
-    def _make_burst_fn(self, k, skey=None, async_entry=False):
-        """``async_entry=False``: the classic burst program (host entry
-        tokens ride the meta vector; returns ``out, kc, vc``).
-        ``async_entry=True``: the pipelined variant — entry tokens (and,
-        sampled, the DFA state row) arrive as DEVICE arrays chained from
-        the previous burst's outputs, so the host packs burst k+1
-        without ever reading burst k; sampled async programs also return
-        the final DFA state row for the next link."""
+    def _make_burst_fn(self, k, skey=None):
+        """The one burst program family: ``burst(p, kc, vc, meta, tokens0,
+        opt)``. Entry tokens are a device argument (``int32[max_seqs]``),
+        so a burst can start from the host's row or from the burst
+        before without being another program. ``opt`` holds the optional
+        inputs under the keys that are present — ``base`` (sampling base
+        key) and ``state`` (DFA state row) for a sampled program, ``dfa``
+        and ``lora`` (their slabs) when those subsystems are live — and
+        ``jit`` specialises on which. → ``(out, st, kc, vc)``: the final
+        DFA state row for the next link, None from a greedy program."""
         from deepspeed_tpu.inference.v2.model_runner import ragged_forward
         cfg, dtype, mesh = self.model_config, self.dtype, self.mesh
         attn_impl = self._attention
@@ -1234,56 +1147,39 @@ class InferenceEngineV2:
         ms, mb = self.max_seqs, self.max_blocks_per_seq
         lora_on = self.lora_store is not None
         sampled = skey == "sampled"
-        structured_on = sampled and self.structured is not None
 
-        def burst(p, kc, vc, meta, entry=None, st0=None,
-                  base=None, slabs=None, lora_slabs=None):
+        def burst(p, kc, vc, meta, tokens0, opt):
             if quantized:
                 from deepspeed_tpu.inference.quantization import dequantize_tree_except
                 p = dequantize_tree_except(p, dtype)  # once per burst, not per step
-            lay = _burst_layout(ms, mb, lora=lora_on, sampled=sampled,
-                                async_entry=async_entry)
-            tokens0 = entry if async_entry else meta[slice(*lay["tokens0"])]
+            lay = _burst_layout(ms, mb, lora=lora_on, sampled=sampled)
             token_seq = meta[slice(*lay["token_seq"])]
             pos0 = meta[slice(*lay["pos0"])]
             tables = meta[slice(*lay["tables"])].reshape(ms + 1, mb)
             last = jnp.arange(ms, dtype=jnp.int32)
             lora_arg = None
-            if lora_slabs is not None:
-                la, lb, scales = lora_slabs
+            if "lora" in opt:
+                la, lb, scales = opt["lora"]
                 seq_adapters = meta[slice(*lay["seq_adapters"])]
                 lora_arg = (la, lb, scales, seq_adapters, None)
 
-            if not sampled:
-                def one(carry, i):
-                    kc, vc, toks = carry
-                    b = {"token_ids": toks, "token_seq": token_seq,
-                         "token_pos": pos0 + i, "block_tables": tables,
-                         "last_index": last}
-                    sel, kc, vc = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
-                                                 attn_impl=attn_impl, lora=lora_arg)
-                    nxt = jnp.argmax(sel, axis=-1).astype(jnp.int32)
-                    return (kc, vc, nxt), nxt
-
-                (kc, vc, _), out = jax.lax.scan(one, (kc, vc, tokens0),
-                                                jnp.arange(k, dtype=jnp.int32))
-                return out, kc, vc
-
-            temp, topk, topp, seed, slot, state0 = unpack_sample_meta(
-                meta[slice(*lay["sample_meta"])], ms)
-            if async_entry:
-                # DFA state chains on device from the previous burst's
-                # final state row; the meta copy is only the cold-start
-                # value the engine materializes for the first link
-                state0 = st0
+            if sampled:
+                # the state row packed in sample_meta is put()'s; a burst's is
+                # opt["state"], so that it can chain on the device
+                temp, topk, topp, seed, slot, _ = unpack_sample_meta(
+                    meta[slice(*lay["sample_meta"])], ms)
+                base, slabs = opt["base"], opt.get("dfa")
 
             def one(carry, i):
-                kc, vc, toks, st = carry
+                kc, vc, toks, st = carry  # st is None in a greedy burst
                 b = {"token_ids": toks, "token_seq": token_seq,
                      "token_pos": pos0 + i, "block_tables": tables,
                      "last_index": last}
                 sel, kc, vc = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
                                              attn_impl=attn_impl, lora=lora_arg)
+                if not sampled:
+                    nxt = jnp.argmax(sel, axis=-1).astype(jnp.int32)
+                    return (kc, vc, nxt, st), nxt
                 if slabs is not None:
                     sel = apply_dfa_mask(sel, slabs[0], slot, st)
                 # step i's token lands at absolute position pos0 + i + 1,
@@ -1294,50 +1190,11 @@ class InferenceEngineV2:
                     st = slabs[1][slot, st, nxt]  # in-scan DFA advance
                 return (kc, vc, nxt, st), nxt
 
-            (kc, vc, _, st_f), out = jax.lax.scan(one, (kc, vc, tokens0, state0),
-                                                  jnp.arange(k, dtype=jnp.int32))
-            if async_entry:
-                return out, st_f, kc, vc
-            return out, kc, vc
+            (kc, vc, _, st), out = jax.lax.scan(one, (kc, vc, tokens0, opt.get("state")),
+                                                jnp.arange(k, dtype=jnp.int32))
+            return out, st, kc, vc
 
-        # explicit arity wrappers: callers pass everything positionally,
-        # so the slab pytrees must never land in the wrong parameter
-        if async_entry:
-            if not sampled and lora_on:
-                fn = lambda p, kc, vc, meta, entry, lslabs: \
-                    burst(p, kc, vc, meta, entry, lora_slabs=lslabs)
-            elif not sampled:
-                fn = lambda p, kc, vc, meta, entry: \
-                    burst(p, kc, vc, meta, entry)
-            elif structured_on and lora_on:
-                fn = burst
-            elif structured_on:
-                fn = lambda p, kc, vc, meta, entry, st0, base, slabs: \
-                    burst(p, kc, vc, meta, entry, st0, base, slabs)
-            elif lora_on:
-                fn = lambda p, kc, vc, meta, entry, st0, base, lslabs: \
-                    burst(p, kc, vc, meta, entry, st0, base, lora_slabs=lslabs)
-            else:
-                fn = lambda p, kc, vc, meta, entry, st0, base: \
-                    burst(p, kc, vc, meta, entry, st0, base)
-        elif not sampled and lora_on:
-            fn = lambda p, kc, vc, meta, lslabs: \
-                burst(p, kc, vc, meta, lora_slabs=lslabs)
-        elif not sampled:
-            fn = lambda p, kc, vc, meta: burst(p, kc, vc, meta)
-        elif structured_on and lora_on:
-            fn = lambda p, kc, vc, meta, base, slabs, lslabs: \
-                burst(p, kc, vc, meta, base=base, slabs=slabs,
-                      lora_slabs=lslabs)
-        elif structured_on:
-            fn = lambda p, kc, vc, meta, base, slabs: \
-                burst(p, kc, vc, meta, base=base, slabs=slabs)
-        elif lora_on:
-            fn = lambda p, kc, vc, meta, base, lslabs: \
-                burst(p, kc, vc, meta, base=base, lora_slabs=lslabs)
-        else:
-            fn = lambda p, kc, vc, meta, base: burst(p, kc, vc, meta, base=base)
-        return maybe_checkify_jit(fn, donate_argnums=(1, 2),
+        return maybe_checkify_jit(burst, donate_argnums=(1, 2),
                                   enabled=self._sanitize)
 
     # -------------------------------------------- speculative decoding
@@ -1470,15 +1327,14 @@ class InferenceEngineV2:
                 # the verify must see the SAME adapter deltas decode does, or
                 # acceptance silently diverges from stepwise decoding
                 key = ("verify", d) if not sampled else ("verify", d, "sampled")
-                if self.async_burst:
+                packed = self.async_burst_depth > 0
+                if packed:
                     # one-fetch-per-burst: the program concatenates tokens and
                     # accept counts into ONE int32 vector, so the host pays a
-                    # single device→host copy instead of two. A distinct key —
-                    # the off state keeps the exact pre-pipeline keys/programs.
+                    # single device→host copy instead of two. A distinct key.
                     key = key + ("packed",)
                 if lora_on:
                     key = key + (self.lora_store.signature(),)
-                packed = self.async_burst
                 fn = self._get_burst_fn(
                     key, lambda: self._make_verify_fn(d, sampled, packed=packed))
                 extra = (self.lora_store.slabs(),) if lora_on else ()

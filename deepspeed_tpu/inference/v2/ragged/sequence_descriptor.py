@@ -11,9 +11,10 @@ import numpy as np
 
 class UnfencedTokenLogError(RuntimeError):
     """A host read (or host mutation) of a token log that still has
-    device-resident segments pending. Under ``DS_ASYNC_BURST`` the
-    engine appends burst outputs to the log as *device* segments — the
-    host materializes them one burst late, when the pipeline fences.
+    device-resident segments pending. With bursts in flight
+    (``async_burst.depth`` > 0) the engine appends burst outputs to the
+    log as *device* segments — the host materializes them one burst
+    late, when the pipeline fences.
     Any consumer of KV content (prefix-cache retire, tier/handoff
     export, suspend, the n-gram drafter) must go through
     ``TokenLog.fence()`` first; reading around the fence would

@@ -51,9 +51,9 @@ class Request:
         self.prefill_steps = 0
         self.generated = []
         self.next_token = None  # decode token awaiting scheduling
-        # pipelined (async) bursts: tokens dispatched to the device but
-        # not yet fenced/accepted — ``len(generated) + _inflight`` is the
-        # request's true generation frontier while bursts are in flight
+        # tokens dispatched to the device but not yet fenced/accepted —
+        # ``len(generated) + _inflight`` is the request's true generation
+        # frontier; 0 whenever no burst of its is in flight
         self._inflight = 0
         self.done = False
         # paused requests hold scheduler state but take no step work —
@@ -105,14 +105,11 @@ class DynamicSplitFuseScheduler:
         # the serving gateway's streaming hook. None = no streaming.
         self.on_token = on_token
         self.requests = OrderedDict()  # uid -> Request
-        # pipelined bursts (DS_ASYNC_BURST): the pump dispatches burst
-        # k+1 while burst k executes on device and fences one burst
-        # late. Only meaningful for on-device sampling with bursting on;
-        # the off state never touches the pipeline — step() runs the
-        # exact pre-pipeline loop.
-        self.async_burst = bool(getattr(engine, "async_burst", False)) \
-            and self._device_greedy and self.max_burst >= 2
-        self.async_depth = max(1, int(getattr(engine, "async_burst_depth", 2)))
+        # how many bursts stay in flight, unfetched (the engine config's
+        # async_burst.depth): the pump dispatches burst k+1 while burst k
+        # executes on device and fences one burst late. 0 fetches every
+        # burst in the call that dispatched it and the pipeline stays empty
+        self.async_depth = int(getattr(engine, "async_burst_depth", 0))
         self._pipeline = deque()  # (AsyncBurstHandle, [Request]) oldest first
         # seq of the step record the engine wrote last (0: it writes none)
         self.last_step_seq = 0
@@ -293,53 +290,89 @@ class DynamicSplitFuseScheduler:
         self.last_step_seq = rec.seq if rec is not None else 0
         return rec
 
+    def _plan_burst(self, rows):
+        """Burst length for ``rows``, or None when the burst path does
+        not apply this round. ``_inflight`` stands in for generated
+        tokens not fenced yet (0 with nothing in flight; the engine's
+        ``seen_tokens`` advanced at dispatch, so the context-room term
+        needs no correction)."""
+        if (self.max_burst < 2 or not rows or len(rows) > self.engine.max_seqs
+                or len(rows) > self.budget):  # burst must respect the per-step
+            # token budget too: one decode token per live request per
+            # burst step, same bound _plan enforces
+            return None
+        k = min(self.max_burst,
+                min(r.max_new_tokens - len(r.generated) - r._inflight for r in rows),
+                min(self.engine.max_ctx_tokens - self.engine.query(r.uid)[0]
+                    for r in rows))
+        if k < 2:
+            return None
+        k = 1 << (k.bit_length() - 1)  # power-of-two bursts: each distinct
+        # k compiles its own scan program, so an arbitrary tail (15, 14,
+        # 13...) would compile once per value; rounding down bounds the
+        # set to log2(max_burst) programs
+        if not self.engine.can_burst([r.uid for r in rows], k):
+            # KV pool too tight to reserve k tokens per sequence up
+            # front. The stepwise path needs at most one block per
+            # sequence per step and EOS flushes free blocks between
+            # steps, so fall back. (A pre-check, not try/except: a
+            # failure inside the compiled burst would land after state
+            # mutation + KV donation and is not recoverable.)
+            return None
+        return k
+
     def _try_burst(self):
         """All live requests decoding → run a k-step decode burst; None
-        when the burst path doesn't apply this round."""
+        when the burst path doesn't apply this round. At depth 0 the
+        burst is fetched in the call and accepted at once. Otherwise it
+        joins the pipeline — entry tokens chained on the device from the
+        burst before — and the oldest burst is fenced once more than
+        ``async_depth`` are in flight; whatever breaks the chain (live
+        set changed, tail too short, pool too tight, a fenced row
+        finished) drains it."""
         with tracing.phase("sched.plan"):
             live = self._live()
-            if (self.max_burst < 2 or not live or len(live) > self.engine.max_seqs
-                    or len(live) > self.budget  # burst must respect the per-step
-                    # token budget too: one decode token per live request per
-                    # burst step, same bound _plan enforces
-                    or any(r.next_token is None for r in live)):
-                return None
-            k = min(self.max_burst,
-                    min(r.max_new_tokens - len(r.generated) for r in live),
-                    min(self.engine.max_ctx_tokens - self.engine.query(r.uid)[0]
-                        for r in live))
-            if k < 2:
-                return None
-            k = 1 << (k.bit_length() - 1)  # power-of-two bursts: each distinct
-            # k compiles its own scan program, so an arbitrary tail (15, 14,
-            # 13...) would compile once per value; rounding down bounds the
-            # set to log2(max_burst) programs
-            uids = [r.uid for r in live]
-            if not self.engine.can_burst(uids, k):
-                # KV pool too tight to reserve k tokens per sequence up
-                # front. The stepwise path needs at most one block per
-                # sequence per step and EOS flushes free blocks between
-                # steps, so fall back. (A pre-check, not try/except: a
-                # failure inside the compiled burst would land after state
-                # mutation + KV donation and is not recoverable.)
-                return None
-            entry = [r.next_token for r in live]
-            sample = self._sample_arg(live)
-        toks = self.engine.decode_burst(uids, entry, k, sample=sample)
+            prev, prev_rows = self._pipeline[-1] if self._pipeline else (None, None)
+            # a chained burst continues the rows of the burst before; a
+            # cold start needs every row's last token on the host
+            fits = live == prev_rows if prev is not None \
+                else all(r.next_token is not None for r in live)
+            k = self._plan_burst(live) if fits else None
+            if k is not None:
+                uids = [r.uid for r in live]
+                entry = None if prev is not None else [r.next_token for r in live]
+                sample = self._sample_arg(live)
+        if k is None:
+            return self._drain_pipeline() if prev is not None else None
+        for r in live:
+            r.next_token = None
+            r._inflight += k
+        if not self.async_depth:
+            # looked up on the engine at call time: whoever rebinds
+            # decode_burst on the instance sees every fetched burst
+            self._accept_burst(self.engine.decode_burst(uids, entry, k, sample=sample), live)
+            return uids
+        self._pipeline.append((self.engine.decode_burst_async(
+            uids, entry, k, sample=sample, prev=prev), live))
+        if len(self._pipeline) > self.async_depth:
+            self._fence_one()
+            if any(r.done for r in live):
+                self._drain_pipeline()  # EOS discovered one burst late
+        return uids
+
+    def _accept_burst(self, toks, rows, settle=True):
+        """Accept a fetched burst's ``[k, len(rows)]`` tokens, oldest
+        step first. A row that ends mid-burst leaves the rest of its
+        ``_inflight`` as debt: KV positions the burst advanced past the
+        end, which hold post-EOS garbage the rewind reclaims."""
         self._ran()
         with tracing.phase("sched.accept"):
-            for r in live:
-                r.next_token = None
-            for step_i in range(k):
-                for j, r in enumerate(live):
+            for step_toks in toks:
+                for r, tok in zip(rows, step_toks):
                     if r.done:
-                        continue  # hit EOS mid-burst; later rows are discarded
-                    # the burst advanced KV by all k tokens; if generation
-                    # ends HERE, positions past entry + the first step_i
-                    # outputs hold post-EOS garbage the rewind reclaims
-                    self._accept_token(r, int(toks[step_i, j]),
-                                       unused_tokens=k - step_i - 1)
-        return uids
+                        continue  # ended in an earlier step; later rows are discarded
+                    r._inflight -= 1
+                    self._accept_token(r, int(tok), settle=settle)
 
     def _spec_of(self, r):
         """The sampling spec governing request ``r``: its own, else the
@@ -419,14 +452,15 @@ class DynamicSplitFuseScheduler:
                     self._accept_token(r, int(toks[j, e]), unused_tokens=a - e)
         return uids
 
-    def _accept_token(self, r, tok, unused_tokens=0):
-        """Record a generated token; finish + flush on EOS/max_new_tokens
-        (single copy of the completion semantics for the stepwise, burst
-        and speculative paths). ``unused_tokens``: KV positions the
-        engine advanced past this token (burst/verify reservations run
-        to their planned end); on completion they are rewound first so
-        retire frees them — and the prefix cache never content-addresses
-        post-EOS garbage."""
+    def _accept_token(self, r, tok, unused_tokens=0, settle=True):
+        """Record a generated token; finish on EOS/max_new_tokens (single
+        copy of the completion semantics for the stepwise, burst,
+        pipelined and speculative paths). ``unused_tokens``: KV positions
+        the engine advanced past this token (burst/verify reservations
+        run to their planned end) on top of the request's ``_inflight``
+        debt. ``settle=False`` leaves the engine side of an ending to
+        :meth:`_drain_pipeline`: younger bursts are still running over
+        the sequence's KV reservation."""
         r.generated.append(tok)
         if len(r.generated) == 1:
             r.first_token_seq = self.last_step_seq
@@ -438,165 +472,72 @@ class DynamicSplitFuseScheduler:
         if (self.eos_token_id is not None and tok == self.eos_token_id) \
                 or len(r.generated) >= r.max_new_tokens:
             r.done = True
-            if unused_tokens:
-                self.engine.rewind(r.uid, unused_tokens)
-            self.engine.flush(r.uid)
+            r.next_token = None
+            r._inflight += unused_tokens
+            if settle:
+                self._settle(r)
         else:
             r.next_token = tok
         if self.on_token is not None:
             self.on_token(r.uid, tok, r.done)
 
-    # ---------------------------------------------- pipelined (async) bursts
+    def _settle(self, r):
+        """The engine side of an ending: rewind the KV positions
+        dispatched past the request's last token (``_inflight``), so
+        retire frees them and the prefix cache never content-addresses
+        post-EOS garbage, then flush."""
+        if r._inflight:
+            self.engine.rewind(r.uid, r._inflight)
+            r._inflight = 0
+        self.engine.flush(r.uid)
+
+    # ------------------------------------------------------ the pipeline
     def _drain_if_inflight(self, r):
         """Settle the whole pipeline when ``r`` has unfenced bursts in
         it (cancel/pause must observe the request's final state)."""
         if r._inflight:
             self._drain_pipeline()
 
-    def _plan_async_k(self, rows):
-        """Burst length for the next pipeline link, or None when the
-        burst path no longer applies. Mirrors :meth:`_try_burst`'s k
-        computation exactly, with ``_inflight`` standing in for the
-        not-yet-fenced generated tokens (the engine's ``seen_tokens``
-        already advanced at dispatch, so the context-room term needs no
-        correction)."""
-        if len(rows) > self.budget or len(rows) > self.engine.max_seqs:
-            return None
-        k = min(self.max_burst,
-                min(r.max_new_tokens - len(r.generated) - r._inflight
-                    for r in rows),
-                min(self.engine.max_ctx_tokens - self.engine.query(r.uid)[0]
-                    for r in rows))
-        if k < 2:
-            return None
-        return 1 << (k.bit_length() - 1)  # power-of-two, see _try_burst
-
-    def _accept_async(self, r, tok):
-        """Fence-time accept: exactly :meth:`_accept_token` minus the
-        completion-side engine work (rewind/flush), which MUST wait for
-        the full pipeline drain — younger bursts are still executing
-        over this sequence's KV reservation."""
-        r._inflight -= 1
-        r.generated.append(tok)
-        if len(r.generated) == 1:
-            r.first_token_seq = self.last_step_seq
-        if r.schema is not None:
-            self.engine.advance_schema(r.uid, tok)
-        if (self.eos_token_id is not None and tok == self.eos_token_id) \
-                or len(r.generated) >= r.max_new_tokens:
-            r.done = True
-            r.next_token = None
-        else:
-            r.next_token = tok
-        if self.on_token is not None:
-            self.on_token(r.uid, tok, r.done)
-
     def _fence_one(self):
         """Fence the OLDEST in-flight burst (the one device→host copy it
-        ever pays) and accept its tokens; post-EOS rows skip the tail —
-        their ``_inflight`` debt is rewound at drain time."""
+        ever pays) and accept its tokens; rows that end skip the tail —
+        their ``_inflight`` debt is settled at drain time."""
         handle, rows = self._pipeline.popleft()
-        toks = handle.fetch()
-        self._ran()
-        with tracing.phase("sched.accept"):
-            for step_i in range(handle.k):
-                for j, r in enumerate(rows):
-                    if r.done:
-                        continue  # finished mid-pipeline; tail is debt
-                    self._accept_async(r, int(toks[step_i, j]))
+        self._accept_burst(handle.fetch(), rows, settle=False)
         return [r.uid for r in rows]
 
     def _drain_pipeline(self):
         """Fence every in-flight burst in dispatch order, then settle
-        finished rows: rewind the speculatively-dispatched tail
-        (``_inflight`` debt — KV positions past EOS/max_new) and flush,
-        matching what the sync paths do per-burst at accept time."""
+        the rows that ended, as the fetched form does at accept time."""
         uids = []
-        settled = []
+        rows = self._pipeline[-1][1] if self._pipeline else []
         while self._pipeline:
-            _, rows = self._pipeline[0]
             uids = self._fence_one()
-            for r in rows:
-                if r not in settled:
-                    settled.append(r)
-        for r in settled:
-            if r.done:
-                if r._inflight:
-                    self.engine.rewind(r.uid, r._inflight)
-                    r._inflight = 0
-                self.engine.flush(r.uid)
-        return uids
-
-    def _pipeline_rows(self):
-        return self._pipeline[-1][1]
-
-    def _continue_pipeline(self):
-        """Pipeline non-empty: dispatch the next chained burst (host
-        packs while the device runs), then fence one burst late. Any
-        condition that breaks the chain — live set changed, tail too
-        short, pool too tight, a fenced row finished — drains."""
-        with tracing.phase("sched.plan"):
-            rows = self._pipeline_rows()
-            live = self._live()
-            chainable = live == rows and not any(r.done for r in rows)
-            k = self._plan_async_k(rows) if chainable else None
-            uids = [r.uid for r in rows]
-            chain = k is not None and self.engine.can_burst(uids, k)
-            sample = self._sample_arg(rows) if chain else None
-        if not chain:
-            return self._drain_pipeline()
-        handle = self.engine.decode_burst_async(
-            uids, None, k, sample=sample, prev=self._pipeline[-1][0])
         for r in rows:
-            r._inflight += k
-        self._pipeline.append((handle, rows))
-        if len(self._pipeline) > self.async_depth:
-            self._fence_one()
-            if any(r.done for r in rows):
-                self._drain_pipeline()  # EOS discovered one burst late
-        return uids
-
-    def _try_async_start(self):
-        """Pipeline cold start: same applicability test as
-        :meth:`_try_burst`, but the burst is dispatched WITHOUT a fetch
-        — the fence lands ``async_depth`` bursts later."""
-        with tracing.phase("sched.plan"):
-            live = self._live()
-            if (not live or len(live) > self.engine.max_seqs
-                    or len(live) > self.budget
-                    or any(r.next_token is None for r in live)):
-                return None
-            k = self._plan_async_k(live)
-            if k is None:
-                return None
-            uids = [r.uid for r in live]
-            if not self.engine.can_burst(uids, k):
-                return None  # tight pool: fall back, see _try_burst
-            entry = [[r.next_token] for r in live]
-            sample = self._sample_arg(live)
-        handle = self.engine.decode_burst_async(uids, entry, k, sample=sample)
-        for r in live:
-            r.next_token = None
-            r._inflight += k
-        self._pipeline.append((handle, live))
+            if r.done:
+                self._settle(r)
         return uids
 
     def step(self):
-        """Schedule + run one engine step; returns the uids stepped."""
-        if self.async_burst and self._pipeline:
-            # in-flight bursts continue (or drain) before anything else
-            # — spec/stepwise paths need the fenced host state
-            return self._continue_pipeline()
-        stepped = self._try_spec_burst()
-        if stepped is not None:
-            return stepped
-        if self.async_burst:
-            stepped = self._try_async_start()
+        """Schedule + run one engine step; returns the uids stepped.
+
+        The order of the decode paths is decided here and nowhere else.
+        The drafter goes first, and a running pipeline yields to it: it
+        proposes from tokens the host has fetched, which a pipeline
+        learns one burst late, so with speculative decoding armed the
+        burst in flight is drained before the next is planned and the
+        drafter gets the turn it gets at depth 0, before every burst.
+        Without a drafter the pipeline continues (or drains) before
+        anything else — the stepwise path needs the fenced host state."""
+        if self._pipeline and getattr(self.engine, "spec", None) is not None:
+            return self._drain_pipeline()
+        if not self._pipeline:
+            stepped = self._try_spec_burst()
             if stepped is not None:
                 return stepped
-        burst = self._try_burst()
-        if burst is not None:
-            return burst
+        stepped = self._try_burst()
+        if stepped is not None:
+            return stepped
         with tracing.phase("sched.plan"):
             uids, chunks = self._plan()
             if not uids:

@@ -741,7 +741,7 @@ class ServingGateway:
                 "host_syncs": int(syncs),
                 "tokens_emitted": int(engine.tokens_emitted),
                 "syncs_per_token": engine.syncs_per_generated_token,
-                "async_burst": int(getattr(engine, "async_burst", 0)),
+                "async_burst": int(getattr(engine, "async_burst_depth", 0)),
             }
         return groups
 

@@ -1,8 +1,8 @@
 """The persistent XLA compile cache, for the entry points that run on the chip.
 
 Every process on the chip otherwise recompiles every program. The entry
-points (``chip_smoke.py``, ``bench.py``, ``bench_sweep.py``,
-``bin/ds_serve``, ``bin/ds_replica``) call :func:`enable_compile_cache`
+points (``chip_smoke.py``, ``benchmark/run.py``, ``bin/ds_serve``,
+``bin/ds_replica``) call :func:`enable_compile_cache`
 once, before their first compilation. It is deliberately NOT a side
 effect of importing the package or of building an engine: the CPU test
 suite compiles thousands of programs and must not fill the checkout.

@@ -303,15 +303,6 @@ register("DS_CONSTRAINED", "optional_bool", None,
          "unchanged).",
          "deepspeed_tpu/inference/structured/__init__.py",
          tuning="offline")
-register("DS_ASYNC_BURST", "optional_bool", None,
-         "Kill switch for pipelined (double-buffered) decode bursts: "
-         "the host plans burst k+1 while burst k executes and fences "
-         "one burst late; set it wins in both directions, unset defers "
-         "to the engine config's async_burst.enabled. Off rebuilds the "
-         "exact pre-pipeline loop (program keys unchanged); the emitted "
-         "streams are bit-identical either way.",
-         "deepspeed_tpu/inference/v2/engine_v2.py",
-         tuning="offline")
 register("DS_SPEC_DECODE", "optional_bool", None,
          "Kill switch for self-speculative decoding (n-gram drafting + "
          "batched verify); set it wins in both directions, unset defers "

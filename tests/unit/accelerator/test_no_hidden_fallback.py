@@ -78,23 +78,6 @@ def test_interpret_mode_is_asked_for_never_inferred_from_the_backend(monkeypatch
     assert default_interpret() is True
 
 
-def test_bench_needs_a_chip_and_a_known_device_kind():
-    import bench
-
-    class Device:
-        device_kind = "TPU v5 lite"
-
-    assert bench._peak_flops(Device()) == 197e12
-    Device.device_kind = "TPU v9 imaginary"
-    with pytest.raises(ValueError, match="no peak FLOP/s on record.*TPU v9 imaginary"):
-        bench._peak_flops(Device())
-    Device.device_kind = "cpu"
-    with pytest.raises(ValueError):
-        bench._peak_flops(Device())
-    with pytest.raises(SystemExit, match="platform 'cpu'.*nothing was run"):
-        bench.main()
-
-
 def test_tpu_accelerator_does_not_guess():
     from deepspeed_tpu.accelerator.tpu_accelerator import TPU_Accelerator
     acc = TPU_Accelerator()

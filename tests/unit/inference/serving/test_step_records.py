@@ -29,12 +29,12 @@ def model_and_params():
     return model, params
 
 
-def make_engine(model_and_params, async_on=False, spec=False, prefix=False, n_seqs=4,
+def make_engine(model_and_params, depth=0, spec=False, prefix=False, n_seqs=4,
                 batch=32, max_context=96):
     model, params = model_and_params
     cfg = RaggedInferenceEngineConfig(
         kv_block_size=8, num_kv_blocks=0,
-        async_burst=AsyncBurstConfig(enabled=async_on, depth=2),
+        async_burst=AsyncBurstConfig(depth=depth),
         spec_decode=SpecDecodeConfig(enabled=spec),
         prefix_cache=PrefixCacheConfig(enabled=prefix),
         state_manager=DSStateManagerConfig(max_ragged_batch_size=batch,
@@ -92,7 +92,7 @@ def run_async(engine):
     # the burst's seq is older than the put's (it was opened first); it ended later
     assert [r["kind"] for r in records_of(engine.trace_id, mark)] == ["put", "burst_async"]
     return dict(kind="burst_async", k=2, n_seqs=2, n_tokens=4, n_prompt_tokens=0,
-                program="aburst2", after=mark,
+                program="burst2", after=mark,
                 phases=["ds.engine.pack", "ds.engine.dispatch", "ds.engine.fetch"])
 
 
@@ -128,7 +128,7 @@ def test_every_path_that_runs_a_program_writes_one_record(model_and_params, path
                     phases=["ds.train.prepare", "ds.train.timer_sync", "ds.train.dispatch",
                             "ds.train.sync", "ds.train.timer_sync", "ds.train.post"])
     else:
-        engine = make_engine(model_and_params, async_on=path == "async_burst_and_fetch",
+        engine = make_engine(model_and_params, depth=2 * (path == "async_burst_and_fetch"),
                              spec=path == "verify_burst")
         want = {"put": run_put, "decode_burst": run_burst, "async_burst_and_fetch": run_async,
                 "verify_burst": run_verify}[path](engine)
@@ -173,8 +173,8 @@ def test_the_scheduler_says_which_tokens_are_prompt(model_and_params):
 
 
 # ------------------------------------------------------------------ the gateway
-def serve(model_and_params, prompts, max_new=6, async_on=False, **config):
-    engine = make_engine(model_and_params, async_on=async_on, n_seqs=8, batch=16)
+def serve(model_and_params, prompts, max_new=6, depth=0, **config):
+    engine = make_engine(model_and_params, depth=depth, n_seqs=8, batch=16)
     mark = last_seq()
     gw = ServingGateway(engine, config=ServingConfig(token_budget=16, **config))
     handles = [gw.submit(p, max_new_tokens=max_new) for p in prompts]
@@ -183,10 +183,10 @@ def serve(model_and_params, prompts, max_new=6, async_on=False, **config):
     return engine, gw, handles, mark
 
 
-@pytest.mark.parametrize("async_on", [False, True])
-def test_request_stamps_are_ordered_and_point_at_records_that_exist(model_and_params, async_on):
+@pytest.mark.parametrize("depth", [0, 2])
+def test_request_stamps_are_ordered_and_point_at_records_that_exist(model_and_params, depth):
     prompts = [np.arange(1, 1 + n, dtype=np.int32) for n in (5, 40, 12, 3)]   # 40 = three chunks
-    engine, gw, handles, mark = serve(model_and_params, prompts, async_on=async_on)
+    engine, gw, handles, mark = serve(model_and_params, prompts, depth=depth)
     gw.drain(timeout=60)
     steps = records_of(engine.trace_id, mark)
     by_seq = {r["seq"]: r for r in steps}
@@ -215,7 +215,7 @@ def test_request_stamps_are_ordered_and_point_at_records_that_exist(model_and_pa
     pumps = {r["seq"] for r in steps if r["kind"] == "pump"}
     engine_records = [r for r in steps if r["kind"] != "pump"]
     assert engine_records and all(r["caused_by"] in pumps for r in engine_records)
-    assert {r["kind"] for r in engine_records} >= {"put", "burst_async" if async_on else "burst"}
+    assert {r["kind"] for r in engine_records} >= {"put", "burst_async" if depth else "burst"}
     # a pump pass holds admit, then the scheduler's phases around the engine call, then deliver
     busy = [r for r in steps if r["kind"] == "pump" and "ds.sched.plan" in names(r)]
     assert busy and all(names(r)[0] == "ds.gateway.admit" and names(r)[-1] == "ds.gateway.deliver"

@@ -1,19 +1,20 @@
-"""Pipelined (double-buffered) decode bursts: DS_ASYNC_BURST.
+"""One decode-burst path at every pipeline depth (``async_burst.depth``).
 
-Contract under test: with the pipeline on, the host plans/dispatches
-burst k+1 while burst k executes and consumes its results ONE burst
-late through a single packed device→host copy — and every stream
-(greedy, sampled, schema-constrained, speculative, replayed) is
-BIT-IDENTICAL to the synchronous path, because entry tokens and DFA
-states chain on device and the counter PRNG keys randomness by
-absolute position, not burst shape. EOS discovered mid-pipeline
+Contract under test: depth 0 fetches every burst in the call that
+dispatched it; at depth 2 the host plans/dispatches burst k+1 while
+burst k executes and consumes its results late through a single
+device→host copy — and every stream (greedy, sampled,
+schema-constrained, speculative, replayed) is BIT-IDENTICAL between
+the two, because both run ONE program family whose entry tokens and
+DFA states are device arguments and the counter PRNG keys randomness
+by absolute position, not burst shape. EOS discovered mid-pipeline
 settles at drain time (rewind of the speculatively-dispatched tail +
 flush) with exact pool accounting; sequence token logs stay
 device-resident until something fences, and an unfenced host read is
-a typed error, never a silent sync; the DS_ASYNC_BURST kill switch
-wins both ways and the off path compiles byte-identical program keys;
-the burst-program cache absorbs the pipelined program set with zero
-evictions; and syncs-per-generated-token drops >= 4x."""
+a typed error, never a silent sync; a running pipeline yields to the
+drafter; whoever rebinds ``engine.decode_burst`` on an instance sees
+every fetched burst; the burst-program cache absorbs the program set
+with zero evictions; and syncs-per-generated-token drops >= 4x."""
 
 import numpy as np
 import pytest
@@ -29,7 +30,7 @@ from deepspeed_tpu.inference.v2 import (DSStateManagerConfig,
                                         RaggedInferenceEngineConfig,
                                         SpecDecodeConfig, StructuredConfig)
 from deepspeed_tpu.inference.v2.config_v2 import AsyncBurstConfig
-from deepspeed_tpu.inference.v2.engine_v2 import async_burst_enabled
+from deepspeed_tpu.inference.v2.engine_v2 import _burst_layout
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import (
     TokenLog, UnfencedTokenLogError)
 from deepspeed_tpu.models import build_llama
@@ -52,14 +53,14 @@ def model_and_params():
     return model, params
 
 
-def make_engine(model_and_params, async_on, depth=2, spec=False,
+def make_engine(model_and_params, depth, spec=False,
                 structured=False, prefix=False, n_seqs=4, max_context=128,
                 batch=64):
     model, params = model_and_params
     cfg = RaggedInferenceEngineConfig(
         kv_block_size=8,
         num_kv_blocks=0,
-        async_burst=AsyncBurstConfig(enabled=async_on, depth=depth),
+        async_burst=AsyncBurstConfig(depth=depth),
         spec_decode=SpecDecodeConfig(enabled=spec),
         structured=StructuredConfig(enabled=structured),
         prefix_cache=PrefixCacheConfig(enabled=prefix),
@@ -93,74 +94,96 @@ def greedy_reqs(uids):
 # ------------------------------------------------------ streams bit-identical
 class TestStreamsBitIdentical:
 
-    def test_greedy_matches_sync_and_engages_pipeline(self, model_and_params):
-        eng_off = make_engine(model_and_params, async_on=False)
-        want = run_fleet(eng_off, greedy_reqs([1, 2, 3]), max_new=21)
-        # the off path never compiled a pipelined program: its key set
-        # is byte-identical to the pre-pipeline engine's
-        assert all(key[0] == "burst" for key in eng_off._burst_fns)
-        eng_off.destroy()
-        eng = make_engine(model_and_params, async_on=True)
-        got = run_fleet(eng, greedy_reqs([1, 2, 3]), max_new=21)
-        assert got == want
-        # ...and the async path actually engaged (not a vacuous pass)
-        assert any(key[0] == "aburst" for key in eng._burst_fns)
+    def test_greedy_matches_depth0_and_engages_pipeline(self, model_and_params):
+        eng0 = make_engine(model_and_params, depth=0)
+        want = run_fleet(eng0, greedy_reqs([1, 2, 3]), max_new=21)
+        eng0.destroy()
+        eng = make_engine(model_and_params, depth=2)
+        sched = DynamicSplitFuseScheduler(eng, token_budget=48, max_burst=8)
+        for uid, p, _, _ in greedy_reqs([1, 2, 3]):
+            sched.add_request(uid, p, max_new_tokens=21)
+        deepest = 0
+        while sched.has_work:
+            sched.step()
+            deepest = max(deepest, len(sched._pipeline))
+        assert {u: r.generated for u, r in sched.requests.items()} == want
+        # ...and the pipeline actually engaged (not a vacuous pass)
+        assert deepest == 2
         eng.destroy()
 
-    def test_sampled_streams_match_sync(self, model_and_params):
+    def test_sampled_streams_match_depth0(self, model_and_params):
         specs = [{"temperature": 0.9 + 0.2 * i, "top_k": 20 + 10 * i,
                   "seed": 100 + i} for i in range(3)]
         reqs = [(i, PROMPT + i, specs[i], None) for i in range(3)]
         outs = {}
-        for async_on in (False, True):
-            eng = make_engine(model_and_params, async_on=async_on)
-            outs[async_on] = run_fleet(eng, reqs, max_new=18)
+        for depth in (0, 2):
+            eng = make_engine(model_and_params, depth=depth)
+            outs[depth] = run_fleet(eng, reqs, max_new=18)
             eng.destroy()
-        assert outs[True] == outs[False]
+        assert outs[2] == outs[0]
 
-    def test_constrained_sampled_streams_match_sync(self, model_and_params):
+    def test_constrained_sampled_streams_match_depth0(self, model_and_params):
         outs = {}
-        for async_on in (False, True):
-            eng = make_engine(model_and_params, async_on=async_on,
+        for depth in (0, 2):
+            eng = make_engine(model_and_params, depth=depth,
                               structured=True)
             vocab = byte_vocab(eng.structured.vocab_size)
             compiled = CompiledSchema(SCHEMA, vocab, eos_token_id=EOS)
             reqs = [(i, PROMPT + i,
                      {"temperature": 1.2, "top_k": 30, "seed": 50 + i},
                      compiled) for i in range(3)]
-            outs[async_on] = run_fleet(eng, reqs, max_new=64, eos=EOS,
+            outs[depth] = run_fleet(eng, reqs, max_new=64, eos=EOS,
                                        retire=True)
             eng.destroy()
-        assert outs[True] == outs[False]
+        assert outs[2] == outs[0]
         # the schema's finite language terminated every lane at EOS —
         # i.e. EOS landed mid-pipeline and the drain settled it
-        for toks in outs[True].values():
+        for toks in outs[2].values():
             assert toks[-1] == EOS
 
     def test_spec_decode_partial_acceptance_matches(self, model_and_params):
-        # repetitive prompts keep the n-gram drafter winning some and
-        # losing some — partial acceptance on both engines
+        # The rule scheduler.step() states: a running pipeline yields to
+        # the drafter. It proposes from tokens the host has fetched, so
+        # with speculative decoding armed the burst in flight is drained
+        # before the next is planned, and the drafter gets exactly the
+        # turns it gets at depth 0. Repetitive prompts keep it winning
+        # some and losing some — partial acceptance on both engines.
         reqs = [(1, REPETITIVE, None, None), (2, PROMPT, None, None)]
-        outs = {}
-        for async_on in (False, True):
-            eng = make_engine(model_and_params, async_on=async_on, spec=True)
-            outs[async_on] = run_fleet(eng, reqs, max_new=20)
-            assert eng.spec.stats()["verify_steps"] > 0
+        outs, stats = {}, {}
+        for depth in (0, 2):
+            eng = make_engine(model_and_params, depth=depth, spec=True)
+            sched = DynamicSplitFuseScheduler(eng, token_budget=48, max_burst=8)
+            for uid, p, _, _ in reqs:
+                sched.add_request(uid, p, max_new_tokens=20)
+            dispatched = 0
+            while sched.has_work:
+                before = eng.spec.stats()["verify_steps"]
+                sched.step()
+                # never two bursts in flight, and none while the drafter verifies
+                assert len(sched._pipeline) <= 1
+                if eng.spec.stats()["verify_steps"] > before:
+                    assert not sched._pipeline
+                dispatched += len(sched._pipeline)
+            outs[depth] = {u: r.generated for u, r in sched.requests.items()}
+            stats[depth] = eng.spec.stats()
+            assert stats[depth]["verify_steps"] > 0
+            assert bool(dispatched) == bool(depth)  # the pipelined form did run
             eng.destroy()
-        assert outs[True] == outs[False]
+        assert outs[2] == outs[0]
+        assert stats[2] == stats[0]  # same drafts offered, same accepted
 
     def test_failover_replay_reproduces_streams(self, model_and_params):
         # the fleet failover contract: a replica rebuilds a mid-flight
         # stream from (seed, position) alone — replaying the same seeded
-        # requests on a FRESH pipelined engine (and on a sync one) must
+        # requests on a FRESH pipelined engine (and on a depth-0 one) must
         # reproduce the original streams bit-identically
         spec = {"temperature": 1.3, "top_k": 40, "seed": 777}
         reqs = [(9, PROMPT, spec, None)]
-        eng = make_engine(model_and_params, async_on=True)
+        eng = make_engine(model_and_params, depth=2)
         original = run_fleet(eng, reqs, max_new=24)
         eng.destroy()
-        for async_on in (True, False):
-            eng = make_engine(model_and_params, async_on=async_on)
+        for depth in (2, 0):
+            eng = make_engine(model_and_params, depth=depth)
             assert run_fleet(eng, reqs, max_new=24) == original
             eng.destroy()
 
@@ -169,8 +192,8 @@ class TestStreamsBitIdentical:
         # pipeline on, that log spent its life as pending DEVICE
         # segments — content must come out identical
         outs, matches = [], []
-        for async_on in (False, True):
-            eng = make_engine(model_and_params, async_on=async_on,
+        for depth in (0, 2):
+            eng = make_engine(model_and_params, depth=depth,
                               prefix=True)
             out = run_fleet(eng, [(1, REPETITIVE, None, None)], max_new=20)[1]
             hist = list(REPETITIVE) + out
@@ -186,7 +209,7 @@ class TestStreamsBitIdentical:
 class TestDrainAccounting:
 
     def test_mid_pipeline_eos_rewinds_and_frees_blocks(self, model_and_params):
-        eng = make_engine(model_and_params, async_on=True, structured=True)
+        eng = make_engine(model_and_params, depth=2, structured=True)
         free0 = eng.free_blocks
         vocab = byte_vocab(eng.structured.vocab_size)
         compiled = CompiledSchema(SCHEMA, vocab, eos_token_id=EOS)
@@ -202,17 +225,17 @@ class TestDrainAccounting:
         eng.destroy()
 
     def test_max_new_exact_under_pipeline(self, model_and_params):
-        eng = make_engine(model_and_params, async_on=True)
+        eng = make_engine(model_and_params, depth=2)
         out = run_fleet(eng, greedy_reqs([1, 2]), max_new=13)
         assert all(len(toks) == 13 for toks in out.values())
         eng.destroy()
 
     def test_cancel_mid_pipeline_drains_and_survivor_matches(
             self, model_and_params):
-        eng_off = make_engine(model_and_params, async_on=False)
+        eng_off = make_engine(model_and_params, depth=0)
         want = run_fleet(eng_off, greedy_reqs([2]), max_new=21)[2]
         eng_off.destroy()
-        eng = make_engine(model_and_params, async_on=True)
+        eng = make_engine(model_and_params, depth=2)
         sched = DynamicSplitFuseScheduler(eng, token_budget=48, max_burst=8)
         for uid, p, _, _ in greedy_reqs([1, 2]):
             sched.add_request(uid, p, max_new_tokens=21)
@@ -242,7 +265,7 @@ class TestTokenLogFencing:
 
     def test_engine_descriptor_log_fences_through_flush(self,
                                                         model_and_params):
-        eng = make_engine(model_and_params, async_on=True, prefix=True)
+        eng = make_engine(model_and_params, depth=2, prefix=True)
         t = int(eng.put([7], [PROMPT], sample="greedy")[0])
         handle = eng.decode_burst_async([7], [[t]], 4)
         desc = eng.state_manager.query(7)
@@ -257,7 +280,7 @@ class TestTokenLogFencing:
         eng.destroy()
 
     def test_chain_validation_is_typed(self, model_and_params):
-        eng = make_engine(model_and_params, async_on=True)
+        eng = make_engine(model_and_params, depth=2)
         t1 = int(eng.put([1], [PROMPT], sample="greedy")[0])
         t2 = int(eng.put([2], [PROMPT + 1], sample="greedy")[0])
         h = eng.decode_burst_async([1, 2], [[t1], [t2]], 2)
@@ -274,32 +297,93 @@ class TestTokenLogFencing:
         eng.destroy()
 
 
-# --------------------------------------------------- kill switch / programs
-class TestKillSwitch:
+# ------------------------------------------------ one family / the program set
+def mixed_run(eng):
+    """Greedy, sampled and tapering bursts through the scheduler."""
+    run_fleet(eng, greedy_reqs([1, 2]), max_new=21)
+    run_fleet(eng, [(3, PROMPT, {"temperature": 1.0, "seed": 5}, None),
+                    (4, PROMPT + 1, None, None)], max_new=21)
 
-    def test_env_wins_both_directions(self, model_and_params, monkeypatch):
-        monkeypatch.setenv("DS_ASYNC_BURST", "0")
-        eng = make_engine(model_and_params, async_on=True)  # config says on
-        assert not eng.async_burst
-        run_fleet(eng, greedy_reqs([1]), max_new=12)
-        assert all(key[0] == "burst" for key in eng._burst_fns)
+
+class TestOneProgramFamily:
+
+    def test_every_depth_runs_one_family(self, model_and_params):
+        keys = {}
+        for depth in (0, 2):
+            eng = make_engine(model_and_params, depth=depth, spec=True)
+            mixed_run(eng)
+            run_fleet(eng, [(5, REPETITIVE, None, None)], max_new=20)  # verifies
+            keys[depth] = set(eng._burst_fns)
+            eng.destroy()
+            assert {key[0] for key in keys[depth]} == {"burst", "verify"}
+        # the pipeline compiles no burst program the fetched form does not
+        bursts = {d: {k for k in keys[d] if k[0] == "burst"} for d in keys}
+        assert bursts[2] <= bursts[0]
+        assert {k[2] for k in bursts[0]} == {None, "sampled"}
+
+    def test_rebinding_decode_burst_on_the_instance_sees_every_burst(
+            self, model_and_params):
+        # the benchmark's seam (benchmark/harness/spans.py): at depth 0
+        # the scheduler reaches the burst through engine.decode_burst,
+        # looked up at call time, and each call leaves ONE "burst" record
+        # with its four phases closed inside it
+        from deepspeed_tpu.utils import tracing
+        eng = make_engine(model_and_params, depth=0)
+        inner, calls = eng.decode_burst, []
+
+        def counted(batch_uids, batch_tokens, k, *args, **kwargs):
+            calls.append((len(batch_uids), k))
+            return inner(batch_uids, batch_tokens, k, *args, **kwargs)
+
+        eng.decode_burst = counted
+        steps = tracing.RECORDER.steps
+        mark = steps[-1].seq if steps else 0
+        emitted0 = eng.tokens_emitted
+        out = run_fleet(eng, greedy_reqs([1, 2, 3]), max_new=21)
+        records = [r for r in tracing.snapshot()["steps"]
+                   if r["engine"] == eng.trace_id and r["seq"] > mark]
+        bursts = [r for r in records if r["kind"] == "burst"]
+        assert calls and [(r["n_seqs"], r["k"]) for r in bursts] == calls
+        assert {r["kind"] for r in records} == {"put", "burst"}
+        for r in bursts:
+            assert [p[0] for p in r["phases"]] == [
+                "ds.engine.pack", "ds.engine.dispatch", "ds.engine.fetch",
+                "ds.engine.log"]
+            assert r["start_ns"] <= r["phases"][0][1] \
+                and r["phases"][-1][2] <= r["end_ns"]
+            assert r["n_tokens"] == r["n_seqs"] * r["k"] and r["n_ctx_tokens"] > 0
+        # every token came through put or a burst the wrapper saw
+        puts = sum(r["n_seqs"] for r in records if r["kind"] == "put")
+        assert eng.tokens_emitted - emitted0 == puts + sum(n * k for n, k in calls)
+        assert sum(len(t) for t in out.values()) == 3 * 21
         eng.destroy()
-        monkeypatch.setenv("DS_ASYNC_BURST", "1")
-        eng = make_engine(model_and_params, async_on=False)  # config says off
-        assert eng.async_burst
-        run_fleet(eng, greedy_reqs([1]), max_new=12)
-        assert any(key[0] == "aburst" for key in eng._burst_fns)
-        eng.destroy()
-        monkeypatch.delenv("DS_ASYNC_BURST")
-        assert async_burst_enabled(AsyncBurstConfig(enabled=True))
-        assert not async_burst_enabled(AsyncBurstConfig(enabled=False))
+
+    @pytest.mark.parametrize("lora,sampled", [(False, False), (False, True),
+                                              (True, False), (True, True)])
+    def test_burst_layout_is_the_packed_vector(self, lora, sampled):
+        # _dispatch_burst asserts that what it concatenates has this size,
+        # so every burst in this file holds the engine to the layout
+        from deepspeed_tpu.inference.structured.sampling import SAMPLE_META_ROWS
+        ms, mb = 4, 16
+        lay = _burst_layout(ms, mb, lora=lora, sampled=sampled)
+        assert "tokens0" not in lay  # entry tokens are an argument of their own
+        want = ["token_seq", "pos0", "tables"] + ["seq_adapters"] * lora \
+            + ["sample_meta"] * sampled
+        assert list(lay) == want
+        spans = list(lay.values())
+        assert spans[0][0] == 0 and all(a[1] == b[0] for a, b in zip(spans, spans[1:]))
+        assert spans[-1][1] == 2 * ms + (ms + 1) * mb + lora * (ms + 1) \
+            + sampled * SAMPLE_META_ROWS * ms
+
+
+class TestProgramSet:
 
     def test_pipelined_program_set_evicts_nothing(self, model_and_params):
         # the burst_fn_cache_cap reasoning: a steady pipelined trace
         # (greedy + sampled + constrained, every power-of-two tail)
         # must fit the cache with ZERO evictions — an eviction would
         # retrace a hot program every burst and thrash
-        eng = make_engine(model_and_params, async_on=True, structured=True)
+        eng = make_engine(model_and_params, depth=2, structured=True)
         vocab = byte_vocab(eng.structured.vocab_size)
         compiled = CompiledSchema(SCHEMA, vocab, eos_token_id=EOS)
         run_fleet(eng, greedy_reqs([1, 2]), max_new=21)
@@ -321,14 +405,14 @@ class TestKillSwitch:
 class TestSyncCounter:
 
     def test_syncs_per_token_drops_4x(self, model_and_params):
-        # the sync burst path pays (n+1) host syncs per k-step burst
+        # the fetched form pays (n+1) host syncs per k-step burst
         # (n entry-token reads + the fetch); the pipeline pays ONE.
         # 6 sequences, bursts of 8: ~7 syncs/burst vs ~1. Prefill puts
-        # sync identically on both paths, so the claim is measured over
+        # sync identically at both depths, so the claim is measured over
         # the decode phase — the surface the pipeline optimizes.
         ratios = {}
-        for async_on in (False, True):
-            eng = make_engine(model_and_params, async_on=async_on, n_seqs=8)
+        for depth in (0, 2):
+            eng = make_engine(model_and_params, depth=depth, n_seqs=8)
             sched = DynamicSplitFuseScheduler(eng, token_budget=48,
                                               max_burst=8)
             for uid, p, _, _ in greedy_reqs([1, 2, 3, 4, 5, 6]):
@@ -343,10 +427,10 @@ class TestSyncCounter:
             # batches, so a handful of tokens predate the snapshot —
             # the overwhelming majority must still come from bursts
             assert decoded >= 6 * 28
-            ratios[async_on] = (eng.host_syncs - syncs0) / decoded
+            ratios[depth] = (eng.host_syncs - syncs0) / decoded
             assert eng.syncs_per_generated_token == \
                 round(eng.host_syncs / eng.tokens_emitted, 4)
             eng.destroy()
-        drop = ratios[False] / ratios[True]
+        drop = ratios[0] / ratios[2]
         assert drop >= 4.0, \
             f"pipelined bursts must cut syncs/token >=4x, got {drop:.2f}x"

@@ -46,15 +46,15 @@ _HOT_PATHS = {
         "DynamicSplitFuseScheduler._try_burst",
         "DynamicSplitFuseScheduler._try_spec_burst",
         "DynamicSplitFuseScheduler.step",
-        # pipelined (DS_ASYNC_BURST) pump: a stray sync here stalls the
-        # double buffer — the ONE intended sync lives in
+        # the planner and the accept side every burst runs through, and
+        # the pipeline (async_burst.depth > 0): a stray sync here stalls
+        # the double buffer — the ONE intended sync lives in
         # AsyncBurstHandle.fetch, reached via _fence_one
-        "DynamicSplitFuseScheduler._plan_async_k",
-        "DynamicSplitFuseScheduler._accept_async",
+        "DynamicSplitFuseScheduler._plan_burst",
+        "DynamicSplitFuseScheduler._accept_burst",
+        "DynamicSplitFuseScheduler._accept_token",
         "DynamicSplitFuseScheduler._fence_one",
         "DynamicSplitFuseScheduler._drain_pipeline",
-        "DynamicSplitFuseScheduler._continue_pipeline",
-        "DynamicSplitFuseScheduler._try_async_start",
     },
     "serving/gateway.py": {
         "ServingGateway._pump_once",
@@ -69,6 +69,7 @@ _HOT_PATHS = {
         "InferenceEngineV2.put",
         "InferenceEngineV2.decode_burst",
         "InferenceEngineV2.decode_burst_async",
+        "InferenceEngineV2._dispatch_burst",
         "InferenceEngineV2.verify_burst",
         "AsyncBurstHandle.fetch",
     },
@@ -363,6 +364,7 @@ REPLAY_CRITICAL = {
         "InferenceEngineV2.put",
         "InferenceEngineV2.decode_burst",
         "InferenceEngineV2.decode_burst_async",
+        "InferenceEngineV2._dispatch_burst",
         "InferenceEngineV2.verify_burst",
         "InferenceEngineV2.draw_seed",
         "AsyncBurstHandle.fetch",
@@ -371,7 +373,7 @@ REPLAY_CRITICAL = {
         "DynamicSplitFuseScheduler._plan",
         "DynamicSplitFuseScheduler._try_burst",
         "DynamicSplitFuseScheduler._try_spec_burst",
-        "DynamicSplitFuseScheduler._plan_async_k",
+        "DynamicSplitFuseScheduler._plan_burst",
     },
     "inference/structured/prng.py": {"*"},
     "inference/structured/sampling.py": {"*"},
