@@ -226,10 +226,12 @@ def _split_expert_stacks(layers, mesh):
 def _layer_groups(stacks, layer):
     """Every layer's routed experts ``[L, E, in, out]`` → (one table of
     ``L x E`` groups ``[L*E, in, out]``: a bitcast, layer ``layer``'s
-    first group in it). ``ragged_dot`` takes its weights as one buffer,
-    so a layer's experts cut out of the stack would be copied first, in
-    every layer of every step: 2.8 GB for Mixtral's 8, 47 % of the
-    device's time; 1.1 GB for Moonlight's 64 (PERF.md, PRs 28 and 29)."""
+    first group in it). A grouped matmul takes its weights as one buffer
+    (the Pallas kernel's index map and ``ragged_dot``'s group sizes both
+    start at the layer's first group), so a layer's experts cut out of
+    the stack would be copied first, in every layer of every step:
+    2.8 GB for Mixtral's 8, 47 % of the device's time; 1.1 GB for
+    Moonlight's 64 (PERF.md, PRs 28, 29 and 31)."""
     table = jax.tree.map(lambda w: w.reshape((-1,) + w.shape[2:]), stacks)
     return table, layer * jax.tree.leaves(stacks)[0].shape[1]
 
@@ -239,8 +241,9 @@ def _moe_mlp(x, p, k, mesh=None, experts=None, layer=None):
     reference inference/v2 cutlass MoE gather/scatter). At serving time
     capacity dropping is undesirable, so every token reaches its full
     top-k: tokens are replicated k× and pushed through the grouped GEMM
-    (``ops/grouped_gemm.py`` — ``lax.ragged_dot`` over expert-sorted
-    rows), then combined with the renormalized gate weights.
+    (``ops/grouped_gemm.py`` — the Pallas grouped matmul on TPU,
+    ``lax.ragged_dot`` over expert-sorted rows elsewhere), then combined
+    with the renormalized gate weights.
 
     Under a mesh with expert/tensor parallelism the grouped GEMM runs in
     a manual shard_map: each shard holds ``E/ep`` experts (column/row

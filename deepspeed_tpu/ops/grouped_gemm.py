@@ -4,9 +4,11 @@ Capability match for the reference's grouped GEMM usage in MoE inference
 kernels (``deepspeed/inference/v2/kernels/cutlass_ops/mixed_gemm`` /
 ``grouped_gemm``): tokens sorted by expert multiply each expert's weight
 without materializing the [E, capacity, ...] dense dispatch tensor.
-TPU-native: ``jax.lax.ragged_dot`` IS the grouped GEMM — XLA lowers it
-to MXU-tiled loops over contiguous groups, so no Pallas kernel is
-needed for the hot path.
+On TPU the grouped GEMM is the Pallas kernel of
+``ops/pallas/grouped_matmul.py`` (row tiles fitted to the rows a group,
+each expert's weights streamed once, a table of every layer's experts
+read where it lies); ``jax.lax.ragged_dot`` is the same mathematics
+wherever the kernel cannot run (:func:`_use_pallas_gmm`).
 
 ``moe_grouped_mlp`` is the drop-in computation for a top-1/top-k MoE
 FFN over flat tokens; the capacity-based einsum dispatch in
@@ -171,21 +173,24 @@ def sort_by_expert(x, expert_idx, num_experts):
     return x_sorted, group_sizes, unsort
 
 
-_GMM_TILE_M = 256  # measured best on v5e at Mixtral training shapes:
-# tm=128 halves the pad waste but loses more to smaller row tiles, and
-# tm=512 doubles the waste for no kernel gain
+_QUANT_TILE_M = 256  # gmm_quant's row tile at training sizes (no chip has run it: ROADMAP S6)
 
 
 # Tests set this to run the Pallas branch in interpret mode on CPU.
 FORCE_INTERPRET = False
 
 
-def _use_pallas_gmm(num_rows, d_model, d_ff, quantized=False):
-    """The Pallas grouped matmul wins on TPU at training batch sizes
-    (~1.6x ragged_dot, 85% of bf16 peak on v5e); its per-group row-tile
-    padding (up to E*tm rows) drowns tiny decode batches, where
-    ragged_dot stays. CPU (tests) always falls back to ragged_dot
-    unless FORCE_INTERPRET exercises the branch in interpret mode.
+def _use_pallas_gmm(num_rows, num_experts, d_model, d_ff, dtype, quantized=False):
+    """Whether the Pallas grouped matmul (``ops/pallas/grouped_matmul.py``)
+    runs the three expert GEMMs: on TPU, wherever its tiles are legal.
+    Measured against ``ragged_dot`` a call at the four shapes the
+    benchmark's cells serve and both directions of the FFN (PERF.md,
+    PR 31: ``tools/kernel_census.py``), it streams the expert weights at
+    a larger share of the HBM roofline at every one, so ``ragged_dot``
+    stays for what the kernel cannot take: no TPU (CPU tests, unless
+    FORCE_INTERPRET runs the branch in interpret mode), widths that are
+    not lane-aligned, an expert matrix of which not even 128 columns fit
+    a weight block, and fewer rows than experts (the gathered path's).
 
     Both contraction widths must be lane-aligned: the kernel tiles N in
     128-wide lanes, and the gate/up GEMMs have N = d_ff while the down
@@ -193,18 +198,21 @@ def _use_pallas_gmm(num_rows, d_model, d_ff, quantized=False):
     (e.g. a debug preset with d_ff=344) would mosaic-fail inside the
     kernel, so gate on both and let ragged_dot take those shapes.
 
-    QUANTIZED stacks drop the row-count floor: ``gmm_quant`` is
+    QUANTIZED stacks take the kernel at any row count: ``gmm_quant`` is
     bandwidth-bound on carrier bytes while every alternative first
-    materializes dequantized expert slabs, so the fused kernel wins on
-    TPU at any batch size (the caller shrinks the row tile at decode
-    scale instead of falling back)."""
+    materializes dequantized expert slabs."""
     if FORCE_INTERPRET:
         return True
     if jax.devices()[0].platform != "tpu":
         return False
     if d_model % 128 or d_ff % 128:
         return False
-    return quantized or num_rows >= 8 * _GMM_TILE_M
+    if quantized:
+        return True
+    from deepspeed_tpu.ops.pallas.grouped_matmul import col_tile
+    itemsize = jnp.dtype(dtype).itemsize
+    return (num_rows >= num_experts and col_tile(d_model, d_ff, itemsize) is not None
+            and col_tile(d_ff, d_model, itemsize) is not None)
 
 
 def _gathered_moe_mlp(x, expert_idx, w_gate, w_up, w_down, activation):
@@ -240,15 +248,34 @@ def _gathered_moe_mlp(x, expert_idx, w_gate, w_up, w_down, activation):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _gmm_dispatch(xp, w, te, tm, interp):
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _tile_routing(expert_idx, num_experts, tm):
+    """expert_idx [M] → (each row's slot in the tile-aligned layout [M],
+    the owning expert per row tile, the tiles the groups fill):
+    :func:`tile_layout` on the groups' sizes, and a row's slot is its
+    group's first padded row plus its rank in the group (its running
+    count down the one-hot's column). Jitted, so programs that serve the
+    same number of rows share its trace."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import tile_layout
+    oh = (expert_idx[:, None] == jnp.arange(num_experts, dtype=expert_idx.dtype)[None, :]
+          ).astype(jnp.int32)
+    ranks = jnp.cumsum(oh, axis=0)
+    padded_starts, te, _, num_tiles = tile_layout(ranks[-1], expert_idx.shape[0], tm)
+    pdst = jnp.sum(oh * (padded_starts[None, :] + ranks - 1), axis=1)
+    return pdst.astype(jnp.int32), te, num_tiles
+
+
+def _gmm_dispatch(xp, w, te, tm, interp, first_group=None, num_tiles=None):
     """One grouped GEMM on the tile-aligned layout: dense stacks hit
-    :func:`gmm`, quantized stacks the fused :func:`gmm_quant` (dequant
-    target = the activation dtype, matching dequantize-at-entry)."""
+    :func:`gmm` (which reads a table of groups from ``first_group`` and
+    skips the layout's tiles past ``num_tiles``), quantized stacks the
+    fused :func:`gmm_quant` (dequant target = the activation dtype,
+    matching dequantize-at-entry)."""
     from deepspeed_tpu.ops.pallas.grouped_matmul import gmm, gmm_quant
     if _is_quantized(w):
         return gmm_quant(xp, w.values, w.scales, te, w.scheme,
                          jnp.dtype(xp.dtype), tm, 512, 256, interp)
-    return gmm(xp, w, te, tm, 512, 256, interp)
+    return gmm(xp, w, te, tm, interp, first_group=first_group, num_tiles=num_tiles)
 
 
 def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation=jax.nn.silu,
@@ -259,28 +286,32 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
     weight may be a dense stack or a grouped-layout ``QuantizedWeight``
     stack (see module docstring).
 
-    On TPU at training sizes the three GEMMs run in the Pallas grouped
-    matmul (``ops/pallas/grouped_matmul.py``) over a tile-aligned padded
-    row layout; elsewhere ``lax.ragged_dot`` is the dispatch, except at
-    decode scale (rows < experts) where the gathered per-row contraction
-    is both faster and — for quantized stacks — the path that never
-    dequantizes more than the selected slabs. The sorted rows and
-    gate/up activations carry ``checkpoint_name`` tags: under the
-    ``remat_policy="moe"`` training policy exactly these are saved,
-    which is the full residual set the backward needs to skip re-running
-    all three grouped GEMMs (``inter`` rebuilds elementwise from
-    gate/up; the down GEMM's forward is dead code in the rebuild).
+    On TPU the three GEMMs run in the Pallas grouped matmul
+    (``ops/pallas/grouped_matmul.py``) over a tile-aligned padded row
+    layout whose row tile is fitted to the rows a group
+    (:func:`_use_pallas_gmm` says where); elsewhere ``lax.ragged_dot`` is
+    the dispatch, except at decode scale (rows < experts) where the
+    gathered per-row contraction is both faster and — for quantized
+    stacks — the path that never dequantizes more than the selected
+    slabs. The sorted rows and gate/up activations carry
+    ``checkpoint_name`` tags: under the ``remat_policy="moe"`` training
+    policy exactly these are saved, which is the full residual set the
+    backward needs to skip re-running all three grouped GEMMs (``inter``
+    rebuilds elementwise from gate/up; the down GEMM's forward is dead
+    code in the rebuild).
 
     ``first_group`` (a traced scalar; None = the stacks are this call's
     ``num_experts``): the stacks are a table of ``G >= num_experts``
     groups — every layer's experts, say — of which this call's are
     ``first_group .. first_group + num_experts``. The dispatch is chosen
-    on ``num_experts`` as without a table. ``ragged_dot`` and the gathered
-    contraction index the table where it lies (the other groups stay
-    empty; a layer's experts cut out of a stack would be copied first,
-    every call; ``GMM_STATS`` counts these as ``ragged_table`` /
-    ``gathered_table``), the Pallas grouped matmul, which pads every
-    group to a row tile, gets the call's groups cut out."""
+    on ``num_experts`` as without a table, and every dense dispatch
+    indexes the table where it lies (a layer's experts cut out of a
+    stack would be copied first, every call): the Pallas kernel adds
+    ``first_group`` in its weight index map, ``ragged_dot`` and the
+    gathered contraction see the other groups empty. ``GMM_STATS`` counts
+    these as ``pallas_table`` / ``ragged_table`` / ``gathered_table``.
+    Only the fused quantized kernel still gets the call's carriers cut
+    out."""
     from jax.ad_checkpoint import checkpoint_name
     quantized = any(_is_quantized(w) for w in (w_gate, w_up, w_down))
     if quantized and not fused_gmm_enabled():
@@ -289,7 +320,7 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
                                 for w in (w_gate, w_up, w_down))
         quantized = False
     d_ff = _stack_dims(w_gate)[1]
-    use_pallas = _use_pallas_gmm(x.shape[0], x.shape[1], d_ff,
+    use_pallas = _use_pallas_gmm(x.shape[0], num_experts, x.shape[1], d_ff, x.dtype,
                                  quantized=quantized)
     if use_pallas and quantized:
         from deepspeed_tpu.ops.pallas.grouped_matmul import gmm_quant_supported
@@ -298,54 +329,53 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
             or gmm_quant_supported(w.values, w.scales, w.scheme)
             for w in (w_gate, w_up, w_down))
     groups, table = num_experts, ""
-    if first_group is not None and use_pallas:
+    if first_group is not None and use_pallas and quantized:
         w_gate, w_up, w_down = (
             jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, first_group, num_experts), w)
             for w in (w_gate, w_up, w_down))
+        first_group = None
     elif first_group is not None:
-        expert_idx = expert_idx + first_group
-        groups, table = jax.tree.leaves(w_gate)[0].shape[0], "_table"
+        table = "_table"
+        if not use_pallas:
+            expert_idx = expert_idx + first_group
+            groups = jax.tree.leaves(w_gate)[0].shape[0]
     if use_pallas:
-        GMM_STATS.count("pallas_quant" if quantized else "pallas")
-        if FORCE_INTERPRET:
-            tm = min(_GMM_TILE_M, max(8, x.shape[0] // 8))
-        elif quantized and x.shape[0] < 8 * _GMM_TILE_M:
+        GMM_STATS.count(("pallas_quant" if quantized else "pallas") + table)
+        from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile
+        if not quantized:
+            tm = row_tile(x.shape[0], num_experts, x.dtype)
+        elif FORCE_INTERPRET:
+            tm = min(_QUANT_TILE_M, max(8, x.shape[0] // 8))
+        elif x.shape[0] < 8 * _QUANT_TILE_M:
             # decode scale: ~one row tile per routed expert keeps the
             # kernel bound on carrier bytes instead of pad compute
             tm = max(16, -(-x.shape[0] // 8) * 8)
         else:
-            tm = _GMM_TILE_M
-        M = x.shape[0]
-        E = num_experts
+            tm = _QUANT_TILE_M
         # Rank-based routing — no argsort: each row's slot within its
         # expert's padded tile range is its running count (one-hot
         # cumsum, O(M*E) elementwise — E is small). One scatter builds
         # the tile-aligned layout and one gather undoes it. Tagged so
         # the "moe" remat policy saves the routing instead of
         # recomputing it in the backward.
-        from deepspeed_tpu.ops.pallas.grouped_matmul import tile_layout
-        oh = jax.nn.one_hot(expert_idx, E, dtype=jnp.int32)
-        ranks = jnp.cumsum(oh, axis=0)
-        sizes = ranks[-1]
-        rank_in_e = jnp.take_along_axis(ranks, expert_idx[:, None], axis=1)[:, 0] - 1
-        padded_starts, te, Mp = tile_layout(sizes, M, tm)
-        pdst = checkpoint_name(
-            (padded_starts[expert_idx] + rank_in_e).astype(jnp.int32), "moe_routing")
+        pdst, te, num_tiles = _tile_routing(expert_idx, num_experts, tm)
+        pdst = checkpoint_name(pdst, "moe_routing")
         te = checkpoint_name(te, "moe_tiles")
         # rows land in distinct padded slots: the uniqueness hint keeps
         # XLA's scatter (and its gather/scatter-add transposes) parallel.
         # (A gather-based pack via a slot→row map was measured and is
         # slower — the transposed scatter-add in backward gives the
         # saving back with interest.)
-        xp = jnp.zeros((Mp, x.shape[1]), x.dtype).at[pdst].set(
+        xp = jnp.zeros((te.shape[0] * tm, x.shape[1]), x.dtype).at[pdst].set(
             x, unique_indices=True)
         xp = checkpoint_name(xp, "moe_xs")
-        interp = FORCE_INTERPRET
-        gate = checkpoint_name(_gmm_dispatch(xp, w_gate, te, tm, interp), "moe_gate")
-        up = checkpoint_name(_gmm_dispatch(xp, w_up, te, tm, interp), "moe_up")
+        def matmul(rows, w):
+            return _gmm_dispatch(rows, w, te, tm, FORCE_INTERPRET, first_group, num_tiles)
+
+        gate = checkpoint_name(matmul(xp, w_gate), "moe_gate")
+        up = checkpoint_name(matmul(xp, w_up), "moe_up")
         inter = activation(gate) * up
-        return jnp.take(_gmm_dispatch(inter, w_down, te, tm, interp), pdst,
-                        axis=0, unique_indices=True)
+        return jnp.take(matmul(inter, w_down), pdst, axis=0, unique_indices=True)
     if x.shape[0] < num_experts:
         GMM_STATS.count(("gathered_quant" if quantized else "gathered") + table)
         return _gathered_moe_mlp(x, expert_idx, w_gate, w_up, w_down,

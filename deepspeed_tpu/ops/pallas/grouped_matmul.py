@@ -8,14 +8,24 @@ of the row-tile ``tm`` (zeros), so every (tm × K) row tile belongs to
 exactly ONE expert and the kernel needs no in-tile masking at all — a
 scalar-prefetched ``tile_experts`` array steers each row tile's weight
 DMA (``PrefetchScalarGridSpec``: the index map picks ``w[e]`` before the
-tile runs). ``lax.ragged_dot`` measures ~98 TFLOP/s on v5e at Mixtral
-shapes vs ~200 for a dense matmul; tile-aligned groups recover dense
-tiling (the padding waste is ≤ E·(tm-1) rows, ~6% at tm=256, T·k=8k).
+tile runs).
+
+The row tile is fitted to the rows a group (:func:`row_tile`): ~12 rows
+a group (64 experts, 128 sequences x top-6) take a 32-row tile and pad
+to at most ``groups x (tm - 1)`` rows more, a training batch takes 256.
+The weights may be a table of more groups than the call's
+(``first_group``, a traced scalar the weight index map adds): every
+layer's experts ``[L*E, K, N]`` are read where they lie, never cut out.
 
 Grid order puts the row-tile sweep innermost so each expert's weight
-slab stays resident in VMEM across its whole row range (weights re-DMA
-only on a group boundary); activations stream at one (tm × K) tile per
-step, which keeps the kernel compute-bound.
+block stays resident in VMEM across its whole row range (weights re-DMA
+only on a group boundary): the expert weights stream through once a
+call, which with few rows a group is all the call costs (HBM-bound).
+The block is ``[K, tn]`` with ``tn`` the widest 128-multiple divisor of
+N whose block fits :data:`_WEIGHT_BLOCK_BYTES` (:func:`col_tile`; the
+whole N for narrow experts), so a weight DMA moves long rows, not
+128-lane slivers. Row tiles past the last group's (the static layout
+holds the worst case) are skipped: they store zeros and fetch nothing.
 
 The backward splits per operand: dx is the same kernel against
 ``w.swapaxes(1, 2)``; dw accumulates ``x_tileᵀ @ dy_tile`` into a
@@ -37,9 +47,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _gmm_kernel(te_ref, x_ref, w_ref, o_ref):
-    o_ref[:] = jnp.dot(x_ref[:], w_ref[0], preferred_element_type=jnp.float32
-                       ).astype(o_ref.dtype)
+def _gmm_kernel(te_ref, meta_ref, x_ref, w_ref, o_ref):
+    real = pl.program_id(1) < meta_ref[1]
+
+    @pl.when(real)
+    def _tile():
+        o_ref[:] = jnp.dot(x_ref[:], w_ref[0], preferred_element_type=jnp.float32
+                           ).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(real))
+    def _past_the_last_group():
+        o_ref[:] = jnp.zeros_like(o_ref)
 
 
 def _gmm_dw_kernel(te_ref, x_ref, dy_ref, o_ref):
@@ -82,27 +100,81 @@ def _fit_tile(t, dim):
     return t
 
 
-def _gmm_raw(x, w, tile_experts, tm, tn, interpret=False):
-    """x [Mp, K] (rows tile-aligned by group), w [E, K, N],
-    tile_experts [Mp/tm] → y [Mp, N] (x.dtype)."""
+# One weight block [K, tn] of the pipeline's two. 28 MiB holds a whole
+# [2048, 1408] expert matrix and keeps a 4096-deep block 3584 columns wide, a
+# 14336-deep one 1024 (rows of 2-7 KiB a DMA): on v5e the widest blocks that
+# fit read fastest at every shape the census times, and a 256-column block
+# a fifth to a third slower (PERF.md, PR 31: tools/kernel_census.py --gmm-sweep).
+_WEIGHT_BLOCK_BYTES = 28 * 1024 * 1024
+_VMEM_SLACK_BYTES = 8 * 1024 * 1024
+
+
+def row_tile(rows, groups, dtype):
+    """The row tile for ``rows`` rows over ``groups`` groups: twice the
+    rows a group holds on average, rounded up to the dtype's sublane
+    multiple (8 rows of 4 bytes, 16 of 2), at most 256. Routed rows
+    scatter about their mean, and a group that overflows its tile costs
+    a second pass of its weights through the MXU: twice the mean holds
+    nearly every group in one tile, and read 0.5-10 % faster on v5e than
+    the mean itself at all eight shapes timed, four times the mean
+    3-12 % slower (PERF.md, PR 31). Pad rows are at most
+    ``groups x (tile - 1)``."""
+    sub = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    twice = max(1, 2 * rows // max(groups, 1))
+    return min(256, -(-twice // sub) * sub)
+
+
+def col_tile(K, N, itemsize):
+    """Columns of a weight block ``[K, tn]``: the whole N if the block
+    fits :data:`_WEIGHT_BLOCK_BYTES`, else the widest divisor of N that
+    is a multiple of 128 (the lane width) and fits. None where not even
+    128 columns fit or N has no such divisor: the caller dispatches
+    elsewhere."""
+    if K * N * itemsize <= _WEIGHT_BLOCK_BYTES or N <= 128:
+        return N
+    fits = [t for t in range(128, N, 128)
+            if N % t == 0 and K * t * itemsize <= _WEIGHT_BLOCK_BYTES]
+    return max(fits) if fits else None
+
+
+# jitted: the gate and up matmuls of a layer (the same shapes) then share one
+# trace and one Mosaic lowering, and programs with the same rows one trace
+@functools.partial(jax.jit, static_argnames=("tm", "interpret", "tn"))
+def _gmm_raw(x, w, tile_experts, meta, tm, interpret=False, tn=None):
+    """x [Mp, K] (rows tile-aligned by group), w [G, K, N],
+    tile_experts [Mp/tm], meta int32 [2] = (the call's first group in
+    ``w``, its row tiles: those past them are skipped) → y [Mp, N]
+    (x.dtype). ``tn`` overrides :func:`col_tile` (the census's sweep)."""
     Mp, K = x.shape
-    E, _, N = w.shape
-    tn = _fit_tile(tn, N)
-    grid = (N // tn, Mp // tm)  # row sweep innermost: w slab stays in VMEM
+    _, _, N = w.shape
+    tn = tn or col_tile(K, N, w.dtype.itemsize)
+    if tn is None or N % tn:
+        raise ValueError(f"gmm: no column tile for a [{K}, {N}] expert matrix")
+    grid = (N // tn, Mp // tm)  # row sweep innermost: w block stays in VMEM
+    # two buffers each of the weight block, the row tile and the output tile, the f32 product
+    vmem = (2 * K * tn * w.dtype.itemsize + 2 * tm * (K + tn) * x.dtype.itemsize
+            + tm * tn * 4 + _VMEM_SLACK_BYTES)
     return pl.pallas_call(
         _gmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((tm, K), lambda j, i, te: (i, 0)),
-                pl.BlockSpec((1, K, tn), lambda j, i, te: (te[i], 0, j)),
+                # a skipped tile asks for the last real tile's rows again: no DMA
+                pl.BlockSpec((tm, K), lambda j, i, te, meta: (
+                    jnp.minimum(i, jnp.maximum(meta[1] - 1, 0)), 0)),
+                pl.BlockSpec((1, K, tn), lambda j, i, te, meta: (meta[0] + te[i], 0, j)),
             ],
-            out_specs=pl.BlockSpec((tm, tn), lambda j, i, te: (i, j)),
+            out_specs=pl.BlockSpec((tm, tn), lambda j, i, te, meta: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=vmem),
         interpret=interpret,
-    )(tile_experts, x, w)
+        # the trace names the custom call after this: the benchmark's per-layer
+        # metrics find the expert matmuls by "ragged_dot" and the Mosaic calls by "^gmm"
+        name="gmm_ragged_dot",
+    )(tile_experts, meta, x, w)
 
 
 def _gmm_dw_raw(x, dy, tile_experts, num_experts, tk, tn, interpret=False):
@@ -117,6 +189,7 @@ def _gmm_dw_raw(x, dy, tile_experts, num_experts, tk, tn, interpret=False):
     # output block accumulates in VMEM, written back on group change
     out = pl.pallas_call(
         _gmm_dw_kernel,
+        name="gmm_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -136,28 +209,42 @@ def _gmm_dw_raw(x, dy, tile_experts, num_experts, tk, tn, interpret=False):
     return jnp.where(present[:, None, None], out, 0.0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def gmm(x, w, tile_experts, tm=256, tn=512, tk=256, interpret=False):
+def gmm(x, w, tile_experts, tm=256, interpret=False, first_group=None, num_tiles=None):
     """Grouped matmul on a tile-aligned row layout.
 
     ``x`` [Mp, K] with rows grouped by expert and each group padded
-    (with zero rows) to a multiple of ``tm``; ``w`` [E, K, N];
+    (with zero rows) to a multiple of ``tm``; ``w`` [G, K, N];
     ``tile_experts`` [Mp/tm] int32 — owning expert of each row tile.
-    → [Mp, N] in ``x.dtype``. Differentiable in x and w.
-    Use :func:`pad_groups_to_tiles` to build the layout.
+    → [Mp, N] in ``x.dtype``: f32 accumulation, one rounding.
+    Differentiable in x and w.
+
+    ``first_group`` (a traced scalar; None = 0): ``w`` is a table of
+    groups of which ``tile_experts`` counts from there — every layer's
+    experts, read where they lie. ``num_tiles`` (traced; None = all):
+    the row tiles the groups fill; the layout's tiles past them are
+    skipped and give zero rows. Use :func:`pad_groups_to_tiles` or
+    :func:`tile_layout` to build the layout, :func:`row_tile` for ``tm``.
     """
-    return _gmm_raw(x, w, tile_experts, tm, tn, interpret)
+    meta = jnp.stack([jnp.asarray(0 if first_group is None else first_group, jnp.int32),
+                      jnp.asarray(tile_experts.shape[0] if num_tiles is None else num_tiles,
+                                  jnp.int32)])
+    return _gmm(x, w, tile_experts, meta, tm, interpret)
 
 
-def _gmm_fwd(x, w, tile_experts, tm, tn, tk, interpret):
-    return _gmm_raw(x, w, tile_experts, tm, tn, interpret), (x, w, tile_experts)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gmm(x, w, tile_experts, meta, tm, interpret):
+    return _gmm_raw(x, w, tile_experts, meta, tm, interpret)
 
 
-def _gmm_bwd(tm, tn, tk, interpret, res, dy):
-    x, w, tile_experts = res
+def _gmm_fwd(x, w, tile_experts, meta, tm, interpret):
+    return _gmm_raw(x, w, tile_experts, meta, tm, interpret), (x, w, tile_experts, meta)
+
+
+def _gmm_bwd(tm, interpret, res, dy):
+    x, w, tile_experts, meta = res
     dy = dy.astype(x.dtype)
     # dx: the same grouped matmul against the transposed expert weights
-    dx = _gmm_raw(dy, w.swapaxes(1, 2), tile_experts, tm, tn, interpret)
+    dx = _gmm_raw(dy, w.swapaxes(1, 2), tile_experts, meta, tm, interpret)
     # dw: one full [K, N] fp32 accumulator block per expert when it fits
     # the 4MB VMEM budget (next to the double-buffered input streams) —
     # x and dy then stream exactly once; otherwise halve the block until
@@ -170,14 +257,15 @@ def _gmm_bwd(tm, tn, tk, interpret, res, dy):
         elif tk_dw % 256 == 0:
             tk_dw //= 2
         else:
-            tk_dw, tn_dw = tk, tn
+            tk_dw, tn_dw = 256, 512
             break
-    dw = _gmm_dw_raw(x, dy, tile_experts, w.shape[0], tk_dw, tn_dw,
+    # over the whole table: a group no tile names gets zeros
+    dw = _gmm_dw_raw(x, dy, tile_experts + meta[0], w.shape[0], tk_dw, tn_dw,
                      interpret).astype(w.dtype)
-    return dx, dw, None
+    return dx, dw, None, None
 
 
-gmm.defvjp(_gmm_fwd, _gmm_bwd)
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -388,19 +476,25 @@ def tile_layout(sizes, num_rows, tm):
     """Shared tile-aligned layout math for :func:`gmm` callers.
 
     ``sizes`` [E] (true per-group row counts, Σ = ``num_rows``) →
-    ``(padded_starts [E], tile_experts [Mp/tm], Mp)``: each group's
-    first padded row, the owning expert per row tile (tail tiles beyond
-    the last padded group clamp to the final expert — their rows are
-    zero by construction, so they contribute nothing), and the static
+    ``(padded_starts [E], tile_experts [Mp/tm], Mp, num_tiles)``: each
+    group's first padded row, the owning expert per row tile, the static
     padded row count (every group padded up to a tile multiple, worst
-    case ``num_rows + E*tm``)."""
+    case ``num_rows + E*tm``) and the traced count of tiles the groups
+    fill. The tiles past them name the last filled tile's expert — their
+    rows are zero by construction, so they contribute nothing, and a
+    kernel that is given ``num_tiles`` skips them without a weight DMA.
+    Comparisons and sums only (no gather, no ``repeat``): every serving
+    program traces and lowers this once a layer body."""
     E = sizes.shape[0]
     Mp = ((num_rows + tm - 1) // tm) * tm + E * tm
-    padded = ((sizes + tm - 1) // tm) * tm
-    padded_starts = jnp.cumsum(padded) - padded
-    tile_experts = jnp.repeat(jnp.arange(E, dtype=jnp.int32), padded // tm,
-                              total_repeat_length=Mp // tm)
-    return padded_starts, tile_experts, Mp
+    tiles = ((sizes + tm - 1) // tm).astype(jnp.int32)
+    ends = jnp.cumsum(tiles)
+    padded_starts = (ends - tiles) * tm
+    num_tiles = ends[-1]
+    tile = jnp.minimum(jnp.arange(Mp // tm, dtype=jnp.int32), jnp.maximum(num_tiles - 1, 0))
+    # a tile's expert: as many groups end at or before it
+    tile_experts = jnp.minimum(jnp.sum(ends[None, :] <= tile[:, None], axis=1), E - 1)
+    return padded_starts, tile_experts.astype(jnp.int32), Mp, num_tiles
 
 
 def pad_groups_to_tiles(sizes, num_rows, tm):
@@ -409,7 +503,7 @@ def pad_groups_to_tiles(sizes, num_rows, tm):
     position. (The training dispatch in ``ops/grouped_gemm.py`` computes
     per-row slots rank-based without sorting; both share
     :func:`tile_layout`.)"""
-    padded_starts, tile_experts, Mp = tile_layout(sizes, num_rows, tm)
+    padded_starts, tile_experts, Mp, _ = tile_layout(sizes, num_rows, tm)
     starts = jnp.cumsum(sizes) - sizes
     row = jnp.arange(num_rows, dtype=jnp.int32)
     expert_of_row = jnp.searchsorted(jnp.cumsum(sizes), row, side="right").astype(jnp.int32)

@@ -513,6 +513,54 @@ class TestExpertsStayInPlace:
         hlo = fn.lower(*shapes).compile().as_text()
         assert layer_stack_moves(hlo, stack_shapes_of(engine)) == []
 
+    @pytest.fixture(scope="class")
+    def moonlight_chunk(self):
+        """Moonlight's prompt-chunk program with the Pallas grouped matmul
+        as the dispatch (interpreted: the CPU has no Mosaic) →
+        (engine, its jaxpr, its compiled HLO, ``GMM_STATS``)."""
+        import deepspeed_tpu.ops.grouped_gemm as gg
+        from deepspeed_tpu.models import build_model
+        config = RaggedInferenceEngineConfig(
+            kv_block_size=16, num_kv_blocks=24,
+            state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
+                                               max_ragged_sequence_count=4,
+                                               max_tracked_sequences=4, max_context=128))
+        gg.FORCE_INTERPRET = True
+        gg.GMM_STATS.reset()
+        try:
+            engine = InferenceEngineV2(model=build_model("moonlight-debug"), config=config,
+                                       dtype=jnp.float32, rng=jax.random.PRNGKey(3))
+            fn, shapes = capture_programs(engine)["greedy_step"]
+            paths = gg.GMM_STATS.snapshot()     # every program traced so far
+            jaxpr = jax.make_jaxpr(fn)(*shapes).jaxpr
+            hlo = fn.lower(*shapes).compile().as_text()
+        finally:
+            gg.FORCE_INTERPRET = False
+        return engine, jaxpr, hlo, paths
+
+    @pytest.mark.parametrize("finding", ["jaxpr", "compiled", "paths"])
+    def test_the_pallas_grouped_matmul_reads_the_table_too(self, moonlight_chunk, finding):
+        """Fails where the kernel gets the layer's groups cut out of the
+        table (``dynamic_slice_in_dim``, until PR 31): three ops a layer
+        with the shape of its 8 experts' stack."""
+        engine, jaxpr, hlo, paths = moonlight_chunk
+        cfg = engine.model_config
+        experts = engine.params["model"]["layers"]["mlp"]["experts"]
+        stacks = {w.shape for w in jax.tree.leaves(experts)}            # [Lm, E, in, out]
+        if finding == "jaxpr":
+            layer = {shape[1:] for shape in stacks}
+            eqns = list(_equations(jaxpr))
+            cut = [e.primitive.name for e in eqns
+                   if any(v.aval.shape in layer for v in e.outvars)]
+            groups = [e.invars[3].aval.shape[0] for e in eqns if e.primitive.name == "pallas_call"]
+            assert cut == []
+            assert groups == [cfg.num_moe_layers * cfg.n_routed_experts] * 3
+            assert expert_findings(jaxpr, stacks) == ([], [])
+        elif finding == "compiled":
+            assert layer_stack_moves(hlo, stacks) == []
+        else:
+            assert set(paths) == {"pallas_table"}
+
     def test_findings_see_a_slice(self, monkeypatch):
         """The readers themselves: the sliced form (the engine's own, with
         the stacks left in the scan) is flagged by both, the table form

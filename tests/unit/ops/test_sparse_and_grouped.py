@@ -158,7 +158,7 @@ class TestGroupedGemm:
         call's experts (every layer's, say). Each dispatch - chosen on the
         call's own expert count, as without a table - gives what it gives
         on the call's experts cut out of the table; ``GMM_STATS`` says
-        which calls indexed the table where it lies (Pallas cuts out)."""
+        which calls indexed the table where it lies (every dispatch)."""
         import deepspeed_tpu.ops.grouped_gemm as gg
         rng = np.random.RandomState(7)
         D, F, E, layers, layer = 64, 128, 4, 3, 1
@@ -175,8 +175,7 @@ class TestGroupedGemm:
                                                         first_group=first))(jnp.int32(layer * E))
         finally:
             gg.FORCE_INTERPRET = False
-        assert gg.GMM_STATS.snapshot() == ({path: 2} if path == "pallas"
-                                           else {path: 1, path + "_table": 1})
+        assert gg.GMM_STATS.snapshot() == {path: 1, path + "_table": 1}
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(np.asarray(got), np.asarray(dense_reference_mlp(x, idx, *cut)),
                                    rtol=1e-4, atol=1e-4)
@@ -196,3 +195,113 @@ class TestGroupedGemm:
         g = jax.grad(loss)(w)
         assert np.isfinite(np.asarray(g)).all()
         assert np.abs(np.asarray(g)).max() > 0
+
+
+def _skewed(rows, groups, seed, empty=()):
+    """Group sizes that sum to ``rows``: random, with ``empty`` groups at 0."""
+    rng = np.random.RandomState(seed)
+    live = [g for g in range(groups) if g not in empty]
+    sizes = np.zeros(groups, np.int64)
+    np.add.at(sizes, rng.choice(live, rows), 1)
+    return sizes
+
+
+# (rows, groups, K, N, dtype, sizes, weight-block bytes): the four shape classes the
+# benchmark's cells serve, scaled down - the rows a group and the factors of N kept -
+# and the layouts that go wrong first
+GMM_CASES = {
+    "moonlight-decode-12-rows-a-group-N-11x128": (96, 8, 256, 1408, jnp.bfloat16, None, None),
+    "moonlight-chunk-48-rows-a-group": (384, 8, 256, 1408, jnp.bfloat16, None, None),
+    "moonlight-down-K-11x128": (96, 8, 1408, 256, jnp.bfloat16, None, None),
+    "mixtral-decode-16-rows-a-group-N-split": (64, 4, 256, 1792, jnp.bfloat16, None,
+                                               256 * 896 * 2),
+    "mixtral-chunk-128-rows-a-group-N-split": (512, 4, 256, 1792, jnp.bfloat16, None,
+                                               256 * 256 * 2),
+    "mixtral-down-K-14x128": (64, 4, 1792, 256, jnp.bfloat16, None, 1792 * 128 * 2),
+    "empty-groups": (96, 8, 128, 256, jnp.float32, _skewed(96, 8, 1, empty=(0, 3, 7)), None),
+    "every-row-in-one-group": (96, 8, 128, 256, jnp.float32, np.eye(8, dtype=np.int64)[5] * 96,
+                               None),
+    "rows-not-a-multiple-of-the-tile": (100, 8, 128, 256, jnp.float32, None, None),
+    "training-tile-256": (1024, 2, 128, 256, jnp.float32, None, None),
+}
+
+
+class TestGmmReadsTheTable:
+    """The Pallas grouped matmul on a table of ``L x E`` groups with a
+    traced ``first_group`` and a row tile fitted to the rows a group,
+    against ``lax.ragged_dot`` on the call's groups cut out: f32
+    accumulation, one rounding to the activation dtype. Interpret mode."""
+
+    @pytest.mark.parametrize("case", sorted(GMM_CASES))
+    def test_matches_ragged_dot_on_the_groups_cut_out(self, case, monkeypatch):
+        from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+        rows, groups, K, N, dtype, sizes, block_bytes = GMM_CASES[case]
+        if block_bytes is not None:
+            monkeypatch.setattr(gm, "_WEIGHT_BLOCK_BYTES", block_bytes)
+            assert 128 <= gm.col_tile(K, N, jnp.dtype(dtype).itemsize) < N
+        sizes = _skewed(rows, groups, 11) if sizes is None else sizes
+        layers, layer = 3, 2
+        rng = np.random.RandomState(5)
+        x = jnp.asarray(rng.randn(rows, K), dtype)
+        table = jnp.asarray(rng.randn(layers * groups, K, N) * K ** -0.5, dtype)
+        tm = gm.row_tile(rows, groups, dtype)
+        sub = 32 // x.dtype.itemsize
+        assert tm == min(256, -(-(2 * rows // groups) // sub) * sub)
+
+        @jax.jit
+        def kernel(x, table, sizes, first):
+            starts, te, Mp, tiles = gm.tile_layout(sizes, rows, tm)
+            assert Mp <= rows + tm - 1 + groups * tm
+            group = jnp.repeat(jnp.arange(groups), sizes, total_repeat_length=rows)
+            dst = starts[group] + jnp.arange(rows) - (jnp.cumsum(sizes) - sizes)[group]
+            xp = jnp.zeros((Mp, K), x.dtype).at[dst].set(x)
+            out = gm.gmm(xp, table, te, tm, True, first_group=first, num_tiles=tiles)
+            pad = jnp.ones(Mp, bool).at[dst].set(False)
+            return out[dst], jnp.abs(jnp.where(pad[:, None], out, 0).astype(jnp.float32)).max()
+
+        sizes = jnp.asarray(sizes, jnp.int32)
+        got, pad_max = kernel(x, table, sizes, jnp.int32(layer * groups))
+        want = jax.lax.ragged_dot(x, table[layer * groups:(layer + 1) * groups], sizes,
+                                  preferred_element_type=jnp.float32).astype(dtype)
+        assert got.dtype == x.dtype and float(pad_max) == 0.0
+        tol = 2 ** -7 if dtype == jnp.bfloat16 else 1e-5
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("rows,groups,dtype,tile", [
+        (768, 64, jnp.bfloat16, 32), (3072, 64, jnp.bfloat16, 96), (128, 8, jnp.bfloat16, 32),
+        (1024, 8, jnp.bfloat16, 256), (65536, 8, jnp.bfloat16, 256), (24, 3, jnp.float32, 16),
+        (3, 8, jnp.bfloat16, 16)])
+    def test_the_row_tile_is_twice_the_rows_a_group(self, rows, groups, dtype, tile):
+        from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile
+        assert row_tile(rows, groups, dtype) == tile
+
+    @pytest.mark.parametrize("K,N,tile", [
+        (2048, 1408, 1408), (1408, 2048, 2048),      # Moonlight: the whole N, both ways
+        (4096, 14336, 3584), (14336, 4096, 1024),    # Mixtral: rows of 7 KiB and 2 KiB
+        (4096, 4096 * 7 + 64, None)])                # no 128-multiple divides N
+    def test_a_weight_block_is_wide(self, K, N, tile):
+        from deepspeed_tpu.ops.pallas.grouped_matmul import col_tile
+        assert col_tile(K, N, 2) == tile
+
+    def test_grad_through_the_table(self):
+        """dx and dw of the table form: dw lands in the call's groups of
+        the table and is zero in every other."""
+        from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+        rows, groups, K, N, tm = 48, 3, 64, 128, 16
+        rng = np.random.RandomState(9)
+        x = jnp.asarray(rng.randn(rows, K), jnp.float32)
+        table = jnp.asarray(rng.randn(3 * groups, K, N) * 0.1, jnp.float32)
+        sizes = jnp.asarray([16, 0, 32], jnp.int32)
+        dst, te, Mp = gm.pad_groups_to_tiles(sizes, rows, tm)
+
+        def kernel(x, table):
+            xp = jnp.zeros((Mp, K), x.dtype).at[dst].set(x)
+            return (gm.gmm(xp, table, te, tm, True, first_group=jnp.int32(groups))[dst] ** 2).sum()
+
+        def ragged(x, table):
+            return (jax.lax.ragged_dot(x, table[groups:2 * groups], sizes) ** 2).sum()
+
+        for got, want in zip(jax.grad(kernel, (0, 1))(x, table), jax.grad(ragged, (0, 1))(x, table)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+

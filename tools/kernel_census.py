@@ -15,6 +15,16 @@ refused kernel does not stop the census or fail it; the verdicts go to
 holds no Mosaic custom call — the dispatch fell through to a reference —
 is reported as such, never as a pass.
 
+The grouped matmul is also timed, a call, beside ``lax.ragged_dot`` at the
+four shape classes the benchmark's MoE cells serve (Moonlight's 768- and
+3072-row programs over 64 experts of 2048 x 1408, Mixtral's 128- and
+1024-row ones over 8 of 4096 x 14336), both directions of the FFN, on a
+table of every layer's experts with a traced ``first_group``: ms a call,
+GB/s of expert weights, share of the chip's 819 GB/s. That table is what
+``ops/grouped_gemm._use_pallas_gmm`` rests on (PERF.md, PR 31).
+``--gmm-sweep`` adds other row and column tiles; ``--gmm-only`` skips
+the verdicts.
+
 Prints one JSON line per kernel and writes ``chiprun_out/kernel_census.json``.
 """
 
@@ -121,6 +131,91 @@ def cases():
            (q, k, v), 3e-2)
 
 
+# (name, rows, groups, layers, d_model, d_ff): a serving program's expert matmuls
+GMM_CLASSES = (("moonlight-decode", 768, 64, 8, 2048, 1408),
+               ("moonlight-chunk", 3072, 64, 8, 2048, 1408),
+               ("mixtral-decode", 128, 8, 4, 4096, 14336),
+               ("mixtral-chunk", 1024, 8, 4, 4096, 14336))
+HBM_GB_S = 819.0  # benchmark/peaks.json, "TPU v5 lite"
+
+
+def _ms_a_call(fn, *args, calls=20):
+    import jax
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    out = [fn(*args) for _ in range(calls)]
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) * 1e3 / calls
+
+
+def grouped_matmul_classes(sweep=False):
+    """Yields one record a shape class and direction: the kernel as
+    ``moe_grouped_mlp`` calls it and ``ragged_dot`` as it did, on the same
+    rows and the same table. The bytes are the call's experts' matrices
+    once (every group has rows here; the record says so)."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+
+    rng = np.random.default_rng(31)
+    for name, rows, groups, layers, d_model, d_ff in GMM_CLASSES:
+        G, layer = layers * groups, layers - 2
+        sizes = np.bincount(rng.integers(0, groups, rows), minlength=groups)
+        table_sizes = np.zeros(G, np.int32)
+        table_sizes[layer * groups:(layer + 1) * groups] = sizes
+        sizes, table_sizes = jnp.asarray(sizes, jnp.int32), jnp.asarray(table_sizes)
+        first = jnp.int32(layer * groups)
+        for direction, (K, N) in (("up", (d_model, d_ff)), ("down", (d_ff, d_model))):
+            table = jax.jit(lambda key: (jax.random.normal(key, (G, K, N), jnp.bfloat16)
+                                         * K ** -0.5))(jax.random.PRNGKey(K))
+            x = jnp.asarray(rng.standard_normal((rows, K), np.float32), jnp.bfloat16)
+            ragged = jax.jit(lambda x, w, s: jax.lax.ragged_dot(
+                x, w, s, preferred_element_type=jnp.float32).astype(x.dtype))
+            want = ragged(x, table, table_sizes)
+            nbytes = groups * K * N * 2
+            record = {"rows": rows, "groups": groups, "table_groups": G, "K": K, "N": N,
+                      "empty_groups": int((sizes == 0).sum()), "weight_bytes": nbytes,
+                      "ragged_dot_ms": _ms_a_call(ragged, x, table, table_sizes)}
+            fitted, wide = gm.row_tile(rows, groups, x.dtype), gm.col_tile(K, N, 2)
+            tiles = [(fitted, wide)]
+            if sweep:
+                sub = 16  # bf16 rows a sublane tile
+                tiles += [(min(256, max(sub, -(-fitted * m // (2 * sub)) * sub)), wide)
+                          for m in (1, 4)]   # the rows a group itself, and four times them
+                tiles += [(fitted, t) for t in range(128, N + 1, 128)
+                          if N % t == 0 and t != wide and t >= 256 and K * t * 2 <= 40 << 20]
+            for tm, tn in dict.fromkeys(tiles):
+                def layout(x, sizes, first, tm=tm):
+                    dst, te, Mp = gm.pad_groups_to_tiles(sizes, rows, tm)
+                    filled = jnp.sum((sizes + tm - 1) // tm).astype(jnp.int32)
+                    xp = jnp.zeros((Mp, K), x.dtype).at[dst].set(x)
+                    return xp, te, jnp.stack([first, filled]), dst
+
+                try:
+                    xp, te, meta, dst = jax.jit(layout)(x, sizes, first)
+                    call = jax.jit(lambda xp, w, te, meta, tm=tm, tn=tn: gm._gmm_raw(
+                        xp, w, te, meta, tm, tn=tn))
+                    if not mosaic_kernels(call.lower(xp, table, te, meta)):
+                        raise RuntimeError("no Mosaic kernel in the lowered program")
+                    ms = _ms_a_call(call, xp, table, te, meta)
+                    err = rel_err(call(xp, table, te, meta)[dst], want)
+                    got = {"tm": tm, "tn": tn, "ms": ms, "gb_s": nbytes / ms / 1e6,
+                           "hbm_share": 100 * nbytes / ms / 1e6 / HBM_GB_S,
+                           "rel_err": float(f"{err:.3e}")}
+                except Exception as e:  # a refusal is a record too
+                    got = {"tm": tm, "tn": tn, "refused": f"{type(e).__name__}: {e}"[:600]}
+                if (tm, tn) == (fitted, wide):
+                    record["kernel"] = got
+                else:
+                    record.setdefault("sweep", []).append(got)
+            record["ragged_dot_hbm_share"] = 100 * nbytes / record["ragged_dot_ms"] / 1e6 / HBM_GB_S
+            del table, want
+            yield f"{name}.{direction}", record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -146,7 +241,10 @@ def main():
     enable_compile_cache()
     report = {"device": {"platform": devices[0].platform, "kind": devices[0].device_kind,
                          "count": len(devices)}, "kernels": {}}
-    for name, fn, ref, args, tol in cases():
+    for name, record in grouped_matmul_classes(sweep="--gmm-sweep" in sys.argv):
+        report.setdefault("grouped_matmul", {})[name] = record
+        print(json.dumps({name: record}), flush=True)
+    for name, fn, ref, args, tol in () if "--gmm-only" in sys.argv else cases():
         try:
             result = verdict(fn, ref, args, tol)
         except Exception as e:  # the census records a refusal and goes on to the next kernel
