@@ -95,7 +95,7 @@ class AsyncBurstHandle:
     Pump-thread only (it is part of the engine step surface)."""
 
     def __init__(self, engine, uids, descs, k, out, st=None,
-                 entry_np=None, prev=None, record=None):
+                 entry_np=None, prev=None, record=None, counts=()):
         self.uids = list(uids)
         self.k = int(k)
         self.out = out            # device [k, max_seqs] int32
@@ -106,6 +106,7 @@ class AsyncBurstHandle:
         self._prev = prev          # previous handle in the device chain
         self._toks = None
         self._record = record      # step record, open until fetch()
+        self._counts = counts      # the program's device-side counts (engine._note_counts)
 
     @property
     def entry_next(self):
@@ -135,12 +136,15 @@ class AsyncBurstHandle:
                 tracing.resume(rec)
             try:
                 with tracing.phase("engine.fetch"):
-                    self._toks = np.asarray(self.out)[:, :len(self.uids)]  # ds-lint: disable=host-sync -- THE one intended sync per pipelined burst, paid at fence time
+                    toks, *counts = jax.device_get((self.out, *self._counts))  # ds-lint: disable=host-sync -- THE one intended sync per pipelined burst, paid at fence time
+                    self._toks = toks[:, :len(self.uids)]
+                    if rec is not None:
+                        self._engine._note_counts(rec, counts)
             finally:
                 if rec is not None:
                     tracing.end(rec)
                     self._engine.last_step = rec
-            self.out = None
+            self.out, self._counts = None, ()
             if self._prev is not None:
                 if self._entry_np is None:
                     self._entry_np = self._prev.fetch()[-1][:len(self.uids)]
@@ -162,12 +166,12 @@ class InferenceEngineV2:
 
     def __init__(self, model=None, config: RaggedInferenceEngineConfig = None,
                  params=None, model_config=None, dtype=jnp.bfloat16, rng=None):
-        """``model``: a ``LlamaForCausalLM``, ``GPTForCausalLM`` or
-        ``MoonlightForCausalLM`` (its scan-stacked params are
+        """``model``: a ``LlamaForCausalLM``, ``GPTForCausalLM``,
+        ``MoonlightForCausalLM`` or ``LongcatFlashForCausalLM`` (its scan-stacked params are
         initialized here when ``params`` is not given), or pass
         ``params`` + ``model_config`` directly. The config's type picks
         the model kind (``model_runner.kind_of``), which says what state
-        the paged pool holds and how a layer steps."""
+        the paged pool holds, in how many layers, and how a layer steps."""
         self._config = config or RaggedInferenceEngineConfig()
         sm = self._config.state_manager
         self.dtype = dtype
@@ -229,7 +233,7 @@ class InferenceEngineV2:
             # at 8 GB PER POOL SHARD (the pool shards whole KV heads over
             # the 'tensor' axis when divisible) with a warning; an explicit
             # num_kv_blocks is honored as given.
-            bytes_per_block = (cfg.num_hidden_layers * self.block_size *
+            bytes_per_block = (kind.state_layers(cfg) * self.block_size *
                                sum(kind.state_rows(cfg)) * jnp.dtype(dtype).itemsize)
             pool_shards = 1
             if self.mesh is not None:
@@ -249,7 +253,7 @@ class InferenceEngineV2:
             from jax.sharding import NamedSharding
             from deepspeed_tpu.inference.v2.sharding import kv_pool_spec
             pool = NamedSharding(self.mesh, kv_pool_spec(self.mesh, cfg.num_key_value_heads))
-        self.kv_cache = BlockedKVCache(cfg.num_hidden_layers, num_blocks, self.block_size,
+        self.kv_cache = BlockedKVCache(kind.state_layers(cfg), num_blocks, self.block_size,
                                        cfg.num_key_value_heads, getattr(cfg, "head_dim", None),
                                        dtype=dtype, sharding=pool, state_kind=kind.state_kind,
                                        row_widths=kind.state_rows(cfg))
@@ -402,13 +406,15 @@ class InferenceEngineV2:
                                         enabled=sanitize)
 
         def step_greedy(p, kc, vc, b, lora_slabs=None):
-            logits, kc, vc = step(p, kc, vc, b, lora_slabs)
+            # (a model kind that counts on the device gives its counts fourth: they
+            # ride out beside the tokens, here and in every program below)
+            logits, kc, vc, *counts = step(p, kc, vc, b, lora_slabs)
             # On-device greedy sampling: ship [n_seqs] int32 tokens to the
             # host instead of [n_seqs, vocab] fp32 logits — vocab-factor
             # less PCIe traffic per decode step (servers sample on-device
             # for the same reason; reference FastGen returns logits only
             # because torch keeps them resident).
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), kc, vc
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kc, vc, *counts)
 
         self._step_greedy = maybe_checkify_jit(step_greedy, donate_argnums=(1, 2),
                                                enabled=sanitize)
@@ -432,8 +438,8 @@ class InferenceEngineV2:
             if lora_slabs is not None:
                 la, lb, scales = lora_slabs
                 lora_arg = (la, lb, scales, b["seq_adapters"], None)
-            logits, kc, vc = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
-                                            attn_impl=attn_impl, lora=lora_arg)
+            logits, kc, vc, *counts = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
+                                                     attn_impl=attn_impl, lora=lora_arg)
             temp, topk, topp, seed, slot, state = unpack_sample_meta(
                 b["sample_meta"], ms)
             if slabs is not None:
@@ -443,7 +449,7 @@ class InferenceEngineV2:
             # same counter key) every other path derives for it
             pos_out = b["token_pos"][b["last_index"]] + 1
             keys = token_keys(base, seed, pos_out)
-            return sample_rows(logits, keys, temp, topk, topp), kc, vc
+            return (sample_rows(logits, keys, temp, topk, topp), kc, vc, *counts)
 
         if structured_on and lora_on:
             sampled_fn = step_sampled
@@ -748,17 +754,19 @@ class InferenceEngineV2:
                     sargs = (self._base_key,)
                     if self.structured is not None:
                         sargs += (self.structured.slabs(),)  # rebind, never retrace
-                    out, self.kv_cache.k, self.kv_cache.v = self._step_sampled(
+                    out, self.kv_cache.k, self.kv_cache.v, *counts = self._step_sampled(
                         self.params, self.kv_cache.k, self.kv_cache.v, arrays,
                         *sargs, *extra)
                 else:
                     fn = self._step_greedy if mode == "greedy" else self._step
-                    out, self.kv_cache.k, self.kv_cache.v = fn(
+                    out, self.kv_cache.k, self.kv_cache.v, *counts = fn(
                         self.params, self.kv_cache.k, self.kv_cache.v, arrays, *extra)
             self.count_host_sync()
             self.tokens_emitted += len(batch_uids)
             with tracing.phase("engine.fetch"):
-                host = np.asarray(out)[np.asarray(slots)]  # ds-lint: disable=host-sync -- THE one intended sync per step: callers consume host tokens/logits
+                host, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- THE one intended sync per step: callers consume host tokens/logits
+                host = host[slots]
+                self._note_counts(rec, counts)
             self.last_step = rec
             return host
 
@@ -796,6 +804,16 @@ class InferenceEngineV2:
                          f"'greedy' (on-device argmax), a sampling dict "
                          f"{{'temperature', 'top_k', 'top_p', 'seed'}}, or a "
                          f"per-sequence list of dict/None")
+
+    def _note_counts(self, rec, counts):
+        """The device-side counts of the program behind step record ``rec``
+        (``kind.step_counts``) → ``rec.counts``. ``counts``: what the
+        program gave past its pools, as it came to the host in the one
+        ``device_get`` that fetched the step's result (every copy is
+        started before the first is waited for); empty for a kind that
+        counts nothing."""
+        if counts:
+            rec.counts = dict(zip(self.kind.step_counts, counts[0].tolist()))
 
     def count_host_sync(self, n=1):
         """Record ``n`` executions of a pragma'd host-sync site. Every
@@ -947,7 +965,8 @@ class InferenceEngineV2:
         DFA state row. → ``(descs, entry_np, out, st)``: ``entry_np`` the
         host entry row (None when chained), ``out`` the device
         ``[k, max_seqs]`` tokens, ``st`` the final DFA state row (None
-        for a greedy burst). Nothing is fetched here."""
+        for a greedy burst), and, fifth, the program's device-side counts
+        (:meth:`_note_counts`). Nothing is fetched here."""
         with tracing.phase("engine.pack"):
             if k < 1:
                 raise ValueError("k must be >= 1")
@@ -1041,10 +1060,10 @@ class InferenceEngineV2:
                 key = key + (self.lora_store.signature(),)
             fn = self._get_burst_fn(key, lambda: self._make_burst_fn(k, skey))
         with tracing.phase("engine.dispatch"):
-            out, st, self.kv_cache.k, self.kv_cache.v = fn(
+            out, st, self.kv_cache.k, self.kv_cache.v, *counts = fn(
                 self.params, self.kv_cache.k, self.kv_cache.v, meta, entry, opt)
         self.tokens_emitted += k * n
-        return descs, entry_np, out, st
+        return descs, entry_np, out, st, counts
 
     def decode_burst(self, batch_uids, batch_tokens, k, sample=None):
         """Run ``k`` decode steps for one current token per uid in ONE
@@ -1067,13 +1086,15 @@ class InferenceEngineV2:
         k, n = int(k), len(batch_uids)
         with tracing.step("burst", engine=self.trace_id, program=f"burst{k}", k=k,
                           n_seqs=n, n_tokens=k * n, uids=tuple(batch_uids)) as rec:
-            descs, entry_np, out, _ = self._dispatch_burst(
+            descs, entry_np, out, _, counts = self._dispatch_burst(
                 rec, batch_uids, batch_tokens, k, sample)
             # the fetched form reads its entry row from the host every
             # burst (one site a row) and pays the fetch
             self.count_host_sync(n + 1)
             with tracing.phase("engine.fetch"):
-                toks = np.asarray(out)[:, :n]  # ds-lint: disable=host-sync -- THE one intended sync per k-step burst
+                toks, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- THE one intended sync per k-step burst
+                toks = toks[:, :n]
+                self._note_counts(rec, counts)
             with tracing.phase("engine.log"):
                 if self._log_tokens:
                     # log what the burst actually WROTE to the KV cache: step i
@@ -1112,13 +1133,13 @@ class InferenceEngineV2:
         rec = tracing.begin("burst_async", engine=self.trace_id, program=f"burst{k}", k=k,
                             n_seqs=n, n_tokens=k * n, uids=tuple(batch_uids))
         try:
-            descs, entry_np, out, st = self._dispatch_burst(
+            descs, entry_np, out, st, counts = self._dispatch_burst(
                 rec, batch_uids, batch_tokens, k, sample, prev)
         finally:
             # open until AsyncBurstHandle.fetch: the device runs meanwhile
             tracing.suspend(rec)
         handle = AsyncBurstHandle(self, batch_uids, descs, k, out, st=st,
-                                  entry_np=entry_np, prev=prev, record=rec)
+                                  entry_np=entry_np, prev=prev, record=rec, counts=counts)
         if self._log_tokens:
             # KV content over [seen, seen+k) = the entry token plus the
             # first k-1 outputs, exactly like the fetched form — but it
@@ -1139,7 +1160,9 @@ class InferenceEngineV2:
         key) and ``state`` (DFA state row) for a sampled program, ``dfa``
         and ``lora`` (their slabs) when those subsystems are live — and
         ``jit`` specialises on which. → ``(out, st, kc, vc)``: the final
-        DFA state row for the next link, None from a greedy program."""
+        DFA state row for the next link, None from a greedy program; a
+        model kind that counts on the device gives its counts fifth,
+        summed over the burst's steps."""
         from deepspeed_tpu.inference.v2.model_runner import ragged_forward
         cfg, dtype, mesh = self.model_config, self.dtype, self.mesh
         attn_impl = self._attention
@@ -1175,11 +1198,11 @@ class InferenceEngineV2:
                 b = {"token_ids": toks, "token_seq": token_seq,
                      "token_pos": pos0 + i, "block_tables": tables,
                      "last_index": last}
-                sel, kc, vc = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
-                                             attn_impl=attn_impl, lora=lora_arg)
+                sel, kc, vc, *counts = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
+                                                      attn_impl=attn_impl, lora=lora_arg)
                 if not sampled:
                     nxt = jnp.argmax(sel, axis=-1).astype(jnp.int32)
-                    return (kc, vc, nxt, st), nxt
+                    return (kc, vc, nxt, st), (nxt, *counts)
                 if slabs is not None:
                     sel = apply_dfa_mask(sel, slabs[0], slot, st)
                 # step i's token lands at absolute position pos0 + i + 1,
@@ -1188,11 +1211,11 @@ class InferenceEngineV2:
                 nxt = sample_rows(sel, keys, temp, topk, topp)
                 if slabs is not None:
                     st = slabs[1][slot, st, nxt]  # in-scan DFA advance
-                return (kc, vc, nxt, st), nxt
+                return (kc, vc, nxt, st), (nxt, *counts)
 
-            (kc, vc, _, st), out = jax.lax.scan(one, (kc, vc, tokens0, opt.get("state")),
-                                                jnp.arange(k, dtype=jnp.int32))
-            return out, st, kc, vc
+            (kc, vc, _, st), (out, *counts) = jax.lax.scan(
+                one, (kc, vc, tokens0, opt.get("state")), jnp.arange(k, dtype=jnp.int32))
+            return (out, st, kc, vc, *(c.sum(axis=0) for c in counts))
 
         return maybe_checkify_jit(burst, donate_argnums=(1, 2),
                                   enabled=self._sanitize)

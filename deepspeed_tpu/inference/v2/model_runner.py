@@ -22,10 +22,12 @@ consumes the padded flat batch —
   ``GPTForCausalLM`` (GPT-2/J/NeoX, OPT, Bloom, Falcon, Phi) checkpoint
   serves directly;
 - what differs between model families sits in one object each
-  (:class:`LlamaKind`, :class:`GPTKind`, :class:`MoonlightKind`;
-  :func:`kind_of` picks by the config's type): the state the pool holds,
-  the layer step, the layer pattern (leading layers, then the scan) and
-  the final norm. :func:`ragged_forward` is the same for all.
+  (:class:`LlamaKind`, :class:`GPTKind`, :class:`MoonlightKind`,
+  :class:`LongcatKind`; :func:`kind_of` picks by the config's type): the
+  state the pool holds and how many layers of it, the layer step, the
+  layer pattern (leading layers, then the scan), what a step counts on
+  the device, and the final norm. :func:`ragged_forward` is the same for
+  all.
 """
 
 import functools
@@ -360,6 +362,14 @@ class LlamaKind:
     name = "llama"
     state_kind = "kv"       # two pools of expanded keys and values, [L, NB, bs, Hkv*Dh]
     lora = True
+    # names of the device-side counts a step of this kind's scan gives (int32, summed over
+    # its layers; they ride out with the step's result into its step record): none
+    step_counts = ()
+
+    @staticmethod
+    def state_layers(cfg):
+        """→ the pools' first axis: the layers of state a token holds."""
+        return cfg.num_hidden_layers
 
     @staticmethod
     def state_rows(cfg):
@@ -458,6 +468,8 @@ class MoonlightKind:
     name = "moonlight"
     state_kind = "latent"
     lora = False
+    step_counts = ()
+    state_layers = LlamaKind.state_layers
 
     @staticmethod
     def state_rows(cfg):
@@ -489,9 +501,61 @@ class MoonlightKind:
     final_norm = LlamaKind.final_norm
 
 
+class LongcatKind(MoonlightKind):
+    """LongCat-Flash (``models/longcat.py``): the latent state of
+    :class:`MoonlightKind`, **two state layers a model layer** (a double
+    layer holds two latent attentions: the pools' first axis is ``2 x
+    num_layers``, and double layer ``l`` writes rows ``2l`` and ``2l +
+    1``), no leading layers, and an expert layer that is one share of an
+    expert-parallel deployment behind a router with zero-compute
+    columns. Each step counts, over its expert layers and its tokens
+    that are not padding: the picks whose expert is held, the
+    zero-compute picks, and the held experts with at least one row."""
+    name = "longcat"
+    step_counts = ("n_picks_held", "n_picks_zero", "n_groups_live")
+
+    @staticmethod
+    def state_layers(cfg):
+        return 2 * cfg.num_layers
+
+    @staticmethod
+    def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
+        if lora is not None or mesh is not None:
+            raise NotImplementedError("the LongCat layer stack serves base-only on one device")
+        # rotations for this batch's positions, not a table of max_position_embeddings
+        # (131072) rows baked into the program: row t is token t's
+        d = cfg.qk_rope_head_dim
+        inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        angle = batch["token_pos"].astype(jnp.float32)[:, None] * inv_freq[None, :]
+        rope = (jnp.cos(angle), jnp.sin(angle), jnp.arange(h.shape[0], dtype=jnp.int32))
+        layers = params["model"]["layers"]
+        # The routed experts ride the step whole [L, held, in, out] and are no part of the
+        # scan's xs (see MoonlightKind); every other weight is [L, in, out] under its half's
+        # name, which the scan reads in place a layer at a time.
+        experts = layers["mlp"]["experts"]
+        sliced = {**layers, "mlp": {k: v for k, v in layers["mlp"].items() if k != "experts"}}
+        step = functools.partial(_longcat_layer_step, cfg, rope, batch, attn_impl, experts)
+        return h, (), step, (layer_ids.reshape(-1, 2), sliced)
+
+    @staticmethod
+    def expert_layer(params, cfg, layer, x):
+        """``M(x)`` of double layer ``layer`` (may be traced) as the step
+        programs compute it - the same router, the same share, the table
+        of every layer's held experts read in place - for a check that
+        wants the expert layer alone: x [T, D], every row a token → m."""
+        mlp = params["model"]["layers"]["mlp"]
+        router = jax.tree.map(lambda w: w[layer], {"router": mlp["router"]})
+        every_row = {"token_seq": jnp.zeros(x.shape[0], jnp.int32),
+                     "block_tables": jnp.zeros((2, 1), jnp.int32)}
+        return _longcat_moe(x, router, mlp["experts"], layer, cfg, every_row)[0]
+
+
 def kind_of(cfg):
     """The model kind of a config, by its type."""
+    from deepspeed_tpu.models.longcat import LongcatFlashConfig
     from deepspeed_tpu.models.moonlight import MoonlightConfig
+    if isinstance(cfg, LongcatFlashConfig):
+        return LongcatKind
     if isinstance(cfg, MoonlightConfig):
         return MoonlightKind
     return GPTKind if hasattr(cfg, "position_embedding") else LlamaKind
@@ -565,6 +629,49 @@ def _moonlight_moe(x, p, experts, layer, cfg):
         return routed + _swiglu(x, p["shared_experts"])
 
 
+def _latent_attention(cfg, attn, norm_scale, h, rope, kc, vc, layer, batch, attn_impl):
+    """``h + o_proj(latent attention(RMS(h)))`` with ``kv_b_proj``
+    absorbed, writing state layer ``layer``: the attention half of a
+    Moonlight layer and of either half of a LongCat double layer. → (h,
+    kc, vc).
+
+    The query is full-rank (``attn["q_proj"]``) or low-rank
+    (``q_b_proj(RMS(q_a_proj(x)))``), whichever the layer's params hold.
+    ``cfg.query_scale`` (LongCat's ``mla_scale_q_lora``) multiplies the
+    query, which enters the scores linearly, so it rides the softmax
+    scale; ``cfg.latent_scale`` (``mla_scale_kv_lora``) multiplies the
+    normalised compressed row, inside its norm's float32, and the pool
+    holds the scaled row (the rotated key is not scaled). A config
+    without them has both at 1. ``rope``: (cos, sin, index): the tables
+    and each token's row in them."""
+    T = h.shape[0]
+    H, r = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    cos, sin, pos = rope
+    with jax.named_scope("ds.mla"):
+        hn = _rms(h, norm_scale, cfg.rms_norm_eps)
+        if "q_proj" in attn:
+            q = _proj(hn, attn["q_proj"])
+        else:
+            q = _proj(_rms(_proj(hn, attn["q_a_proj"]), attn["q_a_layernorm"]["scale"],
+                           cfg.rms_norm_eps), attn["q_b_proj"])
+        q = q.reshape(T, H, dn + dr)
+        kv_a = _proj(hn, attn["kv_a_proj_with_mqa"])                       # [T, r + dr]
+        latent_norm = attn["kv_a_layernorm"]["scale"]
+        if getattr(cfg, "latent_scale", 1.0) != 1.0:
+            latent_norm = latent_norm.astype(jnp.float32) * cfg.latent_scale
+        c_kv = _rms(kv_a[:, :r], latent_norm, cfg.rms_norm_eps)
+        q_rope = _rope_deinterleaved(q[..., dn:], cos, sin, pos)
+        k_rope = _rope_deinterleaved(kv_a[:, None, r:], cos, sin, pos)[:, 0]
+        w_kv = attn["kv_b_proj"]["kernel"].reshape(r, H, dn + dv)
+        q_lat = jnp.einsum("thd,rhd->thr", q[..., :dn], w_kv[..., :dn])    # W_UK absorbed
+        o_lat, kc, vc = _latent_attend(q_lat, q_rope, c_kv, k_rope, kc, vc, layer, batch,
+                                       getattr(cfg, "query_scale", 1.0) / math.sqrt(dn + dr),
+                                       attn_impl)
+        out = jnp.einsum("thr,rhv->thv", o_lat, w_kv[..., dn:])            # W_UV after attention
+        return h + _proj(out.reshape(T, H * dv), attn["o_proj"]), kc, vc
+
+
 def _moonlight_layer_step(cfg, cos, sin, batch, attn_impl, experts, carry, xs):
     """One Moonlight layer over the flat ragged batch: latent attention
     with ``kv_b_proj`` absorbed, then the dense SwiGLU (a leading layer)
@@ -573,26 +680,9 @@ def _moonlight_layer_step(cfg, cos, sin, batch, attn_impl, experts, carry, xs):
     (:meth:`MoonlightKind.layers`)."""
     h, kc, vc = carry
     layer, lp = xs
-    T = h.shape[0]
-    H, r = cfg.num_attention_heads, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    attn = lp["self_attn"]
-
-    with jax.named_scope("ds.mla"):
-        hn = _rms(h, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
-        q = _proj(hn, attn["q_proj"]).reshape(T, H, dn + dr)
-        kv_a = _proj(hn, attn["kv_a_proj_with_mqa"])                       # [T, r + dr]
-        c_kv = _rms(kv_a[:, :r], attn["kv_a_layernorm"]["scale"], cfg.rms_norm_eps)
-        pos = batch["token_pos"]
-        q_rope = _rope_deinterleaved(q[..., dn:], cos, sin, pos)
-        k_rope = _rope_deinterleaved(kv_a[:, None, r:], cos, sin, pos)[:, 0]
-        w_kv = attn["kv_b_proj"]["kernel"].reshape(r, H, dn + dv)
-        q_lat = jnp.einsum("thd,rhd->thr", q[..., :dn], w_kv[..., :dn])    # W_UK absorbed
-        o_lat, kc, vc = _latent_attend(q_lat, q_rope, c_kv, k_rope, kc, vc, layer, batch,
-                                       1.0 / math.sqrt(dn + dr), attn_impl)
-        out = jnp.einsum("thr,rhv->thv", o_lat, w_kv[..., dn:])            # W_UV after attention
-        h = h + _proj(out.reshape(T, H * dv), attn["o_proj"])
-
+    h, kc, vc = _latent_attention(cfg, lp["self_attn"], lp["input_layernorm"]["scale"], h,
+                                  (cos, sin, batch["token_pos"]), kc, vc, layer, batch,
+                                  attn_impl)
     hn2 = _rms(h, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
     if "gate" in lp["mlp"]:
         h = h + _moonlight_moe(hn2, lp["mlp"], experts, layer - cfg.first_k_dense_replace, cfg)
@@ -601,16 +691,85 @@ def _moonlight_layer_step(cfg, cos, sin, batch, attn_impl, experts, carry, xs):
     return (h, kc, vc), None
 
 
+def _longcat_moe(x, p, experts, layer, cfg, batch):
+    """The shortcut expert layer ``M(x)`` as this share gives it, and its
+    three counts. Softmax over every column of the router in float32 (the
+    matmul at the highest precision: the picks are a step function of
+    it); the ``moe_topk`` columns with the largest score + bias, weighted
+    by their unbiased scores, not normalised, times
+    ``routed_scaling_factor``. The picks go to the one expert entry
+    (``ops/grouped_gemm.dropless_moe_ffn``) with the share: held picks
+    through the grouped matmul over the table of every layer's held
+    experts (:func:`_layer_groups`), zero-compute picks as ``(their
+    weights) * x``, the rest left out. → (m [T, D], int32 [3]: picks
+    held, picks zero, held experts with a row — over the tokens that are
+    not padding, whose rows launch no group either)."""
+    from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn
+    router = p["router"]
+    scores = jax.nn.softmax(jnp.dot(x.astype(jnp.float32),
+                                    router["classifier"]["weight"].astype(jnp.float32),
+                                    precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, topk_idx = jax.lax.top_k(scores + router["e_score_correction_bias"].astype(jnp.float32),
+                                cfg.moe_topk)
+    topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1) * cfg.routed_scaling_factor
+    share = ExpertShare(cfg.first_expert_held, cfg.held, cfg.n_routed_experts,
+                        cfg.zero_expert_num)
+    # a padding token picks nothing (-1): the last row of the block tables is padding's
+    real = batch["token_seq"] < batch["block_tables"].shape[0] - 1
+    topk_idx = jnp.where(real[:, None], topk_idx, -1)
+    table, first_group = _layer_groups(experts, layer)
+    m = dropless_moe_ffn(x, topk_idx, topk_vals, table["gate_proj"], table["up_proj"],
+                         table["down_proj"], num_experts=share.routed + share.zero,
+                         widen_boundary=False, first_group=first_group, share=share)
+    held, zero = share.parts(topk_idx)
+    of_expert = topk_idx[..., None] == share.first + jnp.arange(share.held)
+    live = jnp.any(of_expert & held[..., None], axis=(0, 1))
+    return m, jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
+
+
+def _longcat_layer_step(cfg, rope, batch, attn_impl, experts, carry, xs):
+    """One LongCat double layer over the flat ragged batch
+    (``models/longcat.py`` has the equations): attention, then the expert
+    layer ``m = M(x)`` and the first dense SwiGLU on the same normalised
+    stream ``x``; attention and the second dense SwiGLU; ``m`` joins the
+    residual only there, so the expert branch has no data dependence on
+    the second half. ``xs``: (this layer's two state layers, its params,
+    each half's under ``"0"`` / ``"1"``); → the carry and
+    :func:`_longcat_moe`'s counts."""
+    h, kc, vc = carry
+    ids, lp = xs
+
+    def half(i):
+        return {k: v[str(i)] for k, v in lp.items() if k != "mlp"}
+
+    a, b = half(0), half(1)
+    h, kc, vc = _latent_attention(cfg, a["self_attn"], a["input_layernorm"]["scale"], h, rope,
+                                  kc, vc, ids[0], batch, attn_impl)
+    x = _rms(h, a["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
+    with jax.named_scope("ds.moe_routed"):
+        m, counts = _longcat_moe(x, lp["mlp"], experts, ids[0] // 2, cfg, batch)
+    with jax.named_scope("ds.dense_ffn"):
+        h = h + _swiglu(x, a["mlps"])
+    h, kc, vc = _latent_attention(cfg, b["self_attn"], b["input_layernorm"]["scale"], h, rope,
+                                  kc, vc, ids[1], batch, attn_impl)
+    with jax.named_scope("ds.dense_ffn"):
+        h = h + _swiglu(_rms(h, b["post_attention_layernorm"]["scale"], cfg.rms_norm_eps),
+                        b["mlps"]) + m
+    return (h, kc, vc), counts
+
+
 def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=None,
                    attn_impl=None, lora=None):
-    """→ (last-token logits [max_seqs, vocab] fp32, kcache, vcache).
+    """→ (last-token logits [max_seqs, vocab] fp32, kcache, vcache), and
+    where the model kind counts on the device (``kind.step_counts``), a
+    fourth: those counts, int32, summed over the scanned layers.
 
     ``kcache``/``vcache``: the two pools of the model kind's state
     (:func:`kind_of`: keys and values ``[L, NB, bs, Hkv*Dh]``, or the
     latent rows and rotated keys of ``MoonlightKind``), carried through
     the layers and written in place (donate them); ``batch``: the
     arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is a ``LlamaConfig``,
-    ``GPTConfig`` or ``MoonlightConfig``; the layer wiring follows its kind. ``mesh``: an optional
+    ``GPTConfig``, ``MoonlightConfig`` or ``LongcatFlashConfig``; the layer wiring follows its kind. ``mesh``: an optional
     serving mesh — params/KV arrive sharded per
     ``inference/v2/sharding.py`` and the step pins the Megatron layout
     (replicated tokens, head/feature-sharded projections) so GSPMD
@@ -638,7 +797,7 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     carry = (h, kcache, vcache)
     for lead_step, lead_xs in leading:
         carry, _ = lead_step(carry, lead_xs)
-    (h, kc, vc), _ = jax.lax.scan(step, carry, xs)
+    (h, kc, vc), counts = jax.lax.scan(step, carry, xs)
 
     h = kind.final_norm(params, cfg, h)
     if "lm_head" in params:
@@ -647,4 +806,6 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
         logits = h @ embed.T.astype(h.dtype)
     logits = _c(logits, (None, "tensor"), mesh)  # vocab-sharded head
     sel = logits[batch["last_index"]]  # [max_seqs, V]
+    if kind.step_counts:
+        return sel.astype(jnp.float32), kc, vc, counts.sum(axis=0)
     return sel.astype(jnp.float32), kc, vc

@@ -7,11 +7,13 @@ from deepspeed_tpu.models.bert import (BERT_CONFIGS, BertConfig, BertForMaskedLM
                                        build_bert)  # noqa: F401
 from deepspeed_tpu.models.moonlight import (MOONLIGHT_CONFIGS, MoonlightConfig,
                                             MoonlightForCausalLM, build_moonlight)  # noqa: F401
+from deepspeed_tpu.models.longcat import (LONGCAT_CONFIGS, LongcatFlashConfig,
+                                          LongcatFlashForCausalLM, build_longcat)  # noqa: F401
 
 # The causal-LM families a preset name can build, in the order names are looked up
 # (the v2 serving engine takes any of them: inference/v2/model_runner.kind_of).
 MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
-                  (MOONLIGHT_CONFIGS, build_moonlight))
+                  (MOONLIGHT_CONFIGS, build_moonlight), (LONGCAT_CONFIGS, build_longcat))
 
 
 def build_model(preset, **overrides):

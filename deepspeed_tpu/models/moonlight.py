@@ -34,7 +34,7 @@ Training this model is not implemented.
 
 import dataclasses
 import math
-from typing import Optional
+from typing import Callable, Optional
 
 import flax.linen as nn
 import jax
@@ -158,13 +158,15 @@ def _initializer(name):
 
 
 class _Tree(nn.Module):
-    """Declares the parameters of one level of :func:`param_shapes`."""
+    """Declares the parameters of one level of :func:`param_shapes`;
+    ``initializer``: a parameter's name → its initializer."""
     shapes: dict
+    initializer: Callable = _initializer
 
     @nn.compact
     def __call__(self):
-        return {name: _Tree(value, name=name)() if hasattr(value, "items")
-                else self.param(name, _initializer(name), tuple(value))
+        return {name: _Tree(value, self.initializer, name=name)() if hasattr(value, "items")
+                else self.param(name, self.initializer(name), tuple(value))
                 for name, value in self.shapes.items()}
 
 

@@ -28,6 +28,7 @@ expert weight stack materialize in HBM. ``DS_FUSED_GMM=0`` restores
 dequantize-at-entry wholesale (the A/B baseline and escape hatch).
 """
 
+import dataclasses
 import functools
 import threading
 
@@ -249,19 +250,25 @@ def _gathered_moe_mlp(x, expert_idx, w_gate, w_up, w_down, activation):
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
-def _tile_routing(expert_idx, num_experts, tm):
+def _tile_routing(expert_idx, num_experts, tm, live=None):
     """expert_idx [M] → (each row's slot in the tile-aligned layout [M],
     the owning expert per row tile, the tiles the groups fill):
     :func:`tile_layout` on the groups' sizes, and a row's slot is its
     group's first padded row plus its rank in the group (its running
     count down the one-hot's column). Jitted, so programs that serve the
-    same number of rows share its trace."""
+    same number of rows share its trace. ``live`` [M] bool (None: every
+    row): the rows that belong to a group; the others count in no group
+    and get slots past the layout's last row, each its own."""
     from deepspeed_tpu.ops.pallas.grouped_matmul import tile_layout
-    oh = (expert_idx[:, None] == jnp.arange(num_experts, dtype=expert_idx.dtype)[None, :]
-          ).astype(jnp.int32)
+    oh = expert_idx[:, None] == jnp.arange(num_experts, dtype=expert_idx.dtype)[None, :]
+    if live is not None:
+        oh = oh & live[:, None]
+    oh = oh.astype(jnp.int32)
     ranks = jnp.cumsum(oh, axis=0)
-    padded_starts, te, _, num_tiles = tile_layout(ranks[-1], expert_idx.shape[0], tm)
+    padded_starts, te, Mp, num_tiles = tile_layout(ranks[-1], expert_idx.shape[0], tm)
     pdst = jnp.sum(oh * (padded_starts[None, :] + ranks - 1), axis=1)
+    if live is not None:
+        pdst = jnp.where(live, pdst, Mp + jnp.arange(expert_idx.shape[0], dtype=pdst.dtype))
     return pdst.astype(jnp.int32), te, num_tiles
 
 
@@ -279,7 +286,7 @@ def _gmm_dispatch(xp, w, te, tm, interp, first_group=None, num_tiles=None):
 
 
 def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation=jax.nn.silu,
-                    first_group=None):
+                    first_group=None, live=None, rows_a_group=None):
     """Dropless top-1 MoE FFN: x [T, D]; expert_idx [T]; weights
     [E, D, F] / [E, D, F] / [E, F, D] → [T, D]. Every token reaches its
     expert (no capacity drops — the grouped-GEMM advantage). Each
@@ -311,9 +318,20 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
     gathered contraction see the other groups empty. ``GMM_STATS`` counts
     these as ``pallas_table`` / ``ragged_table`` / ``gathered_table``.
     Only the fused quantized kernel still gets the call's carriers cut
-    out."""
+    out.
+
+    ``live`` [T] bool (None: every row): the rows whose expert is among
+    this call's ``num_experts`` — an expert-parallel share's held picks
+    (:class:`ExpertShare`). The other rows lie **outside every group**:
+    no matmul tile runs for them, no expert's weights are read for them,
+    and their result rows are zero; their ``expert_idx`` is not looked at.
+    ``rows_a_group``: the rows a group expects (the row tile is fitted to
+    it; None: ``T / num_experts``). Dense stacks only; ``GMM_STATS``
+    counts these with ``_share``."""
     from jax.ad_checkpoint import checkpoint_name
     quantized = any(_is_quantized(w) for w in (w_gate, w_up, w_down))
+    if live is not None and quantized:
+        raise NotImplementedError("an expert share (live rows) over quantized expert stacks")
     if quantized and not fused_gmm_enabled():
         # DS_FUSED_GMM=0: restore dequantize-then-dispatch wholesale
         w_gate, w_up, w_down = (_unbox_stack(w, x.dtype)
@@ -339,11 +357,14 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
         if not use_pallas:
             expert_idx = expert_idx + first_group
             groups = jax.tree.leaves(w_gate)[0].shape[0]
+    if live is not None:
+        table += "_share"
     if use_pallas:
         GMM_STATS.count(("pallas_quant" if quantized else "pallas") + table)
         from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile
         if not quantized:
-            tm = row_tile(x.shape[0], num_experts, x.dtype)
+            tm = row_tile(x.shape[0] if rows_a_group is None else rows_a_group * num_experts,
+                          num_experts, x.dtype)
         elif FORCE_INTERPRET:
             tm = min(_QUANT_TILE_M, max(8, x.shape[0] // 8))
         elif x.shape[0] < 8 * _QUANT_TILE_M:
@@ -358,7 +379,7 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
         # the tile-aligned layout and one gather undoes it. Tagged so
         # the "moe" remat policy saves the routing instead of
         # recomputing it in the backward.
-        pdst, te, num_tiles = _tile_routing(expert_idx, num_experts, tm)
+        pdst, te, num_tiles = _tile_routing(expert_idx, num_experts, tm, live)
         pdst = checkpoint_name(pdst, "moe_routing")
         te = checkpoint_name(te, "moe_tiles")
         # rows land in distinct padded slots: the uniqueness hint keeps
@@ -366,6 +387,8 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
         # (A gather-based pack via a slot→row map was measured and is
         # slower — the transposed scatter-add in backward gives the
         # saving back with interest.)
+        # (a row outside every group has a slot past the layout: the scatter drops it,
+        # and the gather below fills its result row with zeros)
         xp = jnp.zeros((te.shape[0] * tm, x.shape[1]), x.dtype).at[pdst].set(
             x, unique_indices=True)
         xp = checkpoint_name(xp, "moe_xs")
@@ -375,8 +398,13 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
         gate = checkpoint_name(matmul(xp, w_gate), "moe_gate")
         up = checkpoint_name(matmul(xp, w_up), "moe_up")
         inter = activation(gate) * up
-        return jnp.take(matmul(inter, w_down), pdst, axis=0, unique_indices=True)
-    if x.shape[0] < num_experts:
+        return jnp.take(matmul(inter, w_down), pdst, axis=0, unique_indices=True,
+                        fill_value=None if live is None else 0)
+    if live is not None:
+        # outside every group: past the last group in the sort, in no group's size, and
+        # ragged_dot leaves the rows past its groups zero
+        expert_idx = jnp.where(live, expert_idx, groups)
+    elif x.shape[0] < num_experts:
         GMM_STATS.count(("gathered_quant" if quantized else "gathered") + table)
         return _gathered_moe_mlp(x, expert_idx, w_gate, w_up, w_down,
                                  activation)
@@ -420,6 +448,26 @@ def _join_stacks(flat, tags):
     return out
 
 
+@dataclasses.dataclass(frozen=True)
+class ExpertShare:
+    """Which of a router's columns this process computes: ``held`` routed
+    experts from ``first`` (the stacks given to :func:`dropless_moe_ffn`
+    are these, in order), out of ``routed``, followed by ``zero``
+    zero-compute columns whose expert is the identity. One rank of an
+    expert-parallel deployment holds ``routed / ranks`` experts and
+    computes the identity part of the tokens that live on it."""
+    first: int
+    held: int
+    routed: int
+    zero: int = 0
+
+    def parts(self, topk_idx):
+        """→ (the picks whose expert is held, the zero-compute picks), bool
+        as ``topk_idx``; the rest belong to experts held elsewhere."""
+        return ((topk_idx >= self.first) & (topk_idx < self.first + self.held),
+                topk_idx >= self.routed)
+
+
 def shards_experts(mesh):
     """Whether :func:`dropless_moe_ffn` runs its experts sharded under
     ``mesh``: an ``expert`` or ``tensor`` axis larger than 1."""
@@ -429,7 +477,7 @@ def shards_experts(mesh):
 
 
 def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
-                     widen_boundary=True, first_group=None):
+                     widen_boundary=True, first_group=None, share=None):
     """Post-gate dropless MoE FFN over flat tokens — the one
     implementation behind BOTH v2 ragged serving and dropless training.
 
@@ -458,8 +506,32 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
 
     ``first_group``: the stacks are a table of expert groups of which
     this call's ``num_experts`` start there (:func:`moe_grouped_mlp`);
-    without expert/tensor axes only."""
+    without expert/tensor axes only.
+
+    ``share`` (an :class:`ExpertShare`; None: the stacks are every column
+    of the router, today's programs unchanged): the stacks are the
+    ``share.held`` experts held here and ``topk_idx`` counts over all the
+    router's columns (``num_experts`` is not read). → ``sum_j w_j E_j(x)``
+    over the held picks, which alone become rows of a group, plus ``(the
+    zero-compute picks' weights) * x``; what experts held elsewhere would
+    add is left out, and nothing is multiplied or read for it. A pick of
+    -1 is no pick (a padding token's). One device only: the exchange
+    between the shares of a mesh is not implemented."""
     T, k = topk_idx.shape
+    live = rows_a_group = w_zero = None
+    if share is not None:
+        if shards_experts(mesh):
+            raise NotImplementedError("an expert share on a mesh with expert/tensor axes: the "
+                                      "exchange between shares is not implemented")
+        held, zero = share.parts(topk_idx)
+        if share.zero:
+            with jax.named_scope("ds.moe_zero"):
+                w_zero = jnp.sum(jnp.where(zero, topk_vals, 0), axis=-1, keepdims=True)
+        # the one tail below, over the held experts: a pick that is not held is a row
+        # outside every group, weighted zero
+        live, rows_a_group = held.reshape(-1), max(1, T * k // (share.routed + share.zero))
+        topk_idx, topk_vals = topk_idx - share.first, jnp.where(held, topk_vals, 0)
+        num_experts = share.held
     idx_rep = topk_idx.reshape(-1)  # [T*k]
     if not fused_gmm_enabled():
         # DS_FUSED_GMM=0: unbox quantized stacks up front — everything
@@ -524,9 +596,14 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
     x_rep = jnp.repeat(x, k, axis=0)  # [T*k, D]
     out_rep = moe_grouped_mlp(x_rep, idx_rep, _cast_stack(w1, x.dtype),
                               _cast_stack(w3, x.dtype), _cast_stack(w2, x.dtype),
-                              num_experts=num_experts, first_group=first_group)
+                              num_experts=num_experts, first_group=first_group,
+                              live=live, rows_a_group=rows_a_group)
     out_k = out_rep.reshape(T, k, -1)
-    return jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
+    out = jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
+    if w_zero is not None:
+        with jax.named_scope("ds.moe_zero"):
+            out = out + w_zero.astype(x.dtype) * x
+    return out
 
 
 def dense_reference_mlp(x, expert_idx, w_gate, w_up, w_down, activation=jax.nn.silu):

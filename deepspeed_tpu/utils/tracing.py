@@ -45,7 +45,8 @@ PREFIX = "ds."
 now_ns = time.perf_counter_ns
 
 STEP_FIELDS = ("seq", "engine", "kind", "program", "k", "n_seqs", "n_tokens",
-               "n_prompt_tokens", "n_ctx_tokens", "caused_by", "uids", "start_ns", "end_ns")
+               "n_prompt_tokens", "n_ctx_tokens", "counts", "caused_by", "uids", "start_ns",
+               "end_ns")
 
 
 class StepRecord:
@@ -127,6 +128,9 @@ class Recorder:
         rec.engine, rec.kind, rec.program, rec.k = engine, kind, program, k
         rec.n_seqs, rec.n_tokens, rec.n_prompt_tokens = n_seqs, n_tokens, n_prompt_tokens
         rec.n_ctx_tokens = 0    # the engine adds each row's attended context as it packs
+        # what the program itself counted on the device, by name, fetched with its result
+        # (model_runner: kind.step_counts); None where the model kind counts nothing
+        rec.counts = None
         rec.uids = uids
         rec.phases, rec.keep, rec.end_ns = [], True, None
         parent = rec._parent = self.current()
