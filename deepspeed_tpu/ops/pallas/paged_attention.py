@@ -3,7 +3,7 @@
 TPU-native counterpart of the reference's ragged decode kernels
 (``deepspeed/inference/v2/kernels/ragged_ops/atom_builder`` +
 ``blocked_flash`` over the blocked KV cache,
-``csrc/.../ragged_ops/``). Each grid step handles ONE token: its block
+``csrc/.../ragged_ops/``). Each token walks its own context: its block
 table rides in SMEM (scalar prefetch), KV blocks are dynamically
 indexed out of the pool, and scores accumulate flash-style (running
 max / sum) with positions beyond the token's context masked. GQA is
@@ -14,6 +14,41 @@ Both paths take the WHOLE pool ``[L, NB, bs, Hkv*Dh]`` — the layout
 scan never cuts a layer out of the pool and no program reshapes it:
 the kernel's block DMA reads ``pool[layer, blk]``, the reference
 gathers ``pool[layer, block_tables]``.
+
+**A tile** is ``n`` consecutive table blocks of one token's context laid
+one under the other in a VMEM slot ``[n*bs, Hkv*Dh]``, one slot for K and
+one for V: block ``j`` of the tile lands at rows ``j*bs``. ``n`` follows
+from the shapes (:func:`tile_blocks`: 16 blocks = 256 rows for 16-row
+bf16 blocks of 8 KV heads, so that the ``[H, n*bs]`` score tile is whole
+128-lane vregs and a KV head's two matmuls run once a tile; 1 where a
+block is not a whole number of sublane tiles). All ``2n`` copies of a
+tile are started before any is waited for, and there are **two slots**:
+while tile ``i`` is multiplied, tile ``i+1``'s copies fly into the other
+slot — and after a
+token's last tile, the **next token's first tile** (grid steps run in
+order on one core; every token's table and position are in SMEM from the
+start), so that no token waits for a fetch it could have had. A token
+whose whole context is the one block the token before it had as its own
+(a run of padding rows on the null block; a prompt's first tokens) finds
+it in that token's slot and fetches nothing.
+
+**Stale rows.** A tile's blocks past the context's last are not fetched,
+so their rows are whatever the slot held. Keys: the scores at positions
+past the token's are replaced (``where``), so nothing of them survives,
+NaN or not. Values: a masked position's probability is exactly 0, and
+0 x NaN is NaN, so V's slots are zeroed once, in the first grid step;
+from then on a slot's rows are zeros or rows of blocks that some token's
+context named — what the block-a-step loop also multiplied by 0 in a
+context's last block. A block no context names is never read.
+
+**Arithmetic** is the pool's: a 2-byte pool's rows (and queries of the
+same dtype) go to the MXU as they lie, one pass with float32
+accumulation — bf16 x bf16 products are exact in float32 — and the
+probabilities are rounded to the pool's dtype for the second product, as
+:func:`xla_paged_attention` and the latent kernel do; the scale is
+applied to the float32 scores, and running max, sum and accumulator stay
+float32. A float32 pool (what the CPU tests hold to 1e-5) keeps
+``Precision.HIGHEST``.
 
 The XLA reference path (``xla_paged_attention``) is the same math via
 gather. Which of the two a program runs is decided in ONE place, the
@@ -42,6 +77,10 @@ SMEM_TABLE_BYTES = 768 * 1024
 # The gather reference materializes a dense [T, MB*bs, Hkv, Dh] copy of
 # K and of V per layer; past this it is an opaque allocator OOM.
 GATHER_LIMIT_BYTES = 2 << 30
+# A tile's context rows (the score tile's lanes) and the VMEM its four
+# slots (K and V, two each) may take: tile_blocks().
+TILE_ROWS = 256
+TILE_VMEM_BYTES = 4 << 20
 
 
 def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, alibi_slopes=None):
@@ -77,18 +116,20 @@ def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, alibi_slopes=
 
 
 def kernel_supported(head_dim, block_size, n_kv_heads=None):
-    """Mosaic constraint: the per-block DMA copies a 2-D
-    ``[block_size, Hkv*Dh]`` slice (the pool stores its KV-head and head
-    dims flattened), so the lane dim is ``Hkv * head_dim``
-    — a multiple of 128 for any head count when head_dim % 128 == 0, and
-    the sublane dim is ``block_size`` (multiple of 8). ANY KV-head count
-    is supported this way (round 4's Hkv % 8 restriction came from
-    slicing the un-flattened [bs, Hkv, Dh] pool, whose second-minor dim
-    had to tile the 8-sublane granule — 1/6/12/20-head pools crashed
-    Mosaic; the flattened layout re-measured compiling and matching the
-    XLA reference on a real v5e for all four counts, 2026-08-01). 64-dim-head models (e.g. Bloom-560M, GPT-2) and ALiBi
-    models take the XLA gather path
-    (see ``inference/v2/modules/heuristics.py``). A model whose heads are
+    """Mosaic constraint: a block DMA copies a 2-D ``[block_size,
+    Hkv*Dh]`` slice (the pool stores its KV-head and head dims
+    flattened), so the lane dim is ``Hkv * head_dim`` — a multiple of 128
+    for any head count when head_dim % 128 == 0 — and the sublane dim is
+    ``block_size`` (multiple of 8). ANY KV-head count is supported this
+    way (round 4's Hkv % 8 restriction came from slicing the un-flattened
+    [bs, Hkv, Dh] pool, whose second-minor dim had to tile the 8-sublane
+    granule — 1/6/12/20-head pools crashed Mosaic; the flattened layout
+    re-measured compiling and matching the XLA reference on a real v5e
+    for all four counts, 2026-08-01). How many blocks make a tile is not a
+    matter of support: :func:`tile_blocks` gives every shape this
+    function admits some ``n``, 1 at the least. 64-dim-head models (e.g.
+    Bloom-560M, GPT-2) and ALiBi models take the XLA gather path (see
+    ``inference/v2/modules/heuristics.py``). A model whose heads are
     neither (Moonlight's 192-wide queries over a 576-value latent row) is
     not this kernel's at all: its state kind is ``latent`` and its kernel
     ``paged_mla_attention.paged_mla_decode_attention``, with
@@ -103,91 +144,144 @@ def smem_table_fits(n_tokens, max_blocks):
     return (n_tokens * max_blocks + n_tokens + 1) * 4 <= SMEM_TABLE_BYTES
 
 
+def tile_blocks(block_size, row_bytes, itemsize, max_blocks):
+    """``n``: the table blocks of one tile, from the shapes alone. A tile
+    wants ``TILE_ROWS`` context rows: the score tile is then whole
+    128-lane vregs, a head's two matmuls run once a tile and not once a
+    block, and the loop turns once for 2n copies. It is halved until K's
+    and V's two slots fit ``TILE_VMEM_BYTES``, and is never more than the
+    table has blocks. Block ``j`` lands at rows ``j * block_size`` of a
+    slot, which Mosaic wants to be a whole number of the dtype's sublane
+    tiles (8 rows of 4 bytes, 16 of 2, 32 of 1): a block that is not gets
+    ``n`` = 1, a slot of its own. The cells' shape (16-row bf16 blocks of
+    8 x 128) gets 16 blocks = 256 rows = 2 MiB of slots: on the chip
+    (``tools/kernel_census.py --paged``; PERF.md, PR 33) 1, 2, 4, 8, 16
+    and 32 blocks read 20, 32, 50, 67, 80 and 81 % of the HBM roofline at
+    decode contexts of 128-1536, and all of 8-32 the same 27 % where three
+    rows in four are padding."""
+    if block_size % (32 // itemsize):
+        return 1
+    n = max(1, TILE_ROWS // block_size)
+    while n > 1 and 4 * n * block_size * row_bytes > TILE_VMEM_BYTES:
+        n //= 2
+    return min(n, max_blocks)
+
+
 def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
-            k_buf, v_buf, k_sem, v_sem, *, bs, max_blocks, groups, n_kv_heads):
+            k_buf, v_buf, sems, slot_ref, *, bs, n, max_blocks, groups, n_kv_heads, native):
     """One token: q_ref [1, H, Dh] (VMEM); kc/vc, the whole pool
-    [L, NB, bs, Hkv*Dh], stay in HBM (ANY) — each table block of the
-    layer is DMA'd into the VMEM scratch buffers as a 2-D [bs, Hkv*Dh]
-    slice (lane dim a 128-multiple for ANY KV-head count); tab/pos/layer
-    in SMEM via scalar prefetch. Per-head columns are 128-aligned lane
-    slices of the buffer."""
+    [L, NB, bs, Hkv*Dh], stay in HBM (ANY); tab/pos/layer in SMEM via
+    scalar prefetch. The module docstring says what a tile is, what is in
+    flight when, and what a slot's stale rows may hold."""
     t = pl.program_id(0)
+    T = pl.num_programs(0)
     layer = layer_ref[0]
     H, Dh = q_ref.shape[1], q_ref.shape[2]
-    Hkv = n_kv_heads
-    G = groups
-    pos = pos_ref[t]
+    rows = n * bs
     scale = 1.0 / np.sqrt(Dh)
-    # everything stays 2-D: Mosaic's vector layouts reject >2-D reshapes
-    q = q_ref[0].astype(jnp.float32) * scale  # [H, Dh], heads grouped [Hkv x G]
+    precision = None if native else jax.lax.Precision.HIGHEST
 
-    def block_step(i, carry):
+    # positions and counts are never negative: lax.div / & 1, not the floor
+    # division and modulo whose sign handling Mosaic lowers at length
+    def n_blocks(tok):
+        return jnp.minimum(jax.lax.div(pos_ref[tok], bs) + 1, max_blocks)
+
+    def same_block(tok, before):
+        """Is ``tok``'s whole context the one block ``before`` had as its
+        own? Then it sits in that token's slot already: nothing to fetch."""
+        return ((n_blocks(tok) == 1) & (n_blocks(before) == 1)
+                & (tab_ref[tok, 0] == tab_ref[before, 0]))
+
+    def each_block(tok, i, slot, act):
+        """``act`` on the K and V copies of the blocks of ``tok``'s tile
+        ``i`` that lie inside its context."""
+        def one(j, carry):
+            blk = tab_ref[tok, i * n + j]
+            at = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            act(pltpu.make_async_copy(kc_ref.at[layer, blk], k_buf.at[slot, at], sems.at[0, slot]))
+            act(pltpu.make_async_copy(vc_ref.at[layer, blk], v_buf.at[slot, at], sems.at[1, slot]))
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(n_blocks(tok) - i * n, n), one, 0)
+
+    def start(tok, i, slot):
+        each_block(tok, i, slot, lambda copy: copy.start())
+
+    def head(x, h):
+        """KV head ``h`` of a slot: 128-aligned lanes of its rows."""
+        x = jax.lax.slice(x, (0, h * Dh), (rows, (h + 1) * Dh))
+        return x if native else x.astype(jnp.float32)
+
+    @pl.when(t == 0)
+    def _():
+        slot_ref[0] = 0
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)  # see "stale rows"
+        start(0, 0, 0)
+
+    pos = pos_ref[t]
+    n_tiles = jax.lax.div(n_blocks(t) + n - 1, n)
+    slot0 = slot_ref[0]
+    reused = (t > 0) & same_block(t, jnp.maximum(t - 1, 0))
+    nxt = jnp.minimum(t + 1, T - 1)
+    nxt_fetches = (t + 1 < T) & jnp.logical_not(same_block(nxt, t))
+    q = q_ref[0]  # [H, Dh], heads grouped [Hkv x G]; everything stays 2-D for Mosaic
+    if not native:
+        q = q.astype(jnp.float32)
+
+    def tile_step(i, carry):
         m, l, acc = carry  # [H, 1], [H, 1], [H, Dh]
-        blk = tab_ref[t, i]
-        ck = pltpu.make_async_copy(kc_ref.at[layer, blk], k_buf, k_sem)
-        cv = pltpu.make_async_copy(vc_ref.at[layer, blk], v_buf, v_sem)
-        ck.start()
-        cv.start()
-        ck.wait()
-        cv.wait()
-        kbuf = k_buf[:]  # one read; heads are lane slices of it
-        vbuf = v_buf[:]
-        # per-kv-head 2-D matmuls, statically unrolled; head h occupies
-        # lanes [h*Dh, (h+1)*Dh) of the flattened buffer
-        s_parts = []
-        for h in range(Hkv):
-            kh = jax.lax.slice(kbuf, (0, h * Dh), (bs, (h + 1) * Dh)
-                               ).astype(jnp.float32)  # [bs, Dh]
-            qh = jax.lax.slice(q, (h * G, 0), ((h + 1) * G, Dh))  # [G, Dh]
-            s_parts.append(jax.lax.dot_general(qh, kh, (((1,), (1,)), ((), ())),
-                                               precision=jax.lax.Precision.HIGHEST))
-        s = jnp.concatenate(s_parts, axis=0)  # [H, bs]
-        kv_pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        slot = (slot0 + i) & 1
+        last = i + 1 == n_tiles
+
+        # the next tile in order - this token's, or the next token's first -
+        # flies during this tile's arithmetic
+        @pl.when(jnp.logical_not(last) | nxt_fetches)
+        def _():
+            start(jnp.where(last, nxt, t), jnp.where(last, 0, i + 1), 1 - slot)
+
+        @pl.when((i > 0) | jnp.logical_not(reused))
+        def _():
+            each_block(t, i, slot, lambda copy: copy.wait())
+
+        kbuf = k_buf[slot]  # one read; heads are lane slices of it
+        vbuf = v_buf[slot]
+        s = jnp.concatenate([
+            jax.lax.dot_general(jax.lax.slice(q, (h * groups, 0), ((h + 1) * groups, Dh)),
+                                head(kbuf, h), (((1,), (1,)), ((), ())), precision=precision,
+                                preferred_element_type=jnp.float32)
+            for h in range(n_kv_heads)], axis=0) * scale  # [H, rows]
+        kv_pos = i * rows + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
         s = jnp.where(kv_pos <= pos, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv_parts = []
-        for h in range(Hkv):
-            vh = jax.lax.slice(vbuf, (0, h * Dh), (bs, (h + 1) * Dh)
-                               ).astype(jnp.float32)  # [bs, Dh]
-            ph = jax.lax.slice(p, (h * G, 0), ((h + 1) * G, bs))  # [G, bs]
-            pv_parts.append(jax.lax.dot_general(ph, vh, (((1,), (0,)), ((), ())),
-                                                precision=jax.lax.Precision.HIGHEST))
-        pv = jnp.concatenate(pv_parts, axis=0)  # [H, Dh]
-        acc_new = acc * alpha + pv
-        return m_new, l_new, acc_new
+        p = p.astype(vbuf.dtype if native else jnp.float32)
+        pv = jnp.concatenate([
+            jax.lax.dot_general(jax.lax.slice(p, (h * groups, 0), ((h + 1) * groups, rows)),
+                                head(vbuf, h), (((1,), (0,)), ((), ())), precision=precision,
+                                preferred_element_type=jnp.float32)
+            for h in range(n_kv_heads)], axis=0)  # [H, Dh]
+        return m_new, l_new, acc * alpha + pv
 
     m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((H, 1), jnp.float32)
     a0 = jnp.zeros((H, Dh), jnp.float32)
-    n_blocks = jnp.minimum(pos // bs + 1, max_blocks)
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, block_step, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1e-30)
-    o_ref[0] = out.astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, tile_step, (m0, l0, a0))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    # the next token's first tile is in the other slot, or, reused, in this one
+    last_slot = (slot0 + n_tiles - 1) & 1
+    slot_ref[0] = jnp.where(nxt_fetches, 1 - last_slot, last_slot)
 
 
-def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=None):
-    """Pallas path of :func:`xla_paged_attention` (same contract)."""
-    if interpret is None:
-        from deepspeed_tpu.ops.pallas import default_interpret
-        interpret = default_interpret()
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
+def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret):
+    """The kernel at ``n`` blocks a tile (``tools/kernel_census.py``
+    sweeps it; everything else gets :func:`tile_blocks`'). Jitted so
+    that the serving programs of one shape (23 a cell lower the kernel in
+    their layer body) share one trace of it."""
     T, H, Dh = q.shape
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     MB = block_tables.shape[1]
-    groups = H // Hkv
-    if not interpret:
-        if not kernel_supported(Dh, bs, Hkv):
-            raise ValueError(
-                f"paged decode kernel needs head_dim % 128 == 0 and block_size % 8 == 0, "
-                f"got head_dim={Dh}, block_size={bs}")
-        if not smem_table_fits(T, MB):
-            raise ValueError(
-                f"paged decode block table [{T}, {MB}] overflows the kernel's "
-                f"{SMEM_TABLE_BYTES >> 10} KB SMEM budget — shrink max_ragged_batch_size / "
-                f"max_context, or raise kv_block_size")
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # tables, positions, layer
         grid=(T,),
@@ -198,19 +292,46 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=
         ],
         out_specs=pl.BlockSpec((1, H, Dh), lambda t, tab, pos, layer: (t, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bs, Hkv * Dh), kc.dtype),
-            pltpu.VMEM((bs, Hkv * Dh), vc.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
+            pltpu.VMEM((2, n * bs, Hkv * Dh), kc.dtype),
+            pltpu.VMEM((2, n * bs, Hkv * Dh), vc.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, slot]
+            pltpu.SMEM((1,), jnp.int32),      # the slot of this token's first tile
         ],
     )
-    kernel = functools.partial(_kernel, bs=bs, max_blocks=MB, groups=groups,
-                               n_kv_heads=Hkv)
+    # bf16 x bf16 products are exact in float32, so a 2-byte pool's rows go to
+    # the MXU as they lie; a float32 pool (or query) keeps the six-pass product
+    native = q.dtype == kc.dtype == vc.dtype and kc.dtype.itemsize == 2
+    kernel = functools.partial(_kernel, bs=bs, n=n, max_blocks=MB, groups=H // Hkv,
+                               n_kv_heads=Hkv, native=native)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, H, Dh), q.dtype),
+        # tokens in order on one core: a token starts the next one's first tile
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), token_pos.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q, kc, vc)
+
+
+def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=None):
+    """Pallas path of :func:`xla_paged_attention` (same contract)."""
+    if interpret is None:
+        from deepspeed_tpu.ops.pallas import default_interpret
+        interpret = default_interpret()
+    T, H, Dh = q.shape
+    bs, Hkv = kc.shape[2], kc.shape[3] // Dh
+    MB = block_tables.shape[1]
+    if not interpret:
+        if not kernel_supported(Dh, bs, Hkv):
+            raise ValueError(
+                f"paged decode kernel needs head_dim % 128 == 0 and block_size % 8 == 0, "
+                f"got head_dim={Dh}, block_size={bs}")
+        if not smem_table_fits(T, MB):
+            raise ValueError(
+                f"paged decode block table [{T}, {MB}] overflows the kernel's "
+                f"{SMEM_TABLE_BYTES >> 10} KB SMEM budget — shrink max_ragged_batch_size / "
+                f"max_context, or raise kv_block_size")
+    n = tile_blocks(bs, kc.shape[3] * kc.dtype.itemsize, kc.dtype.itemsize, MB)
+    return _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret)

@@ -113,6 +113,178 @@ def test_layer_index_reads_that_layer_bit_for_bit(path, Hkv, G):
                               np.asarray(by_index(q, kc, vc, tabs, pos, jnp.int32(2))))
 
 
+# ------------------------------------------------------------- tiles of blocks
+BS, DH, MAX_BLOCKS = 16, 128, 40
+
+
+def _tiled_case(positions, dtype, Hkv=2, G=2, MB=MAX_BLOCKS, seed=0, poison=None):
+    """One sequence a token, each with a block table of its own drawn
+    without replacement from a shuffled pool (permuted, non-contiguous);
+    a position of None is a padding row: position 0 on the null block's
+    table of zeros. ``poison``: "unnamed" fills every block no table names
+    with NaN; "past_context" also points every table entry past its
+    token's context at one NaN block (what the reference may then not be
+    given: it gathers them)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import tile_blocks
+    rng = np.random.RandomState(seed)
+    T, NB = len(positions), len(positions) * MB + 2
+    assert tile_blocks(BS, Hkv * DH * jnp.dtype(dtype).itemsize,
+                       jnp.dtype(dtype).itemsize, MB) in (8, 16)  # the cases below are about tiles
+    q = rng.randn(T, Hkv * G, DH).astype(np.float32)
+    kc = rng.randn(2, NB, BS, Hkv * DH).astype(np.float32)
+    vc = rng.randn(2, NB, BS, Hkv * DH).astype(np.float32)
+    tabs = rng.permutation(np.arange(2, NB))[:T * MB].reshape(T, MB).astype(np.int32)
+    pos = np.zeros(T, np.int32)
+    for t, p in enumerate(positions):
+        if p is None:
+            tabs[t] = 0
+        else:
+            pos[t] = p
+    clean_k, clean_v = kc.copy(), vc.copy()
+    if poison:
+        used = np.zeros(NB, bool)
+        for t in range(T):
+            live = pos[t] // BS + 1
+            if poison == "past_context":
+                tabs[t, live:] = 1
+            used[tabs[t, :live if poison == "past_context" else MB]] = True
+        kc[:, ~used] = np.nan
+        vc[:, ~used] = np.nan
+        clean_k[:, ~used] = 0
+        clean_v[:, ~used] = 0
+    cast = lambda a: jnp.asarray(a, dtype)
+    return (cast(q), cast(kc), cast(vc), jnp.asarray(tabs), jnp.asarray(pos), jnp.int32(1),
+            cast(clean_k), cast(clean_v))
+
+
+def _float32_reference(q, kc, vc, tabs, pos, layer):
+    f32 = lambda a: a.astype(jnp.float32)
+    return np.asarray(xla_paged_attention(f32(q), f32(kc), f32(vc), tabs, pos, layer))
+
+
+# float32 pools keep Precision.HIGHEST: 1e-5, as every float32 case above.
+# A bf16 pool's kernel rounds the probabilities to bf16 for the second
+# product (as the reference path does) and its output to bf16: 2**-9 of
+# values up to ~3 each, against the float32 reference on the same rounded
+# inputs. xla_paged_attention in bf16 also rounds its *scores* to bf16
+# before the softmax (2**-9 of |s| up to ~5, so ~2 % of a probability),
+# which the kernel does not: that comparison gets 6e-2.
+TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
+TOL_BF16_REFERENCE = 6e-2
+
+TILE = 16 * BS  # a tile of the cases' shape: 16 blocks (8 where 20 KV heads fill the slots)
+END = MAX_BLOCKS * BS  # 2.5 tiles
+CONTEXTS = {
+    "ends_inside_a_tile": [TILE + 5 * BS + 3, 3 * BS + 1, 2 * TILE + 7],
+    "ends_on_a_tile_edge": [TILE - 1, TILE, 2 * TILE - 1, 2 * TILE],
+    "one_block": [0, 3, BS - 1, BS],
+    "at_max_blocks": [END - 1, END - 2, 5],
+    "padding_beside_long": [None, 2 * TILE + 40, None, None, 11, None, END - BS, None, None],
+    "one_tile_then_many": [7, END - 1, 2, TILE + 1, 1],
+    "sixteen_rows": [TILE + 9, None, None, 3, 2 * TILE - 1, None, 40, 2 * TILE,
+                       None, None, TILE, 5, None, END - BS + 2, None, None],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", list(CONTEXTS))
+def test_tiled_contexts_match_reference(name, dtype):
+    """Contexts that end inside a tile, on a tile's edge, after one block
+    and at ``max_blocks``, and padding rows beside long rows, over
+    permuted non-contiguous tables, through a traced layer index."""
+    q, kc, vc, tabs, pos, layer, _, _ = _tiled_case(CONTEXTS[name], dtype, seed=len(name))
+    got = jax.jit(lambda *a: paged_decode_attention(*a, interpret=True))(q, kc, vc, tabs, pos, layer)
+    assert got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               _float32_reference(q, kc, vc, tabs, pos, layer),
+                               rtol=TOL[dtype], atol=TOL[dtype])
+
+
+def test_shared_single_block_is_fetched_once_and_read_right():
+    """Neighbouring tokens whose whole context is the same one block
+    (padding rows; a prompt's first tokens) reuse the fetched block, at
+    positions of their own; a token on another block in between fetches."""
+    q, kc, vc, tabs, pos, layer, _, _ = _tiled_case([3, 4, 5, 9, 0, 2], jnp.float32, seed=9)
+    tabs = tabs.at[1].set(tabs[0]).at[2].set(tabs[0]).at[5].set(tabs[4])
+    got = paged_decode_attention(q, kc, vc, tabs, pos, layer, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), _float32_reference(q, kc, vc, tabs, pos, layer),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("poison", ["unnamed", "past_context"])
+def test_stale_rows_of_a_tile_never_reach_the_output(poison, dtype):
+    """The stale-row guard. A tile's blocks past the context's last are
+    not fetched, so their rows are what the slot held: with every block
+    the tables do not name filled with NaN - and, harder, every table
+    entry past a context pointing at a NaN block - the output is finite
+    and is the reference's on the pool without the NaNs."""
+    positions = [TILE + 2 * BS + 5, 3, None, 2 * TILE - 1, BS, END - 1, 0]
+    q, kc, vc, tabs, pos, layer, clean_k, clean_v = _tiled_case(positions, dtype, seed=21,
+                                                               poison=poison)
+    assert np.isnan(np.asarray(kc, np.float32)).any()
+    got = np.asarray(paged_decode_attention(q, kc, vc, tabs, pos, layer, interpret=True),
+                     np.float32)
+    assert np.isfinite(got).all()
+    if poison == "unnamed":  # the reference itself reads no NaN here: literally the same call
+        want = xla_paged_attention(q.astype(jnp.float32), kc.astype(jnp.float32),
+                                   vc.astype(jnp.float32), tabs, pos, layer)
+        np.testing.assert_allclose(got, np.asarray(want), rtol=TOL[dtype], atol=TOL[dtype])
+    np.testing.assert_allclose(got, _float32_reference(q, clean_k, clean_v, tabs, pos, layer),
+                               rtol=TOL[dtype], atol=TOL[dtype])
+
+
+@pytest.mark.parametrize("Hkv,G", [(1, 4), (6, 2), (8, 4), (12, 1), (20, 1)])
+def test_bf16_pool_matches_xla_reference(Hkv, G):
+    """A bf16 pool goes to the MXU as it lies (float32 accumulation, the
+    probabilities rounded to bf16): against the reference path in bf16
+    and against float32 on the same inputs."""
+    positions = [TILE + 37, 5, 2 * TILE, None, 3 * BS - 1]
+    q, kc, vc, tabs, pos, layer, _, _ = _tiled_case(positions, jnp.bfloat16, Hkv=Hkv, G=G,
+                                                   MB=36, seed=40 + Hkv)
+    got = np.asarray(paged_decode_attention(q, kc, vc, tabs, pos, layer, interpret=True),
+                     np.float32)
+    ref = np.asarray(xla_paged_attention(q, kc, vc, tabs, pos, layer), np.float32)
+    np.testing.assert_allclose(got, ref, rtol=TOL_BF16_REFERENCE, atol=TOL_BF16_REFERENCE)
+    np.testing.assert_allclose(got, _float32_reference(q, kc, vc, tabs, pos, layer),
+                               rtol=TOL[jnp.bfloat16], atol=TOL[jnp.bfloat16])
+
+
+@pytest.mark.parametrize("bs,row_bytes,itemsize,max_blocks,want", [
+    (16, 2048, 2, 361, 16),    # the cells: 8 KV heads x 128, bf16: 256 rows, 2 MiB of slots
+    (16, 2048, 2, 96, 16),
+    (16, 2048, 2, 3, 3),       # never more than the table has
+    (16, 4096, 2, 64, 16),     # 16 KV heads, bf16: 4 MiB, the budget
+    (16, 8192, 2, 64, 8),      # 32 KV heads: halved to fit
+    (16, 16384, 4, 64, 4),     # the same in float32: halved again
+    (8, 128, 4, 3, 3),         # the float32 debug pools
+    (8, 2048, 2, 64, 1),       # a bf16 block of half a sublane tile: a slot of its own
+    (16, 1024, 1, 64, 1),      # a one-byte pool wants 32-row blocks
+    (32, 2048, 2, 64, 8),
+    (128, 2048, 2, 64, 2),
+    (256, 2048, 2, 64, 1),
+])
+def test_tile_blocks_follows_from_the_shapes(bs, row_bytes, itemsize, max_blocks, want):
+    from deepspeed_tpu.ops.pallas.paged_attention import (TILE_VMEM_BYTES, kernel_supported,
+                                                          tile_blocks)
+    n = tile_blocks(bs, row_bytes, itemsize, max_blocks)
+    assert n == want
+    assert kernel_supported(128, bs)  # every supported shape has some n
+    assert n == 1 or 4 * n * bs * row_bytes <= TILE_VMEM_BYTES
+
+
+@pytest.mark.parametrize("n", [1, 2, 8, 32])
+def test_any_tile_size_gives_the_same_answer(n):
+    """``n`` is a matter of speed alone (the census sweeps it): n = 1 is
+    a block a step with the copy ahead, 32 is wider than the rule's 16."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _paged_call
+    q, kc, vc, tabs, pos, layer, _, _ = _tiled_case(CONTEXTS["padding_beside_long"],
+                                                   jnp.float32, seed=3)
+    got = _paged_call(q, kc, vc, tabs, pos, layer, n, True)
+    np.testing.assert_allclose(np.asarray(got), _float32_reference(q, kc, vc, tabs, pos, layer),
+                               rtol=1e-5, atol=1e-5)
+
+
 # ------------------------------------------------- ragged_forward, end to end
 def _stacked_layer_scan(real_scan, used):
     """The form the layer scan had before the pool became its carry, as a

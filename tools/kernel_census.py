@@ -25,6 +25,16 @@ GB/s of expert weights, share of the chip's 819 GB/s. That table is what
 ``--gmm-sweep`` adds other row and column tiles; ``--gmm-only`` skips
 the verdicts.
 
+``--paged`` times ``paged_decode_attention`` instead, at the shape classes
+the two key-value cells serve (8 KV heads x 128, 16-row bf16 blocks, a
+traced layer index): 64 and 128 decode rows at contexts 128-1536, chat's
+128-row program with a quarter of its rows live, and one 512-token prompt
+chunk at context 0 and at 512 - with 1, 2, 4, 8, 16 and 32 blocks a tile, and,
+where ``--paged-parent DIR`` (default ``_checkout/parent``) holds a checkout
+of an older commit, that commit's kernel beside them. ms a call, GB/s of
+the KV rows the tokens attend to, share of 819. ``paged_attention.tile_blocks``
+rests on this table (PERF.md, PR 33).
+
 Prints one JSON line per kernel and writes ``chiprun_out/kernel_census.json``.
 """
 
@@ -216,6 +226,84 @@ def grouped_matmul_classes(sweep=False):
             yield f"{name}.{direction}", record
 
 
+# (name, rows, live rows, (least, most) context of a live row or None for a chunk, chunk start)
+PAGED_CLASSES = (("mixtral-decode-64", 64, 64, (128, 1536), None),
+                 ("chat-decode-128", 128, 128, (128, 1536), None),
+                 ("chat-decode-128-35live", 128, 35, (100, 600), None),
+                 ("chunk-512-ctx0", 512, 512, None, 0),
+                 ("chunk-512-ctx512", 512, 512, None, 512))
+PAGED_TILES = (1, 2, 4, 8, 16, 32)
+
+
+def paged_attention_classes(parent_dir):
+    """Yields one record a shape class: the kernel at each ``n`` of
+    ``PAGED_TILES`` (the rule's own marked), the parent commit's kernel
+    where there is one, each against ``xla_paged_attention`` on every
+    eighth token. The bytes are the K and V rows at positions <= the
+    token's, once a token: what the token attends to, not the whole
+    blocks fetched."""
+    import importlib.util
+
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    parent = None
+    path = os.path.join(parent_dir, "deepspeed_tpu", "ops", "pallas", "paged_attention.py")
+    if os.path.exists(path):
+        spec = importlib.util.spec_from_file_location("parent_paged_attention", path)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+
+    H, Hkv, Dh, bs, L, NB, MB = 32, 8, HEAD_DIM, 16, 4, 8192, 96
+    rng = np.random.default_rng(33)
+    pool = jax.jit(lambda key: jax.random.normal(key, (L, NB, bs, Hkv * Dh), jnp.bfloat16))
+    kc, vc = pool(jax.random.PRNGKey(1)), pool(jax.random.PRNGKey(2))
+    layer = jnp.int32(L - 2)
+    rule = pa.tile_blocks(bs, Hkv * Dh * 2, 2, MB)
+    for name, T, live, ctx, chunk_start in PAGED_CLASSES:
+        tabs, pos = np.zeros((T, MB), np.int32), np.zeros(T, np.int32)
+        free = iter(rng.permutation(np.arange(1, NB)))
+        if ctx is None:  # one sequence's chunk: every token on the same table
+            pos[:] = chunk_start + np.arange(T)
+            need = -(-(chunk_start + T) // bs)
+            tabs[:, :need] = [next(free) for _ in range(need)]
+        else:            # live rows first, as the engine packs them; the rest padding
+            pos[:live] = np.exp(rng.uniform(np.log(ctx[0]), np.log(ctx[1]), live)).astype(int) - 1
+            for t in range(live):
+                need = pos[t] // bs + 1
+                tabs[t, :need] = [next(free) for _ in range(need)]
+        q = jnp.asarray(rng.standard_normal((T, H, Dh), np.float32), jnp.bfloat16)
+        tabs_d, pos_d = jnp.asarray(tabs), jnp.asarray(pos)
+        some = jnp.arange(0, T, 8)
+        want = jax.jit(pa.xla_paged_attention)(q[some], kc, vc, tabs_d[some], pos_d[some], layer)
+        nbytes = int((pos[:live].astype(np.int64) + 1).sum()) * 2 * Hkv * Dh * 2
+        record = {"rows": T, "live_rows": live, "ctx_tokens": int((pos[:live] + 1).sum()),
+                  "attended_kv_bytes": nbytes, "rule_n": rule}
+
+        def timed(fn):
+            try:
+                call = jax.jit(fn)
+                if not mosaic_kernels(call.lower(q, kc, vc, tabs_d, pos_d, layer)):
+                    raise RuntimeError("no Mosaic kernel in the lowered program")
+                ms = _ms_a_call(call, q, kc, vc, tabs_d, pos_d, layer, calls=100)
+                err = rel_err(call(q, kc, vc, tabs_d, pos_d, layer)[some], want)
+                return {"ms": ms, "gb_s": nbytes / ms / 1e6,
+                        "hbm_share": 100 * nbytes / ms / 1e6 / HBM_GB_S,
+                        "rel_err": float(f"{err:.3e}")}
+            except Exception as e:  # a refusal is a record too
+                return {"refused": f"{type(e).__name__}: {e}"[:600]}
+
+        if parent is not None:
+            record["parent"] = timed(lambda *a: parent.paged_decode_attention(*a, interpret=False))
+        for n in PAGED_TILES:
+            record[f"n={n}"] = timed(lambda *a, n=n: pa._paged_call(*a, n, False))
+        yield name, record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -241,10 +329,17 @@ def main():
     enable_compile_cache()
     report = {"device": {"platform": devices[0].platform, "kind": devices[0].device_kind,
                          "count": len(devices)}, "kernels": {}}
-    for name, record in grouped_matmul_classes(sweep="--gmm-sweep" in sys.argv):
-        report.setdefault("grouped_matmul", {})[name] = record
+    paged = "--paged" in sys.argv
+    if paged:
+        parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
+                      if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
+        section, records = "paged_attention", paged_attention_classes(parent_dir)
+    else:
+        section, records = "grouped_matmul", grouped_matmul_classes(sweep="--gmm-sweep" in sys.argv)
+    for name, record in records:
+        report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
-    for name, fn, ref, args, tol in () if "--gmm-only" in sys.argv else cases():
+    for name, fn, ref, args, tol in () if paged or "--gmm-only" in sys.argv else cases():
         try:
             result = verdict(fn, ref, args, tol)
         except Exception as e:  # the census records a refusal and goes on to the next kernel
@@ -252,7 +347,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open(os.path.join("chiprun_out", "kernel_census.json"), "w") as f:
+    with open(os.path.join("chiprun_out", "paged_census.json" if paged else "kernel_census.json"),
+              "w") as f:
         json.dump(report, f, indent=1)
 
 
