@@ -20,6 +20,7 @@ from deepspeed_tpu.inference.v2.model_runner import kind_of, ragged_forward
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
+from deepspeed_tpu.inference.v2.ragged.slot_pool import SlotPool
 from deepspeed_tpu.utils import tracing
 from deepspeed_tpu.utils.env_registry import env_int
 from deepspeed_tpu.utils.logging import logger
@@ -43,13 +44,16 @@ def _burst_ctx_tokens(seen, k):
     return k * seen + k * (k + 1) // 2
 
 
-def _offsets(fields, lora, sampled, ms):
+def _offsets(fields, lora, sampled, ms, seq_rows=0):
     """field → (start, end) into a flat int32 metadata vector. ``lora``
-    appends the per-sequence adapter-slot row and ``sampled`` the
-    per-sequence sampling-spec rows — each strictly opt-in, so the
-    off-state wire format carries neither."""
+    appends the per-sequence adapter-slot row, ``seq_rows`` the model
+    kind's per-sequence state rows and ``sampled`` the per-sequence
+    sampling-spec rows — each strictly opt-in, so the off-state wire
+    format carries none."""
     if lora:
         fields.append(("seq_adapters", ms + 1))
+    if seq_rows:
+        fields.append(("seq_state", (ms + 1) * seq_rows))
     if sampled:
         fields.append(("sample_meta", SAMPLE_META_ROWS * ms))
     o, lay = 0, {}
@@ -59,14 +63,14 @@ def _offsets(fields, lora, sampled, ms):
     return lay
 
 
-def _burst_layout(ms, mb, lora=False, sampled=False):
+def _burst_layout(ms, mb, lora=False, sampled=False, seq_rows=0):
     """Single source for the decode-burst metadata wire format. Both the
     host pack (``_dispatch_burst``) and the traced unpack
     (``_make_burst_fn``) read this, so the layout cannot silently
     diverge. Entry tokens are not in it: they are a device argument of
     their own, from the host or chained from the burst before."""
     return _offsets([("token_seq", ms), ("pos0", ms), ("tables", (ms + 1) * mb)],
-                    lora, sampled, ms)
+                    lora, sampled, ms, seq_rows)
 
 
 def _verify_layout(ms, mb, d, lora=False, sampled=False):
@@ -167,7 +171,7 @@ class InferenceEngineV2:
     def __init__(self, model=None, config: RaggedInferenceEngineConfig = None,
                  params=None, model_config=None, dtype=jnp.bfloat16, rng=None):
         """``model``: a ``LlamaForCausalLM``, ``GPTForCausalLM``,
-        ``MoonlightForCausalLM`` or ``LongcatFlashForCausalLM`` (its scan-stacked params are
+        ``MoonlightForCausalLM``, ``LongcatFlashForCausalLM`` or ``MiniCPMSalaForCausalLM`` (its scan-stacked params are
         initialized here when ``params`` is not given), or pass
         ``params`` + ``model_config`` directly. The config's type picks
         the model kind (``model_runner.kind_of``), which says what state
@@ -261,6 +265,17 @@ class InferenceEngineV2:
         self.state_kind = kind.state_kind
         self.state_bytes_per_token = self.kv_cache.bytes_per_token()
         self.state_manager = DSStateManager(self.kv_cache, int(sm.max_tracked_sequences))
+        # State beyond the two paged pools, where the model kind keeps any: its own tree
+        # of device arrays, carried and donated through every program beside the pools,
+        # and a slot of it a tracked sequence (ragged/slot_pool.py). None for a kind
+        # that has none, whose programs are then the ones they were.
+        self.state_extra = self.slot_pool = None
+        self._seq_rows = getattr(kind, "seq_rows", 0)
+        if hasattr(kind, "extra_state"):
+            slots = int(sm.max_tracked_sequences)
+            self.state_extra = kind.extra_state(cfg, num_blocks, slots, dtype)
+            per_slot = self.state_extra["slots"]
+            self.slot_pool = SlotPool(slots, per_slot.nbytes // per_slot.shape[1])
         # Radix prefix cache (cross-request KV reuse): config-gated with
         # the DS_PREFIX_CACHE env kill switch. When live, retired
         # sequences' full blocks become content-addressable and new
@@ -361,7 +376,8 @@ class InferenceEngineV2:
                                   int(cfg.max_position_embeddings))
         self._batch = RaggedBatchWrapper(self.max_tokens, self.max_seqs,
                                          self.max_blocks_per_seq,
-                                         lora=self.lora_store is not None)
+                                         lora=self.lora_store is not None,
+                                         seq_rows=self._seq_rows)
         mesh = self.mesh
         # the config's attention pin, and (filled as programs trace) the
         # implementation each program actually selected — see
@@ -379,14 +395,18 @@ class InferenceEngineV2:
 
         ms, mb = self.max_seqs, self.max_blocks_per_seq
         lora_on = self.lora_store is not None
+        seq_rows = self._seq_rows
 
-        def step(p, kc, vc, packed, lora_slabs=None):
+        # ``xc``, in every program: the kind's state beyond the two pools
+        # (``self.state_extra``), donated like them and returned last; None — no
+        # argument and no result of the compiled program — for a kind that has none.
+        def step(p, kc, vc, xc, packed, lora_slabs=None):
             # one flat int32 metadata vector per step (single host→device
             # transfer); static slices rebuild the batch dict on device.
             # The vector's length IS the token bucket, so decode-sized
             # and budget-sized batches compile separate specializations.
             from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import unpack_batch
-            b = unpack_batch(packed, ms, mb, lora=lora_on)
+            b = unpack_batch(packed, ms, mb, lora=lora_on, seq_rows=seq_rows)
             if quantized:
                 # embed/head/norm leaves dequantize here; the scanned
                 # 'layers' stack stays quantized — each scan step
@@ -399,24 +419,27 @@ class InferenceEngineV2:
             if lora_slabs is not None:
                 la, lb, scales = lora_slabs
                 lora_arg = (la, lb, scales, b["seq_adapters"], None)
-            return ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
-                                  attn_impl=attn_impl, lora=lora_arg)
+            return forward(p, kc, vc, xc, b, lora_arg)
 
-        self._step = maybe_checkify_jit(step, donate_argnums=(1, 2),
+        def forward(p, kc, vc, xc, b, lora_arg):
+            return ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh, attn_impl=attn_impl,
+                                  lora=lora_arg, extra=xc)
+
+        self._step = maybe_checkify_jit(step, donate_argnums=(1, 2, 3),
                                         enabled=sanitize)
 
-        def step_greedy(p, kc, vc, b, lora_slabs=None):
+        def step_greedy(p, kc, vc, xc, b, lora_slabs=None):
             # (a model kind that counts on the device gives its counts fourth: they
             # ride out beside the tokens, here and in every program below)
-            logits, kc, vc, *counts = step(p, kc, vc, b, lora_slabs)
+            logits, kc, vc, *counts, xc = step(p, kc, vc, xc, b, lora_slabs)
             # On-device greedy sampling: ship [n_seqs] int32 tokens to the
             # host instead of [n_seqs, vocab] fp32 logits — vocab-factor
             # less PCIe traffic per decode step (servers sample on-device
             # for the same reason; reference FastGen returns logits only
             # because torch keeps them resident).
-            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kc, vc, *counts)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kc, vc, *counts, xc)
 
-        self._step_greedy = maybe_checkify_jit(step_greedy, donate_argnums=(1, 2),
+        self._step_greedy = maybe_checkify_jit(step_greedy, donate_argnums=(1, 2, 3),
                                                enabled=sanitize)
 
         # ONE sampled program for every per-sequence spec: temperature /
@@ -427,9 +450,9 @@ class InferenceEngineV2:
         # program serves any mix of greedy/sampled/constrained rows.
         structured_on = self.structured is not None
 
-        def step_sampled(p, kc, vc, packed, base, slabs=None, lora_slabs=None):
+        def step_sampled(p, kc, vc, xc, packed, base, slabs=None, lora_slabs=None):
             from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import unpack_batch
-            b = unpack_batch(packed, ms, mb, lora=lora_on, sampled=True)
+            b = unpack_batch(packed, ms, mb, lora=lora_on, sampled=True, seq_rows=seq_rows)
             if quantized:
                 from deepspeed_tpu.inference.quantization import \
                     dequantize_tree_except
@@ -438,8 +461,7 @@ class InferenceEngineV2:
             if lora_slabs is not None:
                 la, lb, scales = lora_slabs
                 lora_arg = (la, lb, scales, b["seq_adapters"], None)
-            logits, kc, vc, *counts = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
-                                                     attn_impl=attn_impl, lora=lora_arg)
+            logits, kc, vc, *counts, xc = forward(p, kc, vc, xc, b, lora_arg)
             temp, topk, topp, seed, slot, state = unpack_sample_meta(
                 b["sample_meta"], ms)
             if slabs is not None:
@@ -449,20 +471,20 @@ class InferenceEngineV2:
             # same counter key) every other path derives for it
             pos_out = b["token_pos"][b["last_index"]] + 1
             keys = token_keys(base, seed, pos_out)
-            return (sample_rows(logits, keys, temp, topk, topp), kc, vc, *counts)
+            return (sample_rows(logits, keys, temp, topk, topp), kc, vc, *counts, xc)
 
         if structured_on and lora_on:
             sampled_fn = step_sampled
         elif structured_on:
-            sampled_fn = lambda p, kc, vc, packed, base, slabs: \
-                step_sampled(p, kc, vc, packed, base, slabs)
+            sampled_fn = lambda p, kc, vc, xc, packed, base, slabs: \
+                step_sampled(p, kc, vc, xc, packed, base, slabs)
         elif lora_on:
-            sampled_fn = lambda p, kc, vc, packed, base, lslabs: \
-                step_sampled(p, kc, vc, packed, base, None, lslabs)
+            sampled_fn = lambda p, kc, vc, xc, packed, base, lslabs: \
+                step_sampled(p, kc, vc, xc, packed, base, None, lslabs)
         else:
-            sampled_fn = lambda p, kc, vc, packed, base: \
-                step_sampled(p, kc, vc, packed, base)
-        self._step_sampled = maybe_checkify_jit(sampled_fn, donate_argnums=(1, 2),
+            sampled_fn = lambda p, kc, vc, xc, packed, base: \
+                step_sampled(p, kc, vc, xc, packed, base)
+        self._step_sampled = maybe_checkify_jit(sampled_fn, donate_argnums=(1, 2, 3),
                                                 enabled=sanitize)
         # LRU of compiled multi-step programs: ("burst", k, sample_key)
         # decode bursts and ("verify", d) speculative verifies. Bounded —
@@ -510,7 +532,11 @@ class InferenceEngineV2:
                     f"max_seqs={self.max_seqs} kv_blocks={num_blocks} "
                     f"block_size={self.block_size} tp={tp} ep={ep} "
                     f"kv_bytes={self.kv_cache.bytes()/1e6:.1f}MB "
-                    f"experts={kind.experts_form(self.params, self.mesh)}")
+                    f"experts={kind.experts_form(self.params, self.mesh)}"
+                    + ("" if self.state_extra is None else
+                       f" kind={kind.name} " + " ".join(
+                           f"{name}_bytes={x.nbytes/1e6:.1f}MB{list(x.shape)}"
+                           for name, x in sorted(self.state_extra.items()))))
 
     # ------------------------------------------------------------------
     def _refuse_unsupported(self, kind, n_devices):
@@ -686,6 +712,13 @@ class InferenceEngineV2:
                     seen = desc.seen_tokens if desc is not None else 0
                     if desc is None:
                         new_seqs += 1
+                    if self.slot_pool is not None and (desc is None or desc.state_row is None):
+                        # how such a model attends to a prompt's rows depends on the whole
+                        # prompt's length, which a first chunk does not say
+                        raise ValueError(
+                            f"sequence {uid}: a {self.kind.name!r} model needs the whole "
+                            f"prompt before its first chunk — call prefix_match(uid, prompt) "
+                            f"first (a scheduler does)")
                     if seen + len(tokens) > max_ctx:
                         raise ValueError(f"sequence {uid}: {seen}+{len(tokens)} tokens exceed "
                                          f"max_context={max_ctx}")
@@ -754,13 +787,14 @@ class InferenceEngineV2:
                     sargs = (self._base_key,)
                     if self.structured is not None:
                         sargs += (self.structured.slabs(),)  # rebind, never retrace
-                    out, self.kv_cache.k, self.kv_cache.v, *counts = self._step_sampled(
-                        self.params, self.kv_cache.k, self.kv_cache.v, arrays,
-                        *sargs, *extra)
+                    out, self.kv_cache.k, self.kv_cache.v, *counts, self.state_extra = \
+                        self._step_sampled(self.params, self.kv_cache.k, self.kv_cache.v,
+                                           self.state_extra, arrays, *sargs, *extra)
                 else:
                     fn = self._step_greedy if mode == "greedy" else self._step
-                    out, self.kv_cache.k, self.kv_cache.v, *counts = fn(
-                        self.params, self.kv_cache.k, self.kv_cache.v, arrays, *extra)
+                    out, self.kv_cache.k, self.kv_cache.v, *counts, self.state_extra = fn(
+                        self.params, self.kv_cache.k, self.kv_cache.v, self.state_extra,
+                        arrays, *extra)
             self.count_host_sync()
             self.tokens_emitted += len(batch_uids)
             with tracing.phase("engine.fetch"):
@@ -1006,8 +1040,11 @@ class InferenceEngineV2:
             pos0 = np.zeros(ms, np.int32)
             tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
             adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
+            seq_state = np.zeros((ms + 1, self._seq_rows), np.int32)
             for i, desc in enumerate(descs):
                 desc.slot = i
+                if self._seq_rows:
+                    seq_state[i] = desc.state_row
                 if lora_on:
                     desc.adapter_slot = self.lora_store.slot_of(desc.uid)
                     adapters[i] = desc.adapter_slot
@@ -1025,6 +1062,8 @@ class InferenceEngineV2:
             if lora_on:
                 parts.append(adapters)
                 opt["lora"] = self.lora_store.slabs()
+            if self._seq_rows:
+                parts.append(seq_state.ravel())
             if sampled:
                 for s in specs:
                     if s is not None and "seed" not in s:
@@ -1045,7 +1084,8 @@ class InferenceEngineV2:
                     opt["state"] = self._replicated_input(state)
             meta = np.concatenate(parts)
             assert meta.shape[0] == sum(e - s for s, e in _burst_layout(
-                ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled).values())
+                ms, self.max_blocks_per_seq, lora=lora_on, sampled=sampled,
+                seq_rows=self._seq_rows).values())
             meta = self._replicated_input(meta)
             # Sampled bursts run ONE program regardless of the specs (they are
             # data), keyed "sampled" plus — when constrained decoding is live —
@@ -1060,8 +1100,9 @@ class InferenceEngineV2:
                 key = key + (self.lora_store.signature(),)
             fn = self._get_burst_fn(key, lambda: self._make_burst_fn(k, skey))
         with tracing.phase("engine.dispatch"):
-            out, st, self.kv_cache.k, self.kv_cache.v, *counts = fn(
-                self.params, self.kv_cache.k, self.kv_cache.v, meta, entry, opt)
+            out, st, self.kv_cache.k, self.kv_cache.v, *counts, self.state_extra = fn(
+                self.params, self.kv_cache.k, self.kv_cache.v, self.state_extra, meta, entry,
+                opt)
         self.tokens_emitted += k * n
         return descs, entry_np, out, st, counts
 
@@ -1152,30 +1193,31 @@ class InferenceEngineV2:
         return handle
 
     def _make_burst_fn(self, k, skey=None):
-        """The one burst program family: ``burst(p, kc, vc, meta, tokens0,
-        opt)``. Entry tokens are a device argument (``int32[max_seqs]``),
+        """The one burst program family: ``burst(p, kc, vc, xc, meta,
+        tokens0, opt)``. Entry tokens are a device argument (``int32[max_seqs]``),
         so a burst can start from the host's row or from the burst
         before without being another program. ``opt`` holds the optional
         inputs under the keys that are present — ``base`` (sampling base
         key) and ``state`` (DFA state row) for a sampled program, ``dfa``
         and ``lora`` (their slabs) when those subsystems are live — and
-        ``jit`` specialises on which. → ``(out, st, kc, vc)``: the final
-        DFA state row for the next link, None from a greedy program; a
-        model kind that counts on the device gives its counts fifth,
-        summed over the burst's steps."""
+        ``jit`` specialises on which. → ``(out, st, kc, vc, *counts, xc)``:
+        the final DFA state row for the next link, None from a greedy
+        program; a model kind that counts on the device gives its counts
+        fifth, summed over the burst's steps; the kind's further state last."""
         from deepspeed_tpu.inference.v2.model_runner import ragged_forward
         cfg, dtype, mesh = self.model_config, self.dtype, self.mesh
         attn_impl = self._attention
         quantized = self._quantized
+        seq_rows = self._seq_rows
         ms, mb = self.max_seqs, self.max_blocks_per_seq
         lora_on = self.lora_store is not None
         sampled = skey == "sampled"
 
-        def burst(p, kc, vc, meta, tokens0, opt):
+        def burst(p, kc, vc, xc, meta, tokens0, opt):
             if quantized:
                 from deepspeed_tpu.inference.quantization import dequantize_tree_except
                 p = dequantize_tree_except(p, dtype)  # once per burst, not per step
-            lay = _burst_layout(ms, mb, lora=lora_on, sampled=sampled)
+            lay = _burst_layout(ms, mb, lora=lora_on, sampled=sampled, seq_rows=seq_rows)
             token_seq = meta[slice(*lay["token_seq"])]
             pos0 = meta[slice(*lay["pos0"])]
             tables = meta[slice(*lay["tables"])].reshape(ms + 1, mb)
@@ -1193,16 +1235,21 @@ class InferenceEngineV2:
                     meta[slice(*lay["sample_meta"])], ms)
                 base, slabs = opt["base"], opt.get("dfa")
 
+            seq_state = {}
+            if seq_rows:
+                seq_state["seq_state"] = meta[slice(*lay["seq_state"])].reshape(ms + 1, seq_rows)
+
             def one(carry, i):
-                kc, vc, toks, st = carry  # st is None in a greedy burst
+                kc, vc, xc, toks, st = carry  # st is None in a greedy burst
                 b = {"token_ids": toks, "token_seq": token_seq,
                      "token_pos": pos0 + i, "block_tables": tables,
-                     "last_index": last}
-                sel, kc, vc, *counts = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
-                                                      attn_impl=attn_impl, lora=lora_arg)
+                     "last_index": last, **seq_state}
+                sel, kc, vc, *counts, xc = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
+                                                          attn_impl=attn_impl, lora=lora_arg,
+                                                          extra=xc)
                 if not sampled:
                     nxt = jnp.argmax(sel, axis=-1).astype(jnp.int32)
-                    return (kc, vc, nxt, st), (nxt, *counts)
+                    return (kc, vc, xc, nxt, st), (nxt, *counts)
                 if slabs is not None:
                     sel = apply_dfa_mask(sel, slabs[0], slot, st)
                 # step i's token lands at absolute position pos0 + i + 1,
@@ -1211,13 +1258,13 @@ class InferenceEngineV2:
                 nxt = sample_rows(sel, keys, temp, topk, topp)
                 if slabs is not None:
                     st = slabs[1][slot, st, nxt]  # in-scan DFA advance
-                return (kc, vc, nxt, st), (nxt, *counts)
+                return (kc, vc, xc, nxt, st), (nxt, *counts)
 
-            (kc, vc, _, st), (out, *counts) = jax.lax.scan(
-                one, (kc, vc, tokens0, opt.get("state")), jnp.arange(k, dtype=jnp.int32))
-            return (out, st, kc, vc, *(c.sum(axis=0) for c in counts))
+            (kc, vc, xc, _, st), (out, *counts) = jax.lax.scan(
+                one, (kc, vc, xc, tokens0, opt.get("state")), jnp.arange(k, dtype=jnp.int32))
+            return (out, st, kc, vc, *(c.sum(axis=0) for c in counts), xc)
 
-        return maybe_checkify_jit(burst, donate_argnums=(1, 2),
+        return maybe_checkify_jit(burst, donate_argnums=(1, 2, 3),
                                   enabled=self._sanitize)
 
     # -------------------------------------------- speculative decoding
@@ -1444,8 +1491,8 @@ class InferenceEngineV2:
                  "token_pos": (pos0[:, None] + steps[None, :]).reshape(-1),
                  "block_tables": tables,
                  "last_index": jnp.arange(T, dtype=jnp.int32)}
-            logits, kc, vc = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
-                                            attn_impl=attn_impl, lora=lora_arg)
+            logits, kc, vc, _ = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
+                                               attn_impl=attn_impl, lora=lora_arg)
             if not sampled:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             else:
@@ -1526,7 +1573,18 @@ class InferenceEngineV2:
         tokens whose KV is already in the pool; the caller starts
         prefill at that offset. Always capped one token short of the
         prompt, so the last prompt token is recomputed and first-token
-        logits exist."""
+        logits exist. This is also where a scheduler tells the engine
+        the whole prompt before its first chunk, so a model kind that
+        keeps state a sequence beyond its blocks (``kind.seq_state``: a
+        slot, and what of the prompt's length decides how its rows
+        attend) starts tracking ``uid`` here, with that state; ``put``
+        refuses such a model a sequence it was not told of."""
+        if self.slot_pool is not None:
+            desc = self.state_manager.get_or_create_sequence(uid)
+            if desc.state_row is None:
+                desc.state_row = self.kind.seq_state(
+                    self.model_config, self.slot_pool.acquire(), len(prompt_tokens))
+            return 0
         if self.prefix_cache is None:
             return 0
         desc = self.state_manager.query(uid)
@@ -1648,6 +1706,8 @@ class InferenceEngineV2:
             # log — materialize any pending device segments first
             desc.tokens.fence()
             self.state_manager.flush_sequence(uid)
+            if desc.state_row is not None:
+                self.slot_pool.release(desc.state_row[0])  # the slot goes with the blocks
         elif not suspended:
             raise KeyError(f"unknown sequence {uid}")
         if self.spec is not None:
@@ -1740,6 +1800,7 @@ class InferenceEngineV2:
         engine.destroy parity for back-to-back engine builds."""
         self.params = None
         self.kv_cache = None
+        self.state_extra = self.slot_pool = None
         self.state_manager = None
         self.prefix_cache = None
         if self.kv_tier is not None:

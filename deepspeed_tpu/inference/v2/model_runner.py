@@ -23,11 +23,12 @@ consumes the padded flat batch —
   serves directly;
 - what differs between model families sits in one object each
   (:class:`LlamaKind`, :class:`GPTKind`, :class:`MoonlightKind`,
-  :class:`LongcatKind`; :func:`kind_of` picks by the config's type): the
-  state the pool holds and how many layers of it, the layer step, the
-  layer pattern (leading layers, then the scan), what a step counts on
-  the device, and the final norm. :func:`ragged_forward` is the same for
-  all.
+  :class:`LongcatKind`, :class:`SalaKind`; :func:`kind_of` picks by the
+  config's type): the state the pool holds and how many layers of it,
+  the layer step, the layer pattern (leading layers, then the scan — or,
+  for a stack of two kinds of layer, :meth:`SalaKind.stack`), what state
+  it keeps beyond the two paged pools, what a step counts on the device,
+  and the final norm. :func:`ragged_forward` is the same for all.
 """
 
 import functools
@@ -350,12 +351,27 @@ def _gpt_layer_step(cfg, cos, sin, alibi, batch, mesh, attn_impl, carry, xs):
     return (h, kc, vc), None
 
 
+def _scanned_stack(kind, params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+    """``kind.stack`` of every kind whose stack is :meth:`layers`' pattern:
+    the leading layers, run one by one, then one scan over identical
+    blocks. → (h, kc, vc, extra — which such a kind has none of —, the
+    scan's counts, a row a layer)."""
+    layer_ids = jnp.arange(kc.shape[0], dtype=jnp.int32)
+    h, leading, step, xs = kind.layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora,
+                                       layer_ids)
+    carry = (h, kc, vc)
+    for lead_step, lead_xs in leading:
+        carry, _ = lead_step(carry, lead_xs)
+    (h, kc, vc), counts = jax.lax.scan(step, carry, xs)
+    return h, kc, vc, extra, counts
+
+
 class LlamaKind:
     """What the ragged engine asks of a model family: the per-layer state
     it keeps in the paged pool (``state_kind``, :meth:`state_rows`), its
-    layer step and its layer pattern (:meth:`layers`: the leading layers,
-    run one by one, then the step and ``xs`` of the layer scan), and its
-    final norm. One object per family; :func:`kind_of` picks it from the
+    layer stack (:meth:`stack`; here :meth:`layers`' pattern: the leading
+    layers, run one by one, then the step and ``xs`` of the layer scan),
+    and its final norm. One object per family; :func:`kind_of` picks it from the
     config's type. This one is the Llama family (Llama, Mistral, Mixtral,
     Qwen2, InternLM, Gemma): keys and values, one scan over identical
     blocks."""
@@ -365,6 +381,7 @@ class LlamaKind:
     # names of the device-side counts a step of this kind's scan gives (int32, summed over
     # its layers; they ride out with the step's result into its step record): none
     step_counts = ()
+    stack = classmethod(_scanned_stack)
 
     @staticmethod
     def state_layers(cfg):
@@ -469,6 +486,7 @@ class MoonlightKind:
     state_kind = "latent"
     lora = False
     step_counts = ()
+    stack = classmethod(_scanned_stack)
     state_layers = LlamaKind.state_layers
 
     @staticmethod
@@ -550,10 +568,360 @@ class LongcatKind(MoonlightKind):
         return _longcat_moe(x, router, mlp["experts"], layer, cfg, every_row)[0]
 
 
+class SalaKind:
+    """MiniCPM-SALA (``models/minicpm_sala.py``): a stack of **two kinds
+    of layer in an irregular order**, whose state is of three kinds.
+
+    - The ``minicpm4`` (sparse-attention) layers keep keys and values in
+      the engine's two paged pools, one pool layer a (sparse layer,
+      key-value head): ``[Ls * Hkv, NB, bs, d]``, so that what a
+      key-value head's selection reads is whole rows of one pool layer
+      and the paged kernel's block copy is the head's 128 lanes alone.
+      The other layers hold nothing there (``state_layers`` is not the
+      model's depth).
+    - ``extra_state``'s ``pooled_keys`` ``[Ls * Hkv, NB, bs / stride, d]``:
+      the mean of every stride-group of a block's key rows, which the
+      selection scores (a pooled key is the mean of two neighbouring
+      groups) instead of the keys themselves: 1 / stride of their bytes.
+    - ``extra_state``'s ``slots`` ``[Ll, slots + 1, H, d, d]`` float32: a
+      ``lightning-attn`` layer's state a sequence, the same at token 10
+      and at token 500,000. A tracked sequence owns a slot
+      (``ragged/slot_pool.py``) beside its blocks; slot 0 is padding's.
+      A sequence's first rows (position 0) take the state as zero, so
+      a slot needs no clearing between owners.
+
+    The batch carries, a sequence, ``seq_state`` = (its slot, the first
+    position that attends sparsely). Each step counts, over its tokens
+    that are not padding: the blocks the sparse layers' (token, key-value
+    head)s read and the blocks their contexts hold, and the rows through
+    the linear layers."""
+    name = "sala"
+    state_kind = "sparse_kv+slots"
+    lora = False
+    step_counts = ("n_blocks_selected", "n_blocks_context", "n_linear_rows")
+    seq_rows = 2            # per-sequence rows of the batch: (slot, sparse_from)
+
+    @staticmethod
+    def state_layers(cfg):
+        return len(cfg.sparse_positions) * cfg.num_key_value_heads
+
+    @staticmethod
+    def state_rows(cfg):
+        return cfg.head_dim, cfg.head_dim
+
+    @staticmethod
+    def extra_state(cfg, num_blocks, slots, dtype):
+        """→ the tree of state beyond the two paged pools (zeros)."""
+        H, d = cfg.num_attention_heads, cfg.head_dim
+        groups = cfg.sparse_block_size // cfg.sparse_kernel_stride
+        return {"pooled_keys": jnp.zeros((SalaKind.state_layers(cfg), num_blocks, groups, d),
+                                         dtype),
+                "slots": jnp.zeros((len(cfg.linear_positions), slots + 1, H, d, d),
+                                   jnp.float32)}
+
+    @staticmethod
+    def seq_state(cfg, slot, prompt_len):
+        """A sequence's row of the batch."""
+        return slot, cfg.sparse_from(prompt_len)
+
+    @staticmethod
+    def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+        """The sparse layers one by one, each from its own tree, and one
+        scan over every run of linear layers between them: the scan's
+        ``xs`` is the run's indices and its body reads layer ``i`` out of
+        the whole stack, as a scan reads its ``xs``, so no run is cut out
+        of the stack. → (h, kc, vc, extra, the counts in one row)."""
+        if lora is not None or mesh is not None:
+            raise NotImplementedError("the MiniCPM-SALA layer stack serves base-only on one device")
+        if kc.shape[2] != cfg.sparse_block_size:
+            raise ValueError(f"kv_block_size {kc.shape[2]} is not the selection's block "
+                             f"size {cfg.sparse_block_size}")
+        model = params["model"]
+        ctx = _SalaStep(cfg, batch)
+        linear, kb, slots = model["linear_layers"], extra["pooled_keys"], extra["slots"]
+        log_decay = jnp.asarray([cfg.log_decay(p) for p in cfg.linear_positions], jnp.float32)
+
+        def linear_step(carry, i):
+            h, slots = carry
+            lp = jax.tree.map(lambda w: w[i], linear)
+            return _sala_linear_layer(ctx, lp, log_decay[i], i, h, slots), None
+
+        n_sparse = n_linear = 0
+        run = []
+        for mixer in cfg.mixer_types + (None,):
+            if mixer == "lightning-attn":
+                run.append(n_linear)
+                n_linear += 1
+                continue
+            if run:
+                (h, slots), _ = jax.lax.scan(linear_step, (h, slots),
+                                             jnp.asarray(run, jnp.int32))
+                run = []
+            if mixer is not None:
+                h, kc, vc, kb = _sala_sparse_layer(ctx, model["sparse_layers"][str(n_sparse)],
+                                                   n_sparse, h, kc, vc, kb, attn_impl)
+                n_sparse += 1
+        real = ctx.real.astype(jnp.int32)
+        counts = jnp.stack([n_sparse * jnp.sum(real[:, None] * ctx.counts),
+                            n_sparse * cfg.num_key_value_heads * jnp.sum(real * (ctx.own + 1)),
+                            n_linear * jnp.sum(real)]).astype(jnp.int32)
+        return h, kc, vc, {"pooled_keys": kb, "slots": slots}, counts[None]
+
+    @staticmethod
+    def experts_form(params, mesh):
+        return None
+
+    @staticmethod
+    def final_norm(params, cfg, h):
+        h = _rms(h, params["model"]["norm"]["scale"], cfg.rms_norm_eps)
+        return h / jnp.asarray(cfg.logit_divisor, h.dtype)
+
+    @staticmethod
+    def sparse_layer(params, cfg, layer, x, kc, vc, kb, batch, attn_impl=None):
+        """Sparse layer ``layer``'s mixer alone, as the step programs
+        compute it (the same writes, selection and paged attention), for a
+        check that wants it without the rest: x [T, D] the normalised
+        stream → (y [T, D], kc, vc, kb, the table [T, Hkv, W] of logical
+        blocks each (token, key-value head) read, counts [T, Hkv])."""
+        ctx = _SalaStep(cfg, batch)
+        attn = params["model"]["sparse_layers"][str(layer)]["self_attn"]
+        y, kc, vc, kb, tables = _sala_sparse_mixer(ctx, attn, layer, x, kc, vc, kb, attn_impl)
+        return y, kc, vc, kb, tables, ctx.counts
+
+
+class _SalaStep:
+    """What every layer of one step shares: the batch, each token's
+    sequence row, position, block and slot, whether it attends densely,
+    and how many blocks it reads."""
+
+    def __init__(self, cfg, batch):
+        self.cfg, self.batch = cfg, batch
+        self.seq, self.pos = batch["token_seq"], batch["token_pos"]
+        tables = batch["block_tables"]
+        self.n_rows = tables.shape[0]                      # sequences a step + padding's
+        self.real = self.seq < self.n_rows - 1
+        bs = cfg.sparse_block_size
+        self.own = self.pos // bs
+        state = batch["seq_state"]
+        self.slot = state[:, 0]
+        self.dense = self.pos < state[self.seq, 1]
+        # how many blocks a (token, key-value head) reads is a function of its position
+        # alone (which blocks is the selection's): all of a dense row's, at most topk of a
+        # sparse one's
+        n = jnp.where(self.dense, self.own + 1, jnp.minimum(self.own + 1, cfg.sparse_topk))
+        self.counts = jnp.broadcast_to(n[:, None], (n.shape[0], cfg.num_key_value_heads))
+
+
+def _rope_at(x, positions, theta):
+    """x [T, H, d] rotated by halves at ``positions`` [T] (the angles of
+    this batch's rows, not a table of ``max_position_embeddings`` rows)."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return _rope_flat(x, jnp.cos(angle), jnp.sin(angle), jnp.arange(x.shape[0]))
+
+
+def _sala_mlp(cfg, lp, h):
+    x = _rms(h, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
+    return h + jnp.asarray(cfg.residual_scale, h.dtype) * _swiglu(x, lp["mlp"])
+
+
+def _sala_linear_layer(ctx, lp, log_decay, layer, h, slots):
+    """One ``lightning-attn`` layer over the flat ragged batch: the packed
+    linear step. Rows of one sequence (a prompt chunk, or one decode row)
+    see the sequence's carried state, decayed by their distance from the
+    chunk's first row, and the chunk's earlier rows through a
+    same-sequence-and-causal decay mask; the state each sequence leaves is
+    written back to its slot. Every exponent is ``log lambda * (a
+    distance >= 0)``: nothing overflows, and head 0's decay is 1.
+    → (h, slots)."""
+    cfg = ctx.cfg
+    T, H, d, S = h.shape[0], cfg.num_attention_heads, cfg.head_dim, ctx.n_rows
+    f32 = jnp.float32
+    a = lp["self_attn"]
+    with jax.named_scope("ds.sala.linear"):
+        x = _rms(h, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
+        # The barrier keeps the head-major layouts the einsums below want from reaching
+        # back through the projections into their weights: XLA otherwise stores q, k and
+        # v_proj transposed and copies the whole linear stack (1.2 GB) a step to get them.
+        q, k, v = (y.reshape(T, H, d) for y in jax.lax.optimization_barrier(
+            tuple(_proj(x, a[name]) for name in ("q_proj", "k_proj", "v_proj"))))
+        q = _rms(q, a["q_norm"]["scale"], cfg.rms_norm_eps)
+        k = _rms(k, a["k_norm"]["scale"], cfg.rms_norm_eps)
+        q, k = _rope_at(q, ctx.pos, cfg.rope_theta), _rope_at(k, ctx.pos, cfg.rope_theta)
+
+        seq, pos = ctx.seq, ctx.pos
+        # each sequence row's first and last position in this step; a row with no token
+        # (and padding's, at position 0) carries nothing and is zero rows long
+        first = jnp.full((S,), jnp.iinfo(jnp.int32).max, jnp.int32).at[seq].min(pos)
+        last = jnp.zeros((S,), jnp.int32).at[seq].max(pos)
+        present = first <= last
+        first = jnp.where(present, first, 0)
+        length = jnp.where(present, last - first + 1, 0)
+        of_seq = (seq[:, None] == jnp.arange(S)[None, :]).astype(x.dtype)          # [T, S]
+        carried = slots[layer, ctx.slot]                                           # [S, H, d, d]
+        carried = jnp.where((first == 0)[:, None, None, None], 0.0, carried)
+
+        def decay(distance):            # [...] int >= 0 → [..., H] float32, lambda_h ** distance
+            return jnp.exp(log_decay * distance.astype(f32)[..., None])
+
+        # the carried state, seen from each row: lambda ** (rows since the chunk began + 1)
+        q_in = (q.astype(f32) * decay(pos - first[seq] + 1)[..., None]).astype(x.dtype)
+        inter = jnp.einsum("ts,thd,shde->the", of_seq, q_in, carried.astype(x.dtype),
+                           preferred_element_type=f32)
+        # the chunk's own rows: the decay mask [H, T, T], same sequence and causal
+        apart = pos[:, None] - pos[None, :]
+        mask = (seq[:, None] == seq[None, :]) & (apart >= 0)
+        weights = jnp.where(mask[None], jnp.exp(log_decay[:, None, None]
+                                                * jnp.maximum(apart, 0).astype(f32)[None]), 0.0)
+        scores = jnp.einsum("thd,uhd->htu", q, k, preferred_element_type=f32) * weights
+        intra = jnp.einsum("htu,uhe->the", scores.astype(x.dtype), v,
+                           preferred_element_type=f32)
+        o = ((inter + intra) / math.sqrt(d)).reshape(T, H * d)
+        # what each sequence leaves: its state decayed over the chunk + the chunk's own
+        k_out = (k.astype(f32) * decay(last[seq] - pos)[..., None]).astype(x.dtype)
+        added = jnp.einsum("us,uhd,uhe->shde", of_seq, k_out, v, preferred_element_type=f32)
+        state = carried * decay(length)[..., None, None] + added
+        slots = slots.at[layer, ctx.slot].set(state)
+
+        o = _rms(o.astype(x.dtype), a["o_norm"]["scale"], cfg.rms_norm_eps)
+        o = o * jax.nn.sigmoid(_proj(x, a["o_gate_proj"]))
+        h = h + jnp.asarray(cfg.residual_scale, h.dtype) * _proj(o, a["o_proj"])
+    return _sala_mlp(cfg, lp, h), slots
+
+
+def _sala_select(ctx, q, kb, layer_heads):
+    """InfLLM-v2 selection for every (token, key-value head) of the step.
+    q [T, Hkv, G, d] (normalised); ``kb`` the pooled-key pool; →
+    tables [T, Hkv, W] int32: the **logical** blocks read, ascending, the
+    columns past a row's count holding ``MB`` (no block); a dense row
+    reads its whole context. ``W`` = max(topk, dense_len / block)."""
+    cfg, batch = ctx.cfg, ctx.batch
+    T, Hkv, G, d = q.shape
+    tables = batch["block_tables"]
+    MB = tables.shape[1]
+    per = cfg.sparse_block_size // cfg.sparse_kernel_stride
+    st, ks = cfg.sparse_kernel_stride, cfg.sparse_kernel_size
+    W = min(MB, max(cfg.sparse_topk, cfg.sparse_dense_len // cfg.sparse_block_size))
+    f32 = jnp.float32
+    with jax.named_scope("ds.sala.select"):
+        # a sequence's group means, gathered once a sequence, then laid out a token
+        groups = kb[layer_heads[None, :, None], tables[:, None, :]]       # [S, Hkv, MB, per, d]
+        groups = groups.reshape(tables.shape[0], Hkv, MB * per, d)[ctx.seq]
+        s = jnp.einsum("tkgd,tkjd->tkgj", q, groups, preferred_element_type=f32)
+        # kernel j = groups j and j + 1: its pooled key is the mean of their means
+        s = 0.5 * (s[..., :-1] + s[..., 1:]) / math.sqrt(d)               # [T, Hkv, G, J]
+        J = MB * per - 1
+        ended = (st * jnp.arange(J) + ks)[None, :] <= (ctx.pos + 1)[:, None]       # [T, J]
+        s = jax.nn.softmax(jnp.where(ended[:, None, None], s, -1e30), axis=-1)
+        s = jnp.where(ended[:, None], s.sum(axis=2), 0.0)                 # [T, Hkv, J]
+        # block i is overlapped by kernels per*i - 1 .. per*i + per - 1
+        s = jnp.concatenate([s, jnp.zeros((T, Hkv, 1), f32)], axis=-1).reshape(T, Hkv, MB, per)
+        before = jnp.concatenate([jnp.zeros((T, Hkv, 1), f32), s[:, :, :-1, per - 1]], axis=-1)
+        score = jnp.maximum(s.max(axis=-1), before)                       # [T, Hkv, MB]
+        blocks = jnp.arange(MB)
+        own = ctx.own[:, None]
+        forced = (blocks[None] < cfg.sparse_init_blocks) | (
+            (blocks[None] <= own) & (blocks[None] > own - cfg.sparse_window_size
+                                     // cfg.sparse_block_size))
+        score = jnp.where(forced[:, None], 1e30, score)
+        score = jnp.where((blocks[None] <= own)[:, None], score, -1.0)
+        # The topk best, ascending, without a sort (a top_k and a sort of 416 scores a
+        # (token, head) were 17 of a 512-token step's 92 ms: chip, PR 34). A score that is
+        # not negative orders as its bits do, so the topk-th largest is found a bit at a
+        # time: the largest v that at least topk scores reach. Every score above it is
+        # chosen, and of those equal to it the earliest, as top_k breaks its ties.
+        topk = min(cfg.sparse_topk, MB)
+        bits = jnp.where(score >= 0, jax.lax.bitcast_convert_type(score, jnp.int32), -1)
+
+        def raise_floor(i, v):
+            higher = v | jnp.left_shift(jnp.int32(1), 30 - i)
+            reach = jnp.sum(bits >= higher[..., None], axis=-1) >= topk
+            return jnp.where(reach, higher, v)
+
+        floor = jax.lax.fori_loop(0, 31, raise_floor, jnp.zeros((T, Hkv), jnp.int32))[..., None]
+        above, tied = bits > floor, bits == floor
+        room = topk - jnp.sum(above, axis=-1, keepdims=True)
+        chosen = above | (tied & (jnp.cumsum(tied, axis=-1) <= room))     # [T, Hkv, MB]
+        # column c holds the c-th chosen block: as many blocks as have c or fewer chosen up
+        # to and with them (MB, no block, once c is past the count)
+        upto = jnp.cumsum(chosen, axis=-1)
+        index = jnp.sum(upto[..., :, None] <= jnp.arange(topk), axis=-2)  # [T, Hkv, topk]
+        index = jnp.concatenate([index, jnp.full((T, Hkv, W - topk), MB, index.dtype)], axis=-1)
+        whole = jnp.where(jnp.arange(W)[None] <= own, jnp.arange(W)[None], MB)     # [T, W]
+        return jnp.where(ctx.dense[:, None, None], whole[:, None], index).astype(jnp.int32)
+
+
+
+def _sala_sparse_mixer(ctx, a, layer, x, kc, vc, kb, attn_impl):
+    """The ``minicpm4`` mixer on the normalised stream x [T, D]: write
+    the rows' keys and values and the group means they complete, select,
+    attend over the selected blocks. → (y, kc, vc, kb, the selection's
+    table of logical blocks [T, Hkv, W])."""
+    cfg, batch = ctx.cfg, ctx.batch
+    T = x.shape[0]
+    H, Hkv, d, bs = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+                     cfg.sparse_block_size)
+    st = cfg.sparse_kernel_stride
+    per = bs // st
+    NB = kc.shape[1]
+    tables = batch["block_tables"]
+    heads = layer * Hkv + jnp.arange(Hkv, dtype=jnp.int32)       # this layer's pool layers
+    q = _rms(_proj(x, a["q_proj"]).reshape(T, H, d), a["q_norm"]["scale"], cfg.rms_norm_eps)
+    k = _rms(_proj(x, a["k_proj"]).reshape(T, Hkv, d), a["k_norm"]["scale"], cfg.rms_norm_eps)
+    v = _proj(x, a["v_proj"]).reshape(T, Hkv, d)
+
+    mine = tables[ctx.seq]                                       # [T, MB]: each token's table
+    blk = jnp.take_along_axis(mine, ctx.own[:, None], axis=1)    # [T, 1]
+    off = (ctx.pos % bs)[:, None]
+    kc = kc.at[heads[None, :], blk, off].set(k.astype(kc.dtype))
+    vc = vc.at[heads[None, :], blk, off].set(v.astype(vc.dtype))
+    # the stride-group a row completes: its mean, read back from the pool (a group's
+    # earlier rows may be an earlier step's); every other row writes the null block
+    group = ctx.pos // st
+    done = (ctx.pos % st == st - 1)[:, None]
+    rows = kc.reshape(kc.shape[0], NB, per, st, d)[heads[None, :], blk, (group % per)[:, None]]
+    mean = rows.astype(jnp.float32).mean(axis=2)                 # [T, Hkv, d]
+    kb = kb.at[heads[None, :], jnp.where(done, blk, 0), (group % per)[:, None]].set(
+        mean.astype(kb.dtype))
+
+    tables = _sala_select(ctx, q.reshape(T, Hkv, H // Hkv, d), kb, heads)
+    with jax.named_scope("ds.sala.sparse_attn"):
+        from deepspeed_tpu.inference.v2.modules.heuristics import instantiate_attn
+        from deepspeed_tpu.ops.pallas.paged_attention import selected_tables
+        flat_k = kc.reshape(1, kc.shape[0] * NB, bs, d)          # pool layers on end: a bitcast
+        flat_v = vc.reshape(1, vc.shape[0] * NB, bs, d)
+        MB = mine.shape[1]
+        physical = jnp.take_along_axis(
+            mine[:, None, :], jnp.minimum(tables, MB - 1), axis=2)             # [T, Hkv, W]
+        physical = physical + (heads * NB)[None, :, None]
+        tab, at = selected_tables(physical, ctx.counts, ctx.pos, bs)
+        qv = q.reshape(T * Hkv, H // Hkv, d)
+        name, attn_fn = instantiate_attn(None, d, bs, qv.shape, flat_k.shape, None,
+                                         max_blocks=tab.shape[1],
+                                         override=attn_impl.override if attn_impl else None)
+        if attn_impl is not None:
+            attn_impl.selected[T] = name
+        o = attn_fn(qv, flat_k, flat_v, tab, at, jnp.int32(0), selected=True).reshape(T, H * d)
+    o = o * jax.nn.sigmoid(_proj(x, a["o_gate_proj"]))
+    return _proj(o, a["o_proj"]), kc, vc, kb, tables
+
+
+def _sala_sparse_layer(ctx, lp, layer, h, kc, vc, kb, attn_impl):
+    cfg = ctx.cfg
+    x = _rms(h, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
+    y, kc, vc, kb, _ = _sala_sparse_mixer(ctx, lp["self_attn"], layer, x, kc, vc, kb, attn_impl)
+    h = h + jnp.asarray(cfg.residual_scale, h.dtype) * y
+    return _sala_mlp(cfg, lp, h), kc, vc, kb
+
+
 def kind_of(cfg):
     """The model kind of a config, by its type."""
     from deepspeed_tpu.models.longcat import LongcatFlashConfig
+    from deepspeed_tpu.models.minicpm_sala import MiniCPMSalaConfig
     from deepspeed_tpu.models.moonlight import MoonlightConfig
+    if isinstance(cfg, MiniCPMSalaConfig):
+        return SalaKind
     if isinstance(cfg, LongcatFlashConfig):
         return LongcatKind
     if isinstance(cfg, MoonlightConfig):
@@ -759,17 +1127,20 @@ def _longcat_layer_step(cfg, rope, batch, attn_impl, experts, carry, xs):
 
 
 def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=None,
-                   attn_impl=None, lora=None):
-    """→ (last-token logits [max_seqs, vocab] fp32, kcache, vcache), and
-    where the model kind counts on the device (``kind.step_counts``), a
-    fourth: those counts, int32, summed over the scanned layers.
+                   attn_impl=None, lora=None, extra=None):
+    """→ (last-token logits [max_seqs, vocab] fp32, kcache, vcache, then,
+    where the model kind counts on the device (``kind.step_counts``), those
+    counts, int32, summed over the layers, and last ``extra``): what the
+    engine's programs carry from step to step, in their order.
 
     ``kcache``/``vcache``: the two pools of the model kind's state
     (:func:`kind_of`: keys and values ``[L, NB, bs, Hkv*Dh]``, or the
     latent rows and rotated keys of ``MoonlightKind``), carried through
-    the layers and written in place (donate them); ``batch``: the
-    arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is a ``LlamaConfig``,
-    ``GPTConfig``, ``MoonlightConfig`` or ``LongcatFlashConfig``; the layer wiring follows its kind. ``mesh``: an optional
+    the layers and written in place (donate them); ``extra``: None, or the
+    kind's own tree of further state (``SalaKind.extra_state``: the slot
+    pool of linear states, the pooled keys), carried and donated likewise;
+    ``batch``: the arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is a ``LlamaConfig``,
+    ``GPTConfig``, ``MoonlightConfig``, ``LongcatFlashConfig`` or ``MiniCPMSalaConfig``; the layer wiring follows its kind. ``mesh``: an optional
     serving mesh — params/KV arrive sharded per
     ``inference/v2/sharding.py`` and the step pins the Megatron layout
     (replicated tokens, head/feature-sharded projections) so GSPMD
@@ -791,13 +1162,8 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     if mult != 1.0:  # Gemma: sqrt(hidden_size)
         h = h * jnp.asarray(mult, h.dtype)
 
-    layer_ids = jnp.arange(kcache.shape[0], dtype=jnp.int32)
-    h, leading, step, xs = kind.layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora,
-                                       layer_ids)
-    carry = (h, kcache, vcache)
-    for lead_step, lead_xs in leading:
-        carry, _ = lead_step(carry, lead_xs)
-    (h, kc, vc), counts = jax.lax.scan(step, carry, xs)
+    h, kc, vc, extra, counts = kind.stack(params, cfg, h, kcache, vcache, extra, batch, dtype,
+                                          mesh, attn_impl, lora)
 
     h = kind.final_norm(params, cfg, h)
     if "lm_head" in params:
@@ -807,5 +1173,5 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     logits = _c(logits, (None, "tensor"), mesh)  # vocab-sharded head
     sel = logits[batch["last_index"]]  # [max_seqs, V]
     if kind.step_counts:
-        return sel.astype(jnp.float32), kc, vc, counts.sum(axis=0)
-    return sel.astype(jnp.float32), kc, vc
+        return sel.astype(jnp.float32), kc, vc, counts.sum(axis=0), extra
+    return sel.astype(jnp.float32), kc, vc, extra

@@ -15,7 +15,7 @@ from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
 
 class RaggedBatchWrapper:
 
-    def __init__(self, max_tokens, max_seqs, max_blocks_per_seq, lora=False):
+    def __init__(self, max_tokens, max_seqs, max_blocks_per_seq, lora=False, seq_rows=0):
         self.max_tokens = max_tokens
         self.max_seqs = max_seqs
         self.max_blocks = max_blocks_per_seq
@@ -23,6 +23,10 @@ class RaggedBatchWrapper:
         # Strictly opt-in — off, the packed vector is byte-identical to
         # the pre-LoRA wire format (the DS_LORA=0 kill-switch contract).
         self.lora = bool(lora)
+        # a model kind with per-sequence state beyond the block table (its
+        # ``seq_rows`` int32 values a sequence: ``desc.state_row``) packs them
+        # too; 0 for every other kind, whose vector is unchanged
+        self.seq_rows = int(seq_rows)
         self.clear()
 
     def clear(self):
@@ -36,6 +40,9 @@ class RaggedBatchWrapper:
         if self.lora:
             # pad row (max_seqs) stays 0 = the base slot
             self.seq_adapters = np.zeros(self.max_seqs + 1, np.int32)
+        if self.seq_rows:
+            # pad row (and every row without a sequence) stays 0: padding's slot
+            self.seq_state = np.zeros((self.max_seqs + 1, self.seq_rows), np.int32)
         self._cursor = 0
         self._order = []  # slots in insertion order
 
@@ -68,6 +75,8 @@ class RaggedBatchWrapper:
         self.seq_valid[desc.slot] = True
         if self.lora:
             self.seq_adapters[desc.slot] = getattr(desc, "adapter_slot", 0)
+        if self.seq_rows:
+            self.seq_state[desc.slot] = desc.state_row
         self._cursor += n
         self._order.append(desc.slot)
 
@@ -106,13 +115,15 @@ class RaggedBatchWrapper:
             np.asarray([self._cursor], np.int32)]
         if self.lora:
             parts.append(self.seq_adapters)
+        if self.seq_rows:
+            parts.append(self.seq_state.ravel())
         return np.concatenate(parts)
 
     def slots_in_order(self):
         return list(self._order)
 
 
-def unpack_batch(packed, max_seqs, max_blocks, lora=False, sampled=False):
+def unpack_batch(packed, max_seqs, max_blocks, lora=False, sampled=False, seq_rows=0):
     """Inverse of :meth:`RaggedBatchWrapper.finalize_packed` in traced
     code: static slices of the flat vector back into the step's dict.
     The token-bucket length is derived from the vector's static size, so
@@ -123,9 +134,10 @@ def unpack_batch(packed, max_seqs, max_blocks, lora=False, sampled=False):
     sampled step appends AFTER the wrapper's own fields (6 int32 rows of
     ``max_seqs``, see ``inference.structured.sampling``) as
     ``sample_meta`` — strictly opt-in, so the greedy wire format stays
-    byte-identical to the pre-sampling one."""
+    byte-identical to the pre-sampling one. ``seq_rows``: the wrapper's;
+    above 0, ``seq_state [max_seqs + 1, seq_rows]`` is parsed out."""
     ms, mb = max_seqs, max_blocks
-    extra = (ms + 1) if lora else 0
+    extra = ((ms + 1) if lora else 0) + (ms + 1) * seq_rows
     if sampled:
         extra += 6 * ms
     mt = (packed.shape[0] - (ms + 1) * mb - ms - 1 - extra) // 3
@@ -142,6 +154,10 @@ def unpack_batch(packed, max_seqs, max_blocks, lora=False, sampled=False):
     if lora:
         o += 1
         out["seq_adapters"] = packed[o:o + ms + 1]
+        o += ms
+    if seq_rows:
+        o += 1
+        out["seq_state"] = packed[o:o + (ms + 1) * seq_rows].reshape(ms + 1, seq_rows)
     if sampled:
         out["sample_meta"] = packed[packed.shape[0] - 6 * ms:]
     return out

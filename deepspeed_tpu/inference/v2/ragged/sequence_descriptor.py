@@ -114,6 +114,9 @@ class DSSequenceDescriptor:
         # tokens select in the segmented adapter matmul (0 = base model;
         # stays 0 whenever LoRA serving is off)
         self.adapter_slot = 0
+        # what a model kind with state beyond the block table keeps a sequence
+        # (``kind.seq_state``: its slot of the slot pool first); None for every other kind
+        self.state_row = None
         self.blocks = []  # owned KV block ids, in order
         self.in_flight_tokens = 0
         # ---- prefix-cache bookkeeping (zero/empty when caching is off) ----

@@ -9,11 +9,15 @@ from deepspeed_tpu.models.moonlight import (MOONLIGHT_CONFIGS, MoonlightConfig,
                                             MoonlightForCausalLM, build_moonlight)  # noqa: F401
 from deepspeed_tpu.models.longcat import (LONGCAT_CONFIGS, LongcatFlashConfig,
                                           LongcatFlashForCausalLM, build_longcat)  # noqa: F401
+from deepspeed_tpu.models.minicpm_sala import (MINICPM_SALA_CONFIGS, MiniCPMSalaConfig,
+                                               MiniCPMSalaForCausalLM,
+                                               build_minicpm_sala)  # noqa: F401
 
 # The causal-LM families a preset name can build, in the order names are looked up
 # (the v2 serving engine takes any of them: inference/v2/model_runner.kind_of).
 MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
-                  (MOONLIGHT_CONFIGS, build_moonlight), (LONGCAT_CONFIGS, build_longcat))
+                  (MOONLIGHT_CONFIGS, build_moonlight), (LONGCAT_CONFIGS, build_longcat),
+                  (MINICPM_SALA_CONFIGS, build_minicpm_sala))
 
 
 def build_model(preset, **overrides):

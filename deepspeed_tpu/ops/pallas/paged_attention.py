@@ -50,6 +50,18 @@ applied to the float32 scores, and running max, sum and accumulator stay
 float32. A float32 pool (what the CPU tests hold to 1e-5) keeps
 ``Precision.HIGHEST``.
 
+**A table that is a selection.** Nothing in either path asks that a
+token's table be its sequence's whole table: ``block_tables[t]`` is the
+blocks token ``t`` reads, in the order they are laid under each other,
+and ``token_pos[t]`` says where that context ends — ``pos // bs + 1``
+blocks are read and the rows past ``pos % bs`` of the last are masked.
+So a token that reads only some blocks of its context (block-sparse
+attention: ``model_runner.SalaKind``) is given a table of those blocks,
+ascending with the block that holds the query last, and the position
+that its last row has **in that table**: :func:`selected_tables` says
+how, per (token, key-value head), each a row of the call with the head's
+query group as its heads and the head's own pool layer's blocks.
+
 The XLA reference path (``xla_paged_attention``) is the same math via
 gather. Which of the two a program runs is decided in ONE place, the
 ``inference/v2/modules/heuristics`` registry (``supports()`` there reads
@@ -81,15 +93,21 @@ GATHER_LIMIT_BYTES = 2 << 30
 # slots (K and V, two each) may take: tile_blocks().
 TILE_ROWS = 256
 TILE_VMEM_BYTES = 4 << 20
+# What a slot holds at the least where the table is a selection
+# (``selected=True``): tile_blocks().
+SELECTED_SLOT_BYTES = 512 << 10
 
 
-def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, alibi_slopes=None):
+def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, alibi_slopes=None,
+                        selected=False):
     """Reference math. q: [T, H, Dh]; kc/vc: the pool [L, NB, bs, Hkv*Dh];
     block_tables: [T, MB] (per TOKEN, already indexed by its sequence);
     token_pos: [T]; layer: int32 scalar, the layer of the pool to read.
     → [T, H, Dh]; attends to positions <= token_pos.
     ``alibi_slopes``: optional [H] — adds the Bloom-style linear
-    relative-position penalty slope_h * (k_pos - q_pos) to the scores."""
+    relative-position penalty slope_h * (k_pos - q_pos) to the scores.
+    ``selected``: the kernel's (its tile); the gather reads the same rows
+    either way."""
     T, H, Dh = q.shape
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     gather_bytes = 2 * T * block_tables.shape[1] * bs * Hkv * Dh * kc.dtype.itemsize
@@ -113,6 +131,23 @@ def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, alibi_slopes=
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("thc,tchd->thd", probs, vs)
+
+
+def selected_tables(tables, counts, token_pos, block_size):
+    """The paged call's table and positions for rows that read a
+    **selection** of their context. tables [T, Hkv, W]: per (token,
+    key-value head) the pool blocks to read, the first ``counts [T, Hkv]``
+    columns in ascending order of their place in the sequence, the block
+    that holds the query among them and therefore last; ``token_pos`` [T]:
+    the query's position in its sequence. → (table [T*Hkv, W] with the
+    columns past a row's count on the null block, positions [T*Hkv]): a
+    row reads ``count`` blocks, and in the last — the query's own — the
+    rows up to the query's, which is what the causal mask comes to when
+    every other block read lies wholly before the query."""
+    T, Hkv, W = tables.shape
+    tab = jnp.where(jnp.arange(W)[None, None, :] < counts[..., None], tables, 0)
+    at = (counts - 1) * block_size + (token_pos % block_size)[:, None]
+    return tab.reshape(T * Hkv, W).astype(jnp.int32), at.reshape(T * Hkv).astype(jnp.int32)
 
 
 def kernel_supported(head_dim, block_size, n_kv_heads=None):
@@ -144,7 +179,7 @@ def smem_table_fits(n_tokens, max_blocks):
     return (n_tokens * max_blocks + n_tokens + 1) * 4 <= SMEM_TABLE_BYTES
 
 
-def tile_blocks(block_size, row_bytes, itemsize, max_blocks):
+def tile_blocks(block_size, row_bytes, itemsize, max_blocks, slot_bytes=0):
     """``n``: the table blocks of one tile, from the shapes alone. A tile
     wants ``TILE_ROWS`` context rows: the score tile is then whole
     128-lane vregs, a head's two matmuls run once a tile and not once a
@@ -158,10 +193,13 @@ def tile_blocks(block_size, row_bytes, itemsize, max_blocks):
     (``tools/kernel_census.py --paged``; PERF.md, PR 33) 1, 2, 4, 8, 16
     and 32 blocks read 20, 32, 50, 67, 80 and 81 % of the HBM roofline at
     decode contexts of 128-1536, and all of 8-32 the same 27 % where three
-    rows in four are padding."""
+    rows in four are padding. ``slot_bytes``: what a slot holds at the
+    least, for the caller whose rows are so narrow that ``TILE_ROWS`` of
+    them are a small copy; 0, what every call but a selection's passes,
+    leaves the tile at ``TILE_ROWS`` rows."""
     if block_size % (32 // itemsize):
         return 1
-    n = max(1, TILE_ROWS // block_size)
+    n = max(1, max(TILE_ROWS, slot_bytes // row_bytes) // block_size)
     while n > 1 and 4 * n * block_size * row_bytes > TILE_VMEM_BYTES:
         n //= 2
     return min(n, max_blocks)
@@ -315,8 +353,17 @@ def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret):
       jnp.asarray(layer, jnp.int32).reshape(1), q, kc, vc)
 
 
-def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=None):
-    """Pallas path of :func:`xla_paged_attention` (same contract)."""
+def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=None,
+                           selected=False):
+    """Pallas path of :func:`xla_paged_attention` (same contract).
+    ``selected``: the table is a selection (:func:`selected_tables`) over
+    a pool of one KV head a pool layer, whose 256 rows of 256 bytes are
+    64 KB a slot: a turn's fixed cost (2n copies started and waited for,
+    two 16-row matmuls, the masks) then outweighs its copies — 0.45 us a
+    tile of 4 64-row blocks, 31 % of the HBM roofline, 43 % at 8 blocks
+    and 57 % at 32 (chip, PR 34) — so such a call's slot holds
+    ``SELECTED_SLOT_BYTES`` at the least, the 512 KB that 256 rows of 8
+    heads are. Every other call's tile is what it was."""
     if interpret is None:
         from deepspeed_tpu.ops.pallas import default_interpret
         interpret = default_interpret()
@@ -333,5 +380,6 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=
                 f"paged decode block table [{T}, {MB}] overflows the kernel's "
                 f"{SMEM_TABLE_BYTES >> 10} KB SMEM budget — shrink max_ragged_batch_size / "
                 f"max_context, or raise kv_block_size")
-    n = tile_blocks(bs, kc.shape[3] * kc.dtype.itemsize, kc.dtype.itemsize, MB)
+    n = tile_blocks(bs, kc.shape[3] * kc.dtype.itemsize, kc.dtype.itemsize, MB,
+                    SELECTED_SLOT_BYTES if selected else 0)
     return _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret)

@@ -273,6 +273,25 @@ def test_tile_blocks_follows_from_the_shapes(bs, row_bytes, itemsize, max_blocks
     assert n == 1 or 4 * n * bs * row_bytes <= TILE_VMEM_BYTES
 
 
+@pytest.mark.parametrize("bs,row_bytes,itemsize,max_blocks,want", [
+    (64, 256, 2, 64, 32),      # the selecting layers: one KV head a pool layer, bf16: 512 KB a slot
+    (64, 512, 4, 64, 16),      # the same in float32
+    (64, 256, 2, 6, 6),        # never more than the table has
+    (16, 2048, 2, 64, 16),     # rows of 8 heads: 256 rows are 512 KB already
+    (16, 8192, 2, 64, 8),      # and the budget still halves
+    (8, 256, 2, 64, 1),        # half a sublane tile stays a slot of its own
+])
+def test_a_selection_s_tile_holds_a_slot_of_bytes_too(bs, row_bytes, itemsize, max_blocks, want):
+    """``selected=True`` (``SELECTED_SLOT_BYTES``) widens the tile of narrow
+    rows alone; no other call passes it, so theirs is the table above."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (SELECTED_SLOT_BYTES, TILE_VMEM_BYTES,
+                                                          tile_blocks)
+    n = tile_blocks(bs, row_bytes, itemsize, max_blocks, SELECTED_SLOT_BYTES)
+    assert n == want
+    assert n >= tile_blocks(bs, row_bytes, itemsize, max_blocks)
+    assert n == 1 or 4 * n * bs * row_bytes <= TILE_VMEM_BYTES
+
+
 @pytest.mark.parametrize("n", [1, 2, 8, 32])
 def test_any_tile_size_gives_the_same_answer(n):
     """``n`` is a matter of speed alone (the census sweeps it): n = 1 is
