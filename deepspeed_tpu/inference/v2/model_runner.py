@@ -469,6 +469,32 @@ class GPTKind(LlamaKind):
 LATENT_ROPE_LANES = 128     # the rotated key's row, padded to one lane tile
 
 
+LATENT_FETCH_COUNTS = ("n_blocks_named", "n_blocks_fetched")
+
+
+def _latent_stack(kind, params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+    """:func:`_scanned_stack` for a latent state, then
+    ``LATENT_FETCH_COUNTS``, once a step and not once a layer (every state
+    layer's call has the step's tables and positions): over all the
+    program's rows, padding included, the blocks their contexts name and
+    those of them one call of the latent kernel starts a copy for
+    (``paged_mla_attention.fetch_counts``, its fetch rule; the gather
+    reads whatever is named). They follow the scan's own counts, summed."""
+    from deepspeed_tpu.ops.pallas import paged_mla_attention as pm
+    h, kc, vc, extra, counts = _scanned_stack(kind, params, cfg, h, kc, vc, extra, batch, dtype,
+                                              mesh, attn_impl, lora)
+    tab, bs = batch["block_tables"][batch["token_seq"]], kc.shape[2]
+    n, _ = pm.mla_tile(bs, (kc.shape[3] + vc.shape[3]) * kc.dtype.itemsize, kc.dtype.itemsize,
+                       tab.shape[1], cfg.num_attention_heads)
+    named, fetched = pm.fetch_counts(tab, batch["token_pos"], bs, n)
+    if attn_impl is None or attn_impl.selected.get(tab.shape[0]) != "pallas_paged_mla":
+        fetched = named
+    fetch = jnp.stack([named, fetched])
+    if counts is not None:
+        fetch = jnp.concatenate([counts.sum(axis=0), fetch])
+    return h, kc, vc, extra, fetch[None]
+
+
 class MoonlightKind:
     """Moonlight / DeepSeek-V3 (``models/moonlight.py``): a **latent**
     state and ``dense x first_k_dense_replace, moe x (L - that)``.
@@ -485,8 +511,8 @@ class MoonlightKind:
     name = "moonlight"
     state_kind = "latent"
     lora = False
-    step_counts = ()
-    stack = classmethod(_scanned_stack)
+    step_counts = LATENT_FETCH_COUNTS
+    stack = classmethod(_latent_stack)
     state_layers = LlamaKind.state_layers
 
     @staticmethod
@@ -528,9 +554,10 @@ class LongcatKind(MoonlightKind):
     expert-parallel deployment behind a router with zero-compute
     columns. Each step counts, over its expert layers and its tokens
     that are not padding: the picks whose expert is held, the
-    zero-compute picks, and the held experts with at least one row."""
+    zero-compute picks, and the held experts with at least one row; then
+    the latent state's two (:func:`_latent_stack`)."""
     name = "longcat"
-    step_counts = ("n_picks_held", "n_picks_zero", "n_groups_live")
+    step_counts = ("n_picks_held", "n_picks_zero", "n_groups_live") + LATENT_FETCH_COUNTS
 
     @staticmethod
     def state_layers(cfg):

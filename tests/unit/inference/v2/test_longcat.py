@@ -110,12 +110,14 @@ def test_presets_build_by_name_and_pick_their_kind(model):
     kind = model_runner.kind_of(model.config)
     assert kind is model_runner.LongcatKind and kind.state_kind == "latent"
     assert kind.state_layers(model.config) == 4 and kind.state_rows(model.config) == (32, 128)
-    assert kind.step_counts == ("n_picks_held", "n_picks_zero", "n_groups_live")
+    assert kind.step_counts == ("n_picks_held", "n_picks_zero", "n_groups_live",
+                                "n_blocks_named", "n_blocks_fetched")
     moon = build_model("moonlight-debug").config
     assert model_runner.kind_of(moon) is model_runner.MoonlightKind
     assert model_runner.MoonlightKind.state_layers(moon) == moon.num_hidden_layers
     assert model_runner.LlamaKind.state_layers(build_model("debug").config) == 2
-    assert model_runner.MoonlightKind.step_counts == model_runner.LlamaKind.step_counts == ()
+    assert model_runner.MoonlightKind.step_counts == kind.step_counts[3:]
+    assert model_runner.LlamaKind.step_counts == ()
 
 
 @pytest.mark.parametrize("field,value", [
@@ -442,6 +444,7 @@ def test_step_records_carry_the_device_side_counts(engine, tokens):
     reference's router gives them for the same tokens, in put, burst and
     async-burst records alike; no host sync is added for them."""
     cfg = engine.model_config
+    kind_counts = model_runner.LongcatKind.step_counts
     seq = tokens[1][:14]
     syncs = engine.host_syncs
     engine.put([800], [seq])
@@ -463,7 +466,12 @@ def test_step_records_carry_the_device_side_counts(engine, tokens):
         want["n_picks_held"] += int(held.sum())
         want["n_picks_zero"] += int(picked[:, cfg.n_routed_experts:].sum())
         want["n_groups_live"] += int(held.any(axis=0).sum())
-    assert len(seen) == cfg.num_layers and got == want
+    assert len(seen) == cfg.num_layers and {name: got[name] for name in want} == want
+    # ... and the blocks the latent attention's rows name (a padding row the null block)
+    # and fetch: the gather, which serves here, reads whatever is named
+    block = engine.kv_cache.k.shape[2]
+    named = sum(p // block + 1 for p in range(14)) + int(engine.last_step.program) - 14
+    assert got["n_blocks_named"] == got["n_blocks_fetched"] == named
     assert tracing.snapshot()["steps"][-1]["counts"] == got
     # tokens and counts come to the host in one device_get: every copy is started before
     # the first is waited for
@@ -474,7 +482,7 @@ def test_step_records_carry_the_device_side_counts(engine, tokens):
         engine.put([800], [tokens[1][14:15]])
     finally:
         jax.device_get = get
-    assert fetched == [2] and set(engine.last_step.counts) == set(want)
+    assert fetched == [2] and set(engine.last_step.counts) == set(kind_counts)
     engine.decode_burst([800], [3], 4)
     burst = engine.last_step.counts
     assert engine.last_step.kind == "burst" and burst["n_picks_held"] + burst["n_picks_zero"] <= \
@@ -482,7 +490,7 @@ def test_step_records_carry_the_device_side_counts(engine, tokens):
     handle = engine.decode_burst_async([800], [3], 2)
     assert handle._record.counts is None
     handle.fetch()
-    assert engine.last_step.kind == "burst_async" and set(engine.last_step.counts) == set(want)
+    assert engine.last_step.kind == "burst_async" and set(engine.last_step.counts) == set(kind_counts)
     engine.flush(800)
     # a kind that counts nothing leaves the field empty
     llama = InferenceEngineV2(model=build_model("debug"), config=engine_config(),

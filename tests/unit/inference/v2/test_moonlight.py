@@ -349,15 +349,32 @@ def test_suspend_refuses_the_latent_state(engine, tokens):
 
 
 # ------------------------------------------------------------------- tracing
+def named_blocks(positions, rows):
+    """What a program of ``rows`` rows names: a live row the blocks up to
+    its position's, a padding row the null block."""
+    return sum(p // BLOCK + 1 for p in positions) + rows - len(positions)
+
+
 def test_step_records_carry_the_attended_context(engine, tokens):
-    engine.put([400, 401], [tokens[0][:10], tokens[1][:7]])
-    assert engine.last_step.n_ctx_tokens == 17
-    engine.put([400, 401], [tokens[0][10:11], tokens[1][7:9]])
-    assert engine.last_step.n_ctx_tokens == 11 + 9
+    """... and the blocks the latent attention's rows name and fetch
+    (``MoonlightKind.step_counts``): the gather, which serves here, reads
+    whatever is named."""
+    assert model_runner.MoonlightKind.step_counts == ("n_blocks_named", "n_blocks_fetched")
+    engine.put([400, 401], [tokens[0][:20], tokens[1][:7]])
+    assert engine.last_step.n_ctx_tokens == 27
+    rows = int(engine.last_step.program)
+    named = named_blocks(list(range(20)) + list(range(7)), rows)
+    assert engine.last_step.counts == {"n_blocks_named": named, "n_blocks_fetched": named}
+    engine.put([400, 401], [tokens[0][20:21], tokens[1][7:9]])
+    assert engine.last_step.n_ctx_tokens == 21 + 9
     engine.decode_burst([400, 401], [1, 2], 4)
-    # step j attends seen + j + 1 positions: 11 and 9 seen
-    assert engine.last_step.n_ctx_tokens == (12 + 13 + 14 + 15) + (10 + 11 + 12 + 13)
+    # step j attends seen + j + 1 positions: 21 and 9 seen
+    assert engine.last_step.n_ctx_tokens == (22 + 23 + 24 + 25) + (10 + 11 + 12 + 13)
+    named = sum(named_blocks([21 + j, 9 + j], 2) for j in range(4))
+    assert engine.last_step.counts["n_blocks_named"] >= named    # + the burst's padding rows
+    assert engine.last_step.counts["n_blocks_fetched"] == engine.last_step.counts["n_blocks_named"]
     assert tracing.snapshot()["steps"][-1]["n_ctx_tokens"] == engine.last_step.n_ctx_tokens
+    assert tracing.snapshot()["steps"][-1]["counts"] == engine.last_step.counts
     for uid in (400, 401):
         engine.flush(uid)
 

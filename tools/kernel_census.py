@@ -35,6 +35,18 @@ of an older commit, that commit's kernel beside them. ms a call, GB/s of
 the KV rows the tokens attend to, share of 819. ``paged_attention.tile_blocks``
 rests on this table (PERF.md, PR 33).
 
+``--mla`` times ``paged_mla_decode_attention`` at the shape classes the two
+latent cells serve (256-row bf16 blocks of 512 + 128 values, a traced layer
+index): Moonlight's 128 decode rows at contexts 1024-4096 under 16 heads,
+``longcat-flash-topics``' 256-row decode program with 69 rows of padding at
+128-1536 under 64 heads, and its 512-token mixed step (187 decode rows, a
+200-token chunk at context 100-300, padding) - the parent checkout's kernel,
+then the parts of the pipeline switched on in turn (the next token's first
+tile early, reuse, a tile of blocks), then ``n`` and the unit swept around
+:func:`paged_mla_attention.mla_tile`'s. ms a call, GB/s in
+least bytes (the roofline's: a sequence's context once) and in the bytes the
+call's copies move. ``mla_tile`` rests on this table (PERF.md, PR 35).
+
 Prints one JSON line per kernel and writes ``chiprun_out/kernel_census.json``.
 """
 
@@ -235,6 +247,20 @@ PAGED_CLASSES = (("mixtral-decode-64", 64, 64, (128, 1536), None),
 PAGED_TILES = (1, 2, 4, 8, 16, 32)
 
 
+def _parent_kernel(parent_dir, module):
+    """``ops/pallas/<module>.py`` of the checkout under ``parent_dir`` as a
+    module of its own, or None where there is no such checkout."""
+    import importlib.util
+
+    path = os.path.join(parent_dir, "deepspeed_tpu", "ops", "pallas", module + ".py")
+    if not os.path.exists(path):
+        return None
+    spec = importlib.util.spec_from_file_location("parent_" + module, path)
+    parent = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(parent)
+    return parent
+
+
 def paged_attention_classes(parent_dir):
     """Yields one record a shape class: the kernel at each ``n`` of
     ``PAGED_TILES`` (the rule's own marked), the parent commit's kernel
@@ -242,8 +268,6 @@ def paged_attention_classes(parent_dir):
     eighth token. The bytes are the K and V rows at positions <= the
     token's, once a token: what the token attends to, not the whole
     blocks fetched."""
-    import importlib.util
-
     import numpy as np
 
     import jax
@@ -251,12 +275,7 @@ def paged_attention_classes(parent_dir):
 
     from deepspeed_tpu.ops.pallas import paged_attention as pa
 
-    parent = None
-    path = os.path.join(parent_dir, "deepspeed_tpu", "ops", "pallas", "paged_attention.py")
-    if os.path.exists(path):
-        spec = importlib.util.spec_from_file_location("parent_paged_attention", path)
-        parent = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(parent)
+    parent = _parent_kernel(parent_dir, "paged_attention")
 
     H, Hkv, Dh, bs, L, NB, MB = 32, 8, HEAD_DIM, 16, 4, 8192, 96
     rng = np.random.default_rng(33)
@@ -304,6 +323,104 @@ def paged_attention_classes(parent_dir):
         yield name, record
 
 
+# (name, rows, heads, table columns, decode rows, (least, most) context, chunk (start, tokens))
+MLA_CLASSES = (("moonlight-decode-128", 128, 16, 18, 128, (1024, 4096), None),
+               ("topics-decode-256-69pad", 256, 64, 6, 187, (128, 1536), None),
+               ("topics-mixed-512", 512, 64, 6, 187, (128, 1536), (100, 200)))
+
+
+def mla_variants(n, unit, bs, max_blocks):
+    """(label, n, unit, ahead, reuse): the parts in turn, from the parent's
+    one block a turn to the rule's (n, unit), then ``n`` and the unit
+    swept with the other at the rule's."""
+    def tile(m):
+        return min(m, max_blocks)
+
+    def fitted(m, u):    # the unit, or the tile where it is no whole number of them
+        return u if tile(m) * bs % u == 0 and tile(m) * bs // u <= 8 else tile(m) * bs
+
+    turn = [("one-block-a-turn", 1, bs, False, False), ("+ahead", 1, bs, True, False),
+            ("+reuse", 1, bs, True, True), (f"+tile n={n} (rule)", n, unit, True, True)]
+    sweep = [(f"n={m}", tile(m), fitted(m, unit), True, True) for m in (2, 3, 4, 6, 8)]
+    sweep += [(f"unit={u}", n, fitted(n, u), True, True) for u in (128, 512, n * bs)]
+    seen = {v[1:] for v in turn}
+    return turn + [v for v in sweep if v[1:] not in seen and not seen.add(v[1:])]
+
+
+def paged_mla_classes(parent_dir):
+    """Yields one record a shape class: the parent commit's kernel where
+    there is one, then each of :func:`mla_variants`, each against
+    ``xla_paged_mla_attention`` on every eighth token."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import paged_mla_attention as pm
+
+    parent = _parent_kernel(parent_dir, "paged_mla_attention")
+
+    rank, lanes, bs, L, NB = 512, 128, 256, 4, 2048
+    row_bytes = (rank + lanes) * 2
+    rng = np.random.default_rng(35)
+    pool = jax.jit(lambda key, w: jax.random.normal(key, (L, NB, bs, w), jnp.bfloat16),
+                   static_argnums=1)
+    c_pool, r_pool = pool(jax.random.PRNGKey(1), rank), pool(jax.random.PRNGKey(2), lanes)
+    layer = jnp.int32(L - 2)
+    for name, T, H, MB, live, ctx, chunk in MLA_CLASSES:
+        tabs, pos = np.zeros((T, MB), np.int32), np.zeros(T, np.int32)
+        free = iter(rng.permutation(np.arange(1, NB)))
+        pos[:live] = np.exp(rng.uniform(np.log(ctx[0]), np.log(ctx[1]), live)).astype(int) - 1
+        for t in range(live):   # decode rows first, as the engine packs them
+            need = pos[t] // bs + 1
+            tabs[t, :need] = [next(free) for _ in range(need)]
+        seq_ctx = [int(p) + 1 for p in pos[:live]]
+        if chunk:               # then one sequence's chunk; the rest is padding on the null block
+            at, length = chunk
+            pos[live:live + length] = at + np.arange(length)
+            need = -(-(at + length) // bs)
+            tabs[live:live + length, :need] = [next(free) for _ in range(need)]
+            seq_ctx.append(at + length)
+        q = jnp.asarray(rng.standard_normal((T, H, rank + lanes), np.float32) * 0.1, jnp.bfloat16)
+        tabs_d, pos_d = jnp.asarray(tabs), jnp.asarray(pos)
+        some = jnp.arange(0, T, 8)
+        want = jax.jit(pm.xla_paged_mla_attention)(q[some], c_pool, r_pool, tabs_d[some],
+                                                   pos_d[some], layer)
+        # the roofline's bytes (benchmark/readers/mla_roofline.kernel_bytes, one layer)
+        least = 2 * (sum(seq_ctx) * (rank + lanes) + T * H * (rank + lanes) + T * H * rank)
+        blocks = np.minimum(pos // bs + 1, MB)
+        record = {"rows": T, "heads": H, "table_columns": MB, "ctx_tokens": sum(seq_ctx),
+                  "least_bytes": least, "blocks_named": int(blocks.sum()),
+                  "rule": list(pm.mla_tile(bs, row_bytes, 2, MB, H))}
+
+        def timed(fn, fetched_rows):
+            try:
+                call = jax.jit(fn)
+                if not mosaic_kernels(call.lower(q, c_pool, r_pool, tabs_d, pos_d, layer)):
+                    raise RuntimeError("no Mosaic kernel in the lowered program")
+                ms = _ms_a_call(call, q, c_pool, r_pool, tabs_d, pos_d, layer, calls=100)
+                err = rel_err(call(q, c_pool, r_pool, tabs_d, pos_d, layer)[some], want)
+                fetched = fetched_rows * row_bytes
+                return {"ms": ms, "least_gb_s": least / ms / 1e6,
+                        "hbm_share": 100 * least / ms / 1e6 / HBM_GB_S,
+                        "fetched_over_least": fetched / least, "fetched_gb_s": fetched / ms / 1e6,
+                        "rel_err": float(f"{err:.3e}")}
+            except Exception as e:  # a refusal is a record too
+                return {"refused": f"{type(e).__name__}: {e}"[:600]}
+
+        if parent is not None:
+            record["parent"] = timed(lambda *a: parent.paged_mla_decode_attention(
+                *a, interpret=False), int(blocks.sum()) * bs)
+        n, unit = record["rule"]
+        for label, n, unit, ahead, reuse in mla_variants(n, unit, bs, MB):
+            fetched = int(pm.fetch_counts(tabs_d, pos_d, bs, n)[1]) if reuse else int(blocks.sum())
+            got = timed(lambda *a, n=n, unit=unit, ahead=ahead, reuse=reuse:
+                        pm._mla_call(*a, n, unit, False, ahead=ahead, reuse=reuse), fetched * bs)
+            record[label] = {"n": n, "unit": unit, **got,
+                             "fetch_share": 100.0 * fetched / int(blocks.sum())}
+        yield name, record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -329,17 +446,20 @@ def main():
     enable_compile_cache()
     report = {"device": {"platform": devices[0].platform, "kind": devices[0].device_kind,
                          "count": len(devices)}, "kernels": {}}
-    paged = "--paged" in sys.argv
+    paged, mla = "--paged" in sys.argv, "--mla" in sys.argv
+    parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
+                  if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
     if paged:
-        parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
-                      if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
         section, records = "paged_attention", paged_attention_classes(parent_dir)
+    elif mla:
+        section, records = "paged_mla_attention", paged_mla_classes(parent_dir)
     else:
         section, records = "grouped_matmul", grouped_matmul_classes(sweep="--gmm-sweep" in sys.argv)
     for name, record in records:
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
-    for name, fn, ref, args, tol in () if paged or "--gmm-only" in sys.argv else cases():
+    for name, fn, ref, args, tol in (() if paged or mla or "--gmm-only" in sys.argv
+                                     else cases()):
         try:
             result = verdict(fn, ref, args, tol)
         except Exception as e:  # the census records a refusal and goes on to the next kernel
@@ -347,8 +467,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open(os.path.join("chiprun_out", "paged_census.json" if paged else "kernel_census.json"),
-              "w") as f:
+    out = "paged_census.json" if paged else "mla_census.json" if mla else "kernel_census.json"
+    with open(os.path.join("chiprun_out", out), "w") as f:
         json.dump(report, f, indent=1)
 
 
