@@ -101,45 +101,14 @@ def plan_for(devices):
                 serve_layers=depth(SERVE_WEIGHT_SHARE, 2))
 
 
-class CompileMeter:
-    """Sums JAX's own compile events (``jax.monitoring``): seconds in the
-    backend compiler (or, on a persistent-cache hit, in reading the
-    executable back), seconds tracing and lowering, and cache hits and
-    misses; ``programs`` lists what was compiled, in order. Programs
-    compile on the serving pump thread too."""
-
-    def __init__(self):
-        import jax
-        self._lock = threading.Lock()
-        self._totals = {"compiles": 0, "compile_s": 0.0, "trace_lower_s": 0.0,
-                        "cache_hits": 0, "cache_misses": 0}
-        self.programs = []
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, fun_name=None, **_):
-        with self._lock:
-            if event == "/jax/core/compile/backend_compile_duration":
-                self._totals["compiles"] += 1
-                self._totals["compile_s"] += duration
-                self.programs.append(fun_name)
-            elif event in ("/jax/core/compile/jaxpr_trace_duration",
-                           "/jax/core/compile/jaxpr_to_mlir_module_duration"):
-                self._totals["trace_lower_s"] += duration
-
-    def _on_event(self, event, **_):
-        with self._lock:
-            if event == "/jax/compilation_cache/cache_hits":
-                self._totals["cache_hits"] += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self._totals["cache_misses"] += 1
-
-    def totals(self):
-        with self._lock:
-            return {k: round(v, 2) for k, v in self._totals.items()}
-
-    def since(self, before):
-        return {k: round(v - before[k], 2) for k, v in self.totals().items()}
+def compile_totals():
+    """What the program's own recorder counted of JAX's compile events so far
+    (``utils/tracing.process_counters``: backend compiles, and seconds tracing,
+    lowering and compiling or reading a program back from the persistent
+    cache). Programs compile on the serving pump thread too."""
+    from deepspeed_tpu.utils import tracing
+    _, _, compile_ns, compiles = tracing.process_counters()
+    return {"compiles": compiles, "compile_s": round(compile_ns / 1e9, 2)}
 
 
 def resident_bytes(devices, what):
@@ -165,12 +134,12 @@ def rel_err(got, want):
     return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
 
 
-def run_phase(name, meter, devices, fn):
-    before = meter.totals()
+def run_phase(name, devices, fn):
+    before = compile_totals()
     t0 = time.perf_counter()
     facts = fn()
     facts["wall_s"] = round(time.perf_counter() - t0, 1)
-    facts.update(meter.since(before))
+    facts.update({k: round(v - before[k], 2) for k, v in compile_totals().items()})
     # the process's high-water mark so far: a later phase shows here only if it went higher
     facts["peak_bytes_in_use"] = [d.memory_stats()["peak_bytes_in_use"] for d in devices]
     print(f"[{name}] {json.dumps(facts)}", flush=True)
@@ -266,7 +235,7 @@ def check_zero3_sharded(engine, n_dev):
     return counts
 
 
-def train_phase(plan, devices, meter):
+def train_phase(plan, devices):
     import numpy as np
 
     import jax
@@ -276,6 +245,7 @@ def train_phase(plan, devices, meter):
     from deepspeed_tpu.models import build_llama
     from deepspeed_tpu.parallel import groups
     from deepspeed_tpu.parallel.topology import make_mesh_topology
+    from deepspeed_tpu.utils import tracing
 
     n_dev = len(devices)
     model = build_llama(plan.preset, num_hidden_layers=plan.train_layers,
@@ -294,13 +264,16 @@ def train_phase(plan, devices, meter):
     ids = np.random.default_rng(0).integers(
         0, model.config.vocab_size, (1, plan.micro_batch * n_dev, plan.seq_len), dtype=np.int32)
 
-    losses, step_s, step_programs = [], [], []
+    losses, step_s, step_seqs = [], [], []
     for _ in range(plan.train_steps):
-        compiled = len(meter.programs)
         t0 = time.perf_counter()
         losses.append(float(engine.train_batch(batch=(ids, ids))))
         step_s.append(round(time.perf_counter() - t0, 3))
-        step_programs.append(meter.programs[compiled:])
+        step_seqs.append(tracing.RECORDER.steps[-1].seq)      # the step's own `train` record
+    # the recorder's events say which step compiled what
+    compiled = [e for e in tracing.snapshot()["events"]
+                if e["kind"] == "compile" and e["name"] == "backend_compile_duration"]
+    step_programs = [[e["program"] for e in compiled if e["seq"] == seq] for seq in step_seqs]
     check(all(math.isfinite(l) for l in losses), f"non-finite training loss: {losses}")
     # unit-variance logits over V classes: ln V plus about a half
     check(abs(losses[0] - math.log(model.config.vocab_size)) < 1.5,
@@ -476,7 +449,6 @@ def main():
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
     cache_dir = enable_compile_cache()
-    meter = CompileMeter()
     device = {"platform": platform, "kind": devices[0].device_kind, "count": len(devices)}
     try:
         import libtpu
@@ -504,11 +476,11 @@ def main():
         "plan": dataclasses.asdict(plan)}), flush=True)
 
     t0 = time.perf_counter()
-    run_phase("kernels", meter, devices, lambda: kernels_phase(LLAMA_CONFIGS[plan.preset]))
-    run_phase("train", meter, devices, lambda: train_phase(plan, devices, meter))
-    run_phase("serve", meter, devices, lambda: serve_phase(plan, devices))
+    run_phase("kernels", devices, lambda: kernels_phase(LLAMA_CONFIGS[plan.preset]))
+    run_phase("train", devices, lambda: train_phase(plan, devices))
+    run_phase("serve", devices, lambda: serve_phase(plan, devices))
     print("[total] " + json.dumps({"wall_s": round(time.perf_counter() - t0, 1),
-                                   **meter.totals()}), flush=True)
+                                   **compile_totals()}), flush=True)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

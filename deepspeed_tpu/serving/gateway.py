@@ -31,6 +31,7 @@ import queue as _queue
 import threading
 import time
 from collections import OrderedDict
+from statistics import median
 
 import numpy as np
 
@@ -173,6 +174,16 @@ class RequestHandle:
         return True
 
 
+def _cpu_ms_between(prev, rec):
+    """CPU time of the thread that ran both engine records between their
+    last CPU marks (each record's ``ds.engine.fetch`` exit: its result on
+    the host), in ms; None where a record has no mark or another thread
+    ran it."""
+    if not prev.cpu_marks or not rec.cpu_marks or prev.thread != rec.thread:
+        return None
+    return (rec.cpu_marks[-1][2] - prev.cpu_marks[-1][2]) / 1e6
+
+
 class ServingGateway:
 
     def __init__(self, engine, config=None, monitor=None, auto_start=True):
@@ -200,6 +211,12 @@ class ServingGateway:
         self.metrics.attach_sources(self._gauges, self._external,
                                     engine_id=self._engine_id)
         self._pump_seq = 0   # seq of the pump pass in progress
+        # stall watch (pump thread only): the engine record the last pass
+        # that ran a step ended with, None once the pump had nothing to run;
+        # and what the thread spent waiting and in passes that did nothing
+        # since the last kept pass
+        self._last_engine_rec = None
+        self._waited_ns = self._idle_passes = 0
         # disaggregated serving: a "prefill" gateway exports a KV
         # handoff record into a bounded outbox when a request finishes;
         # the fleet router claims it via take_handoff() and delivers it
@@ -674,6 +691,7 @@ class ServingGateway:
     # ------------------------------------------------------------------ pump
     def _run(self):
         while not self._pump_stop:
+            began = tracing.now_ns()
             try:
                 did_work = self._pump_once()
             except Exception as e:  # crash-safe: never hang clients
@@ -690,6 +708,8 @@ class ServingGateway:
                 self._wake.wait(timeout=self.config.idle_poll_s if in_flight
                                 else 0.05)
                 self._wake.clear()
+                self._idle_passes += 1
+                self._waited_ns += tracing.now_ns() - began
 
     def _pump_once(self):
         """One pump iteration; True when any request made progress."""
@@ -705,14 +725,95 @@ class ServingGateway:
                 if not refreshing:  # admission held while a weight swap is staged
                     did |= self._admit()
                 did |= self._resume_paused()
-            did |= self._step()
+            stepped = self._step()
+            did |= stepped
             rec.keep = did
+            if did:
+                rec.waited_ns, rec.idle_passes = self._waited_ns, self._idle_passes
+                self._waited_ns = self._idle_passes = 0
+        if stepped:
+            self._watch_stall()
         interval = self.config.metrics_interval_steps
         if self.monitor is not None and interval and did:
             steps = self.metrics.counter("engine_steps")
             if steps and steps % interval == 0:
                 self.metrics.write_events(self.monitor, step=steps)
         return did
+
+    def _watch_stall(self):
+        """After a pass that ran a step: the time from the end of the
+        engine's previous step record to the end of this pass's, while the
+        gateway held a request it could run throughout. One subtraction;
+        the rest only when that alone is ``tracing.STALL_NS`` or more."""
+        prev, rec = self._last_engine_rec, getattr(self.engine, "last_step", None)
+        if rec is None or rec.end_ns is None:
+            return
+        self._last_engine_rec = rec
+        if prev is not None and prev is not rec \
+                and rec.end_ns - prev.end_ns >= tracing.STALL_NS:
+            self._check_stall(prev, rec)
+
+    def _check_stall(self, prev, rec):
+        """The interval ``prev.end_ns`` → ``rec.end_ns`` less what
+        ``rec.program`` usually takes from dispatch to the end of its fetch,
+        and less what was spent compiling, is the excess;
+        ``tracing.STALL_NS`` of it or more, on a program seen
+        ``tracing.STALL_MIN_RECORDS`` times before, is a stall: counted,
+        written to the recorder's events and logged, with what the pump
+        thread, the collector and the compiler did meanwhile."""
+        ring = tuple(tracing.RECORDER.steps)
+        earlier = [tracing.device_ns(r) for r in ring if r.engine == rec.engine and r is not rec
+                   and r.kind != "pump" and r.program == rec.program]
+        earlier = [ns for ns in earlier if ns is not None]
+        if len(earlier) < tracing.STALL_MIN_RECORDS:
+            return
+        expected = median(earlier[-tracing.STALL_MEDIAN_OF:])
+        start, end = prev.end_ns, rec.end_ns
+        gc_ns, gc_passes, compile_ns, compiles = (
+            now - then for now, then in zip(rec.counters_at, prev.counters_at))
+        # a program compiled in the interval (a variant the warm-up did not
+        # reach) is no stall: the events ring names the compile already
+        excess = end - start - expected - compile_ns
+        if excess < tracing.STALL_NS:
+            return
+        # where the interval went: every phase of the records that overlap it
+        # (they do not overlap one another), the rest of a pump pass as the
+        # pass itself, and what lies in no pass
+        held, passes, waited = {}, 0, 0
+        for r in ring:
+            if r.engine != rec.engine or r.end_ns <= start or r.start_ns >= end:
+                continue
+            if r.kind == "pump":
+                passes += min(r.end_ns, end) - max(r.start_ns, start)
+                if r.start_ns > start:
+                    waited += r.waited_ns
+            for name, enter, exit_ in r.phases:
+                inside = min(exit_, end) - max(enter, start)
+                if inside > 0:
+                    held[name] = held.get(name, 0) + inside
+        in_phases = sum(held.values())
+        held["ds.gateway.pump"] = max(0, passes - in_phases)
+        held["(no pass)"] = max(0, end - start - max(passes, in_phases))
+        between = max(0, rec.start_ns - start)
+        found = {
+            "excess_ms": excess / 1e6, "expected_ms": expected / 1e6,
+            "where": "inside" if end - start - between - expected >= between else "between",
+            "record_kind": rec.kind, "program": rec.program, "n_seqs": rec.n_seqs,
+            "n_tokens": rec.n_tokens, "phase": max(held, key=held.get),
+            "cpu_ms": _cpu_ms_between(prev, rec), "gc_ms": gc_ns / 1e6,
+            "gc_passes": gc_passes, "compile_ms": compile_ns / 1e6, "compiles": compiles,
+            "waited_ms": waited / 1e6}
+        self.metrics.count("stalls")
+        self.metrics.count("stalled_ms", int(round(found["excess_ms"])))
+        tracing.event("stall", start, end, seq=rec.seq, **found)
+        logger.warning(
+            "serving: stall of {excess_ms:.1f} ms beyond the {expected_ms:.1f} ms a step of "
+            "program {program} takes, {where} {record_kind} record {seq} ({n_seqs} rows, {n_tokens} "
+            "tokens), most of it in {phase}; pump thread cpu {cpu_ms} ms, collector "
+            "{gc_ms:.1f} ms in {gc_passes} passes, compile {compile_ms:.1f} ms in {compiles} "
+            "compiles, waited {waited_ms:.1f} ms".format(
+                seq=rec.seq, **{**found, "cpu_ms": "n/a" if found["cpu_ms"] is None
+                                else f"{found['cpu_ms']:.1f}"}))
 
     def _gauges(self):
         """Gauge source of :class:`ServingMetrics` (read on demand; races
@@ -907,6 +1008,7 @@ class ServingGateway:
 
     def _step(self):
         if not any(uid not in self._paused for uid in self._active):
+            self._last_engine_rec = None   # nothing to run: what follows is no stall
             return False
         stepped = self.scheduler.step()
         self.metrics.count("engine_steps")

@@ -80,11 +80,10 @@ def summarize_steps(records):
     counts, burst_k, burst_ms, mixed_ms = {}, [], [], []
     for rec in records:
         counts[rec.kind] = counts.get(rec.kind, 0) + 1
-        enter = [t for name, t, _ in rec.phases if name == "ds.engine.dispatch"]
-        exit_ = [t for name, _, t in rec.phases if name == "ds.engine.fetch"]
-        if not enter or not exit_:
+        ns = tracing.device_ns(rec)
+        if ns is None:
             continue
-        ms = (exit_[-1] - enter[0]) / 1e6
+        ms = ns / 1e6
         if rec.kind in ("burst", "burst_async"):
             burst_k.append(rec.k)
             burst_ms.append(ms / rec.k)
@@ -104,7 +103,10 @@ class ServingMetrics:
                 "tokens_generated", "engine_steps", "failed",
                 "handoffs_exported", "handoffs_imported",
                 "weight_refreshes", "rejected_unknown_adapter",
-                "rejected_adapter")
+                "rejected_adapter",
+                # stalls the gateway found (gateway._check_stall): their number
+                # and, in whole milliseconds, what they took beyond the step
+                "stalls", "stalled_ms")
 
     def __init__(self, window=1024):
         self._window = window

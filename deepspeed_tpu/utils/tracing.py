@@ -1,9 +1,9 @@
 """Step records and request life-cycle stamps, kept by the program itself.
 
-One process-wide :class:`Recorder` holds two bounded rings. It is always
+One process-wide :class:`Recorder` holds three bounded rings. It is always
 on — there is no switch — because a record costs a few
-``time.perf_counter_ns()`` calls and one append per *model step* (about
-ten a second) or per *request*:
+``time.perf_counter_ns()`` / ``time.thread_time_ns()`` calls and one
+append per *model step* (about ten a second) or per *request*:
 
 * a **step record** for every model program run (``put``, a decode
   burst, an async burst and its fetch, a verify burst, ``train_batch``)
@@ -14,7 +14,29 @@ ten a second) or per *request*:
   thread when this one was opened (the pump pass; 0 outside a gateway);
 * a **request record** for every request a gateway saw end, with the
   stamps of its life (submitted, admitted, first scheduled, first token,
-  ended) and the ``seq`` of the pump pass and step records they fell in.
+  ended) and the ``seq`` of the pump pass and step records they fell in;
+* an **event** for what stops a thread from outside its own code: every
+  collector pass and every compile (trace, lowering, backend compile) of
+  ``EVENT_MIN_NS`` or more, whichever thread it ran on, and every stall a
+  serving gateway found (``serving/gateway.py``), each with its start and
+  end on the records' clock and the ``seq`` of the record that was open
+  on the thread.
+
+A step record says what the thread that opened it did with its time.
+``cpu_marks`` holds that thread's CPU clock (``time.thread_time_ns()``)
+read at the exit of the few phases named in ``CPU_MARKED`` — three a
+served step: the program is packed (the launch follows), its result is
+fetched, its tokens are accepted — as ``[phase, wall ns, cpu ns]``. Two
+marks of one ``thread`` bound an interval: its CPU time over its wall time
+says whether the thread ran or waited — for the device, the interpreter
+lock or the operating system. Only those phases, because the clock is a
+system call: 6 us a reading on the hosts the chips hang on (0.3 us on a
+developer's machine), where it also ticks in steps of about 10 ms, so only
+sums over a second or more of marks mean anything (``PERF.md`` section 6).
+``gc_ns`` / ``gc_passes`` / ``compile_ns`` / ``compiles`` are what four
+process-wide counters (:func:`process_counters`: one ``gc.callbacks``
+hook, one ``jax.monitoring`` listener, installed when this module is
+imported) moved by between the record's begin and its end.
 
 ``with tracing.phase("engine.pack"):`` stamps enter and exit into the
 record that is open on this thread (none open: the stamp is dropped) and
@@ -23,7 +45,7 @@ profiler session the annotation is inert; with one — anybody's
 ``jax.profiler.start_trace`` — the program's phases are on the profiler's
 own timeline beside the device ops.
 
-Every stamp is ``time.perf_counter_ns()``. Writers take no lock: a
+Every wall stamp is ``time.perf_counter_ns()``. Writers take no lock: a
 ``deque.append`` and ``next()`` of an ``itertools.count`` are atomic
 under the interpreter lock, and a record is mutated only by the thread
 that opened it until it is appended. ``snapshot()`` and ``dump(path)``
@@ -31,32 +53,125 @@ copy the rings when asked; nothing is written on the hot path.
 """
 
 import collections
+import gc
 import itertools
 import json
 import threading
 import time
+import weakref
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 STEP_RING = 8192       # ~10 minutes of serving at ten steps a second
 REQUEST_RING = 4096
+EVENT_RING = 1024
+EVENT_MIN_NS = 1_000_000    # a collector pass, a trace or a lowering shorter than this leaves no event
+# a serving gateway calls the time from one engine record's end to the next
+# one's a stall when it exceeds what the program usually takes by STALL_NS,
+# once it has seen the program STALL_MIN_RECORDS times (warm-up compiles are
+# not stalls); what it usually takes is the median of its last
+# STALL_MEDIAN_OF records (serving/gateway.py)
+STALL_NS = 250_000_000
+STALL_MIN_RECORDS = 8
+STALL_MEDIAN_OF = 32
 PREFIX = "ds."
 
 now_ns = time.perf_counter_ns
+cpu_ns = time.thread_time_ns
+# the phases at whose exit the thread's CPU clock is read (a record's
+# cpu_marks): between two marks lie, in a serving step, the host's work after
+# a result (accept), its work before the next launch (deliver, admit, plan,
+# pack) and the program itself (dispatch, fetch); a training step's phases
+# are few and long, so all of its own are marked
+CPU_MARKED = frozenset(PREFIX + name for name in (
+    "engine.pack", "engine.fetch", "sched.accept",
+    "train.prepare", "train.dispatch", "train.sync", "train.post"))
 
 STEP_FIELDS = ("seq", "engine", "kind", "program", "k", "n_seqs", "n_tokens",
                "n_prompt_tokens", "n_ctx_tokens", "counts", "caused_by", "uids", "start_ns",
-               "end_ns")
+               "end_ns", "thread", "gc_ns", "gc_passes", "compile_ns", "compiles",
+               "waited_ns", "idle_passes")
+
+# JAX's own duration events (jax.monitoring) that count as compiling: the
+# ones benchmark/harness/device.CompileMeter sums from outside the program
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_EVENTS = (COMPILE_EVENT, "/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration")
+
+
+class _Process:
+    """What the collector and the compiler took of this process so far,
+    whichever thread they ran on. The collector runs one pass at a time
+    under the interpreter lock, so its hook takes no lock; compiles end on
+    several threads at once, so theirs does."""
+    gc_ns = gc_passes = compile_ns = compiles = 0
+    gc_started = None
+    compile_lock = threading.Lock()
+    recorders = weakref.WeakSet()    # every Recorder's events ring is told
+
+
+def process_counters():
+    """→ ``(gc_ns, gc_passes, compile_ns, compiles)`` since this module was
+    imported: nanoseconds in collector passes and their number, nanoseconds
+    tracing, lowering and compiling (or reading a compiled program back from
+    the persistent cache) and the number of backend compiles."""
+    return _Process.gc_ns, _Process.gc_passes, _Process.compile_ns, _Process.compiles
+
+
+def _tell(kind, start_ns, end_ns, **fields):
+    for recorder in tuple(_Process.recorders):
+        recorder.event(kind, start_ns, end_ns, **fields)
+
+
+def _on_gc(phase, info):
+    if phase == "start":
+        _Process.gc_started = now_ns()
+    elif _Process.gc_started is not None:
+        start, end, _Process.gc_started = _Process.gc_started, now_ns(), None
+        _Process.gc_ns += end - start
+        _Process.gc_passes += 1
+        if end - start >= EVENT_MIN_NS:
+            _tell("gc", start, end, generation=info["generation"], collected=info["collected"])
+
+
+def _on_duration(event, seconds, fun_name=None, **_):
+    if event not in COMPILE_EVENTS:
+        return
+    end = now_ns()
+    ns = int(seconds * 1e9)
+    with _Process.compile_lock:
+        _Process.compile_ns += ns
+        _Process.compiles += event == COMPILE_EVENT
+    if event == COMPILE_EVENT or ns >= EVENT_MIN_NS:
+        _tell("compile", end - ns, end, name=event.rsplit("/", 1)[-1], program=fun_name,
+              seconds=seconds)
+
+
+gc.callbacks.append(_on_gc)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 class StepRecord:
-    __slots__ = STEP_FIELDS + ("phases", "keep", "_parent")
+    # counters_at: the process counters at the record's begin, then at its
+    # end - what the next record's interval is measured from
+    __slots__ = STEP_FIELDS + ("phases", "cpu_marks", "keep", "counters_at", "_parent")
 
     def as_dict(self):
         out = {name: getattr(self, name) for name in STEP_FIELDS}
         out["uids"] = list(self.uids)
         out["phases"] = [list(p) for p in self.phases]
+        out["cpu_marks"] = [list(m) for m in self.cpu_marks]
         return out
+
+
+def device_ns(record):
+    """``ds.engine.dispatch`` enter → ``ds.engine.fetch`` exit of an engine
+    record: from the launch of its program to its result on the host. None
+    without both phases."""
+    enter = [t for name, t, _ in record.phases if name == "ds.engine.dispatch"]
+    exit_ = [t for name, _, t in record.phases if name == "ds.engine.fetch"]
+    return exit_[-1] - enter[0] if enter and exit_ else None
 
 
 class _Step:
@@ -99,15 +214,19 @@ class _Phase:
         exit_ = now_ns()
         if self._record is not None:
             self._record.phases.append((self._name, self._enter, exit_))
+            if self._name in CPU_MARKED:
+                self._record.cpu_marks.append((self._name, exit_, cpu_ns()))
         self._span.__exit__(exc_type, exc, tb)
         return False
 
 
 class Recorder:
 
-    def __init__(self, step_ring=STEP_RING, request_ring=REQUEST_RING):
+    def __init__(self, step_ring=STEP_RING, request_ring=REQUEST_RING, event_ring=EVENT_RING):
         self.steps = collections.deque(maxlen=step_ring)
         self.requests = collections.deque(maxlen=request_ring)
+        self.events = collections.deque(maxlen=event_ring)
+        _Process.recorders.add(self)
         self._seq = itertools.count(1)
         self._engines = itertools.count(1)
         self._open = threading.local()
@@ -132,16 +251,24 @@ class Recorder:
         # (model_runner: kind.step_counts); None where the model kind counts nothing
         rec.counts = None
         rec.uids = uids
-        rec.phases, rec.keep, rec.end_ns = [], True, None
+        rec.phases, rec.cpu_marks, rec.keep, rec.end_ns = [], [], True, None
+        rec.thread = threading.get_ident()
+        # a pump pass that is kept says what its thread did since the last kept pass
+        rec.waited_ns = rec.idle_passes = 0
         parent = rec._parent = self.current()
         rec.caused_by = parent.seq if parent is not None else 0
         self._open.record = rec
+        rec.counters_at = process_counters()
         rec.start_ns = now_ns()
         return rec
 
     def end(self, rec, keep=True):
         rec.end_ns = now_ns()
         self.suspend(rec)
+        before, now = rec.counters_at, process_counters()
+        rec.counters_at = now
+        rec.gc_ns, rec.gc_passes = now[0] - before[0], now[1] - before[1]
+        rec.compile_ns, rec.compiles = now[2] - before[2], now[3] - before[3]
         if keep:
             self.steps.append(rec)
 
@@ -165,21 +292,33 @@ class Recorder:
     def request(self, **stamps):
         self.requests.append(stamps)
 
+    # ------------------------------------------------------------------- events
+    def event(self, kind, start_ns, end_ns, seq=None, **fields):
+        """One entry of the events ring; ``seq`` defaults to the record
+        open on the calling thread (0: none)."""
+        if seq is None:
+            rec = self.current()
+            seq = rec.seq if rec is not None else 0
+        self.events.append({"kind": kind, "start_ns": start_ns, "end_ns": end_ns, "seq": seq,
+                            **fields})
+
     # ------------------------------------------------------------------ reading
     def snapshot(self):
-        """→ ``{"steps": [dict, ...], "requests": [dict, ...]}``, oldest first."""
+        """→ ``{"steps": [dict, ...], "requests": [dict, ...], "events":
+        [dict, ...]}``, oldest first."""
         return {"steps": [r.as_dict() for r in tuple(self.steps)],
-                "requests": [dict(r) for r in tuple(self.requests)]}
+                "requests": [dict(r) for r in tuple(self.requests)],
+                "events": [dict(e) for e in tuple(self.events)]}
 
     def dump(self, path):
-        """Both rings as JSON lines: ``{"record": "step" | "request", ...}``."""
+        """The rings as JSON lines: ``{"record": "step" | "request" | "event", ...}``."""
         snap = self.snapshot()
         with open(path, "w") as f:
-            for key, label in (("steps", "step"), ("requests", "request")):
+            for key, label in (("steps", "step"), ("requests", "request"), ("events", "event")):
                 for rec in snap[key]:
                     # a caller may name its sequences with numpy integers
                     f.write(json.dumps({"record": label, **rec}, default=lambda o: o.item()) + "\n")
-        return len(snap["steps"]) + len(snap["requests"])
+        return sum(len(ring) for ring in snap.values())
 
 
 RECORDER = Recorder()
@@ -193,5 +332,6 @@ resume = RECORDER.resume
 step = RECORDER.step
 phase = RECORDER.phase
 request = RECORDER.request
+event = RECORDER.event
 snapshot = RECORDER.snapshot
 dump = RECORDER.dump

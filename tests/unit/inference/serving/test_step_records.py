@@ -4,6 +4,8 @@ one record per program run on every path that runs one, request stamps
 that are ordered and point at records that exist, and a gateway snapshot
 that keeps every key it had when gauges were pushed each pump pass."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,7 @@ from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, DynamicSplitFuseSc
 from deepspeed_tpu.inference.v2.config_v2 import AsyncBurstConfig
 from deepspeed_tpu.models import build_llama
 from deepspeed_tpu.serving import ServingConfig, ServingGateway
+from deepspeed_tpu.serving.gateway import logger as gateway_logger
 from deepspeed_tpu.utils import tracing
 
 PROMPT = (np.arange(1, 13) % 250).astype(np.int32)           # 12 tokens
@@ -278,3 +281,110 @@ def test_the_snapshot_keeps_every_key_with_the_pulled_sources(model_and_params):
     assert after["gauges"] == live["gauges"] and after["state"] == "stopped"
     assert after["external"]["Serve/Engine"] == live["external"]["Serve/Engine"]
     assert set(after["external"]) == set(live["external"])
+
+
+# ------------------------------------------------------------------------ stalls
+class SkippingClock:
+    """``tracing.now_ns`` with seconds that can be skipped: a delay on the
+    records' clock that costs the test no sleep and the thread no CPU."""
+
+    def __init__(self, monkeypatch):
+        self.skipped = 0
+        real = tracing.now_ns
+        monkeypatch.setattr(tracing, "now_ns", lambda: real() + self.skipped)
+
+    def skip(self, ms):
+        self.skipped += int(ms * 1e6)
+
+
+def stall_gateway(model_and_params, **config):
+    """Every step a ``put``: after its prefill a request runs one program
+    (``8``) again and again, so the ninth decode step has eight before it."""
+    engine = make_engine(model_and_params, n_seqs=8, batch=16)
+    return engine, ServingGateway(engine, config=ServingConfig(token_budget=16, max_burst=1, **config),
+                                  auto_start=False)
+
+
+def pump_until(gw, done, limit=500):
+    for _ in range(limit):
+        if done():
+            return
+        gw._pump_once()
+    raise AssertionError("the pump did not get there")
+
+
+def stalls_of(engine):
+    mine = {r.seq for r in tuple(tracing.RECORDER.steps) if r.engine == engine.trace_id}
+    return [e for e in tracing.snapshot()["events"] if e["kind"] == "stall" and e["seq"] in mine]
+
+
+@pytest.mark.parametrize("where", ["inside", "between"])
+def test_a_delay_after_eight_ordinary_steps_is_a_stall_with_its_place_named(
+        model_and_params, monkeypatch, where):
+    clock = SkippingClock(monkeypatch)
+    engine, gw = stall_gateway(model_and_params)
+    handle = gw.submit(PROMPT, max_new_tokens=24)
+    armed = {"at": None}
+    if where == "inside":          # 0.4 s pass inside one put's wait for the device
+        real_phase = tracing.phase
+
+        @contextlib.contextmanager
+        def phase(name):
+            with real_phase(name):
+                if name == "engine.fetch" and armed["at"] == len(handle._collected):
+                    armed["at"] = None
+                    clock.skip(400)
+                yield
+        monkeypatch.setattr(tracing, "phase", phase)
+    else:                          # ... or in the delivery of one step's token
+        deliver = gw.scheduler.on_token
+
+        def on_token(uid, token, done):
+            if armed["at"] == len(handle._collected):
+                armed["at"] = None
+                clock.skip(400)
+            deliver(uid, token, done)
+        gw.scheduler.on_token = on_token
+    # the warm-up's steps: a delay there (as the compiles themselves are) is no stall
+    armed["at"] = 3
+    pump_until(gw, lambda: len(handle._collected) >= 10)
+    assert armed["at"] is None and gw.snapshot()["counters"]["stalls"] == 0
+    assert stalls_of(engine) == []
+    armed["at"] = 12
+    warned = []
+    monkeypatch.setattr(gateway_logger, "warning", warned.append)
+    pump_until(gw, lambda: handle.done)
+    counters = gw.snapshot()["counters"]
+    assert counters["stalls"] == 1 and abs(counters["stalled_ms"] - 400) < 100
+    (stall,) = stalls_of(engine)
+    record = next(r for r in tracing.snapshot()["steps"] if r["seq"] == stall["seq"])
+    assert (stall["record_kind"], stall["program"], stall["n_seqs"], stall["n_tokens"]) == \
+        (record["kind"], record["program"], 1, 1) == ("put", "8", 1, 1)
+    assert stall["where"] == where
+    assert stall["phase"] == ("ds.engine.fetch" if where == "inside" else "ds.sched.accept")
+    assert abs(stall["excess_ms"] - 400) < 100 and 0 < stall["expected_ms"] < 100
+    assert stall["end_ns"] == record["end_ns"] and stall["end_ns"] - stall["start_ns"] >= 400e6
+    # the pump thread did not compute through it, nothing was compiled or collected for long
+    assert stall["cpu_ms"] < 100 and stall["compiles"] == 0 and stall["waited_ms"] == 0
+    assert stall["gc_ms"] < 100 and stall["compile_ms"] == 0
+    (line,) = warned
+    assert f"{where} put record {stall['seq']}" in line and stall["phase"] in line
+    assert "program 8" in line and "pump thread cpu" in line
+    gw.shutdown()
+
+
+def test_a_gateway_without_requests_waits_and_that_is_no_stall(model_and_params, monkeypatch):
+    clock = SkippingClock(monkeypatch)
+    engine = make_engine(model_and_params, n_seqs=8, batch=16)
+    gw = ServingGateway(engine, config=ServingConfig(token_budget=16, max_burst=1))
+    gw.submit(PROMPT, max_new_tokens=16).result(timeout=120)     # program 8, fifteen times
+    mark = last_seq()
+    clock.skip(500)                                              # half a second with nothing to do
+    gw.submit(PROMPT[:1], max_new_tokens=3).result(timeout=120)  # its first step is program 8 too
+    assert gw.snapshot()["counters"]["stalls"] == 0 and stalls_of(engine) == []
+    first, *rest = [r for r in records_of(engine.trace_id, mark) if r["kind"] == "pump"]
+    assert first["waited_ns"] >= 500e6 and first["idle_passes"] >= 1
+    assert all(r["waited_ns"] == 0 and r["idle_passes"] == 0 for r in rest)
+    served = [r for r in records_of(engine.trace_id) if r["kind"] == "put"]
+    assert sum(r["program"] == "8" for r in served) >= 16
+    gw.drain(timeout=60)

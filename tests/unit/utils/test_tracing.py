@@ -2,9 +2,11 @@
 bounded rings, phases that stamp the record open on the thread, no lock
 on the write path, snapshot()/dump() that read what was written."""
 
+import gc
 import json
 import sys
 import threading
+import time
 
 import pytest
 
@@ -66,7 +68,8 @@ def test_a_phase_outside_any_record_is_a_no_op():
     rec = tracing.Recorder()
     with rec.phase("sched.plan"):
         pass
-    assert rec.current() is None and rec.snapshot() == {"steps": [], "requests": []}
+    assert rec.current() is None
+    assert rec.snapshot() == {"steps": [], "requests": [], "events": []}
 
 
 def test_a_record_is_dropped_when_its_block_raises_or_clears_keep():
@@ -150,3 +153,104 @@ def test_writers_take_no_lock_and_lose_no_record():
     assert all([p[0] for p in r["phases"]] == [f"ds.engine.t{r['engine']}"] for r in snap["steps"])
     assert sorted(q["uid"] for q in snap["requests"]) == [
         (t, i) for t in range(threads) for i in range(each)]
+
+
+# ------------------------------------------- what a record says of its thread's time
+def busy(ms):
+    """Burn CPU on this thread for about ``ms`` milliseconds."""
+    until = time.thread_time_ns() + int(ms * 1e6)
+    while time.thread_time_ns() < until:
+        pass
+
+
+def test_marked_phases_leave_the_threads_cpu_clock_and_the_others_do_not():
+    rec = tracing.Recorder()
+    with rec.step("pump") as pump:
+        with rec.phase("gateway.admit"):
+            busy(1)
+        with rec.step("put") as put:
+            with rec.phase("engine.pack"):
+                busy(3)
+            with rec.phase("engine.dispatch"):
+                pass
+            with rec.phase("engine.fetch"):
+                time.sleep(0.02)                  # waiting costs wall time and no CPU time
+        with rec.phase("sched.accept"):
+            busy(2)
+    inner, outer = rec.snapshot()["steps"]
+    assert all(len(p) == 3 for r in (inner, outer) for p in r["phases"])    # still [name, enter, exit]
+    assert inner["thread"] == outer["thread"] == threading.get_ident()
+    assert [m[0] for m in inner["cpu_marks"]] == ["ds.engine.pack", "ds.engine.fetch"]
+    assert [m[0] for m in outer["cpu_marks"]] == ["ds.sched.accept"]
+    packed, fetched, accepted = inner["cpu_marks"] + outer["cpu_marks"]
+    exits = {name: exit_ for r in (inner, outer) for name, _, exit_ in r["phases"]}
+    assert [m[1] for m in (packed, fetched, accepted)] == [
+        exits["ds.engine.pack"], exits["ds.engine.fetch"], exits["ds.sched.accept"]]
+    # between two marks: the wait for the device is wall time without CPU time, accept is both
+    assert fetched[1] - packed[1] >= 20e6 and 0 <= fetched[2] - packed[2] < 10e6
+    assert 2e6 <= accepted[2] - fetched[2] <= accepted[1] - fetched[1] + 1e6
+    assert put.cpu_marks[0] == tuple(packed) and pump.waited_ns == pump.idle_passes == 0
+    assert {"ds.engine.pack", "ds.engine.fetch", "ds.sched.accept"} <= tracing.CPU_MARKED
+    assert not {"ds.engine.dispatch", "ds.gateway.admit", "ds.sched.plan"} & tracing.CPU_MARKED
+
+
+def events_of(rec, kind, seq):
+    return [e for e in rec.snapshot()["events"] if e["kind"] == kind and e["seq"] == seq]
+
+
+def test_a_collector_pass_inside_a_record_is_counted_and_leaves_an_event():
+    rec = tracing.Recorder()
+    junk = [[i] for i in range(200_000)]                     # a full pass takes over a millisecond
+    with rec.step("pump") as outer:
+        with rec.step("put") as inner:
+            gc.collect()
+    del junk
+    put, pump = rec.snapshot()["steps"]
+    assert put["gc_passes"] >= 1 and put["gc_ns"] > 0
+    # a record holds what happened inside the records it caused, too
+    assert pump["gc_passes"] >= put["gc_passes"] and pump["gc_ns"] >= put["gc_ns"]
+    assert tracing.process_counters()[1] >= pump["gc_passes"]
+    full = [e for e in events_of(rec, "gc", inner.seq) if e["generation"] == 2]
+    assert full and all(e["end_ns"] - e["start_ns"] >= tracing.EVENT_MIN_NS for e in full)
+    assert put["start_ns"] <= full[0]["start_ns"] <= full[0]["end_ns"] <= put["end_ns"]
+    assert "collected" in full[0] and events_of(rec, "gc", outer.seq) == []
+
+
+def test_a_compile_inside_a_record_is_counted_once_and_leaves_an_event():
+    import jax
+    import jax.numpy as jnp
+    rec = tracing.Recorder()
+    fresh = jax.jit(lambda x: x * 3 + len(rec.steps))        # a function nobody has compiled
+    x = jnp.ones(7)
+    with rec.step("put") as first:
+        fresh(x)
+    with rec.step("put") as second:
+        fresh(x)
+    one, two = rec.snapshot()["steps"]
+    assert one["compiles"] >= 1 and one["compile_ns"] > 0
+    assert two["compiles"] == 0 and two["compile_ns"] == 0
+    compiled = [e for e in events_of(rec, "compile", first.seq)
+                if e["name"] == "backend_compile_duration"]
+    assert len(compiled) == one["compiles"] and "lambda" in compiled[-1]["program"]
+    assert compiled[-1]["end_ns"] - compiled[-1]["start_ns"] == int(compiled[-1]["seconds"] * 1e9)
+    assert one["start_ns"] <= compiled[-1]["start_ns"] and compiled[-1]["end_ns"] <= one["end_ns"]
+    assert events_of(rec, "compile", second.seq) == []
+
+
+def test_the_events_ring_is_bounded_and_dumped(tmp_path):
+    rec = tracing.Recorder(event_ring=5)
+    with rec.step("put") as record:
+        for i in range(9):
+            rec.event("stall", i, i + 1, excess_ms=float(i))
+    rec.event("gc", 20, 30, seq=77, generation=2, collected=0)
+    events = rec.snapshot()["events"]
+    assert [e["start_ns"] for e in events] == [5, 6, 7, 8, 20]
+    assert [e["seq"] for e in events] == [record.seq] * 4 + [77]
+    assert tracing.RECORDER.events.maxlen == tracing.EVENT_RING == 1024
+    path = str(tmp_path / "records.jsonl")
+    assert rec.dump(path) == 6
+    with open(path) as f:
+        lines = [json.loads(line) for line in f]
+    assert [line["record"] for line in lines] == ["step"] + ["event"] * 5
+    assert lines[-1] == {"record": "event", **events[-1]}
+    assert (tracing.STALL_NS, tracing.STALL_MIN_RECORDS, tracing.STALL_MEDIAN_OF) == (250_000_000, 8, 32)
