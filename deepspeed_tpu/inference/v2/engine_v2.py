@@ -777,6 +777,7 @@ class InferenceEngineV2:
                     # token batch carries no sharding — only weights/KV do)
                     arrays = jax.device_put(arrays, self._replicated)
                 rec.program, rec.n_seqs, rec.n_tokens = str(bucket), len(batch_uids), total
+                rec.n_rows = bucket
                 # without a scheduler to say which chunks are prompt: rows longer than one
                 rec.n_prompt_tokens = sum(len(t) for t in batch_tokens if len(t) > 1)
             # hot adapter slabs ride as jit ARGUMENTS (not captured constants)
@@ -1125,8 +1126,8 @@ class InferenceEngineV2:
         with nothing in flight: the burst :meth:`decode_burst_async`
         dispatches, fetched and logged before the call returns."""
         k, n = int(k), len(batch_uids)
-        with tracing.step("burst", engine=self.trace_id, program=f"burst{k}", k=k,
-                          n_seqs=n, n_tokens=k * n, uids=tuple(batch_uids)) as rec:
+        with tracing.step("burst", engine=self.trace_id, program=f"burst{k}", k=k, n_seqs=n,
+                          n_tokens=k * n, n_rows=k * self.max_seqs, uids=tuple(batch_uids)) as rec:
             descs, entry_np, out, _, counts = self._dispatch_burst(
                 rec, batch_uids, batch_tokens, k, sample)
             # the fetched form reads its entry row from the host every
@@ -1171,8 +1172,8 @@ class InferenceEngineV2:
         segments (:meth:`TokenLog.append_device`); prefix-cache retire,
         suspend and handoff export fence them lazily."""
         k, n = int(k), len(batch_uids)
-        rec = tracing.begin("burst_async", engine=self.trace_id, program=f"burst{k}", k=k,
-                            n_seqs=n, n_tokens=k * n, uids=tuple(batch_uids))
+        rec = tracing.begin("burst_async", engine=self.trace_id, program=f"burst{k}", k=k, n_seqs=n,
+                            n_tokens=k * n, n_rows=k * self.max_seqs, uids=tuple(batch_uids))
         try:
             descs, entry_np, out, st, counts = self._dispatch_burst(
                 rec, batch_uids, batch_tokens, k, sample, prev)
@@ -1353,6 +1354,7 @@ class InferenceEngineV2:
                     raise err
                 rec.program, rec.n_seqs = f"verify{d}", len(batch_uids)
                 rec.n_tokens = len(batch_uids) * (d + 1)
+                rec.n_rows = self.max_seqs * (d + 1)
                 ms, mb = self.max_seqs, self.max_blocks_per_seq
                 lora_on = self.lora_store is not None
                 toks = np.zeros((ms, d + 1), np.int32)

@@ -113,6 +113,20 @@ def _c_pool(x, n_kv_heads, mesh):
         x, NamedSharding(mesh, kv_pool_spec(mesh, n_kv_heads)))
 
 
+def _live_rows(batch):
+    """The rows of the batch before which every row that is not padding
+    lies: one past the last row with a sequence (``token_seq < max_seqs``).
+    The engine packs live rows first, so it is their count - a ``put``'s
+    ``num_tokens``, a burst's sequences, a verify program's ``(d + 1)`` a
+    sequence - and a paged kernel told it does no work for the rows from
+    there on. Once a step (:func:`ragged_forward` keeps it in the batch)."""
+    if "live_rows" in batch:
+        return batch["live_rows"]
+    seq = batch["token_seq"]
+    rows = jnp.arange(1, seq.shape[0] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(seq < batch["block_tables"].shape[0] - 1, rows, 0))
+
+
 def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl=None):
     """Scatter layer ``layer``'s new K/V rows into the paged pool (one
     scatter into the whole pool, which comes back as the same buffers)
@@ -138,7 +152,7 @@ def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl
                                      override=impl.override if impl else None)
     if impl is not None:
         impl.selected[q.shape[0]] = name
-    out = attn_fn(q, kc, vc, tab, pos, layer)
+    out = attn_fn(q, kc, vc, tab, pos, layer, _live_rows(batch))
     return _c(out, (None, "tensor", None), mesh), kc, vc
 
 
@@ -486,7 +500,7 @@ def _latent_stack(kind, params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_
     tab, bs = batch["block_tables"][batch["token_seq"]], kc.shape[2]
     n, _ = pm.mla_tile(bs, (kc.shape[3] + vc.shape[3]) * kc.dtype.itemsize, kc.dtype.itemsize,
                        tab.shape[1], cfg.num_attention_heads)
-    named, fetched = pm.fetch_counts(tab, batch["token_pos"], bs, n)
+    named, fetched = pm.fetch_counts(tab, batch["token_pos"], bs, n, _live_rows(batch))
     if attn_impl is None or attn_impl.selected.get(tab.shape[0]) != "pallas_paged_mla":
         fetched = named
     fetch = jnp.stack([named, fetched])
@@ -929,7 +943,9 @@ def _sala_sparse_mixer(ctx, a, layer, x, kc, vc, kb, attn_impl):
                                          override=attn_impl.override if attn_impl else None)
         if attn_impl is not None:
             attn_impl.selected[T] = name
-        o = attn_fn(qv, flat_k, flat_v, tab, at, jnp.int32(0), selected=True).reshape(T, H * d)
+        # selected_tables lays the (token, head) rows token-major
+        o = attn_fn(qv, flat_k, flat_v, tab, at, jnp.int32(0), _live_rows(batch) * Hkv,
+                    selected=True).reshape(T, H * d)
     o = o * jax.nn.sigmoid(_proj(x, a["o_gate_proj"]))
     return _proj(o, a["o_proj"]), kc, vc, kb, tables
 
@@ -990,7 +1006,7 @@ def _latent_attend(q_lat, q_rope, c_kv, k_rope, kc, vc, layer, batch, scale, imp
                                      state_kind="latent")
     if impl is not None:
         impl.selected[T] = name
-    return attn_fn(q, kc, vc, tab, batch["token_pos"], layer), kc, vc
+    return attn_fn(q, kc, vc, tab, batch["token_pos"], layer, _live_rows(batch)), kc, vc
 
 
 def _swiglu(x, p):
@@ -1183,6 +1199,7 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     ``attn_impl``: the engine's ``heuristics.AttentionChoice`` (None =
     unpinned and unreported)."""
     kind = kind_of(cfg)
+    batch = dict(batch, live_rows=_live_rows(batch))
     embed = params["model"]["embed_tokens"]
     h = _c(embed[batch["token_ids"]].astype(dtype), (None, None), mesh)  # [T, D]
     mult = getattr(cfg, "embedding_multiplier", 1.0)

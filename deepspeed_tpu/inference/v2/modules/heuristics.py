@@ -122,7 +122,7 @@ class _PallasPagedSharded:
         cls = _PallasPagedSharded
         return shard_map_kernel(
             paged_decode_attention, mesh,
-            in_specs=(cls.Q_SPEC, cls.KV_SPEC, cls.KV_SPEC, P(), P(), P()),
+            in_specs=(cls.Q_SPEC, cls.KV_SPEC, cls.KV_SPEC, P(), P(), P(), P()),
             out_specs=cls.Q_SPEC)
 
 
@@ -176,7 +176,7 @@ class _XlaGatherMLA:
 
 def instantiate_attn(mesh, head_dim, block_size, q_shape, kc_shape, alibi,
                      max_blocks, override=None, state_kind="kv"):
-    """→ ``(impl_name, fn(q, kc, vc, tab, pos, layer))`` — the first supported
+    """→ ``(impl_name, fn(q, kc, vc, tab, pos, layer, live_rows))`` — the first supported
     implementation in registration (priority) order, or the named one
     when the config pins ``override`` (reference
     heuristics.instantiate_attn + config_bundle semantics). A pin that

@@ -29,8 +29,21 @@ token's last tile, the **next token's first tile** (grid steps run in
 order on one core; every token's table and position are in SMEM from the
 start), so that no token waits for a fetch it could have had. A token
 whose whole context is the one block the token before it had as its own
-(a run of padding rows on the null block; a prompt's first tokens) finds
-it in that token's slot and fetches nothing.
+(a prompt's first tokens; padding rows among the live ones) finds it in
+that token's slot and fetches nothing.
+
+**Live rows.** A program's width is static and its batch is not: the
+engine packs the rows that hold a token first and pads the rest (table on
+the null block, position 0). ``live_rows`` says where the padding starts,
+and the grid ends there - a dynamic bound, so the rows from there on cost
+no grid step, no copy and no arithmetic; they are written as zeros after
+the call (their hidden state still goes through the layers behind
+attention, and must be finite whatever the null block holds). A padding
+row among the live ones is a row like any other. On the chip a padding
+row costs 0.02 us here and 0.11 us in the latent kernel, the zeroing
+alone, where it cost 0.58 and 0.85 us (``tools/kernel_census.py --live``;
+PERF.md, PR 37); an early-out a grid step (``pl.when`` around the body)
+read 0.22 and 0.42 and was not kept.
 
 **Stale rows.** A tile's blocks past the context's last are not fetched,
 so their rows are whatever the slot held. Keys: the scores at positions
@@ -98,16 +111,16 @@ TILE_VMEM_BYTES = 4 << 20
 SELECTED_SLOT_BYTES = 512 << 10
 
 
-def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, alibi_slopes=None,
-                        selected=False):
+def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None,
+                        alibi_slopes=None, selected=False):
     """Reference math. q: [T, H, Dh]; kc/vc: the pool [L, NB, bs, Hkv*Dh];
     block_tables: [T, MB] (per TOKEN, already indexed by its sequence);
     token_pos: [T]; layer: int32 scalar, the layer of the pool to read.
     → [T, H, Dh]; attends to positions <= token_pos.
     ``alibi_slopes``: optional [H] — adds the Bloom-style linear
     relative-position penalty slope_h * (k_pos - q_pos) to the scores.
-    ``selected``: the kernel's (its tile); the gather reads the same rows
-    either way."""
+    ``live_rows``, ``selected``: the kernel's (where its grid ends; its
+    tile); the gather computes every row and reads the same rows either way."""
     T, H, Dh = q.shape
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     gather_bytes = 2 * T * block_tables.shape[1] * bs * Hkv * Dh * kc.dtype.itemsize
@@ -203,6 +216,21 @@ def tile_blocks(block_size, row_bytes, itemsize, max_blocks, slot_bytes=0):
     while n > 1 and 4 * n * block_size * row_bytes > TILE_VMEM_BYTES:
         n //= 2
     return min(n, max_blocks)
+
+
+def live_grid(T, live_rows):
+    """→ (``live_rows`` as int32, ``T`` for None; the grid that ends
+    there). A call with no live row still runs row 0: an empty grid is not
+    asked of Mosaic."""
+    live_rows = jnp.asarray(T if live_rows is None else live_rows, jnp.int32)
+    return live_rows, (jnp.maximum(live_rows, 1),)
+
+
+def zeros_past(out, live_rows):
+    """``out`` [T, ...] with zeros in the rows from ``live_rows`` on, which
+    the grid did not reach and nothing wrote."""
+    return jnp.where(jnp.arange(out.shape[0])[:, None, None] < live_rows, out,
+                     jnp.zeros((), out.dtype))
 
 
 def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
@@ -312,17 +340,19 @@ def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))
-def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret):
+def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows=None):
     """The kernel at ``n`` blocks a tile (``tools/kernel_census.py``
     sweeps it; everything else gets :func:`tile_blocks`'). Jitted so
     that the serving programs of one shape (23 a cell lower the kernel in
-    their layer body) share one trace of it."""
+    their layer body) share one trace of it. ``live_rows`` (None: every
+    row) is where the grid ends; the module docstring says why."""
     T, H, Dh = q.shape
+    live_rows, grid = live_grid(T, live_rows)
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     MB = block_tables.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # tables, positions, layer
-        grid=(T,),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, H, Dh), lambda t, tab, pos, layer: (t, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -341,7 +371,7 @@ def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret):
     native = q.dtype == kc.dtype == vc.dtype and kc.dtype.itemsize == 2
     kernel = functools.partial(_kernel, bs=bs, n=n, max_blocks=MB, groups=H // Hkv,
                                n_kv_heads=Hkv, native=native)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, H, Dh), q.dtype),
@@ -351,11 +381,13 @@ def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret):
         name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), token_pos.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q, kc, vc)
+    return zeros_past(out, live_rows)
 
 
-def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=None,
-                           selected=False):
-    """Pallas path of :func:`xla_paged_attention` (same contract).
+def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None,
+                           interpret=None, selected=False):
+    """Pallas path of :func:`xla_paged_attention` (same contract on the
+    rows before ``live_rows``, zeros from there on; None: every row).
     ``selected``: the table is a selection (:func:`selected_tables`) over
     a pool of one KV head a pool layer, whose 256 rows of 256 bytes are
     64 KB a slot: a turn's fixed cost (2n copies started and waited for,
@@ -382,4 +414,4 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, interpret=
                 f"max_context, or raise kv_block_size")
     n = tile_blocks(bs, kc.shape[3] * kc.dtype.itemsize, kc.dtype.itemsize, MB,
                     SELECTED_SLOT_BYTES if selected else 0)
-    return _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret)
+    return _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows)

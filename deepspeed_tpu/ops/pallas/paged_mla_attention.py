@@ -38,11 +38,13 @@ at 64 heads is its arithmetic; PERF.md, PR 35) and that part was left out.
 into the other slot, and after a token's last tile the **next token's
 first tile**. **Reuse**: where the token before had one tile and every
 block the two first tiles share is the same block (:func:`fetch_plan`: a
-run of padding rows on the null block, a prompt chunk's consecutive
-tokens), the next token's first tile **stays** in that slot and only the
-blocks it lacks are fetched - started after this token's wait (a slot has
-one semaphore a pool) into rows this token's scores mask. A chunk's rows
-are in the pool before the call, so a held block is the block.
+prompt chunk's consecutive tokens), the next token's first tile **stays**
+in that slot and only the blocks it lacks are fetched - started after this
+token's wait (a slot has one semaphore a pool) into rows this token's
+scores mask. A chunk's rows are in the pool before the call, so a held
+block is the block. **Live rows**: the grid ends where the call's padding
+rows start (``live_rows``), and those rows are zeros:
+``paged_attention``'s paragraph of that name.
 
 **Stale rows**: ``c`` is the value operand too, so its slots are zeroed in
 the first grid step and scores past the position are replaced;
@@ -65,14 +67,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.paged_attention import (GATHER_LIMIT_BYTES, NEG_INF,
-                                                      SMEM_TABLE_BYTES, smem_table_fits)
+                                                      SMEM_TABLE_BYTES, live_grid,
+                                                      smem_table_fits, zeros_past)
 
 
-def xla_paged_mla_attention(q, c_pool, r_pool, block_tables, token_pos, layer):
+def xla_paged_mla_attention(q, c_pool, r_pool, block_tables, token_pos, layer, live_rows=None):
     """Reference math by gather. q [T, H, rank + lanes], scaled;
     c_pool [L, NB, bs, rank]; r_pool [L, NB, bs, lanes]; block_tables
     [T, MB] (per token); token_pos [T]; layer int32 scalar →
-    [T, H, rank]; attends to positions <= token_pos."""
+    [T, H, rank]; attends to positions <= token_pos. ``live_rows`` is the
+    kernel's: the gather computes every row."""
     T, H, _ = q.shape
     bs, rank = c_pool.shape[2], c_pool.shape[3]
     gather_bytes = T * block_tables.shape[1] * bs * q.shape[2] * c_pool.dtype.itemsize
@@ -142,17 +146,18 @@ def mla_tile(block_size, row_bytes, itemsize, max_blocks, heads):
     return n, MLA_UNIT_ROWS if fits else rows
 
 
-def fetch_plan(block_tables, token_pos, block_size, n):
+def fetch_plan(block_tables, token_pos, block_size, n, live_rows=None):
     """The kernel's fetch rule as one pure function of the call's tables
     and positions (``_kernel``'s ``plan`` states it a token at a time on
     the same integers). A token's first tile **stays** in the slot of the
     token before it where that token had one tile and every block the two
     first tiles share is the same block; it is then fetched from the block
     that token held up to (nothing, where it reaches no further), else
-    whole into the other slot. Every later tile is fetched whole. → per
-    token, int32 [T]: the blocks its context names (``pos // block_size +
-    1``, never more than the table has) and those of them a copy is
-    started for."""
+    whole into the other slot. Every later tile is fetched whole. A row
+    from ``live_rows`` on (None: no such row) is beyond the grid: it names
+    its one block and nothing is fetched for it. → per token, int32 [T]:
+    the blocks its context names (``pos // block_size + 1``, never more
+    than the table has) and those of them a copy is started for."""
     T, MB = block_tables.shape
     block_tables, token_pos = block_tables.astype(jnp.int32), token_pos.astype(jnp.int32)
     named = jnp.minimum(token_pos // block_size + 1, MB)
@@ -162,14 +167,17 @@ def fetch_plan(block_tables, token_pos, block_size, n):
     same = ((block_tables[:, :cols] == jnp.roll(block_tables, 1, axis=0)[:, :cols])
             | (jnp.arange(cols)[None, :] >= jnp.minimum(held, before_held)[:, None]))
     stays = (jnp.arange(T) > 0) & (jnp.roll(named, 1) <= n) & jnp.all(same, axis=1)
-    return named, named - jnp.minimum(jnp.where(stays, before_held, 0), held)
+    fetched = named - jnp.minimum(jnp.where(stays, before_held, 0), held)
+    if live_rows is not None:
+        fetched = jnp.where(jnp.arange(T) < jnp.maximum(live_rows, 1), fetched, 0)
+    return named, fetched
 
 
-def fetch_counts(block_tables, token_pos, block_size, n):
+def fetch_counts(block_tables, token_pos, block_size, n, live_rows=None):
     """:func:`fetch_plan` summed over the call's tokens → int32 (blocks
     named, blocks fetched): what a step record's ``n_blocks_named`` and
     ``n_blocks_fetched`` are of."""
-    named, fetched = fetch_plan(block_tables, token_pos, block_size, n)
+    named, fetched = fetch_plan(block_tables, token_pos, block_size, n, live_rows)
     return jnp.sum(named).astype(jnp.int32), jnp.sum(fetched).astype(jnp.int32)
 
 
@@ -303,13 +311,15 @@ def _kernel(tab_ref, pos_ref, layer_ref, q_ref, c_hbm, r_hbm, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("n", "unit", "interpret", "ahead", "reuse"))
 def _mla_call(q, c_pool, r_pool, block_tables, token_pos, layer, n, unit, interpret,
-              ahead=True, reuse=True):
+              ahead=True, reuse=True, live_rows=None):
     """The kernel at ``n`` blocks a tile whose pair of matmuls grows by
     ``unit`` rows (``tools/kernel_census.py --mla`` sweeps them and
     switches ``ahead`` and ``reuse`` off; everything else gets
     :func:`mla_tile`'s). Jitted so that the serving programs of one
-    shape share one trace of it."""
+    shape share one trace of it. ``live_rows`` (None: every row) is where
+    the grid ends."""
     T, H, width = q.shape
+    live_rows, grid = live_grid(T, live_rows)
     bs, rank, lanes = c_pool.shape[2], c_pool.shape[3], r_pool.shape[3]
     MB = block_tables.shape[1]
     if n * bs // unit > MLA_WIDTHS:
@@ -317,7 +327,7 @@ def _mla_call(q, c_pool, r_pool, block_tables, token_pos, layer, n, unit, interp
                          f"widths: Mosaic's layout inference fails on so deep a chain of branches")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # tables, positions, layer
-        grid=(T,),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, H, width), lambda t, tab, pos, layer: (t, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -333,7 +343,7 @@ def _mla_call(q, c_pool, r_pool, block_tables, token_pos, layer, n, unit, interp
     )
     kernel = functools.partial(_kernel, bs=bs, n=n, unit=unit, max_blocks=MB, rank=rank,
                                ahead=ahead, reuse=reuse)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, H, rank), q.dtype),
@@ -343,11 +353,13 @@ def _mla_call(q, c_pool, r_pool, block_tables, token_pos, layer, n, unit, interp
         name="paged_mla_decode_attention",
     )(block_tables.astype(jnp.int32), token_pos.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q, c_pool, r_pool)
+    return zeros_past(out, live_rows)
 
 
 def paged_mla_decode_attention(q, c_pool, r_pool, block_tables, token_pos, layer,
-                               interpret=None):
-    """Pallas path of :func:`xla_paged_mla_attention` (same contract)."""
+                               live_rows=None, interpret=None):
+    """Pallas path of :func:`xla_paged_mla_attention` (same contract on
+    the rows before ``live_rows``, zeros from there on; None: every row)."""
     if interpret is None:
         from deepspeed_tpu.ops.pallas import default_interpret
         interpret = default_interpret()
@@ -367,4 +379,5 @@ def paged_mla_decode_attention(q, c_pool, r_pool, block_tables, token_pos, layer
                 f"{SMEM_TABLE_BYTES >> 10} KB SMEM budget — shrink max_ragged_batch_size / "
                 f"max_context, or raise kv_block_size")
     n, unit = mla_tile(bs, width * c_pool.dtype.itemsize, c_pool.dtype.itemsize, MB, H)
-    return _mla_call(q, c_pool, r_pool, block_tables, token_pos, layer, n, unit, interpret)
+    return _mla_call(q, c_pool, r_pool, block_tables, token_pos, layer, n, unit, interpret,
+                     live_rows=live_rows)

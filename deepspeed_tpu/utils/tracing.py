@@ -88,7 +88,7 @@ CPU_MARKED = frozenset(PREFIX + name for name in (
     "engine.pack", "engine.fetch", "sched.accept",
     "train.prepare", "train.dispatch", "train.sync", "train.post"))
 
-STEP_FIELDS = ("seq", "engine", "kind", "program", "k", "n_seqs", "n_tokens",
+STEP_FIELDS = ("seq", "engine", "kind", "program", "k", "n_seqs", "n_tokens", "n_rows",
                "n_prompt_tokens", "n_ctx_tokens", "counts", "caused_by", "uids", "start_ns",
                "end_ns", "thread", "gc_ns", "gc_passes", "compile_ns", "compiles",
                "waited_ns", "idle_passes")
@@ -241,11 +241,14 @@ class Recorder:
 
     # ------------------------------------------------------------- step records
     def begin(self, kind, engine=0, program="", k=1, n_seqs=0, n_tokens=0,
-              n_prompt_tokens=0, uids=()):
+              n_prompt_tokens=0, uids=(), n_rows=0):
         rec = StepRecord()
         rec.seq = next(self._seq)
         rec.engine, rec.kind, rec.program, rec.k = engine, kind, program, k
         rec.n_seqs, rec.n_tokens, rec.n_prompt_tokens = n_seqs, n_tokens, n_prompt_tokens
+        # the rows the program ran, padding included, over its k steps: of them n_tokens
+        # held a token (the engine sets it: a put's bucket, a burst's k x max_seqs)
+        rec.n_rows = n_rows
         rec.n_ctx_tokens = 0    # the engine adds each row's attended context as it packs
         # what the program itself counted on the device, by name, fetched with its result
         # (model_runner: kind.step_counts); None where the model kind counts nothing

@@ -125,7 +125,7 @@ def test_tile_and_unit_follow_from_the_shapes(block_size, itemsize, columns, hea
                      jnp.zeros((1, 12), jnp.int32), jnp.zeros(1, jnp.int32), 0, 9, 16, True)
 
 
-def started_copies(monkeypatch, tabs, pos, n):
+def started_copies(monkeypatch, tabs, pos, n, live_rows=None):
     """The copies the kernel starts for these rows, counted where each is
     started (a callback under the very ``start``)."""
     counted = []
@@ -147,7 +147,8 @@ def started_copies(monkeypatch, tabs, pos, n):
     c, r = pools(tabs, jnp.float32, rng, layers=1)
     q = jnp.asarray(rng.standard_normal((len(pos), 2, RANK + LANES)) * 0.1, jnp.float32)
     pm._mla_call.clear_cache()
-    got = pm._mla_call(q, c, r, jnp.asarray(tabs), jnp.asarray(pos), 0, n, n * BS, True)
+    got = pm._mla_call(q, c, r, jnp.asarray(tabs), jnp.asarray(pos), 0, n, n * BS, True,
+                       live_rows=live_rows)
     jax.block_until_ready(got)
     jax.effects_barrier()
     pm._mla_call.clear_cache()      # no later call finds the counting trace
@@ -167,6 +168,24 @@ def test_the_fetch_rule_is_the_copies_the_kernel_starts(monkeypatch, scene, n):
     assert tuple(int(x) for x in totals) == (named.sum(), fetched.sum())
     assert (fetched <= named).all()
     assert (fetched.sum() < named.sum()) == (scene != "edges")    # no two of its rows share a block
+
+
+@pytest.mark.parametrize("scene,live_rows", [("padding", 10), ("padding", 0), ("chunk", 52),
+                                             ("chunk", 30), ("narrow", 0)])
+def test_the_fetch_rule_knows_where_the_grid_ends(monkeypatch, scene, live_rows):
+    """Told the live rows, the kernel starts no copy for a row from there
+    on (but for row 0, which an empty call still runs), and the rule says
+    the same; padding rows before that are rows like any other."""
+    rng = np.random.default_rng(10)
+    spec, max_blocks = SCENES[scene]
+    tabs, pos = rows_of(spec, max_blocks, rng)
+    tabs_d, pos_d = jnp.asarray(tabs), jnp.asarray(pos)
+    named, fetched = (np.asarray(x) for x in pm.fetch_plan(tabs_d, pos_d, BS, 2, jnp.int32(live_rows)))
+    whole = np.asarray(pm.fetch_plan(tabs_d, pos_d, BS, 2)[1])
+    assert started_copies(monkeypatch, tabs, pos, 2, jnp.int32(live_rows)) == 2 * fetched.sum()
+    reached = max(live_rows, 1)
+    np.testing.assert_array_equal(fetched[:reached], whole[:reached])
+    assert fetched[reached:].sum() == 0 and named.sum() == np.minimum(pos // BS + 1, max_blocks).sum()
 
 
 def test_what_the_rule_saves_and_what_it_does_not():

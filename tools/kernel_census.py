@@ -47,6 +47,14 @@ tile early, reuse, a tile of blocks), then ``n`` and the unit swept around
 least bytes (the roofline's: a sequence's context once) and in the bytes the
 call's copies move. ``mla_tile`` rests on this table (PERF.md, PR 35).
 
+``--live 0.25,0.5,1.0`` times both paged kernels at those shares of a
+program's rows live (the rest padding on the null block, as the engine packs
+them: live rows first), at chat's 128-row and Mixtral's 512-row programs and
+``longcat-flash-topics``' 256- and 512-row ones: ms a call, the same live rows
+alone in a call as wide as they are, and from the two **us a padding row a
+layer beside us a live row** - for the kernel, which is told the live rows, and
+for the parent checkout's, which is not (PERF.md, PR 37).
+
 Prints one JSON line per kernel and writes ``chiprun_out/kernel_census.json``.
 """
 
@@ -421,6 +429,97 @@ def paged_mla_classes(parent_dir):
         yield name, record
 
 
+# (name, kernel, rows, heads, table columns, (least, most) context of a live row)
+LIVE_CLASSES = (("chat-decode-128", "kv", 128, 32, 96, (100, 600)),
+                ("batch-mixed-512", "kv", 512, 32, 96, (128, 1536)),
+                ("topics-decode-256", "mla", 256, 64, 6, (128, 1536)),
+                ("topics-mixed-512", "mla", 512, 64, 6, (128, 1536)))
+LIVE_LAYERS = 32
+
+
+def live_rows_sweep(parent_dir, shares):
+    """Yields one record a class of ``LIVE_CLASSES``: at each share of the
+    rows live, the call's ms, the ms of the live rows alone (a call of
+    their own width) and the difference a padding row; the parent
+    checkout's kernel, which runs every row, beside it."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas import paged_mla_attention as pm
+
+    rng = np.random.default_rng(37)
+    L = 4
+    pools = {}
+
+    def pool(NB, bs, width, key):
+        if (NB, bs, width, key) not in pools:
+            pools[NB, bs, width, key] = jax.jit(lambda k: jax.random.normal(
+                k, (L, NB, bs, width), jnp.bfloat16))(jax.random.PRNGKey(key))
+        return pools[NB, bs, width, key]
+
+    for name, kernel, T, H, MB, ctx in LIVE_CLASSES:
+        if kernel == "kv":
+            bs, width, NB = 16, HEAD_DIM, 8192
+            mine, theirs = pa, _parent_kernel(parent_dir, "paged_attention")
+            a, b = pool(NB, bs, 8 * HEAD_DIM, 1), pool(NB, bs, 8 * HEAD_DIM, 2)
+            entry = "paged_decode_attention"
+        else:
+            bs, width, NB = 256, 512 + 128, 2048
+            mine, theirs = pm, _parent_kernel(parent_dir, "paged_mla_attention")
+            a, b = pool(NB, bs, 512, 1), pool(NB, bs, 128, 2)
+            entry = "paged_mla_decode_attention"
+        pos_all = np.exp(rng.uniform(np.log(ctx[0]), np.log(ctx[1]), T)).astype(np.int32) - 1
+        tabs_all = np.zeros((T, MB), np.int32)
+        for t in range(T):      # a block may serve two rows: the reads are what is timed
+            need = pos_all[t] // bs + 1
+            tabs_all[t, :need] = rng.integers(1, NB, need)
+        q = jnp.asarray(rng.standard_normal((T, H, width), np.float32) * 0.1, jnp.bfloat16)
+        record = {"rows": T, "heads": H, "table_columns": MB}
+
+        def ms(fn, q, a, b, tabs, pos, *live):
+            """ms a call of LIVE_LAYERS calls in one program, a layer of the
+            pool each, as a model's scan makes them: a call of 0.1 ms alone
+            in a program is timed by its launch."""
+            try:
+                def layers(q, a, b, tabs, pos, *live):
+                    first = fn(q, a, b, tabs, pos, jnp.int32(0), *live)
+                    return jax.lax.fori_loop(1, LIVE_LAYERS, lambda i, _: fn(
+                        q, a, b, tabs, pos, i % L, *live), first)
+                call = jax.jit(layers)
+                if not mosaic_kernels(call.lower(q, a, b, tabs, pos, *live)):
+                    raise RuntimeError("no Mosaic kernel in the lowered program")
+                return _ms_a_call(call, q, a, b, tabs, pos, *live, calls=10) / LIVE_LAYERS
+            except Exception as e:  # a refusal is a record too
+                return f"{type(e).__name__}: {e}"[:600]
+
+        def per_row(whole, alone, live):
+            if isinstance(whole, str) or isinstance(alone, str):
+                return {"ms": whole, "live_alone_ms": alone}
+            out = {"ms": whole, "live_alone_ms": alone, "us_a_live_row": 1e3 * alone / live}
+            if live < T:
+                out["us_a_padding_row"] = 1e3 * (whole - alone) / (T - live)
+            return out
+
+        for share in shares:
+            live = max(1, int(round(share * T)))
+            tabs, pos = tabs_all.copy(), pos_all.copy()
+            tabs[live:], pos[live:] = 0, 0
+            tabs_d, pos_d, n_live = jnp.asarray(tabs), jnp.asarray(pos), jnp.int32(live)
+            got = {"live_rows": live}
+            alone = (q[:live], a, b, tabs_d[:live], pos_d[:live])
+            if theirs is not None:
+                run = lambda *x: getattr(theirs, entry)(*x, interpret=False)
+                got["parent"] = per_row(ms(run, q, a, b, tabs_d, pos_d), ms(run, *alone), live)
+            run = lambda *x: getattr(mine, entry)(*x, interpret=False)
+            got["kernel"] = per_row(ms(run, q, a, b, tabs_d, pos_d, n_live),
+                                    ms(run, *alone, n_live), live)
+            record[f"live={share}"] = got
+        yield name, record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -449,7 +548,11 @@ def main():
     paged, mla = "--paged" in sys.argv, "--mla" in sys.argv
     parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
-    if paged:
+    live = "--live" in sys.argv
+    if live:
+        shares = [float(x) for x in sys.argv[sys.argv.index("--live") + 1].split(",")]
+        section, records = "live_rows", live_rows_sweep(parent_dir, shares)
+    elif paged:
         section, records = "paged_attention", paged_attention_classes(parent_dir)
     elif mla:
         section, records = "paged_mla_attention", paged_mla_classes(parent_dir)
@@ -458,7 +561,7 @@ def main():
     for name, record in records:
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
-    for name, fn, ref, args, tol in (() if paged or mla or "--gmm-only" in sys.argv
+    for name, fn, ref, args, tol in (() if live or paged or mla or "--gmm-only" in sys.argv
                                      else cases()):
         try:
             result = verdict(fn, ref, args, tol)
@@ -467,7 +570,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = "paged_census.json" if paged else "mla_census.json" if mla else "kernel_census.json"
+    out = ("live_census.json" if live else "paged_census.json" if paged
+           else "mla_census.json" if mla else "kernel_census.json")
     with open(os.path.join("chiprun_out", out), "w") as f:
         json.dump(report, f, indent=1)
 
