@@ -274,8 +274,10 @@ class InferenceEngineV2:
         if hasattr(kind, "extra_state"):
             slots = int(sm.max_tracked_sequences)
             self.state_extra = kind.extra_state(cfg, num_blocks, slots, dtype)
-            per_slot = self.state_extra["slots"]
-            self.slot_pool = SlotPool(slots, per_slot.nbytes // per_slot.shape[1])
+            # a slot is one row of each entry the kind names (their second axis)
+            self.slot_pool = SlotPool(slots, sum(
+                self.state_extra[name].nbytes // self.state_extra[name].shape[1]
+                for name in kind.slot_state))
         # Radix prefix cache (cross-request KV reuse): config-gated with
         # the DS_PREFIX_CACHE env kill switch. When live, retired
         # sequences' full blocks become content-addressable and new
@@ -540,10 +542,12 @@ class InferenceEngineV2:
 
     # ------------------------------------------------------------------
     def _refuse_unsupported(self, kind, n_devices):
-        """A model kind whose state is not keys and values is served by
-        the core path only: every optional subsystem that reads, moves or
-        shards the two KV pools refuses it here, at construction and by
-        name, instead of failing inside a program."""
+        """A model kind whose state is not keys and values alone (a latent
+        row, a selection's pooled keys, a slot of state that is not paged
+        rows at all) is served by the core path only: every optional
+        subsystem that reads, moves or shards the two KV pools refuses it
+        here, at construction and by name, instead of failing inside a
+        program."""
         from deepspeed_tpu.inference.v2.kv_tier import kv_tier_enabled
         from deepspeed_tpu.inference.v2.prefix_cache import prefix_cache_enabled
         from deepspeed_tpu.inference.v2.spec import spec_decode_enabled
@@ -561,7 +565,7 @@ class InferenceEngineV2:
         for subsystem, on in asked.items():
             if on:
                 raise NotImplementedError(
-                    f"{subsystem} does not support the {kind.state_kind!r} paged state of "
+                    f"{subsystem} does not support the {kind.state_kind!r} state of "
                     f"model kind {kind.name!r} ({type(self.model_config).__name__}); "
                     f"turn it off to serve this model")
 
@@ -1727,7 +1731,7 @@ class InferenceEngineV2:
         tracked until :meth:`resume`."""
         if self.state_kind != "kv":
             raise NotImplementedError(
-                f"suspend/resume export does not support the {self.state_kind!r} paged state "
+                f"suspend/resume export does not support the {self.state_kind!r} state "
                 f"of model kind {self.kind.name!r}")
         desc = self.state_manager.query(uid)
         if desc is None:

@@ -23,16 +23,18 @@ consumes the padded flat batch —
   serves directly;
 - what differs between model families sits in one object each
   (:class:`LlamaKind`, :class:`GPTKind`, :class:`MoonlightKind`,
-  :class:`LongcatKind`, :class:`SalaKind`; :func:`kind_of` picks by the
-  config's type): the state the pool holds and how many layers of it,
-  the layer step, the layer pattern (leading layers, then the scan — or,
-  for a stack of two kinds of layer, :meth:`SalaKind.stack`), what state
-  it keeps beyond the two paged pools, what a step counts on the device,
-  and the final norm. :func:`ragged_forward` is the same for all.
+  :class:`LongcatKind`, :class:`SalaKind`, :class:`NemotronHKind`;
+  :func:`kind_of` picks by the config's type): the state the pool holds
+  and how many layers of it, the layer step, the layer pattern (leading
+  layers, then the scan — or, for a stack of several kinds of layer,
+  :meth:`SalaKind.stack` and :meth:`NemotronHKind.stack`), what state it
+  keeps beyond the two paged pools, what a step counts on the device, and
+  the final norm. :func:`ragged_forward` is the same for all.
 """
 
 import functools
 import math
+from typing import NamedTuple
 
 import numpy as np
 
@@ -42,6 +44,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.llama import LlamaConfig, rope_frequencies, rope_scaling_of
+from deepspeed_tpu.models.nemotron_h import relu2
 
 
 def _c(x, entries, mesh):
@@ -641,6 +644,7 @@ class SalaKind:
     lora = False
     step_counts = ("n_blocks_selected", "n_blocks_context", "n_linear_rows")
     seq_rows = 2            # per-sequence rows of the batch: (slot, sparse_from)
+    slot_state = ("slots",)  # the entries of extra_state a slot is a row of
 
     @staticmethod
     def state_layers(cfg):
@@ -767,14 +771,75 @@ def _sala_mlp(cfg, lp, h):
     return h + jnp.asarray(cfg.residual_scale, h.dtype) * _swiglu(x, lp["mlp"])
 
 
+def _row_spans(seq, pos, S):
+    """Where each of the ``S`` sequence rows' tokens lie in a step. ``seq``
+    / ``pos`` [T]: each row's sequence row (padding's is the last) and
+    position. → (first, length) [S]: a sequence row's first position and
+    its number of rows in this step; a row with no token (and padding's,
+    at position 0) is zero rows long and starts at 0, as a sequence's
+    first rows do - where the state it carried is taken as zero whatever
+    its slot held."""
+    first = jnp.full((S,), jnp.iinfo(jnp.int32).max, jnp.int32).at[seq].min(pos)
+    last = jnp.zeros((S,), jnp.int32).at[seq].max(pos)
+    present = first <= last
+    return jnp.where(present, first, 0), jnp.where(present, last - first + 1, 0)
+
+
+class _PackedRows(NamedTuple):
+    """What :func:`_packed_rows` gives; see there."""
+    since: jax.Array
+    until: jax.Array
+    whole: jax.Array
+    weights: jax.Array
+
+
+def _packed_rows(seq, pos, S, log_decay):
+    """The decays of a recurrence over the flat ragged batch, for any
+    recurrence ``S_t = a_t S_{t-1} + (its own term)`` whose state a
+    sequence carries from step to step in a slot: SALA's linear layers
+    (a constant decay a head) and Mamba-2 (a decay a token and head).
+
+    ``seq`` / ``pos`` [T]: each row's sequence row (of ``S``; padding's is
+    the last) and position; ``log_decay`` [T, H] float32, ``log a_t <= 0``
+    of each row. Rows of one sequence (a prompt chunk, or one decode row)
+    are taken by a same-sequence-and-causal mask, so nothing here asks how
+    the rows are ordered. →
+
+    - ``since`` [T, H]: the sum of the log-decays from the sequence's
+      first row of this step up to and with row t: ``exp`` of it is what
+      the carried state has decayed by when row t reads it;
+    - ``until`` [T, H]: the sum over the sequence's rows after t: what row
+      t's own term has decayed by in the state the step leaves;
+    - ``whole`` [S, H]: the sum over all of a sequence's rows;
+    - ``weights`` [H, T, T]: ``exp`` of the sum over the rows after u up to
+      and with t, for u <= t of one sequence; 0 elsewhere.
+
+    Every exponent is a sum of terms <= 0. The sums are one product of
+    the mask with the log-decays at the highest precision (the default
+    would round the summands to bfloat16), so a constant log-decay gives
+    ``log lambda * (a distance)`` to float32's rounding."""
+    f32 = jnp.float32
+    apart = pos[:, None] - pos[None, :]
+    mask = (seq[:, None] == seq[None, :]) & (apart >= 0)                 # [t, u]: u <= t
+    highest = jax.lax.Precision.HIGHEST
+    since = jnp.dot(mask.astype(f32), log_decay, precision=highest)
+    of_seq = (seq[:, None] == jnp.arange(S)[None, :]).astype(f32)
+    whole = jnp.dot(of_seq.T, log_decay, precision=highest)
+    between = since.T[:, :, None] - since.T[:, None, :]                  # [H, t, u]
+    weights = jnp.where(mask[None], jnp.exp(jnp.minimum(between, 0.0)), 0.0)
+    return _PackedRows(since, whole[seq] - since, whole, weights)
+
+
 def _sala_linear_layer(ctx, lp, log_decay, layer, h, slots):
     """One ``lightning-attn`` layer over the flat ragged batch: the packed
     linear step. Rows of one sequence (a prompt chunk, or one decode row)
     see the sequence's carried state, decayed by their distance from the
     chunk's first row, and the chunk's earlier rows through a
     same-sequence-and-causal decay mask; the state each sequence leaves is
-    written back to its slot. Every exponent is ``log lambda * (a
-    distance >= 0)``: nothing overflows, and head 0's decay is 1.
+    written back to its slot. Where a sequence's rows lie is
+    :func:`_row_spans`' and the decays are :func:`_packed_rows`', given
+    this layer's constant log-decay a head at every row: every exponent is
+    a sum of terms <= 0, nothing overflows, and head 0's decay is 1.
     → (h, slots)."""
     cfg = ctx.cfg
     T, H, d, S = h.shape[0], cfg.num_attention_heads, cfg.head_dim, ctx.n_rows
@@ -791,38 +856,26 @@ def _sala_linear_layer(ctx, lp, log_decay, layer, h, slots):
         k = _rms(k, a["k_norm"]["scale"], cfg.rms_norm_eps)
         q, k = _rope_at(q, ctx.pos, cfg.rope_theta), _rope_at(k, ctx.pos, cfg.rope_theta)
 
-        seq, pos = ctx.seq, ctx.pos
-        # each sequence row's first and last position in this step; a row with no token
-        # (and padding's, at position 0) carries nothing and is zero rows long
-        first = jnp.full((S,), jnp.iinfo(jnp.int32).max, jnp.int32).at[seq].min(pos)
-        last = jnp.zeros((S,), jnp.int32).at[seq].max(pos)
-        present = first <= last
-        first = jnp.where(present, first, 0)
-        length = jnp.where(present, last - first + 1, 0)
+        seq = ctx.seq
+        rows = _packed_rows(seq, ctx.pos, S, jnp.broadcast_to(log_decay, (T, H)))
         of_seq = (seq[:, None] == jnp.arange(S)[None, :]).astype(x.dtype)          # [T, S]
         carried = slots[layer, ctx.slot]                                           # [S, H, d, d]
-        carried = jnp.where((first == 0)[:, None, None, None], 0.0, carried)
-
-        def decay(distance):            # [...] int >= 0 → [..., H] float32, lambda_h ** distance
-            return jnp.exp(log_decay * distance.astype(f32)[..., None])
+        fresh = _row_spans(seq, ctx.pos, S)[0] == 0
+        carried = jnp.where(fresh[:, None, None, None], 0.0, carried)
 
         # the carried state, seen from each row: lambda ** (rows since the chunk began + 1)
-        q_in = (q.astype(f32) * decay(pos - first[seq] + 1)[..., None]).astype(x.dtype)
+        q_in = (q.astype(f32) * jnp.exp(rows.since)[..., None]).astype(x.dtype)
         inter = jnp.einsum("ts,thd,shde->the", of_seq, q_in, carried.astype(x.dtype),
                            preferred_element_type=f32)
         # the chunk's own rows: the decay mask [H, T, T], same sequence and causal
-        apart = pos[:, None] - pos[None, :]
-        mask = (seq[:, None] == seq[None, :]) & (apart >= 0)
-        weights = jnp.where(mask[None], jnp.exp(log_decay[:, None, None]
-                                                * jnp.maximum(apart, 0).astype(f32)[None]), 0.0)
-        scores = jnp.einsum("thd,uhd->htu", q, k, preferred_element_type=f32) * weights
+        scores = jnp.einsum("thd,uhd->htu", q, k, preferred_element_type=f32) * rows.weights
         intra = jnp.einsum("htu,uhe->the", scores.astype(x.dtype), v,
                            preferred_element_type=f32)
         o = ((inter + intra) / math.sqrt(d)).reshape(T, H * d)
         # what each sequence leaves: its state decayed over the chunk + the chunk's own
-        k_out = (k.astype(f32) * decay(last[seq] - pos)[..., None]).astype(x.dtype)
+        k_out = (k.astype(f32) * jnp.exp(rows.until)[..., None]).astype(x.dtype)
         added = jnp.einsum("us,uhd,uhe->shde", of_seq, k_out, v, preferred_element_type=f32)
-        state = carried * decay(length)[..., None, None] + added
+        state = carried * jnp.exp(rows.whole)[..., None, None] + added
         slots = slots.at[layer, ctx.slot].set(state)
 
         o = _rms(o.astype(x.dtype), a["o_norm"]["scale"], cfg.rms_norm_eps)
@@ -958,11 +1011,374 @@ def _sala_sparse_layer(ctx, lp, layer, h, kc, vc, kb, attn_impl):
     return _sala_mlp(cfg, lp, h), kc, vc, kb
 
 
+class NemotronHKind:
+    """Nemotron-H (``models/nemotron_h.py``): **every layer one sublayer
+    alone** - a Mamba-2 mixer, an attention or an expert layer, as the
+    pattern's letter says - and state of two kinds side by side.
+
+    - The ``*`` (attention) layers keep keys and values in the engine's two
+      paged pools, ``[La, NB, bs, Hkv * d]``; the other layers hold nothing
+      there (``state_layers`` is not the model's depth).
+    - ``extra_state``'s ``ssm`` ``[Lm, slots + 1, H, P, N]`` float32 and
+      ``conv`` ``[Lm, slots + 1, K - 1, C]``: an ``M`` layer's state a
+      sequence - the recurrence's matrix a head and the last ``K - 1`` rows
+      of ``xBC`` before the convolution's activation - the same at token 10
+      and at token 500,000. A tracked sequence owns a slot of both
+      (``ragged/slot_pool.py``, ``slot_state``); slot 0 is padding's. A
+      sequence's first rows (position 0) take both as zero, so a slot needs
+      no clearing between owners.
+
+    :meth:`stack` runs the pattern as ``cfg.segments`` cuts it: **one scan
+    over a period** wherever a unit of layers repeats, its body the unit's
+    layers in order, each reading its own layer out of its kind's whole
+    stack; single layers elsewhere. The routed experts are one share of an
+    expert-parallel deployment (``ops/grouped_gemm.ExpertShare``) and ride
+    every step whole, one table of ``Le x held`` groups. Each step counts,
+    over its tokens that are not padding: the picks whose expert is held,
+    the zero-compute picks (none: the name is the expert-share readers'),
+    the held experts with at least one row, the rows through the ``M``
+    layers, and the (sequence, ``M`` layer)s whose state it read and
+    wrote."""
+    name = "nemotron_h"
+    state_kind = "kv+slots"
+    lora = False
+    step_counts = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_ssm_rows",
+                   "n_state_slots")
+    seq_rows = 1            # per-sequence rows of the batch: (slot,)
+    slot_state = ("ssm", "conv")    # the entries of extra_state a slot is a row of
+
+    @staticmethod
+    def state_layers(cfg):
+        return max(1, cfg.count("*"))
+
+    @staticmethod
+    def state_rows(cfg):
+        width = cfg.num_key_value_heads * cfg.head_dim
+        return width, width
+
+    @staticmethod
+    def extra_state(cfg, num_blocks, slots, dtype):
+        """→ the tree of state beyond the two paged pools (zeros)."""
+        Lm = cfg.count("M")
+        return {"ssm": jnp.zeros((Lm, slots + 1, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                                  cfg.ssm_state_size), jnp.float32),
+                "conv": jnp.zeros((Lm, slots + 1, cfg.conv_kernel - 1, cfg.conv_dim), dtype)}
+
+    @staticmethod
+    def seq_state(cfg, slot, prompt_len):
+        """A sequence's row of the batch."""
+        return (slot,)
+
+    @staticmethod
+    def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+        if lora is not None or mesh is not None:
+            raise NotImplementedError("the Nemotron-H layer stack serves base-only on one device")
+        model = params["model"]
+        ctx = _NemotronStep(cfg, batch)
+        moe = model.get("moe_layers", {})
+        experts = moe.get("experts")
+        stacks = {"M": model.get("mamba_layers"), "*": model.get("attn_layers"),
+                  "E": {k: v for k, v in moe.items() if k != "experts"}}
+
+        def layer(letter, i, carry):
+            h, kc, vc, ssm, conv, picks = carry
+            lp = jax.tree.map(lambda w: w[i], stacks[letter])
+            x = _rms(h, lp["norm"]["scale"], cfg.layer_norm_epsilon)
+            if letter == "M":
+                with jax.named_scope("ds.nemotron.mamba"):
+                    y, ssm, conv = _mamba_mixer(ctx, lp, i, x, ssm, conv)
+            elif letter == "*":
+                with jax.named_scope("ds.nemotron.attn"):
+                    y, kc, vc = _nemotron_attention(cfg, lp, i, x, kc, vc, batch, attn_impl)
+            else:
+                with jax.named_scope("ds.nemotron.latent_moe"):
+                    y, n = _nemotron_moe(cfg, ctx.real, lp, experts, i, x)
+                picks = picks + n
+            return h + y, kc, vc, ssm, conv, picks
+
+        carry = (h, kc, vc, extra["ssm"], extra["conv"], jnp.zeros((3,), jnp.int32))
+        done = dict.fromkeys("ME*", 0)
+        for unit, repeats in cfg.segments:
+            base, per = dict(done), {t: unit.count(t) for t in "ME*"}
+
+            def period(carry, r, unit=unit, base=base, per=per):
+                seen = dict.fromkeys("ME*", 0)
+                for letter in unit:
+                    carry = layer(letter, base[letter] + r * per[letter] + seen[letter], carry)
+                    seen[letter] += 1
+                return carry, None
+
+            if repeats == 1:
+                carry, _ = period(carry, 0)
+            else:
+                carry, _ = jax.lax.scan(period, carry, jnp.arange(repeats, dtype=jnp.int32))
+            for t in "ME*":
+                done[t] += per[t] * repeats
+        h, kc, vc, ssm, conv, picks = carry
+        real = ctx.real.astype(jnp.int32)
+        live = jnp.sum((ctx.length[:-1] > 0).astype(jnp.int32))
+        counts = jnp.concatenate([picks, jnp.stack([done["M"] * jnp.sum(real),
+                                                    done["M"] * live])]).astype(jnp.int32)
+        return h, kc, vc, {"ssm": ssm, "conv": conv}, counts[None]
+
+    @staticmethod
+    def experts_form(params, mesh):
+        return "table" if "moe_layers" in params["model"] else None
+
+    @staticmethod
+    def final_norm(params, cfg, h):
+        return _rms(h, params["model"]["norm"]["scale"], cfg.layer_norm_epsilon)
+
+    @staticmethod
+    def expert_layer(params, cfg, layer, x):
+        """Expert layer ``layer`` (its index among the ``E`` layers; may be
+        traced) as the step programs compute it - the same router, the same
+        share, the table of every layer's held experts read in place - for
+        a check that wants the layer alone: x [T, D] the normalised stream,
+        every row a token → y."""
+        moe = params["model"]["moe_layers"]
+        lp = jax.tree.map(lambda w: w[layer], {k: v for k, v in moe.items() if k != "experts"})
+        every_row = jnp.ones(x.shape[0], bool)
+        return _nemotron_moe(cfg, every_row, lp, moe["experts"], layer, x)[0]
+
+    @staticmethod
+    def mamba_layer(params, cfg, layer, x, ssm, conv, batch):
+        """``M`` layer ``layer``'s mixer alone (its index among the ``M``
+        layers), as the step programs compute it - the same packed
+        recurrence, the same reads and writes of the slot pool - for a
+        check that wants it without the rest: x [T, D] the normalised
+        stream → (y [T, D], ssm, conv)."""
+        lp = jax.tree.map(lambda w: w[layer], params["model"]["mamba_layers"])
+        return _mamba_mixer(_NemotronStep(cfg, batch), lp, layer, x, ssm, conv)
+
+
+class _NemotronStep:
+    """What every layer of one step shares: each token's sequence row and
+    position, each sequence row's slot, and where its rows lie in the
+    step."""
+
+    def __init__(self, cfg, batch):
+        self.cfg = cfg
+        self.seq, self.pos = batch["token_seq"], batch["token_pos"]
+        self.n_rows = S = batch["block_tables"].shape[0]     # sequences a step + padding's
+        self.real = self.seq < S - 1
+        self.slot = batch["seq_state"][:, 0]
+        T = self.seq.shape[0]
+        # a sequence's rows are one run of the batch, positions ascending (the wrapper
+        # appends a chunk at a time): row ``first_row + j`` is its j-th of this step
+        first, self.length = _row_spans(self.seq, self.pos, S)
+        self.fresh = first == 0         # (or no row at all): what the slot held is not read
+        self.first_row = jnp.minimum(
+            jnp.full((S,), T, jnp.int32).at[self.seq].min(jnp.arange(T, dtype=jnp.int32)), T - 1)
+
+
+MAMBA_ROUND = 4     # sequences with more than one row in a step, taken this many at a time
+
+
+def _mamba_mixer(ctx, p, layer, x, ssm, conv):
+    """One Mamba-2 mixer over the flat ragged batch, on the normalised
+    stream x [T, D]: the packed recurrence. → (y [T, D], ssm, conv).
+
+    The convolution reads, for a row fewer than ``K - 1`` rows into its
+    sequence's rows of this step, the tail the sequence carried in its slot
+    (zero at position 0), and leaves the tail of what it has now seen.
+
+    The recurrence is :func:`_packed_rows`' with the rows' own log-decays
+    ``Delta_t A``: a row sees its chunk's earlier rows through the decay
+    mask ``[H, T, T]`` (scores a group of heads, ``C_t . B_u``, once a
+    group) and the state its sequence carried. A state is ``H x P x N`` =
+    a million floats, so neither it is laid out a row nor the rows a
+    sequence by a one-hot product over all ``S`` sequence rows, as the
+    linear layers of :class:`SalaKind` can afford with 25 states of a
+    sixteenth the size: **every sequence's first row of the step** - its
+    only row, in a decode step - reads and updates the carried state for
+    all slots at once (a batched matrix-vector product and a rank-one
+    update, one pass over the pool's layer where it lies), and the
+    sequences with further rows (prompt chunks: a few a step) take theirs
+    ``MAMBA_ROUND`` sequences a round, by a one-hot product over those
+    alone, for as many rounds as there are such sequences. Float32
+    throughout; the matmuls at the default precision."""
+    cfg = ctx.cfg
+    T, S = x.shape[0], ctx.n_rows
+    H, P, G, N, K = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size,
+                     cfg.conv_kernel)
+    I, C, per = cfg.mamba_inner, cfg.conv_dim, cfg.mamba_num_heads // cfg.n_groups
+    f32 = jnp.float32
+    seq, pos, slot, first_row = ctx.seq, ctx.pos, ctx.slot, ctx.first_row
+    zxbcdt = _proj(x, p["in_proj"])
+    z, xbc, dt = zxbcdt[:, :I], zxbcdt[:, I:I + C], zxbcdt[:, I + C:]
+
+    # ---- the convolution and its tail
+    tail = jnp.where(ctx.fresh[:, None, None], 0, conv[layer, slot])     # [S, K - 1, C]
+    rank = jnp.arange(T, dtype=jnp.int32) - first_row[seq]     # a row's index in its chunk
+    acc = p["conv_bias"].astype(f32)[None, :]
+    kernel = p["conv_kernel"].astype(f32)
+    for j in range(K):
+        back = K - 1 - j                                     # tap j reads `back` rows back
+        tap = xbc if back == 0 else jnp.concatenate(
+            [jnp.zeros((back, C), xbc.dtype), xbc[:T - back]], axis=0)
+        if back:
+            carried_row = tail[seq, jnp.clip(rank + j, 0, K - 2)]       # the tail's row rank + j
+            tap = jnp.where((rank >= back)[:, None], tap, carried_row)
+        acc = acc + kernel[j][None, :] * tap.astype(f32)
+    act = jax.nn.silu(acc)
+    i = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+    into = ctx.length[:, None] - (K - 1) + i                 # [S, K - 1]: chunk row, or < 0
+    kept = jnp.take_along_axis(tail, jnp.clip(ctx.length[:, None] + i, 0, K - 2)[..., None],
+                               axis=1)
+    new_tail = jnp.where((into >= 0)[..., None],
+                         xbc[jnp.clip(first_row[:, None] + into, 0, T - 1)], kept)
+    conv = conv.at[layer, slot].set(new_tail.astype(conv.dtype))
+
+    # ---- the recurrence
+    xs = act[:, :I].reshape(T, H, P)
+    b = act[:, I:I + G * N].reshape(T, G, N)
+    c = act[:, I + G * N:].reshape(T, G, N)
+    delta = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))   # [T, H]
+    rows = _packed_rows(seq, pos, S, delta * -jnp.exp(p["A_log"].astype(f32)))
+    v = delta[..., None] * xs                                            # [T, H, P]
+    # the chunk's own rows: scores a group, the decay mask a head
+    cb = jnp.einsum("tgn,ugn->gtu", c.astype(x.dtype), b.astype(x.dtype),
+                    preferred_element_type=f32)
+    att = (rows.weights.reshape(G, per, T, T) * cb[:, None]).reshape(H, T, T)
+    y = jnp.einsum("htu,uhp->thp", att.astype(x.dtype), v.astype(x.dtype),
+                   preferred_element_type=f32)
+
+    # The carried states, **by slot**: the pool's layer is read where it lies and written
+    # back whole - every slot of it, the slots no row of this step names kept as they are
+    # (decayed by 1, nothing added) - so no copy of the step's S states is gathered and
+    # scattered back. What is a sequence row's is laid out a slot first; rows without a
+    # sequence all name padding's slot 0, whose state is nobody's. (On the chip the reduce
+    # below, the update and the write-back are still three passes over the layer's 541 MB,
+    # 5.4 ms of a decode step's 9 ms a layer, as the gather, update and scatter were: PERF.md
+    # section 7, PR 38.)
+    NS = ssm.shape[1]
+    here = ctx.length > 0
+
+    def by_slot(of_row, fill):
+        return jnp.full((NS,) + of_row.shape[1:], fill, of_row.dtype).at[slot].set(of_row)
+
+    start = by_slot(ctx.fresh, False)                                    # what it held is not read
+    carried = jnp.where(start[:, None, None, None], 0.0, ssm[layer]).reshape(NS, G, per, P, N)
+    # every sequence's first row of the step - its only row, in a decode step - all at once
+    # (a product and a sum over N beside the update below, in float32 as the state is: a
+    # dot would have the states copied in its operand's type first)
+    seen = jnp.sum(carried * by_slot(c[first_row], 0.0)[:, :, None, None, :], axis=-1)
+    seen = seen.reshape(NS, H, P)[slot]
+    seen = seen * jnp.where(here[:, None], jnp.exp(rows.since[first_row]), 0.0)[..., None]
+    y = y.at[first_row].add(seen)
+    left = jnp.where(here[:, None], jnp.exp(rows.until[first_row]), 0.0)[..., None] * v[first_row]
+    decay = by_slot(jnp.where(here[:, None], jnp.exp(rows.whole), 1.0), 1.0)
+    state = (carried * decay.reshape(NS, G, per, 1, 1)
+             + by_slot(jnp.where(here[:, None, None], left, 0.0), 0.0).reshape(NS, G, per, P, 1)
+             * by_slot(b[first_row], 0.0)[:, :, None, None, :])
+
+    # the sequences with further rows, MAMBA_ROUND a round
+    multi = ctx.length > 1
+    n_multi = jnp.sum(multi.astype(jnp.int32))
+    order = jnp.concatenate([jnp.argsort(~multi, stable=True).astype(jnp.int32),
+                             jnp.full((MAMBA_ROUND,), S - 1, jnp.int32)])
+    since = jnp.exp(rows.since).reshape(T, G, per, 1)
+    v_left = (jnp.exp(rows.until)[..., None] * v).reshape(T, G, per, P)
+    row_ids = jnp.arange(T, dtype=jnp.int32)
+    highest = jax.lax.Precision.HIGHEST
+
+    def further(r, acc):
+        y, state = acc
+        at = r * MAMBA_ROUND
+        chosen = jax.lax.dynamic_slice_in_dim(order, at, MAMBA_ROUND)            # [R]
+        valid = at + jnp.arange(MAMBA_ROUND) < n_multi
+        rows_of = ((seq[None, :] == chosen[:, None]) & valid[:, None]
+                   & (row_ids[None, :] != first_row[chosen][:, None])).astype(f32)   # [R, T]
+        reads = rows_of[:, :, None, None] * c[None]                              # [R, T, G, N]
+        # their carried states, read from the pool again: these few. (Float32 operands as
+        # they are: at the default precision XLA rounds the whole pool to bfloat16, a
+        # round, before it gathers these from it.)
+        prior = jnp.where(ctx.fresh[chosen][:, None, None, None], 0.0, ssm[layer, slot[chosen]])
+        y = y + (jnp.einsum("rtgn,rgapn->tgap", reads, prior.reshape(MAMBA_ROUND, G, per, P, N),
+                            precision=highest) * since).reshape(T, H, P)
+        writes = rows_of[:, :, None, None] * b[None]
+        return y, state.at[slot[chosen]].add(
+            jnp.einsum("rtgn,tgap->rgapn", writes, v_left, precision=highest))
+
+    y, state = jax.lax.fori_loop(0, (n_multi + MAMBA_ROUND - 1) // MAMBA_ROUND, further,
+                                 (y, state))
+    ssm = ssm.at[layer].set(state.reshape(NS, H, P, N))
+
+    y = y + p["D"].astype(f32)[None, :, None] * xs
+    y = (y.reshape(T, I) * jax.nn.silu(z.astype(f32))).reshape(T, G, I // G)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + cfg.layer_norm_epsilon)
+    y = (y.reshape(T, I) * p["gate_norm"]["scale"].astype(f32)).astype(x.dtype)
+    return _proj(y, p["out_proj"]), ssm, conv
+
+
+def _nemotron_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
+    """One ``*`` layer's mixer on the normalised stream: grouped-query
+    attention over the paged pool's layer ``layer``, queries and keys as
+    projected (no positional term). → (y, kc, vc)."""
+    T = x.shape[0]
+    Hq, Hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q = _proj(x, p["q_proj"]).reshape(T, Hq, d)
+    k = _proj(x, p["k_proj"]).reshape(T, Hkv, d)
+    v = _proj(x, p["v_proj"]).reshape(T, Hkv, d)
+    out, kc, vc = _paged_attend(q, k, v, kc, vc, layer, batch, d, impl=attn_impl)
+    return _proj(out.reshape(T, Hq * d), p["o_proj"]), kc, vc
+
+
+def _nemotron_moe(cfg, real, p, experts, layer, x):
+    """One LatentMoE layer on the normalised stream, as this share gives
+    it, and its three counts; ``real`` [T]: the rows that are not padding.
+    Sigmoid scores in float32 (the matmul at the highest precision: the
+    picks are a step function of it); the
+    ``num_experts_per_tok`` columns with the largest score + bias, weighted
+    by their unbiased scores over their sum, times
+    ``routed_scaling_factor``. The routed experts work in the latent ``u =
+    x W_down``: ungated, ``relu(u W1)^2 W2``, the held picks through the
+    grouped matmul over the table of every layer's held experts
+    (``ops/grouped_gemm.dropless_moe_ffn``, ``w3=None``), the rest left
+    out; ``W_up`` leaves the latent. The shared expert on the full width.
+    → (y [T, D], int32 [3]: picks held, picks zero-compute (none), held
+    experts with a row - over the tokens that are not padding, whose rows
+    launch no group either)."""
+    from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn
+    router = p["router"]
+    with jax.named_scope("ds.moe_routed"):
+        scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                        router["weight"].astype(jnp.float32),
+                                        precision=jax.lax.Precision.HIGHEST))
+        _, topk_idx = jax.lax.top_k(
+            scores + router["e_score_correction_bias"].astype(jnp.float32),
+            cfg.num_experts_per_tok)
+        topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1)
+        topk_vals = (topk_vals / (topk_vals.sum(-1, keepdims=True) + 1e-20)
+                     * cfg.routed_scaling_factor)
+        share = ExpertShare(cfg.first_expert_held, cfg.held, cfg.n_routed_experts)
+        topk_idx = jnp.where(real[:, None], topk_idx, -1)         # a padding token picks nothing
+        table, first_group = _layer_groups(experts, layer)
+        u = _proj(x, p["latent_down"])
+        v = dropless_moe_ffn(u, topk_idx, topk_vals, table["up_proj"], None, table["down_proj"],
+                             num_experts=share.routed, widen_boundary=False,
+                             first_group=first_group, share=share, activation=relu2)
+        y = _proj(v, p["latent_up"])
+        held, zero = share.parts(topk_idx)
+        of_expert = topk_idx[..., None] == share.first + jnp.arange(share.held)
+        live = jnp.any(of_expert & held[..., None], axis=(0, 1))
+        counts = jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
+    with jax.named_scope("ds.moe_shared"):
+        s = p["shared_experts"]
+        return y + _proj(relu2(_proj(x, s["up_proj"])), s["down_proj"]), counts
+
+
 def kind_of(cfg):
-    """The model kind of a config, by its type."""
+    """The model kind of a config, by its type: one of the kinds this
+    module defines, each of which names its config class in its docstring."""
     from deepspeed_tpu.models.longcat import LongcatFlashConfig
     from deepspeed_tpu.models.minicpm_sala import MiniCPMSalaConfig
     from deepspeed_tpu.models.moonlight import MoonlightConfig
+    from deepspeed_tpu.models.nemotron_h import NemotronHConfig
+    if isinstance(cfg, NemotronHConfig):
+        return NemotronHKind
     if isinstance(cfg, MiniCPMSalaConfig):
         return SalaKind
     if isinstance(cfg, LongcatFlashConfig):
@@ -1181,9 +1597,11 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     latent rows and rotated keys of ``MoonlightKind``), carried through
     the layers and written in place (donate them); ``extra``: None, or the
     kind's own tree of further state (``SalaKind.extra_state``: the slot
-    pool of linear states, the pooled keys), carried and donated likewise;
-    ``batch``: the arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is a ``LlamaConfig``,
-    ``GPTConfig``, ``MoonlightConfig``, ``LongcatFlashConfig`` or ``MiniCPMSalaConfig``; the layer wiring follows its kind. ``mesh``: an optional
+    pool of linear states, the pooled keys; ``NemotronHKind.extra_state``:
+    the Mamba states and convolution tails), carried and donated likewise;
+    ``batch``: the arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is
+    the config of any kind :func:`kind_of` knows; the layer wiring follows
+    its kind. ``mesh``: an optional
     serving mesh — params/KV arrive sharded per
     ``inference/v2/sharding.py`` and the step pins the Megatron layout
     (replicated tokens, head/feature-sharded projections) so GSPMD

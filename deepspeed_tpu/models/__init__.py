@@ -12,12 +12,16 @@ from deepspeed_tpu.models.longcat import (LONGCAT_CONFIGS, LongcatFlashConfig,
 from deepspeed_tpu.models.minicpm_sala import (MINICPM_SALA_CONFIGS, MiniCPMSalaConfig,
                                                MiniCPMSalaForCausalLM,
                                                build_minicpm_sala)  # noqa: F401
+from deepspeed_tpu.models.nemotron_h import (NEMOTRON_H_CONFIGS, NemotronHConfig,
+                                             NemotronHForCausalLM,
+                                             build_nemotron_h)  # noqa: F401
 
 # The causal-LM families a preset name can build, in the order names are looked up
 # (the v2 serving engine takes any of them: inference/v2/model_runner.kind_of).
 MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
                   (MOONLIGHT_CONFIGS, build_moonlight), (LONGCAT_CONFIGS, build_longcat),
-                  (MINICPM_SALA_CONFIGS, build_minicpm_sala))
+                  (MINICPM_SALA_CONFIGS, build_minicpm_sala),
+                  (NEMOTRON_H_CONFIGS, build_nemotron_h))
 
 
 def build_model(preset, **overrides):

@@ -240,11 +240,14 @@ def _gathered_moe_mlp(x, expert_idx, w_gate, w_up, w_down, activation):
         jnp.einsum("td,tdf->tf", x, take(w_gate),
                    preferred_element_type=jnp.float32).astype(x.dtype),
         "moe_gate")
-    up = checkpoint_name(
-        jnp.einsum("td,tdf->tf", x, take(w_up),
-                   preferred_element_type=jnp.float32).astype(x.dtype),
-        "moe_up")
-    inter = activation(gate) * up
+    if w_up is None:
+        inter = activation(gate)
+    else:
+        up = checkpoint_name(
+            jnp.einsum("td,tdf->tf", x, take(w_up),
+                       preferred_element_type=jnp.float32).astype(x.dtype),
+            "moe_up")
+        inter = activation(gate) * up
     return jnp.einsum("tf,tfd->td", inter, take(w_down),
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
@@ -291,7 +294,9 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
     [E, D, F] / [E, D, F] / [E, F, D] → [T, D]. Every token reaches its
     expert (no capacity drops — the grouped-GEMM advantage). Each
     weight may be a dense stack or a grouped-layout ``QuantizedWeight``
-    stack (see module docstring).
+    stack (see module docstring). ``w_up=None``: an **ungated** expert of
+    two matrices, ``activation(x w_gate) w_down``, through the same
+    dispatches with one grouped GEMM fewer.
 
     On TPU the three GEMMs run in the Pallas grouped matmul
     (``ops/pallas/grouped_matmul.py``) over a tile-aligned padded row
@@ -329,12 +334,13 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
     it; None: ``T / num_experts``). Dense stacks only; ``GMM_STATS``
     counts these with ``_share``."""
     from jax.ad_checkpoint import checkpoint_name
-    quantized = any(_is_quantized(w) for w in (w_gate, w_up, w_down))
+    stacks = tuple(w for w in (w_gate, w_up, w_down) if w is not None)
+    quantized = any(_is_quantized(w) for w in stacks)
     if live is not None and quantized:
         raise NotImplementedError("an expert share (live rows) over quantized expert stacks")
     if quantized and not fused_gmm_enabled():
         # DS_FUSED_GMM=0: restore dequantize-then-dispatch wholesale
-        w_gate, w_up, w_down = (_unbox_stack(w, x.dtype)
+        w_gate, w_up, w_down = (w if w is None else _unbox_stack(w, x.dtype)
                                 for w in (w_gate, w_up, w_down))
         quantized = False
     d_ff = _stack_dims(w_gate)[1]
@@ -345,12 +351,12 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
         use_pallas = all(
             not _is_quantized(w)
             or gmm_quant_supported(w.values, w.scales, w.scheme)
-            for w in (w_gate, w_up, w_down))
+            for w in stacks)
     groups, table = num_experts, ""
     if first_group is not None and use_pallas and quantized:
         w_gate, w_up, w_down = (
             jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, first_group, num_experts), w)
-            for w in (w_gate, w_up, w_down))
+            for w in (w_gate, w_up, w_down))        # (None, an ungated expert's, has no leaves)
         first_group = None
     elif first_group is not None:
         table = "_table"
@@ -396,8 +402,11 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
             return _gmm_dispatch(rows, w, te, tm, FORCE_INTERPRET, first_group, num_tiles)
 
         gate = checkpoint_name(matmul(xp, w_gate), "moe_gate")
-        up = checkpoint_name(matmul(xp, w_up), "moe_up")
-        inter = activation(gate) * up
+        if w_up is None:
+            inter = activation(gate)
+        else:
+            up = checkpoint_name(matmul(xp, w_up), "moe_up")
+            inter = activation(gate) * up
         return jnp.take(matmul(inter, w_down), pdst, axis=0, unique_indices=True,
                         fill_value=None if live is None else 0)
     if live is not None:
@@ -412,8 +421,11 @@ def moe_grouped_mlp(x, expert_idx, w_gate, w_up, w_down, num_experts, activation
     xs, sizes, unsort = sort_by_expert(x, expert_idx, groups)
     xs = checkpoint_name(xs, "moe_xs")
     gate = checkpoint_name(grouped_gemm_any(xs, w_gate, sizes).astype(x.dtype), "moe_gate")
-    up = checkpoint_name(grouped_gemm_any(xs, w_up, sizes).astype(x.dtype), "moe_up")
-    inter = activation(gate) * up
+    if w_up is None:
+        inter = activation(gate)
+    else:
+        up = checkpoint_name(grouped_gemm_any(xs, w_up, sizes).astype(x.dtype), "moe_up")
+        inter = activation(gate) * up
     out = grouped_gemm_any(inter, w_down, sizes).astype(x.dtype)
     return jnp.take(out, unsort, axis=0)
 
@@ -452,10 +464,12 @@ def _join_stacks(flat, tags):
 class ExpertShare:
     """Which of a router's columns this process computes: ``held`` routed
     experts from ``first`` (the stacks given to :func:`dropless_moe_ffn`
-    are these, in order), out of ``routed``, followed by ``zero``
-    zero-compute columns whose expert is the identity. One rank of an
-    expert-parallel deployment holds ``routed / ranks`` experts and
-    computes the identity part of the tokens that live on it."""
+    are these, in order), out of ``routed``; and, for a router that has
+    them (LongCat's; ``zero`` is 0 for every other), ``zero`` zero-compute
+    columns after the routed ones, whose expert is the identity. One rank
+    of an expert-parallel deployment holds ``routed / ranks`` experts and
+    computes the identity part, where there is one, of the tokens that
+    live on it."""
     first: int
     held: int
     routed: int
@@ -477,12 +491,16 @@ def shards_experts(mesh):
 
 
 def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
-                     widen_boundary=True, first_group=None, share=None):
+                     widen_boundary=True, first_group=None, share=None,
+                     activation=jax.nn.silu):
     """Post-gate dropless MoE FFN over flat tokens — the one
     implementation behind BOTH v2 ragged serving and dropless training.
 
     ``x`` [T, D]; ``topk_idx``/``topk_vals`` [T, k] (weights already
-    renormalized); ``w1``/``w3`` [E, D, I], ``w2`` [E, I, D] → [T, D].
+    renormalized); ``w1``/``w3`` [E, D, I], ``w2`` [E, I, D] → [T, D]:
+    ``sum_j w_j (activation(x w1_j) * (x w3_j)) w2_j``. ``w3=None``: an
+    **ungated** expert of two matrices, ``activation(x w1_j) w2_j`` (one
+    device only).
 
     Without a mesh (or expert/tensor axes of size 1): tokens replicate
     k×, sort by expert, and ride one grouped GEMM (``lax.ragged_dot``).
@@ -537,9 +555,12 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
         # DS_FUSED_GMM=0: unbox quantized stacks up front — everything
         # below (including the shard plan) then sees dense stacks, which
         # is exactly the pre-fused execution model.
-        w1, w3, w2 = (_unbox_stack(w, x.dtype) for w in (w1, w3, w2))
+        w1, w3, w2 = (w if w is None else _unbox_stack(w, x.dtype) for w in (w1, w3, w2))
 
     if shards_experts(mesh):
+        if w3 is None:
+            raise NotImplementedError("an ungated expert (w3=None) is not sharded over "
+                                      "expert/tensor axes")
         if first_group is not None:
             raise NotImplementedError("a table of expert groups (first_group) is not "
                                       "sharded over expert/tensor axes")
@@ -595,8 +616,9 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
 
     x_rep = jnp.repeat(x, k, axis=0)  # [T*k, D]
     out_rep = moe_grouped_mlp(x_rep, idx_rep, _cast_stack(w1, x.dtype),
-                              _cast_stack(w3, x.dtype), _cast_stack(w2, x.dtype),
-                              num_experts=num_experts, first_group=first_group,
+                              None if w3 is None else _cast_stack(w3, x.dtype),
+                              _cast_stack(w2, x.dtype), num_experts=num_experts,
+                              activation=activation, first_group=first_group,
                               live=live, rows_a_group=rows_a_group)
     out_k = out_rep.reshape(T, k, -1)
     out = jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
@@ -607,9 +629,10 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
 
 
 def dense_reference_mlp(x, expert_idx, w_gate, w_up, w_down, activation=jax.nn.silu):
-    """O(T*E) dense check: every token through every expert, select own."""
+    """O(T*E) dense check: every token through every expert, select own
+    (``w_up=None``: the ungated expert's twin)."""
     gate = jnp.einsum("td,edf->tef", x, w_gate)
-    up = jnp.einsum("td,edf->tef", x, w_up)
-    inter = activation(gate) * up
+    inter = activation(gate) if w_up is None else \
+        activation(gate) * jnp.einsum("td,edf->tef", x, w_up)
     out = jnp.einsum("tef,efd->ted", inter, w_down)
     return jnp.take_along_axis(out, expert_idx[:, None, None], axis=1)[:, 0, :].astype(x.dtype)

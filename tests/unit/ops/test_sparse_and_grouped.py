@@ -180,6 +180,69 @@ class TestGroupedGemm:
         np.testing.assert_allclose(np.asarray(got), np.asarray(dense_reference_mlp(x, idx, *cut)),
                                    rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("rows,path", [(3, "gathered"), (96, "ragged"), (64, "pallas")])
+    def test_an_ungated_expert_of_two_matrices_through_every_dispatch(self, rows, path):
+        """``w_up=None`` and the activation as an argument: ``act(x w1) w2``
+        - here ``relu(.)^2`` - through the gathered, the ragged and the
+        Pallas dispatch, over a table of groups, against
+        ``dense_reference_mlp``'s ungated twin."""
+        import deepspeed_tpu.ops.grouped_gemm as gg
+        relu2 = lambda v: jnp.square(jax.nn.relu(v))  # noqa: E731
+        rng = np.random.RandomState(11)
+        D, F, E, layers, layer = 64, 128, 4, 2, 1
+        x = jnp.asarray(rng.randn(rows, D).astype(np.float32))
+        idx = jnp.asarray(rng.randint(0, E, rows).astype(np.int32))
+        w1, w2 = (jnp.asarray(rng.randn(layers * E, *shape).astype(np.float32) * 0.05)
+                  for shape in ((D, F), (F, D)))
+        gg.FORCE_INTERPRET = path == "pallas"
+        gg.GMM_STATS.reset()
+        try:
+            got = jax.jit(lambda first: moe_grouped_mlp(
+                x, idx, w1, None, w2, E, activation=relu2, first_group=first))(
+                    jnp.int32(layer * E))
+        finally:
+            gg.FORCE_INTERPRET = False
+        assert gg.GMM_STATS.snapshot() == {path + "_table": 1}
+        cut = slice(layer * E, (layer + 1) * E)
+        want = dense_reference_mlp(x, idx, w1[cut], None, w2[cut], activation=relu2)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
+        gated = dense_reference_mlp(x, idx, w1[cut], w1[cut], w2[cut], activation=relu2)
+        assert np.abs(np.asarray(gated) - np.asarray(want)).max() > 1e-3
+
+    @pytest.mark.parametrize("pallas", [False, True])
+    def test_an_ungated_share_sums_the_held_picks_alone(self, pallas):
+        """``dropless_moe_ffn(w3=None, share=...)``: the held picks' ``w_j
+        relu(x w1_j)^2 w2_j`` and nothing for the picks held elsewhere or a
+        padding token's (-1); a sharded mesh refuses an ungated expert."""
+        import deepspeed_tpu.ops.grouped_gemm as gg
+        from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn
+        relu2 = lambda v: jnp.square(jax.nn.relu(v))  # noqa: E731
+        rng = np.random.RandomState(5)
+        T, k, D, F, routed, first, held = 24, 3, 128, 128, 8, 2, 4
+        x = jnp.asarray(rng.randn(T, D).astype(np.float32) * 0.5)
+        idx = np.stack([rng.permutation(routed)[:k] for _ in range(T)]).astype(np.int32)
+        idx[5] = -1
+        vals = jnp.asarray(rng.rand(T, k).astype(np.float32))
+        w1, w2 = (jnp.asarray(rng.randn(held, *shape).astype(np.float32) * 0.05)
+                  for shape in ((D, F), (F, D)))
+        share = ExpertShare(first=first, held=held, routed=routed)
+        gg.FORCE_INTERPRET = pallas
+        gg.GMM_STATS.reset()
+        try:
+            got = dropless_moe_ffn(x, jnp.asarray(idx), vals, w1, None, w2, num_experts=routed,
+                                   share=share, activation=relu2)
+        finally:
+            gg.FORCE_INTERPRET = False
+        assert gg.GMM_STATS.snapshot() == {("pallas" if pallas else "ragged") + "_share": 1}
+        want = np.zeros((T, D), np.float32)
+        for t in range(T):
+            for j in range(k):
+                e = idx[t, j] - first
+                if idx[t, j] >= 0 and 0 <= e < held:
+                    want[t] += float(vals[t, j]) * np.asarray(relu2(x[t] @ w1[e]) @ w2[e])
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+        assert np.abs(want[5]).max() == 0.0
+
     def test_grouped_under_jit_and_grad(self):
         rng = np.random.RandomState(2)
         T, D, F, E = 16, 8, 8, 2
