@@ -853,6 +853,8 @@ class InferenceEngineV2:
         counts nothing."""
         if counts:
             rec.counts = dict(zip(self.kind.step_counts, counts[0].tolist()))
+            # and what serves its Mamba-2 state step, where the model kind has one
+            rec.state_step = self._attention.state_step.get(rec.n_rows // max(rec.k, 1))
 
     def count_host_sync(self, n=1):
         """Record ``n`` executions of a pragma'd host-sync site. Every
@@ -877,6 +879,14 @@ class InferenceEngineV2:
         implementation (``pallas_paged``, ``pallas_paged_sharded``,
         ``xla_gather``) each one compiled in."""
         return dict(self._attention.selected)
+
+    @property
+    def state_step_impls(self):
+        """``{token count: implementation name}`` of the Mamba-2 state step
+        (``pallas_ssm_state`` / ``xla``: ``ops/pallas/ssm_state``) for every
+        program traced so far; empty for a model kind without one. A step
+        record's ``state_step`` says the same of the program it ran."""
+        return dict(self._attention.state_step)
 
     def draw_seed(self):
         """One per-request sampling seed from the engine's deterministic

@@ -1038,7 +1038,10 @@ class NemotronHKind:
     the zero-compute picks (none: the name is the expert-share readers'),
     the held experts with at least one row, the rows through the ``M``
     layers, and the (sequence, ``M`` layer)s whose state it read and
-    wrote."""
+    wrote - each of those one slot fetched and written back by
+    ``ops/pallas/ssm_state.ssm_state_step``, the kernel that takes the
+    ``ssm`` pool in place (``AttentionChoice.state_step`` says whether a
+    program got it)."""
     name = "nemotron_h"
     state_kind = "kv+slots"
     lora = False
@@ -1074,7 +1077,7 @@ class NemotronHKind:
         if lora is not None or mesh is not None:
             raise NotImplementedError("the Nemotron-H layer stack serves base-only on one device")
         model = params["model"]
-        ctx = _NemotronStep(cfg, batch)
+        ctx = _NemotronStep(cfg, batch, attn_impl)
         moe = model.get("moe_layers", {})
         experts = moe.get("experts")
         stacks = {"M": model.get("mamba_layers"), "*": model.get("attn_layers"),
@@ -1116,7 +1119,7 @@ class NemotronHKind:
                 done[t] += per[t] * repeats
         h, kc, vc, ssm, conv, picks = carry
         real = ctx.real.astype(jnp.int32)
-        live = jnp.sum((ctx.length[:-1] > 0).astype(jnp.int32))
+        live = jnp.sum(ctx.here.astype(jnp.int32))
         counts = jnp.concatenate([picks, jnp.stack([done["M"] * jnp.sum(real),
                                                     done["M"] * live])]).astype(jnp.int32)
         return h, kc, vc, {"ssm": ssm, "conv": conv}, counts[None]
@@ -1155,10 +1158,11 @@ class NemotronHKind:
 class _NemotronStep:
     """What every layer of one step shares: each token's sequence row and
     position, each sequence row's slot, and where its rows lie in the
-    step."""
+    step; ``choice``, the engine's ``heuristics.AttentionChoice`` (None =
+    nobody asks), is told which state step the program got."""
 
-    def __init__(self, cfg, batch):
-        self.cfg = cfg
+    def __init__(self, cfg, batch, choice=None):
+        self.cfg, self.choice = cfg, choice
         self.seq, self.pos = batch["token_seq"], batch["token_pos"]
         self.n_rows = S = batch["block_tables"].shape[0]     # sequences a step + padding's
         self.real = self.seq < S - 1
@@ -1168,6 +1172,9 @@ class _NemotronStep:
         # appends a chunk at a time): row ``first_row + j`` is its j-th of this step
         first, self.length = _row_spans(self.seq, self.pos, S)
         self.fresh = first == 0         # (or no row at all): what the slot held is not read
+        # the sequence rows with a token in this step: their slots' states are read and
+        # written; padding's row (the last) owns none
+        self.here = (self.length > 0) & (jnp.arange(S) < S - 1)
         self.first_row = jnp.minimum(
             jnp.full((S,), T, jnp.int32).at[self.seq].min(jnp.arange(T, dtype=jnp.int32)), T - 1)
 
@@ -1191,12 +1198,17 @@ def _mamba_mixer(ctx, p, layer, x, ssm, conv):
     sequence by a one-hot product over all ``S`` sequence rows, as the
     linear layers of :class:`SalaKind` can afford with 25 states of a
     sixteenth the size: **every sequence's first row of the step** - its
-    only row, in a decode step - reads and updates the carried state for
-    all slots at once (a batched matrix-vector product and a rank-one
-    update, one pass over the pool's layer where it lies), and the
-    sequences with further rows (prompt chunks: a few a step) take theirs
-    ``MAMBA_ROUND`` sequences a round, by a one-hot product over those
-    alone, for as many rounds as there are such sequences. Float32
+    only row, in a decode step - reads and updates the carried state in
+    one visit of its sequence's slot, **one pass over the pool's layer
+    where it lies** (``ops/pallas/ssm_state.ssm_state_step``: the pool
+    aliased in and out of a kernel that fetches, uses and rewrites the
+    slots this step's sequences own and no other; where the kernel does not
+    run, ``xla_ssm_state_step``: the same by slot over the whole layer),
+    and the sequences with further rows (prompt chunks: a few a step) take
+    theirs ``MAMBA_ROUND`` sequences a round, by a one-hot product over
+    those alone, for as many rounds as there are such sequences - their
+    reads of the state they carried before that visit, their additions to
+    the state the step leaves after it, into the pool by slot. Float32
     throughout; the matmuls at the default precision."""
     cfg = ctx.cfg
     T, S = x.shape[0], ctx.n_rows
@@ -1244,66 +1256,62 @@ def _mamba_mixer(ctx, p, layer, x, ssm, conv):
     y = jnp.einsum("htu,uhp->thp", att.astype(x.dtype), v.astype(x.dtype),
                    preferred_element_type=f32)
 
-    # The carried states, **by slot**: the pool's layer is read where it lies and written
-    # back whole - every slot of it, the slots no row of this step names kept as they are
-    # (decayed by 1, nothing added) - so no copy of the step's S states is gathered and
-    # scattered back. What is a sequence row's is laid out a slot first; rows without a
-    # sequence all name padding's slot 0, whose state is nobody's. (On the chip the reduce
-    # below, the update and the write-back are still three passes over the layer's 541 MB,
-    # 5.4 ms of a decode step's 9 ms a layer, as the gather, update and scatter were: PERF.md
-    # section 7, PR 38.)
-    NS = ssm.shape[1]
-    here = ctx.length > 0
-
-    def by_slot(of_row, fill):
-        return jnp.full((NS,) + of_row.shape[1:], fill, of_row.dtype).at[slot].set(of_row)
-
-    start = by_slot(ctx.fresh, False)                                    # what it held is not read
-    carried = jnp.where(start[:, None, None, None], 0.0, ssm[layer]).reshape(NS, G, per, P, N)
-    # every sequence's first row of the step - its only row, in a decode step - all at once
-    # (a product and a sum over N beside the update below, in float32 as the state is: a
-    # dot would have the states copied in its operand's type first)
-    seen = jnp.sum(carried * by_slot(c[first_row], 0.0)[:, :, None, None, :], axis=-1)
-    seen = seen.reshape(NS, H, P)[slot]
-    seen = seen * jnp.where(here[:, None], jnp.exp(rows.since[first_row]), 0.0)[..., None]
-    y = y.at[first_row].add(seen)
-    left = jnp.where(here[:, None], jnp.exp(rows.until[first_row]), 0.0)[..., None] * v[first_row]
-    decay = by_slot(jnp.where(here[:, None], jnp.exp(rows.whole), 1.0), 1.0)
-    state = (carried * decay.reshape(NS, G, per, 1, 1)
-             + by_slot(jnp.where(here[:, None, None], left, 0.0), 0.0).reshape(NS, G, per, P, 1)
-             * by_slot(b[first_row], 0.0)[:, :, None, None, :])
-
-    # the sequences with further rows, MAMBA_ROUND a round
+    # The carried states, **one visit a slot**: every sequence's first row of the step - its
+    # only row, in a decode step - reads its slot's state, and the state the step leaves is
+    # written where it lay (``ops/pallas/ssm_state``: the pool aliased in and out of one
+    # kernel, which touches the slots this step's sequences own and nothing else; off the
+    # chip the same mathematics over the whole layer). The write is in place, so whatever
+    # reads a sequence's *prior* state comes before it and whatever adds to the new one after:
+    # the sequences with further rows, MAMBA_ROUND a round, twice over.
+    here = ctx.here
     multi = ctx.length > 1
-    n_multi = jnp.sum(multi.astype(jnp.int32))
+    n_rounds = (jnp.sum(multi.astype(jnp.int32)) + MAMBA_ROUND - 1) // MAMBA_ROUND
     order = jnp.concatenate([jnp.argsort(~multi, stable=True).astype(jnp.int32),
                              jnp.full((MAMBA_ROUND,), S - 1, jnp.int32)])
-    since = jnp.exp(rows.since).reshape(T, G, per, 1)
-    v_left = (jnp.exp(rows.until)[..., None] * v).reshape(T, G, per, P)
     row_ids = jnp.arange(T, dtype=jnp.int32)
     highest = jax.lax.Precision.HIGHEST
 
-    def further(r, acc):
-        y, state = acc
-        at = r * MAMBA_ROUND
-        chosen = jax.lax.dynamic_slice_in_dim(order, at, MAMBA_ROUND)            # [R]
-        valid = at + jnp.arange(MAMBA_ROUND) < n_multi
-        rows_of = ((seq[None, :] == chosen[:, None]) & valid[:, None]
-                   & (row_ids[None, :] != first_row[chosen][:, None])).astype(f32)   # [R, T]
-        reads = rows_of[:, :, None, None] * c[None]                              # [R, T, G, N]
+    def round_of(r):
+        """→ (the round's sequence rows [R], whose further rows each row of the batch is
+        [R, T]); a place past the last such sequence names padding's row and no row."""
+        chosen = jax.lax.dynamic_slice_in_dim(order, r * MAMBA_ROUND, MAMBA_ROUND)
+        rows_of = ((seq[None, :] == chosen[:, None]) & multi[chosen][:, None]
+                   & (row_ids[None, :] != first_row[chosen][:, None])).astype(f32)
+        return chosen, rows_of[:, :, None, None]
+
+    since = jnp.exp(rows.since).reshape(T, G, per, 1)
+
+    def reads(r, y):
         # their carried states, read from the pool again: these few. (Float32 operands as
         # they are: at the default precision XLA rounds the whole pool to bfloat16, a
         # round, before it gathers these from it.)
+        chosen, rows_of = round_of(r)
         prior = jnp.where(ctx.fresh[chosen][:, None, None, None], 0.0, ssm[layer, slot[chosen]])
-        y = y + (jnp.einsum("rtgn,rgapn->tgap", reads, prior.reshape(MAMBA_ROUND, G, per, P, N),
-                            precision=highest) * since).reshape(T, H, P)
-        writes = rows_of[:, :, None, None] * b[None]
-        return y, state.at[slot[chosen]].add(
-            jnp.einsum("rtgn,tgap->rgapn", writes, v_left, precision=highest))
+        return y + (jnp.einsum("rtgn,rgapn->tgap", rows_of * c[None],
+                               prior.reshape(MAMBA_ROUND, G, per, P, N),
+                               precision=highest) * since).reshape(T, H, P)
 
-    y, state = jax.lax.fori_loop(0, (n_multi + MAMBA_ROUND - 1) // MAMBA_ROUND, further,
-                                 (y, state))
-    ssm = ssm.at[layer].set(state.reshape(NS, H, P, N))
+    y = jax.lax.fori_loop(0, n_rounds, reads, y)
+
+    from deepspeed_tpu.ops.pallas import ssm_state
+    impl = ssm_state.state_step_impl(ssm.shape, G, S)
+    if ctx.choice is not None:
+        ctx.choice.state_step[T] = impl
+    step = ssm_state.ssm_state_step if impl == ssm_state.KERNEL else ssm_state.xla_ssm_state_step
+    ssm, seen = step(ssm, layer, slot, ctx.fresh, here, c[first_row], b[first_row],
+                     jnp.exp(rows.whole), jnp.exp(rows.until[first_row])[..., None] * v[first_row])
+    seen = seen * jnp.where(here[:, None], jnp.exp(rows.since[first_row]), 0.0)[..., None]
+    y = y.at[first_row].add(seen)
+
+    v_left = (jnp.exp(rows.until)[..., None] * v).reshape(T, G, per, P)
+
+    def writes(r, ssm):
+        chosen, rows_of = round_of(r)
+        return ssm.at[layer, slot[chosen]].add(
+            jnp.einsum("rtgn,tgap->rgapn", rows_of * b[None], v_left,
+                       precision=highest).reshape(MAMBA_ROUND, H, P, N))
+
+    ssm = jax.lax.fori_loop(0, n_rounds, writes, ssm)
 
     y = y + p["D"].astype(f32)[None, :, None] * xs
     y = (y.reshape(T, I) * jax.nn.silu(z.astype(f32))).reshape(T, G, I // G)

@@ -53,11 +53,15 @@ class AttentionChoice:
     pinned (``override``, None = choose), and ``selected`` — what each
     traced program actually got, keyed by the program's token count, so
     the engine can say which implementation serves and nothing has to be
-    inferred from the backend."""
+    inferred from the backend. ``state_step``: the same for the Mamba-2
+    state step of a model kind that has one (``pallas_ssm_state``, the
+    kernel that visits a step's slots in place, or ``xla``:
+    ``ops/pallas/ssm_state.state_step_impl``; nothing pins it)."""
 
     def __init__(self, override=None):
         self.override = override
         self.selected = {}
+        self.state_step = {}
 
 
 def register_implementation(op, name):

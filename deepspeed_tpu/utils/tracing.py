@@ -89,8 +89,8 @@ CPU_MARKED = frozenset(PREFIX + name for name in (
     "train.prepare", "train.dispatch", "train.sync", "train.post"))
 
 STEP_FIELDS = ("seq", "engine", "kind", "program", "k", "n_seqs", "n_tokens", "n_rows",
-               "n_prompt_tokens", "n_ctx_tokens", "counts", "caused_by", "uids", "start_ns",
-               "end_ns", "thread", "gc_ns", "gc_passes", "compile_ns", "compiles",
+               "n_prompt_tokens", "n_ctx_tokens", "counts", "state_step", "caused_by", "uids",
+               "start_ns", "end_ns", "thread", "gc_ns", "gc_passes", "compile_ns", "compiles",
                "waited_ns", "idle_passes")
 
 # JAX's own duration events (jax.monitoring) that count as compiling: the
@@ -253,6 +253,7 @@ class Recorder:
         # what the program itself counted on the device, by name, fetched with its result
         # (model_runner: kind.step_counts); None where the model kind counts nothing
         rec.counts = None
+        rec.state_step = None   # what serves the program's Mamba-2 state step, if it has one
         rec.uids = uids
         rec.phases, rec.cpu_marks, rec.keep, rec.end_ns = [], [], True, None
         rec.thread = threading.get_ident()

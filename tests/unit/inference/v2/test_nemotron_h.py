@@ -5,7 +5,9 @@ The served path keeps the attention layers' keys and values in paged
 pools and every Mamba-2 layer's state and convolution tail in a slot a
 sequence, takes a step's rows through a packed recurrence (a decay mask
 for a chunk's own rows, the carried state for all sequences' first rows
-at once and for further rows a few sequences a round) and the held picks
+at once - one visit of each slot, ``ops/pallas/ssm_state``: the tests of
+the mixer run it both ways, ``xla`` and the kernel interpreted - and for
+further rows a few sequences a round) and the held picks
 of the expert layer through a grouped matmul in the latent; the reference
 (``models/nemotron_h.reference_logits``) runs whole sequences, the
 recurrence a token at a time, every held expert on every token. They
@@ -63,6 +65,25 @@ def model():
 def engine(model):
     return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
                              rng=jax.random.PRNGKey(5))
+
+
+@pytest.fixture(scope="module")
+def kernel_engine(model):
+    """An engine whose programs are first run under ``DS_PALLAS=1``
+    (``state_step``'s second case), so that they hold the state step's
+    kernel, interpreted."""
+    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
+                             rng=jax.random.PRNGKey(5))
+
+
+@pytest.fixture(params=["xla", "pallas_ssm_state"])
+def state_step(request, monkeypatch):
+    """What serves the Mamba-2 state step in the test: the reference, or
+    the kernel (``DS_PALLAS=1`` forces the kernel paths, interpreted off
+    the chip)."""
+    if request.param != "xla":
+        monkeypatch.setenv("DS_PALLAS", "1")
+    return request.param
 
 
 @pytest.fixture(scope="module")
@@ -188,7 +209,9 @@ def test_decode_bursts_carry_every_state(engine, tokens):
     assert burst == greedy
 
 
-def test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_zero(engine, tokens):
+def test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_zero(
+        request, state_step, tokens):
+    engine = request.getfixturevalue("engine" if state_step == "xla" else "kernel_engine")
     assert engine.slot_pool.free_slots == engine.slot_pool.slots == 4
     serve(engine, [[(11, tokens[2][:30])]])
     slot = engine.state_manager.query(11).state_row[0]
@@ -198,6 +221,8 @@ def test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_ze
     seq = tokens[3][:32]
     rows = serve(engine, [[(12, seq[:2])], [(12, seq[2:31])], [(12, seq[31:32])]])[12]
     assert engine.state_manager.query(12).state_row[0] == slot           # the same slot
+    assert engine.last_step.state_step == state_step
+    assert set(engine.state_step_impls.values()) == {state_step}
     engine.flush(12)
     want = reference(engine, seq)
     assert max(rel_err(r, want[p]) for r, p in zip(rows, (1, 30, 31))) < TOL
@@ -226,7 +251,8 @@ def _pools(cfg, slots, fill):
 
 
 @pytest.mark.parametrize("chunk", [7, 64, 150])
-def test_a_prompt_in_chunks_leaves_the_state_and_the_tail_of_the_recurrence(engine, chunk):
+def test_a_prompt_in_chunks_leaves_the_state_and_the_tail_of_the_recurrence(engine, chunk,
+                                                                            state_step):
     """A prompt of 150 rows through ``M`` layer 1 in chunks of 7, of 64 and
     whole, in a slot that held ones: the same output rows, state and tail
     as the reference's token-by-token recurrence from zero."""
@@ -247,7 +273,7 @@ def test_a_prompt_in_chunks_leaves_the_state_and_the_tail_of_the_recurrence(engi
     assert np.asarray(ssm[layer, 1] == 1.0).all()                     # no other slot is touched
 
 
-def test_decode_rows_beside_chunks_in_one_step_each_from_its_own_state(engine):
+def test_decode_rows_beside_chunks_in_one_step_each_from_its_own_state(engine, state_step):
     """Five sequences' rows in one step - two decode rows, a prompt's
     first chunk, a later chunk of two rows and one of nine (three
     sequences with further rows: two rounds at MAMBA_ROUND 2) - each
@@ -287,6 +313,60 @@ def test_decode_rows_beside_chunks_in_one_step_each_from_its_own_state(engine):
         assert rel_err(conv[layer, slots[i]], tail[0]) < TOL, i
         at += n
     assert np.asarray(ssm[layer, 4] == 0.5).all()                     # a slot no row names
+
+
+def test_the_order_around_the_in_place_write_in_a_mixed_step(engine, state_step):
+    """One step of decode rows, later chunks of several rows, prompts that
+    start with several rows, sequence rows with no token and padding: the
+    further rows of a chunk read the state its sequence carried - the
+    *prior* one, though the step writes the new one where it lay - and add
+    to the one the step leaves; a prompt that starts here reads nothing of
+    what its slot held. Six sequences with further rows, so two rounds at
+    ``MAMBA_ROUND`` 4. State and ``y`` against the token-by-token
+    reference continued from what each sequence carried."""
+    cfg, layer = engine.model_config, 1
+    lp = jax.tree.map(lambda w: w[layer], engine.params["model"]["mamba_layers"])
+    key = jax.random.PRNGKey(11)
+    #          decode  chunk  fresh  decode  chunk  fresh  chunk  chunk  fresh (one row)
+    before = [17,     8,     0,     3,      21,    0,     5,     2,     0]
+    now = [1,         5,     4,     1,      3,     7,     2,     6,     1]
+    rows_of_batch = [0, 1, 2, 4, 5, 6, 7, 9, 10]          # sequence rows 3 and 8 hold nothing
+    slots = [7, 2, 9, 4, 11, 1, 6, 3, 10]
+    xs = [jax.random.normal(jax.random.fold_in(key, i), (b + n, cfg.hidden_size))
+          for i, (b, n) in enumerate(zip(before, now))]
+    ssm, conv = _pools(cfg, 12, 0.25)
+    want = []
+    with jax.default_matmul_precision("highest"):
+        for i, (b, n) in enumerate(zip(before, now)):
+            state = tail = None
+            if b:
+                _, state, tail = reference_mamba(lp, xs[i][None, :b], cfg)
+                ssm = ssm.at[layer, slots[i]].set(state[0])
+                conv = conv.at[layer, slots[i]].set(tail[0])
+            want.append(reference_mamba(lp, xs[i][None, b:], cfg, state, tail))
+    n_rows, pad = 12, 3
+    batch = _batch([(r, b, n) for r, b, n in zip(rows_of_batch, before, now)]
+                   + [(n_rows - 1, 0, 1)] * pad, n_rows, [])
+    state_rows = np.zeros((n_rows, 1), np.int32)
+    state_rows[rows_of_batch, 0] = slots
+    batch["seq_state"] = jnp.asarray(state_rows)
+    x = jnp.concatenate([xs[i][b:] for i, b in enumerate(before)]
+                        + [jnp.ones((pad, cfg.hidden_size))])
+    held = np.asarray(ssm)
+    y, ssm, conv = jax.jit(lambda x, ssm, conv: KIND.mamba_layer(
+        engine.params, cfg, layer, x, ssm, conv, batch), donate_argnums=(1, 2))(x, ssm, conv)
+    at = 0
+    for i, n in enumerate(now):
+        out, state, tail = want[i]
+        assert rel_err(y[at:at + n], out[0]) < TOL, i
+        assert rel_err(ssm[layer, slots[i]], state[0]) < TOL, i
+        assert rel_err(conv[layer, slots[i]], tail[0]) < TOL, i
+        at += n
+    # nothing else of the pool moved: the slots no sequence of the step owns - padding's
+    # slot 0, which the rows without a sequence name, among them - and the other layers
+    for slot in (0, 5, 8, 12):
+        assert np.array_equal(np.asarray(ssm[layer, slot]), held[layer, slot]), slot
+    assert np.array_equal(np.asarray(ssm[0]), held[0])
 
 
 def test_the_packed_rows_bookkeeping_sums_each_sequences_log_decays():

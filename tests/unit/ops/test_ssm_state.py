@@ -1,0 +1,180 @@
+"""The Mamba-2 state step (``ops/pallas/ssm_state``): the kernel,
+interpreted on the CPU, against ``xla_ssm_state_step`` - the same
+equations by slot over the whole layer - and what an in-place kernel must
+leave alone: every slot no live row names and every other layer, bit for
+bit.
+
+The kernel's read ``S c`` goes through the MXU as bfloat16 pieces (the
+state to 16 bits of mantissa: ~2.5e-6 of the read's norm); its update is
+the float32 product to a place or two, so the state is held to 1e-6.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.ops.pallas import ssm_state
+from deepspeed_tpu.ops.pallas.ssm_state import (kernel_supported, ssm_state_step,
+                                                xla_ssm_state_step)
+
+LM, NS, H, P, N, G = 3, 7, 8, 8, 128, 2
+S = 6                       # sequence rows a step; the last is padding's
+
+
+def rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
+
+
+def step_rows(live, fresh=(), seed=0):
+    """``live``: {sequence row: slot}; every other row names padding's slot
+    0 and is fresh, as the engine's rows without a sequence are."""
+    r = np.random.default_rng(seed)
+    slot, here, new = np.zeros(S, np.int32), np.zeros(S, bool), np.ones(S, bool)
+    for s, at in live.items():
+        slot[s], here[s], new[s] = at, True, s in fresh
+    return (jnp.asarray(slot), jnp.asarray(new), jnp.asarray(here),
+            jnp.asarray(r.standard_normal((S, G, N)), jnp.float32),
+            jnp.asarray(r.standard_normal((S, G, N)), jnp.float32),
+            jnp.asarray(r.uniform(0.5, 1.0, (S, H)), jnp.float32),
+            jnp.asarray(r.standard_normal((S, H, P)), jnp.float32))
+
+
+@pytest.fixture(scope="module")
+def pool():
+    return jax.random.normal(jax.random.PRNGKey(1), (LM, NS, H, P, N), jnp.float32)
+
+
+CASES = {"decode_only": ({0: 3, 1: 6, 2: 1, 3: 5, 4: 2}, ()),
+         "fresh_sequences": ({0: 4, 1: 2, 2: 6}, (0, 2)),
+         "absent_rows": ({1: 5, 3: 2}, (3,)),
+         "no_live_row": ({}, ())}
+
+
+@pytest.mark.parametrize("unit", ["mxu", "vpu"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_kernel_is_the_reference_and_touches_nothing_else(pool, case, unit):
+    live, fresh = CASES[case]
+    rows = step_rows(live, fresh)
+    layer = jnp.int32(1)
+    want_pool, want_seen = xla_ssm_state_step(pool, layer, *rows)
+    got_pool, got_seen = ssm_state_step(pool, layer, *rows, unit=unit, interpret=True)
+    pool, want_pool, got_pool = (np.asarray(x) for x in (pool, want_pool, got_pool))
+    named = sorted(live.values())
+    if named:
+        assert rel_err(got_pool[1, named], want_pool[1, named]) < 1e-6
+        assert rel_err(got_seen, want_seen) < (1e-5 if unit == "mxu" else 1e-6)
+    absent = [s for s in range(S) if s not in live]
+    assert not np.asarray(got_seen)[absent].any() and not np.asarray(want_seen)[absent].any()
+    # in place: slots no live row names (padding's slot 0 among them), and every other
+    # layer, are bitwise what they were - in the reference too
+    others = [s for s in range(NS) if s not in named]
+    for new in (got_pool, want_pool):
+        assert np.array_equal(new[1, others], pool[1, others])
+        assert np.array_equal(new[[0, 2]], pool[[0, 2]])
+
+
+def test_a_fresh_sequence_never_sees_what_its_slot_held(pool):
+    """NaNs in a slot whose next owner starts here: nothing of them in
+    what it reads or leaves."""
+    rows = step_rows({0: 4, 1: 2}, fresh=(0,))
+    stale = pool.at[2, 4].set(jnp.nan)
+    new, seen = ssm_state_step(stale, jnp.int32(2), *rows, interpret=True)
+    assert np.isfinite(np.asarray(seen)).all() and np.isfinite(np.asarray(new[2, 4])).all()
+    assert not np.asarray(seen[0]).any()
+
+
+def test_the_layer_may_be_traced_inside_a_scan(pool):
+    """As the step programs have it: the pool the scan's carry, the layer
+    its counter, the call jitted around both."""
+    rows = step_rows({0: 3, 2: 1, 4: 6}, fresh=(2,))
+
+    def through(step):
+        def run(pool):
+            return jax.lax.scan(lambda pool, layer: step(pool, layer, *rows), pool,
+                                jnp.arange(LM, dtype=jnp.int32))
+        return jax.jit(run)(pool)
+
+    want_pool, want_seen = through(xla_ssm_state_step)
+    got_pool, got_seen = through(lambda *a: ssm_state_step(*a, interpret=True))
+    assert rel_err(got_pool, want_pool) < 1e-6 and rel_err(got_seen, want_seen) < 1e-5
+    others = [0, 2, 4, 5]
+    assert np.array_equal(np.asarray(got_pool)[:, others], np.asarray(pool)[:, others])
+
+
+@pytest.mark.parametrize("shape,groups,rows,ok", [
+    ((5, 129, 128, 64, 128), 8, 129, True),         # nemotron3-super-agents
+    ((2, 5, 8, 8, 128), 2, 5, True),
+    ((2, 5, 8, 8, 16), 2, 5, False),                # N is not whole 128-lane vregs
+    ((2, 5, 8, 12, 128), 2, 5, False),              # P is not whole sublane tiles
+    ((2, 5, 8, 8, 128), 3, 5, False),               # heads do not divide into groups
+    ((5, 129, 128, 64, 128), 1, 129, False),        # a 4 MB tile four times over the budget
+    ((5, 2049, 128, 64, 128), 8, 2049, False),      # the rows' decays overflow SMEM
+])
+def test_kernel_supported_refuses_what_it_cannot_tile(shape, groups, rows, ok):
+    assert kernel_supported(shape, groups, rows) is ok
+    if not ok:
+        args = (jax.ShapeDtypeStruct(shape, jnp.float32), jax.ShapeDtypeStruct((), jnp.int32),
+                *(jax.ShapeDtypeStruct(s, d) for s, d in (
+                    ((rows,), jnp.int32), ((rows,), jnp.bool_), ((rows,), jnp.bool_),
+                    ((rows, groups, shape[4]), jnp.float32), ((rows, groups, shape[4]), jnp.float32),
+                    ((rows, shape[2]), jnp.float32), ((rows, shape[2], shape[3]), jnp.float32))))
+        with pytest.raises(ValueError, match="state step kernel needs"):
+            jax.eval_shape(lambda *a: ssm_state_step(*a, interpret=False), *args)
+
+
+def test_the_choice_follows_the_backend_and_the_shapes(monkeypatch):
+    shape = (5, 129, 128, 64, 128)
+    monkeypatch.delenv("DS_PALLAS", raising=False)
+    assert ssm_state.state_step_impl(shape, 8, 129) == "xla"            # not a TPU
+    monkeypatch.setenv("DS_PALLAS", "1")                               # interpreted: any shape
+    assert ssm_state.state_step_impl(shape, 8, 129) == "pallas_ssm_state"
+    assert ssm_state.state_step_impl((2, 5, 8, 8, 16), 2, 5) == "pallas_ssm_state"
+    # compiled, the shapes decide
+    import deepspeed_tpu.ops.pallas as kernels
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    assert ssm_state.state_step_impl(shape, 8, 129) == "pallas_ssm_state"
+    assert ssm_state.state_step_impl((2, 5, 8, 8, 16), 2, 5) == "xla"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_mosaic_takes_the_cells_shape_and_the_pool_is_aliased(one_chip):
+    """Compiled for a described v5e (nothing runs): the kernel lowers at
+    ``nemotron3-super-agents``' shape, the pool comes back as the buffer it
+    came in and the program holds no temporary of its size."""
+    shape, rows, groups = (5, 129, 128, 64, 128), 129, 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(shape, jnp.float32), sds((), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows,), jnp.bool_), sds((rows,), jnp.bool_),
+            sds((rows, groups, 128), jnp.float32), sds((rows, groups, 128), jnp.float32),
+            sds((rows, 128), jnp.float32), sds((rows, 128, 64), jnp.float32))
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(lambda *a: ssm_state_step(*a, interpret=False),
+                           donate_argnums=0).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    memory = compiled.memory_analysis()
+    pool_bytes = int(np.prod(shape)) * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 100
+    assert "ssm_state_step" in compiled.as_text()

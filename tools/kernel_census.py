@@ -55,6 +55,13 @@ alone in a call as wide as they are, and from the two **us a padding row a
 layer beside us a live row** - for the kernel, which is told the live rows, and
 for the parent checkout's, which is not (PERF.md, PR 37).
 
+``--ssm`` times ``ssm_state_step`` at ``nemotron3-super-agents``' shape (5
+layers of 129 slots of 128 x 64 x 128 float32, the layer traced in a scan, the
+pool donated) with 32, 64 and 128 live sequences: ms a layer, GB/s of the
+bytes a step has to move (a live slot once in and once out), share of 819 -
+the MXU form, the plain float32 lane sum, the copies alone, and
+``xla_ssm_state_step``'s three passes beside them (PERF.md, PR 39; ~1 min).
+
 Prints one JSON line per kernel and writes ``chiprun_out/kernel_census.json``.
 """
 
@@ -520,6 +527,78 @@ def live_rows_sweep(parent_dir, shares):
         yield name, record
 
 
+SSM_LIVE = (32, 64, 128)
+SSM_SHAPE = (5, 128, 128, 64, 128, 8)   # M layers, slots, heads, P, N, groups
+
+
+def ssm_state_classes():
+    """Yields one record a number of live sequences: the state step at
+    ``nemotron3-super-agents``' shape (5 ``M`` layers of 128 + 1 slots of
+    128 x 64 x 128 float32 in 8 groups, 129 sequence rows), **all five
+    layers a call** with the layer traced inside a ``lax.scan`` as the step
+    programs have it and the pool donated: the kernel with each unit, and
+    ``xla_ssm_state_step`` beside it. ms a layer, GB/s of the bytes the
+    step has to move (``live x 2 x H x P x N x 4``), share of 819."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import ssm_state as ss
+
+    Lm, slots, H, P, N, G = SSM_SHAPE
+    S = slots + 1
+    rng = np.random.default_rng(39)
+    fill = jax.jit(lambda key: jax.random.normal(key, (Lm, S, H, P, N), jnp.float32))
+
+    def layers(step):
+        def run(pool, *rows):
+            def one(pool, layer):
+                return step(pool, layer, *rows)
+            return jax.lax.scan(one, pool, jnp.arange(Lm, dtype=jnp.int32))
+        return jax.jit(run, donate_argnums=0)
+
+    for live in SSM_LIVE:
+        slot, here, fresh = np.zeros(S, np.int32), np.zeros(S, bool), np.ones(S, bool)
+        slot[:live] = rng.permutation(np.arange(1, S))[:live]
+        here[:live], fresh[:live] = True, False
+        fresh[:live:32] = True                   # a sequence in 32 starts here
+        rows = (jnp.asarray(slot), jnp.asarray(fresh), jnp.asarray(here),
+                jnp.asarray(rng.standard_normal((S, G, N)), jnp.float32),
+                jnp.asarray(rng.standard_normal((S, G, N)), jnp.float32),
+                jnp.asarray(rng.uniform(0.9, 1.0, (S, H)), jnp.float32),
+                jnp.asarray(rng.standard_normal((S, H, P)) * 0.1, jnp.float32))
+        least = live * 2 * H * P * N * 4
+        record = {"live": live, "least_bytes_a_layer": least}
+        want_pool, want_seen = layers(ss.xla_ssm_state_step)(fill(jax.random.PRNGKey(live)), *rows)
+        named = np.asarray(slot[:live:max(live // 8, 1)])      # the slots compared
+
+        def timed(step, calls=20):
+            try:
+                call = layers(step)
+                pool = fill(jax.random.PRNGKey(live))
+                pool, seen = call(pool, *rows)
+                err = max(rel_err(seen, want_seen), rel_err(pool[:, named], want_pool[:, named]))
+                jax.block_until_ready(pool)
+                t0 = time.perf_counter()
+                for _ in range(calls):
+                    pool, seen = call(pool, *rows)
+                jax.block_until_ready((pool, seen))
+                ms = (time.perf_counter() - t0) * 1e3 / calls / Lm
+                return {"ms_a_layer": ms, "gb_s": least / ms / 1e6,
+                        "hbm_share": 100 * least / ms / 1e6 / HBM_GB_S,
+                        "rel_err": float(f"{err:.3e}")}
+            except Exception as e:  # a refusal is a record too
+                return {"refused": f"{type(e).__name__}: {e}"[:600]}
+
+        for unit in ("mxu", "vpu", "none"):
+            record[unit] = timed(lambda *a, unit=unit: ss.ssm_state_step(*a, unit=unit,
+                                                                         interpret=False))
+        if live == SSM_LIVE[-1]:
+            record["xla"] = timed(ss.xla_ssm_state_step, calls=5)
+        yield f"ssm-state-{live}", record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -548,8 +627,10 @@ def main():
     paged, mla = "--paged" in sys.argv, "--mla" in sys.argv
     parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
-    live = "--live" in sys.argv
-    if live:
+    live, ssm = "--live" in sys.argv, "--ssm" in sys.argv
+    if ssm:
+        section, records = "ssm_state", ssm_state_classes()
+    elif live:
         shares = [float(x) for x in sys.argv[sys.argv.index("--live") + 1].split(",")]
         section, records = "live_rows", live_rows_sweep(parent_dir, shares)
     elif paged:
@@ -561,7 +642,8 @@ def main():
     for name, record in records:
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
-    for name, fn, ref, args, tol in (() if live or paged or mla or "--gmm-only" in sys.argv
+    for name, fn, ref, args, tol in (() if ssm or live or paged or mla
+                                     or "--gmm-only" in sys.argv
                                      else cases()):
         try:
             result = verdict(fn, ref, args, tol)
@@ -570,7 +652,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("live_census.json" if live else "paged_census.json" if paged
+    out = ("ssm_census.json" if ssm else "live_census.json" if live
+           else "paged_census.json" if paged
            else "mla_census.json" if mla else "kernel_census.json")
     with open(os.path.join("chiprun_out", out), "w") as f:
         json.dump(report, f, indent=1)
