@@ -23,11 +23,12 @@ consumes the padded flat batch —
   serves directly;
 - what differs between model families sits in one object each
   (:class:`LlamaKind`, :class:`GPTKind`, :class:`MoonlightKind`,
-  :class:`LongcatKind`, :class:`SalaKind`, :class:`NemotronHKind`;
-  :func:`kind_of` picks by the config's type): the state the pool holds
-  and how many layers of it, the layer step, the layer pattern (leading
-  layers, then the scan — or, for a stack of several kinds of layer,
-  :meth:`SalaKind.stack` and :meth:`NemotronHKind.stack`), what state it
+  :class:`LongcatKind`, :class:`SalaKind`, :class:`NemotronHKind`,
+  :class:`Lfm2Kind`; :func:`kind_of` picks by the config's type): the state
+  the pool holds and how many layers of it, the layer step, the layer
+  pattern (leading layers, then the scan — or, for a stack of several
+  kinds of layer, :meth:`SalaKind.stack` and, for :class:`NemotronHKind`
+  and :class:`Lfm2Kind`, :func:`_run_segments`), what state it
   keeps beyond the two paged pools, what a step counts on the device, and
   the final norm. :func:`ragged_forward` is the same for all.
 """
@@ -1011,6 +1012,42 @@ def _sala_sparse_layer(ctx, lp, layer, h, kc, vc, kb, attn_impl):
     return _sala_mlp(cfg, lp, h), kc, vc, kb
 
 
+def _run_segments(segments, counters_of, layer, carry):
+    """A stack of several kinds of layer, run as its config's ``segments``
+    cut it (``[(unit, repeats), ...]`` over a letter a layer): **one scan
+    over a period** wherever a unit of layers repeats, its body the unit's
+    layers in order; single layers elsewhere. A layer reads its own
+    parameters out of its kinds' whole stacks, so it is told where:
+    ``counters_of(letter)`` names the stacks a layer of that letter draws
+    from (one for a layer that is one sublayer, an operator's and a
+    feed-forward's for a layer that is both), and ``layer(letter, at,
+    carry)`` → carry is given ``at[name]``, the layer's index in each (a
+    traced value inside a scan). → (carry, the layers run of each stack)."""
+    names = sorted({name for unit, _ in segments for t in unit for name in counters_of(t)})
+    done = dict.fromkeys(names, 0)
+    for unit, repeats in segments:
+        base = dict(done)
+        per = {name: sum(name in counters_of(t) for t in unit) for name in names}
+
+        def period(carry, r, unit=unit, base=base, per=per):
+            seen = dict.fromkeys(names, 0)
+            for letter in unit:
+                at = {name: base[name] + r * per[name] + seen[name]
+                      for name in counters_of(letter)}
+                carry = layer(letter, at, carry)
+                for name in at:
+                    seen[name] += 1
+            return carry, None
+
+        if repeats == 1:
+            carry, _ = period(carry, 0)
+        else:
+            carry, _ = jax.lax.scan(period, carry, jnp.arange(repeats, dtype=jnp.int32))
+        for name in names:
+            done[name] += per[name] * repeats
+    return carry, done
+
+
 class NemotronHKind:
     """Nemotron-H (``models/nemotron_h.py``): **every layer one sublayer
     alone** - a Mamba-2 mixer, an attention or an expert layer, as the
@@ -1077,7 +1114,7 @@ class NemotronHKind:
         if lora is not None or mesh is not None:
             raise NotImplementedError("the Nemotron-H layer stack serves base-only on one device")
         model = params["model"]
-        ctx = _NemotronStep(cfg, batch, attn_impl)
+        ctx = _SlotStep(cfg, batch, attn_impl)
         moe = model.get("moe_layers", {})
         experts = moe.get("experts")
         stacks = {"M": model.get("mamba_layers"), "*": model.get("attn_layers"),
@@ -1100,23 +1137,9 @@ class NemotronHKind:
             return h + y, kc, vc, ssm, conv, picks
 
         carry = (h, kc, vc, extra["ssm"], extra["conv"], jnp.zeros((3,), jnp.int32))
-        done = dict.fromkeys("ME*", 0)
-        for unit, repeats in cfg.segments:
-            base, per = dict(done), {t: unit.count(t) for t in "ME*"}
-
-            def period(carry, r, unit=unit, base=base, per=per):
-                seen = dict.fromkeys("ME*", 0)
-                for letter in unit:
-                    carry = layer(letter, base[letter] + r * per[letter] + seen[letter], carry)
-                    seen[letter] += 1
-                return carry, None
-
-            if repeats == 1:
-                carry, _ = period(carry, 0)
-            else:
-                carry, _ = jax.lax.scan(period, carry, jnp.arange(repeats, dtype=jnp.int32))
-            for t in "ME*":
-                done[t] += per[t] * repeats
+        carry, done = _run_segments(cfg.segments, lambda letter: (letter,),
+                                    lambda letter, at, carry: layer(letter, at[letter], carry),
+                                    carry)
         h, kc, vc, ssm, conv, picks = carry
         real = ctx.real.astype(jnp.int32)
         live = jnp.sum(ctx.here.astype(jnp.int32))
@@ -1152,10 +1175,10 @@ class NemotronHKind:
         check that wants it without the rest: x [T, D] the normalised
         stream → (y [T, D], ssm, conv)."""
         lp = jax.tree.map(lambda w: w[layer], params["model"]["mamba_layers"])
-        return _mamba_mixer(_NemotronStep(cfg, batch), lp, layer, x, ssm, conv)
+        return _mamba_mixer(_SlotStep(cfg, batch), lp, layer, x, ssm, conv)
 
 
-class _NemotronStep:
+class _SlotStep:
     """What every layer of one step shares: each token's sequence row and
     position, each sequence row's slot, and where its rows lie in the
     step; ``choice``, the engine's ``heuristics.AttentionChoice`` (None =
@@ -1170,13 +1193,51 @@ class _NemotronStep:
         T = self.seq.shape[0]
         # a sequence's rows are one run of the batch, positions ascending (the wrapper
         # appends a chunk at a time): row ``first_row + j`` is its j-th of this step
-        first, self.length = _row_spans(self.seq, self.pos, S)
-        self.fresh = first == 0         # (or no row at all): what the slot held is not read
+        self.first, self.length = _row_spans(self.seq, self.pos, S)
+        self.fresh = self.first == 0    # (or no row at all): what the slot held is not read
         # the sequence rows with a token in this step: their slots' states are read and
         # written; padding's row (the last) owns none
         self.here = (self.length > 0) & (jnp.arange(S) < S - 1)
         self.first_row = jnp.minimum(
             jnp.full((S,), T, jnp.int32).at[self.seq].min(jnp.arange(T, dtype=jnp.int32)), T - 1)
+
+
+def _conv_with_tail(stream, kernel, bias, pool, layer, rows):
+    """A depth-wise causal convolution over the flat ragged batch whose
+    **tail is a slot**: stream [T, C], kernel [K, C] (tap ``j`` reads ``K -
+    1 - j`` rows back), ``bias`` [C] or None, ``pool`` [L, slots + 1, K - 1,
+    C] the carried tails, ``layer`` the pool's layer, ``rows`` the step's
+    :class:`_SlotStep`. A row fewer than ``K - 1`` rows into its
+    sequence's rows of this step reads the rows before them out of the
+    tail its sequence carried in its slot (zero at position 0); every
+    sequence leaves the tail of what it has now seen, the last ``K - 1``
+    rows of its stream. → (filtered [T, C] float32, pool). What enters
+    the stream before (a gate) and what follows (an activation, a gate)
+    are the caller's."""
+    T, C = stream.shape
+    K = kernel.shape[0]
+    f32 = jnp.float32
+    seq, slot, first_row = rows.seq, rows.slot, rows.first_row
+    tail = jnp.where(rows.fresh[:, None, None], 0, pool[layer, slot])    # [S, K - 1, C]
+    rank = jnp.arange(T, dtype=jnp.int32) - first_row[seq]     # a row's index in its chunk
+    acc = None if bias is None else bias.astype(f32)[None, :]
+    kernel = kernel.astype(f32)
+    for j in range(K):
+        back = K - 1 - j                                     # tap j reads `back` rows back
+        tap = stream if back == 0 else jnp.concatenate(
+            [jnp.zeros((min(back, T), C), stream.dtype), stream[:max(T - back, 0)]], axis=0)
+        if back:
+            carried_row = tail[seq, jnp.clip(rank + j, 0, K - 2)]       # the tail's row rank + j
+            tap = jnp.where((rank >= back)[:, None], tap, carried_row)
+        term = kernel[j][None, :] * tap.astype(f32)
+        acc = term if acc is None else acc + term
+    i = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+    into = rows.length[:, None] - (K - 1) + i                # [S, K - 1]: chunk row, or < 0
+    kept = jnp.take_along_axis(tail, jnp.clip(rows.length[:, None] + i, 0, K - 2)[..., None],
+                               axis=1)
+    new_tail = jnp.where((into >= 0)[..., None],
+                         stream[jnp.clip(first_row[:, None] + into, 0, T - 1)], kept)
+    return acc, pool.at[layer, slot].set(new_tail.astype(pool.dtype))
 
 
 MAMBA_ROUND = 4     # sequences with more than one row in a step, taken this many at a time
@@ -1212,35 +1273,15 @@ def _mamba_mixer(ctx, p, layer, x, ssm, conv):
     throughout; the matmuls at the default precision."""
     cfg = ctx.cfg
     T, S = x.shape[0], ctx.n_rows
-    H, P, G, N, K = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size,
-                     cfg.conv_kernel)
+    H, P, G, N = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size
     I, C, per = cfg.mamba_inner, cfg.conv_dim, cfg.mamba_num_heads // cfg.n_groups
     f32 = jnp.float32
     seq, pos, slot, first_row = ctx.seq, ctx.pos, ctx.slot, ctx.first_row
     zxbcdt = _proj(x, p["in_proj"])
     z, xbc, dt = zxbcdt[:, :I], zxbcdt[:, I:I + C], zxbcdt[:, I + C:]
 
-    # ---- the convolution and its tail
-    tail = jnp.where(ctx.fresh[:, None, None], 0, conv[layer, slot])     # [S, K - 1, C]
-    rank = jnp.arange(T, dtype=jnp.int32) - first_row[seq]     # a row's index in its chunk
-    acc = p["conv_bias"].astype(f32)[None, :]
-    kernel = p["conv_kernel"].astype(f32)
-    for j in range(K):
-        back = K - 1 - j                                     # tap j reads `back` rows back
-        tap = xbc if back == 0 else jnp.concatenate(
-            [jnp.zeros((back, C), xbc.dtype), xbc[:T - back]], axis=0)
-        if back:
-            carried_row = tail[seq, jnp.clip(rank + j, 0, K - 2)]       # the tail's row rank + j
-            tap = jnp.where((rank >= back)[:, None], tap, carried_row)
-        acc = acc + kernel[j][None, :] * tap.astype(f32)
+    acc, conv = _conv_with_tail(xbc, p["conv_kernel"], p["conv_bias"], conv, layer, ctx)
     act = jax.nn.silu(acc)
-    i = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-    into = ctx.length[:, None] - (K - 1) + i                 # [S, K - 1]: chunk row, or < 0
-    kept = jnp.take_along_axis(tail, jnp.clip(ctx.length[:, None] + i, 0, K - 2)[..., None],
-                               axis=1)
-    new_tail = jnp.where((into >= 0)[..., None],
-                         xbc[jnp.clip(first_row[:, None] + into, 0, T - 1)], kept)
-    conv = conv.at[layer, slot].set(new_tail.astype(conv.dtype))
 
     # ---- the recurrence
     xs = act[:, :I].reshape(T, H, P)
@@ -1378,13 +1419,220 @@ def _nemotron_moe(cfg, real, p, experts, layer, x):
         return y + _proj(relu2(_proj(x, s["up_proj"])), s["down_proj"]), counts
 
 
+class Lfm2Kind:
+    """LFM2-MoE (``models/lfm2.py``): **every layer an operator and a
+    feed-forward** - the operator a gated short convolution or a
+    grouped-query attention, as ``layer_types`` says; the feed-forward a
+    dense SwiGLU in the leading layers, whole experts behind a biased
+    sigmoid router after them - and state of two kinds side by side.
+
+    - The ``full_attention`` operators keep keys and values (normalised a
+      head, rotated) in the engine's two paged pools, ``[La, NB, bs, Hkv *
+      d]``; the ``conv`` layers hold nothing there.
+    - ``extra_state``'s ``conv`` ``[Lc, slots + 1, K - 1, D]`` in the
+      stream's dtype: a ``conv`` operator's state a sequence, **the last
+      ``K - 1`` rows of the gated stream** before the convolution, the same
+      at token 10 and at token 100,000. A tracked sequence owns a slot
+      (``ragged/slot_pool.py``, ``slot_state``); slot 0 is padding's. A
+      sequence's first rows (position 0) take it as zero, so a slot needs
+      no clearing between owners. The carry is :func:`_conv_with_tail`,
+      the one Nemotron-H's Mamba mixers use.
+
+    :meth:`stack` runs ``cfg.segments`` through :func:`_run_segments`: the
+    leading layers one by one, one scan a period. The experts of every
+    layer ride each step whole, one table of ``Le x E`` groups, and every
+    pick is held (a share of all the router's columns: a padding token
+    picks nothing and launches no group). Each step counts, over its
+    tokens that are not padding: the picks (all held), the zero-compute
+    picks (none: the name is the expert readers'), the experts with at
+    least one row, the rows through the ``conv`` operators, the (sequence,
+    ``conv`` layer)s whose tail it read and wrote, and - once a step, not a
+    layer - ``n_ctx_seq_tokens``: over the step's sequences, the context
+    positions each attends to, counted **once a sequence** however many
+    rows it has in the step: the least any implementation of an attention
+    layer must fetch."""
+    name = "lfm2"
+    state_kind = "kv+slots"
+    lora = False
+    step_counts = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_conv_rows",
+                   "n_tail_slots", "n_ctx_seq_tokens")
+    seq_rows = 1            # per-sequence rows of the batch: (slot,)
+    slot_state = ("conv",)  # the entries of extra_state a slot is a row of
+    state_rows = NemotronHKind.state_rows
+    seq_state = NemotronHKind.seq_state
+
+    @staticmethod
+    def state_layers(cfg):
+        return max(1, cfg.count("full_attention"))
+
+    @staticmethod
+    def extra_state(cfg, num_blocks, slots, dtype):
+        """→ the tree of state beyond the two paged pools (zeros)."""
+        return {"conv": jnp.zeros((cfg.count("conv"), slots + 1, cfg.conv_L_cache - 1,
+                                   cfg.hidden_size), dtype)}
+
+    @staticmethod
+    def _counters(letter):
+        """The stacks a layer of ``cfg.letters``' letter draws from."""
+        return ("conv" if letter in "cC" else "attn", "dense" if letter.isupper() else "moe")
+
+    @staticmethod
+    def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+        if lora is not None or mesh is not None:
+            raise NotImplementedError("the LFM2 layer stack serves base-only on one device")
+        model = params["model"]
+        ctx = _SlotStep(cfg, batch)
+        moe = model.get("moe_ffn", {})
+        experts = moe.get("experts")
+        stacks = {"conv": model.get("conv_layers"), "attn": model.get("attn_layers"),
+                  "dense": model.get("dense_ffn"),
+                  "moe": {k: v for k, v in moe.items() if k != "experts"}}
+
+        def layer(letter, at, carry):
+            h, kc, vc, conv, picks = carry
+            op, ffn = Lfm2Kind._counters(letter)
+            lp = jax.tree.map(lambda w: w[at[op]], stacks[op])
+            x = _rms(h, lp["operator_norm"]["scale"], cfg.norm_eps)
+            if op == "conv":
+                with jax.named_scope("ds.lfm2.conv"):
+                    y, conv = _lfm2_conv(ctx, lp, at[op], x, conv)
+            else:
+                with jax.named_scope("ds.lfm2.attn"):
+                    y, kc, vc = _lfm2_attention(cfg, lp, at[op], x, kc, vc, batch, attn_impl)
+            h = h + y
+            fp = jax.tree.map(lambda w: w[at[ffn]], stacks[ffn])
+            x = _rms(h, fp["ffn_norm"]["scale"], cfg.norm_eps)
+            if ffn == "dense":
+                with jax.named_scope("ds.dense_ffn"):
+                    y = _swiglu(x, fp)
+            else:
+                y, n = _lfm2_moe(cfg, ctx.real, fp, experts, at[ffn], x)
+                picks = picks + n
+            return h + y, kc, vc, conv, picks
+
+        carry = (h, kc, vc, extra["conv"], jnp.zeros((3,), jnp.int32))
+        (h, kc, vc, conv, picks), done = _run_segments(cfg.segments, Lfm2Kind._counters, layer,
+                                                       carry)
+        here = ctx.here.astype(jnp.int32)
+        counts = jnp.concatenate([picks, jnp.stack([
+            done.get("conv", 0) * jnp.sum(ctx.real.astype(jnp.int32)),
+            done.get("conv", 0) * jnp.sum(here),
+            jnp.sum(here * (ctx.first + ctx.length))])]).astype(jnp.int32)
+        return h, kc, vc, {"conv": conv}, counts[None]
+
+    @staticmethod
+    def experts_form(params, mesh):
+        return "table" if "moe_ffn" in params["model"] else None
+
+    @staticmethod
+    def final_norm(params, cfg, h):
+        return _rms(h, params["model"]["embedding_norm"]["scale"], cfg.norm_eps)
+
+    @staticmethod
+    def conv_layer(params, cfg, layer, x, conv, batch):
+        """``conv`` operator ``layer`` alone (its index among the ``conv``
+        layers), as the step programs compute it - the same gates, the same
+        reads and writes of the slot pool - for a check that wants it
+        without the rest: x [T, D] the normalised stream → (y [T, D],
+        conv)."""
+        lp = jax.tree.map(lambda w: w[layer], params["model"]["conv_layers"])
+        return _lfm2_conv(_SlotStep(cfg, batch), lp, layer, x, conv)
+
+    @staticmethod
+    def attention_layer(params, cfg, layer, x, kc, vc, batch, attn_impl=None):
+        """``full_attention`` operator ``layer`` alone (its index among the
+        attention layers), as the step programs compute it - the same
+        norms, rotation, writes into the pools and paged attention: x
+        [T, D] the normalised stream → (y [T, D], kc, vc)."""
+        lp = jax.tree.map(lambda w: w[layer], params["model"]["attn_layers"])
+        return _lfm2_attention(cfg, lp, layer, x, kc, vc, batch, attn_impl)
+
+    @staticmethod
+    def expert_layer(params, cfg, layer, x):
+        """Expert feed-forward ``layer`` (its index among the expert
+        layers; may be traced) as the step programs compute it - the same
+        router, the table of every layer's experts read in place - for a
+        check that wants it alone: x [T, D] the normalised stream, every
+        row a token → y."""
+        moe = params["model"]["moe_ffn"]
+        fp = jax.tree.map(lambda w: w[layer], {k: v for k, v in moe.items() if k != "experts"})
+        return _lfm2_moe(cfg, jnp.ones(x.shape[0], bool), fp, moe["experts"], layer, x)[0]
+
+
+def _lfm2_conv(rows, p, layer, x, conv):
+    """One gated short convolution on the normalised stream x [T, D]:
+    ``[B | C | x'] = x W_in``; ``g = B * x'`` enters the convolution, whose
+    tail is the sequence's slot (:func:`_conv_with_tail`: no bias, no
+    activation); ``C`` gates what leaves it. → (y [T, D], conv)."""
+    D = x.shape[1]
+    bcx = _proj(x, p["in_proj"])
+    gated = bcx[:, :D] * bcx[:, 2 * D:]
+    v, conv = _conv_with_tail(gated, p["conv_kernel"], None, conv, layer, rows)
+    y = (bcx[:, D:2 * D].astype(jnp.float32) * v).astype(x.dtype)
+    return _proj(y, p["out_proj"]), conv
+
+
+def _lfm2_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
+    """One ``full_attention`` operator on the normalised stream:
+    grouped-query attention over the paged pool's layer ``layer``, queries
+    and keys normalised a head (one scale over every head's ``d``) and
+    rotated in halves. → (y, kc, vc)."""
+    T = x.shape[0]
+    Hq, Hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q = _rms(_proj(x, p["q_proj"]).reshape(T, Hq, d), p["q_layernorm"]["scale"], cfg.norm_eps)
+    k = _rms(_proj(x, p["k_proj"]).reshape(T, Hkv, d), p["k_layernorm"]["scale"], cfg.norm_eps)
+    v = _proj(x, p["v_proj"]).reshape(T, Hkv, d)
+    pos = batch["token_pos"]
+    q, k = _rope_at(q, pos, cfg.rope_theta), _rope_at(k, pos, cfg.rope_theta)
+    out, kc, vc = _paged_attend(q, k, v, kc, vc, layer, batch, d, impl=attn_impl)
+    return _proj(out.reshape(T, Hq * d), p["out_proj"]), kc, vc
+
+
+def _lfm2_moe(cfg, real, p, experts, layer, x):
+    """One expert feed-forward on the normalised stream, and its three
+    counts; ``real`` [T]: the rows that are not padding. Moonlight's router
+    without its shared experts: sigmoid scores in float32 (the matmul at
+    the highest precision: the picks are a step function of it); the
+    ``num_experts_per_tok`` columns with the largest score + ``expert_bias``,
+    weighted by their unbiased scores over their sum, times
+    ``routed_scaling_factor``. Every pick through the grouped matmul over
+    the table of every layer's experts (``ops/grouped_gemm.dropless_moe_ffn``;
+    the share is all the router's columns, so that a padding token's picks,
+    -1, are rows of no group). → (y [T, D], int32 [3]: picks held, picks
+    zero-compute (none), experts with a row - over the tokens that are not
+    padding)."""
+    from deepspeed_tpu.models.lfm2 import TOPK_EPS
+    from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn
+    gate = p["gate"]
+    with jax.named_scope("ds.moe_routed"):
+        scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), gate["weight"].astype(jnp.float32),
+                                        precision=jax.lax.Precision.HIGHEST))
+        _, topk_idx = jax.lax.top_k(scores + gate["expert_bias"].astype(jnp.float32),
+                                    cfg.num_experts_per_tok)
+        topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1)
+        topk_vals = (topk_vals / (topk_vals.sum(-1, keepdims=True) + TOPK_EPS)
+                     * cfg.routed_scaling_factor)
+        share = ExpertShare(0, cfg.num_experts, cfg.num_experts)
+        topk_idx = jnp.where(real[:, None], topk_idx, -1)         # a padding token picks nothing
+        table, first_group = _layer_groups(experts, layer)
+        y = dropless_moe_ffn(x, topk_idx, topk_vals, table["gate_proj"], table["up_proj"],
+                             table["down_proj"], num_experts=share.routed,
+                             widen_boundary=False, first_group=first_group, share=share)
+        held, zero = share.parts(topk_idx)
+        live = jnp.any(topk_idx[..., None] == jnp.arange(share.held), axis=(0, 1))
+        return y, jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
+
+
 def kind_of(cfg):
     """The model kind of a config, by its type: one of the kinds this
     module defines, each of which names its config class in its docstring."""
+    from deepspeed_tpu.models.lfm2 import Lfm2MoeConfig
     from deepspeed_tpu.models.longcat import LongcatFlashConfig
     from deepspeed_tpu.models.minicpm_sala import MiniCPMSalaConfig
     from deepspeed_tpu.models.moonlight import MoonlightConfig
     from deepspeed_tpu.models.nemotron_h import NemotronHConfig
+    if isinstance(cfg, Lfm2MoeConfig):
+        return Lfm2Kind
     if isinstance(cfg, NemotronHConfig):
         return NemotronHKind
     if isinstance(cfg, MiniCPMSalaConfig):

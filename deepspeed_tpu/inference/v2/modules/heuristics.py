@@ -11,11 +11,11 @@ a specific implementation by name
 Implementations registered for ``attention`` (the ragged decode op):
 
 - ``pallas_paged``          — single-device Pallas decode kernel
-  (``ops/pallas/paged_attention``); needs ``head_dim % 128 == 0`` and
-  ``block_size % 8 == 0`` (Mosaic lane alignment — 64-dim-head models
-  such as Bloom-560M take the XLA path; lane-packing two 64-dim heads
-  is possible but unimplemented) and a ``[tokens, max_blocks]`` block
-  table that fits the kernel's SMEM budget.
+  (``ops/pallas/paged_attention``); needs ``head_dim % 128 == 0`` - or a
+  head of 64 with an even number of key-value heads, which it takes a
+  pair of heads a 128-lane slice - and ``block_size % 8 == 0`` (Mosaic
+  lane alignment) and a ``[tokens, max_blocks]`` block table that fits
+  the kernel's SMEM budget.
 - ``pallas_paged_sharded``  — the same kernel per tensor-parallel shard
   under ``shard_map`` (query/KV heads divide over 'tensor').
 - ``xla_gather``            — gather-based XLA reference; always

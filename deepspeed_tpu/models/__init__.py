@@ -12,6 +12,8 @@ from deepspeed_tpu.models.longcat import (LONGCAT_CONFIGS, LongcatFlashConfig,
 from deepspeed_tpu.models.minicpm_sala import (MINICPM_SALA_CONFIGS, MiniCPMSalaConfig,
                                                MiniCPMSalaForCausalLM,
                                                build_minicpm_sala)  # noqa: F401
+from deepspeed_tpu.models.lfm2 import (LFM2_CONFIGS, Lfm2MoeConfig, Lfm2MoeForCausalLM,
+                                       build_lfm2)  # noqa: F401
 from deepspeed_tpu.models.nemotron_h import (NEMOTRON_H_CONFIGS, NemotronHConfig,
                                              NemotronHForCausalLM,
                                              build_nemotron_h)  # noqa: F401
@@ -21,7 +23,7 @@ from deepspeed_tpu.models.nemotron_h import (NEMOTRON_H_CONFIGS, NemotronHConfig
 MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
                   (MOONLIGHT_CONFIGS, build_moonlight), (LONGCAT_CONFIGS, build_longcat),
                   (MINICPM_SALA_CONFIGS, build_minicpm_sala),
-                  (NEMOTRON_H_CONFIGS, build_nemotron_h))
+                  (NEMOTRON_H_CONFIGS, build_nemotron_h), (LFM2_CONFIGS, build_lfm2))
 
 
 def build_model(preset, **overrides):

@@ -190,23 +190,28 @@ class NemotronHConfig:
 
     @property
     def segments(self):
-        """The stack as ``[(unit, repeats), ...]``: the longest stretch of a
-        repeating unit of two layers or more wherever the pattern repeats,
-        single layers (``repeats`` 1) elsewhere. ``EMEMEMEMEM*`` is
-        ``[("EM", 5), ("*", 1)]``: the serving stack scans the first and
-        runs the second."""
-        pattern, out, i = self.hybrid_override_pattern, [], 0
-        while i < len(pattern):
-            best = (pattern[i], 1)
-            for p in range(2, (len(pattern) - i) // 2 + 1):
-                unit, r = pattern[i:i + p], 1
-                while pattern[i + r * p:i + (r + 1) * p] == unit:
-                    r += 1
-                if r > 1 and r * p > len(best[0]) * best[1]:
-                    best = (unit, r)
-            out.append(best)
-            i += len(best[0]) * best[1]
-        return tuple(out)
+        """The stack as ``[(unit, repeats), ...]`` (:func:`segments_of` the
+        pattern): ``EMEMEMEMEM*`` is ``[("EM", 5), ("*", 1)]``: the serving
+        stack scans the first and runs the second."""
+        return segments_of(self.hybrid_override_pattern)
+
+
+def segments_of(pattern):
+    """A stack's letters → ``[(unit, repeats), ...]``: the longest stretch
+    of a repeating unit of two layers or more wherever the pattern repeats,
+    single layers (``repeats`` 1) elsewhere."""
+    out, i = [], 0
+    while i < len(pattern):
+        best = (pattern[i], 1)
+        for p in range(2, (len(pattern) - i) // 2 + 1):
+            unit, r = pattern[i:i + p], 1
+            while pattern[i + r * p:i + (r + 1) * p] == unit:
+                r += 1
+            if r > 1 and r * p > len(best[0]) * best[1]:
+                best = (unit, r)
+        out.append(best)
+        i += len(best[0]) * best[1]
+    return tuple(out)
 
 
 NEMOTRON_H_CONFIGS = {

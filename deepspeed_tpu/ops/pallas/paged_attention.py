@@ -109,6 +109,8 @@ TILE_VMEM_BYTES = 4 << 20
 # What a slot holds at the least where the table is a selection
 # (``selected=True``): tile_blocks().
 SELECTED_SLOT_BYTES = 512 << 10
+# The head size two of which make one 128-lane slice: kernel_supported(), _paired().
+PAIRED_HEAD_DIM = 64
 
 
 def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None,
@@ -175,14 +177,26 @@ def kernel_supported(head_dim, block_size, n_kv_heads=None):
     re-measured compiling and matching the XLA reference on a real v5e
     for all four counts, 2026-08-01). How many blocks make a tile is not a
     matter of support: :func:`tile_blocks` gives every shape this
-    function admits some ``n``, 1 at the least. 64-dim-head models (e.g.
-    Bloom-560M, GPT-2) and ALiBi models take the XLA gather path (see
-    ``inference/v2/modules/heuristics.py``). A model whose heads are
-    neither (Moonlight's 192-wide queries over a 576-value latent row) is
-    not this kernel's at all: its state kind is ``latent`` and its kernel
+    function admits some ``n``, 1 at the least.
+
+    **A head of 64** is admitted where the pool's row is still whole lane
+    tiles, ``n_kv_heads * 64 % 128 == 0``: the kernel then takes **a pair
+    of key-value heads a 128-lane slice** (:func:`_paired`), each query
+    head widened to the pair's 128 lanes with the other head's half zero,
+    so every slice, query and accumulator is as lane-aligned as at a head
+    of 128 - the same kernel body, told the true head size for its scale.
+    An odd number of 64-wide heads, other head sizes and ALiBi models take
+    the XLA gather path (see ``inference/v2/modules/heuristics.py``). A
+    model whose heads are neither (Moonlight's 192-wide queries over a
+    576-value latent row) is not this kernel's at all: its state kind is
+    ``latent`` and its kernel
     ``paged_mla_attention.paged_mla_decode_attention``, with
     ``mla_kernel_supported`` as its own constraint."""
-    return head_dim % 128 == 0 and block_size % 8 == 0
+    if block_size % 8:
+        return False
+    if head_dim == PAIRED_HEAD_DIM:
+        return n_kv_heads is not None and n_kv_heads % 2 == 0
+    return head_dim % 128 == 0
 
 
 def smem_table_fits(n_tokens, max_blocks):
@@ -234,17 +248,20 @@ def zeros_past(out, live_rows):
 
 
 def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
-            k_buf, v_buf, sems, slot_ref, *, bs, n, max_blocks, groups, n_kv_heads, native):
+            k_buf, v_buf, sems, slot_ref, *, bs, n, max_blocks, groups, n_kv_heads, native,
+            head_dim=None):
     """One token: q_ref [1, H, Dh] (VMEM); kc/vc, the whole pool
     [L, NB, bs, Hkv*Dh], stay in HBM (ANY); tab/pos/layer in SMEM via
     scalar prefetch. The module docstring says what a tile is, what is in
-    flight when, and what a slot's stale rows may hold."""
+    flight when, and what a slot's stale rows may hold. ``head_dim``: the
+    head size the scores are scaled by where it is not the query's width
+    (a pair of narrow heads a slice, :func:`_paired`); None: the width."""
     t = pl.program_id(0)
     T = pl.num_programs(0)
     layer = layer_ref[0]
     H, Dh = q_ref.shape[1], q_ref.shape[2]
     rows = n * bs
-    scale = 1.0 / np.sqrt(Dh)
+    scale = 1.0 / np.sqrt(Dh if head_dim is None else head_dim)
     precision = None if native else jax.lax.Precision.HIGHEST
 
     # positions and counts are never negative: lax.div / & 1, not the floor
@@ -339,13 +356,15 @@ def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
     slot_ref[0] = jnp.where(nxt_fetches, 1 - last_slot, last_slot)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "interpret"))
-def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows=None):
+@functools.partial(jax.jit, static_argnames=("n", "interpret", "head_dim"))
+def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows=None,
+                head_dim=None):
     """The kernel at ``n`` blocks a tile (``tools/kernel_census.py``
     sweeps it; everything else gets :func:`tile_blocks`'). Jitted so
     that the serving programs of one shape (23 a cell lower the kernel in
     their layer body) share one trace of it. ``live_rows`` (None: every
-    row) is where the grid ends; the module docstring says why."""
+    row) is where the grid ends; the module docstring says why.
+    ``head_dim``: :func:`_kernel`'s."""
     T, H, Dh = q.shape
     live_rows, grid = live_grid(T, live_rows)
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
@@ -370,7 +389,7 @@ def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_ro
     # the MXU as they lie; a float32 pool (or query) keeps the six-pass product
     native = q.dtype == kc.dtype == vc.dtype and kc.dtype.itemsize == 2
     kernel = functools.partial(_kernel, bs=bs, n=n, max_blocks=MB, groups=H // Hkv,
-                               n_kv_heads=Hkv, native=native)
+                               n_kv_heads=Hkv, native=native, head_dim=head_dim)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -384,6 +403,27 @@ def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_ro
     return zeros_past(out, live_rows)
 
 
+def _paired(q, n_kv_heads):
+    """Queries of ``PAIRED_HEAD_DIM``-wide heads [T, H, 64], grouped
+    ``[Hkv x G]`` → (the queries the kernel takes [T, H, 128], ``pick`` that
+    brings its output back to [T, H, 64]). The pool's row holds key-value
+    heads ``2p`` and ``2p + 1`` side by side in lanes ``128 p .. 128 p +
+    127``, so the kernel is given **``Hkv / 2`` heads of 128**, each with
+    the ``2 G`` query heads of its pair: a query of the even head lies in
+    the slice's low half and a query of the odd head in its high half, the
+    other half zero, so ``q . k`` over 128 lanes is the head's own 64
+    products and 64 zeros. The second product gives each query head both
+    heads' values side by side, of which ``pick`` keeps its own half. The
+    kernel is bound by its fetches (one pass over the pool, as at a head
+    of 128); the wider products ride the MXU's 128 lanes, which a 64-wide
+    product would leave half empty."""
+    T, H, d = q.shape
+    odd = ((jnp.arange(H) // (H // n_kv_heads)) % 2 == 1)[None, :, None]
+    zero = jnp.zeros_like(q)
+    wide = jnp.concatenate([jnp.where(odd, zero, q), jnp.where(odd, q, zero)], axis=-1)
+    return wide, lambda out: jnp.where(odd, out[..., d:], out[..., :d])
+
+
 def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None,
                            interpret=None, selected=False):
     """Pallas path of :func:`xla_paged_attention` (same contract on the
@@ -395,7 +435,9 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=
     tile of 4 64-row blocks, 31 % of the HBM roofline, 43 % at 8 blocks
     and 57 % at 32 (chip, PR 34) — so such a call's slot holds
     ``SELECTED_SLOT_BYTES`` at the least, the 512 KB that 256 rows of 8
-    heads are. Every other call's tile is what it was."""
+    heads are. Every other call's tile is what it was. A head of 64 goes
+    to the same kernel a pair of key-value heads a slice (:func:`_paired`);
+    a head of 128 takes the code it took."""
     if interpret is None:
         from deepspeed_tpu.ops.pallas import default_interpret
         interpret = default_interpret()
@@ -405,8 +447,9 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=
     if not interpret:
         if not kernel_supported(Dh, bs, Hkv):
             raise ValueError(
-                f"paged decode kernel needs head_dim % 128 == 0 and block_size % 8 == 0, "
-                f"got head_dim={Dh}, block_size={bs}")
+                f"paged decode kernel needs head_dim % 128 == 0 (or 64, an even number of "
+                f"key-value heads) and block_size % 8 == 0, got head_dim={Dh}, "
+                f"n_kv_heads={Hkv}, block_size={bs}")
         if not smem_table_fits(T, MB):
             raise ValueError(
                 f"paged decode block table [{T}, {MB}] overflows the kernel's "
@@ -414,4 +457,8 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=
                 f"max_context, or raise kv_block_size")
     n = tile_blocks(bs, kc.shape[3] * kc.dtype.itemsize, kc.dtype.itemsize, MB,
                     SELECTED_SLOT_BYTES if selected else 0)
+    if Dh == PAIRED_HEAD_DIM and Hkv % 2 == 0:
+        wide, pick = _paired(q, Hkv)
+        return pick(_paged_call(wide, kc, vc, block_tables, token_pos, layer, n, interpret,
+                                live_rows, head_dim=Dh))
     return _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows)

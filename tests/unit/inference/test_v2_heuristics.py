@@ -64,11 +64,13 @@ def test_kernel_entry_refuses_rather_than_falls_back():
     """Called compiled (``interpret=False``) on a shape Mosaic cannot
     take, the kernel entry raises — it used to return the reference."""
     from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
-    q = jnp.zeros((4, 4, 64))
-    kc = jnp.zeros((1, 8, 16, 2 * 64))
     tab = jnp.zeros((4, 2), jnp.int32)
-    with pytest.raises(ValueError, match="head_dim % 128"):
-        paged_decode_attention(q, kc, kc, tab, jnp.zeros(4, jnp.int32), 0, interpret=False)
+    # a head of 32, and one head of 64 (two make a 128-lane slice: PR 41; one does not)
+    for head_dim, kv_heads in ((32, 2), (64, 1)):
+        q = jnp.zeros((4, 4, head_dim))
+        kc = jnp.zeros((1, 8, 16, kv_heads * head_dim))
+        with pytest.raises(ValueError, match="head_dim % 128"):
+            paged_decode_attention(q, kc, kc, tab, jnp.zeros(4, jnp.int32), 0, interpret=False)
 
 
 def test_engine_config_override_serves_correctly():

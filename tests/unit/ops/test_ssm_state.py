@@ -178,3 +178,37 @@ def test_mosaic_takes_the_cells_shape_and_the_pool_is_aliased(one_chip):
     assert memory.alias_size_in_bytes >= pool_bytes
     assert memory.temp_size_in_bytes < pool_bytes // 100
     assert "ssm_state_step" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [512, 64])
+def test_mosaic_takes_the_paged_kernel_at_a_head_of_64(one_chip, rows):
+    """Compiled for a described v5e (nothing runs; here beside the other
+    such compile because one process loads the TPU's library): the paged
+    attention kernel lowers at ``lfm2-24b-rag``'s shape - 32 query and 8
+    key-value heads of 64, a pair of heads a 128-lane slice, 64-row blocks
+    of 1024 bytes, a 136-block table - in the 512-row and the 64-row
+    program, and the pools are read where they lie."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (kernel_supported,
+                                                          paged_decode_attention,
+                                                          smem_table_fits)
+    assert kernel_supported(64, 64, 8) and smem_table_fits(rows, 136)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (2, 8705, 64, 512)
+    args = (sds((rows, 32, 64), jnp.bfloat16), sds(pool, jnp.bfloat16), sds(pool, jnp.bfloat16),
+            sds((rows, 136), jnp.int32), sds((rows,), jnp.int32), sds((), jnp.int32),
+            sds((), jnp.int32))
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(lambda *a: paged_decode_attention(*a, interpret=False)).lower(
+            *args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert "paged_decode_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < int(np.prod(pool)) * 2 // 100

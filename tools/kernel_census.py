@@ -33,7 +33,10 @@ chunk at context 0 and at 512 - with 1, 2, 4, 8, 16 and 32 blocks a tile, and,
 where ``--paged-parent DIR`` (default ``_checkout/parent``) holds a checkout
 of an older commit, that commit's kernel beside them. ms a call, GB/s of
 the KV rows the tokens attend to, share of 819. ``paged_attention.tile_blocks``
-rests on this table (PERF.md, PR 33).
+rests on this table (PERF.md, PR 33). ``--paged64`` is the same at
+``lfm2-24b-rag``'s shape: 32 query / 8 key-value heads **of 64** (a pair of
+heads a 128-lane slice), 64-row blocks, 64 decode rows at contexts 1024-8192
+and a 512-row chunk at three depths (~1 min; PERF.md, PR 41).
 
 ``--mla`` times ``paged_mla_decode_attention`` at the shape classes the two
 latent cells serve (256-row bf16 blocks of 512 + 128 values, a traced layer
@@ -260,6 +263,13 @@ PAGED_CLASSES = (("mixtral-decode-64", 64, 64, (128, 1536), None),
                  ("chunk-512-ctx0", 512, 512, None, 0),
                  ("chunk-512-ctx512", 512, 512, None, 512))
 PAGED_TILES = (1, 2, 4, 8, 16, 32)
+# ``--paged64``: lfm2-24b-rag's shape (32 query / 8 key-value heads of 64, 64-row blocks)
+PAGED64_CLASSES = (("rag-decode-64-30live", 64, 30, (1024, 8192), None),
+                   ("rag-decode-64", 64, 64, (1024, 8192), None),
+                   ("rag-chunk-512-ctx0", 512, 512, None, 0),
+                   ("rag-chunk-512-ctx2048", 512, 512, None, 2048),
+                   ("rag-chunk-512-ctx7680", 512, 512, None, 7680))
+PAGED64_TILES = (1, 2, 4, 8)
 
 
 def _parent_kernel(parent_dir, module):
@@ -276,13 +286,15 @@ def _parent_kernel(parent_dir, module):
     return parent
 
 
-def paged_attention_classes(parent_dir):
+def paged_attention_classes(parent_dir, narrow=False):
     """Yields one record a shape class: the kernel at each ``n`` of
     ``PAGED_TILES`` (the rule's own marked), the parent commit's kernel
     where there is one, each against ``xla_paged_attention`` on every
     eighth token. The bytes are the K and V rows at positions <= the
     token's, once a token: what the token attends to, not the whole
-    blocks fetched."""
+    blocks fetched. ``narrow``: ``PAGED64_CLASSES`` at a head of 64 (a
+    pair of key-value heads a 128-lane slice), which a parent from before
+    PR 41 refuses."""
     import numpy as np
 
     import jax
@@ -293,12 +305,16 @@ def paged_attention_classes(parent_dir):
     parent = _parent_kernel(parent_dir, "paged_attention")
 
     H, Hkv, Dh, bs, L, NB, MB = 32, 8, HEAD_DIM, 16, 4, 8192, 96
+    classes, tiles = PAGED_CLASSES, PAGED_TILES
+    if narrow:
+        Dh, bs, L, NB, MB = 64, 64, 2, 8705, 136
+        classes, tiles = PAGED64_CLASSES, PAGED64_TILES
     rng = np.random.default_rng(33)
     pool = jax.jit(lambda key: jax.random.normal(key, (L, NB, bs, Hkv * Dh), jnp.bfloat16))
     kc, vc = pool(jax.random.PRNGKey(1)), pool(jax.random.PRNGKey(2))
     layer = jnp.int32(L - 2)
     rule = pa.tile_blocks(bs, Hkv * Dh * 2, 2, MB)
-    for name, T, live, ctx, chunk_start in PAGED_CLASSES:
+    for name, T, live, ctx, chunk_start in classes:
         tabs, pos = np.zeros((T, MB), np.int32), np.zeros(T, np.int32)
         free = iter(rng.permutation(np.arange(1, NB)))
         if ctx is None:  # one sequence's chunk: every token on the same table
@@ -333,8 +349,17 @@ def paged_attention_classes(parent_dir):
 
         if parent is not None:
             record["parent"] = timed(lambda *a: parent.paged_decode_attention(*a, interpret=False))
-        for n in PAGED_TILES:
-            record[f"n={n}"] = timed(lambda *a, n=n: pa._paged_call(*a, n, False))
+        def at(n):
+            if not narrow:
+                return lambda *a: pa._paged_call(*a, n, False)
+
+            def paired(q, *rest):
+                wide, pick = pa._paired(q, Hkv)
+                return pick(pa._paged_call(wide, *rest, n, False, None, head_dim=Dh))
+            return paired
+
+        for n in tiles:
+            record[f"n={n}"] = timed(at(n))
         yield name, record
 
 
@@ -624,7 +649,7 @@ def main():
     enable_compile_cache()
     report = {"device": {"platform": devices[0].platform, "kind": devices[0].device_kind,
                          "count": len(devices)}, "kernels": {}}
-    paged, mla = "--paged" in sys.argv, "--mla" in sys.argv
+    paged, mla = "--paged" in sys.argv or "--paged64" in sys.argv, "--mla" in sys.argv
     parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
     live, ssm = "--live" in sys.argv, "--ssm" in sys.argv
@@ -634,7 +659,8 @@ def main():
         shares = [float(x) for x in sys.argv[sys.argv.index("--live") + 1].split(",")]
         section, records = "live_rows", live_rows_sweep(parent_dir, shares)
     elif paged:
-        section, records = "paged_attention", paged_attention_classes(parent_dir)
+        section, records = "paged_attention", paged_attention_classes(
+            parent_dir, narrow="--paged64" in sys.argv)
     elif mla:
         section, records = "paged_mla_attention", paged_mla_classes(parent_dir)
     else:
