@@ -802,6 +802,7 @@ class InferenceEngineV2:
                         arrays, *extra)
             self.count_host_sync()
             self.tokens_emitted += len(batch_uids)
+            self._note_chunks(rec)  # while the program runs
             with tracing.phase("engine.fetch"):
                 host, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- THE one intended sync per step: callers consume host tokens/logits
                 host = host[slots]
@@ -843,6 +844,22 @@ class InferenceEngineV2:
                          f"'greedy' (on-device argmax), a sampling dict "
                          f"{{'temperature', 'top_k', 'top_p', 'seed'}}, or a "
                          f"per-sequence list of dict/None")
+
+    def _note_chunks(self, rec):
+        """``rec.n_chunk_rows`` / ``n_chunk_tiles`` of the ``put`` whose batch
+        is the one packed: the rows its program's paged kernel attends through
+        a query tile, and the tiles - what ``paged_attention.query_tiles``
+        lays for those rows inside the program, counted here from the host's
+        copy of them (numpy; no device work, no sync). 0 where the program's
+        kernel was given none (the gather, a latent or a selecting model
+        kind), which ``model_runner._paged_attend`` has said in tracing it
+        (``AttentionChoice.tiled``) by the time it is launched."""
+        if rec.n_rows in self._attention.tiled:
+            from deepspeed_tpu.ops.pallas.paged_attention import chunk_counts
+            b = self._batch
+            rec.n_chunk_rows, rec.n_chunk_tiles = chunk_counts(
+                b.token_seq[:rec.n_rows], b.token_pos[:rec.n_rows], self.max_seqs,
+                b.current_tokens)
 
     def _note_counts(self, rec, counts):
         """The device-side counts of the program behind step record ``rec``
@@ -1256,9 +1273,11 @@ class InferenceEngineV2:
 
             def one(carry, i):
                 kc, vc, xc, toks, st = carry  # st is None in a greedy burst
+                # one row a sequence by construction: no row shares a walk of its
+                # context with a neighbour, and the kernel lowers a row a grid step
                 b = {"token_ids": toks, "token_seq": token_seq,
                      "token_pos": pos0 + i, "block_tables": tables,
-                     "last_index": last, **seq_state}
+                     "last_index": last, "query_tiles": None, **seq_state}
                 sel, kc, vc, *counts, xc = ragged_forward(p, kc, vc, b, cfg, dtype, mesh=mesh,
                                                           attn_impl=attn_impl, lora=lora_arg,
                                                           extra=xc)

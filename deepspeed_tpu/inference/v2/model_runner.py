@@ -140,7 +140,11 @@ def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl
     fallback / ALiBi path), optionally pinned by the engine config's
     ``implementation_overrides``; ``impl`` is the engine's
     :class:`AttentionChoice`, which carries the pin in and the selected
-    implementation's name out."""
+    implementation's name out. A row's table here is its sequence's whole
+    table, so this - and nothing else - gives the kernel the step's query
+    tiles (``batch["query_tiles"]``: :func:`ragged_forward`), and notes in
+    ``impl.tiled`` that a program of this width did, for the host's count of
+    the rows they hold."""
     bs = kc.shape[2]
     T, Hkv = k.shape[:2]
     blk = batch["block_tables"][batch["token_seq"], batch["token_pos"] // bs]  # [T]
@@ -154,9 +158,12 @@ def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl
     name, attn_fn = instantiate_attn(mesh, Dh, bs, q.shape, kc.shape, alibi,
                                      max_blocks=tab.shape[1],
                                      override=impl.override if impl else None)
+    tiles = batch.get("query_tiles")
     if impl is not None:
         impl.selected[q.shape[0]] = name
-    out = attn_fn(q, kc, vc, tab, pos, layer, _live_rows(batch))
+        if tiles is not None and name != "xla_gather":  # the gather reads every row alone
+            impl.tiled.add(q.shape[0])
+    out = attn_fn(q, kc, vc, tab, pos, layer, _live_rows(batch), tiles)
     return _c(out, (None, "tensor", None), mesh), kc, vc
 
 
@@ -1874,6 +1881,18 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     unpinned and unreported)."""
     kind = kind_of(cfg)
     batch = dict(batch, live_rows=_live_rows(batch))
+    if "query_tiles" not in batch:
+        # the step's rows as the paged kernel's grid takes them: adjacent rows of one sequence
+        # share a walk of its context. Laid once a step, here and not where it is used, since
+        # that is inside a scan over layers; _paged_attend alone reads it, and a kind whose
+        # attention is not _paged_attend's leaves it unused, which costs its program nothing.
+        # A program of one row a sequence by construction says so (a burst's
+        # ``query_tiles: None``) and lowers the kernel a row a grid step.
+        from deepspeed_tpu.ops.pallas.paged_attention import query_tiles
+        tables = batch["block_tables"]
+        batch["query_tiles"] = query_tiles(batch["token_seq"], batch["token_pos"],
+                                           tables.shape[0] - 1, batch["live_rows"],
+                                           tables.shape[1])
     embed = params["model"]["embed_tokens"]
     h = _c(embed[batch["token_ids"]].astype(dtype), (None, None), mesh)  # [T, D]
     mult = getattr(cfg, "embedding_multiplier", 1.0)

@@ -25,6 +25,17 @@ Every implementation takes the whole pool ``[L, NB, bs, Hkv*Dh]`` and
 the layer to read as an index (``kc_shape`` below is the pool's shape;
 the KV-head count is its last dim over ``head_dim``).
 
+All three also take the step's **query tiles** (``tiles``, after
+``live_rows``; ``paged_attention.query_tiles`` of the batch, or None): the
+adjacent rows of one sequence at consecutive positions - a prompt chunk, a
+verify program's ``d + 1`` rows - which the two kernels attend through one
+walk of the sequence's context a tile of up to ``QUERY_TILE`` rows, and
+the gather ignores. It is no choice of this registry and no setting: the
+caller that knows a row's table is its sequence's whole table
+(``model_runner._paged_attend``) passes what the batch says, a burst
+program (one row a sequence by construction) and a selection's call pass
+None, and a row with no such neighbours is attended alone, as before.
+
 Those three read the state kind ``kv`` (keys and values). A model kind
 whose state is ``latent`` (``model_runner.MoonlightKind``: one
 normalised compressed row and one rotated key a token, shared by all
@@ -56,12 +67,15 @@ class AttentionChoice:
     inferred from the backend. ``state_step``: the same for the Mamba-2
     state step of a model kind that has one (``pallas_ssm_state``, the
     kernel that visits a step's slots in place, or ``xla``:
-    ``ops/pallas/ssm_state.state_step_impl``; nothing pins it)."""
+    ``ops/pallas/ssm_state.state_step_impl``; nothing pins it).
+    ``tiled``: the token counts of the programs whose paged kernel
+    ``model_runner._paged_attend`` gave the step's query tiles."""
 
     def __init__(self, override=None):
         self.override = override
         self.selected = {}
         self.state_step = {}
+        self.tiled = set()
 
 
 def register_implementation(op, name):
@@ -124,9 +138,11 @@ class _PallasPagedSharded:
         from deepspeed_tpu.ops.pallas import shard_map_kernel
         from deepspeed_tpu.ops.pallas.paged_attention import paged_decode_attention
         cls = _PallasPagedSharded
+        # tables, positions, the layer, the live rows and the query tiles (four
+        # arrays, or None: no leaf) are replicated: every shard holds them whole
         return shard_map_kernel(
             paged_decode_attention, mesh,
-            in_specs=(cls.Q_SPEC, cls.KV_SPEC, cls.KV_SPEC, P(), P(), P(), P()),
+            in_specs=(cls.Q_SPEC, cls.KV_SPEC, cls.KV_SPEC, P(), P(), P(), P(), P()),
             out_specs=cls.Q_SPEC)
 
 
@@ -180,7 +196,7 @@ class _XlaGatherMLA:
 
 def instantiate_attn(mesh, head_dim, block_size, q_shape, kc_shape, alibi,
                      max_blocks, override=None, state_kind="kv"):
-    """→ ``(impl_name, fn(q, kc, vc, tab, pos, layer, live_rows))`` — the first supported
+    """→ ``(impl_name, fn(q, kc, vc, tab, pos, layer, live_rows, tiles))`` — the first supported
     implementation in registration (priority) order, or the named one
     when the config pins ``override`` (reference
     heuristics.instantiate_attn + config_bundle semantics). A pin that

@@ -1,9 +1,10 @@
-"""Paged decode attention kernel: one query token vs a block-tabled KV.
+"""Paged attention kernel: a step's query rows vs a block-tabled KV.
 
 TPU-native counterpart of the reference's ragged decode kernels
 (``deepspeed/inference/v2/kernels/ragged_ops/atom_builder`` +
 ``blocked_flash`` over the blocked KV cache,
-``csrc/.../ragged_ops/``). Each token walks its own context: its block
+``csrc/.../ragged_ops/``). Each token walks its own context - or, a
+prompt chunk's rows, one walk together ("A query tile", below): its block
 table rides in SMEM (scalar prefetch), KV blocks are dynamically
 indexed out of the pool, and scores accumulate flash-style (running
 max / sum) with positions beyond the token's context masked. GQA is
@@ -31,6 +32,46 @@ start), so that no token waits for a fetch it could have had. A token
 whose whole context is the one block the token before it had as its own
 (a prompt's first tokens; padding rows among the live ones) finds it in
 that token's slot and fetches nothing.
+
+**A query tile.** A row a grid step fetches a context once a row: a
+512-row chunk of a prompt at context 2048 read its sequence's keys and
+values ~480 times over (3.8 ms a call at ``lfm2-24b-rag``'s shape, 12.5 ms
+at context 7680, where the chunk's bytes are microseconds of HBM; PERF.md,
+PR 41 and 42). The batch says when that is waste: the engine lays a
+sequence's rows of a step side by side at consecutive positions, so rows
+``t .. t+r-1`` of one sequence have one table and need context ``0 ..
+pos[t+r-1]``. :func:`query_tiles` - a pure function of the batch's
+``token_seq`` and ``token_pos``, traced in the program; the host counts a
+step record's ``n_chunk_rows`` / ``n_chunk_tiles`` in numpy by the step of
+it that says which rows share a walk (:func:`chunk_counts`) - cuts the rows
+into **items**: a tile is two
+to ``QUERY_TILE`` such rows inside one block of ``QUERY_TILE`` rows, every
+other row (a decode row among other sequences' rows, padding) an item of
+its own. The grid is one step an
+item, and the pipeline above is the items': a tile's step walks the
+context's tiles **once**, up to its last row's position, multiplies a KV
+head's slot rows by the tile's ``QUERY_TILE x G`` queries at a time, masks
+each query at its own row's position and keeps a running max / sum /
+accumulator a query. A query is a **column** there (scores ``[context
+rows, queries]``): the running max and sum of a tile are one lane each of a
+``[1, queries]`` row and not a sublane each of a ``[queries, 1]`` column,
+which cost as many vector registers as the scores themselves; the
+wrapper hands the kernel the block's queries a second time in that
+layout (``[Hkv, blocks, Dh, QUERY_TILE x G]``, one pass of XLA over q) and
+takes a tile's output back the same way. A row's arithmetic is the row
+path's - the same context tiles in the same order, scores scaled in
+float32, probabilities rounded to the pool's dtype, a tile wholly past a
+row's position contributing exactly nothing - so the two agree to
+rounding. A one-row item takes the one-row body and costs what it cost.
+Who passes tiles: ``model_runner._paged_attend`` for the programs whose
+rows may be neighbours of one sequence (``put``, verify). A program of one
+row a sequence by construction (the burst family) and a selection's call
+(``selected=True``: a table a (token, head), neighbours' tables differ)
+pass none and lower the kernel a row a grid step - the program they lowered
+before there were tiles, operation for operation (the Mosaic module differs
+from PR 41's in its source locations alone); rows that are no whole blocks
+of ``QUERY_TILE``, or whose items do not fit SMEM beside their table, take
+none either.
 
 **Live rows.** A program's width is static and its batch is not: the
 engine packs the rows that hold a token first and pads the rest (table on
@@ -111,9 +152,12 @@ TILE_VMEM_BYTES = 4 << 20
 SELECTED_SLOT_BYTES = 512 << 10
 # The head size two of which make one 128-lane slice: kernel_supported(), _paired().
 PAIRED_HEAD_DIM = 64
+# A query tile (the module docstring): the rows it holds at the most, which is
+# also the height of the kernel's query block.
+QUERY_TILE = 32
 
 
-def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None,
+def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None, tiles=None,
                         alibi_slopes=None, selected=False):
     """Reference math. q: [T, H, Dh]; kc/vc: the pool [L, NB, bs, Hkv*Dh];
     block_tables: [T, MB] (per TOKEN, already indexed by its sequence);
@@ -121,8 +165,9 @@ def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=Non
     → [T, H, Dh]; attends to positions <= token_pos.
     ``alibi_slopes``: optional [H] — adds the Bloom-style linear
     relative-position penalty slope_h * (k_pos - q_pos) to the scores.
-    ``live_rows``, ``selected``: the kernel's (where its grid ends; its
-    tile); the gather computes every row and reads the same rows either way."""
+    ``live_rows``, ``tiles``, ``selected``: the kernel's (where its grid
+    ends; its query tiles; its tile of the context); the gather computes
+    every row and reads the same rows either way."""
     T, H, Dh = q.shape
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     gather_bytes = 2 * T * block_tables.shape[1] * bs * Hkv * Dh * kc.dtype.itemsize
@@ -199,11 +244,12 @@ def kernel_supported(head_dim, block_size, n_kv_heads=None):
     return head_dim % 128 == 0
 
 
-def smem_table_fits(n_tokens, max_blocks):
+def smem_table_fits(n_tokens, max_blocks, tiles=False):
     """Do the ``[n_tokens, max_blocks]`` int32 block table, the
     ``[n_tokens]`` positions and the layer index fit the kernel's SMEM
-    budget?"""
-    return (n_tokens * max_blocks + n_tokens + 1) * 4 <= SMEM_TABLE_BYTES
+    budget - and beside them, for a call with query tiles (``tiles``), the
+    items' rows and lengths, as many each as there are positions?"""
+    return (n_tokens * max_blocks + (3 if tiles else 1) * n_tokens + 1) * 4 <= SMEM_TABLE_BYTES
 
 
 def tile_blocks(block_size, row_bytes, itemsize, max_blocks, slot_bytes=0):
@@ -247,15 +293,97 @@ def zeros_past(out, live_rows):
                      jnp.zeros((), out.dtype))
 
 
-def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
-            k_buf, v_buf, sems, slot_ref, *, bs, n, max_blocks, groups, n_kv_heads, native,
-            head_dim=None):
-    """One token: q_ref [1, H, Dh] (VMEM); kc/vc, the whole pool
-    [L, NB, bs, Hkv*Dh], stay in HBM (ANY); tab/pos/layer in SMEM via
-    scalar prefetch. The module docstring says what a tile is, what is in
-    flight when, and what a slot's stale rows may hold. ``head_dim``: the
-    head size the scores are scaled by where it is not the query's width
-    (a pair of narrow heads a slice, :func:`_paired`); None: the width."""
+def query_tile_rows(n_rows, max_blocks):
+    """The height of the query block of a call over ``n_rows`` rows of
+    ``max_blocks`` table columns that is given tiles: ``QUERY_TILE`` where
+    the rows are whole blocks of it and the items fit SMEM beside the table
+    (:func:`smem_table_fits`), else 1, and a height of 1 is the call without
+    tiles."""
+    whole = n_rows % QUERY_TILE == 0 and smem_table_fits(n_rows, max_blocks, tiles=True)
+    return QUERY_TILE if whole else 1
+
+
+def _runs(token_seq, token_pos, n_seqs, xp):
+    """The step of :func:`query_tiles` that says which rows share a walk, over
+    rows that are whole blocks of ``QUERY_TILE`` (``xp``: ``jnp`` inside a
+    program, ``numpy`` for the host's count): → (``t``, the rows' indices;
+    ``run [T]``, the rows of a row's run - a row that joins no row before it
+    and the rows that join it, inside one block of ``QUERY_TILE`` rows;
+    ``shared [T]``, the rows of runs of two rows or more, which are tiles;
+    ``own [T]``, the rows that start an item)."""
+    T, tq = token_seq.shape[0], QUERY_TILE
+    t = xp.arange(T, dtype=xp.int32)
+    joins = xp.concatenate([
+        xp.zeros(1, bool),
+        (token_seq[1:] < n_seqs) & (token_seq[1:] == token_seq[:-1])
+        & (token_pos[1:] == token_pos[:-1] + 1)]) & (t % tq != 0)
+    # compares over [rows, tq], which fuse: no sort and no scan
+    i = xp.arange(tq, dtype=xp.int32)
+    heads = xp.logical_not(joins).reshape(-1, 1, tq)             # [block, 1, j]: j starts a run
+    first = xp.max(xp.where(heads & (i[None, :] <= i[:, None]), i, 0), axis=2)
+    after = xp.min(xp.where(heads & (i[None, :] > i[:, None]), i, tq), axis=2)
+    run = (after - first).reshape(T)
+    return t, run, run > 1, xp.logical_not(joins)
+
+
+def query_tiles(token_seq, token_pos, n_seqs, live_rows, max_blocks):
+    """A step's rows as the kernel's grid takes them, from what the batch
+    says alone (traced: a program lays its own). A **tile** is two to
+    ``QUERY_TILE`` adjacent rows of one sequence (``token_seq`` below
+    ``n_seqs``, the padding's) at consecutive positions inside one block of
+    ``QUERY_TILE`` rows - what ``RaggedBatchWrapper.insert_sequence`` lays for
+    a prompt chunk or a verify program for its ``d + 1`` rows; every other row
+    is an item of its own. → ``(item_row [T], item_len [T], n_items,
+    shared [T])``: each item's first row and its rows (int32) in the order of
+    the rows, how many items cover the rows before ``live_rows``, and which
+    rows lie in a tile; the entries from ``n_items`` on are rows of their own.
+    None where the rows, under a table of ``max_blocks`` columns, take no
+    tiles at all (:func:`query_tile_rows`)."""
+    T = token_seq.shape[0]
+    if query_tile_rows(T, max_blocks) == 1:
+        return None
+    t, run, shared, own = _runs(token_seq, token_pos, n_seqs, jnp)
+    # item k is the k-th row that starts one: a compare and sums over [rows, rows], fused too,
+    # so that laying a step's tiles costs its program microseconds
+    hit = own & (jnp.cumsum(own, dtype=jnp.int32) - 1 == t[:, None])   # [item, row]
+    item_row = jnp.sum(jnp.where(hit, t, 0), axis=1, dtype=jnp.int32)
+    item_len = jnp.sum(jnp.where(hit, run, 0), axis=1, dtype=jnp.int32)
+    real = t < jnp.sum(own, dtype=jnp.int32)
+    n_items = jnp.sum(own & (t < live_rows), dtype=jnp.int32)
+    return (jnp.where(real, item_row, T - 1), jnp.maximum(item_len, 1), n_items, shared)
+
+
+def chunk_counts(token_seq, token_pos, n_seqs, live_rows):
+    """→ ``(n_chunk_rows, n_chunk_tiles)`` of a batch the host packed
+    (numpy) for a program that lays tiles (so its rows are whole blocks of
+    ``QUERY_TILE``): the rows before ``live_rows`` that :func:`query_tiles`
+    lays in tiles, and those tiles - by the step of it that says so
+    (:func:`_runs`), without the items' table, which only the kernel's grid
+    wants."""
+    t, _, shared, own = _runs(token_seq, token_pos, n_seqs, np)
+    live = shared & (t < live_rows)
+    return int(live.sum()), int((live & own).sum())
+
+
+def _kernel(*refs, bs, n, max_blocks, groups, n_kv_heads, native, head_dim=None, tq=1):
+    """One item of the grid: a row, or with ``tq`` above 1 a row or a tile.
+    q_ref [tq, H, Dh] (VMEM), the item's block of rows; kc/vc, the whole
+    pool [L, NB, bs, Hkv*Dh], stay in HBM (ANY); tab/pos/layer, and the
+    items' first rows and lengths where there are tiles, in SMEM via scalar
+    prefetch. With tiles the block's queries come a second time, as a tile
+    multiplies them: qt_ref [Hkv, Dh, tq x G], a KV head's queries as columns
+    (the block's row, then the head of its group), and a tile's output
+    leaves the same way through ot_ref. The module docstring says what a
+    tile of the context and a query tile are, what is in flight when, and
+    what a slot's stale rows may hold. ``head_dim``: the head size the
+    scores are scaled by where it is not the query's width (a pair of narrow
+    heads a slice, :func:`_paired`); None: the width."""
+    if tq == 1:
+        (tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
+         k_buf, v_buf, sems, slot_ref) = refs
+    else:
+        (tab_ref, pos_ref, layer_ref, row_ref, len_ref, q_ref, qt_ref, kc_ref, vc_ref,
+         o_ref, ot_ref, k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref) = refs
     t = pl.program_id(0)
     T = pl.num_programs(0)
     layer = layer_ref[0]
@@ -264,30 +392,45 @@ def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
     scale = 1.0 / np.sqrt(Dh if head_dim is None else head_dim)
     precision = None if native else jax.lax.Precision.HIGHEST
 
-    # positions and counts are never negative: lax.div / & 1, not the floor
-    # division and modulo whose sign handling Mosaic lowers at length
-    def n_blocks(tok):
-        return jnp.minimum(jax.lax.div(pos_ref[tok], bs) + 1, max_blocks)
+    # An item as the functions below take it. With a row an item (``tq`` 1) it is
+    # the row's index and nothing else: its table and its blocks are read where
+    # they are used, and the kernel lowers what it lowered before there were
+    # tiles, operation for operation (a selection's call, a burst program).
+    # With tiles it is (the row whose table is the item's, the blocks of its
+    # context, which ends at its last row's position), read once a grid step.
+    # Positions and counts are never negative: lax.div / & 1, not the floor
+    # division and modulo whose sign handling Mosaic lowers at length.
+    def item(k):
+        if tq == 1:
+            return k
+        last = row_ref[k] + len_ref[k] - 1
+        return row_ref[k], jnp.minimum(jax.lax.div(pos_ref[last], bs) + 1, max_blocks)
 
-    def same_block(tok, before):
-        """Is ``tok``'s whole context the one block ``before`` had as its
-        own? Then it sits in that token's slot already: nothing to fetch."""
-        return ((n_blocks(tok) == 1) & (n_blocks(before) == 1)
-                & (tab_ref[tok, 0] == tab_ref[before, 0]))
+    def table_row(it):
+        return it if tq == 1 else it[0]
 
-    def each_block(tok, i, slot, act):
-        """``act`` on the K and V copies of the blocks of ``tok``'s tile
-        ``i`` that lie inside its context."""
+    def n_blocks(it):
+        return jnp.minimum(jax.lax.div(pos_ref[it], bs) + 1, max_blocks) if tq == 1 else it[1]
+
+    def same_block(it, before):
+        """Is ``it``'s whole context the one block the item ``before`` it had as
+        its own? Then it sits in that item's slot already: nothing to fetch."""
+        return ((n_blocks(it) == 1) & (n_blocks(before) == 1)
+                & (tab_ref[table_row(it), 0] == tab_ref[table_row(before), 0]))
+
+    def each_block(it, i, slot, act):
+        """``act`` on the K and V copies of the blocks of ``it``'s tile ``i``
+        that lie inside its context."""
         def one(j, carry):
-            blk = tab_ref[tok, i * n + j]
+            blk = tab_ref[table_row(it), i * n + j]
             at = pl.ds(pl.multiple_of(j * bs, bs), bs)
             act(pltpu.make_async_copy(kc_ref.at[layer, blk], k_buf.at[slot, at], sems.at[0, slot]))
             act(pltpu.make_async_copy(vc_ref.at[layer, blk], v_buf.at[slot, at], sems.at[1, slot]))
             return carry
-        jax.lax.fori_loop(0, jnp.minimum(n_blocks(tok) - i * n, n), one, 0)
+        jax.lax.fori_loop(0, jnp.minimum(n_blocks(it) - i * n, n), one, 0)
 
-    def start(tok, i, slot):
-        each_block(tok, i, slot, lambda copy: copy.start())
+    def start(it, i, slot):
+        each_block(it, i, slot, lambda copy: copy.start())
 
     def head(x, h):
         """KV head ``h`` of a slot: 128-aligned lanes of its rows."""
@@ -298,108 +441,205 @@ def _kernel(tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
     def _():
         slot_ref[0] = 0
         v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)  # see "stale rows"
-        start(0, 0, 0)
+        start(item(0), 0, 0)
 
-    pos = pos_ref[t]
-    n_tiles = jax.lax.div(n_blocks(t) + n - 1, n)
+    this = item(t)
+    pos = pos_ref[table_row(this)]  # of the item's first row
+    n_tiles = jax.lax.div(n_blocks(this) + n - 1, n)
     slot0 = slot_ref[0]
-    reused = (t > 0) & same_block(t, jnp.maximum(t - 1, 0))
-    nxt = jnp.minimum(t + 1, T - 1)
-    nxt_fetches = (t + 1 < T) & jnp.logical_not(same_block(nxt, t))
-    q = q_ref[0]  # [H, Dh], heads grouped [Hkv x G]; everything stays 2-D for Mosaic
-    if not native:
-        q = q.astype(jnp.float32)
+    reused = (t > 0) & same_block(this, item(jnp.maximum(t - 1, 0)))
+    nxt = item(jnp.minimum(t + 1, T - 1))
+    nxt_fetches = (t + 1 < T) & jnp.logical_not(same_block(nxt, this))
 
-    def tile_step(i, carry):
-        m, l, acc = carry  # [H, 1], [H, 1], [H, Dh]
+    def fetched(i):
+        """→ the slot that holds this item's tile ``i``, its copies waited
+        for; the next tile in order - this item's, or the next item's first -
+        is started first and flies during this tile's arithmetic."""
         slot = (slot0 + i) & 1
         last = i + 1 == n_tiles
 
-        # the next tile in order - this token's, or the next token's first -
-        # flies during this tile's arithmetic
         @pl.when(jnp.logical_not(last) | nxt_fetches)
         def _():
-            start(jnp.where(last, nxt, t), jnp.where(last, 0, i + 1), 1 - slot)
+            start(jax.tree.map(lambda a, b: jnp.where(last, a, b), nxt, this),
+                  jnp.where(last, 0, i + 1), 1 - slot)
 
         @pl.when((i > 0) | jnp.logical_not(reused))
         def _():
-            each_block(t, i, slot, lambda copy: copy.wait())
+            each_block(this, i, slot, lambda copy: copy.wait())
+        return slot
 
-        kbuf = k_buf[slot]  # one read; heads are lane slices of it
-        vbuf = v_buf[slot]
-        s = jnp.concatenate([
-            jax.lax.dot_general(jax.lax.slice(q, (h * groups, 0), ((h + 1) * groups, Dh)),
-                                head(kbuf, h), (((1,), (1,)), ((), ())), precision=precision,
-                                preferred_element_type=jnp.float32)
-            for h in range(n_kv_heads)], axis=0) * scale  # [H, rows]
-        kv_pos = i * rows + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
-        s = jnp.where(kv_pos <= pos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        p = p.astype(vbuf.dtype if native else jnp.float32)
-        pv = jnp.concatenate([
-            jax.lax.dot_general(jax.lax.slice(p, (h * groups, 0), ((h + 1) * groups, rows)),
-                                head(vbuf, h), (((1,), (0,)), ((), ())), precision=precision,
-                                preferred_element_type=jnp.float32)
-            for h in range(n_kv_heads)], axis=0)  # [H, Dh]
-        return m_new, l_new, acc * alpha + pv
+    def attend_row(r):
+        """Row ``r`` of the query block against its own context."""
+        q = q_ref[r]  # [H, Dh], heads grouped [Hkv x G]; everything stays 2-D for Mosaic
+        if not native:
+            q = q.astype(jnp.float32)
 
-    m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((H, 1), jnp.float32)
-    a0 = jnp.zeros((H, Dh), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_tiles, tile_step, (m0, l0, a0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    # the next token's first tile is in the other slot, or, reused, in this one
+        def tile_step(i, carry):
+            m, l, acc = carry  # [H, 1], [H, 1], [H, Dh]
+            slot = fetched(i)
+            kbuf = k_buf[slot]  # one read; heads are lane slices of it
+            vbuf = v_buf[slot]
+            s = jnp.concatenate([
+                jax.lax.dot_general(jax.lax.slice(q, (h * groups, 0), ((h + 1) * groups, Dh)),
+                                    head(kbuf, h), (((1,), (1,)), ((), ())), precision=precision,
+                                    preferred_element_type=jnp.float32)
+                for h in range(n_kv_heads)], axis=0) * scale  # [H, rows]
+            kv_pos = i * rows + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+            s = jnp.where(kv_pos <= pos, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            p = p.astype(vbuf.dtype if native else jnp.float32)
+            pv = jnp.concatenate([
+                jax.lax.dot_general(jax.lax.slice(p, (h * groups, 0), ((h + 1) * groups, rows)),
+                                    head(vbuf, h), (((1,), (0,)), ((), ())), precision=precision,
+                                    preferred_element_type=jnp.float32)
+                for h in range(n_kv_heads)], axis=0)  # [H, Dh]
+            return m_new, l_new, acc * alpha + pv
+
+        m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((H, 1), jnp.float32)
+        a0 = jnp.zeros((H, Dh), jnp.float32)
+        _, l, acc = jax.lax.fori_loop(0, n_tiles, tile_step, (m0, l0, a0))
+        o_ref[r] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    def attend_tile():
+        """The item's rows of the query block against one walk of their
+        context. Everything is a query a **column**: a KV head's scores
+        ``[rows, tq x G]`` are its slot's rows times qt_ref's columns, a
+        column masked at its own row's position, and the running max and sum
+        of a column are one lane of a ``[1, tq x G]`` row, the accumulator
+        ``[Dh, tq x G]``. The columns of the block's rows that are not the
+        item's are masked everywhere and never stored."""
+        M = tq * groups
+        at = table_row(this) & (tq - 1)
+        col = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (1, M), 1), groups)
+        mine = (col >= at) & (col < at + len_ref[t])
+        q_pos = jnp.where(mine, pos + col - at, -1)  # [1, M]
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def tile_step(i, carry):
+            slot = fetched(i)
+            kbuf = k_buf[slot]
+            vbuf = v_buf[slot]
+            kv_pos = i * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            seen = kv_pos <= q_pos  # [rows, M]
+            for h in range(n_kv_heads):
+                q = qt_ref[h]  # [Dh, M]
+                if not native:
+                    q = q.astype(jnp.float32)
+                s = jax.lax.dot_general(head(kbuf, h), q, (((1,), (0,)), ((), ())),
+                                        precision=precision,
+                                        preferred_element_type=jnp.float32) * scale
+                s = jnp.where(seen, s, NEG_INF)
+                m = m_ref[h]
+                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=0, keepdims=True)
+                p = p.astype(vbuf.dtype if native else jnp.float32)
+                acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                    head(vbuf, h), p, (((0,), (0,)), ((), ())), precision=precision,
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, tile_step, 0)
+        for h in range(n_kv_heads):
+            out = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).astype(ot_ref.dtype)
+            ot_ref[h] = jnp.where(mine, out, ot_ref[h])
+
+    if tq == 1:
+        attend_row(0)
+    else:
+        pl.when(len_ref[t] == 1)(lambda: attend_row(table_row(this) & (tq - 1)))
+        pl.when(len_ref[t] > 1)(attend_tile)
+    # the next item's first tile is in the other slot, or, reused, in this one
     last_slot = (slot0 + n_tiles - 1) & 1
     slot_ref[0] = jnp.where(nxt_fetches, 1 - last_slot, last_slot)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret", "head_dim"))
 def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows=None,
-                head_dim=None):
+                head_dim=None, tiles=None):
     """The kernel at ``n`` blocks a tile (``tools/kernel_census.py``
     sweeps it; everything else gets :func:`tile_blocks`'). Jitted so
     that the serving programs of one shape (23 a cell lower the kernel in
     their layer body) share one trace of it. ``live_rows`` (None: every
     row) is where the grid ends; the module docstring says why.
-    ``head_dim``: :func:`_kernel`'s."""
+    ``head_dim``: :func:`_kernel`'s. ``tiles``: :func:`query_tiles`' of
+    these rows, or None: every row an item, a grid step a row, the query
+    block one row high."""
     T, H, Dh = q.shape
     live_rows, grid = live_grid(T, live_rows)
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     MB = block_tables.shape[1]
+    groups = H // Hkv
+    tq = 1 if tiles is None else QUERY_TILE
+    prefetch = [block_tables.astype(jnp.int32), token_pos.astype(jnp.int32),
+                jnp.asarray(layer, jnp.int32).reshape(1)]
+    operands = [q]
+    scratch = [
+        pltpu.VMEM((2, n * bs, Hkv * Dh), kc.dtype),
+        pltpu.VMEM((2, n * bs, Hkv * Dh), vc.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, slot]
+        pltpu.SMEM((1,), jnp.int32),      # the slot of this item's first tile
+    ]
+    if tq == 1:
+        def block(t, *refs):
+            return t, 0, 0
+        blocks = [pl.BlockSpec((1, H, Dh), block)]
+    else:
+        item_row, item_len, n_items, shared = tiles
+        prefetch += [item_row.astype(jnp.int32), item_len.astype(jnp.int32)]
+        grid = (jnp.maximum(n_items.astype(jnp.int32), 1),)
+        # a KV head's queries as columns, (row, head of the group) in order: what a tile
+        # multiplies a slot by, and how its output comes back (one pass of XLA each way)
+        M = tq * groups
+        operands.append(q.reshape(T // tq, tq, Hkv, groups, Dh).transpose(2, 0, 4, 1, 3)
+                        .reshape(Hkv, T // tq, Dh, M))
+        scratch += [
+            pltpu.VMEM((Hkv, 1, M), jnp.float32),   # running max,
+            pltpu.VMEM((Hkv, 1, M), jnp.float32),   # sum
+            pltpu.VMEM((Hkv, Dh, M), jnp.float32),  # and accumulator a column
+        ]
+
+        def block(t, tab, pos, layer, item_row, item_len):
+            return jax.lax.div(item_row[t], tq), 0, 0
+
+        def columns(t, tab, pos, layer, item_row, item_len):
+            return 0, jax.lax.div(item_row[t], tq), 0, 0
+        blocks = [pl.BlockSpec((tq, H, Dh), block), pl.BlockSpec((Hkv, None, Dh, M), columns)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # tables, positions, layer
+        num_scalar_prefetch=len(prefetch),  # tables, positions, layer; the items
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, H, Dh), lambda t, tab, pos, layer: (t, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, H, Dh), lambda t, tab, pos, layer: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, n * bs, Hkv * Dh), kc.dtype),
-            pltpu.VMEM((2, n * bs, Hkv * Dh), vc.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),  # [K | V, slot]
-            pltpu.SMEM((1,), jnp.int32),      # the slot of this token's first tile
-        ],
+        in_specs=blocks + [pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=blocks if tq > 1 else blocks[0],
+        scratch_shapes=scratch,
     )
     # bf16 x bf16 products are exact in float32, so a 2-byte pool's rows go to
     # the MXU as they lie; a float32 pool (or query) keeps the six-pass product
     native = q.dtype == kc.dtype == vc.dtype and kc.dtype.itemsize == 2
-    kernel = functools.partial(_kernel, bs=bs, n=n, max_blocks=MB, groups=H // Hkv,
-                               n_kv_heads=Hkv, native=native, head_dim=head_dim)
+    kernel = functools.partial(_kernel, bs=bs, n=n, max_blocks=MB, groups=groups,
+                               n_kv_heads=Hkv, native=native, head_dim=head_dim, tq=tq)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, H, Dh), q.dtype),
-        # tokens in order on one core: a token starts the next one's first tile
+        out_shape=[jax.ShapeDtypeStruct(x.shape, q.dtype) for x in operands] if tq > 1
+        else jax.ShapeDtypeStruct((T, H, Dh), q.dtype),
+        # items in order on one core: an item starts the next one's first tile
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables.astype(jnp.int32), token_pos.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, kc, vc)
+    )(*prefetch, *operands, kc, vc)
+    if tq > 1:
+        out, columns = out
+        columns = (columns.reshape(Hkv, T // tq, Dh, tq, groups).transpose(1, 3, 0, 4, 2)
+                   .reshape(T, H, Dh))
+        out = jnp.where(shared[:, None, None], columns, out)
     return zeros_past(out, live_rows)
 
 
@@ -424,10 +664,14 @@ def _paired(q, n_kv_heads):
     return wide, lambda out: jnp.where(odd, out[..., d:], out[..., :d])
 
 
-def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None,
+def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None, tiles=None,
                            interpret=None, selected=False):
     """Pallas path of :func:`xla_paged_attention` (same contract on the
     rows before ``live_rows``, zeros from there on; None: every row).
+    ``tiles``: :func:`query_tiles`' of the batch these rows are, from the
+    caller that knows that a row's table is its sequence's (the module
+    docstring, "A query tile"); None - a selection's call, a program of one
+    row a sequence - walks every row's context for that row alone.
     ``selected``: the table is a selection (:func:`selected_tables`) over
     a pool of one KV head a pool layer, whose 256 rows of 256 bytes are
     64 KB a slot: a turn's fixed cost (2n copies started and waited for,
@@ -444,13 +688,15 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=
     T, H, Dh = q.shape
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     MB = block_tables.shape[1]
+    if selected:
+        tiles = None  # a row's table is its own there: nothing to share
     if not interpret:
         if not kernel_supported(Dh, bs, Hkv):
             raise ValueError(
                 f"paged decode kernel needs head_dim % 128 == 0 (or 64, an even number of "
                 f"key-value heads) and block_size % 8 == 0, got head_dim={Dh}, "
                 f"n_kv_heads={Hkv}, block_size={bs}")
-        if not smem_table_fits(T, MB):
+        if not smem_table_fits(T, MB, tiles=tiles is not None):
             raise ValueError(
                 f"paged decode block table [{T}, {MB}] overflows the kernel's "
                 f"{SMEM_TABLE_BYTES >> 10} KB SMEM budget — shrink max_ragged_batch_size / "
@@ -460,5 +706,6 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=
     if Dh == PAIRED_HEAD_DIM and Hkv % 2 == 0:
         wide, pick = _paired(q, Hkv)
         return pick(_paged_call(wide, kc, vc, block_tables, token_pos, layer, n, interpret,
-                                live_rows, head_dim=Dh))
-    return _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows)
+                                live_rows, head_dim=Dh, tiles=tiles))
+    return _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows,
+                       tiles=tiles)

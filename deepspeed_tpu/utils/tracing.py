@@ -89,7 +89,8 @@ CPU_MARKED = frozenset(PREFIX + name for name in (
     "train.prepare", "train.dispatch", "train.sync", "train.post"))
 
 STEP_FIELDS = ("seq", "engine", "kind", "program", "k", "n_seqs", "n_tokens", "n_rows",
-               "n_prompt_tokens", "n_ctx_tokens", "counts", "state_step", "caused_by", "uids",
+               "n_prompt_tokens", "n_ctx_tokens", "n_chunk_rows", "n_chunk_tiles", "counts",
+               "state_step", "caused_by", "uids",
                "start_ns", "end_ns", "thread", "gc_ns", "gc_passes", "compile_ns", "compiles",
                "waited_ns", "idle_passes")
 
@@ -250,6 +251,9 @@ class Recorder:
         # held a token (the engine sets it: a put's bucket, a burst's k x max_seqs)
         rec.n_rows = n_rows
         rec.n_ctx_tokens = 0    # the engine adds each row's attended context as it packs
+        # of n_rows, the rows the paged kernel attended through a query tile, and the tiles
+        # (ops/pallas/paged_attention.chunk_counts of the batch the engine packed)
+        rec.n_chunk_rows = rec.n_chunk_tiles = 0
         # what the program itself counted on the device, by name, fetched with its result
         # (model_runner: kind.step_counts); None where the model kind counts nothing
         rec.counts = None

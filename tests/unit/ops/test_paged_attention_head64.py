@@ -103,7 +103,7 @@ def test_a_head_of_128_takes_the_code_it_took(monkeypatch):
 
     monkeypatch.setattr(pa, "_paged_call", spy)
     got = paged_decode_attention(q, kc, vc, tabs, pos, jnp.int32(1), interpret=True)
-    assert seen == {"n_args": 9}                           # positional, as before: no head_dim
+    assert seen == {"n_args": 9, "tiles": None}            # positional, as before: no head_dim
     want = xla_paged_attention(q, kc, vc, tabs, pos, jnp.int32(1))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
     monkeypatch.undo()

@@ -150,6 +150,21 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled(fn, args, **jit_options):
+    """``fn`` compiled for the described chip, the persistent cache off around
+    it: a compile for a chip that is not attached is written there and cannot
+    be read back, and the next one would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn, **jit_options).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 def test_mosaic_takes_the_cells_shape_and_the_pool_is_aliased(one_chip):
     """Compiled for a described v5e (nothing runs): the kernel lowers at
     ``nemotron3-super-agents``' shape, the pool comes back as the buffer it
@@ -163,16 +178,7 @@ def test_mosaic_takes_the_cells_shape_and_the_pool_is_aliased(one_chip):
             sds((rows,), jnp.bool_), sds((rows,), jnp.bool_),
             sds((rows, groups, 128), jnp.float32), sds((rows, groups, 128), jnp.float32),
             sds((rows, 128), jnp.float32), sds((rows, 128, 64), jnp.float32))
-    from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(lambda *a: ssm_state_step(*a, interpret=False),
-                           donate_argnums=0).lower(*args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    compiled = _compiled(lambda *a: ssm_state_step(*a, interpret=False), args, donate_argnums=0)
     memory = compiled.memory_analysis()
     pool_bytes = int(np.prod(shape)) * 4
     assert memory.alias_size_in_bytes >= pool_bytes
@@ -200,15 +206,42 @@ def test_mosaic_takes_the_paged_kernel_at_a_head_of_64(one_chip, rows):
     args = (sds((rows, 32, 64), jnp.bfloat16), sds(pool, jnp.bfloat16), sds(pool, jnp.bfloat16),
             sds((rows, 136), jnp.int32), sds((rows,), jnp.int32), sds((), jnp.int32),
             sds((), jnp.int32))
-    from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(lambda *a: paged_decode_attention(*a, interpret=False)).lower(
-            *args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    compiled = _compiled(lambda *a: paged_decode_attention(*a, interpret=False), args)
     assert "paged_decode_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < int(np.prod(pool)) * 2 // 100
+
+
+# (query heads, key-value heads, head, block rows, pool blocks, table blocks) of the two cells
+# whose 512-row programs hold prompt chunks: lfm2-24b-rag, mistral7b-chat-r2
+TILED_SHAPES = {"head64-64row-blocks": (32, 8, 64, 64, 8705, 136),
+                "head128-16row-blocks": (32, 8, 128, 16, 2560, 360)}
+
+
+@pytest.mark.parametrize("shape", list(TILED_SHAPES))
+def test_mosaic_takes_a_query_tile(one_chip, shape):
+    """Compiled for a described v5e (nothing runs; in this file for the reason
+    above): a 512-row ``put`` program's paged kernel with the step's query
+    tiles laid inside the program (``paged_attention.query_tiles``) - the
+    body that attends a tile of a chunk's rows through one walk of its
+    context beside the one-row body - lowers at both cells' shapes."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    H, Hkv, Dh, bs, NB, MB = TILED_SHAPES[shape]
+    rows, n_seqs = 512, 64
+    assert pa.kernel_supported(Dh, bs, Hkv) and pa.smem_table_fits(rows, MB)
+    assert pa.query_tile_rows(rows, MB) == pa.QUERY_TILE
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, kc, vc, tab, pos, layer, live, seq):
+        tiles = pa.query_tiles(seq, pos, n_seqs, live, MB)
+        return pa.paged_decode_attention(q, kc, vc, tab, pos, layer, live, tiles, interpret=False)
+
+    pool = (2, NB, bs, Hkv * Dh)
+    args = (sds((rows, H, Dh), jnp.bfloat16), sds(pool, jnp.bfloat16), sds(pool, jnp.bfloat16),
+            sds((rows, MB), jnp.int32), sds((rows,), jnp.int32), sds((), jnp.int32),
+            sds((), jnp.int32), sds((rows,), jnp.int32))
+    compiled = _compiled(step, args)
+    assert "paged_decode_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < int(np.prod(pool)) * 2 // 100
+

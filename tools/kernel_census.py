@@ -38,6 +38,20 @@ rests on this table (PERF.md, PR 33). ``--paged64`` is the same at
 heads a 128-lane slice), 64-row blocks, 64 decode rows at contexts 1024-8192
 and a 512-row chunk at three depths (~1 min; PERF.md, PR 41).
 
+``--chunk`` times what a **query tile** does to it (PERF.md, PR 42), at both
+shapes: a 512-row prompt chunk at contexts 0 / 512 / 2048 / 7680 (0 / 512 at
+16-row blocks, whose table holds 1536 positions), 64 decode rows alone, a
+mixed step as ``lfm2-24b-rag`` and chat pack one (decode rows, then a chunk)
+and a verify program's 4 and 2 rows a sequence - the parent checkout's kernel,
+this tree's with every row an item (``tiles=None``), with the tiles
+``query_tiles`` lays, and with ``QUERY_TILE`` at other values. ms a call, GB/s
+and share of 819 in least bytes: a sequence's context once a call, whatever
+rows of it the call holds. Then a **selection's** calls, which take no tiles
+and must cost what the parent's cost (``minicpm-sala-longdoc``'s shape: a row
+a (token, key-value head) over a pool of one head a layer, 64-row blocks of
+128 values, 64 selected blocks a row; 1024 rows as a 512-token program holds
+them, 48 as a burst step does), parent beside this tree's (~3 min).
+
 ``--mla`` times ``paged_mla_decode_attention`` at the shape classes the two
 latent cells serve (256-row bf16 blocks of 512 + 128 values, a traced layer
 index): Moonlight's 128 decode rows at contexts 1024-4096 under 16 heads,
@@ -363,6 +377,146 @@ def paged_attention_classes(parent_dir, narrow=False):
         yield name, record
 
 
+# ``--chunk``: (name, head of 64?, rows, decode rows, their (least, most) context,
+# the chunks after them: (rows, context before it) each, one sequence each)
+CHUNK_CLASSES = (
+    ("rag-decode-64", True, 64, 64, (1024, 8192), ()),
+    ("rag-chunk-512-ctx0", True, 512, 0, None, ((512, 0),)),
+    ("rag-chunk-512-ctx512", True, 512, 0, None, ((512, 512),)),
+    ("rag-chunk-512-ctx2048", True, 512, 0, None, ((512, 2048),)),
+    ("rag-chunk-512-ctx7680", True, 512, 0, None, ((512, 7680),)),
+    ("rag-mixed-31+481-ctx2048", True, 512, 31, (1024, 8192), ((481, 2048),)),
+    ("chat-decode-64", False, 64, 64, (128, 1536), ()),
+    ("chat-chunk-512-ctx0", False, 512, 0, None, ((512, 0),)),
+    ("chat-chunk-512-ctx512", False, 512, 0, None, ((512, 512),)),
+    ("chat-mixed-64+448-ctx512", False, 512, 64, (128, 1536), ((448, 512),)),
+    ("chat-verify-32x4", False, 128, 0, None, tuple((4, 97 + 41 * i) for i in range(32))),
+    ("chat-verify-64x2", False, 128, 0, None, tuple((2, 97 + 20 * i) for i in range(64))),
+)
+# QUERY_TILE beside the module's own
+CHUNK_RULES = (("tq=16", 16), ("tq=64", 64))
+# ``selected=True`` calls: (name, rows, selected blocks a row)
+SELECTION_CLASSES = (("sala-selection-1024x64", 1024, 64), ("sala-selection-48x64", 48, 64))
+
+
+def chunk_classes(parent_dir):
+    """Yields one record a class of ``CHUNK_CLASSES``: the parent checkout's
+    kernel, this tree's with no tiles, with :func:`paged_attention.query_tiles`'
+    and with the tile heights of ``CHUNK_RULES``, each against
+    ``xla_paged_attention`` on every eighth row; then one a class of
+    ``SELECTION_CLASSES``: the parent's and this tree's ``selected=True`` call."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    parent = _parent_kernel(parent_dir, "paged_attention")
+    H, Hkv = 32, 8
+    rng = np.random.default_rng(42)
+    pools = {}
+    for name, narrow, T, decode, ctx, chunks in CHUNK_CLASSES:
+        Dh, bs, L, NB, MB = (64, 64, 2, 8705, 136) if narrow else (HEAD_DIM, 16, 4, 8192, 96)
+        if narrow not in pools:
+            pool = jax.jit(lambda key: jax.random.normal(key, (L, NB, bs, Hkv * Dh), jnp.bfloat16))
+            pools = {narrow: (pool(jax.random.PRNGKey(1)), pool(jax.random.PRNGKey(2)))}
+        kc, vc = pools[narrow]
+        layer = jnp.int32(L - 2)
+        n_seqs = decode + len(chunks)
+        tables = np.zeros((n_seqs + 1, MB), np.int32)
+        seq, pos = np.full(T, n_seqs, np.int32), np.zeros(T, np.int32)
+        free = iter(rng.permutation(np.arange(1, NB)))
+        ends = []
+        if decode:   # decode rows first, as the engine packs them
+            pos[:decode] = np.exp(rng.uniform(np.log(ctx[0]), np.log(ctx[1]), decode)).astype(int) - 1
+            seq[:decode] = np.arange(decode)
+            ends += [int(p) + 1 for p in pos[:decode]]
+        at = decode
+        for i, (rows, before) in enumerate(chunks):
+            seq[at:at + rows], pos[at:at + rows] = decode + i, before + np.arange(rows)
+            ends.append(before + rows)
+            at += rows
+        for i, end in enumerate(ends):
+            need = -(-end // bs)
+            tables[i, :need] = [next(free) for _ in range(need)]
+        live = at
+        q = jnp.asarray(rng.standard_normal((T, H, Dh), np.float32), jnp.bfloat16)
+        tabs_d, pos_d, seq_d = jnp.asarray(tables[seq]), jnp.asarray(pos), jnp.asarray(seq)
+        some = jnp.arange(0, live, 8)
+        want = jax.jit(pa.xla_paged_attention)(q[some], kc, vc, tabs_d[some], pos_d[some], layer)
+        least = sum(ends) * 2 * Hkv * Dh * 2
+        record = {"rows": T, "live_rows": live, "seq_ctx_tokens": sum(ends), "least_bytes": least}
+
+        live_d = jnp.int32(live)  # on the device: a Python int is a transfer a call
+        args = (q, kc, vc, tabs_d, pos_d, layer, live_d)
+
+        def timed(fn, *tiles):
+            try:
+                jax.clear_caches()   # the rule is a module constant, not a key of the trace
+                call = jax.jit(fn)
+                if not mosaic_kernels(call.lower(*args, *tiles)):
+                    raise RuntimeError("no Mosaic kernel in the lowered program")
+                ms = _ms_a_call(call, *args, *tiles, calls=100)
+                err = rel_err(call(*args, *tiles)[some], want)
+                return {"ms": ms, "least_gb_s": least / ms / 1e6,
+                        "hbm_share": 100 * least / ms / 1e6 / HBM_GB_S,
+                        "rel_err": float(f"{err:.3e}")}
+            except Exception as e:  # a refusal is a record too
+                return {"refused": f"{type(e).__name__}: {e}"[:600]}
+
+        def tiled():
+            """The kernel over the tiles of the rule in force, laid once a step
+            and not once a layer, so outside the timed call (under 30 us a step
+            inside ``lfm2-24b-rag``'s program: PERF.md, PR 42)."""
+            jax.clear_caches()
+            tiles = jax.jit(lambda seq, pos, live: pa.query_tiles(seq, pos, n_seqs, live, MB))(
+                seq_d, pos_d, live_d)
+            return timed(lambda *a: pa.paged_decode_attention(*a[:7], a[7:], interpret=False),
+                         *tiles)
+
+        if parent is not None:
+            record["parent"] = timed(lambda *a: parent.paged_decode_attention(*a, interpret=False))
+        record["rows-alone"] = timed(lambda *a: pa.paged_decode_attention(*a, interpret=False))
+        rule = pa.QUERY_TILE
+        record["rule"] = {"QUERY_TILE": rule,
+                          "chunk_rows_tiles": pa.chunk_counts(seq, pos, n_seqs, live)}
+        record["tiles"] = tiled()
+        for label, tq in CHUNK_RULES:
+            pa.QUERY_TILE = tq
+            try:
+                record[label] = tiled()
+            finally:
+                pa.QUERY_TILE = rule
+        yield name, record
+
+    # a selection's calls: a table a (token, key-value head), nothing shared, no tiles
+    G, Dh, bs, NB = 16, HEAD_DIM, 64, 8800
+    pool = jax.jit(lambda key: jax.random.normal(key, (1, NB, bs, Dh), jnp.bfloat16))
+    kc, vc = pool(jax.random.PRNGKey(1)), pool(jax.random.PRNGKey(2))
+    for name, T, W in SELECTION_CLASSES:
+        tab = jnp.asarray(np.sort(rng.integers(1, NB, (T, W)), axis=1).astype(np.int32))
+        at = jnp.asarray(((W - 1) * bs + rng.integers(0, bs, T)).astype(np.int32))
+        q = jnp.asarray(rng.standard_normal((T, G, Dh), np.float32), jnp.bfloat16)
+        args = (q, kc, vc, tab, at, jnp.int32(0), jnp.int32(T))
+        some = jnp.arange(0, T, 8)
+        want = jax.jit(pa.xla_paged_attention)(q[some], kc, vc, tab[some], at[some], args[5])
+        least = T * W * bs * Dh * 2 * 2   # a block once a (token, head), keys and values
+        record = {"rows": T, "blocks_a_row": W, "least_bytes": least}
+        for side, mod in (("parent", parent), ("rows-alone", pa)):
+            if mod is None:
+                continue
+            jax.clear_caches()
+            call = jax.jit(lambda *a, mod=mod: mod.paged_decode_attention(
+                *a, interpret=False, selected=True))
+            ms = min(_ms_a_call(call, *args, calls=50) for _ in range(3))
+            err = rel_err(call(*args)[some], want)
+            record[side] = {"ms": ms, "least_gb_s": least / ms / 1e6,
+                            "hbm_share": 100 * least / ms / 1e6 / HBM_GB_S,
+                            "rel_err": float(f"{err:.3e}")}
+        yield name, record
+
+
 # (name, rows, heads, table columns, decode rows, (least, most) context, chunk (start, tokens))
 MLA_CLASSES = (("moonlight-decode-128", 128, 16, 18, 128, (1024, 4096), None),
                ("topics-decode-256-69pad", 256, 64, 6, 187, (128, 1536), None),
@@ -652,8 +806,10 @@ def main():
     paged, mla = "--paged" in sys.argv or "--paged64" in sys.argv, "--mla" in sys.argv
     parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
-    live, ssm = "--live" in sys.argv, "--ssm" in sys.argv
-    if ssm:
+    live, ssm, chunk = "--live" in sys.argv, "--ssm" in sys.argv, "--chunk" in sys.argv
+    if chunk:
+        section, records = "query_tiles", chunk_classes(parent_dir)
+    elif ssm:
         section, records = "ssm_state", ssm_state_classes()
     elif live:
         shares = [float(x) for x in sys.argv[sys.argv.index("--live") + 1].split(",")]
@@ -668,7 +824,7 @@ def main():
     for name, record in records:
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
-    for name, fn, ref, args, tol in (() if ssm or live or paged or mla
+    for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk
                                      or "--gmm-only" in sys.argv
                                      else cases()):
         try:
@@ -678,7 +834,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("ssm_census.json" if ssm else "live_census.json" if live
+    out = ("chunk_census.json" if chunk else "ssm_census.json" if ssm
+           else "live_census.json" if live
            else "paged_census.json" if paged
            else "mla_census.json" if mla else "kernel_census.json")
     with open(os.path.join("chiprun_out", out), "w") as f:
