@@ -8,7 +8,9 @@ filled here by a native flax implementation designed for XLA:
 - one ``nn.scan`` over identical blocks (single compiled layer body,
   layer-stacked params with a leading L dim — the layout ZeRO-3
   gather-per-layer wants);
-- ``nn.remat`` activation checkpointing inside the scan;
+- ``nn.remat`` activation checkpointing inside the scan (under ZeRO-3's
+  ``overlap_comm`` and full rematerialisation the training scan is
+  ``runtime/zero/overlap.py``'s, whose backward gathers a layer once);
 - GQA attention with RoPE, RMSNorm, SwiGLU;
 - Megatron-style tensor-parallel sharding via :meth:`tp_rule`
   (consumed by ``ZeroShardingPolicy``), Ulysses sequence parallelism
@@ -444,7 +446,10 @@ class LlamaModel(nn.Module):
             policy = _remat_policy(cfg.remat_policy)
             block = nn.remat(block, prevent_cse=False, policy=policy)
         carry0 = (h, jnp.zeros((), jnp.float32))
-        if decode:
+        overlapped = None if decode or self.is_initializing() else self._overlapped_layers(carry0, positions)
+        if overlapped is not None:
+            (h, aux_loss), new_cache = overlapped, None
+        elif decode:
             # cache leaves carry a leading L dim and scan over layers
             # threads each layer's slice through as scanned input/output.
             ScanBlocks = nn.scan(block,
@@ -465,6 +470,36 @@ class LlamaModel(nn.Module):
             (h, aux_loss), new_cache = ScanBlocks(cfg, name="layers")(carry0, positions)
         h = RMSNorm(eps=cfg.rms_norm_eps, name="norm")(h)
         return h, embed, aux_loss, new_cache
+
+    def _overlapped_layers(self, carry0, positions):
+        """The training scan under ``zero_optimization.overlap_comm`` at ZeRO
+        stage 3 (``runtime/zero/overlap.py``): its backward gathers a layer
+        once, whole, for recomputation and differentiation alike. None - and
+        the caller runs the plain scan - unless the engine asked for it, the
+        stack is sharded over a zero axis, its layers come from HBM and are
+        recomputed in full: the overlapped scan saves a layer's input and
+        nothing else, which is what ``remat_policy="full"`` states."""
+        from deepspeed_tpu.runtime.zero import overlap
+        cfg = self.config
+        asked = overlap.active()
+        if asked is None or cfg.offload_params or not (cfg.remat and cfg.remat_policy == "full"):
+            return None
+        stacked = self.variables["params"]["layers"]
+        path = "/".join(self.scope.path + ("layers",))
+        layouts = asked.layouts(path, stacked)
+        if layouts is None:
+            return None
+        block = LlamaBlock(cfg, parent=None)
+        keys = None
+        if self.has_rng("dropout"):
+            keys = jax.random.split(self.make_rng("dropout"), cfg.num_hidden_layers)
+
+        def layer(params, carry, positions, key):
+            rngs = None if key is None else {"dropout": key}
+            return block.apply({"params": params}, carry, positions, rngs=rngs)[0]
+
+        asked.scans[path] = cfg.num_hidden_layers  # the backward's gathers
+        return overlap.overlapped_scan(layer, stacked, carry0, positions, keys, *layouts)
 
 
 class LlamaForCausalLM(nn.Module):

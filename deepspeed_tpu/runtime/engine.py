@@ -55,6 +55,7 @@ from deepspeed_tpu.runtime.constants import (ADAGRAD_OPTIMIZER, ADAM_OPTIMIZER, 
                                              LAMB_OPTIMIZER, LION_OPTIMIZER, SGD_OPTIMIZER)
 from deepspeed_tpu.runtime.dataloader import DeepSpeedDataLoader, RepeatingLoader
 from deepspeed_tpu.runtime.fp16.loss_scaler import DynamicLossScaler, has_overflow, scaler_state, update_scale
+from deepspeed_tpu.runtime.zero import overlap
 from deepspeed_tpu.runtime.zero.partitioning import ZeroShardingPolicy, batch_spec, path_tree_map
 from deepspeed_tpu.utils import tracing
 from deepspeed_tpu.utils.env_registry import env_bool, env_int, env_raw
@@ -186,6 +187,11 @@ class DeepSpeedEngine:
             offload_param=zc.offload_param_device().value != "none",
             mics_shard_size=max(0, int(zc.mics_shard_size)),
         )
+
+        # overlap_comm at stage 3: a layer scan traced by the gradient core takes
+        # runtime/zero/overlap.py's backward; None leaves every scan as it is
+        self._layer_overlap = (overlap.LayerOverlap(self.sharding_policy)
+                               if zc.stage == 3 and zc.overlap_comm else None)
 
         # Monitors / timers
         self.monitor = MonitorMaster(self._config.monitor_config)
@@ -859,8 +865,9 @@ class DeepSpeedEngine:
 
         if not self._quantized_comm_enabled():
             def core(params, scale, rng, args, kwargs):
-                (_, loss), grads = jax.value_and_grad(loss_of, has_aux=True)(
-                    params, scale, rng, args, kwargs)
+                with overlap.overlapping(self._layer_overlap):
+                    (_, loss), grads = jax.value_and_grad(loss_of, has_aux=True)(
+                        params, scale, rng, args, kwargs)
                 return loss, grads
             return core
 
@@ -1392,6 +1399,8 @@ class DeepSpeedEngine:
                         self.params, self.master_params, self.opt_state, self.scaler_state, mean_loss, gnorm, overflow = out
                     self._enforce_param_memory_kinds()
                 self._nvme_offload_params()
+                if self._layer_overlap is not None:  # known once the program is traced
+                    rec.n_layers_prefetched = gas * self._layer_overlap.n_layers_prefetched
             self.global_steps += 1
             self.micro_steps += gas
             self.global_samples += self.train_batch_size()

@@ -17,12 +17,25 @@ collectives:
   produced (``with_sharding_constraint`` in the engine's grad
   accumulation), the analogue of IPG bucketing + early reduce-scatter
   (reference stage_1_and_2.py:931).
-- stage 3: + parameters themselves sharded; with scan-over-layers XLA
-  all-gathers each layer's params just before use and frees them after,
-  which replaces the prefetch coordinator
-  (reference partitioned_param_coordinator.py:62). Small params below
-  ``param_persistence_threshold`` stay replicated, the analogue of
-  persistent params (reference parameter_offload.py:242).
+- stage 3: + parameters themselves sharded; with scan-over-layers a
+  layer's parameters are gathered inside the iteration that uses them
+  and freed after it. What the compiler makes of that on a TPU is a ring
+  of partial matmuls a weight (``collective-permute``), which hides in
+  the forward pass and not in the backward. ``overlap_comm`` (true at
+  stage 3 unless set false) hands the backward of a layer scan to
+  ``runtime/zero/overlap.py``: a layer gathered once, whole, for
+  recomputation and differentiation alike, its small gradients summed by
+  one asynchronous all-reduce - the prefetch coordinator and
+  ``overlap_comm`` of the reference (partitioned_param_coordinator.py:62)
+  as a program. It engages where :meth:`ZeroShardingPolicy.zero_sharded`
+  holds for a leaf of the stack, the layers are not streamed from the
+  host and are recomputed in full; ``overlap_comm: false``, stages 0-2,
+  one device, decode and the ``dots`` / ``moe`` remat policies compile
+  what they compiled before. ``stage3_prefetch_bucket_size`` and
+  ``stage3_max_live_parameters`` are accepted and unread: the depth is
+  one layer. Small params below ``param_persistence_threshold`` stay
+  replicated, the analogue of persistent params (reference
+  parameter_offload.py:242).
 """
 
 from typing import Any, Callable, Optional
@@ -168,6 +181,17 @@ class ZeroShardingPolicy:
         if int(np.prod(shape)) < self.param_persistence_threshold:
             return base
         return shard_largest_free_dim(shape, base, self._param_zero_axes(path), self.mesh)
+
+    def gathered_spec(self, path: str, shape) -> P:
+        """Sharding of a parameter as its arithmetic reads it: the zero axes
+        gathered, only the tensor/expert placement left."""
+        return self._base_spec(path, shape)
+
+    def zero_sharded(self, path: str, shape) -> bool:
+        """Is this parameter split over a zero axis (so that using it takes
+        an all-gather)?"""
+        return (_spec_used_axes(self.param_spec(path, shape))
+                != _spec_used_axes(self.gathered_spec(path, shape)))
 
     def opt_spec(self, path: str, shape) -> P:
         """Sharding of fp32 master params and optimizer moments."""

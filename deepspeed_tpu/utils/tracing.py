@@ -89,7 +89,8 @@ CPU_MARKED = frozenset(PREFIX + name for name in (
     "train.prepare", "train.dispatch", "train.sync", "train.post"))
 
 STEP_FIELDS = ("seq", "engine", "kind", "program", "k", "n_seqs", "n_tokens", "n_rows",
-               "n_prompt_tokens", "n_ctx_tokens", "n_chunk_rows", "n_chunk_tiles", "counts",
+               "n_prompt_tokens", "n_ctx_tokens", "n_chunk_rows", "n_chunk_tiles",
+               "n_layers_prefetched", "counts",
                "state_step", "caused_by", "uids",
                "start_ns", "end_ns", "thread", "gc_ns", "gc_passes", "compile_ns", "compiles",
                "waited_ns", "idle_passes")
@@ -254,6 +255,9 @@ class Recorder:
         # of n_rows, the rows the paged kernel attended through a query tile, and the tiles
         # (ops/pallas/paged_attention.chunk_counts of the batch the engine packed)
         rec.n_chunk_rows = rec.n_chunk_tiles = 0
+        # a train step only: the layer gathers its program issues whole, ahead of the
+        # arithmetic that reads them (runtime/zero/overlap.py), known when it is built
+        rec.n_layers_prefetched = 0
         # what the program itself counted on the device, by name, fetched with its result
         # (model_runner: kind.step_counts); None where the model kind counts nothing
         rec.counts = None
