@@ -269,11 +269,11 @@ class InferenceEngineV2:
         # of device arrays, carried and donated through every program beside the pools,
         # and a slot of it a tracked sequence (ragged/slot_pool.py). None for a kind
         # that has none, whose programs are then the ones they were.
-        self.state_extra = self.slot_pool = None
-        self._seq_rows = getattr(kind, "seq_rows", 0)
-        if hasattr(kind, "extra_state"):
-            slots = int(sm.max_tracked_sequences)
-            self.state_extra = kind.extra_state(cfg, num_blocks, slots, dtype)
+        self.slot_pool = None
+        self._seq_rows = kind.seq_rows
+        slots = int(sm.max_tracked_sequences)
+        self.state_extra = kind.extra_state(cfg, num_blocks, slots, dtype)
+        if self.state_extra is not None:
             # a slot is one row of each entry the kind names (their second axis)
             self.slot_pool = SlotPool(slots, sum(
                 self.state_extra[name].nbytes // self.state_extra[name].shape[1]

@@ -18,34 +18,44 @@ consumes the padded flat batch —
   place from the program's argument to its result;
 - the layer stack is ``lax.scan`` over the model's stacked scan params
   (the scan's ``xs``: what is read-only per layer), so any
-  ``LlamaForCausalLM`` (Llama/Mistral/Mixtral/Qwen2) or
-  ``GPTForCausalLM`` (GPT-2/J/NeoX, OPT, Bloom, Falcon, Phi) checkpoint
-  serves directly;
-- what differs between model families sits in one object each
-  (:class:`LlamaKind`, :class:`GPTKind`, :class:`MoonlightKind`,
-  :class:`LongcatKind`, :class:`SalaKind`, :class:`NemotronHKind`,
-  :class:`Lfm2Kind`; :func:`kind_of` picks by the config's type): the state
-  the pool holds and how many layers of it, the layer step, the layer
-  pattern (leading layers, then the scan — or, for a stack of several
-  kinds of layer, :meth:`SalaKind.stack` and, for :class:`NemotronHKind`
-  and :class:`Lfm2Kind`, :func:`_run_segments`), what state it
-  keeps beyond the two paged pools, what a step counts on the device, and
-  the final norm. :func:`ragged_forward` is the same for all.
+  ``LlamaForCausalLM`` or ``GPTForCausalLM`` checkpoint serves directly;
+- what differs between model families sits in one class each, derived
+  from :class:`ModelKind` (``KINDS``; :func:`kind_of` picks by the config's
+  type), which states only what differs from the base: the state the pool
+  holds and how many layers of it, the layer pattern (leading layers, then
+  the scan — or, for a stack of several kinds of layer,
+  :meth:`SalaKind.stack` and :func:`_run_segments`), what state it keeps
+  beyond the two paged pools, what a step counts on the device, the final
+  norm. :func:`ragged_forward` is the same for all. To add a kind:
+  ``docs/MIGRATING.md``, "Adding a model kind".
+
+Written once for the kinds that share it: the biased top-k router and the
+expert layer behind it (:func:`_routed_experts`; a kind gives its ``router``
+values), one layer cut out of a stack (:func:`_layer_of`: the check hooks
+too), a convolution whose tail is a slot, a packed recurrence's decays, the
+latent attention. **Not folded, on purpose**: the grouped-query mixers
+:func:`_nemotron_attention` and :func:`_lfm2_attention`, a dozen lines each,
+differ in a head norm, a rotation and a projection's name (one function of
+those three is as long as the two, and its callers still know all three);
+:func:`_layer_step`'s carries LoRA sites and sharding constraints. A new
+kind takes the nearest; a third wants a reason.
 """
 
 import functools
 import math
-from typing import NamedTuple
-
-import numpy as np
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.llama import LlamaConfig, rope_frequencies, rope_scaling_of
+from deepspeed_tpu.models import (GPTConfig, Lfm2MoeConfig, LlamaConfig, LongcatFlashConfig,
+                                  MiniCPMSalaConfig, MoonlightConfig, NemotronHConfig)
+from deepspeed_tpu.models.lfm2 import TOPK_EPS
+from deepspeed_tpu.models.llama import rope_frequencies, rope_scaling_of
 from deepspeed_tpu.models.nemotron_h import relu2
+from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn, fused_gmm_enabled
 
 
 def _c(x, entries, mesh):
@@ -94,6 +104,14 @@ def _rope_flat(x, cos, sin, positions):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)
+
+
+def _rope_rows(positions, d, theta):
+    """→ (cos, sin) [T, d/2]: the rotations of this batch's ``positions`` [T], row t token
+    t's, not a table of ``max_position_embeddings`` (131072) rows baked into the program."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(angle), jnp.sin(angle)
 
 
 def _rope_flat_interleaved(x, cos, sin, positions):
@@ -264,9 +282,100 @@ def _layer_groups(stacks, layer):
     return table, layer * jax.tree.leaves(stacks)[0].shape[1]
 
 
+def _layer_of(stack, layer):
+    """Layer ``layer`` (may be traced) of a stack ``{name: [L, ...]}``, without
+    its ``experts``, which ride every step whole. A stack's body cuts its
+    layers so, and a kind's check hooks (``expert_layer``, ``mamba_layer``, ...)
+    to run one alone **as the step programs compute it**: the same slices."""
+    return jax.tree.map(lambda w: w[layer], {k: v for k, v in stack.items() if k != "experts"})
+
+
+def _experts_apart(layers):
+    """→ (a stack of layers for a scan's ``xs``, its ``mlp`` without the routed experts;
+    those ``[L, E, in, out]``, which ride the step whole: see :func:`_routed_experts`)."""
+    mlp = layers["mlp"]
+    return {**layers, "mlp": {k: v for k, v in mlp.items() if k != "experts"}}, mlp["experts"]
+
+
+# what a routed-expert layer behind a share counts, over the tokens that are not padding: the
+# picks whose expert is held, the zero-compute picks, the held experts with at least one row
+EXPERT_COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live")
+
+
+class Router(NamedTuple):
+    """What differs between the kinds' biased top-k routers (``kind.router(cfg, params)``)."""
+    weight: jax.Array           # [D, columns]
+    bias: jax.Array             # [columns]: joins the scores for the choice, not the weights
+    top_k: int
+    scale: float                # routed_scaling_factor
+    score: Callable = jax.nn.sigmoid    # or jax.nn.softmax, over the columns
+    # of the scores' matmul, of which the picks are a step function (None: the default)
+    precision: jax.lax.Precision | None = jax.lax.Precision.HIGHEST
+    eps: float | None = 1e-20   # the picks' weights over (their sum + eps); None: as they are
+    share: ExpertShare | None = None    # the columns held here; None: every one
+
+
+def _route(x, r, real=None):
+    """Scores in float32; the ``top_k`` columns with the largest score +
+    bias, weighted by their **unbiased** scores, normalised or not, times
+    ``scale``. ``real`` [T] bool (or a function that gives it here, where
+    LongCat's program has the comparison): a row that is padding picks
+    nothing (-1). → (picks, weights) [T, k]."""
+    scores = r.score(jnp.dot(x.astype(jnp.float32), r.weight.astype(jnp.float32),
+                             precision=r.precision))
+    _, picks = jax.lax.top_k(scores + r.bias.astype(jnp.float32), r.top_k)
+    weights = jnp.take_along_axis(scores, picks, axis=-1)
+    if r.eps is not None:
+        weights = weights / (weights.sum(-1, keepdims=True) + r.eps)
+    weights = weights * r.scale
+    if real is not None:
+        picks = jnp.where((real() if callable(real) else real)[:, None], picks, -1)
+    return picks, weights
+
+
+def _routed_experts(x, r, experts, layer, real=None, enter=None, leave=None):
+    """One routed-expert layer on the normalised stream x [T, D]:
+    :func:`_route`, then the picks through the dropless grouped matmul over
+    the table of every layer's experts, read in place (:func:`_layer_groups`;
+    ``experts`` ``{gate,up,down}_proj [L, E, in, out]``, or ``{up,down}_proj``
+    of ungated ``relu(u W1)^2 W2`` experts; ``layer`` this one's index among
+    them). ``enter`` / ``leave``: the projections into and out of the latent
+    the experts work in (Nemotron-H). Behind a share (``r.share``; or, with
+    padding rows to leave out, every column: it says only that a pick of -1
+    is a row of no group) held picks alone become rows, zero-compute picks
+    give ``(their weights) * x``, the rest is left out, and the layer counts:
+    → (y [T, D], ``EXPERT_COUNTS`` int32 [3] or None)."""
+    with jax.named_scope("ds.moe_routed"):
+        picks, weights = _route(x, r, real)
+        columns = r.weight.shape[-1]
+        share = r.share
+        if share is None and real is not None:
+            share = ExpertShare(0, columns, columns)
+        table, first_group = _layer_groups(experts, layer)
+        gated = "gate_proj" in table
+        u = x if enter is None else _proj(x, enter)
+        y = dropless_moe_ffn(u, picks, weights, table["gate_proj" if gated else "up_proj"],
+                             table["up_proj"] if gated else None, table["down_proj"],
+                             num_experts=columns, widen_boundary=False, first_group=first_group,
+                             share=share, activation=jax.nn.silu if gated else relu2)
+        if leave is not None:
+            y = _proj(y, leave)
+        if share is None:
+            return y, None
+        held, zero = share.parts(picks)
+        if r.share is None:     # every column is held: a pick names its group outright
+            live = jnp.any(picks[..., None] == jnp.arange(share.held), axis=(0, 1))
+        else:
+            of_expert = picks[..., None] == share.first + jnp.arange(share.held)
+            live = jnp.any(of_expert & held[..., None], axis=(0, 1))
+        return y, jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
+
+
 def _moe_mlp(x, p, k, mesh=None, experts=None, layer=None):
     """Dropless top-k MoE over the flat [T, D] batch (Mixtral serving —
-    reference inference/v2 cutlass MoE gather/scatter). At serving time
+    reference inference/v2 cutlass MoE gather/scatter), with a router of
+    its own and not :func:`_route`: top k of the softmax itself, no bias,
+    quantized carriers, a mesh. At serving time
     capacity dropping is undesirable, so every token reaches its full
     top-k: tokens are replicated k× and pushed through the grouped GEMM
     (``ops/grouped_gemm.py`` — the Pallas grouped matmul on TPU,
@@ -274,16 +383,11 @@ def _moe_mlp(x, p, k, mesh=None, experts=None, layer=None):
     with the renormalized gate weights.
 
     Under a mesh with expert/tensor parallelism the grouped GEMM runs in
-    a manual shard_map: each shard holds ``E/ep`` experts (column/row
-    feature shards over 'tensor'), routes every token assignment but
-    masks the non-local ones, and a psum over ('expert', 'tensor')
-    combines — expert weights never leave their shard, the serving
-    analogue of training's expert-axis dispatch.
+    ``dropless_moe_ffn``'s manual shard_map (``E/ep`` experts a shard, a
+    psum over ('expert', 'tensor')): expert weights never leave their shard.
 
     Quantized serving: the MoE subtree stays BOXED through the v2 scan
-    like every other projection — the expert stacks feed the grouped
-    GEMM as grouped-layout carriers and dequantize inside it (fused
-    kernel on TPU, gathered/ragged identical-math fallbacks elsewhere);
+    like every other projection and dequantizes inside the grouped GEMM;
     only the [D, E] router sliver dequantizes here (its fp32 matmul
     needs the logits exactly as the unboxed path computed them).
     ``DS_FUSED_GMM=0`` restores the old dequantize-at-entry subtree.
@@ -293,15 +397,13 @@ def _moe_mlp(x, p, k, mesh=None, experts=None, layer=None):
     grouped GEMM reads the layer's groups where they lie in the table.
     Without them the stacks are ``p``'s own, this layer's slice."""
     from deepspeed_tpu.inference.quantization import QuantizedWeight
-    from deepspeed_tpu.ops.grouped_gemm import dropless_moe_ffn, fused_gmm_enabled
     if not fused_gmm_enabled():
         from deepspeed_tpu.inference.quantization import dequantize_tree
         p = dequantize_tree(p, x.dtype)
     gk = p["gate"]["wg"]["kernel"]
     if isinstance(gk, QuantizedWeight):
         gk = gk.dequantized(x.dtype)
-    gates = jax.nn.softmax(
-        (x.astype(jnp.float32) @ gk.astype(jnp.float32)), axis=-1)
+    gates = jax.nn.softmax(x.astype(jnp.float32) @ gk.astype(jnp.float32), axis=-1)
     topk_vals, topk_idx = jax.lax.top_k(gates, k)  # [T, k]
     if k > 1:
         topk_vals = topk_vals / jnp.maximum(topk_vals.sum(-1, keepdims=True), 1e-9)
@@ -391,21 +493,25 @@ def _scanned_stack(kind, params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn
     return h, kc, vc, extra, counts
 
 
-class LlamaKind:
-    """What the ragged engine asks of a model family: the per-layer state
-    it keeps in the paged pool (``state_kind``, :meth:`state_rows`), its
-    layer stack (:meth:`stack`; here :meth:`layers`' pattern: the leading
-    layers, run one by one, then the step and ``xs`` of the layer scan),
-    and its final norm. One object per family; :func:`kind_of` picks it from the
-    config's type. This one is the Llama family (Llama, Mistral, Mixtral,
-    Qwen2, InternLM, Gemma): keys and values, one scan over identical
-    blocks."""
-    name = "llama"
+class ModelKind:
+    """What the ragged engine asks of a model family: the per-layer state it
+    keeps in the paged pool (``state_kind``, :meth:`state_layers`,
+    :meth:`state_rows`), what it keeps beyond the pool, its layer stack
+    (:meth:`stack`; by default :meth:`layers`' pattern: the leading layers, run
+    one by one, then the step and ``xs`` of the layer scan), what a step counts
+    on the device, its final norm. A kind is a class, never an instance, and
+    states what differs from these defaults: keys and values of every layer,
+    nothing beyond them, no adapters, no counts."""
+    name = None
+    config = None           # the config class :func:`kind_of` finds this kind by
     state_kind = "kv"       # two pools of expanded keys and values, [L, NB, bs, Hkv*Dh]
-    lora = True
-    # names of the device-side counts a step of this kind's scan gives (int32, summed over
-    # its layers; they ride out with the step's result into its step record): none
+    lora = False
+    # names of the device-side counts a step of this kind's stack gives (int32, summed over
+    # its layers; they ride out with the step's result into its step record)
     step_counts = ()
+    seq_rows = 0            # per-sequence rows of the batch (``seq_state``'s length)
+    slot_state = ()         # the entries of extra_state a slot is a row of
+    experts_at = None       # the entry of params["model"] that holds the routed experts, whole
     stack = classmethod(_scanned_stack)
 
     @staticmethod
@@ -420,11 +526,46 @@ class LlamaKind:
         return width, width
 
     @staticmethod
+    def extra_state(cfg, num_blocks, slots, dtype):
+        """→ the tree of state beyond the two paged pools (zeros), or None."""
+        return None
+
+    @staticmethod
+    def seq_state(cfg, slot, prompt_len):
+        """A sequence's row of the batch, for a kind with ``seq_rows``."""
+        return (slot,)
+
+    @classmethod
+    def experts_form(cls, params, mesh):
+        """How a layer reaches its routed experts, for the engine's
+        start-up line: ``table`` (the stacks ride the step whole),
+        ``sliced`` (the scan cuts the layer's out) or None (no experts)."""
+        return "table" if cls.experts_at in params["model"] else None
+
+    @staticmethod
+    def final_norm(params, cfg, h):
+        return _rms(h, params["model"]["norm"]["scale"], cfg.rms_norm_eps)
+
+    @classmethod
+    def base_only(cls, mesh, lora):
+        """Refuses adapters and a mesh, for a kind whose stack serves neither."""
+        if lora is not None or mesh is not None:
+            raise NotImplementedError(f"the {cls.name} layer stack serves base-only"
+                                      + ("" if mesh is None else " on one device"))
+
+
+class LlamaKind(ModelKind):
+    """The Llama family (Llama, Mistral, Mixtral, Qwen2, InternLM, Gemma):
+    one scan over identical blocks, adapters, a mesh."""
+    name = "llama"
+    config = LlamaConfig
+    lora = True
+
+    @staticmethod
     def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
         """→ (h, leading [(step, xs), ...], scan step, scan xs)."""
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta,
-                                    scaling=rope_scaling_of(cfg))
-        cos, sin = jnp.asarray(cos), jnp.asarray(sin)
+        cos, sin = map(jnp.asarray, rope_frequencies(cfg.head_dim, cfg.max_position_embeddings,
+                                                     cfg.rope_theta, scaling=rope_scaling_of(cfg)))
         lora_ctx = None
         layers, experts = _split_expert_stacks(params["model"]["layers"], mesh)
         xs = (layer_ids, layers)
@@ -441,36 +582,26 @@ class LlamaKind:
 
     @staticmethod
     def experts_form(params, mesh):
-        """How a layer reaches its routed experts, for the engine's
-        start-up line: ``table`` (the stacks ride the step whole),
-        ``sliced`` (the scan cuts the layer's out) or None (no experts)."""
         layers = params["model"]["layers"]
         if "moe_mlp" not in layers:
             return None
         return "sliced" if _split_expert_stacks(layers, mesh)[1] is None else "table"
-
-    @staticmethod
-    def final_norm(params, cfg, h):
-        return _rms(h, params["model"]["norm"]["scale"], cfg.rms_norm_eps)
 
 
 class GPTKind(LlamaKind):
     """The GPT family (GPT-2/J/NeoX, OPT, Bloom, Falcon, Phi): the same
     state, its own block and position schemes."""
     name = "gpt"
+    config = GPTConfig
     lora = False
 
     @staticmethod
     def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
-        if lora is not None:
-            raise NotImplementedError(
-                "multi-tenant LoRA serving targets the Llama-family layer "
-                "stack; GPT-family models serve base-only")
+        GPTKind.base_only(None, lora)
         cos = sin = None
         if cfg.position_embedding == "rope" and cfg.rotary_dim > 0:
-            cos, sin = rope_frequencies(cfg.rotary_dim, cfg.max_position_embeddings,
-                                        cfg.rope_theta)
-            cos, sin = jnp.asarray(cos), jnp.asarray(sin)
+            cos, sin = map(jnp.asarray, rope_frequencies(
+                cfg.rotary_dim, cfg.max_position_embeddings, cfg.rope_theta))
         alibi = None
         if cfg.position_embedding == "alibi":
             from deepspeed_tpu.models.gpt import alibi_slopes
@@ -492,8 +623,6 @@ class GPTKind(LlamaKind):
 
 
 LATENT_ROPE_LANES = 128     # the rotated key's row, padded to one lane tile
-
-
 LATENT_FETCH_COUNTS = ("n_blocks_named", "n_blocks_fetched")
 
 
@@ -520,7 +649,7 @@ def _latent_stack(kind, params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_
     return h, kc, vc, extra, fetch[None]
 
 
-class MoonlightKind:
+class MoonlightKind(ModelKind):
     """Moonlight / DeepSeek-V3 (``models/moonlight.py``): a **latent**
     state and ``dense x first_k_dense_replace, moe x (L - that)``.
 
@@ -534,11 +663,11 @@ class MoonlightKind:
     values: ``kv_b_proj`` is absorbed, its key half into the query and
     its value half after the attention."""
     name = "moonlight"
+    config = MoonlightConfig
     state_kind = "latent"
-    lora = False
     step_counts = LATENT_FETCH_COUNTS
+    experts_at = "layers"
     stack = classmethod(_latent_stack)
-    state_layers = LlamaKind.state_layers
 
     @staticmethod
     def state_rows(cfg):
@@ -546,17 +675,11 @@ class MoonlightKind:
 
     @staticmethod
     def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
-        if lora is not None or mesh is not None:
-            raise NotImplementedError("the Moonlight layer stack serves base-only on one device")
-        cos, sin = rope_frequencies(cfg.qk_rope_head_dim, cfg.max_position_embeddings,
-                                    cfg.rope_theta)
-        cos, sin = jnp.asarray(cos), jnp.asarray(sin)
+        MoonlightKind.base_only(mesh, lora)
+        cos, sin = map(jnp.asarray, rope_frequencies(
+            cfg.qk_rope_head_dim, cfg.max_position_embeddings, cfg.rope_theta))
         n_dense = cfg.first_k_dense_replace
-        layers = params["model"]["layers"]
-        # The routed experts ride the step whole [layers, experts, in, out] and are no
-        # part of the scan's xs: _moonlight_moe says how a layer reaches its own.
-        experts = layers["mlp"]["experts"]
-        sliced = {**layers, "mlp": {k: v for k, v in layers["mlp"].items() if k != "experts"}}
+        sliced, experts = _experts_apart(params["model"]["layers"])
         step = functools.partial(_moonlight_layer_step, cfg, cos, sin, batch, attn_impl, experts)
         dense = params["model"]["dense_layers"]
         lead = [(step, (layer_ids[i], jax.tree.map(lambda x, i=i: x[i], dense)))
@@ -564,10 +687,13 @@ class MoonlightKind:
         return h, lead, step, (layer_ids[n_dense:], sliced)
 
     @staticmethod
-    def experts_form(params, mesh):
-        return "table"
-
-    final_norm = LlamaKind.final_norm
+    def router(cfg, mlp):
+        """``noaux_tc``: the matmul as it comes, normalised where
+        ``norm_topk_prob``; every column an expert held here, nothing counted."""
+        gate = mlp["gate"]
+        return Router(gate["weight"], gate["e_score_correction_bias"], cfg.num_experts_per_tok,
+                      cfg.routed_scaling_factor, precision=None,
+                      eps=1e-20 if cfg.norm_topk_prob else None)
 
 
 class LongcatKind(MoonlightKind):
@@ -577,12 +703,11 @@ class LongcatKind(MoonlightKind):
     num_layers``, and double layer ``l`` writes rows ``2l`` and ``2l +
     1``), no leading layers, and an expert layer that is one share of an
     expert-parallel deployment behind a router with zero-compute
-    columns. Each step counts, over its expert layers and its tokens
-    that are not padding: the picks whose expert is held, the
-    zero-compute picks, and the held experts with at least one row; then
-    the latent state's two (:func:`_latent_stack`)."""
+    columns. Each step counts ``EXPERT_COUNTS`` over its expert layers,
+    then the latent state's two (:func:`_latent_stack`)."""
     name = "longcat"
-    step_counts = ("n_picks_held", "n_picks_zero", "n_groups_live") + LATENT_FETCH_COUNTS
+    config = LongcatFlashConfig
+    step_counts = EXPERT_COUNTS + LATENT_FETCH_COUNTS
 
     @staticmethod
     def state_layers(cfg):
@@ -590,37 +715,34 @@ class LongcatKind(MoonlightKind):
 
     @staticmethod
     def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
-        if lora is not None or mesh is not None:
-            raise NotImplementedError("the LongCat layer stack serves base-only on one device")
-        # rotations for this batch's positions, not a table of max_position_embeddings
-        # (131072) rows baked into the program: row t is token t's
-        d = cfg.qk_rope_head_dim
-        inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-        angle = batch["token_pos"].astype(jnp.float32)[:, None] * inv_freq[None, :]
-        rope = (jnp.cos(angle), jnp.sin(angle), jnp.arange(h.shape[0], dtype=jnp.int32))
-        layers = params["model"]["layers"]
-        # The routed experts ride the step whole [L, held, in, out] and are no part of the
-        # scan's xs (see MoonlightKind); every other weight is [L, in, out] under its half's
-        # name, which the scan reads in place a layer at a time.
-        experts = layers["mlp"]["experts"]
-        sliced = {**layers, "mlp": {k: v for k, v in layers["mlp"].items() if k != "experts"}}
+        LongcatKind.base_only(mesh, lora)
+        rope = (*_rope_rows(batch["token_pos"], cfg.qk_rope_head_dim, cfg.rope_theta),
+                jnp.arange(h.shape[0], dtype=jnp.int32))
+        # every weight but the experts is [L, in, out] under its half's name, which the scan
+        # reads in place a layer at a time
+        sliced, experts = _experts_apart(params["model"]["layers"])
         step = functools.partial(_longcat_layer_step, cfg, rope, batch, attn_impl, experts)
         return h, (), step, (layer_ids.reshape(-1, 2), sliced)
 
     @staticmethod
+    def router(cfg, mlp):
+        """Softmax over every column, zero-compute ones too; not normalised; a share."""
+        router = mlp["router"]
+        return Router(router["classifier"]["weight"], router["e_score_correction_bias"],
+                      cfg.moe_topk, cfg.routed_scaling_factor, score=jax.nn.softmax, eps=None,
+                      share=ExpertShare(cfg.first_expert_held, cfg.held, cfg.n_routed_experts,
+                                        cfg.zero_expert_num))
+
+    @staticmethod
     def expert_layer(params, cfg, layer, x):
-        """``M(x)`` of double layer ``layer`` (may be traced) as the step
-        programs compute it - the same router, the same share, the table
-        of every layer's held experts read in place - for a check that
-        wants the expert layer alone: x [T, D], every row a token → m."""
+        """``M(x)`` of double layer ``layer`` alone (:func:`_layer_of`): x
+        [T, D], every row a token → m."""
         mlp = params["model"]["layers"]["mlp"]
-        router = jax.tree.map(lambda w: w[layer], {"router": mlp["router"]})
-        every_row = {"token_seq": jnp.zeros(x.shape[0], jnp.int32),
-                     "block_tables": jnp.zeros((2, 1), jnp.int32)}
-        return _longcat_moe(x, router, mlp["experts"], layer, cfg, every_row)[0]
+        return _routed_experts(x, LongcatKind.router(cfg, _layer_of(mlp, layer)), mlp["experts"],
+                               layer, jnp.ones(x.shape[0], bool))[0]
 
 
-class SalaKind:
+class SalaKind(ModelKind):
     """MiniCPM-SALA (``models/minicpm_sala.py``): a stack of **two kinds
     of layer in an irregular order**, whose state is of three kinds.
 
@@ -648,11 +770,11 @@ class SalaKind:
     head)s read and the blocks their contexts hold, and the rows through
     the linear layers."""
     name = "sala"
+    config = MiniCPMSalaConfig
     state_kind = "sparse_kv+slots"
-    lora = False
     step_counts = ("n_blocks_selected", "n_blocks_context", "n_linear_rows")
     seq_rows = 2            # per-sequence rows of the batch: (slot, sparse_from)
-    slot_state = ("slots",)  # the entries of extra_state a slot is a row of
+    slot_state = ("slots",)
 
     @staticmethod
     def state_layers(cfg):
@@ -664,7 +786,6 @@ class SalaKind:
 
     @staticmethod
     def extra_state(cfg, num_blocks, slots, dtype):
-        """→ the tree of state beyond the two paged pools (zeros)."""
         H, d = cfg.num_attention_heads, cfg.head_dim
         groups = cfg.sparse_block_size // cfg.sparse_kernel_stride
         return {"pooled_keys": jnp.zeros((SalaKind.state_layers(cfg), num_blocks, groups, d),
@@ -674,7 +795,6 @@ class SalaKind:
 
     @staticmethod
     def seq_state(cfg, slot, prompt_len):
-        """A sequence's row of the batch."""
         return slot, cfg.sparse_from(prompt_len)
 
     @staticmethod
@@ -684,8 +804,7 @@ class SalaKind:
         ``xs`` is the run's indices and its body reads layer ``i`` out of
         the whole stack, as a scan reads its ``xs``, so no run is cut out
         of the stack. → (h, kc, vc, extra, the counts in one row)."""
-        if lora is not None or mesh is not None:
-            raise NotImplementedError("the MiniCPM-SALA layer stack serves base-only on one device")
+        SalaKind.base_only(mesh, lora)
         if kc.shape[2] != cfg.sparse_block_size:
             raise ValueError(f"kv_block_size {kc.shape[2]} is not the selection's block "
                              f"size {cfg.sparse_block_size}")
@@ -696,7 +815,7 @@ class SalaKind:
 
         def linear_step(carry, i):
             h, slots = carry
-            lp = jax.tree.map(lambda w: w[i], linear)
+            lp = _layer_of(linear, i)
             return _sala_linear_layer(ctx, lp, log_decay[i], i, h, slots), None
 
         n_sparse = n_linear = 0
@@ -721,13 +840,8 @@ class SalaKind:
         return h, kc, vc, {"pooled_keys": kb, "slots": slots}, counts[None]
 
     @staticmethod
-    def experts_form(params, mesh):
-        return None
-
-    @staticmethod
     def final_norm(params, cfg, h):
-        h = _rms(h, params["model"]["norm"]["scale"], cfg.rms_norm_eps)
-        return h / jnp.asarray(cfg.logit_divisor, h.dtype)
+        return ModelKind.final_norm(params, cfg, h) / jnp.asarray(cfg.logit_divisor, h.dtype)
 
     @staticmethod
     def sparse_layer(params, cfg, layer, x, kc, vc, kb, batch, attn_impl=None):
@@ -766,12 +880,8 @@ class _SalaStep:
 
 
 def _rope_at(x, positions, theta):
-    """x [T, H, d] rotated by halves at ``positions`` [T] (the angles of
-    this batch's rows, not a table of ``max_position_embeddings`` rows)."""
-    d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    return _rope_flat(x, jnp.cos(angle), jnp.sin(angle), jnp.arange(x.shape[0]))
+    """x [T, H, d] rotated by halves at ``positions`` [T]."""
+    return _rope_flat(x, *_rope_rows(positions, x.shape[-1], theta), jnp.arange(x.shape[0]))
 
 
 def _sala_mlp(cfg, lp, h):
@@ -954,7 +1064,6 @@ def _sala_select(ctx, q, kb, layer_heads):
         return jnp.where(ctx.dense[:, None, None], whole[:, None], index).astype(jnp.int32)
 
 
-
 def _sala_sparse_mixer(ctx, a, layer, x, kc, vc, kb, attn_impl):
     """The ``minicpm4`` mixer on the normalised stream x [T, D]: write
     the rows' keys and values and the group means they complete, select,
@@ -1055,7 +1164,7 @@ def _run_segments(segments, counters_of, layer, carry):
     return carry, done
 
 
-class NemotronHKind:
+class NemotronHKind(ModelKind):
     """Nemotron-H (``models/nemotron_h.py``): **every layer one sublayer
     alone** - a Mamba-2 mixer, an attention or an expert layer, as the
     pattern's letter says - and state of two kinds side by side.
@@ -1067,69 +1176,51 @@ class NemotronHKind:
       ``conv`` ``[Lm, slots + 1, K - 1, C]``: an ``M`` layer's state a
       sequence - the recurrence's matrix a head and the last ``K - 1`` rows
       of ``xBC`` before the convolution's activation - the same at token 10
-      and at token 500,000. A tracked sequence owns a slot of both
-      (``ragged/slot_pool.py``, ``slot_state``); slot 0 is padding's. A
-      sequence's first rows (position 0) take both as zero, so a slot needs
-      no clearing between owners.
+      and at token 500,000; slots as :class:`SalaKind`'s, one of both a
+      tracked sequence.
 
-    :meth:`stack` runs the pattern as ``cfg.segments`` cuts it: **one scan
-    over a period** wherever a unit of layers repeats, its body the unit's
-    layers in order, each reading its own layer out of its kind's whole
-    stack; single layers elsewhere. The routed experts are one share of an
+    :meth:`stack` runs the pattern as ``cfg.segments`` cuts it
+    (:func:`_run_segments`). The routed experts are one share of an
     expert-parallel deployment (``ops/grouped_gemm.ExpertShare``) and ride
     every step whole, one table of ``Le x held`` groups. Each step counts,
-    over its tokens that are not padding: the picks whose expert is held,
-    the zero-compute picks (none: the name is the expert-share readers'),
-    the held experts with at least one row, the rows through the ``M``
-    layers, and the (sequence, ``M`` layer)s whose state it read and
+    over its tokens that are not padding: ``EXPERT_COUNTS`` (no pick is
+    zero-compute: the name is the expert-share readers'), the rows through
+    the ``M`` layers, and the (sequence, ``M`` layer)s whose state it read and
     wrote - each of those one slot fetched and written back by
     ``ops/pallas/ssm_state.ssm_state_step``, the kernel that takes the
     ``ssm`` pool in place (``AttentionChoice.state_step`` says whether a
     program got it)."""
     name = "nemotron_h"
+    config = NemotronHConfig
     state_kind = "kv+slots"
-    lora = False
-    step_counts = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_ssm_rows",
-                   "n_state_slots")
-    seq_rows = 1            # per-sequence rows of the batch: (slot,)
-    slot_state = ("ssm", "conv")    # the entries of extra_state a slot is a row of
+    step_counts = EXPERT_COUNTS + ("n_ssm_rows", "n_state_slots")
+    seq_rows = 1            # (slot,)
+    slot_state = ("ssm", "conv")
+    experts_at = "moe_layers"
 
     @staticmethod
     def state_layers(cfg):
         return max(1, cfg.count("*"))
 
     @staticmethod
-    def state_rows(cfg):
-        width = cfg.num_key_value_heads * cfg.head_dim
-        return width, width
-
-    @staticmethod
     def extra_state(cfg, num_blocks, slots, dtype):
-        """→ the tree of state beyond the two paged pools (zeros)."""
         Lm = cfg.count("M")
         return {"ssm": jnp.zeros((Lm, slots + 1, cfg.mamba_num_heads, cfg.mamba_head_dim,
                                   cfg.ssm_state_size), jnp.float32),
                 "conv": jnp.zeros((Lm, slots + 1, cfg.conv_kernel - 1, cfg.conv_dim), dtype)}
 
     @staticmethod
-    def seq_state(cfg, slot, prompt_len):
-        """A sequence's row of the batch."""
-        return (slot,)
-
-    @staticmethod
     def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
-        if lora is not None or mesh is not None:
-            raise NotImplementedError("the Nemotron-H layer stack serves base-only on one device")
+        NemotronHKind.base_only(mesh, lora)
         model = params["model"]
         ctx = _SlotStep(cfg, batch, attn_impl)
-        moe = model.get("moe_layers", {})
-        experts = moe.get("experts")
         stacks = {"M": model.get("mamba_layers"), "*": model.get("attn_layers"),
-                  "E": {k: v for k, v in moe.items() if k != "experts"}}
+                  "E": model.get(NemotronHKind.experts_at, {})}
+        experts = stacks["E"].get("experts")
 
         def layer(letter, i, carry):
             h, kc, vc, ssm, conv, picks = carry
-            lp = jax.tree.map(lambda w: w[i], stacks[letter])
+            lp = _layer_of(stacks[letter], i)
             x = _rms(h, lp["norm"]["scale"], cfg.layer_norm_epsilon)
             if letter == "M":
                 with jax.named_scope("ds.nemotron.mamba"):
@@ -1155,33 +1246,31 @@ class NemotronHKind:
         return h, kc, vc, {"ssm": ssm, "conv": conv}, counts[None]
 
     @staticmethod
-    def experts_form(params, mesh):
-        return "table" if "moe_layers" in params["model"] else None
-
-    @staticmethod
     def final_norm(params, cfg, h):
         return _rms(h, params["model"]["norm"]["scale"], cfg.layer_norm_epsilon)
 
     @staticmethod
+    def router(cfg, p):
+        """Sigmoid scores, the picks' weights over their sum; this rank's share."""
+        router = p["router"]
+        return Router(router["weight"], router["e_score_correction_bias"],
+                      cfg.num_experts_per_tok, cfg.routed_scaling_factor,
+                      share=ExpertShare(cfg.first_expert_held, cfg.held, cfg.n_routed_experts))
+
+    @staticmethod
     def expert_layer(params, cfg, layer, x):
-        """Expert layer ``layer`` (its index among the ``E`` layers; may be
-        traced) as the step programs compute it - the same router, the same
-        share, the table of every layer's held experts read in place - for
-        a check that wants the layer alone: x [T, D] the normalised stream,
-        every row a token → y."""
+        """Expert layer ``layer`` (its index among the ``E`` layers) alone
+        (:func:`_layer_of`): x [T, D] the normalised stream, every row a token → y."""
         moe = params["model"]["moe_layers"]
-        lp = jax.tree.map(lambda w: w[layer], {k: v for k, v in moe.items() if k != "experts"})
-        every_row = jnp.ones(x.shape[0], bool)
-        return _nemotron_moe(cfg, every_row, lp, moe["experts"], layer, x)[0]
+        return _nemotron_moe(cfg, jnp.ones(x.shape[0], bool), _layer_of(moe, layer),
+                             moe["experts"], layer, x)[0]
 
     @staticmethod
     def mamba_layer(params, cfg, layer, x, ssm, conv, batch):
-        """``M`` layer ``layer``'s mixer alone (its index among the ``M``
-        layers), as the step programs compute it - the same packed
-        recurrence, the same reads and writes of the slot pool - for a
-        check that wants it without the rest: x [T, D] the normalised
-        stream → (y [T, D], ssm, conv)."""
-        lp = jax.tree.map(lambda w: w[layer], params["model"]["mamba_layers"])
+        """``M`` layer ``layer``'s mixer (its index among the ``M`` layers)
+        alone - the same packed recurrence, the same reads and writes of the
+        slot pool: x [T, D] the normalised stream → (y [T, D], ssm, conv)."""
+        lp = _layer_of(params["model"]["mamba_layers"], layer)
         return _mamba_mixer(_SlotStep(cfg, batch), lp, layer, x, ssm, conv)
 
 
@@ -1254,9 +1343,7 @@ def _mamba_mixer(ctx, p, layer, x, ssm, conv):
     """One Mamba-2 mixer over the flat ragged batch, on the normalised
     stream x [T, D]: the packed recurrence. → (y [T, D], ssm, conv).
 
-    The convolution reads, for a row fewer than ``K - 1`` rows into its
-    sequence's rows of this step, the tail the sequence carried in its slot
-    (zero at position 0), and leaves the tail of what it has now seen.
+    The convolution's tail is the sequence's slot (:func:`_conv_with_tail`).
 
     The recurrence is :func:`_packed_rows`' with the rows' own log-decays
     ``Delta_t A``: a row sees its chunk's earlier rows through the decay
@@ -1304,11 +1391,7 @@ def _mamba_mixer(ctx, p, layer, x, ssm, conv):
     y = jnp.einsum("htu,uhp->thp", att.astype(x.dtype), v.astype(x.dtype),
                    preferred_element_type=f32)
 
-    # The carried states, **one visit a slot**: every sequence's first row of the step - its
-    # only row, in a decode step - reads its slot's state, and the state the step leaves is
-    # written where it lay (``ops/pallas/ssm_state``: the pool aliased in and out of one
-    # kernel, which touches the slots this step's sequences own and nothing else; off the
-    # chip the same mathematics over the whole layer). The write is in place, so whatever
+    # The carried states, one visit a slot (the docstring). The write is in place, so whatever
     # reads a sequence's *prior* state comes before it and whatever adds to the new one after:
     # the sequences with further rows, MAMBA_ROUND a round, twice over.
     here = ctx.here
@@ -1383,50 +1466,18 @@ def _nemotron_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
 
 
 def _nemotron_moe(cfg, real, p, experts, layer, x):
-    """One LatentMoE layer on the normalised stream, as this share gives
-    it, and its three counts; ``real`` [T]: the rows that are not padding.
-    Sigmoid scores in float32 (the matmul at the highest precision: the
-    picks are a step function of it); the
-    ``num_experts_per_tok`` columns with the largest score + bias, weighted
-    by their unbiased scores over their sum, times
-    ``routed_scaling_factor``. The routed experts work in the latent ``u =
-    x W_down``: ungated, ``relu(u W1)^2 W2``, the held picks through the
-    grouped matmul over the table of every layer's held experts
-    (``ops/grouped_gemm.dropless_moe_ffn``, ``w3=None``), the rest left
-    out; ``W_up`` leaves the latent. The shared expert on the full width.
-    → (y [T, D], int32 [3]: picks held, picks zero-compute (none), held
-    experts with a row - over the tokens that are not padding, whose rows
-    launch no group either)."""
-    from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn
-    router = p["router"]
-    with jax.named_scope("ds.moe_routed"):
-        scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                                        router["weight"].astype(jnp.float32),
-                                        precision=jax.lax.Precision.HIGHEST))
-        _, topk_idx = jax.lax.top_k(
-            scores + router["e_score_correction_bias"].astype(jnp.float32),
-            cfg.num_experts_per_tok)
-        topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1)
-        topk_vals = (topk_vals / (topk_vals.sum(-1, keepdims=True) + 1e-20)
-                     * cfg.routed_scaling_factor)
-        share = ExpertShare(cfg.first_expert_held, cfg.held, cfg.n_routed_experts)
-        topk_idx = jnp.where(real[:, None], topk_idx, -1)         # a padding token picks nothing
-        table, first_group = _layer_groups(experts, layer)
-        u = _proj(x, p["latent_down"])
-        v = dropless_moe_ffn(u, topk_idx, topk_vals, table["up_proj"], None, table["down_proj"],
-                             num_experts=share.routed, widen_boundary=False,
-                             first_group=first_group, share=share, activation=relu2)
-        y = _proj(v, p["latent_up"])
-        held, zero = share.parts(topk_idx)
-        of_expert = topk_idx[..., None] == share.first + jnp.arange(share.held)
-        live = jnp.any(of_expert & held[..., None], axis=(0, 1))
-        counts = jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
+    """One LatentMoE layer on the normalised stream, as this share gives it,
+    and its ``EXPERT_COUNTS``; ``real`` [T]: the rows that are not padding.
+    The routed experts work in the latent ``u = x W_down``, ungated, and
+    ``W_up`` leaves it; the shared expert on the full width."""
+    y, counts = _routed_experts(x, NemotronHKind.router(cfg, p), experts, layer, real,
+                                enter=p["latent_down"], leave=p["latent_up"])
     with jax.named_scope("ds.moe_shared"):
         s = p["shared_experts"]
         return y + _proj(relu2(_proj(x, s["up_proj"])), s["down_proj"]), counts
 
 
-class Lfm2Kind:
+class Lfm2Kind(ModelKind):
     """LFM2-MoE (``models/lfm2.py``): **every layer an operator and a
     feed-forward** - the operator a gated short convolution or a
     grouped-query attention, as ``layer_types`` says; the feed-forward a
@@ -1439,34 +1490,26 @@ class Lfm2Kind:
     - ``extra_state``'s ``conv`` ``[Lc, slots + 1, K - 1, D]`` in the
       stream's dtype: a ``conv`` operator's state a sequence, **the last
       ``K - 1`` rows of the gated stream** before the convolution, the same
-      at token 10 and at token 100,000. A tracked sequence owns a slot
-      (``ragged/slot_pool.py``, ``slot_state``); slot 0 is padding's. A
-      sequence's first rows (position 0) take it as zero, so a slot needs
-      no clearing between owners. The carry is :func:`_conv_with_tail`,
-      the one Nemotron-H's Mamba mixers use.
+      at token 10 and at token 100,000; slots as :class:`SalaKind`'s. The
+      carry is :func:`_conv_with_tail`, the one Nemotron-H's Mamba mixers use.
 
     :meth:`stack` runs ``cfg.segments`` through :func:`_run_segments`: the
     leading layers one by one, one scan a period. The experts of every
-    layer ride each step whole, one table of ``Le x E`` groups, and every
-    pick is held (a share of all the router's columns: a padding token
-    picks nothing and launches no group). Each step counts, over its
-    tokens that are not padding: the picks (all held), the zero-compute
-    picks (none: the name is the expert readers'), the experts with at
-    least one row, the rows through the ``conv`` operators, the (sequence,
+    layer ride each step whole, one table of ``Le x E`` groups. Each step
+    counts, over its tokens that are not padding: ``EXPERT_COUNTS`` (every
+    pick held, none zero-compute), the rows through the ``conv`` operators, the (sequence,
     ``conv`` layer)s whose tail it read and wrote, and - once a step, not a
     layer - ``n_ctx_seq_tokens``: over the step's sequences, the context
     positions each attends to, counted **once a sequence** however many
     rows it has in the step: the least any implementation of an attention
     layer must fetch."""
     name = "lfm2"
+    config = Lfm2MoeConfig
     state_kind = "kv+slots"
-    lora = False
-    step_counts = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_conv_rows",
-                   "n_tail_slots", "n_ctx_seq_tokens")
-    seq_rows = 1            # per-sequence rows of the batch: (slot,)
-    slot_state = ("conv",)  # the entries of extra_state a slot is a row of
-    state_rows = NemotronHKind.state_rows
-    seq_state = NemotronHKind.seq_state
+    step_counts = EXPERT_COUNTS + ("n_conv_rows", "n_tail_slots", "n_ctx_seq_tokens")
+    seq_rows = 1            # (slot,)
+    slot_state = ("conv",)
+    experts_at = "moe_ffn"
 
     @staticmethod
     def state_layers(cfg):
@@ -1474,7 +1517,6 @@ class Lfm2Kind:
 
     @staticmethod
     def extra_state(cfg, num_blocks, slots, dtype):
-        """→ the tree of state beyond the two paged pools (zeros)."""
         return {"conv": jnp.zeros((cfg.count("conv"), slots + 1, cfg.conv_L_cache - 1,
                                    cfg.hidden_size), dtype)}
 
@@ -1485,20 +1527,17 @@ class Lfm2Kind:
 
     @staticmethod
     def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
-        if lora is not None or mesh is not None:
-            raise NotImplementedError("the LFM2 layer stack serves base-only on one device")
+        Lfm2Kind.base_only(mesh, lora)
         model = params["model"]
         ctx = _SlotStep(cfg, batch)
-        moe = model.get("moe_ffn", {})
-        experts = moe.get("experts")
         stacks = {"conv": model.get("conv_layers"), "attn": model.get("attn_layers"),
-                  "dense": model.get("dense_ffn"),
-                  "moe": {k: v for k, v in moe.items() if k != "experts"}}
+                  "dense": model.get("dense_ffn"), "moe": model.get(Lfm2Kind.experts_at, {})}
+        experts = stacks["moe"].get("experts")
 
         def layer(letter, at, carry):
             h, kc, vc, conv, picks = carry
             op, ffn = Lfm2Kind._counters(letter)
-            lp = jax.tree.map(lambda w: w[at[op]], stacks[op])
+            lp = _layer_of(stacks[op], at[op])
             x = _rms(h, lp["operator_norm"]["scale"], cfg.norm_eps)
             if op == "conv":
                 with jax.named_scope("ds.lfm2.conv"):
@@ -1507,13 +1546,13 @@ class Lfm2Kind:
                 with jax.named_scope("ds.lfm2.attn"):
                     y, kc, vc = _lfm2_attention(cfg, lp, at[op], x, kc, vc, batch, attn_impl)
             h = h + y
-            fp = jax.tree.map(lambda w: w[at[ffn]], stacks[ffn])
+            fp = _layer_of(stacks[ffn], at[ffn])
             x = _rms(h, fp["ffn_norm"]["scale"], cfg.norm_eps)
             if ffn == "dense":
                 with jax.named_scope("ds.dense_ffn"):
                     y = _swiglu(x, fp)
             else:
-                y, n = _lfm2_moe(cfg, ctx.real, fp, experts, at[ffn], x)
+                y, n = _routed_experts(x, Lfm2Kind.router(cfg, fp), experts, at[ffn], ctx.real)
                 picks = picks + n
             return h + y, kc, vc, conv, picks
 
@@ -1528,42 +1567,40 @@ class Lfm2Kind:
         return h, kc, vc, {"conv": conv}, counts[None]
 
     @staticmethod
-    def experts_form(params, mesh):
-        return "table" if "moe_ffn" in params["model"] else None
-
-    @staticmethod
     def final_norm(params, cfg, h):
         return _rms(h, params["model"]["embedding_norm"]["scale"], cfg.norm_eps)
 
     @staticmethod
+    def router(cfg, fp):
+        """Moonlight's router, the matmul at the highest precision; no share."""
+        gate = fp["gate"]
+        return Router(gate["weight"], gate["expert_bias"], cfg.num_experts_per_tok,
+                      cfg.routed_scaling_factor, eps=TOPK_EPS)
+
+    @staticmethod
     def conv_layer(params, cfg, layer, x, conv, batch):
-        """``conv`` operator ``layer`` alone (its index among the ``conv``
-        layers), as the step programs compute it - the same gates, the same
-        reads and writes of the slot pool - for a check that wants it
-        without the rest: x [T, D] the normalised stream → (y [T, D],
-        conv)."""
-        lp = jax.tree.map(lambda w: w[layer], params["model"]["conv_layers"])
+        """``conv`` operator ``layer`` (its index among the ``conv`` layers)
+        alone - the same gates, the same reads and writes of the slot pool:
+        x [T, D] the normalised stream → (y [T, D], conv)."""
+        lp = _layer_of(params["model"]["conv_layers"], layer)
         return _lfm2_conv(_SlotStep(cfg, batch), lp, layer, x, conv)
 
     @staticmethod
     def attention_layer(params, cfg, layer, x, kc, vc, batch, attn_impl=None):
-        """``full_attention`` operator ``layer`` alone (its index among the
-        attention layers), as the step programs compute it - the same
-        norms, rotation, writes into the pools and paged attention: x
-        [T, D] the normalised stream → (y [T, D], kc, vc)."""
-        lp = jax.tree.map(lambda w: w[layer], params["model"]["attn_layers"])
+        """``full_attention`` operator ``layer`` (its index among the
+        attention layers) alone - the same norms, rotation, writes into the
+        pools and paged attention: x [T, D] the normalised stream → (y
+        [T, D], kc, vc)."""
+        lp = _layer_of(params["model"]["attn_layers"], layer)
         return _lfm2_attention(cfg, lp, layer, x, kc, vc, batch, attn_impl)
 
     @staticmethod
     def expert_layer(params, cfg, layer, x):
-        """Expert feed-forward ``layer`` (its index among the expert
-        layers; may be traced) as the step programs compute it - the same
-        router, the table of every layer's experts read in place - for a
-        check that wants it alone: x [T, D] the normalised stream, every
-        row a token → y."""
+        """Expert feed-forward ``layer`` (its index among the expert layers)
+        alone: x [T, D] the normalised stream, every row a token → y."""
         moe = params["model"]["moe_ffn"]
-        fp = jax.tree.map(lambda w: w[layer], {k: v for k, v in moe.items() if k != "experts"})
-        return _lfm2_moe(cfg, jnp.ones(x.shape[0], bool), fp, moe["experts"], layer, x)[0]
+        return _routed_experts(x, Lfm2Kind.router(cfg, _layer_of(moe, layer)), moe["experts"],
+                               layer, jnp.ones(x.shape[0], bool))[0]
 
 
 def _lfm2_conv(rows, p, layer, x, conv):
@@ -1595,60 +1632,16 @@ def _lfm2_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
     return _proj(out.reshape(T, Hq * d), p["out_proj"]), kc, vc
 
 
-def _lfm2_moe(cfg, real, p, experts, layer, x):
-    """One expert feed-forward on the normalised stream, and its three
-    counts; ``real`` [T]: the rows that are not padding. Moonlight's router
-    without its shared experts: sigmoid scores in float32 (the matmul at
-    the highest precision: the picks are a step function of it); the
-    ``num_experts_per_tok`` columns with the largest score + ``expert_bias``,
-    weighted by their unbiased scores over their sum, times
-    ``routed_scaling_factor``. Every pick through the grouped matmul over
-    the table of every layer's experts (``ops/grouped_gemm.dropless_moe_ffn``;
-    the share is all the router's columns, so that a padding token's picks,
-    -1, are rows of no group). → (y [T, D], int32 [3]: picks held, picks
-    zero-compute (none), experts with a row - over the tokens that are not
-    padding)."""
-    from deepspeed_tpu.models.lfm2 import TOPK_EPS
-    from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn
-    gate = p["gate"]
-    with jax.named_scope("ds.moe_routed"):
-        scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), gate["weight"].astype(jnp.float32),
-                                        precision=jax.lax.Precision.HIGHEST))
-        _, topk_idx = jax.lax.top_k(scores + gate["expert_bias"].astype(jnp.float32),
-                                    cfg.num_experts_per_tok)
-        topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1)
-        topk_vals = (topk_vals / (topk_vals.sum(-1, keepdims=True) + TOPK_EPS)
-                     * cfg.routed_scaling_factor)
-        share = ExpertShare(0, cfg.num_experts, cfg.num_experts)
-        topk_idx = jnp.where(real[:, None], topk_idx, -1)         # a padding token picks nothing
-        table, first_group = _layer_groups(experts, layer)
-        y = dropless_moe_ffn(x, topk_idx, topk_vals, table["gate_proj"], table["up_proj"],
-                             table["down_proj"], num_experts=share.routed,
-                             widen_boundary=False, first_group=first_group, share=share)
-        held, zero = share.parts(topk_idx)
-        live = jnp.any(topk_idx[..., None] == jnp.arange(share.held), axis=(0, 1))
-        return y, jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
+# Every kind, a kind whose config class derives another's before that one's.
+KINDS = (Lfm2Kind, NemotronHKind, SalaKind, LongcatKind, MoonlightKind, GPTKind, LlamaKind)
 
 
 def kind_of(cfg):
-    """The model kind of a config, by its type: one of the kinds this
-    module defines, each of which names its config class in its docstring."""
-    from deepspeed_tpu.models.lfm2 import Lfm2MoeConfig
-    from deepspeed_tpu.models.longcat import LongcatFlashConfig
-    from deepspeed_tpu.models.minicpm_sala import MiniCPMSalaConfig
-    from deepspeed_tpu.models.moonlight import MoonlightConfig
-    from deepspeed_tpu.models.nemotron_h import NemotronHConfig
-    if isinstance(cfg, Lfm2MoeConfig):
-        return Lfm2Kind
-    if isinstance(cfg, NemotronHConfig):
-        return NemotronHKind
-    if isinstance(cfg, MiniCPMSalaConfig):
-        return SalaKind
-    if isinstance(cfg, LongcatFlashConfig):
-        return LongcatKind
-    if isinstance(cfg, MoonlightConfig):
-        return MoonlightKind
-    return GPTKind if hasattr(cfg, "position_embedding") else LlamaKind
+    """→ the first of ``KINDS`` whose ``config`` class ``cfg`` is an instance of."""
+    for kind in KINDS:
+        if isinstance(cfg, kind.config):
+            return kind
+    raise TypeError(f"no model kind serves a {type(cfg).__name__}")
 
 
 def _rope_deinterleaved(x, cos, sin, positions):
@@ -1693,28 +1686,10 @@ def _swiglu(x, p):
 
 
 def _moonlight_moe(x, p, experts, layer, cfg):
-    """``noaux_tc`` routing in float32: sigmoid scores; the top k are
-    chosen on ``score + e_score_correction_bias`` and weighted by the
-    unbiased scores, normalised, times ``routed_scaling_factor``. Routed
-    part through the dropless grouped GEMM, shared experts on every token.
-    ``experts``: ``{gate,up,down}_proj [Lm, E, in, out]`` of all expert
-    layers, ``layer`` this one's index among them: the grouped GEMM takes
-    the stack as one table of ``Lm x E`` groups and this layer's first
-    group (:func:`_layer_groups`, as Mixtral's :func:`_moe_mlp` does),
-    and chooses its dispatch on ``E``."""
-    from deepspeed_tpu.ops.grouped_gemm import dropless_moe_ffn
-    with jax.named_scope("ds.moe_routed"):
-        scores = jax.nn.sigmoid(x.astype(jnp.float32) @ p["gate"]["weight"].astype(jnp.float32))
-        biased = scores + p["gate"]["e_score_correction_bias"].astype(jnp.float32)
-        _, topk_idx = jax.lax.top_k(biased, cfg.num_experts_per_tok)
-        topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1)
-        if cfg.norm_topk_prob:
-            topk_vals = topk_vals / (topk_vals.sum(-1, keepdims=True) + 1e-20)
-        topk_vals = topk_vals * cfg.routed_scaling_factor
-        table, first_group = _layer_groups(experts, layer)
-        routed = dropless_moe_ffn(x, topk_idx, topk_vals, table["gate_proj"], table["up_proj"],
-                                  table["down_proj"], num_experts=cfg.n_routed_experts,
-                                  widen_boundary=False, first_group=first_group)
+    """The routed experts (:func:`_routed_experts`: ``experts`` every
+    expert layer's, ``layer`` this one's index among them), then the shared
+    experts on every token."""
+    routed, _ = _routed_experts(x, MoonlightKind.router(cfg, p), experts, layer)
     with jax.named_scope("ds.moe_shared"):
         return routed + _swiglu(x, p["shared_experts"])
 
@@ -1781,42 +1756,6 @@ def _moonlight_layer_step(cfg, cos, sin, batch, attn_impl, experts, carry, xs):
     return (h, kc, vc), None
 
 
-def _longcat_moe(x, p, experts, layer, cfg, batch):
-    """The shortcut expert layer ``M(x)`` as this share gives it, and its
-    three counts. Softmax over every column of the router in float32 (the
-    matmul at the highest precision: the picks are a step function of
-    it); the ``moe_topk`` columns with the largest score + bias, weighted
-    by their unbiased scores, not normalised, times
-    ``routed_scaling_factor``. The picks go to the one expert entry
-    (``ops/grouped_gemm.dropless_moe_ffn``) with the share: held picks
-    through the grouped matmul over the table of every layer's held
-    experts (:func:`_layer_groups`), zero-compute picks as ``(their
-    weights) * x``, the rest left out. → (m [T, D], int32 [3]: picks
-    held, picks zero, held experts with a row — over the tokens that are
-    not padding, whose rows launch no group either)."""
-    from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn
-    router = p["router"]
-    scores = jax.nn.softmax(jnp.dot(x.astype(jnp.float32),
-                                    router["classifier"]["weight"].astype(jnp.float32),
-                                    precision=jax.lax.Precision.HIGHEST), axis=-1)
-    _, topk_idx = jax.lax.top_k(scores + router["e_score_correction_bias"].astype(jnp.float32),
-                                cfg.moe_topk)
-    topk_vals = jnp.take_along_axis(scores, topk_idx, axis=-1) * cfg.routed_scaling_factor
-    share = ExpertShare(cfg.first_expert_held, cfg.held, cfg.n_routed_experts,
-                        cfg.zero_expert_num)
-    # a padding token picks nothing (-1): the last row of the block tables is padding's
-    real = batch["token_seq"] < batch["block_tables"].shape[0] - 1
-    topk_idx = jnp.where(real[:, None], topk_idx, -1)
-    table, first_group = _layer_groups(experts, layer)
-    m = dropless_moe_ffn(x, topk_idx, topk_vals, table["gate_proj"], table["up_proj"],
-                         table["down_proj"], num_experts=share.routed + share.zero,
-                         widen_boundary=False, first_group=first_group, share=share)
-    held, zero = share.parts(topk_idx)
-    of_expert = topk_idx[..., None] == share.first + jnp.arange(share.held)
-    live = jnp.any(of_expert & held[..., None], axis=(0, 1))
-    return m, jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
-
-
 def _longcat_layer_step(cfg, rope, batch, attn_impl, experts, carry, xs):
     """One LongCat double layer over the flat ragged batch
     (``models/longcat.py`` has the equations): attention, then the expert
@@ -1824,8 +1763,8 @@ def _longcat_layer_step(cfg, rope, batch, attn_impl, experts, carry, xs):
     stream ``x``; attention and the second dense SwiGLU; ``m`` joins the
     residual only there, so the expert branch has no data dependence on
     the second half. ``xs``: (this layer's two state layers, its params,
-    each half's under ``"0"`` / ``"1"``); → the carry and
-    :func:`_longcat_moe`'s counts."""
+    each half's under ``"0"`` / ``"1"``); → the carry and the expert
+    layer's ``EXPERT_COUNTS``."""
     h, kc, vc = carry
     ids, lp = xs
 
@@ -1836,8 +1775,10 @@ def _longcat_layer_step(cfg, rope, batch, attn_impl, experts, carry, xs):
     h, kc, vc = _latent_attention(cfg, a["self_attn"], a["input_layernorm"]["scale"], h, rope,
                                   kc, vc, ids[0], batch, attn_impl)
     x = _rms(h, a["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
-    with jax.named_scope("ds.moe_routed"):
-        m, counts = _longcat_moe(x, lp["mlp"], experts, ids[0] // 2, cfg, batch)
+    # a padding token picks nothing: the last row of the block tables is padding's
+    m, counts = _routed_experts(
+        x, LongcatKind.router(cfg, lp["mlp"]), experts, ids[0] // 2,
+        real=lambda: batch["token_seq"] < batch["block_tables"].shape[0] - 1)
     with jax.named_scope("ds.dense_ffn"):
         h = h + _swiglu(x, a["mlps"])
     h, kc, vc = _latent_attention(cfg, b["self_attn"], b["input_layernorm"]["scale"], h, rope,
@@ -1859,13 +1800,11 @@ def ragged_forward(params, kcache, vcache, batch, cfg, dtype=jnp.bfloat16, mesh=
     (:func:`kind_of`: keys and values ``[L, NB, bs, Hkv*Dh]``, or the
     latent rows and rotated keys of ``MoonlightKind``), carried through
     the layers and written in place (donate them); ``extra``: None, or the
-    kind's own tree of further state (``SalaKind.extra_state``: the slot
-    pool of linear states, the pooled keys; ``NemotronHKind.extra_state``:
-    the Mamba states and convolution tails), carried and donated likewise;
-    ``batch``: the arrays of ``RaggedBatchWrapper.finalize()``. ``cfg`` is
-    the config of any kind :func:`kind_of` knows; the layer wiring follows
-    its kind. ``mesh``: an optional
-    serving mesh — params/KV arrive sharded per
+    kind's own tree of further state (``kind.extra_state``), carried and
+    donated likewise; ``batch``: the arrays of
+    ``RaggedBatchWrapper.finalize()``. ``cfg`` is the config of any kind
+    :func:`kind_of` knows; the layer wiring follows its kind. ``mesh``: an
+    optional serving mesh — params/KV arrive sharded per
     ``inference/v2/sharding.py`` and the step pins the Megatron layout
     (replicated tokens, head/feature-sharded projections) so GSPMD
     inserts the TP all-reduces.

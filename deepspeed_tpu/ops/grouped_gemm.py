@@ -606,11 +606,13 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
             # Forward-only serving passes widen_boundary=False and
             # keeps the bf16 (half-traffic) expert-axis gather.
             x_in = x.astype(jnp.float32) if widen_boundary else x
-            out_rep = shard_map(
+            # under jit also when called op by op: jax 0.9's eager shard_map, manual over
+            # some of the mesh's axes, asks itself for out_specs over all of them and refuses
+            out_rep = jax.jit(shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(), P(), *flat_specs),
                 out_specs=P(), axis_names={"expert", "tensor"},
-                check_vma=False)(x_in, idx_rep, *parts)
+                check_vma=False))(x_in, idx_rep, *parts)
             out_k = out_rep.reshape(T, k, -1)
             return jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
 

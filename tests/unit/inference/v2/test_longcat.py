@@ -308,9 +308,9 @@ def test_the_shares_add_up_to_the_uncut_layer(whole, ranks):
     for r in range(ranks):
         share, cut = _rank(cfg, mlp, r * held, held)
         ref_sum = ref_sum + reference_experts(cut, x, share, zero=(r == 0))
-        batch = {"token_seq": jnp.zeros(x.shape[0], jnp.int32), "block_tables": jnp.zeros((2, 1))}
         stacks = jax.tree.map(lambda w: w[None], cut["experts"])        # one layer's table
-        m, counts = model_runner._longcat_moe(x, cut, stacks, 0, share, batch)
+        m, counts = model_runner._routed_experts(
+            x, model_runner.LongcatKind.router(share, cut), stacks, 0, jnp.ones(x.shape[0], bool))
         served_sum = served_sum + m
         assert int(counts[0]) + int(counts[1]) <= cfg.moe_topk * x.shape[0]
     # every rank computed the identity part of these tokens: count it once

@@ -39,14 +39,13 @@ def programs(forced):
         bs = getattr(cfg, "sparse_block_size", 16)
         pools = [sds((kind.state_layers(cfg), BLOCKS, bs, w), jnp.float32)
                  for w in kind.state_rows(cfg)]
-        extra = (jax.eval_shape(lambda: kind.extra_state(cfg, BLOCKS, SEQS, jnp.float32))
-                 if hasattr(kind, "extra_state") else None)
+        extra = jax.eval_shape(lambda: kind.extra_state(cfg, BLOCKS, SEQS, jnp.float32))
         for T in (8, 32):
             batch = {"token_ids": sds((T,), jnp.int32), "token_seq": sds((T,), jnp.int32),
                      "token_pos": sds((T,), jnp.int32),
                      "block_tables": sds((SEQS + 1, TABLE), jnp.int32),
                      "last_index": sds((SEQS,), jnp.int32), "num_tokens": sds((), jnp.int32)}
-            if getattr(kind, "seq_rows", 0):
+            if kind.seq_rows:
                 batch["seq_state"] = sds((SEQS + 1, kind.seq_rows), jnp.int32)
             choice = AttentionChoice()
             text = jax.jit(lambda p, kc, vc, b, x: model_runner.ragged_forward(
