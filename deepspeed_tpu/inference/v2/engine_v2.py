@@ -263,6 +263,7 @@ class InferenceEngineV2:
                                        row_widths=kind.state_rows(cfg))
         # what the pool holds, for whoever sizes or reads it (docs/OBSERVABILITY.md)
         self.state_kind = kind.state_kind
+        self._state_step_said = set()       # the programs whose state step has been logged
         self.state_bytes_per_token = self.kv_cache.bytes_per_token()
         self.state_manager = DSStateManager(self.kv_cache, int(sm.max_tracked_sequences))
         # State beyond the two paged pools, where the model kind keeps any: its own tree
@@ -870,8 +871,14 @@ class InferenceEngineV2:
         counts nothing."""
         if counts:
             rec.counts = dict(zip(self.kind.step_counts, counts[0].tolist()))
-            # and what serves its Mamba-2 state step, where the model kind has one
-            rec.state_step = self._attention.state_step.get(rec.n_rows // max(rec.k, 1))
+            # and what serves its state step, where the model kind has one: said once a
+            # program, when its first step comes back (the start-up lines of a warm-up)
+            rows = rec.n_rows // max(rec.k, 1)
+            rec.state_step = self._attention.state_step.get(rows)
+            if rec.state_step is not None and rows not in self._state_step_said:
+                self._state_step_said.add(rows)
+                logger.info(f"InferenceEngineV2: the {rows}-row program's state step is "
+                            f"{rec.state_step} (kind={self.kind.name})")
 
     def count_host_sync(self, n=1):
         """Record ``n`` executions of a pragma'd host-sync site. Every
@@ -899,10 +906,11 @@ class InferenceEngineV2:
 
     @property
     def state_step_impls(self):
-        """``{token count: implementation name}`` of the Mamba-2 state step
-        (``pallas_ssm_state`` / ``xla``: ``ops/pallas/ssm_state``) for every
-        program traced so far; empty for a model kind without one. A step
-        record's ``state_step`` says the same of the program it ran."""
+        """``{token count: implementation name}`` of the state step - Mamba-2's
+        (``pallas_ssm_state`` / ``xla``: ``ops/pallas/ssm_state``) or Mamba-1's
+        (``pallas_selective_scan`` / ``xla``: ``ops/pallas/selective_scan``) -
+        for every program traced so far; empty for a model kind without one. A
+        step record's ``state_step`` says the same of the program it ran."""
         return dict(self._attention.state_step)
 
     def draw_seed(self):

@@ -33,9 +33,10 @@ Written once for the kinds that share it: the biased top-k router and the
 expert layer behind it (:func:`_routed_experts`; a kind gives its ``router``
 values), one layer cut out of a stack (:func:`_layer_of`: the check hooks
 too), a convolution whose tail is a slot, a packed recurrence's decays, the
-latent attention. **Not folded, on purpose**: the grouped-query mixers
-:func:`_nemotron_attention` and :func:`_lfm2_attention`, a dozen lines each,
-differ in a head norm, a rotation and a projection's name (one function of
+latent attention, the position-free grouped-query mixer of the two kinds
+whose state-space layers carry the order (:func:`_plain_gqa_attention`).
+**Not folded, on purpose**: that mixer and :func:`_lfm2_attention`, a dozen
+lines each, differ in a head norm, a rotation and a projection's name (one function of
 those three is as long as the two, and its callers still know all three);
 :func:`_layer_step`'s carries LoRA sites and sharding constraints. A new
 kind takes the nearest; a third wants a reason.
@@ -50,8 +51,9 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models import (GPTConfig, Lfm2MoeConfig, LlamaConfig, LongcatFlashConfig,
-                                  MiniCPMSalaConfig, MoonlightConfig, NemotronHConfig)
+from deepspeed_tpu.models import (GPTConfig, JambaConfig, Lfm2MoeConfig, LlamaConfig,
+                                  LongcatFlashConfig, MiniCPMSalaConfig, MoonlightConfig,
+                                  NemotronHConfig)
 from deepspeed_tpu.models.lfm2 import TOPK_EPS
 from deepspeed_tpu.models.llama import rope_frequencies, rope_scaling_of
 from deepspeed_tpu.models.nemotron_h import relu2
@@ -1227,7 +1229,7 @@ class NemotronHKind(ModelKind):
                     y, ssm, conv = _mamba_mixer(ctx, lp, i, x, ssm, conv)
             elif letter == "*":
                 with jax.named_scope("ds.nemotron.attn"):
-                    y, kc, vc = _nemotron_attention(cfg, lp, i, x, kc, vc, batch, attn_impl)
+                    y, kc, vc = _plain_gqa_attention(cfg, lp, i, x, kc, vc, batch, attn_impl)
             else:
                 with jax.named_scope("ds.nemotron.latent_moe"):
                     y, n = _nemotron_moe(cfg, ctx.real, lp, experts, i, x)
@@ -1452,10 +1454,12 @@ def _mamba_mixer(ctx, p, layer, x, ssm, conv):
     return _proj(y, p["out_proj"]), ssm, conv
 
 
-def _nemotron_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
-    """One ``*`` layer's mixer on the normalised stream: grouped-query
+def _plain_gqa_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
+    """A position-free attention mixer on the normalised stream
+    (:class:`NemotronHKind`'s ``*`` layers, :class:`JambaKind`'s attention
+    layers: the state-space layers carry the order): grouped-query
     attention over the paged pool's layer ``layer``, queries and keys as
-    projected (no positional term). → (y, kc, vc)."""
+    projected, no bias. → (y, kc, vc)."""
     T = x.shape[0]
     Hq, Hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     q = _proj(x, p["q_proj"]).reshape(T, Hq, d)
@@ -1632,8 +1636,153 @@ def _lfm2_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
     return _proj(out.reshape(T, Hq * d), p["out_proj"]), kc, vc
 
 
+class JambaKind(ModelKind):
+    """Jamba (``models/jamba.py``): **every layer a mixer and a dense
+    feed-forward** - the mixer a Mamba-1 state-space layer or, once a
+    period, a position-free grouped-query attention
+    (:func:`_plain_gqa_attention`, :class:`NemotronHKind`'s) - and state of
+    two kinds side by side.
+
+    - The attention layers keep keys and values in the engine's two paged
+      pools, ``[La, NB, bs, Hkv * d]``; the Mamba layers hold nothing there.
+    - ``extra_state``'s ``ssm`` ``[Lm, slots + 1, N, I]`` float32 and
+      ``conv`` ``[Lm, slots + 1, K - 1, I]``: a Mamba layer's state a
+      sequence - ``N`` state columns of every channel, the channels along
+      the lanes, and the last ``K - 1`` rows of ``x`` before the
+      convolution's activation (:func:`_conv_with_tail`) - the same at token
+      10 and at token 200,000; slots as :class:`SalaKind`'s.
+
+    The recurrence's decay is an element's own - ``exp(Delta_t[c] A[n, c])``,
+    another for every channel, state column and token - so no mask over a
+    chunk's rows expresses it (:func:`_packed_rows` is Mamba-2's): every row
+    of a sequence passes through its slot's state in order
+    (``ops/pallas/selective_scan.selective_scan``: the pool aliased in and
+    out, a slot fetched once, its sequence's rows run through it in VMEM,
+    written back once; ``xla_selective_scan`` where the kernel does not
+    run; ``AttentionChoice.state_step`` says which a program got).
+
+    :meth:`stack` runs ``cfg.segments`` - a run of layers of one kind is
+    one scan - through :func:`_run_segments`. Each step counts, over its
+    tokens that are not padding: the rows through the Mamba layers, the
+    (sequence, Mamba layer)s whose state it read and wrote (the names
+    :class:`NemotronHKind`'s records use), and ``n_scan_runs``, those of
+    them with more than one row in the step: the runs a chunk is cut
+    into."""
+    name = "jamba"
+    config = JambaConfig
+    state_kind = "kv+slots"
+    step_counts = ("n_ssm_rows", "n_state_slots", "n_scan_runs")
+    seq_rows = 1            # (slot,)
+    slot_state = ("ssm", "conv")
+
+    @staticmethod
+    def state_layers(cfg):
+        return max(1, cfg.count("a"))
+
+    @staticmethod
+    def extra_state(cfg, num_blocks, slots, dtype):
+        Lm = cfg.count("m")
+        return {"ssm": jnp.zeros((Lm, slots + 1, cfg.mamba_d_state, cfg.mamba_inner),
+                                 jnp.float32),
+                "conv": jnp.zeros((Lm, slots + 1, cfg.mamba_d_conv - 1, cfg.mamba_inner), dtype)}
+
+    @staticmethod
+    def _counters(letter):
+        """The stacks a layer of ``cfg.letters``' letter draws from."""
+        return ("mamba" if letter == "m" else "attn", "ffn")
+
+    @staticmethod
+    def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+        JambaKind.base_only(mesh, lora)
+        model = params["model"]
+        ctx = _SlotStep(cfg, batch, attn_impl)
+        stacks = {"mamba": model.get("mamba_layers"), "attn": model.get("attn_layers"),
+                  "ffn": model["ffn"]}
+
+        def layer(letter, at, carry):
+            h, kc, vc, ssm, conv = carry
+            op, _ = JambaKind._counters(letter)
+            lp = _layer_of(stacks[op], at[op])
+            x = _rms(h, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
+            if op == "mamba":
+                with jax.named_scope("ds.jamba.mamba"):
+                    y, ssm, conv = _jamba_mamba(ctx, lp, at[op], x, ssm, conv)
+            else:
+                with jax.named_scope("ds.jamba.attn"):
+                    y, kc, vc = _plain_gqa_attention(cfg, lp, at[op], x, kc, vc, batch,
+                                                     attn_impl)
+            h = h + y
+            fp = _layer_of(stacks["ffn"], at["ffn"])
+            with jax.named_scope("ds.jamba.mlp"):
+                h = h + _swiglu(_rms(h, fp["pre_ff_layernorm"]["scale"], cfg.rms_norm_eps), fp)
+            return h, kc, vc, ssm, conv
+
+        carry = (h, kc, vc, extra["ssm"], extra["conv"])
+        (h, kc, vc, ssm, conv), done = _run_segments(cfg.segments, JambaKind._counters, layer,
+                                                     carry)
+        Lm = done.get("mamba", 0)
+        counts = jnp.stack([Lm * jnp.sum(ctx.real.astype(jnp.int32)),
+                            Lm * jnp.sum(ctx.here.astype(jnp.int32)),
+                            Lm * jnp.sum((ctx.here & (ctx.length > 1)).astype(jnp.int32))])
+        return h, kc, vc, {"ssm": ssm, "conv": conv}, counts.astype(jnp.int32)[None]
+
+    @staticmethod
+    def final_norm(params, cfg, h):
+        return _rms(h, params["model"]["final_layernorm"]["scale"], cfg.rms_norm_eps)
+
+    @staticmethod
+    def mamba_layer(params, cfg, layer, x, ssm, conv, batch):
+        """Mamba layer ``layer``'s mixer (its index among the Mamba layers)
+        alone - the same convolution, scan and reads and writes of the
+        slot pools: x [T, D] the normalised stream → (y [T, D], ssm, conv)."""
+        lp = _layer_of(params["model"]["mamba_layers"], layer)
+        return _jamba_mamba(_SlotStep(cfg, batch), lp, layer, x, ssm, conv)
+
+    @staticmethod
+    def attention_layer(params, cfg, layer, x, kc, vc, batch, attn_impl=None):
+        """Attention layer ``layer``'s mixer (its index among the attention
+        layers) alone - the same writes into the pools and paged attention:
+        x [T, D] the normalised stream → (y [T, D], kc, vc)."""
+        lp = _layer_of(params["model"]["attn_layers"], layer)
+        return _plain_gqa_attention(cfg, lp, layer, x, kc, vc, batch, attn_impl)
+
+
+def _jamba_mamba(ctx, p, layer, x, ssm, conv):
+    """One Mamba-1 mixer over the flat ragged batch, on the normalised
+    stream x [T, D] → (y [T, D], ssm, conv). The convolution's tail is the
+    sequence's slot (:func:`_conv_with_tail`: bias, SiLU after); ``dt``,
+    ``B`` and ``C`` come off the convolved stream and through the family's
+    three inner norms; the recurrence is the selective scan
+    (:class:`JambaKind`'s docstring), float32 throughout: ``Delta``, the
+    decays, the accumulation and ``S C``."""
+    cfg = ctx.cfg
+    I, N, R = cfg.mamba_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
+    f32, eps = jnp.float32, cfg.rms_norm_eps
+    xz = _proj(x, p["in_proj"])
+    acc, conv = _conv_with_tail(xz[:, :I], p["conv_kernel"], p["conv_bias"], conv, layer, ctx)
+    xs = jax.nn.silu(acc)                                                # [T, I] float32
+    dbc = _proj(xs.astype(x.dtype), p["x_proj"])
+    dt = _rms(dbc[:, :R], p["dt_layernorm"]["scale"], eps)
+    b = _rms(dbc[:, R:R + N], p["b_layernorm"]["scale"], eps).astype(f32)
+    c = _rms(dbc[:, R + N:], p["c_layernorm"]["scale"], eps).astype(f32)
+    delta = jax.nn.softplus(_proj(dt, p["dt_proj"]).astype(f32) + p["dt_bias"].astype(f32))
+
+    from deepspeed_tpu.ops.pallas import selective_scan as scan
+    impl = scan.scan_impl(ssm.shape, x.shape[0], ctx.n_rows)
+    if ctx.choice is not None:
+        ctx.choice.state_step[x.shape[0]] = impl
+    run = scan.selective_scan if impl == scan.KERNEL else scan.xla_selective_scan
+    with jax.named_scope("ds.jamba.scan"):
+        ssm, y = run(ssm, layer, ctx.seq, ctx.slot, ctx.first_row,
+                     jnp.where(ctx.here, ctx.length, 0), ctx.fresh, xs, delta, b, c,
+                     -jnp.exp(p["A_log"].astype(f32)))
+    y = (y + p["D"].astype(f32) * xs) * jax.nn.silu(xz[:, I:].astype(f32))
+    return _proj(y.astype(x.dtype), p["out_proj"]), ssm, conv
+
+
 # Every kind, a kind whose config class derives another's before that one's.
-KINDS = (Lfm2Kind, NemotronHKind, SalaKind, LongcatKind, MoonlightKind, GPTKind, LlamaKind)
+KINDS = (JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind, MoonlightKind, GPTKind,
+         LlamaKind)
 
 
 def kind_of(cfg):

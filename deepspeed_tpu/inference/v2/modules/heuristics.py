@@ -64,10 +64,13 @@ class AttentionChoice:
     pinned (``override``, None = choose), and ``selected`` — what each
     traced program actually got, keyed by the program's token count, so
     the engine can say which implementation serves and nothing has to be
-    inferred from the backend. ``state_step``: the same for the Mamba-2
-    state step of a model kind that has one (``pallas_ssm_state``, the
+    inferred from the backend. ``state_step``: the same for the state step
+    of a model kind that has one - Mamba-2's (``pallas_ssm_state``, the
     kernel that visits a step's slots in place, or ``xla``:
-    ``ops/pallas/ssm_state.state_step_impl``; nothing pins it).
+    ``ops/pallas/ssm_state.state_step_impl``) or Mamba-1's
+    (``pallas_selective_scan``, the kernel that runs each sequence's rows
+    through its slot in one visit, or ``xla``, a ``lax.scan`` over the rows:
+    ``ops/pallas/selective_scan.scan_impl``); nothing pins it.
     ``tiled``: the token counts of the programs whose paged kernel
     ``model_runner._paged_attend`` gave the step's query tiles."""
 

@@ -14,6 +14,8 @@ from deepspeed_tpu.models.minicpm_sala import (MINICPM_SALA_CONFIGS, MiniCPMSala
                                                build_minicpm_sala)  # noqa: F401
 from deepspeed_tpu.models.lfm2 import (LFM2_CONFIGS, Lfm2MoeConfig, Lfm2MoeForCausalLM,
                                        build_lfm2)  # noqa: F401
+from deepspeed_tpu.models.jamba import (JAMBA_CONFIGS, JambaConfig, JambaForCausalLM,
+                                        build_jamba)  # noqa: F401
 from deepspeed_tpu.models.nemotron_h import (NEMOTRON_H_CONFIGS, NemotronHConfig,
                                              NemotronHForCausalLM,
                                              build_nemotron_h)  # noqa: F401
@@ -23,7 +25,8 @@ from deepspeed_tpu.models.nemotron_h import (NEMOTRON_H_CONFIGS, NemotronHConfig
 MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
                   (MOONLIGHT_CONFIGS, build_moonlight), (LONGCAT_CONFIGS, build_longcat),
                   (MINICPM_SALA_CONFIGS, build_minicpm_sala),
-                  (NEMOTRON_H_CONFIGS, build_nemotron_h), (LFM2_CONFIGS, build_lfm2))
+                  (NEMOTRON_H_CONFIGS, build_nemotron_h), (LFM2_CONFIGS, build_lfm2),
+                  (JAMBA_CONFIGS, build_jamba))
 
 
 def build_model(preset, **overrides):

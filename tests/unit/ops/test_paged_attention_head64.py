@@ -123,3 +123,34 @@ def test_the_engine_gets_the_kernel_at_a_head_of_64_where_it_is_forced(monkeypat
     with pytest.raises(ValueError, match="pinned attention='pallas_paged'"):
         instantiate_attn(None, 64, 64, (8, 4, 64), (2, 40, 64, 64), None, max_blocks=8,
                          override="pallas_paged")
+
+
+@pytest.mark.parametrize("Hkv,G", [(1, 20), (8, 4), (2, 16)],
+                         ids=["jamba-20-over-1", "chat-4-a-head", "agents-16-a-head"])
+def test_a_query_group_that_is_no_power_of_two_matches_the_gather(Hkv, G):
+    """Jamba's 20 query heads over one key-value head of 128 - the first
+    group that is no power of two: a tile lays ``32 x 20`` columns - beside
+    the groups the other cells run (4 and 16), a row a grid step and with
+    the step's query tiles: a chunk of 40 rows, a decode row, a chunk that
+    starts its sequence, padding."""
+    bs, MB, NB, n_seqs = 16, 6, 24, 4
+    rng = np.random.RandomState(G)
+    runs = [(0, 10, 40), (1, 70, 1), (2, 0, 20)]             # (sequence, first position, rows)
+    seq = np.concatenate([np.full(n, s, np.int32) for s, _, n in runs] + [np.full(3, n_seqs)])
+    pos = np.concatenate([np.arange(f, f + n) for _, f, n in runs] + [np.zeros(3)]).astype(np.int32)
+    T, live = len(seq), 61
+    assert T == 2 * pa.QUERY_TILE
+    tables = np.concatenate([rng.randint(1, NB, size=(n_seqs, MB)),
+                             np.zeros((1, MB))]).astype(np.int32)
+    q = jnp.asarray(rng.randn(T, Hkv * G, 128).astype(np.float32))
+    kc = jnp.asarray(rng.randn(2, NB, bs, Hkv * 128).astype(np.float32))
+    vc = jnp.asarray(rng.randn(2, NB, bs, Hkv * 128).astype(np.float32))
+    tabs, seq, pos = jnp.asarray(tables[seq]), jnp.asarray(seq), jnp.asarray(pos)
+    want = xla_paged_attention(q, kc, vc, tabs, pos, jnp.int32(1))
+    tiles = pa.query_tiles(seq, pos, n_seqs, jnp.int32(live), MB)
+    for given in (None, tiles):
+        got = paged_decode_attention(q, kc, vc, tabs, pos, jnp.int32(1), live_rows=live,
+                                     tiles=given, interpret=True)
+        np.testing.assert_allclose(np.asarray(got[:live]), np.asarray(want[:live]),
+                                   rtol=1e-5, atol=1e-5)
+        assert not np.asarray(got[live:]).any()

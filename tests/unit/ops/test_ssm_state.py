@@ -211,10 +211,13 @@ def test_mosaic_takes_the_paged_kernel_at_a_head_of_64(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < int(np.prod(pool)) * 2 // 100
 
 
-# (query heads, key-value heads, head, block rows, pool blocks, table blocks) of the two cells
-# whose 512-row programs hold prompt chunks: lfm2-24b-rag, mistral7b-chat-r2
+# (query heads, key-value heads, head, block rows, pool blocks, table blocks) of the cells
+# whose 512-row programs hold prompt chunks: lfm2-24b-rag, mistral7b-chat-r2 and
+# jamba2-3b-chatloop (a query group of 20 over one key-value head: the first group that is
+# no power of two; a tile lays 32 x 20 = 640 columns, five whole lane tiles)
 TILED_SHAPES = {"head64-64row-blocks": (32, 8, 64, 64, 8705, 136),
-                "head128-16row-blocks": (32, 8, 128, 16, 2560, 360)}
+                "head128-16row-blocks": (32, 8, 128, 16, 2560, 360),
+                "group20-one-kv-head": (20, 1, 128, 64, 4097, 16)}
 
 
 @pytest.mark.parametrize("shape", list(TILED_SHAPES))
@@ -223,7 +226,7 @@ def test_mosaic_takes_a_query_tile(one_chip, shape):
     above): a 512-row ``put`` program's paged kernel with the step's query
     tiles laid inside the program (``paged_attention.query_tiles``) - the
     body that attends a tile of a chunk's rows through one walk of its
-    context beside the one-row body - lowers at both cells' shapes."""
+    context beside the one-row body - lowers at the three cells' shapes."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     H, Hkv, Dh, bs, NB, MB = TILED_SHAPES[shape]
     rows, n_seqs = 512, 64
@@ -245,3 +248,31 @@ def test_mosaic_takes_a_query_tile(one_chip, shape):
     assert "paged_decode_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < int(np.prod(pool)) * 2 // 100
 
+
+@pytest.mark.parametrize("rows", [512, 256])
+def test_mosaic_takes_the_selective_scan_at_jambas_shape(one_chip, rows):
+    """Compiled for a described v5e (nothing runs; in this file for the reason
+    above): ``ops/pallas/selective_scan.selective_scan`` lowers at
+    ``jamba2-3b-chatloop``'s shape in the 512-row and the 256-row program,
+    the pool comes back as the buffer it came in, and the program holds no
+    temporary of a slot-pool layer's size, let alone a ``[T, C, N]``."""
+    from deepspeed_tpu.ops.pallas import selective_scan as ss
+    shape, S = (26, 257, 16, 5120), 257
+    assert ss.kernel_supported(shape, rows, S)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(shape, jnp.float32), sds((), jnp.int32), sds((rows,), jnp.int32),
+            sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.bool_),
+            sds((rows, 5120), jnp.float32), sds((rows, 5120), jnp.float32),
+            sds((rows, 16), jnp.float32), sds((rows, 16), jnp.float32),
+            sds((16, 5120), jnp.float32))
+    compiled = _compiled(lambda *a: ss.selective_scan(*a, interpret=False), args,
+                         donate_argnums=0)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= int(np.prod(shape)) * 4
+    # b and c laid a column a sublane, 2 x rows x 8 KB, and nothing of the pool's order
+    assert memory.temp_size_in_bytes < 2 * rows * 16 * 128 * 4 + (1 << 20)
+    assert rows * 5120 * 16 * 4 > 20 * memory.temp_size_in_bytes        # no [T, C, N]
+    assert "selective_scan" in compiled.as_text()

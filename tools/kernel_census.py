@@ -79,9 +79,18 @@ bytes a step has to move (a live slot once in and once out), share of 819 -
 the MXU form, the plain float32 lane sum, the copies alone, and
 ``xla_ssm_state_step``'s three passes beside them (PERF.md, PR 39; ~1 min).
 
+``--scan`` times ``selective_scan`` (Mamba-1, a decay an element) at
+``jamba2-3b-chatloop``'s shape (26 layers of 257 slots of 16 x 5120 float32,
+the layer traced in a scan, the pool donated): 256 one-row sequences in a
+256-row program (a decode step), and a 512-row chunk of 1, 3 and 8 runs (a
+prompt step): ms a layer, us a row, GB/s of the bytes a call has to move (a
+live slot once in and once out, a row's operands in and ``y`` out), share of
+819, the largest error against ``xla_selective_scan`` (PERF.md, PR 45; ~1 min).
+
 Prints one JSON line per kernel and writes ``chiprun_out/kernel_census.json``.
 """
 
+import functools
 import json
 import os
 import sys
@@ -778,6 +787,90 @@ def ssm_state_classes():
         yield f"ssm-state-{live}", record
 
 
+# ``--scan``: jamba2-3b-chatloop's Mamba layers (layers, slots, state columns, channels)
+SCAN_SHAPE = (26, 256, 16, 5120)
+# (rows of the program, runs, rows a run): a decode step, then a prompt chunk cut into runs
+SCAN_STEPS = ((256, 256, 1), (512, 1, 512), (512, 3, 170), (512, 8, 64))
+
+
+def scan_bytes(slots, rows, N, C):
+    """Least bytes through HBM for one Mamba layer of a step: each live
+    slot's float32 state once in and once out, each row's ``x`` and
+    ``delta`` in and ``y`` out (float32, ``C`` wide) and its ``B`` and ``C``
+    (``N`` wide)."""
+    return slots * 2 * N * C * 4 + rows * (3 * C + 2 * N) * 4
+
+
+def selective_scan_classes():
+    """Yields one record a step of ``SCAN_STEPS``: the scan at
+    ``jamba2-3b-chatloop``'s shape, **all 26 layers a call** with the layer
+    traced inside a ``lax.scan`` as the step programs have it and the pool
+    donated; a sequence in 16 fresh. ms a layer, us a row, GB/s of
+    :func:`scan_bytes`, share of 819, the error against
+    ``xla_selective_scan`` over four of the layers."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import selective_scan as ss
+
+    Lm, slots, N, C = SCAN_SHAPE
+    S = slots + 1
+    rng = np.random.default_rng(45)
+    fill = jax.jit(lambda key, Lm: jax.random.normal(key, (Lm, S, N, C), jnp.float32),
+                   static_argnums=1)
+    a = -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32)[:, None], (N, C))
+
+    def layers(step, Lm):
+        def run(pool, *rows):
+            def one(pool, layer):
+                return step(pool, layer, *rows, a)
+            return jax.lax.scan(one, pool, jnp.arange(Lm, dtype=jnp.int32))
+        return jax.jit(run, donate_argnums=0)
+
+    for T, runs, rows_a_run in SCAN_STEPS:
+        seq = np.full(T, S - 1, np.int32)
+        slot, first, length = (np.zeros(S, np.int32) for _ in range(3))
+        fresh = np.ones(S, bool)
+        slot[:runs] = rng.permutation(np.arange(1, S))[:runs]
+        for s in range(runs):
+            first[s], length[s] = s * rows_a_run, rows_a_run
+            seq[first[s]:first[s] + rows_a_run] = s
+            fresh[s] = s % 16 == 15
+        live = runs * rows_a_run
+        rows = (jnp.asarray(seq), jnp.asarray(slot), jnp.asarray(first), jnp.asarray(length),
+                jnp.asarray(fresh), jnp.asarray(rng.standard_normal((T, C)), jnp.float32),
+                jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1), (T, C))), jnp.float32),
+                jnp.asarray(rng.standard_normal((T, N)), jnp.float32),
+                jnp.asarray(rng.standard_normal((T, N)), jnp.float32))
+        least = scan_bytes(runs, live, N, C)
+        record = {"rows": T, "runs": runs, "live_rows": live, "least_bytes_a_layer": least}
+        try:
+            few = 4
+            want_pool, want_y = layers(ss.xla_selective_scan, few)(
+                fill(jax.random.PRNGKey(T + runs), few), *rows)
+            pool, y = layers(functools.partial(ss.selective_scan, interpret=False), few)(
+                fill(jax.random.PRNGKey(T + runs), few), *rows)
+            named = np.asarray(slot[:runs:max(runs // 8, 1)])
+            err = max(rel_err(y, want_y), rel_err(pool[:, named], want_pool[:, named]))
+            call = layers(functools.partial(ss.selective_scan, interpret=False), Lm)
+            pool = fill(jax.random.PRNGKey(1), Lm)
+            pool, y = call(pool, *rows)
+            jax.block_until_ready(pool)
+            t0 = time.perf_counter()
+            for _ in range(10):
+                pool, y = call(pool, *rows)
+            jax.block_until_ready((pool, y))
+            ms = (time.perf_counter() - t0) * 1e3 / 10 / Lm
+            record.update(ms_a_layer=ms, us_a_row=ms * 1e3 / live, gb_s=least / ms / 1e6,
+                          hbm_share=100 * least / ms / 1e6 / HBM_GB_S,
+                          rel_err=float(f"{err:.3e}"))
+        except Exception as e:  # a refusal is a record too
+            record["refused"] = f"{type(e).__name__}: {e}"[:1500]
+        yield f"selective-scan-{T}x{runs}", record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -807,7 +900,10 @@ def main():
     parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
     live, ssm, chunk = "--live" in sys.argv, "--ssm" in sys.argv, "--chunk" in sys.argv
-    if chunk:
+    scan = "--scan" in sys.argv
+    if scan:
+        section, records = "selective_scan", selective_scan_classes()
+    elif chunk:
         section, records = "query_tiles", chunk_classes(parent_dir)
     elif ssm:
         section, records = "ssm_state", ssm_state_classes()
@@ -824,7 +920,7 @@ def main():
     for name, record in records:
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
-    for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk
+    for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk or scan
                                      or "--gmm-only" in sys.argv
                                      else cases()):
         try:
@@ -834,7 +930,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("chunk_census.json" if chunk else "ssm_census.json" if ssm
+    out = ("scan_census.json" if scan else "chunk_census.json" if chunk
+           else "ssm_census.json" if ssm
            else "live_census.json" if live
            else "paged_census.json" if paged
            else "mla_census.json" if mla else "kernel_census.json")
