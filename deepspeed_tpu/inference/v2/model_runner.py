@@ -1215,7 +1215,7 @@ class NemotronHKind(ModelKind):
     def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
         NemotronHKind.base_only(mesh, lora)
         model = params["model"]
-        ctx = _SlotStep(cfg, batch, attn_impl)
+        ctx = _SlotStep(cfg, batch, extra["conv"].shape[1], attn_impl)
         stacks = {"M": model.get("mamba_layers"), "*": model.get("attn_layers"),
                   "E": model.get(NemotronHKind.experts_at, {})}
         experts = stacks["E"].get("experts")
@@ -1273,16 +1273,17 @@ class NemotronHKind(ModelKind):
         alone - the same packed recurrence, the same reads and writes of the
         slot pool: x [T, D] the normalised stream → (y [T, D], ssm, conv)."""
         lp = _layer_of(params["model"]["mamba_layers"], layer)
-        return _mamba_mixer(_SlotStep(cfg, batch), lp, layer, x, ssm, conv)
+        return _mamba_mixer(_SlotStep(cfg, batch, conv.shape[1]), lp, layer, x, ssm, conv)
 
 
 class _SlotStep:
     """What every layer of one step shares: each token's sequence row and
-    position, each sequence row's slot, and where its rows lie in the
-    step; ``choice``, the engine's ``heuristics.AttentionChoice`` (None =
-    nobody asks), is told which state step the program got."""
+    position, each sequence row's slot, where its rows lie in the step, and
+    which sequence row names each of the pools' ``pool_slots`` slots;
+    ``choice``, the engine's ``heuristics.AttentionChoice`` (None = nobody
+    asks), is told which state step the program got."""
 
-    def __init__(self, cfg, batch, choice=None):
+    def __init__(self, cfg, batch, pool_slots, choice=None):
         self.cfg, self.choice = cfg, choice
         self.seq, self.pos = batch["token_seq"], batch["token_pos"]
         self.n_rows = S = batch["block_tables"].shape[0]     # sequences a step + padding's
@@ -1298,6 +1299,11 @@ class _SlotStep:
         self.here = (self.length > 0) & (jnp.arange(S) < S - 1)
         self.first_row = jnp.minimum(
             jnp.full((S,), T, jnp.int32).at[self.seq].min(jnp.arange(T, dtype=jnp.int32)), T - 1)
+        # the live sequence row that names a slot (live rows name distinct slots), or
+        # padding's row where none does: such a slot keeps what it holds
+        self.row_of_slot = jnp.full((pool_slots,), S - 1, jnp.int32).at[
+            jnp.where(self.here, self.slot, pool_slots)].set(jnp.arange(S, dtype=jnp.int32),
+                                                             mode="drop")
 
 
 def _conv_with_tail(stream, kernel, bias, pool, layer, rows):
@@ -1311,31 +1317,59 @@ def _conv_with_tail(stream, kernel, bias, pool, layer, rows):
     sequence leaves the tail of what it has now seen, the last ``K - 1``
     rows of its stream. → (filtered [T, C] float32, pool). What enters
     the stream before (a gate) and what follows (an activation, a gate)
-    are the caller's."""
+    are the caller's.
+
+    **Who moves the tails.** Nothing is gathered or scattered a slab ``[K -
+    1, C]`` at a time, and no ``[S, K - 1, C]`` tensor exists: a slab of 3
+    rows fills no tile, so every such tensor is laid out again before and
+    after each use, and the pool itself - which arrives with its ``K - 1``
+    rows outermost - is copied whole into a slot-major layout before the
+    layer loop and back after it (PERF.md, PR 46). The layer's tails are
+    read where they lie, **a row of every slot at a time**, into one table
+    of rows beside the stream's and a row of zeros; a tap is one gather of
+    rows from that table (a row's own earlier rows, its slot's tail, or
+    zero where the sequence starts here), and so is row ``i`` of every
+    slot's new tail, written back over the layer's ``[slots + 1, C]`` whole:
+    a slot a live row names takes its sequence's, and **a slot no live row
+    names** - padding's slot 0 among them - **takes its own row again, bit
+    for bit** (``rows.row_of_slot``). No arithmetic but the taps' own."""
     T, C = stream.shape
     K = kernel.shape[0]
-    f32 = jnp.float32
-    seq, slot, first_row = rows.seq, rows.slot, rows.first_row
-    tail = jnp.where(rows.fresh[:, None, None], 0, pool[layer, slot])    # [S, K - 1, C]
-    rank = jnp.arange(T, dtype=jnp.int32) - first_row[seq]     # a row's index in its chunk
+    NS = pool.shape[1]
+    f32, i32 = jnp.float32, jnp.int32
+    seq, first_row = rows.seq, rows.first_row
+    moved = jnp.promote_types(stream.dtype, pool.dtype)
+    held = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)       # [NS, K - 1, C]
+    # the table: row k of slot ns at k * NS + ns, the stream's row t at base + t, then zeros
+    base, zero = (K - 1) * NS, (K - 1) * NS + T
+    table = jnp.concatenate([held[:, k].astype(moved) for k in range(K - 1)]
+                            + [stream.astype(moved), jnp.zeros((1, C), moved)], axis=0)
+    at = jnp.arange(T, dtype=i32)
+    rank = at - first_row[seq]                                  # a row's index in its chunk
+    starts, slot = rows.fresh[seq], rows.slot[seq]              # a row's sequence's
     acc = None if bias is None else bias.astype(f32)[None, :]
     kernel = kernel.astype(f32)
     for j in range(K):
         back = K - 1 - j                                     # tap j reads `back` rows back
-        tap = stream if back == 0 else jnp.concatenate(
-            [jnp.zeros((min(back, T), C), stream.dtype), stream[:max(T - back, 0)]], axis=0)
+        tap = stream
         if back:
-            carried_row = tail[seq, jnp.clip(rank + j, 0, K - 2)]       # the tail's row rank + j
-            tap = jnp.where((rank >= back)[:, None], tap, carried_row)
+            carried = jnp.where(starts, zero, jnp.clip(rank + j, 0, K - 2) * NS + slot)
+            tap = table[jnp.where(rank >= back, base + at - back, carried)]
         term = kernel[j][None, :] * tap.astype(f32)
         acc = term if acc is None else acc + term
-    i = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-    into = rows.length[:, None] - (K - 1) + i                # [S, K - 1]: chunk row, or < 0
-    kept = jnp.take_along_axis(tail, jnp.clip(rows.length[:, None] + i, 0, K - 2)[..., None],
-                               axis=1)
-    new_tail = jnp.where((into >= 0)[..., None],
-                         stream[jnp.clip(first_row[:, None] + into, 0, T - 1)], kept)
-    return acc, pool.at[layer, slot].set(new_tail.astype(pool.dtype))
+    # row i of the new tails, a slot a row: chunk row ``into`` of the slot's sequence, or
+    # row ``length + i`` of the tail it carried
+    of = rows.row_of_slot
+    named = of < rows.n_rows - 1
+    length, first, fresh = rows.length[of], first_row[of], rows.fresh[of]
+    own = jnp.arange(NS, dtype=i32)
+    for i in range(K - 1):
+        into = length - (K - 1) + i
+        kept = jnp.where(fresh, zero, jnp.clip(length + i, 0, K - 2) * NS + own)
+        new = jnp.where(into >= 0, base + jnp.clip(first + into, 0, T - 1), kept)
+        rows_i = table[jnp.where(named, new, i * NS + own)]
+        pool = pool.at[layer, :, i].set(rows_i.astype(pool.dtype))
+    return acc, pool
 
 
 MAMBA_ROUND = 4     # sequences with more than one row in a step, taken this many at a time
@@ -1533,7 +1567,7 @@ class Lfm2Kind(ModelKind):
     def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
         Lfm2Kind.base_only(mesh, lora)
         model = params["model"]
-        ctx = _SlotStep(cfg, batch)
+        ctx = _SlotStep(cfg, batch, extra["conv"].shape[1])
         stacks = {"conv": model.get("conv_layers"), "attn": model.get("attn_layers"),
                   "dense": model.get("dense_ffn"), "moe": model.get(Lfm2Kind.experts_at, {})}
         experts = stacks["moe"].get("experts")
@@ -1587,7 +1621,7 @@ class Lfm2Kind(ModelKind):
         alone - the same gates, the same reads and writes of the slot pool:
         x [T, D] the normalised stream → (y [T, D], conv)."""
         lp = _layer_of(params["model"]["conv_layers"], layer)
-        return _lfm2_conv(_SlotStep(cfg, batch), lp, layer, x, conv)
+        return _lfm2_conv(_SlotStep(cfg, batch, conv.shape[1]), lp, layer, x, conv)
 
     @staticmethod
     def attention_layer(params, cfg, layer, x, kc, vc, batch, attn_impl=None):
@@ -1695,7 +1729,7 @@ class JambaKind(ModelKind):
     def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
         JambaKind.base_only(mesh, lora)
         model = params["model"]
-        ctx = _SlotStep(cfg, batch, attn_impl)
+        ctx = _SlotStep(cfg, batch, extra["conv"].shape[1], attn_impl)
         stacks = {"mamba": model.get("mamba_layers"), "attn": model.get("attn_layers"),
                   "ffn": model["ffn"]}
 
@@ -1736,7 +1770,7 @@ class JambaKind(ModelKind):
         alone - the same convolution, scan and reads and writes of the
         slot pools: x [T, D] the normalised stream → (y [T, D], ssm, conv)."""
         lp = _layer_of(params["model"]["mamba_layers"], layer)
-        return _jamba_mamba(_SlotStep(cfg, batch), lp, layer, x, ssm, conv)
+        return _jamba_mamba(_SlotStep(cfg, batch, conv.shape[1]), lp, layer, x, ssm, conv)
 
     @staticmethod
     def attention_layer(params, cfg, layer, x, kc, vc, batch, attn_impl=None):

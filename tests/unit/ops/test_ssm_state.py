@@ -9,6 +9,8 @@ state to 16 bits of mantissa: ~2.5e-6 of the read's norm); its update is
 the float32 product to a place or two, so the state is held to 1e-6.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -276,3 +278,43 @@ def test_mosaic_takes_the_selective_scan_at_jambas_shape(one_chip, rows):
     assert memory.temp_size_in_bytes < 2 * rows * 16 * 128 * 4 + (1 << 20)
     assert rows * 5120 * 16 * 4 > 20 * memory.temp_size_in_bytes        # no [T, C, N]
     assert "selective_scan" in compiled.as_text()
+
+
+def test_the_tail_pool_rides_the_layer_loop_as_it_arrives(one_chip):
+    """Compiled for a described v5e (nothing runs; in this file for the reason
+    above): ``model_runner._conv_with_tail`` in a layer scan at
+    ``jamba2-3b-chatloop``'s shape, the pool donated. The pool comes back as
+    the buffer it came in; **nothing in the program has the pool's shape in
+    another layout** - written a slab ``[K - 1, C]`` at a time, the compiler
+    copies all 205 MB into a slot-major layout before the loop and back after
+    it, 1.5 ms a step (PERF.md, PR 46) - and its temporaries are of the order
+    of the table of rows, not of the pool."""
+    from deepspeed_tpu.inference.v2 import model_runner
+    L, NS, K, C, T = 26, 257, 4, 5120, 512
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(pool, batch, streams, kernels, biases):
+        rows = model_runner._SlotStep(None, batch, NS)
+
+        def layer(carry, x):
+            pool, at, total = carry
+            acc, pool = model_runner._conv_with_tail(*x, pool, at, rows)
+            return (pool, at + 1, total + acc), None
+
+        start = (pool, jnp.int32(0), jnp.zeros((T, C), jnp.float32))
+        return jax.lax.scan(layer, start, (streams, kernels, biases))[0][::2]
+
+    batch = {"token_seq": sds((T,), jnp.int32), "token_pos": sds((T,), jnp.int32),
+             "block_tables": sds((NS, 4), jnp.int32), "seq_state": sds((NS, 1), jnp.int32)}
+    args = (sds((L, NS, K - 1, C), jnp.bfloat16), batch, sds((L, T, C), jnp.bfloat16),
+            sds((L, K, C), jnp.bfloat16), sds((L, C), jnp.bfloat16))
+    compiled = _compiled(step, args, donate_argnums=0)
+    memory = compiled.memory_analysis()
+    pool_bytes = L * NS * (K - 1) * C * 2
+    assert memory.alias_size_in_bytes >= pool_bytes
+    table_bytes = ((K - 1) * NS + T + 1) * C * 2
+    assert memory.temp_size_in_bytes < 4 * table_bytes < pool_bytes // 3
+    layouts = set(re.findall(rf"bf16\[{L},{NS},{K - 1},{C}\](\{{[^}}]*\}})", compiled.as_text()))
+    assert len(layouts) == 1, layouts
