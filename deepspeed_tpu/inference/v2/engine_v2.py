@@ -8,6 +8,7 @@ jitted step (compiled once, KV pool donated) consumes the padded flat
 batch from ``RaggedBatchWrapper``; mixed prefill chunks and decodes
 run in the same program — the Dynamic SplitFuse model."""
 
+import itertools
 from collections import OrderedDict
 
 import numpy as np
@@ -265,7 +266,9 @@ class InferenceEngineV2:
         self.state_kind = kind.state_kind
         self._state_step_said = set()       # the programs whose state step has been logged
         self.state_bytes_per_token = self.kv_cache.bytes_per_token()
-        self.state_manager = DSStateManager(self.kv_cache, int(sm.max_tracked_sequences))
+        self.state_manager = DSStateManager(self.kv_cache, int(sm.max_tracked_sequences),
+                                            max_blocks_per_seq=self.max_blocks_per_seq,
+                                            seq_rows=kind.seq_rows)
         # State beyond the two paged pools, where the model kind keeps any: its own tree
         # of device arrays, carried and donated through every program beside the pools,
         # and a slot of it a tracked sequence (ragged/slot_pool.py). None for a kind
@@ -698,67 +701,73 @@ class InferenceEngineV2:
                     specs = specs if specs is not None else [None] * len(batch_uids)
                 # host-side list→array prep on caller-provided tokens, no device sync
                 self.count_host_sync()
-                batch_tokens = [np.atleast_1d(np.asarray(t, np.int32)) for t in batch_tokens]  # ds-lint: disable=host-sync -- input tokens are host lists, never device arrays
+                sm, n = self.state_manager, len(batch_uids)
+                try:
+                    lens = np.fromiter(map(len, batch_tokens), np.int64, n)
+                except TypeError:   # a bare token is a chunk of one
+                    batch_tokens = [np.atleast_1d(t) for t in batch_tokens]
+                    lens = np.fromiter(map(len, batch_tokens), np.int64, n)
+                total = int(lens.sum())
+                tokens = np.fromiter(itertools.chain.from_iterable(batch_tokens), np.int32, total)
                 # Validate the WHOLE batch before touching any sequence state: a
-                # mid-loop failure after allocate/advance would leave earlier
+                # failure after allocate/advance would leave earlier
                 # sequences claiming KV that was never written.
-                total = sum(len(t) for t in batch_tokens)
                 if total > self.max_tokens:
                     raise ValueError(f"batch has {total} tokens > "
                                      f"max_ragged_batch_size={self.max_tokens}")
-                if len(batch_uids) > self.max_seqs:
-                    raise ValueError(f"{len(batch_uids)} sequences > "
+                if n > self.max_seqs:
+                    raise ValueError(f"{n} sequences > "
                                      f"max_ragged_sequence_count={self.max_seqs}")
-                max_ctx = self.max_ctx_tokens
-                blocks_needed = 0
-                new_seqs = 0
-                for uid, tokens in zip(batch_uids, batch_tokens):
-                    desc = self.state_manager.query(uid)
-                    seen = desc.seen_tokens if desc is not None else 0
-                    if desc is None:
-                        new_seqs += 1
-                    if self.slot_pool is not None and (desc is None or desc.state_row is None):
-                        # how such a model attends to a prompt's rows depends on the whole
-                        # prompt's length, which a first chunk does not say
+                descs = [sm.query(uid) for uid in batch_uids]
+                seen, need = self._seen_and_need(descs, lens)
+                bad = seen + lens > self.max_ctx_tokens
+                if self.slot_pool is not None:
+                    # how such a model attends to a prompt's rows depends on the whole
+                    # prompt's length, which a first chunk does not say
+                    untold = np.fromiter([desc is None or desc.state_row is None
+                                          for desc in descs], bool, n)
+                    bad |= untold
+                if bad.any():
+                    i = int(np.argmax(bad))     # the first sequence at fault, as a walk finds it
+                    if self.slot_pool is not None and untold[i]:
                         raise ValueError(
-                            f"sequence {uid}: a {self.kind.name!r} model needs the whole "
-                            f"prompt before its first chunk — call prefix_match(uid, prompt) "
-                            f"first (a scheduler does)")
-                    if seen + len(tokens) > max_ctx:
-                        raise ValueError(f"sequence {uid}: {seen}+{len(tokens)} tokens exceed "
-                                         f"max_context={max_ctx}")
-                    blocks_needed += (desc.blocks_needed(len(tokens)) if desc is not None
-                                      else -(-len(tokens) // self.block_size))
+                            f"sequence {batch_uids[i]}: a {self.kind.name!r} model needs the "
+                            f"whole prompt before its first chunk — call prefix_match(uid, "
+                            f"prompt) first (a scheduler does)")
+                    raise ValueError(f"sequence {batch_uids[i]}: {seen[i]}+{lens[i]} tokens "
+                                     f"exceed max_context={self.max_ctx_tokens}")
+                blocks_needed = int(need.sum())
                 if blocks_needed > self._reclaimable_blocks():
                     raise RuntimeError(f"KV pool exhausted: need {blocks_needed} blocks, "
                                        f"{self._reclaimable_blocks()} reclaimable — "
                                        f"flush() sequences first")
-                if new_seqs + self.state_manager.n_tracked_sequences > \
-                        self.state_manager.max_tracked_sequences:
+                new_seqs = descs.count(None)
+                if new_seqs + sm.n_tracked_sequences > sm.max_tracked_sequences:
                     raise RuntimeError("max_tracked_sequences exceeded for this batch")
 
+                written = sm.rows_written
+                if new_seqs:
+                    descs = [sm.get_or_create_sequence(uid) if desc is None else desc
+                             for uid, desc in zip(batch_uids, descs)]
+                sm.reserve(descs, need)
+                adapters = self._seat(descs)    # sequence i takes row i of the step's tables
                 self._batch.clear()
-                slots = []
-                for i, (uid, tokens) in enumerate(zip(batch_uids, batch_tokens)):
-                    desc = self.state_manager.get_or_create_sequence(uid)
-                    desc.slot = i  # slots are per-batch rows in the device tables
-                    if self.lora_store is not None:
-                        # re-resolve per batch: a hot-swap/eviction between steps
-                        # may have moved the adapter to a different slot
-                        desc.adapter_slot = self.lora_store.slot_of(uid)
-                    self.state_manager.allocate_for(desc, len(tokens))
-                    self._batch.insert_sequence(desc, tokens)
-                    desc.advance(len(tokens))
-                    rec.n_ctx_tokens += desc.seen_tokens
-                    if self._log_tokens:
-                        # content log: retire-time insertion into the prefix
-                        # trie, and the n-gram drafter's lookup corpus. A host
-                        # append must land AFTER any pending device segments
-                        # from drained pipelined bursts, so fence first (a
-                        # cached re-read once the scheduler has fetched them)
+                _, seq_state = sm.gather(descs, out=self._batch.block_tables[:n])
+                self._batch.insert_batch(0, seen, lens, tokens, adapters=adapters,
+                                         seq_state=seq_state)
+                for desc, new in zip(descs, lens.tolist()):
+                    desc.advance(new)
+                rec.n_ctx_tokens += int((seen + lens).sum())
+                rec.n_table_rows_written = sm.rows_written - written
+                if self._log_tokens:
+                    # content log: retire-time insertion into the prefix
+                    # trie, and the n-gram drafter's lookup corpus. A host
+                    # append must land AFTER any pending device segments
+                    # from drained pipelined bursts, so fence first (a
+                    # cached re-read once the scheduler has fetched them)
+                    for desc, chunk in zip(descs, batch_tokens):
                         desc.tokens.fence()
-                        desc.tokens.extend(int(t) for t in tokens)
-                    slots.append(desc.slot)
+                        desc.tokens.extend(int(t) for t in chunk)
                 # decode bucket: a batch of ≤ max_seqs tokens (pure decode round)
                 # runs the small compiled step; prefill chunks run the full-budget
                 # one. Two programs total — shapes stay static per bucket.
@@ -781,10 +790,10 @@ class InferenceEngineV2:
                     # batch metadata is replicated over the serving mesh (the flat
                     # token batch carries no sharding — only weights/KV do)
                     arrays = jax.device_put(arrays, self._replicated)
-                rec.program, rec.n_seqs, rec.n_tokens = str(bucket), len(batch_uids), total
+                rec.program, rec.n_seqs, rec.n_tokens = str(bucket), n, total
                 rec.n_rows = bucket
                 # without a scheduler to say which chunks are prompt: rows longer than one
-                rec.n_prompt_tokens = sum(len(t) for t in batch_tokens if len(t) > 1)
+                rec.n_prompt_tokens = int(lens[lens > 1].sum())
             # hot adapter slabs ride as jit ARGUMENTS (not captured constants)
             # so promotions/hot-swaps rebind buffers without any retrace
             extra = (self.lora_store.slabs(),) if self.lora_store is not None else ()
@@ -806,7 +815,7 @@ class InferenceEngineV2:
             self._note_chunks(rec)  # while the program runs
             with tracing.phase("engine.fetch"):
                 host, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- THE one intended sync per step: callers consume host tokens/logits
-                host = host[slots]
+                host = host[:n]
                 self._note_counts(rec, counts)
             self.last_step = rec
             return host
@@ -965,35 +974,87 @@ class InferenceEngineV2:
         EOS is currently grammatical."""
         return self.structured is None or self.structured.accepting(uid)
 
+    def _seen_and_need(self, descs, new_tokens):
+        """→ ``(seen, need)``, int64 arrays over ``descs`` (None: a
+        sequence not tracked yet): the tokens each has in the KV cache
+        and the blocks it lacks to hold ``new_tokens`` (one number, or
+        one a sequence) more."""
+        n = len(descs)
+        seen = np.fromiter([0 if desc is None else desc.seen_tokens for desc in descs],
+                           np.int64, n)
+        held = np.fromiter([0 if desc is None else len(desc.blocks) for desc in descs],
+                           np.int64, n)
+        return seen, np.maximum(0, -(-(seen + new_tokens) // self.block_size) - held)
+
+    def _seat(self, descs):
+        """``descs[i]`` takes row ``i`` of the step's tables (``desc.slot``:
+        per batch, not the sequence's row of the manager's table). → the
+        adapter slot each selects, None without LoRA serving: re-resolved
+        per batch, since a hot-swap or an eviction between steps may have
+        moved an adapter to another slot."""
+        for i, desc in enumerate(descs):
+            desc.slot = i
+        if self.lora_store is None:
+            return None
+        adapters = [self.lora_store.slot_of(desc.uid) for desc in descs]
+        for desc, slot in zip(descs, adapters):
+            desc.adapter_slot = slot
+        return adapters
+
+    def _pack_rows(self, rec, plan):
+        """The rows of a burst or verify program, which holds sequence
+        ``i`` at row ``i`` throughout: reserve the blocks ``plan``
+        (:meth:`_validate_burst`'s) found lacking and gather the tables.
+        → ``(descs, token_seq [ms], pos0 [ms], tables [ms + 1, mb],
+        adapters [ms + 1], seq_state [ms + 1, seq_rows] | None)``; rows
+        past the sequences are padding's (the null slot, null blocks, the
+        base adapter). Advancing the sequences is the caller's."""
+        descs, seen, need = plan
+        sm, n, ms = self.state_manager, len(descs), self.max_seqs
+        written = sm.rows_written
+        sm.reserve(descs, need)
+        rec.n_table_rows_written = sm.rows_written - written
+        tables, seq_state = sm.gather(descs, ms + 1)
+        token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
+        token_seq[:n] = np.arange(n)
+        pos0 = np.zeros(ms, np.int32)
+        pos0[:n] = seen
+        adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
+        selected = self._seat(descs)
+        if selected is not None:
+            adapters[:n] = selected
+        return descs, token_seq, pos0, tables, adapters, seq_state
+
     def _validate_burst(self, batch_uids, k):
         """Shared pre-flight for the burst family (``can_burst``,
         ``decode_burst``, ``verify_burst``): every sequence must exist
         with prefilled context and room for ``k`` more tokens, and the
-        pool must cover the whole up-front reservation. → ``(descs,
-        None)`` on success, ``(None, exception)`` on failure — raising
-        is the caller's choice (``can_burst`` answers False, the burst
-        entry points raise), so the probe and the entry points cannot
-        drift."""
-        descs = []
-        need = 0
-        for uid in batch_uids:
-            desc = self.state_manager.query(uid)
-            if desc is None or desc.seen_tokens == 0:
+        pool must cover the whole up-front reservation. → ``((descs,
+        seen, need), None)`` on success - the descriptors, their tokens
+        in the KV cache and the blocks each lacks, as
+        :meth:`_seen_and_need` gives them - ``(None, exception)`` on
+        failure — raising is the caller's choice (``can_burst`` answers
+        False, the burst entry points raise), so the probe and the entry
+        points cannot drift."""
+        descs = [self.state_manager.query(uid) for uid in batch_uids]
+        seen, need = self._seen_and_need(descs, k)
+        bad = (seen == 0) | (seen + k > self.max_ctx_tokens)
+        if bad.any():
+            i = int(np.argmax(bad))     # the first sequence at fault, as a walk finds it
+            if seen[i] == 0:
                 return None, ValueError(
-                    f"sequence {uid} has no prefilled context — "
+                    f"sequence {batch_uids[i]} has no prefilled context — "
                     f"bursts continue existing sequences only")
-            if desc.seen_tokens + k > self.max_ctx_tokens:
-                return None, ValueError(
-                    f"sequence {uid}: {desc.seen_tokens}+{k} tokens exceed "
-                    f"max_context={self.max_ctx_tokens}")
-            need += desc.blocks_needed(k)
-            descs.append(desc)
-        if need > self._reclaimable_blocks():
+            return None, ValueError(
+                f"sequence {batch_uids[i]}: {seen[i]}+{k} tokens exceed "
+                f"max_context={self.max_ctx_tokens}")
+        need_total = int(need.sum())
+        if need_total > self._reclaimable_blocks():
             return None, RuntimeError(
-                f"KV pool exhausted: need {need} blocks, "
+                f"KV pool exhausted: need {need_total} blocks, "
                 f"{self._reclaimable_blocks()} reclaimable — "
                 f"flush() sequences first")
-        return descs, None
+        return (descs, seen, need), None
 
     def can_burst(self, batch_uids, k):
         """True when a ``decode_burst(uids, ·, k)`` (or a ``verify_burst``
@@ -1064,8 +1125,7 @@ class InferenceEngineV2:
                     "drain the pipeline before changing decode mode")
             if n > ms:
                 raise ValueError(f"{n} sequences > max_ragged_sequence_count={ms}")
-            from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
-            descs, err = self._validate_burst(batch_uids, k)
+            plan, err = self._validate_burst(batch_uids, k)
             if err is not None:
                 raise err
             if prev is not None:
@@ -1076,24 +1136,10 @@ class InferenceEngineV2:
                 entry = self._replicated_input(entry_np)
 
             lora_on = self.lora_store is not None
-            token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
-            pos0 = np.zeros(ms, np.int32)
-            tables = np.full((ms + 1, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
-            adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
-            seq_state = np.zeros((ms + 1, self._seq_rows), np.int32)
-            for i, desc in enumerate(descs):
-                desc.slot = i
-                if self._seq_rows:
-                    seq_state[i] = desc.state_row
-                if lora_on:
-                    desc.adapter_slot = self.lora_store.slot_of(desc.uid)
-                    adapters[i] = desc.adapter_slot
-                self.state_manager.allocate_for(desc, k)
-                token_seq[i] = i
-                pos0[i] = desc.seen_tokens
-                tables[i, :len(desc.blocks)] = desc.blocks
+            descs, token_seq, pos0, tables, adapters, seq_state = self._pack_rows(rec, plan)
+            for desc in descs:
                 desc.advance(k)
-                rec.n_ctx_tokens += _burst_ctx_tokens(int(pos0[i]), k)
+            rec.n_ctx_tokens += int(_burst_ctx_tokens(pos0[:n], k).sum())
             parts = [token_seq, pos0, tables.ravel()]
             # the optional inputs of the program: keys present or absent, and
             # jit specialises on which. Slabs ride as ARGUMENTS (not captured
@@ -1366,7 +1412,6 @@ class InferenceEngineV2:
         and trailing whole blocks return to the pool."""
         with tracing.step("verify", engine=self.trace_id, uids=tuple(batch_uids)) as rec:
             with tracing.phase("engine.pack"):
-                from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK
                 if self.spec is None:
                     raise RuntimeError("speculative decoding is disabled "
                                        "(config.spec_decode / DS_SPEC_DECODE)")
@@ -1390,7 +1435,7 @@ class InferenceEngineV2:
                 if d < 1:
                     raise ValueError("verify_burst needs at least one draft token; "
                                      "use put()/decode_burst for draft-free decoding")
-                descs, err = self._validate_burst(batch_uids, d + 1)
+                plan, err = self._validate_burst(batch_uids, d + 1)
                 if err is not None:
                     raise err
                 rec.program, rec.n_seqs = f"verify{d}", len(batch_uids)
@@ -1398,20 +1443,13 @@ class InferenceEngineV2:
                 rec.n_rows = self.max_seqs * (d + 1)
                 ms, mb = self.max_seqs, self.max_blocks_per_seq
                 lora_on = self.lora_store is not None
+                # KV blocks for all d+1 tokens now (static tables inside the program);
+                # seen_tokens advances by what is accepted, once that is known
+                descs, token_seq, pos0, tables, adapters, _ = self._pack_rows(rec, plan)
                 toks = np.zeros((ms, d + 1), np.int32)
                 dlen = np.zeros(ms, np.int32)
-                token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
-                pos0 = np.zeros(ms, np.int32)
-                tables = np.full((ms + 1, mb), NULL_BLOCK, np.int32)
-                adapters = np.zeros(ms + 1, np.int32)   # pad row stays slot 0 = base
                 entries = []
-                for i, (desc, tok, drafts) in enumerate(
-                        zip(descs, batch_tokens, batch_drafts)):
-                    desc.slot = i
-                    if lora_on:
-                        desc.adapter_slot = self.lora_store.slot_of(desc.uid)
-                        adapters[i] = desc.adapter_slot
-                    self.state_manager.allocate_for(desc, d + 1)
+                for i, (tok, drafts) in enumerate(zip(batch_tokens, batch_drafts)):
                     self.count_host_sync()
                     entry = int(np.asarray(tok).reshape(-1)[-1])  # ds-lint: disable=host-sync -- entry tokens come from the previous step's host copy
                     entries.append(entry)
@@ -1419,10 +1457,7 @@ class InferenceEngineV2:
                     toks[i, :len(row)] = row
                     toks[i, len(row):] = entry  # inert pad: dlen masks acceptance
                     dlen[i] = len(drafts)
-                    token_seq[i] = i
-                    pos0[i] = desc.seen_tokens
-                    tables[i, :len(desc.blocks)] = desc.blocks
-                    rec.n_ctx_tokens += desc.seen_tokens + d + 1
+                rec.n_ctx_tokens += int(pos0.sum()) + len(descs) * (d + 1)
                 parts = [toks.ravel(), dlen, token_seq, pos0, tables.ravel()]
                 if lora_on:
                     parts.append(adapters)
@@ -1473,6 +1508,7 @@ class InferenceEngineV2:
                     acc = np.asarray(acc)  # ds-lint: disable=host-sync -- host copy of the device result above, already synced
             n = len(batch_uids)
             with tracing.phase("engine.log"):
+                written = self.state_manager.rows_written
                 for i, desc in enumerate(descs):
                     a = int(acc[i])
                     self.tokens_emitted += a + 1
@@ -1489,6 +1525,8 @@ class InferenceEngineV2:
                     self.state_manager.release_unused_blocks(desc)
                     if int(dlen[i]):
                         self.spec.note(desc.uid, accepted=a, drafted=int(dlen[i]))
+                # the rows whose rejected drafts gave blocks back, beside the pack's
+                rec.n_table_rows_written += self.state_manager.rows_written - written
             self.last_step = rec
             return out[:n], acc[:n]
 
@@ -1625,8 +1663,8 @@ class InferenceEngineV2:
         if self.slot_pool is not None:
             desc = self.state_manager.get_or_create_sequence(uid)
             if desc.state_row is None:
-                desc.state_row = self.kind.seq_state(
-                    self.model_config, self.slot_pool.acquire(), len(prompt_tokens))
+                self.state_manager.set_state_row(desc, self.kind.seq_state(
+                    self.model_config, self.slot_pool.acquire(), len(prompt_tokens)))
             return 0
         if self.prefix_cache is None:
             return 0
@@ -1775,21 +1813,22 @@ class InferenceEngineV2:
             raise KeyError(f"unknown sequence {uid}")
         if uid in self._suspended:
             raise ValueError(f"sequence {uid} is already suspended")
+        # the host copy must carry the WHOLE token log — materialize any
+        # pending device segments before snapshotting it
+        desc.tokens.fence()
+        # the blocks are the handle's from here (freed by offload / kept by
+        # the trie): off the descriptor and its row, never double-freed
+        blocks = self.state_manager.trim_blocks(desc, 0)
         # Shared prefix blocks belong to the radix trie and other live
         # sequences may be attending over them RIGHT NOW: copy their KV
         # into the handle but leave the blocks cached (decref only). The
         # resumed sequence gets private copies — correct, at the price of
         # re-duplicating a prefix that may still be cache-resident.
-        shared = desc.blocks[:desc.shared_blocks]
-        # the host copy must carry the WHOLE token log — materialize any
-        # pending device segments before snapshotting it
-        desc.tokens.fence()
-        handle = self.kv_cache.offload(desc.blocks, keep=shared)
+        handle = self.kv_cache.offload(blocks, keep=blocks[:desc.shared_blocks])
         if self.prefix_cache is not None:
             self.prefix_cache.release_lease(uid)
         self._suspended[uid] = {"handle": handle, "seen_tokens": desc.seen_tokens,
                                 "tokens": list(desc.tokens)}
-        desc.blocks = []  # freed by offload / kept by the trie; never double-free
         desc.shared_blocks = 0
         self.state_manager.drop_sequence(uid)
 
@@ -1831,7 +1870,7 @@ class InferenceEngineV2:
         blocks = self.kv_cache.restore(ent["handle"])
         del self._suspended[uid]
         desc = self.state_manager.get_or_create_sequence(uid)
-        desc.extend_blocks(blocks)
+        self.state_manager.extend_blocks(desc, blocks)
         desc.seen_tokens = ent["seen_tokens"]
         # every restored block is private (shared_blocks stays 0); the
         # token log survives suspension so retire can still cache them

@@ -10,19 +10,44 @@ the prompt's longest cached block-aligned prefix (the descriptor starts
 with those blocks in its table and ``seen_tokens`` past them), block
 allocation reclaims unreferenced cached blocks under pressure, and
 flush retires completed blocks INTO the cache instead of freeing them —
-shared prefix blocks are decref'd, never hard-freed."""
+shared prefix blocks are decref'd, never hard-freed.
 
-from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
+**The table.** A step's block tables are not written sequence by sequence:
+the manager keeps, between steps, one row a tracked sequence - its block
+ids padded with the null block (``block_table``) and, for a model kind
+with state beyond its blocks, its ``state_row`` (``state_table``) - and a
+step gathers its rows with one index (:meth:`gather`). A sequence holds
+its row from creation to flush; the row is written only when the
+sequence's blocks change (:meth:`extend_blocks`, :meth:`trim_blocks`: the
+two places that touch ``desc.blocks``), which a decode row's do once in
+``block_size`` steps. The last row is nobody's and stays null: padding's.
+``rows_written`` counts the writes (a step record's
+``n_table_rows_written`` is its growth over the step)."""
+
+import numpy as np
+
+from deepspeed_tpu.inference.v2.ragged.kv_cache import NULL_BLOCK, BlockedKVCache
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
 
 
 class DSStateManager:
 
-    def __init__(self, kv_cache: BlockedKVCache, max_tracked_sequences: int):
+    def __init__(self, kv_cache: BlockedKVCache, max_tracked_sequences: int,
+                 max_blocks_per_seq: int = None, seq_rows: int = 0):
+        """``max_blocks_per_seq``: the table's width, a step's too (the
+        pool's size where nobody says: no sequence owns more).
+        ``seq_rows``: the length of a sequence's ``state_row`` (0: the
+        model kind keeps none, and there is no ``state_table``)."""
         self.kv_cache = kv_cache
         self.max_tracked_sequences = max_tracked_sequences
+        self.max_blocks_per_seq = int(max_blocks_per_seq or kv_cache.num_blocks)
         self._seqs = {}  # uid -> descriptor
         self.prefix_cache = None
+        rows = max_tracked_sequences + 1    # the last is padding's
+        self.block_table = np.full((rows, self.max_blocks_per_seq), NULL_BLOCK, np.int32)
+        self.state_table = np.zeros((rows, seq_rows), np.int32) if seq_rows else None
+        self._free_rows = list(range(max_tracked_sequences - 1, -1, -1))  # 0 goes first
+        self.rows_written = 0
 
     def attach_prefix_cache(self, prefix_cache) -> None:
         """Route allocation/flush through a radix prefix cache."""
@@ -51,25 +76,93 @@ class DSStateManager:
         if len(self._seqs) >= self.max_tracked_sequences:
             raise RuntimeError(f"max_tracked_sequences={self.max_tracked_sequences} exceeded")
         desc = DSSequenceDescriptor(uid, self.kv_cache.block_size)
+        blocks, cached = [], 0
         if self.prefix_cache is not None and prompt_tokens is not None \
                 and len(prompt_tokens) > 0:
             blocks, cached = self.prefix_cache.acquire(uid, prompt_tokens)
-            if cached:
-                desc.extend_blocks(blocks)
-                desc.shared_blocks = len(blocks)
-                desc.seen_tokens = cached
-                desc.cached_tokens = cached
-                desc.tokens = [int(t) for t in prompt_tokens[:cached]]
+        desc.row = self._free_rows.pop()    # nothing can fail from here
+        if cached:
+            self.extend_blocks(desc, blocks)
+            desc.shared_blocks = len(blocks)
+            desc.seen_tokens = cached
+            desc.cached_tokens = cached
+            desc.tokens = [int(t) for t in prompt_tokens[:cached]]
         self._seqs[uid] = desc
         return desc
 
+    # ------------------------------------------------------------ the table
+    def extend_blocks(self, desc: DSSequenceDescriptor, block_ids) -> None:
+        """Append ``block_ids`` to ``desc``'s blocks and to its row."""
+        held = len(desc.blocks)
+        block_ids = np.atleast_1d(block_ids)
+        if held + len(block_ids) > self.max_blocks_per_seq:
+            raise ValueError(f"sequence {desc.uid} owns {held + len(block_ids)} blocks > "
+                             f"max_blocks_per_seq={self.max_blocks_per_seq} (context overflow)")
+        desc.extend_blocks(block_ids)
+        self.block_table[desc.row, held:held + len(block_ids)] = block_ids
+        self.rows_written += 1
+
+    def trim_blocks(self, desc: DSSequenceDescriptor, keep: int) -> list:
+        """Take the blocks past the first ``keep`` off ``desc`` and null
+        their places in its row. → those blocks, the caller's to free or
+        to hand on."""
+        extra = desc.blocks[keep:]
+        if extra:
+            del desc.blocks[keep:]
+            self.block_table[desc.row, keep:keep + len(extra)] = NULL_BLOCK
+            self.rows_written += 1
+        return extra
+
+    def set_state_row(self, desc: DSSequenceDescriptor, state_row) -> None:
+        """What the model kind keeps a sequence beyond its blocks
+        (``kind.seq_state``), on the descriptor and in its row."""
+        desc.state_row = state_row
+        self.state_table[desc.row] = state_row
+
+    def gather(self, descs, rows=None, out=None):
+        """→ ``(block_tables, seq_state)`` of a step whose row ``i`` is
+        ``descs[i]``: ``[rows, max_blocks_per_seq]`` and ``[rows,
+        seq_rows]`` int32 (None without a ``state_table``), one index of
+        the table each. ``rows`` past the sequences (default: none) are
+        padding's: null blocks, state 0. ``out``: an array of the block
+        tables' shape to lay them in (a wrapper's own), not a new one."""
+        index = [desc.row for desc in descs]
+        index += [self.max_tracked_sequences] * ((rows or 0) - len(index))   # padding's row
+        index = np.fromiter(index, np.intp, len(index))
+        # mode: every index is a row of the table, and numpy then writes ``out`` itself
+        tables = np.take(self.block_table, index, axis=0, out=out, mode="clip")
+        if self.state_table is None:
+            return tables, None
+        return tables, np.take(self.state_table, index, axis=0, mode="clip")
+
+    def _untrack(self, uid) -> DSSequenceDescriptor:
+        """Stop tracking ``uid``: its row is null again and the next new
+        sequence's. The descriptor keeps its blocks for whoever frees them."""
+        desc = self._seqs.pop(uid, None)
+        if desc is None:
+            raise KeyError(f"unknown sequence {uid}")
+        self.block_table[desc.row, :len(desc.blocks)] = NULL_BLOCK
+        if self.state_table is not None:
+            self.state_table[desc.row] = 0
+        self._free_rows.append(desc.row)
+        desc.row = -1
+        return desc
+
+    # ------------------------------------------------------------ the pool
+    def reserve(self, descs, need) -> None:
+        """Reserve ``need[i]`` more blocks for ``descs[i]``: one call to
+        the pool for the whole step, dealt out in the batch's order (the
+        ids each sequence gets are those of a call a sequence)."""
+        total = int(np.sum(need))
+        if total:
+            pool = self.prefix_cache if self.prefix_cache is not None else self.kv_cache
+            ids, at = pool.reserve(total), 0
+            for i in np.flatnonzero(need):
+                self.extend_blocks(descs[i], ids[at:at + need[i]])
+                at += need[i]
+
     def allocate_for(self, desc: DSSequenceDescriptor, new_tokens: int) -> None:
-        need = desc.blocks_needed(new_tokens)
-        if need > 0:
-            if self.prefix_cache is not None:
-                desc.extend_blocks(self.prefix_cache.reserve(need))
-            else:
-                desc.extend_blocks(self.kv_cache.reserve(need))
+        self.reserve([desc], [desc.blocks_needed(new_tokens)])
 
     def rewind_sequence(self, desc: DSSequenceDescriptor, n_tokens: int) -> None:
         """Drop the last ``n_tokens`` of ``desc``'s KV content: the
@@ -99,15 +192,10 @@ class DSStateManager:
         so a trailing trim can never touch the trie's blocks."""
         needed = -(-desc.seen_tokens // self.kv_cache.block_size)
         needed = max(needed, desc.shared_blocks)
-        extra = desc.blocks[needed:]
-        if extra:
-            del desc.blocks[needed:]
-            self.kv_cache.free(extra)
+        self.kv_cache.free(self.trim_blocks(desc, needed))
 
     def flush_sequence(self, uid) -> None:
-        desc = self._seqs.pop(uid, None)
-        if desc is None:
-            raise KeyError(f"unknown sequence {uid}")
+        desc = self._untrack(uid)
         if self.prefix_cache is not None:
             self.prefix_cache.release(uid, desc)
         else:
@@ -116,7 +204,4 @@ class DSStateManager:
     def drop_sequence(self, uid) -> DSSequenceDescriptor:
         """Stop tracking ``uid`` WITHOUT freeing or caching its blocks —
         the suspend path, where ownership moves to the host handle."""
-        desc = self._seqs.pop(uid, None)
-        if desc is None:
-            raise KeyError(f"unknown sequence {uid}")
-        return desc
+        return self._untrack(uid)

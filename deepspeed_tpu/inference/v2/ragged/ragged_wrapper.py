@@ -27,24 +27,44 @@ class RaggedBatchWrapper:
         # ``seq_rows`` int32 values a sequence: ``desc.state_row``) packs them
         # too; 0 for every other kind, whose vector is unchanged
         self.seq_rows = int(seq_rows)
-        self.clear()
-
-    def clear(self):
-        self.token_ids = np.zeros(self.max_tokens, np.int32)
+        # The batch's arrays live as long as the wrapper, and ``clear`` resets in
+        # place what the last batch wrote. Not for the allocations' sake: numpy
+        # lets go of the interpreter lock around every allocation of a KB or
+        # more, every copy or fill of more than 500 elements and every fancy
+        # index, and in a serving process the clients the last step woke are
+        # all waiting for that lock - each such call in the pump's pack cost
+        # 30-60 us on the chip's host, where it costs 1-2 alone (PERF.md, PR 47).
+        # So a batch is laid with slices of what is live, and few calls.
+        self.token_ids = np.zeros(max_tokens, np.int32)
         # pad tokens live in the extra pad slot (row max_seqs)
-        self.token_seq = np.full(self.max_tokens, self.max_seqs, np.int32)
-        self.token_pos = np.zeros(self.max_tokens, np.int32)
-        self.block_tables = np.full((self.max_seqs + 1, self.max_blocks), NULL_BLOCK, np.int32)
-        self.last_index = np.zeros(self.max_seqs, np.int32)
-        self.seq_valid = np.zeros(self.max_seqs, bool)
+        self.token_seq = np.full(max_tokens, max_seqs, np.int32)
+        self.token_pos = np.zeros(max_tokens, np.int32)
+        self.block_tables = np.full((max_seqs + 1, max_blocks_per_seq), NULL_BLOCK, np.int32)
+        self.last_index = np.zeros(max_seqs, np.int32)
+        self.seq_valid = np.zeros(max_seqs, bool)
         if self.lora:
             # pad row (max_seqs) stays 0 = the base slot
-            self.seq_adapters = np.zeros(self.max_seqs + 1, np.int32)
+            self.seq_adapters = np.zeros(max_seqs + 1, np.int32)
         if self.seq_rows:
             # pad row (and every row without a sequence) stays 0: padding's slot
-            self.seq_state = np.zeros((self.max_seqs + 1, self.seq_rows), np.int32)
+            self.seq_state = np.zeros((max_seqs + 1, self.seq_rows), np.int32)
         self._cursor = 0
         self._order = []  # slots in insertion order
+
+    def clear(self):
+        tokens, rows = slice(0, self._cursor), slice(0, max(self._order, default=-1) + 1)
+        self.token_ids[tokens] = 0
+        self.token_seq[tokens] = self.max_seqs
+        self.token_pos[tokens] = 0
+        self.block_tables[rows] = NULL_BLOCK
+        self.last_index[rows] = 0
+        self.seq_valid[rows] = False
+        if self.lora:
+            self.seq_adapters[rows] = 0
+        if self.seq_rows:
+            self.seq_state[rows] = 0
+        self._cursor = 0
+        self._order = []
 
     @property
     def current_tokens(self):
@@ -54,34 +74,61 @@ class RaggedBatchWrapper:
     def current_sequences(self):
         return len(self._order)
 
+    def insert_batch(self, first, seen, lens, tokens, block_rows=None, adapters=None,
+                     seq_state=None):
+        """Append a step's sequences at once: sequence ``i`` takes batch
+        row ``first + i``, has ``seen[i]`` tokens in the KV cache already
+        (its chunk's positions continue from there) and brings
+        ``lens[i]`` new tokens; ``tokens``: every sequence's new tokens,
+        one after the other. ``block_rows [n, max_blocks]``: each
+        sequence's block ids padded with the null block, as the state
+        manager's table keeps them; None where the caller has laid them
+        in ``block_tables[first:first + n]`` itself (``DSStateManager.gather``
+        with ``out``). ``adapters [n]`` / ``seq_state [n, seq_rows]``: what
+        a wrapper with ``lora`` / ``seq_rows`` packs besides. Numpy over
+        the whole batch: nothing here runs once a sequence."""
+        lens = np.asarray(lens, np.int64)
+        n, total = len(lens), len(tokens)
+        if self._cursor + total > self.max_tokens:
+            raise ValueError(f"ragged batch overflow: {self._cursor}+{total} > {self.max_tokens}")
+        if first + n > self.max_seqs:
+            raise ValueError(f"slot {first + n - 1} out of range")
+        ends = self._cursor + np.cumsum(lens)
+        sl, rows = slice(self._cursor, self._cursor + total), slice(first, first + n)
+        self.token_ids[sl] = tokens
+        self.token_seq[sl] = np.repeat(np.arange(first, first + n), lens)
+        # a token's position: its sequence's seen tokens + its place in the chunk
+        self.token_pos[sl] = np.arange(self._cursor, self._cursor + total) \
+            - np.repeat(ends - lens - np.asarray(seen), lens)
+        if block_rows is not None:
+            self.block_tables[rows] = block_rows
+        self.last_index[rows] = ends - 1
+        self.seq_valid[rows] = True
+        if self.lora:
+            self.seq_adapters[rows] = adapters
+        if self.seq_rows:
+            self.seq_state[rows] = seq_state
+        self._cursor += total
+        self._order.extend(range(first, first + n))
+
     def insert_sequence(self, desc, tokens):
         """Append ``tokens`` (this step's chunk) for ``desc``; positions
-        continue from the tokens already in the KV cache."""
-        n = len(tokens)
-        if self._cursor + n > self.max_tokens:
-            raise ValueError(f"ragged batch overflow: {self._cursor}+{n} > {self.max_tokens}")
-        if desc.slot >= self.max_seqs:
-            raise ValueError(f"slot {desc.slot} out of range")
-        if len(desc.blocks) > self.max_blocks:
-            raise ValueError(f"sequence {desc.uid} owns {len(desc.blocks)} blocks > "
-                             f"max_blocks_per_seq={self.max_blocks} (context overflow)")
-        sl = slice(self._cursor, self._cursor + n)
-        self.token_ids[sl] = np.asarray(tokens, np.int32)
-        self.token_seq[sl] = desc.slot
-        self.token_pos[sl] = desc.seen_tokens + np.arange(n, dtype=np.int32)
+        continue from the tokens already in the KV cache. A batch of one,
+        its block row made here from ``desc.blocks``."""
         blocks = desc.blocks
-        self.block_tables[desc.slot, :len(blocks)] = blocks
-        self.last_index[desc.slot] = self._cursor + n - 1
-        self.seq_valid[desc.slot] = True
-        if self.lora:
-            self.seq_adapters[desc.slot] = getattr(desc, "adapter_slot", 0)
-        if self.seq_rows:
-            self.seq_state[desc.slot] = desc.state_row
-        self._cursor += n
-        self._order.append(desc.slot)
+        if len(blocks) > self.max_blocks:
+            raise ValueError(f"sequence {desc.uid} owns {len(blocks)} blocks > "
+                             f"max_blocks_per_seq={self.max_blocks} (context overflow)")
+        row = np.full((1, self.max_blocks), NULL_BLOCK, np.int32)
+        row[0, :len(blocks)] = blocks
+        self.insert_batch(desc.slot, [desc.seen_tokens], [len(tokens)],
+                          np.asarray(tokens, np.int32), row,
+                          adapters=[getattr(desc, "adapter_slot", 0)],
+                          seq_state=[desc.state_row] if self.seq_rows else None)
 
     def finalize(self):
-        """→ dict of numpy arrays for the device step."""
+        """→ dict of numpy arrays for the device step: the wrapper's own,
+        good until its next ``clear``."""
         return {
             "token_ids": self.token_ids,
             "token_seq": self.token_seq,

@@ -117,7 +117,12 @@ class DSSequenceDescriptor:
         # what a model kind with state beyond the block table keeps a sequence
         # (``kind.seq_state``: its slot of the slot pool first); None for every other kind
         self.state_row = None
-        self.blocks = []  # owned KV block ids, in order
+        # owned KV block ids, in order. The state manager keeps them a second time,
+        # padded with the null block, in row ``row`` of its table, from which a step's
+        # block tables are gathered: only ``DSStateManager.extend_blocks`` /
+        # ``trim_blocks`` change this list, and they write the row with it
+        self.blocks = []
+        self.row = -1  # the manager's table row, held from creation to flush
         self.in_flight_tokens = 0
         # ---- prefix-cache bookkeeping (zero/empty when caching is off) ----
         self.cached_tokens = 0   # leading tokens whose KV came from the cache

@@ -90,6 +90,7 @@ CPU_MARKED = frozenset(PREFIX + name for name in (
 
 STEP_FIELDS = ("seq", "engine", "kind", "program", "k", "n_seqs", "n_tokens", "n_rows",
                "n_prompt_tokens", "n_ctx_tokens", "n_chunk_rows", "n_chunk_tiles",
+               "n_table_rows_written",
                "n_layers_prefetched", "counts",
                "state_step", "caused_by", "uids",
                "start_ns", "end_ns", "thread", "gc_ns", "gc_passes", "compile_ns", "compiles",
@@ -255,6 +256,9 @@ class Recorder:
         # of n_rows, the rows the paged kernel attended through a query tile, and the tiles
         # (ops/pallas/paged_attention.chunk_counts of the batch the engine packed)
         rec.n_chunk_rows = rec.n_chunk_tiles = 0
+        # a serving step: the rows of the state manager's block table written for it - new
+        # sequences and those whose blocks changed (ragged_manager); the rest were gathered
+        rec.n_table_rows_written = 0
         # a train step only: the layer gathers its program issues whole, ahead of the
         # arithmetic that reads them (runtime/zero/overlap.py), known when it is built
         rec.n_layers_prefetched = 0
