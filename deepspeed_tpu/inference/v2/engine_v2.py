@@ -916,8 +916,9 @@ class InferenceEngineV2:
     @property
     def state_step_impls(self):
         """``{token count: implementation name}`` of the state step - Mamba-2's
-        (``pallas_ssm_state`` / ``xla``: ``ops/pallas/ssm_state``) or Mamba-1's
-        (``pallas_selective_scan`` / ``xla``: ``ops/pallas/selective_scan``) -
+        (``pallas_ssm_state`` / ``xla``: ``ops/pallas/ssm_state``), Mamba-1's
+        (``pallas_selective_scan`` / ``xla``: ``ops/pallas/selective_scan``) or
+        the Kimi delta rule's (``pallas_kda`` / ``xla``: ``ops/pallas/kda``) -
         for every program traced so far; empty for a model kind without one. A
         step record's ``state_step`` says the same of the program it ran."""
         return dict(self._attention.state_step)

@@ -53,10 +53,11 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import (GPTConfig, JambaConfig, Lfm2MoeConfig, LlamaConfig,
                                   LongcatFlashConfig, MiniCPMSalaConfig, MoonlightConfig,
-                                  NemotronHConfig)
+                                  NemotronHConfig, SolarOpen2Config)
 from deepspeed_tpu.models.lfm2 import TOPK_EPS
 from deepspeed_tpu.models.llama import rope_frequencies, rope_scaling_of
 from deepspeed_tpu.models.nemotron_h import relu2
+from deepspeed_tpu.models.solar_open2 import L2_EPS
 from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn, fused_gmm_enabled
 
 
@@ -1490,17 +1491,23 @@ def _mamba_mixer(ctx, p, layer, x, ssm, conv):
 
 def _plain_gqa_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
     """A position-free attention mixer on the normalised stream
-    (:class:`NemotronHKind`'s ``*`` layers, :class:`JambaKind`'s attention
-    layers: the state-space layers carry the order): grouped-query
-    attention over the paged pool's layer ``layer``, queries and keys as
-    projected, no bias. → (y, kc, vc)."""
+    (:class:`NemotronHKind`'s ``*`` layers, :class:`JambaKind`'s and
+    :class:`SolarOpen2Kind`'s attention layers: the recurrent layers carry
+    the order): grouped-query attention over the paged pool's layer
+    ``layer``, queries and keys as projected, no bias; where the layer has a
+    ``gate_proj`` (Solar Open 2's ``use_gqa_gate``), its output times
+    ``sigmoid(x W_gate)``, element-wise, before ``W_o``. → (y, kc, vc)."""
     T = x.shape[0]
     Hq, Hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     q = _proj(x, p["q_proj"]).reshape(T, Hq, d)
     k = _proj(x, p["k_proj"]).reshape(T, Hkv, d)
     v = _proj(x, p["v_proj"]).reshape(T, Hkv, d)
     out, kc, vc = _paged_attend(q, k, v, kc, vc, layer, batch, d, impl=attn_impl)
-    return _proj(out.reshape(T, Hq * d), p["o_proj"]), kc, vc
+    out = out.reshape(T, Hq * d)
+    if "gate_proj" in p:
+        gate = jax.nn.sigmoid(_proj(x, p["gate_proj"]).astype(jnp.float32))
+        out = (out.astype(jnp.float32) * gate).astype(x.dtype)
+    return _proj(out, p["o_proj"]), kc, vc
 
 
 def _nemotron_moe(cfg, real, p, experts, layer, x):
@@ -1814,9 +1821,195 @@ def _jamba_mamba(ctx, p, layer, x, ssm, conv):
     return _proj(y.astype(x.dtype), p["out_proj"]), ssm, conv
 
 
+class SolarOpen2Kind(ModelKind):
+    """Solar Open 2 (``models/solar_open2.py``): **every layer a mixer and a
+    routed feed-forward** - the mixer a Kimi-delta-attention (KDA) layer or,
+    once a period of four, a gated position-free grouped-query attention
+    (:func:`_plain_gqa_attention` with its ``gate_proj``); the feed-forward
+    one share of an expert-parallel deployment behind a sigmoid router,
+    beside a shared expert - and state of two kinds side by side.
+
+    - The attention layers keep keys and values in the engine's two paged
+      pools, ``[Lg, NB, bs, Hkv * d]``; the KDA layers hold nothing there.
+    - ``extra_state``'s ``kda`` ``[Lk, slots + 1, H, d, d]`` float32 and
+      ``conv`` ``[Lk, slots + 1, K - 1, 3 I]``: a KDA layer's state a
+      sequence - a ``d x d`` matrix a head, key rows and value columns
+      (4.19 MB a layer at 64 x 128 x 128: Nemotron-H's is 4.25),
+      and the last ``K - 1`` rows of ``[W_q | W_k | W_v] a`` before the
+      three convolutions' activation, side by side
+      (:func:`_conv_with_tail`) - the same at token 10 and at token
+      1,000,000; slots as :class:`SalaKind`'s.
+
+    The recurrence's transition is **not diagonal** - ``(I - beta k k^T)
+    Diag(alpha)``: a token decays every key row by a factor of its own,
+    then rotates the state toward its key before it writes - so neither a
+    decay mask (:func:`_packed_rows`) nor an element-wise scan expresses a
+    chunk: every row of a sequence passes through its slot's state in order
+    (``ops/pallas/kda.kda_delta_rule``: the pool aliased in and out, a slot
+    fetched once, its sequence's rows run through it in VMEM, written back
+    once; ``xla_kda_delta_rule`` where the kernel does not run;
+    ``AttentionChoice.state_step`` says which a program got).
+
+    :meth:`stack` runs ``cfg.segments`` through :func:`_run_segments`. The
+    routed experts ride every step whole, one table of ``L x held`` groups.
+    Each step counts, over its tokens that are not padding:
+    ``EXPERT_COUNTS``, the rows through the KDA layers, the (sequence, KDA
+    layer)s whose state it read and wrote (:class:`NemotronHKind`'s and
+    :class:`JambaKind`'s name), and ``n_scan_runs``, those of them with more
+    than one row in the step."""
+    name = "solar_open2"
+    config = SolarOpen2Config
+    state_kind = "kv+slots"
+    step_counts = EXPERT_COUNTS + ("n_kda_rows", "n_state_slots", "n_scan_runs")
+    seq_rows = 1            # (slot,)
+    slot_state = ("kda", "conv")
+    experts_at = "moe"
+
+    @staticmethod
+    def state_layers(cfg):
+        return max(1, cfg.count("g"))
+
+    @staticmethod
+    def extra_state(cfg, num_blocks, slots, dtype):
+        Lk, H, d = cfg.count("k"), cfg.kda_heads, cfg.kda_head_dim
+        return {"kda": jnp.zeros((Lk, slots + 1, H, d, d), jnp.float32),
+                "conv": jnp.zeros((Lk, slots + 1, cfg.kda_conv - 1, 3 * cfg.kda_inner), dtype)}
+
+    @staticmethod
+    def _counters(letter):
+        """The stacks a layer of ``cfg.letters``' letter draws from."""
+        return ("kda" if letter == "k" else "gqa", "moe")
+
+    @staticmethod
+    def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+        SolarOpen2Kind.base_only(mesh, lora)
+        model = params["model"]
+        ctx = _SlotStep(cfg, batch, extra["conv"].shape[1], attn_impl)
+        stacks = {"kda": model.get("kda_layers"), "gqa": model.get("gqa_layers"),
+                  "moe": model[SolarOpen2Kind.experts_at]}
+        experts = stacks["moe"]["experts"]
+
+        def layer(letter, at, carry):
+            h, kc, vc, kda, conv, picks = carry
+            op, _ = SolarOpen2Kind._counters(letter)
+            lp = _layer_of(stacks[op], at[op])
+            x = _rms(h, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
+            if op == "kda":
+                with jax.named_scope("ds.solar.kda"):
+                    y, kda, conv = _solar_kda(ctx, lp, at[op], x, kda, conv)
+            else:
+                with jax.named_scope("ds.solar.attn"):
+                    y, kc, vc = _plain_gqa_attention(cfg, lp, at[op], x, kc, vc, batch,
+                                                     attn_impl)
+            h = h + y
+            fp = _layer_of(stacks["moe"], at["moe"])
+            x = _rms(h, fp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
+            y, n = _solar_moe(cfg, ctx.real, fp, experts, at["moe"], x)
+            return h + y, kc, vc, kda, conv, picks + n
+
+        carry = (h, kc, vc, extra["kda"], extra["conv"], jnp.zeros((3,), jnp.int32))
+        (h, kc, vc, kda, conv, picks), done = _run_segments(
+            cfg.segments, SolarOpen2Kind._counters, layer, carry)
+        Lk = done.get("kda", 0)
+        counts = jnp.concatenate([picks, jnp.stack([
+            Lk * jnp.sum(ctx.real.astype(jnp.int32)),
+            Lk * jnp.sum(ctx.here.astype(jnp.int32)),
+            Lk * jnp.sum((ctx.here & (ctx.length > 1)).astype(jnp.int32))])])
+        return h, kc, vc, {"kda": kda, "conv": conv}, counts.astype(jnp.int32)[None]
+
+    @staticmethod
+    def router(cfg, fp):
+        """Sigmoid scores, the picks' weights over their sum; this rank's share."""
+        gate = fp["gate"]
+        return Router(gate["weight"], gate["e_score_correction_bias"], cfg.num_experts_per_tok,
+                      cfg.routed_scaling_factor,
+                      share=ExpertShare(cfg.first_expert_held, cfg.held, cfg.n_routed_experts))
+
+    @staticmethod
+    def kda_layer(params, cfg, layer, x, kda, conv, batch):
+        """KDA layer ``layer``'s mixer (its index among the KDA layers) alone
+        - the same convolutions, delta rule and reads and writes of the slot
+        pools: x [T, D] the normalised stream → (y [T, D], kda, conv)."""
+        lp = _layer_of(params["model"]["kda_layers"], layer)
+        return _solar_kda(_SlotStep(cfg, batch, conv.shape[1]), lp, layer, x, kda, conv)
+
+    @staticmethod
+    def attention_layer(params, cfg, layer, x, kc, vc, batch, attn_impl=None):
+        """Attention layer ``layer``'s mixer (its index among the attention
+        layers) alone - the same writes into the pools, paged attention and
+        gate: x [T, D] the normalised stream → (y [T, D], kc, vc)."""
+        lp = _layer_of(params["model"]["gqa_layers"], layer)
+        return _plain_gqa_attention(cfg, lp, layer, x, kc, vc, batch, attn_impl)
+
+    @staticmethod
+    def expert_layer(params, cfg, layer, x):
+        """Routed feed-forward ``layer`` (the layer's position in the stack)
+        alone (:func:`_layer_of`): x [T, D] the normalised stream, every row
+        a token → y."""
+        moe = params["model"]["moe"]
+        return _solar_moe(cfg, jnp.ones(x.shape[0], bool), _layer_of(moe, layer),
+                          moe["experts"], layer, x)[0]
+
+
+def _solar_moe(cfg, real, fp, experts, layer, x):
+    """One routed feed-forward on the normalised stream, as this share gives
+    it, and its ``EXPERT_COUNTS``; ``real`` [T]: the rows that are not
+    padding. The held picks through the grouped matmul; the shared expert
+    on every row."""
+    y, counts = _routed_experts(x, SolarOpen2Kind.router(cfg, fp), experts, layer, real)
+    with jax.named_scope("ds.moe_shared"):
+        return y + _swiglu(x, fp["shared_experts"]), counts
+
+
+def _solar_kda(ctx, p, layer, x, kda, conv):
+    """One Kimi-delta-attention mixer over the flat ragged batch, on the
+    normalised stream x [T, D] → (y [T, D], kda, conv). The three
+    convolutions' tail is the sequence's slot (:func:`_conv_with_tail`: one
+    call over ``q | k | v`` side by side, no bias, SiLU after); the decays
+    and ``beta`` come off the stream through their projections with float32
+    results (the decay's low-rank factor stays float32 into its second
+    matrix, at the highest precision: [T, 128] x [128, I]); the recurrence
+    is the delta rule (:class:`SolarOpen2Kind`'s docstring). Float32 throughout what the recurrence reads and does: the
+    L2 norms, ``softplus``, ``beta``, the state, ``S^T q`` and the head
+    norm."""
+    cfg = ctx.cfg
+    T = x.shape[0]
+    H, d, I = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_inner
+    f32 = jnp.float32
+
+    def wide(low, p):   # a projection whose result is float32 (a float32 ``low``: exactly)
+        return jnp.dot(low, p["kernel"].astype(low.dtype), preferred_element_type=f32,
+                       precision=jax.lax.Precision.HIGHEST if low.dtype == f32 else None)
+
+    acc, conv = _conv_with_tail(_proj(x, p["qkv_proj"]), p["conv_kernel"], None, conv, layer, ctx)
+    act = jax.nn.silu(acc)                                               # [T, 3 I] float32
+    q, k, v = (act[:, i * I:(i + 1) * I].reshape(T, H, d) for i in range(3))
+    q = q * (jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + L2_EPS) / math.sqrt(d))
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + L2_EPS)
+    # the decay compounds over a sequence: its two factors keep float32 between them
+    step = jax.nn.softplus(wide(wide(x, p["f_a_proj"]), p["f_b_proj"])
+                           + p["dt_bias"].astype(f32))
+    log_alpha = -jnp.exp(p["A_log"].astype(f32))[:, None] * step.reshape(T, H, d)
+    beta = 2.0 * jax.nn.sigmoid(wide(x, p["b_proj"]))                    # [T, H], in (0, 2)
+
+    from deepspeed_tpu.ops.pallas import kda as rule
+    impl = rule.delta_rule_impl(kda.shape, T, ctx.n_rows)
+    if ctx.choice is not None:
+        ctx.choice.state_step[T] = impl
+    run = rule.kda_delta_rule if impl == rule.KERNEL else rule.xla_kda_delta_rule
+    with jax.named_scope("ds.solar.kda_state"):
+        kda, o = run(kda, layer, ctx.seq, ctx.slot, ctx.first_row,
+                     jnp.where(ctx.here, ctx.length, 0), ctx.fresh, q, k, v, log_alpha, beta)
+    gate = jax.nn.sigmoid(wide(_proj(x, p["g_a_proj"]), p["g_b_proj"])
+                          + p["g_b_proj"]["bias"].astype(f32))
+    o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_norm_eps)
+    o = (o * p["o_norm"]["scale"].astype(f32)).reshape(T, I) * gate
+    return _proj(o.astype(x.dtype), p["o_proj"]), kda, conv
+
+
 # Every kind, a kind whose config class derives another's before that one's.
-KINDS = (JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind, MoonlightKind, GPTKind,
-         LlamaKind)
+KINDS = (SolarOpen2Kind, JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind, MoonlightKind,
+         GPTKind, LlamaKind)
 
 
 def kind_of(cfg):

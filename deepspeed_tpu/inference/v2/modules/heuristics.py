@@ -70,7 +70,10 @@ class AttentionChoice:
     ``ops/pallas/ssm_state.state_step_impl``) or Mamba-1's
     (``pallas_selective_scan``, the kernel that runs each sequence's rows
     through its slot in one visit, or ``xla``, a ``lax.scan`` over the rows:
-    ``ops/pallas/selective_scan.scan_impl``); nothing pins it.
+    ``ops/pallas/selective_scan.scan_impl``) or the Kimi delta rule's
+    (``pallas_kda``, the same one visit of a slot for a transition that
+    rotates the state as well as decaying it, or ``xla``:
+    ``ops/pallas/kda.delta_rule_impl``); nothing pins it.
     ``tiled``: the token counts of the programs whose paged kernel
     ``model_runner._paged_attend`` gave the step's query tiles."""
 

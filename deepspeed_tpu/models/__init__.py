@@ -19,6 +19,9 @@ from deepspeed_tpu.models.jamba import (JAMBA_CONFIGS, JambaConfig, JambaForCaus
 from deepspeed_tpu.models.nemotron_h import (NEMOTRON_H_CONFIGS, NemotronHConfig,
                                              NemotronHForCausalLM,
                                              build_nemotron_h)  # noqa: F401
+from deepspeed_tpu.models.solar_open2 import (SOLAR_OPEN2_CONFIGS, SolarOpen2Config,
+                                              SolarOpen2ForCausalLM,
+                                              build_solar_open2)  # noqa: F401
 
 # The causal-LM families a preset name can build, in the order names are looked up
 # (the v2 serving engine takes any of them: inference/v2/model_runner.kind_of).
@@ -26,7 +29,7 @@ MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
                   (MOONLIGHT_CONFIGS, build_moonlight), (LONGCAT_CONFIGS, build_longcat),
                   (MINICPM_SALA_CONFIGS, build_minicpm_sala),
                   (NEMOTRON_H_CONFIGS, build_nemotron_h), (LFM2_CONFIGS, build_lfm2),
-                  (JAMBA_CONFIGS, build_jamba))
+                  (JAMBA_CONFIGS, build_jamba), (SOLAR_OPEN2_CONFIGS, build_solar_open2))
 
 
 def build_model(preset, **overrides):

@@ -211,26 +211,38 @@ def _kernel(meta_ref, row_ref, slot_ref, start_ref, end_ref, fresh_ref,
                 store(n - back).wait()
 
 
+def live_runs(seq, first_row, length, n_tokens):
+    """What a kernel that runs a step's rows through their slots in row order
+    prefetches of the step (this one and ``kda.kda_delta_rule``): → (``order``
+    [S]: the sequence rows, the live ones first, in the order of their rows;
+    the number of live ones; one past the last live row; ``mine`` [T]: whether
+    a row lies in its sequence's run; ``row_seq`` [T]: a row's sequence's place
+    in ``order``, -1 where it is no sequence's; ``start`` [S]: the first row of
+    each of ``order``)."""
+    i32 = jnp.int32
+    S = first_row.shape[0]
+    here = length > 0
+    order = jnp.argsort(jnp.where(here, first_row, n_tokens), stable=True).astype(i32)
+    rank = jnp.zeros((S,), i32).at[order].set(jnp.arange(S, dtype=i32))
+    n_live = jnp.sum(here.astype(i32))
+    n_rows = jnp.max(jnp.where(here, first_row + length, 0)).astype(i32)
+    at = jnp.arange(n_tokens, dtype=i32)
+    mine = (at >= first_row[seq]) & (at < first_row[seq] + length[seq])
+    row_seq = jnp.where(mine, rank[seq], -1).astype(i32)
+    return order, n_live, n_rows, mine, row_seq, first_row[order].astype(i32)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _scan_call(pool, layer, seq, slot, first_row, length, fresh, x, delta, b, c, a, interpret):
     """The kernel over the live rows (jitted so that a cell's programs
     share one trace of it)."""
     N, C = pool.shape[2:]
-    T, S = x.shape[0], slot.shape[0]
+    T = x.shape[0]
     f32, i32 = jnp.float32, jnp.int32
     rows = ROWS if T % ROWS == 0 else T
     lanes = LANES if C % LANES == 0 else C         # b and c are laid this wide
     piece = next((w for w in (PIECE, PIECE // 2) if C % w == 0), lanes)
-    here = length > 0
-    # the live sequences in the order of their rows
-    order = jnp.argsort(jnp.where(here, first_row, T), stable=True).astype(i32)
-    rank = jnp.zeros((S,), i32).at[order].set(jnp.arange(S, dtype=i32))
-    n_live = jnp.sum(here.astype(i32))
-    n_rows = jnp.max(jnp.where(here, first_row + length, 0)).astype(i32)
-    at = jnp.arange(T, dtype=i32)
-    mine = (at >= first_row[seq]) & (at < first_row[seq] + length[seq])
-    row_seq = jnp.where(mine, rank[seq], -1).astype(i32)
-    start = first_row[order].astype(i32)
+    order, n_live, n_rows, mine, row_seq, start = live_runs(seq, first_row, length, T)
 
     def columns_of(v):  # [T, N] → [T, N, lanes]: a column a sublane, the same along the lanes
         return jnp.broadcast_to(v.astype(f32)[:, :, None], (T, N, lanes))

@@ -265,7 +265,7 @@ class Recorder:
         # what the program itself counted on the device, by name, fetched with its result
         # (model_runner: kind.step_counts); None where the model kind counts nothing
         rec.counts = None
-        rec.state_step = None   # what serves the program's Mamba-2 state step, if it has one
+        rec.state_step = None   # what serves the program's state step, if its kind has one
         rec.uids = uids
         rec.phases, rec.cpu_marks, rec.keep, rec.end_ns = [], [], True, None
         rec.thread = threading.get_ident()

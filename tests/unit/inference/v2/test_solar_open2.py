@@ -1,0 +1,620 @@
+"""Solar Open 2 through the v2 ragged engine at the debug preset: the served
+logits against the plain float32 reference, and the pieces alone.
+
+The served path keeps the attention layers' keys and values in paged pools
+and every KDA layer's state - a ``d x d`` matrix a head, float32 - and
+convolution tails in a slot a sequence; a step's rows go through
+``ops/pallas/kda`` (the kernel, interpreted, under ``DS_PALLAS=1``; its XLA
+fallback otherwise), each sequence's run from its own slot; the routed
+experts are one share behind the whole router. The reference
+(``models/solar_open2.reference_logits``) runs whole sequences, the
+convolutions as shifted products and the delta rule a token at a time from a
+zero start, every held expert on every token. They share no line.
+
+Tolerances: float32 engines on the CPU differ from the reference by the
+order of float32 additions (relative L2 errors of 2-4e-6 were read when this
+was written); ``TOL`` = 2e-5. The bfloat16 engine's is written where it is
+used.
+"""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
+                                        KVTierConfig, PrefixCacheConfig,
+                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
+from deepspeed_tpu.inference.v2 import model_runner
+from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
+from deepspeed_tpu.models import SOLAR_OPEN2_CONFIGS, build_model
+from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config, layer_params, param_shapes,
+                                              reference_attention, reference_kda,
+                                              reference_logits, reference_moe)
+from deepspeed_tpu.utils import tracing
+
+TOL = 2e-5
+DEBUG = SOLAR_OPEN2_CONFIGS["solar-open2-debug"]
+BLOCK = 16
+KIND = model_runner.SolarOpen2Kind
+LK = DEBUG.count("k")
+COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_kda_rows", "n_state_slots",
+          "n_scan_runs")
+
+
+def rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def engine_config(**over):
+    return RaggedInferenceEngineConfig(
+        kv_block_size=BLOCK, num_kv_blocks=96,
+        state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
+                                           max_ragged_sequence_count=4,
+                                           max_tracked_sequences=4, max_context=192), **over)
+
+
+@pytest.fixture(scope="module")
+def model():
+    return build_model("solar-open2-debug")
+
+
+@pytest.fixture(scope="module")
+def engine(model):
+    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
+                             rng=jax.random.PRNGKey(5))
+
+
+@pytest.fixture(scope="module")
+def kernel_engine(model):
+    """An engine whose programs are first run under ``DS_PALLAS=1``
+    (``state_step``'s second case), so that they hold the delta rule's
+    kernel, interpreted."""
+    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
+                             rng=jax.random.PRNGKey(5))
+
+
+@pytest.fixture(params=["xla", "pallas_kda"])
+def state_step(request, monkeypatch):
+    """What serves the delta rule in the test: the fallback, or the kernel
+    (``DS_PALLAS=1`` forces the kernel paths, interpreted off the chip)."""
+    if request.param != "xla":
+        monkeypatch.setenv("DS_PALLAS", "1")
+    return request.param
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(3).integers(0, 256, (4, 192), dtype=np.int32)
+
+
+def reference(engine, seq):
+    return np.asarray(reference_logits(engine.params, jnp.asarray(seq)[None],
+                                       engine.model_config))[0]
+
+
+def serve(engine, plan):
+    """``plan``: steps of ``[(uid, tokens)]`` → {uid: [the logits row of
+    each of its steps]}; a uid's first appearance tells the engine its
+    prompt, as the scheduler does."""
+    rows = {}
+    for step in plan:
+        for u, t in step:
+            if engine.state_manager.query(u) is None:
+                engine.prefix_match(u, t)
+        out = engine.put([u for u, _ in step], [t for _, t in step])
+        for (u, _), row in zip(step, out):
+            rows.setdefault(u, []).append(row)
+    return rows
+
+
+def count(cfg):
+    return sum(int(np.prod(s)) for s in jax.tree.leaves(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
+
+
+# ------------------------------------------------------------- the model file
+def test_the_presets_are_the_published_stack_its_share_and_a_small_one_of_its_pattern():
+    whole = SolarOpen2Config()
+    assert build_model("solar-open2-250b").config == whole
+    assert whole.letters == "gkkk" * 12 and whole.segments == (("gkkk", 12),)
+    assert [i for i, t in enumerate(whole.letters) if t == "g"] == list(range(0, 48, 4))
+    assert (whole.hidden_size, whole.head_dim, whole.num_attention_heads,
+            whole.num_key_value_heads, whole.kda_heads, whole.kda_head_dim, whole.kda_conv,
+            whole.n_routed_experts, whole.num_experts_per_tok, whole.moe_intermediate_size,
+            whole.vocab_size, whole.max_position_embeddings, whole.rms_norm_eps) == (
+                4096, 128, 64, 8, 64, 128, 4, 320, 8, 1280, 196608, 1048576, 1e-5)
+    # ISSUE 48's count: 36 x 154.7 M + 12 x 126.1 M + 48 x 5.033 B + 2 x 196,608 x 4096
+    assert count(whole) == 250288105216
+    # of which a token reads 8 of 320 experts a layer: the "250B-A15B" of the model's name
+    assert round((count(whole) - 48 * 312 * 3 * 4096 * 1280) / 1e9, 1) == 14.7
+    share = SOLAR_OPEN2_CONFIGS["solar-open2-ep8-4l"]
+    assert share.letters == "gkkk" and share.segments == (("g", 1), ("k", 3))
+    assert (share.held, share.first_expert_held, share.n_routed_experts) == (40, 0, 320)
+    assert round(count(share) / 1e9, 2) == 3.31                          # 6.62 GB in bfloat16
+    assert DEBUG.letters == "gkkkgkkk" and DEBUG.segments == (("gkkk", 2),)   # two whole periods
+    assert DEBUG.num_attention_heads // DEBUG.num_key_value_heads == 2
+    assert DEBUG.num_key_value_heads > 1 and DEBUG.kda_conv == 4
+    assert (DEBUG.n_routed_experts, DEBUG.num_experts_per_tok, DEBUG.n_shared_experts) == (16, 4, 1)
+    assert model_runner.kind_of(DEBUG) is KIND
+
+
+def test_the_shapes_are_the_catalog_rows():
+    every = param_shapes(SolarOpen2Config())
+    shapes = every["model"]
+    assert shapes["embed_tokens"] == (196608, 4096) and every["lm_head"]["kernel"] == (4096, 196608)
+    kda, gqa, moe = shapes["kda_layers"], shapes["gqa_layers"], shapes["moe"]
+    assert kda["qkv_proj"]["kernel"] == (36, 4096, 3 * 8192)
+    assert kda["conv_kernel"] == (36, 4, 3 * 8192)                     # three convolutions of 4
+    assert kda["f_a_proj"]["kernel"] == (36, 4096, 128)                # the two-factor form
+    assert kda["f_b_proj"]["kernel"] == (36, 128, 8192)
+    assert kda["g_a_proj"]["kernel"] == (36, 4096, 128)
+    assert kda["g_b_proj"] == {"kernel": (36, 128, 8192), "bias": (36, 8192)}
+    assert kda["b_proj"]["kernel"] == (36, 4096, 64) and kda["A_log"] == (36, 64)
+    assert kda["dt_bias"] == (36, 8192) and kda["o_norm"]["scale"] == (36, 128)
+    assert gqa["q_proj"]["kernel"] == gqa["gate_proj"]["kernel"] == (12, 4096, 8192)
+    assert gqa["k_proj"]["kernel"] == (12, 4096, 1024)
+    assert moe["gate"]["weight"] == (48, 4096, 320)
+    assert moe["experts"]["gate_proj"] == (48, 320, 4096, 1280)
+    assert moe["shared_experts"]["down_proj"]["kernel"] == (48, 1280, 4096)
+    held = param_shapes(SOLAR_OPEN2_CONFIGS["solar-open2-ep8-4l"])["model"]["moe"]
+    assert held["experts"]["up_proj"] == (4, 40, 4096, 1280)           # the share's 40
+    assert held["gate"]["weight"] == (4, 4096, 320)                    # behind the whole router
+
+
+@pytest.mark.parametrize("field,value", [
+    ("kda_use_full_proj", True), ("use_rope", True), ("use_gqa_gate", False),
+    ("kda_allow_neg_eigval", False), ("first_k_dense_replace", 1), ("n_shared_experts", 2),
+    ("norm_topk_prob", False), ("tie_word_embeddings", True), ("gqa_layers", (0, 9)),
+    ("num_key_value_heads", 3),
+    ("linear_attn_config", {"head_dim": 16, "num_heads": 4, "num_kv_heads": 2,
+                            "short_conv_kernel_size": 4})])
+def test_what_is_not_implemented_is_refused_by_name(field, value):
+    name = "num_kv_heads" if field == "linear_attn_config" else field
+    with pytest.raises(ValueError, match=name):
+        dataclasses.replace(DEBUG, **{field: value})
+
+
+def test_a_share_outside_the_router_is_refused():
+    with pytest.raises(ValueError, match="not among the 16 routed"):
+        dataclasses.replace(DEBUG, experts_held=8, first_expert_held=12)
+    with pytest.raises(ValueError, match="exceeds the router"):
+        dataclasses.replace(DEBUG, num_experts_per_tok=17)
+
+
+def test_the_flax_module_is_the_reference_and_the_seeded_recurrence_lives(model):
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 24), dtype=np.int32))
+    params = model.init(jax.random.PRNGKey(1), ids)["params"]
+    got = model.apply({"params": params}, ids)
+    assert got.shape == (2, 24, 256) and got.dtype == jnp.float32
+    assert np.array_equal(np.asarray(got), np.asarray(reference_logits(params, ids, DEBUG)))
+    # causal: a sequence's later tokens do not reach its earlier logits
+    again = model.apply({"params": params}, ids.at[:, 20:].set(0))
+    assert rel_err(again[:, :20], got[:, :20]) < 1e-6
+    kda = params["model"]["kda_layers"]
+    # A_log = log U(1, 16) a head; the step log-uniform in [1e-3, 1e-1] a channel
+    rate = np.exp(np.asarray(kda["A_log"]))
+    assert 1.0 <= rate.min() < 3.0 and 12.0 < rate.max() <= 16.0
+    step = np.asarray(jax.nn.softplus(kda["dt_bias"]))
+    assert 9e-4 < step.min() < 2e-3 and 0.05 < step.max() < 0.11
+    assert not np.asarray(params["model"]["moe"]["gate"]["e_score_correction_bias"]).any()
+    # neither dead nor exploding: a state after 150 tokens is of its increments' order
+    lp = jax.tree.map(lambda w: w[0], kda)
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 150, DEBUG.hidden_size))
+    _, state, _ = reference_kda(lp, x, DEBUG)
+    assert 1e-3 < float(jnp.abs(state).max()) < 1e2 and bool(jnp.isfinite(state).all())
+
+
+# ---------------------------------------------- the engine against the forward
+@pytest.mark.parametrize("prompt,steps,chunks", [
+    (20, 6, [20]), (75, 12, [32, 32, 11]), (100, 4, [7, 32, 32, 29]), (3, 8, [2, 1]),
+    (40, 3, [1, 1, 1, 31, 6]), (64, 64, [32, 32])])
+def test_prefill_in_chunks_then_decode_through_the_pools_and_the_slots(engine, tokens, prompt,
+                                                                       steps, chunks):
+    """Whole and in chunks (of 1 and 2 rows among them: shorter than the
+    convolutions' tail), then decode rows - 64 of them in the last case, so
+    that an error of the state would compound."""
+    seq = tokens[0][:prompt + steps]
+    plan, at = [], 0
+    for n in chunks:
+        plan.append([(7, seq[at:at + n])])
+        at += n
+    plan += [[(7, seq[prompt + j:prompt + j + 1])] for j in range(steps)]
+    engine.prefix_match(7, seq[:prompt])
+    rows = serve(engine, plan)[7]
+    engine.flush(7)
+    want = reference(engine, seq)
+    compared = [sum(chunks[:i + 1]) - 1 for i in range(len(chunks))] \
+        + [prompt + j for j in range(steps)]
+    assert max(rel_err(r, want[p]) for r, p in zip(rows, compared)) < TOL
+
+
+@pytest.mark.parametrize("cut", [1, 2, 3, 4, 5, 8, 9, 16, 17])
+def test_a_chunk_cut_at_every_offset_of_a_small_grid(request, state_step, tokens, cut):
+    """Around the convolutions' four taps and the kernel's blocks of 8 rows,
+    through the fallback and through the kernel."""
+    engine = request.getfixturevalue("engine" if state_step == "xla" else "kernel_engine")
+    seq = tokens[1][:20]
+    engine.prefix_match(9, seq[:19])
+    rows = serve(engine, [[(9, seq[:cut])], [(9, seq[cut:19])], [(9, seq[19:20])]])[9]
+    assert engine.last_step.state_step == state_step
+    engine.flush(9)
+    want = reference(engine, seq)
+    assert max(rel_err(r, want[p]) for r, p in zip(rows, (cut - 1, 18, 19))) < TOL
+
+
+def test_two_prompts_in_one_chunk_beside_decoding_sequences(request, state_step, tokens):
+    """One step holds a decode row, the end of one prompt and the start of
+    another: three runs of the delta rule, each from its own slot."""
+    engine = request.getfixturevalue("engine" if state_step == "xla" else "kernel_engine")
+    a, b, c = tokens[0][:60], tokens[1][:41], tokens[2][:30]
+    for uid, seq in ((1, a[:50]), (2, b[:40]), (3, c[:29])):
+        engine.prefix_match(uid, seq)
+    rows = serve(engine, [[(1, a[:32])], [(3, c[:29])],
+                          [(3, c[29:30]), (1, a[32:50]), (2, b[:13])]])
+    counts = engine.last_step.counts
+    assert tuple(counts) == COUNTS
+    assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_scan_runs"]) == (
+        32 * LK, 3 * LK, 2 * LK)
+    # 32 tokens x 4 picks x 8 layers, all 16 experts held: every pick a row of some group
+    assert counts["n_picks_held"] == 32 * 4 * 8 and counts["n_picks_zero"] == 0
+    more = serve(engine, [[(1, a[50:51]), (2, b[13:40])], [(1, a[51:52]), (2, b[40:41])]])
+    assert engine.last_step.counts["n_scan_runs"] == 0
+    assert engine.last_step.counts["n_state_slots"] == 2 * LK
+    for uid in (1, 2, 3):
+        engine.flush(uid)
+    wa, wb, wc = reference(engine, a), reference(engine, b), reference(engine, c)
+    got = [(rows[1][1], wa[49]), (more[1][0], wa[50]), (more[1][1], wa[51]),
+           (more[2][0], wb[39]), (more[2][1], wb[40]), (rows[3][0], wc[28]), (rows[3][1], wc[29])]
+    assert max(rel_err(g, w) for g, w in got) < TOL
+
+
+def test_decode_bursts_carry_every_state(engine, tokens):
+    seq = tokens[1][:80]
+    engine.prefix_match(50, seq)
+    for at in (0, 32, 64):
+        out = engine.put([50], [seq[at:at + 32][:80 - at]])
+    first = int(np.argmax(out[0]))
+    burst = [first] + [int(t) for t in engine.decode_burst([50], [first], 8)[:, 0]]
+    burst += [int(t) for t in engine.decode_burst([50], burst[-1:], 8)[:, 0]]
+    counts = engine.last_step.counts
+    assert counts["n_kda_rows"] == counts["n_state_slots"] == 8 * LK
+    assert counts["n_scan_runs"] == 0
+    engine.flush(50)
+    full = np.concatenate([seq, np.asarray(burst[:-1], np.int32)])
+    greedy = [int(t) for t in np.argmax(reference(engine, full)[79:], axis=-1)]
+    assert burst == greedy
+
+
+def test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_zero(
+        request, state_step, tokens):
+    engine = request.getfixturevalue("engine" if state_step == "xla" else "kernel_engine")
+    assert engine.state_kind == "kv+slots" and set(engine.state_extra) == {"kda", "conv"}
+    assert engine.slot_pool.free_slots == engine.slot_pool.slots == 4
+    serve(engine, [[(11, tokens[2][:30])]])
+    slot = engine.state_manager.query(11).state_row[0]
+    engine.flush(11)
+    assert np.abs(np.asarray(engine.state_extra["kda"][:, slot])).max() > 1e-4
+    assert np.abs(np.asarray(engine.state_extra["conv"][:, slot])).max() > 1e-4
+    seq = tokens[3][:32]
+    rows = serve(engine, [[(12, seq[:2])], [(12, seq[2:31])], [(12, seq[31:32])]])[12]
+    assert engine.state_manager.query(12).state_row[0] == slot           # the same slot
+    assert engine.last_step.state_step == state_step
+    assert set(engine.state_step_impls.values()) == {state_step}
+    engine.flush(12)
+    want = reference(engine, seq)
+    assert max(rel_err(r, want[p]) for r, p in zip(rows, (1, 30, 31))) < TOL
+    # a slot's bytes are both entries': what the gate and the start-up line count
+    per = sum(int(np.prod(engine.state_extra[k].shape[2:])) * engine.state_extra[k].dtype.itemsize
+              for k in KIND.slot_state)
+    assert engine.slot_pool.bytes_per_slot == LK * per
+    assert engine.state_extra["kda"].dtype == jnp.float32
+    assert engine.state_extra["kda"].shape == (LK, 5, 4, 16, 16)
+    assert engine.state_extra["conv"].shape == (LK, 5, 3, 3 * 64)       # three tails side by side
+    assert engine.kv_cache.k.shape[0] == DEBUG.count("g")
+
+
+def test_the_gate_on_slots_admits_no_more_sequences_than_slots(engine, tokens):
+    for uid in range(30, 34):
+        serve(engine, [[(uid, tokens[0][:5])]])
+    assert engine.slot_pool.free_slots == 0
+    with pytest.raises(Exception):
+        serve(engine, [[(34, tokens[0][:5])]])
+    for uid in range(30, 34):
+        engine.flush(uid)
+    assert engine.slot_pool.free_slots == 4
+
+
+def test_a_bfloat16_engine_keeps_its_state_in_float32_and_reads_close(model, tokens):
+    """The served types: bfloat16 weights, stream, keys, values and tails, a
+    float32 state. Against the float32 reference on the same (bfloat16)
+    weights the logits differ by bfloat16's rounding of the stream, 2**-8 a
+    value, gathered over eight layers of two sublayers at a hidden size of
+    64, and - where a router's margin is under that rounding - by a pick
+    (all 16 experts are held here and 4 are picked, so a flipped pick is a
+    quarter of a layer's routed part): median 0.033, largest 0.091 was read
+    over the 66 positions. The limits leave twice that and say what a
+    fault of the mechanism's size reads, not more: the pieces are held to
+    ``TOL`` alone, above and below, and their controls with them."""
+    served = InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.bfloat16,
+                               rng=jax.random.PRNGKey(5))
+    assert served.state_extra["kda"].dtype == jnp.float32
+    assert served.state_extra["conv"].dtype == jnp.bfloat16
+    seq = tokens[2][:124]
+    rows = serve(served, [[(5, seq[:32])], [(5, seq[32:60])]]
+                 + [[(5, seq[60 + j:61 + j])] for j in range(64)])[5]
+    served.flush(5)
+    want = np.asarray(reference_logits(served.params, jnp.asarray(seq)[None], DEBUG))[0]
+    errs = [rel_err(r, want[p]) for r, p in zip(rows, [31, 59] + list(range(60, 124)))]
+    assert np.median(errs) < 0.07 and max(errs) < 0.2, (np.median(errs), max(errs))
+
+
+# --------------------------------------------------------- the pieces alone
+def _batch(rows, n_rows, slots):
+    """``rows``: [(sequence row, first position, length)] in batch order."""
+    seq = np.concatenate([np.full(n, s, np.int32) for s, _, n in rows])
+    pos = np.concatenate([np.arange(f, f + n, dtype=np.int32) for _, f, n in rows])
+    state = np.zeros((n_rows, 1), np.int32)
+    state[:len(slots), 0] = slots
+    return {"token_seq": jnp.asarray(seq), "token_pos": jnp.asarray(pos),
+            "block_tables": jnp.zeros((n_rows, 1), jnp.int32), "seq_state": jnp.asarray(state)}
+
+
+_MIXERS = {}
+
+
+def kda_layer(state_step, params, layer, x, kda, conv, batch):
+    """``SolarOpen2Kind.kda_layer`` at the debug preset, jitted - a program a
+    shape for each of ``state_step``'s cases, which is read when a program is
+    traced."""
+    if state_step not in _MIXERS:
+        _MIXERS[state_step] = jax.jit(
+            lambda params, layer, x, kda, conv, batch: KIND.kda_layer(
+                params, DEBUG, layer, x, kda, conv, batch))
+    return _MIXERS[state_step](params, jnp.int32(layer), x, kda, conv, batch)
+
+
+def _pools(cfg, slots, fill):
+    H, d = cfg.kda_heads, cfg.kda_head_dim
+    return (jnp.full((LK, slots + 1, H, d, d), fill, jnp.float32),
+            jnp.full((LK, slots + 1, cfg.kda_conv - 1, 3 * cfg.kda_inner), fill, jnp.float32))
+
+
+@pytest.mark.parametrize("chunk", [1, 7, 64, 100])
+def test_a_prompt_in_chunks_leaves_the_state_and_the_tail_of_the_recurrence(engine, chunk,
+                                                                            state_step):
+    """A prompt of 100 rows through KDA layer 3 one row a call, in chunks of
+    7 and 64 rows and whole, in a slot that held ones: the reference's output
+    rows, state and tails."""
+    cfg, layer, S = engine.model_config, 3, 100
+    x = jax.random.normal(jax.random.PRNGKey(2), (S, cfg.hidden_size))
+    lp = jax.tree.map(lambda w: w[layer], engine.params["model"]["kda_layers"])
+    with jax.default_matmul_precision("highest"):
+        want, state, tail = reference_kda(lp, x[None], cfg)
+    kda, conv = _pools(cfg, 2, 1.0)
+    got = []
+    for at in range(0, S, chunk):
+        n = min(chunk, S - at)
+        y, kda, conv = kda_layer(state_step, engine.params, layer, x[at:at + n], kda, conv,
+                                 _batch([(0, at, n)], 2, [2]))
+        got.append(y)
+    assert rel_err(jnp.concatenate(got), want[0]) < TOL
+    assert rel_err(kda[layer, 2], state[0]) < TOL and rel_err(conv[layer, 2], tail[0]) < TOL
+    assert np.asarray(kda[layer, 1] == 1.0).all() and np.asarray(kda[0] == 1.0).all()
+
+
+def test_several_runs_in_one_step_each_from_its_own_slot(engine, state_step):
+    """Decode rows, runs that go on from a carried slot and runs that start,
+    side by side in one call, then padding's rows: every run the
+    reference's continuation of its own sequence, the slot of a sequence
+    that starts ignored (it held 0.5), a slot no row names untouched."""
+    cfg, layer = engine.model_config, 4
+    lp = jax.tree.map(lambda w: w[layer], engine.params["model"]["kda_layers"])
+    key = jax.random.PRNGKey(4)
+    before = [30, 12, 0, 9, 0, 1]                # rows each sequence has behind it
+    now = [1, 1, 6, 2, 9, 1]
+    slots = [3, 1, 6, 2, 5, 4]
+    xs = [jax.random.normal(jax.random.fold_in(key, i), (b + n, cfg.hidden_size))
+          for i, (b, n) in enumerate(zip(before, now))]
+    kda, conv = _pools(cfg, 7, 0.5)
+    want = []
+    with jax.default_matmul_precision("highest"):
+        for i, (b, n) in enumerate(zip(before, now)):
+            state = tail = None
+            if b:
+                _, state, tail = reference_kda(lp, xs[i][None, :b], cfg)
+                kda = kda.at[layer, slots[i]].set(state[0])
+                conv = conv.at[layer, slots[i]].set(tail[0])
+            want.append(reference_kda(lp, xs[i][None, b:], cfg, state, tail))
+    batch = _batch([(i, b, n) for i, (b, n) in enumerate(zip(before, now))] + [(7, 0, 1)] * 4,
+                   8, slots)
+    x = jnp.concatenate([xs[i][b:] for i, b in enumerate(before)]
+                        + [jnp.ones((4, cfg.hidden_size))])
+    held = np.asarray(kda)
+    y, kda, conv = kda_layer(state_step, engine.params, layer, x, kda, conv, batch)
+    at = 0
+    for i, n in enumerate(now):
+        out, state, tail = want[i]
+        assert rel_err(y[at:at + n], out[0]) < TOL, i
+        assert rel_err(kda[layer, slots[i]], state[0]) < TOL, i
+        assert rel_err(conv[layer, slots[i]], tail[0]) < TOL, i
+        at += n
+    assert np.array_equal(np.asarray(kda[layer, 7]), held[layer, 7])      # a slot no row names
+    assert np.array_equal(np.asarray(kda[layer, 0]), held[layer, 0])      # padding's
+    assert np.array_equal(np.asarray(kda[0]), held[0])
+
+
+def test_a_state_carried_in_bfloat16_and_a_clipped_beta_are_seen(engine):
+    """The controls of the checks above: the state rounded to bfloat16 after
+    every decode row drifts from the float32 recurrence by far more than
+    ``TOL``; and a ``beta`` held to (0, 1) - what forgetting
+    ``kda_allow_neg_eigval`` does - moves the rows themselves."""
+    cfg, layer, S = engine.model_config, 1, 80
+    x = jax.random.normal(jax.random.PRNGKey(7), (S, cfg.hidden_size))
+    lp = jax.tree.map(lambda w: w[layer], engine.params["model"]["kda_layers"])
+    with jax.default_matmul_precision("highest"):
+        want, state, _ = reference_kda(lp, x[None], cfg)
+    kda, conv = _pools(cfg, 2, 0.0)
+    for at in range(S):
+        _, kda, conv = kda_layer("xla", engine.params, layer, x[at:at + 1], kda, conv,
+                                 _batch([(0, at, 1)], 2, [2]))
+        kda = kda.astype(jnp.bfloat16).astype(jnp.float32)
+    assert rel_err(kda[layer, 2], state[0]) > 50 * TOL
+    # beta = 2 sigmoid(.): halving b_proj's output range is sigmoid alone
+    from deepspeed_tpu.models import solar_open2
+
+    original = solar_open2.delta_rule
+    solar_open2.delta_rule = lambda q, k, v, g, beta, s: original(q, k, v, g, beta / 2.0, s)
+    try:
+        with jax.default_matmul_precision("highest"):
+            wrong = reference_kda(lp, x[None], cfg)[0]
+    finally:
+        solar_open2.delta_rule = original
+    assert rel_err(wrong, want) > 0.05
+
+
+def test_the_served_attention_layer_is_the_references_and_its_gate_is_seen(engine):
+    """Two key-value heads under two query heads each, no positional term,
+    the sigmoid gate on the output, over a chunk cut; the reference without
+    the gate - the control - reads far off."""
+    cfg, layer, S = engine.model_config, 1, 40
+    x = jax.random.normal(jax.random.PRNGKey(6), (S, cfg.hidden_size))
+    lp = jax.tree.map(lambda w: w[layer], engine.params["model"]["gqa_layers"])
+    with jax.default_matmul_precision("highest"):
+        want = reference_attention(lp, x[None], cfg)[0]
+        gateless = reference_attention(lp, x[None], cfg, gated=False)[0]
+    shape = (cfg.count("g"), 8, BLOCK, cfg.num_key_value_heads * cfg.head_dim)
+    kc, vc = jnp.zeros(shape), jnp.zeros(shape)
+    got = []
+    for at, n in ((0, 25), (25, 15)):
+        batch = _batch([(0, at, n)], 2, [1])
+        batch["block_tables"] = jnp.asarray([[1, 2, 3], [0, 0, 0]], jnp.int32)
+        y, kc, vc = KIND.attention_layer(engine.params, cfg, layer, x[at:at + n], kc, vc, batch)
+        got.append(y)
+    assert rel_err(jnp.concatenate(got), want) < TOL
+    assert rel_err(gateless, want) > 0.3
+
+
+def test_the_served_expert_layer_is_the_references(engine):
+    cfg, layer = engine.model_config, 5
+    x = jax.random.normal(jax.random.PRNGKey(8), (24, cfg.hidden_size))
+    fp = jax.tree.map(lambda w: w[layer], engine.params["model"]["moe"])
+    with jax.default_matmul_precision("highest"):
+        want = reference_moe(fp, x, cfg)
+    got = KIND.expert_layer(engine.params, cfg, jnp.int32(layer), x)
+    assert rel_err(got, want) < TOL
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer(engine):
+    """The guide's section 4: the routed parts that the 8 shares give
+    (experts 0-1, 2-3, ... of 16, each behind the whole router), with the
+    shared expert counted once, sum to the uncut reference's layer - by the
+    reference's shares and by the served layer's."""
+    cfg, layer = engine.model_config, 2
+    x = jax.random.normal(jax.random.PRNGKey(9), (20, cfg.hidden_size))
+    fp = jax.tree.map(lambda w: w[layer], engine.params["model"]["moe"])
+    with jax.default_matmul_precision("highest"):
+        whole = reference_moe(fp, x, cfg)
+        shared = reference_moe(fp, x, cfg, share=(0, 0))
+    summed, served = shared, shared
+    for first in range(0, 16, 2):
+        part = dataclasses.replace(cfg, experts_held=2, first_expert_held=first)
+        held = {**fp, "experts": jax.tree.map(lambda w: w[first:first + 2], fp["experts"])}
+        with jax.default_matmul_precision("highest"):
+            summed = summed + reference_moe(held, x, part, shared=False)
+        params = {"model": {"moe": jax.tree.map(
+            lambda w: w[None], {**held, "experts": held["experts"]})}}
+        served = served + KIND.expert_layer(params, part, jnp.int32(0), x) - shared
+    assert rel_err(summed, whole) < TOL
+    assert rel_err(served, whole) < TOL
+
+
+def test_layer_params_cuts_each_layers_mixer_and_feed_forward(engine):
+    cfg, params = engine.model_config, engine.params
+    mixer, moe = layer_params(params, cfg, 1)
+    assert "A_log" in mixer and "gate" in moe
+    mixer, moe = layer_params(params, cfg, 4)                # letters gkkkgkkk: the second 'g'
+    assert np.array_equal(np.asarray(mixer["gate_proj"]["kernel"]),
+                          np.asarray(params["model"]["gqa_layers"]["gate_proj"]["kernel"][1]))
+    assert np.array_equal(np.asarray(moe["gate"]["weight"]),
+                          np.asarray(params["model"]["moe"]["gate"]["weight"][4]))
+    mixer, _ = layer_params(params, cfg, 6)                  # the fifth KDA layer
+    assert np.array_equal(np.asarray(mixer["dt_bias"]),
+                          np.asarray(params["model"]["kda_layers"]["dt_bias"][4]))
+
+
+# ----------------------------------------------------------- what is refused
+@pytest.mark.parametrize("name,over", [
+    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
+    ("KV tier", {"kv_tier": KVTierConfig(enabled=True)}),
+    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
+    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
+    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
+    ("tensor/expert-parallel sharding", {"tensor_parallel_degree": 2}),
+])
+def test_each_subsystem_that_assumes_two_kv_pools_refuses_the_model_by_name(model, name, over):
+    with pytest.raises(NotImplementedError, match=name) as e:
+        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
+    assert "'kv+slots'" in str(e.value) and "'solar_open2'" in str(e.value)
+
+
+def test_suspend_and_an_unannounced_prompt_are_refused_by_name(engine, tokens):
+    with pytest.raises(ValueError, match="needs the whole prompt.*prefix_match"):
+        engine.put([70], [tokens[0][:5]])
+    serve(engine, [[(70, tokens[0][:5])]])
+    with pytest.raises(NotImplementedError, match="suspend/resume.*kv\\+slots"):
+        engine.suspend(70)
+    engine.flush(70)
+    assert engine.slot_pool.free_slots == engine.slot_pool.slots
+
+
+# ------------------------------------------------------------------- tracing
+def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine, tokens):
+    a, b = tokens[2][:40], tokens[3][:9]
+    engine.prefix_match(60, a)
+    engine.prefix_match(61, b)
+    syncs = engine.host_syncs
+    engine.put([60, 61], [a[:20], b])
+    assert engine.host_syncs - syncs == 2            # as for any model kind: pack + fetch
+    counts = engine.last_step.counts
+    assert tuple(counts) == KIND.step_counts == COUNTS
+    assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_scan_runs"]) == (
+        29 * LK, 2 * LK, 2 * LK)
+    assert counts["n_picks_held"] == 29 * 4 * 8 and 0 < counts["n_groups_live"] <= 16 * 8
+    assert tracing.snapshot()["steps"][-1]["counts"] == counts
+    assert tracing.snapshot()["steps"][-1]["state_step"] == "xla"
+    engine.flush(60)
+    engine.flush(61)
+    lowered = engine._step.lower(engine.params, engine.kv_cache.k, engine.kv_cache.v,
+                                 engine.state_extra, engine._batch.finalize_packed()).as_text(
+                                     debug_info=True)
+    for scope in ("ds.solar.kda", "ds.solar.kda_state", "ds.solar.attn", "ds.moe_routed",
+                  "ds.moe_shared"):
+        assert scope in lowered, scope
+
+
+# ------------------------------------------------------------------- gateway
+def test_the_gateway_serves_it_through_the_same_scheduler(model, engine, tokens):
+    from deepspeed_tpu.serving import ServingConfig, ServingGateway
+    prompts = [tokens[0][:75], tokens[1][:9], tokens[2][:40]]
+    served = InferenceEngineV2(params=engine.params, model_config=model.config,
+                               config=engine_config(), dtype=jnp.float32)
+    pool = served.slot_pool
+    gateway = ServingGateway(served, config=ServingConfig(default_max_new_tokens=12))
+    try:
+        handles = [gateway.submit(p, max_new_tokens=12) for p in prompts]
+        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
+    finally:
+        gateway.shutdown()
+    for prompt, stream in zip(prompts, streams):
+        full = np.concatenate([prompt, np.asarray(stream[:-1], np.int32)])
+        ref = reference(engine, full)
+        assert stream == [int(t) for t in np.argmax(ref[len(prompt) - 1:], axis=-1)]
+    records = [r for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id]
+    assert {"burst", "put"} <= {r["kind"] for r in records}
+    assert all(r["counts"] is not None for r in records if r["kind"] in ("burst", "put"))
+    assert pool.free_slots == pool.slots                   # every slot came back

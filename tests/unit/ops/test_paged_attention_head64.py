@@ -125,12 +125,14 @@ def test_the_engine_gets_the_kernel_at_a_head_of_64_where_it_is_forced(monkeypat
                          override="pallas_paged")
 
 
-@pytest.mark.parametrize("Hkv,G", [(1, 20), (8, 4), (2, 16)],
-                         ids=["jamba-20-over-1", "chat-4-a-head", "agents-16-a-head"])
+@pytest.mark.parametrize("Hkv,G", [(1, 20), (8, 4), (2, 16), (8, 8)],
+                         ids=["jamba-20-over-1", "chat-4-a-head", "agents-16-a-head",
+                              "solar-8-a-head-over-8"])
 def test_a_query_group_that_is_no_power_of_two_matches_the_gather(Hkv, G):
     """Jamba's 20 query heads over one key-value head of 128 - the first
     group that is no power of two: a tile lays ``32 x 20`` columns - beside
-    the groups the other cells run (4 and 16), a row a grid step and with
+    the groups the other cells run (4 and 16, and Solar Open 2's 8 over 8
+    key-value heads: a 1024-lane row), a row a grid step and with
     the step's query tiles: a chunk of 40 rows, a decode row, a chunk that
     starts its sequence, padding."""
     bs, MB, NB, n_seqs = 16, 6, 24, 4

@@ -219,7 +219,9 @@ def test_mosaic_takes_the_paged_kernel_at_a_head_of_64(one_chip, rows):
 # no power of two; a tile lays 32 x 20 = 640 columns, five whole lane tiles)
 TILED_SHAPES = {"head64-64row-blocks": (32, 8, 64, 64, 8705, 136),
                 "head128-16row-blocks": (32, 8, 128, 16, 2560, 360),
-                "group20-one-kv-head": (20, 1, 128, 64, 4097, 16)}
+                "group20-one-kv-head": (20, 1, 128, 64, 4097, 16),
+                # solar-open2-reason: 64 query heads over 8 key-value heads, an 80-block table
+                "group8-eight-kv-heads": (64, 8, 128, 64, 10241, 80)}
 
 
 @pytest.mark.parametrize("shape", list(TILED_SHAPES))
@@ -278,6 +280,33 @@ def test_mosaic_takes_the_selective_scan_at_jambas_shape(one_chip, rows):
     assert memory.temp_size_in_bytes < 2 * rows * 16 * 128 * 4 + (1 << 20)
     assert rows * 5120 * 16 * 4 > 20 * memory.temp_size_in_bytes        # no [T, C, N]
     assert "selective_scan" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [512, 192])
+def test_mosaic_takes_the_delta_rule_at_solars_shape(one_chip, rows):
+    """Compiled for a described v5e (nothing runs; in this file for the reason
+    above): ``ops/pallas/kda.kda_delta_rule`` lowers at ``solar-open2-reason``'s
+    shape in the 512-row and the 192-row program, the pool comes back as the
+    buffer it came in, and the program holds no temporary of a slot's order
+    beyond the rows' own operands, let alone a ``[T, H, d, d]``."""
+    from deepspeed_tpu.ops.pallas import kda
+    shape, S = (3, 193, 64, 128, 128), 193
+    assert kda.kernel_supported(shape, rows, S)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(shape, jnp.float32), sds((), jnp.int32), sds((rows,), jnp.int32),
+            sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.bool_),
+            *[sds((rows, 64, 128), jnp.float32)] * 4, sds((rows, 64), jnp.float32))
+    compiled = _compiled(lambda *a: kda.kda_delta_rule(*a, interpret=False), args,
+                         donate_argnums=0)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= int(np.prod(shape)) * 4
+    # the decays and beta laid a row wide, the mask: a few rows' operands, nothing of the pool's
+    assert memory.temp_size_in_bytes < 4 * rows * 64 * 128 * 4
+    assert rows * 64 * 128 * 128 * 4 > 100 * memory.temp_size_in_bytes      # no [T, H, d, d]
+    assert "kda_delta_rule" in compiled.as_text()
 
 
 def test_the_tail_pool_rides_the_layer_loop_as_it_arrives(one_chip):

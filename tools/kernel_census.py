@@ -87,6 +87,15 @@ prompt step): ms a layer, us a row, GB/s of the bytes a call has to move (a
 live slot once in and once out, a row's operands in and ``y`` out), share of
 819, the largest error against ``xla_selective_scan`` (PERF.md, PR 45; ~1 min).
 
+``--kda`` times ``kda_delta_rule`` (the Kimi delta rule: a transition that
+rotates as well as decays) at ``solar-open2-reason``'s shape (3 layers of 193
+slots of 64 x 128 x 128 float32, the layer traced in a scan, the pool
+donated): 192 one-row sequences in a 192-row program (a decode step), and a
+512-row chunk of 1, 3 and 8 runs (a prompt step): ms a layer, us a row, GB/s
+of the bytes a call has to move (a live slot once in and once out, a row's
+operands in and ``o`` out), share of 819, the largest error against
+``xla_kda_delta_rule`` on one layer (PERF.md, PR 48; ~1 min).
+
 Prints one JSON line per kernel and writes ``chiprun_out/kernel_census.json``.
 """
 
@@ -871,6 +880,96 @@ def selective_scan_classes():
         yield f"selective-scan-{T}x{runs}", record
 
 
+# ``--kda``: solar-open2-reason's KDA layers (layers, slots, heads, head size)
+KDA_SHAPE = (3, 192, 64, 128)
+# (rows of the program, runs, rows a run): a decode step, then a prompt chunk cut into runs
+KDA_STEPS = ((192, 192, 1), (512, 1, 512), (512, 3, 170), (512, 8, 64))
+
+
+def kda_bytes(slots, rows, H, d):
+    """Least bytes through HBM for one KDA layer of a step: each live
+    slot's float32 state ``[H, d, d]`` once in and once out, each row's
+    ``q``, ``k``, ``v`` and decays in and ``o`` out (float32, ``H d`` wide)
+    and its ``beta`` (``H`` wide)."""
+    return slots * 2 * H * d * d * 4 + rows * (5 * H * d + H) * 4
+
+
+def kda_classes():
+    """Yields one record a step of ``KDA_STEPS``: the delta rule at
+    ``solar-open2-reason``'s shape, **all 3 layers a call** with the layer
+    traced inside a ``lax.scan`` as the step programs have it and the pool
+    donated; a sequence in 16 fresh. ms a layer, us a row, GB/s of
+    :func:`kda_bytes`, share of 819, the error against
+    ``xla_kda_delta_rule`` on one layer of a third of the slots."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import kda
+
+    Lk, slots, H, d = KDA_SHAPE
+    S = slots + 1
+    rng = np.random.default_rng(48)
+    fill = jax.jit(lambda key, Lk, NS: 0.1 * jax.random.normal(key, (Lk, NS, H, d, d),
+                                                               jnp.float32),
+                   static_argnums=(1, 2))
+
+    def layers(step, Lk):
+        def run(pool, *rows):
+            def one(pool, layer):
+                return step(pool, layer, *rows)
+            return jax.lax.scan(one, pool, jnp.arange(Lk, dtype=jnp.int32))
+        return jax.jit(run, donate_argnums=0)
+
+    def step_rows(T, runs, rows_a_run, NS):
+        seq = np.full(T, S - 1, np.int32)
+        slot, first, length = (np.zeros(S, np.int32) for _ in range(3))
+        fresh = np.ones(S, bool)
+        slot[:runs] = rng.permutation(np.arange(1, NS))[:runs]
+        for s in range(runs):
+            first[s], length[s] = s * rows_a_run, rows_a_run
+            seq[first[s]:first[s] + rows_a_run] = s
+            fresh[s] = s % 16 == 15
+        k = rng.standard_normal((T, H, d))
+        return (jnp.asarray(seq), jnp.asarray(slot), jnp.asarray(first), jnp.asarray(length),
+                jnp.asarray(fresh), jnp.asarray(rng.standard_normal((T, H, d)) / d, jnp.float32),
+                jnp.asarray(k / np.linalg.norm(k, axis=-1, keepdims=True), jnp.float32),
+                jnp.asarray(rng.standard_normal((T, H, d)), jnp.float32),
+                jnp.asarray(-np.exp(rng.uniform(np.log(1e-3), np.log(1.6), (T, H, d))),
+                            jnp.float32),
+                jnp.asarray(rng.uniform(0.0, 2.0, (T, H)), jnp.float32))
+
+    for T, runs, rows_a_run in KDA_STEPS:
+        live = runs * rows_a_run
+        least = kda_bytes(runs, live, H, d)
+        record = {"rows": T, "runs": runs, "live_rows": live, "least_bytes_a_layer": least}
+        try:
+            few = min(runs, 64) + 1         # the reference gathers every sequence row's state
+            small = step_rows(T, min(runs, 64), rows_a_run if runs > 1 else 64, few)
+            want_pool, want_o = layers(kda.xla_kda_delta_rule, 1)(
+                fill(jax.random.PRNGKey(T + runs), 1, few), *small)
+            pool, o = layers(functools.partial(kda.kda_delta_rule, interpret=False), 1)(
+                fill(jax.random.PRNGKey(T + runs), 1, few), *small)
+            err = max(rel_err(o, want_o), rel_err(pool, want_pool))
+            rows = step_rows(T, runs, rows_a_run, S)
+            call = layers(functools.partial(kda.kda_delta_rule, interpret=False), Lk)
+            pool = fill(jax.random.PRNGKey(1), Lk, S)
+            pool, o = call(pool, *rows)
+            jax.block_until_ready(pool)
+            t0 = time.perf_counter()
+            for _ in range(10):
+                pool, o = call(pool, *rows)
+            jax.block_until_ready((pool, o))
+            ms = (time.perf_counter() - t0) * 1e3 / 10 / Lk
+            record.update(ms_a_layer=ms, us_a_row=ms * 1e3 / live, gb_s=least / ms / 1e6,
+                          hbm_share=100 * least / ms / 1e6 / HBM_GB_S,
+                          rel_err=float(f"{err:.3e}"))
+        except Exception as e:  # a refusal is a record too
+            record["refused"] = f"{type(e).__name__}: {e}"[:1500]
+        yield f"kda-{T}x{runs}", record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -900,8 +999,10 @@ def main():
     parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
     live, ssm, chunk = "--live" in sys.argv, "--ssm" in sys.argv, "--chunk" in sys.argv
-    scan = "--scan" in sys.argv
-    if scan:
+    scan, kda = "--scan" in sys.argv, "--kda" in sys.argv
+    if kda:
+        section, records = "kda", kda_classes()
+    elif scan:
         section, records = "selective_scan", selective_scan_classes()
     elif chunk:
         section, records = "query_tiles", chunk_classes(parent_dir)
@@ -920,7 +1021,7 @@ def main():
     for name, record in records:
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
-    for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk or scan
+    for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk or scan or kda
                                      or "--gmm-only" in sys.argv
                                      else cases()):
         try:
@@ -930,7 +1031,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("scan_census.json" if scan else "chunk_census.json" if chunk
+    out = ("kda_census.json" if kda else "scan_census.json" if scan
+           else "chunk_census.json" if chunk
            else "ssm_census.json" if ssm
            else "live_census.json" if live
            else "paged_census.json" if paged
