@@ -1288,6 +1288,9 @@ class _SlotStep:
         self.cfg, self.choice = cfg, choice
         self.seq, self.pos = batch["token_seq"], batch["token_pos"]
         self.n_rows = S = batch["block_tables"].shape[0]     # sequences a step + padding's
+        # a program of one row a sequence by construction says so (a burst's step:
+        # ``ragged_forward``); a kernel with a form for longer runs leaves it out of such a program
+        self.one_row_runs = batch.get("query_tiles", ()) is None
         self.real = self.seq < S - 1
         self.slot = batch["seq_state"][:, 0]
         T = self.seq.shape[0]
@@ -1847,7 +1850,11 @@ class SolarOpen2Kind(ModelKind):
     chunk: every row of a sequence passes through its slot's state in order
     (``ops/pallas/kda.kda_delta_rule``: the pool aliased in and out, a slot
     fetched once, its sequence's rows run through it in VMEM, written back
-    once; ``xla_kda_delta_rule`` where the kernel does not run;
+    once - a row at a time on the vector unit where the sequence has few
+    rows in the step, every decode row among them, and a block of 64 at a
+    time in the rule's chunked (WY) form, float32 products on the matrix
+    unit, where it has ``kda.MIN_CHUNK_RUN`` or more: a prompt chunk;
+    ``xla_kda_delta_rule`` where the kernel does not run;
     ``AttentionChoice.state_step`` says which a program got).
 
     :meth:`stack` runs ``cfg.segments`` through :func:`_run_segments`. The
@@ -1855,12 +1862,15 @@ class SolarOpen2Kind(ModelKind):
     Each step counts, over its tokens that are not padding:
     ``EXPERT_COUNTS``, the rows through the KDA layers, the (sequence, KDA
     layer)s whose state it read and wrote (:class:`NemotronHKind`'s and
-    :class:`JambaKind`'s name), and ``n_scan_runs``, those of them with more
-    than one row in the step."""
+    :class:`JambaKind`'s name), ``n_scan_runs``, those of them with more
+    than one row in the step, and ``n_kda_chunk_rows``, the rows of
+    ``n_kda_rows`` that went through the rule's block form (``kda.chunk_rows``:
+    what the kernel decides by, whichever implementation the program got)."""
     name = "solar_open2"
     config = SolarOpen2Config
     state_kind = "kv+slots"
-    step_counts = EXPERT_COUNTS + ("n_kda_rows", "n_state_slots", "n_scan_runs")
+    step_counts = EXPERT_COUNTS + ("n_kda_rows", "n_state_slots", "n_scan_runs",
+                                   "n_kda_chunk_rows")
     seq_rows = 1            # (slot,)
     slot_state = ("kda", "conv")
     experts_at = "moe"
@@ -1911,10 +1921,13 @@ class SolarOpen2Kind(ModelKind):
         (h, kc, vc, kda, conv, picks), done = _run_segments(
             cfg.segments, SolarOpen2Kind._counters, layer, carry)
         Lk = done.get("kda", 0)
+        from deepspeed_tpu.ops.pallas.kda import chunk_rows
+        length = jnp.where(ctx.here, ctx.length, 0)
         counts = jnp.concatenate([picks, jnp.stack([
             Lk * jnp.sum(ctx.real.astype(jnp.int32)),
             Lk * jnp.sum(ctx.here.astype(jnp.int32)),
-            Lk * jnp.sum((ctx.here & (ctx.length > 1)).astype(jnp.int32))])])
+            Lk * jnp.sum((ctx.here & (ctx.length > 1)).astype(jnp.int32)),
+            Lk * jnp.sum(jnp.where(chunk_rows(length, h.shape[0]), length, 0))])])
         return h, kc, vc, {"kda": kda, "conv": conv}, counts.astype(jnp.int32)[None]
 
     @staticmethod
@@ -1996,7 +2009,8 @@ def _solar_kda(ctx, p, layer, x, kda, conv):
     impl = rule.delta_rule_impl(kda.shape, T, ctx.n_rows)
     if ctx.choice is not None:
         ctx.choice.state_step[T] = impl
-    run = rule.kda_delta_rule if impl == rule.KERNEL else rule.xla_kda_delta_rule
+    run = (functools.partial(rule.kda_delta_rule, one_row_runs=ctx.one_row_runs)
+           if impl == rule.KERNEL else rule.xla_kda_delta_rule)
     with jax.named_scope("ds.solar.kda_state"):
         kda, o = run(kda, layer, ctx.seq, ctx.slot, ctx.first_row,
                      jnp.where(ctx.here, ctx.length, 0), ctx.fresh, q, k, v, log_alpha, beta)

@@ -42,7 +42,7 @@ BLOCK = 16
 KIND = model_runner.SolarOpen2Kind
 LK = DEBUG.count("k")
 COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_kda_rows", "n_state_slots",
-          "n_scan_runs")
+          "n_scan_runs", "n_kda_chunk_rows")
 
 
 def rel_err(got, want):
@@ -260,6 +260,7 @@ def test_two_prompts_in_one_chunk_beside_decoding_sequences(request, state_step,
     assert tuple(counts) == COUNTS
     assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_scan_runs"]) == (
         32 * LK, 3 * LK, 2 * LK)
+    assert counts["n_kda_chunk_rows"] == 0           # a batch of 32 rows is no whole block
     # 32 tokens x 4 picks x 8 layers, all 16 experts held: every pick a row of some group
     assert counts["n_picks_held"] == 32 * 4 * 8 and counts["n_picks_zero"] == 0
     more = serve(engine, [[(1, a[50:51]), (2, b[13:40])], [(1, a[51:52]), (2, b[40:41])]])
@@ -271,6 +272,66 @@ def test_two_prompts_in_one_chunk_beside_decoding_sequences(request, state_step,
     got = [(rows[1][1], wa[49]), (more[1][0], wa[50]), (more[1][1], wa[51]),
            (more[2][0], wb[39]), (more[2][1], wb[40]), (rows[3][0], wc[28]), (rows[3][1], wc[29])]
     assert max(rel_err(g, w) for g, w in got) < TOL
+
+
+@pytest.fixture(scope="module")
+def block_engine(model):
+    """A batch of one whole block of the delta rule's block form (64 rows),
+    first run under ``DS_PALLAS=1``: a run of ``kda.MIN_CHUNK_RUN`` rows or
+    more goes through the chunked form, interpreted."""
+    return InferenceEngineV2(model=model, config=RaggedInferenceEngineConfig(
+        kv_block_size=BLOCK, num_kv_blocks=96,
+        state_manager=DSStateManagerConfig(max_ragged_batch_size=64, max_ragged_sequence_count=4,
+                                           max_tracked_sequences=4, max_context=192)),
+        dtype=jnp.float32, rng=jax.random.PRNGKey(5))
+
+
+def test_a_prompt_chunk_goes_through_the_block_form_beside_decode_rows(
+        block_engine, tokens, monkeypatch):
+    """A step with a prompt chunk and decode rows counts the chunk's rows
+    through the block form, a decode-only step none; a prompt cut into two
+    chunks (both block-form runs, the second from the carried state) serves
+    the logits of the uncut run."""
+    from deepspeed_tpu.ops.pallas import kda
+    monkeypatch.setenv("DS_PALLAS", "1")
+    engine = block_engine
+    a, b, n = tokens[0][:90], tokens[1][:12], kda.MIN_CHUNK_RUN
+    assert kda.CHUNK == 64 and n + 6 <= 40
+    engine.prefix_match(1, a[:89])
+    engine.prefix_match(2, b[:11])
+    rows = serve(engine, [[(2, b[:10]), (1, a[:40])]])
+    counts = engine.last_step.counts
+    assert engine.last_step.state_step == "pallas_kda" and tuple(counts) == COUNTS
+    assert (counts["n_kda_rows"], counts["n_scan_runs"], counts["n_kda_chunk_rows"]) == (
+        50 * LK, 2 * LK, 40 * LK)
+    more = serve(engine, [[(2, b[10:11]), (1, a[40:89])], [(2, b[11:12]), (1, a[89:90])]])
+    counts = engine.last_step.counts                # two decode rows
+    assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_kda_chunk_rows"]) == (
+        2 * LK, 2 * LK, 0)
+    engine.flush(1)
+    engine.flush(2)
+    wa, wb = reference(engine, a), reference(engine, b)
+    got = [(rows[1][0], wa[39]), (more[1][0], wa[88]), (more[1][1], wa[89]),
+           (rows[2][0], wb[9]), (more[2][0], wb[10]), (more[2][1], wb[11])]
+    assert max(rel_err(g, w) for g, w in got) < TOL
+    # the same prompt cut elsewhere: a whole block's 64 rows, then 25 from the carried state
+    engine.prefix_match(3, a[:89])
+    whole = serve(engine, [[(3, a[:64])], [(3, a[64:89])], [(3, a[89:90])]])[3]
+    assert engine.last_step.counts["n_kda_chunk_rows"] == 0
+    engine.flush(3)
+    assert rel_err(whole[1], more[1][0]) < TOL and rel_err(whole[2], more[1][1]) < TOL
+
+
+def test_a_bursts_step_says_that_it_holds_one_row_a_sequence():
+    """``decode_burst``'s steps name ``query_tiles: None`` (one row a sequence
+    by construction), which the delta rule's call reads as it does the paged
+    kernel's: such a program holds no block form."""
+    batch = {"token_seq": jnp.zeros((4,), jnp.int32), "token_pos": jnp.zeros((4,), jnp.int32),
+             "block_tables": jnp.zeros((3, 2), jnp.int32),
+             "seq_state": jnp.zeros((3, 1), jnp.int32)}
+    assert not model_runner._SlotStep(DEBUG, batch, 4).one_row_runs
+    assert not model_runner._SlotStep(DEBUG, dict(batch, query_tiles=()), 4).one_row_runs
+    assert model_runner._SlotStep(DEBUG, dict(batch, query_tiles=None), 4).one_row_runs
 
 
 def test_decode_bursts_carry_every_state(engine, tokens):
@@ -582,8 +643,8 @@ def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine,
     assert engine.host_syncs - syncs == 2            # as for any model kind: pack + fetch
     counts = engine.last_step.counts
     assert tuple(counts) == KIND.step_counts == COUNTS
-    assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_scan_runs"]) == (
-        29 * LK, 2 * LK, 2 * LK)
+    assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_scan_runs"],
+            counts["n_kda_chunk_rows"]) == (29 * LK, 2 * LK, 2 * LK, 0)
     assert counts["n_picks_held"] == 29 * 4 * 8 and 0 < counts["n_groups_live"] <= 16 * 8
     assert tracing.snapshot()["steps"][-1]["counts"] == counts
     assert tracing.snapshot()["steps"][-1]["state_step"] == "xla"

@@ -306,7 +306,21 @@ def test_mosaic_takes_the_delta_rule_at_solars_shape(one_chip, rows):
     # the decays and beta laid a row wide, the mask: a few rows' operands, nothing of the pool's
     assert memory.temp_size_in_bytes < 4 * rows * 64 * 128 * 4
     assert rows * 64 * 128 * 128 * 4 > 100 * memory.temp_size_in_bytes      # no [T, H, d, d]
-    assert "kda_delta_rule" in compiled.as_text()
+    text = compiled.as_text()
+    # both forms (PR 49: the row form, and the block form for runs of MIN_CHUNK_RUN rows or
+    # more), each under a name the benchmark's reader finds (^kda_delta_rule)
+    assert set(re.findall(r"kda_delta_rule\w*", text)) >= {"kda_delta_rule",
+                                                           "kda_delta_rule_blocks"}
+    assert f"f32[{rows},64,128,128]" not in text
+    # a program of one row a sequence by construction (a burst's step) holds the row form alone
+    alone = _compiled(lambda *a: kda.kda_delta_rule(*a, interpret=False, one_row_runs=True), args,
+                      donate_argnums=0).as_text()
+    assert "kda_delta_rule" in alone and "kda_delta_rule_blocks" not in alone
+    # nothing but the two kernels (and what hands a buffer on) gives a result of the pool's shape
+    made = [line for line in text.splitlines()
+            if re.search(r"= (\([^)]*\) )?f32\[3,193,64,128,128\]", line)]
+    assert made and all(re.search(r"parameter\(|custom-call\(|get-tuple-element\(|bitcast\(",
+                                  line) for line in made), made
 
 
 def test_the_tail_pool_rides_the_layer_loop_as_it_arrives(one_chip):

@@ -90,11 +90,16 @@ live slot once in and once out, a row's operands in and ``y`` out), share of
 ``--kda`` times ``kda_delta_rule`` (the Kimi delta rule: a transition that
 rotates as well as decays) at ``solar-open2-reason``'s shape (3 layers of 193
 slots of 64 x 128 x 128 float32, the layer traced in a scan, the pool
-donated): 192 one-row sequences in a 192-row program (a decode step), and a
-512-row chunk of 1, 3 and 8 runs (a prompt step): ms a layer, us a row, GB/s
-of the bytes a call has to move (a live slot once in and once out, a row's
-operands in and ``o`` out), share of 819, the largest error against
-``xla_kda_delta_rule`` on one layer (PERF.md, PR 48; ~1 min).
+donated): 192 one-row sequences in a 192-row program (a decode step), a
+512-row chunk of 1, 3 and 8 runs (a prompt step) and a mixed step (191
+one-row sequences, then a prompt's 295 rows; with the row form alone beside
+it), in the form the kernel chooses; then runs of 2, 8, 16, 32, 64, 128 and
+512 rows in the row form and in the block form (PR 49: where the two cross),
+the block form's with the error of the control, one bfloat16 pass: ms a
+layer, us a row and a run, GB/s of the bytes a call has to move (a live slot
+once in and once out, a row's operands in and ``o`` out), share of 819, the
+largest error against ``xla_kda_delta_rule`` on one layer (PERF.md, PRs 48
+and 49; ~2.5 min).
 
 Prints one JSON line per kernel and writes ``chiprun_out/kernel_census.json``.
 """
@@ -882,8 +887,19 @@ def selective_scan_classes():
 
 # ``--kda``: solar-open2-reason's KDA layers (layers, slots, heads, head size)
 KDA_SHAPE = (3, 192, 64, 128)
-# (rows of the program, runs, rows a run): a decode step, then a prompt chunk cut into runs
-KDA_STEPS = ((192, 192, 1), (512, 1, 512), (512, 3, 170), (512, 8, 64))
+# (rows of the program, runs, rows a run, rows of one more run behind them, the least run
+# that takes the block form - None: the kernel's own): a decode step as a burst holds it (the
+# row form alone) and as a ``put`` program does; a prompt chunk cut into runs; a mixed step of
+# the cell, 191 decode rows and a prompt's 295, with the row form alone beside it; then runs
+# of 2 to 512 rows back to back from row 0 in the row form and in the block form, to see
+# where the two cross (PR 49)
+ROW_FORM, BLOCK_FORM, BURST = 1 << 20, 1, "a burst's step: one row a sequence by construction"
+KDA_STEPS = (((192, 192, 1, 0, BURST), (192, 192, 1, 0, None), (512, 1, 512, 0, None),
+              (512, 3, 170, 0, None), (512, 8, 64, 0, None), (512, 191, 1, 295, ROW_FORM),
+              (512, 191, 1, 295, None))
+             + tuple((512, min(8, 512 // n), n, 0, form) for n in (2, 8, 16, 32, 64, 128, 512)
+                     for form in (ROW_FORM, BLOCK_FORM)))
+KDA_FORM_NAMES = {None: "chosen", ROW_FORM: "rows", BLOCK_FORM: "blocks", BURST: "burst"}
 
 
 def kda_bytes(slots, rows, H, d):
@@ -898,9 +914,10 @@ def kda_classes():
     """Yields one record a step of ``KDA_STEPS``: the delta rule at
     ``solar-open2-reason``'s shape, **all 3 layers a call** with the layer
     traced inside a ``lax.scan`` as the step programs have it and the pool
-    donated; a sequence in 16 fresh. ms a layer, us a row, GB/s of
-    :func:`kda_bytes`, share of 819, the error against
-    ``xla_kda_delta_rule`` on one layer of a third of the slots."""
+    donated; a sequence in 16 fresh. ms a layer, us a row and a run, GB/s
+    of :func:`kda_bytes`, share of 819, the error against
+    ``xla_kda_delta_rule`` on one layer of a third of the slots (a forced
+    block form's also with its products in one bfloat16 pass: the control)."""
     import numpy as np
 
     import jax
@@ -922,14 +939,17 @@ def kda_classes():
             return jax.lax.scan(one, pool, jnp.arange(Lk, dtype=jnp.int32))
         return jax.jit(run, donate_argnums=0)
 
-    def step_rows(T, runs, rows_a_run, NS):
+    def step_rows(T, runs, rows_a_run, NS, then=0):
+        """``runs`` runs of ``rows_a_run`` rows back to back from row 0, then one of
+        ``then`` rows."""
         seq = np.full(T, S - 1, np.int32)
         slot, first, length = (np.zeros(S, np.int32) for _ in range(3))
         fresh = np.ones(S, bool)
-        slot[:runs] = rng.permutation(np.arange(1, NS))[:runs]
-        for s in range(runs):
-            first[s], length[s] = s * rows_a_run, rows_a_run
-            seq[first[s]:first[s] + rows_a_run] = s
+        lengths = [rows_a_run] * runs + [then] * bool(then)
+        slot[:len(lengths)] = rng.permutation(np.arange(1, NS))[:len(lengths)]
+        for s, n in enumerate(lengths):
+            first[s], length[s] = sum(lengths[:s]), n
+            seq[first[s]:first[s] + n] = s
             fresh[s] = s % 16 == 15
         k = rng.standard_normal((T, H, d))
         return (jnp.asarray(seq), jnp.asarray(slot), jnp.asarray(first), jnp.asarray(length),
@@ -940,20 +960,29 @@ def kda_classes():
                             jnp.float32),
                 jnp.asarray(rng.uniform(0.0, 2.0, (T, H)), jnp.float32))
 
-    for T, runs, rows_a_run in KDA_STEPS:
-        live = runs * rows_a_run
-        least = kda_bytes(runs, live, H, d)
-        record = {"rows": T, "runs": runs, "live_rows": live, "least_bytes_a_layer": least}
+    def rule(form, **control):
+        how = {"one_row_runs": True} if form == BURST else {"min_run": form}
+        return lambda *a: kda._delta_call(*a, interpret=False, **how, **control)
+
+    for T, runs, rows_a_run, then, form in KDA_STEPS:
+        live = runs * rows_a_run + then
+        least = kda_bytes(runs + bool(then), live, H, d)
+        record = {"rows": T, "runs": runs, "rows_a_run": rows_a_run, "then": then,
+                  "live_rows": live, "form": KDA_FORM_NAMES[form], "least_bytes_a_layer": least}
         try:
-            few = min(runs, 64) + 1         # the reference gathers every sequence row's state
-            small = step_rows(T, min(runs, 64), rows_a_run if runs > 1 else 64, few)
+            few = min(runs, 64) + 2         # the reference gathers every sequence row's state
+            small = step_rows(T, min(runs, 64), rows_a_run, few, then)
             want_pool, want_o = layers(kda.xla_kda_delta_rule, 1)(
                 fill(jax.random.PRNGKey(T + runs), 1, few), *small)
-            pool, o = layers(functools.partial(kda.kda_delta_rule, interpret=False), 1)(
-                fill(jax.random.PRNGKey(T + runs), 1, few), *small)
+            pool, o = layers(rule(form), 1)(fill(jax.random.PRNGKey(T + runs), 1, few), *small)
             err = max(rel_err(o, want_o), rel_err(pool, want_pool))
-            rows = step_rows(T, runs, rows_a_run, S)
-            call = layers(functools.partial(kda.kda_delta_rule, interpret=False), Lk)
+            if form == BLOCK_FORM:          # the control: the same products in one bfloat16 pass
+                pool, o = layers(rule(form, one_pass=True), 1)(
+                    fill(jax.random.PRNGKey(T + runs), 1, few), *small)
+                record["rel_err_one_bf16_pass"] = float(
+                    f"{max(rel_err(o, want_o), rel_err(pool, want_pool)):.3e}")
+            rows = step_rows(T, runs, rows_a_run, S, then)
+            call = layers(rule(form), Lk)
             pool = fill(jax.random.PRNGKey(1), Lk, S)
             pool, o = call(pool, *rows)
             jax.block_until_ready(pool)
@@ -962,12 +991,13 @@ def kda_classes():
                 pool, o = call(pool, *rows)
             jax.block_until_ready((pool, o))
             ms = (time.perf_counter() - t0) * 1e3 / 10 / Lk
-            record.update(ms_a_layer=ms, us_a_row=ms * 1e3 / live, gb_s=least / ms / 1e6,
+            record.update(ms_a_layer=ms, us_a_row=ms * 1e3 / live,
+                          us_a_run=ms * 1e3 / (runs + bool(then)), gb_s=least / ms / 1e6,
                           hbm_share=100 * least / ms / 1e6 / HBM_GB_S,
                           rel_err=float(f"{err:.3e}"))
         except Exception as e:  # a refusal is a record too
             record["refused"] = f"{type(e).__name__}: {e}"[:1500]
-        yield f"kda-{T}x{runs}", record
+        yield f"kda-{T}x{runs}x{rows_a_run}+{then}-{KDA_FORM_NAMES[form]}", record
 
 
 def verdict(fn, ref, args, tol):
