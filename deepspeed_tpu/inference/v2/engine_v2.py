@@ -176,7 +176,39 @@ class InferenceEngineV2:
         initialized here when ``params`` is not given), or pass
         ``params`` + ``model_config`` directly. The config's type picks
         the model kind (``model_runner.kind_of``), which says what state
-        the paged pool holds, in how many layers, and how a layer steps."""
+        the paged pool holds, in how many layers, and how a layer steps.
+
+        The constructor is one step record of kind ``setup`` (utils/tracing.py)
+        in four contiguous phases. None of them waits for the device: the
+        weights' program and the pools' fills are launched in ``ds.setup.params``
+        and ``ds.setup.pools`` and end under a later phase or under the first
+        program's ``ds.engine.fetch``, as they always did."""
+        # step records: this engine's number in them
+        self.trace_id = tracing.engine_id()
+        with tracing.setup(self.trace_id) as setup:
+            self._construct(setup, model, config, params, model_config, dtype, rng)
+        phases = " ".join(f"{name.rsplit('.', 1)[-1]}={(exit_ - enter) / 1e9:.2f}"
+                          for name, enter, exit_ in setup.record.phases)
+        age = setup.record.process_age_ns
+        logger.info(f"InferenceEngineV2: max_tokens={self.max_tokens} "
+                    f"max_seqs={self.max_seqs} kv_blocks={self.kv_cache.num_blocks} "
+                    f"block_size={self.block_size} "
+                    f"tp={int(self._config.tensor_parallel_degree)} "
+                    f"ep={int(self._config.expert_parallel_degree)} "
+                    f"kv_bytes={self.kv_cache.bytes()/1e6:.1f}MB "
+                    f"experts={self.kind.experts_form(self.params, self.mesh)}"
+                    + ("" if self.state_extra is None else
+                       f" kind={self.kind.name} " + " ".join(
+                           f"{name}_bytes={x.nbytes/1e6:.1f}MB{list(x.shape)}"
+                           for name, x in sorted(self.state_extra.items())))
+                    + f" setup_s={(setup.record.end_ns - setup.record.start_ns) / 1e9:.2f} "
+                    f"({phases}; compiling {setup.record.compile_ns / 1e9:.2f}) "
+                    f"process_age_s={'n/a' if age is None else f'{age / 1e9:.2f}'}")
+
+    def _construct(self, setup, model, config, params, model_config, dtype, rng):
+        """The constructor's work, under its ``setup`` record."""
+        # made or taken, cast, quantized, sharded - launched, not waited for
+        setup.phase("setup.params")
         self._config = config or RaggedInferenceEngineConfig()
         sm = self._config.state_manager
         self.dtype = dtype
@@ -219,6 +251,8 @@ class InferenceEngineV2:
         else:
             rng = rng if rng is not None else jax.random.PRNGKey(0)
             self.params = self._init_params(model, rng)
+        # the two paged pools, the sequence table, the kind's own state and its slots
+        setup.phase("setup.pools")
         # monotone weight-version tag: bumped by swap_params (live weight
         # refresh); stamped into the prefix trie's root key so every
         # cached KV identity — and every exported handoff record — is
@@ -282,6 +316,9 @@ class InferenceEngineV2:
             self.slot_pool = SlotPool(slots, sum(
                 self.state_extra[name].nbytes // self.state_extra[name].shape[1]
                 for name in kind.slot_state))
+        # what the config turns on for this model kind (prefix cache, spill tier, drafting,
+        # adapters, schemas), the step's host batch and the attention table
+        setup.phase("setup.kind")
         # Radix prefix cache (cross-request KV reuse): config-gated with
         # the DS_PREFIX_CACHE env kill switch. When live, retired
         # sequences' full blocks become content-addressable and new
@@ -399,6 +436,9 @@ class InferenceEngineV2:
         self._sanitize = sanitize_enabled()
         sanitize = self._sanitize
 
+        # the step functions wrapped for jit (none is traced before its first call), the
+        # burst programs' table, the sampling keys
+        setup.phase("setup.programs")
         ms, mb = self.max_seqs, self.max_blocks_per_seq
         lora_on = self.lora_store is not None
         seq_rows = self._seq_rows
@@ -512,9 +552,7 @@ class InferenceEngineV2:
         # number the pipelined pump exists to drive toward 1/k.
         self.host_syncs = 0
         self.tokens_emitted = 0
-        # step records (utils/tracing.py): this engine's number in them, and
-        # the record of the program run last — the scheduler reads its seq
-        self.trace_id = tracing.engine_id()
+        # the step record of the program run last — the scheduler reads its seq
         self.last_step = None
         self._suspended = {}  # uid -> {"handle": host KV, "seen_tokens": int}
         # Counter-PRNG root for sampling: every sampled token's key folds
@@ -534,15 +572,6 @@ class InferenceEngineV2:
         if self.mesh is not None:
             from jax.sharding import PartitionSpec as _P
             self._replicated = NamedSharding(self.mesh, _P())
-        logger.info(f"InferenceEngineV2: max_tokens={self.max_tokens} "
-                    f"max_seqs={self.max_seqs} kv_blocks={num_blocks} "
-                    f"block_size={self.block_size} tp={tp} ep={ep} "
-                    f"kv_bytes={self.kv_cache.bytes()/1e6:.1f}MB "
-                    f"experts={kind.experts_form(self.params, self.mesh)}"
-                    + ("" if self.state_extra is None else
-                       f" kind={kind.name} " + " ".join(
-                           f"{name}_bytes={x.nbytes/1e6:.1f}MB{list(x.shape)}"
-                           for name, x in sorted(self.state_extra.items()))))
 
     # ------------------------------------------------------------------
     def _refuse_unsupported(self, kind, n_devices):
@@ -877,17 +906,23 @@ class InferenceEngineV2:
         program gave past its pools, as it came to the host in the one
         ``device_get`` that fetched the step's result (every copy is
         started before the first is waited for); empty for a kind that
-        counts nothing."""
+        counts nothing. A program's first step says a line here (the
+        start-up lines of a warm-up): what building it cost, where this
+        record built it (``rec.build``: the compile events of its dispatch),
+        and what serves its state step, where the model kind has one."""
+        rows = rec.n_rows // max(rec.k, 1)
+        said = []
         if counts:
             rec.counts = dict(zip(self.kind.step_counts, counts[0].tolist()))
-            # and what serves its state step, where the model kind has one: said once a
-            # program, when its first step comes back (the start-up lines of a warm-up)
-            rows = rec.n_rows // max(rec.k, 1)
             rec.state_step = self._attention.state_step.get(rows)
             if rec.state_step is not None and rows not in self._state_step_said:
                 self._state_step_said.add(rows)
-                logger.info(f"InferenceEngineV2: the {rows}-row program's state step is "
-                            f"{rec.state_step} (kind={self.kind.name})")
+                said.append(f"'s state step is {rec.state_step} (kind={self.kind.name})")
+        if rec.build is not None and rec.build.compiles:
+            said.append(f" built in {rec.build.describe()}")
+        if said:
+            name = "" if rec.program == str(rows) else f" {rec.program}"
+            logger.info(f"InferenceEngineV2: the {rows}-row program{name}" + ";".join(said))
 
     def count_host_sync(self, n=1):
         """Record ``n`` executions of a pragma'd host-sync site. Every

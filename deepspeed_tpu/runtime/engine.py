@@ -60,37 +60,14 @@ from deepspeed_tpu.runtime.zero.partitioning import ZeroShardingPolicy, batch_sp
 from deepspeed_tpu.utils import tracing
 from deepspeed_tpu.utils.env_registry import env_bool, env_int, env_raw
 from deepspeed_tpu.utils.logging import log_dist, logger
-from deepspeed_tpu.utils.timer import (BACKWARD_GLOBAL_TIMER, BACKWARD_MICRO_TIMER, FORWARD_GLOBAL_TIMER,
-                                       FORWARD_MICRO_TIMER, STEP_GLOBAL_TIMER, STEP_MICRO_TIMER, TRAIN_BATCH_TIMER,
-                                       NoopTimer, SynchronizedWallClockTimer, ThroughputTimer)
+from deepspeed_tpu.utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER,
+                                       TRAIN_BATCH_TIMER, NoopTimer, SynchronizedWallClockTimer,
+                                       ThroughputTimer)
 
 MEMORY_OPT_ALLREDUCE_SIZE = 500000000
 
 DeepSpeedOptimizerCallable = object
 DeepSpeedSchedulerCallable = object
-
-
-class EngineTimers:
-    """Wall-clock timers (reference engine.py:148)."""
-
-    def __init__(self, enable_micro_timers, enable_global_timers):
-        self.forward_timers = []
-        self.backward_timers = []
-        self.step_timers = []
-        self.global_timers = []
-        self.micro_timers = []
-
-        if enable_micro_timers:
-            self.forward_timers += [FORWARD_MICRO_TIMER]
-            self.backward_timers += [BACKWARD_MICRO_TIMER]
-            self.step_timers += [STEP_MICRO_TIMER]
-            self.micro_timers += [FORWARD_MICRO_TIMER, BACKWARD_MICRO_TIMER, STEP_MICRO_TIMER]
-
-        if enable_global_timers:
-            self.forward_timers += [FORWARD_GLOBAL_TIMER]
-            self.backward_timers += [BACKWARD_GLOBAL_TIMER]
-            self.step_timers += [STEP_GLOBAL_TIMER]
-            self.global_timers += [FORWARD_GLOBAL_TIMER, BACKWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER]
 
 
 class DeepSpeedEngine:
@@ -111,13 +88,28 @@ class DeepSpeedEngine:
                  mesh=None,
                  loss_fn=None,
                  dont_change_device=False):
+        """The constructor is one step record of kind ``setup`` (utils/tracing.py):
+        the mesh and the ZeRO rules (``ds.setup.partition``), the optimizer and
+        its schedule (``ds.setup.optimizer``), monitors, checkpointing and the
+        data loader (``ds.setup.services``). The state itself is made at the
+        first forward or ``train_batch`` (:meth:`_materialize_state`, a ``setup``
+        record of its own)."""
+        self.trace_id = tracing.engine_id()  # this engine's number in the step records
+        with tracing.setup(self.trace_id) as setup:
+            self._construct(setup, model, optimizer, model_parameters, training_data,
+                            lr_scheduler, mpu, dist_init_required, collate_fn, config,
+                            config_class, mesh, loss_fn)
+        self._report_config()
+
+    def _construct(self, setup, model, optimizer, model_parameters, training_data, lr_scheduler,
+                   mpu, dist_init_required, collate_fn, config, config_class, mesh, loss_fn):
+        setup.phase("setup.partition")
         self.client_optimizer = optimizer
         self.client_lr_scheduler = lr_scheduler
         self.training_data = training_data
         self.collate_fn = collate_fn
         self.mpu = mpu
         self.loss_fn = loss_fn
-        self.trace_id = tracing.engine_id()  # this engine's number in the step records
         self.global_steps = 0
         self.global_samples = 0
         self.micro_steps = 0
@@ -168,13 +160,6 @@ class DeepSpeedEngine:
             "bf16": jnp.bfloat16,
         }.get(self._config.grad_accum_dtype, jnp.float32)
 
-        # Loss scaler (host mirror; device state lives in self.scaler_state)
-        self._build_loss_scaler()
-
-        # Optimizer object (DeepSpeed-shaped; jitted transform drives updates)
-        self.optimizer = self._configure_optimizer()
-        self.lr_scheduler = self._configure_lr_scheduler(lr_scheduler)
-
         # ZeRO sharding policy
         zc = self._config.zero_config
         self.zero_stage = zc.stage
@@ -193,12 +178,19 @@ class DeepSpeedEngine:
         self._layer_overlap = (overlap.LayerOverlap(self.sharding_policy)
                                if zc.stage == 3 and zc.overlap_comm else None)
 
+        setup.phase("setup.optimizer")
+        # Loss scaler (host mirror; device state lives in self.scaler_state)
+        self._build_loss_scaler()
+
+        # Optimizer object (DeepSpeed-shaped; jitted transform drives updates)
+        self.optimizer = self._configure_optimizer()
+        self.lr_scheduler = self._configure_lr_scheduler(lr_scheduler)
+
+        setup.phase("setup.services")
         # Monitors / timers
         self.monitor = MonitorMaster(self._config.monitor_config)
         self.wall_clock_breakdown_enabled = self._config.wall_clock_breakdown
         self.timers = SynchronizedWallClockTimer() if self.wall_clock_breakdown_enabled else NoopTimer()
-        self.engine_timers = EngineTimers(enable_micro_timers=self.wall_clock_breakdown_enabled,
-                                          enable_global_timers=self.wall_clock_breakdown_enabled)
         self.tput_timer = ThroughputTimer(
             config=self._config.timers_config,
             batch_size=self.train_batch_size(),
@@ -254,8 +246,6 @@ class DeepSpeedEngine:
         self._pending = None  # (loss, grads) from the last forward
         self.global_grad_norm = 0.0
         self.overflow = False
-
-        self._report_config()
 
     # ------------------------------------------------------------------
     # Config accessors (parity with reference engine surface)
@@ -579,6 +569,15 @@ class DeepSpeedEngine:
     def _materialize_state(self, *fwd_args, **fwd_kwargs):
         if self._initialized:
             return
+        # a setup record of its own (program "state") inside the first step's: the
+        # parameters made or taken and cast (ds.setup.params), the ZeRO shardings and the
+        # placement under them (ds.setup.partition), master copy and optimizer state
+        # (ds.setup.optimizer). Each phase launches device work and waits for none of it.
+        with tracing.setup(self.trace_id, program="state") as setup:
+            self._make_state(setup, fwd_args, fwd_kwargs)
+
+    def _make_state(self, setup, fwd_args, fwd_kwargs):
+        setup.phase("setup.params")
         self._configure_param_offload()
         if self.params is None:
             self.params = self._init_params(*fwd_args, **fwd_kwargs)
@@ -589,6 +588,7 @@ class DeepSpeedEngine:
                 lambda x, s: jax.device_put(
                     x.astype(self.compute_dtype) if _is_float(x) else x, s), self.params, shardings)
 
+        setup.phase("setup.partition")
         self._param_shardings = self.sharding_policy.tree_param_shardings(self.params)
         self._param_specs = self.sharding_policy.tree_param_specs(self.params)
         self._opt_shardings = self.sharding_policy.tree_opt_shardings(self.params)
@@ -613,6 +613,7 @@ class DeepSpeedEngine:
                     self._param_nvme_path,
                     aio_threads=int(self._config.zero_config.offload_param.buffer_count or 4))
 
+        setup.phase("setup.optimizer")
         offload_device = self._config.zero_config.offload_optimizer_device().value
         if offload_device != "none":
             # ZeRO-Offload: fp32 master + moments on host (RAM or NVMe),

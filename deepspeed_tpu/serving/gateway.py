@@ -756,7 +756,9 @@ class ServingGateway:
     def _check_stall(self, prev, rec):
         """The interval ``prev.end_ns`` → ``rec.end_ns`` less what
         ``rec.program`` usually takes from dispatch to the end of its fetch,
-        and less what was spent compiling, is the excess;
+        and less what was spent compiling (every compile event's own time, so
+        a nest of traces counts once and cannot outgrow the interval), is the
+        excess;
         ``tracing.STALL_NS`` of it or more, on a program seen
         ``tracing.STALL_MIN_RECORDS`` times before, is a stall: counted,
         written to the recorder's events and logged, with what the pump

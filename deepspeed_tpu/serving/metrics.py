@@ -15,7 +15,8 @@ are read when ``snapshot()`` / ``events()`` is called, not pushed on every
 pump pass. The ``steps`` group is a summary of the process's newest step
 records (``deepspeed_tpu/utils/tracing.py``) for this gateway's engine —
 the same records, quantities and clock the benchmark's per-layer metrics
-read.
+read — and ``setup`` what setting that engine up cost (the recorder's
+``setup`` records and build table), also counted in ``SETUP_COUNTERS``.
 
 Thread-safe: ``submit()`` runs on client threads while the pump thread
 records step/token events.
@@ -79,6 +80,8 @@ def summarize_steps(records):
     and per ``put`` that carried prompt tokens."""
     counts, burst_k, burst_ms, mixed_ms = {}, [], [], []
     for rec in records:
+        if rec.kind == "setup":     # a constructor, no program: the ``setup`` group's
+            continue
         counts[rec.kind] = counts.get(rec.kind, 0) + 1
         ns = tracing.device_ns(rec)
         if ns is None:
@@ -95,6 +98,34 @@ def summarize_steps(records):
             "mixed_step_ms_p50": median(mixed_ms) if mixed_ms else 0.0}
 
 
+# What setting this gateway's engine up cost (``tracing.setup_summary``), as counters:
+# whole milliseconds and numbers that only grow, read when a snapshot is asked for.
+# ``setup_init_ms``: the engine's ``setup`` records; ``setup_build_ms``: own trace +
+# lowering + backend time inside its other records (its programs, as their first steps
+# built them), ``setup_build_trace_ms`` the tracing of that; ``setup_outside_compile_ms``:
+# the process's compile time under no record at all - the caller's own ``jit``s
+SETUP_COUNTERS = ("setup_init_ms", "setup_build_ms", "setup_build_trace_ms",
+                  "setup_outside_compile_ms", "programs_built", "compile_cache_hits",
+                  "compile_cache_misses")
+
+
+def setup_counters(setup):
+    """``tracing.setup_summary``'s dict → ``SETUP_COUNTERS``' values."""
+    init, build, outside = setup["init_build"], setup["build"], setup["outside"]
+
+    def ms(*ns):
+        return sum(ns) // 1_000_000
+
+    return {"setup_init_ms": ms(setup["init_ns"]),
+            "setup_build_ms": ms(build["trace_ns"], build["lower_ns"], build["backend_ns"]),
+            "setup_build_trace_ms": ms(build["trace_ns"]),
+            "setup_outside_compile_ms": ms(outside["trace_ns"], outside["lower_ns"],
+                                           outside["backend_ns"]),
+            "programs_built": build["programs"],
+            "compile_cache_hits": build["hits"] + init["hits"],
+            "compile_cache_misses": build["misses"] + init["misses"]}
+
+
 class ServingMetrics:
 
     COUNTERS = ("submitted", "admitted", "completed", "cancelled",
@@ -106,7 +137,7 @@ class ServingMetrics:
                 "rejected_adapter",
                 # stalls the gateway found (gateway._check_stall): their number
                 # and, in whole milliseconds, what they took beyond the step
-                "stalls", "stalled_ms")
+                "stalls", "stalled_ms") + SETUP_COUNTERS
 
     def __init__(self, window=1024):
         self._window = window
@@ -211,8 +242,13 @@ class ServingMetrics:
         # the newest `window` step records, like the histograms' percentiles
         recent = itertools.islice(reversed(tuple(tracing.RECORDER.steps)), self._window)
         steps = summarize_steps(r for r in recent if mine is None or r.engine == mine)
+        setup = tracing.setup_summary(mine) if mine is not None else None
         with self._lock:
+            if setup is not None:
+                for name, value in setup_counters(setup).items():
+                    self._counters[name] = max(self._counters[name], int(value))
             return {
+                "setup": setup,
                 "counters": dict(self._counters),
                 "gauges": dict(self._gauges),
                 "external": {p: dict(v) for p, v in self._external.items()},

@@ -283,6 +283,75 @@ def test_the_snapshot_keeps_every_key_with_the_pulled_sources(model_and_params):
     assert set(after["external"]) == set(live["external"])
 
 
+def test_the_trainer_sets_up_in_two_records_and_its_state_is_built_inside_the_first_step():
+    import deepspeed_tpu
+    from deepspeed_tpu.utils import groups
+    from tests.unit.simple_model import SimpleModel
+    groups.destroy_mesh()
+    engine, *_ = deepspeed_tpu.initialize(
+        model=SimpleModel(hidden_dim=16), config={
+            "train_batch_size": 16, "gradient_accumulation_steps": 2,
+            "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 2}, "mesh": {"data_parallel_size": 8}})
+    x = np.random.RandomState(0).randn(16, 16).astype(np.float32)
+    engine.train_batch(batch=(x, np.arange(16) % 16))
+    built, state, step = records_of(engine.trace_id)      # in the order they ended
+    assert [(r["kind"], r["program"]) for r in (built, state, step)] == [
+        ("setup", "engine"), ("setup", "state"), ("train", "train_batch")]
+    assert names(built) == ["ds.setup.partition", "ds.setup.optimizer", "ds.setup.services"]
+    assert names(state) == ["ds.setup.params", "ds.setup.partition", "ds.setup.optimizer"]
+    assert (built["caused_by"], state["caused_by"]) == (0, step["seq"])
+    for record in (built, state):
+        assert ordered(record) and record["process_age_ns"] > 0
+        covered = record["phases"][-1][2] - record["phases"][0][1]
+        assert covered >= 0.95 * (record["end_ns"] - record["start_ns"])
+    assert step["phases"][0][1] <= state["start_ns"] <= state["end_ns"] <= step["phases"][0][2]
+    # the state's programs are the state's row, the step's own program the step's
+    rows = {(row["kind"], row["program"]): row for row in tracing.snapshot()["builds"]
+            if row["engine"] == engine.trace_id}
+    assert rows["setup", "state"]["compiles"] >= 2 and rows["train", "train_batch"]["compiles"] >= 1
+    assert rows["setup", "state"]["seq"] == state["seq"]
+    summary = tracing.setup_summary(engine.trace_id)
+    assert summary["build"]["programs"] == 1 and summary["process_age_ns"] == built["process_age_ns"]
+    assert summary["init_ns"] == sum(r["end_ns"] - r["start_ns"] for r in (built, state))
+    engine.train_batch(batch=(x, np.arange(16) % 16))
+    assert [r["kind"] for r in records_of(engine.trace_id)].count("setup") == 2
+
+
+def test_the_snapshot_says_what_set_up_cost_in_counters_that_only_grow(model_and_params):
+    from deepspeed_tpu.serving.metrics import SETUP_COUNTERS
+    engine = make_engine(model_and_params, n_seqs=8, batch=16)
+    gw = ServingGateway(engine, config=ServingConfig(token_budget=16))
+    idle = gw.snapshot()
+    assert idle["setup"]["build"]["programs"] == idle["counters"]["programs_built"] == 0
+    gw.submit(PROMPT, max_new_tokens=6).result(timeout=120)
+    live = gw.snapshot()
+    setup, counters = live["setup"], live["counters"]
+    assert setup == tracing.setup_summary(engine.trace_id) and setup["engine"] == engine.trace_id
+    assert list(setup["phases_ns"]) == ["ds.setup.params", "ds.setup.pools", "ds.setup.kind",
+                                        "ds.setup.programs"]
+    assert set(SETUP_COUNTERS) == {
+        "setup_init_ms", "setup_build_ms", "setup_build_trace_ms", "setup_outside_compile_ms",
+        "programs_built", "compile_cache_hits", "compile_cache_misses"} <= set(counters)
+    assert all(type(counters[name]) is int for name in SETUP_COUNTERS)
+    assert counters["setup_init_ms"] == setup["init_ns"] // 1_000_000
+    built = setup["build"]
+    assert counters["setup_build_ms"] == (built["trace_ns"] + built["lower_ns"]
+                                          + built["backend_ns"]) // 1_000_000
+    assert counters["setup_build_ms"] > counters["setup_build_trace_ms"] > 0
+    assert counters["programs_built"] == built["programs"] >= 2       # a prompt's and a decode's
+    programs = {row["program"] for row in tracing.snapshot()["builds"]
+                if row["engine"] == engine.trace_id and row["kind"] not in ("setup", "pump")}
+    assert len(programs) == built["programs"]
+    assert {f"serving/count/{name}" for name in SETUP_COUNTERS} <= {
+        tag for tag, _, _ in gw.metrics.events()}
+    # the stall rule and the steps' summary pass the constructor's record by
+    assert "setup" not in live["steps"]["counts"] and counters["stalls"] == 0
+    gw.drain(timeout=60)
+    after = gw.snapshot()["counters"]
+    assert all(after[name] >= counters[name] for name in SETUP_COUNTERS)
+
+
 # ------------------------------------------------------------------------ stalls
 class SkippingClock:
     """``tracing.now_ns`` with seconds that can be skipped: a delay on the

@@ -43,7 +43,8 @@ def serve(model_and_params, n_seqs, sample, max_burst):
         sched.add_request(uid, prompt, max_new_tokens=9,
                           sample=sample and dict(sample, seed=100 + uid))
     out = sched.run_to_completion()
-    records = [r for r in tracing.snapshot()["steps"] if r["engine"] == engine.trace_id]
+    records = [r for r in tracing.snapshot()["steps"]
+               if r["engine"] == engine.trace_id and r["kind"] != "setup"]
     assert set(engine.attention_impls.values()) == {"pallas_paged"}
     engine.destroy()
     return out, records
