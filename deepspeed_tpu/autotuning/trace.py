@@ -414,8 +414,8 @@ def replay_lockstep(gateway, trace: ServingTrace,
         for _ in range(pump_per_arrival):
             gateway._pump_once()
             note_admissions()
-    while gateway._active or len(gateway.queue) > 0:
-        gateway._pump_once()
+    while gateway._active or gateway._ending or len(gateway.queue) > 0:
+        gateway._pump_once()    # _ending: retired, its last tokens not streamed yet
         note_admissions()
     return _finalize(gateway, per_request, admitted_order, handles,
                      time.monotonic() - t0)

@@ -554,6 +554,11 @@ class InferenceEngineV2:
         self.tokens_emitted = 0
         # the step record of the program run last — the scheduler reads its seq
         self.last_step = None
+        # host work a caller leaves for the time a program runs, called between
+        # every fetched program's dispatch and its fetch: the scheduler hands the
+        # step before's tokens to their streams there. The fetch is in a finally:
+        # a dispatched program is fetched whatever this raises
+        self.while_running = None
         self._suspended = {}  # uid -> {"handle": host KV, "seen_tokens": int}
         # Counter-PRNG root for sampling: every sampled token's key folds
         # (request seed, absolute position) into this DS_SEED-derived
@@ -841,11 +846,15 @@ class InferenceEngineV2:
                         arrays, *extra)
             self.count_host_sync()
             self.tokens_emitted += len(batch_uids)
-            self._note_chunks(rec)  # while the program runs
-            with tracing.phase("engine.fetch"):
-                host, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- THE one intended sync per step: callers consume host tokens/logits
-                host = host[:n]
-                self._note_counts(rec, counts)
+            try:
+                self._note_chunks(rec)  # while the program runs
+                if self.while_running is not None:
+                    self.while_running()
+            finally:
+                with tracing.phase("engine.fetch"):
+                    host, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- THE one intended sync per step: callers consume host tokens/logits
+                    host = host[:n]
+                    self._note_counts(rec, counts)
             self.last_step = rec
             return host
 
@@ -1254,10 +1263,14 @@ class InferenceEngineV2:
             # the fetched form reads its entry row from the host every
             # burst (one site a row) and pays the fetch
             self.count_host_sync(n + 1)
-            with tracing.phase("engine.fetch"):
-                toks, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- THE one intended sync per k-step burst
-                toks = toks[:, :n]
-                self._note_counts(rec, counts)
+            try:
+                if self.while_running is not None:
+                    self.while_running()
+            finally:
+                with tracing.phase("engine.fetch"):
+                    toks, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- THE one intended sync per k-step burst
+                    toks = toks[:, :n]
+                    self._note_counts(rec, counts)
             with tracing.phase("engine.log"):
                 if self._log_tokens:
                     # log what the burst actually WROTE to the KV cache: step i
@@ -1523,25 +1536,23 @@ class InferenceEngineV2:
                     key, lambda: self._make_verify_fn(d, sampled, packed=packed))
                 extra = (self.lora_store.slabs(),) if lora_on else ()
                 sargs = (self._base_key,) if sampled else ()
-            if packed:
-                with tracing.phase("engine.dispatch"):
-                    wire, self.kv_cache.k, self.kv_cache.v = fn(
-                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                        *sargs, *extra)
-                self.count_host_sync()
+            with tracing.phase("engine.dispatch"):
+                # result: packed, one vector (tokens, then accept counts); else both
+                *result, self.kv_cache.k, self.kv_cache.v = fn(
+                    self.params, self.kv_cache.k, self.kv_cache.v, meta, *sargs, *extra)
+            self.count_host_sync(1 if packed else 2)
+            try:
+                if self.while_running is not None:
+                    self.while_running()
+            finally:
                 with tracing.phase("engine.fetch"):
-                    wire = np.asarray(wire)  # ds-lint: disable=host-sync -- THE one intended sync per verify burst (packed tokens + accept counts)
-                out = wire[:ms * (d + 1)].reshape(ms, d + 1)
-                acc = wire[ms * (d + 1):].astype(np.int64)
-            else:
-                with tracing.phase("engine.dispatch"):
-                    out, acc, self.kv_cache.k, self.kv_cache.v = fn(
-                        self.params, self.kv_cache.k, self.kv_cache.v, meta,
-                        *sargs, *extra)
-                self.count_host_sync(2)
-                with tracing.phase("engine.fetch"):
-                    out = np.asarray(out)  # ds-lint: disable=host-sync -- THE one intended sync per verify burst
-                    acc = np.asarray(acc)  # ds-lint: disable=host-sync -- host copy of the device result above, already synced
+                    if packed:
+                        wire = np.asarray(result[0])  # ds-lint: disable=host-sync -- THE one intended sync per verify burst (packed tokens + accept counts)
+                        out = wire[:ms * (d + 1)].reshape(ms, d + 1)
+                        acc = wire[ms * (d + 1):].astype(np.int64)
+                    else:
+                        out = np.asarray(result[0])  # ds-lint: disable=host-sync -- THE one intended sync per verify burst
+                        acc = np.asarray(result[1])  # ds-lint: disable=host-sync -- host copy of the device result above, already synced
             n = len(batch_uids)
             with tracing.phase("engine.log"):
                 written = self.state_manager.rows_written

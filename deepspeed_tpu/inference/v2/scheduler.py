@@ -9,6 +9,7 @@ prompts are SPLIT across steps, decodes are FUSED into prefill steps,
 so step latency stays flat and the MXU stays fed."""
 
 from collections import OrderedDict, deque
+import functools
 
 import numpy as np
 
@@ -72,7 +73,7 @@ class DynamicSplitFuseScheduler:
     ``max_new_tokens``."""
 
     def __init__(self, engine, token_budget=None, sample_fn=None, eos_token_id=None,
-                 max_burst=16, sampling=None, on_token=None):
+                 max_burst=16, sampling=None, on_tokens=None):
         self.engine = engine
         self.budget = int(token_budget or engine.max_tokens)
         if self.budget > engine.max_tokens:
@@ -101,9 +102,24 @@ class DynamicSplitFuseScheduler:
         self.max_burst = max(1, int(max_burst)) if self._device_greedy else 1
         self.sample_fn = sample_fn or (lambda logits: int(np.argmax(logits)))
         self.eos_token_id = eos_token_id
-        # on_token(uid, token, done): called for every accepted token —
-        # the serving gateway's streaming hook. None = no streaming.
-        self.on_token = on_token
+        # on_tokens(rows, in_flight): the serving gateway's streaming hook,
+        # one call for an engine call's worth of accepted tokens. rows:
+        # [(uid, token, done)] in the order accepted — a stream's tokens in
+        # order, its done row last; in_flight: a program is on the device
+        # meanwhile. None = no streaming.
+        self.on_tokens = on_tokens
+        # accepted and not handed over yet: the rows wait for the NEXT
+        # program's dispatch, and the engine runs hand_over while that
+        # program runs (engine.while_running) — nothing the next plan needs
+        # is in the hook, so the device does not wait for it. An engine
+        # that never runs it loses nothing: see _ran
+        self._rows = []
+        engine.while_running = None if on_tokens is None \
+            else functools.partial(self.hand_over, in_flight=True)
+        # uids whose last token was accepted (their rows may still be in
+        # _rows): the owner takes the list and retires them, which frees
+        # their room for the next admission at once
+        self.ended = []
         self.requests = OrderedDict()  # uid -> Request
         # how many bursts stay in flight, unfetched (the engine config's
         # async_burst.depth): the pump dispatches burst k+1 while burst k
@@ -188,6 +204,7 @@ class DynamicSplitFuseScheduler:
         if r is None:
             raise KeyError(f"unknown request {uid}")
         self._drain_if_inflight(r)
+        self.hand_over()  # its stream holds every token this returns
         if not r.done:
             r.done = True
             r.next_token = None
@@ -219,6 +236,7 @@ class DynamicSplitFuseScheduler:
         if r.done or r.paused:
             raise ValueError(f"request {uid} is not pausable (done={r.done})")
         self._drain_if_inflight(r)
+        self.hand_over()  # no dispatch may follow: every request paused
         if r.done:
             raise ValueError(f"request {uid} finished while its pipelined "
                              f"bursts drained — not pausable")
@@ -285,10 +303,25 @@ class DynamicSplitFuseScheduler:
 
     def _ran(self):
         """The engine call just returned: note the seq of the step record
-        it wrote, so that what is accepted next can point at it."""
+        it wrote, so that what is accepted next can point at it. Rows still
+        waiting were not handed over while its program ran (an engine
+        without ``while_running``): they go now, before this call's."""
+        self.hand_over()
         rec = getattr(self.engine, "last_step", None)
         self.last_step_seq = rec.seq if rec is not None else 0
         return rec
+
+    def hand_over(self, in_flight=False):
+        """Give the streaming hook the rows accepted since the last
+        hand-over, in one call; → how many. Whoever stops the next dispatch
+        from coming calls this first, so no row waits for it."""
+        rows = self._rows
+        if not rows:
+            return 0
+        self._rows = []
+        with tracing.phase("sched.deliver"):
+            self.on_tokens(rows, in_flight)
+        return len(rows)
 
     def _plan_burst(self, rows):
         """Burst length for ``rows``, or None when the burst path does
@@ -460,7 +493,8 @@ class DynamicSplitFuseScheduler:
         run to their planned end) on top of the request's ``_inflight``
         debt. ``settle=False`` leaves the engine side of an ending to
         :meth:`_drain_pipeline`: younger bursts are still running over
-        the sequence's KV reservation."""
+        the sequence's KV reservation. What the next plan needs is done
+        here; what a client sees is a row left for :meth:`hand_over`."""
         r.generated.append(tok)
         if len(r.generated) == 1:
             r.first_token_seq = self.last_step_seq
@@ -478,8 +512,10 @@ class DynamicSplitFuseScheduler:
                 self._settle(r)
         else:
             r.next_token = tok
-        if self.on_token is not None:
-            self.on_token(r.uid, tok, r.done)
+        if self.on_tokens is not None:
+            self._rows.append((r.uid, tok, r.done))
+            if r.done:
+                self.ended.append(r.uid)
 
     def _settle(self, r):
         """The engine side of an ending: rewind the KV positions
@@ -519,9 +555,17 @@ class DynamicSplitFuseScheduler:
         return uids
 
     def step(self):
-        """Schedule + run one engine step; returns the uids stepped.
+        """Schedule + run one engine step; returns the uids stepped. What
+        it accepts is handed to the streaming hook while the NEXT step's
+        program runs — or here, when this step dispatched nothing or left
+        bursts in flight (a pipeline fences a burst late as it is)."""
+        stepped = self._step()
+        if not stepped or self.async_depth:
+            self.hand_over()
+        return stepped
 
-        The order of the decode paths is decided here and nowhere else.
+    def _step(self):
+        """The order of the decode paths is decided here and nowhere else.
         The drafter goes first, and a running pipeline yields to it: it
         proposes from tokens the host has fetched, which a pipeline
         learns one burst late, so with speculative decoding armed the

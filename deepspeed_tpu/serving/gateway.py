@@ -11,7 +11,14 @@ for the host-sync cadence that dominates ragged-serving wall time.
 Layering (everything engine-side stays single-threaded in the pump):
 
     client threads --submit()--> AdmissionQueue --pump--> scheduler --> engine
-                   <--handle.tokens() stream-- on_token callback <--+
+                   <--handle.tokens() stream-- on_tokens(rows) <----+
+
+A step's tokens reach their streams while the NEXT step's program runs:
+the scheduler keeps the rows it accepted and the engine runs its hand-over
+between that program's dispatch and its fetch, so the device does not wait
+for what only a client reads (``_on_tokens``). What frees room - retiring a
+finished request, its gate commitment - is done in the pass that accepted
+its last token (``_retire``).
 
 Admission is KV-block aware (:class:`CapacityGate`): a request enters
 the scheduler only when its full worst-case footprint fits the pool next
@@ -202,7 +209,7 @@ class ServingGateway:
             eos_token_id=cfg.eos_token_id,
             max_burst=cfg.max_burst,
             sampling=cfg.sampling,
-            on_token=self._on_token)
+            on_tokens=self._on_tokens)
         self.metrics = ServingMetrics(window=cfg.metrics_window)
         # gauges and subsystem stats are read when somebody asks
         # (snapshot() / events()), not pushed on every pump pass
@@ -231,7 +238,9 @@ class ServingGateway:
         self._uids = itertools.count()
         self._active = {}    # uid -> handle, admitted to the scheduler
         self._paused = []    # uids preempted (KV suspended), admission order
-        self._finished = []  # uids completed during the current step
+        # uid -> (handle, its scheduler.Request): retired, and its last
+        # tokens are still on their way to the stream (_on_tokens ends it)
+        self._ending = {}
         self._cancels = []   # handles with a pending cancel request
         self._cancel_lock = tracked_lock(threading.Lock(),
                                          "ServingGateway._cancel_lock")
@@ -473,7 +482,7 @@ class ServingGateway:
         if thread is None:
             # manual-pump mode (auto_start=False): drive the pump inline
             deadline = time.monotonic() + timeout
-            while self._active or len(self.queue) > 0:
+            while self._active or self._ending or len(self.queue) > 0:
                 if time.monotonic() > deadline:
                     raise TimeoutError(
                         f"drain: in-flight requests still running after "
@@ -668,6 +677,12 @@ class ServingGateway:
         for entry in self.queue.candidates():
             self.queue.remove(entry)
             self._end(entry, "failed", error)
+        try:
+            # what was generated is streamed before a handle fails (and a
+            # retired request whose tokens all arrive has completed)
+            self.scheduler.hand_over()
+        except Exception:
+            pass  # the hook itself may be what killed the pump
         for uid, handle in list(self._active.items()):
             try:
                 self.scheduler.cancel(uid)
@@ -675,7 +690,10 @@ class ServingGateway:
                 pass
             self._end(handle, "failed", error,
                       request=self.scheduler.requests.get(uid))
+        for handle, request in self._ending.values():
+            self._end(handle, "failed", error, request=request)
         self._active.clear()
+        self._ending.clear()
         self._paused = []
 
     def __enter__(self):
@@ -701,7 +719,7 @@ class ServingGateway:
                 self._fail_outstanding(GatewayFailedError(
                     f"serving pump died: {type(e).__name__}: {e}"))
                 return
-            in_flight = bool(self._active) or len(self.queue) > 0
+            in_flight = bool(self._active or self._ending) or len(self.queue) > 0
             if not in_flight and self._state == "draining":
                 return
             if not did_work:
@@ -725,8 +743,8 @@ class ServingGateway:
                 if not refreshing:  # admission held while a weight swap is staged
                     did |= self._admit()
                 did |= self._resume_paused()
-            stepped = self._step()
-            did |= stepped
+            progressed, stepped = self._step()
+            did |= progressed
             rec.keep = did
             if did:
                 rec.waited_ns, rec.idle_passes = self._waited_ns, self._idle_passes
@@ -878,7 +896,7 @@ class ServingGateway:
         for handle in cancels:
             if handle.done:
                 continue
-            did |= self._terminate(handle, "cancelled", RequestCancelledError(
+            did |= self._terminate(handle, "cancelled", lambda: RequestCancelledError(
                 f"request {handle.uid} cancelled after "
                 f"{len(handle._collected)} tokens"), "cancelled")
         return did
@@ -887,28 +905,35 @@ class ServingGateway:
         now = time.perf_counter()  # the clock of submitted_at and deadline
         did = False
         for entry in self.queue.expired(now):
-            did |= self._terminate(entry, "deadline", DeadlineExceededError(
+            did |= self._terminate(entry, "deadline", lambda: DeadlineExceededError(
                 f"request {entry.uid} expired in queue after "
                 f"{(now - entry.submitted_at) * 1e3:.0f}ms"), "deadline_expired")
         for uid, handle in list(self._active.items()):
             if handle.deadline is not None and now >= handle.deadline:
-                did |= self._terminate(handle, "deadline", DeadlineExceededError(
+                did |= self._terminate(handle, "deadline", lambda: DeadlineExceededError(
                     f"request {uid} exceeded its deadline mid-generation "
                     f"({len(handle._collected)} tokens generated)"),
                     "deadline_expired")
         return did
 
     def _terminate(self, handle, status, error, counter):
-        """Stop a queued or active request with the given terminal state."""
+        """Stop a queued or active request with the given terminal state.
+        ``error()`` makes its error, here and at once, when the stream holds
+        every token the request generated (``scheduler.cancel`` hands over
+        what waited)."""
         uid = handle.uid
         request = None
         if uid in self._active:
             self.scheduler.cancel(uid)
+            if uid not in self._active:
+                # its last token was in a pipelined burst that cancel drained:
+                # it has completed, retired and ended with its tokens (_on_tokens)
+                return True
             request = self.scheduler.retire(uid)
             self._release(handle)
         elif not self.queue.remove(handle):
-            return False  # already finished concurrently
-        return self._end(handle, status, error, counter, request)
+            return False  # already finished concurrently, or ending (_ending)
+        return self._end(handle, status, error(), counter, request)
 
     def _release(self, handle):
         self.gate.release(len(handle.prompt), handle.max_new_tokens)
@@ -1009,30 +1034,41 @@ class ServingGateway:
         return did
 
     def _step(self):
+        """→ (a request made progress, an engine step ran)."""
         if not any(uid not in self._paused for uid in self._active):
             self._last_engine_rec = None   # nothing to run: what follows is no stall
-            return False
+            # ... and no dispatch follows for the last step's tokens to ride
+            return self.scheduler.hand_over() > 0, False
         stepped = self.scheduler.step()
         self.metrics.count("engine_steps")
-        if not stepped and not self._finished:
+        ended, self.scheduler.ended = self.scheduler.ended, []
+        if not stepped and not ended:
             # every live request is schedulable yet nothing ran — a real
             # stall would spin the pump forever; fail fast instead
             raise RuntimeError(
                 f"scheduler stalled with {len(self._active)} active requests")
         with tracing.phase("gateway.deliver"):
-            for uid in self._finished:
-                handle = self._active.get(uid)
-                if handle is None:
-                    continue
-                request = self.scheduler.retire(uid)
-                self._release(handle)
-                if self.role == "prefill":
-                    # retire first: the release path folds the request's
-                    # full blocks into the trie, which is what export walks
-                    self._export_handoff(handle)
-                self._end(handle, "completed", request=request)
-            self._finished = []
-        return True
+            for uid in ended:
+                self._retire(uid)
+        return True, True
+
+    def _retire(self, uid):
+        """A request's last token was accepted: give its room back now, in
+        the pass that accepted it, so that the next ``_admit`` sees it. What
+        its client sees - the last tokens, the end of the stream, the
+        request record - follows with the tokens (``_on_tokens``)."""
+        handle = self._active.get(uid)
+        if handle is None:
+            return
+        request = self.scheduler.retire(uid)
+        self._release(handle)
+        if self.role == "prefill":
+            # retire first: the release path folds the request's full
+            # blocks into the trie, which is what export walks. Here, not
+            # with the tokens: the export gathers from the pool, which a
+            # dispatched program holds until it ends
+            self._export_handoff(handle)
+        self._ending[uid] = (handle, request)
 
     def _export_handoff(self, handle):
         """Prefill-role finish hook (pump thread only — the export
@@ -1077,31 +1113,56 @@ class ServingGateway:
         self.metrics.count("handoffs_imported")
         return n
 
-    def _on_token(self, uid, token, done):
-        """Streaming hook, called by the scheduler for every accepted
-        token (pump thread)."""
-        handle = self._active.get(uid)
-        if handle is None:
-            return
+    def _on_tokens(self, rows, in_flight):
+        """Streaming hook (pump thread): the scheduler's rows ``(uid, token,
+        done)`` of one engine call, handed over while the next program runs
+        (``in_flight``) or with none dispatched. This is when a client can
+        see them, so the first-token stamps and ``ttft_s`` are taken here:
+        one clock reading a batch, and the metrics' lock once a batch for
+        each kind of observation."""
         now = tracing.now_ns()
-        if handle.first_token_ns is None:
-            handle.first_token_ns = now
-            handle.ttft_s = (now - handle.submitted_ns) / 1e9
-            request = self.scheduler.requests.get(uid)
-            scheduled = request.first_scheduled_ns if request is not None else None
-            # ttft = queue_wait (submitted → admitted) + sched_wait + prefill_span
-            self.metrics.observe_first_token(
-                handle.ttft_s,
-                sched_wait_s=None if scheduled is None
-                else (scheduled - handle.admitted_ns) / 1e9,
-                prefill_span_s=None if scheduled is None else (now - scheduled) / 1e9)
-        else:
-            self.metrics.observe_token_latency((now - handle.last_token_ns) / 1e9)
-        handle.last_token_ns = now
-        handle._emit(int(token))
-        self.metrics.count("tokens_generated")
-        if done:
-            self._finished.append(uid)
+        active, ending = self._active, self._ending
+        firsts, latencies = [], []
+        for uid, token, done in rows:
+            handle = active.get(uid)
+            if handle is None:
+                retired = ending.get(uid)
+                if retired is None:
+                    continue    # cancelled meanwhile: its stream has ended
+                handle = retired[0]
+            if handle.first_token_ns is None:
+                firsts.append(self._first_token(handle, now))
+            else:   # 0 for a burst's later tokens: they arrive with its first
+                latencies.append((now - handle.last_token_ns) / 1e9)
+            handle.last_token_ns = now
+            handle._emit(int(token))
+            if done:
+                self._retire(uid)   # where its pass has not yet (a pipeline's drain)
+                handle, request = ending.pop(uid)
+                self._end(handle, "completed", request=request)
+        n = len(firsts) + len(latencies)
+        if n:
+            self.metrics.count("tokens_generated", n)
+            self.metrics.count("tokens_delivered_in_flight" if in_flight
+                               else "tokens_delivered_idle", n)
+        if firsts:
+            self.metrics.observe_first_tokens(firsts)
+        if latencies:
+            self.metrics.observe_token_latencies(latencies)
+
+    def _first_token(self, handle, now):
+        """Stamp a request's first token → what ``observe_first_tokens``
+        takes of it: ttft = queue_wait (submitted → admitted) + sched_wait
+        + prefill_span, in seconds."""
+        handle.first_token_ns = now
+        handle.ttft_s = (now - handle.submitted_ns) / 1e9
+        ended = self._ending.get(handle.uid)
+        request = ended[1] if ended else self.scheduler.requests.get(handle.uid)
+        scheduled = request.first_scheduled_ns if request is not None else None
+        if scheduled is None:
+            return handle.ttft_s, None, None
+        return (handle.ttft_s, (scheduled - handle.admitted_ns) / 1e9,
+                (now - scheduled) / 1e9)
 
     # ------------------------------------------------------------------ misc
     @property

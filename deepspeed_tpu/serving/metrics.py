@@ -132,6 +132,9 @@ class ServingMetrics:
                 "rejected_queue_full", "rejected_too_large", "shed",
                 "deadline_expired", "preemptions", "resumes",
                 "tokens_generated", "engine_steps", "failed",
+                # of tokens_generated: handed to their streams while the next
+                # program ran, and with none dispatched (gateway._on_tokens)
+                "tokens_delivered_in_flight", "tokens_delivered_idle",
                 "handoffs_exported", "handoffs_imported",
                 "weight_refreshes", "rejected_unknown_adapter",
                 "rejected_adapter",
@@ -169,19 +172,23 @@ class ServingMetrics:
         with self._lock:
             self.ttft.observe(seconds * 1e3)
 
-    def observe_first_token(self, ttft_s, sched_wait_s=None, prefill_span_s=None):
-        """A request's first token: TTFT and the two spans of it that
-        follow admission (None: the scheduler stamped none)."""
+    def observe_first_tokens(self, firsts):
+        """First tokens of requests, a batch at a time: ``(ttft_s,
+        sched_wait_s, prefill_span_s)`` each - TTFT and the two spans of it
+        that follow admission (None: the scheduler stamped none)."""
         with self._lock:
-            self.ttft.observe(ttft_s * 1e3)
-            if sched_wait_s is not None:
-                self.sched_wait.observe(sched_wait_s * 1e3)
-            if prefill_span_s is not None:
-                self.prefill_span.observe(prefill_span_s * 1e3)
+            for ttft_s, sched_wait_s, prefill_span_s in firsts:
+                self.ttft.observe(ttft_s * 1e3)
+                if sched_wait_s is not None:
+                    self.sched_wait.observe(sched_wait_s * 1e3)
+                if prefill_span_s is not None:
+                    self.prefill_span.observe(prefill_span_s * 1e3)
 
-    def observe_token_latency(self, seconds):
+    def observe_token_latencies(self, seconds):
+        """Gaps between two tokens of a stream, a batch at a time."""
         with self._lock:
-            self.token_latency.observe(seconds * 1e3)
+            for s in seconds:
+                self.token_latency.observe(s * 1e3)
 
     def observe_queue_wait(self, seconds):
         with self._lock:

@@ -94,9 +94,10 @@ now_ns = time.perf_counter_ns
 cpu_ns = time.thread_time_ns
 # the phases at whose exit the thread's CPU clock is read (a record's
 # cpu_marks): between two marks lie, in a serving step, the host's work after
-# a result (accept), its work before the next launch (deliver, admit, plan,
-# pack) and the program itself (dispatch, fetch); a training step's phases
-# are few and long, so all of its own are marked
+# a result (accept), its work before the next launch (retire, admit, plan,
+# pack) and the program itself (dispatch, the hand-over of the step before's
+# tokens, fetch); a training step's phases are few and long, so all of its
+# own are marked
 CPU_MARKED = frozenset(PREFIX + name for name in (
     "engine.pack", "engine.fetch", "sched.accept",
     "train.prepare", "train.dispatch", "train.sync", "train.post"))

@@ -546,7 +546,11 @@ def test_sampled_stream_kill_midgeneration_replays_bit_identical(
     peer = GatewayReplica("r1", factory, serving_config=scfg)
     router = FleetRouter([faulty, peer],
                          config=FleetConfig(retry_backoff_s=0.01,
-                                            stream_token_timeout_s=9.0),
+                                            # r0 crashes, nothing hangs: room for the two cold
+                                            # compiles (checkified under DS_SANITIZE) a token
+                                            # now waits for - its own program's and the next
+                                            # one's, whose dispatch hands it over
+                                            stream_token_timeout_s=20.0),
                          auto_heartbeat=False)
     streams, errors = drive(router)
     assert not errors, {i: str(e) for i, e in errors.items()}

@@ -344,7 +344,7 @@ class TestGatewayRefresh:
         # h1 was in flight when the swap staged: old weights end to end
         assert list(h1.tokens(timeout=5.0)) == VersionedEngine.stream(12, 4, 0)
         # h2 was queued behind the held admission: new weights end to end
-        pump_until(gw, lambda: sum(gw.inflight().values()) == 0)
+        pump_until(gw, lambda: h2.done)   # a step's tokens: behind the next dispatch, or a pass that has none
         assert list(h2.tokens(timeout=5.0)) == VersionedEngine.stream(6, 3, 1)
         assert gw.metrics.snapshot()["counters"].get("failed", 0) == 0
         gw.shutdown()
@@ -356,7 +356,7 @@ class TestGatewayRefresh:
         eng = refresh_engine()
         gw = make_gateway(eng, role="prefill")
         h = gw.submit(PROMPT, max_new_tokens=2)
-        pump_until(gw, lambda: sum(gw.inflight().values()) == 0)
+        pump_until(gw, lambda: h.done)
         list(h.tokens(timeout=5.0))
         assert len(gw._handoffs) == 1  # prefill finish exported a record
         stale = record_for(PROMPT, 0)
@@ -385,7 +385,7 @@ class TestGatewayRefresh:
         assert gw.weight_version == 0 and eng.swaps == []
         assert gw._pending_refresh is None  # withdrawn; admission resumes
         # the in-flight stream was never disturbed: full length, old weights
-        pump_until(gw, lambda: sum(gw.inflight().values()) == 0, n=400)
+        pump_until(gw, lambda: h.done, n=400)
         assert list(h.tokens(timeout=5.0)) == VersionedEngine.stream(12, 30, 0)
         # and a later unhurried refresh adopts cleanly
         assert gw.refresh_weights(params_for(1), 1, timeout=5.0) == 1

@@ -5,6 +5,7 @@ that are ordered and point at records that exist, and a gateway snapshot
 that keeps every key it had when gauges were pushed each pump pass."""
 
 import contextlib
+import threading
 
 import numpy as np
 import pytest
@@ -158,6 +159,45 @@ def test_a_rejected_batch_writes_no_record(model_and_params):
     engine.destroy()
 
 
+@pytest.mark.parametrize("path", ["put", "decode_burst", "verify_burst"])
+def test_a_dispatched_program_is_fetched_whatever_the_work_meanwhile_raises(
+        model_and_params, monkeypatch, path):
+    """``engine.while_running`` (the scheduler's hand-over) runs between a
+    program's dispatch and its fetch; if it raises, the fetch still
+    happens, the exception reaches the caller, and the engine goes on."""
+    engine = make_engine(model_and_params, spec=path == "verify_burst")
+    first = engine.put([1], [REPETITIVE], sample="greedy")
+    run = {"put": lambda tok: engine.put([1], [[tok]], sample="greedy"),
+           "decode_burst": lambda tok: engine.decode_burst([1], [tok], 2),
+           "verify_burst": lambda tok: engine.verify_burst([1], [[tok]], [[8, 9, 10]])}[path]
+    entered, real_phase, me = [], tracing.phase, threading.get_ident()
+
+    def phase(name):
+        if threading.get_ident() == me:     # another test's pump thread may still be polling
+            entered.append(name)
+        return real_phase(name)
+    monkeypatch.setattr(tracing, "phase", phase)
+
+    def work():
+        entered.append("work")
+        raise RuntimeError("a stream's queue broke")
+    engine.while_running = work
+    mark, seen = last_seq(), engine.query(1)[0]
+    with pytest.raises(RuntimeError, match="queue broke"):
+        run(int(first[0]))
+    assert entered[-3:] == ["engine.dispatch", "work", "engine.fetch"]
+    assert records_of(engine.trace_id, mark) == [] and tracing.current() is None
+    # the program ran and its rows are written; a verify counts them once it knows how many
+    # were accepted, which it was not told: the same rows are written again
+    assert (engine.query(1)[0] > seen) == (path != "verify_burst")
+    engine.while_running = lambda: entered.append("work")
+    del entered[:]
+    run(int(first[0]))                          # ... and the next runs, the work inside it
+    assert entered.index("engine.dispatch") < entered.index("work") < entered.index("engine.fetch")
+    assert len(records_of(engine.trace_id, mark)) == 1
+    engine.destroy()
+
+
 def test_the_scheduler_says_which_tokens_are_prompt(model_and_params):
     """A one-token prompt chunk looks like a decode token to the engine;
     ``_plan`` knows better and corrects the record."""
@@ -223,6 +263,20 @@ def test_request_stamps_are_ordered_and_point_at_records_that_exist(model_and_pa
     busy = [r for r in steps if r["kind"] == "pump" and "ds.sched.plan" in names(r)]
     assert busy and all(names(r)[0] == "ds.gateway.admit" and names(r)[-1] == "ds.gateway.deliver"
                         and ordered(r) for r in busy)
+    # a step's tokens are handed over inside the next engine record, while its program runs
+    # (a pipeline, which fences a burst late as it is, hands over as it accepts)
+    counters = gw.snapshot()["counters"]
+    flown, idle = counters["tokens_delivered_in_flight"], counters["tokens_delivered_idle"]
+    assert flown + idle == counters["tokens_generated"] == 6 * len(handles)
+    delivering = [r for r in engine_records if "ds.sched.deliver" in names(r)]
+    if depth:
+        assert delivering == [] and flown == 0
+        return
+    assert {r["kind"] for r in delivering} == {"put", "burst"} and flown > idle
+    for r in delivering:
+        at = {name: (enter, exit_) for name, enter, exit_ in r["phases"]}
+        assert at["ds.engine.dispatch"][1] <= at["ds.sched.deliver"][0]
+        assert at["ds.sched.deliver"][1] <= at["ds.engine.fetch"][0] and ordered(r)
 
 
 def test_an_idle_gateway_writes_nothing_and_a_request_that_never_ran_has_no_step(model_and_params):
@@ -387,33 +441,48 @@ def stalls_of(engine):
     return [e for e in tracing.snapshot()["events"] if e["kind"] == "stall" and e["seq"] in mine]
 
 
-@pytest.mark.parametrize("where", ["inside", "between"])
+# where a delay lies -> (the stall's ``where``, the phase that held most of it)
+DELAYS = {"inside": ("inside", "ds.engine.fetch"), "between": ("between", "ds.sched.accept"),
+          "deliver": ("inside", "ds.sched.deliver")}
+
+
+@pytest.mark.parametrize("delay", list(DELAYS))
 def test_a_delay_after_eight_ordinary_steps_is_a_stall_with_its_place_named(
-        model_and_params, monkeypatch, where):
+        model_and_params, monkeypatch, delay):
+    where, held = DELAYS[delay]
     clock = SkippingClock(monkeypatch)
     engine, gw = stall_gateway(model_and_params)
     handle = gw.submit(PROMPT, max_new_tokens=24)
     armed = {"at": None}
-    if where == "inside":          # 0.4 s pass inside one put's wait for the device
+
+    def skip_once():
+        if armed["at"] == len(handle._collected):
+            armed["at"] = None
+            clock.skip(400)
+    if delay == "inside":          # 0.4 s pass inside one put's wait for the device
         real_phase = tracing.phase
 
         @contextlib.contextmanager
         def phase(name):
             with real_phase(name):
-                if name == "engine.fetch" and armed["at"] == len(handle._collected):
-                    armed["at"] = None
-                    clock.skip(400)
+                if name == "engine.fetch":
+                    skip_once()
                 yield
         monkeypatch.setattr(tracing, "phase", phase)
-    else:                          # ... or in the delivery of one step's token
-        deliver = gw.scheduler.on_token
+    elif delay == "between":       # ... or between two puts, where a step's token is accepted
+        accept = gw.scheduler._accept_token
 
-        def on_token(uid, token, done):
-            if armed["at"] == len(handle._collected):
-                armed["at"] = None
-                clock.skip(400)
-            deliver(uid, token, done)
-        gw.scheduler.on_token = on_token
+        def accept_token(r, tok, **kwargs):
+            skip_once()
+            accept(r, tok, **kwargs)
+        gw.scheduler._accept_token = accept_token
+    else:                          # ... or in its delivery, which the next put's record holds
+        deliver = gw.scheduler.on_tokens
+
+        def on_tokens(rows, in_flight):
+            skip_once()
+            deliver(rows, in_flight)
+        gw.scheduler.on_tokens = on_tokens
     # the warm-up's steps: a delay there (as the compiles themselves are) is no stall
     armed["at"] = 3
     pump_until(gw, lambda: len(handle._collected) >= 10)
@@ -429,8 +498,7 @@ def test_a_delay_after_eight_ordinary_steps_is_a_stall_with_its_place_named(
     record = next(r for r in tracing.snapshot()["steps"] if r["seq"] == stall["seq"])
     assert (stall["record_kind"], stall["program"], stall["n_seqs"], stall["n_tokens"]) == \
         (record["kind"], record["program"], 1, 1) == ("put", "8", 1, 1)
-    assert stall["where"] == where
-    assert stall["phase"] == ("ds.engine.fetch" if where == "inside" else "ds.sched.accept")
+    assert stall["where"] == where and stall["phase"] == held
     assert abs(stall["excess_ms"] - 400) < 100 and 0 < stall["expected_ms"] < 100
     assert stall["end_ns"] == record["end_ns"] and stall["end_ns"] - stall["start_ns"] >= 400e6
     # the pump thread did not compute through it, nothing was compiled or collected for long

@@ -46,6 +46,8 @@ _HOT_PATHS = {
         "DynamicSplitFuseScheduler._try_burst",
         "DynamicSplitFuseScheduler._try_spec_burst",
         "DynamicSplitFuseScheduler.step",
+        "DynamicSplitFuseScheduler._step",
+        "DynamicSplitFuseScheduler.hand_over",
         # the planner and the accept side every burst runs through, and
         # the pipeline (async_burst.depth > 0): a stray sync here stalls
         # the double buffer — the ONE intended sync lives in
@@ -63,7 +65,8 @@ _HOT_PATHS = {
         "ServingGateway._process_cancels",
         "ServingGateway._process_deadlines",
         "ServingGateway._resume_paused",
-        "ServingGateway._on_token",
+        "ServingGateway._retire",
+        "ServingGateway._on_tokens",
     },
     "inference/v2/engine_v2.py": {
         "InferenceEngineV2.put",
