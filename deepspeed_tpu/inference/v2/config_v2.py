@@ -136,6 +136,10 @@ class RaggedInferenceEngineConfig(DeepSpeedConfigModel):
     implementation_overrides: dict = {}
     kv_block_size: int = 16
     num_kv_blocks: int = 0  # 0 = derive from max_context * max sequences
+    # a model kind with window layers keeps their keys and values in a pool of its own
+    # (ragged/kv_cache.WindowPool): its blocks; 0 = every tracked sequence's bound
+    # between steps + one step's rows
+    num_window_blocks: int = 0
     state_manager: DSStateManagerConfig = DSStateManagerConfig()
     quantization: QuantizationConfig = QuantizationConfig()
     prefix_cache: PrefixCacheConfig = PrefixCacheConfig()

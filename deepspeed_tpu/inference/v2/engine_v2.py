@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
 from deepspeed_tpu.inference.v2.model_runner import kind_of, ragged_forward
-from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
+from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache, WindowPool
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
 from deepspeed_tpu.inference.v2.ragged.slot_pool import SlotPool
@@ -300,18 +300,35 @@ class InferenceEngineV2:
         self.state_kind = kind.state_kind
         self._state_step_said = set()       # the programs whose state step has been logged
         self.state_bytes_per_token = self.kv_cache.bytes_per_token()
-        self.state_manager = DSStateManager(self.kv_cache, int(sm.max_tracked_sequences),
+        # A kind with window layers: a second pool for their keys and values, whose blocks
+        # a sequence gives back as they fall behind its window. Its device arrays are the
+        # programs' ``extra`` (carried and donated like any kind's), its table a sequence
+        # the batch's ``seq_state`` row (a ring: ragged_manager); None for every other kind.
+        self.window_pool = None
+        self._seq_rows = kind.seq_rows
+        slots = int(sm.max_tracked_sequences)
+        window = kind.window(cfg)
+        if window is not None:
+            positions, window_layers = window
+            self.window_pool = WindowPool(
+                positions, self.block_size, self.max_tokens,
+                int(self._config.num_window_blocks) or WindowPool.default_blocks(
+                    positions, self.block_size, self.max_tokens, slots))
+            self._seq_rows = self.window_pool.ring
+        self.state_manager = DSStateManager(self.kv_cache, slots,
                                             max_blocks_per_seq=self.max_blocks_per_seq,
-                                            seq_rows=kind.seq_rows)
+                                            seq_rows=self._seq_rows,
+                                            window_pool=self.window_pool)
         # State beyond the two paged pools, where the model kind keeps any: its own tree
         # of device arrays, carried and donated through every program beside the pools,
         # and a slot of it a tracked sequence (ragged/slot_pool.py). None for a kind
         # that has none, whose programs are then the ones they were.
         self.slot_pool = None
-        self._seq_rows = kind.seq_rows
-        slots = int(sm.max_tracked_sequences)
-        self.state_extra = kind.extra_state(cfg, num_blocks, slots, dtype)
-        if self.state_extra is not None:
+        if window is not None:
+            self.state_extra = self.window_pool.arrays(window_layers, kind.state_rows(cfg)[0], dtype)
+        else:
+            self.state_extra = kind.extra_state(cfg, num_blocks, slots, dtype)
+        if self.state_extra is not None and kind.slot_state:
             # a slot is one row of each entry the kind names (their second axis)
             self.slot_pool = SlotPool(slots, sum(
                 self.state_extra[name].nbytes // self.state_extra[name].shape[1]
@@ -775,6 +792,7 @@ class InferenceEngineV2:
                     raise RuntimeError(f"KV pool exhausted: need {blocks_needed} blocks, "
                                        f"{self._reclaimable_blocks()} reclaimable — "
                                        f"flush() sequences first")
+                need_window = self._window_need(descs, lens)
                 new_seqs = descs.count(None)
                 if new_seqs + sm.n_tracked_sequences > sm.max_tracked_sequences:
                     raise RuntimeError("max_tracked_sequences exceeded for this batch")
@@ -784,6 +802,8 @@ class InferenceEngineV2:
                     descs = [sm.get_or_create_sequence(uid) if desc is None else desc
                              for uid, desc in zip(batch_uids, descs)]
                 sm.reserve(descs, need)
+                if need_window is not None:
+                    sm.reserve_window(descs, need_window)
                 adapters = self._seat(descs)    # sequence i takes row i of the step's tables
                 self._batch.clear()
                 _, seq_state = sm.gather(descs, out=self._batch.block_tables[:n])
@@ -791,6 +811,8 @@ class InferenceEngineV2:
                                          seq_state=seq_state)
                 for desc, new in zip(descs, lens.tolist()):
                     desc.advance(new)
+                if need_window is not None:
+                    sm.release_behind(descs)    # the step's tables are gathered: see _window_need
                 rec.n_ctx_tokens += int((seen + lens).sum())
                 rec.n_table_rows_written = sm.rows_written - written
                 if self._log_tokens:
@@ -1031,6 +1053,22 @@ class InferenceEngineV2:
                            np.int64, n)
         return seen, np.maximum(0, -(-(seen + new_tokens) // self.block_size) - held)
 
+    def _window_need(self, descs, new_tokens):
+        """→ the window-pool blocks each of ``descs`` lacks to hold
+        ``new_tokens`` more (``DSStateManager.window_need``), None for a kind
+        without window layers; raises where the pool cannot give them. A
+        step reserves them with the full pool's, gathers its tables, advances
+        its sequences and then gives back what fell behind their windows
+        (``release_behind``): the program it dispatches reads those blocks,
+        and whatever writes them next is dispatched after it."""
+        if self.window_pool is None:
+            return None
+        need = self.state_manager.window_need(descs, new_tokens)
+        if int(need.sum()) > self.window_pool.free_blocks:
+            raise RuntimeError(f"window pool exhausted: need {int(need.sum())} blocks, "
+                               f"{self.window_pool.free_blocks} free — flush() sequences first")
+        return need
+
     def _seat(self, descs):
         """``descs[i]`` takes row ``i`` of the step's tables (``desc.slot``:
         per batch, not the sequence's row of the manager's table). → the
@@ -1054,10 +1092,12 @@ class InferenceEngineV2:
         adapters [ms + 1], seq_state [ms + 1, seq_rows] | None)``; rows
         past the sequences are padding's (the null slot, null blocks, the
         base adapter). Advancing the sequences is the caller's."""
-        descs, seen, need = plan
+        descs, seen, need, need_window = plan
         sm, n, ms = self.state_manager, len(descs), self.max_seqs
         written = sm.rows_written
         sm.reserve(descs, need)
+        if need_window is not None:
+            sm.reserve_window(descs, need_window)
         rec.n_table_rows_written = sm.rows_written - written
         tables, seq_state = sm.gather(descs, ms + 1)
         token_seq = np.full(ms, ms, np.int32)   # pad rows write the null slot
@@ -1099,7 +1139,11 @@ class InferenceEngineV2:
                 f"KV pool exhausted: need {need_total} blocks, "
                 f"{self._reclaimable_blocks()} reclaimable — "
                 f"flush() sequences first")
-        return (descs, seen, need), None
+        try:
+            need_window = self._window_need(descs, k)
+        except RuntimeError as e:
+            return None, e
+        return (descs, seen, need, need_window), None
 
     def can_burst(self, batch_uids, k):
         """True when a ``decode_burst(uids, ·, k)`` (or a ``verify_burst``
@@ -1184,6 +1228,8 @@ class InferenceEngineV2:
             descs, token_seq, pos0, tables, adapters, seq_state = self._pack_rows(rec, plan)
             for desc in descs:
                 desc.advance(k)
+            if self.window_pool is not None:
+                self.state_manager.release_behind(descs)
             rec.n_ctx_tokens += int(_burst_ctx_tokens(pos0[:n], k).sum())
             parts = [token_seq, pos0, tables.ravel()]
             # the optional inputs of the program: keys present or absent, and

@@ -51,9 +51,10 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models import (GPTConfig, JambaConfig, Lfm2MoeConfig, LlamaConfig,
+from deepspeed_tpu.models import (GPTConfig, JambaConfig, LagunaConfig, Lfm2MoeConfig, LlamaConfig,
                                   LongcatFlashConfig, MiniCPMSalaConfig, MoonlightConfig,
                                   NemotronHConfig, SolarOpen2Config)
+from deepspeed_tpu.models import laguna
 from deepspeed_tpu.models.lfm2 import TOPK_EPS
 from deepspeed_tpu.models.llama import rope_frequencies, rope_scaling_of
 from deepspeed_tpu.models.nemotron_h import relu2
@@ -152,7 +153,8 @@ def _live_rows(batch):
     return jnp.max(jnp.where(seq < batch["block_tables"].shape[0] - 1, rows, 0))
 
 
-def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl=None):
+def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl=None,
+                  window=None):
     """Scatter layer ``layer``'s new K/V rows into the paged pool (one
     scatter into the whole pool, which comes back as the same buffers)
     and attend over each token's block-tabled context. The attention
@@ -165,21 +167,40 @@ def _paged_attend(q, k, v, kc, vc, layer, batch, Dh, alibi=None, mesh=None, impl
     table, so this - and nothing else - gives the kernel the step's query
     tiles (``batch["query_tiles"]``: :func:`ragged_forward`), and notes in
     ``impl.tiled`` that a program of this width did, for the host's count of
-    the rows they hold."""
+    the rows they hold.
+
+    ``window``: None, or the positions a row attends to, its own last (a
+    window layer of :class:`LagunaKind`): ``kc`` / ``vc`` are then the
+    **window pool's** arrays, and a sequence's table there is its ring, a
+    row of ``batch["seq_state"]`` (``ragged_manager``: the block of
+    positions ``b * bs ..`` in column ``b % ring``). The new rows go where
+    the ring says; the call is given the blocks a row's window touches and
+    no other (``paged_attention.window_tables``) and, by name
+    (``window=``), the lower edge of the mask."""
     bs = kc.shape[2]
     T, Hkv = k.shape[:2]
-    blk = batch["block_tables"][batch["token_seq"], batch["token_pos"] // bs]  # [T]
+    if window is None:
+        tables = batch["block_tables"]
+        blk = tables[batch["token_seq"], batch["token_pos"] // bs]  # [T]
+    else:
+        tables = batch["seq_state"]
+        blk = tables[batch["token_seq"], (batch["token_pos"] // bs) % tables.shape[1]]
     off = batch["token_pos"] % bs
     kc = _c_pool(kc.at[layer, blk, off].set(k.reshape(T, -1).astype(kc.dtype)), Hkv, mesh)
     vc = _c_pool(vc.at[layer, blk, off].set(v.reshape(T, -1).astype(vc.dtype)), Hkv, mesh)
 
     from deepspeed_tpu.inference.v2.modules.heuristics import instantiate_attn
-    tab = batch["block_tables"][batch["token_seq"]]  # [T, MB]
+    tab = tables[batch["token_seq"]]  # [T, MB]
     pos = batch["token_pos"]
+    tiles = batch.get("query_tiles")
+    if window is not None:
+        from deepspeed_tpu.ops.pallas.paged_attention import QUERY_TILE, window_tables
+        tab, pos = window_tables(tab, pos, window, bs, 1 if tiles is None else QUERY_TILE)
     name, attn_fn = instantiate_attn(mesh, Dh, bs, q.shape, kc.shape, alibi,
                                      max_blocks=tab.shape[1],
                                      override=impl.override if impl else None)
-    tiles = batch.get("query_tiles")
+    if window is not None:
+        attn_fn = functools.partial(attn_fn, window=window)
     if impl is not None:
         impl.selected[q.shape[0]] = name
         if tiles is not None and name != "xla_gather":  # the gather reads every row alone
@@ -531,6 +552,15 @@ class ModelKind:
     @staticmethod
     def extra_state(cfg, num_blocks, slots, dtype):
         """→ the tree of state beyond the two paged pools (zeros), or None."""
+        return None
+
+    @staticmethod
+    def window(cfg):
+        """→ None, or ``(the positions a window layer's row attends to, the
+        window layers)``: the engine then keeps a second pool for those
+        layers (``ragged/kv_cache.WindowPool``), whose arrays
+        (``WindowPool.arrays``: ``wk`` / ``wv``) are the step's ``extra``
+        and whose table a sequence the step's ``seq_state`` row."""
         return None
 
     @staticmethod
@@ -2021,9 +2051,203 @@ def _solar_kda(ctx, p, layer, x, kda, conv):
     return _proj(o.astype(x.dtype), p["o_proj"]), kda, conv
 
 
+class LagunaKind(ModelKind):
+    """Laguna (``models/laguna.py``): **window and full attention layers 3 :
+    1**, of different shapes - 64 query heads under a window of 512, 48 over
+    the whole context, both over the same 8 key-value heads of 128 - each
+    with a sigmoid gate a head on its output and a routed feed-forward
+    behind it (a dense SwiGLU in the leading layer), and **a cache of two
+    lifetimes**:
+
+    - the full layers keep keys and values in the engine's two paged pools,
+      ``[Lf, NB, bs, Hkv * d]``, under the sequence's block table, as every
+      ``kv`` kind does;
+    - the window layers keep theirs in the step's ``extra``, ``wk`` / ``wv``
+      ``[Lw, NBw, bs, Hkv * d]``, the **window pool**
+      (``ragged/kv_cache.WindowPool``), under a table of its own: a ring a
+      sequence, ``seq_state`` [S, ring], from which the blocks that fall
+      wholly behind ``pos - window + 1`` go back to the allocator after each
+      step. A sequence holds ``ceil(window / bs) + 1`` of them at any length
+      where a table over its context would hold ``pos / bs``; a row reads
+      the blocks its window touches and no other (:func:`_paged_attend`'s
+      ``window``: ``paged_attention.window_tables``, the kernel's call named
+      ``paged_window_attention`` in a device trace).
+
+    Why pools and not a ring of rows a slot in ``extra_state`` (the other
+    form ISSUE 52 allowed): a ring is laid for the widest step - ``window +
+    token_budget`` rows a tracked sequence, 17 blocks where a decoding
+    sequence needs 9 - whatever the sequences do, and a prompt chunk's rows
+    would wrap in it mid-tile; blocks from an allocator cost a sequence what
+    it holds, the paged kernel reads them as it reads any block, and the
+    gate counts them as it counts the others.
+
+    The two kinds of layer rotate differently (:func:`_laguna_rope`: the
+    full layers the first half of a head's columns at YaRN's frequencies,
+    cos and sin times the attention factor; the window layers all columns,
+    plain), so a step computes two pairs of cos and sin rows, once.
+    :meth:`stack` runs ``cfg.segments`` through :func:`_run_segments`; the
+    routed experts are one share of an expert-parallel deployment and ride
+    every step whole. Each step counts, over its tokens that are not
+    padding: ``EXPERT_COUNTS``; ``n_ctx_seq_tokens`` (:class:`Lfm2Kind`'s:
+    the context positions each of the step's sequences attends to in a full
+    layer, once a sequence); ``n_win_seq_tokens``, the same for a window
+    layer - the positions from its first row's lower bound to its last row:
+    the least a window layer must fetch."""
+    name = "laguna"
+    config = LagunaConfig
+    state_kind = "kv+window"
+    step_counts = EXPERT_COUNTS + ("n_ctx_seq_tokens", "n_win_seq_tokens")
+    experts_at = "moe"
+
+    @staticmethod
+    def state_layers(cfg):
+        return max(1, cfg.count(laguna.FULL))
+
+    @staticmethod
+    def window(cfg):
+        Lw = cfg.count(laguna.WINDOW)
+        return (cfg.sliding_window, Lw) if Lw else None
+
+    @staticmethod
+    def _counters(letter):
+        """The stacks a layer of ``cfg.letters``' letter draws from."""
+        return (laguna.FULL if letter in "fF" else laguna.WINDOW,
+                "dense" if letter.isupper() else "moe")
+
+    @staticmethod
+    def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+        LagunaKind.base_only(mesh, lora)
+        model = params["model"]
+        stacks = {**{kind: model.get(name) for kind, name in laguna.STACKS.items()},
+                  "dense": model.get("dense_ffn"), "moe": model.get(LagunaKind.experts_at, {})}
+        experts = stacks["moe"].get("experts")
+        S = batch["block_tables"].shape[0]
+        real = batch["token_seq"] < S - 1
+        rope = {kind: _laguna_rope(cfg, kind, batch["token_pos"]) for kind in laguna.STACKS
+                if stacks[kind] is not None}
+
+        def layer(letter, at, carry):
+            h, kc, vc, wk, wv, picks = carry
+            op, ffn = LagunaKind._counters(letter)
+            lp = _layer_of(stacks[op], at[op])
+            x = _rms(h, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
+            if op == laguna.FULL:
+                with jax.named_scope("ds.laguna.full_attn"):
+                    y, kc, vc = _laguna_attention(cfg, op, lp, at[op], x, kc, vc, batch,
+                                                  attn_impl, rope[op])
+            else:
+                with jax.named_scope("ds.laguna.window_attn"):
+                    y, wk, wv = _laguna_attention(cfg, op, lp, at[op], x, wk, wv, batch,
+                                                  attn_impl, rope[op])
+            h = h + y
+            fp = _layer_of(stacks[ffn], at[ffn])
+            x = _rms(h, fp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
+            if ffn == "dense":
+                with jax.named_scope("ds.dense_ffn"):
+                    y = _swiglu(x, fp)
+            else:
+                y, n = _laguna_moe(cfg, real, fp, experts, at[ffn], x)
+                picks = picks + n
+            return h + y, kc, vc, wk, wv, picks
+
+        extra = extra or {"wk": None, "wv": None}
+        carry = (h, kc, vc, extra["wk"], extra["wv"], jnp.zeros((3,), jnp.int32))
+        (h, kc, vc, wk, wv, picks), _ = _run_segments(cfg.segments, LagunaKind._counters, layer,
+                                                      carry)
+        first, length = _row_spans(batch["token_seq"], batch["token_pos"], S)
+        here = ((length > 0) & (jnp.arange(S) < S - 1)).astype(jnp.int32)
+        end = first + length
+        behind = jnp.maximum(first - (cfg.sliding_window - 1), 0)
+        counts = jnp.concatenate([picks, jnp.stack([
+            jnp.sum(here * end), jnp.sum(here * (end - behind))])]).astype(jnp.int32)
+        return h, kc, vc, (None if wk is None else {"wk": wk, "wv": wv}), counts[None]
+
+    @staticmethod
+    def router(cfg, fp):
+        """Sigmoid scores, the picks' weights over their sum times the scaling
+        factor (:class:`MoonlightKind`'s form at the highest precision); this
+        rank's share."""
+        gate = fp["gate"]
+        return Router(gate["weight"], gate["e_score_correction_bias"], cfg.num_experts_per_tok,
+                      cfg.moe_routed_scaling_factor,
+                      share=ExpertShare(cfg.first_expert_held, cfg.held, cfg.num_experts))
+
+    @staticmethod
+    def attention_layer(params, cfg, kind, layer, x, kc, vc, batch, attn_impl=None):
+        """Attention layer ``layer`` of ``kind`` (``laguna.FULL`` / ``WINDOW``;
+        its index among that kind's layers) alone - the same projections,
+        rotation, writes into its pool (``kc`` / ``vc``: the full pools or the
+        window pool's arrays), paged attention and gate: x [T, D] the
+        normalised stream → (y [T, D], kc, vc)."""
+        lp = _layer_of(params["model"][laguna.STACKS[kind]], layer)
+        return _laguna_attention(cfg, kind, lp, layer, x, kc, vc, batch, attn_impl,
+                                 _laguna_rope(cfg, kind, batch["token_pos"]))
+
+    @staticmethod
+    def expert_layer(params, cfg, layer, x):
+        """Routed feed-forward ``layer`` (its index among the routed layers)
+        alone (:func:`_layer_of`): x [T, D] the normalised stream, every row
+        a token → y."""
+        moe = params["model"]["moe"]
+        return _laguna_moe(cfg, jnp.ones(x.shape[0], bool), _layer_of(moe, layer),
+                           moe["experts"], layer, x)[0]
+
+
+def _laguna_rope(cfg, kind, positions):
+    """→ (cos, sin) [T, r / 2] float32 of a ``kind`` layer at this batch's
+    ``positions``: ``r`` the rotated columns of a head, the kind's
+    frequencies (``LagunaConfig.rope``: YaRN's for a full layer), times its
+    attention factor."""
+    inv_freq, factor = cfg.rope(kind)
+    angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq)[None, :]
+    return jnp.cos(angle) * factor, jnp.sin(angle) * factor
+
+
+def _laguna_attention(cfg, kind, p, layer, x, kc, vc, batch, attn_impl, rope):
+    """One Laguna attention mixer on the normalised stream: grouped-query
+    attention over layer ``layer`` of the pool it is given - a full layer's
+    over the sequence's context, a window layer's over the last
+    ``sliding_window`` positions of the window pool - queries and keys
+    rotated over their first ``r`` columns by halves, the rest as projected;
+    the output times ``sigmoid(x W_g)`` **a head** before ``W_o``. Not
+    :func:`_plain_gqa_attention` (position-free, a gate an element) nor
+    :func:`_lfm2_attention` (head norms, no gate): the third mixer has a
+    rotation of part of a head, a head count by kind and a window, which
+    neither knows. → (y, kc, vc)."""
+    T = x.shape[0]
+    Hq, Hkv, d = cfg.heads(kind), cfg.num_key_value_heads, cfg.head_dim
+    cos, sin = (t[:, None, :] for t in rope)
+    r = 2 * cos.shape[-1]
+
+    def rotated(t):
+        t32 = t.astype(jnp.float32)
+        t1, t2 = t32[..., :r // 2], t32[..., r // 2:r]
+        return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin, t32[..., r:]],
+                               axis=-1).astype(t.dtype)
+
+    q = rotated(_proj(x, p["q_proj"]).reshape(T, Hq, d))
+    k = rotated(_proj(x, p["k_proj"]).reshape(T, Hkv, d))
+    v = _proj(x, p["v_proj"]).reshape(T, Hkv, d)
+    out, kc, vc = _paged_attend(q, k, v, kc, vc, layer, batch, d, impl=attn_impl,
+                                window=cfg.sliding_window if kind == laguna.WINDOW else None)
+    gate = jax.nn.sigmoid(_proj(x, p["g_proj"]).astype(jnp.float32))          # [T, Hq]
+    out = (out.astype(jnp.float32) * gate[:, :, None]).astype(x.dtype)
+    return _proj(out.reshape(T, Hq * d), p["o_proj"]), kc, vc
+
+
+def _laguna_moe(cfg, real, fp, experts, layer, x):
+    """One routed feed-forward on the normalised stream, as this share gives
+    it, and its ``EXPERT_COUNTS``; ``real`` [T]: the rows that are not
+    padding. The held picks through the grouped matmul; the shared expert on
+    every row."""
+    y, counts = _routed_experts(x, LagunaKind.router(cfg, fp), experts, layer, real)
+    with jax.named_scope("ds.moe_shared"):
+        return y + _swiglu(x, fp["shared_experts"]), counts
+
+
 # Every kind, a kind whose config class derives another's before that one's.
-KINDS = (SolarOpen2Kind, JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind, MoonlightKind,
-         GPTKind, LlamaKind)
+KINDS = (LagunaKind, SolarOpen2Kind, JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind,
+         MoonlightKind, GPTKind, LlamaKind)
 
 
 def kind_of(cfg):

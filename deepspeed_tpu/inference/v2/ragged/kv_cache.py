@@ -216,6 +216,84 @@ class BlockedKVCache:
         return blocks
 
 
+class WindowPool:
+    """The host's side of a **second pool**, for the layers that attend to a
+    window: the allocator of its blocks and its geometry. A window layer
+    needs the last ``window`` positions of a sequence, not its context, so
+    its keys and values live apart from the full layers' - device arrays
+    ``[Lw, num_blocks, block_size, Hkv * Dh]`` of their own, which the engine
+    lays once (:meth:`arrays`) and hands to every program as its ``extra``
+    (carried and donated beside the two pools: this object never holds them) -
+    under a table of their own a sequence, a **ring** of :attr:`ring`
+    columns (``DSStateManager``: the block that holds positions ``b *
+    block_size ..`` stands in column ``b % ring``), from which a step's
+    blocks that lie wholly behind a sequence's window go back to the
+    allocator. Block 0 is the null block, as in :class:`BlockedKVCache`.
+
+    :meth:`bound` is what a sequence holds at the most: ``ceil(window /
+    block_size) + 1`` blocks between steps and in a decode step at any
+    length, ``ceil((window + k - 1) / block_size) + 1`` inside a step that
+    brings it ``k`` rows (the ``window + k - 1`` positions from its first
+    row's lower bound to its last row). The ring
+    has the columns of the widest step (``max_rows``: the token budget).
+    Counters, for whoever sizes the pool (``stats``): blocks in use, their
+    high water, blocks released, and how often the admission gate held a
+    request back for this pool."""
+
+    def __init__(self, window, block_size, max_rows, num_blocks):
+        assert num_blocks >= 2, "need at least one real block beyond the null block"
+        self.window, self.block_size, self.num_blocks = int(window), int(block_size), int(num_blocks)
+        self.ring = self.bound(max_rows)
+        self._allocator = BlockedAllocator(self.num_blocks)
+        self._allocator.allocate(1)  # pin the null block forever
+        self.released = self.high_water = self.gate_refused = 0
+
+    @staticmethod
+    def default_blocks(window, block_size, max_rows, sequences):
+        """The pool that never refuses ``sequences`` tracked sequences: the
+        null block, every sequence's bound between steps and one more (a
+        decode step's, a short burst's), and one step's rows over them all -
+        what the admission gate commits (``serving/admission.CapacityGate``)."""
+        return 1 + sequences * (-(-window // block_size) + 2) + -(-max_rows // block_size)
+
+    def arrays(self, layers, row_width, dtype):
+        """→ the pool's device arrays (zeros), keys and values: the tree a
+        kind with window layers is given as its programs' ``extra``."""
+        shape = (layers, self.num_blocks, self.block_size, row_width)
+        return {"wk": jnp.zeros(shape, dtype), "wv": jnp.zeros(shape, dtype)}
+
+    def bound(self, rows=1):
+        """The blocks a sequence may hold inside a step of ``rows`` rows of it."""
+        return -(-(self.window + rows - 1) // self.block_size) + 1
+
+    @property
+    def free_blocks(self) -> int:
+        return self._allocator.free_blocks
+
+    @property
+    def in_use(self) -> int:
+        return self.num_blocks - 1 - self._allocator.free_blocks
+
+    def reserve(self, n):
+        ids = self._allocator.allocate(n)
+        self.high_water = max(self.high_water, self.in_use)
+        return ids
+
+    def free(self, blocks, behind=False):
+        """``behind``: the blocks fell behind their sequence's window (counted
+        as released); else they go with the sequence or a rewind."""
+        blocks = list(blocks)
+        if blocks:
+            self._allocator.free(blocks)
+            if behind:
+                self.released += len(blocks)
+
+    def stats(self):
+        return {"blocks": self.num_blocks - 1, "in_use": self.in_use,
+                "high_water": self.high_water, "released": self.released,
+                "ring_columns": self.ring, "gate_refused": self.gate_refused}
+
+
 # donated pools: the functional .at[].set aliases in place, no pool copy
 _scatter_blocks = jax.jit(
     lambda pk, pv, ids, kv, vv: (pk.at[:, ids].set(kv), pv.at[:, ids].set(vv)),

@@ -22,7 +22,21 @@ sequence's blocks change (:meth:`extend_blocks`, :meth:`trim_blocks`: the
 two places that touch ``desc.blocks``), which a decode row's do once in
 ``block_size`` steps. The last row is nobody's and stays null: padding's.
 ``rows_written`` counts the writes (a step record's
-``n_table_rows_written`` is its growth over the step)."""
+``n_table_rows_written`` is its growth over the step).
+
+**The window pool's table.** For a model kind with window layers
+(``kv_cache.WindowPool``) the ``state_table`` is that pool's table: a
+sequence's row is a **ring** of ``window_pool.ring`` columns, the block of
+its positions ``b * block_size ..`` in column ``b % ring``, null where the
+sequence holds none - so the row is as short at position 200,000 as at 600
+and rides a step's ``seq_state`` as any kind's state row does. A step
+reserves both pools' blocks (:meth:`window_need`, :meth:`reserve_window`),
+and once its sequences have advanced the blocks that lie wholly behind their
+windows go back (:meth:`release_behind`): a sequence holds at most
+``window_pool.bound(rows)`` blocks inside a step of ``rows`` rows of it and
+``bound(1)`` between steps, whatever its length. :meth:`rewind_sequence`,
+:meth:`release_unused_blocks`, :meth:`flush_sequence` and
+:meth:`drop_sequence` keep both tables."""
 
 import numpy as np
 
@@ -33,12 +47,18 @@ from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDesc
 class DSStateManager:
 
     def __init__(self, kv_cache: BlockedKVCache, max_tracked_sequences: int,
-                 max_blocks_per_seq: int = None, seq_rows: int = 0):
+                 max_blocks_per_seq: int = None, seq_rows: int = 0, window_pool=None):
         """``max_blocks_per_seq``: the table's width, a step's too (the
         pool's size where nobody says: no sequence owns more).
         ``seq_rows``: the length of a sequence's ``state_row`` (0: the
-        model kind keeps none, and there is no ``state_table``)."""
+        model kind keeps none, and there is no ``state_table``).
+        ``window_pool``: the ``WindowPool`` of a kind with window layers,
+        whose ring the ``state_table`` then is (``seq_rows`` its columns)."""
         self.kv_cache = kv_cache
+        self.window_pool = window_pool
+        if window_pool is not None and seq_rows != window_pool.ring:
+            raise ValueError(f"the window pool's table has {window_pool.ring} columns a "
+                             f"sequence, not seq_rows={seq_rows}")
         self.max_tracked_sequences = max_tracked_sequences
         self.max_blocks_per_seq = int(max_blocks_per_seq or kv_cache.num_blocks)
         self._seqs = {}  # uid -> descriptor
@@ -163,6 +183,74 @@ class DSStateManager:
 
     def allocate_for(self, desc: DSSequenceDescriptor, new_tokens: int) -> None:
         self.reserve([desc], [desc.blocks_needed(new_tokens)])
+        if self.window_pool is not None:
+            self.reserve_window([desc], self.window_need([desc], new_tokens))
+
+    # ------------------------------------------------------ the window pool
+    def window_need(self, descs, new_tokens):
+        """→ int64 over ``descs`` (None: a sequence not tracked yet): the
+        window-pool blocks each lacks to hold ``new_tokens`` (one number, or
+        one a sequence) more positions. A sequence whose rewind crossed into
+        what its window had released is refused here, before any step."""
+        n = len(descs)
+        for desc in descs:
+            if desc is not None and desc.window_stale:
+                raise ValueError(f"sequence {desc.uid} was rewound past the blocks its window "
+                                 f"had released: it cannot be continued")
+        seen = np.fromiter([0 if d is None else d.seen_tokens for d in descs], np.int64, n)
+        have = np.fromiter([0 if d is None else d.window_first + len(d.window_blocks)
+                            for d in descs], np.int64, n)
+        return np.maximum(0, -(-(seen + new_tokens) // self.window_pool.block_size) - have)
+
+    def reserve_window(self, descs, need) -> None:
+        """:meth:`reserve` for the window pool: ``need[i]`` more blocks for
+        ``descs[i]``, one call to the allocator a step, each sequence's laid
+        in its ring behind the ones it holds."""
+        total = int(np.sum(need))
+        if not total:
+            return
+        pool = self.window_pool
+        ids, at = pool.reserve(total), 0
+        for i in np.flatnonzero(need):
+            desc, new = descs[i], ids[at:at + need[i]]
+            at += need[i]
+            if len(desc.window_blocks) + len(new) > pool.ring:
+                raise ValueError(f"sequence {desc.uid} would hold {len(desc.window_blocks)}+"
+                                 f"{len(new)} window blocks > the ring's {pool.ring}")
+            start = desc.window_first + len(desc.window_blocks)
+            self.state_table[desc.row, (start + np.arange(len(new))) % pool.ring] = new
+            desc.window_blocks.extend(int(b) for b in new)
+            self.rows_written += 1
+
+    def release_behind(self, descs) -> None:
+        """After a step's sequences advanced: the window-pool blocks that lie
+        wholly before ``seen_tokens - window + 1`` - the lowest position the
+        sequence's next row attends to - go back to the allocator, and their
+        ring columns are null."""
+        pool = self.window_pool
+        for desc in descs:
+            first = max(0, desc.seen_tokens - pool.window + 1) // pool.block_size
+            drop = min(first - desc.window_first, len(desc.window_blocks))
+            if drop > 0:
+                self.state_table[desc.row,
+                                 (desc.window_first + np.arange(drop)) % pool.ring] = NULL_BLOCK
+                pool.free(desc.window_blocks[:drop], behind=True)
+                del desc.window_blocks[:drop]
+                desc.window_first += drop
+                self.rows_written += 1
+
+    def _trim_window(self, desc) -> None:
+        """The window-pool blocks past ``desc``'s length go back (a rewind, a
+        reservation made for rows that were never written)."""
+        pool = self.window_pool
+        keep = max(0, -(-desc.seen_tokens // pool.block_size) - desc.window_first)
+        extra = desc.window_blocks[keep:]
+        if extra:
+            start = desc.window_first + keep
+            self.state_table[desc.row, (start + np.arange(len(extra))) % pool.ring] = NULL_BLOCK
+            del desc.window_blocks[keep:]
+            pool.free(extra)
+            self.rows_written += 1
 
     def rewind_sequence(self, desc: DSSequenceDescriptor, n_tokens: int) -> None:
         """Drop the last ``n_tokens`` of ``desc``'s KV content: the
@@ -180,6 +268,12 @@ class DSStateManager:
                 f"{desc.cached_tokens}-token shared prefix")
         if n_tokens:
             desc.rewind(n_tokens)
+        pool = self.window_pool
+        if pool is not None and desc.window_first * pool.block_size > max(
+                0, desc.seen_tokens - pool.window + 1):
+            # the next row's window reaches into a block that has gone back: an ending's
+            # rewind (the sequence is flushed next) may, a sequence that goes on may not
+            desc.window_stale = True
         self.release_unused_blocks(desc)
 
     def release_unused_blocks(self, desc: DSSequenceDescriptor) -> None:
@@ -193,6 +287,8 @@ class DSStateManager:
         needed = -(-desc.seen_tokens // self.kv_cache.block_size)
         needed = max(needed, desc.shared_blocks)
         self.kv_cache.free(self.trim_blocks(desc, needed))
+        if self.window_pool is not None:
+            self._trim_window(desc)
 
     def flush_sequence(self, uid) -> None:
         desc = self._untrack(uid)
@@ -200,8 +296,12 @@ class DSStateManager:
             self.prefix_cache.release(uid, desc)
         else:
             self.kv_cache.free(desc.blocks)
+        if self.window_pool is not None:
+            self.window_pool.free(desc.window_blocks)
+            desc.window_blocks = []
 
     def drop_sequence(self, uid) -> DSSequenceDescriptor:
         """Stop tracking ``uid`` WITHOUT freeing or caching its blocks —
-        the suspend path, where ownership moves to the host handle."""
+        the suspend path, where ownership moves to the host handle (the
+        descriptor keeps its window-pool blocks too, for whoever frees them)."""
         return self._untrack(uid)

@@ -123,6 +123,13 @@ class DSSequenceDescriptor:
         # ``trim_blocks`` change this list, and they write the row with it
         self.blocks = []
         self.row = -1  # the manager's table row, held from creation to flush
+        # a model kind with window layers (``DSStateManager``'s window pool): the blocks of
+        # that pool the sequence holds, consecutive blocks of its positions from block
+        # ``window_first`` of them on (the ones before fell behind the window and went
+        # back), and whether a rewind has crossed into what was released
+        self.window_blocks = []
+        self.window_first = 0
+        self.window_stale = False
         self.in_flight_tokens = 0
         # ---- prefix-cache bookkeeping (zero/empty when caching is off) ----
         self.cached_tokens = 0   # leading tokens whose KV came from the cache

@@ -22,6 +22,8 @@ from deepspeed_tpu.models.nemotron_h import (NEMOTRON_H_CONFIGS, NemotronHConfig
 from deepspeed_tpu.models.solar_open2 import (SOLAR_OPEN2_CONFIGS, SolarOpen2Config,
                                               SolarOpen2ForCausalLM,
                                               build_solar_open2)  # noqa: F401
+from deepspeed_tpu.models.laguna import (LAGUNA_CONFIGS, LagunaConfig, LagunaForCausalLM,
+                                         build_laguna)  # noqa: F401
 
 # The causal-LM families a preset name can build, in the order names are looked up
 # (the v2 serving engine takes any of them: inference/v2/model_runner.kind_of).
@@ -29,7 +31,8 @@ MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
                   (MOONLIGHT_CONFIGS, build_moonlight), (LONGCAT_CONFIGS, build_longcat),
                   (MINICPM_SALA_CONFIGS, build_minicpm_sala),
                   (NEMOTRON_H_CONFIGS, build_nemotron_h), (LFM2_CONFIGS, build_lfm2),
-                  (JAMBA_CONFIGS, build_jamba), (SOLAR_OPEN2_CONFIGS, build_solar_open2))
+                  (JAMBA_CONFIGS, build_jamba), (SOLAR_OPEN2_CONFIGS, build_solar_open2),
+                  (LAGUNA_CONFIGS, build_laguna))
 
 
 def build_model(preset, **overrides):

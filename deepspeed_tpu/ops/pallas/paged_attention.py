@@ -158,7 +158,7 @@ QUERY_TILE = 32
 
 
 def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None, tiles=None,
-                        alibi_slopes=None, selected=False):
+                        alibi_slopes=None, selected=False, window=None):
     """Reference math. q: [T, H, Dh]; kc/vc: the pool [L, NB, bs, Hkv*Dh];
     block_tables: [T, MB] (per TOKEN, already indexed by its sequence);
     token_pos: [T]; layer: int32 scalar, the layer of the pool to read.
@@ -167,7 +167,9 @@ def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=Non
     relative-position penalty slope_h * (k_pos - q_pos) to the scores.
     ``live_rows``, ``tiles``, ``selected``: the kernel's (where its grid
     ends; its query tiles; its tile of the context); the gather computes
-    every row and reads the same rows either way."""
+    every row and reads the same rows either way. ``window``: the kernel's
+    (:func:`window_tables`' table and positions; a row attends to the last
+    ``window`` positions up to its own)."""
     T, H, Dh = q.shape
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
     gather_bytes = 2 * T * block_tables.shape[1] * bs * Hkv * Dh * kc.dtype.itemsize
@@ -187,8 +189,10 @@ def xla_paged_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=Non
     if alibi_slopes is not None:
         rel = (k_idx[None, :] - token_pos[:, None]).astype(jnp.float32)  # [T, C]
         scores = scores + alibi_slopes[None, :, None] * rel[:, None, :]
-    mask = (k_idx[None, :] <= token_pos[:, None])[:, None, :]
-    scores = jnp.where(mask, scores, NEG_INF)
+    mask = k_idx[None, :] <= token_pos[:, None]
+    if window is not None:
+        mask &= k_idx[None, :] > token_pos[:, None] - window
+    scores = jnp.where(mask[:, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("thc,tchd->thd", probs, vs)
 
@@ -208,6 +212,36 @@ def selected_tables(tables, counts, token_pos, block_size):
     tab = jnp.where(jnp.arange(W)[None, None, :] < counts[..., None], tables, 0)
     at = (counts - 1) * block_size + (token_pos % block_size)[:, None]
     return tab.reshape(T * Hkv, W).astype(jnp.int32), at.reshape(T * Hkv).astype(jnp.int32)
+
+
+def window_columns(window, block_size, rows=1):
+    """The columns of a windowed call's table: the blocks that the windows
+    of ``rows`` rows of one sequence at consecutive positions can touch, the
+    first row's window starting anywhere in its first block (9 for a window
+    of 512 over 64-row blocks, 10 under a query tile of 32 rows)."""
+    return (block_size + window + rows - 3) // block_size + 1
+
+
+def window_tables(rings, token_pos, window, block_size, rows=1):
+    """The paged call's table and positions for rows that attend to the
+    last ``window`` positions of their context (``window=`` of both paths).
+    rings [T, R]: per token its sequence's row of the window pool's table, a
+    **ring**: the block that holds positions ``b * block_size ..`` stands in
+    column ``b % R`` (``ragged_manager.WindowTable``), so the table is as
+    short at position 200,000 as at 600; ``token_pos`` [T]: the query's
+    position in its sequence. → (table [T, :func:`window_columns`], whose
+    column 0 is the block that holds the row's **lower bound** ``max(0, pos -
+    window + 1)`` and whose next columns the blocks after it, in order;
+    positions [T] **in that table**, ``pos - (the first block's first
+    position)``). The blocks before the lower bound's are in no column: a
+    call never names, let alone fetches, a block that lies wholly outside
+    its rows' windows. A query tile walks its first row's table up to its
+    last row's position (``rows``: the rows a tile may hold), which the
+    sequence holds at once (``WindowTable``'s bound)."""
+    first = jnp.maximum(token_pos - (window - 1), 0) // block_size
+    columns = first[:, None] + jnp.arange(window_columns(window, block_size, rows))[None, :]
+    table = jnp.take_along_axis(rings, columns % rings.shape[1], axis=1)
+    return table.astype(jnp.int32), (token_pos - first * block_size).astype(jnp.int32)
 
 
 def kernel_supported(head_dim, block_size, n_kv_heads=None):
@@ -365,7 +399,8 @@ def chunk_counts(token_seq, token_pos, n_seqs, live_rows):
     return int(live.sum()), int((live & own).sum())
 
 
-def _kernel(*refs, bs, n, max_blocks, groups, n_kv_heads, native, head_dim=None, tq=1):
+def _kernel(*refs, bs, n, max_blocks, groups, n_kv_heads, native, head_dim=None, tq=1,
+            window=None):
     """One item of the grid: a row, or with ``tq`` above 1 a row or a tile.
     q_ref [tq, H, Dh] (VMEM), the item's block of rows; kc/vc, the whole
     pool [L, NB, bs, Hkv*Dh], stay in HBM (ANY); tab/pos/layer, and the
@@ -377,7 +412,11 @@ def _kernel(*refs, bs, n, max_blocks, groups, n_kv_heads, native, head_dim=None,
     tile of the context and a query tile are, what is in flight when, and
     what a slot's stale rows may hold. ``head_dim``: the head size the
     scores are scaled by where it is not the query's width (a pair of narrow
-    heads a slice, :func:`_paired`); None: the width."""
+    heads a slice, :func:`_paired`); None: the width. ``window``: None, or
+    the positions a row attends to, its own last (:func:`window_tables`'
+    table and positions: a row's lower bound lies in its table's first
+    block, so the walk is the unwindowed one over a short table and the
+    mask gains its lower edge)."""
     if tq == 1:
         (tab_ref, pos_ref, layer_ref, q_ref, kc_ref, vc_ref, o_ref,
          k_buf, v_buf, sems, slot_ref) = refs
@@ -403,8 +442,11 @@ def _kernel(*refs, bs, n, max_blocks, groups, n_kv_heads, native, head_dim=None,
     def item(k):
         if tq == 1:
             return k
-        last = row_ref[k] + len_ref[k] - 1
-        return row_ref[k], jnp.minimum(jax.lax.div(pos_ref[last], bs) + 1, max_blocks)
+        if window is None:
+            end = pos_ref[row_ref[k] + len_ref[k] - 1]
+        else:   # positions are each row's own table's: the last row's in the first row's table
+            end = pos_ref[row_ref[k]] + len_ref[k] - 1
+        return row_ref[k], jnp.minimum(jax.lax.div(end, bs) + 1, max_blocks)
 
     def table_row(it):
         return it if tq == 1 else it[0]
@@ -485,7 +527,10 @@ def _kernel(*refs, bs, n, max_blocks, groups, n_kv_heads, native, head_dim=None,
                                     preferred_element_type=jnp.float32)
                 for h in range(n_kv_heads)], axis=0) * scale  # [H, rows]
             kv_pos = i * rows + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
-            s = jnp.where(kv_pos <= pos, s, NEG_INF)
+            seen = kv_pos <= pos
+            if window is not None:
+                seen &= kv_pos > pos - window
+            s = jnp.where(seen, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
@@ -527,6 +572,8 @@ def _kernel(*refs, bs, n, max_blocks, groups, n_kv_heads, native, head_dim=None,
             vbuf = v_buf[slot]
             kv_pos = i * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
             seen = kv_pos <= q_pos  # [rows, M]
+            if window is not None:
+                seen &= kv_pos > q_pos - window
             for h in range(n_kv_heads):
                 q = qt_ref[h]  # [Dh, M]
                 if not native:
@@ -562,9 +609,9 @@ def _kernel(*refs, bs, n, max_blocks, groups, n_kv_heads, native, head_dim=None,
     slot_ref[0] = jnp.where(nxt_fetches, 1 - last_slot, last_slot)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "interpret", "head_dim"))
+@functools.partial(jax.jit, static_argnames=("n", "interpret", "head_dim", "window"))
 def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows=None,
-                head_dim=None, tiles=None):
+                head_dim=None, tiles=None, window=None):
     """The kernel at ``n`` blocks a tile (``tools/kernel_census.py``
     sweeps it; everything else gets :func:`tile_blocks`'). Jitted so
     that the serving programs of one shape (23 a cell lower the kernel in
@@ -572,7 +619,8 @@ def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_ro
     row) is where the grid ends; the module docstring says why.
     ``head_dim``: :func:`_kernel`'s. ``tiles``: :func:`query_tiles`' of
     these rows, or None: every row an item, a grid step a row, the query
-    block one row high."""
+    block one row high. ``window``: :func:`_kernel`'s; such a call has a
+    name of its own in the device trace, ``paged_window_attention``."""
     T, H, Dh = q.shape
     live_rows, grid = live_grid(T, live_rows)
     bs, Hkv = kc.shape[2], kc.shape[3] // Dh
@@ -624,7 +672,8 @@ def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_ro
     # the MXU as they lie; a float32 pool (or query) keeps the six-pass product
     native = q.dtype == kc.dtype == vc.dtype and kc.dtype.itemsize == 2
     kernel = functools.partial(_kernel, bs=bs, n=n, max_blocks=MB, groups=groups,
-                               n_kv_heads=Hkv, native=native, head_dim=head_dim, tq=tq)
+                               n_kv_heads=Hkv, native=native, head_dim=head_dim, tq=tq,
+                               **({} if window is None else {"window": window}))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -633,7 +682,7 @@ def _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_ro
         # items in order on one core: an item starts the next one's first tile
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_decode_attention",
+        name="paged_decode_attention" if window is None else "paged_window_attention",
     )(*prefetch, *operands, kc, vc)
     if tq > 1:
         out, columns = out
@@ -665,7 +714,7 @@ def _paired(q, n_kv_heads):
 
 
 def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=None, tiles=None,
-                           interpret=None, selected=False):
+                           interpret=None, selected=False, window=None):
     """Pallas path of :func:`xla_paged_attention` (same contract on the
     rows before ``live_rows``, zeros from there on; None: every row).
     ``tiles``: :func:`query_tiles`' of the batch these rows are, from the
@@ -681,7 +730,12 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=
     ``SELECTED_SLOT_BYTES`` at the least, the 512 KB that 256 rows of 8
     heads are. Every other call's tile is what it was. A head of 64 goes
     to the same kernel a pair of key-value heads a slice (:func:`_paired`);
-    a head of 128 takes the code it took."""
+    a head of 128 takes the code it took. ``window``: None - today's
+    program - or the positions a row attends to, its own last, with
+    :func:`window_tables`' table and positions for ``block_tables`` and
+    ``token_pos``: the walk starts at the block that holds a row's (a query
+    tile's first row's) lower bound, because no block before it is in the
+    table, and the mask gains its lower edge."""
     if interpret is None:
         from deepspeed_tpu.ops.pallas import default_interpret
         interpret = default_interpret()
@@ -703,9 +757,10 @@ def paged_decode_attention(q, kc, vc, block_tables, token_pos, layer, live_rows=
                 f"max_context, or raise kv_block_size")
     n = tile_blocks(bs, kc.shape[3] * kc.dtype.itemsize, kc.dtype.itemsize, MB,
                     SELECTED_SLOT_BYTES if selected else 0)
+    windowed = {} if window is None else {"window": window}   # no window: the call as it was
     if Dh == PAIRED_HEAD_DIM and Hkv % 2 == 0:
         wide, pick = _paired(q, Hkv)
         return pick(_paged_call(wide, kc, vc, block_tables, token_pos, layer, n, interpret,
-                                live_rows, head_dim=Dh, tiles=tiles))
+                                live_rows, head_dim=Dh, tiles=tiles, **windowed))
     return _paged_call(q, kc, vc, block_tables, token_pos, layer, n, interpret, live_rows,
-                       tiles=tiles)
+                       tiles=tiles, **windowed)

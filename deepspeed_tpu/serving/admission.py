@@ -106,6 +106,15 @@ class CapacityGate:
     (EOS may finish a request early) — the price is a little pool
     headroom, the payoff is that admission can never over-subscribe the
     pool and crash the pump mid-step.
+
+    An engine whose model kind has window layers has a second pool
+    (``engine.window_pool``), and a request is admitted on **both** pools'
+    worst case: in the window pool a sequence holds ``bound(1)`` blocks
+    between steps whatever its length, one more inside a decode step or a
+    short burst, and the rows of one step (the token budget, however the
+    scheduler deals it) add ``ceil(token_budget / block_size)`` over all
+    sequences - kept back from ``usable_window_blocks`` once, not committed
+    a request. ``refused_by`` counts what held a request back, by pool.
     """
 
     def __init__(self, engine, token_budget, pool="unified"):
@@ -126,10 +135,23 @@ class CapacityGate:
         self.token_budget = int(token_budget)
         self.committed_blocks = 0
         self.active = 0  # requests currently holding a commitment
+        self.window_pool = getattr(engine, "window_pool", None)
+        self.committed_window_blocks = self.usable_window_blocks = 0
+        if self.window_pool is not None:
+            self.usable_window_blocks = int(self.window_pool.free_blocks) \
+                - -(-self.token_budget // self.block_size)
+        self.refused_by = {"kv_blocks": 0, "window_blocks": 0, "sequences": 0}
 
     def footprint(self, prompt_len, max_new_tokens):
         """Worst-case KV blocks a request will ever hold."""
         return -(-(prompt_len + max_new_tokens) // self.block_size)
+
+    def window_footprint(self, prompt_len, max_new_tokens):
+        """Worst-case window-pool blocks a request holds outside a prompt
+        chunk's own rows (0 without such a pool)."""
+        if self.window_pool is None:
+            return 0
+        return min(self.footprint(prompt_len, max_new_tokens), self.window_pool.bound(1) + 1)
 
     def check_feasible(self, prompt_len, max_new_tokens):
         """Raise :class:`RequestTooLargeError` when the request could not
@@ -153,25 +175,40 @@ class CapacityGate:
                 f"— raise num_kv_blocks or shrink the request",
                 needed_blocks=need, usable_blocks=self.usable_blocks,
                 pool=self.pool)
+        need = self.window_footprint(prompt_len, max_new_tokens)
+        if need > self.usable_window_blocks:
+            raise RequestTooLargeError(
+                f"request needs {need} window-pool blocks but the pool only has "
+                f"{self.usable_window_blocks} beside one step's rows — raise num_window_blocks",
+                needed_blocks=need, usable_blocks=self.usable_window_blocks, pool=self.pool)
 
     def try_commit(self, prompt_len, max_new_tokens):
         """Reserve the request's footprint; False when it doesn't fit
         right now (caller keeps it queued)."""
         need = self.footprint(prompt_len, max_new_tokens)
+        need_window = self.window_footprint(prompt_len, max_new_tokens)
         if self.committed_blocks + need > self.usable_blocks:
+            self.refused_by["kv_blocks"] += 1
+            return False
+        if self.committed_window_blocks + need_window > self.usable_window_blocks:
+            self.refused_by["window_blocks"] += 1
+            self.window_pool.gate_refused += 1
             return False
         if self.active + 1 > self.max_tracked:
+            self.refused_by["sequences"] += 1
             return False
         self.committed_blocks += need
+        self.committed_window_blocks += need_window
         self.active += 1
         return True
 
     def release(self, prompt_len, max_new_tokens):
         need = self.footprint(prompt_len, max_new_tokens)
         self.committed_blocks -= need
+        self.committed_window_blocks -= self.window_footprint(prompt_len, max_new_tokens)
         self.active -= 1
-        assert self.committed_blocks >= 0 and self.active >= 0, \
-            "capacity release without matching commit"
+        assert self.committed_blocks >= 0 and self.active >= 0 \
+            and self.committed_window_blocks >= 0, "capacity release without matching commit"
 
 
 # ---------------------------------------------------------------- wait queue

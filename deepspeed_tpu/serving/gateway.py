@@ -852,7 +852,7 @@ class ServingGateway:
         groups = {}
         for prefix, attr in (("Serve/PrefixCache", "prefix_cache"),
                              ("Serve/KVTier", "kv_tier"), ("Serve/Spec", "spec"),
-                             ("Serve/LoRA", "lora_store")):
+                             ("Serve/LoRA", "lora_store"), ("Serve/WindowPool", "window_pool")):
             subsystem = getattr(engine, attr, None)
             if subsystem is not None:
                 groups[prefix] = subsystem.stats()
@@ -864,6 +864,9 @@ class ServingGateway:
                 "syncs_per_token": engine.syncs_per_generated_token,
                 "async_burst": int(getattr(engine, "async_burst_depth", 0)),
             }
+        if "Serve/WindowPool" in groups:    # the gate's queue by which pool refused
+            groups["Serve/WindowPool"].update(
+                {f"gate_refused_by_{name}": n for name, n in self.gate.refused_by.items()})
         return groups
 
     def _end(self, handle, status, error=None, counter=None, request=None):

@@ -18,7 +18,7 @@ from tools import hash_step_programs
 PRESETS = ("debug", "mixtral-debug", "gpt2-debug", "opt-debug", "bloom-debug", "neox-debug",
            "gptj-debug", "falcon-debug", "moonlight-debug", "longcat-flash-debug",
            "minicpm-sala-debug", "nemotron-h-debug", "lfm2-debug", "jamba-debug",
-           "solar-open2-debug")
+           "solar-open2-debug", "laguna-debug")
 
 
 def _params(preset, shapes_only=False):
@@ -47,6 +47,10 @@ def test_every_kind_derives_the_base_and_answers_the_whole_seam():
         assert (extra is None) == (not kind.slot_state)
         assert set(kind.slot_state) <= set(extra or ())
         assert (kind.seq_rows > 0) == (extra is not None)
+        # a second pool is the engine's to lay (its arrays, its ring a sequence): the kind
+        # says only that it has window layers, and keeps no state of its own kind beside it
+        assert kind.window(cfg) is None or (extra is None and kind.window(cfg)[1] >= 1)
+        assert (kind.window(cfg) is not None) == (kind.state_kind == "kv+window")
         if kind.seq_rows:
             assert len(kind.seq_state(cfg, 1, 40)) == kind.seq_rows
         assert all(isinstance(name, str) for name in kind.step_counts)
@@ -54,7 +58,7 @@ def test_every_kind_derives_the_base_and_answers_the_whole_seam():
         assert kind.state_layers(cfg) >= 1 and len(kind.state_rows(cfg)) == 2
 
 
-def test_the_hashed_presets_are_the_fifteen_in_their_order():
+def test_the_hashed_presets_are_the_sixteen_in_their_order():
     """The parent's and a change's outputs of the tool must line up (a new
     kind's preset goes last: its lines are the only ones a diff shows)."""
     assert hash_step_programs.PRESETS == PRESETS

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 PRESETS = ("debug", "mixtral-debug", "gpt2-debug", "opt-debug", "bloom-debug", "neox-debug",
            "gptj-debug", "falcon-debug", "moonlight-debug", "longcat-flash-debug",
            "minicpm-sala-debug", "nemotron-h-debug", "lfm2-debug", "jamba-debug",
-           "solar-open2-debug")
+           "solar-open2-debug", "laguna-debug")
 SEQS, TABLE, BLOCKS = 4, 12, 64
 
 
@@ -41,13 +41,21 @@ def programs(forced):
         pools = [sds((kind.state_layers(cfg), BLOCKS, bs, w), jnp.float32)
                  for w in kind.state_rows(cfg)]
         extra = jax.eval_shape(lambda: kind.extra_state(cfg, BLOCKS, SEQS, jnp.float32))
+        seq_rows = kind.seq_rows
+        if kind.window(cfg) is not None:    # a second pool, its ring a sequence's state row
+            from deepspeed_tpu.inference.v2.ragged.kv_cache import WindowPool
+            window, layers = kind.window(cfg)
+            pool = WindowPool(window, bs, 32, BLOCKS)
+            extra = jax.eval_shape(lambda: pool.arrays(layers, kind.state_rows(cfg)[0],
+                                                       jnp.float32))
+            seq_rows = pool.ring
         for T in (8, 32):
             batch = {"token_ids": sds((T,), jnp.int32), "token_seq": sds((T,), jnp.int32),
                      "token_pos": sds((T,), jnp.int32),
                      "block_tables": sds((SEQS + 1, TABLE), jnp.int32),
                      "last_index": sds((SEQS,), jnp.int32), "num_tokens": sds((), jnp.int32)}
-            if kind.seq_rows:
-                batch["seq_state"] = sds((SEQS + 1, kind.seq_rows), jnp.int32)
+            if seq_rows:
+                batch["seq_state"] = sds((SEQS + 1, seq_rows), jnp.int32)
             choice = AttentionChoice()
             text = jax.jit(lambda p, kc, vc, b, x: model_runner.ragged_forward(
                 p, kc, vc, b, cfg, jnp.float32, attn_impl=choice, extra=x)).lower(

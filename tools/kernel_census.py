@@ -52,6 +52,16 @@ a (token, key-value head) over a pool of one head a layer, 64-row blocks of
 128 values, 64 selected blocks a row; 1024 rows as a 512-token program holds
 them, 48 as a burst step does), parent beside this tree's (~3 min).
 
+``--window`` times the **windowed** call (PR 52: ``paged_decode_attention(
+window=512)`` over ``paged_attention.window_tables``' table of the 9-10 blocks a
+row's or a query tile's window touches) at ``laguna-xs2-repochat``'s shape - 64
+query heads over 8 key-value heads of 128, 64-row blocks - for 48 decode rows at
+contexts 600-17,000, a 512-row chunk at position 6,000 and a mixed step of both,
+against its least bytes (a window a sequence, once a call), and beside it today's
+call over the same sequences' whole contexts and over contexts cut to the
+window's: the windowed call has to cost what the unwindowed one costs at 512
+tokens, not at the sequence's (~1 min, ``chiprun_out/window_census.json``).
+
 ``--mla`` times ``paged_mla_decode_attention`` at the shape classes the two
 latent cells serve (256-row bf16 blocks of 512 + 128 values, a traced layer
 index): Moonlight's 128 decode rows at contexts 1024-4096 under 16 heads,
@@ -1000,6 +1010,102 @@ def kda_classes():
         yield f"kda-{T}x{runs}x{rows_a_run}+{then}-{KDA_FORM_NAMES[form]}", record
 
 
+def window_bytes(seq_tokens, row_bytes=4096):
+    """Least bytes of a windowed call: the positions its sequences attend to
+    (a window a sequence, once a call), keys and values (``benchmark/readers/
+    laguna.attention_bytes`` at one layer)."""
+    return seq_tokens * row_bytes
+
+
+def window_classes():
+    """``--window``: the windowed call of ``laguna-xs2-repochat``'s window
+    layers (``paged_decode_attention(window=512)``: 64 query heads over 8
+    key-value heads of 128, 64-row blocks, a table of the 9-10 blocks
+    ``paged_attention.window_tables`` cuts out of a sequence's ring) against
+    its least bytes - 48 decode rows at contexts 600-17,000, and a 512-row
+    prompt chunk at position 6,000 with the step's query tiles - and, beside
+    each, the **unwindowed** kernel over the same sequences' whole context
+    and over a 512-token context: the windowed call at any context has to
+    cost what the unwindowed one costs at 512, not what it costs at the
+    sequence's. → (name, record) a class."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    H, Hkv, Dh, bs, W, L, NB, MB, ring = 64, 8, HEAD_DIM, 64, 512, 2, 4609, 272, 17
+    rng = np.random.default_rng(52)
+    pool = jax.jit(lambda key: jax.random.normal(key, (L, NB, bs, Hkv * Dh), jnp.bfloat16))
+    kc, vc = pool(jax.random.PRNGKey(1)), pool(jax.random.PRNGKey(2))
+    layer = jnp.int32(1)
+    for name, T, decode, chunk in (("decode48", 48, 48, None), ("chunk512", 512, 0, (512, 6000)),
+                                   ("mixed", 512, 48, (464, 6000))):
+        n_seqs = decode + (chunk is not None)
+        seq, pos = np.full(T, n_seqs, np.int32), np.zeros(T, np.int32)
+        pos[:decode] = np.exp(rng.uniform(np.log(600), np.log(17000), decode)).astype(int) - 1
+        seq[:decode] = np.arange(decode)
+        if chunk is not None:
+            rows, before = chunk
+            seq[decode:decode + rows], pos[decode:decode + rows] = decode, before + np.arange(rows)
+        live = decode + (chunk[0] if chunk else 0)
+        free = iter(rng.permutation(np.arange(1, NB)))
+        tables = np.zeros((n_seqs + 1, MB), np.int32)
+        rings = np.zeros((n_seqs + 1, ring), np.int32)
+        ends, lows = [], []
+        for i in range(n_seqs):
+            rows_i = pos[seq == i]
+            end, low = int(rows_i.max()) + 1, max(0, int(rows_i.min()) - W + 1)
+            ends.append(end), lows.append(low)
+            for b in range(-(-end // bs)):
+                tables[i, b] = next(free)
+                if b >= low // bs:      # the ring holds what the window still needs
+                    rings[i, b % ring] = tables[i, b]
+        # the same sequences as today's call would see them were their contexts the window's:
+        # a table that starts at the block of the lower bound, positions counted from there
+        cut, first = np.zeros_like(tables), np.zeros(n_seqs + 1, np.int32)
+        for i, low in enumerate(lows):
+            b0 = low // bs
+            cut[i, :MB - b0], first[i] = tables[i, b0:], b0 * bs
+        q = jnp.asarray(rng.standard_normal((T, H, Dh), np.float32), jnp.bfloat16)
+        seq_d, pos_d, live_d = jnp.asarray(seq), jnp.asarray(pos), jnp.int32(live)
+        tiles = jax.jit(lambda s, p, n: pa.query_tiles(s, p, n_seqs, n, MB))(seq_d, pos_d, live_d) \
+            if T % pa.QUERY_TILE == 0 else None
+        tab_w, pos_w = jax.jit(lambda r, p: pa.window_tables(
+            r, p, W, bs, 1 if tiles is None else pa.QUERY_TILE))(jnp.asarray(rings[seq]), pos_d)
+        win_tokens = sum(e - l for e, l in zip(ends, lows))
+        some = jnp.arange(0, live, 8)
+        want = jax.jit(lambda *a: pa.xla_paged_attention(*a, window=W))(
+            q[some], kc, vc, tab_w[some], pos_w[some], layer)
+
+        def timed(least, tab, at, **kw):
+            try:
+                call = jax.jit(lambda q, kc, vc, tab, at, layer, live, *tiles: (
+                    pa.paged_decode_attention(q, kc, vc, tab, at, layer, live, tiles or None,
+                                              interpret=False, **kw)))
+                args = (q, kc, vc, tab, at, layer, live_d, *(tiles or ()))
+                ms = _ms_a_call(call, *args, calls=100)
+                out = {"ms": ms, "least_bytes": least, "least_gb_s": least / ms / 1e6,
+                       "hbm_share": 100 * least / ms / 1e6 / HBM_GB_S}
+                if kw:
+                    out["rel_err"] = float(f"{rel_err(call(*args)[some], want):.3e}")
+                return out
+            except Exception as e:  # a refusal is a record too
+                return {"refused": f"{type(e).__name__}: {e}"[:600]}
+
+        row = 2 * Hkv * Dh * 2
+        record = {"rows": T, "live_rows": live, "win_seq_tokens": win_tokens,
+                  "ctx_seq_tokens": sum(ends), "table_columns": int(tab_w.shape[1]),
+                  "windowed": timed(window_bytes(win_tokens, row), tab_w, pos_w, window=W),
+                  # the same rows through today's call over their whole contexts ...
+                  "unwindowed_whole_context": timed(sum(ends) * row, jnp.asarray(tables[seq]), pos_d),
+                  # ... and over a context cut to the window's length (positions 0 .. W - 1 + rows)
+                  "unwindowed_at_512": timed(window_bytes(win_tokens, row),
+                                             jnp.asarray(cut[seq]), jnp.asarray(pos - first[seq]))}
+        yield name, record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -1029,8 +1135,10 @@ def main():
     parent_dir = (sys.argv[sys.argv.index("--paged-parent") + 1]
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
     live, ssm, chunk = "--live" in sys.argv, "--ssm" in sys.argv, "--chunk" in sys.argv
-    scan, kda = "--scan" in sys.argv, "--kda" in sys.argv
-    if kda:
+    scan, kda, window = "--scan" in sys.argv, "--kda" in sys.argv, "--window" in sys.argv
+    if window:
+        section, records = "window_attention", window_classes()
+    elif kda:
         section, records = "kda", kda_classes()
     elif scan:
         section, records = "selective_scan", selective_scan_classes()
@@ -1052,7 +1160,7 @@ def main():
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
     for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk or scan or kda
-                                     or "--gmm-only" in sys.argv
+                                     or window or "--gmm-only" in sys.argv
                                      else cases()):
         try:
             result = verdict(fn, ref, args, tol)
@@ -1061,7 +1169,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("kda_census.json" if kda else "scan_census.json" if scan
+    out = ("window_census.json" if window else "kda_census.json" if kda
+           else "scan_census.json" if scan
            else "chunk_census.json" if chunk
            else "ssm_census.json" if ssm
            else "live_census.json" if live
