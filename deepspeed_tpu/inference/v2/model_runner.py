@@ -59,7 +59,8 @@ from deepspeed_tpu.models.lfm2 import TOPK_EPS
 from deepspeed_tpu.models.llama import rope_frequencies, rope_scaling_of
 from deepspeed_tpu.models.nemotron_h import relu2
 from deepspeed_tpu.models.solar_open2 import L2_EPS
-from deepspeed_tpu.ops.grouped_gemm import ExpertShare, dropless_moe_ffn, fused_gmm_enabled
+from deepspeed_tpu.ops.grouped_gemm import (ExpertShare, dropless_moe_ffn, expert_share_ffn,
+                                            fused_gmm_enabled)
 
 
 def _c(x, entries, mesh):
@@ -324,6 +325,10 @@ def _experts_apart(layers):
 # what a routed-expert layer behind a share counts, over the tokens that are not padding: the
 # picks whose expert is held, the zero-compute picks, the held experts with at least one row
 EXPERT_COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live")
+# and behind a share that holds fewer experts than the router has: the passes its held picks
+# took through the grouped matmul (``expert_share_ffn``: one a layer unless a step's held
+# picks outgrow a pass's rows)
+SHARE_COUNTS = EXPERT_COUNTS + ("n_share_passes",)
 
 
 class Router(NamedTuple):
@@ -368,7 +373,8 @@ def _routed_experts(x, r, experts, layer, real=None, enter=None, leave=None):
     padding rows to leave out, every column: it says only that a pick of -1
     is a row of no group) held picks alone become rows, zero-compute picks
     give ``(their weights) * x``, the rest is left out, and the layer counts:
-    → (y [T, D], ``EXPERT_COUNTS`` int32 [3] or None)."""
+    → (y [T, D], ``SHARE_COUNTS`` int32 [4] behind ``r.share``, ``EXPERT_COUNTS``
+    [3] where every column is held, None without a share)."""
     with jax.named_scope("ds.moe_routed"):
         picks, weights = _route(x, r, real)
         columns = r.weight.shape[-1]
@@ -378,10 +384,16 @@ def _routed_experts(x, r, experts, layer, real=None, enter=None, leave=None):
         table, first_group = _layer_groups(experts, layer)
         gated = "gate_proj" in table
         u = x if enter is None else _proj(x, enter)
-        y = dropless_moe_ffn(u, picks, weights, table["gate_proj" if gated else "up_proj"],
-                             table["up_proj"] if gated else None, table["down_proj"],
-                             num_experts=columns, widen_boundary=False, first_group=first_group,
-                             share=share, activation=jax.nn.silu if gated else relu2)
+        stacks = (table["gate_proj" if gated else "up_proj"],
+                  table["up_proj"] if gated else None, table["down_proj"])
+        activation = jax.nn.silu if gated else relu2
+        if share is None:
+            y = dropless_moe_ffn(u, picks, weights, *stacks, num_experts=columns,
+                                 widen_boundary=False, first_group=first_group,
+                                 activation=activation)
+        else:
+            y, passes = expert_share_ffn(u, picks, weights, *stacks, share,
+                                         first_group=first_group, activation=activation)
         if leave is not None:
             y = _proj(y, leave)
         if share is None:
@@ -389,10 +401,10 @@ def _routed_experts(x, r, experts, layer, real=None, enter=None, leave=None):
         held, zero = share.parts(picks)
         if r.share is None:     # every column is held: a pick names its group outright
             live = jnp.any(picks[..., None] == jnp.arange(share.held), axis=(0, 1))
-        else:
-            of_expert = picks[..., None] == share.first + jnp.arange(share.held)
-            live = jnp.any(of_expert & held[..., None], axis=(0, 1))
-        return y, jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
+            return y, jnp.stack([held.sum(), zero.sum(), live.sum()]).astype(jnp.int32)
+        of_expert = picks[..., None] == share.first + jnp.arange(share.held)
+        live = jnp.any(of_expert & held[..., None], axis=(0, 1))
+        return y, jnp.stack([held.sum(), zero.sum(), live.sum(), passes]).astype(jnp.int32)
 
 
 def _moe_mlp(x, p, k, mesh=None, experts=None, layer=None):
@@ -736,11 +748,11 @@ class LongcatKind(MoonlightKind):
     num_layers``, and double layer ``l`` writes rows ``2l`` and ``2l +
     1``), no leading layers, and an expert layer that is one share of an
     expert-parallel deployment behind a router with zero-compute
-    columns. Each step counts ``EXPERT_COUNTS`` over its expert layers,
+    columns. Each step counts ``SHARE_COUNTS`` over its expert layers,
     then the latent state's two (:func:`_latent_stack`)."""
     name = "longcat"
     config = LongcatFlashConfig
-    step_counts = EXPERT_COUNTS + LATENT_FETCH_COUNTS
+    step_counts = SHARE_COUNTS + LATENT_FETCH_COUNTS
 
     @staticmethod
     def state_layers(cfg):
@@ -1216,7 +1228,7 @@ class NemotronHKind(ModelKind):
     (:func:`_run_segments`). The routed experts are one share of an
     expert-parallel deployment (``ops/grouped_gemm.ExpertShare``) and ride
     every step whole, one table of ``Le x held`` groups. Each step counts,
-    over its tokens that are not padding: ``EXPERT_COUNTS`` (no pick is
+    over its tokens that are not padding: ``SHARE_COUNTS`` (no pick is
     zero-compute: the name is the expert-share readers'), the rows through
     the ``M`` layers, and the (sequence, ``M`` layer)s whose state it read and
     wrote - each of those one slot fetched and written back by
@@ -1226,7 +1238,7 @@ class NemotronHKind(ModelKind):
     name = "nemotron_h"
     config = NemotronHConfig
     state_kind = "kv+slots"
-    step_counts = EXPERT_COUNTS + ("n_ssm_rows", "n_state_slots")
+    step_counts = SHARE_COUNTS + ("n_ssm_rows", "n_state_slots")
     seq_rows = 1            # (slot,)
     slot_state = ("ssm", "conv")
     experts_at = "moe_layers"
@@ -1267,7 +1279,8 @@ class NemotronHKind(ModelKind):
                 picks = picks + n
             return h + y, kc, vc, ssm, conv, picks
 
-        carry = (h, kc, vc, extra["ssm"], extra["conv"], jnp.zeros((3,), jnp.int32))
+        carry = (h, kc, vc, extra["ssm"], extra["conv"],
+                 jnp.zeros((len(SHARE_COUNTS),), jnp.int32))
         carry, done = _run_segments(cfg.segments, lambda letter: (letter,),
                                     lambda letter, at, carry: layer(letter, at[letter], carry),
                                     carry)
@@ -1545,7 +1558,7 @@ def _plain_gqa_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
 
 def _nemotron_moe(cfg, real, p, experts, layer, x):
     """One LatentMoE layer on the normalised stream, as this share gives it,
-    and its ``EXPERT_COUNTS``; ``real`` [T]: the rows that are not padding.
+    and its ``SHARE_COUNTS``; ``real`` [T]: the rows that are not padding.
     The routed experts work in the latent ``u = x W_down``, ungated, and
     ``W_up`` leaves it; the shared expert on the full width."""
     y, counts = _routed_experts(x, NemotronHKind.router(cfg, p), experts, layer, real,
@@ -1890,7 +1903,7 @@ class SolarOpen2Kind(ModelKind):
     :meth:`stack` runs ``cfg.segments`` through :func:`_run_segments`. The
     routed experts ride every step whole, one table of ``L x held`` groups.
     Each step counts, over its tokens that are not padding:
-    ``EXPERT_COUNTS``, the rows through the KDA layers, the (sequence, KDA
+    ``SHARE_COUNTS``, the rows through the KDA layers, the (sequence, KDA
     layer)s whose state it read and wrote (:class:`NemotronHKind`'s and
     :class:`JambaKind`'s name), ``n_scan_runs``, those of them with more
     than one row in the step, and ``n_kda_chunk_rows``, the rows of
@@ -1899,7 +1912,7 @@ class SolarOpen2Kind(ModelKind):
     name = "solar_open2"
     config = SolarOpen2Config
     state_kind = "kv+slots"
-    step_counts = EXPERT_COUNTS + ("n_kda_rows", "n_state_slots", "n_scan_runs",
+    step_counts = SHARE_COUNTS + ("n_kda_rows", "n_state_slots", "n_scan_runs",
                                    "n_kda_chunk_rows")
     seq_rows = 1            # (slot,)
     slot_state = ("kda", "conv")
@@ -1947,7 +1960,8 @@ class SolarOpen2Kind(ModelKind):
             y, n = _solar_moe(cfg, ctx.real, fp, experts, at["moe"], x)
             return h + y, kc, vc, kda, conv, picks + n
 
-        carry = (h, kc, vc, extra["kda"], extra["conv"], jnp.zeros((3,), jnp.int32))
+        carry = (h, kc, vc, extra["kda"], extra["conv"],
+                 jnp.zeros((len(SHARE_COUNTS),), jnp.int32))
         (h, kc, vc, kda, conv, picks), done = _run_segments(
             cfg.segments, SolarOpen2Kind._counters, layer, carry)
         Lk = done.get("kda", 0)
@@ -1996,7 +2010,7 @@ class SolarOpen2Kind(ModelKind):
 
 def _solar_moe(cfg, real, fp, experts, layer, x):
     """One routed feed-forward on the normalised stream, as this share gives
-    it, and its ``EXPERT_COUNTS``; ``real`` [T]: the rows that are not
+    it, and its ``SHARE_COUNTS``; ``real`` [T]: the rows that are not
     padding. The held picks through the grouped matmul; the shared expert
     on every row."""
     y, counts = _routed_experts(x, SolarOpen2Kind.router(cfg, fp), experts, layer, real)
@@ -2088,7 +2102,7 @@ class LagunaKind(ModelKind):
     :meth:`stack` runs ``cfg.segments`` through :func:`_run_segments`; the
     routed experts are one share of an expert-parallel deployment and ride
     every step whole. Each step counts, over its tokens that are not
-    padding: ``EXPERT_COUNTS``; ``n_ctx_seq_tokens`` (:class:`Lfm2Kind`'s:
+    padding: ``SHARE_COUNTS``; ``n_ctx_seq_tokens`` (:class:`Lfm2Kind`'s:
     the context positions each of the step's sequences attends to in a full
     layer, once a sequence); ``n_win_seq_tokens``, the same for a window
     layer - the positions from its first row's lower bound to its last row:
@@ -2096,7 +2110,7 @@ class LagunaKind(ModelKind):
     name = "laguna"
     config = LagunaConfig
     state_kind = "kv+window"
-    step_counts = EXPERT_COUNTS + ("n_ctx_seq_tokens", "n_win_seq_tokens")
+    step_counts = SHARE_COUNTS + ("n_ctx_seq_tokens", "n_win_seq_tokens")
     experts_at = "moe"
 
     @staticmethod
@@ -2151,7 +2165,8 @@ class LagunaKind(ModelKind):
             return h + y, kc, vc, wk, wv, picks
 
         extra = extra or {"wk": None, "wv": None}
-        carry = (h, kc, vc, extra["wk"], extra["wv"], jnp.zeros((3,), jnp.int32))
+        carry = (h, kc, vc, extra["wk"], extra["wv"],
+                 jnp.zeros((len(SHARE_COUNTS),), jnp.int32))
         (h, kc, vc, wk, wv, picks), _ = _run_segments(cfg.segments, LagunaKind._counters, layer,
                                                       carry)
         first, length = _row_spans(batch["token_seq"], batch["token_pos"], S)
@@ -2237,7 +2252,7 @@ def _laguna_attention(cfg, kind, p, layer, x, kc, vc, batch, attn_impl, rope):
 
 def _laguna_moe(cfg, real, fp, experts, layer, x):
     """One routed feed-forward on the normalised stream, as this share gives
-    it, and its ``EXPERT_COUNTS``; ``real`` [T]: the rows that are not
+    it, and its ``SHARE_COUNTS``; ``real`` [T]: the rows that are not
     padding. The held picks through the grouped matmul; the shared expert on
     every row."""
     y, counts = _routed_experts(x, LagunaKind.router(cfg, fp), experts, layer, real)
@@ -2378,7 +2393,7 @@ def _longcat_layer_step(cfg, rope, batch, attn_impl, experts, carry, xs):
     residual only there, so the expert branch has no data dependence on
     the second half. ``xs``: (this layer's two state layers, its params,
     each half's under ``"0"`` / ``"1"``); → the carry and the expert
-    layer's ``EXPERT_COUNTS``."""
+    layer's ``SHARE_COUNTS``."""
     h, kc, vc = carry
     ids, lp = xs
 

@@ -482,6 +482,111 @@ class ExpertShare:
                 topk_idx >= self.routed)
 
 
+def share_pass_rows(T, k, share, dtype, multiple=2):
+    """The static number of rows one pass of :func:`expert_share_ffn` lays
+    out: ``multiple`` times the held picks a step of ``T`` tokens expects if
+    the router spreads its ``k`` picks evenly, ``T k held / (routed + zero)``,
+    rounded up to the row tile and at least a row a held expert - twice the
+    mean, :func:`row_tile`'s rule for a group, holds nearly every step in
+    one pass (PERF.md, PR 53). From the call's shapes and the share alone."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile
+    columns = share.routed + share.zero
+    tm = row_tile(max(1, T * k // columns) * share.held, share.held, dtype)
+    expected = -(-T * k * share.held // columns)
+    return -(-max(multiple * expected, share.held) // tm) * tm
+
+
+def expert_share_ffn(x, topk_idx, topk_vals, w1, w3, w2, share, first_group=None,
+                     activation=jax.nn.silu, pass_rows=None):
+    """:func:`dropless_moe_ffn` behind an :class:`ExpertShare` (its ``share``
+    branch; one device) → (``[T, D]``, the passes it ran, int32).
+
+    **Only the held picks are laid out.** Their flat indices ``t k + j`` are
+    compacted in order (a token's picks adjacent, tokens ascending) and go
+    through :func:`moe_grouped_mlp` ``cap`` = :func:`share_pass_rows` rows at
+    a pass: the pass's token rows gathered from ``x`` (``[cap, D]``), a
+    layout of ``cap + held x tile`` rows, and ``weight x result`` summed into
+    ``[T, D]`` by token in float32. The passes are one loop whose trip count,
+    ``ceil(held picks / cap)``, is read on the device: one in nearly every
+    step, none where no pick is held, ``T k / cap`` where every pick of the
+    router is held here - no pick is dropped at any load. Nothing of ``T k``
+    rows by the model's width is built.
+
+    Where ``cap >= T k`` statically - the share that holds every column,
+    which says only that a pick of -1 is no row (``lfm2-24b-rag``'s), or a
+    step of few tokens over a large share - the picks are laid out as
+    without a share, each a row, the ones not held outside every group: one
+    pass, the program those kinds lowered before PR 53. ``pass_rows``
+    overrides ``cap`` (``tools/kernel_census.py --share``'s sweep alone)."""
+    T, k = topk_idx.shape
+    held, zero = share.parts(topk_idx)
+    w_zero = None
+    if share.zero:
+        with jax.named_scope("ds.moe_zero"):
+            w_zero = jnp.sum(jnp.where(zero, topk_vals, 0), axis=-1, keepdims=True)
+    live, rows_a_group = held.reshape(-1), max(1, T * k // (share.routed + share.zero))
+    topk_idx, topk_vals = topk_idx - share.first, jnp.where(held, topk_vals, 0)
+    idx_rep = topk_idx.reshape(-1)  # [T*k]
+    if not fused_gmm_enabled():
+        w1, w3, w2 = (w if w is None else _unbox_stack(w, x.dtype) for w in (w1, w3, w2))
+
+    def tail(rows, idx, live):
+        return moe_grouped_mlp(rows, idx, _cast_stack(w1, x.dtype),
+                               None if w3 is None else _cast_stack(w3, x.dtype),
+                               _cast_stack(w2, x.dtype), num_experts=share.held,
+                               activation=activation, first_group=first_group,
+                               live=live, rows_a_group=rows_a_group)
+
+    cap = share_pass_rows(T, k, share, x.dtype) if pass_rows is None else pass_rows
+    if cap >= T * k:
+        # a pick that is not held is a row outside every group, weighted zero
+        out_rep = tail(jnp.repeat(x, k, axis=0), idx_rep, live)
+        out = jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_rep.reshape(T, k, -1))
+        passes = 1
+    else:
+        out, passes = _held_picks_in_passes(x, idx_rep, topk_vals, live, cap, tail)
+    if w_zero is not None:
+        with jax.named_scope("ds.moe_zero"):
+            out = out + w_zero.astype(x.dtype) * x
+    return out, passes
+
+
+def _held_picks_in_passes(x, experts, vals, live, cap, tail):
+    """The held picks (``live`` [T k] bool, over the picks' ``experts``
+    [T k] and ``vals`` [T, k]) through ``tail(rows [cap, D], experts [cap],
+    live [cap]) -> [cap, D]``, ``cap`` at a pass → (``sum_j w_j tail_j``
+    [T, D] in ``x.dtype``, the passes)."""
+    T, k = vals.shape
+    n = T * k
+    at = jnp.arange(n, dtype=jnp.int32)
+    n_held = jnp.sum(live, dtype=jnp.int32)
+    # held picks first, in their own order; what follows them is never live
+    order, experts, weights = jax.lax.sort(
+        (jnp.where(live, at, n + at), experts, vals.reshape(-1)), num_keys=1)
+    room = -(-n // cap) * cap - n       # the last pass's slice stays inside
+    tokens, experts, weights = (jnp.pad(a, (0, room)) for a in (order // k, experts, weights))
+    passes = (n_held + cap - 1) // cap
+
+    def one_pass(p, out):
+        here = p * cap + jnp.arange(cap, dtype=jnp.int32) < n_held
+        tok, e, w = (jax.lax.dynamic_slice_in_dim(a, p * cap, cap)
+                     for a in (tokens, experts, weights))
+        tok = jnp.where(here, tok, T)   # past the last token: its row is summed nowhere
+        y = tail(jnp.take(x, tok, axis=0, mode="clip"), e, here)
+        # by token on the matrix unit: [T, cap] of the picks' weights, a row's at its token
+        # (a sorted segment_sum, a scatter-add, read 8-14x this product's time on v5e: PERF.md,
+        # PR 53). The product multiplies every token by every row, so a row that is not
+        # finite is taken out and its token alone made NaN: a pick never reaches another's
+        fine = jnp.all(jnp.isfinite(y), axis=1)
+        mine = tok[None, :] == jnp.arange(T, dtype=jnp.int32)[:, None]
+        out = out + jnp.dot(jnp.where(mine, w.astype(x.dtype)[None, :], 0),
+                            jnp.where(fine[:, None], y, 0), preferred_element_type=jnp.float32)
+        return jnp.where(jnp.any(mine & ~fine[None, :], axis=1)[:, None], jnp.nan, out)
+
+    out = jax.lax.fori_loop(0, passes, one_pass, jnp.zeros((T, x.shape[1]), jnp.float32))
+    return out.astype(x.dtype), passes
+
+
 def shards_experts(mesh):
     """Whether :func:`dropless_moe_ffn` runs its experts sharded under
     ``mesh``: an ``expert`` or ``tensor`` axis larger than 1."""
@@ -530,26 +635,19 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
     of the router, today's programs unchanged): the stacks are the
     ``share.held`` experts held here and ``topk_idx`` counts over all the
     router's columns (``num_experts`` is not read). → ``sum_j w_j E_j(x)``
-    over the held picks, which alone become rows of a group, plus ``(the
+    over the held picks, which alone are laid out as rows, plus ``(the
     zero-compute picks' weights) * x``; what experts held elsewhere would
-    add is left out, and nothing is multiplied or read for it. A pick of
-    -1 is no pick (a padding token's). One device only: the exchange
-    between the shares of a mesh is not implemented."""
+    add is left out, and nothing is multiplied, read or laid out for it:
+    :func:`expert_share_ffn`, which also says how many passes the held picks
+    took. A pick of -1 is no pick (a padding token's). One device only: the
+    exchange between the shares of a mesh is not implemented."""
     T, k = topk_idx.shape
-    live = rows_a_group = w_zero = None
     if share is not None:
         if shards_experts(mesh):
             raise NotImplementedError("an expert share on a mesh with expert/tensor axes: the "
                                       "exchange between shares is not implemented")
-        held, zero = share.parts(topk_idx)
-        if share.zero:
-            with jax.named_scope("ds.moe_zero"):
-                w_zero = jnp.sum(jnp.where(zero, topk_vals, 0), axis=-1, keepdims=True)
-        # the one tail below, over the held experts: a pick that is not held is a row
-        # outside every group, weighted zero
-        live, rows_a_group = held.reshape(-1), max(1, T * k // (share.routed + share.zero))
-        topk_idx, topk_vals = topk_idx - share.first, jnp.where(held, topk_vals, 0)
-        num_experts = share.held
+        return expert_share_ffn(x, topk_idx, topk_vals, w1, w3, w2, share,
+                                first_group=first_group, activation=activation)[0]
     idx_rep = topk_idx.reshape(-1)  # [T*k]
     if not fused_gmm_enabled():
         # DS_FUSED_GMM=0: unbox quantized stacks up front — everything
@@ -620,14 +718,9 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
     out_rep = moe_grouped_mlp(x_rep, idx_rep, _cast_stack(w1, x.dtype),
                               None if w3 is None else _cast_stack(w3, x.dtype),
                               _cast_stack(w2, x.dtype), num_experts=num_experts,
-                              activation=activation, first_group=first_group,
-                              live=live, rows_a_group=rows_a_group)
+                              activation=activation, first_group=first_group)
     out_k = out_rep.reshape(T, k, -1)
-    out = jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
-    if w_zero is not None:
-        with jax.named_scope("ds.moe_zero"):
-            out = out + w_zero.astype(x.dtype) * x
-    return out
+    return jnp.einsum("tk,tkd->td", topk_vals.astype(x.dtype), out_k)
 
 
 def dense_reference_mlp(x, expert_idx, w_gate, w_up, w_down, activation=jax.nn.silu):

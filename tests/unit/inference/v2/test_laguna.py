@@ -41,8 +41,8 @@ DEBUG = LAGUNA_CONFIGS["laguna-debug"]
 BLOCK = 4
 KIND = model_runner.LagunaKind
 W = DEBUG.sliding_window
-COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_ctx_seq_tokens",
-          "n_win_seq_tokens")
+COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_share_passes",
+          "n_ctx_seq_tokens", "n_win_seq_tokens")
 
 
 def rel_err(got, want):
@@ -364,6 +364,7 @@ def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine,
     assert counts["n_ctx_seq_tokens"] == 14 + 3
     assert counts["n_win_seq_tokens"] == (14 - (11 - W + 1)) + 3
     assert counts["n_picks_held"] == 6 * 4 * 11 and 0 < counts["n_groups_live"] <= 16 * 11
+    assert counts["n_share_passes"] == 11       # every expert held: each layer one pass
     assert tracing.snapshot()["steps"][-1]["counts"] == counts
     engine.flush(60)
     engine.flush(61)

@@ -111,12 +111,12 @@ def test_presets_build_by_name_and_pick_their_kind(model):
     assert kind is model_runner.LongcatKind and kind.state_kind == "latent"
     assert kind.state_layers(model.config) == 4 and kind.state_rows(model.config) == (32, 128)
     assert kind.step_counts == ("n_picks_held", "n_picks_zero", "n_groups_live",
-                                "n_blocks_named", "n_blocks_fetched")
+                                "n_share_passes", "n_blocks_named", "n_blocks_fetched")
     moon = build_model("moonlight-debug").config
     assert model_runner.kind_of(moon) is model_runner.MoonlightKind
     assert model_runner.MoonlightKind.state_layers(moon) == moon.num_hidden_layers
     assert model_runner.LlamaKind.state_layers(build_model("debug").config) == 2
-    assert model_runner.MoonlightKind.step_counts == kind.step_counts[3:]
+    assert model_runner.MoonlightKind.step_counts == kind.step_counts[4:]
     assert model_runner.LlamaKind.step_counts == ()
 
 
@@ -451,7 +451,13 @@ def test_step_records_carry_the_device_side_counts(engine, tokens):
     assert engine.host_syncs - syncs == 2            # as for any model kind: pack + fetch
     got = engine.last_step.counts
     # the reference's picks, layer by layer, from its own hidden states
-    want = {"n_picks_held": 0, "n_picks_zero": 0, "n_groups_live": 0}
+    want = {"n_picks_held": 0, "n_picks_zero": 0, "n_groups_live": 0, "n_share_passes": 0}
+    # a pass's rows in this step's program (PR 53): this engine's share is 4 of 8 + 4 columns
+    from deepspeed_tpu.ops.grouped_gemm import share_pass_rows
+    rows = int(engine.last_step.program)
+    cap = share_pass_rows(rows, cfg.moe_topk, ExpertShare(
+        cfg.first_expert_held, cfg.held, cfg.n_routed_experts, cfg.zero_expert_num), jnp.float32)
+    assert cap < rows * cfg.moe_topk            # the held picks are compacted here
     seen = []
     experts = longcat.reference_experts
     try:
@@ -466,6 +472,7 @@ def test_step_records_carry_the_device_side_counts(engine, tokens):
         want["n_picks_held"] += int(held.sum())
         want["n_picks_zero"] += int(picked[:, cfg.n_routed_experts:].sum())
         want["n_groups_live"] += int(held.any(axis=0).sum())
+        want["n_share_passes"] += -(-int(held.sum()) // cap)
     assert len(seen) == cfg.num_layers and {name: got[name] for name in want} == want
     # ... and the blocks the latent attention's rows name (a padding row the null block)
     # and fetch: the gather, which serves here, reads whatever is named

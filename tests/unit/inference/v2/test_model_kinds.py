@@ -126,3 +126,69 @@ def test_the_one_router_gives_each_kinds_reference_picks_and_weights(name):
     np.testing.assert_allclose(got[:-1], want[:-1], rtol=1e-5, atol=1e-7)
     unbiased = np.asarray(model_runner._route(x, r._replace(bias=jnp.zeros_like(bias)))[0])
     assert (np.sort(unbiased[:-1]) != np.sort(picks[:-1])).any()        # the bias did choose
+
+
+SHARE_KINDS = {"longcat-flash-debug": model_runner.LongcatKind,
+               "nemotron-h-debug": model_runner.NemotronHKind,
+               "solar-open2-debug": model_runner.SolarOpen2Kind,
+               "laguna-debug": model_runner.LagunaKind}
+
+
+def test_the_kinds_behind_a_share_count_their_passes_and_no_other_kind_does():
+    """``n_share_passes`` (PR 53: the passes a step's held picks took through
+    the grouped matmul, summed over its expert layers) rides the step counts
+    of the four kinds whose router has more columns than the rank holds, right
+    behind ``EXPERT_COUNTS``; the share of every column (LFM2's, which says
+    only that a padding row picks nothing) and the kinds without a share count
+    what they counted. (That a step's record carries the number: each of the
+    four kinds' own ``test_step_records_carry_...``.)"""
+    for preset in PRESETS:
+        kind = model_runner.kind_of(models.build_model(preset).config)
+        if preset in SHARE_KINDS:
+            assert kind is SHARE_KINDS[preset]
+            assert kind.step_counts[:4] == model_runner.SHARE_COUNTS == \
+                model_runner.EXPERT_COUNTS + ("n_share_passes",)
+        else:
+            assert "n_share_passes" not in kind.step_counts
+    assert model_runner.Lfm2Kind.step_counts[:3] == model_runner.EXPERT_COUNTS
+
+
+@pytest.mark.parametrize("every_column", [False, True])
+def test_without_a_share_and_with_every_column_held_the_tail_lowers_as_before(every_column):
+    """``tools/hash_step_programs.py``'s rule on the one function PR 53
+    changed: ``dropless_moe_ffn(share=None)`` (Mixtral's, Moonlight's and
+    training's tail) and the share that holds every column (``_routed_experts``
+    builds it for LFM2's padding rows) lower to the text of the tail as it was
+    before the held picks were compacted, transcribed here - every pick a
+    row, the take and the ``tk,tkd->td`` sum over all of them."""
+    from deepspeed_tpu.ops.grouped_gemm import (ExpertShare, dropless_moe_ffn, expert_share_ffn,
+                                                moe_grouped_mlp)
+    T, k, D, F, E = 32, 3, 64, 128, 8
+    share = ExpertShare(0, E, E) if every_column else None
+
+    def before(x, idx, vals, w1, w3, w2, first):
+        live = rows = None
+        if share is not None:
+            held, _ = share.parts(idx)
+            live, rows = held.reshape(-1), max(1, T * k // (share.routed + share.zero))
+            idx, vals = idx - share.first, jnp.where(held, vals, 0)
+        idx_rep = idx.reshape(-1)
+        out_rep = moe_grouped_mlp(jnp.repeat(x, k, axis=0), idx_rep, w1.astype(x.dtype),
+                                  w3.astype(x.dtype), w2.astype(x.dtype), num_experts=E,
+                                  activation=jax.nn.silu, first_group=first, live=live,
+                                  rows_a_group=rows)
+        return jnp.einsum("tk,tkd->td", vals.astype(x.dtype), out_rep.reshape(T, k, -1))
+
+    def tail(x, idx, vals, w1, w3, w2, first):
+        if share is None:
+            return dropless_moe_ffn(x, idx, vals, w1, w3, w2, num_experts=E, first_group=first,
+                                    widen_boundary=False)
+        return expert_share_ffn(x, idx, vals, w1, w3, w2, share, first_group=first)[0]
+
+    sds = jax.ShapeDtypeStruct
+    args = (sds((T, D), jnp.float32), sds((T, k), jnp.int32), sds((T, k), jnp.float32),
+            sds((2 * E, D, F), jnp.float32), sds((2 * E, D, F), jnp.float32),
+            sds((2 * E, F, D), jnp.float32), sds((), jnp.int32))
+    texts = [jax.jit(fn).lower(*args).as_text().replace(fn.__name__, "fn")
+             for fn in (before, tail)]
+    assert texts[0] == texts[1] and "stablehlo.dot_general" in texts[0]

@@ -459,6 +459,7 @@ def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine,
     assert counts["n_state_slots"] == 2 * cfg.count("M") and counts["n_picks_zero"] == 0
     assert 0 < counts["n_picks_held"] <= 29 * cfg.num_experts_per_tok * cfg.count("E")
     assert 0 < counts["n_groups_live"] <= cfg.held * cfg.count("E")
+    assert counts["n_share_passes"] == cfg.count("E")   # every expert held: a pass a layer
     assert tracing.snapshot()["steps"][-1]["counts"] == counts
     engine.flush(60)
     engine.flush(61)

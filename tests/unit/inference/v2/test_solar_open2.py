@@ -41,8 +41,8 @@ DEBUG = SOLAR_OPEN2_CONFIGS["solar-open2-debug"]
 BLOCK = 16
 KIND = model_runner.SolarOpen2Kind
 LK = DEBUG.count("k")
-COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_kda_rows", "n_state_slots",
-          "n_scan_runs", "n_kda_chunk_rows")
+COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_share_passes", "n_kda_rows",
+          "n_state_slots", "n_scan_runs", "n_kda_chunk_rows")
 
 
 def rel_err(got, want):
@@ -646,6 +646,7 @@ def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine,
     assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_scan_runs"],
             counts["n_kda_chunk_rows"]) == (29 * LK, 2 * LK, 2 * LK, 0)
     assert counts["n_picks_held"] == 29 * 4 * 8 and 0 < counts["n_groups_live"] <= 16 * 8
+    assert counts["n_share_passes"] == 8        # every expert held: each layer one pass
     assert tracing.snapshot()["steps"][-1]["counts"] == counts
     assert tracing.snapshot()["steps"][-1]["state_step"] == "xla"
     engine.flush(60)

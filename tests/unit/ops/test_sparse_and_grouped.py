@@ -1,6 +1,8 @@
 """Sparse attention + grouped MoE GEMM tests (analogue of reference
 tests/unit/ops/sparse_attention/ and MoE gemm coverage)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -77,6 +79,26 @@ class TestSparseSelfAttention:
         o1 = attn(q, q, v1)
         o2 = attn(q, q, v2)
         np.testing.assert_array_equal(np.asarray(o1[:, :8]), np.asarray(o2[:, :8]))
+
+
+@functools.lru_cache(maxsize=None)
+def _compacted_share(gated, pallas, zero):
+    """→ (the jitted ``expert_share_ffn`` of one form - gated or not, its
+    dispatch (the caller forces it while it traces), with or without
+    zero-compute columns - the stacks, the activation, a pass's rows) at
+    32 tokens x 4 picks over experts 2..5 of 16."""
+    from deepspeed_tpu.ops.grouped_gemm import ExpertShare, expert_share_ffn, share_pass_rows
+    act = jax.nn.silu if gated else lambda v: jnp.square(jax.nn.relu(v))
+    rng = np.random.RandomState(7 + zero)
+    T, k, D, F, held = 32, 4, 128, 128, 4
+    share = ExpertShare(first=2, held=held, routed=16, zero=zero)
+    w1, w3, w2 = (jnp.asarray(rng.randn(held, *shape).astype(np.float32) * 0.05)
+                  for shape in ((D, F), (D, F), (F, D)))
+    if not gated:
+        w3 = None
+    run = jax.jit(lambda x, idx, vals: expert_share_ffn(x, idx, vals, w1, w3, w2, share,
+                                                        activation=act))
+    return run, (w1, w3, w2), act, share_pass_rows(T, k, share, jnp.float32)
 
 
 class TestGroupedGemm:
@@ -242,6 +264,85 @@ class TestGroupedGemm:
                     want[t] += float(vals[t, j]) * np.asarray(relu2(x[t] @ w1[e]) @ w2[e])
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
         assert np.abs(want[5]).max() == 0.0
+
+    @pytest.mark.parametrize("load", ["typical", "every_pick", "none", "a_pass_full",
+                                      "a_pass_and_one"])
+    @pytest.mark.parametrize("zero", [0, 8])
+    @pytest.mark.parametrize("pallas", [False, True])
+    @pytest.mark.parametrize("gated", [True, False])
+    def test_a_compacted_share_is_the_held_picks_through_the_reference(self, gated, pallas,
+                                                                       zero, load):
+        """``expert_share_ffn`` over passes of ``share_pass_rows`` rows: the
+        held picks' ``w_j E_j(x)`` by ``dense_reference_mlp`` (plus the
+        zero-compute picks' ``w x``) at a typical load (one pass), with every
+        pick held (``T k / cap`` passes: nothing is dropped), with none held
+        and padding tokens' picks of -1 (no pass, no row out), and with
+        exactly a pass's rows held and one more; the passes returned are
+        ``ceil(held picks / cap)``. One program a form serves its five loads."""
+        import deepspeed_tpu.ops.grouped_gemm as gg
+        T, k, D, first, held, routed = 32, 4, 128, 2, 4, 16
+        run, (w1, w3, w2), act, cap = _compacted_share(gated, pallas, zero)
+        assert cap < T * k and cap % 16 == 0
+        rng = np.random.RandomState(53)
+        n_held = {"typical": cap // 2 - 3, "every_pick": T * k, "none": 0, "a_pass_full": cap,
+                  "a_pass_and_one": cap + 1}[load]
+        elsewhere = [c for c in range(routed + zero) if not first <= c < first + held]
+        idx = rng.choice(elsewhere, (T, k)).astype(np.int32)
+        if load == "none":
+            idx[[3, 17, 31]] = -1
+        flat = idx.reshape(-1)
+        flat[rng.permutation(T * k)[:n_held]] = first + rng.randint(0, held, n_held)
+        x = jnp.asarray(rng.randn(T, D).astype(np.float32) * 0.5)
+        vals = rng.rand(T, k).astype(np.float32)
+        gg.FORCE_INTERPRET = pallas
+        try:
+            got, passes = run(x, jnp.asarray(idx), jnp.asarray(vals))
+        finally:
+            gg.FORCE_INTERPRET = False
+        assert int(passes) == -(-n_held // cap)
+        tok, j = np.nonzero((idx >= first) & (idx < first + held))
+        assert len(tok) == n_held
+        want = np.zeros((T, D), np.float32)
+        if n_held:
+            rows = dense_reference_mlp(x[tok], jnp.asarray(idx[tok, j] - first), w1, w3, w2,
+                                       activation=act)
+            np.add.at(want, tok, vals[tok, j][:, None] * np.asarray(rows))
+        want += np.where(idx >= routed, vals, 0).sum(-1, keepdims=True) * np.asarray(x)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
+        if load == "none":
+            assert not np.asarray(got)[[3, 17, 31]].any()
+
+    def test_a_share_builds_no_array_of_every_pick_by_the_width(self):
+        """The point of PR 53: behind a share that holds fewer columns than the
+        router has, nothing of ``T k`` rows by the model's width is built -
+        no repeat, no take, no einsum over every pick; the share of every
+        column still lays every pick (the programs of the kinds that pass it
+        are unchanged), which is also what shows that the search sees."""
+        from deepspeed_tpu.ops.grouped_gemm import (ExpertShare, expert_share_ffn,
+                                                    share_pass_rows)
+        T, k, D, F, held, routed = 64, 4, 128, 256, 4, 32
+        stacks = [jnp.zeros((held,) + s, jnp.float32) for s in ((D, F), (D, F), (F, D))]
+
+        def wide_rows(share):
+            jaxpr = jax.make_jaxpr(lambda x, idx, vals: expert_share_ffn(
+                x, idx, vals, *stacks, share))(
+                    jnp.zeros((T, D)), jnp.zeros((T, k), jnp.int32), jnp.zeros((T, k)))
+            found, todo = [], [jaxpr.jaxpr]
+            while todo:
+                inner = todo.pop()
+                for eqn in inner.eqns:
+                    found += [v.aval.shape for v in eqn.outvars
+                              if getattr(v.aval, "shape", ())[:1] == (T * k,)
+                              and set(v.aval.shape[1:]) & {D, F}]
+                    todo += [getattr(sub, "jaxpr", sub) for sub in jax.core.jaxprs_in_params(
+                        eqn.params)]
+            return found
+
+        share = ExpertShare(0, held, routed)
+        cap = share_pass_rows(T, k, share, jnp.float32)
+        assert cap + held * 16 < T * k      # a pass's layout is not T k rows by chance
+        assert wide_rows(share) == []
+        assert (T * k, D) in wide_rows(ExpertShare(0, held, held))
 
     def test_grouped_under_jit_and_grad(self):
         rng = np.random.RandomState(2)

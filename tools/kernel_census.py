@@ -62,6 +62,18 @@ call over the same sequences' whole contexts and over contexts cut to the
 window's: the windowed call has to cost what the unwindowed one costs at 512
 tokens, not at the sequence's (~1 min, ``chiprun_out/window_census.json``).
 
+``--share`` times one expert layer's tail behind an **expert share** (PR 53:
+``ops/grouped_gemm.expert_share_ffn``) at the four share cells' shapes - a
+512-token mixed step and the burst's rows of ``longcat-flash-topics`` (16 of
+768 columns, 12 picks), ``laguna-xs2-repochat`` (32 of 256, 8),
+``solar-open2-reason`` (40 of 320, 8) and ``nemotron3-super-agents`` (128 of
+512, 22) - with every pick a row of the layout (``pass_rows = T k``: the
+program before PR 53) and with the held picks compacted into passes of 1, 2
+and 4 times the picks a step expects (2 is ``share_pass_rows``' rule), and the
+parts alone: the ways to list the held picks and to sum a pass's rows by
+token. Every time is a turn of a loop inside one program, the expert stacks
+its arguments (~8 min, ``chiprun_out/share_census.json``; PERF.md, PR 53).
+
 ``--mla`` times ``paged_mla_decode_attention`` at the shape classes the two
 latent cells serve (256-row bf16 blocks of 512 + 128 values, a traced layer
 index): Moonlight's 128 decode rows at contexts 1024-4096 under 16 heads,
@@ -1106,6 +1118,176 @@ def window_classes():
         yield name, record
 
 
+# ``--share``: (name, tokens, picks a token, held, routed, zero-compute columns, width the
+# experts see, their inner width, gated): the four cells that serve one rank's share
+SHARE_CLASSES = (("longcat-mixed-512", 512, 12, 16, 512, 256, 6144, 2048, True),
+                 ("longcat-burst-256", 256, 12, 16, 512, 256, 6144, 2048, True),
+                 ("laguna-mixed-512", 512, 8, 32, 256, 0, 2048, 512, True),
+                 ("laguna-burst-64", 64, 8, 32, 256, 0, 2048, 512, True),
+                 ("solar-mixed-512", 512, 8, 40, 320, 0, 4096, 1280, True),
+                 ("solar-burst-192", 192, 8, 40, 320, 0, 4096, 1280, True),
+                 ("nemotron-mixed-512", 512, 22, 128, 512, 0, 1024, 2688, False),
+                 ("nemotron-burst-128", 128, 22, 128, 512, 0, 1024, 2688, False))
+
+
+def share_classes():
+    """Yields one record a shape class: one expert layer's tail behind a
+    share (``ops/grouped_gemm.expert_share_ffn`` over a table of two layers'
+    experts, the router's picks drawn evenly over its columns) with every
+    pick a row of the layout (``pass_rows = T k``: the program before PR 53)
+    and with the held picks compacted into passes of 1, 2 and 4 times the
+    picks a step expects (2 is the rule's), ms a layer and the passes run; then
+    the parts alone at the rule's ``cap``: the ways to list the held picks in
+    order and the ways to sum a pass's rows into ``[T, D]`` by token. Every
+    time is of ``SHARE_LOOP`` turns of a loop inside one program, each turn's
+    input a function of the last turn's result: the device's time, not the
+    host's dispatch (0.2-0.5 ms a call on the chip's host)."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import grouped_gemm as gg
+
+    rng = np.random.default_rng(53)
+    relu2 = lambda v: jnp.square(jax.nn.relu(v))  # noqa: E731
+    for name, T, k, held, routed, zero, D, F, gated in SHARE_CLASSES:
+        share = gg.ExpertShare(0, held, routed, zero)
+        columns = routed + zero
+        picks = np.stack([rng.permutation(columns)[:k] for _ in range(T)]).astype(np.int32)
+        idx = jnp.asarray(picks)
+        vals = jnp.asarray(rng.uniform(0.05, 0.2, (T, k)), jnp.float32)
+        x = jnp.asarray(rng.standard_normal((T, D), np.float32), jnp.bfloat16)
+        G = 2 * held
+        stacks = tuple(None if shape is None else jax.jit(
+            lambda key, shape=shape: jax.random.normal(key, (G,) + shape, jnp.bfloat16)
+            * shape[0] ** -0.5)(jax.random.PRNGKey(i))
+            for i, shape in enumerate(((D, F), (D, F) if gated else None, (F, D))))
+        first = jnp.int32(held)
+        cap = gg.share_pass_rows(T, k, share, x.dtype)
+        record = {"T": T, "k": k, "held": held, "columns": columns, "D": D, "F": F,
+                  "held_picks": int((picks < held).sum()), "cap": cap,
+                  "weight_bytes": held * D * F * 2 * (3 if gated else 2)}
+
+        def layer(rows):
+            def once(x, idx, vals, first, stacks):
+                return gg.expert_share_ffn(x, idx, vals, *stacks, share, first_group=first,
+                                           activation=jax.nn.silu if gated else relu2,
+                                           pass_rows=rows)
+
+            def looped(x, idx, vals, first, stacks):
+                def turn(_, carry):
+                    y, passes = once(x + carry[0] * jnp.bfloat16(1e-3), idx, vals, first, stacks)
+                    return y, jnp.asarray(passes, jnp.int32)
+                return jax.lax.fori_loop(0, SHARE_LOOP, turn, (jnp.zeros_like(x), jnp.int32(0)))
+
+            return jax.jit(once), jax.jit(looped)
+
+        args = (x, idx, vals, first, stacks)
+        once, looped = layer(T * k)
+        want, _ = once(*args)
+        record["every_pick_a_row_ms"] = _ms_a_call(looped, *args, calls=3) / SHARE_LOOP
+        for multiple in (1, 2, 4):
+            rows = gg.share_pass_rows(T, k, share, x.dtype, multiple=multiple)
+            if rows >= T * k:
+                continue
+            once, looped = layer(rows)
+            got, passes = once(*args)
+            record[f"passes_of_{multiple}x"] = {
+                "rows": rows, "passes": int(passes),
+                "ms": _ms_a_call(looped, *args, calls=3) / SHARE_LOOP,
+                "rel_err": float(f"{rel_err(got, want):.3e}")}
+        if cap < T * k:
+            record["parts_us"] = _share_parts(T, k, D, cap, idx < held, idx, vals, x.dtype)
+        del stacks, args
+        yield name, record
+
+
+SHARE_LOOP = 20
+
+
+def _share_parts(T, k, D, cap, held, idx, vals, dtype):
+    """us a turn of the candidates for the two parts of a pass that are not
+    the grouped matmul: listing the held picks, and the sum by token."""
+    import jax
+    import jax.numpy as jnp
+
+    n = T * k
+    at = jnp.arange(n, dtype=jnp.int32)
+    turns = 10 * SHARE_LOOP
+
+    def sort3(live, idx, vals):
+        return jax.lax.sort((jnp.where(live, at, n + at), idx, vals), num_keys=1)
+
+    def sort1_gather(live, idx, vals):
+        order = jnp.sort(jnp.where(live, at, n + at))[:cap] % n
+        return order, idx[order], vals[order]
+
+    def scatter_gather(live, idx, vals):
+        slot = jnp.where(live, jnp.cumsum(live, dtype=jnp.int32) - 1, n)
+        order = jnp.zeros((n,), jnp.int32).at[slot].set(at, mode="drop", unique_indices=True)[:cap]
+        return order, idx[order], vals[order]
+
+    def search_gather(live, idx, vals):
+        csum = jnp.cumsum(live, dtype=jnp.int32)
+        order = jnp.sum(csum[None, :] <= jnp.arange(cap, dtype=jnp.int32)[:, None], axis=1)
+        order = jnp.minimum(order, n - 1)
+        return order, idx[order], vals[order]
+
+    def search_alone(live, idx, vals):
+        csum = jnp.cumsum(live, dtype=jnp.int32)
+        order = jnp.sum(csum[None, :] <= jnp.arange(cap, dtype=jnp.int32)[:, None], axis=1)
+        return order, order, vals[:cap]
+
+    def listed(fn):
+        def looped(held, idx, vals):
+            def turn(_, c):     # c stays 0, which the compiler cannot know
+                a, b, w = fn(held.reshape(-1) ^ (c != 0), idx.reshape(-1) + c, vals.reshape(-1))
+                return jnp.minimum(a[0] + b[0], 0) * (w[0] > 1e9)
+            return jax.lax.fori_loop(0, turns, turn, jnp.int32(0))
+        return jax.jit(looped)
+
+    out = {}
+    for fn in (sort3, sort1_gather, scatter_gather, search_gather, search_alone):
+        out["list_" + fn.__name__] = _ms_a_call(listed(fn), held, idx, vals, calls=3) * 1e3 / turns
+    tok = jnp.sort(jnp.where(held.reshape(-1), at, n + at))[:cap] // k
+    tok = jnp.where(tok < T, tok, T)
+    y = jax.random.normal(jax.random.PRNGKey(5), (cap, D), dtype)
+    w = jnp.full((cap,), 0.1, jnp.float32)
+
+    def segment_sum(acc, y, w, tok):
+        prod = y.astype(jnp.float32) * w.astype(dtype).astype(jnp.float32)[:, None]
+        return acc + jax.ops.segment_sum(prod, tok, num_segments=T, indices_are_sorted=True)
+
+    def one_hot_product(acc, y, w, tok):
+        hot = jnp.where(tok[None, :] == jnp.arange(T, dtype=jnp.int32)[:, None],
+                        w.astype(dtype)[None, :], 0)
+        return acc + jnp.dot(hot, y, preferred_element_type=jnp.float32)
+
+    def one_hot_guarded(acc, y, w, tok):    # the one that serves: a row not finite reaches
+        fine = jnp.all(jnp.isfinite(y), axis=1)     # its own token alone
+        mine = tok[None, :] == jnp.arange(T, dtype=jnp.int32)[:, None]
+        acc = acc + jnp.dot(jnp.where(mine, w.astype(dtype)[None, :], 0),
+                            jnp.where(fine[:, None], y, 0), preferred_element_type=jnp.float32)
+        return jnp.where(jnp.any(mine & ~fine[None, :], axis=1)[:, None], jnp.nan, acc)
+
+    def carried_alone(acc, y, w, tok):      # the float32 carry's read and write, and y's read
+        return acc + jnp.sum(y.astype(jnp.float32) * w[:, None], axis=0, keepdims=True)
+
+    def summed(fn):
+        def looped(y, w, tok):
+            def turn(_, acc):   # y a function of the carry, or the product leaves the loop
+                return fn(acc, y + (acc[:1, :1] * 1e-9).astype(dtype), w, tok)
+            return jax.lax.fori_loop(0, turns, turn, jnp.zeros((T, D), jnp.float32))
+        return jax.jit(looped)
+
+    want = segment_sum(jnp.zeros((T, D), jnp.float32), y, w, tok)
+    for fn in (segment_sum, one_hot_product, one_hot_guarded, carried_alone):
+        out["sum_" + fn.__name__] = _ms_a_call(summed(fn), y, w, tok, calls=3) * 1e3 / turns
+    out["sum_one_hot_rel_err"] = float(f"{rel_err(one_hot_product(want * 0, y, w, tok), want):.3e}")
+    return out
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -1136,7 +1318,10 @@ def main():
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
     live, ssm, chunk = "--live" in sys.argv, "--ssm" in sys.argv, "--chunk" in sys.argv
     scan, kda, window = "--scan" in sys.argv, "--kda" in sys.argv, "--window" in sys.argv
-    if window:
+    share = "--share" in sys.argv
+    if share:
+        section, records = "expert_share", share_classes()
+    elif window:
         section, records = "window_attention", window_classes()
     elif kda:
         section, records = "kda", kda_classes()
@@ -1160,7 +1345,7 @@ def main():
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
     for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk or scan or kda
-                                     or window or "--gmm-only" in sys.argv
+                                     or window or share or "--gmm-only" in sys.argv
                                      else cases()):
         try:
             result = verdict(fn, ref, args, tol)
@@ -1169,7 +1354,8 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("window_census.json" if window else "kda_census.json" if kda
+    out = ("share_census.json" if share else "window_census.json" if window
+           else "kda_census.json" if kda
            else "scan_census.json" if scan
            else "chunk_census.json" if chunk
            else "ssm_census.json" if ssm
