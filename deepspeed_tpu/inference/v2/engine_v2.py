@@ -196,6 +196,9 @@ class InferenceEngineV2:
                     f"tp={int(self._config.tensor_parallel_degree)} "
                     f"ep={int(self._config.expert_parallel_degree)} "
                     f"kv_bytes={self.kv_cache.bytes()/1e6:.1f}MB "
+                    f"state_layers={self.kv_cache.num_layers} "
+                    f"param_layers={self.param_layers} "
+                    f"state_bytes_per_token={self.state_bytes_per_token} "
                     f"experts={self.kind.experts_form(self.params, self.mesh)}"
                     + ("" if self.state_extra is None else
                        f" kind={self.kind.name} " + " ".join(
@@ -243,7 +246,7 @@ class InferenceEngineV2:
         qmode = getattr(self._config.quantization, "quantization_mode", "none")
         self._qmode = qmode
         self._quantized = bool(qmode and qmode != "none")
-        if kind.state_kind != "kv":
+        if kind.state_kind != "kv" or kind.refuses:
             self._refuse_unsupported(kind, tp * ep)
         if params is not None:
             owns = all(not isinstance(leaf, jax.Array) for leaf in jax.tree.leaves(params))
@@ -300,6 +303,9 @@ class InferenceEngineV2:
         self.state_kind = kind.state_kind
         self._state_step_said = set()       # the programs whose state step has been logged
         self.state_bytes_per_token = self.kv_cache.bytes_per_token()
+        # the layers of parameters under the pools' ``state_layers``: fewer where a layer
+        # keeps no paged state, **fewer still where the stack runs several times** (Ouro)
+        self.param_layers = getattr(cfg, "num_hidden_layers", None) or cfg.num_layers
         # A kind with window layers: a second pool for their keys and values, whose blocks
         # a sequence gives back as they fall behind its window. Its device arrays are the
         # programs' ``extra`` (carried and donated like any kind's), its table a sequence
@@ -602,7 +608,9 @@ class InferenceEngineV2:
         rows at all) is served by the core path only: every optional
         subsystem that reads, moves or shards the two KV pools refuses it
         here, at construction and by name, instead of failing inside a
-        program."""
+        program. A kind whose state is keys and values refuses what it
+        names itself (``kind.refuses``: a stack run several times has no
+        adapter slabs a pass) and keeps the rest."""
         from deepspeed_tpu.inference.v2.kv_tier import kv_tier_enabled
         from deepspeed_tpu.inference.v2.prefix_cache import prefix_cache_enabled
         from deepspeed_tpu.inference.v2.spec import spec_decode_enabled
@@ -618,7 +626,7 @@ class InferenceEngineV2:
             "tensor/expert-parallel sharding": n_devices > 1,
         }
         for subsystem, on in asked.items():
-            if on:
+            if on and (kind.state_kind != "kv" or subsystem in kind.refuses):
                 raise NotImplementedError(
                     f"{subsystem} does not support the {kind.state_kind!r} state of "
                     f"model kind {kind.name!r} ({type(self.model_config).__name__}); "

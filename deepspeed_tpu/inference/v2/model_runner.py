@@ -53,8 +53,8 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import (GPTConfig, JambaConfig, LagunaConfig, Lfm2MoeConfig, LlamaConfig,
                                   LongcatFlashConfig, MiniCPMSalaConfig, MoonlightConfig,
-                                  NemotronHConfig, SolarOpen2Config)
-from deepspeed_tpu.models import laguna
+                                  NemotronHConfig, OuroConfig, SolarOpen2Config)
+from deepspeed_tpu.models import laguna, ouro
 from deepspeed_tpu.models.lfm2 import TOPK_EPS
 from deepspeed_tpu.models.llama import rope_frequencies, rope_scaling_of
 from deepspeed_tpu.models.nemotron_h import relu2
@@ -104,8 +104,11 @@ def _proj(x, p):
 
 def _rope_flat(x, cos, sin, positions):
     """x: [T, H, D]; cos/sin tables [maxlen, D/2]; positions [T]."""
-    c = cos[positions][:, None, :]
-    s = sin[positions][:, None, :]
+    return _rotate_halves(x, cos[positions][:, None, :], sin[positions][:, None, :])
+
+
+def _rotate_halves(x, c, s):
+    """x [T, H, D] rotated by halves, in float32: c / s [T, 1, D/2], row t token t's."""
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)
@@ -548,6 +551,9 @@ class ModelKind:
     seq_rows = 0            # per-sequence rows of the batch (``seq_state``'s length)
     slot_state = ()         # the entries of extra_state a slot is a row of
     experts_at = None       # the entry of params["model"] that holds the routed experts, whole
+    # the optional subsystems (``InferenceEngineV2._refuse_unsupported``'s names) a kind
+    # whose state is ``kv`` refuses all the same; any other state refuses them all
+    refuses = ()
     stack = classmethod(_scanned_stack)
 
     @staticmethod
@@ -2260,8 +2266,157 @@ def _laguna_moe(cfg, real, fp, experts, layer, x):
         return y + _swiglu(x, fp["shared_experts"]), counts
 
 
+class OuroKind(ModelKind):
+    """Ouro (``models/ouro.py``): **one stack of ``L`` layers run ``R =
+    total_ut_steps`` times with the same weights**, every pass writing keys
+    and values of its own - so **the pools are ``R x L`` layers deep under
+    ``L`` layers of parameters**: pass ``u``, layer ``l`` reads
+    ``layers[l]`` and reads and writes pool layer ``u L + l`` (the published
+    cache index), never another pass's. Every other kind's pool layer and
+    weight layer are one index; here the scan's ``xs`` pair ``u L +
+    arange(L)`` with the stack.
+
+    :meth:`passes` is **a Python loop of ``R`` scans over the layers**, each
+    with the same stack as its ``xs``: ``R`` loops in the program that read
+    a layer's matrices as :class:`LlamaKind`'s one loop does, the pools
+    carried through all of them and written in place, and what no pass
+    changes (the rows' rotation, the gate's vector) computed once before
+    them (:meth:`_pass`). Not a scan over the passes of that scan: with the
+    stack invariant to an outer loop, the chip's compiler hoists the
+    relayout it wants of ``q_proj`` and ``k_proj`` out of both - two copies
+    of a whole stack (2 x 403 MB at the published size) every step and 815
+    MB of temporaries, where this form has 8 MB. The choice is the
+    compiler's and a small thing moves it: with the gate's vector cut out
+    of its matrix inside every pass, this form too compiled to the two
+    copies and 807 MB (PERF.md, PR 54: all three compiled for the chip, the
+    last also measured on it). A block has four norms - each sublayer's
+    output is normed before it joins the residual (``input_layernorm_2``,
+    ``post_attention_layernorm_2``) - and the model's norm closes **every**
+    pass, so what enters pass ``u + 1`` is normed and :meth:`final_norm` has
+    nothing left to do. After each pass the exit gate reads the normed stream
+    (``sigmoid(x_u w_g + b_g)``, float32); :meth:`stack` sends to the head,
+    row by row, the ``x_u`` of the first pass whose cumulative exit
+    probability reaches ``early_exit_threshold`` (``ouro.exit_steps``; at the
+    published threshold 1 the last). All ``R`` passes run for every row, as
+    the published code's do: the threshold selects, it skips no compute.
+
+    Adapters, weight-only quantization and a mesh are refused by name at
+    construction (``refuses``: the adapters' slabs are ``L`` deep and a row
+    meets each ``R`` times; neither of the others is tested here); the prefix
+    cache, the KV tier and drafting read the pools through the cache's own
+    ``R L`` layers and serve. Each step counts: ``n_stack_passes`` (``R``:
+    what an adaptive exit would lower), ``n_loop_token_layers`` (rows that
+    are not padding x passes x layers) and ``n_exit_early_rows`` (such rows
+    whose exit step is under ``R - 1``: 0 at threshold 1)."""
+    name = "ouro"
+    config = OuroConfig
+    step_counts = ("n_stack_passes", "n_loop_token_layers", "n_exit_early_rows")
+    refuses = ("LoRA serving", "weight-only quantization", "tensor/expert-parallel sharding")
+
+    @staticmethod
+    def state_layers(cfg):
+        return cfg.state_layers
+
+    @staticmethod
+    def pool_layers(cfg, u):
+        """→ [L] int32: the pool layers pass ``u`` reads and writes, layer
+        ``l``'s at ``l`` (the published ``current_ut * num_hidden_layers +
+        layer_idx``)."""
+        L = cfg.num_hidden_layers
+        return u * L + jnp.arange(L, dtype=jnp.int32)
+
+    @staticmethod
+    def _pass(params, cfg, batch, attn_impl):
+        """→ ``one_pass(u, h, kc, vc)`` → (x_u, g_u, kc, vc), with what no pass
+        changes computed here, once: the rows' rotation, the gate's vector."""
+        model, eps = params["model"], cfg.rms_norm_eps
+        rope = tuple(t[:, None, :]
+                     for t in _rope_rows(batch["token_pos"], cfg.head_dim, cfg.rope_theta))
+        step = functools.partial(_ouro_layer_step, cfg, rope, batch, attn_impl)
+        gate = model["early_exit_gate"]
+        w, b = gate["kernel"][:, 0].astype(jnp.float32), gate["bias"][0].astype(jnp.float32)
+
+        def one_pass(u, h, kc, vc):
+            (h, kc, vc), _ = jax.lax.scan(step, (h, kc, vc),
+                                          (OuroKind.pool_layers(cfg, u), model["layers"]))
+            with jax.named_scope("ds.ouro.loop_norm"):
+                h = _rms(h, model["norm"]["scale"], eps)
+            with jax.named_scope("ds.ouro.gate"):
+                # [T, D] x [D, 1] as a product and a sum a row: float32, whatever a
+                # backend makes of a float32 matmul
+                g = jax.nn.sigmoid(jnp.sum(h.astype(jnp.float32) * w, axis=-1) + b)
+            return h, g, kc, vc
+
+        return one_pass
+
+    @staticmethod
+    def run_pass(params, cfg, u, h, kc, vc, batch, attn_impl=None):
+        """Pass ``u`` (may be traced) of the stack alone: h [T, D], the stream
+        that enters it → (x_u [T, D]: what the model's norm leaves of it, g_u
+        [T] float32: its gate, kc, vc). The check hooks' way to run one pass
+        **on a given stream** (the seeded weights amplify a difference ~2.6
+        times a pass: PERF.md, PR 54); :meth:`passes`' own step."""
+        return OuroKind._pass(params, cfg, batch, attn_impl)(u, h, kc, vc)
+
+    @staticmethod
+    def passes(params, cfg, h, kc, vc, batch, attn_impl=None):
+        """h [T, D] (the embedded rows) through all ``R`` passes → (x [R, T,
+        D]: every pass's normed stream ``x_u``, g [R, T] float32: its gate,
+        kc, vc)."""
+        one_pass = OuroKind._pass(params, cfg, batch, attn_impl)
+        x, g = [], []
+        for u in range(cfg.total_ut_steps):
+            h, g_u, kc, vc = one_pass(u, h, kc, vc)
+            x.append(h)
+            g.append(g_u)
+        return jnp.stack(x), jnp.stack(g), kc, vc
+
+    @staticmethod
+    def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+        OuroKind.base_only(mesh, lora)
+        R = cfg.total_ut_steps
+        x, g, kc, vc = OuroKind.passes(params, cfg, h, kc, vc, batch, attn_impl)
+        exit_step = ouro.exit_steps(g, cfg.early_exit_threshold)               # [T]
+        h = jnp.take_along_axis(x, exit_step[None, :, None], axis=0)[0]
+        real = batch["token_seq"] < batch["block_tables"].shape[0] - 1
+        rows = jnp.sum(real.astype(jnp.int32))
+        counts = jnp.stack([jnp.int32(R), rows * (R * cfg.num_hidden_layers),
+                            jnp.sum((real & (exit_step < R - 1)).astype(jnp.int32))])
+        return h, kc, vc, extra, counts.astype(jnp.int32)[None]
+
+    @staticmethod
+    def final_norm(params, cfg, h):
+        """The model's norm closed the pass whose stream this is."""
+        return h
+
+
+def _ouro_layer_step(cfg, rope, batch, attn_impl, carry, xs):
+    """One Ouro block over the flat ragged batch: ``rope`` = (cos, sin) [T, 1,
+    d / 2] of the step's rows, ``xs`` = (the **pool's** layer ``u L + l``,
+    layer ``l``'s parameters). Not :func:`_layer_step`:
+    four norms where that knows two, and neither adapters nor a mesh."""
+    h, kc, vc = carry
+    layer, lp = xs
+    T = h.shape[0]
+    H, Hkv, d, eps = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+                      cfg.rms_norm_eps)
+    attn = lp["self_attn"]
+    with jax.named_scope("ds.ouro.attn"):
+        x = _rms(h, lp["input_layernorm"]["scale"], eps)
+        q = _rotate_halves(_proj(x, attn["q_proj"]).reshape(T, H, d), *rope)
+        k = _rotate_halves(_proj(x, attn["k_proj"]).reshape(T, Hkv, d), *rope)
+        v = _proj(x, attn["v_proj"]).reshape(T, Hkv, d)
+        out, kc, vc = _paged_attend(q, k, v, kc, vc, layer, batch, d, impl=attn_impl)
+        h = h + _rms(_proj(out.reshape(T, H * d), attn["o_proj"]),
+                     lp["input_layernorm_2"]["scale"], eps)
+    with jax.named_scope("ds.ouro.mlp"):
+        x = _rms(h, lp["post_attention_layernorm"]["scale"], eps)
+        h = h + _rms(_swiglu(x, lp["mlp"]), lp["post_attention_layernorm_2"]["scale"], eps)
+    return (h, kc, vc), None
+
+
 # Every kind, a kind whose config class derives another's before that one's.
-KINDS = (LagunaKind, SolarOpen2Kind, JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind,
+KINDS = (OuroKind, LagunaKind, SolarOpen2Kind, JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind,
          MoonlightKind, GPTKind, LlamaKind)
 
 

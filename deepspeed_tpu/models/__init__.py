@@ -24,6 +24,8 @@ from deepspeed_tpu.models.solar_open2 import (SOLAR_OPEN2_CONFIGS, SolarOpen2Con
                                               build_solar_open2)  # noqa: F401
 from deepspeed_tpu.models.laguna import (LAGUNA_CONFIGS, LagunaConfig, LagunaForCausalLM,
                                          build_laguna)  # noqa: F401
+from deepspeed_tpu.models.ouro import (OURO_CONFIGS, OuroConfig, OuroForCausalLM,
+                                       build_ouro)  # noqa: F401
 
 # The causal-LM families a preset name can build, in the order names are looked up
 # (the v2 serving engine takes any of them: inference/v2/model_runner.kind_of).
@@ -32,7 +34,7 @@ MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
                   (MINICPM_SALA_CONFIGS, build_minicpm_sala),
                   (NEMOTRON_H_CONFIGS, build_nemotron_h), (LFM2_CONFIGS, build_lfm2),
                   (JAMBA_CONFIGS, build_jamba), (SOLAR_OPEN2_CONFIGS, build_solar_open2),
-                  (LAGUNA_CONFIGS, build_laguna))
+                  (LAGUNA_CONFIGS, build_laguna), (OURO_CONFIGS, build_ouro))
 
 
 def build_model(preset, **overrides):

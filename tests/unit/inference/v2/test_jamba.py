@@ -90,9 +90,19 @@ def tokens():
     return np.random.default_rng(3).integers(0, 256, (4, 192), dtype=np.int32)
 
 
+_REFERENCE = {}        # a config → its jitted reference, one program for every length
+
+
 def reference(engine, seq):
-    return np.asarray(reference_logits(engine.params, jnp.asarray(seq)[None],
-                                       engine.model_config))[0]
+    """The reference's logits [len(seq), V]: the sequence padded to the rows'
+    192 tokens, which a causal model's rows before the padding cannot see, so
+    that one compiled program serves every length a test asks for."""
+    cfg = engine.model_config
+    if cfg not in _REFERENCE:
+        _REFERENCE[cfg] = jax.jit(lambda params, ids: reference_logits(params, ids, cfg))
+    padded = np.zeros((1, 192), np.int32)
+    padded[0, :len(seq)] = seq
+    return np.asarray(_REFERENCE[cfg](engine.params, jnp.asarray(padded))[0, :len(seq)])
 
 
 def serve(engine, plan):

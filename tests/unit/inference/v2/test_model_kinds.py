@@ -18,7 +18,7 @@ from tools import hash_step_programs
 PRESETS = ("debug", "mixtral-debug", "gpt2-debug", "opt-debug", "bloom-debug", "neox-debug",
            "gptj-debug", "falcon-debug", "moonlight-debug", "longcat-flash-debug",
            "minicpm-sala-debug", "nemotron-h-debug", "lfm2-debug", "jamba-debug",
-           "solar-open2-debug", "laguna-debug")
+           "solar-open2-debug", "laguna-debug", "ouro-debug")
 
 
 def _params(preset, shapes_only=False):
@@ -58,7 +58,7 @@ def test_every_kind_derives_the_base_and_answers_the_whole_seam():
         assert kind.state_layers(cfg) >= 1 and len(kind.state_rows(cfg)) == 2
 
 
-def test_the_hashed_presets_are_the_sixteen_in_their_order():
+def test_the_hashed_presets_are_the_seventeen_in_their_order():
     """The parent's and a change's outputs of the tool must line up (a new
     kind's preset goes last: its lines are the only ones a diff shows)."""
     assert hash_step_programs.PRESETS == PRESETS

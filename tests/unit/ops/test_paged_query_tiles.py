@@ -21,6 +21,9 @@ TQ = pa.QUERY_TILE
 TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2e-2}
 # (query heads, key-value heads, head size): a head of 128, and a head of 64 a pair a slice
 HEADS = {"head128": (8, 2, 128), "head64-paired": (8, 4, 64)}
+# as many key-value heads as query heads (a stack whose every head has keys of its own:
+# ``OuroKind``): a key-value row is fetched for one query head, a product a head is one row
+GROUP_OF_ONE = {"head128-group1": (4, 4, 128)}
 
 # the rows of a batch in order: (rows, context before them) a sequence; None: a padding row
 BATCHES = {
@@ -40,7 +43,7 @@ BATCHES = {
 def _batch(name, heads, dtype, seed=0):
     """→ (q, kc, vc, tables [S + 1, MB], token_seq, token_pos, live rows)"""
     T, layout = BATCHES[name]
-    H, Hkv, Dh = HEADS[heads]
+    H, Hkv, Dh = {**HEADS, **GROUP_OF_ONE}[heads]
     rng = np.random.default_rng(seed)
     seqs = [r for r in layout if r is not None]
     n_seqs = len(seqs)
@@ -91,6 +94,20 @@ def test_tiles_match_the_gather_and_the_row_path(name, heads, dtype):
     np.testing.assert_allclose(tiled, want, rtol=TOL[dtype], atol=TOL[dtype])
     np.testing.assert_allclose(tiled, rows, rtol=TOL[dtype], atol=TOL[dtype] / 2)
     assert not past.any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_a_query_group_of_one_rows_alone_and_in_tiles(dtype):
+    """``H = Hkv`` (beside the groups of 4 and 2 above): decode rows, padding
+    among them and two chunks, a row a grid step and in query tiles, against
+    the gather - the slices of ``groups`` query rows a key-value head are
+    slices of one."""
+    args = _batch("padding_among_live_rows", "head128-group1", dtype)
+    assert args[0].shape[1] * 128 == args[1].shape[-1]              # H = Hkv
+    (tiled, rows, want), dead = _three_ways(*args)
+    np.testing.assert_allclose(tiled, want, rtol=TOL[dtype], atol=TOL[dtype])
+    np.testing.assert_allclose(rows, want, rtol=TOL[dtype], atol=TOL[dtype])
+    assert not np.isnan(dead).any()
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])

@@ -38,6 +38,15 @@ rests on this table (PERF.md, PR 33). ``--paged64`` is the same at
 heads a 128-lane slice), 64-row blocks, 64 decode rows at contexts 1024-8192
 and a 512-row chunk at three depths (~1 min; PERF.md, PR 41).
 
+``--paged1`` times the call of the stack that runs several times (PR 54,
+``ouro-2.6b-mathqa``): **16 query heads over 16 key-value heads of 128, a query
+group of one**, 16-row blocks, a pool 192 layers deep, 16 rows of which 12 are
+live at contexts 100-448 - all 192 layers' calls from a loop inside one program
+(a call is ~20 us: from the chip's host its dispatch would be all that is
+timed), with 1-32 blocks a tile, and the same rows at a group of four (4
+key-value heads) beside it: ms a call, GB/s of the attended rows
+(:func:`paged_bytes`), share of 819 (~1 min, ``chiprun_out/paged1_census.json``).
+
 ``--chunk`` times what a **query tile** does to it (PERF.md, PR 42), at both
 shapes: a 512-row prompt chunk at contexts 0 / 512 / 2048 / 7680 (0 / 512 at
 16-row blocks, whose table holds 1536 positions), 64 decode rows alone, a
@@ -329,6 +338,62 @@ PAGED64_CLASSES = (("rag-decode-64-30live", 64, 30, (1024, 8192), None),
                    ("rag-chunk-512-ctx2048", 512, 512, None, 2048),
                    ("rag-chunk-512-ctx7680", 512, 512, None, 7680))
 PAGED64_TILES = (1, 2, 4, 8)
+
+
+def paged_bytes(ctx_tokens, kv_heads, head_dim, itemsize=2):
+    """Least bytes one paged call fetches for rows that attend to
+    ``ctx_tokens`` positions in all: a position's keys and values."""
+    return ctx_tokens * 2 * kv_heads * head_dim * itemsize
+
+
+def paged_group1_classes():
+    """Yields one record a key-value head count (16: the cell's group of one;
+    4: a group of four on the same rows): the paged call at every pool layer
+    from a loop inside one program, at each ``n`` of ``PAGED_TILES``."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+
+    H, Dh, bs, L, NB, MB, T, live = 16, HEAD_DIM, 16, 192, 368, 32, 16, 12
+    rng = np.random.default_rng(54)
+    pos = np.zeros(T, np.int32)
+    pos[:live] = np.exp(rng.uniform(np.log(100), np.log(448), live)).astype(int) - 1
+    tabs = np.zeros((T, MB), np.int32)
+    free = iter(rng.permutation(np.arange(1, NB)))
+    for t in range(live):
+        need = pos[t] // bs + 1
+        tabs[t, :need] = [next(free) for _ in range(need)]
+    tabs_d, pos_d = jnp.asarray(tabs), jnp.asarray(pos)
+    q = jnp.asarray(rng.standard_normal((T, H, Dh), np.float32), jnp.bfloat16)
+    ctx = int((pos[:live] + 1).sum())
+    for Hkv in (16, 4):
+        pool = jax.jit(lambda key: jax.random.normal(key, (L, NB, bs, Hkv * Dh), jnp.bfloat16))
+        kc, vc = pool(jax.random.PRNGKey(1)), pool(jax.random.PRNGKey(2))
+        want = jax.jit(pa.xla_paged_attention)(q, kc, vc, tabs_d, pos_d, jnp.int32(L - 2))
+        nbytes = paged_bytes(ctx, Hkv, Dh)
+        record = {"rows": T, "live_rows": live, "ctx_tokens": ctx, "layers": L,
+                  "query_group": H // Hkv, "attended_kv_bytes_a_call": nbytes,
+                  "rule_n": pa.tile_blocks(bs, Hkv * Dh * 2, 2, MB)}
+        for n in PAGED_TILES:
+            def every_layer(q, kc, vc, tabs, pos, n=n):
+                def body(layer, acc):
+                    return acc + pa._paged_call(q, kc, vc, tabs, pos, layer, n, False).astype(
+                        jnp.float32)
+                return jax.lax.fori_loop(0, L, body, jnp.zeros(q.shape, jnp.float32))
+            try:
+                one = jax.jit(lambda *a, n=n: pa._paged_call(*a, n, False))
+                err = rel_err(one(q, kc, vc, tabs_d, pos_d, jnp.int32(L - 2))[:live], want[:live])
+                ms = _ms_a_call(jax.jit(every_layer), q, kc, vc, tabs_d, pos_d, calls=10) / L
+                record[f"n={n}"] = {"ms": ms, "gb_s": nbytes / ms / 1e6,
+                                    "hbm_share": 100 * nbytes / ms / 1e6 / HBM_GB_S,
+                                    "rel_err": float(f"{err:.3e}")}
+            except Exception as e:  # a refusal is a record too
+                record[f"n={n}"] = {"refused": f"{type(e).__name__}: {e}"[:600]}
+        del kc, vc
+        yield f"mathqa-decode-16-12live-kv{Hkv}", record
 
 
 def _parent_kernel(parent_dir, module):
@@ -1318,8 +1383,10 @@ def main():
                   if "--paged-parent" in sys.argv else os.path.join("_checkout", "parent"))
     live, ssm, chunk = "--live" in sys.argv, "--ssm" in sys.argv, "--chunk" in sys.argv
     scan, kda, window = "--scan" in sys.argv, "--kda" in sys.argv, "--window" in sys.argv
-    share = "--share" in sys.argv
-    if share:
+    share, paged1 = "--share" in sys.argv, "--paged1" in sys.argv
+    if paged1:
+        section, records = "paged_group1", paged_group1_classes()
+    elif share:
         section, records = "expert_share", share_classes()
     elif window:
         section, records = "window_attention", window_classes()
@@ -1345,7 +1412,7 @@ def main():
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
     for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk or scan or kda
-                                     or window or share or "--gmm-only" in sys.argv
+                                     or window or share or paged1 or "--gmm-only" in sys.argv
                                      else cases()):
         try:
             result = verdict(fn, ref, args, tol)
@@ -1354,7 +1421,7 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("share_census.json" if share else "window_census.json" if window
+    out = ("paged1_census.json" if paged1 else "share_census.json" if share else "window_census.json" if window
            else "kda_census.json" if kda
            else "scan_census.json" if scan
            else "chunk_census.json" if chunk
