@@ -8,6 +8,7 @@ jitted step (compiled once, KV pool donated) consumes the padded flat
 batch from ``RaggedBatchWrapper``; mixed prefill chunks and decodes
 run in the same program — the Dynamic SplitFuse model."""
 
+import bisect
 import itertools
 from collections import OrderedDict
 
@@ -43,6 +44,27 @@ def _burst_ctx_tokens(seen, k):
     """Context positions one row attends over a burst of ``k`` steps that
     starts with ``seen`` tokens cached: step ``j`` attends ``seen + j + 1``."""
     return k * seen + k * (k + 1) // 2
+
+
+def put_ladder(max_seqs, max_tokens, rungs=True):
+    """The row counts a ``put`` program may have, ascending: the decode-sized
+    one (``max_seqs``), the budget-sized one (``max_tokens``) and, between
+    them, one rung of half the budget where the decode batch is at most an
+    eighth of the rung (``max_tokens // 2 >= 8 * max_seqs``). A rung is a
+    program to build, seconds of every start, and it serves the prompt steps
+    that fit it beside their decode rows: with 16 sequences under a rung of
+    256 nearly every one did (0.95-0.98, a step 120 -> 69 ms), with 64 a
+    fifth, with 128 a twenty-fifth to a third, for 4-10 % of a warm start
+    each (PERF.md section 6, PR 55). A step runs the smallest that holds its
+    tokens. Two numbers the engine has, and nothing else: a tuple, so that a
+    rung more is an entry more.
+    ``rungs=False``: the two ends alone (the engine says when)."""
+    if max_seqs >= max_tokens:
+        return (max_seqs,)
+    rung = max_tokens // 2
+    if rungs and rung >= 8 * max_seqs:
+        return (max_seqs, rung, max_tokens)
+    return (max_seqs, max_tokens)
 
 
 def _offsets(fields, lora, sampled, ms, seq_rows=0):
@@ -444,6 +466,16 @@ class InferenceEngineV2:
                                          self.max_blocks_per_seq,
                                          lora=self.lora_store is not None,
                                          seq_rows=self._seq_rows)
+        # the row counts a put may run at (put_ladder), and the (mode, rows) a put or
+        # build_put_programs has run: each is one compiled program. No rung where the
+        # kind's expert layers run behind a share (``n_share_passes`` among its counts):
+        # laguna-xs2-ep8-20l's 256-row program never came back from its first step on the
+        # chip, and did with ``lax.ragged_dot`` for the share's grouped matmul, or with
+        # 1024 rows a pass of it for 512; the layer alone runs at every size, and which op
+        # of that one compile waits is not known (PERF.md section 6, PR 55)
+        self.put_buckets = put_ladder(self.max_seqs, self.max_tokens,
+                                      rungs="n_share_passes" not in kind.step_counts)
+        self._put_built, self._put_mode = set(), None   # ... and the mode of the last
         mesh = self.mesh
         # the config's attention pin, and (filled as programs trace) the
         # implementation each program actually selected — see
@@ -832,48 +864,23 @@ class InferenceEngineV2:
                     for desc, chunk in zip(descs, batch_tokens):
                         desc.tokens.fence()
                         desc.tokens.extend(int(t) for t in chunk)
-                # decode bucket: a batch of ≤ max_seqs tokens (pure decode round)
-                # runs the small compiled step; prefill chunks run the full-budget
-                # one. Two programs total — shapes stay static per bucket.
-                bucket = self.max_seqs if total <= self.max_seqs else self.max_tokens
-                arrays = self._batch.finalize_packed(bucket=bucket)
+                # the smallest program that holds the step (put_ladder): a pure decode
+                # round runs the decode-sized one, a short prompt beside its decode rows
+                # the rung where there is one, a full chunk the budget-sized one. One
+                # program a rung and mode — shapes stay static per bucket.
+                bucket = self.put_buckets[bisect.bisect_left(self.put_buckets, total)]
                 if mode == "packed":
-                    # sampling specs ride the SAME flat metadata vector: resolve
-                    # engine-stream seeds for specs submitted without one, then
-                    # append the six int32 rows per sequence
+                    # resolve engine-stream seeds for specs submitted without one
                     for s in specs:
                         if s is not None and "seed" not in s:
                             s["seed"] = self.draw_seed()
-                    dfa = None
-                    if self.structured is not None:
-                        dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
-                               for u in batch_uids]
-                    arrays = np.concatenate(
-                        [arrays, pack_sample_meta(specs, self.max_seqs, dfa=dfa)])
-                if self.mesh is not None:
-                    # batch metadata is replicated over the serving mesh (the flat
-                    # token batch carries no sharding — only weights/KV do)
-                    arrays = jax.device_put(arrays, self._replicated)
+                arrays = self._packed_batch(bucket, mode, specs, batch_uids)
                 rec.program, rec.n_seqs, rec.n_tokens = str(bucket), n, total
                 rec.n_rows = bucket
                 # without a scheduler to say which chunks are prompt: rows longer than one
                 rec.n_prompt_tokens = int(lens[lens > 1].sum())
-            # hot adapter slabs ride as jit ARGUMENTS (not captured constants)
-            # so promotions/hot-swaps rebind buffers without any retrace
-            extra = (self.lora_store.slabs(),) if self.lora_store is not None else ()
             with tracing.phase("engine.dispatch"):
-                if mode == "packed":
-                    sargs = (self._base_key,)
-                    if self.structured is not None:
-                        sargs += (self.structured.slabs(),)  # rebind, never retrace
-                    out, self.kv_cache.k, self.kv_cache.v, *counts, self.state_extra = \
-                        self._step_sampled(self.params, self.kv_cache.k, self.kv_cache.v,
-                                           self.state_extra, arrays, *sargs, *extra)
-                else:
-                    fn = self._step_greedy if mode == "greedy" else self._step
-                    out, self.kv_cache.k, self.kv_cache.v, *counts, self.state_extra = fn(
-                        self.params, self.kv_cache.k, self.kv_cache.v, self.state_extra,
-                        arrays, *extra)
+                out, counts = self._dispatch_put(mode, bucket, arrays)
             self.count_host_sync()
             self.tokens_emitted += len(batch_uids)
             try:
@@ -887,6 +894,90 @@ class InferenceEngineV2:
                     self._note_counts(rec, counts)
             self.last_step = rec
             return host
+
+    def _packed_batch(self, bucket, mode, specs=(), batch_uids=()):
+        """The batch ``self._batch`` holds as ONE flat int32 vector at
+        ``bucket`` rows, as the ``put`` program of ``mode`` takes it: in
+        ``"packed"`` mode the sampling specs of ``batch_uids`` (and their DFA
+        slot and state, with constrained decoding on) ride the same vector,
+        six int32 rows a sequence."""
+        arrays = self._batch.finalize_packed(bucket=bucket)
+        if mode == "packed":
+            dfa = None
+            if self.structured is not None:
+                dfa = [(self.structured.slot_of(u), self.structured.state_of(u))
+                       for u in batch_uids]
+            arrays = np.concatenate(
+                [arrays, pack_sample_meta(specs, self.max_seqs, dfa=dfa)])
+        if self.mesh is not None:
+            # batch metadata is replicated over the serving mesh (the flat
+            # token batch carries no sharding — only weights/KV do)
+            arrays = jax.device_put(arrays, self._replicated)
+        return arrays
+
+    def _dispatch_put(self, mode, bucket, arrays):
+        """Launch the ``put`` program of ``mode`` over ``arrays``
+        (:meth:`_packed_batch` at ``bucket`` rows): the pools and the kind's
+        further state are donated and taken back. → ``(out, counts)``, device
+        arrays; nothing is waited for."""
+        # hot adapter slabs ride as jit ARGUMENTS (not captured constants)
+        # so promotions/hot-swaps rebind buffers without any retrace
+        extra = (self.lora_store.slabs(),) if self.lora_store is not None else ()
+        if mode == "packed":
+            fn, sargs = self._step_sampled, (self._base_key,)
+            if self.structured is not None:
+                sargs += (self.structured.slabs(),)  # rebind, never retrace
+        else:
+            fn, sargs = (self._step_greedy if mode == "greedy" else self._step), ()
+        out, self.kv_cache.k, self.kv_cache.v, *counts, self.state_extra = fn(
+            self.params, self.kv_cache.k, self.kv_cache.v, self.state_extra, arrays,
+            *sargs, *extra)
+        self._put_mode = mode
+        self._put_built.add((mode, bucket))
+        return out, counts
+
+    def build_put_programs(self, max_tokens=None):
+        """Build the ``put`` programs of the mode the last ``put`` ran in
+        (``"logits"``, ``"greedy"`` or ``"packed"``, :meth:`_classify_sample`'s)
+        over ``max_seqs`` rows that no step has run yet
+        - those a step of at most ``max_tokens`` tokens can land on (a
+        scheduler's budget; None: the engine's) - by running each on a
+        **batch of the null sequence alone**: one token of a sequence whose
+        table is all null blocks and whose state row is the null slot's, every
+        other row padding, so every write lands in the null block and the null
+        slot and the pools hold afterwards what they held. Whoever serves traffic calls this after a
+        prompt step (a scheduler does; two set look-ups once every size is
+        built): the stall of a compile then comes once, before traffic, and
+        not again at the first step that fits another rung. A caller of
+        :meth:`put` alone compiles each program at its first use, as ever.
+        Each build is a step record of kind ``build`` - no reader of the
+        programs' steps counts it, and the build table lists the program under
+        this engine. PUMP-THREAD ONLY, with no burst in flight. → the row
+        counts built."""
+        mode, built = self._put_mode, []
+        reach = bisect.bisect_left(self.put_buckets, max_tokens or self.max_tokens)
+        for bucket in self.put_buckets[1:reach + 1]:
+            if (mode, bucket) in self._put_built:
+                continue
+            with tracing.step("build", engine=self.trace_id, program=str(bucket),
+                              n_rows=bucket) as rec:
+                with tracing.phase("engine.pack"):
+                    # one row of the null sequence (its table all null blocks, its state
+                    # row the null slot's) and padding: what a step's first token is to
+                    # every kernel, where a batch of no live row at all is an input no
+                    # step ever gives them
+                    self._batch.clear()
+                    self._batch.insert_batch(
+                        0, [0], [1], [0], adapters=[0],
+                        seq_state=np.zeros((1, self._seq_rows), np.int32))
+                    arrays = self._packed_batch(bucket, mode)
+                with tracing.phase("engine.dispatch"):
+                    out, counts = self._dispatch_put(mode, bucket, arrays)
+                with tracing.phase("engine.fetch"):
+                    _, *counts = jax.device_get((out, *counts))  # ds-lint: disable=host-sync -- before traffic: a program's build ends with its first run
+                    self._note_counts(rec, counts)
+            built.append(bucket)
+        return built
 
     def _classify_sample(self, sample, n):
         """Normalize ``put``/burst ``sample`` arguments → ``(mode,

@@ -150,7 +150,9 @@ class RaggedBatchWrapper:
         ``max_tokens`` — shape bucketing: a pure-decode step (≤ max_seqs
         real tokens) compiles to a program ~max_tokens/max_seqs× smaller
         than the prefill-chunk program, so decode rounds don't pay the
-        full token budget in MLP flops and KV-gather traffic."""
+        full token budget in MLP flops and KV-gather traffic. Any length
+        from the batch's tokens to ``max_tokens`` is a program of its own;
+        the engine picks from ``engine_v2.put_ladder``."""
         bucket = self.max_tokens if bucket is None else int(bucket)
         if not self._cursor <= bucket <= self.max_tokens:
             raise ValueError(f"bucket {bucket} must cover the {self._cursor} batched "

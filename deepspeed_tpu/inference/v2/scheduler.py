@@ -605,6 +605,12 @@ class DynamicSplitFuseScheduler:
                 if r.prefilling:
                     continue  # mid-prompt chunk: its last-token logits are unused
                 self._accept_token(r, int(row) if self._device_greedy else self.sample_fn(row))
+        if rec is not None and rec.n_tokens > self.engine.max_seqs:
+            # a prompt step ran, and built its own program if it was the first of its
+            # size: have the engine build the other sizes a prompt step of this budget
+            # can land on in the same mode, so that a compile stops traffic once, here,
+            # and a warm-up need not know the sizes (nothing to do once they are built)
+            self.engine.build_put_programs(self.budget)
         return uids
 
     def run_to_completion(self, max_steps=10000):

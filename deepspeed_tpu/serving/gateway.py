@@ -1042,8 +1042,16 @@ class ServingGateway:
             self._last_engine_rec = None   # nothing to run: what follows is no stall
             # ... and no dispatch follows for the last step's tokens to ride
             return self.scheduler.hand_over() > 0, False
+        before = getattr(self.engine, "last_step", None)
         stepped = self.scheduler.step()
         self.metrics.count("engine_steps")
+        rec = getattr(self.engine, "last_step", None)
+        if rec is not before and rec.kind == "put" and rec.n_tokens > self.engine.max_seqs:
+            # a step that carried a prompt, and whether it ran a program smaller
+            # than the budget's (a rung of engine.put_buckets)
+            self.metrics.count("prompt_steps")
+            if rec.n_rows < self.engine.max_tokens:
+                self.metrics.count("prompt_steps_on_rung")
         ended, self.scheduler.ended = self.scheduler.ended, []
         if not stepped and not ended:
             # every live request is schedulable yet nothing ran — a real

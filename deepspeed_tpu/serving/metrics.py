@@ -135,6 +135,9 @@ class ServingMetrics:
                 # of tokens_generated: handed to their streams while the next
                 # program ran, and with none dispatched (gateway._on_tokens)
                 "tokens_delivered_in_flight", "tokens_delivered_idle",
+                # of engine_steps: a put of more than max_seqs tokens (it carried a
+                # prompt), and those that ran below the budget-sized program
+                "prompt_steps", "prompt_steps_on_rung",
                 "handoffs_exported", "handoffs_imported",
                 "weight_refreshes", "rejected_unknown_adapter",
                 "rejected_adapter",

@@ -7,7 +7,9 @@ append per *model step* (about ten a second) or per *request*:
 
 * a **step record** for every model program run (``put``, a decode
   burst, an async burst and its fetch, a verify burst, ``train_batch``)
-  and for every serving pump pass that did work (kind ``pump``). The
+  and for every serving pump pass that did work (kind ``pump``); a ``put``
+  program an engine runs on the null sequence alone to have it built before traffic
+  is a record of kind ``build``. The
   code that runs the program opens the record and knows what the device
   trace cannot: which program, how many steps ``k``, how many rows and
   tokens. ``caused_by`` is the ``seq`` of the record that was open on the
@@ -549,8 +551,10 @@ class Recorder:
         build.add(part, own_ns, whole_ns, fun_name, cache)
 
     def _note_build(self, rec):
-        """A record that built something ends: its row of the table."""
-        key = (rec.engine, rec.kind, rec.program)
+        """A record that built something ends: its row of the table (a
+        ``put`` program built before traffic, kind ``build``, is the row of
+        the ``put`` of its size: a row a program, whoever ran it first)."""
+        key = (rec.engine, "put" if rec.kind == "build" else rec.kind, rec.program)
         with _Process.compile_lock:
             row = self.builds.get(key)
             if row is None:

@@ -143,7 +143,9 @@ def parent_put(self, batch_uids, batch_tokens, do_checks=True, sample=None):
                     desc.tokens.fence()
                     desc.tokens.extend(int(t) for t in tokens)
                 slots.append(desc.slot)
-            bucket = self.max_seqs if total <= self.max_seqs else self.max_tokens
+            # (the one line that is not the parent's: which program a step takes is the
+            # engine's ladder since PR 55 - test_put_ladder.py - and no packer's business)
+            bucket = next(b for b in self.put_buckets if total <= b)
             arrays = self._batch.finalize_packed(bucket=bucket)
             if mode == "packed":
                 for s in specs:

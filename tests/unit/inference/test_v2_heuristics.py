@@ -91,5 +91,6 @@ def test_engine_config_override_serves_correctly():
     p32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
     want = np.asarray(model.apply({"params": p32}, jnp.asarray(ids)[None, :]))[0, -1]
     np.testing.assert_allclose(out[0], want, rtol=2e-4, atol=2e-4)
-    # the engine reports what its one traced program (64-token bucket) selected
-    assert engine.attention_impls == {64: "xla_gather"}
+    # the engine reports what its one traced program (nine tokens: the 32-row rung) selected
+    assert engine.put_buckets == (4, 32, 64)
+    assert engine.attention_impls == {32: "xla_gather"}

@@ -344,7 +344,8 @@ class TestOneProgramFamily:
                    if r["engine"] == eng.trace_id and r["seq"] > mark]
         bursts = [r for r in records if r["kind"] == "burst"]
         assert calls and [(r["n_seqs"], r["k"]) for r in bursts] == calls
-        assert {r["kind"] for r in records} == {"put", "burst"}
+        # (build: after the prompts' step the scheduler had the rung built)
+        assert {r["kind"] for r in records} == {"build", "put", "burst"}
         for r in bursts:
             assert [p[0] for p in r["phases"]] == [
                 "ds.engine.pack", "ds.engine.dispatch", "ds.engine.fetch",

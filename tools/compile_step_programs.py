@@ -2,7 +2,7 @@
 """A serving preset's step programs compiled for a described TPU v5e chip.
 
     JAX_PLATFORMS=cpu python3 tools/compile_step_programs.py ouro-2.6b \
-        [--rows 16,512] [--blocks 368] [--block-size 16] [--seqs 16] [--table 32] \
+        [--rows 16,256,512] [--blocks 368] [--block-size 16] [--seqs 16] [--table 32] \
         [--over '{"num_hidden_layers": 22}'] [--shapes '2048,2048|2048,5632|5632,2048'] \
         [--out DIR]
 
@@ -17,8 +17,8 @@ the trailing dims; parameters and tuple reads left out): a ``copy`` of a
 whole stack, or a slice of one in another layout, is the chip's compiler
 re-laying weights (PERF.md, PR 54: 2 x 403 MB a step and 807 MB of
 temporaries came and went with where a gate's vector was cut out of its
-matrix; the scan's own slices of a layer - ``fusion ... kLoop``, seven a loop - are
-listed too: on the chip they fuse into their consumers and a trace shows no op
+matrix; PR 55 added a 256-row program and looked here first; the scan's own
+slices of a layer - ``fusion ... kLoop``, seven a loop - are listed too: on the chip they fuse into their consumers and a trace shows no op
 of their shape). ``--out DIR`` keeps the HLO texts. ~5 s a program at 48 layers.
 
 It proves compilation only - never a time, and never that a result is right.
@@ -33,11 +33,15 @@ import time
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ["DS_PALLAS"] = "1"
 
+BUDGET = 512    # the token budget of every engine the benchmark builds
+
 
 def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("preset")
-    parser.add_argument("--rows", default="16,512")
+    parser.add_argument("--rows", default=None,
+                        help="default: every size a put can be (engine_v2.put_ladder of --seqs "
+                             "and the benchmark's token budget)")
     parser.add_argument("--blocks", type=int, default=368)
     parser.add_argument("--block-size", type=int, default=16)
     parser.add_argument("--seqs", type=int, default=16)
@@ -56,6 +60,7 @@ def main():
     pallas.default_interpret = lambda: False          # the kernels lower compiled, by Mosaic
     from deepspeed_tpu import models
     from deepspeed_tpu.inference.v2 import model_runner
+    from deepspeed_tpu.inference.v2.engine_v2 import put_ladder
     from deepspeed_tpu.inference.v2.modules.heuristics import AttentionChoice
 
     chip = SingleDeviceSharding(topologies.get_topology_desc(
@@ -77,7 +82,8 @@ def main():
     pools = [sds((kind.state_layers(cfg), args.blocks, args.block_size, width), jnp.bfloat16)
              for width in kind.state_rows(cfg)]
     shaped = re.compile(r"= bf16\[(\d+,)*(" + args.shapes + r")\]")
-    for rows in (int(r) for r in args.rows.split(",")):
+    ladder = put_ladder(args.seqs, BUDGET)
+    for rows in (ladder if args.rows is None else (int(r) for r in args.rows.split(","))):
         batch = {"token_ids": sds((rows,), jnp.int32), "token_seq": sds((rows,), jnp.int32),
                  "token_pos": sds((rows,), jnp.int32),
                  "block_tables": sds((args.seqs + 1, args.table), jnp.int32),
