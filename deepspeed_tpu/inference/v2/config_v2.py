@@ -12,9 +12,6 @@ class DSStateManagerConfig(DeepSpeedConfigModel):
     max_ragged_batch_size: int = 768
     max_ragged_sequence_count: int = 512
     max_context: int = 8192
-    memory_config_mode: str = "reserve"  # "reserve" | "allocate"
-    memory_reserve_percentage: int = 90
-    offload_kv: bool = False
 
 
 class QuantizationConfig(DeepSpeedConfigModel):

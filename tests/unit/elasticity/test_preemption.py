@@ -481,7 +481,7 @@ sys.exit(PREEMPT_RC if n <= 2 else 0)
         a worker that traps SIGTERM, finishes its 'step', and exits
         PREEMPT_RC counts as a clean shutdown."""
         with tempfile.TemporaryDirectory() as d:
-            done = os.path.join(d, "done")
+            done, ready = os.path.join(d, "done"), os.path.join(d, "ready")
             script = os.path.join(d, "w.py")
             with open(script, "w") as f:
                 f.write(f"""
@@ -489,6 +489,7 @@ import os, signal, sys, time
 sys.path.insert(0, {REPO_ROOT!r})
 from deepspeed_tpu.elasticity.preemption import PREEMPT_RC, PreemptionGuard
 g = PreemptionGuard(grace_s=10).install()
+open({ready!r}, "w").write("guarded")
 while not g.preempted:
     time.sleep(0.05)
 time.sleep(0.3)  # "finish the in-flight step"
@@ -500,7 +501,13 @@ sys.exit(PREEMPT_RC)
             result = {}
             t = threading.Thread(target=lambda: result.update(rc=agent.run()))
             t.start()
-            time.sleep(1.0)  # let it spawn and install the guard
+            # the guard is installed when the worker says so, not after a second: importing
+            # ``deepspeed_tpu`` takes longer than that on a loaded machine, and a SIGTERM
+            # before the guard is the default handler's
+            waited = time.monotonic()
+            while not os.path.exists(ready):
+                assert time.monotonic() - waited < 30, "the worker never installed its guard"
+                time.sleep(0.05)
             agent.shutdown()
             t.join(timeout=30)
             assert not t.is_alive()

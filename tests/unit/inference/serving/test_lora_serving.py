@@ -86,15 +86,35 @@ class TestSegmentedKernel:
             assert np.array_equal(np.asarray(d), np.zeros_like(d))
 
     def test_row_independence_bitwise(self):
-        """Each token's delta is bit-identical whether it shares the
-        batch with other tenants or runs solo — the arithmetic half of
-        the cross-tenant-isolation guarantee."""
+        """Each token's delta is bit-identical whatever shares the batch
+        with it - other tenants' rows or the base slot's - the arithmetic
+        half of the cross-tenant-isolation guarantee. Batches of one
+        shape are compared: two *shapes* of one matmul (T rows, one row)
+        are not added in one order on every backend, so the solo row is
+        held to float32 rounding, not to its bits."""
         x, slots, a, b, scales = _rand_case(seed=3)
+        T, G = x.shape[0], a.shape[0]
+        others = jnp.asarray(np.random.RandomState(4).randn(*x.shape), x.dtype)
+
+        def leaks(delta):
+            """Does any row of ``delta``'s result move with its neighbours?"""
+            mixed = np.asarray(delta(x, slots, a, b, scales))
+            for t in range(T):
+                own = jnp.arange(T) == t
+                for xs, ss in ((others, (slots + 1) % G), (jnp.zeros_like(x), slots * 0)):
+                    beside = delta(jnp.where(own[:, None], x, xs), jnp.where(own, slots, ss),
+                                   a, b, scales)
+                    if not np.array_equal(mixed[t], np.asarray(beside)[t]):
+                        return True
+            return False
+
+        assert not leaks(lora_delta_ref)
+        # the control: a neighbour's activations reaching a row by 1e-6 of their sum is seen
+        assert leaks(lambda x, *rest: lora_delta_ref(x, *rest) + 1e-6 * jnp.sum(x[:, :1]))
         mixed = np.asarray(lora_delta_ref(x, slots, a, b, scales))
-        for t in range(x.shape[0]):
-            solo = np.asarray(lora_delta_ref(x[t:t + 1], slots[t:t + 1],
-                                             a, b, scales))
-            assert np.array_equal(mixed[t], solo[0]), f"row {t} differs"
+        for t in range(T):
+            solo = np.asarray(lora_delta_ref(x[t:t + 1], slots[t:t + 1], a, b, scales))
+            np.testing.assert_allclose(solo[0], mixed[t], rtol=1e-5, atol=1e-6)
 
     def test_segmentation_layout_is_static_and_grouped(self):
         slots = jnp.asarray([2, 0, 1, 2, 0, 2], jnp.int32)

@@ -263,19 +263,29 @@ class TestRejectionSampledSpec:
         """Acceptance = exact match against the counter-keyed draw from
         the filtered target — for point-mass n-gram drafts that IS the
         rejection-sampling scheme, and it makes the emitted stream
-        bit-identical to the spec-off run per seed."""
+        bit-identical to the spec-off run per seed.
+
+        The traffic makes the drafter's part certain: at temperature 0.5
+        over the top 2 the sampled streams repeat themselves (229 229 84
+        229 84 229 91 147 91 147 ... for seed 50), so the n-gram drafter
+        finds matches and the verify both accepts and rejects drafts
+        (9 drafted, 3 accepted when this was written) — at temperature
+        1.1 over the top 24 twelve tokens never repeated and nothing was
+        drafted, so nothing was compared."""
         runs = {}
         for spec_on in (False, True):
             engine = make_engine(model_and_params, spec=spec_on)
             sched = DynamicSplitFuseScheduler(engine, max_burst=4)
             for i in range(3):
                 sched.add_request(i, REPETITIVE + i, max_new_tokens=12,
-                                  sample={"temperature": 1.1, "top_k": 24,
+                                  sample={"temperature": 0.5, "top_k": 2,
                                           "seed": 50 + i})
             runs[spec_on] = sched.run_to_completion()
             if spec_on:
                 st = engine.spec
                 assert st.drafted > 0, "spec decode never drafted"
+                assert st.accepted >= 1, "no draft was accepted"
+                assert st.drafted - st.accepted >= 1, "no draft was rejected"
             engine.destroy()
         assert runs[True] == runs[False]
 

@@ -25,74 +25,59 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
-                                        KVTierConfig, PrefixCacheConfig,
-                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
 from deepspeed_tpu.inference.v2 import model_runner
-from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
-from deepspeed_tpu.models import LAGUNA_CONFIGS, build_model
+from deepspeed_tpu.models import LAGUNA_CONFIGS
 from deepspeed_tpu.models import laguna
-from deepspeed_tpu.models.laguna import (FULL, WINDOW, LagunaConfig, layer_params, param_shapes,
+from deepspeed_tpu.models.laguna import (FULL, WINDOW, layer_params, param_shapes,
                                          reference_attention, reference_logits, reference_moe)
-from deepspeed_tpu.utils import tracing
 
-TOL = 2e-5
+from unit.inference.v2 import kind_conformance as conformance
+from unit.inference.v2.kinds import Burst, Case, Gateway, Plan, Refused, count, rel_err
+
 DEBUG = LAGUNA_CONFIGS["laguna-debug"]
 BLOCK = 4
 KIND = model_runner.LagunaKind
 W = DEBUG.sliding_window
-COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_share_passes",
-          "n_ctx_seq_tokens", "n_win_seq_tokens")
 
 
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def engine_config(**over):
-    return RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=96,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=16,
-                                           max_ragged_sequence_count=4,
-                                           max_tracked_sequences=4, max_context=128), **over)
-
-
-@pytest.fixture(scope="module")
-def model():
-    return build_model("laguna-debug")
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(5))
-
-
-@pytest.fixture(scope="module")
-def tokens():
+def _tokens():
     rng = np.random.RandomState(11)
     return [rng.randint(0, DEBUG.vocab_size, size=80).astype(np.int32) for _ in range(4)]
 
 
-@pytest.fixture(scope="module")
-def reference(engine):
-    """seq → the reference's logits [len(seq), V]. One program for every
-    length: the sequence is padded to 80 tokens, which a causal model's rows
-    before the padding cannot see."""
-    cfg, params = engine.model_config, engine.params    # (a gateway's shutdown takes the engine's)
-    program = jax.jit(lambda params, ids: reference_logits(params, ids, cfg))
-
-    def logits(seq):
-        padded = np.zeros((1, 80), np.int32)
-        padded[0, :len(seq)] = seq
-        return np.asarray(program(params, jnp.asarray(padded))[0, :len(seq)])
-    return logits
-
-
-def count(shapes):
-    return sum(int(np.prod(s)) for s in jax.tree.leaves(shapes,
-                                                         is_leaf=lambda x: isinstance(x, tuple)))
+CASE = Case(
+    preset="laguna-debug", block=BLOCK, rows=16, context=128, tokens=_tokens,
+    reference=lambda params, ids, cfg, prompt: reference_logits(params, ids, cfg),
+    refused=tuple(Refused(field, value, field.split("_")[0]) for field, value in (
+        ("gating", False), ("gating", "per-element"), ("moe_router_logit_softcapping", 30.0),
+        ("moe_apply_router_weight_on_input", True), ("attention_bias", True),
+        ("tie_word_embeddings", True),
+        ("layer_types", (FULL, WINDOW, WINDOW, FULL) + (WINDOW,) * 8),
+        ("layer_types", (WINDOW, FULL, WINDOW, WINDOW) * 3),
+        ("mlp_layer_types", ("sparse", "dense") + ("sparse",) * 10),
+        ("num_attention_heads_per_layer", (6, 8, 8, 4) + (6, 8, 8, 8) * 2))),
+    prefill=((37, 30, (13, 16, 8)),       # cuts inside the window; then far past 3 x W
+             (5, 6, (5,)),                # shorter than the window throughout
+             (16, 4, (16,))),             # one whole chunk of the budget
+    # at every offset of the window and the block; one length: one program of the reference
+    cuts=tuple((cut + 14, 0, (cut, 14)) for cut in (1, 4, 5, 8, 9)),
+    # a mixed step: two decode rows (one 3 x W long, one short), a prompt's second chunk and
+    # a new prompt, all in one program
+    plans={"two_prompts_in_one_chunk_beside_decoding_sequences": Plan(
+        [[(10, 0, 0, 16)], [(10, 0, 16, 29), (11, 1, 0, 3)], [(11, 1, 3, 5), (12, 2, 0, 12)],
+         [(10, 0, 29, 30), (11, 1, 5, 6), (12, 2, 12, 20), (13, 3, 0, 5)]],
+        {10: (0, 29), 11: (1, 5), 12: (2, 20), 13: (3, 5)})},
+    burst=Burst(2, 0, 21, (8,)),
+    # positions 11-13 and 0-2 in the step that is read; every expert held: each layer one pass
+    records=Plan([[(60, 2, 0, 11)], [(60, 2, 11, 14), (61, 3, 0, 3)]], {60: (2, 14), 61: (3, 3)}, {
+        "n_ctx_seq_tokens": 14 + 3, "n_win_seq_tokens": (14 - (11 - W + 1)) + 3,
+        "n_picks_held": 6 * 4 * 11, "n_groups_live": (1, 16 * 11), "n_share_passes": 11}),
+    step_counts=("n_picks_held", "n_picks_zero", "n_groups_live", "n_share_passes",
+                 "n_ctx_seq_tokens", "n_win_seq_tokens"),
+    scopes=("ds.laguna.full_attn", "ds.laguna.window_attn", "ds.moe_routed", "ds.moe_shared",
+            "ds.dense_ffn"),
+    gateway=Gateway(((0, 45), (1, 9), (2, 30))))
+TOL = CASE.tol
 
 
 # ---------------------------------------------------------------- the config
@@ -134,20 +119,6 @@ def test_yarn_over_half_a_head():
     assert inv[1] == pytest.approx(1.0 / 10000 ** (2 / 128))
 
 
-@pytest.mark.parametrize("field,value", [
-    ("gating", False), ("gating", "per-element"), ("moe_router_logit_softcapping", 30.0),
-    ("moe_apply_router_weight_on_input", True), ("attention_bias", True),
-    ("tie_word_embeddings", True),
-    ("layer_types", (FULL, WINDOW, WINDOW, FULL) + (WINDOW,) * 8),
-    ("layer_types", (WINDOW, FULL, WINDOW, WINDOW) * 3),
-    ("mlp_layer_types", ("sparse", "dense") + ("sparse",) * 10),
-    ("num_attention_heads_per_layer", (6, 8, 8, 4) + (6, 8, 8, 8) * 2),
-])
-def test_what_is_not_implemented_is_refused_by_name(field, value):
-    with pytest.raises(ValueError, match=field.split("_")[0]):
-        dataclasses.replace(DEBUG, **{field: value})
-
-
 def test_a_share_outside_the_router_is_refused():
     with pytest.raises(ValueError, match="not among the 16 routed"):
         dataclasses.replace(DEBUG, experts_held=4, first_expert_held=14)
@@ -162,74 +133,15 @@ def test_the_flax_module_is_the_reference(model, engine, reference, tokens):
 
 
 # ------------------------------------------------------------ the served path
-def serve(engine, plan):
-    """``plan``: steps of ``[(uid, tokens), ...]`` → {uid: [a row of logits a step]}."""
-    out = {}
-    for step in plan:
-        logits = engine.put([u for u, _ in step], [t for _, t in step])
-        for (u, _), row in zip(step, logits):
-            out.setdefault(u, []).append(row)
-    return out
-
-
-@pytest.mark.parametrize("prompt,steps,chunks", [
-    (37, 30, (13, 16, 8)),       # cuts inside the window; then far past 3 x W
-    (5, 6, (5,)),                # shorter than the window throughout
-    (16, 4, (16,)),              # one whole chunk of the budget
-])
-def test_prefill_in_chunks_then_decode_through_both_pools(engine, reference, tokens, prompt,
-                                                          steps, chunks):
-    seq = tokens[0][:prompt + steps]
-    ref = reference(seq)
-    at, got = 0, []
-    for n in chunks:
-        got.append((at + n - 1, engine.put([1], [seq[at:at + n]])[0]))
-        at += n
-    for i in range(steps):
-        got.append((prompt + i, engine.put([1], [seq[prompt + i:prompt + i + 1]])[0]))
-    desc = engine.state_manager.query(1)
-    assert len(desc.window_blocks) <= engine.window_pool.bound(1)
-    assert desc.window_first == max(0, prompt + steps - W + 1) // BLOCK
-    engine.flush(1)
-    assert engine.window_pool.in_use == 0
-    for pos, row in got:
-        assert rel_err(row, ref[pos]) < TOL, pos
-    assert prompt + steps < 3 * W or engine.window_pool.released > 0
-
-
-@pytest.mark.parametrize("cut", [1, 4, 5, 8, 9])
-def test_a_chunk_cut_at_every_offset_of_the_window_and_the_block(engine, reference, tokens, cut):
-    seq = tokens[1][:26]        # one length: one program of the reference
-    ref = reference(seq)
-    got = serve(engine, [[(2, seq[:cut])], [(2, seq[cut:cut + 14])]])[2]
-    engine.flush(2)
-    assert rel_err(got[0], ref[cut - 1]) < TOL and rel_err(got[1], ref[cut + 13]) < TOL
-
-
-def test_two_prompts_in_one_chunk_beside_decoding_sequences(engine, reference, tokens):
-    """A mixed step: two decode rows (one 3 x W long, one short), a prompt's
-    second chunk and a new prompt, all in one program."""
-    a, b, c, d = tokens[0][:30], tokens[1][:6], tokens[2][:20], tokens[3][:5]
-    serve(engine, [[(10, a[:16])], [(10, a[16:29]), (11, b[:3])], [(11, b[3:5]), (12, c[:12])]])
-    got = serve(engine, [[(10, a[29:]), (11, b[5:]), (12, c[12:]), (13, d)]])
-    for uid, seq in ((10, a), (11, b), (12, c), (13, d)):
-        assert rel_err(got[uid][0], reference(seq)[-1]) < TOL, uid
-        engine.flush(uid)
-    assert engine.window_pool.in_use == 0 and engine.kv_cache.free_blocks == 95
-
-
-def test_decode_bursts_go_through_both_pools(engine, reference, tokens):
+def test_a_rewind_into_what_the_window_released_is_allowed_then_refused_to_go_on(engine, tokens):
     prompt, k = tokens[2][:21], 8
     engine.put([20], [prompt[:16]])
     first = int(np.argmax(engine.put([20], [prompt[16:]])[0]))
-    toks = engine.decode_burst([20], [[first]], k)
+    engine.decode_burst([20], [[first]], k)
     desc = engine.state_manager.query(20)
     assert desc.seen_tokens == 21 + k
     assert len(desc.window_blocks) <= engine.window_pool.bound(1)
-    seq = np.concatenate([prompt, [first], toks[:-1, 0]]).astype(np.int32)
-    ref = reference(seq)
-    assert [int(t) for t in toks[:, 0]] == [int(t) for t in np.argmax(ref[21:], axis=-1)]
-    # an ending's rewind crosses into what the window released: allowed, then refused to go on
+    # an ending's rewind crosses into what the window released
     engine.rewind(20, k)
     assert desc.window_stale and desc.seen_tokens == 21
     with pytest.raises(ValueError, match="rewound past the blocks its window had released"):
@@ -329,76 +241,22 @@ def test_layer_params_cuts_each_layers_attention_and_feed_forward(engine):
                           np.asarray(params["model"]["full_layers"]["o_proj"]["kernel"][2]))
 
 
-# ----------------------------------------------------------- what is refused
-@pytest.mark.parametrize("name,over", [
-    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
-    ("KV tier", {"kv_tier": KVTierConfig(enabled=True)}),
-    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
-    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
-    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
-    ("tensor/expert-parallel sharding", {"tensor_parallel_degree": 2}),
-])
-def test_each_subsystem_that_shares_or_moves_blocks_refuses_the_model_by_name(model, name, over):
-    with pytest.raises(NotImplementedError, match=name) as e:
-        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
-    assert "'kv+window'" in str(e.value) and "'laguna'" in str(e.value)
+class TestServing(conformance.ChunkCuts, conformance.NotKV):
+    def retired(self, engine, uid, fed):
+        desc = engine.state_manager.query(uid)
+        assert len(desc.window_blocks) <= engine.window_pool.bound(1)
+        assert desc.seen_tokens == fed and desc.window_first == max(0, fed - W + 1) // BLOCK
+        assert fed < 3 * W or engine.window_pool.released > 0
 
+    def idle(self, engine):
+        assert engine.window_pool.in_use == 0 and engine.kv_cache.free_blocks == CASE.blocks - 1
 
-def test_suspend_is_refused_by_name(engine, tokens):
-    engine.put([70], [tokens[0][:5]])
-    with pytest.raises(NotImplementedError, match="suspend/resume.*kv\\+window"):
-        engine.suspend(70)
-    engine.flush(70)
-    assert engine.window_pool.in_use == 0
-
-
-# ------------------------------------------------------------------- tracing
-def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine, tokens):
-    a, b = tokens[2][:14], tokens[3][:3]
-    engine.put([60], [a[:11]])
-    syncs = engine.host_syncs
-    engine.put([60, 61], [a[11:], b])               # positions 11-13 and 0-2
-    assert engine.host_syncs - syncs == 2            # as for any model kind: pack + fetch
-    counts = engine.last_step.counts
-    assert tuple(counts) == KIND.step_counts == COUNTS
-    assert counts["n_ctx_seq_tokens"] == 14 + 3
-    assert counts["n_win_seq_tokens"] == (14 - (11 - W + 1)) + 3
-    assert counts["n_picks_held"] == 6 * 4 * 11 and 0 < counts["n_groups_live"] <= 16 * 11
-    assert counts["n_share_passes"] == 11       # every expert held: each layer one pass
-    assert tracing.snapshot()["steps"][-1]["counts"] == counts
-    engine.flush(60)
-    engine.flush(61)
-    lowered = engine._step.lower(engine.params, engine.kv_cache.k, engine.kv_cache.v,
-                                 engine.state_extra, engine._batch.finalize_packed()).as_text(
-                                     debug_info=True)
-    for scope in ("ds.laguna.full_attn", "ds.laguna.window_attn", "ds.moe_routed",
-                  "ds.moe_shared", "ds.dense_ffn"):
-        assert scope in lowered, scope
-
-
-# ------------------------------------------------------------------- gateway
-def test_the_gateway_serves_it_through_the_same_scheduler(engine, reference, tokens):
-    """(The file's last test: the gateway's shutdown destroys the engine it was given.)"""
-    from deepspeed_tpu.serving import ServingConfig, ServingGateway
-    prompts = [tokens[0][:45], tokens[1][:9], tokens[2][:30]]
-    pool = engine.window_pool
-    assert pool.in_use == 0
-    released, first_seq = pool.released, len(tracing.snapshot()["steps"])
-    pool.high_water = 0
-    gateway = ServingGateway(engine, config=ServingConfig(default_max_new_tokens=12))
-    try:
-        assert gateway.gate.usable_window_blocks == pool.free_blocks - 16 // BLOCK
-        handles = [gateway.submit(p, max_new_tokens=12) for p in prompts]
-        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
+    def around_the_traffic(self, gateway, served):
+        pool = served.window_pool
+        assert gateway.gate.usable_window_blocks == pool.free_blocks - CASE.rows // BLOCK
+        yield
         external = gateway.snapshot()["external"]["Serve/WindowPool"]
-    finally:
-        gateway.shutdown()
-    for prompt, stream in zip(prompts, streams):
-        full = np.concatenate([prompt, np.asarray(stream[:-1], np.int32)])
-        assert stream == [int(t) for t in np.argmax(reference(full)[len(prompt) - 1:], axis=-1)]
-    records = [r for r in tracing.snapshot()["steps"][first_seq:] if r["engine"] == engine.trace_id]
-    assert {"burst", "put"} <= {r["kind"] for r in records}
-    assert all(r["counts"] is not None for r in records if r["kind"] in ("burst", "put"))
-    assert pool.in_use == 0 and pool.released > released   # every block came back
-    assert pool.high_water <= 3 * (pool.bound(1) + 1) + 16 // BLOCK
-    assert external["released"] > released and external["gate_refused_by_window_blocks"] == 0
+        yield
+        assert pool.in_use == 0 and pool.released > 0          # every block came back
+        assert pool.high_water <= 3 * (pool.bound(1) + 1) + CASE.rows // BLOCK
+        assert external["released"] > 0 and external["gate_refused_by_window_blocks"] == 0

@@ -24,9 +24,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
-                                        KVTierConfig, PrefixCacheConfig,
-                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
 from deepspeed_tpu.inference.v2 import model_runner
 from deepspeed_tpu.models import LFM2_CONFIGS, build_model
 from deepspeed_tpu.models.lfm2 import (ATTENTION, CONV, PUBLISHED_LAYER_TYPES, Lfm2MoeConfig,
@@ -34,74 +31,42 @@ from deepspeed_tpu.models.lfm2 import (ATTENTION, CONV, PUBLISHED_LAYER_TYPES, L
                                        reference_conv, reference_experts, reference_logits,
                                        reference_router)
 
-TOL = 2e-5
+from unit.inference.v2 import kind_conformance as conformance
+from unit.inference.v2.kinds import (Burst, Case, Refused, count, rel_err, serve, slot_batch,
+                                     two_prompts, two_sequences)
+
 DEBUG = LFM2_CONFIGS["lfm2-debug"]
-BLOCK = 16
 KIND = model_runner.Lfm2Kind
+LC = DEBUG.count(CONV)
+PICKS = DEBUG.num_experts_per_tok * DEBUG.num_moe_layers
 
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def engine_config(**over):
-    return RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=96,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
-                                           max_ragged_sequence_count=4,
-                                           max_tracked_sequences=4, max_context=192), **over)
-
-
-@pytest.fixture(scope="module")
-def model():
-    return build_model("lfm2-debug")
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(5))
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(3).integers(0, 256, (4, 192), dtype=np.int32)
-
-
-_REFERENCE = {}        # a config → its jitted reference, one program for every length
-
-
-def reference(engine, seq):
-    """The reference's logits [len(seq), V]: the sequence padded to the rows'
-    192 tokens, which a causal model's rows before the padding cannot see, so
-    that one compiled program serves every length a test asks for."""
-    cfg = engine.model_config
-    if cfg not in _REFERENCE:
-        _REFERENCE[cfg] = jax.jit(lambda params, ids: reference_logits(params, ids, cfg))
-    padded = np.zeros((1, 192), np.int32)
-    padded[0, :len(seq)] = seq
-    return np.asarray(_REFERENCE[cfg](engine.params, jnp.asarray(padded))[0, :len(seq)])
-
-
-def serve(engine, plan):
-    """``plan``: steps of ``[(uid, tokens)]`` → {uid: [the logits row of
-    each of its steps]}; a uid's first appearance tells the engine its
-    prompt, as the scheduler does."""
-    rows = {}
-    for step in plan:
-        for u, t in step:
-            if engine.state_manager.query(u) is None:
-                engine.prefix_match(u, t)
-        out = engine.put([u for u, _ in step], [t for _, t in step])
-        for (u, _), row in zip(step, out):
-            rows.setdefault(u, []).append(row)
-    return rows
-
-
-def count(cfg):
-    return sum(int(np.prod(s)) for s in jax.tree.leaves(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
+CASE = Case(
+    preset="lfm2-debug",
+    reference=lambda params, ids, cfg, prompt: reference_logits(params, ids, cfg),
+    refused=tuple(Refused(*row) for row in (
+        ("layer_types", ("conv", "sliding_attention"), None, {"num_hidden_layers": 2}),
+        ("conv_bias", True), ("conv_L_cache", 1), ("norm_topk_prob", False),
+        ("use_expert_bias", False), ("tie_word_embeddings", False), ("num_attention_heads", 3),
+        ("num_key_value_heads", 3))),
+    # chunk boundaries at every offset mod 3 of the convolution (chunks of 1 and 2 rows among
+    # them: shorter than the tail), then decode rows
+    prefill=((20, 6, [20]), (75, 5, [32, 32, 11]), (100, 4, [7, 32, 32, 29]), (3, 8, [2, 1]),
+             (40, 3, [1, 1, 1, 31, 6]), (62, 3, [30, 31, 1]), (64, 3, [32, 32])),
+    cuts=tuple((19, 1, [cut, 19 - cut]) for cut in range(1, 8)),
+    # the last step: two decode rows; a context counts once a sequence, at its length
+    plans={"two_prompts_in_one_chunk": two_prompts({
+        4: {"n_conv_rows": 2 * LC, "n_tail_slots": 2 * LC, "n_ctx_seq_tokens": 52 + 41,
+            "n_picks_held": 2 * PICKS, "n_picks_zero": 0}})},
+    burst=Burst(1, 0, 80, (8, 8), {"n_conv_rows": 8 * LC, "n_tail_slots": 8 * LC,
+                                   "n_ctx_seq_tokens": sum(range(89, 97))}),
+    records=two_sequences({"n_conv_rows": 29 * LC, "n_tail_slots": 2 * LC, "n_ctx_seq_tokens": 29,
+                           "n_picks_held": 29 * PICKS, "n_picks_zero": 0}),
+    step_counts=("n_picks_held", "n_picks_zero", "n_groups_live", "n_conv_rows", "n_tail_slots",
+                 "n_ctx_seq_tokens"),
+    scopes=("ds.lfm2.conv", "ds.lfm2.attn", "ds.dense_ffn", "ds.moe_routed"),
+    # a slot is K - 1 rows of the hidden width a conv layer, in float32: what the gate counts
+    state_extra=("conv",), slot_bytes=LC * 2 * DEBUG.hidden_size * 4)
+TOL = CASE.tol
 
 
 # ------------------------------------------------------------- the model file
@@ -125,8 +90,8 @@ def test_the_presets_are_the_published_stack_and_its_cut():
     assert dataclasses.replace(cut, num_hidden_layers=40,
                                layer_types=PUBLISHED_LAYER_TYPES) == whole   # nothing else is cut
     assert model_runner.kind_of(DEBUG) is KIND
-    assert count(whole) == 23843661440                  # the published "24B": 23.84 B, tied head
-    assert count(cut) == 5267090176                     # benchmark/configs/lfm2-24b-a2b-10l.json
+    assert count(param_shapes(whole)) == 23843661440   # the published "24B": 23.84 B, tied head
+    assert count(param_shapes(cut)) == 5267090176   # benchmark/configs/lfm2-24b-a2b-10l.json
 
 
 def test_the_shapes_are_the_catalog_rows():
@@ -143,18 +108,6 @@ def test_the_shapes_are_the_catalog_rows():
     assert shapes["moe_ffn"]["gate"]["expert_bias"] == (38, 64)
 
 
-@pytest.mark.parametrize("field,value", [
-    ("layer_types", ("conv", "sliding_attention")), ("conv_bias", True), ("conv_L_cache", 1),
-    ("norm_topk_prob", False), ("use_expert_bias", False), ("tie_word_embeddings", False),
-    ("num_attention_heads", 3), ("num_key_value_heads", 3)])
-def test_what_is_not_implemented_is_refused_by_name(field, value):
-    over = {field: value}
-    if field == "layer_types":
-        over["num_hidden_layers"] = len(value)
-    with pytest.raises(ValueError, match=field):
-        dataclasses.replace(DEBUG, **over)
-
-
 def test_the_flax_module_is_the_reference(model):
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 24), dtype=np.int32))
     params = model.init(jax.random.PRNGKey(1), ids)["params"]
@@ -168,62 +121,7 @@ def test_the_flax_module_is_the_reference(model):
     assert float(jnp.std(bias)) > 0.05                  # seeded, not zero
 
 
-# ---------------------------------------------- the engine against the forward
-@pytest.mark.parametrize("prompt,steps,chunks", [
-    (20, 6, [20]), (75, 5, [32, 32, 11]), (100, 4, [7, 32, 32, 29]), (3, 8, [2, 1]),
-    (40, 3, [1, 1, 1, 31, 6]), (62, 3, [30, 31, 1]), (64, 3, [32, 32])])
-def test_prefill_in_chunks_then_decode_through_the_pools_and_the_slots(engine, tokens, prompt,
-                                                                       steps, chunks):
-    """Chunk boundaries at every offset mod 3 of the convolution (chunks of
-    1 and 2 rows among them: shorter than the tail), then decode rows."""
-    seq = tokens[0][:prompt + steps]
-    plan, at = [], 0
-    for n in chunks:
-        plan.append([(7, seq[at:at + n])])
-        at += n
-    plan += [[(7, seq[prompt + j:prompt + j + 1])] for j in range(steps)]
-    engine.prefix_match(7, seq[:prompt])
-    rows = serve(engine, plan)[7]
-    engine.flush(7)
-    want = reference(engine, seq)
-    compared = [sum(chunks[:i + 1]) - 1 for i in range(len(chunks))] \
-        + [prompt + j for j in range(steps)]
-    assert max(rel_err(r, want[p]) for r, p in zip(rows, compared)) < TOL
-
-
-@pytest.mark.parametrize("cut", range(1, 8))
-def test_a_chunk_cut_at_every_offset_of_the_convolution(engine, tokens, cut):
-    seq = tokens[1][:20]
-    engine.prefix_match(9, seq[:19])
-    rows = serve(engine, [[(9, seq[:cut])], [(9, seq[cut:19])], [(9, seq[19:20])]])[9]
-    engine.flush(9)
-    want = reference(engine, seq)
-    assert max(rel_err(r, want[p]) for r, p in zip(rows, (cut - 1, 18, 19))) < TOL
-
-
-def test_two_prompts_in_one_chunk_beside_decoding_sequences(engine, tokens):
-    """One step holds a decode row, the end of one prompt and the start of
-    another; a sequence of one row beside chunks."""
-    a, b, c = tokens[0][:60], tokens[1][:41], tokens[2][:30]
-    for uid, seq in ((1, a[:50]), (2, b[:40]), (3, c[:29])):
-        engine.prefix_match(uid, seq)
-    rows = serve(engine, [[(1, a[:32])], [(3, c[:29])],
-                          [(3, c[29:30]), (1, a[32:50]), (2, b[:13])],
-                          [(1, a[50:51]), (2, b[13:40])],
-                          [(1, a[51:52]), (2, b[40:41])]])
-    counts = engine.last_step.counts
-    assert counts["n_conv_rows"] == counts["n_tail_slots"] == 2 * DEBUG.count(CONV)
-    assert counts["n_ctx_seq_tokens"] == 52 + 41          # once a sequence: its context's length
-    assert counts["n_picks_held"] == 2 * DEBUG.num_experts_per_tok * DEBUG.num_moe_layers
-    assert counts["n_picks_zero"] == 0
-    for uid in (1, 2, 3):
-        engine.flush(uid)
-    wa, wb, wc = reference(engine, a), reference(engine, b), reference(engine, c)
-    got = [(rows[1][1], wa[49]), (rows[1][2], wa[50]), (rows[1][3], wa[51]),
-           (rows[2][1], wb[39]), (rows[2][2], wb[40]), (rows[3][0], wc[28]), (rows[3][1], wc[29])]
-    assert max(rel_err(g, w) for g, w in got) < TOL
-
-
+# ------------------------------------------------------------ the counts
 def test_a_chunk_counts_its_context_once_a_sequence(engine, tokens):
     seq = tokens[2][:50]
     engine.prefix_match(21, seq)
@@ -235,73 +133,7 @@ def test_a_chunk_counts_its_context_once_a_sequence(engine, tokens):
     assert counts["n_tail_slots"] == DEBUG.count(CONV)
 
 
-def test_decode_bursts_carry_every_tail(engine, tokens):
-    seq = tokens[1][:80]
-    engine.prefix_match(50, seq)
-    for at in (0, 32, 64):
-        out = engine.put([50], [seq[at:at + 32][:80 - at]])
-    first = int(np.argmax(out[0]))
-    burst = [first] + [int(t) for t in engine.decode_burst([50], [first], 8)[:, 0]]
-    burst += [int(t) for t in engine.decode_burst([50], burst[-1:], 8)[:, 0]]
-    counts = engine.last_step.counts
-    assert counts["n_conv_rows"] == counts["n_tail_slots"] == 8 * DEBUG.count(CONV)
-    assert counts["n_ctx_seq_tokens"] == sum(range(89, 97))
-    engine.flush(50)
-    full = np.concatenate([seq, np.asarray(burst[:-1], np.int32)])
-    greedy = [int(t) for t in np.argmax(reference(engine, full)[79:], axis=-1)]
-    assert burst == greedy
-
-
-def test_a_slot_is_reused_with_its_stale_tail_and_the_next_owner_starts_from_zero(engine, tokens):
-    assert engine.state_kind == "kv+slots" and set(engine.state_extra) == {"conv"}
-    assert engine.slot_pool.free_slots == engine.slot_pool.slots == 4
-    serve(engine, [[(11, tokens[2][:30])]])
-    slot = engine.state_manager.query(11).state_row[0]
-    engine.flush(11)
-    assert np.abs(np.asarray(engine.state_extra["conv"][:, slot])).max() > 1e-4
-    seq = tokens[3][:32]
-    rows = serve(engine, [[(12, seq[:2])], [(12, seq[2:31])], [(12, seq[31:32])]])[12]
-    assert engine.state_manager.query(12).state_row[0] == slot           # the same slot
-    engine.flush(12)
-    want = reference(engine, seq)
-    assert max(rel_err(r, want[p]) for r, p in zip(rows, (1, 30, 31))) < TOL
-    # a slot is K - 1 rows of the hidden width a conv layer: what the gate counts
-    assert engine.slot_pool.bytes_per_slot == DEBUG.count(CONV) * 2 * DEBUG.hidden_size * 4
-    assert engine.kv_cache.k.shape[0] == DEBUG.count(ATTENTION)
-
-
-def test_the_gate_on_slots_admits_no_more_sequences_than_slots(engine, tokens):
-    for uid in range(30, 34):
-        serve(engine, [[(uid, tokens[0][:5])]])
-    assert engine.slot_pool.free_slots == 0
-    with pytest.raises(Exception):
-        serve(engine, [[(34, tokens[0][:5])]])
-    for uid in range(30, 34):
-        engine.flush(uid)
-    assert engine.slot_pool.free_slots == 4
-
-
-@pytest.mark.parametrize("subsystem,over", [
-    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
-    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
-    ("KV tier", {"kv_tier": KVTierConfig(enabled=True),
-                 "prefix_cache": PrefixCacheConfig(enabled=False)})])
-def test_the_subsystems_that_read_the_kv_pools_refuse_this_kind_by_name(model, subsystem, over):
-    with pytest.raises(NotImplementedError, match=subsystem):
-        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
-
-
 # --------------------------------------------------------- the pieces alone
-def _batch(rows, n_rows, slots):
-    """``rows``: [(sequence row, first position, length)] in batch order."""
-    seq = np.concatenate([np.full(n, s, np.int32) for s, _, n in rows])
-    pos = np.concatenate([np.arange(f, f + n, dtype=np.int32) for _, f, n in rows])
-    state = np.zeros((n_rows, 1), np.int32)
-    state[:len(slots), 0] = slots
-    return {"token_seq": jnp.asarray(seq), "token_pos": jnp.asarray(pos),
-            "block_tables": jnp.zeros((n_rows, 1), jnp.int32), "seq_state": jnp.asarray(state)}
-
-
 def _pool(cfg, slots, fill):
     return jnp.full((cfg.count(CONV), slots + 1, cfg.conv_L_cache - 1, cfg.hidden_size), fill,
                     jnp.float32)
@@ -326,7 +158,7 @@ def test_a_prompt_in_chunks_leaves_the_references_last_two_gated_rows(engine, ch
     for at in range(0, S, chunk):
         n = min(chunk, S - at)
         y, conv = KIND.conv_layer(engine.params, cfg, layer, x[at:at + n], conv,
-                                  _batch([(0, at, n)], 2, [2]))
+                                  slot_batch([(0, at, n)], 2, [2]))
         got.append(y)
     assert rel_err(jnp.concatenate(got), want[0]) < TOL
     assert rel_err(conv[layer, 2], tail[0]) < TOL
@@ -342,9 +174,10 @@ def test_a_dropped_tail_is_seen(engine):
     with jax.default_matmul_precision("highest"):
         want, _ = reference_conv(lp, x[None], cfg)
     conv = _pool(cfg, 2, 0.0)
-    _, conv = KIND.conv_layer(engine.params, cfg, layer, x[:6], conv, _batch([(0, 0, 6)], 2, [2]))
+    _, conv = KIND.conv_layer(engine.params, cfg, layer, x[:6], conv,
+                              slot_batch([(0, 0, 6)], 2, [2]))
     y, _ = KIND.conv_layer(engine.params, cfg, layer, x[6:], jnp.zeros_like(conv),
-                           _batch([(0, 6, 6)], 2, [2]))
+                           slot_batch([(0, 6, 6)], 2, [2]))
     assert rel_err(y[:2], want[0, 6:8]) > 0.05 and rel_err(y[2:], want[0, 8:]) < TOL
 
 
@@ -366,7 +199,7 @@ def test_decode_rows_beside_chunks_in_one_step_each_from_its_own_tail(engine):
                 _, tail = reference_conv(lp, xs[i][None, :b], cfg)
                 conv = conv.at[layer, slots[i]].set(tail[0])
             want.append(reference_conv(lp, xs[i][None, b:], cfg, tail))
-    batch = _batch([(i, b, n) for i, (b, n) in enumerate(zip(before, now))] + [(7, 0, 1)] * 3,
+    batch = slot_batch([(i, b, n) for i, (b, n) in enumerate(zip(before, now))] + [(7, 0, 1)] * 3,
                    8, slots)
     x = jnp.concatenate([xs[i][b:] for i, b in enumerate(before)]
                         + [jnp.ones((3, cfg.hidden_size))])
@@ -388,11 +221,12 @@ def test_the_served_attention_operator_is_the_references(engine):
     lp = jax.tree.map(lambda w: w[layer], engine.params["model"]["attn_layers"])
     with jax.default_matmul_precision("highest"):
         want = reference_attention(lp, x[None], cfg)[0]
-    shape = (cfg.count(ATTENTION), 8, BLOCK, cfg.num_key_value_heads * cfg.head_dim)
+    assert engine.kv_cache.k.shape[0] == cfg.count(ATTENTION)       # no pool layer for a conv one
+    shape = (cfg.count(ATTENTION), 8, CASE.block, cfg.num_key_value_heads * cfg.head_dim)
     kc, vc = jnp.zeros(shape), jnp.zeros(shape)
     got = []
     for at, n in ((0, 25), (25, 15)):
-        batch = _batch([(0, at, n)], 2, [1])
+        batch = slot_batch([(0, at, n)], 2, [1])
         batch["block_tables"] = jnp.asarray([[1, 2, 3], [0, 0, 0]], jnp.int32)
         y, kc, vc = KIND.attention_layer(engine.params, cfg, layer, x[at:at + n], kc, vc, batch)
         got.append(y)
@@ -455,3 +289,7 @@ def test_layer_params_cuts_each_layers_operator_and_feed_forward(engine):
                           np.asarray(params["model"]["attn_layers"]["q_proj"]["kernel"][2]))
     assert np.array_equal(np.asarray(ffn["gate"]["expert_bias"]),
                           np.asarray(params["model"]["moe_ffn"]["gate"]["expert_bias"][3]))
+
+
+class TestServing(conformance.ChunkCuts, conformance.Slots, conformance.NotKV):
+    pass

@@ -22,11 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
-                                        KVTierConfig, PrefixCacheConfig,
-                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
-from deepspeed_tpu.inference.v2 import model_runner
-from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, model_runner
 from deepspeed_tpu.models import LONGCAT_CONFIGS, LongcatFlashConfig, build_model
 from deepspeed_tpu.models import longcat
 from deepspeed_tpu.models.longcat import (param_shapes, reference_experts, reference_logits,
@@ -35,44 +31,39 @@ from deepspeed_tpu.ops import grouped_gemm
 from deepspeed_tpu.ops.grouped_gemm import GMM_STATS, ExpertShare, dropless_moe_ffn
 from deepspeed_tpu.utils import tracing
 
-TOL = 2e-5
-BLOCK = 16
+from unit.inference.v2 import kind_conformance as conformance
+from unit.inference.v2.kinds import (Burst, Case, Gateway, Plan, Refused, count, engine_config,
+                                     one_long_prompt, rel_err, two_pool_subsystems,
+                                     uniform_tokens)
+
 DEBUG = LONGCAT_CONFIGS["longcat-flash-debug"]
+BLOCK = 16
 
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def engine_config(**over):
-    return RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=64,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=48,
-                                           max_ragged_sequence_count=8,
-                                           max_tracked_sequences=8, max_context=256), **over)
-
-
-@pytest.fixture(scope="module")
-def model():
+CASE = Case(
     # experts 2..5 of 8: a share that starts past expert 0, as every rank but one does
-    return build_model("longcat-flash-debug", experts_held=4, first_expert_held=2)
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(7))
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(11).integers(0, 256, (4, 160), dtype=np.int32)
-
-
-def reference(engine, seq):
-    return np.asarray(reference_logits(engine.params, jnp.asarray(seq)[None],
-                                       engine.model_config))[0]
+    preset="longcat-flash-debug", preset_over={"experts_held": 4, "first_expert_held": 2},
+    block=BLOCK, blocks=64, rows=48, sequences=8, context=256, rng=7,
+    tokens=uniform_tokens(11, (4, 160)),
+    reference=lambda params, ids, cfg, prompt: reference_logits(params, ids, cfg),
+    refused=tuple(Refused(*row) for row in (
+        ("attention_method", "MHA"), ("q_lora_rank", None), ("zero_expert_type", "copy"),
+        ("norm_topk_prob", True), ("router_bias", True), ("rope_scaling", {"type": "yarn"}),
+        ("attention_bias", True), ("tie_word_embeddings", True), ("hidden_act", "gelu"),
+        ("moe_topk", 13), ("experts_held", 9, "routed"), ("first_expert_held", 6, "routed"))),
+    prefill=((40, 0, [40]),),
+    plans={"one_prompt_over_several_chunks_beside_decoding_sequences": one_long_prompt()},
+    # a prompt of 23, then bursts of 16 + 16 + 8 steps over block boundaries; with random
+    # weights the largest logit changes on rounding where the margin is small
+    burst=Burst(0, 60, 23, (16, 16, 8), {}, 1e-4, 35),
+    records=Plan([[(800, 1, 0, 14)]], {800: (1, 14)}, {
+        "n_picks_held": (1, DEBUG.moe_topk * 14 * DEBUG.num_layers),
+        "n_groups_live": (1, 4 * DEBUG.num_layers)}),
+    step_counts=("n_picks_held", "n_picks_zero", "n_groups_live", "n_share_passes",
+                 "n_blocks_named", "n_blocks_fetched"),
+    scopes=("ds.mla", "ds.moe_routed"),
+    subsystems=two_pool_subsystems("expert_parallel_degree"),
+    gateway=Gateway(((0, 60), (1, 9), (2, 33))))
+TOL = CASE.tol
 
 
 def prefill_state(engine, uid, seq, chunks):
@@ -96,12 +87,10 @@ def test_the_debug_preset_has_every_mechanism():
     assert DEBUG.mla_scale_q_lora and DEBUG.mla_scale_kv_lora
     assert DEBUG.query_scale != 1.0 and DEBUG.latent_scale != 1.0
     assert len({DEBUG.qk_nope_head_dim, DEBUG.qk_rope_head_dim, DEBUG.v_head_dim}) == 3
-    count = lambda cfg: sum(int(np.prod(s)) for s in jax.tree.leaves(  # noqa: E731
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
-    assert abs(count(LongcatFlashConfig()) - 560.7e9) < 0.1e9   # the published 560B
+    assert abs(count(param_shapes(LongcatFlashConfig())) - 560.7e9) < 0.1e9   # the published 560B
     share = LONGCAT_CONFIGS["longcat-flash-omni-ep32"]
     assert (share.num_layers, share.held, share.vocab_size) == (4, 16, 16384)
-    assert abs(2 * count(share) - 10.345e9) < 0.005e9                        # bytes in bf16
+    assert abs(2 * count(param_shapes(share)) - 10.345e9) < 0.005e9   # bytes in bf16
     assert share.query_scale == 2.0 and abs(share.latent_scale ** 2 - 12.0) < 1e-9
 
 
@@ -118,18 +107,6 @@ def test_presets_build_by_name_and_pick_their_kind(model):
     assert model_runner.LlamaKind.state_layers(build_model("debug").config) == 2
     assert model_runner.MoonlightKind.step_counts == kind.step_counts[4:]
     assert model_runner.LlamaKind.step_counts == ()
-
-
-@pytest.mark.parametrize("field,value", [
-    ("attention_method", "MHA"), ("q_lora_rank", None), ("zero_expert_type", "copy"),
-    ("norm_topk_prob", True), ("router_bias", True), ("rope_scaling", {"type": "yarn"}),
-    ("attention_bias", True), ("tie_word_embeddings", True), ("hidden_act", "gelu"),
-    ("moe_topk", 13), ("experts_held", 9), ("first_expert_held", 6)])
-def test_what_is_not_implemented_is_refused_by_name(field, value):
-    name = {"experts_held": "routed", "first_expert_held": "routed"}.get(field, field)
-    with pytest.raises(ValueError, match=name):
-        dataclasses.replace(DEBUG, experts_held=4, **{field: value}) if field != "experts_held" \
-            else dataclasses.replace(DEBUG, experts_held=value)
 
 
 def test_the_parameter_tree_has_the_checkpoints_names_and_a_live_bias(engine):
@@ -160,54 +137,6 @@ def test_the_state_is_two_latent_rows_a_token_a_model_layer(engine):
     full = LONGCAT_CONFIGS["longcat-flash-omni-ep32"]
     kind = model_runner.LongcatKind
     assert kind.state_layers(full) * sum(kind.state_rows(full)) * 2 == 10240   # B a token, bf16
-
-
-def test_prefill_in_one_chunk(engine, tokens):
-    seq = tokens[0][:40]
-    got = engine.put([100], [seq])
-    engine.flush(100)
-    assert rel_err(got[0], reference(engine, seq)[-1]) < TOL
-
-
-def test_prefill_over_several_chunks_beside_decoding_sequences(engine, tokens):
-    """A 130-token prompt in chunks of 40 + 40 + 40 + 10 (context crosses
-    eight 16-token blocks), while two other sequences decode one token in
-    each of the same steps."""
-    long_, a, b = tokens[1][:130], tokens[2][:30], tokens[3][:21]
-    want = {1: reference(engine, long_), 2: reference(engine, a), 3: reference(engine, b)}
-    engine.put([2, 3], [a[:20], b[:11]])
-    errs, fed = [], 0
-    for step, n in enumerate((40, 40, 40, 10)):
-        out = engine.put([1, 2, 3], [long_[fed:fed + n], a[20 + step:21 + step],
-                                     b[11 + step:12 + step]])
-        fed += n
-        errs += [rel_err(out[0], want[1][fed - 1]), rel_err(out[1], want[2][20 + step]),
-                 rel_err(out[2], want[3][11 + step])]
-    for uid in (1, 2, 3):
-        engine.flush(uid)
-    assert len(errs) == 12 and max(errs) < TOL, errs
-
-
-def test_forty_decode_steps_through_the_cache_in_bursts(engine, tokens):
-    """Prompt of 23, then decode bursts of 16 + 16 + 8 steps over block
-    boundaries: the logits of one more step read every row the bursts
-    wrote, in both state layers of both model layers."""
-    prompt = tokens[0][60:83]
-    first = int(np.argmax(engine.put([5], [prompt])[0]))
-    generated, last = [first], first
-    for k in (16, 16, 8):
-        out = engine.decode_burst([5], [last], k)
-        generated += [int(t) for t in out[:, 0]]
-        last = generated[-1]
-    after = engine.put([5], [np.asarray([last], np.int32)])
-    engine.flush(5)
-    seq = np.concatenate([prompt, np.asarray(generated, np.int32)])
-    want = reference(engine, seq)
-    assert len(generated) == 41 and rel_err(after[0], want[-1]) < TOL
-    top2 = np.sort(want[len(prompt) - 1:-1], axis=-1)[:, -2:]
-    clear = (top2[:, 1] - top2[:, 0]) > 1e-4
-    assert clear.sum() >= 35
-    assert (np.argmax(want[len(prompt) - 1:-1], axis=-1)[clear] == np.asarray(generated)[clear]).all()
 
 
 def test_one_chunk_and_three_leave_the_same_state_in_both_state_layers(engine, tokens):
@@ -248,7 +177,8 @@ MUTATIONS = {
 
 
 @pytest.mark.parametrize("mutation", MUTATIONS)
-def test_each_mutation_of_the_reference_is_caught(engine, tokens, mutation, monkeypatch):
+def test_each_mutation_of_the_reference_is_caught(engine, reference, tokens, mutation,
+                                                  monkeypatch):
     """The tolerance would catch each piece of the mathematics left out or
     done otherwise: bf16 weights, a missing scaling factor, the identity
     part left out, experts chosen without the bias, weights normalised,
@@ -257,7 +187,7 @@ def test_each_mutation_of_the_reference_is_caught(engine, tokens, mutation, monk
     got = engine.put([200], [seq[:-1]])
     got = np.stack([got[0], engine.put([200], [seq[-1:]])[0]])
     engine.flush(200)
-    assert rel_err(got, reference(engine, seq)[-2:]) < TOL
+    assert rel_err(got, reference(seq)[-2:]) < TOL
     params, cfg = engine.params, engine.model_config
     if mutation == "no_zero_experts_part":
         experts = longcat.reference_experts
@@ -423,21 +353,6 @@ def test_padding_rows_pick_nothing_and_count_nothing(engine, tokens):
     engine.flush(700)
 
 
-# ------------------------------------------------------------ what is refused
-@pytest.mark.parametrize("name,over", [
-    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
-    ("KV tier", {"kv_tier": KVTierConfig(enabled=True)}),
-    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
-    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
-    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
-    ("tensor/expert-parallel sharding", {"expert_parallel_degree": 2}),
-])
-def test_each_subsystem_that_assumes_two_kv_pools_refuses_the_model_by_name(model, name, over):
-    with pytest.raises(NotImplementedError, match=name) as e:
-        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
-    assert "'latent'" in str(e.value) and "longcat" in str(e.value)
-
-
 # ------------------------------------------------------------------- tracing
 def test_step_records_carry_the_device_side_counts(engine, tokens):
     """Held picks, zero picks and live held groups of a step, as the
@@ -463,7 +378,7 @@ def test_step_records_carry_the_device_side_counts(engine, tokens):
     try:
         longcat.reference_experts = lambda mlp, x, c, zero=True: (
             seen.append(reference_router(mlp, x, c)[0]), experts(mlp, x, c, zero))[1]
-        reference(engine, seq)
+        reference_logits(engine.params, jnp.asarray(seq)[None], cfg)    # op by op: the patch runs
     finally:
         longcat.reference_experts = experts
     for weights in seen:
@@ -500,39 +415,11 @@ def test_step_records_carry_the_device_side_counts(engine, tokens):
     assert engine.last_step.kind == "burst_async" and set(engine.last_step.counts) == set(kind_counts)
     engine.flush(800)
     # a kind that counts nothing leaves the field empty
-    llama = InferenceEngineV2(model=build_model("debug"), config=engine_config(),
+    llama = InferenceEngineV2(model=build_model("debug"), config=engine_config(CASE),
                               dtype=jnp.float32)
     llama.put([1], [seq])
     assert llama.last_step.counts is None and "counts" in tracing.STEP_FIELDS
 
 
-# ------------------------------------------------------------------- gateway
-def test_the_gateway_serves_it_through_the_same_scheduler(model, engine, tokens):
-    """Behind ``ServingGateway`` (admission, SplitFuse scheduler, decode
-    bursts): the greedy stream of each request is the one the engine
-    gives alone, prompts longer than the token budget included."""
-    from deepspeed_tpu.serving import ServingConfig, ServingGateway
-    prompts = [tokens[0][:60], tokens[1][:9], tokens[2][:33]]
-    alone = []
-    for i, prompt in enumerate(prompts):
-        out, fed, stream = None, 0, []
-        while fed < len(prompt):
-            out = engine.put([500 + i], [prompt[fed:fed + 48]])
-            fed += 48
-        for _ in range(12):
-            stream.append(int(np.argmax(out[0])))
-            out = engine.put([500 + i], [np.asarray(stream[-1:], np.int32)])
-        engine.flush(500 + i)
-        alone.append(stream)
-    served = InferenceEngineV2(params=engine.params, model_config=model.config,
-                               config=engine_config(), dtype=jnp.float32)
-    gateway = ServingGateway(served, config=ServingConfig(default_max_new_tokens=12))
-    try:
-        handles = [gateway.submit(p, max_new_tokens=12) for p in prompts]
-        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
-    finally:
-        gateway.shutdown()
-    assert streams == alone
-    records = [r for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id]
-    assert {"burst", "put"} <= {r["kind"] for r in records}
-    assert all(r["counts"] is not None for r in records if r["kind"] in ("burst", "put"))
+class TestServing(conformance.NotKV):
+    pass

@@ -13,7 +13,6 @@ differ by the order of float32 additions (relative L2 errors of 1.7-2.3e-7
 were read when this was written); ``TOL`` = 2e-5 is a hundred times that.
 """
 
-import dataclasses
 import functools
 import math
 
@@ -23,11 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
-                                        KVTierConfig, PrefixCacheConfig,
-                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
-from deepspeed_tpu.inference.v2 import model_runner
-from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, model_runner
 from deepspeed_tpu.inference.v2.ragged.slot_pool import SlotPool
 from deepspeed_tpu.models import MINICPM_SALA_CONFIGS, build_model
 from deepspeed_tpu.models import minicpm_sala
@@ -37,61 +32,53 @@ from deepspeed_tpu.models.minicpm_sala import (LINEAR, PUBLISHED_MIXER_TYPES, SP
 from deepspeed_tpu.ops.pallas.paged_attention import (SELECTED_SLOT_BYTES, paged_decode_attention,
                                                       selected_tables, tile_blocks,
                                                       xla_paged_attention)
-from deepspeed_tpu.utils import tracing
 
-TOL = 2e-5
+from unit.inference.v2 import kind_conformance as conformance
+from unit.inference.v2.kinds import (Burst, Case, Plan, Refused, count, engine_config, rel_err,
+                                     second_engine, serve)
+
 DEBUG = MINICPM_SALA_CONFIGS["minicpm-sala-debug"]
 BLOCK = DEBUG.sparse_block_size
+LL = len(DEBUG.linear_positions)
+HEADS_LAYERS = DEBUG.num_key_value_heads * len(DEBUG.sparse_positions)
 
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def engine_config(**over):
-    return RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=96,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
-                                           max_ragged_sequence_count=4,
-                                           max_tracked_sequences=4, max_context=192), **over)
-
-
-@pytest.fixture(scope="module")
-def model():
-    return build_model("minicpm-sala-debug")
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(5))
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(3).integers(0, 256, (4, 192), dtype=np.int32)
-
-
-def reference(engine, seq, prompt_len):
-    return np.asarray(reference_logits(engine.params, jnp.asarray(seq)[None],
-                                       engine.model_config, prompt_len=prompt_len))[0]
-
-
-def serve(engine, plan, prompts=None):
-    """``plan``: steps of ``[(uid, tokens)]`` → {uid: [the logits row of
-    each of its steps]}; a uid's first appearance tells the engine its
-    prompt (``prompts[uid]``; its first chunk where none is given) as the
-    scheduler does."""
-    rows = {}
-    for step in plan:
-        for u, t in step:
-            if engine.state_manager.query(u) is None:
-                engine.prefix_match(u, (prompts or {}).get(u, t))
-        out = engine.put([u for u, _ in step], [t for _, t in step])
-        for (u, _), row in zip(step, out):
-            rows.setdefault(u, []).append(row)
-    return rows
+CASE = Case(
+    preset="minicpm-sala-debug", block=BLOCK,
+    reference=lambda params, ids, cfg, prompt: reference_logits(params, ids, cfg,
+                                                                 prompt_len=prompt),
+    refused=tuple(Refused(*row) for row in (
+        ("attn_use_rope", True), ("lightning_use_rope", False), ("qk_norm", False),
+        ("use_output_norm", False), ("use_output_gate", False), ("attn_use_output_gate", False),
+        ("attention_bias", True), ("tie_word_embeddings", True), ("hidden_act", "gelu"),
+        ("lightning_nkv", 2), ("lightning_scale", "1"), ("sparse_kernel_size", 12),
+        ("sparse_block_size", 18), ("sparse_topk", 2), ("mixer_types", (SPARSE,) * 6),
+        ("mixer_types", (SPARSE, LINEAR, "mamba", LINEAR, SPARSE, LINEAR)))),
+    prefill=((100, 6, (32, 32, 32, 4)),    # a prompt over dense_len: sparse from its first row
+             (100, 4, (7, 32, 29, 32)),    # the same, chunks that end inside blocks and kernels
+             (40, 30, (32, 8)),    # under dense_len: dense, then sparse once the context is 64
+             (64, 3, (32, 32))),           # exactly dense_len
+    # a step's 32 rows hold the end of one prompt, the start of the next and two decode rows;
+    # every sequence has its own slot and blocks; prompts 1 and 2 are over dense_len
+    plans={"two_sequences_in_one_chunk_beside_decoding_ones": Plan(
+        [[(3, 2, 0, 20)], [(4, 3, 0, 32)], [(4, 3, 32, 64)], [(4, 3, 64, 66)],
+         [(3, 2, 20, 21), (4, 3, 66, 67), (1, 0, 0, 30)],
+         [(3, 2, 21, 22), (4, 3, 67, 68), (1, 0, 30, 60)],
+         [(3, 2, 22, 23), (4, 3, 68, 69), (1, 0, 60, 70), (2, 1, 0, 19)],   # two prompts a chunk
+         [(3, 2, 23, 24), (4, 3, 69, 70), (1, 0, 70, 71), (2, 1, 19, 48)],
+         [(1, 0, 71, 72), (2, 1, 48, 78)], [(2, 1, 78, 90)], [(2, 1, 90, 91), (1, 0, 72, 73)]],
+        {1: (0, 70), 2: (1, 90), 3: (2, 20), 4: (3, 66)})},
+    burst=Burst(1, 0, 80, (8, 8), {"n_linear_rows": 8 * LL}),
+    # rows 64..95 of a sparse-from-0 sequence: each reads topk of its five or six blocks
+    records=Plan([[(60, 2, 0, 32)], [(60, 2, 32, 64)], [(60, 2, 64, 96)]], {60: (2, 100)}, {
+        "n_blocks_selected": HEADS_LAYERS * 32 * DEBUG.sparse_topk,
+        "n_blocks_context": HEADS_LAYERS * sum(p // BLOCK + 1 for p in range(64, 96)),
+        "n_linear_rows": 32 * LL}),
+    step_counts=("n_blocks_selected", "n_blocks_context", "n_linear_rows"),
+    scopes=("ds.sala.select", "ds.sala.sparse_attn", "ds.sala.linear"),
+    # a slot: a linear layer's state a head, float32 (the pooled keys are rows of blocks)
+    state_extra=("slots", "pooled_keys"),
+    slot_bytes=LL * 4 * DEBUG.lightning_nh * DEBUG.lightning_head_dim ** 2)
+TOL = CASE.tol
 
 
 # ------------------------------------------------------------- the model file
@@ -108,110 +95,17 @@ def test_the_presets_are_the_published_pattern_and_its_cut():
     assert cut.log_decay(1)[0] == 0.0                       # head 0 never forgets
     assert DEBUG.mixer_types.count(SPARSE) == 2 and DEBUG.mixer_types.count(LINEAR) == 4
     assert model_runner.kind_of(DEBUG) is model_runner.SalaKind
-    shapes = param_shapes(cut)
-    n = sum(int(np.prod(s)) for s in jax.tree.leaves(
-        shapes, is_leaf=lambda x: isinstance(x, tuple)))
-    assert 5.03e9 < n < 5.05e9                              # ISSUE 34's count: 5.04 B
-
-
-@pytest.mark.parametrize("field,value", [
-    ("attn_use_rope", True), ("lightning_use_rope", False), ("qk_norm", False),
-    ("use_output_norm", False), ("use_output_gate", False), ("attn_use_output_gate", False),
-    ("attention_bias", True), ("tie_word_embeddings", True), ("hidden_act", "gelu"),
-    ("lightning_nkv", 2), ("lightning_scale", "1"), ("sparse_kernel_size", 12),
-    ("sparse_block_size", 18), ("sparse_topk", 2), ("mixer_types", (SPARSE,) * 6),
-    ("mixer_types", (SPARSE, LINEAR, "mamba", LINEAR, SPARSE, LINEAR)),
-])
-def test_what_is_not_implemented_is_refused_by_name(field, value):
-    with pytest.raises(ValueError, match=field):
-        dataclasses.replace(DEBUG, **{field: value})
-
-
-# -------------------------------------------------- served against the reference
-@pytest.mark.parametrize("prompt,steps,chunks", [
-    (100, 6, (32, 32, 32, 4)),       # a prompt over dense_len: sparse from its first row
-    (100, 4, (7, 32, 29, 32)),       # the same, chunks that end inside blocks and kernels
-    (40, 30, (32, 8)),               # under dense_len: dense, then sparse once the context is 64
-    (64, 3, (32, 32)),               # exactly dense_len
-])
-def test_prefill_in_chunks_then_decode_through_the_pools(engine, tokens, prompt, steps, chunks):
-    seq = tokens[0][:prompt + steps]
-    want = reference(engine, seq, prompt)
-    plan, fed = [], 0
-    for n in chunks:
-        plan.append([(900, seq[fed:fed + n])])
-        fed += n
-    assert fed == prompt
-    plan += [[(900, seq[prompt + j:prompt + j + 1])] for j in range(steps)]
-    rows = serve(engine, plan, {900: seq[:prompt]})[900]
-    engine.flush(900)
-    at = list(np.cumsum(chunks) - 1) + [prompt + j for j in range(steps)]
-    errs = [rel_err(row, want[p]) for row, p in zip(rows, at)]
-    assert max(errs) < TOL, errs
-
-
-def test_two_sequences_in_one_chunk_beside_decoding_ones(engine, tokens):
-    """A step's 32 rows hold the end of one prompt, the start of the next
-    and two decode rows; every sequence has its own slot and blocks."""
-    a, b, c, d = (tokens[i] for i in range(4))
-    la, lb = 70, 90                                     # prompts: both over dense_len
-    plan = [[(3, c[:20])], [(4, d[:32])], [(4, d[32:64])], [(4, d[64:66])],
-            [(3, c[20:21]), (4, d[66:67]), (1, a[:30])],
-            [(3, c[21:22]), (4, d[67:68]), (1, a[30:60])],
-            [(3, c[22:23]), (4, d[68:69]), (1, a[60:70]), (2, b[:19])],   # two prompts a chunk
-            [(3, c[23:24]), (4, d[69:70]), (1, a[70:71]), (2, b[19:48])],
-            [(1, a[71:72]), (2, b[48:78])], [(2, b[78:90])], [(2, b[90:91]), (1, a[72:73])]]
-    rows = serve(engine, plan, {1: a[:la], 2: b[:lb], 3: c[:20], 4: d[:66]})
-    for uid in (1, 2, 3, 4):
-        engine.flush(uid)
-    want = {1: (a, la, [29, 59, 69, 70, 71, 72]), 2: (b, lb, [18, 47, 77, 89, 90]),
-            3: (c, 20, [19, 20, 21, 22, 23]), 4: (d, 66, [31, 63, 65, 66, 67, 68, 69])}
-    for uid, (seq, n, at) in want.items():
-        ref = reference(engine, seq[:at[-1] + 1], n)
-        errs = [rel_err(row, ref[p]) for row, p in zip(rows[uid], at)]
-        assert max(errs) < TOL, (uid, errs)
-
-
-def test_decode_bursts_carry_every_state(engine, tokens):
-    """Sixteen tokens in bursts of 8 (one program, the pools and the extra
-    tree carried through its scan) are the stepwise stream."""
-    seq = tokens[1][:80]
-    engine.prefix_match(50, seq)
-    out = engine.put([50], [seq[:32]])
-    out = engine.put([50], [seq[32:64]])
-    out = engine.put([50], [seq[64:80]])
-    first = int(np.argmax(out[0]))
-    burst = [first] + [int(t) for t in engine.decode_burst([50], [first], 8)[:, 0]]
-    burst += [int(t) for t in engine.decode_burst([50], burst[-1:], 8)[:, 0]]
-    assert engine.last_step.counts["n_linear_rows"] == 8 * len(DEBUG.linear_positions)
-    engine.flush(50)
-    full = np.concatenate([seq, np.asarray(burst[:-1], np.int32)])
-    ref = reference(engine, full, 80)
-    greedy = [int(t) for t in np.argmax(ref[79:], axis=-1)]
-    assert burst == greedy
+    assert 5.03e9 < count(param_shapes(cut)) < 5.05e9   # ISSUE 34's count: 5.04 B
 
 
 # ------------------------------------------------------------- the slot pool
-def test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_zero(engine, tokens):
-    """The pool is not cleared between owners: the state the last owner
-    left is still in the slot when the next sequence's first rows run, and
-    they take it as zero. Were it carried, every logit would move."""
-    assert engine.slot_pool.free_slots == engine.slot_pool.slots == 4
+def test_a_stale_state_would_move_every_row(engine, tokens):
+    """The control of the shared test of a reused slot: the rows of a next
+    owner over the state the last one left are far off."""
     serve(engine, [[(11, tokens[2][:30])]])
     slot = engine.state_manager.query(11).state_row[0]
-    assert slot >= 1 and engine.slot_pool.free_slots == 3
     engine.flush(11)
-    assert engine.slot_pool.free_slots == 4
-    stale = np.asarray(engine.state_extra["slots"][:, slot])
-    assert np.abs(stale).max() > 1e-3                     # what a missing reset would carry
-    seq = tokens[3][:31]
-    rows = serve(engine, [[(12, seq[:30])], [(12, seq[30:31])]])[12]
-    assert engine.state_manager.query(12).state_row[0] == slot     # the same slot
-    engine.flush(12)
-    want = reference(engine, seq, 30)
-    assert rel_err(rows[0], want[29]) < TOL and rel_err(rows[1], want[30]) < TOL
-    # the control: the same rows over the stale state are far off
-    carried = jnp.asarray(stale[0])
+    carried = jnp.asarray(np.asarray(engine.state_extra["slots"][:, slot])[0])
     x = jax.random.normal(jax.random.PRNGKey(0), (30, DEBUG.num_attention_heads, DEBUG.head_dim))
     moved = jnp.einsum("thd,hde->the", x, carried)
     assert float(jnp.abs(moved).max()) > 1e-2
@@ -229,7 +123,7 @@ def test_the_slot_pool_hands_out_every_slot_but_paddings():
         pool.release(0)
 
 
-def test_a_first_chunk_of_a_prompt_the_engine_was_not_told_is_refused(engine, tokens):
+def test_a_first_chunk_of_a_prompt_the_engine_was_not_told_is_refused(engine, reference, tokens):
     """How a prompt's rows attend depends on the whole prompt's length
     (``dense_len``), which a first chunk does not say: ``put`` takes no
     chunk of a sequence that ``prefix_match`` did not announce, long or
@@ -250,19 +144,13 @@ def test_a_first_chunk_of_a_prompt_the_engine_was_not_told_is_refused(engine, to
                           [(31, seq[96:100])]], {31: seq})[31]   # the same uid, a long prompt now
     assert engine.state_manager.query(31).state_row[1] == 0
     engine.flush(31)
-    want = reference(engine, seq, 100)
+    want = reference(seq, 100)
     assert max(rel_err(r, want[p]) for r, p in zip(rows, (31, 63, 95, 99))) < TOL
 
 
-def test_the_engine_and_the_gate_admit_on_slots(model, engine, tokens):
+def test_the_engine_and_the_gate_admit_on_slots(engine, tokens):
     from deepspeed_tpu.serving.admission import CapacityGate
-    small = InferenceEngineV2(
-        params=engine.params, model_config=model.config, dtype=jnp.float32,
-        config=RaggedInferenceEngineConfig(
-            kv_block_size=BLOCK, num_kv_blocks=96,
-            state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
-                                               max_ragged_sequence_count=4,
-                                               max_tracked_sequences=2, max_context=192)))
+    small = second_engine(CASE, engine, tracked=2)
     assert small.slot_pool.slots == 2 and CapacityGate(small, 32).max_tracked == 2
     serve(small, [[(1, tokens[0][:4]), (2, tokens[1][:4])]])
     with pytest.raises(RuntimeError, match="max_tracked_sequences"):   # a slot a tracked sequence
@@ -458,71 +346,28 @@ def test_a_table_that_is_a_selection_reads_those_blocks_and_masks_only_the_last(
 
 
 # ----------------------------------------------------------- what is refused
-@pytest.mark.parametrize("name,over", [
-    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
-    ("KV tier", {"kv_tier": KVTierConfig(enabled=True)}),
-    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
-    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
-    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
-    ("tensor/expert-parallel sharding", {"tensor_parallel_degree": 2}),
-])
-def test_each_subsystem_that_assumes_two_kv_pools_refuses_the_model_by_name(model, name, over):
-    with pytest.raises(NotImplementedError, match=name) as e:
-        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
-    assert "'sparse_kv+slots'" in str(e.value) and "sala" in str(e.value)
-
-
-def test_suspend_and_a_wrong_block_size_are_refused_by_name(model, engine, tokens):
-    serve(engine, [[(70, tokens[0][:5])]])
-    with pytest.raises(NotImplementedError, match="suspend/resume.*sparse_kv\\+slots"):
-        engine.suspend(70)
-    engine.flush(70)
-    wrong = InferenceEngineV2(
-        params=engine.params, model_config=model.config, dtype=jnp.float32,
-        config=RaggedInferenceEngineConfig(
-            kv_block_size=32, num_kv_blocks=16,
-            state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
-                                               max_ragged_sequence_count=2,
-                                               max_tracked_sequences=2, max_context=64)))
+def test_a_wrong_block_size_is_refused_by_name(engine, tokens):
+    wrong = second_engine(CASE, engine, block=32, blocks=16, sequences=2, context=64)
     with pytest.raises(ValueError, match="kv_block_size 32 is not the selection's block size 16"):
         serve(wrong, [[(1, tokens[0][:5])]])
 
 
 # ------------------------------------------------------------------- tracing
-def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine, tokens):
-    cfg = engine.model_config
-    seq = tokens[2][:100]
-    engine.prefix_match(60, seq)
-    syncs = engine.host_syncs
-    engine.put([60], [seq[:32]])
-    assert engine.host_syncs - syncs == 2            # as for any model kind: pack + fetch
-    first = engine.last_step.counts
-    # rows 0..31 of a sparse-from-0 sequence: each reads min(topk, its blocks) of its blocks
+def test_a_prompts_first_rows_select_every_block_they_have(engine, tokens):
+    """Rows 0..31 of a sparse-from-0 sequence: each reads min(topk, its blocks) of its blocks."""
+    serve(engine, [[(60, tokens[2][:32])]], {60: tokens[2][:100]})
     blocks = [p // BLOCK + 1 for p in range(32)]
-    heads_layers = cfg.num_key_value_heads * len(cfg.sparse_positions)
-    assert first == {"n_blocks_selected": heads_layers * sum(min(cfg.sparse_topk, b) for b in blocks),
-                     "n_blocks_context": heads_layers * sum(blocks),
-                     "n_linear_rows": 32 * len(cfg.linear_positions)}
-    engine.put([60], [seq[32:64]])
-    engine.put([60], [seq[64:96]])
-    third = engine.last_step.counts
-    blocks = [p // BLOCK + 1 for p in range(64, 96)]
-    assert third["n_blocks_selected"] == heads_layers * 32 * cfg.sparse_topk \
-        < third["n_blocks_context"] == heads_layers * sum(blocks)
-    assert tracing.snapshot()["steps"][-1]["counts"] == third
+    assert engine.last_step.counts == {
+        "n_blocks_selected": HEADS_LAYERS * sum(min(DEBUG.sparse_topk, b) for b in blocks),
+        "n_blocks_context": HEADS_LAYERS * sum(blocks), "n_linear_rows": 32 * LL}
     engine.flush(60)
-    lowered = engine._step.lower(engine.params, engine.kv_cache.k, engine.kv_cache.v,
-                                 engine.state_extra, engine._batch.finalize_packed()).as_text(
-                                     debug_info=True)
-    for scope in ("ds.sala.select", "ds.sala.sparse_attn", "ds.sala.linear"):
-        assert scope in lowered, scope
 
 
 def test_the_kinds_without_further_state_compile_to_the_programs_they_had(engine):
     """``xc`` is None for them: no argument and no result of the compiled
     program, whose inputs are the parameters, the two pools and the packed
     batch, and whose outputs are the logits and the two pools."""
-    llama = InferenceEngineV2(model=build_model("debug"), config=engine_config(),
+    llama = InferenceEngineV2(model=build_model("debug"), config=engine_config(CASE),
                               dtype=jnp.float32)
     assert llama.state_extra is None and llama.slot_pool is None and llama._seq_rows == 0
     packed = llama._batch.finalize_packed()
@@ -536,28 +381,5 @@ def test_the_kinds_without_further_state_compile_to_the_programs_they_had(engine
     assert engine._batch.finalize_packed().shape[0] == packed.shape[0] + 5 * 2
 
 
-# ------------------------------------------------------------------- gateway
-def test_the_gateway_serves_it_through_the_same_scheduler(model, engine, tokens):
-    """Behind ``ServingGateway`` (admission, SplitFuse scheduler, decode
-    bursts): the greedy stream of each request is the reference's, prompts
-    longer than the token budget and than dense_len included; the
-    scheduler tells the engine each prompt's length before its first chunk."""
-    from deepspeed_tpu.serving import ServingConfig, ServingGateway
-    prompts = [tokens[0][:75], tokens[1][:9], tokens[2][:40]]
-    served = InferenceEngineV2(params=engine.params, model_config=model.config,
-                               config=engine_config(), dtype=jnp.float32)
-    pool = served.slot_pool
-    gateway = ServingGateway(served, config=ServingConfig(default_max_new_tokens=12))
-    try:
-        handles = [gateway.submit(p, max_new_tokens=12) for p in prompts]
-        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
-    finally:
-        gateway.shutdown()
-    for prompt, stream in zip(prompts, streams):
-        full = np.concatenate([prompt, np.asarray(stream[:-1], np.int32)])
-        ref = reference(engine, full, len(prompt))
-        assert stream == [int(t) for t in np.argmax(ref[len(prompt) - 1:], axis=-1)]
-    records = [r for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id]
-    assert {"burst", "put"} <= {r["kind"] for r in records}
-    assert all(r["counts"] is not None for r in records if r["kind"] in ("burst", "put"))
-    assert pool.free_slots == pool.slots                   # every slot came back
+class TestServing(conformance.Slots, conformance.NotKV):
+    pass

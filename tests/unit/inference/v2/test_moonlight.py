@@ -23,11 +23,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
-                                        KVTierConfig, PrefixCacheConfig,
-                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
 from deepspeed_tpu.inference.v2 import model_runner
-from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
 from deepspeed_tpu.inference.v2.modules import heuristics
 from deepspeed_tpu.models import (MOONLIGHT_CONFIGS, MoonlightConfig, build_llama, build_model)
 from deepspeed_tpu.models import moonlight
@@ -36,42 +32,32 @@ from deepspeed_tpu.ops.pallas.paged_mla_attention import (paged_mla_decode_atten
                                                           xla_paged_mla_attention)
 from deepspeed_tpu.utils import tracing
 
-TOL = 2e-5
+from unit.inference.v2 import kind_conformance as conformance
+from unit.inference.v2.kinds import (Burst, Case, Gateway, Plan, Refused, count, one_long_prompt,
+                                     rel_err, uniform_tokens)
+
 BLOCK = 16
 
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def engine_config(**over):
-    return RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=64,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=48,
-                                           max_ragged_sequence_count=8,
-                                           max_tracked_sequences=8, max_context=256), **over)
-
-
-@pytest.fixture(scope="module")
-def model():
-    return build_model("moonlight-debug")
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(7))
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(11).integers(0, 256, (4, 160), dtype=np.int32)
-
-
-def reference(engine, seq):
-    return np.asarray(reference_logits(engine.params, jnp.asarray(seq)[None],
-                                       engine.model_config))[0]
+CASE = Case(
+    preset="moonlight-debug",
+    block=BLOCK, blocks=64, rows=48, sequences=8, context=256, rng=7,
+    tokens=uniform_tokens(11, (4, 160)),
+    reference=lambda params, ids, cfg, prompt: reference_logits(params, ids, cfg),
+    # what the source switches on elsewhere
+    refused=tuple(Refused(*row) for row in (
+        ("q_lora_rank", 1536), ("n_group", 8), ("topk_group", 4), ("scoring_func", "softmax"),
+        ("topk_method", "greedy"), ("rope_scaling", {"type": "yarn"}))),
+    prefill=((40, 0, [40]),),
+    plans={"one_prompt_over_several_chunks_beside_decoding_sequences": one_long_prompt()},
+    # a prompt of 23 (inside the second block), then bursts of 16 + 16 + 8 = 40 steps over
+    # block boundaries at 32 and 48. Logits, and tokens only where the margin is clear: with
+    # random weights the largest logit changes on rounding
+    burst=Burst(0, 60, 23, (16, 16, 8), {}, 1e-4, 35),
+    records=Plan([[(400, 0, 0, 20), (401, 1, 0, 7)]], {400: (0, 20), 401: (1, 7)}),
+    step_counts=("n_blocks_named", "n_blocks_fetched"),
+    scopes=("ds.mla", "ds.moe_routed", "ds.moe_shared"),
+    gateway=Gateway(((0, 60), (1, 9), (2, 33))))
+TOL = CASE.tol
 
 
 # ------------------------------------------------------------------ the model
@@ -82,8 +68,7 @@ def test_the_debug_preset_has_every_mechanism():
     assert 1 <= cfg.n_shared_experts <= 2 and cfg.kv_lora_rank > 0
     assert len({cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim}) == 3
     full = MOONLIGHT_CONFIGS["moonlight-16b-a3b"]
-    n = sum(int(np.prod(s)) for s in jax.tree.leaves(
-        param_shapes(full), is_leaf=lambda x: isinstance(x, tuple)))
+    n = count(param_shapes(full))
     assert abs(n - 15.96e9) < 0.01e9, n     # the published parameter count
 
 
@@ -96,15 +81,6 @@ def test_presets_build_by_name_through_one_registry(model):
     assert model_runner.kind_of(model.config) is model_runner.MoonlightKind
     assert model_runner.kind_of(build_llama("debug").config) is model_runner.LlamaKind
     assert model_runner.kind_of(build_model("gpt2-debug").config) is model_runner.GPTKind
-
-
-@pytest.mark.parametrize("field,value", [("q_lora_rank", 1536), ("n_group", 8), ("topk_group", 4),
-                                         ("scoring_func", "softmax"),
-                                         ("topk_method", "greedy"),
-                                         ("rope_scaling", {"type": "yarn"})])
-def test_what_the_source_switches_on_elsewhere_is_refused_by_name(field, value):
-    with pytest.raises(ValueError, match=field):
-        dataclasses.replace(MOONLIGHT_CONFIGS["moonlight-debug"], **{field: value})
 
 
 def test_the_parameter_tree_has_the_checkpoints_names_and_a_live_bias(engine):
@@ -129,57 +105,6 @@ def test_the_state_is_one_latent_row_a_token_a_layer(engine):
     full = MOONLIGHT_CONFIGS["moonlight-16b-a3b"]
     assert sum(model_runner.MoonlightKind.state_rows(full)) == 640   # <= 640 values, 1280 B in bf16
     assert model_runner.LlamaKind.state_rows(build_llama("debug").config) == (32, 32)
-
-
-def test_prefill_in_one_chunk(engine, tokens):
-    seq = tokens[0][:40]
-    got = engine.put([100], [seq])
-    engine.flush(100)
-    assert rel_err(got[0], reference(engine, seq)[-1]) < TOL
-
-
-def test_prefill_over_several_chunks_beside_decoding_sequences(engine, tokens):
-    """A 130-token prompt in chunks of 40 + 40 + 40 + 10 (context crosses
-    eight 16-token blocks), while two other sequences decode one token in
-    each of the same steps."""
-    long_, a, b = tokens[1][:130], tokens[2][:30], tokens[3][:21]
-    want = {1: reference(engine, long_), 2: reference(engine, a), 3: reference(engine, b)}
-    engine.put([2, 3], [a[:20], b[:11]])
-    errs, fed = [], 0
-    for step, n in enumerate((40, 40, 40, 10)):
-        out = engine.put([1, 2, 3], [long_[fed:fed + n], a[20 + step:21 + step],
-                                     b[11 + step:12 + step]])
-        fed += n
-        errs += [rel_err(out[0], want[1][fed - 1]), rel_err(out[1], want[2][20 + step]),
-                 rel_err(out[2], want[3][11 + step])]
-    for uid in (1, 2, 3):
-        engine.flush(uid)
-    assert len(errs) == 12 and max(errs) < TOL, errs
-
-
-def test_forty_decode_steps_through_the_cache_in_bursts(engine, tokens):
-    """Prompt of 23 (inside the second block), then decode bursts of 16 +
-    16 + 8 = 40 steps over block boundaries at 32 and 48. A burst returns
-    tokens, so the check is (1) the logits of one more step, which read
-    every row the bursts wrote, and (2) that each burst token is the
-    reference's argmax where its margin is clear of rounding."""
-    prompt = tokens[0][60:83]
-    first = int(np.argmax(engine.put([5], [prompt])[0]))
-    generated, last = [first], first
-    for k in (16, 16, 8):
-        out = engine.decode_burst([5], [last], k)
-        generated += [int(t) for t in out[:, 0]]
-        last = generated[-1]
-    after = engine.put([5], [np.asarray([last], np.int32)])
-    engine.flush(5)
-    seq = np.concatenate([prompt, np.asarray(generated, np.int32)])
-    want = reference(engine, seq)
-    assert len(generated) == 41
-    assert rel_err(after[0], want[-1]) < TOL
-    top2 = np.sort(want[len(prompt) - 1:-1], axis=-1)[:, -2:]
-    clear = (top2[:, 1] - top2[:, 0]) > 1e-4
-    assert clear.sum() >= 35
-    assert (np.argmax(want[len(prompt) - 1:-1], axis=-1)[clear] == np.asarray(generated)[clear]).all()
 
 
 @pytest.mark.parametrize("rows,path", [(2, "gathered"), (9, "ragged")])
@@ -228,7 +153,8 @@ MUTATIONS = {
 
 
 @pytest.mark.parametrize("mutation", MUTATIONS)
-def test_each_mutation_of_the_reference_is_caught(engine, tokens, mutation, monkeypatch):
+def test_each_mutation_of_the_reference_is_caught(engine, reference, tokens, mutation,
+                                                  monkeypatch):
     """The tolerance would catch each piece of the mathematics left out or
     done in less: a reference on weights rounded to bfloat16, a missing
     ``routed_scaling_factor``, a missing shared expert, experts chosen
@@ -237,7 +163,7 @@ def test_each_mutation_of_the_reference_is_caught(engine, tokens, mutation, monk
     got = engine.put([200], [seq[:-1]])
     got = np.stack([got[0], engine.put([200], [seq[-1:]])[0]])
     engine.flush(200)
-    assert rel_err(got, reference(engine, seq)[-2:]) < TOL
+    assert rel_err(got, reference(seq)[-2:]) < TOL
     params, cfg = engine.params, engine.model_config
     if mutation == "raw_c_kv":
         rms_norm = moonlight._rms_norm
@@ -326,28 +252,6 @@ def test_the_registry_names_the_latent_implementations_and_a_wrong_pin_says_the_
                                         override=pin, state_kind=kind)
 
 
-# ------------------------------------------------------------ what is refused
-@pytest.mark.parametrize("name,over", [
-    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
-    ("KV tier", {"kv_tier": KVTierConfig(enabled=True)}),
-    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
-    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
-    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
-    ("tensor/expert-parallel sharding", {"tensor_parallel_degree": 2}),
-])
-def test_each_subsystem_that_assumes_two_kv_pools_refuses_the_model_by_name(model, name, over):
-    with pytest.raises(NotImplementedError, match=name) as e:
-        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
-    assert "'latent'" in str(e.value) and "moonlight" in str(e.value)
-
-
-def test_suspend_refuses_the_latent_state(engine, tokens):
-    engine.put([300], [tokens[0][:5]])
-    with pytest.raises(NotImplementedError, match="suspend/resume export"):
-        engine.suspend(300)
-    engine.flush(300)
-
-
 # ------------------------------------------------------------------- tracing
 def named_blocks(positions, rows):
     """What a program of ``rows`` rows names: a live row the blocks up to
@@ -379,32 +283,5 @@ def test_step_records_carry_the_attended_context(engine, tokens):
         engine.flush(uid)
 
 
-# ------------------------------------------------------------------- gateway
-def test_the_gateway_serves_it_through_the_same_scheduler(model, engine, tokens):
-    """Behind ``ServingGateway`` (admission, SplitFuse scheduler, decode
-    bursts): the greedy stream of each request is the one the engine
-    gives alone, prompts longer than the token budget included."""
-    from deepspeed_tpu.serving import ServingConfig, ServingGateway
-    prompts = [tokens[0][:60], tokens[1][:9], tokens[2][:33]]
-    alone = []
-    for i, prompt in enumerate(prompts):
-        out, fed, stream = None, 0, []
-        while fed < len(prompt):
-            out = engine.put([500 + i], [prompt[fed:fed + 48]])
-            fed += 48
-        for _ in range(12):
-            stream.append(int(np.argmax(out[0])))
-            out = engine.put([500 + i], [np.asarray(stream[-1:], np.int32)])
-        engine.flush(500 + i)
-        alone.append(stream)
-    served = InferenceEngineV2(params=engine.params, model_config=model.config,
-                               config=engine_config(), dtype=jnp.float32)
-    gateway = ServingGateway(served, config=ServingConfig(default_max_new_tokens=12))
-    try:
-        handles = [gateway.submit(p, max_new_tokens=12) for p in prompts]
-        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
-    finally:
-        gateway.shutdown()
-    assert streams == alone
-    kinds = {r["kind"] for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id}
-    assert "burst" in kinds and "put" in kinds
+class TestServing(conformance.NotKV):
+    pass

@@ -26,99 +26,51 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
-                                        KVTierConfig, PrefixCacheConfig,
-                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
 from deepspeed_tpu.inference.v2 import model_runner
-from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
-from deepspeed_tpu.models import NEMOTRON_H_CONFIGS, build_model
+from deepspeed_tpu.models import NEMOTRON_H_CONFIGS
+from deepspeed_tpu.models.moonlight import _rms_norm
 from deepspeed_tpu.models.nemotron_h import (PUBLISHED_PATTERN, NemotronHConfig, layer_params,
                                              param_shapes, reference_experts, reference_logits,
-                                             reference_mamba)
-from deepspeed_tpu.utils import tracing
+                                             reference_mamba, reference_router)
 
-TOL = 2e-5
+from unit.inference.v2 import kind_conformance as conformance
+from unit.inference.v2.kinds import (Burst, Case, Refused, count, rel_err, slot_batch, two_prompts,
+                                     two_pool_subsystems, two_sequences)
+
 DEBUG = NEMOTRON_H_CONFIGS["nemotron-h-debug"]
-BLOCK = 16
 KIND = model_runner.NemotronHKind
+LM, LE = DEBUG.count("M"), DEBUG.count("E")
 
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def engine_config(**over):
-    return RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=96,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
-                                           max_ragged_sequence_count=4,
-                                           max_tracked_sequences=4, max_context=192), **over)
-
-
-@pytest.fixture(scope="module")
-def model():
-    return build_model("nemotron-h-debug")
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(5))
-
-
-@pytest.fixture(scope="module")
-def kernel_engine(model):
-    """An engine whose programs are first run under ``DS_PALLAS=1``
-    (``state_step``'s second case), so that they hold the state step's
-    kernel, interpreted."""
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(5))
-
-
-@pytest.fixture(params=["xla", "pallas_ssm_state"])
-def state_step(request, monkeypatch):
-    """What serves the Mamba-2 state step in the test: the reference, or
-    the kernel (``DS_PALLAS=1`` forces the kernel paths, interpreted off
-    the chip)."""
-    if request.param != "xla":
-        monkeypatch.setenv("DS_PALLAS", "1")
-    return request.param
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(3).integers(0, 256, (4, 192), dtype=np.int32)
-
-
-_REFERENCE = {}        # a config → its jitted reference, one program for every length
-
-
-def reference(engine, seq):
-    """The reference's logits [len(seq), V]: the sequence padded to the rows'
-    192 tokens, which a causal model's rows before the padding cannot see, so
-    that one compiled program serves every length a test asks for."""
-    cfg = engine.model_config
-    if cfg not in _REFERENCE:
-        _REFERENCE[cfg] = jax.jit(lambda params, ids: reference_logits(params, ids, cfg))
-    padded = np.zeros((1, 192), np.int32)
-    padded[0, :len(seq)] = seq
-    return np.asarray(_REFERENCE[cfg](engine.params, jnp.asarray(padded))[0, :len(seq)])
-
-
-def serve(engine, plan):
-    """``plan``: steps of ``[(uid, tokens)]`` → {uid: [the logits row of
-    each of its steps]}; a uid's first appearance tells the engine its
-    prompt, as the scheduler does."""
-    rows = {}
-    for step in plan:
-        for u, t in step:
-            if engine.state_manager.query(u) is None:
-                engine.prefix_match(u, t)
-        out = engine.put([u for u, _ in step], [t for _, t in step])
-        for (u, _), row in zip(step, out):
-            rows.setdefault(u, []).append(row)
-    return rows
+CASE = Case(
+    preset="nemotron-h-debug",
+    reference=lambda params, ids, cfg, prompt: reference_logits(params, ids, cfg),
+    refused=tuple(Refused(*row) for row in (
+        ("hybrid_override_pattern", "EM-M", None, {"num_hidden_layers": 4}), ("n_group", 2),
+        ("topk_group", 2), ("norm_topk_prob", False), ("attention_bias", True),
+        ("mamba_proj_bias", True), ("mlp_bias", True), ("use_conv_bias", False),
+        ("tie_word_embeddings", True), ("mamba_hidden_act", "gelu"), ("mlp_hidden_act", "silu"),
+        ("expand", 4), ("n_shared_experts", 2))),
+    prefill=((20, 6, [20]), (75, 5, [32, 32, 11]), (100, 4, [7, 32, 32, 29]), (3, 8, [2, 1])),
+    # the sequences with further rows go through the rounds, the others through the
+    # all-at-once path, in one program
+    plans={"two_prompts_in_one_chunk": two_prompts()},
+    burst=Burst(1, 0, 80, (8, 8), {"n_ssm_rows": 8 * LM, "n_state_slots": 8 * LM}),
+    # every expert held: a pass a layer
+    records=two_sequences({"n_ssm_rows": 29 * LM, "n_state_slots": 2 * LM, "n_picks_zero": 0,
+                           "n_picks_held": (1, 29 * DEBUG.num_experts_per_tok * LE),
+                           "n_groups_live": (1, DEBUG.held * LE), "n_share_passes": LE}),
+    step_counts=("n_picks_held", "n_picks_zero", "n_groups_live", "n_share_passes", "n_ssm_rows",
+                 "n_state_slots"),
+    scopes=("ds.nemotron.mamba", "ds.nemotron.attn", "ds.nemotron.latent_moe", "ds.moe_routed",
+            "ds.moe_shared"),
+    subsystems=two_pool_subsystems("expert_parallel_degree"),
+    # a slot: a layer's state a head and the convolution's K - 1 rows, float32
+    state_extra=("ssm", "conv"), state_step="pallas_ssm_state",
+    slot_bytes=LM * 4 * (DEBUG.mamba_num_heads * DEBUG.mamba_head_dim * DEBUG.ssm_state_size
+                         + (DEBUG.conv_kernel - 1) * DEBUG.conv_dim),
+    kernel_tests=(
+        "test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_zero",))
+TOL = CASE.tol
 
 
 # ------------------------------------------------------------- the model file
@@ -136,27 +88,8 @@ def test_the_presets_are_the_published_pattern_and_its_cut():
                 4096, 128, 64, 8, 128, 1024, 2688, 22, 512, 128, 32768)
     assert cut.conv_dim == 10240 and cut.mamba_inner == 8192
     assert model_runner.kind_of(DEBUG) is KIND
-
-    def count(cfg):
-        return sum(int(np.prod(s)) for s in jax.tree.leaves(
-            param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
-
-    assert 120.6e9 < count(whole) < 120.8e9                   # the published "120B": 120.67 B
-    assert count(cut) == 4648163712                           # ISSUE 38's count: 4.648 B
-
-
-@pytest.mark.parametrize("field,value", [
-    ("hybrid_override_pattern", "EM-M"), ("n_group", 2), ("topk_group", 2),
-    ("norm_topk_prob", False), ("attention_bias", True), ("mamba_proj_bias", True),
-    ("mlp_bias", True), ("use_conv_bias", False), ("tie_word_embeddings", True),
-    ("mamba_hidden_act", "gelu"), ("mlp_hidden_act", "silu"), ("expand", 4),
-    ("n_shared_experts", 2)])
-def test_what_is_not_implemented_is_refused_by_name(field, value):
-    over = {field: value}
-    if field == "hybrid_override_pattern":
-        over["num_hidden_layers"] = len(value)
-    with pytest.raises(ValueError, match=field):
-        dataclasses.replace(DEBUG, **over)
+    assert 120.6e9 < count(param_shapes(whole)) < 120.8e9   # the published "120B": 120.67 B
+    assert count(param_shapes(cut)) == 4648163712   # ISSUE 38's count: 4.648 B
 
 
 def test_a_share_outside_the_routers_columns_is_refused():
@@ -164,95 +97,7 @@ def test_a_share_outside_the_routers_columns_is_refused():
         dataclasses.replace(DEBUG, experts_held=6, first_expert_held=4)
 
 
-# ---------------------------------------------- the engine against the forward
-@pytest.mark.parametrize("prompt,steps,chunks", [
-    (20, 6, [20]), (75, 5, [32, 32, 11]), (100, 4, [7, 32, 32, 29]), (3, 8, [2, 1])])
-def test_prefill_in_chunks_then_decode_through_the_pools_and_the_slots(engine, tokens, prompt,
-                                                                       steps, chunks):
-    seq = tokens[0][:prompt + steps]
-    plan, at = [], 0
-    for n in chunks:
-        plan.append([(7, seq[at:at + n])])
-        at += n
-    plan += [[(7, seq[prompt + j:prompt + j + 1])] for j in range(steps)]
-    engine.prefix_match(7, seq[:prompt])
-    rows = serve(engine, plan)[7]
-    engine.flush(7)
-    want = reference(engine, seq)
-    compared = [sum(chunks[:i + 1]) - 1 for i in range(len(chunks))] \
-        + [prompt + j for j in range(steps)]
-    assert max(rel_err(r, want[p]) for r, p in zip(rows, compared)) < TOL
-
-
-def test_two_prompts_in_one_chunk_beside_decoding_sequences(engine, tokens):
-    """One step holds a decode row, the end of one prompt and the start of
-    another: the sequences with further rows go through the rounds, the
-    others through the all-at-once path, in one program."""
-    a, b, c = tokens[0][:60], tokens[1][:41], tokens[2][:30]
-    for uid, seq in ((1, a[:50]), (2, b[:40]), (3, c[:29])):
-        engine.prefix_match(uid, seq)
-    rows = serve(engine, [[(1, a[:32])], [(3, c[:29])],
-                          [(3, c[29:30]), (1, a[32:50]), (2, b[:13])],
-                          [(1, a[50:51]), (2, b[13:40])],
-                          [(1, a[51:52]), (2, b[40:41])]])
-    for uid in (1, 2, 3):
-        engine.flush(uid)
-    wa, wb, wc = reference(engine, a), reference(engine, b), reference(engine, c)
-    got = [(rows[1][1], wa[49]), (rows[1][2], wa[50]), (rows[1][3], wa[51]),
-           (rows[2][1], wb[39]), (rows[2][2], wb[40]), (rows[3][0], wc[28]), (rows[3][1], wc[29])]
-    assert max(rel_err(g, w) for g, w in got) < TOL
-
-
-def test_decode_bursts_carry_every_state(engine, tokens):
-    seq = tokens[1][:80]
-    engine.prefix_match(50, seq)
-    for at in (0, 32, 64):
-        out = engine.put([50], [seq[at:at + 32][:80 - at]])
-    first = int(np.argmax(out[0]))
-    burst = [first] + [int(t) for t in engine.decode_burst([50], [first], 8)[:, 0]]
-    burst += [int(t) for t in engine.decode_burst([50], burst[-1:], 8)[:, 0]]
-    counts = engine.last_step.counts
-    assert counts["n_ssm_rows"] == counts["n_state_slots"] == 8 * DEBUG.count("M")
-    engine.flush(50)
-    full = np.concatenate([seq, np.asarray(burst[:-1], np.int32)])
-    greedy = [int(t) for t in np.argmax(reference(engine, full)[79:], axis=-1)]
-    assert burst == greedy
-
-
-def test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_zero(
-        request, state_step, tokens):
-    engine = request.getfixturevalue("engine" if state_step == "xla" else "kernel_engine")
-    assert engine.slot_pool.free_slots == engine.slot_pool.slots == 4
-    serve(engine, [[(11, tokens[2][:30])]])
-    slot = engine.state_manager.query(11).state_row[0]
-    engine.flush(11)
-    assert np.abs(np.asarray(engine.state_extra["ssm"][:, slot])).max() > 1e-3
-    assert np.abs(np.asarray(engine.state_extra["conv"][:, slot])).max() > 1e-3
-    seq = tokens[3][:32]
-    rows = serve(engine, [[(12, seq[:2])], [(12, seq[2:31])], [(12, seq[31:32])]])[12]
-    assert engine.state_manager.query(12).state_row[0] == slot           # the same slot
-    assert engine.last_step.state_step == state_step
-    assert set(engine.state_step_impls.values()) == {state_step}
-    engine.flush(12)
-    want = reference(engine, seq)
-    assert max(rel_err(r, want[p]) for r, p in zip(rows, (1, 30, 31))) < TOL
-    # a slot's bytes are both entries': what the gate and the start-up line count
-    per = sum(int(np.prod(engine.state_extra[k].shape[2:])) * engine.state_extra[k].dtype.itemsize
-              for k in KIND.slot_state)
-    assert engine.slot_pool.bytes_per_slot == DEBUG.count("M") * per
-
-
 # --------------------------------------------------------- the pieces alone
-def _batch(rows, n_rows, slots):
-    """``rows``: [(sequence row, first position, length)] in batch order."""
-    seq = np.concatenate([np.full(n, s, np.int32) for s, _, n in rows])
-    pos = np.concatenate([np.arange(f, f + n, dtype=np.int32) for _, f, n in rows])
-    state = np.zeros((n_rows, 1), np.int32)
-    state[:len(slots), 0] = slots
-    return {"token_seq": jnp.asarray(seq), "token_pos": jnp.asarray(pos),
-            "block_tables": jnp.zeros((n_rows, 1), jnp.int32), "seq_state": jnp.asarray(state)}
-
-
 def _pools(cfg, slots, fill):
     Lm = cfg.count("M")
     return (jnp.full((Lm, slots + 1, cfg.mamba_num_heads, cfg.mamba_head_dim,
@@ -276,7 +121,7 @@ def test_a_prompt_in_chunks_leaves_the_state_and_the_tail_of_the_recurrence(engi
     for at in range(0, S, chunk):
         n = min(chunk, S - at)
         y, ssm, conv = KIND.mamba_layer(engine.params, cfg, layer, x[at:at + n], ssm, conv,
-                                        _batch([(0, at, n)], 2, [2]))
+                                        slot_batch([(0, at, n)], 2, [2]))
         got.append(y)
     assert rel_err(jnp.concatenate(got), want[0]) < TOL
     assert rel_err(ssm[layer, 2], state[0]) < TOL and rel_err(conv[layer, 2], tail[0]) < TOL
@@ -307,7 +152,7 @@ def test_decode_rows_beside_chunks_in_one_step_each_from_its_own_state(engine, s
                 ssm = ssm.at[layer, slots[i]].set(state[0])
                 conv = conv.at[layer, slots[i]].set(tail[0])
             want.append(reference_mamba(lp, xs[i][None, b:], cfg, state, tail))
-    batch = _batch([(i, b, n) for i, (b, n) in enumerate(zip(before, now))], 7, slots)
+    batch = slot_batch([(i, b, n) for i, (b, n) in enumerate(zip(before, now))], 7, slots)
     x = jnp.concatenate([xs[i][b:] for i, b in enumerate(before)])
     old = model_runner.MAMBA_ROUND
     model_runner.MAMBA_ROUND = 2
@@ -355,7 +200,7 @@ def test_the_order_around_the_in_place_write_in_a_mixed_step(engine, state_step)
                 conv = conv.at[layer, slots[i]].set(tail[0])
             want.append(reference_mamba(lp, xs[i][None, b:], cfg, state, tail))
     n_rows, pad = 12, 3
-    batch = _batch([(r, b, n) for r, b, n in zip(rows_of_batch, before, now)]
+    batch = slot_batch([(r, b, n) for r, b, n in zip(rows_of_batch, before, now)]
                    + [(n_rows - 1, 0, 1)] * pad, n_rows, [])
     state_rows = np.zeros((n_rows, 1), np.int32)
     state_rows[rows_of_batch, 0] = slots
@@ -428,84 +273,12 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer(engine):
     assert rel_err(served - shared, want - shared) > 0.3
 
 
-# ----------------------------------------------------------- what is refused
-@pytest.mark.parametrize("name,over", [
-    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
-    ("KV tier", {"kv_tier": KVTierConfig(enabled=True)}),
-    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
-    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
-    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
-    ("tensor/expert-parallel sharding", {"expert_parallel_degree": 2}),
-])
-def test_each_subsystem_that_assumes_two_kv_pools_refuses_the_model_by_name(model, name, over):
-    with pytest.raises(NotImplementedError, match=name) as e:
-        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
-    assert "'kv+slots'" in str(e.value) and "nemotron_h" in str(e.value)
-
-
-def test_suspend_and_an_unannounced_prompt_are_refused_by_name(engine, tokens):
-    with pytest.raises(ValueError, match="needs the whole prompt.*prefix_match"):
-        engine.put([70], [tokens[0][:5]])
-    serve(engine, [[(70, tokens[0][:5])]])
-    with pytest.raises(NotImplementedError, match="suspend/resume.*kv\\+slots"):
-        engine.suspend(70)
-    engine.flush(70)
-    assert engine.slot_pool.free_slots == engine.slot_pool.slots
-
-
-# ------------------------------------------------------------------- tracing
-def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine, tokens):
-    from deepspeed_tpu.models.nemotron_h import reference_router
-    cfg = engine.model_config
-    a, b = tokens[2][:40], tokens[3][:9]
-    engine.prefix_match(60, a)
-    engine.prefix_match(61, b)
-    syncs = engine.host_syncs
-    engine.put([60, 61], [a[:20], b])
-    assert engine.host_syncs - syncs == 2            # as for any model kind: pack + fetch
-    counts = engine.last_step.counts
-    assert set(counts) == set(KIND.step_counts)
-    assert counts["n_ssm_rows"] == 29 * cfg.count("M")
-    assert counts["n_state_slots"] == 2 * cfg.count("M") and counts["n_picks_zero"] == 0
-    assert 0 < counts["n_picks_held"] <= 29 * cfg.num_experts_per_tok * cfg.count("E")
-    assert 0 < counts["n_groups_live"] <= cfg.held * cfg.count("E")
-    assert counts["n_share_passes"] == cfg.count("E")   # every expert held: a pass a layer
-    assert tracing.snapshot()["steps"][-1]["counts"] == counts
-    engine.flush(60)
-    engine.flush(61)
-    # the first expert layer reads the embedding alone: its picks are the reference's
-    x = engine.params["model"]["embed_tokens"][jnp.concatenate([a[:20], b])]
-    first = layer_params(engine.params, cfg, 0)
-    from deepspeed_tpu.models.moonlight import _rms_norm
-    weights, _ = reference_router(first, _rms_norm(x, first["norm"]["scale"],
-                                                   cfg.layer_norm_epsilon), cfg)
-    assert int((np.asarray(weights) > 0).sum()) == 29 * cfg.num_experts_per_tok
-    lowered = engine._step.lower(engine.params, engine.kv_cache.k, engine.kv_cache.v,
-                                 engine.state_extra, engine._batch.finalize_packed()).as_text(
-                                     debug_info=True)
-    for scope in ("ds.nemotron.mamba", "ds.nemotron.attn", "ds.nemotron.latent_moe",
-                  "ds.moe_routed", "ds.moe_shared"):
-        assert scope in lowered, scope
-
-
-# ------------------------------------------------------------------- gateway
-def test_the_gateway_serves_it_through_the_same_scheduler(model, engine, tokens):
-    from deepspeed_tpu.serving import ServingConfig, ServingGateway
-    prompts = [tokens[0][:75], tokens[1][:9], tokens[2][:40]]
-    served = InferenceEngineV2(params=engine.params, model_config=model.config,
-                               config=engine_config(), dtype=jnp.float32)
-    pool = served.slot_pool
-    gateway = ServingGateway(served, config=ServingConfig(default_max_new_tokens=12))
-    try:
-        handles = [gateway.submit(p, max_new_tokens=12) for p in prompts]
-        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
-    finally:
-        gateway.shutdown()
-    for prompt, stream in zip(prompts, streams):
-        full = np.concatenate([prompt, np.asarray(stream[:-1], np.int32)])
-        ref = reference(engine, full)
-        assert stream == [int(t) for t in np.argmax(ref[len(prompt) - 1:], axis=-1)]
-    records = [r for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id]
-    assert {"burst", "put"} <= {r["kind"] for r in records}
-    assert all(r["counts"] is not None for r in records if r["kind"] in ("burst", "put"))
-    assert pool.free_slots == pool.slots                   # every slot came back
+class TestServing(conformance.Slots, conformance.NotKV):
+    def recorded(self, engine, tokens):
+        """The first expert layer reads the embedding alone: its picks are the reference's."""
+        cfg = engine.model_config
+        x = engine.params["model"]["embed_tokens"][jnp.concatenate([tokens[2][:20], tokens[3][:9]])]
+        first = layer_params(engine.params, cfg, 0)
+        weights, _ = reference_router(first, _rms_norm(x, first["norm"]["scale"],
+                                                       cfg.layer_norm_epsilon), cfg)
+        assert int((np.asarray(weights) > 0).sum()) == 29 * cfg.num_experts_per_tok

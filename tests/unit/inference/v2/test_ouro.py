@@ -22,49 +22,49 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
-                                        PrefixCacheConfig, RaggedInferenceEngineConfig)
-from deepspeed_tpu.inference.v2 import model_runner
-from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
-from deepspeed_tpu.models import OURO_CONFIGS, build_model
+from deepspeed_tpu.inference.v2 import PrefixCacheConfig, model_runner
+from deepspeed_tpu.models import OURO_CONFIGS
 from deepspeed_tpu.models import ouro
 from deepspeed_tpu.models.ouro import OuroConfig, param_shapes, reference_forward
 from deepspeed_tpu.utils import tracing
 
-TOL, CLOSE = 2e-5, 0.03
+from unit.inference.v2 import kind_conformance as conformance
+from unit.inference.v2.kinds import (Burst, Case, Gateway, Plan, Refused, count, rel_err,
+                                     second_engine, serve, two_pool_subsystems, two_sequences,
+                                     uniform_tokens)
+
+CLOSE = 0.03
 DEBUG = OURO_CONFIGS["ouro-debug"]
 KIND = model_runner.OuroKind
 BLOCK = 8
 R, L = DEBUG.total_ut_steps, DEBUG.num_hidden_layers
 
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def engine_config(blocks=64, **over):
-    return RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=blocks,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
-                                           max_ragged_sequence_count=4,
-                                           max_tracked_sequences=4, max_context=128), **over)
-
-
-@pytest.fixture(scope="module")
-def model():
-    return build_model("ouro-debug")
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(7))
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(11).integers(0, 256, (4, 96), dtype=np.int32)
+CASE = Case(
+    preset="ouro-debug", block=BLOCK, blocks=64, context=128, rng=7,
+    tokens=uniform_tokens(11, (4, 96)),
+    reference=lambda params, ids, cfg, prompt: reference_forward(params, ids, cfg).logits,
+    refused=tuple(Refused(*row) for row in (
+        ("layer_types", ("full_attention", "sliding_attention", "full_attention")),
+        ("use_sliding_window", True), ("rope_scaling", {"rope_type": "yarn", "factor": 4.0}),
+        ("tie_word_embeddings", True), ("total_ut_steps", 0))),
+    # a prompt of 31 tokens in one chunk or cut at block edges and inside blocks, then six rows
+    prefill=((31, 6, [31]), (31, 6, [17, 14]), (31, 6, [8, 16, 7]), (31, 6, [1, 24, 6]),
+             (31, 6, [16, 15])),
+    plans={"two_prompts_in_one_chunk_beside_decoding_sequences": Plan(
+        [[(1, 0, 0, 20), (2, 1, 0, 9)],
+         [(1, 0, 20, 21), (2, 1, 9, 10), (3, 2, 0, 14), (4, 3, 0, 15)],
+         [(1, 0, 21, 22), (2, 1, 10, 11), (3, 2, 14, 15), (4, 3, 15, 16)]],
+        {1: (0, 20), 2: (1, 9), 3: (2, 14), 4: (3, 15)})},
+    burst=Burst(2, 0, 30, (5,), {"n_stack_passes": 5 * R, "n_loop_token_layers": 5 * R * L,
+                                 "n_exit_early_rows": 0}, 1e-4, 4),
+    records=two_sequences({"n_stack_passes": R, "n_loop_token_layers": 29 * R * L,
+                           "n_exit_early_rows": 0}),
+    step_counts=("n_stack_passes", "n_loop_token_layers", "n_exit_early_rows"),
+    scopes=("ds.ouro.attn", "ds.ouro.mlp", "ds.ouro.loop_norm", "ds.ouro.gate"),
+    # what a stack run several times does not serve: its state is keys and values, the rest it keeps
+    subsystems=two_pool_subsystems()[3:],
+    gateway=Gateway(((0, 30), (1, 9)), 6))
+TOL = CASE.tol
 
 
 @pytest.fixture(scope="module")
@@ -72,23 +72,6 @@ def forward(engine, tokens):
     """The reference's whole forward of the four sequences, once a module."""
     return jax.tree.map(np.asarray, reference_forward(engine.params, jnp.asarray(tokens),
                                                       DEBUG))
-
-
-def other(engine, cfg=DEBUG, dtype=jnp.float32, **over):
-    """A second engine on ``engine``'s weights."""
-    return InferenceEngineV2(params=engine.params, model_config=cfg, config=engine_config(**over),
-                             dtype=dtype)
-
-
-def serve(engine, plan):
-    """``plan``: steps of ``[(uid, tokens)]`` → {uid: [the logits row of each
-    of its steps]}."""
-    rows = {}
-    for step in plan:
-        out = engine.put([u for u, _ in step], [t for _, t in step])
-        for (u, _), row in zip(step, out):
-            rows.setdefault(u, []).append(row)
-    return rows
 
 
 def in_chunks(seq, cuts, decode_from):
@@ -110,8 +93,7 @@ def test_the_presets_are_the_published_stack_and_a_small_one_of_its_pattern():
            "early_exit_threshold": 1, "use_sliding_window": False, "vocab_size": 49152}
     assert {k: getattr(cfg, k) for k in row} == row
     assert OuroConfig(layer_types=["full_attention"] * 48).layer_types == ("full_attention",) * 48
-    n = sum(int(np.prod(s)) for s in jax.tree.leaves(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
+    n = count(param_shapes(cfg))
     # the layers counted ONCE: 48 x (4 x 2048^2 + 3 x 2048 x 5632 + 4 x 2048), two tables of
     # 49,152 x 2048, the model's norm and a gate of 2049
     assert n == 48 * (4 * 2048 ** 2 + 3 * 2048 * 5632 + 4 * 2048) + 2 * 49152 * 2048 + 2048 + 2049
@@ -123,18 +105,6 @@ def test_the_presets_are_the_published_stack_and_a_small_one_of_its_pattern():
     assert set(param_shapes(DEBUG)["model"]["layers"]) == {
         "input_layernorm", "input_layernorm_2", "post_attention_layernorm",
         "post_attention_layernorm_2", "self_attn", "mlp"}
-
-
-@pytest.mark.parametrize("field,value", [
-    ("layer_types", ("full_attention", "sliding_attention", "full_attention")),
-    ("use_sliding_window", True),
-    ("rope_scaling", {"rope_type": "yarn", "factor": 4.0}),
-    ("tie_word_embeddings", True),
-    ("total_ut_steps", 0),
-])
-def test_what_is_not_implemented_is_refused_by_name(field, value):
-    with pytest.raises(ValueError, match=field):
-        dataclasses.replace(DEBUG, **{field: value})
 
 
 def test_the_flax_module_is_the_reference_and_every_pass_differs(model, engine, tokens, forward):
@@ -160,33 +130,6 @@ def test_the_exit_step_is_the_first_pass_whose_cumulative_probability_reaches_th
 
 
 # ---------------------------------------------------------- the served logits
-@pytest.mark.parametrize("cuts", [(), (17,), (8, 24), (1, 25), (16,)],
-                         ids=["one_chunk", "cut_17", "cut_8_24", "cut_1_25", "cut_16"])
-def test_prefill_in_chunks_then_decode_through_the_pools_and_a_burst(engine, tokens, forward,
-                                                                     cuts):
-    """A prompt of 31 tokens in one chunk or cut at block edges and inside
-    blocks, then single decode rows, then a burst whose tokens the reference
-    is read at: every step's logits are the reference's at that position."""
-    seq, uid = tokens[0], 100 + len(cuts) + sum(cuts)
-    prompt = 31
-    plan = in_chunks(seq[:prompt + 6], cuts, prompt)
-    rows = serve(engine, [[(uid, part)] for part in plan])[uid]
-    ends = np.cumsum([len(part) for part in plan]) - 1
-    assert len(rows) == len(ends)
-    for row, end in zip(rows, ends):
-        assert rel_err(row, forward.logits[0, end]) < TOL, end
-    # a burst of 5 from the next token on: the engine's own (greedy) tokens, judged by the
-    # reference's logits on the sequence they make
-    at = prompt + 6
-    burst = engine.decode_burst([uid], [int(seq[at])], 5)[:, 0]
-    full = np.concatenate([seq[:at + 1], burst])
-    ref = np.asarray(reference_forward(engine.params, jnp.asarray(full)[None], DEBUG).logits[0])
-    assert burst.tolist() == np.argmax(ref[at:at + 5], axis=-1).tolist()
-    after = engine.put([uid], [full[-1:]])[0]                # through what the burst wrote
-    assert rel_err(after, ref[-1]) < TOL
-    engine.flush(uid)
-
-
 def _batch(seq_rows, n_rows, table):
     """``seq_rows``: (first position, length) of sequence 0's rows in this
     step; the rest of ``n_rows`` is padding's."""
@@ -224,21 +167,8 @@ def test_every_pass_s_stream_and_gate_through_the_pools(engine, tokens, forward)
     assert written.all() and written.shape == (R * L,)
 
 
-def test_two_prompts_in_one_chunk_beside_decoding_sequences(engine, tokens, forward):
-    a, b, c, d = (tokens[i] for i in range(4))
-    rows = serve(engine, [[(1, a[:20]), (2, b[:9])],
-                          [(1, a[20:21]), (2, b[9:10]), (3, c[:14]), (4, d[:15])],
-                          [(1, a[21:22]), (2, b[10:11]), (3, c[14:15]), (4, d[15:16])]])
-    want = {1: (0, [19, 20, 21]), 2: (1, [8, 9, 10]), 3: (2, [13, 14]), 4: (3, [14, 15])}
-    for uid, (i, ends) in want.items():
-        for row, end in zip(rows[uid], ends):
-            assert rel_err(row, forward.logits[i, end]) < TOL, (uid, end)
-    for uid in want:
-        engine.flush(uid)
-
-
 def test_a_freed_sequence_s_blocks_are_reused_by_the_next_owner(engine, tokens, forward):
-    engine = other(engine, blocks=6)                # five blocks beside padding's: 40 positions
+    engine = second_engine(CASE, engine, blocks=6)   # five blocks beside padding's: 40 positions
     free = engine.state_manager.free_blocks
     serve(engine, [[(10, tokens[2][:30])]])
     held = list(engine.state_manager.query(10).blocks)
@@ -253,7 +183,7 @@ def test_a_freed_sequence_s_blocks_are_reused_by_the_next_owner(engine, tokens, 
 
 
 def test_a_bfloat16_engine_reads_close(engine, tokens, forward):
-    served = other(engine, dtype=jnp.bfloat16)
+    served = second_engine(CASE, engine, dtype=jnp.bfloat16)
     assert served.kv_cache.k.dtype == jnp.bfloat16
     seq = tokens[0]
     rows = serve(served, [[(1, part)] for part in in_chunks(seq[:44], (19,), 38)])[1]
@@ -270,7 +200,7 @@ def test_passes_that_share_one_cache_are_seen_from_the_second_chunk_on(engine, t
     second chunk and the first decode step do not."""
     monkeypatch.setattr(KIND, "pool_layers",
                         staticmethod(lambda cfg, u: jnp.arange(L, dtype=jnp.int32)))
-    shared = other(engine)
+    shared = second_engine(CASE, engine)
     seq = tokens[0]
     rows = serve(shared, [[(1, seq[:30])], [(1, seq[30:31])], [(2, seq[:20])], [(2, seq[20:30])]])
     assert rel_err(rows[1][0], forward.logits[0, 29]) < TOL
@@ -310,7 +240,7 @@ def test_a_threshold_under_one_sends_rows_to_the_head_after_different_passes(eng
     cfg = dataclasses.replace(DEBUG, early_exit_threshold=0.45)
     want = jax.tree.map(np.asarray, reference_forward(engine.params, jnp.asarray(tokens[:2]), cfg))
     assert len(set(want.exit_step.ravel().tolist())) >= 2 and want.exit_step.max() < R - 1
-    served = other(engine, cfg)
+    served = second_engine(CASE, engine, cfg)
     rows = serve(served, [[(1, part)] for part in in_chunks(tokens[0][:30], (11,), 24)])[1]
     ends = [10, 23, 24, 25, 26, 27, 28, 29]
     assert len({int(want.exit_step[0, e]) for e in ends}) >= 2
@@ -328,7 +258,7 @@ def test_a_threshold_under_one_sends_rows_to_the_head_after_different_passes(eng
 
 def test_one_pass_is_the_same_block_run_once(engine, tokens):
     cfg = dataclasses.replace(DEBUG, total_ut_steps=1)
-    served = other(engine, cfg)
+    served = second_engine(CASE, engine, cfg)
     assert served.kv_cache.k.shape[0] == L
     want = np.asarray(reference_forward(engine.params, jnp.asarray(tokens[:1]), cfg).logits[0])
     # the same block run once, written out: the layers, the model's norm, the head
@@ -363,21 +293,10 @@ def test_the_pool_is_r_times_l_layers_deep_and_whoever_sizes_it_counts_them(engi
     assert gate.usable_blocks * BLOCK * engine.state_bytes_per_token <= cache.bytes()
 
 
-@pytest.mark.parametrize("name,over", [
-    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
-    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
-    ("tensor/expert-parallel sharding", {"tensor_parallel_degree": 2}),
-])
-def test_what_a_stack_run_several_times_does_not_serve_is_refused_by_name(model, name, over):
-    with pytest.raises(NotImplementedError, match=name) as e:
-        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
-    assert "'ouro'" in str(e.value)
-
-
 def test_the_prefix_cache_shares_blocks_of_all_the_passes(engine, tokens, forward):
     """A block is ``R L`` layers of a run of positions, so a shared prefix's
     blocks carry every pass's keys and values to the next request."""
-    served = other(engine, prefix_cache=PrefixCacheConfig(enabled=True))
+    served = second_engine(CASE, engine, prefix_cache=PrefixCacheConfig(enabled=True))
     seq = tokens[0]
     serve(served, [[(1, seq[:32])], [(1, seq[32:40])]])
     served.flush(1)
@@ -388,45 +307,26 @@ def test_the_prefix_cache_shares_blocks_of_all_the_passes(engine, tokens, forwar
     assert rel_err(rows[1], forward.logits[0, 37]) < TOL
 
 
-# ------------------------------------------------------------------- tracing
-def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine, tokens):
-    engine.put([60, 61], [tokens[2][:20], tokens[3][:9]])
-    counts = engine.last_step.counts
-    assert tuple(counts) == KIND.step_counts == ("n_stack_passes", "n_loop_token_layers",
-                                                 "n_exit_early_rows")
-    assert counts == {"n_stack_passes": R, "n_loop_token_layers": 29 * R * L,
-                      "n_exit_early_rows": 0}
-    assert tracing.snapshot()["steps"][-1]["counts"] == counts
-    burst = engine.decode_burst([60, 61], [1, 2], 4)
-    assert burst.shape == (4, 2)
-    assert engine.last_step.counts == {"n_stack_passes": 4 * R, "n_loop_token_layers":
-                                       4 * 2 * R * L, "n_exit_early_rows": 0}
-    engine.flush(60)
-    engine.flush(61)
-    lowered = engine._step.lower(engine.params, engine.kv_cache.k, engine.kv_cache.v,
-                                 engine.state_extra, engine._batch.finalize_packed()).as_text(
-                                     debug_info=True)
-    for scope in ("ds.ouro.attn", "ds.ouro.mlp", "ds.ouro.loop_norm", "ds.ouro.gate"):
-        assert scope in lowered, scope
+class TestServing(conformance.Served):
+    def after_the_rows(self, engine, uid, row, fed, reference):
+        """A burst of 5 from the next token on: the engine's own (greedy)
+        tokens, judged by the reference's logits on the sequence they make."""
+        burst = engine.decode_burst([uid], [int(row[fed])], 5)[:, 0]
+        full = np.concatenate([row[:fed + 1], burst])
+        want = reference(full)
+        assert burst.tolist() == np.argmax(want[fed:fed + 5], axis=-1).tolist()
+        after = engine.put([uid], [full[-1:]])[0]                # through what the burst wrote
+        assert rel_err(after, want[-1]) < TOL
 
+    def recorded(self, engine, tokens):
+        assert engine.decode_burst([60, 61], [1, 2], 4).shape == (4, 2)
+        assert engine.last_step.counts == {"n_stack_passes": 4 * R, "n_loop_token_layers":
+                                           4 * 2 * R * L, "n_exit_early_rows": 0}
 
-# ------------------------------------------------------------------- gateway
-def test_the_gateway_serves_it_through_the_same_scheduler(engine, tokens):
-    from deepspeed_tpu.serving import ServingConfig, ServingGateway
-    prompts = [tokens[0][:30], tokens[1][:9]]
-    served = other(engine)
-    gateway = ServingGateway(served, config=ServingConfig(default_max_new_tokens=6))
-    try:
-        handles = [gateway.submit(p, max_new_tokens=6) for p in prompts]
-        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
+    def around_the_traffic(self, gateway, served):
+        yield
         assert served.free_blocks == served.kv_cache.num_blocks - 1      # every block came back
-    finally:
-        gateway.shutdown()
-    for prompt, stream in zip(prompts, streams):
-        full = np.concatenate([prompt, np.asarray(stream[:-1], np.int32)])
-        ref = np.asarray(reference_forward(engine.params, jnp.asarray(full)[None], DEBUG).logits[0])
-        assert stream == [int(t) for t in np.argmax(ref[len(prompt) - 1:], axis=-1)]
-    records = [r for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id]
-    assert {"burst", "put"} <= {r["kind"] for r in records}
-    assert all(r["counts"]["n_stack_passes"] % R == 0 for r in records
-               if r["kind"] in ("burst", "put"))
+        yield
+        records = [r for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id]
+        assert all(r["counts"]["n_stack_passes"] % R == 0 for r in records
+                   if r["kind"] in ("burst", "put"))

@@ -18,12 +18,9 @@ from deepspeed_tpu.inference.v2.scheduler import DynamicSplitFuseScheduler
 from deepspeed_tpu.models import build_model
 from deepspeed_tpu.utils import tracing
 
-TOL = 2e-5      # the kinds' own tests' (test_ouro.py ... test_solar_open2.py)
+from unit.inference.v2.kinds import rel_err
 
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+TOL = 2e-5      # the kinds' own tests' (``kinds.Case.tol``)
 
 
 def make_engine(preset="debug", block=8, seqs=4, tokens=64, context=128, config=None, **model):

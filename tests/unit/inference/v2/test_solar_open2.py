@@ -25,106 +25,61 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2 import (DSStateManagerConfig, InferenceEngineV2,
-                                        KVTierConfig, PrefixCacheConfig,
-                                        RaggedInferenceEngineConfig, SpecDecodeConfig)
-from deepspeed_tpu.inference.v2 import model_runner
-from deepspeed_tpu.inference.v2.config_v2 import LoRAServingConfig, QuantizationConfig
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, model_runner
 from deepspeed_tpu.models import SOLAR_OPEN2_CONFIGS, build_model
 from deepspeed_tpu.models.solar_open2 import (SolarOpen2Config, layer_params, param_shapes,
                                               reference_attention, reference_kda,
                                               reference_logits, reference_moe)
-from deepspeed_tpu.utils import tracing
 
-TOL = 2e-5
+from unit.inference.v2 import kind_conformance as conformance
+from unit.inference.v2.kinds import (Burst, Case, Refused, count, engine_config, rel_err, serve,
+                                     slot_batch, two_prompts, two_sequences)
+
 DEBUG = SOLAR_OPEN2_CONFIGS["solar-open2-debug"]
-BLOCK = 16
 KIND = model_runner.SolarOpen2Kind
 LK = DEBUG.count("k")
-COUNTS = ("n_picks_held", "n_picks_zero", "n_groups_live", "n_share_passes", "n_kda_rows",
-          "n_state_slots", "n_scan_runs", "n_kda_chunk_rows")
+# around the convolutions' four taps and the kernel's blocks of 8
+CUTS = (1, 2, 3, 4, 5, 8, 9, 16, 17)
 
-
-def rel_err(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def engine_config(**over):
-    return RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=96,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=32,
-                                           max_ragged_sequence_count=4,
-                                           max_tracked_sequences=4, max_context=192), **over)
-
-
-@pytest.fixture(scope="module")
-def model():
-    return build_model("solar-open2-debug")
-
-
-@pytest.fixture(scope="module")
-def engine(model):
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(5))
-
-
-@pytest.fixture(scope="module")
-def kernel_engine(model):
-    """An engine whose programs are first run under ``DS_PALLAS=1``
-    (``state_step``'s second case), so that they hold the delta rule's
-    kernel, interpreted."""
-    return InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.float32,
-                             rng=jax.random.PRNGKey(5))
-
-
-@pytest.fixture(params=["xla", "pallas_kda"])
-def state_step(request, monkeypatch):
-    """What serves the delta rule in the test: the fallback, or the kernel
-    (``DS_PALLAS=1`` forces the kernel paths, interpreted off the chip)."""
-    if request.param != "xla":
-        monkeypatch.setenv("DS_PALLAS", "1")
-    return request.param
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(3).integers(0, 256, (4, 192), dtype=np.int32)
-
-
-_REFERENCE = {}        # a config → its jitted reference, one program for every length
-
-
-def reference(engine, seq):
-    """The reference's logits [len(seq), V]: the sequence padded to the rows'
-    192 tokens, which a causal model's rows before the padding cannot see, so
-    that one compiled program serves every length a test asks for."""
-    cfg = engine.model_config
-    if cfg not in _REFERENCE:
-        _REFERENCE[cfg] = jax.jit(lambda params, ids: reference_logits(params, ids, cfg))
-    padded = np.zeros((1, 192), np.int32)
-    padded[0, :len(seq)] = seq
-    return np.asarray(_REFERENCE[cfg](engine.params, jnp.asarray(padded))[0, :len(seq)])
-
-
-def serve(engine, plan):
-    """``plan``: steps of ``[(uid, tokens)]`` → {uid: [the logits row of
-    each of its steps]}; a uid's first appearance tells the engine its
-    prompt, as the scheduler does."""
-    rows = {}
-    for step in plan:
-        for u, t in step:
-            if engine.state_manager.query(u) is None:
-                engine.prefix_match(u, t)
-        out = engine.put([u for u, _ in step], [t for _, t in step])
-        for (u, _), row in zip(step, out):
-            rows.setdefault(u, []).append(row)
-    return rows
-
-
-def count(cfg):
-    return sum(int(np.prod(s)) for s in jax.tree.leaves(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
+CASE = Case(
+    preset="solar-open2-debug",
+    reference=lambda params, ids, cfg, prompt: reference_logits(params, ids, cfg),
+    refused=tuple(Refused(*row) for row in (
+        ("kda_use_full_proj", True), ("use_rope", True), ("use_gqa_gate", False),
+        ("kda_allow_neg_eigval", False), ("first_k_dense_replace", 1), ("n_shared_experts", 2),
+        ("norm_topk_prob", False), ("tie_word_embeddings", True), ("gqa_layers", (0, 9)),
+        ("num_key_value_heads", 3),
+        ("linear_attn_config", {"head_dim": 16, "num_heads": 4, "num_kv_heads": 2,
+                                "short_conv_kernel_size": 4}, "num_kv_heads"))),
+    # whole and in chunks (of 1 and 2 rows among them: shorter than the convolutions' tail),
+    # then decode rows - 64 of them in the last case, so that an error of the state would compound
+    prefill=((20, 6, [20]), (75, 12, [32, 32, 11]), (100, 4, [7, 32, 32, 29]), (3, 8, [2, 1]),
+             (40, 3, [1, 1, 1, 31, 6]), (64, 64, [32, 32])),
+    cuts=tuple((19, 1, [cut, 19 - cut]) for cut in CUTS),
+    # three runs of the delta rule in the third step, each from its own slot; a batch of 32 rows
+    # is no whole block; 32 tokens x 4 picks x 8 layers, all 16 experts held: every pick a row
+    # of some group
+    plans={"two_prompts_in_one_chunk": two_prompts({
+        2: {"n_kda_rows": 32 * LK, "n_state_slots": 3 * LK, "n_scan_runs": 2 * LK,
+            "n_kda_chunk_rows": 0, "n_picks_held": 32 * 4 * 8, "n_picks_zero": 0},
+        4: {"n_scan_runs": 0, "n_state_slots": 2 * LK}})},
+    burst=Burst(1, 0, 80, (8, 8),
+                {"n_kda_rows": 8 * LK, "n_state_slots": 8 * LK, "n_scan_runs": 0}),
+    # every expert held: each layer one pass
+    records=two_sequences({"n_kda_rows": 29 * LK, "n_state_slots": 2 * LK, "n_scan_runs": 2 * LK,
+                           "n_kda_chunk_rows": 0, "n_picks_held": 29 * 4 * 8,
+                           "n_groups_live": (1, 16 * 8), "n_share_passes": 8}),
+    step_counts=("n_picks_held", "n_picks_zero", "n_groups_live", "n_share_passes", "n_kda_rows",
+                 "n_state_slots", "n_scan_runs", "n_kda_chunk_rows"),
+    scopes=("ds.solar.kda", "ds.solar.kda_state", "ds.solar.attn", "ds.moe_routed",
+            "ds.moe_shared"),
+    # a slot: a layer's state a head, float32, and three tails of K - 1 rows side by side
+    state_extra=("kda", "conv"), state_step="pallas_kda",
+    slot_bytes=LK * 4 * (DEBUG.kda_heads * DEBUG.kda_head_dim ** 2
+                         + (DEBUG.kda_conv - 1) * 3 * DEBUG.kda_inner),
+    kernel_tests=("test_a_chunk_cut_at_every_offset", "test_sequences_side_by_side_in_a_step",
+                  "test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_zero"))
+TOL = CASE.tol
 
 
 # ------------------------------------------------------------- the model file
@@ -139,13 +94,13 @@ def test_the_presets_are_the_published_stack_its_share_and_a_small_one_of_its_pa
             whole.vocab_size, whole.max_position_embeddings, whole.rms_norm_eps) == (
                 4096, 128, 64, 8, 64, 128, 4, 320, 8, 1280, 196608, 1048576, 1e-5)
     # ISSUE 48's count: 36 x 154.7 M + 12 x 126.1 M + 48 x 5.033 B + 2 x 196,608 x 4096
-    assert count(whole) == 250288105216
+    assert count(param_shapes(whole)) == 250288105216
     # of which a token reads 8 of 320 experts a layer: the "250B-A15B" of the model's name
-    assert round((count(whole) - 48 * 312 * 3 * 4096 * 1280) / 1e9, 1) == 14.7
+    assert round((count(param_shapes(whole)) - 48 * 312 * 3 * 4096 * 1280) / 1e9, 1) == 14.7
     share = SOLAR_OPEN2_CONFIGS["solar-open2-ep8-4l"]
     assert share.letters == "gkkk" and share.segments == (("g", 1), ("k", 3))
     assert (share.held, share.first_expert_held, share.n_routed_experts) == (40, 0, 320)
-    assert round(count(share) / 1e9, 2) == 3.31                          # 6.62 GB in bfloat16
+    assert round(count(param_shapes(share)) / 1e9, 2) == 3.31   # 6.62 GB in bfloat16
     assert DEBUG.letters == "gkkkgkkk" and DEBUG.segments == (("gkkk", 2),)   # two whole periods
     assert DEBUG.num_attention_heads // DEBUG.num_key_value_heads == 2
     assert DEBUG.num_key_value_heads > 1 and DEBUG.kda_conv == 4
@@ -174,19 +129,6 @@ def test_the_shapes_are_the_catalog_rows():
     held = param_shapes(SOLAR_OPEN2_CONFIGS["solar-open2-ep8-4l"])["model"]["moe"]
     assert held["experts"]["up_proj"] == (4, 40, 4096, 1280)           # the share's 40
     assert held["gate"]["weight"] == (4, 4096, 320)                    # behind the whole router
-
-
-@pytest.mark.parametrize("field,value", [
-    ("kda_use_full_proj", True), ("use_rope", True), ("use_gqa_gate", False),
-    ("kda_allow_neg_eigval", False), ("first_k_dense_replace", 1), ("n_shared_experts", 2),
-    ("norm_topk_prob", False), ("tie_word_embeddings", True), ("gqa_layers", (0, 9)),
-    ("num_key_value_heads", 3),
-    ("linear_attn_config", {"head_dim": 16, "num_heads": 4, "num_kv_heads": 2,
-                            "short_conv_kernel_size": 4})])
-def test_what_is_not_implemented_is_refused_by_name(field, value):
-    name = "num_kv_heads" if field == "linear_attn_config" else field
-    with pytest.raises(ValueError, match=name):
-        dataclasses.replace(DEBUG, **{field: value})
 
 
 def test_a_share_outside_the_router_is_refused():
@@ -219,85 +161,18 @@ def test_the_flax_module_is_the_reference_and_the_seeded_recurrence_lives(model)
     assert 1e-3 < float(jnp.abs(state).max()) < 1e2 and bool(jnp.isfinite(state).all())
 
 
-# ---------------------------------------------- the engine against the forward
-@pytest.mark.parametrize("prompt,steps,chunks", [
-    (20, 6, [20]), (75, 12, [32, 32, 11]), (100, 4, [7, 32, 32, 29]), (3, 8, [2, 1]),
-    (40, 3, [1, 1, 1, 31, 6]), (64, 64, [32, 32])])
-def test_prefill_in_chunks_then_decode_through_the_pools_and_the_slots(engine, tokens, prompt,
-                                                                       steps, chunks):
-    """Whole and in chunks (of 1 and 2 rows among them: shorter than the
-    convolutions' tail), then decode rows - 64 of them in the last case, so
-    that an error of the state would compound."""
-    seq = tokens[0][:prompt + steps]
-    plan, at = [], 0
-    for n in chunks:
-        plan.append([(7, seq[at:at + n])])
-        at += n
-    plan += [[(7, seq[prompt + j:prompt + j + 1])] for j in range(steps)]
-    engine.prefix_match(7, seq[:prompt])
-    rows = serve(engine, plan)[7]
-    engine.flush(7)
-    want = reference(engine, seq)
-    compared = [sum(chunks[:i + 1]) - 1 for i in range(len(chunks))] \
-        + [prompt + j for j in range(steps)]
-    assert max(rel_err(r, want[p]) for r, p in zip(rows, compared)) < TOL
-
-
-@pytest.mark.parametrize("cut", [1, 2, 3, 4, 5, 8, 9, 16, 17])
-def test_a_chunk_cut_at_every_offset_of_a_small_grid(request, state_step, tokens, cut):
-    """Around the convolutions' four taps and the kernel's blocks of 8 rows,
-    through the fallback and through the kernel."""
-    engine = request.getfixturevalue("engine" if state_step == "xla" else "kernel_engine")
-    seq = tokens[1][:20]
-    engine.prefix_match(9, seq[:19])
-    rows = serve(engine, [[(9, seq[:cut])], [(9, seq[cut:19])], [(9, seq[19:20])]])[9]
-    assert engine.last_step.state_step == state_step
-    engine.flush(9)
-    want = reference(engine, seq)
-    assert max(rel_err(r, want[p]) for r, p in zip(rows, (cut - 1, 18, 19))) < TOL
-
-
-def test_two_prompts_in_one_chunk_beside_decoding_sequences(request, state_step, tokens):
-    """One step holds a decode row, the end of one prompt and the start of
-    another: three runs of the delta rule, each from its own slot."""
-    engine = request.getfixturevalue("engine" if state_step == "xla" else "kernel_engine")
-    a, b, c = tokens[0][:60], tokens[1][:41], tokens[2][:30]
-    for uid, seq in ((1, a[:50]), (2, b[:40]), (3, c[:29])):
-        engine.prefix_match(uid, seq)
-    rows = serve(engine, [[(1, a[:32])], [(3, c[:29])],
-                          [(3, c[29:30]), (1, a[32:50]), (2, b[:13])]])
-    counts = engine.last_step.counts
-    assert tuple(counts) == COUNTS
-    assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_scan_runs"]) == (
-        32 * LK, 3 * LK, 2 * LK)
-    assert counts["n_kda_chunk_rows"] == 0           # a batch of 32 rows is no whole block
-    # 32 tokens x 4 picks x 8 layers, all 16 experts held: every pick a row of some group
-    assert counts["n_picks_held"] == 32 * 4 * 8 and counts["n_picks_zero"] == 0
-    more = serve(engine, [[(1, a[50:51]), (2, b[13:40])], [(1, a[51:52]), (2, b[40:41])]])
-    assert engine.last_step.counts["n_scan_runs"] == 0
-    assert engine.last_step.counts["n_state_slots"] == 2 * LK
-    for uid in (1, 2, 3):
-        engine.flush(uid)
-    wa, wb, wc = reference(engine, a), reference(engine, b), reference(engine, c)
-    got = [(rows[1][1], wa[49]), (more[1][0], wa[50]), (more[1][1], wa[51]),
-           (more[2][0], wb[39]), (more[2][1], wb[40]), (rows[3][0], wc[28]), (rows[3][1], wc[29])]
-    assert max(rel_err(g, w) for g, w in got) < TOL
-
-
+# ------------------------------------------------- the delta rule's block form
 @pytest.fixture(scope="module")
 def block_engine(model):
     """A batch of one whole block of the delta rule's block form (64 rows),
     first run under ``DS_PALLAS=1``: a run of ``kda.MIN_CHUNK_RUN`` rows or
     more goes through the chunked form, interpreted."""
-    return InferenceEngineV2(model=model, config=RaggedInferenceEngineConfig(
-        kv_block_size=BLOCK, num_kv_blocks=96,
-        state_manager=DSStateManagerConfig(max_ragged_batch_size=64, max_ragged_sequence_count=4,
-                                           max_tracked_sequences=4, max_context=192)),
-        dtype=jnp.float32, rng=jax.random.PRNGKey(5))
+    return InferenceEngineV2(model=model, config=engine_config(CASE, rows=64), dtype=jnp.float32,
+                             rng=jax.random.PRNGKey(CASE.rng))
 
 
 def test_a_prompt_chunk_goes_through_the_block_form_beside_decode_rows(
-        block_engine, tokens, monkeypatch):
+        block_engine, reference, tokens, monkeypatch):
     """A step with a prompt chunk and decode rows counts the chunk's rows
     through the block form, a decode-only step none; a prompt cut into two
     chunks (both block-form runs, the second from the carried state) serves
@@ -311,7 +186,7 @@ def test_a_prompt_chunk_goes_through_the_block_form_beside_decode_rows(
     engine.prefix_match(2, b[:11])
     rows = serve(engine, [[(2, b[:10]), (1, a[:40])]])
     counts = engine.last_step.counts
-    assert engine.last_step.state_step == "pallas_kda" and tuple(counts) == COUNTS
+    assert engine.last_step.state_step == "pallas_kda" and tuple(counts) == CASE.step_counts
     assert (counts["n_kda_rows"], counts["n_scan_runs"], counts["n_kda_chunk_rows"]) == (
         50 * LK, 2 * LK, 40 * LK)
     more = serve(engine, [[(2, b[10:11]), (1, a[40:89])], [(2, b[11:12]), (1, a[89:90])]])
@@ -320,7 +195,7 @@ def test_a_prompt_chunk_goes_through_the_block_form_beside_decode_rows(
         2 * LK, 2 * LK, 0)
     engine.flush(1)
     engine.flush(2)
-    wa, wb = reference(engine, a), reference(engine, b)
+    wa, wb = reference(a), reference(b)
     got = [(rows[1][0], wa[39]), (more[1][0], wa[88]), (more[1][1], wa[89]),
            (rows[2][0], wb[9]), (more[2][0], wb[10]), (more[2][1], wb[11])]
     assert max(rel_err(g, w) for g, w in got) < TOL
@@ -344,63 +219,7 @@ def test_a_bursts_step_says_that_it_holds_one_row_a_sequence():
     assert model_runner._SlotStep(DEBUG, dict(batch, query_tiles=None), 4).one_row_runs
 
 
-def test_decode_bursts_carry_every_state(engine, tokens):
-    seq = tokens[1][:80]
-    engine.prefix_match(50, seq)
-    for at in (0, 32, 64):
-        out = engine.put([50], [seq[at:at + 32][:80 - at]])
-    first = int(np.argmax(out[0]))
-    burst = [first] + [int(t) for t in engine.decode_burst([50], [first], 8)[:, 0]]
-    burst += [int(t) for t in engine.decode_burst([50], burst[-1:], 8)[:, 0]]
-    counts = engine.last_step.counts
-    assert counts["n_kda_rows"] == counts["n_state_slots"] == 8 * LK
-    assert counts["n_scan_runs"] == 0
-    engine.flush(50)
-    full = np.concatenate([seq, np.asarray(burst[:-1], np.int32)])
-    greedy = [int(t) for t in np.argmax(reference(engine, full)[79:], axis=-1)]
-    assert burst == greedy
-
-
-def test_a_slot_is_reused_with_its_stale_state_and_the_next_owner_starts_from_zero(
-        request, state_step, tokens):
-    engine = request.getfixturevalue("engine" if state_step == "xla" else "kernel_engine")
-    assert engine.state_kind == "kv+slots" and set(engine.state_extra) == {"kda", "conv"}
-    assert engine.slot_pool.free_slots == engine.slot_pool.slots == 4
-    serve(engine, [[(11, tokens[2][:30])]])
-    slot = engine.state_manager.query(11).state_row[0]
-    engine.flush(11)
-    assert np.abs(np.asarray(engine.state_extra["kda"][:, slot])).max() > 1e-4
-    assert np.abs(np.asarray(engine.state_extra["conv"][:, slot])).max() > 1e-4
-    seq = tokens[3][:32]
-    rows = serve(engine, [[(12, seq[:2])], [(12, seq[2:31])], [(12, seq[31:32])]])[12]
-    assert engine.state_manager.query(12).state_row[0] == slot           # the same slot
-    assert engine.last_step.state_step == state_step
-    assert set(engine.state_step_impls.values()) == {state_step}
-    engine.flush(12)
-    want = reference(engine, seq)
-    assert max(rel_err(r, want[p]) for r, p in zip(rows, (1, 30, 31))) < TOL
-    # a slot's bytes are both entries': what the gate and the start-up line count
-    per = sum(int(np.prod(engine.state_extra[k].shape[2:])) * engine.state_extra[k].dtype.itemsize
-              for k in KIND.slot_state)
-    assert engine.slot_pool.bytes_per_slot == LK * per
-    assert engine.state_extra["kda"].dtype == jnp.float32
-    assert engine.state_extra["kda"].shape == (LK, 5, 4, 16, 16)
-    assert engine.state_extra["conv"].shape == (LK, 5, 3, 3 * 64)       # three tails side by side
-    assert engine.kv_cache.k.shape[0] == DEBUG.count("g")
-
-
-def test_the_gate_on_slots_admits_no_more_sequences_than_slots(engine, tokens):
-    for uid in range(30, 34):
-        serve(engine, [[(uid, tokens[0][:5])]])
-    assert engine.slot_pool.free_slots == 0
-    with pytest.raises(Exception):
-        serve(engine, [[(34, tokens[0][:5])]])
-    for uid in range(30, 34):
-        engine.flush(uid)
-    assert engine.slot_pool.free_slots == 4
-
-
-def test_a_bfloat16_engine_keeps_its_state_in_float32_and_reads_close(model, tokens):
+def test_a_bfloat16_engine_keeps_its_state_in_float32_and_reads_close(model, engine, tokens):
     """The served types: bfloat16 weights, stream, keys, values and tails, a
     float32 state. Against the float32 reference on the same (bfloat16)
     weights the logits differ by bfloat16's rounding of the stream, 2**-8 a
@@ -411,9 +230,12 @@ def test_a_bfloat16_engine_keeps_its_state_in_float32_and_reads_close(model, tok
     over the 66 positions. The limits leave twice that and say what a
     fault of the mechanism's size reads, not more: the pieces are held to
     ``TOL`` alone, above and below, and their controls with them."""
-    served = InferenceEngineV2(model=model, config=engine_config(), dtype=jnp.bfloat16,
-                               rng=jax.random.PRNGKey(5))
-    assert served.state_extra["kda"].dtype == jnp.float32
+    served = InferenceEngineV2(model=model, config=engine_config(CASE), dtype=jnp.bfloat16,
+                               rng=jax.random.PRNGKey(CASE.rng))
+    assert engine.state_extra["kda"].dtype == served.state_extra["kda"].dtype == jnp.float32
+    assert engine.state_extra["kda"].shape == (LK, 5, 4, 16, 16)
+    assert engine.state_extra["conv"].shape == (LK, 5, 3, 3 * 64)       # three tails side by side
+    assert engine.kv_cache.k.shape[0] == DEBUG.count("g")
     assert served.state_extra["conv"].dtype == jnp.bfloat16
     seq = tokens[2][:124]
     rows = serve(served, [[(5, seq[:32])], [(5, seq[32:60])]]
@@ -425,16 +247,6 @@ def test_a_bfloat16_engine_keeps_its_state_in_float32_and_reads_close(model, tok
 
 
 # --------------------------------------------------------- the pieces alone
-def _batch(rows, n_rows, slots):
-    """``rows``: [(sequence row, first position, length)] in batch order."""
-    seq = np.concatenate([np.full(n, s, np.int32) for s, _, n in rows])
-    pos = np.concatenate([np.arange(f, f + n, dtype=np.int32) for _, f, n in rows])
-    state = np.zeros((n_rows, 1), np.int32)
-    state[:len(slots), 0] = slots
-    return {"token_seq": jnp.asarray(seq), "token_pos": jnp.asarray(pos),
-            "block_tables": jnp.zeros((n_rows, 1), jnp.int32), "seq_state": jnp.asarray(state)}
-
-
 _MIXERS = {}
 
 
@@ -471,7 +283,7 @@ def test_a_prompt_in_chunks_leaves_the_state_and_the_tail_of_the_recurrence(engi
     for at in range(0, S, chunk):
         n = min(chunk, S - at)
         y, kda, conv = kda_layer(state_step, engine.params, layer, x[at:at + n], kda, conv,
-                                 _batch([(0, at, n)], 2, [2]))
+                                 slot_batch([(0, at, n)], 2, [2]))
         got.append(y)
     assert rel_err(jnp.concatenate(got), want[0]) < TOL
     assert rel_err(kda[layer, 2], state[0]) < TOL and rel_err(conv[layer, 2], tail[0]) < TOL
@@ -501,7 +313,7 @@ def test_several_runs_in_one_step_each_from_its_own_slot(engine, state_step):
                 kda = kda.at[layer, slots[i]].set(state[0])
                 conv = conv.at[layer, slots[i]].set(tail[0])
             want.append(reference_kda(lp, xs[i][None, b:], cfg, state, tail))
-    batch = _batch([(i, b, n) for i, (b, n) in enumerate(zip(before, now))] + [(7, 0, 1)] * 4,
+    batch = slot_batch([(i, b, n) for i, (b, n) in enumerate(zip(before, now))] + [(7, 0, 1)] * 4,
                    8, slots)
     x = jnp.concatenate([xs[i][b:] for i, b in enumerate(before)]
                         + [jnp.ones((4, cfg.hidden_size))])
@@ -532,7 +344,7 @@ def test_a_state_carried_in_bfloat16_and_a_clipped_beta_are_seen(engine):
     kda, conv = _pools(cfg, 2, 0.0)
     for at in range(S):
         _, kda, conv = kda_layer("xla", engine.params, layer, x[at:at + 1], kda, conv,
-                                 _batch([(0, at, 1)], 2, [2]))
+                                 slot_batch([(0, at, 1)], 2, [2]))
         kda = kda.astype(jnp.bfloat16).astype(jnp.float32)
     assert rel_err(kda[layer, 2], state[0]) > 50 * TOL
     # beta = 2 sigmoid(.): halving b_proj's output range is sigmoid alone
@@ -558,11 +370,11 @@ def test_the_served_attention_layer_is_the_references_and_its_gate_is_seen(engin
     with jax.default_matmul_precision("highest"):
         want = reference_attention(lp, x[None], cfg)[0]
         gateless = reference_attention(lp, x[None], cfg, gated=False)[0]
-    shape = (cfg.count("g"), 8, BLOCK, cfg.num_key_value_heads * cfg.head_dim)
+    shape = (cfg.count("g"), 8, CASE.block, cfg.num_key_value_heads * cfg.head_dim)
     kc, vc = jnp.zeros(shape), jnp.zeros(shape)
     got = []
     for at, n in ((0, 25), (25, 15)):
-        batch = _batch([(0, at, n)], 2, [1])
+        batch = slot_batch([(0, at, n)], 2, [1])
         batch["block_tables"] = jnp.asarray([[1, 2, 3], [0, 0, 0]], jnp.int32)
         y, kc, vc = KIND.attention_layer(engine.params, cfg, layer, x[at:at + n], kc, vc, batch)
         got.append(y)
@@ -618,75 +430,5 @@ def test_layer_params_cuts_each_layers_mixer_and_feed_forward(engine):
                           np.asarray(params["model"]["kda_layers"]["dt_bias"][4]))
 
 
-# ----------------------------------------------------------- what is refused
-@pytest.mark.parametrize("name,over", [
-    ("prefix cache", {"prefix_cache": PrefixCacheConfig(enabled=True)}),
-    ("KV tier", {"kv_tier": KVTierConfig(enabled=True)}),
-    ("speculative decoding", {"spec_decode": SpecDecodeConfig(enabled=True)}),
-    ("LoRA serving", {"lora": LoRAServingConfig(enabled=True)}),
-    ("weight-only quantization", {"quantization": QuantizationConfig(quantization_mode="wf6af16")}),
-    ("tensor/expert-parallel sharding", {"tensor_parallel_degree": 2}),
-])
-def test_each_subsystem_that_assumes_two_kv_pools_refuses_the_model_by_name(model, name, over):
-    with pytest.raises(NotImplementedError, match=name) as e:
-        InferenceEngineV2(model=model, config=engine_config(**over), dtype=jnp.float32)
-    assert "'kv+slots'" in str(e.value) and "'solar_open2'" in str(e.value)
-
-
-def test_suspend_and_an_unannounced_prompt_are_refused_by_name(engine, tokens):
-    with pytest.raises(ValueError, match="needs the whole prompt.*prefix_match"):
-        engine.put([70], [tokens[0][:5]])
-    serve(engine, [[(70, tokens[0][:5])]])
-    with pytest.raises(NotImplementedError, match="suspend/resume.*kv\\+slots"):
-        engine.suspend(70)
-    engine.flush(70)
-    assert engine.slot_pool.free_slots == engine.slot_pool.slots
-
-
-# ------------------------------------------------------------------- tracing
-def test_step_records_carry_the_counts_and_the_scopes_are_in_the_program(engine, tokens):
-    a, b = tokens[2][:40], tokens[3][:9]
-    engine.prefix_match(60, a)
-    engine.prefix_match(61, b)
-    syncs = engine.host_syncs
-    engine.put([60, 61], [a[:20], b])
-    assert engine.host_syncs - syncs == 2            # as for any model kind: pack + fetch
-    counts = engine.last_step.counts
-    assert tuple(counts) == KIND.step_counts == COUNTS
-    assert (counts["n_kda_rows"], counts["n_state_slots"], counts["n_scan_runs"],
-            counts["n_kda_chunk_rows"]) == (29 * LK, 2 * LK, 2 * LK, 0)
-    assert counts["n_picks_held"] == 29 * 4 * 8 and 0 < counts["n_groups_live"] <= 16 * 8
-    assert counts["n_share_passes"] == 8        # every expert held: each layer one pass
-    assert tracing.snapshot()["steps"][-1]["counts"] == counts
-    assert tracing.snapshot()["steps"][-1]["state_step"] == "xla"
-    engine.flush(60)
-    engine.flush(61)
-    lowered = engine._step.lower(engine.params, engine.kv_cache.k, engine.kv_cache.v,
-                                 engine.state_extra, engine._batch.finalize_packed()).as_text(
-                                     debug_info=True)
-    for scope in ("ds.solar.kda", "ds.solar.kda_state", "ds.solar.attn", "ds.moe_routed",
-                  "ds.moe_shared"):
-        assert scope in lowered, scope
-
-
-# ------------------------------------------------------------------- gateway
-def test_the_gateway_serves_it_through_the_same_scheduler(model, engine, tokens):
-    from deepspeed_tpu.serving import ServingConfig, ServingGateway
-    prompts = [tokens[0][:75], tokens[1][:9], tokens[2][:40]]
-    served = InferenceEngineV2(params=engine.params, model_config=model.config,
-                               config=engine_config(), dtype=jnp.float32)
-    pool = served.slot_pool
-    gateway = ServingGateway(served, config=ServingConfig(default_max_new_tokens=12))
-    try:
-        handles = [gateway.submit(p, max_new_tokens=12) for p in prompts]
-        streams = [[int(t) for t in h.result(timeout=300)] for h in handles]
-    finally:
-        gateway.shutdown()
-    for prompt, stream in zip(prompts, streams):
-        full = np.concatenate([prompt, np.asarray(stream[:-1], np.int32)])
-        ref = reference(engine, full)
-        assert stream == [int(t) for t in np.argmax(ref[len(prompt) - 1:], axis=-1)]
-    records = [r for r in tracing.snapshot()["steps"] if r["engine"] == served.trace_id]
-    assert {"burst", "put"} <= {r["kind"] for r in records}
-    assert all(r["counts"] is not None for r in records if r["kind"] in ("burst", "put"))
-    assert pool.free_slots == pool.slots                   # every slot came back
+class TestServing(conformance.ChunkCuts, conformance.Slots, conformance.NotKV):
+    pass
