@@ -6,7 +6,18 @@ DeepSpeed-FastGen paper): every engine step carries a fixed token
 budget; running (decode) sequences get one token each first, and the
 remaining budget is filled with chunks of pending prompts — long
 prompts are SPLIT across steps, decodes are FUSED into prefill steps,
-so step latency stays flat and the MXU stays fed."""
+so step latency stays flat and the MXU stays fed.
+
+A step is also fitted to the **blocks that are free** (``_plan``,
+``_plan_burst``): whoever admits requests may let in more than the pool
+holds at their worst case (``serving/admission.CapacityGate`` commits what
+a request holds, not what it may reach), so a decode row whose next token
+opens a block that is not there waits a step - it keeps its token, the rows
+beside it run, end and free blocks - a prompt chunk is cut to the blocks
+there are, and a burst is as long as the pool can reserve. ``engine.put``
+is never asked for blocks the pool has not got. When no row can run at all,
+:meth:`DynamicSplitFuseScheduler.preempt_for_room` gives up one request,
+for its owner to run again from its tokens (preemption by recompute)."""
 
 from collections import OrderedDict, deque
 import functools
@@ -60,6 +71,9 @@ class Request:
         # paused requests hold scheduler state but take no step work —
         # their KV may be suspended to host (gateway preemption)
         self.paused = False
+        # tokens it had in the cache when it was given up for room
+        # (preempt_for_room): what running it again computes a second time
+        self.recomputed = 0
 
     @property
     def prefilling(self):
@@ -133,6 +147,9 @@ class DynamicSplitFuseScheduler:
         # in the step, and the requests that got their first place in it
         self._planned_prompt_tokens = 0
         self._planned_first = []
+        self.block_size = int(engine.block_size)
+        # decode rows that waited a step for a block (a count that only grows)
+        self.rows_held_back = 0
 
     def add_request(self, uid, prompt_tokens, max_new_tokens=16, priority=0,
                     spec=True, adapter_id=None, sample=None, schema=None):
@@ -258,18 +275,49 @@ class DynamicSplitFuseScheduler:
             self.engine.resume(uid)
         r.paused = False
 
+    def _free_blocks(self):
+        """Blocks the pool can give a step: the free list and what the
+        prefix cache gives back on demand."""
+        return int(self.engine.free_blocks) + int(getattr(self.engine, "evictable_blocks", 0))
+
+    def _fit(self, uid, want, free):
+        """→ ``(tokens, blocks)``: how many of the ``want`` tokens ``uid``
+        asks a place for fit the room in its last block and ``free`` more
+        blocks, and the blocks they claim."""
+        state = self.engine.query(uid)
+        room = state[1] if state is not None else 0
+        if want <= room:
+            return want, 0
+        need = -(-(want - room) // self.block_size)
+        if need <= free:
+            return want, need
+        return room + free * self.block_size, free
+
     def _plan(self):
-        """One step's (uids, token-chunks) within the budget: decodes
-        first, then prompt chunks (splitting long prompts)."""
+        """One step's (uids, token-chunks) within the budget and the free
+        blocks: decodes first, then prompt chunks (splitting long prompts).
+        A decode row that needs a block the pool has not got keeps its
+        token and waits; a prompt chunk is cut to the blocks there are."""
         uids, chunks = [], []
         budget = self.budget
         max_seqs = self.engine.max_seqs
         live = self._live()
         self._planned_prompt_tokens = 0
         self._planned_first = []
+        # no request claims more than a block a decode row, or its chunk's: where the
+        # pool has that for every row the step fits as it is, and nobody is asked
+        block = self.block_size
+        avail, claimed = self._free_blocks(), 0
+        tight = avail < len(live) + -(-budget // block)
         # 1) decodes: one token each
         for r in live:
             if r.next_token is not None and budget > 0 and len(uids) < max_seqs:
+                if tight:
+                    fits, need = self._fit(r.uid, 1, avail - claimed)
+                    if not fits:
+                        self.rows_held_back += 1
+                        continue
+                    claimed += need
                 uids.append(r.uid)
                 chunks.append([r.next_token])
                 r.next_token = None
@@ -278,8 +326,10 @@ class DynamicSplitFuseScheduler:
         for r in live:
             if budget <= 0 or len(uids) >= max_seqs:
                 break
-            if r.prefilling and r.uid not in uids:
+            if r.prefilling:
                 if not r.prefix_checked:
+                    if tight and avail - claimed < 1:
+                        continue    # a prompt that has not begun begins with a block
                     # first time this request is scheduled: ask the engine
                     # for its longest cached prompt prefix — prefill then
                     # starts at the first uncached token (batch positions
@@ -291,7 +341,18 @@ class DynamicSplitFuseScheduler:
                     if match is not None and r.prefill_cursor == 0:
                         r.prefix_cached_tokens = int(match(r.uid, r.prompt))
                         r.prefill_cursor = r.prefix_cached_tokens
+                        if r.prefix_cached_tokens:
+                            # the blocks it leased are no longer the cache's to give
+                            if not tight:   # what the rows placed may claim, at the most
+                                tight = True
+                                claimed = len(uids) + -(-(self.budget - budget) // block)
+                            avail = self._free_blocks()
                 take = min(budget, len(r.prompt) - r.prefill_cursor)
+                if tight:
+                    take, need = self._fit(r.uid, take, avail - claimed)
+                    if take < 1:
+                        continue
+                    claimed += need
                 chunk = r.prompt[r.prefill_cursor:r.prefill_cursor + take]
                 r.prefill_cursor += take
                 r.prefill_steps += 1
@@ -300,6 +361,33 @@ class DynamicSplitFuseScheduler:
                 chunks.append(chunk)
                 budget -= take
         return uids, chunks
+
+    def preempt_for_room(self):
+        """No live row could run: each needs a block, none is free and
+        nothing is in flight that would free one. Give up the request that
+        is cheapest to make again - fewest tokens in the cache, and never
+        one of a higher priority than the rest - and → its ``Request``, off
+        the table, with ``recomputed`` the tokens it had in the cache (its
+        owner runs it again with ``prompt + generated`` as its prompt and
+        what is left of ``max_new_tokens``); its stream holds every token it
+        generated. None where there is nobody to give up: one live request
+        alone always fits the pool it was admitted to."""
+        live = self._live()
+        held = {}
+        for r in live:
+            state = self.engine.query(r.uid)
+            if state is not None and state[0] + state[1] > 0:
+                held[r.uid] = state[0]
+        if len(live) < 2 or not held:
+            return None
+        # _live() is stable: among equals the youngest goes
+        victim = min((r for r in reversed(live) if r.uid in held),
+                     key=lambda r: (r.priority, held[r.uid]))
+        self.hand_over()    # nothing is in flight where no row could be planned
+        victim.recomputed = held[victim.uid]
+        self.engine.flush(victim.uid)
+        del self.requests[victim.uid]
+        return victim
 
     def _ran(self):
         """The engine call just returned: note the seq of the step record
@@ -334,21 +422,30 @@ class DynamicSplitFuseScheduler:
             # token budget too: one decode token per live request per
             # burst step, same bound _plan enforces
             return None
+        states = [self.engine.query(r.uid) for r in rows]
         k = min(self.max_burst,
                 min(r.max_new_tokens - len(r.generated) - r._inflight for r in rows),
-                min(self.engine.max_ctx_tokens - self.engine.query(r.uid)[0]
-                    for r in rows))
+                self.engine.max_ctx_tokens - max(seen for seen, _ in states))
         if k < 2:
             return None
         k = 1 << (k.bit_length() - 1)  # power-of-two bursts: each distinct
         # k compiles its own scan program, so an arbitrary tail (15, 14,
         # 13...) would compile once per value; rounding down bounds the
         # set to log2(max_burst) programs
+        # ... and as long as the pool can reserve up front: k tokens a row less the
+        # room in its last block, in whole blocks
+        free = self._free_blocks()
+        if free < len(rows) * -(-k // self.block_size):
+            room = np.fromiter((room for _, room in states), np.int64, len(states))
+            while k >= 2 and int((-(-np.maximum(k - room, 0) // self.block_size)).sum()) > free:
+                k >>= 1
+            if k < 2:
+                return None
         if not self.engine.can_burst([r.uid for r in rows], k):
-            # KV pool too tight to reserve k tokens per sequence up
-            # front. The stepwise path needs at most one block per
-            # sequence per step and EOS flushes free blocks between
-            # steps, so fall back. (A pre-check, not try/except: a
+            # The engine's own check (its window pool's too): too tight to
+            # reserve k tokens per sequence up front. The stepwise path needs
+            # at most one block per sequence per step and EOS flushes free
+            # blocks between steps, so fall back. (A pre-check, not try/except: a
             # failure inside the compiled burst would land after state
             # mutation + KV donation and is not recoverable.)
             return None

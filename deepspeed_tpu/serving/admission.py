@@ -8,13 +8,14 @@ Two layers sit between ``submit()`` and the engine:
    a strictly higher-priority one), or ``block`` (the submitting thread
    waits for room, bounded by a timeout).
 
-2. :class:`CapacityGate` — KV-block and token-budget accounting. A
-   request is only handed to the scheduler once its *full* footprint
-   (prompt + max_new_tokens, rounded up to KV blocks) fits the pool
-   alongside every other active request's committed footprint, so the
-   engine's "KV pool exhausted" runtime error can never fire mid-flight
-   and wedge the pump. Requests that could never fit — even on an idle
-   engine — are rejected at ``submit()`` with an actionable
+2. :class:`CapacityGate` — KV-block accounting on what requests hold. A
+   request is handed to the scheduler once its *prompt's* blocks fit the
+   pool beside what every live request holds and a small reserve derived
+   from the engine; its answer's blocks are claimed as it grows, and the
+   scheduler fits every step to the blocks that are free, so the engine's
+   "KV pool exhausted" runtime error cannot fire mid-flight and wedge the
+   pump. Requests that could never fit — even on an idle engine — are
+   rejected at ``submit()`` with an actionable
    :class:`RequestTooLargeError` instead of queueing forever.
 """
 
@@ -98,43 +99,63 @@ class GatewayFailedError(ServingError):
 
 # ---------------------------------------------------------------- capacity
 class CapacityGate:
-    """Static feasibility + dynamic KV-block commitment accounting.
+    """Static feasibility + admission on the blocks requests **hold**.
+
+    A request's commitment is what it holds in the pool, and it moves with
+    it: on admission its **prompt's** blocks (``ceil((prompt + 1) /
+    block)``: they are certain, so a prompt is never let into a pool that
+    cannot hold it), nothing for the answer it has not made, and as it
+    generates the blocks it has. The gate keeps no sum of its own for that:
+    it reads the engine's count (``free_blocks`` + ``evictable_blocks``) and
+    takes off the prompt blocks of requests it admitted that the engine has
+    not laid yet (:meth:`headroom`). A request is admitted while the
+    headroom, less its prompt's blocks, stays at or above a **reserve**
+    (:meth:`reserve`): the blocks one engine call is expected to claim,
+    from ``block_size``, ``token_budget``, ``max_burst`` and the live
+    count. Nothing holds back a request when every admitted request's
+    worst case (``prompt + max_new_tokens``) fits beside its own - such a
+    pool cannot run dry - or when nothing is active: :meth:`check_feasible`
+    has refused at ``submit()`` what cannot run alone.
+
+    What makes that safe is the scheduler's half
+    (``DynamicSplitFuseScheduler._plan``): a step is fitted to the blocks
+    that are free - a decode row whose next token opens a block that is not
+    there waits a step, a prompt chunk is cut to the blocks there are - and
+    when no row can run the gateway preempts one request by recompute
+    (``ServingGateway._preempt_for_room``), so ``engine.put`` is never asked
+    for blocks the pool has not got.
 
     ``usable_blocks`` is snapshotted from an idle engine at gateway
-    construction; every admitted request commits its worst-case block
-    footprint until it finishes. Commitment is deliberately conservative
-    (EOS may finish a request early) — the price is a little pool
-    headroom, the payoff is that admission can never over-subscribe the
-    pool and crash the pump mid-step.
+    construction (evictable prefix-cache blocks are reclaimable capacity:
+    a warm cache never shrinks what admission believes the pool can hold).
 
     An engine whose model kind has window layers has a second pool
-    (``engine.window_pool``), and a request is admitted on **both** pools'
-    worst case: in the window pool a sequence holds ``bound(1)`` blocks
-    between steps whatever its length, one more inside a decode step or a
-    short burst, and the rows of one step (the token budget, however the
-    scheduler deals it) add ``ceil(token_budget / block_size)`` over all
-    sequences - kept back from ``usable_window_blocks`` once, not committed
-    a request. ``refused_by`` counts what held a request back, by pool.
+    (``engine.window_pool``), whose commitment is already what a sequence
+    holds: ``bound(1)`` blocks between steps whatever its length, one more
+    inside a decode step or a short burst, and the rows of one step (the
+    token budget, however the scheduler deals it) add ``ceil(token_budget /
+    block_size)`` over all sequences - kept back from
+    ``usable_window_blocks`` once, not committed a request. ``refused_by``
+    counts what held a request back, by pool.
     """
 
-    def __init__(self, engine, token_budget, pool="unified"):
+    def __init__(self, engine, token_budget, pool="unified", max_burst=1):
         # which fleet pool this gate protects ("unified" | "prefill" |
         # "decode") — stamped into every rejection's details so the
         # router can steer (a saturated prefill pool means degrade or
         # re-pool, NOT retry the same gate)
         self.pool = str(pool)
+        self.engine = engine
         self.block_size = int(engine.block_size)
-        # evictable prefix-cache blocks are RECLAIMABLE capacity: the
-        # allocator takes them back (LRU) on demand, so a warm cache must
-        # not shrink what admission believes the pool can hold — caching
-        # trades idle space for hits, never admission headroom
-        self.usable_blocks = int(engine.free_blocks) + \
-            int(getattr(engine, "evictable_blocks", 0))
+        self.usable_blocks = self._engine_blocks()
         self.max_ctx_tokens = int(engine.max_ctx_tokens)
         self.max_tracked = int(engine.state_manager.max_tracked_sequences)
         self.token_budget = int(token_budget)
-        self.committed_blocks = 0
-        self.active = 0  # requests currently holding a commitment
+        self.max_burst = int(max_burst)
+        # uid -> [prompt blocks the engine has yet to lay, worst-case blocks,
+        # window-pool blocks]: every request holding a place
+        self._admitted = {}
+        self.committed_worst = 0
         self.window_pool = getattr(engine, "window_pool", None)
         self.committed_window_blocks = self.usable_window_blocks = 0
         if self.window_pool is not None:
@@ -142,20 +163,62 @@ class CapacityGate:
                 - -(-self.token_budget // self.block_size)
         self.refused_by = {"kv_blocks": 0, "window_blocks": 0, "sequences": 0}
 
+    def _engine_blocks(self):
+        """Blocks the pool can give right now: the free list and what the
+        prefix cache gives back on demand (LRU)."""
+        return int(self.engine.free_blocks) + int(getattr(self.engine, "evictable_blocks", 0))
+
+    @property
+    def active(self):
+        """Requests holding a place."""
+        return len(self._admitted)
+
+    @property
+    def committed_blocks(self):
+        """Prompt blocks of admitted requests that the engine has not laid
+        yet: all the gate holds back beyond the engine's own count."""
+        awaited = 0
+        for uid, held in self._admitted.items():
+            if held[0]:
+                state = self.engine.query(uid)
+                laid = (state[0] + state[1]) // self.block_size if state is not None else 0
+                if laid >= held[0]:
+                    held[0] = 0     # prefilled: what it holds is in the engine's count
+                else:
+                    awaited += held[0] - laid
+        return awaited
+
+    def prompt_blocks(self, prompt_len):
+        """Blocks a prompt and its first token hold."""
+        return -(-(prompt_len + 1) // self.block_size)
+
     def footprint(self, prompt_len, max_new_tokens):
         """Worst-case KV blocks a request will ever hold."""
         return -(-(prompt_len + max_new_tokens) // self.block_size)
 
     def window_footprint(self, prompt_len, max_new_tokens):
-        """Worst-case window-pool blocks a request holds outside a prompt
-        chunk's own rows (0 without such a pool)."""
+        """Window-pool blocks a request holds outside a prompt chunk's own
+        rows (0 without such a pool)."""
         if self.window_pool is None:
             return 0
         return min(self.footprint(prompt_len, max_new_tokens), self.window_pool.bound(1) + 1)
 
+    def reserve(self, live):
+        """Blocks kept free beside ``live`` requests: what one engine call
+        is expected to claim - a prompt step's ``token_budget`` rows, and a
+        burst of ``max_burst`` tokens over every live row."""
+        return -(-self.token_budget // self.block_size) \
+            + -(-live * self.max_burst // self.block_size)
+
+    def headroom(self):
+        """Blocks the pool can give, less the prompt blocks of admitted
+        requests that the engine has not laid yet."""
+        return self._engine_blocks() - self.committed_blocks
+
     def check_feasible(self, prompt_len, max_new_tokens):
         """Raise :class:`RequestTooLargeError` when the request could not
-        run even on an idle engine."""
+        run even on an idle engine (its worst case: nothing is preempted
+        for a request that cannot end alone)."""
         if prompt_len < 1:
             raise RequestTooLargeError("empty prompt can never be scheduled")
         total = prompt_len + max_new_tokens
@@ -182,33 +245,36 @@ class CapacityGate:
                 f"{self.usable_window_blocks} beside one step's rows — raise num_window_blocks",
                 needed_blocks=need, usable_blocks=self.usable_window_blocks, pool=self.pool)
 
-    def try_commit(self, prompt_len, max_new_tokens):
-        """Reserve the request's footprint; False when it doesn't fit
-        right now (caller keeps it queued)."""
-        need = self.footprint(prompt_len, max_new_tokens)
+    def try_commit(self, uid, prompt_len, max_new_tokens, resumed_blocks=0):
+        """Give ``uid`` a place; False when it does not fit right now
+        (caller keeps it queued). ``resumed_blocks``: what a suspended
+        request's resume lays at once (``engine.suspended_blocks``)."""
+        need = max(self.prompt_blocks(prompt_len), int(resumed_blocks))
+        worst = max(self.footprint(prompt_len, max_new_tokens), need)
         need_window = self.window_footprint(prompt_len, max_new_tokens)
-        if self.committed_blocks + need > self.usable_blocks:
+        live = len(self._admitted)
+        if live and self.committed_worst + worst > self.usable_blocks \
+                and self.headroom() - need < self.reserve(live + 1):
             self.refused_by["kv_blocks"] += 1
             return False
         if self.committed_window_blocks + need_window > self.usable_window_blocks:
             self.refused_by["window_blocks"] += 1
             self.window_pool.gate_refused += 1
             return False
-        if self.active + 1 > self.max_tracked:
+        if live + 1 > self.max_tracked:
             self.refused_by["sequences"] += 1
             return False
-        self.committed_blocks += need
+        self._admitted[uid] = [need, worst, need_window]
+        self.committed_worst += worst
         self.committed_window_blocks += need_window
-        self.active += 1
         return True
 
-    def release(self, prompt_len, max_new_tokens):
-        need = self.footprint(prompt_len, max_new_tokens)
-        self.committed_blocks -= need
-        self.committed_window_blocks -= self.window_footprint(prompt_len, max_new_tokens)
-        self.active -= 1
-        assert self.committed_blocks >= 0 and self.active >= 0 \
-            and self.committed_window_blocks >= 0, "capacity release without matching commit"
+    def release(self, uid):
+        """``uid`` ended, was suspended or was preempted: its place is free
+        (the blocks it held are the engine's to count)."""
+        _, worst, need_window = self._admitted.pop(uid)
+        self.committed_worst -= worst
+        self.committed_window_blocks -= need_window
 
 
 # ---------------------------------------------------------------- wait queue
@@ -287,6 +353,14 @@ class AdmissionQueue:
             entry._depth_at_enqueue = len(self._entries)
             self._arrived.notify_all()
             return None
+
+    def push_front(self, entry):
+        """Put back, at the head of its priority level, a request the
+        gateway had admitted and preempted for room: it was accepted long
+        ago, so neither ``max_depth`` nor a closed queue refuses it."""
+        with self._lock:
+            self._entries.insert(0, entry)
+            self._arrived.notify_all()
 
     def candidates(self):
         """Snapshot in scheduling order: highest priority first, FIFO

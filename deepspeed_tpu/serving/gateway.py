@@ -21,10 +21,16 @@ finished request, its gate commitment - is done in the pass that accepted
 its last token (``_retire``).
 
 Admission is KV-block aware (:class:`CapacityGate`): a request enters
-the scheduler only when its full worst-case footprint fits the pool next
-to every other active request, so the engine's "KV pool exhausted" error
-can never wedge the pump. Higher-priority requests may *preempt* running
-lower-priority ones (KV suspended to host via ``engine.suspend``,
+the scheduler when its prompt's blocks fit the pool beside what every
+live request holds and a small reserve; its answer's blocks are claimed as
+it grows. The scheduler fits every step to the blocks that are free (a
+decode row that needs a block the pool has not got waits a step), so the
+engine's "KV pool exhausted" error cannot wedge the pump; and when no row
+can run, the request that is cheapest to make again is **preempted by
+recompute** (``_preempt_for_room``): flushed, back at the head of the
+queue with ``prompt + generated`` as its prompt, its stream unbroken.
+Higher-priority requests may also *preempt* running lower-priority ones
+(``allow_preemption``: KV suspended to host via ``engine.suspend``,
 resumed when the pool has room again).
 
 Lifecycle: ``drain()`` stops admission, finishes everything in flight,
@@ -224,6 +230,7 @@ class ServingGateway:
         # since the last kept pass
         self._last_engine_rec = None
         self._waited_ns = self._idle_passes = 0
+        self._rows_held_back = 0    # the scheduler's count, as last read
         # disaggregated serving: a "prefill" gateway exports a KV
         # handoff record into a bounded outbox when a request finishes;
         # the fleet router claims it via take_handoff() and delivers it
@@ -232,7 +239,8 @@ class ServingGateway:
         self._handoffs = OrderedDict()   # uid -> exported handoff record
         self._handoff_lock = tracked_lock(threading.Lock(),
                                           "ServingGateway._handoff_lock")
-        self.gate = CapacityGate(engine, self.scheduler.budget, pool=cfg.role)
+        self.gate = CapacityGate(engine, self.scheduler.budget, pool=cfg.role,
+                                 max_burst=self.scheduler.max_burst)
         self.queue = AdmissionQueue(cfg.max_queue_depth, cfg.admission_policy,
                                     cfg.block_timeout_s)
         self._uids = itertools.count()
@@ -548,6 +556,8 @@ class ServingGateway:
         yet, so nothing can double-emit). Returns the number shed."""
         n = 0
         for entry in self.queue.candidates():
+            if entry._collected:
+                continue    # preempted for room with part of its answer sent: it ends here
             if self.queue.remove(entry) and self._end(entry, "failed", error):
                 n += 1
         return n
@@ -939,30 +949,34 @@ class ServingGateway:
         return self._end(handle, status, error(), counter, request)
 
     def _release(self, handle):
-        self.gate.release(len(handle.prompt), handle.max_new_tokens)
+        self.gate.release(handle.uid)
         self._active.pop(handle.uid, None)
         if handle.uid in self._paused:
             self._paused.remove(handle.uid)
 
     def _admit(self):
         """Move queued requests into the scheduler, highest priority
-        first, while their full KV footprint fits; optionally preempt
-        lower-priority running requests for the head of the queue."""
+        first, while the gate has room for their prompts; optionally preempt
+        lower-priority running requests for the head of the queue. A request
+        that was preempted for room comes back with what it generated as
+        the end of its prompt, and the rest of its answer to make."""
         did = False
         for entry in self.queue.candidates():
-            plen, max_new = len(entry.prompt), entry.max_new_tokens
-            while not self.gate.try_commit(plen, max_new):
+            uid, made = entry.uid, entry._collected
+            prompt = entry.prompt + made if made else entry.prompt
+            max_new = entry.max_new_tokens - len(made)
+            while not self.gate.try_commit(uid, len(prompt), max_new):
                 if not self.config.allow_preemption or not self._preempt_for(entry):
                     return did  # strict priority order: no skip-ahead
             if not self.queue.remove(entry):  # cancelled concurrently
-                self.gate.release(plen, max_new)
+                self.gate.release(uid)
                 continue
             if entry.done:  # shed/failed between snapshot and now
-                self.gate.release(plen, max_new)
+                self.gate.release(uid)
                 continue
             schema = getattr(entry, "schema", None)
             try:
-                self.scheduler.add_request(entry.uid, entry.prompt,
+                self.scheduler.add_request(uid, prompt,
                                            max_new_tokens=max_new,
                                            priority=entry.priority,
                                            spec=getattr(entry, "spec", True),
@@ -970,6 +984,9 @@ class ServingGateway:
                                                               None),
                                            sample=getattr(entry, "sample", None),
                                            schema=schema)
+                if schema is not None:
+                    for token in made:  # the DFA stands where the stream does
+                        self.engine.advance_schema(uid, token)
             except Exception as e:
                 from deepspeed_tpu.serving.admission import ServingError
                 # schema bind failures (every DFA slot leased by a live
@@ -982,7 +999,7 @@ class ServingGateway:
                 # with leased slots, publication vanished): fail THIS
                 # request with the retryable error instead of killing
                 # the pump — the fleet router fails it over
-                self.gate.release(plen, max_new)
+                self.gate.release(uid)
                 self._end(entry, "failed", e, "rejected_schema" if schema is not None
                           else "rejected_adapter")
                 did = True
@@ -992,7 +1009,7 @@ class ServingGateway:
             entry.queue_wait_s = (entry.admitted_ns - entry.submitted_ns) / 1e9
             self.metrics.observe_queue_wait(entry.queue_wait_s)
             self.metrics.count("admitted")
-            self._active[entry.uid] = entry
+            self._active[uid] = entry
             did = True
         return did
 
@@ -1013,7 +1030,7 @@ class ServingGateway:
             # victim already finished — nothing left to preempt; the
             # normal finish path releases its gate tokens
             return False
-        self.gate.release(len(handle.prompt), handle.max_new_tokens)
+        self.gate.release(uid)
         self._paused.append(uid)
         self.metrics.count("preemptions")
         logger.info(f"serving: preempted request {uid} (priority "
@@ -1028,7 +1045,9 @@ class ServingGateway:
         did = False
         for uid in sorted(self._paused, key=lambda u: -self._active[u].priority):
             handle = self._active[uid]
-            if not self.gate.try_commit(len(handle.prompt), handle.max_new_tokens):
+            resumed = self.engine.suspended_blocks(uid) if self.engine.is_suspended(uid) else 0
+            if not self.gate.try_commit(uid, len(handle.prompt), handle.max_new_tokens,
+                                        resumed_blocks=resumed):
                 break
             self.scheduler.unpause(uid)
             self._paused.remove(uid)
@@ -1052,8 +1071,12 @@ class ServingGateway:
             self.metrics.count("prompt_steps")
             if rec.n_rows < self.engine.max_tokens:
                 self.metrics.count("prompt_steps_on_rung")
+        held_back = self.scheduler.rows_held_back
+        if held_back != self._rows_held_back:
+            self.metrics.count("rows_held_back", held_back - self._rows_held_back)
+            self._rows_held_back = held_back
         ended, self.scheduler.ended = self.scheduler.ended, []
-        if not stepped and not ended:
+        if not stepped and not ended and not self._preempt_for_room():
             # every live request is schedulable yet nothing ran — a real
             # stall would spin the pump forever; fail fast instead
             raise RuntimeError(
@@ -1062,6 +1085,29 @@ class ServingGateway:
             for uid in ended:
                 self._retire(uid)
         return True, True
+
+    def _preempt_for_room(self):
+        """The last resort, for every state kind: no live row could run
+        (each needs a block, none is free, nothing is ending). The scheduler
+        gives up the request that is cheapest to make again
+        (``preempt_for_room``: flushed, no ``engine.suspend``); its place at
+        the gate is released and it goes back to the head of the queue, to
+        be admitted again (``_admit``) with ``prompt + generated`` as its
+        prompt and what is left of ``max_new_tokens``. Its stream keeps what
+        it was sent and continues; a sampled request keeps its seed, so its
+        tokens depend on (seed, position) as before. False: nobody to give up."""
+        request = self.scheduler.preempt_for_room()
+        if request is None:
+            return False
+        handle = self._active[request.uid]
+        self._release(handle)
+        handle.status = "queued"
+        self.queue.push_front(handle)
+        self.metrics.count("preempted_for_room")
+        self.metrics.count("recomputed_tokens", request.recomputed)
+        logger.info(f"serving: preempted request {request.uid} for room after "
+                    f"{len(handle._collected)} tokens ({request.recomputed} to recompute)")
+        return True
 
     def _retire(self, uid):
         """A request's last token was accepted: give its room back now, in

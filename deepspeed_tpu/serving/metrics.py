@@ -138,6 +138,9 @@ class ServingMetrics:
                 # of engine_steps: a put of more than max_seqs tokens (it carried a
                 # prompt), and those that ran below the budget-sized program
                 "prompt_steps", "prompt_steps_on_rung",
+                # a pool run dry: decode rows that waited a step for a block, requests
+                # preempted by recompute, and the tokens they had in the cache
+                "rows_held_back", "preempted_for_room", "recomputed_tokens",
                 "handoffs_exported", "handoffs_imported",
                 "weight_refreshes", "rejected_unknown_adapter",
                 "rejected_adapter",

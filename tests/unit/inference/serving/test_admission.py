@@ -25,7 +25,10 @@ from deepspeed_tpu.serving import (CapacityGate, DeadlineExceededError,
 
 class FakeEngine:
     """InferenceEngineV2 stand-in: real bookkeeping surface (put/query/
-    flush/suspend/resume/destroy), deterministic token arithmetic."""
+    flush/suspend/resume/destroy), deterministic token arithmetic. Its pool
+    is kept like the engine's: a sequence holds ``ceil(seen / block_size)``
+    of ``free_blocks`` blocks, and a ``put`` that asks for more than are
+    free raises the engine's error."""
 
     def __init__(self, max_tokens=64, max_seqs=8, block_size=8,
                  max_ctx_tokens=64, free_blocks=16, max_tracked=8):
@@ -33,7 +36,7 @@ class FakeEngine:
         self.max_seqs = max_seqs
         self.block_size = block_size
         self.max_ctx_tokens = max_ctx_tokens
-        self.free_blocks = free_blocks
+        self.total_blocks = free_blocks
         self.state_manager = types.SimpleNamespace(
             max_tracked_sequences=max_tracked)
         self._seen = {}       # uid -> tokens ingested
@@ -45,7 +48,19 @@ class FakeEngine:
         """The deterministic stream ``put`` produces for a request."""
         return [(uid * 7 + prompt_len + i) % 97 for i in range(n)]
 
+    def _blocks(self, seen):
+        return -(-seen // self.block_size)
+
+    @property
+    def free_blocks(self):
+        return self.total_blocks - sum(map(self._blocks, self._seen.values()))
+
     def put(self, uids, chunks, sample=None):
+        need = sum(self._blocks(self._seen.get(uid, 0) + len(toks))
+                   - self._blocks(self._seen.get(uid, 0)) for uid, toks in zip(uids, chunks))
+        if need > self.free_blocks:
+            raise RuntimeError(f"KV pool exhausted: need {need} blocks, "
+                               f"{self.free_blocks} reclaimable")
         out = []
         for uid, toks in zip(uids, chunks):
             self._seen[uid] = self._seen.get(uid, 0) + len(toks)
@@ -55,7 +70,8 @@ class FakeEngine:
     def query(self, uid):
         if uid not in self._seen:
             return None
-        return self._seen[uid], self.block_size
+        seen = self._seen[uid]
+        return seen, self._blocks(seen) * self.block_size - seen
 
     def flush(self, uid):
         suspended = self._suspended.pop(uid, None) is not None
@@ -69,6 +85,9 @@ class FakeEngine:
 
     def is_suspended(self, uid):
         return uid in self._suspended
+
+    def suspended_blocks(self, uid):
+        return -(-self._suspended[uid] // self.block_size)
 
     def resume(self, uid):
         self._seen[uid] = self._suspended.pop(uid)
@@ -98,18 +117,40 @@ def pump_until(gw, cond, n=200):
 class TestCapacityGate:
 
     def test_footprint_and_commit_accounting(self):
-        gate = CapacityGate(FakeEngine(block_size=8, free_blocks=4), 64)
+        """A request commits its prompt's blocks, then what it holds: the
+        gate reads the engine's count less the prompt blocks not laid yet."""
+        engine = FakeEngine(block_size=8, free_blocks=8)
+        gate = CapacityGate(engine, 8)                     # a reserve of 1 block + the burst's
         assert gate.footprint(8, 8) == 2 and gate.footprint(9, 8) == 3
-        assert gate.try_commit(8, 8) and gate.committed_blocks == 2
-        assert gate.try_commit(8, 8) and gate.committed_blocks == 4
-        assert not gate.try_commit(1, 1)  # pool committed out
-        gate.release(8, 8)
-        assert gate.try_commit(1, 1)
+        assert gate.prompt_blocks(7) == 1 and gate.prompt_blocks(8) == 2
+        assert gate.reserve(3) == 1 + 1 and CapacityGate(engine, 64, max_burst=16).reserve(3) == 8 + 6
+        assert gate.try_commit(0, 15, 40) and gate.committed_blocks == 2     # worst case 7
+        assert gate.headroom() == 6 and gate.active == 1
+        engine.put([0], [list(range(10))])                 # two blocks laid: the engine counts them
+        assert engine.free_blocks == 6 and gate.headroom() == 6 and gate.committed_blocks == 0
+        assert gate.try_commit(1, 15, 40) and gate.committed_blocks == 2     # 14 > 8 at the worst
+        assert gate.try_commit(2, 7, 40) and gate.headroom() == 3
+        assert not gate.try_commit(3, 8, 40)               # 3 - 2 is under the reserve of four live
+        assert gate.refused_by == {"kv_blocks": 1, "window_blocks": 0, "sequences": 0}
+        gate.release(1)
+        assert gate.try_commit(3, 8, 40)
+        for uid in (0, 2, 3):
+            gate.release(uid)
+        assert gate.active == 0 and gate.committed_blocks == 0 and gate.committed_worst == 0
+        assert gate.headroom() == engine.free_blocks == 6
+
+    def test_worst_cases_that_fit_and_a_first_request_meet_no_reserve(self):
+        gate = CapacityGate(FakeEngine(block_size=8, free_blocks=4), 64, max_burst=16)
+        assert gate.reserve(1) == 8 + 2                    # more than the pool
+        assert gate.try_commit(0, 8, 8) and gate.try_commit(1, 8, 8)         # 2 + 2 of 4
+        assert not gate.try_commit(2, 1, 1)                # beyond the worst cases: the reserve
+        gate.release(0), gate.release(1)
+        assert gate.try_commit(2, 20, 12)                  # alone: check_feasible let it in
 
     def test_max_tracked_bounds_admission(self):
         gate = CapacityGate(FakeEngine(free_blocks=100, max_tracked=1), 64)
-        assert gate.try_commit(1, 1)
-        assert not gate.try_commit(1, 1)  # blocks free, but tracking full
+        assert gate.try_commit(0, 1, 1)
+        assert not gate.try_commit(1, 1, 1)  # blocks free, but tracking full
 
     def test_feasibility_errors_are_actionable(self):
         gate = CapacityGate(FakeEngine(max_ctx_tokens=64, free_blocks=4), 64)

@@ -145,7 +145,8 @@ def test_cancel_mid_decode_frees_blocks(model_and_params):
     with pytest.raises(RequestCancelledError):
         h.result(timeout=5)
     assert int(engine.free_blocks) == free0  # cancelled KV released
-    assert gw.gate.committed_blocks == 0
+    # nothing held once every request has ended: no place, no prompt awaited, no worst case
+    assert gw.gate.active == 0 and gw.gate.committed_blocks == 0 == gw.gate.committed_worst
     # the gateway keeps serving after a cancellation
     h2 = gw.submit(np.arange(6, dtype=np.int32), max_new_tokens=2)
     for _ in range(8):
@@ -213,7 +214,8 @@ def test_drain_finishes_queued_and_inflight(model_and_params):
     assert all(h.status == "completed" for h in handles)
     assert all(len(h.result(timeout=1)) == 3 for h in handles)
     assert gw.state == "stopped" and engine.kv_cache is None
-    assert gw.gate.committed_blocks == 0
+    # nothing held once every request has ended: no place, no prompt awaited, no worst case
+    assert gw.gate.active == 0 and gw.gate.committed_blocks == 0 == gw.gate.committed_worst
     snap = gw.snapshot()
     assert snap["counters"]["completed"] == 6
     assert snap["gauges"]["kv_free_blocks"] == free0  # last observed: idle

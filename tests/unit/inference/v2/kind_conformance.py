@@ -221,6 +221,64 @@ class Served:
         assert {"burst", "put"} <= {r["kind"] for r in records}
         assert all(r["counts"] is not None for r in records if r["kind"] in ("burst", "put"))
 
+    def test_a_pool_run_dry_preempts_by_recompute_and_every_stream_stands(self, case, engine,
+                                                                          tokens):
+        """The gate lets a request in on its prompt's blocks; here into a
+        pool of the prompts' blocks and not one more (the gate's reserve
+        taken away: the scheduler's half must hold alone), each prompt a
+        token short of its last block's end. After one decode step every row
+        needs a block that is not there: rows wait, and one request at a time is given up,
+        flushed - blocks, slot, window ring - and made again from its tokens.
+        No ``put`` is asked for a block the pool has not got, and each greedy
+        stream is the one the request gives with the pool to itself."""
+        from deepspeed_tpu.serving import ServingConfig, ServingGateway
+        new, block = case.gateway.new, case.block
+        prompts = [tokens[r][:-(-(n + 1) // block) * block - 1] for r, n in case.gateway.prompts]
+        # one step holds every prompt, so that the rows reach their blocks' ends together
+        rows = -(-sum(len(p) for p in prompts) // case.rows) * case.rows
+        served = second_engine(case, engine, rows=rows,
+                               blocks=1 + sum(-(-(len(p) + 1) // block) for p in prompts))
+        gateway = ServingGateway(served, auto_start=False, config=ServingConfig(
+            default_max_new_tokens=new, max_burst=4))
+        gateway.gate.reserve = lambda live: 0
+
+        def streams(handles):
+            for _ in range(600):
+                if all(h.done for h in handles):
+                    return [[int(t) for t in h.result(timeout=1)] for h in handles]
+                gateway._pump_once()
+            raise AssertionError("the requests did not end")
+
+        seen_at, preempt = [], gateway.scheduler.preempt_for_room
+
+        def watched():
+            seen = {uid: served.query(uid)[0] for uid in gateway._active}
+            request = preempt()
+            seen_at.append(seen[request.uid])
+            assert request.recomputed == seen_at[-1] >= block
+            return request
+        gateway.scheduler.preempt_for_room = watched
+        watch = self.around_the_traffic(gateway, served)
+        try:
+            next(watch)
+            alone = [streams([gateway.submit(p)])[0] for p in prompts]
+            assert not seen_at and gateway.snapshot()["counters"]["rows_held_back"] == 0
+            handles = [gateway.submit(p) for p in prompts]
+            gateway._pump_once()
+            assert gateway.gate.active == len(prompts) and gateway.gate.headroom() == 0
+            together = streams(handles)
+            counters = gateway.snapshot()["counters"]
+            assert gateway.gate.active == 0 and gateway.gate.committed_blocks == 0
+            next(watch)
+        finally:
+            gateway.shutdown()
+        assert next(watch, None) is None
+        assert together == alone and all(len(s) == new for s in together)
+        assert counters["rows_held_back"] >= len(prompts) and counters["failed"] == 0
+        assert counters["preempted_for_room"] == len(seen_at) >= 1
+        assert counters["recomputed_tokens"] == sum(seen_at)
+        assert counters["completed"] == 2 * len(prompts)
+
     def alone(self, engine, uid, prompt, new, rows):
         """The greedy stream of ``new`` tokens that ``engine`` gives ``prompt``
         by itself: chunks of ``rows``, then a row a step."""

@@ -253,10 +253,11 @@ class TestServing(conformance.ChunkCuts, conformance.NotKV):
 
     def around_the_traffic(self, gateway, served):
         pool = served.window_pool
-        assert gateway.gate.usable_window_blocks == pool.free_blocks - CASE.rows // BLOCK
+        step_rows = served.max_tokens // BLOCK          # one step's rows, kept back once
+        assert gateway.gate.usable_window_blocks == pool.free_blocks - step_rows
         yield
         external = gateway.snapshot()["external"]["Serve/WindowPool"]
         yield
         assert pool.in_use == 0 and pool.released > 0          # every block came back
-        assert pool.high_water <= 3 * (pool.bound(1) + 1) + CASE.rows // BLOCK
+        assert pool.high_water <= 3 * (pool.bound(1) + 1) + step_rows
         assert external["released"] > 0 and external["gate_refused_by_window_blocks"] == 0

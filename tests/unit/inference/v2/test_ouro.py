@@ -285,11 +285,14 @@ def test_the_pool_is_r_times_l_layers_deep_and_whoever_sizes_it_counts_them(engi
     assert engine.state_bytes_per_token == cache.bytes_per_token() == R * L * 2 * width * 4
     assert cache.bytes() == 64 * BLOCK * engine.state_bytes_per_token
     assert engine.params["model"]["layers"]["mlp"]["up_proj"]["kernel"].shape[0] == L
-    # the gate commits a request's worst case in blocks of R L layers each: the blocks are the
-    # allocator's, whatever their depth
+    # the gate commits a request's prompt, and refuses what cannot run alone, in blocks of
+    # R L layers each: the blocks are the allocator's, whatever their depth
     from deepspeed_tpu.serving.admission import CapacityGate
     gate = CapacityGate(engine, engine.max_tokens)
-    assert gate.footprint(33, 20) == -(-53 // BLOCK)
+    assert gate.prompt_blocks(33) == -(-34 // BLOCK) and gate.footprint(33, 20) == -(-53 // BLOCK)
+    assert gate.try_commit(1, 33, 20) and gate.committed_blocks == gate.prompt_blocks(33)
+    assert gate.headroom() == gate.usable_blocks - gate.prompt_blocks(33)
+    gate.release(1)
     assert gate.usable_blocks * BLOCK * engine.state_bytes_per_token <= cache.bytes()
 
 

@@ -4,8 +4,6 @@ allocator and the state manager's ring table (``ragged/kv_cache.WindowPool``,
 reserve, gather, advance, release behind - and the admission gate on either
 pool (``serving/admission.CapacityGate``)."""
 
-import types
-
 import numpy as np
 import pytest
 
@@ -124,29 +122,50 @@ def test_release_unused_blocks_keeps_both_tables():
     assert len(desc.blocks) == len(desc.window_blocks) == 2 and pool.in_use == 2
 
 
-def _engine(sm, pool, budget=BUDGET):
-    return types.SimpleNamespace(block_size=BS, free_blocks=sm.kv_cache.free_blocks,
-                                 max_ctx_tokens=17408, state_manager=sm, window_pool=pool)
+class _engine:
+    """What the gate reads of an engine: the full pool's count as it stands,
+    and what a sequence has laid."""
+    block_size, max_ctx_tokens = BS, 17408
+
+    def __init__(self, sm, pool):
+        self.state_manager, self.window_pool = sm, pool
+
+    @property
+    def free_blocks(self):
+        return self.state_manager.kv_cache.free_blocks
+
+    def query(self, uid):
+        desc = self.state_manager.query(uid)
+        return desc and (desc.seen_tokens, len(desc.blocks) * BS - desc.seen_tokens)
 
 
-def test_the_gate_admits_on_either_pools_worst_case():
+def test_the_gate_admits_on_what_a_sequence_holds_in_either_pool():
+    """The window pool's commitment is what a sequence holds there already
+    (``bound(1) + 1`` at any length) and stays; the full pool's is the
+    prompt's blocks, then what the engine says the sequence has laid."""
     sm, pool = manager(window_blocks=1 + 8 + 2 * 10, tracked=8)
     gate = CapacityGate(_engine(sm, pool), BUDGET)
     assert gate.usable_window_blocks == 20 and gate.window_footprint(6000, 400) == 10
     assert gate.window_footprint(100, 28) == 2                  # a short request holds what it is
-    assert gate.try_commit(6000, 400) and gate.try_commit(600, 400)
-    assert not gate.try_commit(900, 100)                        # the window pool refuses
+    assert gate.try_commit(1, 6000, 400) and gate.try_commit(2, 600, 400)
+    assert gate.committed_blocks == 94 + 10 and gate.committed_worst == 100 + 16
+    assert not gate.try_commit(3, 900, 100)                     # the window pool refuses
     assert gate.refused_by == {"kv_blocks": 0, "window_blocks": 1, "sequences": 0}
     assert pool.gate_refused == 1
-    gate.release(600, 400)
-    assert gate.try_commit(900, 100)
-    gate.release(900, 100), gate.release(6000, 400)
+    gate.release(2)
+    assert gate.try_commit(3, 900, 100)
+    gate.release(3), gate.release(1)
     assert gate.committed_window_blocks == gate.committed_blocks == gate.active == 0
-    # the full pool refuses as it did
+    # the full pool lets in two prompts whose worst cases (100 + 100 of 199) kept the
+    # second out, and refuses what would leave less than the reserve
     sm, pool = manager(window_blocks=512, tracked=8, blocks=200)
     gate = CapacityGate(_engine(sm, pool), BUDGET)
-    assert gate.try_commit(6000, 400) and not gate.try_commit(6000, 400)
+    assert gate.usable_blocks == 199 and gate.reserve(3) == 8 + 1
+    assert gate.try_commit(1, 6000, 400) and gate.try_commit(2, 6000, 400)         # 94 + 94
+    assert not gate.try_commit(3, 600, 400)                                        # + 10 + 9
     assert gate.refused_by["kv_blocks"] == 1 and pool.gate_refused == 0
+    step(sm, 1, 512)                            # what a sequence has laid the engine counts
+    assert gate.committed_blocks == 86 + 94 and gate.headroom() == 199 - 8 - 86 - 94
     with pytest.raises(RequestTooLargeError, match="KV blocks"):
         gate.check_feasible(16000, 1000)
     small = CapacityGate(_engine(*manager(window_blocks=1 + 8 + 5)), BUDGET)
@@ -160,8 +179,8 @@ def test_what_the_gate_admits_a_steps_rows_fit():
     tracked = 6
     sm, pool = manager(window_blocks=1 + 8 + tracked * 10, tracked=tracked)
     gate = CapacityGate(_engine(sm, pool), BUDGET)
-    assert all(gate.try_commit(3000, 1000) for _ in range(tracked))
-    assert not gate.try_commit(3000, 1000)
+    assert all(gate.try_commit(uid, 3000, 1000) for uid in range(tracked))
+    assert not gate.try_commit(tracked, 3000, 1000)
     rng = np.random.RandomState(0)
     for uid in range(tracked):
         step(sm, uid, 512)
