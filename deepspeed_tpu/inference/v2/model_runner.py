@@ -615,6 +615,9 @@ class LlamaKind(ModelKind):
     @staticmethod
     def layers(params, cfg, h, batch, dtype, mesh, attn_impl, lora, layer_ids):
         """→ (h, leading [(step, xs), ...], scan step, scan xs)."""
+        if cfg.layer_types or cfg.sliding_window:
+            raise NotImplementedError("the Llama family's layer_types / sliding_window are the "
+                                      "training block's (models/llama.py); not served")
         cos, sin = map(jnp.asarray, rope_frequencies(cfg.head_dim, cfg.max_position_embeddings,
                                                      cfg.rope_theta, scaling=rope_scaling_of(cfg)))
         lora_ctx = None

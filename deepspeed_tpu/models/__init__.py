@@ -26,6 +26,8 @@ from deepspeed_tpu.models.laguna import (LAGUNA_CONFIGS, LagunaConfig, LagunaFor
                                          build_laguna)  # noqa: F401
 from deepspeed_tpu.models.ouro import (OURO_CONFIGS, OuroConfig, OuroForCausalLM,
                                        build_ouro)  # noqa: F401
+from deepspeed_tpu.models.mellum import (MELLUM_CONFIGS, MellumConfig,
+                                         build_mellum)  # noqa: F401  (trained, not served)
 
 # The causal-LM families a preset name can build, in the order names are looked up
 # (the v2 serving engine takes any of them: inference/v2/model_runner.kind_of).

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.linear.quant_dense import QuantDense
+from deepspeed_tpu.moe.sharded_moe import STEP_COUNTS
 
 from deepspeed_tpu.ops.pallas import spec_divides as _spec_divides
 from deepspeed_tpu.sequence.layer import (constrain, constrain_hidden, head_to_seq_shard, heads_spec,
@@ -56,6 +57,22 @@ class LlamaConfig:
     rope_low_freq_factor: float = 1.0
     rope_high_freq_factor: float = 4.0
     rope_original_max_position: int = 8192
+    # "yarn" (the transformers library's ``_compute_yarn_parameters``) reads the factor and
+    # the original length above and these; cos and sin are multiplied by the attention
+    # factor (0: ``0.1 ln(factor) + 1``)
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_attention_factor: float = 0.0
+    # the kinds of layer (``layer_types``) the rescaling applies to; () = every layer. A
+    # layer of another kind rotates by the plain ``rope_theta`` table.
+    rope_scaling_kinds: tuple = ()
+    # A kind a layer (HF ``layer_types``): "full_attention" | "sliding_attention"; () = every
+    # layer alike - windowed if ``sliding_window`` is set (Mistral v0.1), else full. A
+    # sliding layer's query attends its ``sliding_window`` newest keys, itself among them.
+    # Layers of both kinds are one scanned body that is handed its kind a layer: both
+    # attentions are in the program once, and a layer's parameters lie in the one stack.
+    layer_types: tuple = ()
+    sliding_window: int = 0
     tie_word_embeddings: bool = False
     # Qwen2-style QKV biases (Llama/Mistral/Mixtral: False)
     attention_bias: bool = False
@@ -91,6 +108,8 @@ class LlamaConfig:
     offload_params: bool = False
     # MoE (0 = dense)
     moe_num_experts: int = 0
+    # an expert's width (0 = ``intermediate_size``)
+    moe_intermediate_size: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_loss_coef: float = 0.01
@@ -106,6 +125,39 @@ class LlamaConfig:
     @property
     def head_dim(self):
         return self.head_dim_override or self.hidden_size // self.num_attention_heads
+
+    @property
+    def layer_kinds(self):
+        """``layer_types`` checked, where its layers are not all alike (kinds
+        of attention, or a rescaling some kinds rotate by): the stack then
+        hands every layer its kind; () where one static kind serves them all."""
+        kinds = tuple(self.layer_types)
+        if not kinds:
+            return ()
+        if len(kinds) != self.num_hidden_layers:
+            raise ValueError(f"layer_types names {len(kinds)} layers, num_hidden_layers "
+                             f"{self.num_hidden_layers}")
+        unknown = set(kinds) - {FULL, SLIDING}
+        if unknown:
+            raise ValueError(f"layer_types {sorted(unknown)}: {FULL!r} | {SLIDING!r}")
+        if SLIDING in kinds and self.sliding_window < 1:
+            raise ValueError("a sliding_attention layer needs sliding_window")
+        return kinds if len(set(kinds)) > 1 else ()
+
+    @property
+    def uniform_kind(self):
+        """The one kind of every layer where ``layer_types`` names one."""
+        kinds = tuple(self.layer_types)
+        return kinds[0] if kinds and len(set(kinds)) == 1 else None
+
+    def window_of(self, kind):
+        """The window of a layer of ``kind`` (None: none)."""
+        if kind == SLIDING or (not self.layer_types and self.sliding_window > 0):
+            return self.sliding_window
+        return None
+
+
+FULL, SLIDING = "full_attention", "sliding_attention"
 
 
 # Named presets (tiny ones drive tests/bench; large ones mirror the
@@ -185,14 +237,22 @@ class RMSNorm(nn.Module):
 
 
 def rope_frequencies(head_dim: int, max_len: int, theta: float, scaling=None):
-    """cos/sin tables [T, D/2]. ``scaling``: None, ("linear", factor), or
+    """cos/sin tables [T, D/2]. ``scaling``: None, ("linear", factor),
     ("llama3", factor, low_freq_factor, high_freq_factor, orig_max) —
     the Llama-3.x wavelength-dependent inv_freq rescale (long wavelengths
-    divided by ``factor``, short kept, smooth ramp between)."""
+    divided by ``factor``, short kept, smooth ramp between) — or ("yarn",
+    factor, orig_max, beta_fast, beta_slow, attention_factor): YaRN's
+    frequencies (``models/laguna.yarn_inv_freq``), cos and sin both times
+    the attention factor."""
     inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
+    gain = 1.0
     if scaling is not None and scaling[0] != "none":
         kind = scaling[0]
-        if kind == "linear":
+        if kind == "yarn":
+            from deepspeed_tpu.models.laguna import yarn_inv_freq
+            _, factor, orig_max, beta_fast, beta_slow, gain = scaling
+            inv_freq = yarn_inv_freq(head_dim, theta, factor, orig_max, beta_fast, beta_slow)
+        elif kind == "linear":
             inv_freq = inv_freq / scaling[1]
         elif kind == "llama3":
             _, factor, low_f, high_f, orig_max = scaling
@@ -207,21 +267,30 @@ def rope_frequencies(head_dim: int, max_len: int, theta: float, scaling=None):
             raise ValueError(f"unknown rope scaling {kind!r}")
     t = np.arange(max_len, dtype=np.float32)
     freqs = np.outer(t, inv_freq)  # [T, D/2]
+    if gain != 1.0:
+        return (np.cos(freqs) * gain).astype(np.float32), (np.sin(freqs) * gain).astype(np.float32)
     return np.cos(freqs), np.sin(freqs)
 
 
-def rope_scaling_of(cfg):
-    """Config → the ``scaling`` tuple ``rope_frequencies`` takes."""
+def rope_scaling_of(cfg, layer_kind=None):
+    """Config → the ``scaling`` tuple ``rope_frequencies`` takes, for a
+    layer of ``layer_kind`` where the config rescales some kinds only."""
     kind = getattr(cfg, "rope_scaling_type", "none")
-    if kind == "none":
+    kinds = getattr(cfg, "rope_scaling_kinds", ())
+    if kind == "none" or (kinds and layer_kind not in kinds):
         return None
+    if kind == "yarn":
+        import math
+        gain = cfg.rope_attention_factor or 0.1 * math.log(cfg.rope_scaling_factor) + 1.0
+        return ("yarn", cfg.rope_scaling_factor, cfg.rope_original_max_position,
+                cfg.rope_yarn_beta_fast, cfg.rope_yarn_beta_slow, gain)
     if kind == "linear":
         return ("linear", cfg.rope_scaling_factor)
     if kind == "llama3":
         return ("llama3", cfg.rope_scaling_factor, cfg.rope_low_freq_factor,
                 cfg.rope_high_freq_factor, cfg.rope_original_max_position)
     raise ValueError(f"unknown rope_scaling_type {kind!r}: expected 'none', 'linear', "
-                     f"or 'llama3'")
+                     f"'llama3' or 'yarn'")
 
 
 def apply_rope(x, cos, sin, positions):
@@ -241,11 +310,12 @@ def repeat_kv(k, v, n_rep: int):
     return jnp.repeat(k, n_rep, axis=-2), jnp.repeat(v, n_rep, axis=-2)
 
 
-def einsum_attention(q, k, v, causal=True, bias=None, mask=None):
+def einsum_attention(q, k, v, causal=True, bias=None, mask=None, window=None):
     """Reference attention: [B, S, H, D] → [B, S, H, D]; softmax in fp32.
 
     ``mask``: optional [.., Sq, Sk] bool (True = attend), e.g. the
     KV-cache validity mask during decode; overrides ``causal``.
+    ``window`` (with ``causal``): the newest keys a query attends.
     """
     dtype = q.dtype
     scale = 1.0 / np.sqrt(q.shape[-1])
@@ -257,15 +327,18 @@ def einsum_attention(q, k, v, causal=True, bias=None, mask=None):
     elif causal:
         sq, sk = scores.shape[-2], scores.shape[-1]
         cmask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            cmask = cmask & ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         scores = jnp.where(cmask, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _local_attention(q, k, v, impl: str, causal=True):
+def _local_attention(q, k, v, impl: str, causal=True, window=None):
     """``impl``: "einsum", "flash" (a pin: the Pallas kernels run or this
     raises — never the XLA reference under the kernel's name), or "auto"
-    (the kernels where they can run and pay off, else einsum)."""
+    (the kernels where they can run and pay off, else einsum). ``window``:
+    the newest keys a query attends (None: all before it)."""
     from deepspeed_tpu.ops.pallas import kernel_dispatch, shard_map_kernel
     from deepspeed_tpu.parallel import groups
     mesh = groups.get_mesh(required=False)
@@ -284,27 +357,35 @@ def _local_attention(q, k, v, impl: str, causal=True):
             f"manual shard_map, and batch/heads that divide the mesh)")
     if impl == "flash":
         from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-        attend = lambda a, b, c: flash_attention(a, b, c, causal=causal, force_pallas=True)
+        if window is None:
+            attend = lambda a, b, c: flash_attention(a, b, c, causal=causal, force_pallas=True)
+        else:
+            attend = lambda a, b, c: flash_attention(a, b, c, causal=causal, force_pallas=True,
+                                                     window=window)
         if mode == "shard_map":
             # Run the kernel per-shard on the post-Ulysses layout (full
             # sequence, head-sharded) — causal masking is shard-local.
             spec = heads_spec(mesh)
             return shard_map_kernel(attend, mesh, (spec, spec, spec), spec)(q, k, v)
         return attend(q, k, v)
-    return einsum_attention(q, k, v, causal=causal)
+    return einsum_attention(q, k, v, causal=causal, window=window)
 
 
 class LlamaAttention(nn.Module):
     config: LlamaConfig
+    kind: Optional[str] = None      # the layer's kind, where it is known as the module is built
 
     @nn.compact
-    def __call__(self, h, positions, layer_cache=None):
+    def __call__(self, h, positions, layer_cache=None, sliding=None):
         """Training: ``layer_cache=None`` → causal self-attention with the
         Ulysses seq↔head exchange. Decode: ``layer_cache`` is this
         layer's ``{'k','v'}`` [B, S_max, Hkv, D] KV cache and
         ``positions`` [1 or B, T] the absolute write positions; returns
         ``(out, new_layer_cache)`` (equivalent of the reference's
-        softmax_context KV-cache kernels, csrc/transformer/inference)."""
+        softmax_context KV-cache kernels, csrc/transformer/inference).
+        ``sliding`` (a traced bool; training only): the layer's kind where the
+        stack's layers are of both kinds - its table of positions is picked
+        by it and its attention is a ``lax.cond`` of the two."""
         cfg = self.config
         B, S, D = h.shape
         H, Hkv, Dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -314,10 +395,17 @@ class LlamaAttention(nn.Module):
         k = QuantDense(Hkv * Dh, use_bias=qkv_bias, name="k_proj")(h).reshape(B, S, Hkv, Dh)
         v = QuantDense(Hkv * Dh, use_bias=qkv_bias, name="v_proj")(h).reshape(B, S, Hkv, Dh)
 
+        kind = self.kind or cfg.uniform_kind
         cos, sin = rope_frequencies(Dh, cfg.max_position_embeddings, cfg.rope_theta,
-                                    scaling=rope_scaling_of(cfg))
+                                    scaling=rope_scaling_of(cfg, FULL if sliding is not None else kind))
+        if sliding is not None and rope_scaling_of(cfg, SLIDING) != rope_scaling_of(cfg, FULL):
+            other = rope_frequencies(Dh, cfg.max_position_embeddings, cfg.rope_theta,
+                                     scaling=rope_scaling_of(cfg, SLIDING))
+            cos, sin = (jnp.where(sliding, jnp.asarray(o), jnp.asarray(t))
+                        for o, t in zip(other, (cos, sin)))
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
+        window = cfg.window_of(kind)
 
         if layer_cache is not None:
             start = positions[0, 0]
@@ -332,10 +420,17 @@ class LlamaAttention(nn.Module):
             k_idx = jnp.arange(s_max)[None, :]
             q_pos = (start + jnp.arange(S))[:, None]
             mask = (k_idx <= q_pos)[None, None, :, :]  # [1, 1, T, S_max]
+            if window is not None:
+                mask = mask & (k_idx > q_pos - window)[None, None, :, :]
             out = einsum_attention(q, kx, vx, mask=mask)
             out = out.reshape(B, S, H * Dh)
             return QuantDense(D, use_bias=cfg.attention_out_bias, name="o_proj")(out), new_cache
 
+        if sliding is not None and (layer_cache is not None or cfg.sp_impl != "ulysses"):
+            raise NotImplementedError("layers of both kinds: the training forward under "
+                                      "sp_impl='ulysses' only")
+        if cfg.sp_impl == "ring" and window is not None:
+            raise NotImplementedError("a sliding window under ring attention (sp_impl='ring')")
         if cfg.sp_impl == "ring":
             # Ring context parallelism: stay sequence-sharded; K/V blocks
             # rotate over the 'sequence' axis (no seq↔head exchange).
@@ -349,7 +444,17 @@ class LlamaAttention(nn.Module):
             q = seq_to_head_shard(q)
             k = seq_to_head_shard(k)
             v = seq_to_head_shard(v)
-            out = _local_attention(q, k, v, cfg.attention_impl, causal=True)
+            def attend(window):
+                def run(q, k, v):
+                    with jax.named_scope("ds.train.attn_full" if window is None
+                                         else "ds.train.attn_window"):
+                        return _local_attention(q, k, v, cfg.attention_impl, causal=True,
+                                                window=window)
+                return run
+            if sliding is None:
+                out = attend(window)(q, k, v)
+            else:
+                out = jax.lax.cond(sliding, attend(cfg.sliding_window), attend(None), q, k, v)
             out = head_to_seq_shard(out)
         else:
             raise ValueError(f"unknown sp_impl {cfg.sp_impl!r}: expected 'ulysses' or 'ring'")
@@ -378,14 +483,16 @@ class LlamaMLP(nn.Module):
 
 class LlamaBlock(nn.Module):
     config: LlamaConfig
+    kind: Optional[str] = None
 
     @nn.compact
-    def __call__(self, carry, positions, layer_cache=None):
+    def __call__(self, carry, positions, layer_cache=None, sliding=None):
         h, aux_loss = carry
         cfg = self.config
         decode = layer_cache is not None
         attn_in = RMSNorm(eps=cfg.rms_norm_eps, name="input_layernorm")(h)
-        attn_out, new_cache = LlamaAttention(cfg, name="self_attn")(attn_in, positions, layer_cache)
+        attn_out, new_cache = LlamaAttention(cfg, kind=self.kind, name="self_attn")(
+            attn_in, positions, layer_cache, sliding)
         h = h + attn_out
         if not decode:
             h = constrain_hidden(h)
@@ -393,7 +500,8 @@ class LlamaBlock(nn.Module):
         if cfg.moe_num_experts > 0:
             from deepspeed_tpu.moe.layer import MoE
             mlp_out, layer_aux = MoE(hidden_size=cfg.hidden_size,
-                                     intermediate_size=cfg.intermediate_size,
+                                     intermediate_size=(cfg.moe_intermediate_size
+                                                        or cfg.intermediate_size),
                                      num_experts=cfg.moe_num_experts,
                                      k=cfg.moe_top_k,
                                      capacity_factor=cfg.moe_capacity_factor,
@@ -433,6 +541,10 @@ class LlamaModel(nn.Module):
             h = constrain_hidden(h)
         positions = (start_pos + jnp.arange(input_ids.shape[1]))[None, :]
 
+        kinds = cfg.layer_kinds
+        if kinds and (decode or cfg.offload_params):
+            raise NotImplementedError("layer_types of several kinds: the training forward only "
+                                      "(no decode cache, no streamed parameters)")
         block = LlamaBlock
         if cfg.offload_params:
             # Training: inside remat, so the host→device copies are
@@ -446,8 +558,20 @@ class LlamaModel(nn.Module):
             policy = _remat_policy(cfg.remat_policy)
             block = nn.remat(block, prevent_cse=False, policy=policy)
         carry0 = (h, jnp.zeros((), jnp.float32))
-        overlapped = None if decode or self.is_initializing() else self._overlapped_layers(carry0, positions)
-        if overlapped is not None:
+        overlapped = None if decode or kinds or self.is_initializing() \
+            else self._overlapped_layers(carry0, positions)
+        if kinds:
+            # one body for both kinds, handed its kind a layer: an iteration is a layer, so a
+            # layer's recomputation and its residuals live for that layer's backward alone
+            ScanBlocks = nn.scan(block,
+                                 variable_axes={"params": 0, STEP_COUNTS: 0},
+                                 split_rngs={"params": True, "dropout": True},
+                                 in_axes=(nn.broadcast, nn.broadcast, 0),
+                                 length=cfg.num_hidden_layers,
+                                 metadata_params={nn.PARTITION_NAME: "layers"})
+            (h, aux_loss), new_cache = ScanBlocks(cfg, name="layers")(
+                carry0, positions, None, jnp.asarray([k == SLIDING for k in kinds]))
+        elif overlapped is not None:
             (h, aux_loss), new_cache = overlapped, None
         elif decode:
             # cache leaves carry a leading L dim and scan over layers
@@ -462,7 +586,7 @@ class LlamaModel(nn.Module):
             (h, aux_loss), new_cache = ScanBlocks(cfg, name="layers")(carry0, positions, cache)
         else:
             ScanBlocks = nn.scan(block,
-                                 variable_axes={"params": 0},
+                                 variable_axes={"params": 0, STEP_COUNTS: 0},
                                  split_rngs={"params": True, "dropout": True},
                                  in_axes=nn.broadcast,
                                  length=cfg.num_hidden_layers,
@@ -506,7 +630,10 @@ class LlamaForCausalLM(nn.Module):
     """Causal LM with internal next-token shift.
 
     ``__call__(input_ids, labels)`` → ``(loss, logits)``;
-    ``__call__(input_ids)`` → ``logits``. Positions with label -100 are
+    ``__call__(input_ids)`` → ``logits``; ``__call__(input_ids, labels,
+    per_position=True)`` → ``(nll [B, S - 1] float32, None)``: each position's
+    next-token term of the loss before its mean (no load-balancing part), by
+    the loss's own path. Positions with label -100 are
     ignored (HF convention). For sequences longer than
     ``2 * config.loss_chunk`` the loss is computed chunk-wise and the
     second element is **None** — the full [B, S, vocab] logits are never
@@ -519,7 +646,7 @@ class LlamaForCausalLM(nn.Module):
     param_stream_prefix = "model/layers/"
 
     @nn.compact
-    def __call__(self, input_ids, labels=None, cache=None, start_pos=0):
+    def __call__(self, input_ids, labels=None, cache=None, start_pos=0, per_position=False):
         cfg = self.config
         decode = cache is not None
         h, embed, aux_loss, new_cache = LlamaModel(cfg, name="model")(input_ids, cache=cache,
@@ -527,6 +654,10 @@ class LlamaForCausalLM(nn.Module):
         S = input_ids.shape[1]
         chunked = (labels is not None and not decode and cfg.loss_chunk > 0
                    and S > 2 * cfg.loss_chunk)
+        if per_position:
+            if labels is None or decode or cfg.tie_word_embeddings:
+                raise ValueError("per_position: the training loss of an untied head")
+            return self._position_nll(cfg, h, labels, cfg.loss_chunk if chunked else S), None
         if not chunked:
             if cfg.tie_word_embeddings:
                 logits = jnp.einsum("bsd,vd->bsv", h, embed.astype(h.dtype))
@@ -549,6 +680,19 @@ class LlamaForCausalLM(nn.Module):
         if cfg.moe_num_experts > 0:
             loss = loss + cfg.moe_aux_loss_coef * aux_loss / cfg.num_hidden_layers
         return loss, logits
+
+    def _position_nll(self, cfg, h, labels, C):
+        """The loss's terms, a position each, ``C`` positions at a time."""
+        hs, ls = h[:, :-1], labels[:, 1:]
+        lm_head = QuantDense(cfg.vocab_size, use_bias=False, name="lm_head")
+        out = []
+        for i in range(0, hs.shape[1], C):
+            logits = constrain(lm_head(hs[:, i:i + C]), (("data", "expert"), None, "tensor"))
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            tgt = ls[:, i:i + C].astype(jnp.int32)
+            nll = -jnp.take_along_axis(logp, jnp.maximum(tgt, 0)[..., None], axis=-1)[..., 0]
+            out.append(jnp.where(tgt != -100, nll, 0.0))
+        return jnp.concatenate(out, axis=1)
 
     def _chunked_causal_loss(self, cfg, h, embed, labels):
         C = cfg.loss_chunk
@@ -574,6 +718,15 @@ class LlamaForCausalLM(nn.Module):
                 s, c = step(lm_head, hs[:, i * C:(i + 1) * C], ls[:, i * C:(i + 1) * C])
                 total, count = total + s, count + c
         return total / jnp.maximum(count, 1).astype(jnp.float32)
+
+    def step_count_names(self, mesh):
+        """What the forward counts on the device for the trainer's step record
+        under ``mesh`` (``moe/sharded_moe.py``): the expert exchange's rows."""
+        from deepspeed_tpu.moe.sharded_moe import exchange_count_names
+        cfg = self.config
+        if cfg.moe_num_experts > 0 and not cfg.moe_drop_tokens:
+            return exchange_count_names(mesh)
+        return ()
 
     def tp_rule(self, path: str, shape) -> P:
         """Megatron-style tensor sharding (consumed by ZeroShardingPolicy).

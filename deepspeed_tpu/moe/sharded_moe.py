@@ -25,6 +25,20 @@ from deepspeed_tpu.sequence.layer import constrain
 
 MIN_CAPACITY = 4
 
+# The flax collection a dropless layer on an expert axis sows its exchange's counts into;
+# the trainer makes it mutable, sums each name over the layers and writes the sums on its
+# step record (``runtime/engine.py``; docs/OBSERVABILITY.md).
+STEP_COUNTS = "step_counts"
+EXCHANGE_COUNTS = ("n_expert_rows", "expert_rows_max_rank", "n_share_passes",
+                   "rows_beyond_passes")
+
+
+def exchange_count_names(mesh):
+    """:data:`EXCHANGE_COUNTS` where a dropless layer under ``mesh`` runs the
+    expert exchange (``ops/grouped_gemm.mesh_share_axes``), else ()."""
+    from deepspeed_tpu.ops.grouped_gemm import mesh_share_axes
+    return EXCHANGE_COUNTS if mesh_share_axes(mesh) is not None else ()
+
 
 def _capacity(num_tokens: int, num_experts: int, k: int, capacity_factor: float,
               min_capacity: int = MIN_CAPACITY) -> int:
@@ -137,8 +151,10 @@ class TopKGate(nn.Module):
             rng = self.make_rng("dropout") if self.has_rng("dropout") else None
             if rng is not None:
                 x32 = multiplicative_jitter(x32, rng)
-        logits = nn.Dense(self.num_experts, use_bias=False, name="wg",
-                          dtype=jnp.float32)(x32)
+        # float32 in fact: at the default precision a TPU multiplies float32 operands in one
+        # bfloat16 pass, which moves a logit by ~1e-2 and a step's picks with it (PERF.md, PR 58)
+        logits = nn.Dense(self.num_experts, use_bias=False, name="wg", dtype=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)(x32)
         logits = logits.reshape(-1, self.num_experts)
         if self.noisy_gate_policy == "RSample" and train:
             rng = self.make_rng("dropout") if self.has_rng("dropout") else None
@@ -178,24 +194,27 @@ class MOELayer(nn.Module):
         B, S, D = x.shape
 
         # the gate consumes x 3-D (only its [T, E] logits flatten)
-        aux_loss, combine, dispatch = TopKGate(num_experts=self.num_experts, k=self.k,
-                                               capacity_factor=self.capacity_factor,
-                                               eval_capacity_factor=self.eval_capacity_factor,
-                                               min_capacity=self.min_capacity,
-                                               noisy_gate_policy=self.noisy_gate_policy,
-                                               drop_tokens=self.drop_tokens,
-                                               name="gate")(x, train=train)
+        with jax.named_scope("ds.moe_route"):
+            aux_loss, combine, dispatch = TopKGate(
+                num_experts=self.num_experts, k=self.k, capacity_factor=self.capacity_factor,
+                eval_capacity_factor=self.eval_capacity_factor, min_capacity=self.min_capacity,
+                noisy_gate_policy=self.noisy_gate_policy, drop_tokens=self.drop_tokens,
+                name="gate")(x, train=train)
 
         if not self.drop_tokens:
-            # Dropless dispatch (reference drop_tokens=False no-drop
-            # gather): the serving grouped GEMM (lax.ragged_dot over
-            # expert-sorted rows) IS the training dispatch — every token
-            # reaches its full top-k and ragged_dot differentiates. Under
-            # an expert-parallel axis the same manual shard_map as v2
-            # serving runs: experts stay on their shard, each shard masks
-            # non-local assignments, psum combines (the gather implied by
-            # the replicated in_spec is over the expert axis only — batch
-            # sharding on data/sequence stays automatic).
+            # Dropless dispatch (reference drop_tokens=False no-drop gather): the
+            # serving grouped GEMM over expert-sorted rows IS the training dispatch -
+            # every token reaches its full top-k, and the grouped matmul
+            # differentiates. On an expert axis (``ops/grouped_gemm.exchanges_shares``)
+            # every rank computes its own experts' share of its axis's tokens: the
+            # rows gathered over the axis in the compute dtype, the picks its experts
+            # hold laid out compacted in passes of a static size (one for an even
+            # router, as many as the held picks take: none is dropped), a token's
+            # picks summed to [T, D] and that reduce-scattered onto the token's rank
+            # (``expert_share_exchange_ffn``); its counts go to the step record.
+            # With a tensor axis beside the expert axis the older dispatch stays:
+            # every pick a row on every shard, the picks held elsewhere zeroed, a
+            # float32 psum.
             #
             # Quantized (OptimizedLinear-style frozen-base) training:
             # dropless_moe_ffn also accepts grouped-layout
@@ -205,7 +224,9 @@ class MOELayer(nn.Module):
             # self.param unboxes AxisMetadata — so a frozen-base trainer
             # passes the boxed stacks to dropless_moe_ffn directly, as
             # the v2 runner does.
-            from deepspeed_tpu.ops.grouped_gemm import dropless_moe_ffn
+            from deepspeed_tpu.ops.grouped_gemm import (ExpertShare, dropless_moe_ffn,
+                                                        exchanges_shares,
+                                                        expert_share_exchange_ffn, mesh_share_rows)
             from deepspeed_tpu.parallel import groups
             mesh = groups.get_mesh(required=False)
             topk_w, topk_idx = combine, dispatch  # [T, k] each (gate's dropless form)
@@ -214,9 +235,24 @@ class MOELayer(nn.Module):
             w1 = self.param("experts_w1", init, (E, D, I))
             w3 = self.param("experts_w3", init, (E, D, I))
             w2 = self.param("experts_w2", init, (E, I, D))
-            combined = dropless_moe_ffn(x.reshape(B * S, D), topk_idx,
-                                        topk_w.astype(x.dtype),
-                                        w1, w3, w2, num_experts=E, mesh=mesh)
+            whole = ExpertShare(0, E, E)
+            if exchanges_shares(mesh, B * S, whole, (w1, w3, w2)):
+                combined, counts = expert_share_exchange_ffn(
+                    x.reshape(B * S, D), topk_idx, topk_w.astype(x.dtype), w1, w3, w2,
+                    whole, mesh)
+                if not self.is_initializing():
+                    held, passes = counts[..., 0], counts[..., 1]  # [copies over data, ranks]
+                    rows = mesh_share_rows(B * S // held.shape[0], self.k, whole, held.shape[1],
+                                           x.dtype)
+                    for name, value in zip(EXCHANGE_COUNTS, (
+                            jnp.sum(held), jnp.max(jnp.sum(held, axis=0)), jnp.max(passes),
+                            jnp.sum(jnp.maximum(held - passes * rows, 0)))):
+                        self.sow(STEP_COUNTS, name, value.astype(jnp.int32),
+                                 reduce_fn=jnp.add, init_fn=lambda: jnp.zeros((), jnp.int32))
+            else:
+                combined = dropless_moe_ffn(x.reshape(B * S, D), topk_idx,
+                                            topk_w.astype(x.dtype),
+                                            w1, w3, w2, num_experts=E, mesh=mesh)
             return combined.reshape(B, S, D), aux_loss
 
         # [E, C, D] expert-major dispatch (XLA inserts token→expert a2a).

@@ -32,6 +32,8 @@ import dataclasses
 import functools
 import threading
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax import shard_map
@@ -587,6 +589,290 @@ def _held_picks_in_passes(x, experts, vals, live, cap, tail):
     return out.astype(x.dtype), passes
 
 
+# The rows of one pass of a rank's layout over the held picks an even router gives it
+# (``T k held / routed``): half as much again. A router that sends a rank more takes more
+# passes - each of this static size, their number read on the device - and never loses a
+# pick, but a pass of 81,920 rows costs a layer ~100 ms on a v5e whatever it holds, so the
+# margin clears what seeded routers do: at a random initialisation a step of 32768 tokens'
+# 8 picks reads 1.11-1.27 of the even share on the fullest rank of four (nine seeds), after
+# one Adam step at 3e-4 without warm-up 1.30; at 1.25 a second pass ran in 0-45 of a
+# window's ~55 steps by the seed and the rate spread by 7 % (PERF.md, PR 58).
+MESH_SHARE_MARGIN = 1.5
+# a step of so few picks over the axis (a debug size: eight experts spread a hundred tokens
+# far less evenly than the margin) is laid out whole in one pass
+MESH_SHARE_SMALL = 8192
+_SUM_CHUNK_ROWS = 16384     # rows :func:`_sum_picks_by_token` adds at a time (151 MB of float32 at 2304)
+
+
+def mesh_share_rows(T, k, share, ranks, dtype):
+    """The rows one pass of a rank of :func:`expert_share_exchange_ffn` lays
+    out for the picks its ``share.held / ranks`` experts hold of the
+    axis's ``T`` tokens: :data:`MESH_SHARE_MARGIN` times the even router's
+    ``T k held / (ranks columns)``, rounded up to the row tile, at most every
+    pick - and every pick where there are :data:`MESH_SHARE_SMALL` or fewer."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile
+    held, columns = share.held // ranks, share.routed + share.zero
+    tm = row_tile(max(1, T * k // columns) * held, held, dtype)
+    want = T * k if T * k <= MESH_SHARE_SMALL else int(-(-T * k * held * MESH_SHARE_MARGIN // columns))
+    return -(-min(max(want, held), T * k) // tm) * tm
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _sum_picks_by_token(y, w, tok, n_tokens, k):
+    """``out[t] = sum of w[r] y[r] over the rows r with tok[r] == t`` →
+    ``[n_tokens, D]`` float32, for rows **sorted by token** (``tok``
+    ascending; a row of no token carries ``n_tokens`` and lies after them),
+    a token at most ``k`` rows. A scatter-add told its indices are sorted,
+    :data:`_SUM_CHUNK_ROWS` rows at a time so that the float32 products are a
+    chunk's and not the layout's. Timed alone on a v5e (``tools/kernel_census.py
+    --flash-window``; PERF.md, PR 58), 98304 rows of 2304 onto 32768 tokens:
+    this form 19.3 ms, ONE sorted ``segment_sum`` over all the rows 8.8 (its
+    products are 906 MB of float32 at once), and PR 58's first form - blocks of
+    128 tokens on the matrix unit, each block's ``128 k`` candidate rows
+    gathered - 19.3 at 81920 rows: the chunking costs what the blocks did, and
+    the single scatter-add is the next thing to try where the step's memory
+    allows (not run in the step: the chip budget was spent). Serving's
+    one-product form multiplies every token by every row. The backward is a
+    gather: a row's cotangent is its token's."""
+    R, D = y.shape
+    chunk = min(R, _SUM_CHUNK_ROWS)
+    pad = -R % chunk
+    rows = jnp.pad(y, ((0, pad), (0, 0))).reshape(-1, chunk, D)
+    weights = jnp.pad(w, (0, pad)).reshape(-1, chunk)
+    tokens = jnp.pad(tok, (0, pad), constant_values=n_tokens).reshape(-1, chunk)
+
+    def add(acc, part):
+        yc, wc, tc = part
+        return acc.at[tc].add(yc.astype(jnp.float32) * wc.astype(jnp.float32)[:, None],
+                              indices_are_sorted=True, mode="drop"), None
+
+    out, _ = jax.lax.scan(add, jnp.zeros((n_tokens, D), jnp.float32), (rows, weights, tokens))
+    return out
+
+
+def _sum_picks_fwd(y, w, tok, n_tokens, k):
+    return _sum_picks_by_token(y, w, tok, n_tokens, k), (y, w, tok)
+
+
+def _sum_picks_bwd(n_tokens, k, res, dout):
+    y, w, tok = res
+    rows = _rows_of_tokens_impl(dout, tok, n_tokens)
+    dy = (rows * w.astype(jnp.float32)[:, None]).astype(y.dtype)
+    dw = jnp.sum(rows * y.astype(jnp.float32), axis=-1).astype(w.dtype)
+    return dy, dw, np.zeros(tok.shape, dtype=jax.dtypes.float0)
+
+
+_sum_picks_by_token.defvjp(_sum_picks_fwd, _sum_picks_bwd)
+
+
+def _rows_of_tokens_impl(x, tok, n_tokens):
+    rows = jnp.take(x, jnp.minimum(tok, n_tokens - 1), axis=0)
+    return jnp.where((tok < n_tokens)[:, None], rows, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_of_tokens(x, tok, k):
+    """``x[tok]`` for rows sorted by token (a row of no token, ``tok ==
+    len(x)``, is zero): the gather whose backward is
+    :func:`_sum_picks_by_token` and not a scatter-add."""
+    return _rows_of_tokens_impl(x, tok, x.shape[0])
+
+
+def _rows_of_tokens_fwd(x, tok, k):
+    return _rows_of_tokens_impl(x, tok, x.shape[0]), (tok, jnp.zeros((x.shape[0], 0), x.dtype))
+
+
+def _rows_of_tokens_bwd(k, res, drows):
+    tok, proto = res
+    dx = _sum_picks_by_token(drows, jnp.ones(tok.shape, drows.dtype), tok, proto.shape[0], k)
+    return dx.astype(proto.dtype), np.zeros(tok.shape, dtype=jax.dtypes.float0)
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+def mesh_share_axes(mesh):
+    """The mesh axes a step's tokens are split over for
+    :func:`expert_share_exchange_ffn` (``data`` and ``expert``, those larger
+    than 1), or None where that dispatch does not apply: no expert axis, or a
+    ``tensor`` / ``sequence`` / ``pipe`` axis beside it (the masked dispatch's)."""
+    if mesh is None or mesh.shape.get("expert", 1) <= 1:
+        return None
+    if any(mesh.shape.get(a, 1) > 1 for a in ("tensor", "sequence", "pipe")):
+        return None
+    return tuple(a for a in ("data", "expert") if mesh.shape.get(a, 1) > 1)
+
+
+def expert_share_exchange_ffn(x, topk_idx, topk_vals, w1, w3, w2, share, mesh,
+                              activation=jax.nn.silu):
+    """:func:`dropless_moe_ffn` over an expert axis: every rank computes its
+    own share and the ranks exchange tokens on either side of it → (``[T,
+    D]``, counts ``[copies over data, ranks, 2]`` int32: a rank's held picks
+    and the passes they took). Differentiable in ``x``, ``topk_vals`` and
+    the stacks.
+
+    ``x`` [T, D] is split by token over ``data`` and ``expert``; the stacks
+    ``[share.held, ...]`` by expert over ``expert``. A rank (a) gathers the
+    rows, picks and weights of its expert axis's tokens in the compute dtype
+    (``T_a = T / data`` rows), (b) lists **the picks its own experts hold**,
+    in order, and lays out those alone, :func:`mesh_share_rows` rows at a
+    pass - a static size, :data:`MESH_SHARE_MARGIN` over an even router's
+    share: one pass then, ``ceil(held picks / rows)`` whatever the router
+    does, so **no pick is ever dropped** -, (c) runs the three grouped matmuls
+    over a pass's rows (:func:`moe_grouped_mlp`), (d) weights and sums a
+    token's rows into ``[T_a, D]`` float32 (:func:`_sum_picks_by_token`: the
+    rows are sorted by token), and (e) reduce-scatters that over the axis, in
+    the compute dtype, onto the rank that owns each token. Nothing of ``T k``
+    rows by the model's width is built, summed or sent.
+
+    **The backward** is the same exchange transposed (a gather of the
+    output's cotangent, a reduce-scatter of the rows'), and between them the
+    passes again: the number of passes is read on the device, so the loop is
+    no ``scan`` to transpose - :func:`_share_passes` is a ``custom_vjp`` whose
+    backward runs each pass's forward again and pulls the cotangent through
+    it, summing the stacks' gradients over the passes. It keeps no residual
+    but its inputs (under any ``remat_policy`` the expert layer's forward
+    runs once in the forward and once in the backward).
+
+    An all-to-all of the held picks would move 0.92 of what the gather moves
+    at 8 picks over 4 ranks (a token misses a rank with probability 0.085) and
+    needs a listing by destination on the sender beside this one: the gather
+    is taken."""
+    from jax.sharding import PartitionSpec as P
+    T, k = topk_idx.shape
+    axes = mesh_share_axes(mesh)
+    ep = mesh.shape["expert"]
+    n_shards = 1
+    for a in axes:
+        n_shards *= mesh.shape[a]
+    Ta = T // n_shards * ep
+    held = share.held // ep
+    cap = mesh_share_rows(Ta, k, share, ep, x.dtype)
+    rows_a_group = max(1, Ta * k // (share.routed + share.zero))
+    dtype = x.dtype
+
+    def body(x_l, idx_l, val_l, w1s, w3s, w2s):
+        with jax.named_scope("ds.moe_exchange"):
+            x_all, idx_all, val_all = (jax.lax.all_gather(a, "expert", axis=0, tiled=True)
+                                       for a in (x_l, idx_l, val_l))
+        first = share.first + jax.lax.axis_index("expert") * held
+        with jax.named_scope("ds.moe_experts"):
+            out, n_held = _share_passes(
+                x_all, val_all.reshape(-1), w1s.astype(dtype), w3s.astype(dtype),
+                w2s.astype(dtype), idx_all.reshape(-1) - first, k, held, cap, rows_a_group,
+                activation)
+        with jax.named_scope("ds.moe_exchange"):
+            out_l = jax.lax.psum_scatter(out.astype(_exchange_dtype(dtype, mesh)), "expert",
+                                         scatter_dimension=0, tiled=True).astype(dtype)
+        return out_l, jnp.stack([n_held, (n_held + cap - 1) // cap])[None]
+
+    tokens = P(axes if len(axes) > 1 else axes[0])
+    stacks = P("expert")
+    every = P(tuple(mesh.axis_names))
+    out, counts = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(tokens, tokens, tokens, stacks, stacks, stacks),
+        out_specs=(tokens, every), check_vma=False))(x, topk_idx, topk_vals, w1, w3, w2)
+    # one row a device; the ranks of one expert axis differ, its copies over data do not
+    return out, counts.reshape(-1, ep, 2)
+
+
+def _pass_of(p, order, n_held, x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group,
+             activation):
+    """What pass ``p`` of a rank's held picks adds to ``[T_a, D]`` float32: the
+    picks ``order[p cap : (p + 1) cap]`` (flat indices ``t k + j``, ascending:
+    a token's adjacent) laid out, multiplied, weighted and summed by token."""
+    Ta = x_all.shape[0]
+    pick = jax.lax.dynamic_slice_in_dim(order, p * cap, cap)
+    here = p * cap + jnp.arange(cap, dtype=jnp.int32) < n_held
+    pick = jnp.where(here, pick, 0)
+    tok = jnp.where(here, pick // k, Ta)
+    rows = _rows_of_tokens(x_all, tok, k)
+    y = moe_grouped_mlp(rows, jnp.where(here, jnp.take(experts, pick), 0), w1, w3, w2,
+                        num_experts=held, activation=activation, live=here,
+                        rows_a_group=rows_a_group)
+    weights = jnp.where(here, jnp.take(vals, pick), 0).astype(x_all.dtype)
+    return _sum_picks_by_token(y, weights, tok, Ta, k)
+
+
+def _held_order(experts, held, cap):
+    """→ (the flat indices of the picks whose expert - ``experts`` counts from
+    this rank's first - is held here, ascending, then filler, padded to whole
+    passes; how many are held)."""
+    n = experts.shape[0]
+    live = (experts >= 0) & (experts < held)
+    at = jnp.arange(n, dtype=jnp.int32)
+    order = jnp.sort(jnp.where(live, at, n + at))
+    return jnp.pad(order, (0, -n % cap + cap)), jnp.sum(live, dtype=jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _share_passes(x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group, activation):
+    """Every pass of a rank's held picks (:func:`_pass_of`) summed →
+    (``[T_a, D]`` float32, the held picks). ``experts`` [T_a k] int32: each
+    pick's expert counted from this rank's first (held: ``0 <= e < held``)."""
+    order, n_held = _held_order(experts, held, cap)
+
+    def one(p, acc):
+        return acc + _pass_of(p, order, n_held, x_all, vals, w1, w3, w2, experts, k, held, cap,
+                              rows_a_group, activation)
+
+    out = jax.lax.fori_loop(0, (n_held + cap - 1) // cap, one,
+                            jnp.zeros(x_all.shape, jnp.float32))
+    return out, n_held
+
+
+def _share_passes_fwd(x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group, activation):
+    out = _share_passes(x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group, activation)
+    return out, (x_all, vals, w1, w3, w2, experts)
+
+
+def _share_passes_bwd(k, held, cap, rows_a_group, activation, res, cts):
+    x_all, vals, w1, w3, w2, experts = res
+    dout = cts[0]
+    order, n_held = _held_order(experts, held, cap)
+    args = (x_all, vals, w1, w3, w2)
+
+    def one(p, grads):
+        _, vjp = jax.vjp(lambda *a: _pass_of(p, order, n_held, *a, experts, k, held, cap,
+                                             rows_a_group, activation), *args)
+        return jax.tree.map(lambda g, d: g + d.astype(g.dtype), grads, vjp(dout))
+
+    # the stacks' gradients summed over the passes in float32, the rest in their own dtype
+    zeros = tuple(jnp.zeros(a.shape, jnp.float32 if i >= 2 else a.dtype)
+                  for i, a in enumerate(args))
+    grads = jax.lax.fori_loop(0, (n_held + cap - 1) // cap, one, zeros)
+    grads = tuple(g.astype(a.dtype) for g, a in zip(grads, args))
+    return (*grads, np.zeros(experts.shape, dtype=jax.dtypes.float0))
+
+
+_share_passes.defvjp(_share_passes_fwd, _share_passes_bwd)
+
+
+def _exchange_dtype(dtype, mesh):
+    """The reduce-scatter's dtype: the compute dtype, but float32 on a mesh of
+    CPU devices, whose XLA CHECK-crashes on a bfloat16 reduction inside a
+    shard_map."""
+    if jnp.dtype(dtype) == jnp.bfloat16 and mesh.devices.flat[0].platform == "cpu":
+        return jnp.float32
+    return dtype
+
+
+def exchanges_shares(mesh, T, share, stacks, first_group=None):
+    """Whether :func:`dropless_moe_ffn` takes :func:`expert_share_exchange_ffn`
+    for ``T`` tokens behind ``share`` under ``mesh``: an expert axis alone
+    beside ``data``, dense gated stacks that are no table, and tokens and held
+    experts that divide over the ranks."""
+    axes = mesh_share_axes(mesh)
+    if axes is None or first_group is not None:
+        return False
+    if any(w is None or _is_quantized(w) for w in stacks):
+        return False
+    n_shards = 1
+    for a in axes:
+        n_shards *= mesh.shape[a]
+    return T % n_shards == 0 and share.held % mesh.shape["expert"] == 0
+
+
 def shards_experts(mesh):
     """Whether :func:`dropless_moe_ffn` runs its experts sharded under
     ``mesh``: an ``expert`` or ``tensor`` axis larger than 1."""
@@ -609,15 +895,25 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
 
     Without a mesh (or expert/tensor axes of size 1): tokens replicate
     k×, sort by expert, and ride one grouped GEMM (``lax.ragged_dot``).
-    With expert/tensor axes: a shard_map manual over ONLY those axes —
-    each shard routes every token it holds but masks non-local expert
-    assignments, and a psum over ('expert', 'tensor') combines; expert
-    weights never leave their shard. Other mesh axes (data/sequence
-    batch sharding in training) stay under automatic partitioning, so
-    the gather implied by the replicated in_spec is over the expert
-    axis only. Differentiable end-to-end (ragged_dot has grad rules;
-    psum transposes), so the same dispatch trains Mixtral-style
-    dropless models.
+
+    **With an expert axis** (and no tensor, sequence or pipe axis beside it:
+    :func:`mesh_share_axes`), dense stacks, a training caller (``widen_boundary``,
+    the default) or a ``share``: :func:`expert_share_exchange_ffn`.
+    Every rank gathers its axis's tokens in the compute dtype, lays out and
+    multiplies only the picks its own experts hold, sums a token's picks to
+    ``[T, D]`` and reduce-scatters that onto the token's rank; the backward is
+    the same exchange transposed. Expert weights never leave their rank.
+
+    With a tensor axis, quantized stacks, or a forward-only serving caller
+    (``widen_boundary=False``: its programs are what they were): a shard_map
+    manual over ONLY the expert and tensor axes - each shard routes every
+    token it holds, every pick a row, the picks of experts held elsewhere
+    pointed at local expert 0 and zeroed afterwards, and a float32 psum over
+    ('expert', 'tensor') combines; ``widen_boundary`` carries x across that
+    boundary in float32 (its transposed psum crashed XLA:CPU in bfloat16).
+    Other mesh axes stay under automatic partitioning. Differentiable
+    end-to-end either way (ragged_dot and the Pallas grouped matmul have grad
+    rules; the collectives transpose).
 
     Expert weights may be grouped-layout ``QuantizedWeight`` stacks.
     Under a mesh they cross the shard_map boundary DESTRUCTURED into
@@ -639,13 +935,28 @@ def dropless_moe_ffn(x, topk_idx, topk_vals, w1, w3, w2, num_experts, mesh=None,
     zero-compute picks' weights) * x``; what experts held elsewhere would
     add is left out, and nothing is multiplied, read or laid out for it:
     :func:`expert_share_ffn`, which also says how many passes the held picks
-    took. A pick of -1 is no pick (a padding token's). One device only: the
-    exchange between the shares of a mesh is not implemented."""
+    took. A pick of -1 is no pick (a padding token's). On a mesh with an
+    expert axis the held experts are split over its ranks and exchanged as
+    above (:func:`expert_share_exchange_ffn`, dense gated stacks)."""
     T, k = topk_idx.shape
+    # the exchange is the training dispatch (``widen_boundary``, the default) and a share's on a
+    # mesh; serving's expert-parallel engines (``widen_boundary=False``) keep their program
+    if (widen_boundary or share is not None) and exchanges_shares(
+            mesh, T, share or ExpertShare(0, num_experts, num_experts), (w1, w3, w2), first_group):
+        whole = share or ExpertShare(0, num_experts, num_experts)
+        out = expert_share_exchange_ffn(x, topk_idx, topk_vals, w1, w3, w2, whole, mesh,
+                                        activation=activation)[0]
+        if whole.zero:
+            with jax.named_scope("ds.moe_zero"):
+                w_zero = jnp.sum(jnp.where(whole.parts(topk_idx)[1], topk_vals, 0), axis=-1,
+                                 keepdims=True)
+                out = out + w_zero.astype(x.dtype) * x
+        return out
     if share is not None:
         if shards_experts(mesh):
-            raise NotImplementedError("an expert share on a mesh with expert/tensor axes: the "
-                                      "exchange between shares is not implemented")
+            raise NotImplementedError(
+                "an expert share on a mesh with a tensor, sequence or pipe axis, over quantized "
+                "or ungated stacks, or whose tokens or experts do not divide over the ranks")
         return expert_share_ffn(x, topk_idx, topk_vals, w1, w3, w2, share,
                                 first_group=first_group, activation=activation)[0]
     idx_rep = topk_idx.reshape(-1)  # [T*k]

@@ -70,6 +70,12 @@ DeepSpeedOptimizerCallable = object
 DeepSpeedSchedulerCallable = object
 
 
+def _reduce_count(name, values):
+    """A step count over its layers (and micro-batches): a name with ``_max`` in it is the
+    largest, any other the sum."""
+    return jnp.max(values) if "_max" in name else jnp.sum(values)
+
+
 class DeepSpeedEngine:
     """DeepSpeed engine: wraps a model to expose forward/backward/step."""
 
@@ -423,6 +429,26 @@ class DeepSpeedEngine:
             except TypeError:
                 return self.module.apply({"params": params}, *args, **kwargs)
         return self.module(params, *args, **kwargs)
+
+    def _step_count_names(self):
+        """What the model counts on the device in a training step under this
+        mesh (``module.step_count_names(mesh)``; () for a model that counts
+        nothing, whose step program is then what it was)."""
+        names = getattr(self.module, "step_count_names", None)
+        return tuple(names(self.mesh)) if callable(names) else ()
+
+    def _apply_counting(self, params, *args, rngs=None, **kwargs):
+        """:meth:`_apply_module` with the model's ``step_counts`` collection
+        mutable → (the model's output, {name: the sum over the layers that
+        sowed it})."""
+        from deepspeed_tpu.moe.sharded_moe import STEP_COUNTS
+        out, state = self.module.apply({"params": params}, *args, rngs=rngs,
+                                       mutable=[STEP_COUNTS], **kwargs)
+        found = {name: [] for name in self._step_count_names()}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(state.get(STEP_COUNTS, {})):
+            found[next(k.key for k in reversed(path) if hasattr(k, "key"))].append(leaf.reshape(-1))
+        return out, {name: _reduce_count(name, jnp.concatenate(leaves)) if leaves
+                     else jnp.zeros((), jnp.int32) for name, leaves in found.items()}
 
     def _init_params(self, *fwd_args, **fwd_kwargs):
         assert hasattr(self.module, "init"), (
@@ -846,8 +872,23 @@ class DeepSpeedEngine:
                 jnp.zeros((n,) + p.shape, jnp.float32),
                 NamedSharding(self.mesh, P("data"))), self.params)
 
+    def _loss_and_grads_core(self):
+        """:meth:`_vag_core` for the paths that write no counts on a step
+        record (forward/backward by hand, the offloaded update): the loss
+        alone beside the gradients."""
+        core = self._vag_core()
+        if not self._step_count_names():
+            return core
+
+        def loss_only(*args):
+            (loss, _), grads = core(*args)
+            return loss, grads
+        return loss_only
+
     def _vag_core(self):
-        """(params, scale, rng, args, kwargs) -> (loss, raw_grads).
+        """(params, scale, rng, args, kwargs) -> (loss, raw_grads); for a
+        model that counts its steps (:meth:`_step_count_names`), ``loss`` is
+        ``(loss, {name: count})``.
 
         Default: one auto-sharded value_and_grad — GSPMD inserts the DP
         grad reduction. With ZeRO++ flags (zero_quantized_gradients /
@@ -857,20 +898,29 @@ class DeepSpeedEngine:
         reduce-scatter (qgZ) — reference coalesced_collectives.py:31 —
         while TP/SP/EP axes stay under GSPMD inside the region."""
         gas = self.gradient_accumulation_steps()
+        counting = bool(self._step_count_names())
 
         def loss_of(params, scale, rng, args, kwargs):
-            out = self._apply_module(params, *args, rngs={"dropout": rng}, **kwargs)
+            if counting:
+                out, counts = self._apply_counting(params, *args, rngs={"dropout": rng}, **kwargs)
+            else:
+                out = self._apply_module(params, *args, rngs={"dropout": rng}, **kwargs)
             loss = out[0] if isinstance(out, (tuple, list)) else out
             scaled = (loss.astype(jnp.float32) * scale) / gas
-            return scaled, loss
+            return scaled, ((loss, counts) if counting else loss)
 
         if not self._quantized_comm_enabled():
             def core(params, scale, rng, args, kwargs):
+                # counting: ``loss`` is (loss, {name: count}), and the fused step
+                # (_train_batch_fn) takes the pair apart
                 with overlap.overlapping(self._layer_overlap):
                     (_, loss), grads = jax.value_and_grad(loss_of, has_aux=True)(
                         params, scale, rng, args, kwargs)
                 return loss, grads
             return core
+        if counting:
+            raise NotImplementedError("a model that counts its steps (an expert exchange) "
+                                      "under quantized ZeRO++ communication")
 
         from deepspeed_tpu.ops.pallas import manual_axes
         from deepspeed_tpu.runtime.comm.compressed import (quant_all_gather, quant_all_reduce,
@@ -969,7 +1019,7 @@ class DeepSpeedEngine:
             return self._jit_cache[key]
         acc_dtype = self._grad_accum_dtype
         grad_specs = self._grad_specs
-        core = self._vag_core()
+        core = self._loss_and_grads_core()
 
         def fn(params, scale, rng, args, kwargs):
             loss, grads = core(params, scale, rng, args, kwargs)
@@ -1244,6 +1294,7 @@ class DeepSpeedEngine:
 
         core = self._vag_core()
         tied = self.master_params is self.params
+        counting = bool(self._step_count_names())
 
         def body(params, master, opt_state, scaler_st, lr, rng, batches):
             scale = scaler_st["cur_scale"]
@@ -1267,6 +1318,11 @@ class DeepSpeedEngine:
 
             new_params, new_master, new_opt, new_scaler, gnorm, overflow = self._update_math(
                 params, master, opt_state, acc, scaler_st, lr)
+            if counting:
+                # the model's counts ride the gradient norm: one vector, read at its sync
+                losses, counts = losses
+                gnorm = (gnorm, jnp.stack([_reduce_count(n, counts[n])
+                                           for n in self._step_count_names()]))
             return new_params, new_master, new_opt, new_scaler, losses.mean(), gnorm, overflow
 
         if tied:
@@ -1294,7 +1350,7 @@ class DeepSpeedEngine:
         grad_specs = self._grad_specs
         mesh = self.mesh
 
-        core = self._vag_core()
+        core = self._loss_and_grads_core()
 
         def fn(params, scaler_st, rng, batches):
             scale = scaler_st["cur_scale"]
@@ -1407,6 +1463,10 @@ class DeepSpeedEngine:
             self.global_samples += self.train_batch_size()
             with tracing.phase("train.sync"):
                 self.overflow = bool(overflow) if self.fp16_enabled() else False
+                if isinstance(gnorm, tuple):
+                    gnorm, counts = gnorm
+                    rec.counts = dict(zip(self._step_count_names(),
+                                          (int(c) for c in np.asarray(counts))))
                 self.global_grad_norm = float(gnorm)
             if not self.overflow and self.lr_scheduler is not None:
                 self.lr_scheduler.step()

@@ -298,10 +298,19 @@ def test_the_whole_share_is_the_program_every_expert_model_runs_today():
     plain = dropless_moe_ffn(x, idx, vals, *w, num_experts=E)
     shared = dropless_moe_ffn(x, idx, vals, *w, num_experts=E, share=ExpertShare(0, E, E, 0))
     assert rel_err(shared, plain) < 1e-6
-    with pytest.raises(NotImplementedError, match="exchange"):
-        from deepspeed_tpu.parallel.topology import make_mesh_topology
-        dropless_moe_ffn(x, idx, vals, *w, num_experts=E, share=ExpertShare(0, 4, E, 0),
-                         mesh=make_mesh_topology(expert=2, data=1, devices=jax.devices()[:2]))
+    # a share on a mesh with an expert axis: its held experts split over the ranks and the
+    # tokens exchanged on either side (PR 58; it raised before) - the one-device share's answer
+    from deepspeed_tpu.parallel.topology import make_mesh_topology
+    half = ExpertShare(0, 4, E, 0)
+    held = [a[:4] for a in w]
+    alone = dropless_moe_ffn(x, idx, vals, *held, num_experts=E, share=half)
+    on_mesh = dropless_moe_ffn(x, idx, vals, *held, num_experts=E, share=half,
+                               mesh=make_mesh_topology(expert=2, data=1, devices=jax.devices()[:2]))
+    assert rel_err(on_mesh, alone) < 1e-6
+    with pytest.raises(NotImplementedError, match="tensor"):
+        dropless_moe_ffn(x, idx, vals, *held, num_experts=E, share=half,
+                         mesh=make_mesh_topology(expert=2, tensor=2, data=1,
+                                                 devices=jax.devices()[:4]))
 
 
 def test_a_pick_of_minus_one_is_no_pick():

@@ -25,6 +25,18 @@ GB/s of expert weights, share of the chip's 819 GB/s. That table is what
 ``--gmm-sweep`` adds other row and column tiles; ``--gmm-only`` skips
 the verdicts.
 
+``--flash-window`` (PR 58) times the training block's attention calls alone
+at ``mellum2-12b-moe8k-x4``'s shape - one sequence of 8192 x 32 heads x 128 -
+the windowed kernels (``flash_window_fwd`` / ``_dkv`` / ``_dq``, a window of
+1024) beside the causal ones, forward and forward + backward, from a loop
+inside one program: ms a call, the block pairs the grid visits (15 of 36) and
+the share of the bf16 peak of the operations the band or the triangle needs;
+then the expert exchange's sum by token (81920 laid-out rows onto 32768
+tokens: the program's sorted scatter-add in chunks beside one ``segment_sum``;
+PR 58's first form, blocks of 128 tokens on the matrix unit, read 19.3 ms where
+the ``segment_sum`` read 8.0) and its
+listing sort (~3 min on one chip, ``chiprun_out/flash_window_census.json``).
+
 ``--paged`` times ``paged_decode_attention`` instead, at the shape classes
 the two key-value cells serve (8 KV heads x 128, 16-row bf16 blocks, a
 traced layer index): 64 and 128 decode rows at contexts 128-1536, chat's
@@ -1353,6 +1365,107 @@ def _share_parts(T, k, D, cap, held, idx, vals, dtype):
     return out
 
 
+def flash_window_classes():
+    """``mellum2-12b-moe8k-x4``'s attention calls alone (PR 58): one sequence of
+    8192 x 32 heads x 128 (the key-value heads already repeated, as the training
+    block hands them over), the windowed kernels at a window of 1024 beside the
+    causal ones - forward, and forward + backward (``dkv`` and ``dq``) - each
+    from a loop inside one program. Yields the time a call, the block pairs the
+    grid visits, and the share of the bf16 peak of the operations the band or
+    the triangle NEEDS (``benchmark/readers/mellum.py``). Then the expert
+    exchange's sum by token (``ops/grouped_gemm._sum_picks_by_token``: a rank's
+    81920 laid-out rows of 2304 onto 32768 tokens, a sorted scatter-add in
+    chunks) beside one sorted ``segment_sum``, and its listing of the held
+    picks (the sort)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark.readers import mellum as work
+    from deepspeed_tpu.ops import grouped_gemm as gg
+    import importlib
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")   # the module, not
+    S, H, d, W, LOOPS = 8192, 32, 128, 1024, 4                                  # the function
+    peak = 197e12
+    q, k, v = (jax.random.normal(key, (1, S, H, d), jnp.bfloat16)
+               for key in jax.random.split(jax.random.PRNGKey(0), 3))
+
+    def looped(fn):
+        def run(q, k, v):
+            def turn(_, carry):
+                return fn(q + carry.astype(q.dtype), k, v)
+            return jax.lax.fori_loop(0, LOOPS, turn, jnp.zeros((), jnp.float32))
+        return jax.jit(run)
+
+    def forward(window):
+        return lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, window=window, force_pallas=True, interpret=False)[0, 0, 0, :2]
+            .astype(jnp.float32)) * 0
+
+    def both(window):
+        def fn(q, k, v):
+            grads = jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+                *a, causal=True, window=window, force_pallas=True, interpret=False)
+                .astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+            return sum(jnp.sum(g[0, 0, 0, :2].astype(jnp.float32)) for g in grads) * 0
+        return fn
+
+    band, causal = fa.window_block_pairs(S, W)
+    for name, window, pairs in (("window", W, band), ("causal", None, causal)):
+        need = work.attention_pairs(S, window)
+        record = {"block_pairs_visited": pairs}
+        for what, make, backward in (("fwd", forward, False), ("fwd_bwd", both, True)):
+            try:
+                ms = _ms_a_call(looped(make(window)), q, k, v, calls=5) / LOOPS
+                flops = work.attention_flops(need, H, d, backward=backward)
+                record[what] = {"ms": ms, "needed_tflops": flops / 1e12,
+                                "peak_share": 100 * flops / peak / (ms / 1e3)}
+            except Exception as e:
+                record[what] = {"refused": f"{type(e).__name__}: {e}"[:600]}
+        if window is not None:      # the same answer as the XLA reference's mask, at 2048
+            small = tuple(x[:, :2048, :4] for x in (q, k, v))
+            got = fa.flash_attention(*small, causal=True, window=W, force_pallas=True, interpret=False)
+            want = fa.flash_attention(*small, causal=True, window=W, force_pallas=False)
+            record["rel_err_vs_masked_softmax"] = float(f"{rel_err(got, want):.3e}")
+        yield f"flash_{name}_8192x32x128", record
+
+    T, kk, R, D = 32768, 8, gg.mesh_share_rows(32768, 8, gg.ExpertShare(0, 64, 64), 4, jnp.bfloat16), 2304
+    rng = np.random.default_rng(0)
+    held = np.sort(rng.choice(T * kk, 65536, replace=False)).astype(np.int32)
+    tok = np.full((R,), T, np.int32)
+    tok[:held.size] = held // kk
+    y = jax.random.normal(jax.random.PRNGKey(1), (R, D), jnp.bfloat16)
+    w = jax.random.uniform(jax.random.PRNGKey(2), (R,), jnp.bfloat16)
+    tok = jnp.asarray(tok)
+
+    def summed(fn):
+        def run(y, w, tok):
+            def turn(_, acc):
+                return acc + fn(y + acc[:1, :1].astype(y.dtype), w, tok)
+            return jax.lax.fori_loop(0, LOOPS, turn, jnp.zeros((T, D), jnp.float32))
+        return jax.jit(run)
+
+    chunks = lambda y, w, tok: gg._sum_picks_by_token(y, w, tok, T, kk)
+    segment = lambda y, w, tok: jax.ops.segment_sum(
+        y.astype(jnp.float32) * w.astype(jnp.float32)[:, None], tok, num_segments=T + 1,
+        indices_are_sorted=True)[:T]
+    record = {"rows": R, "tokens": T}
+    for name, fn in (("sorted_scatter_add_in_chunks", chunks), ("sorted_segment_sum", segment)):
+        try:
+            record[name] = {"ms": _ms_a_call(summed(fn), y, w, tok, calls=3) / LOOPS}
+        except Exception as e:
+            record[name] = {"refused": f"{type(e).__name__}: {e}"[:600]}
+    record["rel_err_chunks_vs_segment"] = float(f"{rel_err(chunks(y, w, tok), segment(y, w, tok)):.3e}")
+    live = jnp.asarray(np.isin(np.arange(T * kk), held))
+    at = jnp.arange(T * kk, dtype=jnp.int32)
+
+    def listing(live):
+        def turn(_, c):
+            return c + jnp.sort(jnp.where(live, at + c, T * kk + at))[:R][0] * 0
+        return jax.lax.fori_loop(0, LOOPS, turn, jnp.zeros((), jnp.int32))
+    record["listing_sort"] = {"ms": _ms_a_call(jax.jit(listing), live, calls=3) / LOOPS}
+    yield "exchange_sum_by_token_81920_to_32768", record
+
+
 def verdict(fn, ref, args, tol):
     import jax
 
@@ -1384,7 +1497,10 @@ def main():
     live, ssm, chunk = "--live" in sys.argv, "--ssm" in sys.argv, "--chunk" in sys.argv
     scan, kda, window = "--scan" in sys.argv, "--kda" in sys.argv, "--window" in sys.argv
     share, paged1 = "--share" in sys.argv, "--paged1" in sys.argv
-    if paged1:
+    flash_window = "--flash-window" in sys.argv
+    if flash_window:
+        section, records = "flash_window", flash_window_classes()
+    elif paged1:
         section, records = "paged_group1", paged_group1_classes()
     elif share:
         section, records = "expert_share", share_classes()
@@ -1412,7 +1528,8 @@ def main():
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
     for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk or scan or kda
-                                     or window or share or paged1 or "--gmm-only" in sys.argv
+                                     or window or share or paged1 or flash_window
+                                     or "--gmm-only" in sys.argv
                                      else cases()):
         try:
             result = verdict(fn, ref, args, tol)
@@ -1421,7 +1538,7 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("paged1_census.json" if paged1 else "share_census.json" if share else "window_census.json" if window
+    out = ("flash_window_census.json" if flash_window else "paged1_census.json" if paged1 else "share_census.json" if share else "window_census.json" if window
            else "kda_census.json" if kda
            else "scan_census.json" if scan
            else "chunk_census.json" if chunk
