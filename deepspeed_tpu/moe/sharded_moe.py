@@ -30,7 +30,7 @@ MIN_CAPACITY = 4
 # step record (``runtime/engine.py``; docs/OBSERVABILITY.md).
 STEP_COUNTS = "step_counts"
 EXCHANGE_COUNTS = ("n_expert_rows", "expert_rows_max_rank", "n_share_passes",
-                   "rows_beyond_passes")
+                   "rows_beyond_passes", "rows_by_kernel")
 
 
 def exchange_count_names(mesh):
@@ -208,10 +208,15 @@ class MOELayer(nn.Module):
             # differentiates. On an expert axis (``ops/grouped_gemm.exchanges_shares``)
             # every rank computes its own experts' share of its axis's tokens: the
             # rows gathered over the axis in the compute dtype, the picks its experts
-            # hold laid out compacted in passes of a static size (one for an even
-            # router, as many as the held picks take: none is dropped), a token's
-            # picks summed to [T, D] and that reduce-scattered onto the token's rank
-            # (``expert_share_exchange_ffn``); its counts go to the step record.
+            # hold listed in passes of a static size (one for an even router, as many
+            # as the held picks take: none is dropped), each one's row copied once
+            # from its token's row into its slot of the grouped matmul's layout and
+            # the product's row read once from there into its token's sum (two row
+            # kernels, each the other's transpose: ``ops/pallas/moe_rows.py``), and
+            # that [T, D] reduce-scattered onto the token's rank
+            # (``expert_share_exchange_ffn``); its counts go to the step record,
+            # ``rows_by_kernel`` (the rows those two kernels copied: twice the held
+            # picks, 0 where their ``jnp`` forms ran) among them.
             # With a tensor axis beside the expert axis the older dispatch stays:
             # every pick a row on every shard, the picks held elsewhere zeroed, a
             # float32 psum.
@@ -246,7 +251,8 @@ class MOELayer(nn.Module):
                                            x.dtype)
                     for name, value in zip(EXCHANGE_COUNTS, (
                             jnp.sum(held), jnp.max(jnp.sum(held, axis=0)), jnp.max(passes),
-                            jnp.sum(jnp.maximum(held - passes * rows, 0)))):
+                            jnp.sum(jnp.maximum(held - passes * rows, 0)),
+                            jnp.sum(counts[..., 2]))):
                         self.sow(STEP_COUNTS, name, value.astype(jnp.int32),
                                  reduce_fn=jnp.add, init_fn=lambda: jnp.zeros((), jnp.int32))
             else:

@@ -55,7 +55,9 @@ class GroupedGemmStats:
     Records which path each ``moe_grouped_mlp`` trace took
     (pallas/gathered/ragged, quantized or dense, and ``_table`` where
     the stacks were a table of groups indexed where it lies: see
-    ``first_group``) so bench lanes and the parity suite can assert the
+    ``first_group``), and which layout a pass of the training exchange got
+    (``pallas_exchange`` / ``ragged_exchange``: :func:`_pass_layout`), so
+    bench lanes and the parity suite can assert the
     path they think they measured is the one that ran. Serving traces
     from gateway worker threads, so all counter access takes the lock.
     """
@@ -601,7 +603,6 @@ MESH_SHARE_MARGIN = 1.5
 # a step of so few picks over the axis (a debug size: eight experts spread a hundred tokens
 # far less evenly than the margin) is laid out whole in one pass
 MESH_SHARE_SMALL = 8192
-_SUM_CHUNK_ROWS = 16384     # rows :func:`_sum_picks_by_token` adds at a time (151 MB of float32 at 2304)
 
 
 def mesh_share_rows(T, k, share, ranks, dtype):
@@ -615,80 +616,6 @@ def mesh_share_rows(T, k, share, ranks, dtype):
     tm = row_tile(max(1, T * k // columns) * held, held, dtype)
     want = T * k if T * k <= MESH_SHARE_SMALL else int(-(-T * k * held * MESH_SHARE_MARGIN // columns))
     return -(-min(max(want, held), T * k) // tm) * tm
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _sum_picks_by_token(y, w, tok, n_tokens, k):
-    """``out[t] = sum of w[r] y[r] over the rows r with tok[r] == t`` →
-    ``[n_tokens, D]`` float32, for rows **sorted by token** (``tok``
-    ascending; a row of no token carries ``n_tokens`` and lies after them),
-    a token at most ``k`` rows. A scatter-add told its indices are sorted,
-    :data:`_SUM_CHUNK_ROWS` rows at a time so that the float32 products are a
-    chunk's and not the layout's. Timed alone on a v5e (``tools/kernel_census.py
-    --flash-window``; PERF.md, PR 58), 98304 rows of 2304 onto 32768 tokens:
-    this form 19.3 ms, ONE sorted ``segment_sum`` over all the rows 8.8 (its
-    products are 906 MB of float32 at once), and PR 58's first form - blocks of
-    128 tokens on the matrix unit, each block's ``128 k`` candidate rows
-    gathered - 19.3 at 81920 rows: the chunking costs what the blocks did, and
-    the single scatter-add is the next thing to try where the step's memory
-    allows (not run in the step: the chip budget was spent). Serving's
-    one-product form multiplies every token by every row. The backward is a
-    gather: a row's cotangent is its token's."""
-    R, D = y.shape
-    chunk = min(R, _SUM_CHUNK_ROWS)
-    pad = -R % chunk
-    rows = jnp.pad(y, ((0, pad), (0, 0))).reshape(-1, chunk, D)
-    weights = jnp.pad(w, (0, pad)).reshape(-1, chunk)
-    tokens = jnp.pad(tok, (0, pad), constant_values=n_tokens).reshape(-1, chunk)
-
-    def add(acc, part):
-        yc, wc, tc = part
-        return acc.at[tc].add(yc.astype(jnp.float32) * wc.astype(jnp.float32)[:, None],
-                              indices_are_sorted=True, mode="drop"), None
-
-    out, _ = jax.lax.scan(add, jnp.zeros((n_tokens, D), jnp.float32), (rows, weights, tokens))
-    return out
-
-
-def _sum_picks_fwd(y, w, tok, n_tokens, k):
-    return _sum_picks_by_token(y, w, tok, n_tokens, k), (y, w, tok)
-
-
-def _sum_picks_bwd(n_tokens, k, res, dout):
-    y, w, tok = res
-    rows = _rows_of_tokens_impl(dout, tok, n_tokens)
-    dy = (rows * w.astype(jnp.float32)[:, None]).astype(y.dtype)
-    dw = jnp.sum(rows * y.astype(jnp.float32), axis=-1).astype(w.dtype)
-    return dy, dw, np.zeros(tok.shape, dtype=jax.dtypes.float0)
-
-
-_sum_picks_by_token.defvjp(_sum_picks_fwd, _sum_picks_bwd)
-
-
-def _rows_of_tokens_impl(x, tok, n_tokens):
-    rows = jnp.take(x, jnp.minimum(tok, n_tokens - 1), axis=0)
-    return jnp.where((tok < n_tokens)[:, None], rows, 0)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rows_of_tokens(x, tok, k):
-    """``x[tok]`` for rows sorted by token (a row of no token, ``tok ==
-    len(x)``, is zero): the gather whose backward is
-    :func:`_sum_picks_by_token` and not a scatter-add."""
-    return _rows_of_tokens_impl(x, tok, x.shape[0])
-
-
-def _rows_of_tokens_fwd(x, tok, k):
-    return _rows_of_tokens_impl(x, tok, x.shape[0]), (tok, jnp.zeros((x.shape[0], 0), x.dtype))
-
-
-def _rows_of_tokens_bwd(k, res, drows):
-    tok, proto = res
-    dx = _sum_picks_by_token(drows, jnp.ones(tok.shape, drows.dtype), tok, proto.shape[0], k)
-    return dx.astype(proto.dtype), np.zeros(tok.shape, dtype=jax.dtypes.float0)
-
-
-_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 def mesh_share_axes(mesh):
@@ -707,32 +634,39 @@ def expert_share_exchange_ffn(x, topk_idx, topk_vals, w1, w3, w2, share, mesh,
                               activation=jax.nn.silu):
     """:func:`dropless_moe_ffn` over an expert axis: every rank computes its
     own share and the ranks exchange tokens on either side of it → (``[T,
-    D]``, counts ``[copies over data, ranks, 2]`` int32: a rank's held picks
-    and the passes they took). Differentiable in ``x``, ``topk_vals`` and
-    the stacks.
+    D]``, counts ``[copies over data, ranks, 3]`` int32: a rank's held picks,
+    the passes they took and the rows its two row kernels copied).
+    Differentiable in ``x``, ``topk_vals`` and the stacks.
 
     ``x`` [T, D] is split by token over ``data`` and ``expert``; the stacks
     ``[share.held, ...]`` by expert over ``expert``. A rank (a) gathers the
     rows, picks and weights of its expert axis's tokens in the compute dtype
     (``T_a = T / data`` rows), (b) lists **the picks its own experts hold**,
-    in order, and lays out those alone, :func:`mesh_share_rows` rows at a
-    pass - a static size, :data:`MESH_SHARE_MARGIN` over an even router's
-    share: one pass then, ``ceil(held picks / rows)`` whatever the router
-    does, so **no pick is ever dropped** -, (c) runs the three grouped matmuls
-    over a pass's rows (:func:`moe_grouped_mlp`), (d) weights and sums a
-    token's rows into ``[T_a, D]`` float32 (:func:`_sum_picks_by_token`: the
-    rows are sorted by token), and (e) reduce-scatters that over the axis, in
-    the compute dtype, onto the rank that owns each token. Nothing of ``T k``
-    rows by the model's width is built, summed or sent.
+    in order, :func:`mesh_share_rows` at a pass - a static size,
+    :data:`MESH_SHARE_MARGIN` over an even router's share: one pass then,
+    ``ceil(held picks / rows)`` whatever the router does, so **no pick is
+    ever dropped** - and gives each its slot of the grouped matmul's
+    tile-aligned layout (:func:`_pass_layout`: integers alone), (c) copies
+    each held pick's row **once** from its token's row into its slot
+    (``ops/pallas/moe_rows.gather_rows``: a kernel that copies whole rows by
+    DMA; a tile's padding is written as zeros and read from nowhere), (d)
+    runs the three grouped matmuls over the layout, (e) reads the down
+    product's row **once** from its slot into its token's weighted sum,
+    ``[T_a, D]`` float32 (``gather_sum_rows``: the same kernel transposed;
+    a token's picks that are not held here are skipped), and (f)
+    reduce-scatters that over the axis, in the compute dtype, onto the rank
+    that owns each token. Nothing of ``T k`` rows by the model's width is
+    built, summed or sent, and no array in pick order exists.
 
     **The backward** is the same exchange transposed (a gather of the
     output's cotangent, a reduce-scatter of the rows'), and between them the
     passes again: the number of passes is read on the device, so the loop is
     no ``scan`` to transpose - :func:`_share_passes` is a ``custom_vjp`` whose
     backward runs each pass's forward again and pulls the cotangent through
-    it, summing the stacks' gradients over the passes. It keeps no residual
-    but its inputs (under any ``remat_policy`` the expert layer's forward
-    runs once in the forward and once in the backward).
+    it (each row kernel's cotangent is the other kernel), summing the stacks'
+    gradients over the passes. It keeps no residual but its inputs (under any
+    ``remat_policy`` the expert layer's forward runs once in the forward and
+    once in the backward).
 
     An all-to-all of the held picks would move 0.92 of what the gather moves
     at 8 picks over 4 ranks (a token misses a rank with probability 0.085) and
@@ -757,14 +691,14 @@ def expert_share_exchange_ffn(x, topk_idx, topk_vals, w1, w3, w2, share, mesh,
                                        for a in (x_l, idx_l, val_l))
         first = share.first + jax.lax.axis_index("expert") * held
         with jax.named_scope("ds.moe_experts"):
-            out, n_held = _share_passes(
+            out, n_held, n_copied = _share_passes(
                 x_all, val_all.reshape(-1), w1s.astype(dtype), w3s.astype(dtype),
                 w2s.astype(dtype), idx_all.reshape(-1) - first, k, held, cap, rows_a_group,
                 activation)
         with jax.named_scope("ds.moe_exchange"):
             out_l = jax.lax.psum_scatter(out.astype(_exchange_dtype(dtype, mesh)), "expert",
                                          scatter_dimension=0, tiled=True).astype(dtype)
-        return out_l, jnp.stack([n_held, (n_held + cap - 1) // cap])[None]
+        return out_l, jnp.stack([n_held, (n_held + cap - 1) // cap, n_copied])[None]
 
     tokens = P(axes if len(axes) > 1 else axes[0])
     stacks = P("expert")
@@ -773,52 +707,110 @@ def expert_share_exchange_ffn(x, topk_idx, topk_vals, w1, w3, w2, share, mesh,
         body, mesh=mesh, in_specs=(tokens, tokens, tokens, stacks, stacks, stacks),
         out_specs=(tokens, every), check_vma=False))(x, topk_idx, topk_vals, w1, w3, w2)
     # one row a device; the ranks of one expert axis differ, its copies over data do not
-    return out, counts.reshape(-1, ep, 2)
+    return out, counts.reshape(-1, ep, 3)
 
 
-def _pass_of(p, order, n_held, x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group,
-             activation):
-    """What pass ``p`` of a rank's held picks adds to ``[T_a, D]`` float32: the
-    picks ``order[p cap : (p + 1) cap]`` (flat indices ``t k + j``, ascending:
-    a token's adjacent) laid out, multiplied, weighted and summed by token."""
-    Ta = x_all.shape[0]
-    pick = jax.lax.dynamic_slice_in_dim(order, p * cap, cap)
+def _use_pallas_rows(width, dtype):
+    """Whether the two row kernels (``ops/pallas/moe_rows.py``) move a pass's
+    rows: on TPU (or interpreted, under FORCE_INTERPRET) wherever they can
+    address a row; elsewhere their ``jnp`` forms do."""
+    from deepspeed_tpu.ops.pallas.moe_rows import rows_kernel_supported
+    if not rows_kernel_supported(width, dtype):
+        return False
+    return FORCE_INTERPRET or jax.devices()[0].platform == "tpu"
+
+
+def _pass_layout(experts, here, picks, n_tokens, k, held, rows_a_group, d_model, d_ff, dtype):
+    """Where a pass's picks lie, as integers alone → (``slot_token`` [S]:
+    the token whose row each slot of the layout gets, ``n_tokens`` where it
+    gets none - a tile's padding, a pick that is no pick -; ``slots``
+    [n_tokens, k]: the slot of each token's ``j``-th pick, ``S`` where this
+    pass does not hold it; ``matmul(rows [S, ...], stack)``: one grouped
+    matmul over that layout). ``experts`` / ``here`` / ``picks`` [cap]: each
+    listed pick's expert, whether it is one, and its flat index ``t k + j``.
+
+    On TPU the layout is the Pallas grouped matmul's - every expert's rows
+    padded to whole row tiles (:func:`_tile_routing`), the tiles past the
+    groups' skipped -; elsewhere the picks sorted by expert, for
+    ``lax.ragged_dot``."""
+    cap = experts.shape[0]
+    if _use_pallas_gmm(cap, held, d_model, d_ff, dtype):
+        from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile
+        GMM_STATS.count("pallas_exchange")
+        tm = row_tile(rows_a_group * held, held, dtype)
+        slot, te, num_tiles = _tile_routing(experts, held, tm, here)   # not here: past the layout
+        n_slots = te.shape[0] * tm
+
+        def matmul(rows, w):
+            return _gmm_dispatch(rows, w, te, tm, FORCE_INTERPRET, None, num_tiles)
+    else:
+        GMM_STATS.count("ragged_exchange")
+        group = jnp.where(here, experts, held)        # what is no pick sorts behind every group
+        slot = jnp.argsort(jnp.argsort(group, stable=True)).astype(jnp.int32)
+        sizes = jnp.bincount(group, length=held + 1)[:held]
+        n_slots = cap
+
+        def matmul(rows, w):
+            return grouped_gemm(rows, w, sizes).astype(rows.dtype)
+
+    slot_token = jnp.full((n_slots,), n_tokens, jnp.int32).at[slot].set(
+        jnp.where(here, picks // k, n_tokens), mode="drop", unique_indices=True)
+    slots = jnp.full((n_tokens * k,), n_slots, jnp.int32).at[
+        jnp.where(here, picks, n_tokens * k)].set(slot, mode="drop", unique_indices=True)
+    return slot_token, slots.reshape(n_tokens, k), matmul
+
+
+def _pass_of(p, order, n_held, x_all, vals, w1, w3, w2, k, held, cap, rows_a_group, activation):
+    """What pass ``p`` of a rank's held picks adds to ``[T_a, D]`` float32 - the
+    picks ``order[0][p cap : (p + 1) cap]`` (flat indices ``t k + j``, ascending;
+    ``order[1]``: their experts) laid out (:func:`_pass_layout`), each one's row
+    copied from its token's into its slot, multiplied, and a token's slots read,
+    weighted and summed - and the rows the two kernels copied for it (0 where
+    the ``jnp`` forms ran)."""
+    from deepspeed_tpu.ops.pallas.moe_rows import gather_rows, gather_sum_rows
+    Ta, D = x_all.shape
+    pick, expert = (jax.lax.dynamic_slice_in_dim(a, p * cap, cap) for a in order)
     here = p * cap + jnp.arange(cap, dtype=jnp.int32) < n_held
     pick = jnp.where(here, pick, 0)
-    tok = jnp.where(here, pick // k, Ta)
-    rows = _rows_of_tokens(x_all, tok, k)
-    y = moe_grouped_mlp(rows, jnp.where(here, jnp.take(experts, pick), 0), w1, w3, w2,
-                        num_experts=held, activation=activation, live=here,
-                        rows_a_group=rows_a_group)
-    weights = jnp.where(here, jnp.take(vals, pick), 0).astype(x_all.dtype)
-    return _sum_picks_by_token(y, weights, tok, Ta, k)
+    slot_token, slots, matmul = _pass_layout(
+        jnp.where(here, expert, 0), here, pick, Ta, k, held, rows_a_group, D, w1.shape[-1],
+        x_all.dtype)
+    kernel = _use_pallas_rows(D, x_all.dtype)
+    xp = gather_rows(x_all, slot_token, slots, kernel, FORCE_INTERPRET)
+    y = matmul(activation(matmul(xp, w1)) * matmul(xp, w3), w2)
+    out = gather_sum_rows(y, slots, vals.reshape(Ta, k), slot_token, kernel, FORCE_INTERPRET)
+    copied = jnp.sum(slot_token < Ta, dtype=jnp.int32) + jnp.sum(slots < y.shape[0],
+                                                                 dtype=jnp.int32)
+    return out, copied * int(kernel)
 
 
 def _held_order(experts, held, cap):
-    """→ (the flat indices of the picks whose expert - ``experts`` counts from
-    this rank's first - is held here, ascending, then filler, padded to whole
-    passes; how many are held)."""
+    """→ ((the flat indices of the picks whose expert - ``experts`` counts from
+    this rank's first - is held here, ascending, then filler; each one's
+    expert, sorted along with it), both padded to whole passes; how many are
+    held)."""
     n = experts.shape[0]
     live = (experts >= 0) & (experts < held)
     at = jnp.arange(n, dtype=jnp.int32)
-    order = jnp.sort(jnp.where(live, at, n + at))
-    return jnp.pad(order, (0, -n % cap + cap)), jnp.sum(live, dtype=jnp.int32)
+    order = jax.lax.sort((jnp.where(live, at, n + at), experts), num_keys=1)
+    return tuple(jnp.pad(a, (0, -n % cap + cap)) for a in order), jnp.sum(live, dtype=jnp.int32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _share_passes(x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group, activation):
     """Every pass of a rank's held picks (:func:`_pass_of`) summed →
-    (``[T_a, D]`` float32, the held picks). ``experts`` [T_a k] int32: each
-    pick's expert counted from this rank's first (held: ``0 <= e < held``)."""
+    (``[T_a, D]`` float32, the held picks, the rows the row kernels copied).
+    ``experts`` [T_a k] int32: each pick's expert counted from this rank's
+    first (held: ``0 <= e < held``)."""
     order, n_held = _held_order(experts, held, cap)
 
-    def one(p, acc):
-        return acc + _pass_of(p, order, n_held, x_all, vals, w1, w3, w2, experts, k, held, cap,
-                              rows_a_group, activation)
+    def one(p, carry):
+        return jax.tree.map(jnp.add, carry, _pass_of(
+            p, order, n_held, x_all, vals, w1, w3, w2, k, held, cap, rows_a_group, activation))
 
-    out = jax.lax.fori_loop(0, (n_held + cap - 1) // cap, one,
-                            jnp.zeros(x_all.shape, jnp.float32))
-    return out, n_held
+    out, copied = jax.lax.fori_loop(0, (n_held + cap - 1) // cap, one,
+                                    (jnp.zeros(x_all.shape, jnp.float32), jnp.int32(0)))
+    return out, n_held, copied
 
 
 def _share_passes_fwd(x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group, activation):
@@ -833,8 +825,8 @@ def _share_passes_bwd(k, held, cap, rows_a_group, activation, res, cts):
     args = (x_all, vals, w1, w3, w2)
 
     def one(p, grads):
-        _, vjp = jax.vjp(lambda *a: _pass_of(p, order, n_held, *a, experts, k, held, cap,
-                                             rows_a_group, activation), *args)
+        _, vjp = jax.vjp(lambda *a: _pass_of(p, order, n_held, *a, k, held, cap, rows_a_group,
+                                             activation)[0], *args)
         return jax.tree.map(lambda g, d: g + d.astype(g.dtype), grads, vjp(dout))
 
     # the stacks' gradients summed over the passes in float32, the rest in their own dtype

@@ -130,15 +130,21 @@ def test_bf16_expert4_is_the_reference_to_rounding(reference):
     assert losses[2] < losses[1] < losses[0]
 
 
-def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch):
+@pytest.mark.parametrize("row_kernels", [False, True], ids=["jnp_rows", "row_kernels"])
+def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch, row_kernels):
     """(c): one expert layer under ``expert=4``. What rank ``r``'s two experts
     add, by the reference's loop over experts, summed over the four ranks is
     the reference's uncut layer, and the exchange's output is that sum
-    (float32: 1e-5). With the pass's margin taken away and a router that sends
-    every pick to one rank, that rank runs four passes where an even router
-    takes one, and output and gradient are still exact: a pass has a static
-    size, their number is the router's."""
+    (float32: 1e-5), its gradients in the rows, the picks' weights and the
+    three stacks those of the plain sum over experts. With the pass's margin
+    taken away and a router that sends every pick to one rank, that rank runs
+    four passes where an even router takes one, and output and gradients are
+    still exact: a pass has a static size, their number is the router's.
+    ``row_kernels``: once with the rows moved by the ``jnp`` forms (this
+    backend's choice), once through the two Pallas row kernels and the Pallas
+    grouped matmul, interpreted - the path a TPU takes."""
     from deepspeed_tpu.ops import grouped_gemm as gg
+    monkeypatch.setattr(gg, "FORCE_INTERPRET", row_kernels)
     params = _host(mellum.seeded_params(CFG, 11))
     p = mellum.layer_params(params, CFG, 3)
     h = jnp.asarray(np.random.default_rng(3).standard_normal((4, 32, CFG.hidden_size)), jnp.float32)
@@ -148,17 +154,46 @@ def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch):
                   for r in range(4)]
         np.testing.assert_allclose(sum(shares), whole, atol=1e-5)
         m = mellum._rms(h, p["post_attention_layernorm"]["scale"], CFG.rms_norm_eps)
-        _, picks, weights = mellum.reference_route(p, m.reshape(-1, CFG.hidden_size), CFG)
+        flat = m.reshape(-1, CFG.hidden_size)
+        _, picks, weights = mellum.reference_route(p, flat, CFG)
     moe = p["moe_mlp"]["deepspeed_moe"]
+    stacks = tuple(moe[name] for name in ("experts_w1", "experts_w3", "experts_w2"))
     mesh = make_mesh_topology(expert=4, devices=jax.devices()[:4])
     share = gg.ExpertShare(0, CFG.num_experts, CFG.num_experts)
 
-    def exchange(picks, weights):
-        return jax.jit(lambda x, i, w: gg.expert_share_exchange_ffn(
-            x, i, w, moe["experts_w1"], moe["experts_w3"], moe["experts_w2"], share, mesh))(
-            m.reshape(-1, CFG.hidden_size), picks, weights)
+    def exchange(picks):
+        def loss(x, w, *stacks):
+            out, counts = jax.jit(lambda *a: gg.expert_share_exchange_ffn(*a, share, mesh))(
+                x, picks, w, *stacks)
+            return jnp.sum(out * jnp.cos(out)), (out, counts)
+        return loss
 
-    out, counts = exchange(picks, weights)
+    def plain(picks):
+        def loss(x, w, w1, w3, w2):
+            each = jnp.stack([(jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e]
+                              for e in range(CFG.num_experts)])
+            out = sum(w[:, j:j + 1] * each[picks[:, j], jnp.arange(x.shape[0])]
+                      for j in range(picks.shape[1]))
+            return jnp.sum(out * jnp.cos(out)), (out, None)
+        return loss
+
+    def both(picks):
+        """→ the exchange's output and counts, after holding output and the five
+        gradients to the plain sum's."""
+        (_, (out, counts)), grads = jax.value_and_grad(
+            exchange(picks), argnums=range(5), has_aux=True)(flat, weights, *stacks)
+        with jax.default_matmul_precision("highest"):
+            (_, (want, _)), want_grads = jax.value_and_grad(
+                plain(picks), argnums=range(5), has_aux=True)(flat, weights, *stacks)
+        np.testing.assert_allclose(out, want, atol=1e-5)
+        for name, got, wanted in zip(("x", "topk_vals", "w1", "w3", "w2"), grads, want_grads):
+            np.testing.assert_allclose(got, wanted, atol=1e-5 + 1e-5 * np.abs(wanted).max(),
+                                       err_msg=name)
+        # every held pick's row went through each of the two kernels once, and no padding
+        assert (counts[..., 2] == 2 * counts[..., 0] * row_kernels).all()
+        return out, counts
+
+    out, counts = both(picks)
     np.testing.assert_allclose(out.reshape(whole.shape), whole, atol=1e-5)
     assert int(counts[..., 0].sum()) == picks.size and counts[0, :, 1].tolist() == [1, 1, 1, 1]
     for r in range(4):      # a rank's count is the picks of its two experts
@@ -167,28 +202,11 @@ def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch):
     monkeypatch.setattr(gg, "MESH_SHARE_MARGIN", 1.0)
     monkeypatch.setattr(gg, "MESH_SHARE_SMALL", 0)      # the margin's rule at this size too
     crowded = jnp.stack([jnp.zeros_like(picks[:, 0]), jnp.ones_like(picks[:, 0])], axis=1)
-
-    def loss(w):
-        out, counts = exchange(crowded, w)
-        return jnp.sum(out * jnp.cos(out)), (out, counts)
-
-    (_, (out, counts)), dw = jax.value_and_grad(loss, has_aux=True)(weights)
+    _, counts = both(crowded)                            # the backward walks the four passes too
     rows = gg.mesh_share_rows(picks.shape[0], 2, share, 4, jnp.float32)
     assert rows == picks.size // 4                       # an even router's share, no room
     assert counts[0, :, 0].tolist() == [picks.size, 0, 0, 0]
     assert counts[0, :, 1].tolist() == [4, 0, 0, 0]      # four passes on the one rank, none lost
-    with jax.default_matmul_precision("highest"):
-        flat = m.reshape(-1, CFG.hidden_size)
-
-        def plain(w):
-            each = [(jax.nn.silu(flat @ moe["experts_w1"][e]) * (flat @ moe["experts_w3"][e]))
-                    @ moe["experts_w2"][e] for e in (0, 1)]
-            out = w[:, :1] * each[0] + w[:, 1:] * each[1]
-            return jnp.sum(out * jnp.cos(out)), out
-
-        (_, want), want_dw = jax.value_and_grad(plain, has_aux=True)(weights)
-    np.testing.assert_allclose(out, want, atol=1e-5)
-    np.testing.assert_allclose(dw, want_dw, atol=1e-5)   # the backward walks the four passes too
 
 
 def _masked_softmax(q, k, v, window, seg):

@@ -361,3 +361,29 @@ def test_the_tail_pool_rides_the_layer_loop_as_it_arrives(one_chip):
     assert memory.temp_size_in_bytes < 4 * table_bytes < pool_bytes // 3
     layouts = set(re.findall(rf"bf16\[{L},{NS},{K - 1},{C}\](\{{[^}}]*\}})", compiled.as_text()))
     assert len(layouts) == 1, layouts
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_mosaic_takes_the_row_kernels_at_mellums_shape(one_chip, dtype):
+    """Compiled for a described v5e (nothing runs): the training exchange's two
+    row kernels (``ops/pallas/moe_rows.py``) at ``mellum2-12b-moe8k-x4``'s
+    shape - 32768 tokens of 2304, a layout of 102400 slots, 8 picks a token -
+    each behind its pass that lays the source out a row at a time, and nothing
+    of ``T k`` rows among the temporaries (the largest is the source's copy).
+    A DMA of one row of the tiled ``[N, D]`` array itself is what Mosaic
+    refuses (``Slice shape along dimension 0 must be aligned to tiling``)."""
+    from deepspeed_tpu.ops.pallas import moe_rows
+    T, D, S, k = 32768, 2304, 102400, 8
+    width = jnp.dtype(dtype).itemsize
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    gather = _compiled(lambda x, i: moe_rows.gather_rows(x, i, None, True, False),
+                       (sds((T, D), dtype), sds((S,), jnp.int32)))
+    assert gather.memory_analysis().temp_size_in_bytes < 1.1 * T * D * width
+    summed = _compiled(lambda y, s, w: moe_rows.gather_sum_rows(y, s, w, None, True, False),
+                       (sds((S, D), dtype), sds((T, k), jnp.int32), sds((T, k), jnp.float32)))
+    assert summed.memory_analysis().temp_size_in_bytes < 1.1 * S * D * width
+    for text, name in ((gather.as_text(), "moe_rows_gather"), (summed.as_text(), "moe_rows_sum")):
+        assert name in text and "moe_rows_pack" in text

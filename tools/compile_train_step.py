@@ -91,6 +91,8 @@ def main(argv=None):
         d % 128 == 0 and f % 128 == 0 and rows >= experts
         and col_tile(d, f, jnp.dtype(dtype).itemsize) is not None
         and col_tile(f, d, jnp.dtype(dtype).itemsize) is not None)
+    from deepspeed_tpu.ops.pallas.moe_rows import rows_kernel_supported
+    grouped_gemm._use_pallas_rows = rows_kernel_supported     # the exchange's row kernels likewise
 
     from benchmark.harness import spec
     bench = spec.Benchmark(ROOT)

@@ -30,12 +30,20 @@ at ``mellum2-12b-moe8k-x4``'s shape - one sequence of 8192 x 32 heads x 128 -
 the windowed kernels (``flash_window_fwd`` / ``_dkv`` / ``_dq``, a window of
 1024) beside the causal ones, forward and forward + backward, from a loop
 inside one program: ms a call, the block pairs the grid visits (15 of 36) and
-the share of the bf16 peak of the operations the band or the triangle needs;
-then the expert exchange's sum by token (81920 laid-out rows onto 32768
-tokens: the program's sorted scatter-add in chunks beside one ``segment_sum``;
-PR 58's first form, blocks of 128 tokens on the matrix unit, read 19.3 ms where
-the ``segment_sum`` read 8.0) and its
-listing sort (~3 min on one chip, ``chiprun_out/flash_window_census.json``).
+the share of the bf16 peak of the operations the band or the triangle needs
+(~1.5 min on one chip, ``chiprun_out/flash_window_census.json``).
+
+``--moe-rows`` (PR 59) times the two row kernels of the training exchange
+(``ops/pallas/moe_rows.py``: ``gather_rows`` / ``gather_sum_rows``) alone at
+that cell's shape - 32768 tokens of 2304 in bf16, one pass's layout of 102400
+slots (98304 + 16 experts' padding), 8 picks a token of which ~65536 are held
+- each from a loop inside one program, with the part of each that lays the
+source out a row at a time (``moe_rows_pack``) left out, at three block sizes, and
+beside them the XLA forms they replace: ``jnp.take`` of the tokens' rows and
+the scatter into the layout, the gather back and the sorted ``segment_sum``.
+ms a call and GB/s of useful rows (held rows x 4608 bytes, read and written
+once), and that kernel and ``jnp`` form agree (~2 min,
+``chiprun_out/moe_rows_census.json``).
 
 ``--paged`` times ``paged_decode_attention`` instead, at the shape classes
 the two key-value cells serve (8 KV heads x 128, 16-row bf16 blocks, a
@@ -1372,16 +1380,10 @@ def flash_window_classes():
     causal ones - forward, and forward + backward (``dkv`` and ``dq``) - each
     from a loop inside one program. Yields the time a call, the block pairs the
     grid visits, and the share of the bf16 peak of the operations the band or
-    the triangle NEEDS (``benchmark/readers/mellum.py``). Then the expert
-    exchange's sum by token (``ops/grouped_gemm._sum_picks_by_token``: a rank's
-    81920 laid-out rows of 2304 onto 32768 tokens, a sorted scatter-add in
-    chunks) beside one sorted ``segment_sum``, and its listing of the held
-    picks (the sort)."""
+    the triangle NEEDS (``benchmark/readers/mellum.py``)."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from benchmark.readers import mellum as work
-    from deepspeed_tpu.ops import grouped_gemm as gg
     import importlib
     fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")   # the module, not
     S, H, d, W, LOOPS = 8192, 32, 128, 1024, 4                                  # the function
@@ -1428,42 +1430,102 @@ def flash_window_classes():
             record["rel_err_vs_masked_softmax"] = float(f"{rel_err(got, want):.3e}")
         yield f"flash_{name}_8192x32x128", record
 
-    T, kk, R, D = 32768, 8, gg.mesh_share_rows(32768, 8, gg.ExpertShare(0, 64, 64), 4, jnp.bfloat16), 2304
-    rng = np.random.default_rng(0)
-    held = np.sort(rng.choice(T * kk, 65536, replace=False)).astype(np.int32)
-    tok = np.full((R,), T, np.int32)
-    tok[:held.size] = held // kk
-    y = jax.random.normal(jax.random.PRNGKey(1), (R, D), jnp.bfloat16)
-    w = jax.random.uniform(jax.random.PRNGKey(2), (R,), jnp.bfloat16)
-    tok = jnp.asarray(tok)
 
-    def summed(fn):
-        def run(y, w, tok):
-            def turn(_, acc):
-                return acc + fn(y + acc[:1, :1].astype(y.dtype), w, tok)
-            return jax.lax.fori_loop(0, LOOPS, turn, jnp.zeros((T, D), jnp.float32))
+def moe_rows_classes(T=32768, D=2304, n_held=65536, interpret=False):
+    """``mellum2-12b-moe8k-x4``'s row moves alone (PR 59): one pass of a rank's
+    held picks as :func:`ops.grouped_gemm._pass_layout` lays it out (random
+    picks of 16 experts, the layout of the Pallas grouped matmul), the two row
+    kernels beside the XLA forms PR 58 ran, each from a loop inside one program.
+    GB/s counts the held rows alone, once read and once written. (The arguments:
+    a rehearsal's, interpreted on the CPU at a small size under
+    ``grouped_gemm.FORCE_INTERPRET``, which gives the chip's layout there.)"""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from deepspeed_tpu.ops import grouped_gemm as gg
+    from deepspeed_tpu.ops.pallas import moe_rows as mr
+    from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile
+    k, held, LOOPS = 8, 16, 4
+    dtype = jnp.bfloat16
+    cap = gg.mesh_share_rows(T, k, gg.ExpertShare(0, 64, 64), 4, dtype)
+    rng = np.random.default_rng(0)
+    pick = np.zeros((cap,), np.int32)
+    pick[:n_held] = np.sort(rng.choice(T * k, n_held, replace=False))
+    here = jnp.arange(cap) < n_held
+    experts = jnp.asarray(rng.integers(0, held, cap).astype(np.int32))
+    pick = jnp.asarray(pick)
+    slot_token, slots = jax.jit(lambda e, h, p: gg._pass_layout(
+        e, h, p, T, k, held, T * k // 64, D, 896, dtype)[:2])(experts, here, pick)
+    S = slot_token.shape[0]
+    tm = row_tile(T * k // 64 * held, held, dtype)
+    slot_of_pick, _, _ = gg._tile_routing(jnp.where(here, experts, 0), held, tm, here)
+    tok_of_pick = jnp.where(here, pick // k, T)
+    x = jax.random.normal(jax.random.PRNGKey(1), (T, D), dtype)
+    y = jax.random.normal(jax.random.PRNGKey(2), (S, D), dtype)
+    w = jax.random.uniform(jax.random.PRNGKey(3), (T, k), jnp.float32)
+    useful = 2 * n_held * D * jnp.dtype(dtype).itemsize
+
+    def looped(fn, out_shape, out_dtype):
+        def run(src, index, *rest):
+            def turn(_, out):       # the indices a function of the carry (plus 0), or the call
+                moved = (out.ravel()[0] != out.ravel()[0]).astype(jnp.int32)   # leaves the loop
+                return fn(src, index + moved, *rest)
+            return jax.lax.fori_loop(0, LOOPS, turn, jnp.zeros(out_shape, out_dtype))
         return jax.jit(run)
 
-    chunks = lambda y, w, tok: gg._sum_picks_by_token(y, w, tok, T, kk)
-    segment = lambda y, w, tok: jax.ops.segment_sum(
-        y.astype(jnp.float32) * w.astype(jnp.float32)[:, None], tok, num_segments=T + 1,
-        indices_are_sorted=True)[:T]
-    record = {"rows": R, "tokens": T}
-    for name, fn in (("sorted_scatter_add_in_chunks", chunks), ("sorted_segment_sum", segment)):
+    def timed(name, fn, out_shape, out_dtype, *args):
         try:
-            record[name] = {"ms": _ms_a_call(summed(fn), y, w, tok, calls=3) / LOOPS}
+            ms = _ms_a_call(looped(fn, out_shape, out_dtype), *args, calls=3) / LOOPS
+            record[name] = {"ms": ms, "useful_GBps": useful / ms / 1e6}
         except Exception as e:
             record[name] = {"refused": f"{type(e).__name__}: {e}"[:600]}
-    record["rel_err_chunks_vs_segment"] = float(f"{rel_err(chunks(y, w, tok), segment(y, w, tok)):.3e}")
-    live = jnp.asarray(np.isin(np.arange(T * kk), held))
-    at = jnp.arange(T * kk, dtype=jnp.int32)
+        print(json.dumps({name: record[name]}), file=sys.stderr, flush=True)   # as it goes
 
-    def listing(live):
-        def turn(_, c):
-            return c + jnp.sort(jnp.where(live, at + c, T * kk + at))[:R][0] * 0
-        return jax.lax.fori_loop(0, LOOPS, turn, jnp.zeros((), jnp.int32))
-    record["listing_sort"] = {"ms": _ms_a_call(jax.jit(listing), live, calls=3) / LOOPS}
-    yield "exchange_sum_by_token_81920_to_32768", record
+    # dispatch: the tokens' rows into the layout
+    def xla_dispatch(x, tok_of_pick, slot_of_pick):         # PR 58: pick order between them
+        rows = jnp.where((tok_of_pick < T)[:, None],
+                         jnp.take(x, jnp.minimum(tok_of_pick, T - 1), axis=0), 0)
+        return jnp.zeros((S, D), dtype).at[slot_of_pick].set(rows, unique_indices=True)
+
+    x_rows, y_rows = mr._as_rows(x, interpret), mr._as_rows(y, interpret)
+    pack = jax.jit(lambda a: mr._as_rows(a, interpret))
+    record = {"tokens": T, "slots": S, "held_rows": n_held, "row_bytes": D * 2,
+              # not from a loop (its input would not change): twenty calls queued from the host
+              "pack_tokens_alone_ms": _ms_a_call(pack, x)}
+    timed("jnp_take_into_layout", lambda x, i: mr.gather_rows(x, i), (S, D), dtype, x, slot_token)
+    timed("xla_take_then_scatter", xla_dispatch, (S, D), dtype, x, tok_of_pick, slot_of_pick)
+    # the kernel alone: the source already a row at a time
+    for block in (128, 256, 512):
+        timed(f"gather_rows_packed_block{block}", lambda r, i: mr._gather_packed(
+            r, i, dtype, block, interpret), (S, D), dtype, x_rows, slot_token)
+    timed("gather_rows", lambda x, i: mr.gather_rows(x, i, None, True, interpret), (S, D), dtype,
+          x, slot_token)
+    got = mr.gather_rows(x, slot_token, None, True, interpret)
+    record["gather_rows_equal_jnp"] = bool(jnp.array_equal(got, mr.gather_rows(x, slot_token)))
+    yield "moe_rows_dispatch_32768_to_102400", record
+
+    # combine: the layout's rows into their tokens' sums
+    def xla_combine(y, slot_of_pick, tok_of_pick, w_of_pick):  # PR 58: gather back, sorted sum
+        rows = jnp.take(y, slot_of_pick, axis=0, mode="fill", fill_value=0).astype(jnp.float32)
+        return jax.ops.segment_sum(rows * w_of_pick[:, None], tok_of_pick, num_segments=T + 1,
+                                   indices_are_sorted=True)[:T]
+
+    w_of_pick = jnp.where(here, jnp.take(w.reshape(-1), pick), 0)
+    record = {"tokens": T, "slots": S, "held_rows": n_held, "row_bytes": D * 2,
+              "pack_layout_alone_ms": _ms_a_call(pack, y)}
+    timed("xla_take_then_segment_sum", xla_combine, (T, D), jnp.float32, y, slot_of_pick,
+          tok_of_pick, w_of_pick)
+    for block in (8, 16, 32):
+        timed(f"gather_sum_rows_packed_block{block}", lambda r, s, w: mr._sum_packed(
+            r, s, w, dtype, block, interpret), (T, D), jnp.float32, y_rows, slots, w)
+    timed("gather_sum_rows_unweighted", lambda y, s: mr.gather_sum_rows(
+        y, s, None, None, True, interpret), (T, D), jnp.float32, y, slots)
+    timed("gather_sum_rows", lambda y, s, w: mr.gather_sum_rows(y, s, w, None, True, interpret),
+          (T, D), jnp.float32, y, slots, w)
+    got = mr.gather_sum_rows(y, slots, w, None, True, interpret)
+    record["rel_err_vs_segment_sum"] = float(
+        f"{rel_err(got, xla_combine(y, slot_of_pick, tok_of_pick, w_of_pick)):.3e}")
+    yield "moe_rows_combine_102400_to_32768", record
 
 
 def verdict(fn, ref, args, tol):
@@ -1497,8 +1559,10 @@ def main():
     live, ssm, chunk = "--live" in sys.argv, "--ssm" in sys.argv, "--chunk" in sys.argv
     scan, kda, window = "--scan" in sys.argv, "--kda" in sys.argv, "--window" in sys.argv
     share, paged1 = "--share" in sys.argv, "--paged1" in sys.argv
-    flash_window = "--flash-window" in sys.argv
-    if flash_window:
+    flash_window, moe_rows = "--flash-window" in sys.argv, "--moe-rows" in sys.argv
+    if moe_rows:
+        section, records = "moe_rows", moe_rows_classes()
+    elif flash_window:
         section, records = "flash_window", flash_window_classes()
     elif paged1:
         section, records = "paged_group1", paged_group1_classes()
@@ -1528,7 +1592,7 @@ def main():
         report.setdefault(section, {})[name] = record
         print(json.dumps({name: record}), flush=True)
     for name, fn, ref, args, tol in (() if ssm or live or paged or mla or chunk or scan or kda
-                                     or window or share or paged1 or flash_window
+                                     or window or share or paged1 or flash_window or moe_rows
                                      or "--gmm-only" in sys.argv
                                      else cases()):
         try:
@@ -1538,7 +1602,7 @@ def main():
         report["kernels"][name] = result
         print(json.dumps({name: result}), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
-    out = ("flash_window_census.json" if flash_window else "paged1_census.json" if paged1 else "share_census.json" if share else "window_census.json" if window
+    out = ("moe_rows_census.json" if moe_rows else "flash_window_census.json" if flash_window else "paged1_census.json" if paged1 else "share_census.json" if share else "window_census.json" if window
            else "kda_census.json" if kda
            else "scan_census.json" if scan
            else "chunk_census.json" if chunk
