@@ -662,11 +662,25 @@ def expert_share_exchange_ffn(x, topk_idx, topk_vals, w1, w3, w2, share, mesh,
     output's cotangent, a reduce-scatter of the rows'), and between them the
     passes again: the number of passes is read on the device, so the loop is
     no ``scan`` to transpose - :func:`_share_passes` is a ``custom_vjp`` whose
-    backward runs each pass's forward again and pulls the cotangent through
-    it (each row kernel's cotangent is the other kernel), summing the stacks'
-    gradients over the passes. It keeps no residual but its inputs (under any
-    ``remat_policy`` the expert layer's forward runs once in the forward and
-    once in the backward).
+    backward is **written out** (:func:`_share_passes_bwd`), not derived. A
+    pass makes ``xp``, ``g | u = xp (w1 | w3)`` and ``h = act(g) u`` again (it
+    keeps no residual but its inputs: under any ``remat_policy`` the expert
+    layer's forward runs once in the forward and once in the backward) but
+    **not** ``y = h w2``: the cotangent's rows are copied into the layout
+    unweighted (``G``), ``dhu = G w2^T``, and a pick's weight ``v`` - which
+    crosses the linear product - is applied, and differentiated, in the hidden
+    width: ``dv = <h, dhu>``, ``dh = v dhu`` (pulled through ``activation``'s
+    own ``jax.vjp``), ``dw2 = (v h)^T G``; then ``dw1 | dw3 = xp^T (dg | du)``
+    and the rows' ``(dg | du) (w1 | w3)^T`` summed by token: three grouped
+    products and two calls for the stacks' gradients a pass, where the derived
+    transpose ran six and three. **The first pass is the result**, forward and
+    backward: the sum by token and every gradient are written by it into
+    arrays nobody filled (``jax.lax.empty``), and a later pass adds to them
+    inside the kernels that write them (``gather_sum_rows_onto``,
+    ``gmm_dw_onto``: the accumulator is an aliased input the first pass does
+    not read), so the common step - one pass a layer - writes no zeros and
+    adds to none; a rank that holds no pick runs one pass over an empty
+    layout, which writes zeros.
 
     An all-to-all of the held picks would move 0.92 of what the gather moves
     at 8 picks over 4 ranks (a token misses a rank with probability 0.085) and
@@ -720,14 +734,33 @@ def _use_pallas_rows(width, dtype):
     return FORCE_INTERPRET or jax.devices()[0].platform == "tpu"
 
 
+class _PassProducts:
+    """The grouped products over one pass's layout (:func:`_pass_layout`):
+    ``matmul(rows [S, K], w [held, K, N])``, ``matmul_t(rows [S, N], w)`` -
+    the same against the transposed stack - and ``dw_onto(acc, fresh, x [S,
+    K], dy [S, N])`` - the stack's gradient ``x^T dy`` a group, float32,
+    written onto ``acc`` [held, K, N] (``fresh`` [held] bool: the experts whose
+    block of ``acc`` holds nothing yet and is not read; another's is added to)
+    → (that, ``named`` [held] bool: the experts whose blocks it wrote;
+    another's keeps what ``acc`` held)."""
+
+    def __init__(self, matmul, dw_onto):
+        self.matmul, self.dw_onto = matmul, dw_onto
+
+    def matmul_t(self, rows, w):
+        return self.matmul(rows, w.swapaxes(1, 2))
+
+
 def _pass_layout(experts, here, picks, n_tokens, k, held, rows_a_group, d_model, d_ff, dtype):
     """Where a pass's picks lie, as integers alone → (``slot_token`` [S]:
     the token whose row each slot of the layout gets, ``n_tokens`` where it
     gets none - a tile's padding, a pick that is no pick -; ``slots``
     [n_tokens, k]: the slot of each token's ``j``-th pick, ``S`` where this
-    pass does not hold it; ``matmul(rows [S, ...], stack)``: one grouped
-    matmul over that layout). ``experts`` / ``here`` / ``picks`` [cap]: each
-    listed pick's expert, whether it is one, and its flat index ``t k + j``.
+    pass does not hold it; ``slot_pick`` [S]: the flat index ``t k + j`` of
+    each slot's pick, ``n_tokens k`` where it has none; the grouped products
+    over that layout, :class:`_PassProducts`). ``experts`` / ``here`` /
+    ``picks`` [cap]: each listed pick's expert, whether it is one, and its
+    flat index ``t k + j``.
 
     On TPU the layout is the Pallas grouped matmul's - every expert's rows
     padded to whole row tiles (:func:`_tile_routing`), the tiles past the
@@ -735,7 +768,7 @@ def _pass_layout(experts, here, picks, n_tokens, k, held, rows_a_group, d_model,
     ``lax.ragged_dot``."""
     cap = experts.shape[0]
     if _use_pallas_gmm(cap, held, d_model, d_ff, dtype):
-        from deepspeed_tpu.ops.pallas.grouped_matmul import row_tile
+        from deepspeed_tpu.ops.pallas.grouped_matmul import dw_tiles, gmm_dw_onto, row_tile
         GMM_STATS.count("pallas_exchange")
         tm = row_tile(rows_a_group * held, held, dtype)
         slot, te, num_tiles = _tile_routing(experts, held, tm, here)   # not here: past the layout
@@ -743,6 +776,10 @@ def _pass_layout(experts, here, picks, n_tokens, k, held, rows_a_group, d_model,
 
         def matmul(rows, w):
             return _gmm_dispatch(rows, w, te, tm, FORCE_INTERPRET, None, num_tiles)
+
+        def dw_onto(acc, fresh, x, dy):
+            return gmm_dw_onto(acc, fresh, x, dy, te, num_tiles, *dw_tiles(*acc.shape[1:]),
+                               FORCE_INTERPRET)
     else:
         GMM_STATS.count("ragged_exchange")
         group = jnp.where(here, experts, held)        # what is no pick sorts behind every group
@@ -753,35 +790,29 @@ def _pass_layout(experts, here, picks, n_tokens, k, held, rows_a_group, d_model,
         def matmul(rows, w):
             return grouped_gemm(rows, w, sizes).astype(rows.dtype)
 
-    slot_token = jnp.full((n_slots,), n_tokens, jnp.int32).at[slot].set(
-        jnp.where(here, picks // k, n_tokens), mode="drop", unique_indices=True)
+        def dw_onto(acc, fresh, x, dy):
+            # ragged_dot's own transpose in the stack: x^T dy a group, in float32
+            dw, = jax.linear_transpose(lambda w: grouped_gemm(x, w, sizes),
+                                       jax.ShapeDtypeStruct(acc.shape, x.dtype))(
+                dy.astype(jnp.float32))
+            return jnp.where(fresh[:, None, None], dw, acc + dw), jnp.ones((held,), bool)
+
+    slot_pick = jnp.full((n_slots,), n_tokens * k, jnp.int32).at[slot].set(
+        jnp.where(here, picks, n_tokens * k), mode="drop", unique_indices=True)
+    slot_token = jnp.where(slot_pick < n_tokens * k, slot_pick // k, n_tokens)
     slots = jnp.full((n_tokens * k,), n_slots, jnp.int32).at[
         jnp.where(here, picks, n_tokens * k)].set(slot, mode="drop", unique_indices=True)
-    return slot_token, slots.reshape(n_tokens, k), matmul
+    return slot_token, slots.reshape(n_tokens, k), slot_pick, _PassProducts(matmul, dw_onto)
 
 
-def _pass_of(p, order, n_held, x_all, vals, w1, w3, w2, k, held, cap, rows_a_group, activation):
-    """What pass ``p`` of a rank's held picks adds to ``[T_a, D]`` float32 - the
-    picks ``order[0][p cap : (p + 1) cap]`` (flat indices ``t k + j``, ascending;
-    ``order[1]``: their experts) laid out (:func:`_pass_layout`), each one's row
-    copied from its token's into its slot, multiplied, and a token's slots read,
-    weighted and summed - and the rows the two kernels copied for it (0 where
-    the ``jnp`` forms ran)."""
-    from deepspeed_tpu.ops.pallas.moe_rows import gather_rows, gather_sum_rows
-    Ta, D = x_all.shape
+def _layout_of_pass(p, order, n_held, n_tokens, k, held, cap, rows_a_group, d_model, d_ff, dtype):
+    """:func:`_pass_layout` of pass ``p`` of a rank's held picks: the picks
+    ``order[0][p cap : (p + 1) cap]`` (flat indices ``t k + j``, ascending;
+    ``order[1]``: their experts), as many as there are."""
     pick, expert = (jax.lax.dynamic_slice_in_dim(a, p * cap, cap) for a in order)
     here = p * cap + jnp.arange(cap, dtype=jnp.int32) < n_held
-    pick = jnp.where(here, pick, 0)
-    slot_token, slots, matmul = _pass_layout(
-        jnp.where(here, expert, 0), here, pick, Ta, k, held, rows_a_group, D, w1.shape[-1],
-        x_all.dtype)
-    kernel = _use_pallas_rows(D, x_all.dtype)
-    xp = gather_rows(x_all, slot_token, slots, kernel, FORCE_INTERPRET)
-    y = matmul(activation(matmul(xp, w1)) * matmul(xp, w3), w2)
-    out = gather_sum_rows(y, slots, vals.reshape(Ta, k), slot_token, kernel, FORCE_INTERPRET)
-    copied = jnp.sum(slot_token < Ta, dtype=jnp.int32) + jnp.sum(slots < y.shape[0],
-                                                                 dtype=jnp.int32)
-    return out, copied * int(kernel)
+    return _pass_layout(jnp.where(here, expert, 0), here, jnp.where(here, pick, 0), n_tokens, k,
+                        held, rows_a_group, d_model, d_ff, dtype)
 
 
 def _held_order(experts, held, cap):
@@ -796,21 +827,50 @@ def _held_order(experts, held, cap):
     return tuple(jnp.pad(a, (0, -n % cap + cap)) for a in order), jnp.sum(live, dtype=jnp.int32)
 
 
+def _n_passes(n_held, cap):
+    """The passes a rank's loops run: ``ceil(held picks / cap)``, and one where
+    it holds none - the first pass **is** the result (it writes every row and
+    every named block, zeros where nothing is held), the others add to it."""
+    return jnp.maximum((n_held + cap - 1) // cap, 1)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _share_passes(x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group, activation):
-    """Every pass of a rank's held picks (:func:`_pass_of`) summed →
-    (``[T_a, D]`` float32, the held picks, the rows the row kernels copied).
-    ``experts`` [T_a k] int32: each pick's expert counted from this rank's
-    first (held: ``0 <= e < held``)."""
+    """Every pass of a rank's held picks summed → (``[T_a, D]`` float32, the
+    held picks, the rows the row kernels copied - 0 where the ``jnp`` forms
+    ran). ``experts`` [T_a k] int32: each pick's expert counted from this
+    rank's first (held: ``0 <= e < held``).
+
+    A pass lays its picks out (:func:`_layout_of_pass`), copies each one's row
+    from its token's into its slot, multiplies, and reads a token's slots,
+    weighted, into its sum: **the first pass writes the result, a later one
+    adds to it in the kernel that writes it** (``gather_sum_rows_onto``), so
+    the step whose layers each take one pass writes ``[T_a, D]`` once and no
+    zeros before it."""
+    from deepspeed_tpu.ops.pallas.moe_rows import gather_rows, gather_sum_rows_onto
+    Ta, D = x_all.shape
     order, n_held = _held_order(experts, held, cap)
+    kernel = _use_pallas_rows(D, x_all.dtype)
 
     def one(p, carry):
-        return jax.tree.map(jnp.add, carry, _pass_of(
-            p, order, n_held, x_all, vals, w1, w3, w2, k, held, cap, rows_a_group, activation))
+        out, copied = carry
+        slot_token, slots, _, on = _layout_of_pass(
+            p, order, n_held, Ta, k, held, cap, rows_a_group, D, w1.shape[-1], x_all.dtype)
+        xp = gather_rows(x_all, slot_token, None, kernel, FORCE_INTERPRET)
+        y = on.matmul(activation(on.matmul(xp, w1)) * on.matmul(xp, w3), w2)
+        out = gather_sum_rows_onto(out, p == 0, y, slots, vals.reshape(Ta, k), kernel,
+                                   FORCE_INTERPRET)
+        return out, copied + _rows_copied(slot_token, slots, Ta, kernel)
 
-    out, copied = jax.lax.fori_loop(0, (n_held + cap - 1) // cap, one,
-                                    (jnp.zeros(x_all.shape, jnp.float32), jnp.int32(0)))
+    out, copied = jax.lax.fori_loop(0, _n_passes(n_held, cap), one,
+                                    (jax.lax.empty((Ta, D), jnp.float32), jnp.int32(0)))
     return out, n_held, copied
+
+
+def _rows_copied(slot_token, slots, n_tokens, kernel):
+    """The rows the two row kernels copy for one pass's layout (0: the ``jnp`` forms)."""
+    return (jnp.sum(slot_token < n_tokens, dtype=jnp.int32)
+            + jnp.sum(slots < slot_token.shape[0], dtype=jnp.int32)) * int(kernel)
 
 
 def _share_passes_fwd(x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_group, activation):
@@ -819,22 +879,61 @@ def _share_passes_fwd(x_all, vals, w1, w3, w2, experts, k, held, cap, rows_a_gro
 
 
 def _share_passes_bwd(k, held, cap, rows_a_group, activation, res, cts):
+    """The passes' backward, written out. A pass, given the output's cotangent
+    ``dout`` [T_a, D]: ``xp`` and ``g | u = xp (w1 | w3)`` again - the two
+    stacks side by side, one product - and ``h = act(g) u`` (not ``y = h w2``:
+    nothing here needs it); ``G``, the rows of ``dout`` copied into the layout
+    by the dispatch's own kernel, **unweighted**, and ``dhu = G w2^T``; then,
+    since a pick's weight ``v`` crosses the linear product (``<h w2, G> = <h,
+    G w2^T>``), everything that weight touches in the hidden width: the
+    weight's own gradient ``<h[s], dhu[s]>`` (float32; a pick lies in one pass,
+    so it is set from its slot, not gathered for every pick and added), ``dh =
+    v dhu`` pulled through the activation to ``dg | du``, and ``v h`` for ``dw2
+    = (v h)^T G``; ``dw1 | dw3 = xp^T (dg | du)``; the rows' ``(dg | du) (w1 |
+    w3)^T``, one product and no sum of two, summed by token. Three grouped
+    products and two calls for the stacks' gradients, which skip the layout's
+    tiles past the groups' as the products do. As in the forward, the first
+    pass writes each gradient and a later one adds to it where it is written
+    (the stacks' and the rows' in float32, rounded once after the last pass;
+    an expert's block of a stack's gradient is written by the first pass that
+    holds a pick of it)."""
+    from deepspeed_tpu.ops.pallas.moe_rows import gather_rows, gather_sum_rows_onto
     x_all, vals, w1, w3, w2, experts = res
-    dout = cts[0]
+    (Ta, D), f32, dtype = x_all.shape, jnp.float32, x_all.dtype
+    dout = cts[0].astype(dtype)             # the rows' own type, as they cross the layout
     order, n_held = _held_order(experts, held, cap)
-    args = (x_all, vals, w1, w3, w2)
+    kernel = _use_pallas_rows(D, dtype)
+    w13, F = jnp.concatenate([w1, w3], axis=2), w1.shape[-1]
 
-    def one(p, grads):
-        _, vjp = jax.vjp(lambda *a: _pass_of(p, order, n_held, *a, k, held, cap, rows_a_group,
-                                             activation)[0], *args)
-        return jax.tree.map(lambda g, d: g + d.astype(g.dtype), grads, vjp(dout))
+    def one(p, carry):
+        dx, dvals, dw13, dw2, named = carry
+        slot_token, slots, slot_pick, on = _layout_of_pass(
+            p, order, n_held, Ta, k, held, cap, rows_a_group, D, F, dtype)
+        xp = gather_rows(x_all, slot_token, None, kernel, FORCE_INTERPRET)
+        G = gather_rows(dout, slot_token, None, kernel, FORCE_INTERPRET)
+        h, pull = jax.vjp(lambda gu: activation(gu[:, :F]) * gu[:, F:], on.matmul(xp, w13))
+        dhu = on.matmul_t(G, w2).astype(f32)
+        v = jnp.take(vals.astype(f32), slot_pick, mode="fill", fill_value=0)[:, None]
+        dv = jnp.sum(h.astype(f32) * dhu, axis=-1)
+        dgu, = pull((v * dhu).astype(dtype))
+        fresh = ~named
+        dw2, wrote = on.dw_onto(dw2, fresh, (v * h.astype(f32)).astype(dtype), G)
+        dw13, _ = on.dw_onto(dw13, fresh, xp, dgu)
+        dx = gather_sum_rows_onto(dx, p == 0, on.matmul_t(dgu, w13), slots, None, kernel,
+                                  FORCE_INTERPRET)
+        # a pick lies in one pass: its weight's gradient is set, from its slot, not added
+        dvals = dvals.at[slot_pick].set(dv, mode="drop", unique_indices=True)
+        return dx, dvals, dw13, dw2, named | wrote
 
-    # the stacks' gradients summed over the passes in float32, the rest in their own dtype
-    zeros = tuple(jnp.zeros(a.shape, jnp.float32 if i >= 2 else a.dtype)
-                  for i, a in enumerate(args))
-    grads = jax.lax.fori_loop(0, (n_held + cap - 1) // cap, one, zeros)
-    grads = tuple(g.astype(a.dtype) for g, a in zip(grads, args))
-    return (*grads, np.zeros(experts.shape, dtype=jax.dtypes.float0))
+    empty = jax.lax.empty
+    dx, dvals, dw13, dw2, named = jax.lax.fori_loop(0, _n_passes(n_held, cap), one, (
+        empty((Ta, D), f32), jnp.zeros(vals.shape, f32), empty(w13.shape, f32),
+        empty(w2.shape, f32), jnp.zeros((held,), bool)))
+    # an expert no pass named holds whatever its block held
+    dw1, dw3, dw2 = (jnp.where(named[:, None, None], dw, 0.0).astype(dtype)
+                     for dw in (dw13[..., :F], dw13[..., F:], dw2))
+    return (dx.astype(dtype), dvals.astype(vals.dtype), dw1, dw3, dw2,
+            np.zeros(experts.shape, dtype=jax.dtypes.float0))
 
 
 _share_passes.defvjp(_share_passes_fwd, _share_passes_bwd)

@@ -75,6 +75,30 @@ def _gmm_dw_kernel(te_ref, x_ref, dy_ref, o_ref):
         o_ref[0] += upd
 
 
+def _gmm_dw_onto_kernel(te_ref, fresh_ref, tiles_ref, x_ref, dy_ref, acc_ref, o_ref):
+    m = pl.program_id(2)
+
+    @pl.when(m < tiles_ref[0])
+    def _tile():
+        upd = jax.lax.dot_general(
+            x_ref[:], dy_ref[:], dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        new = (m == 0) | (te_ref[m] != te_ref[jnp.maximum(m - 1, 0)])
+        fresh = fresh_ref[te_ref[m]] != 0
+
+        @pl.when(new & fresh)
+        def _init():
+            o_ref[0] = upd
+
+        @pl.when(new & jnp.logical_not(fresh))
+        def _onto():
+            o_ref[0] = acc_ref[0] + upd
+
+        @pl.when(jnp.logical_not(new))
+        def _acc():
+            o_ref[0] += upd
+
+
 def _fit_tile(t, dim):
     """Largest divisor of ``dim`` that is ≤ t and a multiple of 128 (the
     lane width) when possible — tiles MUST divide the dim exactly or the
@@ -209,6 +233,80 @@ def _gmm_dw_raw(x, dy, tile_experts, num_experts, tk, tn, interpret=False):
     return jnp.where(present[:, None, None], out, 0.0)
 
 
+def gmm_dw_onto(acc, fresh, x, dy, tile_experts, num_tiles, tk, tn, interpret=False):
+    """:func:`_gmm_dw_raw` written **onto** ``acc`` [E, K, N] float32, which
+    the result takes the place of (aliased) → (that, ``named`` [E] bool: the
+    experts whose blocks it wrote). ``fresh`` [E] bool: the experts whose
+    block of ``acc`` holds nothing yet - theirs becomes ``dw`` alone and is
+    not read (it may hold anything: ``jax.lax.empty``); another's becomes
+    ``acc + dw``, summed in float32 in the kernel's own block. An expert that
+    is not ``named`` keeps what ``acc`` held. ``num_tiles`` (traced): the row
+    tiles the groups fill (:func:`tile_layout`); the layout's tiles past them
+    - rows of zeros - are neither fetched nor multiplied (the first always
+    is: a layout that holds nothing writes its one named expert zeros)."""
+    Mp, K = x.shape
+    _, N = dy.shape
+    n_tiles = tile_experts.shape[0]
+    tm = Mp // n_tiles
+    tk = _fit_tile(tk, K)
+    tn = _fit_tile(tn, N)
+    tiles = jnp.maximum(jnp.asarray(num_tiles, jnp.int32), 1).reshape(1)
+
+    def rows(i, tiles):         # a skipped tile asks for the last real tile's rows again: no DMA
+        return jnp.minimum(i, tiles[0] - 1)
+
+    def block(kt, j, i, te, fresh, tiles):
+        return te[rows(i, tiles)], kt, j
+
+    def acc_block(kt, j, i, te, fresh, tiles):
+        # a fresh expert asks for block 0, which nobody reads: where every expert is fresh
+        # (a first pass) it is fetched once
+        e, kt, j = block(kt, j, i, te, fresh, tiles)
+        return tuple(jnp.where(fresh[e] != 0, 0, b) for b in (e, kt, j))
+
+    out = pl.pallas_call(
+        _gmm_dw_onto_kernel,
+        name="gmm_dw",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(K // tk, N // tn, n_tiles),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda kt, j, i, te, fresh, tiles: (rows(i, tiles), kt)),
+                pl.BlockSpec((tm, tn), lambda kt, j, i, te, fresh, tiles: (rows(i, tiles), j)),
+                pl.BlockSpec((1, tk, tn), acc_block),
+            ],
+            out_specs=pl.BlockSpec((1, tk, tn), block),
+        ),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={5: 0},
+        # two buffers each of the output's block and the accumulator's, and of the row tiles
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=(
+            4 * tk * tn * 4 + 2 * tm * (tk + tn) * x.dtype.itemsize + _VMEM_SLACK_BYTES)),
+        interpret=interpret,
+    )(tile_experts, fresh.astype(jnp.int32), tiles, x, dy, acc)
+    real = jnp.arange(n_tiles, dtype=jnp.int32) < tiles[0]
+    named = jnp.any(real[:, None] & (tile_experts[:, None] == jnp.arange(
+        acc.shape[0], dtype=tile_experts.dtype)[None, :]), axis=0)
+    return out, named
+
+
+def dw_tiles(K, N):
+    """(tk, tn) of ``gmm_dw``'s output block for a ``[K, N]`` expert matrix:
+    one full [K, N] fp32 accumulator block per expert when it fits the 4MB
+    VMEM budget (next to the double-buffered input streams) — x and dy then
+    stream exactly once; otherwise halve the block until it fits, re-reading
+    x per n-tile and dy per k-tile."""
+    tk, tn = K, N
+    while tk * tn * 4 > 4 * 1024 * 1024:  # fit VMEM next to the streams
+        if tn >= tk and tn % 256 == 0:
+            tn //= 2
+        elif tk % 256 == 0:
+            tk //= 2
+        else:
+            return 256, 512
+    return tk, tn
+
+
 def gmm(x, w, tile_experts, tm=256, interpret=False, first_group=None, num_tiles=None):
     """Grouped matmul on a tile-aligned row layout.
 
@@ -245,22 +343,8 @@ def _gmm_bwd(tm, interpret, res, dy):
     dy = dy.astype(x.dtype)
     # dx: the same grouped matmul against the transposed expert weights
     dx = _gmm_raw(dy, w.swapaxes(1, 2), tile_experts, meta, tm, interpret)
-    # dw: one full [K, N] fp32 accumulator block per expert when it fits
-    # the 4MB VMEM budget (next to the double-buffered input streams) —
-    # x and dy then stream exactly once; otherwise halve the block until
-    # it fits, re-reading x per n-tile and dy per k-tile.
-    K, N = w.shape[1], w.shape[2]
-    tk_dw, tn_dw = K, N
-    while tk_dw * tn_dw * 4 > 4 * 1024 * 1024:  # fit VMEM next to the streams
-        if tn_dw >= tk_dw and tn_dw % 256 == 0:
-            tn_dw //= 2
-        elif tk_dw % 256 == 0:
-            tk_dw //= 2
-        else:
-            tk_dw, tn_dw = 256, 512
-            break
     # over the whole table: a group no tile names gets zeros
-    dw = _gmm_dw_raw(x, dy, tile_experts + meta[0], w.shape[0], tk_dw, tn_dw,
+    dw = _gmm_dw_raw(x, dy, tile_experts + meta[0], w.shape[0], *dw_tiles(*w.shape[1:]),
                      interpret).astype(w.dtype)
     return dx, dw, None, None
 

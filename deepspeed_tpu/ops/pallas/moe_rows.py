@@ -17,7 +17,10 @@ other's transpose:
 ``idx`` and ``slots`` name each other's places (``idx[slots[t, j]] == t``
 for every live slot), so the cotangent of one function is the other applied
 to the cotangent: the two ``custom_vjp`` rules below call each other and
-derive nothing. A call that is differentiated is given both arrays.
+derive nothing. A call that is differentiated is given both arrays. (The
+exchange itself differentiates neither since PR 60: its backward is written
+out and calls :func:`gather_rows` and :func:`gather_sum_rows_onto` - the sum
+written onto an accumulator, so that one pass of many adds where it writes.)
 
 **The kernels.** A DMA can take eight rows of a tiled ``[N, D]`` array or
 none (Mosaic: a slice along dimension 0 must be aligned to the tiling), so
@@ -177,13 +180,21 @@ def _gather_kernel(counts_ref, f0, t0, f1, t1, idx_ref, src_ref, out_ref, buf, s
         out_ref[:, h * W:(h + 1) * W] = jnp.where(live, half, 0.0).astype(out_ref.dtype)
 
 
-def _sum_kernel(counts_ref, planes_ref, f0, t0, f1, t1, slots_ref, *rest, n_src, block, k, pack,
-                weighted):
+def _sum_kernel(counts_ref, planes_ref, first_ref, f0, t0, f1, t1, slots_ref, *rest, n_src, block,
+                k, pack, weighted):
     w_ref = rest[0] if weighted else None
-    src_ref, out_ref, buf, sems = rest[-4:]
+    src_ref, acc_ref, out_ref, buf, sems = rest[-5:]
     side = _pipelined(counts_ref, (f0, t0, f1, t1), src_ref, buf, sems)
     W = buf.shape[-1]
-    out_ref[...] = jnp.zeros(out_ref.shape, jnp.float32)
+
+    @pl.when(first_ref[0] != 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.float32)
+
+    @pl.when(first_ref[0] == 0)
+    def _():
+        out_ref[...] = acc_ref[...]
+
     for j in range(k):      # plane j: every token's j-th held pick; the block's fullest token has
         @pl.when(j < planes_ref[pl.program_id(0)])                          # planes_ref[i] of them
         def _():
@@ -259,8 +270,9 @@ def _gather_packed(rows, idx, dtype, block, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("dtype", "block", "interpret"))
-def _sum_packed(rows, slots, w, dtype, block, interpret):
-    """``moe_rows_sum`` over a source already a row at a time → ``[T, D]`` float32."""
+def _sum_packed(acc, first, rows, slots, w, dtype, block, interpret):
+    """``moe_rows_sum`` over a source already a row at a time, onto ``acc``
+    (:func:`gather_sum_rows_onto`) → ``[T, D]`` float32 in ``acc``'s place."""
     (S, _, W), (T, k) = rows.shape, slots.shape
     pack = _pack(dtype)
     slots, w, held = _held_first(slots.astype(jnp.int32),
@@ -271,18 +283,25 @@ def _sum_packed(rows, slots, w, dtype, block, interpret):
     planes = jnp.max(jnp.pad(held, (0, counts.shape[0] * block - T)).reshape(-1, block), axis=1)
     by_token = pl.BlockSpec((block, k), lambda i, *_: (i, 0))
     weights = () if w is None else (w,)
+    first = jnp.asarray(first, jnp.int32).reshape(1)
+    # the first pass asks for the accumulator's block 0 all through: fetched once, read never
+    onto = pl.BlockSpec((block, W * pack), lambda i, counts, planes, first: (
+        jnp.where(first[0] != 0, 0, i), 0))
+    operands = (counts, planes, first, *lists, slots, *weights, rows, acc)
     return pl.pallas_call(
         functools.partial(_sum_kernel, n_src=S, block=block, k=k, pack=pack,
                           weighted=w is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(counts.shape[0],),
-            in_specs=specs + [by_token] * (1 + len(weights)) + [pl.BlockSpec(memory_space=pl.ANY)],
+            num_scalar_prefetch=3, grid=(counts.shape[0],),
+            in_specs=specs + [by_token] * (1 + len(weights))
+            + [pl.BlockSpec(memory_space=pl.ANY), onto],
             out_specs=pl.BlockSpec((block, W * pack), lambda i, *_: (i, 0)),
             scratch_shapes=[pltpu.VMEM((2, k * block, 1, W), jnp.uint32),
                             pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((T, W * pack), jnp.float32),
+        input_output_aliases={len(operands) - 1: 0},
         compiler_params=_params(), interpret=interpret, name="moe_rows_sum",
-    )(counts, planes, *lists, slots, *weights, rows)
+    )(*operands)
 
 
 def _gather(src, idx, kernel, interpret):
@@ -291,13 +310,33 @@ def _gather(src, idx, kernel, interpret):
     return jnp.take(src, idx, axis=0, mode="fill", fill_value=0)
 
 
-def _gather_sum(src, slots, w, kernel, interpret):
-    if kernel:
-        return _sum_packed(_as_rows(src, interpret), slots, w, src.dtype, SUM_BLOCK, interpret)
+def _sum_jnp(src, slots, w):
     rows = jnp.take(src, slots, axis=0, mode="fill", fill_value=0).astype(jnp.float32)
     if w is not None:
         rows = rows * w.astype(jnp.float32)[..., None]
     return jnp.sum(jnp.where((slots < src.shape[0])[..., None], rows, 0.0), axis=1)
+
+
+def gather_sum_rows_onto(acc, first, src, slots, w=None, kernel=False, interpret=False):
+    """:func:`gather_sum_rows` written **onto** ``acc`` [T, D] float32, which
+    the result takes the place of: ``first`` (a traced bool) - the sum alone,
+    and ``acc`` is not read (it may hold anything: ``jax.lax.empty``);
+    otherwise ``acc +`` the sum, added in the kernel's own output block, so
+    one pass of many costs no pass over ``[T, D]`` of its own and the first
+    writes no zeros. Not differentiable: the exchange's backward is written
+    out (``ops/grouped_gemm._share_passes_bwd``)."""
+    if kernel:
+        return _sum_packed(acc, first, _as_rows(src, interpret), slots, w, src.dtype, SUM_BLOCK,
+                           interpret)
+    out = _sum_jnp(src, slots, w)
+    return jnp.where(first, out, acc + out)
+
+
+def _gather_sum(src, slots, w, kernel, interpret):
+    if kernel:
+        acc = jax.lax.empty((slots.shape[0], src.shape[1]), jnp.float32)
+        return gather_sum_rows_onto(acc, True, src, slots, w, kernel, interpret)
+    return _sum_jnp(src, slots, w)
 
 
 def _no_cotangent(index):

@@ -130,8 +130,51 @@ def test_bf16_expert4_is_the_reference_to_rounding(reference):
     assert losses[2] < losses[1] < losses[0]
 
 
-@pytest.mark.parametrize("row_kernels", [False, True], ids=["jnp_rows", "row_kernels"])
-def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch, row_kernels):
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, a kernel's own
+    (its blocks are no arrays of the program) left out → (its jaxpr, the equation)."""
+    for eqn in jaxpr.eqns:
+        yield jaxpr, eqn
+        for value in () if eqn.primitive.name == "pallas_call" else eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _pass_loops(jaxpr, shapes):
+    """The exchange's two loops over passes in a differentiated step's jaxpr,
+    forward then backward, each as (grouped products, stacks' gradients by the
+    kernel's name, the float32 arrays of ``shapes`` its body makes by
+    ``broadcast_in_dim`` or adds, the primitives that made its carry's arrays
+    of ``shapes``)."""
+    def big(var):
+        aval = getattr(var, "aval", None)
+        return aval is not None and aval.dtype == jnp.float32 and aval.shape in shapes
+
+    loops = []
+    for outer, eqn in _eqns(jaxpr):
+        if eqn.primitive.name != "while":
+            continue
+        inside = [e for _, e in _eqns(eqn.params["body_jaxpr"].jaxpr)]
+        named = lambda e, name: e.primitive.name == "pallas_call" and e.params["name"] == name
+        products = sum(e.primitive.name == "ragged_dot_general" or named(e, "gmm_ragged_dot")
+                       for e in inside)
+        if not products:
+            continue            # a loop of another part of the program
+        made_by = {v: e.primitive.name for e in outer.eqns for v in e.outvars}
+        loops.append((
+            products, sum(named(e, "gmm_dw") for e in inside),
+            [e.primitive.name for e in inside
+             if e.primitive.name in ("broadcast_in_dim", "add", "add_any") and big(e.outvars[0])],
+            [made_by.get(v) for v in eqn.invars[eqn.params["cond_nconsts"]
+                                                + eqn.params["body_nconsts"]:] if big(v)]))
+    return loops
+
+
+@pytest.mark.parametrize("row_kernels,act", [(False, "silu"), (True, "silu"), (False, "gelu")],
+                         ids=["jnp_rows", "row_kernels", "jnp_rows_gated_gelu"])
+def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch, row_kernels, act):
     """(c): one expert layer under ``expert=4``. What rank ``r``'s two experts
     add, by the reference's loop over experts, summed over the four ranks is
     the reference's uncut layer, and the exchange's output is that sum
@@ -142,7 +185,17 @@ def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch, row_k
     still exact: a pass has a static size, their number is the router's.
     ``row_kernels``: once with the rows moved by the ``jnp`` forms (this
     backend's choice), once through the two Pallas row kernels and the Pallas
-    grouped matmul, interpreted - the path a TPU takes."""
+    grouped matmul, interpreted - the path a TPU takes. ``act``: the
+    configuration's SiLU, and a gated GELU (the written backward pulls the
+    cotangent through whatever ``activation`` it is handed).
+
+    **The backward as it is written** (its jaxpr): a pass multiplies three
+    grouped products and the stacks' gradients in two (the derived transpose:
+    six and three) - ``y = h w2`` is not made again, ``w1`` and ``w3`` stand
+    side by side -, its loop is handed float32 ``[T_a, D]`` and ``[held, K, N]``
+    arrays that nobody filled (``jax.lax.empty``) and no array of zeros, and
+    through the kernels its body neither makes nor adds one: the first pass
+    writes, a later one adds where it is written."""
     from deepspeed_tpu.ops import grouped_gemm as gg
     monkeypatch.setattr(gg, "FORCE_INTERPRET", row_kernels)
     params = _host(mellum.seeded_params(CFG, 11))
@@ -160,17 +213,18 @@ def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch, row_k
     stacks = tuple(moe[name] for name in ("experts_w1", "experts_w3", "experts_w2"))
     mesh = make_mesh_topology(expert=4, devices=jax.devices()[:4])
     share = gg.ExpertShare(0, CFG.num_experts, CFG.num_experts)
+    activation = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[act]
 
     def exchange(picks):
         def loss(x, w, *stacks):
-            out, counts = jax.jit(lambda *a: gg.expert_share_exchange_ffn(*a, share, mesh))(
-                x, picks, w, *stacks)
+            out, counts = jax.jit(lambda *a: gg.expert_share_exchange_ffn(
+                *a, share, mesh, activation=activation))(x, picks, w, *stacks)
             return jnp.sum(out * jnp.cos(out)), (out, counts)
         return loss
 
     def plain(picks):
         def loss(x, w, w1, w3, w2):
-            each = jnp.stack([(jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e]
+            each = jnp.stack([(activation(x @ w1[e]) * (x @ w3[e])) @ w2[e]
                               for e in range(CFG.num_experts)])
             out = sum(w[:, j:j + 1] * each[picks[:, j], jnp.arange(x.shape[0])]
                       for j in range(picks.shape[1]))
@@ -194,7 +248,8 @@ def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch, row_k
         return out, counts
 
     out, counts = both(picks)
-    np.testing.assert_allclose(out.reshape(whole.shape), whole, atol=1e-5)
+    if act == "silu":
+        np.testing.assert_allclose(out.reshape(whole.shape), whole, atol=1e-5)
     assert int(counts[..., 0].sum()) == picks.size and counts[0, :, 1].tolist() == [1, 1, 1, 1]
     for r in range(4):      # a rank's count is the picks of its two experts
         assert int(counts[0, r, 0]) == int(((picks >= 2 * r) & (picks < 2 * r + 2)).sum())
@@ -207,6 +262,19 @@ def test_shares_add_up_to_the_uncut_layer_and_no_pick_is_lost(monkeypatch, row_k
     assert rows == picks.size // 4                       # an even router's share, no room
     assert counts[0, :, 0].tolist() == [picks.size, 0, 0, 0]
     assert counts[0, :, 1].tolist() == [4, 0, 0, 0]      # four passes on the one rank, none lost
+
+    (held, K, N), Ta = (d // 4 if i == 0 else d for i, d in enumerate(stacks[0].shape)), len(flat)
+    forward, backward = _pass_loops(jax.make_jaxpr(jax.grad(
+        lambda *a: exchange(picks)(*a)[0], argnums=range(5)))(flat, weights, *stacks).jaxpr,
+        {(Ta, K), (held, K, N), (held, N, K), (held, K, 2 * N)})
+    # g | u, dhu and the rows' cotangent (w1 and w3 side by side: one product each way), then
+    # dw2 and dw1 | dw3 - grouped products like the others where ragged_dot multiplies
+    assert forward[:2] == ((3, 0)) and backward[:2] == ((3, 2) if row_kernels else (5, 0))
+    assert forward[3] == ["empty"] and backward[3] == ["empty"] * 3
+    if row_kernels:
+        assert forward[2] == [] and backward[2] == []
+    else:       # the ``jnp`` forms add a pass to what stands, and make no zeros either
+        assert "broadcast_in_dim" not in forward[2] + backward[2]
 
 
 def _masked_softmax(q, k, v, window, seg):

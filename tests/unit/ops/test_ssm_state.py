@@ -387,3 +387,35 @@ def test_mosaic_takes_the_row_kernels_at_mellums_shape(one_chip, dtype):
     assert summed.memory_analysis().temp_size_in_bytes < 1.1 * S * D * width
     for text, name in ((gather.as_text(), "moe_rows_gather"), (summed.as_text(), "moe_rows_sum")):
         assert name in text and "moe_rows_pack" in text
+
+
+@pytest.mark.parametrize("K,N", [(2304, 1792), (896, 2304)], ids=["dw1_dw3", "dw2"])
+def test_mosaic_takes_the_kernels_that_add_where_they_write_at_mellums_shape(one_chip, K, N):
+    """Compiled for a described v5e (nothing runs): ``gmm_dw_onto`` at the two
+    shapes the exchange's written backward calls it with (``xp^T (dg | du)``,
+    ``(v h)^T G``; 102400 slots, 16 experts) and ``gather_sum_rows_onto`` at the
+    rows' - their accumulators **aliased** to their results (donated: no copy
+    of ``[16, K, N]`` or ``[32768, 2304]`` float32 among the temporaries), the
+    stack's two blocks of output and two of accumulator inside the VMEM the
+    kernel asks for (16 MiB, the default, refuses them)."""
+    from deepspeed_tpu.ops.pallas import moe_rows
+    from deepspeed_tpu.ops.pallas.grouped_matmul import dw_tiles, gmm_dw_onto
+    S, E, T, D, k, tm = 102400, 16, 32768, 2304, 8, 256
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    dw = _compiled(lambda acc, fresh, x, dy, te, n: gmm_dw_onto(acc, fresh, x, dy, te, n,
+                                                                *dw_tiles(K, N))[0],
+                   (sds((E, K, N), jnp.float32), sds((E,), bool), sds((S, K), jnp.bfloat16),
+                    sds((S, N), jnp.bfloat16), sds((S // tm,), jnp.int32), sds((), jnp.int32)),
+                   donate_argnums=0)
+    assert "gmm_dw" in dw.as_text()
+    assert dw.memory_analysis().temp_size_in_bytes < E * K * N * 4 // 2
+    if N == D:
+        summed = _compiled(lambda acc, first, y, s: moe_rows.gather_sum_rows_onto(
+            acc, first, y, s, None, True, False),
+            (sds((T, D), jnp.float32), sds((), bool), sds((S, D), jnp.bfloat16),
+             sds((T, k), jnp.int32)), donate_argnums=0)
+        assert "moe_rows_sum" in summed.as_text()
+        assert summed.memory_analysis().temp_size_in_bytes < 1.1 * S * D * 2

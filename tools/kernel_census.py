@@ -1517,7 +1517,8 @@ def moe_rows_classes(T=32768, D=2304, n_held=65536, interpret=False):
           tok_of_pick, w_of_pick)
     for block in (8, 16, 32):
         timed(f"gather_sum_rows_packed_block{block}", lambda r, s, w: mr._sum_packed(
-            r, s, w, dtype, block, interpret), (T, D), jnp.float32, y_rows, slots, w)
+            jax.lax.empty((T, D), jnp.float32), True, r, s, w, dtype, block, interpret),
+            (T, D), jnp.float32, y_rows, slots, w)
     timed("gather_sum_rows_unweighted", lambda y, s: mr.gather_sum_rows(
         y, s, None, None, True, interpret), (T, D), jnp.float32, y, slots)
     timed("gather_sum_rows", lambda y, s, w: mr.gather_sum_rows(y, s, w, None, True, interpret),
