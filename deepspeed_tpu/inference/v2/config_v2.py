@@ -24,9 +24,15 @@ class PrefixCacheConfig(DeepSpeedConfigModel):
     directions (kill switch). ``max_cached_blocks`` caps how many pool
     blocks the trie may own at once (0 = bounded only by pool pressure —
     unreferenced cached blocks are evicted LRU when allocation needs
-    them)."""
+    them). ``snapshot_slots``: for a model kind whose sequences hold a slot
+    of state beside their blocks and lets it be snapshotted
+    (``ModelKind.snapshots``), the slots of the slot pool the cache owns,
+    beyond ``max_tracked_sequences`` - each a copy of a sequence's state at
+    a block boundary (0 = as many as tracked sequences: a trailing snapshot
+    each); evicted LRU, and a live sequence may always take one."""
     enabled: bool = False
     max_cached_blocks: int = 0
+    snapshot_slots: int = 0
 
 
 class KVTierConfig(DeepSpeedConfigModel):

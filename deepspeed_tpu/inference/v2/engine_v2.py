@@ -355,6 +355,11 @@ class InferenceEngineV2:
         if window is not None:
             self.state_extra = self.window_pool.arrays(window_layers, kind.state_rows(cfg)[0], dtype)
         else:
+            from deepspeed_tpu.inference.v2.prefix_cache import prefix_cache_enabled
+            if kind.snapshots and prefix_cache_enabled(self._config.prefix_cache):
+                # the prefix cache's own slots, beyond the tracked sequences', in the same
+                # arrays: a trailing snapshot a sequence where nobody says how many
+                slots += int(self._config.prefix_cache.snapshot_slots) or slots
             self.state_extra = kind.extra_state(cfg, num_blocks, slots, dtype)
         if self.state_extra is not None and kind.slot_state:
             # a slot is one row of each entry the kind names (their second axis)
@@ -374,8 +379,15 @@ class InferenceEngineV2:
         if prefix_cache_enabled(self._config.prefix_cache):
             self.prefix_cache = PrefixCacheManager(
                 self.kv_cache,
-                max_cached_blocks=int(self._config.prefix_cache.max_cached_blocks))
+                max_cached_blocks=int(self._config.prefix_cache.max_cached_blocks),
+                slot_pool=self.slot_pool)
             self.state_manager.attach_prefix_cache(self.prefix_cache)
+        # a kind with snapshots: the copies a step asked for (slot -> slot, on the device,
+        # dispatched behind the step's program) and their counts for its record
+        self._copy_slots_fn = None
+        self._snapshots_restored = 0
+        self._snapshot_counts = {}      # a step record's seq -> (taken, restored)
+        self._snapshotting = self.prefix_cache is not None and self.slot_pool is not None
         # Host-RAM KV spill tier (tier-2): trie eviction demotes blocks
         # into a byte-budgeted host store instead of dropping them.
         # Config-gated with the DS_KV_TIER env kill switch; layered on
@@ -640,7 +652,11 @@ class InferenceEngineV2:
         rows at all) is served by the core path only: every optional
         subsystem that reads, moves or shards the two KV pools refuses it
         here, at construction and by name, instead of failing inside a
-        program. A kind whose state is keys and values refuses what it
+        program: the KV tier and the handoff, ``suspend``, speculative
+        decoding, LoRA, quantization and sharding on every such kind, and
+        the prefix cache on ``latent``, ``sparse_kv+slots``, ``kv+window``
+        and every ``kv+slots`` kind but one that says its slots may be
+        snapshotted (``kind.snapshots``). A kind whose state is keys and values refuses what it
         names itself (``kind.refuses``: a stack run several times has no
         adapter slabs a pass) and keeps the rest."""
         from deepspeed_tpu.inference.v2.kv_tier import kv_tier_enabled
@@ -658,6 +674,12 @@ class InferenceEngineV2:
             "tensor/expert-parallel sharding": n_devices > 1,
         }
         for subsystem, on in asked.items():
+            if on and subsystem == "prefix cache" and kind.snapshots:
+                # keys and values beside slots that may be snapshotted: the cache keeps a
+                # copy of a sequence's slot at a block boundary (prefix_cache/manager.py).
+                # The other kinds of such state (Nemotron-H, LFM2, Jamba, Solar Open 2:
+                # ``snapshots`` False) are refused below by name until a cell runs them.
+                continue
             if on and (kind.state_kind != "kv" or subsystem in kind.refuses):
                 raise NotImplementedError(
                     f"{subsystem} does not support the {kind.state_kind!r} state of "
@@ -881,6 +903,8 @@ class InferenceEngineV2:
                 rec.n_prompt_tokens = int(lens[lens > 1].sum())
             with tracing.phase("engine.dispatch"):
                 out, counts = self._dispatch_put(mode, bucket, arrays)
+                if self._snapshotting:
+                    self._snapshot_landings(descs, rec)
             self.count_host_sync()
             self.tokens_emitted += len(batch_uids)
             try:
@@ -1044,6 +1068,9 @@ class InferenceEngineV2:
         said = []
         if counts:
             rec.counts = dict(zip(self.kind.step_counts, counts[0].tolist()))
+            if self._snapshotting:
+                taken, restored = self._snapshot_counts.pop(rec.seq, (0, 0))
+                rec.counts.update(n_snapshots_taken=taken, n_snapshots_restored=restored)
             rec.state_step = self._attention.state_step.get(rows)
             if rec.state_step is not None and rows not in self._state_step_said:
                 self._state_step_said.add(rows)
@@ -1253,6 +1280,12 @@ class InferenceEngineV2:
         mutation and donation, so it is NOT safely recoverable; only
         this pre-check is)."""
         _, err = self._validate_burst(batch_uids, int(k))
+        if err is None and self._snapshotting:
+            # a burst may end on a block boundary and may not pass one: the state at the
+            # boundary is copied between programs, not inside one
+            bs = self.block_size
+            return all(int(k) <= bs - self.state_manager.query(uid).seen_tokens % bs
+                       for uid in batch_uids)
         return err is None
 
     def _get_burst_fn(self, key, make):
@@ -1379,6 +1412,8 @@ class InferenceEngineV2:
             out, st, self.kv_cache.k, self.kv_cache.v, *counts, self.state_extra = fn(
                 self.params, self.kv_cache.k, self.kv_cache.v, self.state_extra, meta, entry,
                 opt)
+            if self._snapshotting:
+                self._snapshot_landings(descs, rec)
         self.tokens_emitted += k * n
         return descs, entry_np, out, st, counts
 
@@ -1839,7 +1874,7 @@ class InferenceEngineV2:
         admission counts these as reclaimable capacity."""
         return self.prefix_cache.evictable_blocks if self.prefix_cache is not None else 0
 
-    def prefix_match(self, uid, prompt_tokens):
+    def prefix_match(self, uid, prompt_tokens, breakpoints=()):
         """Start tracking ``uid`` with its longest cached prompt prefix
         pre-populated (no-op returning 0 when the prefix cache is off or
         the sequence already exists). → the number of leading prompt
@@ -1851,13 +1886,35 @@ class InferenceEngineV2:
         keeps state a sequence beyond its blocks (``kind.seq_state``: a
         slot, and what of the prompt's length decides how its rows
         attend) starts tracking ``uid`` here, with that state; ``put``
-        refuses such a model a sequence it was not told of."""
+        refuses such a model a sequence it was not told of. With the prefix
+        cache on, such a sequence starts behind the deepest **snapshot** on
+        its prompt's cached path, that state copied into its own slot
+        (``prefix_cache/manager.py``), or at 0. ``breakpoints``: token
+        offsets of the prompt where a prefix shared with other requests ends
+        (a system prompt's length): a snapshot is taken at the last block
+        boundary at or before each (:meth:`chunk_cut`, :meth:`_snapshot_landings`)."""
         if self.slot_pool is not None:
-            desc = self.state_manager.get_or_create_sequence(uid)
+            sm = self.state_manager
+            desc = sm.query(uid)
+            if desc is None:
+                prompt = None if self.prefix_cache is None else \
+                    [int(t) for t in np.atleast_1d(np.asarray(prompt_tokens))]
+                desc = sm.get_or_create_sequence(uid, prompt_tokens=prompt)
             if desc.state_row is None:
-                self.state_manager.set_state_row(desc, self.kind.seq_state(
-                    self.model_config, self.slot_pool.acquire(), len(prompt_tokens)))
-            return 0
+                # (read before the sequence's own slot is taken: if taking it evicts this
+                # very snapshot, the slot handed over is the one that holds the state)
+                held = self.prefix_cache.snapshot_of(uid) if desc.cached_tokens else None
+                slot = self.slot_pool.acquire()
+                sm.set_state_row(desc, self.kind.seq_state(self.model_config, slot,
+                                                           len(prompt_tokens)))
+                if held is not None:
+                    self._copy_slots([(held, slot)])
+                    self._snapshots_restored += 1
+                bs = self.block_size
+                desc.snapshot_marks = sorted({int(b) // bs * bs for b in breakpoints
+                                              if desc.cached_tokens < int(b) // bs * bs
+                                              < len(prompt_tokens)})
+            return desc.cached_tokens
         if self.prefix_cache is None:
             return 0
         desc = self.state_manager.query(uid)
@@ -1866,6 +1923,65 @@ class InferenceEngineV2:
         prompt = [int(t) for t in np.atleast_1d(np.asarray(prompt_tokens))]
         desc = self.state_manager.get_or_create_sequence(uid, prompt_tokens=prompt)
         return desc.cached_tokens
+
+    def chunk_cut(self, uid, cursor, take):
+        """→ ``take``, or fewer rows where a prompt chunk of ``take`` rows from
+        ``cursor`` would pass one of ``uid``'s breakpoints without ending on
+        it: a scheduler asks before it cuts a chunk, so that the state can be
+        copied where the shared prefix ends."""
+        desc = self.state_manager.query(uid) if self.prefix_cache is not None else None
+        for mark in desc.snapshot_marks if desc is not None else ():
+            if cursor < mark < cursor + take:
+                return mark - cursor
+        return take
+
+    def _copy_slots(self, pairs):
+        """``pairs`` [(from, to)]: slot ``from``'s row of every entry of the
+        kind's ``slot_state`` over slot ``to``'s, in every layer, on the
+        device: one program, dispatched behind whatever wrote ``from`` (the
+        arrays are donated through every program, so the device runs them in
+        order), never a trip through the host."""
+        if self._copy_slots_fn is None:
+            names, scope = self.kind.slot_state, self.kind.snapshot_scope
+
+            def snapshot_copy_slots(extra, src, dst, n):
+                def one(i, extra):
+                    with jax.named_scope(scope):
+                        return {name: jax.lax.dynamic_update_slice_in_dim(
+                            x, jax.lax.dynamic_slice_in_dim(x, src[i], 1, axis=1), dst[i], axis=1)
+                            if name in names else x for name, x in extra.items()}
+                return jax.lax.fori_loop(0, n, one, extra)
+
+            # (the program's name is what a device trace knows it by: benchmark/readers/granite.py)
+            self._copy_slots_fn = jax.jit(snapshot_copy_slots, donate_argnums=0)
+        room = self.max_seqs            # at the most a copy a sequence of a step
+        src, dst = (np.zeros(room, np.int32) for _ in range(2))
+        src[:len(pairs)], dst[:len(pairs)] = zip(*pairs)
+        self.state_extra = self._copy_slots_fn(self.state_extra, src, dst, np.int32(len(pairs)))
+
+    def _snapshot_landings(self, descs, rec):
+        """After a step's program is dispatched: each of its sequences whose
+        length now lands on a block boundary has its slot copied into one of
+        the cache's - a ``breakpoint`` where its request named the place, else
+        ``trailing`` (the newer replaces the older). The rule, whole: **a
+        snapshot is taken wherever a step leaves a sequence on a block
+        boundary**; a scheduler cuts prompt chunks so that the boundaries a
+        request named are such places (:meth:`chunk_cut`), and bursts so that
+        none is passed over (:meth:`can_burst`)."""
+        pairs, bs = [], self.block_size
+        for desc in descs:
+            seen = desc.seen_tokens
+            if seen % bs or not seen:
+                continue
+            kind = "breakpoint" if seen in desc.snapshot_marks else "trailing"
+            slot = self.prefix_cache.snapshot_slot(desc.uid, seen, kind)
+            if slot is not None:
+                pairs.append((desc.state_row[0], slot))
+        if pairs:
+            self._copy_slots(pairs)
+        # for the step's record, once its device counts are in (_note_counts)
+        self._snapshot_counts[rec.seq] = (len(pairs), self._snapshots_restored)
+        self._snapshots_restored = 0
 
     def prefetch_prefix(self, prompt_tokens):
         """Fire-and-forget: stage this prompt's tier-2 KV extension on

@@ -51,8 +51,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models import (GPTConfig, JambaConfig, LagunaConfig, Lfm2MoeConfig, LlamaConfig,
-                                  LongcatFlashConfig, MiniCPMSalaConfig, MoonlightConfig,
+from deepspeed_tpu.models import (GPTConfig, GraniteHybridConfig, JambaConfig, LagunaConfig,
+                                  Lfm2MoeConfig, LlamaConfig, LongcatFlashConfig, MiniCPMSalaConfig, MoonlightConfig,
                                   NemotronHConfig, OuroConfig, SolarOpen2Config)
 from deepspeed_tpu.models import laguna, ouro
 from deepspeed_tpu.models.lfm2 import TOPK_EPS
@@ -550,6 +550,11 @@ class ModelKind:
     step_counts = ()
     seq_rows = 0            # per-sequence rows of the batch (``seq_state``'s length)
     slot_state = ()         # the entries of extra_state a slot is a row of
+    # whether the prefix cache may keep **snapshots** of this kind's slots (a copy of a
+    # sequence's whole slot as it stood at a block boundary: ``prefix_cache/manager.py``), and
+    # the named scope the engine's slot-to-slot copy program bears
+    snapshots = False
+    snapshot_scope = "ds.snapshot"
     experts_at = None       # the entry of params["model"] that holds the routed experts, whole
     # the optional subsystems (``InferenceEngineV2._refuse_unsupported``'s names) a kind
     # whose state is ``kv`` refuses all the same; any other state refuses them all
@@ -1544,17 +1549,23 @@ def _mamba_mixer(ctx, p, layer, x, ssm, conv):
     return _proj(y, p["out_proj"]), ssm, conv
 
 
-def _plain_gqa_attention(cfg, p, layer, x, kc, vc, batch, attn_impl):
+def _plain_gqa_attention(cfg, p, layer, x, kc, vc, batch, attn_impl, softmax_scale=None):
     """A position-free attention mixer on the normalised stream
-    (:class:`NemotronHKind`'s ``*`` layers, :class:`JambaKind`'s and
-    :class:`SolarOpen2Kind`'s attention layers: the recurrent layers carry
-    the order): grouped-query attention over the paged pool's layer
-    ``layer``, queries and keys as projected, no bias; where the layer has a
-    ``gate_proj`` (Solar Open 2's ``use_gqa_gate``), its output times
-    ``sigmoid(x W_gate)``, element-wise, before ``W_o``. → (y, kc, vc)."""
+    (:class:`NemotronHKind`'s ``*`` layers, :class:`JambaKind`'s,
+    :class:`SolarOpen2Kind`'s and :class:`GraniteHybridKind`'s attention
+    layers: the recurrent layers carry the order): grouped-query attention
+    over the paged pool's layer ``layer``, queries and keys as projected, no
+    bias; where the layer has a ``gate_proj`` (Solar Open 2's
+    ``use_gqa_gate``), its output times ``sigmoid(x W_gate)``, element-wise,
+    before ``W_o``. ``softmax_scale``: what multiplies the scores where that
+    is not ``1 / sqrt(d)`` (Granite's ``attention_multiplier``): the queries
+    are scaled beforehand, as :func:`_gpt_layer_step` does, since every
+    attention implementation divides by ``sqrt(d)``. → (y, kc, vc)."""
     T = x.shape[0]
     Hq, Hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     q = _proj(x, p["q_proj"]).reshape(T, Hq, d)
+    if softmax_scale is not None:
+        q = (q.astype(jnp.float32) * (softmax_scale * math.sqrt(d))).astype(q.dtype)
     k = _proj(x, p["k_proj"]).reshape(T, Hkv, d)
     v = _proj(x, p["v_proj"]).reshape(T, Hkv, d)
     out, kc, vc = _paged_attend(q, k, v, kc, vc, layer, batch, d, impl=attn_impl)
@@ -2418,8 +2429,150 @@ def _ouro_layer_step(cfg, rope, batch, attn_impl, carry, xs):
     return (h, kc, vc), None
 
 
+class GraniteHybridKind(ModelKind):
+    """Granite 4.0-H (``models/granite_hybrid.py``): **every layer a mixer
+    and a routed feed-forward**, each added to the stream times
+    ``residual_multiplier`` - the mixer :class:`NemotronHKind`'s Mamba-2
+    layer (:func:`_mamba_mixer`, here in **one group**: every head reads the
+    same ``B`` and ``C`` row) or, once a period, the position-free attention
+    (:func:`_plain_gqa_attention`, scores times ``attention_multiplier``);
+    the feed-forward small SwiGLU experts behind a router that takes the
+    softmax over its picks (:func:`_routed_experts`), one share of an
+    expert-parallel deployment, beside a shared SwiGLU. State as
+    :class:`NemotronHKind`'s: the attention layers' keys and values in the two
+    paged pools ``[La, NB, bs, Hkv * d]``, and ``extra_state``'s ``ssm``
+    ``[Lm, slots + 1, H, P, N]`` float32 and ``conv`` ``[Lm, slots + 1, K - 1,
+    C]``, a slot a sequence.
+
+    The vocabulary is tied: ``ragged_forward`` multiplies the embedding rows
+    by ``embedding_multiplier`` and takes the head from the same matrix;
+    :meth:`final_norm` divides by ``logits_scaling`` (16: a power of two, so
+    dividing the normalised row is dividing the logits, bit for bit).
+
+    **The slot may be snapshotted** (``snapshots``): a slot's content after
+    exactly ``p`` tokens is a function of those tokens alone and a row at
+    position 0 is the only one that ignores it, so a copy taken at a block
+    boundary, restored into another sequence's slot, lets that sequence start
+    at ``p`` (``prefix_cache/manager.py``). Each step counts ``SHARE_COUNTS``,
+    the rows through the mamba layers and the (sequence, mamba layer)s whose
+    state it read and wrote, as :class:`NemotronHKind` does, and of those the
+    ones whose sequence starts in the step (``n_fresh_slots``: written, not read)."""
+    name = "granite_hybrid"
+    config = GraniteHybridConfig
+    state_kind = "kv+slots"
+    step_counts = SHARE_COUNTS + ("n_ssm_rows", "n_state_slots", "n_fresh_slots")
+    seq_rows = 1            # (slot,)
+    slot_state = ("ssm", "conv")
+    snapshots = True
+    snapshot_scope = "ds.granite.snapshot"
+    experts_at = "moe_layers"
+
+    @staticmethod
+    def state_layers(cfg):
+        return max(1, cfg.count("attention"))
+
+    @staticmethod
+    def extra_state(cfg, num_blocks, slots, dtype):
+        Lm = cfg.count("mamba")
+        return {"ssm": jnp.zeros((Lm, slots + 1, cfg.mamba_n_heads, cfg.mamba_d_head,
+                                  cfg.mamba_d_state), jnp.float32),
+                "conv": jnp.zeros((Lm, slots + 1, cfg.mamba_d_conv - 1, cfg.conv_dim), dtype)}
+
+    @staticmethod
+    def _counters(letter):
+        """The stacks a layer of ``cfg.letters``' letter draws from."""
+        return (letter, "f")
+
+    @staticmethod
+    def stack(params, cfg, h, kc, vc, extra, batch, dtype, mesh, attn_impl, lora):
+        GraniteHybridKind.base_only(mesh, lora)
+        model = params["model"]
+        ctx = _SlotStep(cfg, batch, extra["conv"].shape[1], attn_impl)
+        stacks = {"m": model.get("mamba_layers"), "a": model.get("attn_layers"),
+                  "f": model[GraniteHybridKind.experts_at]}
+        experts = stacks["f"]["experts"]
+        r = jnp.asarray(cfg.residual_multiplier, h.dtype)
+
+        def layer(letter, at, carry):
+            h, kc, vc, ssm, conv, picks = carry
+            i = at[letter]
+            lp = _layer_of(stacks[letter], i)
+            x = _rms(h, lp["norm"]["scale"], cfg.rms_norm_eps)
+            if letter == "m":
+                with jax.named_scope("ds.granite.mamba"):
+                    y, ssm, conv = _mamba_mixer(ctx, lp, i, x, ssm, conv)
+            else:
+                with jax.named_scope("ds.granite.attn"):
+                    y, kc, vc = _plain_gqa_attention(cfg, lp, i, x, kc, vc, batch, attn_impl,
+                                                     softmax_scale=cfg.attention_multiplier)
+            h = h + r * y
+            fp = _layer_of(stacks["f"], at["f"])
+            with jax.named_scope("ds.granite.moe"):
+                y, n = _granite_moe(cfg, ctx.real, fp, experts, at["f"],
+                                    _rms(h, fp["norm"]["scale"], cfg.rms_norm_eps))
+            return h + r * y, kc, vc, ssm, conv, picks + n
+
+        carry = (h, kc, vc, extra["ssm"], extra["conv"],
+                 jnp.zeros((len(SHARE_COUNTS),), jnp.int32))
+        carry, done = _run_segments(cfg.segments, GraniteHybridKind._counters, layer, carry)
+        h, kc, vc, ssm, conv, picks = carry
+        mamba = done.get("m", 0)
+        counts = jnp.concatenate([picks, jnp.stack([
+            mamba * jnp.sum(ctx.real.astype(jnp.int32)),
+            mamba * jnp.sum(ctx.here.astype(jnp.int32)),
+            mamba * jnp.sum((ctx.here & ctx.fresh).astype(jnp.int32))])]).astype(jnp.int32)
+        return h, kc, vc, {"ssm": ssm, "conv": conv}, counts[None]
+
+    @staticmethod
+    def final_norm(params, cfg, h):
+        return _rms(h, params["model"]["norm"]["scale"], cfg.rms_norm_eps) \
+            * jnp.asarray(1.0 / cfg.logits_scaling, h.dtype)
+
+    @staticmethod
+    def router(cfg, fp):
+        """The softmax over the picks: over every column, the picks' weights over
+        their sum (``exp(l_j) / Z`` over ``sum_picks exp(l_i) / Z``); no bias,
+        no scale; this rank's share."""
+        weight = fp["router"]["weight"]
+        return Router(weight, jnp.zeros((weight.shape[-1],), jnp.float32),
+                      cfg.num_experts_per_tok, 1.0, score=jax.nn.softmax,
+                      share=ExpertShare(cfg.first_expert_held, cfg.held, cfg.num_local_experts))
+
+    @staticmethod
+    def expert_layer(params, cfg, layer, x):
+        """Layer ``layer``'s feed-forward alone (:func:`_layer_of`): x [T, D] the
+        normalised stream, every row a token → y (the routed share and the
+        shared expert, before the residual multiplier)."""
+        moe = params["model"]["moe_layers"]
+        return _granite_moe(cfg, jnp.ones(x.shape[0], bool), _layer_of(moe, layer),
+                            moe["experts"], layer, x)[0]
+
+    @staticmethod
+    def mamba_layer(params, cfg, layer, x, ssm, conv, batch):
+        """``mamba`` layer ``layer``'s mixer (its index among the mamba layers)
+        alone - the same packed recurrence, the same reads and writes of the
+        slot pool: x [T, D] the normalised stream → (y [T, D], ssm, conv)."""
+        lp = _layer_of(params["model"]["mamba_layers"], layer)
+        return _mamba_mixer(_SlotStep(cfg, batch, conv.shape[1]), lp, layer, x, ssm, conv)
+
+    @staticmethod
+    def attention_layer(params, cfg, layer, x, kc, vc, batch, attn_impl=None):
+        """Attention mixer ``layer`` (its index among the attention layers) alone."""
+        lp = _layer_of(params["model"]["attn_layers"], layer)
+        return _plain_gqa_attention(cfg, lp, layer, x, kc, vc, batch, attn_impl,
+                                    softmax_scale=cfg.attention_multiplier)
+
+
+def _granite_moe(cfg, real, fp, experts, layer, x):
+    """One Granite feed-forward on the normalised stream, as this share gives
+    it, and its ``SHARE_COUNTS``; ``real`` [T]: the rows that are not padding."""
+    y, counts = _routed_experts(x, GraniteHybridKind.router(cfg, fp), experts, layer, real)
+    with jax.named_scope("ds.moe_shared"):
+        return y + _swiglu(x, fp["shared_experts"]), counts
+
+
 # Every kind, a kind whose config class derives another's before that one's.
-KINDS = (OuroKind, LagunaKind, SolarOpen2Kind, JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind,
+KINDS = (GraniteHybridKind, OuroKind, LagunaKind, SolarOpen2Kind, JambaKind, Lfm2Kind, NemotronHKind, SalaKind, LongcatKind,
          MoonlightKind, GPTKind, LlamaKind)
 
 

@@ -19,11 +19,43 @@ ownership on top of :class:`BlockedKVCache`/:class:`BlockedAllocator`:
 - allocation pressure reclaims unreferenced cached blocks in LRU order
   (``reserve``/``ensure_free``), so caching only ever trades IDLE pool
   space for hits — it can never starve live sequences.
+
+**Snapshots** (``slot_pool`` given: a model kind whose sequences hold a slot
+of recurrent state beside their blocks, ``ModelKind.snapshots``). Blocks
+alone cannot start such a sequence past token 0: the keys and values of the
+first ``p`` tokens are in the pool, but the state the other layers had
+reached after them is not. A snapshot is a copy of a sequence's whole slot as
+it stood after exactly ``p`` tokens, ``p`` a block boundary, in a slot of the
+same device array that the cache owns (``SlotPool.acquire(cached=True)``);
+the engine copies slot to slot on the device (``InferenceEngineV2
+._copy_slots``) and this class keeps the books:
+
+- a radix node at depth ``p`` may carry one (``RadixNode.snapshot``), and
+  :meth:`acquire` / :meth:`match_len` end a match at **the deepest node on the
+  matched path that carries one**: the sequence leases the blocks up to it and
+  starts at ``p`` with that state copied into its own slot
+  (:meth:`snapshot_of`); with none on the path it starts at 0, leasing nothing;
+- the engine asks for a slot to copy into (:meth:`snapshot_slot`) when a live
+  sequence's length lands on a block boundary - **trailing** (every such
+  landing: the newer replaces the older, and the first one of a sequence that
+  resumed from a trailing snapshot replaces that one, so a conversation keeps
+  at most one) - or on a **breakpoint** its request named (where a prefix
+  shared with other requests ends: a system prompt's last whole block). They
+  wait under the sequence's uid until it retires, when :meth:`release` hangs
+  each on the node of its depth (a node that has one already keeps it) and
+  inserts no block past the deepest of them: a block past every snapshot could
+  never be matched;
+- the cache's slots are evicted least recently used - taken, restored from or
+  matched - whether they hang on a node or still wait (:meth:`_evict_snapshot`:
+  the slot pool's ``reclaim``, so a live sequence's slot is always there), and
+  leave with their node when its block is evicted or the trie is cleared for a
+  new weight version (``RadixPrefixIndex.on_unlink``).
 """
 
+import collections
 import threading
 
-from deepspeed_tpu.inference.v2.prefix_cache.radix_index import RadixPrefixIndex
+from deepspeed_tpu.inference.v2.prefix_cache.radix_index import RadixNode, RadixPrefixIndex
 from deepspeed_tpu.utils.env_registry import env_opt_bool
 from deepspeed_tpu.utils.sanitize import (check_prefix_index,
                                           sanitize_enabled, tracked_lock)
@@ -42,8 +74,15 @@ def prefix_cache_enabled(config) -> bool:
 
 class PrefixCacheManager:
 
-    def __init__(self, kv_cache, max_cached_blocks=0):
+    def __init__(self, kv_cache, max_cached_blocks=0, slot_pool=None):
         self.kv_cache = kv_cache
+        # the snapshots' books (the module docstring); all empty without a slot pool
+        self.slot_pool = slot_pool
+        self._snapshots = collections.OrderedDict()   # slot -> its node, or the uid it waits under
+        self._waiting = {}          # uid -> {depth in tokens: (slot, kind)}
+        self._resumed = {}          # uid -> the node whose trailing snapshot it started from
+        self.snapshots_taken = self.snapshots_restored = self.snapshot_evictions = 0
+        self.tokens_saved_by_kind = {"blocks": 0, "breakpoint": 0, "trailing": 0}
         self.block_size = int(kv_cache.block_size)
         # 0 = bounded only by pool pressure (LRU eviction on demand)
         self.max_cached_blocks = int(max_cached_blocks)
@@ -65,6 +104,93 @@ class PrefixCacheManager:
         self._lock = tracked_lock(threading.RLock(),
                                   "PrefixCacheManager._lock")
         self._sanitize = sanitize_enabled()
+        if slot_pool is not None:
+            slot_pool.reclaim = self._evict_snapshot
+            self.index.on_unlink = self._node_left
+
+    # ------------------------------------------------------------ snapshots
+    def _deepest_snapshot(self, path):
+        """→ how many nodes of ``path`` a sequence of a slot kind can start
+        behind: up to the deepest that carries a snapshot."""
+        if self.slot_pool is None:
+            return len(path)
+        return max((i + 1 for i, node in enumerate(path) if node.snapshot is not None), default=0)
+
+    def snapshot_of(self, uid):
+        """→ the slot holding the state ``uid``'s leased prefix ends in (the
+        engine copies it into the sequence's own), or None: no lease."""
+        with self._lock:
+            path = self._leases.get(uid)
+            return path[-1].snapshot if path else None
+
+    def snapshot_slot(self, uid, depth, kind):
+        """``uid``'s state after ``depth`` tokens (a block boundary) is to be
+        kept: → the cache's slot to copy it into, or None where the cache can
+        have none. ``kind``: ``trailing`` | ``breakpoint`` (the module docstring)."""
+        with self._lock:
+            waiting = self._waiting.setdefault(uid, {})
+            if depth in waiting:
+                return None
+            slot = None
+            if kind == "trailing":
+                older = [d for d, (_, k) in waiting.items() if k == "trailing"]
+                if older:
+                    slot = waiting.pop(older[0])[0]
+                else:
+                    node = self._resumed.pop(uid, None)
+                    if node is not None and node.snapshot_kind == "trailing" \
+                            and node.snapshot is not None:
+                        slot, node.snapshot, node.snapshot_kind = node.snapshot, None, None
+            if slot is None:
+                if not self.slot_pool.reclaimable_slots:
+                    return None
+                slot = self.slot_pool.acquire(cached=True)
+            waiting[depth] = (slot, kind)
+            self._snapshots[slot] = uid
+            self._snapshots.move_to_end(slot)
+            self.snapshots_taken += 1
+            return slot
+
+    def _evict_snapshot(self):
+        """The least recently used of the cache's slots goes back to the
+        pool; → whether there was one."""
+        with self._lock:
+            if not self._snapshots:
+                return False
+            slot, holder = next(iter(self._snapshots.items()))      # the oldest
+            if isinstance(holder, RadixNode):
+                holder.snapshot = holder.snapshot_kind = None
+            else:       # it waits under a live sequence's uid
+                waiting = self._waiting.get(holder, {})
+                for depth in [d for d, (s, _) in waiting.items() if s == slot]:
+                    del waiting[depth]
+            self._give_back(slot, evicted=True)
+            return True
+
+    def _give_back(self, slot, evicted=False):
+        """One of the cache's slots returns to the pool."""
+        del self._snapshots[slot]
+        self.slot_pool.release(slot)
+        self.snapshot_evictions += evicted
+
+    def _node_left(self, node):
+        """A node left the trie carrying a snapshot: its slot goes back."""
+        self._give_back(node.snapshot, evicted=True)
+        node.snapshot = node.snapshot_kind = None
+
+    def _hang_snapshots(self, uid, chain):
+        """``uid`` retires and ``chain`` is the node of each of its leading
+        blocks in the trie: each snapshot that waited hangs on the node of its
+        depth; one whose node is missing or has one already goes back."""
+        for depth, (slot, kind) in self._waiting.pop(uid, {}).items():
+            at = depth // self.block_size - 1
+            node = chain[at] if 0 <= at < len(chain) else None
+            if node is None or node.snapshot is not None:
+                self._give_back(slot)
+                continue
+            node.snapshot, node.snapshot_kind = slot, kind
+            self._snapshots[slot] = node
+        self._resumed.pop(uid, None)
 
     def _check(self):
         if self._sanitize:
@@ -137,6 +263,7 @@ class PrefixCacheManager:
             if self.tier is not None:
                 self._promote_tier_hits_locked(prompt_tokens, max_blocks)
             path = self.index.match(prompt_tokens, max_blocks)
+            path = path[:self._deepest_snapshot(path)]
             self.lookups += 1
             if not path:
                 return [], 0
@@ -152,6 +279,12 @@ class PrefixCacheManager:
             cached = len(path) * self.block_size
             self.hits += 1
             self.tokens_saved += cached
+            last = path[-1]
+            self.tokens_saved_by_kind[last.snapshot_kind or "blocks"] += cached
+            if last.snapshot is not None:
+                self._snapshots.move_to_end(last.snapshot)
+                self.snapshots_restored += 1
+                self._resumed[uid] = last
             if tier2_blocks:
                 self.tier2_hits += 1
                 self.tier2_tokens_saved += tier2_blocks * self.block_size
@@ -241,7 +374,7 @@ class PrefixCacheManager:
         with self._lock:
             max_blocks = (len(prompt_tokens) - 1) // self.block_size
             path = self.index.match(prompt_tokens, max_blocks)
-            n = len(path)
+            n = self._deepest_snapshot(path)
             if self.tier is not None and n < max_blocks:
                 parent_key = path[-1].key if path else self.index.root.key
                 n += self.tier.probe_chain(parent_key, prompt_tokens, n,
@@ -254,6 +387,8 @@ class PrefixCacheManager:
         with self._lock:
             for node in self._leases.pop(uid, ()):
                 self.index.decref(node)
+            if uid in self._waiting or uid in self._resumed:
+                self._hang_snapshots(uid, ())       # nothing was inserted: they go back
             self._check()
 
     def release(self, uid, desc):
@@ -266,9 +401,14 @@ class PrefixCacheManager:
             # only blocks whose token content was recorded are insertable
             full = min(desc.seen_tokens, len(desc.tokens)) // bs
             full = min(full, len(desc.blocks))
+            if self.slot_pool is not None:
+                # no block past the deepest snapshot: it could never be matched
+                deepest = max(self._waiting.get(uid, {}), default=0) // bs
+                full = min(full, max(deepest, len(self._leases.get(uid, ()))))
             freed = []
             node = self.index.root
             chain = set()
+            nodes = []      # the node of each leading block, in order
             for i in range(full):
                 chunk = tuple(int(t) for t in desc.tokens[i * bs:(i + 1) * bs])
                 block = int(desc.blocks[i])
@@ -281,6 +421,7 @@ class PrefixCacheManager:
                     node = existing
                     self.index.touch(node)
                     chain.add(node)
+                    nodes.append(node)
                     continue
                 if self.max_cached_blocks and \
                         self.index.num_nodes >= self.max_cached_blocks:
@@ -293,8 +434,11 @@ class PrefixCacheManager:
                     freed.extend(evicted)
                 node = self.index.insert_child(node, chunk, block)
                 chain.add(node)
+                nodes.append(node)
                 self.insertions += 1
             freed.extend(int(b) for b in desc.blocks[full:])
+            if self.slot_pool is not None:
+                self._hang_snapshots(uid, nodes)
             self.release_lease(uid)
             if freed:
                 self.kv_cache.free(freed)
@@ -315,4 +459,12 @@ class PrefixCacheManager:
             # no tier is attached — the schema stays stable for monitors)
             "tier2_hits": self.tier2_hits,
             "tier2_tokens_saved": self.tier2_tokens_saved,
+            # the slot kinds' snapshots (0s for a kind of keys and values alone)
+            "snapshots_cached": len(self._snapshots),
+            "snapshots_taken": self.snapshots_taken,
+            "snapshots_restored": self.snapshots_restored,
+            "snapshot_evictions": self.snapshot_evictions,
+            # tokens_saved by what a match ended on: blocks alone (a kind of keys and
+            # values), a breakpoint's snapshot, a trailing one
+            "tokens_saved_by_kind": dict(self.tokens_saved_by_kind),
         }

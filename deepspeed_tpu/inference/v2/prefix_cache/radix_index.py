@@ -16,9 +16,18 @@ whole subtree — the count of ref-0 nodes IS the number of reclaimable
 blocks, and eviction can always cascade leaf-by-leaf in LRU order
 without stranding a referenced descendant.
 
+A node may carry a **snapshot** (``snapshot``: a slot of the engine's slot
+pool, ``snapshot_kind``: why it was taken): for a model kind whose sequences
+hold a slot of state beside their blocks, the state as it stood after exactly
+the tokens the node's path spells. The index only carries the field and tells
+its owner when a node that has one leaves the trie (``on_unlink``); what a
+snapshot is and who owns its slot is ``manager.py``'s.
+
 Pure host-side bookkeeping; the device only ever sees block ids through
 the block tables the sequences build.
 """
+
+import heapq
 
 
 def _chunk_key(parent_key, chunk):
@@ -29,7 +38,7 @@ def _chunk_key(parent_key, chunk):
 
 class RadixNode:
     __slots__ = ("key", "tokens", "block_id", "parent", "children", "ref",
-                 "last_used", "tier2")
+                 "last_used", "tier2", "snapshot", "snapshot_kind")
 
     def __init__(self, key, tokens, block_id, parent):
         self.key = key
@@ -43,6 +52,9 @@ class RadixNode:
         # acquire that matches through here consumes the flag for
         # tier-2-hit attribution (promotion metrics without double counts)
         self.tier2 = False
+        # a slot of the slot pool holding the state after this node's path, or None
+        self.snapshot = None
+        self.snapshot_kind = None
 
     @property
     def is_leaf(self):
@@ -64,6 +76,7 @@ class RadixPrefixIndex:
         self.num_nodes = 0       # cached blocks currently owned by the trie
         self._ref0 = 0           # nodes with ref == 0 (== reclaimable blocks)
         self.evictions = 0       # blocks evicted over the index's lifetime
+        self.on_unlink = None    # told each node that leaves the trie carrying a snapshot
 
     # ------------------------------------------------------------- queries
     @property
@@ -131,6 +144,8 @@ class RadixPrefixIndex:
         self.num_nodes -= 1
         self._ref0 -= 1
         self.evictions += 1
+        if node.snapshot is not None and self.on_unlink is not None:
+            self.on_unlink(node)
 
     def clear(self, new_root_key=None):
         """Drop EVERY cached node (weight-refresh invalidation: KV built
@@ -150,6 +165,8 @@ class RadixPrefixIndex:
                         "prefix-cache clear with a live lease outstanding"
                     blocks.append(child.block_id)
                     stack.append(child)
+                    if child.snapshot is not None and self.on_unlink is not None:
+                        self.on_unlink(child)
         self.root.children = {}
         self.evictions += self.num_nodes
         self.num_nodes = 0
@@ -172,25 +189,26 @@ class RadixPrefixIndex:
         ``(parent_key, tokens, block_id)`` tuples, captured BEFORE the
         unlink severs ``parent``. The KV-tier demotion path re-chains a
         spilled block's identity from exactly these fields."""
+        # one walk for the reclaimable leaves, then a heap: a victim's parent joins it once
+        # its last child has gone (the same victims, in the same order, as a walk a victim)
+        heap, stack = [], [self.root]
+        while stack:
+            node = stack.pop()
+            for bucket in node.children.values():
+                for child in bucket:
+                    if child.ref == 0 and child.is_leaf:
+                        if child not in protect:
+                            heap.append((child.last_used, id(child), child))
+                    else:
+                        stack.append(child)     # a subtree may hold ref-0 leaves
+        heapq.heapify(heap)
         victims = []
-        while len(victims) < n_blocks:
-            victim = None
-            stack = [self.root]
-            while stack:
-                node = stack.pop()
-                for bucket in node.children.values():
-                    for child in bucket:
-                        if child.ref > 0:
-                            stack.append(child)  # subtree may hold ref-0 leaves
-                        elif child.is_leaf:
-                            if child not in protect and (
-                                    victim is None
-                                    or child.last_used < victim.last_used):
-                                victim = child
-                        else:
-                            stack.append(child)
-            if victim is None:
-                break
-            victims.append((victim.parent.key, victim.tokens, victim.block_id))
+        while heap and len(victims) < n_blocks:
+            _, _, victim = heapq.heappop(heap)
+            parent = victim.parent
+            victims.append((parent.key, victim.tokens, victim.block_id))
             self._unlink(victim)
+            if parent is not self.root and parent.ref == 0 and parent.is_leaf \
+                    and parent not in protect:
+                heapq.heappush(heap, (parent.last_used, id(parent), parent))
         return victims

@@ -134,6 +134,9 @@ class DSSequenceDescriptor:
         # ---- prefix-cache bookkeeping (zero/empty when caching is off) ----
         self.cached_tokens = 0   # leading tokens whose KV came from the cache
         self.shared_blocks = 0   # leading blocks owned by the radix trie
+        # block boundaries of the prompt at which its request asked for a snapshot of the
+        # sequence's slot (``InferenceEngineV2.prefix_match``'s breakpoints), ascending
+        self.snapshot_marks = ()
         # token ids written to the KV cache, in order (== KV content over
         # [0, seen_tokens)); the engine records these only when a prefix
         # cache is attached, so retire can content-address the blocks

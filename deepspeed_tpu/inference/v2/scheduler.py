@@ -30,8 +30,12 @@ from deepspeed_tpu.utils import tracing
 class Request:
 
     def __init__(self, uid, prompt_tokens, max_new_tokens, priority=0, spec=True,
-                 adapter_id=None, sample=None, schema=None):
+                 adapter_id=None, sample=None, schema=None, breakpoints=()):
         self.uid = uid
+        # token offsets of the prompt where a prefix shared with other requests ends (a
+        # system prompt's length): the engine keeps a snapshot of the sequence's state
+        # there, for a model kind that has such state and a prefix cache (engine.prefix_match)
+        self.breakpoints = tuple(int(b) for b in breakpoints)
         self.prompt = list(np.atleast_1d(np.asarray(prompt_tokens)).tolist())
         self.max_new_tokens = max_new_tokens
         self.priority = int(priority)  # larger = scheduled first
@@ -152,7 +156,7 @@ class DynamicSplitFuseScheduler:
         self.rows_held_back = 0
 
     def add_request(self, uid, prompt_tokens, max_new_tokens=16, priority=0,
-                    spec=True, adapter_id=None, sample=None, schema=None):
+                    spec=True, adapter_id=None, sample=None, schema=None, breakpoints=()):
         if uid in self.requests:
             raise ValueError(f"uid {uid} already queued")
         if sample is not None:
@@ -173,7 +177,7 @@ class DynamicSplitFuseScheduler:
                              f"constraint")
         req = Request(uid, prompt_tokens, max_new_tokens, priority=priority,
                       spec=spec, adapter_id=adapter_id, sample=sample,
-                      schema=schema)
+                      schema=schema, breakpoints=breakpoints)
         if not req.prompt:
             raise ValueError(f"uid {uid}: empty prompt can never be scheduled")
         if schema is not None:
@@ -339,7 +343,8 @@ class DynamicSplitFuseScheduler:
                     self._planned_first.append(r)
                     match = getattr(self.engine, "prefix_match", None)
                     if match is not None and r.prefill_cursor == 0:
-                        r.prefix_cached_tokens = int(match(r.uid, r.prompt))
+                        named = {"breakpoints": r.breakpoints} if r.breakpoints else {}
+                        r.prefix_cached_tokens = int(match(r.uid, r.prompt, **named))
                         r.prefill_cursor = r.prefix_cached_tokens
                         if r.prefix_cached_tokens:
                             # the blocks it leased are no longer the cache's to give
@@ -348,6 +353,8 @@ class DynamicSplitFuseScheduler:
                                 claimed = len(uids) + -(-(self.budget - budget) // block)
                             avail = self._free_blocks()
                 take = min(budget, len(r.prompt) - r.prefill_cursor)
+                if r.breakpoints:   # a chunk ends where a snapshot is to be taken
+                    take = self.engine.chunk_cut(r.uid, r.prefill_cursor, take)
                 if tight:
                     take, need = self._fit(r.uid, take, avail - claimed)
                     if take < 1:

@@ -26,6 +26,9 @@ from deepspeed_tpu.models.laguna import (LAGUNA_CONFIGS, LagunaConfig, LagunaFor
                                          build_laguna)  # noqa: F401
 from deepspeed_tpu.models.ouro import (OURO_CONFIGS, OuroConfig, OuroForCausalLM,
                                        build_ouro)  # noqa: F401
+from deepspeed_tpu.models.granite_hybrid import (GRANITE_HYBRID_CONFIGS, GraniteHybridConfig,
+                                                 GraniteHybridForCausalLM,
+                                                 build_granite_hybrid)  # noqa: F401
 from deepspeed_tpu.models.mellum import (MELLUM_CONFIGS, MellumConfig,
                                          build_mellum)  # noqa: F401  (trained, not served)
 
@@ -36,7 +39,8 @@ MODEL_REGISTRY = ((LLAMA_CONFIGS, build_llama), (GPT_CONFIGS, build_gpt),
                   (MINICPM_SALA_CONFIGS, build_minicpm_sala),
                   (NEMOTRON_H_CONFIGS, build_nemotron_h), (LFM2_CONFIGS, build_lfm2),
                   (JAMBA_CONFIGS, build_jamba), (SOLAR_OPEN2_CONFIGS, build_solar_open2),
-                  (LAGUNA_CONFIGS, build_laguna), (OURO_CONFIGS, build_ouro))
+                  (LAGUNA_CONFIGS, build_laguna), (OURO_CONFIGS, build_ouro),
+                  (GRANITE_HYBRID_CONFIGS, build_granite_hybrid))
 
 
 def build_model(preset, **overrides):

@@ -23,13 +23,18 @@ rows, their slots, whether they are fresh and the decays ride in SMEM
 (scalar prefetch). The grid runs over the **live** sequence rows alone, a
 dynamic bound as the paged kernels' ``live_rows`` is, so what a call moves
 is ``live rows x 2 x H x P x N x 4`` bytes and not the layer's. A grid
-step takes one slot a group at a time: a tile ``[H / G, P, N]`` (512 KB at
-128 x 64 x 128 in 8 groups) is copied into one of two VMEM buffers while
-the tile before it is worked on - the next sequence's first tile is
-started from the last tile of this one, as the paged kernels start the
-next token's - and the result leaves from one of two others, so the reads,
-the arithmetic and the writes of neighbouring tiles overlap. A fresh
-sequence's tiles are not fetched.
+step takes one slot a tile at a time: a tile is ``[Ht, P, N]``, ``Ht`` heads
+**inside one group** (:func:`tile_heads`: a whole group's ``H / G`` where
+four such tiles fit ``TILE_VMEM_BYTES`` - 512 KB at 128 x 64 x 128 in 8
+groups - and otherwise the largest divisor of ``H / G`` whose tile is no
+larger than that 512 KB, the size the pipeline was fitted at: 16 heads of
+the 128 of a model with **one group**, whose ``b`` / ``c`` row every tile
+then shares; tile ``t`` reads group ``t * Ht // (H / G)``'s). It is copied
+into one of two VMEM buffers while the tile before it is worked on - the
+next sequence's first tile is started from the last tile of this one, as
+the paged kernels start the next token's - and the result leaves from one
+of two others, so the reads, the arithmetic and the writes of neighbouring
+tiles overlap. A fresh sequence's tiles are not fetched.
 
 **Arithmetic.** The state is float32 and stays so: the update multiplies
 and adds float32 on the VPU. What the vector unit does badly is the two
@@ -68,6 +73,8 @@ TILE_VMEM_BYTES = 8 << 20
 # scalar prefetch holds the [S, H] decays beside the rows' indices
 SMEM_BYTES = 512 << 10
 READ_PIECES = 2
+# a tile that is not a whole group is at most this large: the tile the pipeline was fitted at
+FITTED_TILE_BYTES = 512 << 10
 
 
 def xla_ssm_state_step(pool, layer, slot, fresh, here, c, b, decay, left):
@@ -98,17 +105,30 @@ def xla_ssm_state_step(pool, layer, slot, fresh, here, c, b, decay, left):
     return pool.at[layer].set(state.reshape(NS, H, P, N)), seen
 
 
+def tile_heads(pool_shape, n_groups):
+    """→ ``Ht``, the heads of one tile ``[Ht, P, N]``: a whole group's where
+    four such tiles fit ``TILE_VMEM_BYTES`` (every shape the kernel took
+    before it had tiles inside a group: their programs are the ones they
+    were), else the largest divisor of ``H / G`` whose tile is within
+    ``FITTED_TILE_BYTES``; 0 where even one head's is not."""
+    _, _, H, P, N = pool_shape
+    per, head = H // n_groups, P * N * 4
+    if 4 * per * head <= TILE_VMEM_BYTES:
+        return per
+    return max((d for d in range(1, per + 1) if per % d == 0 and d * head <= FITTED_TILE_BYTES),
+               default=0)
+
+
 def kernel_supported(pool_shape, n_groups, n_rows):
-    """Can Mosaic tile it? A tile is ``[H / G, P, N]`` float32 rows of the
-    pool: ``N`` whole 128-lane vregs, ``P`` whole 8-sublane tiles (the
-    tile is worked on as ``[H / G * P, N]``), four of it within
-    ``TILE_VMEM_BYTES``, and the ``n_rows`` sequence rows' decays within
-    the SMEM budget."""
+    """Can Mosaic tile it? A tile is ``[Ht, P, N]`` float32 rows of the
+    pool (:func:`tile_heads`): ``N`` whole 128-lane vregs, ``P`` whole
+    8-sublane tiles (the tile is worked on as ``[Ht * P, N]``), four of it
+    within ``TILE_VMEM_BYTES``, and the ``n_rows`` sequence rows' decays
+    within the SMEM budget."""
     _, _, H, P, N = pool_shape
     if n_groups < 1 or H % n_groups or N % 128 or P % 8:
         return False
-    tile = H // n_groups * P * N * 4
-    return 4 * tile <= TILE_VMEM_BYTES and n_rows * (H + 3) * 4 + 4 <= SMEM_BYTES
+    return tile_heads(pool_shape, n_groups) > 0 and n_rows * (H + 3) * 4 + 4 <= SMEM_BYTES
 
 
 def state_step_impl(pool_shape, n_groups, n_rows):
@@ -149,9 +169,10 @@ def _rows_of(pieces, order):
 
 def _kernel(meta_ref, row_ref, slot_ref, fresh_ref, decay_ref,
             c_ref, b_ref, left_ref, pool_ref, out_ref, seen_ref,
-            in_buf, out_buf, sems, *, G, per, P, N, unit):
-    """One live sequence row: its slot's ``G`` tiles in turn. c/b/left/seen
-    blocks [1, G, N] / [1, G, per * P] of the row (VMEM); pool/out: the
+            in_buf, out_buf, sems, *, G, per, group_tiles, P, N, unit):
+    """One live sequence row: its slot's ``G`` tiles of ``per`` heads in
+    turn (the caller's ``tiles`` and ``Ht``: a group is ``group_tiles`` of
+    them). c/b blocks [1, groups, N], left/seen [1, G, per * P] of the row (VMEM); pool/out: the
     whole pool, one buffer under two names (HBM); the rest in SMEM, meta
     the layer and the number of live rows (the grid's bound, but 1 where
     there is none: an empty grid is not asked of Mosaic, and that step
@@ -199,8 +220,9 @@ def _kernel(meta_ref, row_ref, slot_ref, fresh_ref, decay_ref,
 
             # a fresh sequence's buffer holds whatever it held: selected away, NaN or not
             tile = jnp.where(fresh, 0.0, in_buf[buf]).reshape(R, N)
-            c = c_ref[0, pl.ds(g, 1), :]             # [1, N]
-            b = b_ref[0, pl.ds(g, 1), :]
+            group = g if group_tiles == 1 else g // group_tiles
+            c = c_ref[0, pl.ds(group, 1), :]         # [1, N]
+            b = b_ref[0, pl.ds(group, 1), :]
             left = left_ref[0, pl.ds(g, 1), :]       # [1, R]
             if unit == "mxu":
                 cm = _rows_of(_pieces(c, 3), (0, 1, 2))
@@ -243,26 +265,29 @@ def _state_call(pool, layer, slot, fresh, here, c, b, decay, left, unit, interpr
     """The kernel over the live rows (jitted so that a cell's programs
     share one trace of it)."""
     H, P, N = pool.shape[2:]
-    S, G = c.shape[:2]
-    per = H // G
+    S, groups = c.shape[:2]
+    # ``per`` heads a tile, ``G`` tiles a slot, ``group_tiles`` of them a group (1: a tile is
+    # a group, as it was before tiles lay inside one)
+    per = tile_heads(pool.shape, groups) or H // groups     # (interpreted: any shape runs)
+    G, group_tiles = H // per, H // groups // per
     f32 = jnp.float32
     order = jnp.argsort(jnp.logical_not(here), stable=True).astype(jnp.int32)   # live rows first
     n_live = jnp.sum(here.astype(jnp.int32))
 
-    def row_block(width):
-        return pl.BlockSpec((1, G, width), lambda i, meta, row, *_: (row[i], 0, 0))
+    def row_block(width, rows=G):
+        return pl.BlockSpec((1, rows, width), lambda i, meta, row, *_: (row[i], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,      # layer and live rows; the rows, their slots, fresh; decays
         grid=(jnp.maximum(n_live, 1),),
-        in_specs=[row_block(N), row_block(N), row_block(per * P),
+        in_specs=[row_block(N, groups), row_block(N, groups), row_block(per * P),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[pl.BlockSpec(memory_space=pl.ANY), row_block(per * P)],
         scratch_shapes=[pltpu.VMEM((2, per, P, N), f32), pltpu.VMEM((2, per, P, N), f32),
                         pltpu.SemaphoreType.DMA((2, 2))],       # [in | out, buffer]
     )
     new, seen = pl.pallas_call(
-        functools.partial(_kernel, G=G, per=per, P=P, N=N, unit=unit),
+        functools.partial(_kernel, G=G, per=per, group_tiles=group_tiles, P=P, N=N, unit=unit),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((S, G, per * P), f32)],
@@ -290,7 +315,7 @@ def ssm_state_step(pool, layer, slot, fresh, here, c, b, decay, left, unit="mxu"
         interpret = default_interpret()
     if not interpret and not kernel_supported(pool.shape, c.shape[1], c.shape[0]):
         raise ValueError(
-            f"the state step kernel needs N % 128 == 0, P % 8 == 0, a group's tile within "
-            f"{TILE_VMEM_BYTES >> 20} MB of VMEM four times and the rows' decays in SMEM; got a "
+            f"the state step kernel needs N % 128 == 0, P % 8 == 0, a head's [P, N] within "
+            f"{FITTED_TILE_BYTES >> 10} KB and the rows' decays in SMEM; got a "
             f"pool {pool.shape} in {c.shape[1]} groups under {c.shape[0]} sequence rows")
     return _state_call(pool, layer, slot, fresh, here, c, b, decay, left, unit, interpret)

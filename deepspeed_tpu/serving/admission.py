@@ -136,7 +136,9 @@ class CapacityGate:
     token budget, however the scheduler deals it) add ``ceil(token_budget /
     block_size)`` over all sequences - kept back from
     ``usable_window_blocks`` once, not committed a request. ``refused_by``
-    counts what held a request back, by pool.
+    counts what held a request back, by pool. An engine whose sequences own
+    a slot of state each is also asked for the slot an arriving request will
+    own: free, or one of the prefix cache's snapshots, which give way (LRU).
     """
 
     def __init__(self, engine, token_budget, pool="unified", max_burst=1):
@@ -162,6 +164,11 @@ class CapacityGate:
             self.usable_window_blocks = int(self.window_pool.free_blocks) \
                 - -(-self.token_budget // self.block_size)
         self.refused_by = {"kv_blocks": 0, "window_blocks": 0, "sequences": 0}
+        # an engine whose sequences each own a slot of state (``ragged/slot_pool.py``): the
+        # pool may hold the prefix cache's snapshots too, which a sequence can always take
+        self.slot_pool = getattr(engine, "slot_pool", None)
+        if self.slot_pool is not None:
+            self.refused_by["slots"] = 0
 
     def _engine_blocks(self):
         """Blocks the pool can give right now: the free list and what the
@@ -264,6 +271,13 @@ class CapacityGate:
         if live + 1 > self.max_tracked:
             self.refused_by["sequences"] += 1
             return False
+        if self.slot_pool is not None:
+            # the slot this request will own, beside those of admitted requests that the
+            # engine has not begun (a begun one holds its slot): free or the cache's to give
+            awaited = sum(1 for held in self._admitted if self.engine.query(held) is None)
+            if self.slot_pool.reclaimable_slots < awaited + 1:
+                self.refused_by["slots"] += 1
+                return False
         self._admitted[uid] = [need, worst, need_window]
         self.committed_worst += worst
         self.committed_window_blocks += need_window

@@ -279,7 +279,7 @@ class ServingGateway:
     # ---------------------------------------------------------------- client
     def submit(self, prompt_tokens, max_new_tokens=None, priority=None,
                deadline_ms=None, spec=True, adapter_id=None, sample=None,
-               schema=None):
+               schema=None, cache_breakpoints=()):
         """Accept a request from any thread → :class:`RequestHandle`.
         ``spec=False`` opts this request out of speculative decoding
         (it still rides in verify batches, just without drafts).
@@ -293,7 +293,11 @@ class ServingGateway:
         regex (str), or a precompiled
         :class:`~deepspeed_tpu.inference.structured.grammar.CompiledSchema`;
         raw schemas compile through the process-wide schema cache over
-        ``config.token_strings``.
+        ``config.token_strings``. ``cache_breakpoints``: token offsets of
+        the prompt where a prefix shared with other requests ends (a system
+        prompt's length): behind a model kind with recurrent state and a
+        prefix cache, the state is snapshotted at the last block boundary at
+        or before each, so the next request with that prefix starts there.
 
         Raises :class:`RequestTooLargeError` when the request can never
         fit this engine, :class:`QueueFullError` per the admission
@@ -390,6 +394,9 @@ class ServingGateway:
                                spec=spec, adapter_id=adapter_id,
                                sample=sample, schema=schema)
         handle._cancel_cb = self._request_cancel
+        # where a prefix shared with other requests ends (a system prompt's length), for the
+        # prefix cache of a model kind with recurrent state (scheduler.Request.breakpoints)
+        handle.cache_breakpoints = tuple(int(b) for b in cache_breakpoints)
         try:
             shed = self.queue.push(handle)
         except Exception as e:
@@ -983,7 +990,8 @@ class ServingGateway:
                                            adapter_id=getattr(entry, "adapter_id",
                                                               None),
                                            sample=getattr(entry, "sample", None),
-                                           schema=schema)
+                                           schema=schema,
+                                           breakpoints=getattr(entry, "cache_breakpoints", ()))
                 if schema is not None:
                     for token in made:  # the DFA stands where the stream does
                         self.engine.advance_schema(uid, token)

@@ -18,7 +18,7 @@ from tools import hash_step_programs
 PRESETS = ("debug", "mixtral-debug", "gpt2-debug", "opt-debug", "bloom-debug", "neox-debug",
            "gptj-debug", "falcon-debug", "moonlight-debug", "longcat-flash-debug",
            "minicpm-sala-debug", "nemotron-h-debug", "lfm2-debug", "jamba-debug",
-           "solar-open2-debug", "laguna-debug", "ouro-debug")
+           "solar-open2-debug", "laguna-debug", "ouro-debug", "granite-hybrid-debug")
 
 
 def _params(preset, shapes_only=False):
@@ -131,13 +131,14 @@ def test_the_one_router_gives_each_kinds_reference_picks_and_weights(name):
 SHARE_KINDS = {"longcat-flash-debug": model_runner.LongcatKind,
                "nemotron-h-debug": model_runner.NemotronHKind,
                "solar-open2-debug": model_runner.SolarOpen2Kind,
-               "laguna-debug": model_runner.LagunaKind}
+               "laguna-debug": model_runner.LagunaKind,
+               "granite-hybrid-debug": model_runner.GraniteHybridKind}
 
 
 def test_the_kinds_behind_a_share_count_their_passes_and_no_other_kind_does():
     """``n_share_passes`` (PR 53: the passes a step's held picks took through
     the grouped matmul, summed over its expert layers) rides the step counts
-    of the four kinds whose router has more columns than the rank holds, right
+    of the five kinds whose router has more columns than the rank holds, right
     behind ``EXPERT_COUNTS``; the share of every column (LFM2's, which says
     only that a padding row picks nothing) and the kinds without a share count
     what they counted. (That a step's record carries the number: each of the

@@ -112,7 +112,8 @@ def test_the_layer_may_be_traced_inside_a_scan(pool):
     ((2, 5, 8, 8, 16), 2, 5, False),                # N is not whole 128-lane vregs
     ((2, 5, 8, 12, 128), 2, 5, False),              # P is not whole sublane tiles
     ((2, 5, 8, 8, 128), 3, 5, False),               # heads do not divide into groups
-    ((5, 129, 128, 64, 128), 1, 129, False),        # a 4 MB tile four times over the budget
+    ((9, 137, 128, 64, 128), 1, 137, True),         # granite4-h-small-sessions: tiles in a group
+    ((2, 5, 1, 8192, 128), 1, 5, False),            # one head's [P, N] over the fitted tile
     ((5, 2049, 128, 64, 128), 8, 2049, False),      # the rows' decays overflow SMEM
 ])
 def test_kernel_supported_refuses_what_it_cannot_tile(shape, groups, rows, ok):
@@ -125,6 +126,40 @@ def test_kernel_supported_refuses_what_it_cannot_tile(shape, groups, rows, ok):
                     ((rows, shape[2]), jnp.float32), ((rows, shape[2], shape[3]), jnp.float32))))
         with pytest.raises(ValueError, match="state step kernel needs"):
             jax.eval_shape(lambda *a: ssm_state_step(*a, interpret=False), *args)
+
+
+@pytest.mark.parametrize("shape,groups,heads", [
+    ((5, 129, 128, 64, 128), 8, 16),        # nemotron3-super-agents: a tile is a group, as it was
+    ((9, 137, 128, 64, 128), 1, 16),        # one group of 128 heads: eight tiles of the same 512 KB
+    ((2, 5, 8, 8, 128), 2, 4), ((2, 5, 64, 64, 128), 2, 32), ((2, 5, 96, 64, 128), 1, 16)])
+def test_a_tile_is_a_group_where_four_fit_and_the_fitted_size_inside_one(shape, groups, heads):
+    assert ssm_state.tile_heads(shape, groups) == heads
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_tiles_inside_a_group_are_the_reference(monkeypatch, case, groups):
+    """A budget so small that a group's tile does not fit: ``Ht`` = 2 heads a
+    tile, two or four tiles sharing a group's ``b`` and ``c`` row (one group:
+    Granite 4.0-H's ``mamba_n_groups`` 1), against ``xla_ssm_state_step``."""
+    head = P * N * 4
+    monkeypatch.setattr(ssm_state, "TILE_VMEM_BYTES", 4 * (H // groups) * head - 1)
+    monkeypatch.setattr(ssm_state, "FITTED_TILE_BYTES", 2 * head)
+    slots = NS + 2 + groups           # a pool no other test traces the kernel at
+    pool = jax.random.normal(jax.random.PRNGKey(3), (LM, slots, H, P, N), jnp.float32)
+    assert ssm_state.tile_heads(pool.shape, groups) == 2
+    live, fresh = CASES[case]
+    slot, new, here, c, b, decay, left = step_rows(live, fresh, seed=5)
+    rows = (slot, new, here, c[:, :groups], b[:, :groups], decay, left)
+    want_pool, want_seen = xla_ssm_state_step(pool, jnp.int32(1), *rows)
+    got_pool, got_seen = ssm_state_step(pool, jnp.int32(1), *rows, interpret=True)
+    named = sorted(live.values())
+    if named:
+        assert rel_err(np.asarray(got_pool)[1, named], np.asarray(want_pool)[1, named]) < 1e-6
+        assert rel_err(got_seen, want_seen) < 1e-5
+    others = [s for s in range(slots) if s not in named]
+    assert np.array_equal(np.asarray(got_pool)[1, others], np.asarray(pool)[1, others])
+    assert np.array_equal(np.asarray(got_pool)[[0, 2]], np.asarray(pool)[[0, 2]])
 
 
 def test_the_choice_follows_the_backend_and_the_shapes(monkeypatch):
@@ -167,11 +202,14 @@ def _compiled(fn, args, **jit_options):
         compilation_cache.reset_cache()
 
 
-def test_mosaic_takes_the_cells_shape_and_the_pool_is_aliased(one_chip):
-    """Compiled for a described v5e (nothing runs): the kernel lowers at
-    ``nemotron3-super-agents``' shape, the pool comes back as the buffer it
-    came in and the program holds no temporary of its size."""
-    shape, rows, groups = (5, 129, 128, 64, 128), 129, 8
+@pytest.mark.parametrize("shape,rows,groups", [
+    ((5, 129, 128, 64, 128), 129, 8),       # nemotron3-super-agents
+    ((9, 137, 128, 64, 128), 49, 1)],       # granite4-h-small-sessions: one group, tiles inside it
+    ids=["nemotron", "granite"])
+def test_mosaic_takes_the_cells_shape_and_the_pool_is_aliased(one_chip, shape, rows, groups):
+    """Compiled for a described v5e (nothing runs): the kernel lowers at the
+    cells' shapes, the pool comes back as the buffer it came in and the
+    program holds no temporary of its size."""
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

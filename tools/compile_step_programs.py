@@ -4,7 +4,7 @@
     JAX_PLATFORMS=cpu python3 tools/compile_step_programs.py ouro-2.6b \
         [--rows 16,256,512] [--blocks 368] [--block-size 16] [--seqs 16] [--table 32] \
         [--over '{"num_hidden_layers": 22}'] [--shapes '2048,2048|2048,5632|5632,2048'] \
-        [--out DIR]
+        [--slots 136] [--out DIR]
 
 No chip: libtpu's compile-only client (the ``on-chip-measurement`` guide's
 section 2) compiles ``model_runner.ragged_forward`` on ``ShapeDtypeStruct``s
@@ -48,6 +48,9 @@ def main():
     parser.add_argument("--table", type=int, default=32, help="blocks a sequence's table holds")
     parser.add_argument("--over", default="{}", help="JSON of overrides of the preset's config")
     parser.add_argument("--shapes", default="2048,2048|2048,5632|5632,2048")
+    parser.add_argument("--slots", type=int, default=None,
+                        help="a kind with a slot of state a sequence (kv+slots): the slot pool's "
+                             "slots, the prefix cache's among them (default: --seqs)")
     parser.add_argument("--out", default=None)
     args = parser.parse_args()
 
@@ -72,9 +75,13 @@ def main():
     model = models.build_model(args.preset, **json.loads(args.over))
     cfg = model.config
     kind = model_runner.kind_of(cfg)
-    if kind.state_kind != "kv":
+    if kind.state_kind not in ("kv", "kv+slots"):
         raise SystemExit(f"{args.preset}: a {kind.state_kind!r} state has pools of its own shapes; "
-                         f"this tool lays the two key-value pools only")
+                         f"this tool lays the two key-value pools and a slot pool only")
+    extra = None
+    if kind.slot_state:     # the kind's own tree beside the pools, a slot a sequence
+        extra = jax.tree.map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: kind.extra_state(cfg, args.blocks, args.slots or args.seqs, jnp.bfloat16)))
     params = jax.tree.map(
         lambda x: sds(x.shape, jnp.bfloat16),
         jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
@@ -88,13 +95,16 @@ def main():
                  "token_pos": sds((rows,), jnp.int32),
                  "block_tables": sds((args.seqs + 1, args.table), jnp.int32),
                  "last_index": sds((args.seqs,), jnp.int32), "num_tokens": sds((), jnp.int32)}
+        if kind.seq_rows:
+            batch["seq_state"] = sds((args.seqs + 1, kind.seq_rows), jnp.int32)
         choice = AttentionChoice("pallas_paged")
         start = time.time()
-        step = jax.jit(lambda p, kc, vc, b: model_runner.ragged_forward(
-            p, kc, vc, b, cfg, jnp.bfloat16, attn_impl=choice), donate_argnums=(1, 2))
-        compiled = step.lower(params, *pools, batch).compile()
+        step = jax.jit(lambda p, kc, vc, b, x: model_runner.ragged_forward(
+            p, kc, vc, b, cfg, jnp.bfloat16, attn_impl=choice, extra=x),
+            donate_argnums=(1, 2, 4))
+        compiled = step.lower(params, *pools, batch, extra).compile()
         print(f"{args.preset} rows={rows}: compiled in {time.time() - start:.1f} s, "
-              f"{dict(choice.selected)}")
+              f"{dict(choice.selected)} state step {dict(choice.state_step)}")
         print(" ", compiled.memory_analysis())
         text = compiled.as_text()
         if args.out:

@@ -291,6 +291,8 @@ LOCKING_METHODS = {
         "match_len": ("PrefixCacheManager._lock", "TierManager._lock",
                       "HostKVStore._lock"),
         "release_lease": ("PrefixCacheManager._lock",),
+        "snapshot_of": ("PrefixCacheManager._lock",),
+        "snapshot_slot": ("PrefixCacheManager._lock",),
         "release": ("PrefixCacheManager._lock", "TierManager._lock",
                     "HostKVStore._lock"),
         "invalidate_for_version": ("PrefixCacheManager._lock",

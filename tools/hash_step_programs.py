@@ -22,7 +22,7 @@ import jax.numpy as jnp
 PRESETS = ("debug", "mixtral-debug", "gpt2-debug", "opt-debug", "bloom-debug", "neox-debug",
            "gptj-debug", "falcon-debug", "moonlight-debug", "longcat-flash-debug",
            "minicpm-sala-debug", "nemotron-h-debug", "lfm2-debug", "jamba-debug",
-           "solar-open2-debug", "laguna-debug", "ouro-debug")
+           "solar-open2-debug", "laguna-debug", "ouro-debug", "granite-hybrid-debug")
 SEQS, TABLE, BLOCKS = 4, 12, 64
 
 

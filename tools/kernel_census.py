@@ -125,7 +125,9 @@ for the parent checkout's, which is not (PERF.md, PR 37).
 
 ``--ssm`` times ``ssm_state_step`` at ``nemotron3-super-agents``' shape (5
 layers of 129 slots of 128 x 64 x 128 float32, the layer traced in a scan, the
-pool donated) with 32, 64 and 128 live sequences: ms a layer, GB/s of the
+pool donated) with 32, 64 and 128 live sequences, and at
+``granite4-h-small-sessions``' (9 layers of 137 slots, **one group**: tiles of
+16 heads inside it; 16, 32 and 48 live; records ``ssm-state-granite-*``): ms a layer, GB/s of the
 bytes a step has to move (a live slot once in and once out), share of 819 -
 the MXU form, the plain float32 lane sum, the copies alone, and
 ``xla_ssm_state_step``'s three passes beside them (PERF.md, PR 39; ~1 min).
@@ -836,18 +838,28 @@ def live_rows_sweep(parent_dir, shares):
         yield name, record
 
 
-SSM_LIVE = (32, 64, 128)
-SSM_SHAPE = (5, 128, 128, 64, 128, 8)   # M layers, slots, heads, P, N, groups
+# name -> (M layers, slots, heads, P, N, groups), the live sequences timed: nemotron3-super-agents'
+# (a tile is a group of 16 heads) and granite4-h-small-sessions' (one group: eight tiles of 16
+# heads inside it, 48 live slots beside the prefix cache's 88)
+SSM_SHAPES = {"": ((5, 128, 128, 64, 128, 8), (32, 64, 128)),
+              "granite-": ((9, 136, 128, 64, 128, 1), (16, 32, 48))}
 
 
 def ssm_state_classes():
-    """Yields one record a number of live sequences: the state step at
-    ``nemotron3-super-agents``' shape (5 ``M`` layers of 128 + 1 slots of
-    128 x 64 x 128 float32 in 8 groups, 129 sequence rows), **all five
-    layers a call** with the layer traced inside a ``lax.scan`` as the step
+    """Yields one record a shape and a number of live sequences: the state
+    step at ``nemotron3-super-agents``' shape (5 ``M`` layers of 128 + 1 slots
+    of 128 x 64 x 128 float32 in 8 groups, 129 sequence rows) and at
+    ``granite4-h-small-sessions``' (9 layers of 136 + 1 slots, **one group**:
+    records ``ssm-state-granite-*``), **all the layers a
+    call** with the layer traced inside a ``lax.scan`` as the step
     programs have it and the pool donated: the kernel with each unit, and
     ``xla_ssm_state_step`` beside it. ms a layer, GB/s of the bytes the
     step has to move (``live x 2 x H x P x N x 4``), share of 819."""
+    for name, (shape, lives) in SSM_SHAPES.items():
+        yield from _ssm_state_shape(name, shape, lives)
+
+
+def _ssm_state_shape(name, shape, lives):
     import numpy as np
 
     import jax
@@ -855,7 +867,7 @@ def ssm_state_classes():
 
     from deepspeed_tpu.ops.pallas import ssm_state as ss
 
-    Lm, slots, H, P, N, G = SSM_SHAPE
+    Lm, slots, H, P, N, G = shape
     S = slots + 1
     rng = np.random.default_rng(39)
     fill = jax.jit(lambda key: jax.random.normal(key, (Lm, S, H, P, N), jnp.float32))
@@ -867,7 +879,7 @@ def ssm_state_classes():
             return jax.lax.scan(one, pool, jnp.arange(Lm, dtype=jnp.int32))
         return jax.jit(run, donate_argnums=0)
 
-    for live in SSM_LIVE:
+    for live in lives:
         slot, here, fresh = np.zeros(S, np.int32), np.zeros(S, bool), np.ones(S, bool)
         slot[:live] = rng.permutation(np.arange(1, S))[:live]
         here[:live], fresh[:live] = True, False
@@ -903,9 +915,9 @@ def ssm_state_classes():
         for unit in ("mxu", "vpu", "none"):
             record[unit] = timed(lambda *a, unit=unit: ss.ssm_state_step(*a, unit=unit,
                                                                          interpret=False))
-        if live == SSM_LIVE[-1]:
+        if live == lives[-1]:
             record["xla"] = timed(ss.xla_ssm_state_step, calls=5)
-        yield f"ssm-state-{live}", record
+        yield f"ssm-state-{name}{live}", record
 
 
 # ``--scan``: jamba2-3b-chatloop's Mamba layers (layers, slots, state columns, channels)
