@@ -299,7 +299,7 @@ def test_windowed_flash_kernels_against_a_masked_softmax(seq, window, block_q, b
     """(d): ``flash_window_fwd`` / ``_dkv`` / ``_dq``, interpreted, forward and
     the gradients of q, k and v: float32 inputs, so 5e-6 (the online softmax's
     other order of sums)."""
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention, window_block_pairs
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention, flash_schedule
     rng = np.random.default_rng(seq + window)
     q, k, v = (jnp.asarray(rng.standard_normal((2, seq, 2, 32)), jnp.float32) for _ in range(3))
     seg = jnp.asarray(np.sort(rng.integers(0, 3, (2, seq)), axis=1), jnp.int32) if segments else None
@@ -318,8 +318,8 @@ def test_windowed_flash_kernels_against_a_masked_softmax(seq, window, block_q, b
     np.testing.assert_allclose(got, want, atol=5e-6)
     for g, w in zip(got_grads, want_grads):
         np.testing.assert_allclose(g, w, atol=5e-6)
-    band, causal = window_block_pairs(seq, window, block_q, block_k)
-    assert band <= causal
+    band, causal = (flash_schedule(seq, w, True, block_q, block_k) for w in (window, None))
+    assert band["tiles"] <= causal["tiles"] and band["needed"] <= causal["needed"]
     if window == seq:
         full = flash_attention(q, k, v, causal=True, block_q=block_q, block_k=block_k,
                                segment_ids=seg, interpret=True, force_pallas=True)
@@ -327,8 +327,19 @@ def test_windowed_flash_kernels_against_a_masked_softmax(seq, window, block_q, b
 
 
 def test_the_cell_walks_15_of_36_block_pairs():
-    from deepspeed_tpu.ops.pallas.flash_attention import window_block_pairs
-    assert window_block_pairs(8192, 1024) == (15, 36)
+    """... and inside them the backward kernels compute 1.5 times the band's
+    pairs in pieces of 512, two thirds of that under a mask; the forward walks
+    a block a piece, all fifteen crossed: twice the pairs, all masked."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_schedule
+    band, full = flash_schedule(8192, 1024), flash_schedule(8192)
+    assert (band["tiles"], full["tiles"]) == (15, 36)
+    assert band["pairs"] == {"skipped": 49 * 1024 * 1024, "whole": 0, "crossed": 15 * 1024 * 1024}
+    assert band["needed"] == 1024 * 1025 // 2 + 7168 * 1024
+    back = flash_schedule(8192, 1024, backward=True)
+    assert back["pairs"] == {"skipped": (256 - 45) * 512 * 512, "whole": 15 * 512 * 512,
+                             "crossed": 30 * 512 * 512}
+    assert round(back["computed_over_needed"], 2) == 1.5
+    assert round(band["computed_over_needed"], 2) == 2.0
 
 
 def test_positions_by_kind_and_a_window_of_the_whole_sequence():

@@ -1385,23 +1385,34 @@ def _share_parts(T, k, D, cap, held, idx, vals, dtype):
     return out
 
 
-def flash_window_classes():
-    """``mellum2-12b-moe8k-x4``'s attention calls alone (PR 58): one sequence of
-    8192 x 32 heads x 128 (the key-value heads already repeated, as the training
-    block hands them over), the windowed kernels at a window of 1024 beside the
-    causal ones - forward, and forward + backward (``dkv`` and ``dq``) - each
-    from a loop inside one program. Yields the time a call, the block pairs the
-    grid visits, and the share of the bf16 peak of the operations the band or
-    the triangle NEEDS (``benchmark/readers/mellum.py``)."""
+FLASH_CLASSES = (("window_8192x32x128", 8192, 1024),     # mellum2-12b-moe8k-x4's three band layers
+                 ("causal_8192x32x128", 8192, None),     # ... and its full layer
+                 ("causal_4096x32x128", 4096, None))     # mistral7b-zero3-x4's eleven layers
+
+
+def flash_window_classes(parent_dir):
+    """The training cells' attention calls alone (PR 58; PR 62: Mistral's shape,
+    the schedule by class, the parent's kernels and the pieces swept): one
+    sequence of 8192 or 4096 x 32 heads x 128 (the key-value heads already
+    repeated, as the training block hands them over), a window of 1024 beside
+    the causal kernels - forward, and forward + backward (``dkv`` and ``dq``) -
+    each from a loop inside one program. Yields the time a call, the schedule
+    (``flash_schedule`` of the forward and of the backward kernels: pairs
+    skipped, whole and crossed, computed over needed, masked over computed -
+    the forward's pairs, every one masked, are what all three kernels computed
+    before PR 62), and the
+    share of the bf16 peak of the operations the band or the triangle NEEDS
+    (``benchmark/readers/mellum.py``); the same for the kernels of the checkout
+    under ``parent_dir`` and for this tree's at other numbers of pieces a side
+    of a block in the forward and in the backward kernels."""
     import jax
     import jax.numpy as jnp
-    from benchmark.readers import mellum as work
     import importlib
+    from benchmark.readers import mellum as work
     fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")   # the module, not
-    S, H, d, W, LOOPS = 8192, 32, 128, 1024, 4                                  # the function
+    H, d, LOOPS = 32, 128, 4                                                    # the function
     peak = 197e12
-    q, k, v = (jax.random.normal(key, (1, S, H, d), jnp.bfloat16)
-               for key in jax.random.split(jax.random.PRNGKey(0), 3))
+    parent = _parent_kernel(parent_dir, "flash_attention")
 
     def looped(fn):
         def run(q, k, v):
@@ -1410,37 +1421,51 @@ def flash_window_classes():
             return jax.lax.fori_loop(0, LOOPS, turn, jnp.zeros((), jnp.float32))
         return jax.jit(run)
 
-    def forward(window):
-        return lambda q, k, v: jnp.sum(fa.flash_attention(
+    def forward(module, window):
+        return lambda q, k, v: jnp.sum(module.flash_attention(
             q, k, v, causal=True, window=window, force_pallas=True, interpret=False)[0, 0, 0, :2]
             .astype(jnp.float32)) * 0
 
-    def both(window):
+    def both(module, window):
         def fn(q, k, v):
-            grads = jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+            grads = jax.grad(lambda *a: jnp.sum(module.flash_attention(
                 *a, causal=True, window=window, force_pallas=True, interpret=False)
                 .astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
             return sum(jnp.sum(g[0, 0, 0, :2].astype(jnp.float32)) for g in grads) * 0
         return fn
 
-    band, causal = fa.window_block_pairs(S, W)
-    for name, window, pairs in (("window", W, band), ("causal", None, causal)):
-        need = work.attention_pairs(S, window)
-        record = {"block_pairs_visited": pairs}
+    def timed(module, window, S, qkv):
+        need, out = work.attention_pairs(S, window), {}
         for what, make, backward in (("fwd", forward, False), ("fwd_bwd", both, True)):
             try:
-                ms = _ms_a_call(looped(make(window)), q, k, v, calls=5) / LOOPS
+                ms = _ms_a_call(looped(make(module, window)), *qkv, calls=5) / LOOPS
                 flops = work.attention_flops(need, H, d, backward=backward)
-                record[what] = {"ms": ms, "needed_tflops": flops / 1e12,
-                                "peak_share": 100 * flops / peak / (ms / 1e3)}
+                out[what] = {"ms": ms, "needed_tflops": flops / 1e12,
+                             "peak_share": 100 * flops / peak / (ms / 1e3)}
             except Exception as e:
-                record[what] = {"refused": f"{type(e).__name__}: {e}"[:600]}
-        if window is not None:      # the same answer as the XLA reference's mask, at 2048
-            small = tuple(x[:, :2048, :4] for x in (q, k, v))
-            got = fa.flash_attention(*small, causal=True, window=W, force_pallas=True, interpret=False)
-            want = fa.flash_attention(*small, causal=True, window=W, force_pallas=False)
-            record["rel_err_vs_masked_softmax"] = float(f"{rel_err(got, want):.3e}")
-        yield f"flash_{name}_8192x32x128", record
+                out[what] = {"refused": f"{type(e).__name__}: {e}"[:600]}
+        return out
+
+    for name, S, window in FLASH_CLASSES:
+        qkv = tuple(jax.random.normal(key, (1, S, H, d), jnp.bfloat16)
+                    for key in jax.random.split(jax.random.PRNGKey(0), 3))
+        record = {"schedule": {"forward": fa.flash_schedule(S, window),
+                               "backward": fa.flash_schedule(S, window, backward=True)}}
+        record.update(timed(fa, window, S, qkv))
+        if parent is not None:
+            record["parent"] = timed(parent, window, S, qkv)
+        was = fa._FORWARD_PIECES, fa._BACKWARD_PIECES
+        for pieces in ((1, 1), (1, 2), (2, 2), (1, 4), (4, 4)):
+            if pieces != was:
+                fa._FORWARD_PIECES, fa._BACKWARD_PIECES = pieces
+                record["pieces_fwd_%d_bwd_%d" % pieces] = timed(fa, window, S, qkv)
+        fa._FORWARD_PIECES, fa._BACKWARD_PIECES = was
+        # the same answer as the XLA reference's mask, at 2048
+        small = tuple(x[:, :2048, :4] for x in qkv)
+        got = fa.flash_attention(*small, causal=True, window=window, force_pallas=True, interpret=False)
+        want = fa.flash_attention(*small, causal=True, window=window, force_pallas=False)
+        record["rel_err_vs_masked_softmax"] = float(f"{rel_err(got, want):.3e}")
+        yield f"flash_{name}", record
 
 
 def moe_rows_classes(T=32768, D=2304, n_held=65536, interpret=False):
@@ -1576,7 +1601,7 @@ def main():
     if moe_rows:
         section, records = "moe_rows", moe_rows_classes()
     elif flash_window:
-        section, records = "flash_window", flash_window_classes()
+        section, records = "flash_window", flash_window_classes(parent_dir)
     elif paged1:
         section, records = "paged_group1", paged_group1_classes()
     elif share:
